@@ -285,34 +285,32 @@ class PagedKVPool:
         # layout the Pallas paged-attention kernel DMAs (batch dims of its
         # MXU contractions must lead), and the natural axis to shard over
         # `tp` (each chip holds its head shard of every page).
-        zeros = partial(
-            jnp.zeros,
+        # Born sharded: a pool that only fits spread over the mesh must
+        # never exist whole on device 0 first.
+        self.kv = jnp.zeros(
             (2, num_layers, num_kv_heads, num_slots, head_dim),
             dtype=dtype,
+            device=sharding,
         )
-        if sharding is not None:
-            self.kv = jax.device_put(zeros(), sharding)
-        else:
-            self.kv = zeros()
         # Per-(token, head) symmetric scales for quantized pools: value ≈
         # int8 * scale (ops/quant.py). Same [2, L, Hkv, slots] geometry as
         # the data minus head_dim — shards identically over `tp`, and the
         # per-layer pages view is again a pure reshape.
         self.kv_scale = None
         if quant is not None:
-            sc = jnp.zeros((2, num_layers, num_kv_heads, num_slots), jnp.float32)
-            if sharding is not None:
+            scale_sharding = None
+            if isinstance(sharding, jax.sharding.NamedSharding):
                 # Scale sharding mirrors the data's head axis; the slot and
                 # trailing axes are replicated the same way.
-                from jax.sharding import NamedSharding, PartitionSpec
-
-                if isinstance(sharding, NamedSharding):
-                    spec = tuple(sharding.spec) + (None,) * 5
-                    sc = jax.device_put(
-                        sc,
-                        NamedSharding(sharding.mesh, PartitionSpec(*spec[:4])),
-                    )
-            self.kv_scale = sc
+                spec = tuple(sharding.spec) + (None,) * 5
+                scale_sharding = jax.sharding.NamedSharding(
+                    sharding.mesh, jax.sharding.PartitionSpec(*spec[:4])
+                )
+            self.kv_scale = jnp.zeros(
+                (2, num_layers, num_kv_heads, num_slots),
+                jnp.float32,
+                device=scale_sharding,
+            )
 
     @property
     def num_pages(self) -> int:

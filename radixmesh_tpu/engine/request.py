@@ -82,6 +82,9 @@ class Request:
     )
     lock_node: object = None  # TreeNode protected while RUNNING
     cancelled: bool = False  # aborted by Engine.cancel (output is partial)
+    # Set when the engine step serving this request raised: the message
+    # the runner fails the request with (server/http_frontend.py).
+    error: str | None = None
     # Resume-admission (crash recovery, server/recovery.py): the last
     # ``resume_offset`` tokens of ``prompt`` are output the FIRST life of
     # this request already delivered to the client — replayed through

@@ -6,7 +6,7 @@ admissible request to completion, chunk loop and all) and only then one
 decode step. A long prompt therefore monopolized the device for its
 entire prefill while every running stream stalled — the ``prefill_convoy``
 stall cause the token timeline attributes, and the reason the "wide"
-workload's p50 TTFT sat at 5x "base" (BENCH_FULL_r05).
+workload's p50 TTFT sat at 5x "base" (r05 CPU record).
 
 This module is the Sarathi-Serve/Orca answer, kept as PURE host-side
 policy so its invariants are unit-testable without a device:

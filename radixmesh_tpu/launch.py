@@ -25,12 +25,23 @@ from radixmesh_tpu.utils.logging import configure_logger, get_logger
 __all__ = ["main"]
 
 
-def _apply_platform_env() -> None:
-    """Re-assert the operator's platform choice via jax.config (shared
-    sitecustomize-override fix, ``utils/platform.py``)."""
-    from radixmesh_tpu.utils.platform import pin_platform
+def _log_devices(log, when: str) -> None:
+    """One line naming the hardware this process computes on — platform,
+    kind, count as JAX reports them — and each device's peak memory so
+    far, so a start-up log says what it started ON and what it took."""
+    from radixmesh_tpu.obs.step_plane import describe_devices
 
-    pin_platform()
+    dev = describe_devices()
+    peaks = ", ".join(
+        f"dev{m['id']} peak {m['peak_bytes_in_use'] / 2**30:.2f} GiB "
+        f"of {m['bytes_limit'] / 2**30:.2f} GiB"
+        for m in dev["memory"]
+    )
+    log.info(
+        "%s: platform=%s device_kind=%s count=%d%s",
+        when, dev["platform"], dev["device_kind"], dev["count"],
+        f" ({peaks})" if peaks else "",
+    )
 
 
 def _configure_tracing(args: argparse.Namespace, node: str = "") -> None:
@@ -77,7 +88,6 @@ def _dump_trace(args: argparse.Namespace, log) -> None:
 
 
 def _run_node(args: argparse.Namespace) -> int:
-    _apply_platform_env()
     import jax
 
     from radixmesh_tpu.cache.kv_pool import PagedKVPool
@@ -260,7 +270,12 @@ def _run_node(args: argparse.Namespace) -> int:
 
         model = cfg.model
         log.info("initializing model %s...", model.get("preset", "llama3-tiny"))
-        params = init_params(mcfg, jax.random.PRNGKey(int(model.get("seed", 0))))
+        _log_devices(log, "devices")
+        params = init_params(
+            mcfg,
+            jax.random.PRNGKey(int(model.get("seed", 0))),
+            weight_quant=model.get("weight_quant"),
+        )
         engine = Engine(
             mcfg,
             params,
@@ -542,7 +557,6 @@ def _run_node(args: argparse.Namespace) -> int:
 
 
 def _run_serve(args: argparse.Namespace) -> int:
-    _apply_platform_env()
     import jax
 
     from radixmesh_tpu.engine.engine import Engine
@@ -554,13 +568,18 @@ def _run_serve(args: argparse.Namespace) -> int:
     _configure_tracing(args, node="serve")
     cfg = get_config(args.model)
     log.info("initializing %s (%d layers)...", args.model, cfg.n_layers)
+    _log_devices(log, "devices")
     if args.weights:
         from radixmesh_tpu.models.hf_io import load_hf_checkpoint
 
         log.info("loading HF checkpoint from %s", args.weights)
         params = load_hf_checkpoint(args.weights, cfg)
     else:
-        params = init_params(cfg, jax.random.PRNGKey(args.seed))
+        # Drawn directly in the serving form (W8A16 when asked), a layer
+        # at a time: the float pytree of a 7B model never exists.
+        params = init_params(
+            cfg, jax.random.PRNGKey(args.seed), weight_quant=args.weight_quant
+        )
     tokenizer = None
     if args.tokenizer:
         from radixmesh_tpu.server.tokenizer import load_tokenizer
@@ -620,6 +639,7 @@ def _run_serve(args: argparse.Namespace) -> int:
             ),
         )
         log.info("SLO control plane enabled (%d tenants)", len(tenants))
+    _log_devices(log, "weights + KV pool resident")
     frontend = ServingFrontend(
         engine, host=args.host, port=args.http_port,
         profile_dir=args.profile_dir, tokenizer=tokenizer, slo=slo_cfg,
@@ -948,7 +968,8 @@ def main(argv: list[str] | None = None) -> int:
     serve.add_argument(
         "--peak-tflops", type=float, default=None,
         help="nominal accelerator peak for the MFU estimate (default: "
-        "detected from the jax device kind; 1.0 off-accelerator)",
+        "looked up from the jax device kind — an unknown accelerator is "
+        "an error; 1.0 on a CPU)",
     )
     serve.add_argument(
         "--kv-quant", choices=["int8"], default=None,
@@ -957,8 +978,8 @@ def main(argv: list[str] | None = None) -> int:
     serve.add_argument(
         "--weight-quant", choices=["int8"], default=None,
         help="W8A16 weights: int8 storage + per-out-channel scales "
-             "(halves the decode weight stream; llama3-8b fits one 16 GB "
-             "v5e)",
+             "(halves the decode weight stream; random init draws them "
+             "directly in this form, so qwen2-7b starts on one 16 GB v5e)",
     )
     serve.add_argument(
         "--spec-decode-tokens", type=int, default=0,

@@ -1,7 +1,7 @@
 """The mesh doctor: a rule engine over the observability planes.
 
 Every diagnosis since PR 9 was a human reading raw artifacts — the
-prefill convoy (BENCH_FULL_r05's 1 756 ms wide-shape p50 TTFT), the
+prefill convoy (a 1 756 ms wide-shape p50 TTFT in the r05 CPU record), the
 hot-shard skew (16.3, OBS_r09), the paged small-batch gap — all found
 by eyeball. This module closes the loop: it CONSUMES the substrate
 (FleetView health + shard heat, the phase attributor's per-shape
@@ -18,7 +18,7 @@ schema contract — see :data:`RULE_EVIDENCE_FIELDS`):
   AND its owner set (the item-2 rebalancer's trigger input).
 - ``prefill_convoy`` — one request shape's exclusive prefill-phase
   share of e2e over threshold while slower than the rest of the
-  traffic: names the convoying shape (the BENCH_FULL_r05 pathology,
+  traffic: names the convoying shape (the r05 wide-shape pathology,
   now machine-detected).
 - ``restore_park_stall`` — requests parked in RESTORING behind a slow
   restore lane (live parked count + queued restores, or the

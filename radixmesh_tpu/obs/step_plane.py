@@ -1,8 +1,9 @@
 """TPU step attribution: per-wave token accounting, pad fraction, MFU.
 
-BENCH_FULL_r04 says the TPU is ~2% utilized (mfu ≈ 0.021) and p99 TTFT
-sits in the seconds — but nothing in the repo could say WHERE a prefill
-or decode wave's wall-clock goes, or how much of each launch is padding.
+The r04 chip record (removed in PR 21) put the TPU at ~2% utilization
+(mfu ≈ 0.021) with p99 TTFT in the seconds — but nothing in the repo
+could say WHERE a prefill or decode wave's wall-clock goes, or how much
+of each launch is padding.
 This module is the measurement leg ROADMAP item 2 (tree-sourced
 speculative decoding + chunked prefill) gates its before/after on:
 
@@ -30,8 +31,8 @@ Accounting is OFF by default (``Engine(step_accounting=True)`` /
 one-branch-when-off contract — a single ``is not None`` test — which
 ``tests/test_trace_plane.py`` re-proves at these call sites.
 
-Import-light (stdlib only at module scope): the peak-FLOPs lookup
-imports jax lazily and degrades to a nominal figure off-accelerator.
+Import-light (stdlib only at module scope): the device lookups import
+jax lazily.
 """
 
 from __future__ import annotations
@@ -45,6 +46,7 @@ __all__ = [
     "PEAK_TFLOPS_BY_DEVICE",
     "DEFAULT_PEAK_TFLOPS",
     "detect_peak_tflops",
+    "describe_devices",
     "analytic_flops_per_token",
     "StepAccounting",
 ]
@@ -60,25 +62,58 @@ PEAK_TFLOPS_BY_DEVICE = {
     "tpu v5p": 459.0,
     "tpu v6e": 918.0,
 }
-# Off-accelerator (CPU tests, interpret mode): a nominal 1 TFLOP/s so
-# MFU stays finite and comparable across runs on the same host — the
-# value is labeled an estimate everywhere it surfaces.
+# CPU only (tests, interpret mode): a nominal 1 TFLOP/s so MFU stays
+# finite and comparable across runs on the same host — the value is
+# labeled an estimate everywhere it surfaces. Never applied to an
+# accelerator: an unknown accelerator kind is an error.
 DEFAULT_PEAK_TFLOPS = 1.0
 
 
 def detect_peak_tflops() -> float:
-    """Peak TFLOP/s of the default jax device, by device-kind lookup;
-    the nominal default when jax is absent or the kind is unknown."""
-    try:
-        import jax
+    """Peak TFLOP/s of the default jax device, by device-kind lookup.
+    The nominal figure on a CPU; an accelerator this table does not know
+    raises — a made-up peak would turn every MFU reading into fiction."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 — no backend = nominal figure
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return DEFAULT_PEAK_TFLOPS
+    kind = dev.device_kind.lower()
     for name, tflops in PEAK_TFLOPS_BY_DEVICE.items():
         if name in kind:
             return tflops
-    return DEFAULT_PEAK_TFLOPS
+    raise ValueError(
+        f"no peak FLOP/s known for device kind {dev.device_kind!r} "
+        f"(platform {dev.platform!r}); add it to PEAK_TFLOPS_BY_DEVICE or "
+        "pass peak_tflops explicitly"
+    )
+
+
+def describe_devices() -> dict:
+    """Platform, kind and count of this process's devices as JAX reports
+    them, plus each local device's memory counters where the backend
+    keeps them (``memory_stats()`` is None on a CPU) — the block
+    ``serve`` logs at start and ``/debug/state`` publishes, so every
+    number read off a node names the hardware it came from."""
+    import jax
+
+    local = jax.local_devices()
+    memory = []
+    for d in local:
+        stats = d.memory_stats()
+        if stats:
+            memory.append({
+                "id": d.id,
+                "bytes_in_use": stats.get("bytes_in_use"),
+                "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+                "bytes_limit": stats.get("bytes_limit"),
+            })
+    return {
+        "platform": local[0].platform,
+        "device_kind": local[0].device_kind,
+        "count": jax.device_count(),
+        "memory": memory,
+    }
 
 
 def analytic_flops_per_token(n_params: int) -> float:

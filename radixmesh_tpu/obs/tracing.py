@@ -9,8 +9,10 @@ Two levels:
 - :func:`profile` — capture a full device+host trace of a block into a
   TensorBoard logdir.
 
-Both degrade to no-ops if the profiler backend is unavailable (e.g. some
-CPU-only CI images), so production code can annotate unconditionally.
+Neither hides a missing profiler: ``jax.profiler`` is part of the one
+installation this repo runs on, so a failure to import or start it raises
+— a capture that silently recorded nothing is worse than an error
+(``POST /profile`` answers 500 with the message).
 """
 
 from __future__ import annotations
@@ -28,27 +30,20 @@ __all__ = ["annotate", "profile", "timed", "recorded"]
 @contextlib.contextmanager
 def annotate(name: str) -> Iterator[None]:
     """Named span on the profiler timeline (xplane TraceAnnotation)."""
-    try:
-        import jax.profiler as _prof
+    import jax.profiler as _prof
 
-        cm = _prof.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler backend missing
-        cm = contextlib.nullcontext()
-    with cm:
+    with _prof.TraceAnnotation(name):
         yield
 
 
 @contextlib.contextmanager
 def profile(log_dir: str) -> Iterator[None]:
     """Capture a device+host profiler trace of the block to ``log_dir``
-    (view with TensorBoard's profile plugin)."""
-    try:
-        import jax.profiler as _prof
+    (view with TensorBoard's profile plugin, or read the ``.xplane.pb``
+    with ``jax.profiler.ProfileData``)."""
+    import jax.profiler as _prof
 
-        cm = _prof.trace(log_dir)
-    except Exception:  # pragma: no cover - profiler backend missing
-        cm = contextlib.nullcontext()
-    with cm:
+    with _prof.trace(log_dir):
         yield
 
 

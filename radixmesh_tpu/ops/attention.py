@@ -306,14 +306,44 @@ def attend_chunk_hybrid(
     return out.transpose(0, 3, 1, 2, 4).reshape(B, C, Hq, D).astype(q.dtype)
 
 
+def kernel_shape_ok(head_dim: int) -> bool:
+    """Shapes the Pallas kernels can tile: ``head_dim`` must be a lane
+    multiple of 128 for the DMA tiling (llama3.2-1b's 64 and every tiny
+    preset's 32 are not — those models run the jnp reference on any
+    backend, and ``Engine`` says so once at start on a TPU)."""
+    return head_dim % 128 == 0
+
+
 def default_use_kernel(head_dim: int) -> bool:
-    """THE backend/shape policy for dispatching to the Pallas kernels,
-    shared by every paged-attention entry (single-chip, sharded, and the
-    pp stage bodies — policy drift between them silently changes which
-    backend runs the kernels): TPU-ish backends only, and ``head_dim``
-    must be a lane multiple of 128 for the DMA tiling (production models
-    are all D=128)."""
-    return jax.default_backend() not in ("cpu",) and head_dim % 128 == 0
+    """HOST-side answer to "will a launch from this process run the
+    Pallas kernels": a TPU backend and a tileable ``head_dim``. The
+    engine picks its launch path with it (``select_paged``, compact vs
+    fused decode) and publishes it in the dispatch records; the pp stage
+    bodies take it as their default. INSIDE a jitted launch the choice
+    follows the lowering target instead (``_by_lowering_target``), so an
+    ahead-of-time compile for a TPU topology from a CPU host lowers the
+    kernels, not the reference. The two agree whenever a launch runs on
+    this process's default backend — always, for an engine."""
+    return jax.default_backend() == "tpu" and kernel_shape_ok(head_dim)
+
+
+def _dispatch(use_kernel, head_dim: int, kernel_fn, reference_fn, *args):
+    """Kernel-vs-reference dispatch shared by every paged-attention entry
+    (single-chip and sharded). ``use_kernel=None`` follows the LOWERING
+    TARGET: the Pallas kernel when the computation is lowered for a TPU,
+    the jnp reference for any other target or an untileable ``head_dim``.
+    ``lax.platform_dependent`` resolves the branch at lowering time, so
+    the compiler never sees a conditional and only the chosen branch is
+    lowered (Mosaic is never asked to lower for a CPU). An explicit
+    ``use_kernel`` is honored as given (tests in interpret mode, the pp
+    stage bodies)."""
+    if use_kernel is None:
+        if not kernel_shape_ok(head_dim):
+            return reference_fn(*args)
+        return jax.lax.platform_dependent(
+            *args, tpu=kernel_fn, default=reference_fn
+        )
+    return (kernel_fn if use_kernel else reference_fn)(*args)
 
 
 def batch_bucket(n: int, floor: int = 1) -> int:
@@ -387,17 +417,14 @@ def paged_attention(
     lengths: jnp.ndarray,
     use_kernel: bool | None = None,
 ) -> jnp.ndarray:
-    """Decode attention over radix-cache pages. Dispatches to the Pallas
-    TPU kernel on TPU backends, the jnp reference elsewhere (CPU, or shapes
-    the TPU DMA can't tile: head_dim must be a lane multiple of 128 —
-    production models are all D=128)."""
-    if use_kernel is None:
-        use_kernel = default_use_kernel(q.shape[-1])
-    if use_kernel:
-        from radixmesh_tpu.ops.paged_attention import paged_attention_kernel
+    """Decode attention over radix-cache pages: the Pallas TPU kernel or
+    the jnp reference, chosen by ``_dispatch``."""
+    from radixmesh_tpu.ops.paged_attention import paged_attention_kernel
 
-        return paged_attention_kernel(q, k_pages, v_pages, page_table, lengths)
-    return attend_decode_ref(q, k_pages, v_pages, page_table, lengths)
+    return _dispatch(
+        use_kernel, q.shape[-1], paged_attention_kernel, attend_decode_ref,
+        q, k_pages, v_pages, page_table, lengths,
+    )
 
 
 def paged_attention_pool_kernel_sharded(
@@ -467,9 +494,8 @@ def paged_attention_pool(
     per-layer pool slice is ever materialized in HBM. With ``mesh``, the
     TPU kernel runs tensor-parallel via ``shard_map`` (heads sharded); the
     jnp path needs no wrapper — GSPMD partitions it from input shardings."""
-    if use_kernel is None:
-        use_kernel = default_use_kernel(q.shape[-1])
-    if use_kernel:
+
+    def kernel(q, kv_pages, page_table, lengths, layer, kv_scales):
         if mesh is not None and mesh.shape.get("tp", 1) > 1:
             return paged_attention_pool_kernel_sharded(
                 q, kv_pages, page_table, lengths, layer, mesh,
@@ -480,13 +506,21 @@ def paged_attention_pool(
         return paged_attention_pool_kernel(
             q, kv_pages, page_table, lengths, layer, kv_scales=kv_scales
         )
-    k_pages, v_pages = kv_pages[0, layer], kv_pages[1, layer]
-    if kv_scales is not None:
-        return attend_decode_ref(
-            q, k_pages, v_pages, page_table, lengths,
-            kv_scales[0, layer], kv_scales[1, layer],
-        )
-    return attend_decode_ref(q, k_pages, v_pages, page_table, lengths)
+
+    def reference(q, kv_pages, page_table, lengths, layer, kv_scales):
+        k_pages, v_pages = kv_pages[0, layer], kv_pages[1, layer]
+        if kv_scales is not None:
+            return attend_decode_ref(
+                q, k_pages, v_pages, page_table, lengths,
+                kv_scales[0, layer], kv_scales[1, layer],
+            )
+        return attend_decode_ref(q, k_pages, v_pages, page_table, lengths)
+
+    return _dispatch(
+        use_kernel, q.shape[-1], kernel, reference,
+        q, kv_pages, page_table, lengths, jnp.asarray(layer, jnp.int32),
+        kv_scales,
+    )
 
 
 def paged_attention_pool_bucketed(
@@ -602,17 +636,17 @@ def paged_chunk_attention(
     interpret: bool = False,
     kv_scales: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """Chunk-prefill attention with backend dispatch, mirroring
-    ``paged_decode_attention``: the Pallas chunk kernel on TPU backends
-    (lane-aligned heads), the jnp ``attend_chunk_hybrid`` elsewhere. The
-    kernel derives causal masks from ``prior_lengths`` + chunk offsets,
-    which is exact for the canonical ``q_positions`` every serving path
-    produces (chunked prefill AND the speculative verify chunk); the jnp
-    path masks against ``q_positions`` directly. With ``mesh`` carrying a
-    tp axis the kernel runs per-chip on its head shard via shard_map."""
-    if use_kernel is None:
-        use_kernel = default_use_kernel(q.shape[-1])
-    if use_kernel:
+    """Chunk-prefill attention with the ``_dispatch`` choice, mirroring
+    ``paged_decode_attention``: the Pallas chunk kernel or the jnp
+    ``attend_chunk_hybrid``. The kernel derives causal masks from
+    ``prior_lengths`` + chunk offsets, which is exact for the canonical
+    ``q_positions`` every serving path produces (chunked prefill AND the
+    speculative verify chunk); the jnp path masks against ``q_positions``
+    directly. With ``mesh`` carrying a tp axis the kernel runs per-chip on
+    its head shard via shard_map."""
+
+    def kernel(q, k_cur, v_cur, kv_pages, page_table, q_positions,
+               prior_lengths, kv_lengths, layer, kv_scales):
         if mesh is not None and mesh.shape.get("tp", 1) > 1:
             return paged_chunk_attention_kernel_sharded(
                 q, k_cur, v_cur, kv_pages, page_table, prior_lengths,
@@ -625,10 +659,19 @@ def paged_chunk_attention(
             q, k_cur, v_cur, kv_pages, page_table, prior_lengths,
             kv_lengths, layer, interpret=interpret, kv_scales=kv_scales,
         )
-    return attend_chunk_hybrid(
+
+    def reference(q, k_cur, v_cur, kv_pages, page_table, q_positions,
+                  prior_lengths, kv_lengths, layer, kv_scales):
+        return attend_chunk_hybrid(
+            q, k_cur, v_cur, kv_pages, page_table, q_positions,
+            prior_lengths, kv_lengths, layer, kv_block_pages=kv_block_pages,
+            kv_scales=kv_scales,
+        )
+
+    return _dispatch(
+        use_kernel, q.shape[-1], kernel, reference,
         q, k_cur, v_cur, kv_pages, page_table, q_positions, prior_lengths,
-        kv_lengths, layer, kv_block_pages=kv_block_pages,
-        kv_scales=kv_scales,
+        kv_lengths, jnp.asarray(layer, jnp.int32), kv_scales,
     )
 
 
@@ -711,12 +754,13 @@ def paged_decode_attention(
     On TPU this is a single aliased ``pallas_call`` — the pool buffer flows
     through unchanged (zero copies in the layer scan; the XLA scatter +
     separate kernel read used to cost a full pool copy per layer). The jnp
-    fallback (CPU/odd shapes) scatters then attends the oracle way.
-    Returns ``(attn [B, Hq, D], kv_pages)``.
+    reference (any other lowering target, untileable head_dim) scatters
+    then attends the oracle way. Returns ``(attn [B, Hq, D], kv_pages)``
+    — plus the updated ``kv_scales`` for int8 pools.
     """
-    if use_kernel is None:
-        use_kernel = default_use_kernel(q.shape[-1])
-    if use_kernel:
+
+    def kernel(q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
+               kv_scales):
         if mesh is not None and mesh.shape.get("tp", 1) > 1:
             return paged_decode_fused_sharded(
                 q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
@@ -728,29 +772,37 @@ def paged_decode_attention(
             q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
             kv_scales=kv_scales,
         )
-    page = kv_pages.shape[4]
-    pg, off = slots // page, slots % page
-    # Force ``layer`` to an advanced (array) index: the advanced indices
-    # (layer, pg, off) are then non-adjacent, so the broadcast batch axis
-    # lands FIRST → target [B, Hkv, D] regardless of how layer was passed.
-    layer = jnp.asarray(layer)
-    if kv_scales is not None:
-        from radixmesh_tpu.ops.quant import quantize_kv
 
-        kq, ks = quantize_kv(k_new, axis=-1)
-        vq, vs = quantize_kv(v_new, axis=-1)
-        kv_pages = kv_pages.at[0, layer, :, pg, off].set(kq)
-        kv_pages = kv_pages.at[1, layer, :, pg, off].set(vq)
-        kv_scales = kv_scales.at[0, layer, :, pg, off].set(ks)
-        kv_scales = kv_scales.at[1, layer, :, pg, off].set(vs)
+    def reference(q, k_new, v_new, kv_pages, slots, page_table, lengths,
+                  layer, kv_scales):
+        page = kv_pages.shape[4]
+        pg, off = slots // page, slots % page
+        # ``layer`` is an ARRAY index: the advanced indices (layer, pg,
+        # off) are then non-adjacent, so the broadcast batch axis lands
+        # FIRST → target [B, Hkv, D].
+        if kv_scales is not None:
+            from radixmesh_tpu.ops.quant import quantize_kv
+
+            kq, ks = quantize_kv(k_new, axis=-1)
+            vq, vs = quantize_kv(v_new, axis=-1)
+            kv_pages = kv_pages.at[0, layer, :, pg, off].set(kq)
+            kv_pages = kv_pages.at[1, layer, :, pg, off].set(vq)
+            kv_scales = kv_scales.at[0, layer, :, pg, off].set(ks)
+            kv_scales = kv_scales.at[1, layer, :, pg, off].set(vs)
+            attn = attend_decode_ref(
+                q, kv_pages[0, layer], kv_pages[1, layer], page_table,
+                lengths, kv_scales[0, layer], kv_scales[1, layer],
+            )
+            return attn, kv_pages, kv_scales
+        kv_pages = kv_pages.at[0, layer, :, pg, off].set(k_new)
+        kv_pages = kv_pages.at[1, layer, :, pg, off].set(v_new)
         attn = attend_decode_ref(
-            q, kv_pages[0, layer], kv_pages[1, layer], page_table, lengths,
-            kv_scales[0, layer], kv_scales[1, layer],
+            q, kv_pages[0, layer], kv_pages[1, layer], page_table, lengths
         )
-        return attn, kv_pages, kv_scales
-    kv_pages = kv_pages.at[0, layer, :, pg, off].set(k_new)
-    kv_pages = kv_pages.at[1, layer, :, pg, off].set(v_new)
-    attn = attend_decode_ref(
-        q, kv_pages[0, layer], kv_pages[1, layer], page_table, lengths
+        return attn, kv_pages
+
+    return _dispatch(
+        use_kernel, q.shape[-1], kernel, reference,
+        q, k_new, v_new, kv_pages, slots, page_table, lengths,
+        jnp.asarray(layer, jnp.int32), kv_scales,
     )
-    return attn, kv_pages

@@ -1,0 +1,239 @@
+"""Kernel-vs-oracle parity at a caller-chosen geometry.
+
+The unit tests (``tests/test_ops.py``) pin the Pallas kernels against
+their jnp oracles in interpret mode at D=32, G in {1, 2, 4}. That proves
+the algorithm; it says nothing about what Mosaic compiles for a real
+model's head geometry on silicon. This module is the same comparison as
+a callable: ``chip_smoke.py`` runs it on the chip at the served geometry
+(Qwen2-7B: Hq 28, Hkv 4 → G 7, D 128, page 16, chunk 512) for the
+kernels the default ``serve`` flags reach, ``scripts/kernelbench.py``
+runs it for the variants only a flag selects, and the CPU rehearsal runs
+it in interpret mode at tiny size.
+
+Every case compares a kernel called DIRECTLY with the reference branch
+of its dispatcher in ``ops/attention.py`` (``use_kernel=False``) on the
+same seeded inputs: ragged lengths (1 token, mid-page, page-aligned,
+full), half the rows on consecutive page runs (the coalesced
+one-descriptor DMA plan) and half on permuted pages (the per-page plan).
+A case that raises — a kernel Mosaic refuses — is recorded with the
+compiler's message, not skipped.
+"""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+import numpy as np
+
+from radixmesh_tpu.ops.attention import (
+    paged_attention_pool,
+    paged_chunk_attention,
+    paged_decode_attention,
+)
+from radixmesh_tpu.ops.paged_attention import (
+    paged_attention_pool_kernel,
+    paged_chunk_attention_kernel,
+    paged_decode_fused_kernel,
+)
+from radixmesh_tpu.ops.quant import quantize_kv
+
+__all__ = ["TOLERANCE", "DEFAULT_CASES", "FLAG_CASES", "check_kernels"]
+
+# Absolute AND relative bound on |kernel - oracle| per output element.
+# Both sides read the same bf16 (or int8 + f32 scale) K/V and accumulate
+# in f32; they differ by (a) the final rounding of an O(1) output to
+# bf16, one ulp = 2^-8 ≈ 4e-3 relative, and (b) the MXU's bf16 passes
+# over the f32 q·k and p·v products inside the kernel, ~2^-9 relative per
+# score, i.e. ~1e-2 on a softmax weight. 2e-2 covers both with a margin
+# of about two; K/V held in anything narrower than the pool dtype (fp8's
+# 2^-4 steps) lands at 6e-2 and fails.
+TOLERANCE = 2e-2
+
+# Kernels the default ``serve`` flags reach (bf16 pool, auto grid).
+DEFAULT_CASES = ("fused_decode", "chunk_prefill")
+# Variants only a flag selects: int8 KV (``--kv-quant int8``), the
+# per-head grids (``fuse_heads=False``, huge-Hkv configs), and the
+# read-only pool kernel (pp serving, the small-batch crossover).
+FLAG_CASES = (
+    "fused_decode_int8",
+    "chunk_prefill_int8",
+    "fused_decode_per_head",
+    "fused_decode_int8_per_head",
+    "pool_read",
+    "pool_read_int8",
+    "pool_read_per_head",
+    "pool_read_int8_per_head",
+)
+
+
+def _inputs(hq, hkv, head_dim, page, batch, chunk, max_pages, seed):
+    """Seeded inputs shared by every case: a 2-layer bf16 pool and its
+    int8 twin, a page table (even rows consecutive runs, odd rows
+    permuted), ragged lengths, decode and chunk queries."""
+    rng = np.random.default_rng(seed)
+    L, P = 2, batch * max_pages + 2
+    kv = rng.standard_normal((2, L, hkv, P * page, head_dim)).astype(np.float32)
+    kv8, scales = quantize_kv(jnp.asarray(kv), axis=-1)
+    # Even rows own a consecutive page run; odd rows share out the rest,
+    # shuffled. Rows never overlap: each decode row writes its own slot.
+    pages = np.empty((batch, max_pages), np.int64)
+    pages[0::2] = (
+        np.arange(0, batch, 2)[:, None] * max_pages + np.arange(max_pages)
+    )
+    rest = np.setdiff1d(np.arange(batch * max_pages), pages[0::2].ravel())
+    pages[1::2] = rng.permutation(rest).reshape(-1, max_pages)
+    full = max_pages * page
+    ragged = [1, page + 3, 5 * page, full, full - 1, 2 * page, 3, full // 2 + 1]
+    lengths = np.asarray(
+        [min(ragged[b % len(ragged)], full) for b in range(batch)], np.int32
+    )
+    pos = lengths - 1
+    slots = pages[np.arange(batch), pos // page] * page + pos % page
+    # Chunk prefill: prior context per row (none, mid-page, long), the
+    # last row's chunk only partly valid.
+    prior_cycle = [0, page // 2 + 1, full - chunk, 2 * page]
+    prior = np.asarray(
+        [max(0, min(prior_cycle[b % 4], full - chunk)) for b in range(batch)],
+        np.int32,
+    )
+    valid = np.full((batch,), chunk, np.int32)
+    valid[-1] = max(1, chunk // 3)
+
+    def bf16(shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    return {
+        "kv": jnp.asarray(kv, jnp.bfloat16).reshape(
+            2, L, hkv, P, page, head_dim
+        ),
+        "kv8": kv8.reshape(2, L, hkv, P, page, head_dim),
+        "scales": scales.reshape(2, L, hkv, P, page),
+        "pt": jnp.asarray(pages, jnp.int32),
+        "lengths": jnp.asarray(lengths),
+        "slots": jnp.asarray(slots, jnp.int32),
+        "q": bf16((batch, hq, head_dim)),
+        "k_new": bf16((batch, hkv, head_dim)),
+        "v_new": bf16((batch, hkv, head_dim)),
+        "qc": bf16((batch, chunk, hq, head_dim)),
+        "kc": bf16((batch, chunk, hkv, head_dim)),
+        "vc": bf16((batch, chunk, hkv, head_dim)),
+        "prior": jnp.asarray(prior),
+        "kvlen": jnp.asarray(prior + valid),
+        "chunk_valid": np.arange(chunk)[None, :] < valid[:, None],
+    }
+
+
+def _err(got, want, mask=None) -> float:
+    """Largest ``|got - want| / (1 + |want|)`` over the compared elements
+    (one number against one bound covers the absolute and the relative
+    tolerance); inf when either side holds a non-finite value."""
+    g = np.asarray(got, np.float32)
+    w = np.asarray(want, np.float32)
+    if mask is not None:
+        g, w = g[mask], w[mask]
+    if not (np.isfinite(g).all() and np.isfinite(w).all()):
+        return float("inf")
+    return float(np.max(np.abs(g - w) / (1.0 + np.abs(w))))
+
+
+def _run_case(name: str, x: dict, interpret: bool) -> dict:
+    int8 = "int8" in name
+    fuse = False if name.endswith("per_head") else None
+    kv = x["kv8"] if int8 else x["kv"]
+    sc = x["scales"] if int8 else None
+    layer = 1
+    if name.startswith("fused_decode"):
+        got = paged_decode_fused_kernel(
+            x["q"], x["k_new"], x["v_new"], kv, x["slots"], x["pt"],
+            x["lengths"], layer, interpret=interpret, kv_scales=sc,
+            fuse_heads=fuse,
+        )
+        want = paged_decode_attention(
+            x["q"], x["k_new"], x["v_new"], kv, x["slots"], x["pt"],
+            x["lengths"], layer, use_kernel=False, kv_scales=sc,
+        )
+        # The fused kernel also WRITES this token's K/V row: the pool it
+        # returns must equal the reference's scatter everywhere, not just
+        # where attention read it — exactly for a bf16 pool; within one
+        # int8 step for a quantized one (the kernel wrapper quantizes the
+        # f32 row, the reference the bf16 row).
+        pool_diff = float(np.max(np.abs(
+            np.asarray(got[1], np.float32) - np.asarray(want[1], np.float32)
+        )))
+        res = {
+            "max_err": _err(got[0], want[0]),
+            "pool_write_diff": pool_diff,
+            "pool_write_ok": pool_diff <= (1.0 if int8 else 0.0),
+        }
+        if int8:
+            res["max_err"] = max(res["max_err"], _err(got[2], want[2]))
+        return res
+    if name.startswith("chunk_prefill"):
+        pos = x["prior"][:, None] + jnp.arange(x["qc"].shape[1])[None]
+        got = paged_chunk_attention_kernel(
+            x["qc"], x["kc"], x["vc"], kv, x["pt"], x["prior"], x["kvlen"],
+            layer, interpret=interpret, kv_scales=sc,
+        )
+        want = paged_chunk_attention(
+            x["qc"], x["kc"], x["vc"], kv, x["pt"], pos, x["prior"],
+            x["kvlen"], layer, use_kernel=False, kv_scales=sc,
+            # The oracle scans KV in blocks of this many pages, which
+            # must divide the page-table width.
+            kv_block_pages=int(np.gcd(x["pt"].shape[1], 32)),
+        )
+        return {"max_err": _err(got, want, x["chunk_valid"])}
+    if name.startswith("pool_read"):
+        got = paged_attention_pool_kernel(
+            x["q"], kv, x["pt"], x["lengths"], layer, interpret=interpret,
+            kv_scales=sc, fuse_heads=fuse,
+        )
+        want = paged_attention_pool(
+            x["q"], kv, x["pt"], x["lengths"], layer, use_kernel=False,
+            kv_scales=sc,
+        )
+        return {"max_err": _err(got, want)}
+    raise ValueError(f"unknown kernel case {name!r}")
+
+
+def check_kernels(
+    cases: tuple[str, ...],
+    *,
+    hq: int,
+    hkv: int,
+    head_dim: int,
+    page: int,
+    batch: int = 8,
+    chunk: int = 512,
+    max_pages: int = 64,
+    interpret: bool = False,
+    seed: int = 0,
+) -> list[dict]:
+    """Run each named case at the given geometry; one result dict per
+    case: ``{"kernel", "ok", "max_err", "tolerance"}`` with ``max_err``
+    the largest ``|kernel - oracle| / (1 + |oracle|)`` (the fused cases
+    add the pool-write comparison), or ``{"kernel", "ok": False,
+    "error"}`` carrying the exception (the compiler's message when Mosaic
+    refuses the kernel)."""
+    if chunk > max_pages * page:
+        raise ValueError(
+            f"chunk {chunk} exceeds the table's {max_pages * page} tokens"
+        )
+    x = _inputs(hq, hkv, head_dim, page, batch, chunk, max_pages, seed)
+    out = []
+    for name in cases:
+        try:
+            res = _run_case(name, x, interpret)
+        except Exception as exc:  # noqa: BLE001 — the verdict records the compiler's message; callers fail on ok=False
+            out.append({
+                "kernel": name, "ok": False,
+                "error": f"{type(exc).__name__}: {exc}"[:2000],
+            })
+            continue
+        out.append({
+            "kernel": name,
+            "ok": bool(
+                res["max_err"] <= TOLERANCE and res.get("pool_write_ok", True)
+            ),
+            "tolerance": TOLERANCE,
+            **res,
+        })
+    return out

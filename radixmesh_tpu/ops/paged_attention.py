@@ -52,7 +52,9 @@ Design (shared by both grids):
   (per-head grid) or [Hkv, G, D] batched (fused-heads grid).
 
 Entry points (all with jnp oracles in ``ops/attention.py``, parity pinned
-by ``tests/test_ops.py`` in interpreter mode and on real TPU by bench.py):
+by ``tests/test_ops.py`` in interpreter mode and on the chip at the served
+geometry by ``ops/kernel_check.py``; each ``pallas_call`` carries a stable
+``radixmesh_*`` name so a device trace can be reduced per kernel):
 
 - ``paged_attention_pool_kernel`` — read-only attention over ``length``
   tokens already resident in pool pages.
@@ -91,12 +93,6 @@ __all__ = [
 
 # exp(finite - MASK) == 0 without the NaN risk of -inf - -inf.
 _MASK = -0.7 * float(np.finfo(np.float32).max)
-
-if not hasattr(pltpu, "CompilerParams"):
-    # jax < 0.5 ships the same dataclass as TPUCompilerParams; alias it so
-    # the kernels (written against the current name) import either way.
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 
 def _contig_flags(
     page_table: jnp.ndarray,  # [B, padded] int32 (already block-padded)
@@ -1006,6 +1002,7 @@ def paged_attention_pool_kernel(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
+        name="radixmesh_pool_attn",
     )(*args)
     return out.reshape(B, Hq, D).astype(q.dtype)
 
@@ -1086,6 +1083,7 @@ def _pool_kernel_mh(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
+        name="radixmesh_pool_attn_mh",
     )(*args)
     return out.reshape(B, Hq, D).astype(q.dtype)
 
@@ -1208,6 +1206,7 @@ def _fused_decode_mh(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
+        name="radixmesh_decode_fused_mh",
     )(*args)
     attn = out.reshape(B, Hq, D).astype(q.dtype)
     if quantized:
@@ -1377,6 +1376,7 @@ def paged_decode_fused_kernel(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
+        name="radixmesh_decode_fused",
     )(*args)
     kv_out, out = res
     attn = out.reshape(B, Hq, D).astype(q.dtype)
@@ -1661,6 +1661,7 @@ def paged_chunk_attention_kernel(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
+        name="radixmesh_chunk_attn",
     )(*args)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, C, Hq, D).astype(q.dtype)
 

@@ -2,11 +2,11 @@
 
 Decode is weight-streaming-bound: every step reads all dense weights from
 HBM once, so storing them int8 halves the dominant traffic — and halves
-resident weight bytes, which is what puts Llama-3-8B (~16 GB bf16) onto a
-single 16 GB v5e chip at all (VERDICT round-4 next-step #7: the north-star
-model on the actually-available silicon). The reference has no analogue
-(no model executor at all); this extends the TPU-first serving stack the
-same way int8 KV extends the pool.
+resident weight bytes, which is what puts an 8B-class model (~16 GB bf16)
+onto a single 16 GB v5e chip at all. The reference has no analogue (no
+model executor at all); this extends the TPU-first serving stack the same
+way int8 KV extends the pool. ``models.llama.init_params(weight_quant=
+"int8")`` draws random weights directly in this form, layer by layer.
 
 Scheme: symmetric per-OUTPUT-channel scales over each weight's
 contraction axis — ``scale[o] = amax_i |w[i, o]| / 127`` — applied AFTER
@@ -27,12 +27,10 @@ ride the same leading axes as their weight.
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 
 __all__ = [
     "quantize_weight",
     "quantize_params",
-    "random_w8_params",
     "LAYER_QUANT_WEIGHTS",
 ]
 
@@ -84,70 +82,3 @@ def quantize_params(params: dict) -> dict:
         out["lm_head"] = q
         out["lm_head_s"] = s
     return out
-
-
-def _np_quant(w: np.ndarray, axis: int):
-    amax = np.abs(w).max(axis=axis)
-    scale = np.maximum(amax, _EPS) / 127.0
-    q = np.clip(
-        np.round(w / np.expand_dims(scale, axis)), -127, 127
-    ).astype(np.int8)
-    return q, scale.astype(np.float32)
-
-
-def random_w8_params(cfg, seed: int = 0, dtype=jnp.bfloat16) -> dict:
-    """Random-init a model DIRECTLY in W8A16 form, on the host, one layer
-    at a time — so an 8B-class model can be benched on a 16 GB chip
-    without ever materializing its bf16 pytree on device (or its f32
-    pytree on host). Same ``1/sqrt(fan_in)`` init as
-    ``models.llama.init_params``; numpy leaves, ready for ``device_put``
-    or direct use (jit transfers them)."""
-    rng = np.random.default_rng(seed)
-    L, H = cfg.n_layers, cfg.hidden
-    qd, kvd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-
-    def stacked(in_dim: int, out_dim: int):
-        """[L, in, out] int8 + [L, out] scale, one layer resident at a
-        time (largest transient: one f32 layer slab)."""
-        qs = np.empty((L, in_dim, out_dim), np.int8)
-        ss = np.empty((L, out_dim), np.float32)
-        for i in range(L):
-            w = rng.standard_normal((in_dim, out_dim), dtype=np.float32)
-            w *= 1.0 / np.sqrt(in_dim)
-            qs[i], ss[i] = _np_quant(w, axis=0)
-        return qs, ss
-
-    layers: dict = {
-        "attn_norm": np.ones((L, H), _np_dtype(dtype)),
-        "mlp_norm": np.ones((L, H), _np_dtype(dtype)),
-    }
-    dims = {
-        "wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd), "wo": (qd, H),
-        "w_gate": (H, cfg.intermediate), "w_up": (H, cfg.intermediate),
-        "w_down": (cfg.intermediate, H),
-    }
-    for name, (i_dim, o_dim) in dims.items():
-        layers[name], layers[name + "_s"] = stacked(i_dim, o_dim)
-    if cfg.qkv_bias:
-        layers["bq"] = np.zeros((L, qd), _np_dtype(dtype))
-        layers["bk"] = np.zeros((L, kvd), _np_dtype(dtype))
-        layers["bv"] = np.zeros((L, kvd), _np_dtype(dtype))
-    emb = rng.standard_normal((cfg.vocab_size, H), dtype=np.float32)
-    emb *= 1.0 / np.sqrt(H)
-    eq, es = _np_quant(emb, axis=1)  # per-row (vocab) scales
-    params = {
-        "embed": eq,
-        "embed_s": es,
-        "final_norm": np.ones((H,), _np_dtype(dtype)),
-        "layers": layers,
-    }
-    if not cfg.tie_embeddings:
-        head = rng.standard_normal((H, cfg.vocab_size), dtype=np.float32)
-        head *= 1.0 / np.sqrt(H)
-        params["lm_head"], params["lm_head_s"] = _np_quant(head, axis=0)
-    return params
-
-
-def _np_dtype(dtype):
-    """numpy dtype for the norm/bias leaves (ml_dtypes supplies bf16)."""
-    return np.dtype(dtype)

@@ -19,8 +19,6 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from radixmesh_tpu.utils.platform import pin_platform
-
 __all__ = ["MultihostInfo", "init_multihost", "global_mesh"]
 
 
@@ -62,14 +60,12 @@ def init_multihost(
             f"{flags} --xla_force_host_platform_device_count="
             f"{local_device_count}"
         ).strip()
-    pin_platform()
+        # Virtual devices exist on the CPU platform only: rehearsal mode
+        # IS a CPU run unless the caller named another platform.
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
-    try:
-        plat = jax.config.read("jax_platforms")
-    except Exception:  # noqa: BLE001 — config name drift across jax versions
-        plat = os.environ.get("JAX_PLATFORMS")
-    if plat and "cpu" in str(plat):
+    if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
         # CPU processes have no ICI; collectives ride Gloo over TCP.
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(

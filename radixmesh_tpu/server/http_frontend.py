@@ -53,6 +53,7 @@ from radixmesh_tpu.obs.attribution import ensure_attributor
 from radixmesh_tpu.obs.blackbox import BlackBox
 from radixmesh_tpu.obs.doctor import MeshDoctor
 from radixmesh_tpu.obs.metrics import get_registry
+from radixmesh_tpu.obs.step_plane import describe_devices
 from radixmesh_tpu.obs.timeseries import TelemetryHistory
 from radixmesh_tpu.obs.trace_plane import get_recorder
 from radixmesh_tpu.policy.retry import jittered_retry_after
@@ -81,6 +82,15 @@ class EngineRunner:
             target=self._run, daemon=True, name="engine-runner"
         )
         self.log = get_logger("engine.runner")
+        # Steps that raised (see _fail_step): count + last message, both
+        # on /debug/state.
+        self.step_failures = 0
+        self.last_step_error: str | None = None
+        self._m_step_failures = get_registry().counter(
+            "radixmesh_engine_step_failures_total",
+            "engine steps that raised; each failed its in-flight requests",
+            ("engine",),
+        ).labels(engine=engine.name)
 
     def start(self) -> "EngineRunner":
         self._thread.start()
@@ -248,11 +258,34 @@ class EngineRunner:
                 if has_work:
                     try:
                         self.engine.step()
-                    except Exception:  # noqa: BLE001 — a bad request must not kill serving
-                        self.log.exception("engine step failed")
+                    except Exception as exc:  # noqa: BLE001 — serving boundary: report to the clients, keep answering
+                        self._fail_step(exc)
             if not has_work:
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
+            else:
+                # meshcheck: ok[sleep-audit] a zero-length yield, not a
+                # wait: threading.Lock is not fair — looping straight
+                # back, this thread re-takes the lock before a submitter
+                # parked on it is ever scheduled, and an arrival waits out
+                # the whole running batch instead of one step.
+                time.sleep(0)
+
+    def _fail_step(self, exc: Exception) -> None:
+        """A step that raised — a kernel the compiler refuses, a device
+        out of memory, a scheduler bug — is not retried: the same work
+        would raise again (recompiling each time) while its clients wait
+        out a timeout. Count it and finish every request the engine
+        holds with the error; requests submitted afterwards get a fresh
+        step."""
+        msg = f"{type(exc).__name__}: {exc}"
+        self.step_failures += 1
+        self.last_step_error = msg
+        self._m_step_failures.inc()
+        n = self.engine.cancel_all(error=msg)
+        self.log.exception(
+            "engine step failed; failed %d in-flight request(s)", n
+        )
 
 
 def _json_response(handler: BaseHTTPRequestHandler, code: int, obj: dict) -> None:
@@ -631,6 +664,8 @@ class ServingFrontend:
                     "decode_steps": eng.stats.decode_steps,
                     "finished": eng.stats.finished,
                     "preemptions": eng.stats.preemptions,
+                    "step_failures": self.runner.step_failures,
+                    "last_step_error": self.runner.last_step_error,
                     "hit_rate": round(eng.stats.hit_rate, 4),
                     # Histogram-derived (interpolated within buckets):
                     # bounded-memory estimates over the process lifetime,
@@ -649,6 +684,9 @@ class ServingFrontend:
                     "evictable_tokens": getattr(tree, "evictable_size_", None),
                     "protected_tokens": getattr(tree, "protected_size_", None),
                 },
+                # The hardware every number on this node came from, with
+                # live per-device memory counters (obs/step_plane.py).
+                "device": describe_devices(),
                 "trace": get_recorder().stats(),
                 # Per-bucket trace exemplars (obs/metrics.py): the fleet
                 # aggregator's HTTP peer transport reads this section to
@@ -695,6 +733,15 @@ class ServingFrontend:
                 # last wave, with its batch bucket — the visibility half
                 # of the ops/attention.py::select_paged crossover.
                 state["decode_dispatch"] = dispatch
+            if eng._last_prefill_path is not None:
+                # Which prefill launch the last admission wave took
+                # (dense / chunk / sp), and the attention a chunk launch
+                # runs here: Pallas kernel ("paged") or the jnp reference
+                # — the reference must never serve silently.
+                state["prefill_dispatch"] = {
+                    "path": eng._last_prefill_path,
+                    "chunk_attention": eng.chunk_attention,
+                }
             if eng.mesh is not None:
                 state["membership"] = _membership_state(eng.mesh)
             if self.slo_enabled:
@@ -784,15 +831,22 @@ class ServingFrontend:
                     f"capture-{int(time.time())}-"
                     f"{frontend._profile_seq:04d}",
                 )
+                stats = frontend.runner.engine.stats
                 with _profile(logdir):
+                    before = stats.decode_steps
                     # meshcheck: ok[sleep-audit] the sleep IS the
                     # requested jax.profiler capture window.
                     time.sleep(seconds)
+                    steps = stats.decode_steps - before
             except Exception as e:  # noqa: BLE001 — report, don't kill the handler
                 return 500, {"error": str(e)}
             finally:
                 frontend._profile_lock.release()
-            return 200, {"profiled_s": seconds, "logdir": logdir}
+            # decode_steps: what the window covered — a capture of an idle
+            # engine is a valid file with nothing to read in it.
+            return 200, {
+                "profiled_s": seconds, "logdir": logdir, "decode_steps": steps,
+            }
 
         self._run_profile = _run_profile
 
@@ -1102,6 +1156,14 @@ class ServingFrontend:
                         time.monotonic() - req.submit_time, cat="http",
                         output_tokens=len(tokens),
                     )
+                if req.error is not None:
+                    # The engine step serving this request raised
+                    # (EngineRunner._fail_step).
+                    _json_response(
+                        self, 500,
+                        {"error": req.error, "failed": True, "rid": req.rid},
+                    )
+                    return
                 if req.shed and not tokens:
                     # Dropped from the SLO queue before any work ran
                     # (dispatch-time deadline check or shutdown flush).
@@ -1173,6 +1235,8 @@ class ServingFrontend:
                             done_evt["text"] = frontend.tokenizer.decode(final)
                         if req.cancelled:
                             done_evt["cancelled"] = True
+                        if req.error is not None:
+                            done_evt["error"] = req.error
                         self.wfile.write(
                             f"data: {json.dumps(done_evt)}\n\n".encode()
                         )

@@ -3370,7 +3370,7 @@ def run_doctor_workload(
     b. **Convoying long-prompt burst**: ``long_prompt``-token requests
        served in small prefill waves spend most of their e2e in
        exclusive prefill time and run well slower than the short-shape
-       fleet — the BENCH_FULL_r05 pathology, seeded on purpose; the
+       fleet — the r05 wide-shape pathology, seeded on purpose; the
        doctor must name the convoying SHAPE bucket from the phase
        attributor's per-shape table.
     c. **Throttled restore lane**: host-tier prefixes re-requested

@@ -98,8 +98,6 @@ class _Client(threading.Thread):
 def run_soak(seconds: float, clients: int, n_conv: int, gen_len: int) -> dict:
     import jax
 
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS") or "cpu")
-
     from radixmesh_tpu.engine.engine import Engine
     from radixmesh_tpu.models.llama import ModelConfig, init_params
     from radixmesh_tpu.server.http_frontend import ServingFrontend

@@ -290,18 +290,18 @@ class TestSpecOnDecodeWorker:
         """Speculative decoding on the decode side of a disaggregated
         deployment: greedy outputs must match a spec-off decode worker."""
         prompt = [5, 1, 5, 1, 5, 1, 5, 1]
+        # Long enough for this model's greedy stream to repeat a bigram
+        # (the n-gram drafter needs one; with these weights it first
+        # happens at token 19).
+        sampling = SamplingParams(max_new_tokens=24)
         pw = make_prefill(model)
         dw_plain = make_decode(model)
-        want = dw_plain.submit(
-            pw.prefill_handoff(prompt, SamplingParams(max_new_tokens=10))
-        )
+        want = dw_plain.submit(pw.prefill_handoff(prompt, sampling))
         dw_plain.run_until_drained()
 
         pw2 = make_prefill(model)
         dw_spec = make_decode(model, spec_decode_tokens=3)
-        got = dw_spec.submit(
-            pw2.prefill_handoff(prompt, SamplingParams(max_new_tokens=10))
-        )
+        got = dw_spec.submit(pw2.prefill_handoff(prompt, sampling))
         dw_spec.run_until_drained()
         assert got.generated == want.generated
         # The equality must not be vacuous: speculation actually engaged.

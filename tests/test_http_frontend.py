@@ -262,6 +262,108 @@ class TestProfileEndpoint:
             f.close()
 
 
+    def test_missing_profiler_is_an_error_not_an_empty_capture(
+        self, tmp_path, monkeypatch
+    ):
+        """A profiler that cannot start answers 500 with the message — it
+        used to be swallowed into a 200 with nothing captured."""
+        import jax.profiler
+
+        def broken(_logdir):
+            raise RuntimeError("profiler backend missing")
+
+        monkeypatch.setattr(jax.profiler, "trace", broken)
+        cfg = ModelConfig.tiny()
+        eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                     num_slots=64, page_size=4, max_batch=1, name="http-prof3")
+        f = ServingFrontend(eng, port=0, profile_dir=str(tmp_path))
+        try:
+            with pytest.raises(urllib.error.HTTPError) as ei:
+                _post(f"http://127.0.0.1:{f.port}/profile", {"seconds": 0.1})
+            assert ei.value.code == 500
+            assert "profiler backend missing" in ei.value.read().decode()
+        finally:
+            f.close()
+
+
+class TestStepFailure:
+    """A step that raises fails its requests with the error and is
+    counted; it is not retried in a loop while the clients time out."""
+
+    def _frontend(self, name):
+        cfg = ModelConfig.tiny()
+        eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                     num_slots=256, page_size=4, max_batch=2, name=name)
+        return eng, ServingFrontend(eng, port=0)
+
+    def test_prefill_failure_answers_500_and_serving_continues(
+        self, monkeypatch
+    ):
+        eng, f = self._frontend("http-fail")
+        base = f"http://127.0.0.1:{f.port}"
+        real = eng._prefill_dense
+        free0 = eng.pool.free_slots
+        calls = []
+
+        def refused(*a, **k):
+            calls.append(1)
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        try:
+            monkeypatch.setattr(eng, "_prefill_dense", refused)
+            with pytest.raises(urllib.error.HTTPError) as ei:
+                _post(base + "/generate",
+                      {"input_ids": list(range(1, 20)), "max_tokens": 4})
+            assert ei.value.code == 500
+            body = json.loads(ei.value.read())
+            assert body["failed"] is True
+            assert "Mosaic failed to compile" in body["error"]
+            assert calls == [1], "the failing step was retried"
+            state = json.loads(_get(base + "/debug/state")[1])
+            assert state["engine"]["step_failures"] == 1
+            assert "Mosaic failed" in state["engine"]["last_step_error"]
+            # The member was mid-admission (out of `waiting`, not yet in a
+            # row): its pages and prefix lock came back.
+            assert eng.pool.free_slots == free0
+            assert not eng.has_work()
+            metrics = _get(base + "/metrics")[1].decode()
+            assert (
+                'radixmesh_engine_step_failures_total{engine="http-fail"} 1'
+                in metrics
+            )
+            # The next request gets a fresh step.
+            monkeypatch.setattr(eng, "_prefill_dense", real)
+            status, out = _post(
+                base + "/generate",
+                {"input_ids": list(range(1, 20)), "max_tokens": 4},
+            )
+            assert status == 200 and len(out["output_ids"]) == 4
+        finally:
+            f.close()
+
+    def test_decode_failure_fails_every_running_stream(self, monkeypatch):
+        from radixmesh_tpu.engine.request import RequestState, SamplingParams
+
+        eng, f = self._frontend("http-fail2")
+        try:
+            def boom(*a, **k):
+                raise MemoryError("RESOURCE_EXHAUSTED: out of HBM")
+
+            monkeypatch.setattr(eng, "_decode_once", boom)
+            reqs = [
+                f.runner.submit(list(range(i, i + 12)),
+                                SamplingParams(max_new_tokens=8))
+                for i in (1, 40)
+            ]
+            for req in reqs:
+                f.runner.wait(req, timeout=30.0)
+                assert req.state is RequestState.FINISHED
+                assert req.error == "MemoryError: RESOURCE_EXHAUSTED: out of HBM"
+            assert f.runner.step_failures >= 1
+        finally:
+            f.close()
+
+
 class TestGracefulShutdown:
     def test_close_cancels_stragglers(self):
         """Waiters must not hang on requests the stopped scheduler will
@@ -460,6 +562,15 @@ class TestDebugEndpoints:
         assert state["pool"]["num_slots"] == 512
         assert state["pool"]["free_slots"] <= state["pool"]["num_slots"]
         assert "trace" in state and state["trace"]["capacity"] > 0
+        # The hardware the node's numbers came from, as JAX reports it.
+        dev = jax.devices()[0]
+        assert state["device"] == {
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "count": jax.device_count(),
+            "memory": [],  # the CPU backend keeps no memory counters
+        }
+        assert state["engine"]["step_failures"] == 0
 
     def test_debug_requests_table(self, frontend):
         _post(

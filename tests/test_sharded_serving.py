@@ -46,6 +46,32 @@ def test_sharded_engine_matches_single_device(mesh):
     assert want == got
 
 
+def test_weights_and_pool_are_born_sharded(mesh):
+    """``init_params(mesh=)`` and the engine's pool never exist whole on
+    one device first: every tp-sharded leaf arrives as four quarter
+    shards (a model bigger than one chip has no other way in), with the
+    same values as the unsharded draw — and the engine's own placement
+    step leaves them where they are."""
+    sharded = init_params(CFG, jax.random.PRNGKey(0), mesh=mesh)
+    for name, full in (("wq", PARAMS["layers"]["wq"]), ("embed", None)):
+        leaf = sharded["layers"][name] if full is not None else sharded[name]
+        shards = {s.device.id: s.data.shape for s in leaf.addressable_shards}
+        assert len(shards) == 8  # dp=1 x sp=2 x tp=4: tp quarters, sp copies
+        tp_axis = {"wq": 2, "embed": 0}[name]
+        want = list(leaf.shape)
+        want[tp_axis] //= 4
+        assert set(shards.values()) == {tuple(want)}
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b: bool(jnp.array_equal(a, b)), sharded, PARAMS
+    ))
+    engine = Engine(
+        CFG, sharded, num_slots=1024, page_size=4, max_batch=4, device_mesh=mesh
+    )
+    assert engine.params["layers"]["wq"] is sharded["layers"]["wq"]
+    kv = engine.pool.kv
+    assert {s.data.shape[2] for s in kv.addressable_shards} == {1}  # Hkv 4 / tp 4
+
+
 def test_sharded_prefix_hit(mesh):
     """Cache publish + reuse work against the kv-head-sharded pool."""
     engine = Engine(

@@ -541,6 +541,26 @@ class TestStepAccounting:
         with pytest.raises(ValueError):
             acct.note_wave("warp", 1, 1, 1.0)
 
+    def test_peak_lookup_knows_the_device_or_raises(self, monkeypatch):
+        """A CPU gets the nominal figure, a listed TPU its published
+        peak, and an accelerator the table does not know is an error —
+        not a made-up 1 TFLOP/s every MFU reading would be scaled by."""
+        import jax
+
+        from radixmesh_tpu.obs.step_plane import detect_peak_tflops
+
+        assert detect_peak_tflops() == 1.0  # this process: cpu
+
+        class Dev:
+            platform = "tpu"
+            device_kind = "TPU v5 lite"
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+        assert detect_peak_tflops() == 197.0
+        Dev.device_kind = "TPU v9x"
+        with pytest.raises(ValueError, match="no peak FLOP/s known"):
+            detect_peak_tflops()
+
     def test_engine_reports_prefill_and_decode_waves(self):
         set_recorder(FlightRecorder(capacity=4096, sample=1.0))
         eng = _tiny_engine("steps-on", step_accounting=True, peak_tflops=1.0)

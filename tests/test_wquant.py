@@ -148,20 +148,23 @@ class TestEngineWeightQuant:
         assert got == want
 
 
-class TestRandomW8Params:
+class TestDirectW8Init:
     def test_decodes_through_engine(self, model):
-        """Host-side W8A16 random init (the 8B-on-one-chip bench path)
-        must produce a pytree the whole serving stack accepts."""
-        from radixmesh_tpu.ops.wquant import random_w8_params
+        """``init_params(weight_quant="int8")`` (the 7B-on-one-chip serve
+        path: weights drawn directly in W8A16 form, a layer at a time)
+        must produce a pytree the whole serving stack accepts — and the
+        engine's own quantize step must leave it alone."""
+        from radixmesh_tpu.models.llama import init_params
 
         cfg, _ = model
-        params = random_w8_params(cfg, seed=3, dtype=cfg.dtype)
-        assert params["layers"]["wq"].dtype == np.int8
-        assert params["embed"].dtype == np.int8
+        params = init_params(cfg, jax.random.PRNGKey(3), weight_quant="int8")
+        assert params["layers"]["wq"].dtype == jnp.int8
+        assert params["embed"].dtype == jnp.int8
         eng = Engine(
-            cfg, jax.tree.map(jnp.asarray, params), num_slots=256,
-            page_size=4, max_batch=2, max_seq_len=64,
+            cfg, params, num_slots=256, page_size=4, max_batch=2,
+            max_seq_len=64, weight_quant="int8",
         )
+        assert eng.params["layers"]["wq"] is params["layers"]["wq"]
         prompts = [
             np.random.default_rng(4).integers(0, cfg.vocab_size, 9).tolist()
         ]
@@ -169,20 +172,30 @@ class TestRandomW8Params:
         assert len(out[0]) == 5
 
     def test_matches_quantize_params_scheme(self, model):
-        """Same quantization scheme as quantize_params: per-out-channel
-        over the contraction axis, embed per row."""
-        from radixmesh_tpu.ops.wquant import random_w8_params
+        """Same layout as quantize_params: per-out-channel scales over
+        the contraction axis, embed per row — and the same 1/sqrt(fan_in)
+        init as the float path."""
+        from radixmesh_tpu.models.llama import init_params
+        from radixmesh_tpu.ops.wquant import quantize_params
 
         cfg, _ = model
-        p = random_w8_params(cfg, seed=0, dtype=cfg.dtype)
-        L = cfg.n_layers
-        assert p["layers"]["wq_s"].shape == (L, cfg.n_heads * cfg.head_dim)
-        assert p["layers"]["w_down_s"].shape == (L, cfg.hidden)
-        assert p["embed_s"].shape == (cfg.vocab_size,)
+        p = init_params(cfg, jax.random.PRNGKey(0), weight_quant="int8")
+        ref = quantize_params(init_params(cfg, jax.random.PRNGKey(0)))
+        assert jax.tree.map(lambda x: (x.shape, x.dtype), p) == jax.tree.map(
+            lambda x: (x.shape, x.dtype), ref
+        )
         deq = p["layers"]["wq"][0].astype(np.float32) * p["layers"]["wq_s"][0]
-        assert np.abs(deq).std() > 0  # non-degenerate init
-        # int8 payload actually saturates the range somewhere.
-        assert p["layers"]["wq"].max() == 127 or p["layers"]["wq"].min() == -127
+        np.testing.assert_allclose(
+            float(deq.std()) * np.sqrt(cfg.hidden), 1.0, rtol=0.1
+        )
+        # Every output channel saturates the int8 range (its amax → ±127).
+        assert (np.abs(np.asarray(p["layers"]["wq"])).max(axis=1) == 127).all()
+
+    def test_rejects_unknown_scheme(self, model):
+        from radixmesh_tpu.models.llama import init_params
+
+        with pytest.raises(ValueError, match="unknown weight quantization"):
+            init_params(model[0], jax.random.PRNGKey(0), weight_quant="int4")
 
 
 class TestW8Compositions:
