@@ -1,0 +1,5 @@
+"""``decode_step_device_ms`` in the closed-loop batch cell, where it moves
+``tokens_per_s`` (that cell reports no token-gap tail): the same reader."""
+from benchmarks.harness.registry import load_module
+
+read = load_module("layer_metrics", "decode_step_device_ms").read
