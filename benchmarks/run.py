@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The cell benchmark's one command.
 
-    python benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 One run of one cell of ``BENCHMARK.json``, measured from the client's
 side of the served HTTP path: start the configuration's server as a child
@@ -13,6 +13,17 @@ then the cell's own traffic for a stated time) -> a window of
 stdout is the result as one JSON object; earlier lines carry medians,
 sample counts and diagnostics. No TPU, too few chips, or a tree that
 lacks the program: no result line and a non-zero exit.
+
+``--trace 0`` prints the end-to-end metrics. ``--trace 2`` is a ``--trace
+0`` run followed by a short traced window in the same process: up to the
+end of the drain it does exactly what ``--trace 0`` does, and every
+end-to-end number and every check of ``correct`` comes from that state
+alone; then, with the load still running, the server's profiler is started
+and stopped once (thrown away: the first start's cost falls into no
+number), a capture of 3 s is taken, the load stops, the server exits, the
+capture is reduced and deleted, and the last line carries end-to-end and
+per-layer metrics side by side. ``--trace 1`` (one capture in the middle of
+the window, per-layer metrics only) is the older form of the same.
 
 ``--rehearse-cpu`` (this file's only extra switch) runs the same code on
 the CPU with the ``qwen2-tiny`` preset and every length divided by 32, to
@@ -37,13 +48,14 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 import numpy as np  # noqa: E402
 
-from benchmarks.harness import ledger, loadgen, registry, xplane  # noqa: E402
+from benchmarks.harness import hostspans, ledger, loadgen, registry, xplane  # noqa: E402
 from benchmarks.harness.opsbytes import kv_bytes_per_token  # noqa: E402
 from benchmarks.harness.peaks import peaks_for  # noqa: E402
 from benchmarks.harness.server import NoAccelerator, Server, child_env  # noqa: E402
 
 REHEARSAL_SHRINK = 32
 PLAN_TAIL_S = 30.0  # traffic goes on this long after the window
+CAPTURE_S = 3.0  # the profiler capture of --trace 1 and --trace 2
 _TOKEN_KEYS = ("system_len", "turn_tokens", "max_tokens")
 
 
@@ -266,6 +278,72 @@ def sleep_until(t: float) -> None:
         time.sleep(min(d, 0.5))
 
 
+def frozen(rec: ledger.Record) -> ledger.Record:
+    """A request's record as it stands now, for --trace 2: the load goes
+    on while the capture runs, and nothing that is sent or finishes then
+    may reach the measured numbers. The status is read first
+    (``ledger.finalize`` sets it last), so a copy that says ``ok`` is
+    whole; one still in flight is settled as ``load.stop()`` would."""
+    status = rec.status
+    out = copy.copy(rec)
+    out.token_t = list(rec.token_t)
+    out.status = "unfinished" if status == "inflight" else status
+    return out
+
+
+def end_to_end(metrics: list[dict], summary: dict, setup_s: float) -> dict:
+    """The end-to-end part of the result line — one code path for
+    ``--trace 0`` and ``--trace 2``: the window's ledger summary and the
+    set-up time, nothing of the capture."""
+    out = {}
+    for m in metrics:
+        value = setup_s if m["name"] == "setup_s" else summary.get(m["name"])
+        if value is not None:
+            out[m["name"]] = {"value": value, "unit": m["unit"]}
+    return out
+
+
+def capture_after_drain(base: str, prof_s: float) -> dict:
+    """--trace 2, after the end-of-drain snapshot and with the load still
+    running: start and stop the profiler once and throw that away, then
+    one capture of ``prof_s``. Returns the server's answer and, on this
+    process's clock, when the capture ran and the backlog at its ends."""
+    loadgen.get_json(base, "/debug/profile?seconds=0.2", 300.0)
+
+    def backlog() -> int:
+        eng = loadgen.get_json(base, "/debug/state")["engine"]
+        return eng["waiting"] + eng["batch_rows_active"]
+
+    b0, t_a = backlog(), time.monotonic()
+    answer = loadgen.get_json(base, f"/debug/profile?seconds={prof_s}", 300.0)
+    t_b = time.monotonic()
+    return {**answer, "t": (t_a, t_b), "backlog_start_end": [b0, backlog()]}
+
+
+def capture_line(captured: dict, records: list, server, t1: float,
+                 summary: dict, last_arrival_t: float | None) -> dict:
+    """What the ``timeline`` line says of the --trace 2 capture: when it
+    began relative to the window's end, the backlog at its two ends, the
+    tokens that arrived during it (against the window's rate: what a
+    capture costs), compilations inside it, and whether the plan's arrivals
+    had already stopped — then its per-step numbers are not the cell's."""
+    if "t" not in captured:
+        return {"error": captured.get("error")}
+    t_a, t_b = captured["t"]
+    tokens = sum(1 for r_ in records if r_.tag == "load"
+                 for t in r_.token_t if t_a <= t < t_b)
+    return {
+        "began_after_window_s": round(t_a - t1, 2),
+        "seconds": round(t_b - t_a, 3),
+        "decode_steps": captured.get("decode_steps"),
+        "backlog_start_end": captured["backlog_start_end"],
+        "tokens_per_s": tokens / (t_b - t_a),
+        "tokens_per_s_of_window": summary["tokens_per_s"],
+        "compiles": len(server.compiles_between(t_a, t_b)),
+        "arrivals_had_stopped": last_arrival_t is not None and t_a > last_arrival_t,
+    }
+
+
 def run(args) -> int:
     r = registry.resolve(args.workload)
     if args.rehearse_cpu:
@@ -323,6 +401,10 @@ def run(args) -> int:
                             request_timeout=float(cell.get("request_timeout_s", 180)))
         poller = Poller(base)
         t_start = load.start()
+        # An open loop's arrivals end with the plan (a closed loop cycles).
+        arrivals = getattr(plan, "arrivals", None)
+        last_arrival_t = (t_start + float(arrivals[-1])
+                          if arrivals is not None and len(arrivals) else None)
         poller.start()
         t0 = t_start + warm_s
         t1 = t0 + args.seconds
@@ -333,8 +415,8 @@ def run(args) -> int:
 
         captured: dict = {}
         tracer = None
-        if args.trace:
-            prof_s = min(3.0, max(0.2, args.seconds / 2.0))
+        prof_s = min(CAPTURE_S, max(0.2, args.seconds / 2.0))
+        if args.trace == 1:
 
             def capture():
                 sleep_until(t0 + (t1 - t0) / 2.0 - prof_s / 2.0)
@@ -354,10 +436,24 @@ def run(args) -> int:
         while load.pending_in(t0, t1) and time.monotonic() < deadline:
             time.sleep(0.1)
         drained_s = time.monotonic() - t1
-        records = load.stop()
-        if tracer is not None:
-            tracer.join(timeout=300.0)
-        end = snapshot(base)
+        if args.trace == 2:
+            # The measured state ends here; the load goes on for the capture.
+            with load._lock:
+                records = [frozen(r_) for r_ in load.records]
+            end = snapshot(base)
+            polls = list(poller.samples)
+            try:
+                captured = capture_after_drain(base, prof_s)
+            except (OSError, RuntimeError, ValueError) as e:
+                captured = {"error": str(e)}
+            all_records = load.stop()
+            final = snapshot(base)
+        else:
+            records = all_records = load.stop()
+            if tracer is not None:
+                tracer.join(timeout=300.0)
+            end = final = snapshot(base)
+            polls = poller.samples
         poller.stop()
     except NoAccelerator as e:
         note(f"no accelerator: {e}")
@@ -373,26 +469,29 @@ def run(args) -> int:
     limits = cell["limits"]
     summary = ledger.summarize(records, t0, t1, limits)
     in_win = server.compiles_between(t0, t1)
-    dev = end["state"]["device"]
+    dev = final["state"]["device"]  # memory peak: of the whole run
     peaks = None if args.rehearse_cpu else peaks_for(dev["device_kind"])
-    trace = None
+    trace = spans = None
     if args.trace:
         path = xplane.find_capture(profile_dir) if "error" not in captured else None
         if path is None:
             note(f"no profiler capture: {captured}")
             return 1
-        planes = xplane.load(path)
+        planes = hostspans.device_planes(path)  # = xplane.load, parsed once
         trace = xplane.reduce(planes, captured.get("profiled_s"))
+        spans = hostspans.of_capture(path)
         if args.dump_trace:
             os.makedirs(os.path.dirname(args.dump_trace) or ".", exist_ok=True)
             with open(args.dump_trace, "w") as fh:
                 json.dump({"lines": {p: {ln: len(ev) for ln, ev in ls.items()}
                                      for p, ls in planes.items()},
                            "fixture": xplane.fixture_slice(planes),
+                           "host_fixture": hostspans.fixture_slice(path),
+                           "host_spans": spans,
                            "reduced": trace}, fh)
     ctx = {
         "summary": summary, "records": records, "window": (t0, t1),
-        "before": before, "after": after, "polls": poller.samples,
+        "before": before, "after": after, "polls": polls,
         "compiles_in_window": in_win, "trace": trace, "config": config,
         "cell": cell, "peaks": peaks,
         "kv_bytes_per_token": kv_bytes_per_token(config),
@@ -438,7 +537,9 @@ def run(args) -> int:
     say({"diag": "timeline", "server_up_s": round(t_up - T_PROCESS, 2),
          "warm_shapes_s": round(t_shapes - t_up, 2), "probe_s": round(t_probe - t_shapes, 2),
          "warm_traffic_s": warm_s, "window_s": round(t1 - t0, 3),
-         "drained_s": round(drained_s, 2), "warm_shapes": warmed})
+         "drained_s": round(drained_s, 2), "warm_shapes": warmed,
+         **({"capture": capture_line(captured, all_records, server, t1, summary,
+                                     last_arrival_t)} if args.trace == 2 else {})})
     say({"diag": "window", **{k: v for k, v in summary.items()},
          "backlog_start_end": [snap["state"]["engine"]["waiting"]
                                + snap["state"]["engine"]["batch_rows_active"]
@@ -447,27 +548,28 @@ def run(args) -> int:
          "cached_over_resent": (cached_since / resent) if resent else None,
          "cached_over_resent_window_edges": (
              d_stats["cached_tokens"] / resent_window if resent_window else None),
-         "pool_polls_free_slots_evictable": [[f, e] for _, f, _, e in poller.samples][-12:],
+         "pool_polls_free_slots_evictable": [[f, e] for _, f, _, e in polls][-12:],
          "slo_tier": after["stats"].get("slo", {}).get("tier"),
          "slo_tier_events": after["stats"].get("slo", {}).get("tier_events")})
     say({"diag": "compiles", "total": len(server.compiles),
          "total_s": round(sum(c[2] for c in server.compiles), 2),
          "in_window": [[round(c[0] - t0, 2), c[1], round(c[2], 3)] for c in in_win][:40]})
     say({"diag": "correct", **checks, "probe": pr})
+    if spans is not None:
+        say({"diag": "host_spans", **spans})
 
-    wanted = r["per_layer"] if args.trace else r["end_to_end"]
-    metrics = {}
-    for m in wanted:
-        if not args.trace:
-            value = setup_s if m["name"] == "setup_s" else summary.get(m["name"])
-        else:
-            value = registry.load_module("layer_metrics", m["name"]).read(ctx)
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    metrics = {} if args.trace == 1 else end_to_end(r["end_to_end"], summary, setup_s)
     if args.trace:
+        for m in r["per_layer"]:
+            value = registry.load_module("layer_metrics", m["name"]).read(ctx)
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    if args.trace == 1:
         say({"diag": "end_to_end_of_traced_run", "setup_s": setup_s,
              **{m["name"]: summary.get(m["name"]) for m in r["end_to_end"]
                 if m["name"] != "setup_s"}})
+    if args.trace == 2:  # reduced: the capture goes
+        shutil.rmtree(profile_dir, ignore_errors=True)
     memory = [x["peak_bytes_in_use"] for x in dev["memory"]
               if x.get("peak_bytes_in_use") is not None]
     device = {"platform": dev["platform"], "kind": dev["device_kind"],
@@ -478,8 +580,12 @@ def run(args) -> int:
     if trace is not None:
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
+        # --trace 2 names the idle time by what the engine thread was doing
+        # (harness/hostspans.py); --trace 1, and a program without the
+        # spans, by the device modules on either side.
+        by_host = spans.get("by_span") if args.trace == 2 and spans else None
         result["breakdown"] = {"device_ops": trace.get("device_ops", []),
-                               "idle_gaps": trace.get("idle_gaps", [])}
+                               "idle_gaps": by_host or trace.get("idle_gaps", [])}
     if args.rehearse_cpu:
         result["rehearsal"] = "tiny model on the cpu: not a measurement"
     say(result)
@@ -491,10 +597,10 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearse-cpu", action="store_true")
     ap.add_argument("--dump-trace", default=None,
-                    help="with --trace 1: write line names, a fixture slice and "
+                    help="with --trace 1 or 2: write line names, a fixture slice and "
                     "the reduction as JSON here (for building test fixtures)")
     args = ap.parse_args()
     try:

@@ -30,6 +30,7 @@ double-freeing slots the engine still references).
 
 from __future__ import annotations
 
+import bisect
 import itertools
 import time
 from dataclasses import dataclass, field
@@ -61,6 +62,7 @@ from radixmesh_tpu.obs.attribution import shape_bucket
 from radixmesh_tpu.obs.fleet_plane import eviction_counters
 from radixmesh_tpu.obs.metrics import TOKEN_LEN_BUCKETS, get_registry
 from radixmesh_tpu.obs.trace_plane import get_recorder
+from radixmesh_tpu.obs.tracing import annotate
 from radixmesh_tpu.ops.sampling import sample_tokens, spec_verify_sample
 from radixmesh_tpu.utils.logging import get_logger
 
@@ -94,6 +96,33 @@ def _pow2_at_least(n: int, floor: int = 8) -> int:
 # and attend_chunk_hybrid requires max_pages to divide by it — one
 # constant so the padding and the kernels can't drift apart.
 _KV_BLOCK_PAGES = 32
+
+# Upper edges (ms) of ``Engine.admission_wait``'s buckets; one overflow
+# bucket follows the last.
+_ADMISSION_WAIT_LE_MS = (1, 2, 5, 10, 20, 50, 100, 200, 500, 1000, 2000, 5000)
+_ADMISSION_WAIT_LE_S = tuple(ms / 1e3 for ms in _ADMISSION_WAIT_LE_MS)
+
+
+class _TreeSpan:
+    """An ``rm.tree.*`` span whose host seconds also accumulate in
+    ``Engine.tree_host`` (a capture holds too few admissions for a
+    statistic, so the counter runs always). Engine thread only; the two
+    instances never nest inside themselves."""
+
+    __slots__ = ("_name", "_acc", "_n", "_s", "_ann", "_t0")
+
+    def __init__(self, name: str, acc: dict, n_key: str, s_key: str):
+        self._name, self._acc, self._n, self._s = name, acc, n_key, s_key
+
+    def __enter__(self):
+        self._ann = annotate(self._name)
+        self._ann.__enter__()
+        self._t0 = time.monotonic()
+
+    def __exit__(self, *exc):
+        self._acc[self._n] += 1
+        self._acc[self._s] += time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
 
 
 @dataclass
@@ -298,6 +327,27 @@ class Engine:
         # this process runs: the Pallas kernel or the jnp reference.
         self._last_dispatch: dict | None = None
         self._last_prefill_path: str | None = None  # "sp" | "dense" | "chunk"
+        # Always-on accumulators served on /debug/state (a 3 s profiler
+        # capture holds ~8 admissions: too few for a queue statistic).
+        # admission_wait: requeue/dispatch/submission (whichever was
+        # last) to the instant a batch row is secured, per admission.
+        # tree_host: host seconds in the tree walk + slot acquisition
+        # and in _publish, at the rm.tree.match / rm.tree.publish sites.
+        self.admission_wait = {
+            "count": 0,
+            "sum_s": 0.0,
+            "le_ms": list(_ADMISSION_WAIT_LE_MS),
+            "counts": [0] * (len(_ADMISSION_WAIT_LE_MS) + 1),
+        }
+        self.tree_host = {
+            "matches": 0, "match_s": 0.0, "publishes": 0, "publish_s": 0.0,
+        }
+        self._span_match = _TreeSpan(
+            "rm.tree.match", self.tree_host, "matches", "match_s"
+        )
+        self._span_publish = _TreeSpan(
+            "rm.tree.publish", self.tree_host, "publishes", "publish_s"
+        )
         self.chunk_attention = (
             "paged" if default_use_kernel(cfg.head_dim) else "reference"
         )
@@ -961,25 +1011,35 @@ class Engine:
         inline backlog and each wave packs the decode step PLUS a
         budget-bounded slice of their chunked prefill into a single
         fused launch — long prompts advance between decode steps instead
-        of convoying them."""
-        self._admit()
-        if self._inline:
-            self._wave_step()
-            return
-        if any(r is not None for r in self._rows):
-            self._decode_once()
-        elif not self.waiting and (
-            self._restoring
-            or (
-                self.kv_transfer is not None
-                and self.kv_transfer.has_engine_work()
-            )
-        ):
-            # Nothing to decode and nothing admittable: the only live
-            # work is an in-flight restore (a parked request's or a
-            # prefetch hint's) — yield to the plane worker instead of
-            # busy-spinning the scheduler loop against it.
-            self.kv_transfer.wait_progress()
+        of convoying them.
+
+        Host spans (``obs.tracing.annotate``; inert without a profiler
+        capture): ``rm.engine.step`` ⊃ ``rm.admit`` ⊃ {``rm.tree.match``,
+        ``rm.prefill.dense`` / ``rm.prefill.group``,
+        ``rm.first_token.sync``}, then ``rm.decode.build`` / ``.dispatch``
+        / ``.sample`` / ``.sync`` / ``.consume`` (⊃ ``rm.tree.publish``)
+        — the same names in every decode launch path, so a reader of a
+        capture never needs to know which ran."""
+        with annotate("rm.engine.step"):
+            with annotate("rm.admit"):
+                self._admit()
+            if self._inline:
+                self._wave_step()
+                return
+            if any(r is not None for r in self._rows):
+                self._decode_once()
+            elif not self.waiting and (
+                self._restoring
+                or (
+                    self.kv_transfer is not None
+                    and self.kv_transfer.has_engine_work()
+                )
+            ):
+                # Nothing to decode and nothing admittable: the only live
+                # work is an in-flight restore (a parked request's or a
+                # prefetch hint's) — yield to the plane worker instead of
+                # busy-spinning the scheduler loop against it.
+                self.kv_transfer.wait_progress()
 
     def has_work(self) -> bool:
         return (
@@ -1206,74 +1266,79 @@ class Engine:
                 if row < 0:
                     break
                 req = self.waiting[idx]
-                # One tree walk serves both the defer check and acquisition
-                # (match_and_load also restores host-tier KV, so a
-                # restorable prefix never triggers a needless deferral).
-                tr = req.trace
-                t_match = time.monotonic() if tr is not None else 0.0
-                if hasattr(self.tree, "match_and_load"):
-                    match = None
-                    if self.kv_transfer is not None:
+                with self._span_match:
+                    # One tree walk serves both the defer check and acquisition
+                    # (match_and_load also restores host-tier KV, so a
+                    # restorable prefix never triggers a needless deferral).
+                    tr = req.trace
+                    t_match = time.monotonic() if tr is not None else 0.0
+                    if hasattr(self.tree, "match_and_load"):
+                        match = None
+                        if self.kv_transfer is not None:
+                            match = self.tree.match_prefix(req.prompt)
+                            if match.host_nodes or match.disk_nodes:
+                                # Disk extensions ALWAYS park (extent reads
+                                # are staged-only); host-only extensions
+                                # park past the min-restore threshold.
+                                if match.disk_nodes or (
+                                    match.host_length + match.disk_length
+                                    >= self._kv_min_restore
+                                ):
+                                    if self._park_for_restore(req, match):
+                                        self.waiting.pop(idx)
+                                        continue  # parked; don't advance idx
+                                    # Park failed: begin_restore may have
+                                    # EVICTED for room, so the walked match
+                                    # can hold stale slots — re-walk.
+                                    match = None
+                                else:
+                                    # Small restore: tree untouched since the
+                                    # walk — hand the match to the sync path
+                                    # (one walk total, not two).
+                                    match = self.tree.match_and_load(
+                                        req.prompt, match=match
+                                    )
+                        if match is None:
+                            match = self.tree.match_and_load(req.prompt)
+                    else:
                         match = self.tree.match_prefix(req.prompt)
-                        if match.host_nodes or match.disk_nodes:
-                            # Disk extensions ALWAYS park (extent reads
-                            # are staged-only); host-only extensions
-                            # park past the min-restore threshold.
-                            if match.disk_nodes or (
-                                match.host_length + match.disk_length
-                                >= self._kv_min_restore
-                            ):
-                                if self._park_for_restore(req, match):
-                                    self.waiting.pop(idx)
-                                    continue  # parked; don't advance idx
-                                # Park failed: begin_restore may have
-                                # EVICTED for room, so the walked match
-                                # can hold stale slots — re-walk.
-                                match = None
-                            else:
-                                # Small restore: tree untouched since the
-                                # walk — hand the match to the sync path
-                                # (one walk total, not two).
-                                match = self.tree.match_and_load(
-                                    req.prompt, match=match
-                                )
-                    if match is None:
-                        match = self.tree.match_and_load(req.prompt)
-                else:
-                    match = self.tree.match_prefix(req.prompt)
-                if tr is not None:
-                    tr.add(
-                        "prefix_match",
-                        t_match,
-                        time.monotonic() - t_match,
-                        cached_tokens=int(match.length),
-                        prompt_tokens=len(req.prompt),
-                    )
-                if self._defer_for_prefix_wave(req, match.length, group):
-                    # Admitting this request NOW would recompute a prefix a
-                    # groupmate is about to publish; next wave it's a cache
-                    # hit instead (the serial-admission sharing the batch
-                    # path would otherwise lose).
-                    idx += 1
-                    continue
-                acquired = self._acquire_prompt_slots(req, match)
-                if acquired is None:
-                    break  # pool exhausted even after evict: wait for finishes
+                    if tr is not None:
+                        tr.add(
+                            "prefix_match",
+                            t_match,
+                            time.monotonic() - t_match,
+                            cached_tokens=int(match.length),
+                            prompt_tokens=len(req.prompt),
+                        )
+                    if self._defer_for_prefix_wave(req, match.length, group):
+                        # Admitting this request NOW would recompute a prefix a
+                        # groupmate is about to publish; next wave it's a cache
+                        # hit instead (the serial-admission sharing the batch
+                        # path would otherwise lose).
+                        idx += 1
+                        continue
+                    acquired = self._acquire_prompt_slots(req, match)
+                    if acquired is None:
+                        break  # pool exhausted even after evict: wait for finishes
                 self.waiting.pop(idx)
+                # Queue wait: preemption requeue, SLO dispatch, or
+                # submission — whichever happened LAST — up to the
+                # instant a batch row was secured (a preempted
+                # request's first life must not render as queueing).
+                # Counted for every admission (self.admission_wait); the
+                # sampled flight-recorder span covers the same interval.
+                t_start = max(
+                    req.requeue_time, req.admit_time, req.submit_time
+                )
+                waited = time.monotonic() - t_start
+                aw = self.admission_wait
+                aw["count"] += 1
+                aw["sum_s"] += waited
+                aw["counts"][
+                    bisect.bisect_left(_ADMISSION_WAIT_LE_S, waited)
+                ] += 1
                 if tr is not None:
-                    # Queue wait: preemption requeue, SLO dispatch, or
-                    # submission — whichever happened LAST — up to the
-                    # instant a batch row was secured (a preempted
-                    # request's first life must not render as queueing).
-                    t_start = max(
-                        req.requeue_time, req.admit_time, req.submit_time
-                    )
-                    tr.add(
-                        "admission_wait",
-                        t_start,
-                        time.monotonic() - t_start,
-                        cat="queue",
-                    )
+                    tr.add("admission_wait", t_start, waited, cat="queue")
                 reuse, prefix_slots, own = acquired
                 self._rows[row] = req  # reserve the row; re-set on install
                 group.append((req, row, reuse, prefix_slots, own))
@@ -1366,12 +1431,15 @@ class Engine:
                     <= self.long_prefill_threshold
                 ):
                     path = "dense"
-                    pending = [self._prefill_dense(*sub[0])]
+                    with annotate("rm.prefill.dense"):
+                        pending = [self._prefill_dense(*sub[0])]
                 else:
                     path = "chunk"
-                    pending = self._prefill_group(sub)
+                    with annotate("rm.prefill.group"):
+                        pending = self._prefill_group(sub)
                 self._last_prefill_path = path
-                self._finalize_first_tokens(pending)
+                with annotate("rm.first_token.sync"):
+                    self._finalize_first_tokens(pending)
                 if traced or acct is not None:
                     dur = time.monotonic() - t_wave
                     new_tok = sum(len(m[0].prompt) - m[2] for m in sub)
@@ -1987,59 +2055,60 @@ class Engine:
         """Insert the first ``key_len`` tokens (whose KV is in the pool)
         into the tree; canonicalize shared prefixes; move the lock to the
         deepest published node."""
-        tr = req.trace
-        t_pub = time.monotonic() if tr is not None else 0.0
-        key = self._sequence_key(req, key_len)
-        matched = self.tree.insert(key, req.token_slots[:key_len].copy())
-        m2 = self.tree.match_prefix(key)
-        new_lock = m2.last_node
-        if matched > req.prefix_len:
-            # Over [prefix_len, matched) the tree kept already-present
-            # slots. Where they're ours (this request published them
-            # earlier) nothing changes; where another request published the
-            # same tokens first, ours are duplicates — point our page table
-            # at the canonical slots and free only the differing ones.
-            canon = m2.indices()
-            old = req.token_slots[: len(canon)].copy()
-            dup = old[old != canon]
-            if dup.size:
-                req.token_slots[: len(canon)] = canon
-                req.own_slots = np.setdiff1d(req.own_slots, dup)
-                self.pool.free(dup)
-        # Slots now referenced by tree nodes are tree-owned: drop them from
-        # own_slots so release() never double-frees them.
-        aligned = key_len - key_len % self.page_size
-        tree_owned = req.token_slots[matched:aligned]
-        if tree_owned.size:
-            req.own_slots = np.setdiff1d(req.own_slots, tree_owned)
-        if new_lock is not req.lock_node:
-            self.tree.inc_lock_ref(new_lock)
-            if req.lock_node is not None:
-                self.tree.dec_lock_ref(req.lock_node)
-            req.lock_node = new_lock
-        if self.mesh is not None and aligned > 0:
-            # Advertise the (canonical) published prefix around the ring
-            # (radix_mesh.py:193-201). Only the page-ALIGNED prefix: the
-            # local tree truncates inserts to page multiples, so residue
-            # slots [aligned, key_len) are freed at release — advertising
-            # them would map tokens to recycled slots ring-wide, and the
-            # router would promise hits the node cannot serve. A traced
-            # request's trace id rides the frames (old-wire-tolerant
-            # trailer) so replicas stitch their apply/lag spans under it.
-            self.mesh.insert(
-                key[:aligned],
-                req.token_slots[:aligned],
-                trace_id=tr.trace_id if tr is not None else 0,
-            )
-        if tr is not None:
-            tr.add(
-                "publish",
-                t_pub,
-                time.monotonic() - t_pub,
-                cat="cache",
-                tokens=int(key_len),
-                ring_advertised=bool(self.mesh is not None and aligned > 0),
-            )
+        with self._span_publish:
+            tr = req.trace
+            t_pub = time.monotonic() if tr is not None else 0.0
+            key = self._sequence_key(req, key_len)
+            matched = self.tree.insert(key, req.token_slots[:key_len].copy())
+            m2 = self.tree.match_prefix(key)
+            new_lock = m2.last_node
+            if matched > req.prefix_len:
+                # Over [prefix_len, matched) the tree kept already-present
+                # slots. Where they're ours (this request published them
+                # earlier) nothing changes; where another request published the
+                # same tokens first, ours are duplicates — point our page table
+                # at the canonical slots and free only the differing ones.
+                canon = m2.indices()
+                old = req.token_slots[: len(canon)].copy()
+                dup = old[old != canon]
+                if dup.size:
+                    req.token_slots[: len(canon)] = canon
+                    req.own_slots = np.setdiff1d(req.own_slots, dup)
+                    self.pool.free(dup)
+            # Slots now referenced by tree nodes are tree-owned: drop them from
+            # own_slots so release() never double-frees them.
+            aligned = key_len - key_len % self.page_size
+            tree_owned = req.token_slots[matched:aligned]
+            if tree_owned.size:
+                req.own_slots = np.setdiff1d(req.own_slots, tree_owned)
+            if new_lock is not req.lock_node:
+                self.tree.inc_lock_ref(new_lock)
+                if req.lock_node is not None:
+                    self.tree.dec_lock_ref(req.lock_node)
+                req.lock_node = new_lock
+            if self.mesh is not None and aligned > 0:
+                # Advertise the (canonical) published prefix around the ring
+                # (radix_mesh.py:193-201). Only the page-ALIGNED prefix: the
+                # local tree truncates inserts to page multiples, so residue
+                # slots [aligned, key_len) are freed at release — advertising
+                # them would map tokens to recycled slots ring-wide, and the
+                # router would promise hits the node cannot serve. A traced
+                # request's trace id rides the frames (old-wire-tolerant
+                # trailer) so replicas stitch their apply/lag spans under it.
+                self.mesh.insert(
+                    key[:aligned],
+                    req.token_slots[:aligned],
+                    trace_id=tr.trace_id if tr is not None else 0,
+                )
+            if tr is not None:
+                tr.add(
+                    "publish",
+                    t_pub,
+                    time.monotonic() - t_pub,
+                    cat="cache",
+                    tokens=int(key_len),
+                    ring_advertised=bool(self.mesh is not None and aligned > 0),
+                )
 
     def _release(self, req: Request) -> None:
         """cache_finished_req (radix_cache.py:439-486): publish the full
@@ -2155,78 +2224,101 @@ class Engine:
             # (seed, position) draw below.
             self._decode_multi_once(1, force_compact=True)
             return
-        slots = np.full(self.max_batch, self._scratch_slot, dtype=np.int32)
-        lengths = np.ones(self.max_batch, dtype=np.int32)
-        preempted: list[Request] = []
-        for row, req in enumerate(self._rows):
-            if req is None:
-                continue
-            page_idx, offset = divmod(req.kv_len, self.page_size)
-            if offset == 0:  # crossing into a fresh page
-                new = self._alloc_pages(1, cause="preempt")
-                if new is None:
-                    preempted.append(req)
+        with annotate("rm.decode.build"):
+            slots = np.full(
+                self.max_batch, self._scratch_slot, dtype=np.int32
+            )
+            lengths = np.ones(self.max_batch, dtype=np.int32)
+            preempted: list[Request] = []
+            for row, req in enumerate(self._rows):
+                if req is None:
                     continue
-                req.own_slots = np.concatenate([req.own_slots, new])
-                self._page_table[row, page_idx] = new[0] // self.page_size
-                slot = int(new[0])
-            else:
-                slot = int(
-                    self._page_table[row, page_idx] * self.page_size + offset
-                )
-            slots[row] = slot
-            lengths[row] = req.kv_len + 1
-        for req in preempted:
-            self._preempt(req)
+                page_idx, offset = divmod(req.kv_len, self.page_size)
+                if offset == 0:  # crossing into a fresh page
+                    new = self._alloc_pages(1, cause="preempt")
+                    if new is None:
+                        preempted.append(req)
+                        continue
+                    req.own_slots = np.concatenate([req.own_slots, new])
+                    self._page_table[row, page_idx] = new[0] // self.page_size
+                    slot = int(new[0])
+                else:
+                    slot = int(
+                        self._page_table[row, page_idx] * self.page_size
+                        + offset
+                    )
+                slots[row] = slot
+                lengths[row] = req.kv_len + 1
+            for req in preempted:
+                self._preempt(req)
 
-        active = [(row, r) for row, r in enumerate(self._rows) if r is not None]
-        if not active:
-            return
-        step_t0 = time.monotonic()
-        if self._pp or seeded:
-            # A decode step is a C=1 chunk through the layer pipeline
-            # (parallel/pp_serving.py) — same page-table attention, same
-            # pool scatter, stage weights never move. The chunk path's
-            # blockwise attention needs a KV-block-multiple table width,
-            # which the bucket keeps (floor = block). Seeded launches
-            # ride it on every backend: it returns LOGITS, and the
-            # replay contract needs the canonical host-side draw.
-            res = self._forward_chunk(
-                jnp.asarray(self._tokens)[:, None],
-                jnp.asarray(lengths - 1)[:, None],
-                jnp.asarray(slots)[:, None],
-                jnp.asarray(self._decode_pt_bucket(1, floor=_KV_BLOCK_PAGES)),
-                jnp.asarray(lengths),
-                _KV_BLOCK_PAGES,
+            active = [
+                (row, r) for row, r in enumerate(self._rows) if r is not None
+            ]
+            if not active:
+                return
+            step_t0 = time.monotonic()
+            # A pp or seeded step is a C=1 chunk (below): blockwise
+            # attention needs a KV-block-multiple table width, which the
+            # bucket keeps (floor = block).
+            chunked = self._pp or seeded
+            tokens_d = jnp.asarray(self._tokens)
+            slots_d = jnp.asarray(slots)
+            pt_d = jnp.asarray(
+                self._decode_pt_bucket(1, floor=_KV_BLOCK_PAGES)
+                if chunked
+                else self._decode_pt_bucket(1)
             )
-            logits = self._commit_pool_update(res)[:, 0]
-        else:
-            res = decode_step(
-                self.params,
-                self.cfg,
-                jnp.asarray(self._tokens),
-                self.pool.kv,
-                jnp.asarray(slots),
-                jnp.asarray(self._decode_pt_bucket(1)),
-                jnp.asarray(lengths),
-                self.page_size,
-                mesh=self.device_mesh,
-                kv_scale=self.pool.kv_scale,
-            )
-            logits = self._commit_pool_update(res)
+            lengths_d = jnp.asarray(lengths)
+            positions_d = jnp.asarray(lengths - 1) if chunked else None
+        with annotate("rm.decode.dispatch"):
+            if chunked:
+                # A decode step is a C=1 chunk through the layer pipeline
+                # (parallel/pp_serving.py) — same page-table attention,
+                # same pool scatter, stage weights never move. Seeded
+                # launches ride it on every backend: it returns LOGITS,
+                # and the replay contract needs the canonical host-side
+                # draw.
+                res = self._forward_chunk(
+                    tokens_d[:, None],
+                    positions_d[:, None],
+                    slots_d[:, None],
+                    pt_d,
+                    lengths_d,
+                    _KV_BLOCK_PAGES,
+                )
+                logits = self._commit_pool_update(res)[:, 0]
+            else:
+                res = decode_step(
+                    self.params,
+                    self.cfg,
+                    tokens_d,
+                    self.pool.kv,
+                    slots_d,
+                    pt_d,
+                    lengths_d,
+                    self.page_size,
+                    mesh=self.device_mesh,
+                    kv_scale=self.pool.kv_scale,
+                )
+                logits = self._commit_pool_update(res)
         if seeded:
-            sampled = np.zeros(self.max_batch, dtype=np.int64)
-            for row, req in active:
-                sampled[row] = self._sample_seeded_row(req, logits[row])
+            # The canonical per-row (seed, position) draw syncs per row:
+            # sample and sync are one span here.
+            with annotate("rm.decode.sample"):
+                sampled = np.zeros(self.max_batch, dtype=np.int64)
+                for row, req in active:
+                    sampled[row] = self._sample_seeded_row(req, logits[row])
         else:
-            self._rng, key = jax.random.split(self._rng)
-            sampled = np.asarray(
-                sample_tokens(
+            with annotate("rm.decode.sample"):
+                self._rng, key = jax.random.split(self._rng)
+                sampled_d = sample_tokens(
                     logits, key, temperature=jnp.asarray(self._temps),
                     top_p=jnp.asarray(self._top_ps),
                     top_k=jnp.asarray(self._top_ks),
                 )
-            )
+            with annotate("rm.decode.sync"):  # the wait for the device
+                sampled = np.asarray(sampled_d)
         self.stats.decode_steps += 1
         # sample_tokens materialized on host above, so this spans the full
         # dispatch+device time of the step — the per-token latency (TPOT)
@@ -2246,8 +2338,11 @@ class Engine:
                     k_steps=1, batch_rows=len(active),
                 )
 
-        for row, req in active:
-            self._consume_token(req, row, int(slots[row]), int(sampled[row]))
+        with annotate("rm.decode.consume"):
+            for row, req in active:
+                self._consume_token(
+                    req, row, int(slots[row]), int(sampled[row])
+                )
 
     def _multi_step_k(self, k: int) -> int:
         """The largest fusable step count ≤ k this launch: bounded by
@@ -2324,77 +2419,95 @@ class Engine:
         ``force_compact`` pins the gathered compact-working-set variant
         even where the paged kernel exists — the small-batch crossover
         (``select_paged``) chose dense for this wave."""
-        lengths = np.ones(self.max_batch, dtype=np.int32)
-        active = self._provision_rows(k - 1)
-        if not active:
-            return
-        for row, req in active:
-            lengths[row] = req.kv_len + 1
-        step_t0 = time.monotonic()
-        self._rng, key = jax.random.split(self._rng)
-        if self._pp:
-            from radixmesh_tpu.parallel.pp_serving import pp_decode_multi
-
-            res = pp_decode_multi(
-                self.params,
-                self.cfg,
-                jnp.asarray(self._tokens),
-                self.pool.kv,
-                jnp.asarray(self._decode_pt_bucket(k)),
-                jnp.asarray(lengths),
-                key,
-                jnp.asarray(self._temps),
-                jnp.asarray(self._top_ps),
-                jnp.asarray(self._top_ks),
-                page_size=self.page_size,
-                k_steps=k,
-                mesh=self.device_mesh,
-                kv_scale=self.pool.kv_scale,
-                scratch_slot=self._scratch_slot,
-            )
-        elif force_compact or not default_use_kernel(self.cfg.head_dim):
+        with annotate("rm.decode.build"):
+            lengths = np.ones(self.max_batch, dtype=np.int32)
+            active = self._provision_rows(k - 1)
+            if not active:
+                return
+            for row, req in active:
+                lengths[row] = req.kv_len + 1
+            step_t0 = time.monotonic()
             # No aliased kernel on this backend (or the crossover chose
             # dense): decode over a gathered compact working set so each
             # launch pays ONE pool gather + ONE scatter-back instead of
             # k·L pool-sized scatter copies
             # (see models/llama.py::decode_multi_compact).
-            compact, pt_c = self._compact_decode_tables(active, k)
-            res = decode_multi_compact(
-                self.params,
-                self.cfg,
-                jnp.asarray(self._tokens),
-                self.pool.kv,
-                jnp.asarray(compact),
-                jnp.asarray(pt_c),
-                jnp.asarray(lengths),
-                key,
-                jnp.asarray(self._temps),
-                jnp.asarray(self._top_ps),
-                self.page_size,
-                k_steps=k,
-                mesh=self.device_mesh,
-                kv_scale=self.pool.kv_scale,
-                top_ks=jnp.asarray(self._top_ks),
+            compact = not self._pp and (
+                force_compact or not default_use_kernel(self.cfg.head_dim)
             )
-        else:
-            res = decode_multi(
-                self.params,
-                self.cfg,
-                jnp.asarray(self._tokens),
-                self.pool.kv,
-                jnp.asarray(self._decode_pt_bucket(k)),
-                jnp.asarray(lengths),
-                key,
-                jnp.asarray(self._temps),
-                jnp.asarray(self._top_ps),
-                self.page_size,
-                k_steps=k,
-                mesh=self.device_mesh,
-                kv_scale=self.pool.kv_scale,
-                top_ks=jnp.asarray(self._top_ks),
-            )
-        sampled = self._commit_pool_update(res)
-        sampled = np.asarray(sampled)  # [k, B] — the ONE round trip
+            if compact:
+                pages, pt_c = self._compact_decode_tables(active, k)
+                pages_d, pt_d = jnp.asarray(pages), jnp.asarray(pt_c)
+            else:
+                pt_d = jnp.asarray(self._decode_pt_bucket(k))
+            tokens_d = jnp.asarray(self._tokens)
+            lengths_d = jnp.asarray(lengths)
+            temps_d = jnp.asarray(self._temps)
+            top_ps_d = jnp.asarray(self._top_ps)
+            top_ks_d = jnp.asarray(self._top_ks)
+        with annotate("rm.decode.sample"):
+            # The sampler itself is fused into the launch (device-side
+            # sampling feeds each step): only its key is drawn here.
+            self._rng, key = jax.random.split(self._rng)
+        with annotate("rm.decode.dispatch"):
+            if self._pp:
+                from radixmesh_tpu.parallel.pp_serving import pp_decode_multi
+
+                res = pp_decode_multi(
+                    self.params,
+                    self.cfg,
+                    tokens_d,
+                    self.pool.kv,
+                    pt_d,
+                    lengths_d,
+                    key,
+                    temps_d,
+                    top_ps_d,
+                    top_ks_d,
+                    page_size=self.page_size,
+                    k_steps=k,
+                    mesh=self.device_mesh,
+                    kv_scale=self.pool.kv_scale,
+                    scratch_slot=self._scratch_slot,
+                )
+            elif compact:
+                res = decode_multi_compact(
+                    self.params,
+                    self.cfg,
+                    tokens_d,
+                    self.pool.kv,
+                    pages_d,
+                    pt_d,
+                    lengths_d,
+                    key,
+                    temps_d,
+                    top_ps_d,
+                    self.page_size,
+                    k_steps=k,
+                    mesh=self.device_mesh,
+                    kv_scale=self.pool.kv_scale,
+                    top_ks=top_ks_d,
+                )
+            else:
+                res = decode_multi(
+                    self.params,
+                    self.cfg,
+                    tokens_d,
+                    self.pool.kv,
+                    pt_d,
+                    lengths_d,
+                    key,
+                    temps_d,
+                    top_ps_d,
+                    self.page_size,
+                    k_steps=k,
+                    mesh=self.device_mesh,
+                    kv_scale=self.pool.kv_scale,
+                    top_ks=top_ks_d,
+                )
+            sampled = self._commit_pool_update(res)
+        with annotate("rm.decode.sync"):
+            sampled = np.asarray(sampled)  # [k, B] — the ONE round trip
         self.stats.decode_steps += k
         elapsed = time.monotonic() - step_t0
         for _ in range(k):
@@ -2413,15 +2526,18 @@ class Engine:
                 )
 
         ps = self.page_size
-        for row, req in active:
-            base = req.kv_len
-            for i in range(k):
-                pos = base + i
-                slot = int(
-                    self._page_table[row, pos // ps] * ps + pos % ps
-                )
-                if self._consume_token(req, row, slot, int(sampled[i, row])):
-                    break  # finished mid-launch: surplus tokens discarded
+        with annotate("rm.decode.consume"):
+            for row, req in active:
+                base = req.kv_len
+                for i in range(k):
+                    pos = base + i
+                    slot = int(
+                        self._page_table[row, pos // ps] * ps + pos % ps
+                    )
+                    if self._consume_token(
+                        req, row, slot, int(sampled[i, row])
+                    ):
+                        break  # finished mid-launch: surplus discarded
 
     def _spec_ok(self, g: int) -> bool:
         """Speculative decoding is considered whenever rows are active and
@@ -2590,167 +2706,171 @@ class Engine:
         the same wave. ``decode=False`` (prefill/boost waves, the
         all-seeded fallback's second launch) advances the backlog alone."""
         ps = self.page_size
-        jobs = (
-            [
-                (job, w)
-                for job, w in zip(self._inline, inline)
-                if w > 0 and job.pos < job.total
-            ]
-            if inline is not None
-            else []
-        )
-        if inline is None:
-            C = g + 1  # legacy speculative shape, untouched
-        else:
-            # Chunk width covers the widest live window this wave —
-            # pow2-bucketed so varying allotments reuse compiled
-            # variants (floor matches _prefill_group's chunk floor).
-            C = _pow2_at_least(
-                max([g + 1] + [w for _, w in jobs]), floor=16
-            )
-        # Provision only each row's actual verify window (draft + bonus):
-        # an opted-out row (empty draft) needs exactly the one position a
-        # plain step would, so γ positions of headroom it lacks must not
-        # preempt it. Inline jobs never provision — their pages were all
-        # acquired up front at admission.
-        active = (
-            self._provision_rows(
-                g, extras={row: len(d) for row, d in drafts.items()}
-            )
-            if decode
-            else []
-        )
-        if not active and not jobs:
-            return
-        step_t0 = time.monotonic()
-
-        B = self.max_batch
-        kv_block = _KV_BLOCK_PAGES
-        maxp = _pow2_at_least(
-            max(
+        with annotate("rm.decode.build"):
+            jobs = (
                 [
-                    (r.kv_len + len(drafts.get(row, r.prompt[:0]))) // ps
-                    + 1
-                    for row, r in active
+                    (job, w)
+                    for job, w in zip(self._inline, inline)
+                    if w > 0 and job.pos < job.total
                 ]
-                + [(job.pos + w) // ps + 1 for job, w in jobs]
-            ),
-            floor=kv_block,
-        )
-        toks = np.zeros((B, C), dtype=np.int32)
-        draft_len = np.zeros((B,), dtype=np.int32)
-        sl = np.full((B, C), self._scratch_slot, dtype=np.int32)
-        poss = np.zeros((B, C), dtype=np.int32)
-        kvlen = np.zeros((B,), dtype=np.int32)
-        pt = np.full((B, maxp), self._scratch_page, dtype=np.int32)
-        for row, req in active:
-            draft = drafts.get(row, req.prompt[:0])
-            drafts[row] = draft
-            w = len(draft) + 1  # this row's live verify window
-            toks[row, 0] = self._tokens[row]
-            toks[row, 1 : 1 + len(draft)] = draft
-            pos = req.kv_len + np.arange(C, dtype=np.int32)
-            poss[row] = np.minimum(pos, self.max_seq_len - 1)
-            n_pages = min((req.kv_len + len(draft)) // ps + 1, self.max_pages)
-            pt[row, :n_pages] = self._page_table[row, :n_pages]
-            # Positions past the row's window write their (garbage) K/V to
-            # the scratch slot; causal masking keeps them out of every
-            # logit the verify actually uses.
-            sl[row, :w] = pt[row, pos[:w] // ps] * ps + pos[:w] % ps
-            kvlen[row] = req.kv_len + w
-            draft_len[row] = len(draft)
-            # Conservation contract: a draft counts as PROPOSED here iff
-            # its row survives to the verify below — every proposed
-            # token is then accounted accepted or rejected in the
-            # accept loop, so proposed == accepted + rejected holds on
-            # every path (drafts of rows preempted by _provision_rows
-            # above were never proposed). The per-class ledger rides the
-            # same two sites, so it cannot undercount either.
-            self.stats.spec_proposed += len(draft)
-            self._m_spec_proposed.inc(len(draft))
-            if len(draft):
-                cell = self._spec_shape.setdefault(
-                    shape_bucket(len(req.prompt)), [0, 0]
+                if inline is not None
+                else []
+            )
+            if inline is None:
+                C = g + 1  # legacy speculative shape, untouched
+            else:
+                # Chunk width covers the widest live window this wave —
+                # pow2-bucketed so varying allotments reuse compiled
+                # variants (floor matches _prefill_group's chunk floor).
+                C = _pow2_at_least(
+                    max([g + 1] + [w for _, w in jobs]), floor=16
                 )
-                cell[0] += len(draft)
+            # Provision only each row's actual verify window (draft + bonus):
+            # an opted-out row (empty draft) needs exactly the one position a
+            # plain step would, so γ positions of headroom it lacks must not
+            # preempt it. Inline jobs never provision — their pages were all
+            # acquired up front at admission.
+            active = (
+                self._provision_rows(
+                    g, extras={row: len(d) for row, d in drafts.items()}
+                )
+                if decode
+                else []
+            )
+            if not active and not jobs:
+                return
+            step_t0 = time.monotonic()
 
-        for job, w in jobs:
-            # Inline prefill rows: the live window is the job's allotted
-            # prompt slice [pos, pos+w) — exactly a _prefill_group chunk
-            # for one row, riding the decode launch. draft_len stays 0,
-            # so the verify below treats the row as undrafted and its
-            # (meaningless mid-prompt) bonus sample is never consumed.
-            row, pos, prompt = job.row, job.pos, job.req.prompt
-            toks[row, :w] = prompt[pos : pos + w]
-            p = pos + np.arange(C, dtype=np.int32)
-            poss[row] = np.minimum(p, self.max_seq_len - 1)
-            sl[row, :w] = job.token_slots[pos : pos + w]
-            kvlen[row] = pos + w
-            npg = min(-(-job.total // ps), maxp)
-            pt[row, :npg] = job.token_slots[::ps][:npg] // ps
+            B = self.max_batch
+            kv_block = _KV_BLOCK_PAGES
+            maxp = _pow2_at_least(
+                max(
+                    [
+                        (r.kv_len + len(drafts.get(row, r.prompt[:0]))) // ps
+                        + 1
+                        for row, r in active
+                    ]
+                    + [(job.pos + w) // ps + 1 for job, w in jobs]
+                ),
+                floor=kv_block,
+            )
+            toks = np.zeros((B, C), dtype=np.int32)
+            draft_len = np.zeros((B,), dtype=np.int32)
+            sl = np.full((B, C), self._scratch_slot, dtype=np.int32)
+            poss = np.zeros((B, C), dtype=np.int32)
+            kvlen = np.zeros((B,), dtype=np.int32)
+            pt = np.full((B, maxp), self._scratch_page, dtype=np.int32)
+            for row, req in active:
+                draft = drafts.get(row, req.prompt[:0])
+                drafts[row] = draft
+                w = len(draft) + 1  # this row's live verify window
+                toks[row, 0] = self._tokens[row]
+                toks[row, 1 : 1 + len(draft)] = draft
+                pos = req.kv_len + np.arange(C, dtype=np.int32)
+                poss[row] = np.minimum(pos, self.max_seq_len - 1)
+                n_pages = min((req.kv_len + len(draft)) // ps + 1, self.max_pages)
+                pt[row, :n_pages] = self._page_table[row, :n_pages]
+                # Positions past the row's window write their (garbage) K/V to
+                # the scratch slot; causal masking keeps them out of every
+                # logit the verify actually uses.
+                sl[row, :w] = pt[row, pos[:w] // ps] * ps + pos[:w] % ps
+                kvlen[row] = req.kv_len + w
+                draft_len[row] = len(draft)
+                # Conservation contract: a draft counts as PROPOSED here iff
+                # its row survives to the verify below — every proposed
+                # token is then accounted accepted or rejected in the
+                # accept loop, so proposed == accepted + rejected holds on
+                # every path (drafts of rows preempted by _provision_rows
+                # above were never proposed). The per-class ledger rides the
+                # same two sites, so it cannot undercount either.
+                self.stats.spec_proposed += len(draft)
+                self._m_spec_proposed.inc(len(draft))
+                if len(draft):
+                    cell = self._spec_shape.setdefault(
+                        shape_bucket(len(req.prompt)), [0, 0]
+                    )
+                    cell[0] += len(draft)
+
+            for job, w in jobs:
+                # Inline prefill rows: the live window is the job's allotted
+                # prompt slice [pos, pos+w) — exactly a _prefill_group chunk
+                # for one row, riding the decode launch. draft_len stays 0,
+                # so the verify below treats the row as undrafted and its
+                # (meaningless mid-prompt) bonus sample is never consumed.
+                row, pos, prompt = job.row, job.pos, job.req.prompt
+                toks[row, :w] = prompt[pos : pos + w]
+                p = pos + np.arange(C, dtype=np.int32)
+                poss[row] = np.minimum(p, self.max_seq_len - 1)
+                sl[row, :w] = job.token_slots[pos : pos + w]
+                kvlen[row] = pos + w
+                npg = min(-(-job.total // ps), maxp)
+                pt[row, :npg] = job.token_slots[::ps][:npg] // ps
+            toks_d, poss_d, sl_d = (
+                jnp.asarray(toks), jnp.asarray(poss), jnp.asarray(sl)
+            )
+            pt_d, kvlen_d = jnp.asarray(pt), jnp.asarray(kvlen)
 
         # The verify pass is just a C-wide chunk; _forward_chunk picks
         # the pipeline schedule under pp (parallel/pp_serving.py).
-        res = self._forward_chunk(
-            jnp.asarray(toks),
-            jnp.asarray(poss),
-            jnp.asarray(sl),
-            jnp.asarray(pt),
-            jnp.asarray(kvlen),
-            kv_block,
-        )
-        logits = self._commit_pool_update(res)
-        if active:
-            self._rng, key = jax.random.split(self._rng)
-            accept_len, bonus = spec_verify_sample(
-                logits,
-                jnp.asarray(toks[:, 1:]),
-                jnp.asarray(draft_len),
-                key,
-                jnp.asarray(self._temps),
-                jnp.asarray(self._top_ps),
-                jnp.asarray(self._top_ks),
+        with annotate("rm.decode.dispatch"):
+            res = self._forward_chunk(
+                toks_d, poss_d, sl_d, pt_d, kvlen_d, kv_block
             )
-            accept_len = np.asarray(accept_len)  # [B] one sync
-            bonus = np.asarray(bonus)
+            logits = self._commit_pool_update(res)
+        if active:
+            with annotate("rm.decode.sample"):
+                self._rng, key = jax.random.split(self._rng)
+                accept_len, bonus = spec_verify_sample(
+                    logits,
+                    jnp.asarray(toks[:, 1:]),
+                    jnp.asarray(draft_len),
+                    key,
+                    jnp.asarray(self._temps),
+                    jnp.asarray(self._top_ps),
+                    jnp.asarray(self._top_ks),
+                )
+            with annotate("rm.decode.sync"):
+                accept_len = np.asarray(accept_len)  # [B] one sync
+                bonus = np.asarray(bonus)
             self.stats.decode_steps += 1
 
         emitted_total = 0
-        for row, req in active:
-            draft = drafts[row]
-            a = int(accept_len[row])
-            rejected = len(draft) - a
-            self.stats.spec_accepted += a
-            self.stats.spec_rejected += rejected
-            self._m_spec_accepted.inc(a)
-            if rejected:
-                self._m_spec_rejected.inc(rejected)
-            # Rejected tail: the gap before this row's NEXT token
-            # includes re-decoding it — the spec_verify_miss stall
-            # attribution (consumed by _stall_cause).
-            req.spec_miss = rejected
-            if a:
-                cell = self._spec_shape.setdefault(
-                    shape_bucket(len(req.prompt)), [0, 0]
-                )
-                cell[1] += a
-            if len(draft):
-                self.spec_ledger.note_wave(
-                    req.tenant,
-                    shape_bucket(len(req.prompt)),
-                    sources.get(row, "ngram") if sources else "ngram",
-                    len(draft),
-                    a,
-                    len(draft),
-                )
-            base = req.kv_len
-            for i in range(a + 1):  # a accepted drafts + 1 bonus token
-                pos = base + i
-                slot = int(self._page_table[row, pos // ps] * ps + pos % ps)
-                token = int(draft[i]) if i < a else int(bonus[row])
-                emitted_total += 1
-                if self._consume_token(req, row, slot, token):
-                    break
+        with annotate("rm.decode.consume"):
+            for row, req in active:
+                draft = drafts[row]
+                a = int(accept_len[row])
+                rejected = len(draft) - a
+                self.stats.spec_accepted += a
+                self.stats.spec_rejected += rejected
+                self._m_spec_accepted.inc(a)
+                if rejected:
+                    self._m_spec_rejected.inc(rejected)
+                # Rejected tail: the gap before this row's NEXT token
+                # includes re-decoding it — the spec_verify_miss stall
+                # attribution (consumed by _stall_cause).
+                req.spec_miss = rejected
+                if a:
+                    cell = self._spec_shape.setdefault(
+                        shape_bucket(len(req.prompt)), [0, 0]
+                    )
+                    cell[1] += a
+                if len(draft):
+                    self.spec_ledger.note_wave(
+                        req.tenant,
+                        shape_bucket(len(req.prompt)),
+                        sources.get(row, "ngram") if sources else "ngram",
+                        len(draft),
+                        a,
+                        len(draft),
+                    )
+                base = req.kv_len
+                for i in range(a + 1):  # a accepted drafts + 1 bonus token
+                    pos = base + i
+                    slot = int(self._page_table[row, pos // ps] * ps + pos % ps)
+                    token = int(draft[i]) if i < a else int(bonus[row])
+                    emitted_total += 1
+                    if self._consume_token(req, row, slot, token):
+                        break
         inline_tok = 0
         pending: list[tuple] = []
         for job, w in jobs:
@@ -2785,7 +2905,8 @@ class Engine:
             self._last_inline_prefill_t = time.monotonic()
             self._inline = [j for j in self._inline if j.pos < j.total]
         if pending:
-            self._finalize_first_tokens(pending)
+            with annotate("rm.first_token.sync"):
+                self._finalize_first_tokens(pending)
         elapsed = time.monotonic() - step_t0
         if active:
             for _ in range(max(emitted_total, 1)):

@@ -33,7 +33,9 @@ from radixmesh_tpu.obs.trace_plane import (
     stitch_traces,
     write_trace,
 )
-from radixmesh_tpu.obs.tracing import annotate, profile, recorded, timed
+# ``annotate`` (jax.profiler.TraceAnnotation) is taken from obs.tracing
+# directly: re-exporting it here would import jax into mesh-only processes.
+from radixmesh_tpu.obs.tracing import profile, recorded
 
 __all__ = [
     "FleetConfig",
@@ -59,8 +61,6 @@ __all__ = [
     "TelemetryHistory",
     "BlackBox",
     "load_blackbox",
-    "annotate",
     "profile",
     "recorded",
-    "timed",
 ]

@@ -3,9 +3,11 @@ tracing at all — only rank-prefixed logging, ``util/log.py:5-13``).
 
 Two levels:
 
-- :func:`annotate` — named span inside an already-running trace; shows up
-  on the TensorBoard/xplane timeline alongside XLA ops. No-op overhead when
-  no trace is active.
+- :data:`annotate` — ``jax.profiler.TraceAnnotation`` itself: a named span
+  written into the profiler's own trace, so it is on the device trace's
+  clock. Without a capture it is one atomic load. The program's spans
+  (the ``rm.*`` names: ``engine/engine.py``, ``server/http_frontend.py``,
+  ``slo/runner.py``) all go through it; names are constants.
 - :func:`profile` — capture a full device+host trace of a block into a
   TensorBoard logdir.
 
@@ -18,45 +20,45 @@ installation this repo runs on, so a failure to import or start it raises
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from typing import Iterator
 
-from radixmesh_tpu.obs.metrics import Histogram
 from radixmesh_tpu.obs.trace_plane import get_recorder
 
-__all__ = ["annotate", "profile", "timed", "recorded"]
+__all__ = ["annotate", "profile", "recorded"]
+
+
+@functools.cache
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use: mesh-only
+    processes (routers, the chaos benches) import this module for
+    :func:`recorded` and never pay the jax import unless a span is opened."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+def __getattr__(name: str):  # PEP 562: ``from ...tracing import annotate``
+    if name == "annotate":
+        return _trace_annotation()
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 @contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named span on the profiler timeline (xplane TraceAnnotation)."""
-    import jax.profiler as _prof
-
-    with _prof.TraceAnnotation(name):
-        yield
-
-
-@contextlib.contextmanager
-def profile(log_dir: str) -> Iterator[None]:
+def profile(log_dir: str, python_tracer: bool = False) -> Iterator[None]:
     """Capture a device+host profiler trace of the block to ``log_dir``
     (view with TensorBoard's profile plugin, or read the ``.xplane.pb``
-    with ``jax.profiler.ProfileData``)."""
+    with ``jax.profiler.ProfileData``). The python tracer is most of what
+    a capture costs and most of its bytes, and the ``rm.*`` annotations do
+    not need it (the host tracer writes them to plane ``/host:CPU``): off
+    unless asked for."""
     import jax.profiler as _prof
 
-    with _prof.trace(log_dir):
+    options = _prof.ProfileOptions()
+    options.python_tracer_level = 1 if python_tracer else 0
+    with _prof.trace(log_dir, profiler_options=options):
         yield
-
-
-@contextlib.contextmanager
-def timed(hist: Histogram, name: str | None = None) -> Iterator[None]:
-    """Observe the block's wall time into ``hist`` and, when a profiler
-    trace is running, annotate the span with ``name``."""
-    t0 = time.monotonic()
-    with annotate(name or hist.name):
-        try:
-            yield
-        finally:
-            hist.observe(time.monotonic() - t0)
 
 
 @contextlib.contextmanager
@@ -66,13 +68,14 @@ def recorded(lane: str, name: str, **args) -> Iterator[None]:
     on ``lane`` for the request-flight timeline. One branch when the
     recorder is disabled (it still annotates — that is already a no-op
     without a live profiler trace)."""
+    ann = _trace_annotation()
     rec = get_recorder()
     if not rec.enabled:
-        with annotate(name):
+        with ann(name):
             yield
         return
     t0 = time.monotonic()
-    with annotate(name):
+    with ann(name):
         try:
             yield
         finally:

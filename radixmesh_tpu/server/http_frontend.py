@@ -56,6 +56,7 @@ from radixmesh_tpu.obs.metrics import get_registry
 from radixmesh_tpu.obs.step_plane import describe_devices
 from radixmesh_tpu.obs.timeseries import TelemetryHistory
 from radixmesh_tpu.obs.trace_plane import get_recorder
+from radixmesh_tpu.obs.tracing import annotate
 from radixmesh_tpu.policy.retry import jittered_retry_after
 from radixmesh_tpu.slo.control import RequestShed
 from radixmesh_tpu.router.cache_aware_router import CacheAwareRouter
@@ -125,6 +126,11 @@ class EngineRunner:
         resume_tokens: Sequence[int] | None = None,
         trace_id: int | None = None,
     ) -> Request:
+        # Arrival is stamped BEFORE the lock (as SLORunner.submit does):
+        # engine.step() runs under it, so a submit landing mid-step waits
+        # the step out first — queueing like any other, and the largest
+        # part of engine.admission_wait on a busy engine.
+        t_arrival = time.monotonic()
         with self._lock:
             if self._closed:
                 # After the shutdown cancel sweep nothing steps the engine
@@ -134,10 +140,12 @@ class EngineRunner:
                 raise RuntimeError(
                     "node is draining — retry via the router"
                 )
-            req = self.engine.add_request(
+            req = self.engine.make_request(
                 prompt, sampling, resume_tokens=resume_tokens,
                 trace_id=trace_id,
             )
+            req.submit_time = t_arrival
+            self.engine.enqueue(req)
         self._wake.set()
         return req
 
@@ -253,7 +261,8 @@ class EngineRunner:
     def _run(self) -> None:
         while not self._stop.is_set():
             with self._lock:
-                self._pre_step()
+                with annotate("rm.runner.pre_step"):
+                    self._pre_step()
                 has_work = self.engine.has_work()
                 if has_work:
                     try:
@@ -268,7 +277,9 @@ class EngineRunner:
                 # wait: threading.Lock is not fair — looping straight
                 # back, this thread re-takes the lock before a submitter
                 # parked on it is ever scheduled, and an arrival waits out
-                # the whole running batch instead of one step.
+                # the whole running batch instead of one step. (In a
+                # profiler capture this hand-over is the engine thread's
+                # time under no rm.* span; it needs none of its own.)
                 time.sleep(0)
 
     def _fail_step(self, exc: Exception) -> None:
@@ -673,6 +684,11 @@ class ServingFrontend:
                     "p50_ttft_s": round(eng._m_ttft.quantile(0.5), 6),
                     "p99_ttft_s": round(eng._m_ttft.quantile(0.99), 6),
                     "p50_tpot_s": round(eng._m_tpot.quantile(0.5), 6),
+                    # Queue wait of every admission (requeue / dispatch /
+                    # submission, whichever was last, to the instant a
+                    # row was secured): count, sum and bucket counts;
+                    # read as a delta between two snapshots.
+                    "admission_wait": eng.admission_wait,
                 },
                 "pool": {
                     "num_slots": eng.pool.num_slots,
@@ -683,6 +699,9 @@ class ServingFrontend:
                 "cache": {
                     "evictable_tokens": getattr(tree, "evictable_size_", None),
                     "protected_tokens": getattr(tree, "protected_size_", None),
+                    # Host seconds in the tree walk + slot acquisition
+                    # (rm.tree.match) and in publish (rm.tree.publish).
+                    "tree_host": eng.tree_host,
                 },
                 # The hardware every number on this node came from, with
                 # live per-device memory counters (obs/step_plane.py).
@@ -807,12 +826,17 @@ class ServingFrontend:
             # the very first samples are already crash-durable.
             self.history.start()
 
-        def _run_profile(seconds: float) -> tuple[int, dict]:
+        def _run_profile(
+            seconds: float, python: bool = False
+        ) -> tuple[int, dict]:
             """One ``jax.profiler`` capture window into a fresh numbered
             subdirectory of the operator-configured base dir. Shared by
             POST /profile and GET /debug/profile?seconds=N (the step-
             attribution quickstart's one-liner) so the path policy —
-            clients never choose filesystem paths — cannot drift."""
+            clients never choose filesystem paths — cannot drift. The
+            python tracer is off unless ``python`` (``?python=1`` /
+            ``"python": true``): the ``rm.*`` host spans and the device
+            planes do not need it, and it is most of a capture's cost."""
             if frontend.profile_dir is None:
                 return 403, {"error": "profiling disabled (no --profile-dir)"}
             if not (0.0 < seconds <= 60.0):
@@ -832,7 +856,7 @@ class ServingFrontend:
                     f"{frontend._profile_seq:04d}",
                 )
                 stats = frontend.runner.engine.stats
-                with _profile(logdir):
+                with _profile(logdir, python_tracer=python):
                     before = stats.decode_steps
                     # meshcheck: ok[sleep-audit] the sleep IS the
                     # requested jax.profiler capture window.
@@ -905,7 +929,8 @@ class ServingFrontend:
                             self, 400, {"error": "seconds must be a number"}
                         )
                         return
-                    code, obj = frontend._run_profile(seconds)
+                    python = q.get("python", ["0"])[-1] not in ("", "0")
+                    code, obj = frontend._run_profile(seconds, python)
                     _json_response(self, code, obj)
                 elif self.path == "/debug/requests":
                     _json_response(self, 200, frontend._debug_requests())
@@ -1002,7 +1027,9 @@ class ServingFrontend:
                     except (TypeError, ValueError, json.JSONDecodeError) as e:
                         _json_response(self, 400, {"error": str(e)})
                         return
-                    code, obj = frontend._run_profile(seconds)
+                    code, obj = frontend._run_profile(
+                        seconds, bool(body.get("python", False))
+                    )
                     _json_response(self, code, obj)
                     return
                 if self.path == "/cancel":
@@ -1215,32 +1242,37 @@ class ServingFrontend:
                 self.end_headers()
                 sent = 0
                 while True:
-                    tokens = frontend.runner.tokens_so_far(req)
-                    for t in tokens[sent:]:
-                        self.wfile.write(
-                            f"data: {json.dumps({'token': t})}\n\n".encode()
-                        )
-                    sent = len(tokens)
-                    self.wfile.flush()
-                    if req.state is RequestState.FINISHED:
-                        final = frontend.runner.tokens_so_far(req)
-                        for t in final[sent:]:
+                    # One span per wake-up (not per token): encode +
+                    # write + flush of whatever landed since the last.
+                    with annotate("rm.http.emit"):
+                        tokens = frontend.runner.tokens_so_far(req)
+                        for t in tokens[sent:]:
                             self.wfile.write(
-                                f"data: {json.dumps({'token': t})}\n\n".encode()
+                                f"data: {json.dumps({'token': t})}\n\n"
+                                .encode()
                             )
-                        done_evt = {"done": True, "output_ids": final}
-                        if req.resume_offset:
-                            done_evt["resumed_from"] = req.resume_offset
-                        if frontend.tokenizer is not None:
-                            done_evt["text"] = frontend.tokenizer.decode(final)
-                        if req.cancelled:
-                            done_evt["cancelled"] = True
-                        if req.error is not None:
-                            done_evt["error"] = req.error
-                        self.wfile.write(
-                            f"data: {json.dumps(done_evt)}\n\n".encode()
-                        )
+                        sent = len(tokens)
                         self.wfile.flush()
+                    if req.state is RequestState.FINISHED:
+                        with annotate("rm.http.emit"):
+                            final = frontend.runner.tokens_so_far(req)
+                            for t in final[sent:]:
+                                self.wfile.write(
+                                    f"data: {json.dumps({'token': t})}\n\n".encode()
+                                )
+                            done_evt = {"done": True, "output_ids": final}
+                            if req.resume_offset:
+                                done_evt["resumed_from"] = req.resume_offset
+                            if frontend.tokenizer is not None:
+                                done_evt["text"] = frontend.tokenizer.decode(final)
+                            if req.cancelled:
+                                done_evt["cancelled"] = True
+                            if req.error is not None:
+                                done_evt["error"] = req.error
+                            self.wfile.write(
+                                f"data: {json.dumps(done_evt)}\n\n".encode()
+                            )
+                            self.wfile.flush()
                         tr = req.trace
                         if tr is not None:
                             tr.add(

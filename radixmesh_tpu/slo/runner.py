@@ -149,7 +149,7 @@ class SLORunner(EngineRunner):
         # Keep the engine's own FIFO shallow: dispatch at most one
         # admission wave ahead, so ordering stays with the WFQ and a
         # deadline re-check happens as close to prefill as possible.
-        with annotate("slo.pump"):
+        with annotate("rm.slo.pump"):
             while len(self.engine.waiting) < self.engine.max_batch:
                 req = self.ctl.pop_ready(now)
                 if req is None:

@@ -1,0 +1,208 @@
+"""The program's host spans (``rm.*`` through ``obs.tracing.annotate`` =
+``jax.profiler.TraceAnnotation``) and its two always-on counters.
+
+One tiny model served through ``ServingFrontend`` with a profile
+directory: a ``/debug/profile`` capture taken during a few requests holds
+every span name, nested as ``Engine.step`` documents them, on the engine
+thread's line; ``rm.http.emit`` on the stream handlers' lines; nothing of
+the python tracer unless ``?python=1``. Without a capture the same
+requests leave no trace file. ``/debug/state`` counts every admission's
+queue wait once and the tree's host seconds."""
+
+import glob
+import json
+import os
+import threading
+import time
+import urllib.request
+
+import jax
+import pytest
+
+from radixmesh_tpu.engine.engine import Engine
+from radixmesh_tpu.engine.request import SamplingParams
+from radixmesh_tpu.models.llama import ModelConfig, init_params
+from radixmesh_tpu.server.http_frontend import ServingFrontend
+from radixmesh_tpu.slo.control import SLOConfig
+
+ENGINE_SPANS = {
+    # name: parent (None = top level of the engine thread's line)
+    "rm.runner.pre_step": None,
+    "rm.slo.pump": "rm.runner.pre_step",
+    "rm.engine.step": None,
+    "rm.admit": "rm.engine.step",
+    "rm.tree.match": "rm.admit",
+    "rm.prefill.dense": "rm.admit",
+    "rm.prefill.group": "rm.admit",
+    "rm.first_token.sync": "rm.admit",
+    "rm.decode.build": "rm.engine.step",
+    "rm.decode.dispatch": "rm.engine.step",
+    "rm.decode.sample": "rm.engine.step",
+    "rm.decode.sync": "rm.engine.step",
+    "rm.decode.consume": "rm.engine.step",
+    "rm.tree.publish": None,  # under rm.decode.consume at finish, a prefill at install
+}
+
+
+def _stream(base, ids, n):
+    req = urllib.request.Request(
+        base + "/generate",
+        data=json.dumps({"input_ids": ids, "max_tokens": n, "stream": True,
+                         "stop_token_ids": []}).encode(),
+        headers={"Content-Type": "application/json"}, method="POST",
+    )
+    with urllib.request.urlopen(req, timeout=60) as r:
+        return r.read()
+
+
+def _get(url):
+    with urllib.request.urlopen(url, timeout=60) as r:
+        return json.loads(r.read())
+
+
+def _lines(logdir):
+    """``[(line events)]`` of plane /host:CPU: ``(name, start, end)``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out.append([(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                        for e in line.events])
+    return out
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """A served tiny model; the traffic of one round (a lone streamed
+    request: dense prefill; three requests put in the queue together: one
+    batched prefill) is run once to compile, then under each capture."""
+    cfg = ModelConfig.tiny()
+    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0)), num_slots=1024,
+                 page_size=4, max_batch=4, name="host-spans")
+    profile_dir = str(tmp_path_factory.mktemp("profile"))
+    f = ServingFrontend(eng, port=0, profile_dir=profile_dir, slo=SLOConfig())
+    base = f"http://127.0.0.1:{f.port}"
+    rounds = iter(range(1, 100))
+
+    def traffic():
+        k = 1000 * next(rounds)
+        _stream(base, list(range(k, k + 20)), 6)
+        with f.runner._lock:  # one admission wave -> rm.prefill.group
+            reqs = [eng.add_request(list(range(k + 100 * i, k + 100 * i + 20)),
+                                    SamplingParams(max_new_tokens=6))
+                    for i in (1, 2, 3)]
+        f.runner._wake.set()
+        for r in reqs:
+            f.runner.wait(r, timeout=60)
+        return 4  # requests sent
+
+    def capture(query=""):
+        got = {}
+        t = threading.Thread(target=lambda: got.update(
+            _get(base + "/debug/profile?seconds=1.5" + query)))
+        t.start()
+        time.sleep(0.3)
+        traffic()
+        t.join()
+        return _lines(got["logdir"])
+
+    traffic()  # compiles
+    yield {"frontend": f, "engine": eng, "base": base, "traffic": traffic,
+           "capture": capture, "profile_dir": profile_dir}
+    f.close()
+
+
+def test_no_capture_leaves_no_trace_and_counts_every_admission(served):
+    eng, base = served["engine"], served["base"]
+    files = lambda: sorted(  # noqa: E731
+        p for p, _, fs in os.walk(served["profile_dir"]) for _ in fs)
+    before_files = files()
+
+    def state():
+        # A waiter wakes on ``state = FINISHED``, before that step has
+        # counted and published the request; /debug/state takes no lock,
+        # the step does.
+        with served["frontend"].runner._lock:
+            return _get(base + "/debug/state")
+
+    a = state()
+    n = served["traffic"]()
+    b = state()
+    assert files() == before_files  # spans without a capture write nothing
+    wa, wb = a["engine"]["admission_wait"], b["engine"]["admission_wait"]
+    assert wb["count"] - wa["count"] == n  # each admitted request once
+    assert sum(wb["counts"]) == wb["count"] == eng.admission_wait["count"]
+    assert len(wb["counts"]) == len(wb["le_ms"]) + 1
+    assert 0.0 < wb["sum_s"] - wa["sum_s"] < 5.0
+    ta, tb = a["cache"]["tree_host"], b["cache"]["tree_host"]
+    assert tb["matches"] - ta["matches"] == n  # one walk per admission
+    # one publish when a prompt is installed, one when the request finishes
+    finished = b["engine"]["finished"] - a["engine"]["finished"]
+    assert finished == n and tb["publishes"] - ta["publishes"] == 2 * n
+    assert tb["match_s"] > ta["match_s"] and tb["publish_s"] > ta["publish_s"]
+
+
+def test_capture_holds_every_span_nested_as_documented(served):
+    lines = served["capture"]()
+    engine = max(lines, key=lambda evs: sum(n == "rm.engine.step" for n, _, _ in evs))
+    spans = [e for e in engine if e[0].startswith("rm.")]
+    names = {n for n, _, _ in spans}
+    assert set(ENGINE_SPANS) <= names, set(ENGINE_SPANS) - names
+    assert "rm.http.emit" not in names
+
+    def inside(child, parent):
+        return any(p[0] == parent and p[1] <= child[1] and child[2] <= p[2]
+                   for p in spans)
+
+    for name, parent in ENGINE_SPANS.items():
+        if parent is not None:
+            mine = [s for s in spans if s[0] == name]
+            assert all(inside(s, parent) for s in mine), (name, parent)
+    publishes = [s for s in spans if s[0] == "rm.tree.publish"]
+    assert any(inside(s, "rm.decode.consume") for s in publishes)
+    assert all(inside(s, "rm.engine.step") for s in publishes)
+    # a decode step's five phases follow each other inside one step
+    step = next(p for p in spans if p[0] == "rm.engine.step" and any(
+        s[0] == "rm.decode.dispatch" and p[1] <= s[1] and s[2] <= p[2] for s in spans))
+    phases = sorted((s for s in spans if s[0].startswith("rm.decode.")
+                     and step[1] <= s[1] and s[2] <= step[2]), key=lambda s: s[1])
+    assert [s[0].rsplit(".", 1)[1] for s in phases] == [
+        "build", "sample", "dispatch", "sync", "consume"]  # the fused CPU launch
+    assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
+    # the stream handler's spans are on another line
+    emit_lines = [evs for evs in lines if any(n == "rm.http.emit" for n, _, _ in evs)]
+    assert emit_lines and all(evs is not engine for evs in emit_lines)
+    # python tracer off: no frame events ("$file:line fn") anywhere
+    assert not any(n.startswith("$") for evs in lines for n, _, _ in evs)
+
+
+def test_python_tracer_only_on_request(served):
+    lines = served["capture"]("&python=1")
+    assert any(n.startswith("$") for evs in lines for n, _, _ in evs)
+    assert any(n == "rm.engine.step" for evs in lines for n, _, _ in evs)
+
+
+def test_readmission_after_preemption_waits_from_its_requeue_time():
+    cfg = ModelConfig.tiny()
+    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0)), num_slots=512,
+                 page_size=4, max_batch=2, max_seq_len=128, name="host-spans-2")
+    req = eng.add_request(list(range(1, 20)), SamplingParams(max_new_tokens=8))
+    req.submit_time -= 100.0  # its first life queued for 100 s
+    for _ in range(3):
+        eng.step()
+    aw = eng.admission_wait
+    assert aw["count"] == 1 and aw["counts"][-1] == 1  # beyond the last edge
+    assert aw["sum_s"] == pytest.approx(100.0, abs=1.0)
+    eng._preempt(req)
+    while eng.has_work():
+        eng.step()
+    assert len(req.output_tokens) == 8
+    assert aw["count"] == 2 and sum(aw["counts"]) == 2
+    assert aw["counts"][-1] == 1  # the second wait is not the first one again
+    assert aw["sum_s"] == pytest.approx(100.0, abs=1.0)
+    assert eng.tree_host["matches"] == 2
+    assert eng.tree_host["publishes"] == 4  # install, preempt, install, finish

@@ -269,7 +269,7 @@ class TestProfileEndpoint:
         used to be swallowed into a 200 with nothing captured."""
         import jax.profiler
 
-        def broken(_logdir):
+        def broken(_logdir, **_kw):
             raise RuntimeError("profiler backend missing")
 
         monkeypatch.setattr(jax.profiler, "trace", broken)
