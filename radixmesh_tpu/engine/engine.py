@@ -333,6 +333,7 @@ class Engine:
         # last) to the instant a batch row is secured, per admission.
         # tree_host: host seconds in the tree walk + slot acquisition
         # and in _publish, at the rm.tree.match / rm.tree.publish sites.
+        # sampler: launches by the executable _launch_draws chose.
         self.admission_wait = {
             "count": 0,
             "sum_s": 0.0,
@@ -342,6 +343,7 @@ class Engine:
         self.tree_host = {
             "matches": 0, "match_s": 0.0, "publishes": 0, "publish_s": 0.0,
         }
+        self.sampler = {"argmax_launches": 0, "drawing_launches": 0}
         self._span_match = _TreeSpan(
             "rm.tree.match", self.tree_host, "matches", "match_s"
         )
@@ -1693,6 +1695,22 @@ class Engine:
             r.sampling.seed is not None for r in rows
         )
 
+    def _launch_draws(self, rows: Iterable[Request]) -> bool:
+        """THE rule for which sampler executable a launch runs
+        (``ops/sampling.py``, static ``draws``): False — the argmax and
+        nothing else — when no row of the launch draws, by the sampler's
+        own test (``temperature <= 0`` is greedy). ``rows`` are the
+        requests whose tokens the launch will hand out, never
+        ``self._temps``: a released row keeps its last temperature there
+        until the next request is installed, and one finished sampling
+        request must not pin the sort on an empty row. Counted per
+        launch in ``self.sampler`` (/debug/state)."""
+        draws = any(not (r.sampling.temperature <= 0.0) for r in rows)
+        self.sampler[
+            "drawing_launches" if draws else "argmax_launches"
+        ] += 1
+        return draws
+
     def _seed_key(self, req: Request) -> jax.Array:
         """Canonical per-row sampling key: a pure function of (seed,
         absolute token position). ``req.num_tokens`` IS the position of
@@ -1719,16 +1737,20 @@ class Engine:
         exact shape and key schedule, so a request resurrected on
         another node redraws the same continuation its first life would
         have drawn (the categorical draw depends on the batch SHAPE, so
-        shape-stability here is what makes cross-life replay exact)."""
-        tok = sample_tokens(
-            logit_row[None, :],
-            self._seed_key(req),
-            temperature=jnp.asarray(
-                [req.sampling.temperature], jnp.float32
-            ),
-            top_p=jnp.asarray([req.sampling.top_p], jnp.float32),
-            top_k=jnp.asarray([req.sampling.top_k], jnp.int32),
-        )
+        shape-stability here is what makes cross-life replay exact). A
+        greedy row's token is the argmax in either life: no key."""
+        if self._launch_draws((req,)):
+            tok = sample_tokens(
+                logit_row[None, :],
+                self._seed_key(req),
+                temperature=jnp.asarray(
+                    [req.sampling.temperature], jnp.float32
+                ),
+                top_p=jnp.asarray([req.sampling.top_p], jnp.float32),
+                top_k=jnp.asarray([req.sampling.top_k], jnp.int32),
+            )
+        else:
+            tok = sample_tokens(logit_row[None, :], None, draws=False)
         return int(np.asarray(tok)[0])
 
     def _record_first_token(self, req: Request) -> None:
@@ -1765,25 +1787,28 @@ class Engine:
                 self._record_first_token(req)
                 req.note_progress()
             return
-        self._rng, key = jax.random.split(self._rng)
         # Pad to a power-of-two batch (repeating row 0) so serving queue
         # depths don't each compile a fresh sample_tokens variant.
         n = len(pending)
         n_b = _pow2_at_least(n, floor=1)
         logits = [logit for _, logit in pending]
-        temps = [r.sampling.temperature for r, _ in pending]
-        tops = [r.sampling.top_p for r, _ in pending]
-        topks = [r.sampling.top_k for r, _ in pending]
         pad = n_b - n
-        sampled = np.asarray(
-            sample_tokens(
-                jnp.stack(logits + [logits[0]] * pad),
+        logits_d = jnp.stack(logits + [logits[0]] * pad)
+        if self._launch_draws(r for r, _ in pending):
+            self._rng, key = jax.random.split(self._rng)
+            temps = [r.sampling.temperature for r, _ in pending]
+            tops = [r.sampling.top_p for r, _ in pending]
+            topks = [r.sampling.top_k for r, _ in pending]
+            sampled_d = sample_tokens(
+                logits_d,
                 key,
                 temperature=jnp.asarray(temps + [0.0] * pad, jnp.float32),
                 top_p=jnp.asarray(tops + [1.0] * pad, jnp.float32),
                 top_k=jnp.asarray(topks + [0] * pad, jnp.int32),
             )
-        )[:n]
+        else:
+            sampled_d = sample_tokens(logits_d, None, draws=False)
+        sampled = np.asarray(sampled_d)[:n]
         now = time.monotonic()
         for (req, _), tok in zip(pending, sampled):
             req.first_token_time = now
@@ -2311,12 +2336,15 @@ class Engine:
                     sampled[row] = self._sample_seeded_row(req, logits[row])
         else:
             with annotate("rm.decode.sample"):
-                self._rng, key = jax.random.split(self._rng)
-                sampled_d = sample_tokens(
-                    logits, key, temperature=jnp.asarray(self._temps),
-                    top_p=jnp.asarray(self._top_ps),
-                    top_k=jnp.asarray(self._top_ks),
-                )
+                if self._launch_draws(r for _, r in active):
+                    self._rng, key = jax.random.split(self._rng)
+                    sampled_d = sample_tokens(
+                        logits, key, temperature=jnp.asarray(self._temps),
+                        top_p=jnp.asarray(self._top_ps),
+                        top_k=jnp.asarray(self._top_ks),
+                    )
+                else:
+                    sampled_d = sample_tokens(logits, None, draws=False)
             with annotate("rm.decode.sync"):  # the wait for the device
                 sampled = np.asarray(sampled_d)
         self.stats.decode_steps += 1
@@ -2447,8 +2475,12 @@ class Engine:
             top_ks_d = jnp.asarray(self._top_ks)
         with annotate("rm.decode.sample"):
             # The sampler itself is fused into the launch (device-side
-            # sampling feeds each step): only its key is drawn here.
-            self._rng, key = jax.random.split(self._rng)
+            # sampling feeds each step): only its key is drawn here, and
+            # an argmax launch reads none (the stream does not advance).
+            draws = self._launch_draws(r for _, r in active)
+            key = self._rng
+            if draws:
+                self._rng, key = jax.random.split(self._rng)
         with annotate("rm.decode.dispatch"):
             if self._pp:
                 from radixmesh_tpu.parallel.pp_serving import pp_decode_multi
@@ -2469,6 +2501,7 @@ class Engine:
                     mesh=self.device_mesh,
                     kv_scale=self.pool.kv_scale,
                     scratch_slot=self._scratch_slot,
+                    draws=draws,
                 )
             elif compact:
                 res = decode_multi_compact(
@@ -2487,6 +2520,7 @@ class Engine:
                     mesh=self.device_mesh,
                     kv_scale=self.pool.kv_scale,
                     top_ks=top_ks_d,
+                    draws=draws,
                 )
             else:
                 res = decode_multi(
@@ -2504,6 +2538,7 @@ class Engine:
                     mesh=self.device_mesh,
                     kv_scale=self.pool.kv_scale,
                     top_ks=top_ks_d,
+                    draws=draws,
                 )
             sampled = self._commit_pool_update(res)
         with annotate("rm.decode.sync"):
@@ -2819,7 +2854,10 @@ class Engine:
             logits = self._commit_pool_update(res)
         if active:
             with annotate("rm.decode.sample"):
-                self._rng, key = jax.random.split(self._rng)
+                draws = self._launch_draws(r for _, r in active)
+                key = None
+                if draws:
+                    self._rng, key = jax.random.split(self._rng)
                 accept_len, bonus = spec_verify_sample(
                     logits,
                     jnp.asarray(toks[:, 1:]),
@@ -2828,6 +2866,7 @@ class Engine:
                     jnp.asarray(self._temps),
                     jnp.asarray(self._top_ps),
                     jnp.asarray(self._top_ks),
+                    draws=draws,
                 )
             with annotate("rm.decode.sync"):
                 accept_len = np.asarray(accept_len)  # [B] one sync

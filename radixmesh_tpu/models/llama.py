@@ -679,7 +679,7 @@ def decode_step(
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "page_size", "k_steps", "mesh"),
+    static_argnames=("cfg", "page_size", "k_steps", "mesh", "draws"),
     donate_argnums=(3,),
     donate_argnames=("kv_scale",),
 )
@@ -698,6 +698,7 @@ def decode_multi(
     mesh=None,
     kv_scale: jnp.ndarray | None = None,
     top_ks: jnp.ndarray | int = 0,  # [B] (0 = off)
+    draws: bool = True,  # ops/sampling.py: False = every row is an argmax
 ):
     """``k_steps`` decode iterations fused in ONE dispatch: sampling stays
     on device and each sampled token feeds the next step, so the host pays
@@ -725,9 +726,12 @@ def decode_multi(
         logits, pool = res[0], res[1]
         if scale is not None:
             scale = res[2]
-        k, sk = jax.random.split(k)
+        sk = None
+        if draws:
+            k, sk = jax.random.split(k)
         nxt = sample_tokens(
-            logits, sk, temperature=temperatures, top_p=top_ps, top_k=top_ks
+            logits, sk, temperature=temperatures, top_p=top_ps, top_k=top_ks,
+            draws=draws,
         ).astype(jnp.int32)
         return (nxt, pool, scale, k), nxt
 
@@ -741,7 +745,7 @@ def decode_multi(
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "page_size", "k_steps", "mesh"),
+    static_argnames=("cfg", "page_size", "k_steps", "mesh", "draws"),
     donate_argnums=(3,),
     donate_argnames=("kv_scale",),
 )
@@ -762,6 +766,7 @@ def decode_multi_compact(
     mesh=None,
     kv_scale: jnp.ndarray | None = None,
     top_ks: jnp.ndarray | int = 0,
+    draws: bool = True,
 ):
     """``decode_multi`` over a gathered COMPACT working set — the decode
     path for backends without the aliased Pallas kernel (CPU today).
@@ -803,7 +808,7 @@ def decode_multi_compact(
     res = decode_multi(
         params, cfg, tokens, sub_pool, page_table_c, lengths, key,
         temperatures, top_ps, page_size=page_size, k_steps=k_steps,
-        mesh=mesh, kv_scale=sub_scale, top_ks=top_ks,
+        mesh=mesh, kv_scale=sub_scale, top_ks=top_ks, draws=draws,
     )
     sampled, sub_pool = res[0], res[1]
     pages = pages.at[:, :, :, compact_pages].set(

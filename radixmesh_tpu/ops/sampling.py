@@ -1,9 +1,14 @@
 """Token sampling: greedy / temperature / top-k / top-p, fully jittable
 (static control flow; masking instead of data-dependent branches), plus
 the speculative-decoding verifier (exact rejection sampling against a
-point-mass draft)."""
+point-mass draft). Both take one static ``draws``: a launch none of whose
+rows draws (the caller knows its rows' temperatures before it launches)
+compiles to the argmax alone — no vocabulary sort, no softmax, no random
+draw."""
 
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -52,24 +57,33 @@ def _filtered_logits(
     return jnp.where(scaled < threshold, -jnp.inf, scaled)
 
 
-@jax.jit
+@partial(jax.jit, static_argnames=("draws",))
 def sample_tokens(
     logits: jnp.ndarray,
-    rng: jax.Array,
+    rng: jax.Array | None,
     temperature: float | jnp.ndarray = 0.0,
     top_k: int | jnp.ndarray = 0,
     top_p: float | jnp.ndarray = 1.0,
+    *,
+    draws: bool = True,
 ) -> jnp.ndarray:
     """Sample one token id per row of ``logits`` [..., vocab].
 
     ``temperature==0`` → greedy. ``top_k``/``top_p`` filter before the
-    categorical draw. All paths execute; selection is by ``jnp.where`` so a
-    single compiled executable serves every setting of the dynamic args.
+    categorical draw. ``draws`` (static) says whether ANY row of this
+    launch may have ``temperature > 0``. True: all paths execute and
+    selection is by ``jnp.where``, so one executable serves every setting
+    of the dynamic args — a single drawing row makes every row of the
+    launch pay the sort. False: the argmax and nothing else; ``rng`` and
+    the three settings are not read (``rng`` may be None). With every
+    temperature 0 the two return the same ids.
     ``temperature``/``top_k``/``top_p`` may be scalars or per-row arrays
     of shape ``logits.shape[:-1]`` (the continuous-batching engine passes
     one value per batch row; ``top_k`` is dynamic — no recompile per k).
     """
     greedy = jnp.argmax(logits, axis=-1)
+    if not draws:
+        return greedy
     temperature = jnp.broadcast_to(
         jnp.asarray(temperature, dtype=jnp.float32), logits.shape[:-1]
     )
@@ -81,15 +95,17 @@ def sample_tokens(
     return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
-@jax.jit
+@partial(jax.jit, static_argnames=("draws",))
 def spec_verify_sample(
     logits: jnp.ndarray,  # [B, C, V] verify-pass logits (C = gamma + 1)
     drafts: jnp.ndarray,  # [B, C-1] draft token per position (pad arbitrary)
     draft_len: jnp.ndarray,  # [B] real draft tokens per row (0..C-1)
-    rng: jax.Array,
+    rng: jax.Array | None,
     temperature: jnp.ndarray,  # [B]
     top_p: jnp.ndarray,  # [B]
     top_k: jnp.ndarray | int = 0,  # [B] (0 = off)
+    *,
+    draws: bool = True,
 ):
     """Exact speculative verification against a point-mass draft.
 
@@ -102,12 +118,23 @@ def spec_verify_sample(
     ``p_gamma``. The emitted sequence is then distributed EXACTLY as
     step-by-step sampling: P(d) = p(d) on accept, and for x != d,
     (1 - p(d)) * p(x)/(1 - p(d)) = p(x) on reject. Greedy rows
-    (temperature 0) degrade to argmax-prefix matching.
+    (temperature 0) degrade to argmax-prefix matching, and a launch with
+    no other row (static ``draws`` False, as in ``sample_tokens``) is that
+    matching alone: no filtered distribution, no uniform draw, the same
+    ``(accept_len, bonus)``.
 
     Returns ``(accept_len [B], bonus [B])``: rows emit
     ``drafts[:accept_len]`` then ``bonus``.
     """
     B, C, V = logits.shape
+    if not draws:
+        greedy_tok = jnp.argmax(logits, axis=-1)  # [B, C]
+        ok = (greedy_tok[:, : C - 1] == drafts) & (
+            jnp.arange(C - 1)[None, :] < draft_len[:, None]
+        )
+        accept_len = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
+        bonus = jnp.take_along_axis(greedy_tok, accept_len[:, None], axis=1)
+        return accept_len, bonus[:, 0].astype(jnp.int32)
     temperature = jnp.asarray(temperature, jnp.float32)
     top_p = jnp.asarray(top_p, jnp.float32)
     greedy_row = temperature <= 0.0  # [B]

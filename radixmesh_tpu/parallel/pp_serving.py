@@ -375,7 +375,8 @@ def pp_forward_chunk(
 @partial(
     jax.jit,
     static_argnames=(
-        "cfg", "page_size", "k_steps", "mesh", "use_kernel", "interpret"
+        "cfg", "page_size", "k_steps", "mesh", "use_kernel", "interpret",
+        "draws",
     ),
     donate_argnames=("kv_pool", "kv_scale"),
 )
@@ -398,6 +399,7 @@ def pp_decode_multi(
     use_kernel: bool | None = None,
     interpret: bool = False,
     scratch_slot: jnp.ndarray | int | None = None,
+    draws: bool = True,  # ops/sampling.py: False = every row is an argmax
 ):
     """``k_steps`` fused decode iterations through the layer PIPELINE:
     one host round trip per k tokens per batch, under pp×tp.
@@ -663,7 +665,8 @@ def pp_decode_multi(
                 logits = logits_part
             sampled = sample_tokens(
                 logits,
-                jax.random.fold_in(key, jnp.clip(v, 0, None)),
+                jax.random.fold_in(key, jnp.clip(v, 0, None))
+                if draws else None,
                 temperature=jax.lax.dynamic_index_in_dim(
                     temp_all, m, 0, keepdims=False
                 ),
@@ -673,6 +676,7 @@ def pp_decode_multi(
                 top_k=jax.lax.dynamic_index_in_dim(
                     topk_all, m, 0, keepdims=False
                 ),
+                draws=draws,
             ).astype(jnp.int32)
             keep = jnp.logical_and(idx == last, valid)
             cur = outs[m, :, s]

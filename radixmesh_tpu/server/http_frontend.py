@@ -689,6 +689,9 @@ class ServingFrontend:
                     # row was secured): count, sum and bucket counts;
                     # read as a delta between two snapshots.
                     "admission_wait": eng.admission_wait,
+                    # Sampler launches by the executable they ran: the
+                    # argmax alone, or the one that sorts and draws.
+                    "sampler": eng.sampler,
                 },
                 "pool": {
                     "num_slots": eng.pool.num_slots,

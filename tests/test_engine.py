@@ -380,3 +380,113 @@ class TestPrefillWaveSlicing:
         )
         want = eng_wide.generate(prompts, SamplingParams(max_new_tokens=4))
         assert out == want
+
+
+class TestSamplerLaunchRule:
+    """``Engine._launch_draws``: every sampler launch runs the argmax
+    alone unless a LIVE row of that launch draws (``engine.sampler`` on
+    /debug/state counts both kinds)."""
+
+    @staticmethod
+    def _first_token_launches(eng):
+        """Spy: non-seeded ``_finalize_first_tokens`` calls (one batched
+        sampler launch each)."""
+        calls = []
+        orig = eng._finalize_first_tokens
+
+        def spy(pending):
+            calls.append(len(pending))
+            return orig(pending)
+
+        eng._finalize_first_tokens = spy
+        return calls
+
+    @pytest.mark.parametrize("k_steps", [1, 3])
+    def test_all_greedy_traffic_never_draws(self, model, k_steps):
+        cfg, params = model
+        rng = prompts_rng()
+        prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in (5, 12, 21)]
+        eng = make_engine(model, decode_steps_per_launch=k_steps)
+        first = self._first_token_launches(eng)
+        rng_before = np.asarray(eng._rng).copy()
+        outs = eng.generate(prompts, SamplingParams(max_new_tokens=7))
+        for p, o in zip(prompts, outs):
+            assert o == oracle_generate(cfg, params, p, 7)
+        assert eng.sampler["drawing_launches"] == 0
+        if k_steps == 1:  # one launch a decode step, one a first-token round
+            assert eng.sampler["argmax_launches"] == (
+                eng.stats.decode_steps + len(first)
+            )
+        else:  # fused launches hold up to k steps each
+            decode_launches = eng.sampler["argmax_launches"] - len(first)
+            assert 0 < decode_launches < eng.stats.decode_steps
+        # The unseeded stream carries no contract; an argmax launch reads
+        # no key, so it does not advance it.
+        np.testing.assert_array_equal(np.asarray(eng._rng), rng_before)
+
+    def test_one_drawing_row_pins_the_launch_only_while_it_lives(self, model):
+        cfg, params = model
+        rng = prompts_rng()
+        long_p, short_p = (rng.integers(0, cfg.vocab_size, n).tolist()
+                           for n in (9, 6))
+        eng = make_engine(model)
+        greedy = eng.add_request(long_p, SamplingParams(max_new_tokens=24))
+        drawer = eng.add_request(
+            short_p, SamplingParams(max_new_tokens=4, temperature=0.8)
+        )
+        while drawer.state is not RequestState.FINISHED:
+            before = dict(eng.sampler)
+            eng.step()
+            # Every launch that held the drawing row drew: its first-token
+            # launch and each decode step.
+            assert eng.sampler["argmax_launches"] == before["argmax_launches"]
+            assert eng.sampler["drawing_launches"] > before["drawing_launches"]
+        row = next(i for i, t in enumerate(eng._temps) if t > 0)
+        drew = eng.sampler["drawing_launches"]
+        steps = 0
+        while eng.has_work():
+            eng.step()
+            steps += 1
+            # The finished request's row is empty and still holds its
+            # temperature: the rule reads the live rows, not ``_temps``.
+            assert eng._rows[row] is None
+            assert eng._temps[row] == np.float32(0.8)
+        assert steps > 5 and greedy.state is RequestState.FINISHED
+        assert eng.sampler["drawing_launches"] == drew
+        assert greedy.generated == oracle_generate(cfg, params, long_p, 24)
+
+    def test_seeded_greedy_rows_take_the_argmax_and_replay_the_same(self, model):
+        cfg, params = model
+        rng = prompts_rng()
+        prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in (7, 11)]
+        eng = make_engine(model)
+        outs = eng.generate(
+            prompts, SamplingParams(max_new_tokens=6, temperature=0.0, seed=99)
+        )
+        assert eng.sampler["drawing_launches"] == 0
+        # The canonical seeded path is one [1, V] launch a row and token.
+        assert eng.sampler["argmax_launches"] == sum(len(o) for o in outs)
+        for p, o in zip(prompts, outs):
+            assert o == oracle_generate(cfg, params, p, 6)
+        # A seeded row that draws still takes the keyed [1, V] draw, and
+        # draws the same tokens in a second life.
+        sp = SamplingParams(max_new_tokens=6, temperature=0.9, seed=99)
+        a = make_engine(model).generate(prompts[:1], sp)
+        eng_b = make_engine(model)
+        b = eng_b.generate(prompts[:1], sp)
+        assert a == b and eng_b.sampler["argmax_launches"] == 0
+        assert eng_b.sampler["drawing_launches"] == len(b[0])
+
+    def test_spec_verify_launches_follow_the_same_rule(self, model):
+        cfg, params = model
+        base = prompts_rng().integers(1, cfg.vocab_size, 6).tolist()
+        eng = make_engine(model, spec_decode_tokens=4)
+        sp = SamplingParams(temperature=0.0, max_new_tokens=16)
+        want = make_engine(model).generate([base * 4], sp)
+        assert eng.generate([base * 4], sp) == want
+        assert eng.stats.spec_accepted + eng.stats.spec_rejected > 0
+        assert eng.sampler["drawing_launches"] == 0
+        eng.generate(
+            [base * 3], SamplingParams(temperature=0.7, max_new_tokens=8)
+        )
+        assert eng.sampler["drawing_launches"] > 0

@@ -7,7 +7,8 @@ every span name, nested as ``Engine.step`` documents them, on the engine
 thread's line; ``rm.http.emit`` on the stream handlers' lines; nothing of
 the python tracer unless ``?python=1``. Without a capture the same
 requests leave no trace file. ``/debug/state`` counts every admission's
-queue wait once and the tree's host seconds."""
+queue wait once, the tree's host seconds, and the sampler's launches by
+the executable they ran."""
 
 import glob
 import json
@@ -144,6 +145,14 @@ def test_no_capture_leaves_no_trace_and_counts_every_admission(served):
     finished = b["engine"]["finished"] - a["engine"]["finished"]
     assert finished == n and tb["publishes"] - ta["publishes"] == 2 * n
     assert tb["match_s"] > ta["match_s"] and tb["publish_s"] > ta["publish_s"]
+    # engine.sampler: the traffic is greedy, so every decode step's launch
+    # and every first-token launch (the lone request's, the wave's) ran
+    # the argmax alone.
+    sa, sb = a["engine"]["sampler"], b["engine"]["sampler"]
+    assert sb == eng.sampler and sb["drawing_launches"] == 0
+    steps = b["engine"]["decode_steps"] - a["engine"]["decode_steps"]
+    assert steps > 0
+    assert sb["argmax_launches"] - sa["argmax_launches"] >= steps + 2
 
 
 def test_capture_holds_every_span_nested_as_documented(served):

@@ -218,6 +218,102 @@ class TestSampling:
         assert min(hot.count(0), hot.count(1)) > 30
 
 
+def _logits_with_ties(rows, vocab, dtype, seed=0):
+    """Random logits whose row maximum appears two or three times (bf16
+    rounding adds ties of its own): first-index argmax is the contract."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(rows, vocab)).astype(np.float32) * 3.0
+    for r in range(rows):
+        at = rng.choice(vocab, size=2 + r % 2, replace=False)
+        x[r, at] = x[r].max() + 1.0
+    return jnp.asarray(x).astype(dtype)
+
+
+class TestSamplerRunsWhatItsRowsAsk:
+    """``sample_tokens(..., draws=...)`` (static): a launch no row of
+    which draws is the argmax alone; every other launch is the executable
+    of before PR 26, to the bit (``frozen_sampler``)."""
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("rows", [1, 4, 32])
+    def test_argmax_variant_equals_drawing_variant_on_greedy_rows(
+        self, rows, dtype
+    ):
+        logits = _logits_with_ties(rows, 1000, dtype, seed=rows)
+        zeros = jnp.zeros((rows,), jnp.float32)
+        drawing = sample_tokens(
+            logits, jax.random.PRNGKey(3), temperature=zeros,
+            top_k=jnp.zeros((rows,), jnp.int32), top_p=jnp.ones((rows,)),
+        )
+        argmax = sample_tokens(logits, None, draws=False)
+        assert argmax.dtype == drawing.dtype and argmax.shape == drawing.shape
+        np.testing.assert_array_equal(np.asarray(argmax), np.asarray(drawing))
+        # Ties were really there, and the first index won.
+        x = np.asarray(logits.astype(jnp.float32))
+        assert ((x == x.max(-1, keepdims=True)).sum(-1) >= 2).all()
+        np.testing.assert_array_equal(np.asarray(argmax), x.argmax(-1))
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("rows,seed", [(1, 0), (5, 1), (5, 2), (32, 3)])
+    def test_drawing_variant_is_frozen(self, rows, seed, dtype):
+        from tests import frozen_sampler
+
+        # greedy, temperature only, top-k, top-p, all three — cycled.
+        kinds = [(0.0, 0, 1.0), (0.8, 0, 1.0), (1.0, 7, 1.0),
+                 (1.3, 0, 0.9), (0.7, 50, 0.95)]
+        rows_kinds = [kinds[(i + seed) % len(kinds)] for i in range(rows)]
+        temps = jnp.asarray([k[0] for k in rows_kinds], jnp.float32)
+        top_k = jnp.asarray([k[1] for k in rows_kinds], jnp.int32)
+        top_p = jnp.asarray([k[2] for k in rows_kinds], jnp.float32)
+        logits = _logits_with_ties(rows, 1000, dtype, seed=seed)
+        for key in (jax.random.PRNGKey(seed), jax.random.PRNGKey(2**31 + seed)):
+            want = frozen_sampler.sample_tokens(
+                logits, key, temperature=temps, top_k=top_k, top_p=top_p
+            )
+            got = sample_tokens(
+                logits, key, temperature=temps, top_k=top_k, top_p=top_p
+            )
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        if rows > 1:  # the draws are draws: not every row came out greedy
+            greedy = np.asarray(jnp.argmax(logits, axis=-1))
+            assert (np.asarray(got) != greedy).any()
+
+    def test_greedy_launch_at_served_size_is_one_reduction(self):
+        """[32 rows, Qwen2's 152 064]: the program of an all-greedy launch
+        holds no vocabulary sort, no cumulative sum, no random bits —
+        as lowered and after XLA's passes (guards the gain with no chip).
+        The drawing variant is the positive control: the same search
+        finds all three there."""
+        import re
+
+        logits = jax.ShapeDtypeStruct((32, 152064), jnp.float32)
+        per_row = [jax.ShapeDtypeStruct((32,), t)
+                   for t in (jnp.float32, jnp.int32, jnp.float32)]
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+
+        def ops(lowered):
+            """Op and callee names of the StableHLO text, and opcodes of
+            the compiled HLO (its metadata quotes this file: left out)."""
+            hlo = re.sub(r"metadata=\{[^}]*\}", "", lowered.compile().as_text())
+            named = re.findall(r"stablehlo\.(\w+)|@(\w+)", lowered.as_text())
+            return {a or b for a, b in named} | set(
+                re.findall(r" ([a-z][\w-]*)\(", hlo)
+            )
+
+        def heavy(names):
+            return sorted(n for n in names if re.search(
+                r"sort|cumsum|cumulative|reduce[_-]window|rng|threefry|"
+                r"^exp|^log|erf", n))
+
+        greedy = ops(sample_tokens.lower(logits, None, draws=False))
+        assert "reduce" in greedy and heavy(greedy) == []
+        drawing = heavy(ops(sample_tokens.lower(logits, key, *per_row)))
+        assert "sort" in drawing
+        assert any("threefry" in n or "rng" in n for n in drawing)
+        assert any("cumsum" in n or "window" in n for n in drawing)
+
+
 class TestPagedDecodeFused:
     """Fused write+attend decode kernel: one aliased pallas_call writes the
     current token's K/V row into the pool and attends over all ``length``

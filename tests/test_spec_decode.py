@@ -201,6 +201,76 @@ class TestRejectionSamplingExactness:
 
 
 
+def _verify_case(rows, chunk, vocab, seed):
+    """Verify-pass logits whose argmax the drafts follow for a while (so
+    accept lengths differ by row), with drafts shorter than the chunk."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    logits = rng.normal(size=(rows, chunk, vocab)).astype(np.float32) * 2.0
+    greedy = logits.argmax(-1)
+    drafts = greedy[:, : chunk - 1].copy()
+    for r in range(rows):  # row r's draft leaves the argmax at r % chunk
+        drafts[r, r % chunk :] = (drafts[r, r % chunk :] + 1) % vocab
+    draft_len = rng.integers(0, chunk, rows)
+    return (jnp.asarray(logits), jnp.asarray(drafts, jnp.int32),
+            jnp.asarray(draft_len, jnp.int32))
+
+
+class TestVerifierRunsWhatItsRowsAsk:
+    """``spec_verify_sample(..., draws=...)`` (static), as
+    ``sample_tokens``: an all-greedy launch is argmax-prefix matching
+    alone; any other launch is the executable of before PR 26 to the bit
+    (``tests/frozen_sampler.py``)."""
+
+    @pytest.mark.parametrize("rows,chunk", [(1, 3), (4, 5), (8, 2)])
+    def test_matching_alone_equals_the_drawing_variant_on_greedy_rows(
+        self, rows, chunk
+    ):
+        import jax
+        import jax.numpy as jnp
+
+        from radixmesh_tpu.ops.sampling import spec_verify_sample
+
+        logits, drafts, draft_len = _verify_case(rows, chunk, 64, seed=rows)
+        zeros, ones = jnp.zeros((rows,), jnp.float32), jnp.ones((rows,))
+        want = spec_verify_sample(
+            logits, drafts, draft_len, jax.random.PRNGKey(5), zeros, ones,
+            jnp.zeros((rows,), jnp.int32),
+        )
+        got = spec_verify_sample(
+            logits, drafts, draft_len, None, zeros, ones, draws=False
+        )
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        if rows > 1:  # some drafts accepted, some cut: not a vacuous match
+            assert len(set(np.asarray(got[0]).tolist())) > 1
+
+    @pytest.mark.parametrize("rows,chunk,seed", [(5, 3, 0), (5, 5, 1), (10, 4, 2)])
+    def test_drawing_variant_is_frozen(self, rows, chunk, seed):
+        import jax
+        import jax.numpy as jnp
+
+        from radixmesh_tpu.ops.sampling import spec_verify_sample
+        from tests import frozen_sampler
+
+        kinds = [(0.0, 0, 1.0), (0.8, 0, 1.0), (1.0, 7, 1.0),
+                 (1.3, 0, 0.9), (0.7, 20, 0.95)]
+        rows_kinds = [kinds[(i + seed) % len(kinds)] for i in range(rows)]
+        temps = jnp.asarray([k[0] for k in rows_kinds], jnp.float32)
+        top_k = jnp.asarray([k[1] for k in rows_kinds], jnp.int32)
+        top_p = jnp.asarray([k[2] for k in rows_kinds], jnp.float32)
+        logits, drafts, draft_len = _verify_case(rows, chunk, 64, seed)
+        for key in (jax.random.PRNGKey(seed), jax.random.PRNGKey(2**31 + seed)):
+            args = (logits, drafts, draft_len, key, temps, top_p, top_k)
+            want = frozen_sampler.spec_verify_sample(*args)
+            got = spec_verify_sample(*args)
+            for g, w in zip(got, want):
+                assert g.dtype == w.dtype
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 class TestTreeDrafts:
     """The radix cache doubles as the drafter: a replayed request finds
     the previous generation's published tokens cached beyond its history
