@@ -45,6 +45,7 @@ import numpy as np
 from radixmesh_tpu.comm.communicator import Communicator
 from radixmesh_tpu.engine.engine import Engine, _pow2_at_least
 from radixmesh_tpu.engine.request import Request, RequestState, SamplingParams
+from radixmesh_tpu.models.llama import refuse_unsupported
 from radixmesh_tpu.obs.trace_plane import get_recorder
 from radixmesh_tpu.utils.logging import get_logger
 
@@ -139,6 +140,7 @@ class PrefillWorker(Engine):
         :meth:`DecodeWorker.cached_prefix_len`). With ``device_kv`` the
         packet's KV stays a ``jax.Array`` for the ICI path
         (:class:`IciHandoff`) — no device→host copy."""
+        refuse_unsupported(self.cfg, disaggregated=True)
         req = self.add_request(prompt, sampling)
         self._admit()
         if req.state is not RequestState.RUNNING:
@@ -281,6 +283,7 @@ class DecodeWorker:
         plane=None,
         stage_layers: int = 0,
     ):
+        refuse_unsupported(engine.cfg, disaggregated=True)
         self.engine = engine
         self.log = get_logger("disagg.decode")
         self._pending: list[tuple[Request, list[_StagedKV], int]] = []

@@ -49,8 +49,10 @@ from radixmesh_tpu.models.llama import (
     decode_multi,
     decode_multi_compact,
     decode_step,
+    new_moe_stats,
     prefill_chunk_paged,
     prefill_forward,
+    refuse_unsupported,
 )
 from radixmesh_tpu.ops.attention import (
     default_use_kernel,
@@ -220,6 +222,17 @@ class Engine:
     ):
         if page_size & (page_size - 1):
             raise ValueError("page_size must be a power of two")
+        # A model with experts or window layers: whatever assumes the
+        # dense layer is refused here, by name (models/llama.py).
+        refuse_unsupported(
+            cfg, weight_quant=weight_quant,
+            kv_quant=kv_quant or (pool is not None and pool.quant),
+            device_mesh=device_mesh,
+            decode_steps_per_launch=decode_steps_per_launch,
+            spec_decode_tokens=spec_decode_tokens,
+            prefill_inline_budget=prefill_inline_budget,
+            host_cache_slots=host_cache_slots, kv_tier_dir=kv_tier_dir,
+        )
         self.cfg = cfg
         # Multi-chip serving (SURVEY §7 stage 7): tp shards heads/ffn/vocab
         # across the device mesh; the SAME scheduler/tree/publish code runs
@@ -344,6 +357,20 @@ class Engine:
             "matches": 0, "match_s": 0.0, "publishes": 0, "publish_s": 0.0,
         }
         self.sampler = {"argmax_launches": 0, "drawing_launches": 0}
+        # moe: the expert layer's counters, accumulated ON THE DEVICE by
+        # every step (models/llama.py) and read only when /debug/state is
+        # asked; None for a dense model. attn_window: context tokens that
+        # decode launches did / did not have to read on window layers,
+        # summed over rows and sliding layers (host arithmetic on the
+        # lengths, in rm.decode.build).
+        self._moe_stats = new_moe_stats() if cfg.n_experts else None
+        self.attn_window = {"window_tokens_skipped": 0, "window_tokens_read": 0}
+        self._sliding_layers = (
+            cfg.layer_types.count("sliding_attention") if cfg.has_window else 0
+        )
+        # The expert ids of the last launch ([L, B, S, k], on the device):
+        # fetched only for a request that asked for them.
+        self._last_expert_ids = None
         self._span_match = _TreeSpan(
             "rm.tree.match", self.tree_host, "matches", "match_s"
         )
@@ -1780,6 +1807,7 @@ class Engine:
             # token its first life drew at that position.
             now = time.monotonic()
             for req, logit in pending:
+                self._keep_logits(req, logit)
                 tok = self._sample_seeded_row(req, logit)
                 req.first_token_time = now
                 req.output_tokens = [tok]
@@ -1791,6 +1819,8 @@ class Engine:
         # depths don't each compile a fresh sample_tokens variant.
         n = len(pending)
         n_b = _pow2_at_least(n, floor=1)
+        for req, logit in pending:
+            self._keep_logits(req, logit)
         logits = [logit for _, logit in pending]
         pad = n_b - n
         logits_d = jnp.stack(logits + [logits[0]] * pad)
@@ -1826,6 +1856,29 @@ class Engine:
             # may be a whole decode wave away.
             req.note_progress()
 
+    @staticmethod
+    def _keep_logits(req: Request, logit_row) -> None:
+        """The logits hook: a request that asked (``return_logits``)
+        keeps the float32 row the sampler is about to see. One
+        device-to-host copy, for such a request only."""
+        if req.sampling.return_logits:
+            req.logit_rows.append(np.asarray(logit_row, dtype=np.float32))
+
+    def _keep_expert_ids(self, req: Request, start: int, b: int, n: int) -> None:
+        """Same hook, expert choices: positions ``start .. start + n`` of
+        ``req`` were batch row ``b`` of the last launch."""
+        if req.sampling.return_logits and self._last_expert_ids is not None:
+            # One whole copy a launch, sliced on the host: an eager device
+            # slice would compile a program for every (row, length).
+            if not isinstance(self._last_expert_ids, np.ndarray):
+                self._last_expert_ids = np.asarray(self._last_expert_ids)
+            ids = self._last_expert_ids[:, b, :n]  # [L, n, k]
+            req.expert_rows.append((int(start), ids.transpose(1, 0, 2).copy()))
+
+    def _moe_kw(self) -> dict:
+        """What a step of a model with experts takes beside the rest."""
+        return {} if self._moe_stats is None else {"moe_stats": self._moe_stats}
+
     def _prefill_dense(
         self,
         req: Request,
@@ -1852,7 +1905,10 @@ class Engine:
             g = self.pool.gather(prefix_slots)  # [2, L, n, Hkv, D]
             cached_k = cached_k.at[:, 0, p_b - reuse :].set(g[0])
             cached_v = cached_v.at[:, 0, p_b - reuse :].set(g[1])
-        logits, new_k, new_v = prefill_forward(
+        moe_kw = self._moe_kw()
+        if moe_kw:  # keep the bucket's padding out of the counters
+            moe_kw["new_lengths"] = jnp.full((1,), n_new, dtype=jnp.int32)
+        logits, new_k, new_v, *aux = prefill_forward(
             self.params,
             self.cfg,
             jnp.asarray(tokens),
@@ -1860,7 +1916,12 @@ class Engine:
             cached_k,
             cached_v,
             jnp.full((1,), reuse, dtype=jnp.int32),
+            **moe_kw,
         )
+        if aux:
+            self._moe_stats = aux[0]["moe_stats"]
+            self._last_expert_ids = aux[0]["expert_ids"]
+            self._keep_expert_ids(req, reuse, 0, n_new)
         self.pool.write(own[:n_new], new_k[:, 0, :n_new], new_v[:, 0, :n_new])
 
         req.output_tokens = []
@@ -1925,6 +1986,7 @@ class Engine:
             kv_scale=self.pool.kv_scale,
             mesh=self.device_mesh,
             logits_at=logits_at,
+            **self._moe_kw(),
         )
 
     def _sp_capable(self, member: tuple) -> bool:
@@ -2049,6 +2111,9 @@ class Engine:
             for i in range(N):
                 if lastpos[i] >= 0:
                     final_logits[i] = logits[i, 0]
+                nv = min(max(totals[i] - reuses[i] - ci * C, 0), C)
+                if nv:
+                    self._keep_expert_ids(group[i][0], reuses[i] + ci * C, i, nv)
 
         out = []
         for i, (req, row, reuse, prefix_slots, own) in enumerate(group):
@@ -2156,9 +2221,15 @@ class Engine:
     # ------------------------------------------------------------------
 
     def _commit_pool_update(self, res):
-        """Unpack a model call's ``(out, kv_pool[, kv_scale])`` result:
-        store the updated pool buffers and return the leading value (the
-        quantized pool threads its scale array through every call)."""
+        """Unpack a model call's ``(out, kv_pool[, kv_scale][, aux])``
+        result: store the updated pool buffers and return the leading
+        value (the quantized pool threads its scale array through every
+        call; a model with experts returns its counters and the launch's
+        expert ids as a trailing ``aux``)."""
+        if self._moe_stats is not None:
+            *res, aux = res
+            self._moe_stats = aux["moe_stats"]
+            self._last_expert_ids = aux["expert_ids"]
         if self.pool.quant is not None:
             out, self.pool.kv, self.pool.kv_scale = res
         else:
@@ -2238,7 +2309,11 @@ class Engine:
             ),
         )
         self._last_dispatch = last_dispatch()
-        if not self._pp and not use_paged and not seeded:
+        # A model with experts or window layers decodes through
+        # decode_step on every backend (the jnp attention where there is
+        # no kernel): the compact path is decode_multi, which refuses it.
+        step_only = bool(self.cfg.n_experts or self.cfg.has_window)
+        if not self._pp and not use_paged and not seeded and not step_only:
             # Dense single step (small-batch paged fast path,
             # ops/attention.py::select_paged): either no paged kernel on
             # this backend, or the batch sits below --paged-min-batch —
@@ -2276,6 +2351,14 @@ class Engine:
                 lengths[row] = req.kv_len + 1
             for req in preempted:
                 self._preempt(req)
+            if self._sliding_layers:
+                ctx = lengths[[r is not None for r in self._rows]]
+                read = np.minimum(ctx, self.cfg.sliding_window)
+                aw = self.attn_window
+                aw["window_tokens_read"] += int(read.sum()) * self._sliding_layers
+                aw["window_tokens_skipped"] += (
+                    int((ctx - read).sum()) * self._sliding_layers
+                )
 
             active = [
                 (row, r) for row, r in enumerate(self._rows) if r is not None
@@ -2325,8 +2408,15 @@ class Engine:
                     self.page_size,
                     mesh=self.device_mesh,
                     kv_scale=self.pool.kv_scale,
+                    **self._moe_kw(),
                 )
                 logits = self._commit_pool_update(res)
+        asked = [(row, r) for row, r in active if r.sampling.return_logits]
+        if asked:  # the logits hook: one copy of the launch's logits
+            logits_h = np.asarray(logits, dtype=np.float32)
+            for row, req in asked:
+                req.logit_rows.append(logits_h[row])
+                self._keep_expert_ids(req, req.kv_len, row, 1)
         if seeded:
             # The canonical per-row (seed, position) draw syncs per row:
             # sample and sync are one span here.

@@ -29,6 +29,10 @@ class SamplingParams:
     # the continuation its first life would have drawn (the recovery
     # plane's replay-determinism contract, server/recovery.py).
     seed: int | None = None
+    # The logits hook (POST /generate "return_logits"): keep, for every
+    # generated position, the float32 logits row the sampler saw, and for
+    # a model with experts the expert ids every computed position chose.
+    return_logits: bool = False
 
 
 class RequestState(enum.Enum):
@@ -124,6 +128,12 @@ class Request:
     # life): the inter-token-latency clock. Reset by Engine._preempt so
     # a requeued life's first token reads as TTFT, not a huge gap.
     last_token_time: float = 0.0
+    # The logits hook (``SamplingParams.return_logits``): one float32 [V]
+    # row per generated token, in order, and ``(first position, ids
+    # [n, L, k])`` for each run of positions a launch computed (a prefix
+    # served from the tree was computed by an earlier request: no ids).
+    logit_rows: list = field(default_factory=list, repr=False, compare=False)
+    expert_rows: list = field(default_factory=list, repr=False, compare=False)
     # Draft tokens the LAST speculative wave rejected for this row:
     # the spec_verify_miss stall attribution, consumed (zeroed) by
     # Engine._stall_cause.

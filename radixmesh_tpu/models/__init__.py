@@ -6,7 +6,7 @@ from radixmesh_tpu.models.llama import (
     param_logical_axes,
     convert_hf_state_dict,
 )
-from radixmesh_tpu.models import qwen2  # noqa: F401  (registers presets)
+from radixmesh_tpu.models import mellum2, qwen2
 
 _PRESETS = {
     "llama3-8b": ModelConfig.llama3_8b,
@@ -21,6 +21,9 @@ _PRESETS = {
     "qwen2.5-14b": qwen2.qwen25_14b,
     "qwen2.5-32b": qwen2.qwen25_32b,
     "qwen2-tiny": qwen2.qwen2_tiny,
+    "mellum2-12b-a2.5b": mellum2.mellum2_12b_a2_5b,
+    "mellum2-12b-a2.5b-l12": mellum2.mellum2_12b_a2_5b_l12,
+    "mellum2-tiny": mellum2.mellum2_tiny,
 }
 
 

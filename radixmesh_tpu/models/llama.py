@@ -21,6 +21,17 @@ the serving stack's model side that the north star requires
   single-chip or pjit-sharded.
 - Qwen2 is the same architecture with QKV biases and its own dims
   (``models/qwen2.py``).
+- **Layers of different kinds, one scanned body** (``models/mellum2.py``):
+  a config with ``layer_types`` scans a per-layer table beside the weights
+  — rotary inverse frequencies, the cos/sin factor, the attention window
+  (0 = full) — so window and full layers, each with its own RoPE, share
+  the traced body. A config with ``n_experts`` takes the sparse expert
+  layer (``ops/moe.py``) where the others take ``_mlp``; that choice is
+  static. Such a config's entry points return one more value, ``aux``:
+  the expert ids every token chose (a scan output; fetched only when a
+  request asks) and the ``moe`` counters, accumulated on the device.
+  What does not serve such a config is refused by name
+  (``unsupported_reason``), never served by a dense fallback.
 """
 
 from __future__ import annotations
@@ -38,8 +49,9 @@ from radixmesh_tpu.ops.attention import (
     paged_chunk_attention,
     paged_decode_attention,
 )
+from radixmesh_tpu.ops.moe import STATS as MOE_STATS, moe_ffn
 from radixmesh_tpu.ops.norm import rms_norm
-from radixmesh_tpu.ops.rope import apply_rope, rope_frequencies
+from radixmesh_tpu.ops.rope import apply_rope, rope_frequencies, yarn_frequencies
 from radixmesh_tpu.ops.sampling import sample_tokens
 from radixmesh_tpu.ops.wquant import quantize_weight
 
@@ -54,6 +66,12 @@ __all__ = [
     "decode_multi_compact",
     "param_logical_axes",
     "convert_hf_state_dict",
+    "UnsupportedForModel",
+    "unsupported_reason",
+    "refuse_unsupported",
+    "MOE_SITES",
+    "new_moe_stats",
+    "read_moe_stats",
 ]
 
 
@@ -75,9 +93,54 @@ class ModelConfig:
     tie_embeddings: bool = False
     max_seq_len: int = 8192
     dtype: Any = jnp.bfloat16
+    # Layers of different kinds. ``layer_types`` names each layer
+    # ("sliding_attention" | "full_attention"; None = every layer full,
+    # one RoPE from ``rope_theta``/``rope_scaling``). A sliding layer's
+    # query sees ``sliding_window`` keys, its own among them
+    # (transformers' convention). ``rope_by_type`` holds the rotary
+    # parameters of each layer type as ``((type, ((key, value), ...)),
+    # ...)`` — tuples, because the config is a jit-static argument.
+    layer_types: tuple | None = None
+    sliding_window: int = 0
+    rope_by_type: tuple | None = None
+    # Sparse expert layer in every layer when ``n_experts`` > 0
+    # (``intermediate`` is then used by no layer).
+    n_experts: int = 0
+    n_experts_per_tok: int = 0
+    moe_intermediate: int = 0
+    norm_topk_prob: bool = True
+
+    def __post_init__(self):
+        if self.layer_types is not None:
+            kinds = set(self.layer_types)
+            if len(self.layer_types) != self.n_layers or not kinds <= {
+                "sliding_attention", "full_attention"
+            }:
+                raise ValueError(
+                    f"layer_types must name {self.n_layers} layers as "
+                    f"sliding_attention or full_attention, got "
+                    f"{len(self.layer_types)} of {sorted(kinds)}"
+                )
+            if "sliding_attention" in kinds and self.sliding_window < 2:
+                raise ValueError("sliding layers need sliding_window >= 2")
+            missing = kinds - {t for t, _ in self.rope_by_type or ()}
+            if missing:
+                raise ValueError(f"rope_by_type lacks {sorted(missing)}")
+        if self.n_experts and not (
+            0 < self.n_experts_per_tok <= self.n_experts
+            and self.moe_intermediate > 0
+        ):
+            raise ValueError(
+                "n_experts needs 0 < n_experts_per_tok <= n_experts and "
+                "moe_intermediate > 0"
+            )
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+    @property
+    def has_window(self) -> bool:
+        return bool(self.layer_types) and "sliding_attention" in self.layer_types
 
     @classmethod
     def llama3_8b(cls) -> "ModelConfig":
@@ -212,11 +275,11 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
     keys = jax.random.split(key, 9)
     L, H, V = cfg.n_layers, cfg.hidden, cfg.vocab_size
     qd, kvd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-    dims = {
-        "wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd), "wo": (qd, H),
-        "w_gate": (H, cfg.intermediate), "w_up": (H, cfg.intermediate),
-        "w_down": (cfg.intermediate, H),
-    }
+    ffn_width = cfg.moe_intermediate if cfg.n_experts else cfg.intermediate
+    ffn = {"w_gate": (H, ffn_width), "w_up": (H, ffn_width),
+           "w_down": (ffn_width, H)}
+    dims = {"wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd), "wo": (qd, H)}
+    dims |= {"w_router": (H, cfg.n_experts)} if cfg.n_experts else ffn
     layers = {
         "attn_norm": jnp.ones((L, H), dtype=cfg.dtype),
         "mlp_norm": jnp.ones((L, H), dtype=cfg.dtype),
@@ -228,12 +291,20 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         )
         if s is not None:
             layers[name + "_s"] = s
+    if cfg.n_experts:
+        # [L, E, in, out], one expert's block at a time.
+        E = cfg.n_experts
+        for k, (name, (i_dim, o_dim)) in zip(
+            jax.random.split(keys[6], 3), ffn.items()
+        ):
+            w, _ = _draw_blocks(k, L * E, (i_dim, o_dim), i_dim, cfg.dtype, None)
+            layers[name] = w.reshape(L, E, i_dim, o_dim)
     if cfg.qkv_bias:
         layers["bq"] = jnp.zeros((L, qd), dtype=cfg.dtype)
         layers["bk"] = jnp.zeros((L, kvd), dtype=cfg.dtype)
         layers["bv"] = jnp.zeros((L, kvd), dtype=cfg.dtype)
     params = {"final_norm": jnp.ones((H,), dtype=cfg.dtype), "layers": layers}
-    n_blk = _vocab_blocks(V, H, H * max(cfg.intermediate, qd))
+    n_blk = _vocab_blocks(V, H, H * max(ffn_width, qd))
     # [V, H] in row blocks; per-ROW scales serve the gather and the tied
     # LM head alike (ops/wquant.py).
     w, s = _draw_blocks(
@@ -278,6 +349,7 @@ def init_params(
     model bigger than one chip is never gathered on device 0."""
     if weight_quant not in (None, "int8"):
         raise ValueError(f"unknown weight quantization {weight_quant!r}")
+    refuse_unsupported(cfg, weight_quant=weight_quant, device_mesh=mesh)
     return _init_params(key, cfg, weight_quant, mesh)
 
 
@@ -302,6 +374,16 @@ def param_logical_axes(cfg: ModelConfig, params: dict | None = None) -> dict:
             "w_down": ("layer", "ffn", "embed"),
         },
     }
+    if cfg.n_experts:
+        # No mesh axis is mapped to "expert" (parallel/sharding.py): every
+        # chip would hold every expert. Engine refuses a mesh for such a
+        # config; an expert-parallel share is ROADMAP's.
+        axes["layers"] |= {
+            "w_router": ("layer", "embed", "expert"),
+            "w_gate": ("layer", "expert", "embed", "ffn"),
+            "w_up": ("layer", "expert", "embed", "ffn"),
+            "w_down": ("layer", "expert", "ffn", "embed"),
+        }
     if cfg.qkv_bias:
         axes["layers"]["bq"] = ("layer", "q_heads")
         axes["layers"]["bk"] = ("layer", "kv_heads")
@@ -384,6 +466,184 @@ def _mlp(lp: dict, x: jnp.ndarray) -> jnp.ndarray:
     return _wmm(lp, "w_down", "bsi,ih->bsh", gate * up)
 
 
+class UnsupportedForModel(ValueError):
+    """A serving option that this model's layers cannot run under."""
+
+
+def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
+    """Why ``options`` cannot serve ``cfg``, or None. A config with
+    experts or window layers runs on one chip in bf16 through
+    ``prefill_forward`` / ``prefill_chunk_paged`` / ``decode_step`` and
+    nothing else; every other launch path assumes the dense layer and is
+    refused here by name, so that none of them serves such a model with a
+    dense layer's arithmetic. ``options`` are ``Engine``'s keyword names
+    (a falsy value is "not asked for")."""
+    if not (cfg.n_experts or cfg.has_window):
+        return None
+    mesh = options.get("device_mesh")
+    named = {
+        "weight_quant": "int8 weights (--weight-quant int8)",
+        "kv_quant": "an int8 KV pool (--kv-quant int8)",
+        "spec_decode_tokens": "speculative decode (--spec-decode-tokens)",
+        "prefill_inline_budget": "inline prefill waves (--prefill-inline-budget)",
+        "host_cache_slots": "the host KV tier (--host-cache-slots)",
+        "kv_tier_dir": "the disk KV tier (--kv-tier-dir)",
+        "disaggregated": "disaggregated prefill/decode (engine/disagg.py)",
+        "hf_checkpoint": "loading a HuggingFace checkpoint (--weights)",
+    }
+    asked = [text for key, text in named.items() if options.get(key)]
+    if int(options.get("decode_steps_per_launch") or 1) > 1:
+        asked.append("multi-step decode (--decode-steps-per-launch)")
+    if mesh is not None and any(n > 1 for n in dict(mesh.shape).values()):
+        asked.append(f"a device mesh {dict(mesh.shape)} (tp / sp / pp)")
+    if not asked:
+        return None
+    kinds = " and ".join(
+        k for k, on in (("sparse experts", cfg.n_experts),
+                        ("window attention", cfg.has_window)) if on
+    )
+    return (
+        f"a model with {kinds} does not support " + "; ".join(asked)
+        + ": it serves on one chip, bf16 weights and KV, one decode step a "
+        "launch (ROADMAP D6 / R4)"
+    )
+
+
+def refuse_unsupported(cfg: ModelConfig, **options) -> None:
+    reason = unsupported_reason(cfg, **options)
+    if reason is not None:
+        raise UnsupportedForModel(reason)
+
+
+def _layer_tables(cfg: ModelConfig) -> dict | None:
+    """Per-layer inputs scanned beside the weights of a model whose
+    layers differ in kind: ``inv_freq`` [L, D/2] and ``rope_scale`` [L]
+    (each layer type's RoPE: default, or YaRN with its attention factor on
+    cos/sin) and ``window`` [L] (keys a query sees; 0 = all). None for a
+    model of one layer kind, whose body closes over one ``inv_freq``."""
+    if cfg.layer_types is None:
+        return None
+    by_type = {}
+    for kind, pairs in cfg.rope_by_type:
+        rp = dict(pairs)
+        theta = float(rp.get("rope_theta", cfg.rope_theta))
+        if rp.get("rope_type", "default") == "yarn":
+            inv = yarn_frequencies(
+                cfg.head_dim, theta, float(rp["factor"]),
+                int(rp["original_max_position_embeddings"]),
+                float(rp.get("beta_fast", 32.0)),
+                float(rp.get("beta_slow", 1.0)),
+                bool(rp.get("truncate", True)),
+            )
+            scale = float(rp["attention_factor"])
+        elif rp.get("rope_type", "default") == "default":
+            inv = rope_frequencies(cfg.head_dim, theta)
+            scale = 1.0
+        else:
+            raise ValueError(f"unknown rope_type {rp['rope_type']!r}")
+        by_type[kind] = (inv, scale)
+    return {
+        "inv_freq": jnp.stack(
+            [jnp.asarray(by_type[t][0]) for t in cfg.layer_types]
+        ),
+        "rope_scale": jnp.asarray(
+            [by_type[t][1] for t in cfg.layer_types], jnp.float32
+        ),
+        "window": jnp.asarray(
+            [cfg.sliding_window if t == "sliding_attention" else 0
+             for t in cfg.layer_types], jnp.int32,
+        ),
+    }
+
+
+def _rope_of(inv_freq, table: dict | None):
+    """``(inv_freq, cos/sin factor, window)`` of one layer: its row of
+    ``_layer_tables``, or the model's one RoPE (``inv_freq``, computed
+    once outside the scan) and no window."""
+    if table is None:
+        return inv_freq, None, None
+    return table["inv_freq"], table["rope_scale"], table["window"]
+
+
+# ``moe`` counters live on the device as uint32 [site, counter, (lo, hi)]:
+# 64 bits a counter (a day of serving overflows 32), no int64 on the chip.
+MOE_SITES = ("decode", "prefill")
+
+
+def new_moe_stats() -> jnp.ndarray:
+    return jnp.zeros((len(MOE_SITES), len(MOE_STATS), 2), jnp.uint32)
+
+
+def read_moe_stats(stats) -> dict:
+    """The device counters as ``{site: {counter: int}}`` (a host read:
+    waits for the step that wrote them)."""
+    a = np.asarray(stats).astype(np.uint64)
+    return {
+        site: {
+            name: int(a[i, j, 0] + (a[i, j, 1] << np.uint64(32)))
+            for j, name in enumerate(MOE_STATS)
+        }
+        for i, site in enumerate(MOE_SITES)
+    }
+
+
+def _bump_moe_stats(stats, site: str, delta: jnp.ndarray) -> jnp.ndarray:
+    """``stats[site] += delta`` ([n_counters] int32, non-negative) with
+    the carry into the high word."""
+    stats = new_moe_stats() if stats is None else stats
+    i = MOE_SITES.index(site)
+    lo = stats[i, :, 0] + delta.astype(jnp.uint32)
+    hi = stats[i, :, 1] + (lo < stats[i, :, 0]).astype(jnp.uint32)
+    return stats.at[i].set(jnp.stack([lo, hi], axis=-1))
+
+
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _scanned_layers(cfg: ModelConfig, layers: dict) -> dict:
+    """What the layer scan slices per layer: every stacked leaf but the
+    expert weights, which the expert kernel indexes itself (``_ffn``)."""
+    if not cfg.n_experts:
+        return layers
+    return {k: v for k, v in layers.items() if k not in _EXPERT_WEIGHTS}
+
+
+def _ffn(params: dict, lp: dict, l_idx, x: jnp.ndarray, cfg: ModelConfig,
+         site: str, valid: jnp.ndarray | None = None, **kernel_kw):
+    """Layer ``l_idx``'s feed-forward on ``x`` [B, S, H]: ``_mlp``, or the
+    sparse expert layer for a config with experts (a static choice; it
+    takes the STACKED expert weights and the layer's index, because a
+    scan's per-layer slice of them handed to a kernel is a copy).
+    Returns ``(y, aux)``; ``aux`` is None for the dense layer, else
+    ``(expert ids [B, S, k], this launch's counter increments [4])``,
+    the counters over the tokens ``valid`` [B, S] marks."""
+    if not cfg.n_experts:
+        return _mlp(lp, x), None
+    B, S, H = x.shape
+    stacked = params["layers"]
+    y, ids, stats = moe_ffn(
+        x.reshape(B * S, H), lp["w_router"], stacked["w_gate"],
+        stacked["w_up"], stacked["w_down"], layer=l_idx,
+        k=cfg.n_experts_per_tok, norm_topk=cfg.norm_topk_prob,
+        site=site, valid=None if valid is None else valid.reshape(B * S),
+        **kernel_kw,
+    )
+    return y.reshape(B, S, H), (ids.reshape(B, S, -1), stats)
+
+
+def _moe_aux(cfg: ModelConfig, per_layer, moe_stats, site: str) -> tuple:
+    """The trailing ``aux`` of an entry point: ``()`` for a dense model,
+    else one dict — the scan's stacked expert ids [L, B, S, k] and the
+    counters with this launch added."""
+    if not cfg.n_experts:
+        return ()
+    ids, stats = per_layer
+    return ({
+        "expert_ids": ids,
+        "moe_stats": _bump_moe_stats(moe_stats, site, jnp.sum(stats, axis=0)),
+    },)
+
+
 def _attn_out(lp: dict, attn: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """The wo projection shared by every forward variant: attn is
     [B, S, Hq·D] (or already [B, S, Hq, D])."""
@@ -415,6 +675,8 @@ def prefill_forward(
     cached_k: jnp.ndarray,  # [L, B, P_max, Hkv, D] rotated prefix K, RIGHT-aligned
     cached_v: jnp.ndarray,  # [L, B, P_max, Hkv, D]
     prefix_lengths: jnp.ndarray,  # [B] valid cached-prefix tokens (≤ P_max)
+    new_lengths: jnp.ndarray | None = None,  # [B] real new tokens (counters)
+    moe_stats: jnp.ndarray | None = None,  # the device's moe counters
 ):
     """Prefill new tokens against an optional cached prefix.
 
@@ -427,9 +689,11 @@ def prefill_forward(
     caller writes new_k/new_v into the paged pool at the slots the radix
     tree allocated, which is how a served prompt becomes a reusable cached
     prefix (the contract the reference's commented-out scheduler hooks
-    sketch, ``radix_cache.py:439-519``).
+    sketch, ``radix_cache.py:439-519``). A config with experts returns
+    ``aux`` as a fourth value (module docstring); ``new_lengths`` keeps
+    the bucket's padding out of its counters.
     """
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    one_rope = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)
     p_max = cached_k.shape[2]
     s_new = tokens.shape[1]
@@ -438,25 +702,34 @@ def prefill_forward(
     # buffer [pad | prefix | new]: p + pad.
     attn_pos = positions + pad[:, None]
     kv_end = jnp.full_like(prefix_lengths, p_max + s_new)
+    valid = None
+    if new_lengths is not None:
+        valid = jnp.arange(s_new)[None, :] < new_lengths[:, None]
 
     def layer(x, xs):
-        lp, ck, cv = xs
+        l_idx, lp, ck, cv, table = xs
+        inv_freq, rope_scale, window = _rope_of(one_rope, table)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         q, k, v = _qkv(lp, h, cfg)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+        q = apply_rope(q, positions, inv_freq, rope_scale)
+        k = apply_rope(k, positions, inv_freq, rope_scale)
         k_ctx = jnp.concatenate([ck, k], axis=1)  # [B, P_max + S, Hkv, D]
         v_ctx = jnp.concatenate([cv, v], axis=1)
-        attn = attend_prefill(q, k_ctx, v_ctx, attn_pos, kv_end, kv_start=pad)
+        attn = attend_prefill(
+            q, k_ctx, v_ctx, attn_pos, kv_end, kv_start=pad, window=window
+        )
         x = x + _attn_out(lp, attn, cfg)
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(lp, h2)
-        return x, (k, v)
+        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid)
+        return x + y, (k, v, aux)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], cached_k, cached_v)
+    x, (new_k, new_v, aux) = jax.lax.scan(
+        layer, x,
+        (jnp.arange(cfg.n_layers), _scanned_layers(cfg, params["layers"]),
+         cached_k, cached_v, _layer_tables(cfg)),
     )
-    return _logits(params, cfg, x), new_k, new_v
+    return (_logits(params, cfg, x), new_k, new_v,
+            *_moe_aux(cfg, aux, moe_stats, "prefill"))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "axis"))
@@ -489,6 +762,7 @@ def prefill_forward_sp(
     """
     from radixmesh_tpu.parallel.ring_attention import ring_self_attention
 
+    refuse_unsupported(cfg, device_mesh=mesh)
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -539,6 +813,7 @@ def prefill_chunk_paged(
     use_kernel: bool | None = None,
     interpret: bool = False,
     logits_at: jnp.ndarray | None = None,  # [B] per-row chunk offsets, or None
+    moe_stats: jnp.ndarray | None = None,  # the device's moe counters
 ):
     """One CHUNK of long-context prefill against the paged pool (SURVEY §5:
     the 32k Qwen2 gate must never materialize O(S²) scores — VERDICT
@@ -557,9 +832,12 @@ def prefill_chunk_paged(
     chunk offset per row: only those positions hit the LM head and logits
     are ``[B, 1, V]`` (``prefill_forward_sp``'s contract) — a prefill wave
     samples one position per row, and the default 8 × 512 wave's
-    all-position f32 logits at a 152k vocabulary are 2.3 GiB.
+    all-position f32 logits at a 152k vocabulary are 2.3 GiB. A config
+    with experts returns ``aux`` as one more trailing value (module
+    docstring); its counters leave out the chunk's padding.
     """
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
+    one_rope = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)  # [B, C, H]
     num_slots = kv_pool.shape[3]
     pages_shape = (
@@ -578,13 +856,18 @@ def prefill_chunk_paged(
     # rows may carry clamped positions; their outputs are discarded and
     # the masking below stays finite either way.)
     prior_lengths = jnp.minimum(positions[:, 0], kv_lengths)
+    valid = positions < kv_lengths[:, None]  # real tokens, for the counters
+    moe_kw = {} if use_kernel is None else {
+        "use_kernel": use_kernel, "interpret": interpret,
+    }
 
     def layer(x, xs):
-        l_idx, lp = xs
+        l_idx, lp, table = xs
+        inv_freq, rope_scale, window = _rope_of(one_rope, table)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         q, k, v = _qkv(lp, h, cfg)  # [B,C,*,D]
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+        q = apply_rope(q, positions, inv_freq, rope_scale)
+        k = apply_rope(k, positions, inv_freq, rope_scale)
         if kv_scale is not None:
             # Quantize NOW and attend the dequantized copy, so the chunk
             # sees exactly what any later pool read will see (the fused
@@ -608,23 +891,25 @@ def prefill_chunk_paged(
             use_kernel=use_kernel,
             mesh=mesh,
             interpret=interpret,
+            window=window,
         )
         x = x + _attn_out(lp, attn, cfg)
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(lp, h2)
+        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, **moe_kw)
+        x = x + y
         if kv_scale is not None:
             return x, (k_int, v_int, k_sc, v_sc)
-        return x, (k.astype(kv_pool.dtype), v.astype(kv_pool.dtype))
+        return x, (k.astype(kv_pool.dtype), v.astype(kv_pool.dtype), aux)
 
     def pick(x):
         if logits_at is None:
             return x
         return jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
 
+    xs = (jnp.arange(cfg.n_layers), _scanned_layers(cfg, params["layers"]),
+          _layer_tables(cfg))
     if kv_scale is not None:
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            layer, x, (jnp.arange(cfg.n_layers), params["layers"])
-        )
+        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(layer, x, xs)
         # Already quantized in-layer (same ints attention saw); scatter the
         # int8 payloads + scales: scan stacks [L, B, C, Hkv(, D)] → the
         # pool target [:, :, :, slots[B, C]] expects [2, L, Hkv, B, C(, D)].
@@ -633,15 +918,14 @@ def prefill_chunk_paged(
         kv_pool = kv_pool.at[:, :, :, slots].set(new_kv)
         kv_scale = kv_scale.at[:, :, :, slots].set(new_s)
         return _logits(params, cfg, pick(x)), kv_pool, kv_scale
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (jnp.arange(cfg.n_layers), params["layers"])
-    )
+    x, (new_k, new_v, aux) = jax.lax.scan(layer, x, xs)
     # One scatter for the whole chunk across all layers: scan stacks
     # [L, B, C, Hkv, D]; the pool indexed at [:, :, :, slots[B,C]] expects
     # [2, L, Hkv, B, C, D].
     new_kv = jnp.stack([new_k, new_v]).transpose(0, 1, 4, 2, 3, 5)
     kv_pool = kv_pool.at[:, :, :, slots].set(new_kv)
-    return _logits(params, cfg, pick(x)), kv_pool
+    return (_logits(params, cfg, pick(x)), kv_pool,
+            *_moe_aux(cfg, aux, moe_stats, "prefill"))
 
 
 @partial(
@@ -661,6 +945,7 @@ def decode_step(
     page_size: int = 16,
     mesh=None,
     kv_scale: jnp.ndarray | None = None,  # [2, L, Hkv, num_slots] int8 pool
+    moe_stats: jnp.ndarray | None = None,  # the device's moe counters
 ):
     """One decode step for a continuous batch: writes this token's K/V into
     the paged pool inside the layer scan, attends over the radix-cache
@@ -670,10 +955,12 @@ def decode_step(
     the pages view is a pure reshape). ``mesh`` (static) enables the
     tensor-parallel kernel path: heads/pool sharded over the mesh's tp
     axis, the Pallas kernel shard_map'd per chip; all other ops partition
-    via GSPMD from the params/pool shardings."""
+    via GSPMD from the params/pool shardings. A config with experts
+    returns ``aux`` as a third value (module docstring); a row whose
+    ``lengths`` is 1 is an idle batch row and stays out of its counters."""
     return _decode_core(
         params, cfg, tokens, kv_pool, slots, page_table, lengths, page_size,
-        mesh, kv_scale,
+        mesh, kv_scale, moe_stats,
     )
 
 
@@ -709,6 +996,9 @@ def decode_multi(
     kv_pool)``; stop-token/length bookkeeping happens on host afterwards
     (surplus tokens past a stop are discarded — latency is bought with a
     little bubble compute)."""
+    # One step's counters and expert ids have nowhere to go in a fused
+    # loop, and a k-step launch was never run with a window.
+    refuse_unsupported(cfg, decode_steps_per_launch=2)
     B = tokens.shape[0]
     rows = jnp.arange(B)
 
@@ -834,9 +1124,12 @@ def _decode_core(
     page_size: int,
     mesh,
     kv_scale: jnp.ndarray | None = None,
+    moe_stats: jnp.ndarray | None = None,
 ):
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
+    one_rope = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
     positions = lengths - 1  # [B]
+    live = (lengths > 1)[:, None]  # idle rows ride with lengths == 1
     x = _embed_lookup(params, tokens)[:, None, :]  # [B, 1, H]
     B = tokens.shape[0]
     num_slots = kv_pool.shape[3]
@@ -851,11 +1144,12 @@ def _decode_core(
 
     def layer(carry, xs):
         x, kv_pool, kv_scale = carry
-        l_idx, lp = xs
+        l_idx, lp, table = xs
+        inv_freq, rope_scale, window = _rope_of(one_rope, table)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         q, k, v = _qkv(lp, h, cfg)  # [B,1,*,D]
-        q = apply_rope(q, positions[:, None], inv_freq)
-        k = apply_rope(k, positions[:, None], inv_freq)
+        q = apply_rope(q, positions[:, None], inv_freq, rope_scale)
+        k = apply_rope(k, positions[:, None], inv_freq, rope_scale)
         # Fused write+attend: the Pallas kernel writes this token's K/V row
         # into the (aliased) pool and attends over this layer's pages — the
         # pool buffer flows through the scan with zero copies. (A separate
@@ -890,6 +1184,7 @@ def _decode_core(
                 lengths,
                 l_idx,
                 mesh=mesh,
+                window=window,
             )
         kv_pool = kv_pages.reshape(2, cfg.n_layers, cfg.n_kv_heads, num_slots,
                                    cfg.head_dim)
@@ -899,16 +1194,18 @@ def _decode_core(
             reshape=(cfg.n_heads, cfg.head_dim, cfg.hidden),
         )[:, None, :]
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(lp, h2)
-        return (x, kv_pool, kv_scale), None
+        y, aux = _ffn(params, lp, l_idx, h2, cfg, "decode", live)
+        return (x + y, kv_pool, kv_scale), aux
 
-    (x, kv_pool, kv_scale), _ = jax.lax.scan(
-        layer, (x, kv_pool, kv_scale), (jnp.arange(cfg.n_layers), params["layers"])
+    (x, kv_pool, kv_scale), aux = jax.lax.scan(
+        layer, (x, kv_pool, kv_scale),
+        (jnp.arange(cfg.n_layers), _scanned_layers(cfg, params["layers"]),
+         _layer_tables(cfg)),
     )
     logits = _logits(params, cfg, x)[:, 0]
     if kv_scale is not None:
         return logits, kv_pool, kv_scale
-    return logits, kv_pool
+    return logits, kv_pool, *_moe_aux(cfg, aux, moe_stats, "decode")
 
 
 # ---------------------------------------------------------------------------
@@ -924,6 +1221,7 @@ def convert_hf_state_dict(cfg: ModelConfig, state: dict) -> dict:
     ``[out, in]``; we store ``[in, out]`` so every projection is applied as
     ``x @ W``).
     """
+    refuse_unsupported(cfg, hf_checkpoint=True)
     L = cfg.n_layers
 
     def get(name):

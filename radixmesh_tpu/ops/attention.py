@@ -27,6 +27,12 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
+def _in_window(distance, window):
+    """Is a key ``distance`` positions behind its query inside a window of
+    ``window`` keys (the query's own among them)? 0 means no window."""
+    return (window <= 0) | (distance < window)
+
+
 @partial(jax.jit, static_argnames=())
 def attend_prefill(
     q: jnp.ndarray,  # [B, S_new, Hq, D]
@@ -35,13 +41,15 @@ def attend_prefill(
     q_positions: jnp.ndarray,  # [B, S_new] index-space positions of q tokens
     kv_lengths: jnp.ndarray,  # [B] valid context end (index space)
     kv_start: jnp.ndarray | None = None,  # [B] valid context begin (ragged pad)
+    window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
 ) -> jnp.ndarray:
     """Causal attention where queries start mid-context (after a cached
     prefix): query at index-space position p attends to kv indices in
     ``[kv_start, min(p+1, kv_lengths))``. ``kv_start`` masks front padding
     when ragged cached prefixes are right-aligned into a fixed-size prefix
-    region (see ``models/llama.py::prefill_forward``). Returns
-    [B, S_new, Hq, D]."""
+    region (see ``models/llama.py::prefill_forward``). ``window`` (a
+    traced scalar; the query's own key counts) hides keys further back
+    than that on a window layer. Returns [B, S_new, Hq, D]."""
     B, S_new, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
@@ -64,6 +72,10 @@ def attend_prefill(
     valid = kv_pos < kv_lengths[:, None, None, None, None]
     if kv_start is not None:
         valid = valid & (kv_pos >= kv_start[:, None, None, None, None])
+    if window is not None:
+        valid = valid & _in_window(
+            q_positions[:, None, None, :, None] - kv_pos, window
+        )
     logits = jnp.where(causal & valid, logits, _NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum(
@@ -85,6 +97,7 @@ def attend_decode_ref(
     lengths: jnp.ndarray,  # [B] context length incl. current token
     k_scales: jnp.ndarray | None = None,  # [Hkv, P, page] int8-pool scales
     v_scales: jnp.ndarray | None = None,
+    window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
 ) -> jnp.ndarray:
     """Gather-based paged decode attention — the numerics oracle for the
     Pallas kernel and the CPU execution path. With ``*_scales`` the pages
@@ -114,7 +127,12 @@ def attend_decode_ref(
         )
         * scale
     )
-    valid = jnp.arange(max_ctx)[None, None, None, :] < lengths[:, None, None, None]
+    kv_pos = jnp.arange(max_ctx)[None, None, None, :]
+    valid = kv_pos < lengths[:, None, None, None]
+    if window is not None:  # the query sits at lengths - 1
+        valid = valid & _in_window(
+            lengths[:, None, None, None] - 1 - kv_pos, window
+        )
     logits = jnp.where(valid, logits, _NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum(
@@ -171,6 +189,7 @@ def _page_block_softmax(
     layer: jnp.ndarray | int,
     kv_block_pages: int,
     kv_scales: jnp.ndarray | None = None,  # [2, L, Hkv, P, page] int8 pools
+    window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
 ):
     """Shared core of the chunked-prefill attentions: scan fixed-size page
     blocks of one layer's pool context, maintaining the online softmax
@@ -220,6 +239,8 @@ def _page_block_softmax(
         )  # [B, Hkv, G, C, bk]
         kv_pos = (blk * bk + jnp.arange(bk))[None, None, None, None, :]
         ok = (kv_pos <= qpos) & (kv_pos < bound)
+        if window is not None:
+            ok = ok & _in_window(qpos - kv_pos, window)
         s = jnp.where(ok, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -256,6 +277,7 @@ def attend_chunk_hybrid(
     layer: jnp.ndarray | int,
     kv_block_pages: int = 32,
     kv_scales: jnp.ndarray | None = None,
+    window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
 ) -> jnp.ndarray:
     """Chunk attention with the current chunk's K/V taken DENSE from the
     layer activations instead of read back out of the pool: prior context
@@ -270,7 +292,7 @@ def attend_chunk_hybrid(
     Hkv = k_cur.shape[2]
     m, l, acc = _page_block_softmax(
         q, kv_pages, page_table, q_positions, prior_lengths, layer,
-        kv_block_pages, kv_scales,
+        kv_block_pages, kv_scales, window,
     )
     scale = 1.0 / jnp.sqrt(jnp.asarray(D, dtype=jnp.float32))
     qg = (q.astype(jnp.float32) * scale).reshape(
@@ -292,6 +314,8 @@ def attend_chunk_hybrid(
     ok2 = (kv_abs <= qpos) & (
         kv_abs < kv_lengths[:, None, None, None, None]
     )
+    if window is not None:
+        ok2 = ok2 & _in_window(qpos - kv_abs, window)
     s2 = jnp.where(ok2, s2, _NEG_INF)
     m_f = jnp.maximum(m, jnp.max(s2, axis=-1, keepdims=True))
     p2 = jnp.exp(s2 - m_f)
@@ -635,6 +659,7 @@ def paged_chunk_attention(
     mesh=None,
     interpret: bool = False,
     kv_scales: jnp.ndarray | None = None,
+    window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
 ) -> jnp.ndarray:
     """Chunk-prefill attention with the ``_dispatch`` choice, mirroring
     ``paged_decode_attention``: the Pallas chunk kernel or the jnp
@@ -646,8 +671,9 @@ def paged_chunk_attention(
     its head shard via shard_map."""
 
     def kernel(q, k_cur, v_cur, kv_pages, page_table, q_positions,
-               prior_lengths, kv_lengths, layer, kv_scales):
+               prior_lengths, kv_lengths, layer, kv_scales, window):
         if mesh is not None and mesh.shape.get("tp", 1) > 1:
+            _no_window_on_a_mesh(window)
             return paged_chunk_attention_kernel_sharded(
                 q, k_cur, v_cur, kv_pages, page_table, prior_lengths,
                 kv_lengths, layer, mesh, interpret=interpret,
@@ -658,21 +684,32 @@ def paged_chunk_attention(
         return paged_chunk_attention_kernel(
             q, k_cur, v_cur, kv_pages, page_table, prior_lengths,
             kv_lengths, layer, interpret=interpret, kv_scales=kv_scales,
+            window=window,
         )
 
     def reference(q, k_cur, v_cur, kv_pages, page_table, q_positions,
-                  prior_lengths, kv_lengths, layer, kv_scales):
+                  prior_lengths, kv_lengths, layer, kv_scales, window):
         return attend_chunk_hybrid(
             q, k_cur, v_cur, kv_pages, page_table, q_positions,
             prior_lengths, kv_lengths, layer, kv_block_pages=kv_block_pages,
-            kv_scales=kv_scales,
+            kv_scales=kv_scales, window=window,
         )
 
     return _dispatch(
         use_kernel, q.shape[-1], kernel, reference,
         q, k_cur, v_cur, kv_pages, page_table, q_positions, prior_lengths,
-        kv_lengths, jnp.asarray(layer, jnp.int32), kv_scales,
+        kv_lengths, jnp.asarray(layer, jnp.int32), kv_scales, window,
     )
+
+
+def _no_window_on_a_mesh(window) -> None:
+    """The tensor-parallel kernel wrappers know no window; ``Engine``
+    refuses a window model on a mesh at start-up, and this keeps any other
+    caller from getting full attention where it asked for a window."""
+    if window is not None:
+        raise NotImplementedError(
+            "window attention on a tensor-parallel mesh is not supported"
+        )
 
 
 def paged_decode_fused_sharded(
@@ -748,6 +785,7 @@ def paged_decode_attention(
     use_kernel: bool | None = None,
     mesh=None,
     kv_scales: jnp.ndarray | None = None,  # [2, L, Hkv, P, page] — updated too
+    window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
 ):
     """One decode step's KV write + paged attention, fused.
 
@@ -760,8 +798,9 @@ def paged_decode_attention(
     """
 
     def kernel(q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
-               kv_scales):
+               kv_scales, window):
         if mesh is not None and mesh.shape.get("tp", 1) > 1:
+            _no_window_on_a_mesh(window)
             return paged_decode_fused_sharded(
                 q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
                 mesh, kv_scales=kv_scales,
@@ -770,11 +809,11 @@ def paged_decode_attention(
 
         return paged_decode_fused_kernel(
             q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
-            kv_scales=kv_scales,
+            kv_scales=kv_scales, window=window,
         )
 
     def reference(q, k_new, v_new, kv_pages, slots, page_table, lengths,
-                  layer, kv_scales):
+                  layer, kv_scales, window):
         page = kv_pages.shape[4]
         pg, off = slots // page, slots % page
         # ``layer`` is an ARRAY index: the advanced indices (layer, pg,
@@ -792,17 +831,19 @@ def paged_decode_attention(
             attn = attend_decode_ref(
                 q, kv_pages[0, layer], kv_pages[1, layer], page_table,
                 lengths, kv_scales[0, layer], kv_scales[1, layer],
+                window=window,
             )
             return attn, kv_pages, kv_scales
         kv_pages = kv_pages.at[0, layer, :, pg, off].set(k_new)
         kv_pages = kv_pages.at[1, layer, :, pg, off].set(v_new)
         attn = attend_decode_ref(
-            q, kv_pages[0, layer], kv_pages[1, layer], page_table, lengths
+            q, kv_pages[0, layer], kv_pages[1, layer], page_table, lengths,
+            window=window,
         )
         return attn, kv_pages
 
     return _dispatch(
         use_kernel, q.shape[-1], kernel, reference,
         q, k_new, v_new, kv_pages, slots, page_table, lengths,
-        jnp.asarray(layer, jnp.int32), kv_scales,
+        jnp.asarray(layer, jnp.int32), kv_scales, window,
     )

@@ -1,0 +1,255 @@
+"""Sparse expert layer: route over all experts, sort the (token, expert)
+pairs by expert, one grouped matrix product per projection, weighted
+combine.
+
+    r = W_r x (float32)      p = softmax(r) over all E experts
+    S = the k largest of p   w_e = p_e / sum_S p   (``norm_topk``)
+    y = sum_{e in S} w_e * W_down,e (silu(W_gate,e x) * W_up,e x)
+
+No token is dropped and there is no capacity factor: every pair is
+computed. The pairs are laid out expert by expert, each expert's rows
+padded up to a whole tile of ``tm`` rows, so that a tile of the grouped
+product belongs to exactly one expert (``expert_layout``). The product is
+one Pallas kernel, named by its call site for the device trace
+(``radixmesh_moe_gmm_decode`` / ``radixmesh_moe_gmm_prefill``): grid over
+the row tiles that are in use, the expert's whole ``[K, N]`` weight as one
+block. Consecutive tiles of one expert keep the same block index, so the
+pipeline fetches an expert's weight once however many rows it was given,
+and an expert that no row chose is never fetched: a decode step streams
+the experts that were hit and nothing else.
+
+A ``jax.numpy`` path of the same layer serves every other lowering target
+(the CPU): a scan over the experts, each applied to all tokens and
+weighted by the routing weight, which is zero where the expert was not
+chosen.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["route", "expert_layout", "grouped_matmul", "moe_ffn", "STATS"]
+
+# What one expert-layer launch adds to the ``moe`` counters, in order.
+STATS = ("layer_launches", "assignments", "experts_hit", "max_load")
+
+_PREC = jax.lax.Precision.HIGHEST
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def route(x: jnp.ndarray, w_router: jnp.ndarray, k: int, norm_topk: bool):
+    """``x`` [T, H] -> (expert ids [T, k] int32, weights [T, k] float32).
+    All arithmetic in float32, softmax over ALL experts before the top-k
+    (the source's order), ties to the lower expert id (``lax.top_k``)."""
+    r = jnp.einsum(
+        "th,he->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=_PREC,
+    )
+    p = jax.nn.softmax(r, axis=-1)
+    top_p, top_e = jax.lax.top_k(p, k)
+    if norm_topk:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_e.astype(jnp.int32), top_p
+
+
+def tile_rows(n_tokens: int, k: int, n_experts: int) -> int:
+    """Rows per tile of the grouped product: about the mean load of an
+    expert, a power of two within [16, 256] (16 rows fill a bf16 sublane
+    tile; past 256 the padding of each expert outgrows the saving)."""
+    mean = max(1, -(-n_tokens * k // n_experts))
+    return min(256, max(16, 1 << (mean - 1).bit_length()))
+
+
+def expert_layout(top_e: jnp.ndarray, n_experts: int, tm: int) -> dict:
+    """The pairs of ``top_e`` [T, k] sorted by expert (a counting sort,
+    stable in pair order), each expert's run padded to a multiple of
+    ``tm``. Returns ``row_token`` [M_pad] (the token of each row; pad rows
+    name token 0 and are never gathered back), ``dest`` [T, k] (the row of
+    each pair), ``tile_group`` [M_pad // tm] (the expert of each tile)
+    and ``num_tiles`` (tiles in use)."""
+    T, k = top_e.shape
+    M = T * k
+    m_pad = -(-(M + n_experts * (tm - 1)) // tm) * tm
+    flat = top_e.reshape(M)
+    onehot = flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None, :]
+    ranks = jnp.cumsum(onehot.astype(jnp.int32), axis=0)  # inclusive
+    pos = jnp.take_along_axis(ranks, flat[:, None], axis=1)[:, 0] - 1
+    sizes = ranks[-1]
+    padded = (sizes + tm - 1) // tm * tm
+    ends = jnp.cumsum(padded)
+    dest = (ends - padded)[flat] + pos
+    row_token = jnp.zeros((m_pad,), jnp.int32).at[dest].set(
+        jnp.arange(M, dtype=jnp.int32) // k
+    )
+    tile_group = jnp.minimum(
+        jnp.searchsorted(
+            ends // tm, jnp.arange(m_pad // tm, dtype=jnp.int32), side="right"
+        ),
+        n_experts - 1,
+    ).astype(jnp.int32)
+    return {
+        "row_token": row_token,
+        "dest": dest.reshape(T, k),
+        "tile_group": tile_group,
+        "num_tiles": (ends[-1] // tm).astype(jnp.int32),
+    }
+
+
+def _gmm_kernel(tile_group_ref, layer_ref, lhs_ref, rhs_ref, out_ref, *,
+                precision):
+    del tile_group_ref, layer_ref  # consumed by the index maps
+    out_ref[...] = jax.lax.dot_general(
+        lhs_ref[...], rhs_ref[...],
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=precision,
+    )
+
+
+def grouped_matmul(
+    lhs: jnp.ndarray,  # [M_pad, K] rows sorted by expert, tile-aligned
+    rhs: jnp.ndarray,  # [L, E, K, N]: every layer's experts, stacked
+    layer: jnp.ndarray,  # () which layer's
+    tile_group: jnp.ndarray,  # [M_pad // tm] expert of each row tile
+    num_tiles: jnp.ndarray,  # () tiles in use
+    *,
+    tm: int,
+    site: str,  # "decode" | "prefill": the kernel's name in the trace
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``out[tile] = lhs[tile] @ rhs[layer, tile_group[tile]]`` for the
+    tiles in use, float32 out. Rows of tiles past ``num_tiles`` are not
+    written. The kernel takes the STACKED weights and the layer's index
+    (scalar prefetch, as the attention kernels take the whole pool): a
+    layer's slice handed to a custom call is a copy, 0.8 GB a layer at
+    the published widths — three times the step (PERF.md, PR 27)."""
+    m_pad, K = lhs.shape
+    N = rhs.shape[-1]
+    precision = _PREC if lhs.dtype == jnp.float32 else None
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, precision=precision),
+        out_shape=jax.ShapeDtypeStruct((m_pad, N), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(num_tiles,),
+            in_specs=[
+                pl.BlockSpec((tm, K), lambda i, tg, ly: (i, 0)),
+                pl.BlockSpec((None, None, K, N),
+                             lambda i, tg, ly: (ly[0], tg[i], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((tm, N), lambda i, tg, ly: (i, 0)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name=f"radixmesh_moe_gmm_{site}",
+    )(tile_group, jnp.asarray(layer, jnp.int32).reshape(1), lhs, rhs)
+
+
+def _experts_kernel(x, top_e, top_w, w_gate, w_up, w_down, layer, *, site,
+                    interpret):
+    T, k = top_e.shape
+    E = w_gate.shape[1]
+    tm = tile_rows(T, k, E)
+    with jax.named_scope("rm_moe_route"):
+        lay = expert_layout(top_e, E, tm)
+        rows = x[lay["row_token"]]  # [M_pad, H]
+    gmm = functools.partial(
+        grouped_matmul, layer=layer, tile_group=lay["tile_group"],
+        num_tiles=lay["num_tiles"], tm=tm, site=site, interpret=interpret,
+    )
+    gate = gmm(rows, w_gate)
+    up = gmm(rows, w_up)
+    h = (jax.nn.silu(gate) * up).astype(x.dtype)
+    out = gmm(h, w_down)  # [M_pad, H] float32
+    with jax.named_scope("rm_moe_combine"):
+        picked = out[lay["dest"]]  # [T, k, H]
+        return jnp.sum(picked * top_w[..., None], axis=1)
+
+
+def _experts_reference(x, top_e, top_w, w_gate, w_up, w_down, layer):
+    """The same layer without the sort: every expert applied to all
+    tokens, weighted by its routing weight (zero where it was not chosen),
+    one expert at a time."""
+    w_gate, w_up, w_down = (
+        jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+        for w in (w_gate, w_up, w_down)
+    )
+    E = w_gate.shape[0]
+    hit = top_e[..., None] == jnp.arange(E, dtype=jnp.int32)  # [T, k, E]
+    w_full = jnp.sum(jnp.where(hit, top_w[..., None], 0.0), axis=1)  # [T, E]
+
+    def one(acc, xs):
+        wg, wu, wd, w_e = xs
+        gate = jnp.einsum("th,hf->tf", x, wg, precision=_PREC,
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("th,hf->tf", x, wu, precision=_PREC,
+                        preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+        o = jnp.einsum("tf,fh->th", h, wd, precision=_PREC,
+                       preferred_element_type=jnp.float32)
+        return acc + w_e[:, None] * o, None
+
+    acc0 = jnp.zeros(x.shape, jnp.float32)
+    y, _ = jax.lax.scan(one, acc0, (w_gate, w_up, w_down, w_full.T))
+    return y
+
+
+def moe_ffn(
+    x: jnp.ndarray,  # [T, H]
+    w_router: jnp.ndarray,  # [H, E]
+    w_gate: jnp.ndarray,  # [E, H, F], or every layer's [L, E, H, F]
+    w_up: jnp.ndarray,  # [E, H, F]
+    w_down: jnp.ndarray,  # [E, F, H]
+    *,
+    layer: jnp.ndarray | int = 0,  # which layer of stacked expert weights
+    k: int,
+    norm_topk: bool,
+    site: str,
+    valid: jnp.ndarray | None = None,  # [T] rows that are real tokens
+    use_kernel: bool | None = None,
+    interpret: bool = False,
+):
+    """The expert layer on ``x``. Returns ``(y [T, H] in x's dtype, expert
+    ids [T, k], stats [4] int32)``; ``stats`` is what this launch adds to
+    the counters named in ``STATS``, over the rows ``valid`` marks (all,
+    if None). A layer scan passes the stacked expert weights and its
+    ``layer`` index, never a slice (``grouped_matmul``). ``use_kernel=None``
+    follows the lowering target: the Pallas kernel for a TPU, the
+    ``jax.numpy`` path for anything else."""
+    if w_gate.ndim == 3:
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+    layer = jnp.asarray(layer, jnp.int32)
+    E = w_router.shape[1]
+    with jax.named_scope("rm_moe_route"):
+        top_e, top_w = route(x, w_router, k, norm_topk)
+        ok = jnp.ones(x.shape[:1], bool) if valid is None else valid
+        hit = (top_e[..., None] == jnp.arange(E, dtype=jnp.int32)) & ok[
+            :, None, None
+        ]
+        load = jnp.sum(hit, axis=(0, 1)).astype(jnp.int32)  # [E]
+        stats = jnp.stack([
+            jnp.int32(1),
+            jnp.sum(ok).astype(jnp.int32) * k,
+            jnp.sum(load > 0).astype(jnp.int32),
+            jnp.max(load),
+        ])
+
+    def kernel(*args):
+        return _experts_kernel(*args, site=site, interpret=interpret)
+
+    args = (x, top_e, top_w, w_gate, w_up, w_down, layer)
+    if use_kernel is None:
+        y = jax.lax.platform_dependent(
+            *args, tpu=kernel, default=_experts_reference
+        )
+    else:
+        y = (kernel if use_kernel else _experts_reference)(*args)
+    return y.astype(x.dtype), top_e, stats

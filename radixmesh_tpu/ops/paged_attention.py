@@ -158,6 +158,25 @@ def _prep_scales(
     )
 
 
+def _window_bounds(starts_ref, b, batch_size: int, bk: int, hbm_len):
+    """``(first_block, visible)`` of a block loop. Without ``starts_ref``
+    (every layer that attends its whole context) the loop starts at block
+    0 and a position is visible below ``hbm_len``: the code the kernels
+    always had. With it (a window layer) row ``bb`` starts at the block
+    that holds ``starts_ref[bb]`` — pages wholly before the window are
+    never fetched — and a position is visible from ``starts_ref[b]`` on.
+    The chain prefetch asks ``first_block`` of the NEXT row too, so what
+    it fetches is what that row's loop consumes first."""
+    if starts_ref is None:
+        return (lambda bb: 0), (lambda pos: pos < hbm_len)
+    start = starts_ref[b]
+
+    def first_block(bb):
+        return starts_ref[jax.lax.clamp(0, bb, batch_size - 1)] // bk
+
+    return first_block, (lambda pos: (pos < hbm_len) & (pos >= start))
+
+
 class _GatedCopy:
     """A compute block's HBM→VMEM gather with two runtime-selected DMA
     plans: ``_run`` (one coalesced descriptor, taken when the ``contig``
@@ -276,6 +295,7 @@ def _run_block_loop(
     num_kv_heads: int,
     min_length: int,  # lengths_ref value below which a row has no HBM work
     prep_ref=None,  # VMEM (2, nblocks, bk) f32 prepared scales (int8 pools)
+    starts_ref=None,  # SMEM [B] first visible token per row (window layers)
 ):
     """Initialize the online-softmax scratch and contract ``hbm_len``
     tokens of HBM pages into it, chain-prefetching block DMAs across grid
@@ -288,6 +308,7 @@ def _run_block_loop(
     bk = page * pages_per_block
     nblocks = pages_per_seq // pages_per_block
     quantized = prep_ref is not None
+    first_block, visible = _window_bounds(starts_ref, b, batch_size, bk, hbm_len)
 
     def block_copies(bb, hh, ii, slot):
         off = bb * pages_per_seq + ii * pages_per_block
@@ -318,11 +339,12 @@ def _run_block_loop(
                 ),
                 b + 1,
             )
-            return (nb, 0, 0)
+            return (nb, 0, first_block(nb))
 
         def advance_h():
             return jax.lax.cond(
-                h + 1 < num_kv_heads, lambda: (b, h + 1, 0), advance_b
+                h + 1 < num_kv_heads, lambda: (b, h + 1, first_block(b)),
+                advance_b,
             )
 
         return jax.lax.cond(i * bk < hbm_len, lambda: (b, h, i), advance_h)
@@ -360,7 +382,7 @@ def _run_block_loop(
         if quantized:
             s = s * prep_ref[0, pl.ds(i, 1), :]  # (1, bk) k-scales
         pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < hbm_len, s, _MASK)
+        s = jnp.where(visible(pos), s, _MASK)
 
         m_prev = m_scr[...]
         m_blk = jnp.max(s, axis=-1, keepdims=True)  # [G, 1]
@@ -380,10 +402,9 @@ def _run_block_loop(
         # one ever holds NaN/Inf, 0·NaN = NaN would poison the
         # accumulator (ADVICE round-5 #1). Zero BOTH factors — p (pad
         # v-scale rows may be non-finite) and v (pad pool bytes may be).
-        p = jnp.where(pos < hbm_len, p, 0.0)
+        p = jnp.where(visible(pos), p, 0.0)
         v = jnp.where(
-            i * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-            < hbm_len,
+            visible(i * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)),
             v,
             0.0,
         )
@@ -395,7 +416,7 @@ def _run_block_loop(
         acc_scr[...] = acc_scr[...] * corr + pv
         return ()
 
-    jax.lax.fori_loop(0, pl.cdiv(hbm_len, bk), body, ())
+    jax.lax.fori_loop(first_block(b), pl.cdiv(hbm_len, bk), body, ())
 
 
 def _kernel(
@@ -474,6 +495,7 @@ def _mh_block_loop(
     num_kv_heads: int,
     min_length: int,  # lengths_ref value below which a row has no HBM work
     prep_ref=None,  # VMEM (2, Hkv, nblocks, bk) f32 prepared scales
+    starts_ref=None,  # SMEM [B] first visible token per row (window layers)
 ):
     """The heads-batched analog of ``_run_block_loop``: one program per
     SEQUENCE, ``(Hkv, G, ·)`` batched MXU contractions, chain-prefetched
@@ -490,6 +512,7 @@ def _mh_block_loop(
     nblocks = pages_per_seq // pages_per_block
     Hkv = num_kv_heads
     quantized = prep_ref is not None
+    first_block, visible = _window_bounds(starts_ref, b, batch_size, bk, hbm_len)
 
     def block_copies(bb, ii, slot):
         off = bb * pages_per_seq + ii * pages_per_block
@@ -521,7 +544,7 @@ def _mh_block_loop(
                 ),
                 b + 1,
             )
-            return (nb, 0)
+            return (nb, first_block(nb))
 
         return jax.lax.cond(i * bk < hbm_len, lambda: (b, i), advance_b)
 
@@ -560,7 +583,7 @@ def _mh_block_loop(
         if quantized:
             s = s * prep_ref[0, :, pl.ds(i, 1), :]  # (Hkv, 1, bk) k-scales
         pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < hbm_len, s, _MASK)
+        s = jnp.where(visible(pos), s, _MASK)
 
         m_prev = m_scr[...]
         m_blk = jnp.max(s, axis=-1, keepdims=True)  # (Hkv, G, 1)
@@ -577,10 +600,9 @@ def _mh_block_loop(
         # Exact zeros at masked positions (see _run_block_loop): pad
         # fetches may stage unreferenced pages; NaN/Inf there (or in pad
         # scale rows) must not ride 0·NaN into the accumulator.
-        p = jnp.where(pos < hbm_len, p, 0.0)
+        p = jnp.where(visible(pos), p, 0.0)
         v = jnp.where(
-            i * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
-            < hbm_len,
+            visible(i * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)),
             v,
             0.0,
         )
@@ -592,7 +614,7 @@ def _mh_block_loop(
         acc_scr[...] = acc_scr[...] * corr + pv
         return ()
 
-    jax.lax.fori_loop(0, pl.cdiv(hbm_len, bk), body, ())
+    jax.lax.fori_loop(first_block(b), pl.cdiv(hbm_len, bk), body, ())
 
 
 def _mh_kernel(
@@ -663,6 +685,7 @@ def _mh_fused_kernel(
     num_kv_heads: int,
     group: int,
     quantized: bool,
+    windowed: bool = False,
 ):
     """Heads-fused decode step: the ``_fused_kernel`` contract (write the
     current token's K/V row through the aliased pool output, fold it in
@@ -673,7 +696,11 @@ def _mh_fused_kernel(
     = int8 · scale, computed by the wrapper) for the VMEM fold-in — so
     attention sees bit-exactly what any later pool read will see, with
     zero in-kernel scale handling. The scale POOL is updated by the
-    wrapper with one XLA scatter."""
+    wrapper with one XLA scatter. ``windowed``: one more scalar-prefetch
+    array, the first visible token of each row (``_window_bounds``)."""
+    starts_ref = None
+    if windowed:
+        starts_ref, *refs = refs
     if quantized:
         (q_ref, k_new_ref, v_new_ref, k_deq_ref, v_deq_ref, kv_hbm, prep_ref,
          kv_out, o_ref,
@@ -734,7 +761,7 @@ def _mh_fused_kernel(
             page=page, pages_per_block=pages_per_block,
             pages_per_seq=pages_per_seq, batch_size=batch_size,
             num_kv_heads=num_kv_heads, min_length=2,
-            prep_ref=prep_ref,
+            prep_ref=prep_ref, starts_ref=starts_ref,
         )
         s_cur = jax.lax.dot_general(  # (Hkv, G, 1)
             q, k_cur,
@@ -771,6 +798,7 @@ def _fused_kernel(
     batch_size: int,
     num_kv_heads: int,
     quantized: bool,
+    windowed: bool = False,
 ):
     """Fused decode attention: write this token's K/V row into the pool
     (replacing the XLA scatter — the pool is aliased through the call, so
@@ -778,7 +806,10 @@ def _fused_kernel(
     the current one folded in from VMEM (see module docstring). Quantized
     pools follow the ``_mh_fused_kernel`` contract: int8 row written
     verbatim, dequantized twin folded in, scale pool scattered by the
-    wrapper."""
+    wrapper. ``windowed`` as in ``_mh_fused_kernel``."""
+    starts_ref = None
+    if windowed:
+        starts_ref, *refs = refs
     if quantized:
         (q_ref, k_new_ref, v_new_ref, k_deq_ref, v_deq_ref, kv_hbm, prep_ref,
          kv_out, o_ref,
@@ -843,7 +874,7 @@ def _fused_kernel(
             page=page, pages_per_block=pages_per_block,
             pages_per_seq=pages_per_seq, batch_size=batch_size,
             num_kv_heads=num_kv_heads, min_length=2,
-            prep_ref=prep_ref,
+            prep_ref=prep_ref, starts_ref=starts_ref,
         )
         # Fold in the current token from VMEM (one more online-softmax
         # step with a single-position block).
@@ -1088,10 +1119,20 @@ def _pool_kernel_mh(
     return out.reshape(B, Hq, D).astype(q.dtype)
 
 
+def _window_starts(lengths, window):
+    """First visible token of each row on a layer whose window holds
+    ``window`` keys, the query's own among them; 0 (everything) where
+    ``window`` is 0. ``lengths`` count the query's token."""
+    window = jnp.asarray(window, jnp.int32)
+    return jnp.where(window > 0, jnp.maximum(lengths - window, 0), 0).astype(
+        jnp.int32
+    )
+
+
 def _fused_decode_mh(
     q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
     pages_per_block: int | None = None, interpret: bool = False,
-    kv_scales=None,
+    kv_scales=None, window=None,
 ):
     """Heads-batched fused decode wrapper (see ``_mh_fused_kernel``)."""
     B, Hq, D = q.shape
@@ -1142,6 +1183,7 @@ def _fused_decode_mh(
         num_kv_heads=Hkv,
         group=G,
         quantized=quantized,
+        windowed=window is not None,
     )
     in_specs = [q_spec, kv_new_spec, kv_new_spec]
     if quantized:
@@ -1154,7 +1196,7 @@ def _fused_decode_mh(
                 lambda b, *_: (0, b, 0, 0, 0),
             )
         )
-    n_scalars = 7
+    n_scalars = 7 + (window is not None)
     # Flat arg index of kv_pages (aliased onto output 0): scalars + q +
     # k_new + v_new (+ k_deq + v_deq).
     kv_arg = n_scalars + (5 if quantized else 3)
@@ -1182,6 +1224,7 @@ def _fused_decode_mh(
         jnp.asarray(layer, dtype=jnp.int32).reshape(1),
         jnp.zeros((1,), jnp.int32),
         jnp.ones((1,), jnp.int32),
+        *([] if window is None else [_window_starts(lengths, window)]),
         q4,
         k_new.astype(kv_pages.dtype).reshape(B, Hkv, 1, D),
         v_new.astype(kv_pages.dtype).reshape(B, Hkv, 1, D),
@@ -1255,11 +1298,16 @@ def paged_decode_fused_kernel(
     interpret: bool = False,
     kv_scales: jnp.ndarray | None = None,  # [2, L, Hkv, P, page] int8 pool
     fuse_heads: bool | None = None,  # None → _auto_fuse_heads policy
+    window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
 ):
     """Fused decode step attention: returns ``(attn_out [B, Hq, D],
     kv_pages)`` — plus the updated ``kv_scales`` when quantized — where
     the pool buffers are the SAME memory updated in place (the caller
-    threads them as scan carries with zero copies)."""
+    threads them as scan carries with zero copies). ``window`` (a traced
+    scalar, so that one scanned layer body serves window and full layers)
+    limits each row to its last ``window`` tokens and starts the block
+    loop at the block that holds the first of them; ``None`` compiles the
+    kernel without a window."""
     B, Hq, D = q.shape
     _, _, Hkv, P, page, _ = kv_pages.shape
     if Hq % Hkv:
@@ -1275,7 +1323,7 @@ def paged_decode_fused_kernel(
         return _fused_decode_mh(
             q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
             pages_per_block=pages_per_block, interpret=interpret,
-            kv_scales=kv_scales,
+            kv_scales=kv_scales, window=window,
         )
     page_table, ppb, padded = _block_geometry(page_table, page, pages_per_block)
     lengths = jnp.asarray(lengths, dtype=jnp.int32)
@@ -1311,6 +1359,7 @@ def paged_decode_fused_kernel(
         batch_size=B,
         num_kv_heads=Hkv,
         quantized=quantized,
+        windowed=window is not None,
     )
     in_specs = [q_spec, kv_new_spec, kv_new_spec]
     if quantized:
@@ -1323,7 +1372,7 @@ def paged_decode_fused_kernel(
                 lambda b, h, *_: (0, b, h, 0, 0),
             )
         )
-    n_scalars = 7
+    n_scalars = 7 + (window is not None)
     kv_arg = n_scalars + (5 if quantized else 3)
 
     scratch = [
@@ -1352,6 +1401,7 @@ def paged_decode_fused_kernel(
         jnp.asarray(layer, dtype=jnp.int32).reshape(1),
         jnp.zeros((1,), jnp.int32),  # double-buffer slot
         jnp.ones((1,), jnp.int32),  # cold-start flag
+        *([] if window is None else [_window_starts(lengths, window)]),
         q4,
         k_new.astype(new_dtype).reshape(B, Hkv, 1, D),
         v_new.astype(new_dtype).reshape(B, Hkv, 1, D),
@@ -1403,6 +1453,7 @@ def _chunk_kernel(
     c_block: int,  # Cblk — queries per program
     group: int,  # G — q heads per kv head
     quantized: bool,
+    windowed: bool = False,
 ):
     """Chunk-prefill attention program for one ``(b, h, c-block)``: stream
     the row's PRIOR context from pool pages through the online softmax
@@ -1410,7 +1461,15 @@ def _chunk_kernel(
     in as one dense causal block from VMEM. Query positions are canonical
     (``prior + chunk offset`` — see the wrapper's contract), so masks
     derive from scalars: prior bound for the page part, intra-chunk
-    causality + ``kvlen`` bound for the dense part."""
+    causality + ``kvlen`` bound for the dense part. ``windowed``: one
+    more scalar-prefetch value, the keys a query of this layer sees (its
+    own among them; 0 = all). Keys further back are masked, and the page
+    loop starts at the block that holds the first key the program's
+    FIRST query still sees — earlier pages are not fetched."""
+    window = None
+    if windowed:
+        window_ref, *refs = refs
+        window = window_ref[0]
     if quantized:
         (q_ref, kc_ref, vc_ref, kv_hbm, prep_ref, o_ref,
          m_scr, l_scr, acc_scr, k_buf, v_buf, sems) = refs
@@ -1441,10 +1500,25 @@ def _chunk_kernel(
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
     n_blocks = pl.cdiv(prior, bk)
+    first = 0
+    if windowed:
+        # Query row r of this program sits at prior + cb*Cblk + r // G.
+        q_pos = (
+            prior + cb * c_block
+            + jax.lax.broadcasted_iota(jnp.int32, (q_rows, 1), 0) // group
+        )
+        first = jnp.where(
+            window > 0,
+            jnp.maximum(prior + cb * c_block - window + 1, 0) // bk,
+            0,
+        )
 
-    @pl.when(n_blocks > 0)
+        def in_window(kv_pos):
+            return (window <= 0) | (q_pos - kv_pos < window)
+
+    @pl.when(n_blocks > first)
     def _cold_start():
-        for c in block_copies(0, 0):
+        for c in block_copies(first, jax.lax.rem(first, 2) if windowed else 0):
             c.start()
 
     def body(i, _):
@@ -1468,7 +1542,10 @@ def _chunk_kernel(
         kv_pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # Canonical query positions sit at/after ``prior``, so the page
         # part needs only the prior bound (strictly causal already).
-        s = jnp.where(kv_pos < prior, s, _MASK)
+        seen = kv_pos < prior
+        if windowed:
+            seen = seen & in_window(kv_pos)
+        s = jnp.where(seen, s, _MASK)
 
         m_prev = m_scr[...]
         m_blk = jnp.max(s, axis=-1, keepdims=True)
@@ -1485,7 +1562,7 @@ def _chunk_kernel(
         # Exact zeros at masked positions (see _run_block_loop): pad
         # fetches may stage unreferenced pages; NaN/Inf there (or in pad
         # scale rows) must not ride 0·NaN into the accumulator.
-        p = jnp.where(kv_pos < prior, p, 0.0)
+        p = jnp.where(seen, p, 0.0)
         v = jnp.where(
             i * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
             < prior,
@@ -1500,7 +1577,7 @@ def _chunk_kernel(
         acc_scr[...] = acc_scr[...] * corr + pv
         return ()
 
-    jax.lax.fori_loop(0, n_blocks, body, ())
+    jax.lax.fori_loop(first, n_blocks, body, ())
 
     # Dense block: the chunk itself, causal in chunk coordinates. Key
     # c_k's absolute position is prior + c_k; query row r (= c*G + g of
@@ -1518,6 +1595,8 @@ def _chunk_kernel(
     )
     c_k = jax.lax.broadcasted_iota(jnp.int32, s2.shape, 1)
     ok = (c_k <= c_q) & (prior + c_k < kvlen)
+    if windowed:
+        ok = ok & ((window <= 0) | (c_q - c_k < window))
     s2 = jnp.where(ok, s2, _MASK)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s2, axis=-1, keepdims=True))
@@ -1560,6 +1639,7 @@ def paged_chunk_attention_kernel(
     q_block: int | None = None,
     interpret: bool = False,
     kv_scales: jnp.ndarray | None = None,  # [2, L, Hkv, P, page] int8 pool
+    window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
 ) -> jnp.ndarray:
     """Pallas chunk-prefill attention: SURVEY §7 hard part (a) for the
     PREFILL side (VERDICT round-3 next-step #3 "pool-page chunk
@@ -1615,6 +1695,7 @@ def paged_chunk_attention_kernel(
         c_block=cblk,
         group=G,
         quantized=quantized,
+        windowed=window is not None,
     )
     in_specs = [q_spec, kc_spec, kc_spec, pl.BlockSpec(memory_space=pl.ANY)]
     if quantized:
@@ -1634,7 +1715,7 @@ def paged_chunk_attention_kernel(
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=5 + (window is not None),
         grid=(B, Hkv, C // cblk),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -1646,6 +1727,8 @@ def paged_chunk_attention_kernel(
         jnp.asarray(page_table, dtype=jnp.int32).reshape(-1),
         contig,
         jnp.asarray(layer, dtype=jnp.int32).reshape(1),
+        *([] if window is None
+          else [jnp.asarray(window, dtype=jnp.int32).reshape(1)]),
         q5,
         kc,
         vc,

@@ -39,6 +39,7 @@ be layered client-side.
 
 from __future__ import annotations
 
+import base64
 import json
 import os
 import threading
@@ -46,8 +47,11 @@ import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Sequence
 
+import numpy as np
+
 from radixmesh_tpu.engine.engine import Engine
 from radixmesh_tpu.engine.request import Request, RequestState, SamplingParams
+from radixmesh_tpu.models.llama import read_moe_stats
 from radixmesh_tpu.obs.aggregator import FleetAggregator, HttpPeer
 from radixmesh_tpu.obs.attribution import ensure_attributor
 from radixmesh_tpu.obs.blackbox import BlackBox
@@ -343,6 +347,35 @@ def _ids_from_body(body: dict, tokenizer, who: str) -> list[int]:
 class _FrontendServer(ThreadingHTTPServer):
     daemon_threads = True
     allow_reuse_address = True
+
+
+def _b64_array(a: np.ndarray) -> dict:
+    a = np.ascontiguousarray(a)
+    return {"dtype": str(a.dtype), "shape": list(a.shape),
+            "b64": base64.b64encode(a.tobytes()).decode("ascii")}
+
+
+def _logits_payload(req: Request) -> dict:
+    """What ``"return_logits": true`` adds to a reply's final event: for
+    every generated position the float32 logits row the sampler saw
+    (``logits``: [n_generated, V], little-endian, base64), and for a model
+    with experts the ids every computed position chose (``expert_ids``:
+    runs of ``{"start": first position, ...[n, L, k] int32}``; a prefix
+    served from the tree has none)."""
+    if not req.sampling.return_logits:
+        return {}
+    rows = (
+        np.stack(req.logit_rows)
+        if req.logit_rows
+        else np.zeros((0, 0), np.float32)
+    )
+    out = {"logits": _b64_array(rows.astype("<f4"))}
+    if req.expert_rows:
+        out["expert_ids"] = [
+            {"start": start, **_b64_array(ids.astype("<i4"))}
+            for start, ids in req.expert_rows
+        ]
+    return out
 
 
 def _request_row(req: Request, now: float) -> dict:
@@ -706,6 +739,9 @@ class ServingFrontend:
                     # (rm.tree.match) and in publish (rm.tree.publish).
                     "tree_host": eng.tree_host,
                 },
+                # Window layers: context tokens decode launches had to
+                # read / could skip (summed over rows x sliding layers).
+                "attn": eng.attn_window,
                 # The hardware every number on this node came from, with
                 # live per-device memory counters (obs/step_plane.py).
                 "device": describe_devices(),
@@ -715,6 +751,10 @@ class ServingFrontend:
                 # link fleet-tail buckets back to stitched traces.
                 "exemplars": get_registry().exemplars(),
             }
+            if eng._moe_stats is not None:
+                # The expert layer's counters, by call site: kept on the
+                # device, fetched here and nowhere else.
+                state["moe"] = read_moe_stats(eng._moe_stats)
             host = getattr(tree, "host", None)
             if host is not None:
                 state["host_tier"] = {
@@ -1069,6 +1109,7 @@ class ServingFrontend:
                         max_new_tokens=int(body.get("max_tokens", 16)),
                         stop_token_ids=stop_ids,
                         seed=None if seed is None else int(seed),
+                        return_logits=bool(body.get("return_logits", False)),
                     )
                     # Resume admission (crash recovery): output a prior
                     # life already delivered — replayed through prefill
@@ -1234,6 +1275,7 @@ class ServingFrontend:
                             if req.shed
                             else {}
                         ),
+                        **_logits_payload(req),
                     },
                 )
 
@@ -1272,6 +1314,7 @@ class ServingFrontend:
                                 done_evt["cancelled"] = True
                             if req.error is not None:
                                 done_evt["error"] = req.error
+                            done_evt.update(_logits_payload(req))
                             self.wfile.write(
                                 f"data: {json.dumps(done_evt)}\n\n".encode()
                             )

@@ -278,13 +278,19 @@ class TestMeshUnderChaos:
                 import queue as _q
 
                 full = nodes[0]._out_q
-                # Fill to capacity, then one more send must drop+tag.
-                while True:
-                    try:
-                        full.put_nowait(b"pad")
-                    except _q.Full:
+                # Fill to capacity, then one more send must drop+tag. The
+                # node's sender thread drains the queue meanwhile: if it
+                # took an item between the fill and the send, the frame
+                # found room and nothing was lost — fill again.
+                for _ in range(100):
+                    while True:
+                        try:
+                            full.put_nowait(b"pad")
+                        except _q.Full:
+                            break
+                    nodes[0]._send_bytes(frame)
+                    if losses:
                         break
-                nodes[0]._send_bytes(frame)
                 assert losses == [("queue_full", int(OplogType.INSERT))]
                 from radixmesh_tpu.obs.metrics import get_registry
 

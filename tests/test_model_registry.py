@@ -24,6 +24,29 @@ _SIZES = {
     "qwen2-72b": (71, 74),
     "qwen2.5-14b": (14, 15.5),
     "qwen2.5-32b": (31, 34),
+    "mellum2-12b-a2.5b": (12.03, 12.27),  # 12.15 B +- 1%
+    "mellum2-12b-a2.5b-l12": (5.4, 5.55),  # 12 layers + embedding and head
+}
+
+# The model's public config.json (JetBrains/Mellum2-12B-A2.5B-Instruct), as
+# the catalog of the model-configs guide holds it: every key that says
+# something about the shape.
+_MELLUM2_SOURCE = {
+    "attention_bias": False, "head_dim": 128, "hidden_act": "silu",
+    "hidden_size": 2304, "intermediate_size": 7168,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"],  # x 7
+    "max_position_embeddings": 131072, "moe_intermediate_size": 896,
+    "norm_topk_prob": True, "num_attention_heads": 32, "num_experts": 64,
+    "num_experts_per_tok": 8, "num_hidden_layers": 28,
+    "num_key_value_heads": 4, "rms_norm_eps": 1e-06,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 8192, "beta_fast": 32,
+            "beta_slow": 1, "attention_factor": 1.2772588722239782},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 500000},
+    },
+    "sliding_window": 1024, "tie_word_embeddings": False, "vocab_size": 98304,
 }
 
 
@@ -59,6 +82,54 @@ def test_preset_param_count(preset):
     assert lo * 1e9 < n < hi * 1e9, f"{preset}: {n/1e9:.2f}B params"
     if cfg.tie_embeddings:
         assert "lm_head" not in abstract
+
+
+def test_mellum2_presets_are_the_source_key_by_key():
+    """The 28-layer preset against the source's config, key by key; the
+    12-layer cut and the tiny preset differ from it only where they say;
+    the benchmark's configuration file states the same numbers."""
+    import json
+    import os
+
+    src = _MELLUM2_SOURCE
+    cfg = get_config("mellum2-12b-a2.5b")
+    assert cfg.n_layers == src["num_hidden_layers"]
+    assert list(cfg.layer_types) == src["layer_types"] * 7
+    assert (cfg.hidden, cfg.intermediate) == (src["hidden_size"], src["intermediate_size"])
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (
+        src["num_attention_heads"], src["num_key_value_heads"], src["head_dim"])
+    assert (cfg.n_experts, cfg.n_experts_per_tok, cfg.moe_intermediate) == (
+        src["num_experts"], src["num_experts_per_tok"], src["moe_intermediate_size"])
+    assert cfg.norm_topk_prob is src["norm_topk_prob"]
+    assert cfg.sliding_window == src["sliding_window"]
+    assert cfg.vocab_size == src["vocab_size"]
+    assert cfg.rms_eps == src["rms_norm_eps"]
+    assert cfg.qkv_bias is src["attention_bias"]
+    assert cfg.tie_embeddings is src["tie_word_embeddings"]
+    assert {k: dict(v) for k, v in cfg.rope_by_type} == src["rope_parameters"]
+    assert cfg.max_seq_len == 32768  # served; the source declares 131072
+    cut = get_config("mellum2-12b-a2.5b-l12")
+    assert cut == cfg.replace(n_layers=12, layer_types=cfg.layer_types[:12])
+    tiny = get_config("mellum2-tiny")
+    assert tiny.layer_types == cfg.layer_types[:4] and tiny.n_experts == 8
+    assert (tiny.n_experts_per_tok, tiny.sliding_window, tiny.vocab_size) == (2, 16, 512)
+    # active parameters a token: 8 of 64 experts in each of 28 layers
+    abstract = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
+    total = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(abstract))
+    one_expert = 3 * cfg.hidden * cfg.moe_intermediate
+    active = total - cfg.n_layers * (cfg.n_experts - cfg.n_experts_per_tok) * one_expert
+    assert 2.2e9 < active < 2.5e9, active
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "mellum2-12b-a2.5b-bf16-l12.json")
+    with open(path) as fh:
+        bench = json.load(fh)
+    for key, want in src.items():
+        if key in bench["reduced"]:
+            continue
+        assert bench[key] == want, key
+    assert bench["num_hidden_layers"] == 12 and bench["layer_types"] == src["layer_types"] * 3
+    assert sorted(bench["reduced"]) == [
+        "layer_types", "max_position_embeddings", "mlp_layer_types", "num_hidden_layers"]
 
 
 def test_unknown_preset_lists_known():

@@ -108,3 +108,48 @@ def test_decode_step_and_prefill_wave_fit_one_v5e(v5e):
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     ) / gib
     assert live < 15.75, f"prefill wave needs {live:.2f} GiB"
+
+
+def test_mellum2_period_compiles_for_one_v5e(v5e):
+    """One period of Mellum2-12B-A2.5B (sliding x3 + full) at published
+    widths, bf16: Mosaic accepts the grouped expert product (64 experts of
+    2304 x 896 as one block each) and the fused decode and chunk kernels
+    WITH a window start (32 q / 4 kv heads, G = 8), under both call-site
+    names; the pool is aliased through; the step returns the expert ids
+    and the counters."""
+    from radixmesh_tpu.models.mellum2 import mellum2_12b_a2_5b
+
+    sd = _sd(v5e)
+    cfg = mellum2_12b_a2_5b(4)
+    params = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda k: _init_params(k, cfg, None, None),
+            jax.ShapeDtypeStruct((2,), jnp.uint32),
+        ),
+    )
+    pool = sd((2, cfg.n_layers, cfg.n_kv_heads, 65536, cfg.head_dim), cfg.dtype)
+    rows = 32
+    compiled = decode_step.lower(
+        params, cfg, sd((rows,), jnp.int32), pool, sd((rows,), jnp.int32),
+        sd((rows, 1024), jnp.int32), sd((rows,), jnp.int32), PAGE,
+    ).compile()
+    text = compiled.as_text()
+    assert "radixmesh_moe_gmm_decode" in text
+    assert "radixmesh_decode_fused_mh" in text
+    logits, _, aux = compiled.out_info
+    assert logits.shape == (rows, cfg.vocab_size)
+    assert aux["expert_ids"].shape == (4, rows, 1, 8)
+    assert aux["moe_stats"].shape == (2, 4, 2)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * 4 * 4 * 65536 * 128 * 2  # the pool
+    assert mem.temp_size_in_bytes / 2.0**30 < 0.3
+
+    compiled = prefill_chunk_paged.lower(
+        params, cfg, sd((B, CHUNK), jnp.int32), sd((B, CHUNK), jnp.int32),
+        pool, sd((B, CHUNK), jnp.int32), sd((B, 512), jnp.int32),
+        sd((B,), jnp.int32), page_size=PAGE, logits_at=sd((B,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "radixmesh_moe_gmm_prefill" in text and "radixmesh_chunk_attn" in text
+    assert compiled.out_info[0].shape == (B, 1, cfg.vocab_size)
