@@ -103,6 +103,13 @@ _KV_BLOCK_PAGES = 32
 # bucket follows the last.
 _ADMISSION_WAIT_LE_MS = (1, 2, 5, 10, 20, 50, 100, 200, 500, 1000, 2000, 5000)
 _ADMISSION_WAIT_LE_S = tuple(ms / 1e3 for ms in _ADMISSION_WAIT_LE_MS)
+# Where a flush of the deferred emission ran (keys of ``Engine.emit``):
+# behind a dispatched decode launch, behind a dispatched prefill, or with
+# no launch to hide under (end of a step that leaves no row, cancel,
+# preemption).
+_UNDER_DECODE = "flushes_under_decode"
+_UNDER_PREFILL = "flushes_under_prefill"
+_EXPOSED = "flushes_exposed"
 
 
 class _TreeSpan:
@@ -357,6 +364,20 @@ class Engine:
             "matches": 0, "match_s": 0.0, "publishes": 0, "publish_s": 0.0,
         }
         self.sampler = {"argmax_launches": 0, "drawing_launches": 0}
+        # emit: decode tokens handed to their observers (_flush_emit) and
+        # the flushes by what the device was running beneath them.
+        self.emit = {
+            "tokens": 0,
+            _UNDER_DECODE: 0,
+            _UNDER_PREFILL: 0,
+            _EXPOSED: 0,
+        }
+        # Queued for the next flush. Per token, by _advance_token:
+        # (request, step stamp, counted as output). Per decode launch:
+        # (start, seconds, latency samples of seconds / samples, the step
+        # plane's note_wave arguments, [(trace, decode_chunk attributes)]).
+        self._emit_rows: list[tuple[Request, float, bool]] = []
+        self._emit_steps: list[tuple] = []
         # moe: the expert layer's counters, accumulated ON THE DEVICE by
         # every step (models/llama.py) and read only when /debug/state is
         # asked; None for a dense model. attn_window: context tokens that
@@ -673,7 +694,7 @@ class Engine:
         # ITL ring + stall attribution, the per-class speculation ledger
         # (and its adaptive-γ controller, off unless spec_adaptive), and
         # the per-tenant goodput decomposition. The timeline/goodput pair
-        # keeps the one-branch-when-off contract in _consume_token; the
+        # keeps the one-branch-when-off contract in _flush_emit; the
         # ledger always exists — spec counting must stay conserved
         # whether or not anyone is watching.
         from radixmesh_tpu.obs.token_timeline import (
@@ -859,6 +880,7 @@ class Engine:
         for req in self._rows:
             if req is not None and req.rid == rid:
                 req.cancelled = True
+                self._flush_emit(_EXPOSED, only=req)
                 req.state = RequestState.FINISHED
                 self.stats.finished += 1
                 self._release(req)
@@ -917,6 +939,7 @@ class Engine:
         parked, mid-prefill (shutdown sweep; with ``error``, the sweep
         after a step raised: each request carries the message to its
         client). Returns the number cancelled."""
+        self._flush_emit(_EXPOSED)  # no step may follow (shutdown, a failed one)
         reqs = (
             list(self.waiting)
             + [r for r in self._rows if r is not None]
@@ -1042,20 +1065,26 @@ class Engine:
         fused launch — long prompts advance between decode steps instead
         of convoying them.
 
+        A decode step's tokens are in ``output_tokens`` when the step
+        returns, but their stream wake-ups, timeline entries and output
+        counter (:meth:`_flush_emit`) run in the NEXT step, once its
+        launch is on the device — or at the end of this one when it
+        leaves no row to decode. First tokens and finishes wake their
+        waiters at once.
+
         Host spans (``obs.tracing.annotate``; inert without a profiler
         capture): ``rm.engine.step`` ⊃ ``rm.admit`` ⊃ {``rm.tree.match``,
         ``rm.prefill.dense`` / ``rm.prefill.group``,
         ``rm.first_token.sync``}, then ``rm.decode.build`` / ``.dispatch``
-        / ``.sample`` / ``.sync`` / ``.consume`` (⊃ ``rm.tree.publish``)
-        — the same names in every decode launch path, so a reader of a
-        capture never needs to know which ran."""
+        / ``.sample`` / ``.emit`` / ``.sync`` / ``.consume`` (⊃
+        ``rm.tree.publish``) — the same names in every decode launch
+        path, so a reader of a capture never needs to know which ran."""
         with annotate("rm.engine.step"):
             with annotate("rm.admit"):
                 self._admit()
             if self._inline:
                 self._wave_step()
-                return
-            if any(r is not None for r in self._rows):
+            elif any(r is not None for r in self._rows):
                 self._decode_once()
             elif not self.waiting and (
                 self._restoring
@@ -1069,6 +1098,9 @@ class Engine:
                 # prefetch hint's) — yield to the plane worker instead of
                 # busy-spinning the scheduler loop against it.
                 self.kv_transfer.wait_progress()
+            if not any(r is not None for r in self._rows):
+                # No decode launch will follow to hide the emission under.
+                self._flush_emit(_EXPOSED)
 
     def has_work(self) -> bool:
         return (
@@ -1467,6 +1499,9 @@ class Engine:
                     with annotate("rm.prefill.group"):
                         pending = self._prefill_group(sub)
                 self._last_prefill_path = path
+                # The prefill is on the device and its sync comes next:
+                # the last decode step's emission goes here.
+                self._flush_emit(_UNDER_PREFILL)
                 with annotate("rm.first_token.sync"):
                     self._finalize_first_tokens(pending)
                 if traced or acct is not None:
@@ -1852,7 +1887,7 @@ class Engine:
                 self.goodput.note_token(req.tenant)
             self._record_first_token(req)
             # Wake streamers parked on the request condition: this is
-            # THE first-token site, and the next _consume_token notify
+            # THE first-token site, and the next decode token's notify
             # may be a whole decode wave away.
             req.note_progress()
 
@@ -2412,6 +2447,10 @@ class Engine:
                 )
                 logits = self._commit_pool_update(res)
         asked = [(row, r) for row, r in active if r.sampling.return_logits]
+        if asked or seeded:
+            # Both read logits back before the sampler is launched: the
+            # last step's emission goes now, behind the step itself.
+            self._flush_emit(_UNDER_DECODE)
         if asked:  # the logits hook: one copy of the launch's logits
             logits_h = np.asarray(logits, dtype=np.float32)
             for row, req in asked:
@@ -2435,31 +2474,30 @@ class Engine:
                     )
                 else:
                     sampled_d = sample_tokens(logits, None, draws=False)
+            self._flush_emit(_UNDER_DECODE)
             with annotate("rm.decode.sync"):  # the wait for the device
                 sampled = np.asarray(sampled_d)
         self.stats.decode_steps += 1
         # sample_tokens materialized on host above, so this spans the full
         # dispatch+device time of the step — the per-token latency (TPOT)
         # seen by every active request.
-        elapsed = time.monotonic() - step_t0
-        self._note_decode_time(elapsed)
-        if self.step_acct is not None:
-            self.step_acct.note_wave(
-                "decode", len(active), self.max_batch, elapsed,
-                rows=len(active),
-            )
-        for _, req in active:
-            tr = req.trace
-            if tr is not None:
-                tr.add(
-                    "decode_chunk", step_t0, elapsed, cat="decode",
-                    k_steps=1, batch_rows=len(active),
-                )
-
+        stamp = time.monotonic()
+        elapsed = stamp - step_t0
+        self._emit_steps.append((
+            step_t0,
+            elapsed,
+            1,
+            ("decode", len(active), self.max_batch, elapsed, len(active)),
+            [
+                (r.trace, {"k_steps": 1, "batch_rows": len(active)})
+                for _, r in active
+                if r.trace is not None
+            ],
+        ))
         with annotate("rm.decode.consume"):
             for row, req in active:
-                self._consume_token(
-                    req, row, int(slots[row]), int(sampled[row])
+                self._advance_token(
+                    req, row, int(slots[row]), int(sampled[row]), stamp
                 )
 
     def _multi_step_k(self, k: int) -> int:
@@ -2631,25 +2669,26 @@ class Engine:
                     draws=draws,
                 )
             sampled = self._commit_pool_update(res)
+        self._flush_emit(_UNDER_DECODE)
         with annotate("rm.decode.sync"):
             sampled = np.asarray(sampled)  # [k, B] — the ONE round trip
         self.stats.decode_steps += k
-        elapsed = time.monotonic() - step_t0
-        for _ in range(k):
-            self._note_decode_time(elapsed / k)
-        if self.step_acct is not None:
-            self.step_acct.note_wave(
+        stamp = time.monotonic()
+        elapsed = stamp - step_t0
+        self._emit_steps.append((
+            step_t0,
+            elapsed,
+            k,
+            (
                 "decode", k * len(active), k * self.max_batch, elapsed,
-                rows=len(active),
-            )
-        for _, req in active:
-            tr = req.trace
-            if tr is not None:
-                tr.add(
-                    "decode_chunk", step_t0, elapsed, cat="decode",
-                    k_steps=k, batch_rows=len(active),
-                )
-
+                len(active),
+            ),
+            [
+                (r.trace, {"k_steps": k, "batch_rows": len(active)})
+                for _, r in active
+                if r.trace is not None
+            ],
+        ))
         ps = self.page_size
         with annotate("rm.decode.consume"):
             for row, req in active:
@@ -2659,8 +2698,8 @@ class Engine:
                     slot = int(
                         self._page_table[row, pos // ps] * ps + pos % ps
                     )
-                    if self._consume_token(
-                        req, row, slot, int(sampled[i, row])
+                    if self._advance_token(
+                        req, row, slot, int(sampled[i, row]), stamp
                     ):
                         break  # finished mid-launch: surplus discarded
 
@@ -2958,12 +2997,16 @@ class Engine:
                     jnp.asarray(self._top_ks),
                     draws=draws,
                 )
+        # A wave without decode rows is a prefill launch to hide under.
+        self._flush_emit(_UNDER_DECODE if active else _UNDER_PREFILL)
+        if active:
             with annotate("rm.decode.sync"):
                 accept_len = np.asarray(accept_len)  # [B] one sync
                 bonus = np.asarray(bonus)
             self.stats.decode_steps += 1
 
         emitted_total = 0
+        stamp = time.monotonic()
         with annotate("rm.decode.consume"):
             for row, req in active:
                 draft = drafts[row]
@@ -2998,7 +3041,7 @@ class Engine:
                     slot = int(self._page_table[row, pos // ps] * ps + pos % ps)
                     token = int(draft[i]) if i < a else int(bonus[row])
                     emitted_total += 1
-                    if self._consume_token(req, row, slot, token):
+                    if self._advance_token(req, row, slot, token, stamp):
                         break
         inline_tok = 0
         pending: list[tuple] = []
@@ -3034,32 +3077,41 @@ class Engine:
             self._last_inline_prefill_t = time.monotonic()
             self._inline = [j for j in self._inline if j.pos < j.total]
         if pending:
+            # The finalizer waits for the device with nothing queued on
+            # it: this wave's tokens go first.
+            self._flush_emit(_EXPOSED)
             with annotate("rm.first_token.sync"):
                 self._finalize_first_tokens(pending)
         elapsed = time.monotonic() - step_t0
-        if active:
-            for _ in range(max(emitted_total, 1)):
-                self._note_decode_time(elapsed / max(emitted_total, 1))
-        if self.step_acct is not None:
-            # The launch processes B·C positions; the USEFUL work is the
-            # accepted+bonus decode tokens actually emitted plus the
-            # inline prefill tokens advanced.
-            self.step_acct.note_wave(
+        # The launch processes B·C positions; the USEFUL work is the
+        # accepted+bonus decode tokens actually emitted plus the inline
+        # prefill tokens advanced.
+        self._emit_steps.append((
+            step_t0,
+            elapsed,
+            max(emitted_total, 1) if active else 0,
+            (
                 "decode" if active else "prefill",
                 emitted_total + inline_tok,
                 B * C,
                 elapsed,
-                rows=len(active) + len(jobs),
-            )
-        for row, req in active:
-            tr = req.trace
-            if tr is not None:
-                tr.add(
-                    "decode_chunk", step_t0, elapsed, cat="decode",
-                    k_steps=1, batch_rows=len(active), speculative=True,
-                    draft_tokens=int(draft_len[row]),
-                    accepted_tokens=int(accept_len[row]),
+                len(active) + len(jobs),
+            ),
+            [
+                (
+                    r.trace,
+                    {
+                        "k_steps": 1,
+                        "batch_rows": len(active),
+                        "speculative": True,
+                        "draft_tokens": int(draft_len[row]),
+                        "accepted_tokens": int(accept_len[row]),
+                    },
                 )
+                for row, r in active
+                if r.trace is not None
+            ],
+        ))
 
     def hint_stall(self, cause: str) -> None:
         """One-shot stall-cause latch for external planes: the next
@@ -3083,10 +3135,12 @@ class Engine:
             return hint
         if self._restoring:
             return "restore_park"
-        if now - self._last_prefill_t <= gap_s:
+        # ``now`` is the step's stamp, and the flush may run behind a
+        # LATER prefill's dispatch: a launch after ``now`` is not in the gap.
+        if 0.0 <= now - self._last_prefill_t <= gap_s:
             # A prefill wave launched inside the gap: the decode convoy.
             return "prefill_convoy"
-        if now - self._last_inline_prefill_t <= gap_s:
+        if 0.0 <= now - self._last_inline_prefill_t <= gap_s:
             # An inline prefill chunk (mixed compute wave) launched
             # inside the gap: budget-bounded by design, so it is NOT a
             # convoy — before this branch existed, a gap spanning a
@@ -3098,12 +3152,12 @@ class Engine:
             return "spec_verify_miss"
         return "scheduler_wait"
 
-    def _note_token_time(self, req: Request) -> None:
-        """Per-emitted-token timeline/goodput accounting. The FIRST
-        token of a request only stamps the clock (its latency is TTFT,
-        not ITL); every later token records its inter-token gap, with
+    def _note_token_time(self, req: Request, now: float) -> None:
+        """Per-emitted-token timeline/goodput accounting, ``now`` being
+        the stamp of the step that made the token. The FIRST token of a
+        request only stamps the clock (its latency is TTFT, not ITL);
+        every later token records its inter-token gap, with
         over-threshold gaps attributed to a stall cause."""
-        now = time.monotonic()
         prev = req.last_token_time
         req.last_token_time = now
         self.goodput.note_token(req.tenant)
@@ -3119,28 +3173,35 @@ class Engine:
             trace_id=getattr(req.trace, "trace_id", None), now=now,
         )
 
-    def _consume_token(self, req: Request, row: int, slot: int, token: int) -> bool:
-        """Account one decode iteration for ``req``: the fed token's KV
-        landed at ``slot``, ``token`` was sampled. Returns True when the
+    def _advance_token(
+        self, req: Request, row: int, slot: int, token: int, stamp: float
+    ) -> bool:
+        """The half of one decode iteration that the next launch, or a
+        finish, needs: the fed token's KV landed at ``slot``, ``token``
+        was sampled in the step stamped ``stamp``. Returns True when the
         request finished (stop token / length cap) and was released —
-        shared by single-step and fused multi-step decode so the subtle
-        stop/stats bookkeeping cannot drift between them."""
+        shared by every decode path so the subtle stop/stats bookkeeping
+        cannot drift between them. What only observers need (the output
+        counter, the token timeline, the stream wake-up) is queued for
+        :meth:`_flush_emit`; a finish wakes its waiters at once, through
+        ``Request.__setattr__``."""
         req.token_slots = np.append(req.token_slots, slot)
         req.kv_len += 1
         req.output_tokens.append(token)
         self.stats.generated_tokens += 1
-        if self.timeline is not None:  # one branch when off (PR 2 contract)
-            self._note_token_time(req)
-        if req.is_finished_by(token) or req.num_tokens >= self.max_seq_len:
-            # Don't count the terminal token as output if it's a stop.
-            if token in req.sampling.stop_token_ids:
+        finished = (
+            req.is_finished_by(token) or req.num_tokens >= self.max_seq_len
+        )
+        # Don't count the terminal token as output if it's a stop.
+        stop = finished and token in req.sampling.stop_token_ids
+        self._emit_rows.append((req, stamp, not stop))
+        if finished:
+            if stop:
                 req.output_tokens.pop()
                 self.stats.generated_tokens -= 1
-            else:
-                self._m_generated.inc()
             if req.submit_time:
                 self._m_req_e2e.labels(tenant=req.tenant).observe(
-                    time.monotonic() - req.submit_time,
+                    stamp - req.submit_time,
                     trace_id=getattr(req.trace, "trace_id", None),
                 )
             req.state = RequestState.FINISHED
@@ -3148,7 +3209,6 @@ class Engine:
             self._release(req)
             self._pressure = False  # freed memory: resume admission
             return True
-        self._m_generated.inc()
         self._tokens[row] = token
         if (
             self.stream_publish_tokens > 0
@@ -3162,11 +3222,56 @@ class Engine:
             # call _preempt makes; idempotent for already-published
             # prefixes.
             self._publish(req, req.kv_len)
-        # Streaming waiters block on the request condition instead of
-        # polling (server/http_frontend.py) — wake them per token so
-        # first-token latency isn't quantized by a poll interval.
-        req.note_progress()
         return False
+
+    def _flush_emit(self, under: str, only: Request | None = None) -> None:
+        """The deferred half of the decode steps since the last flush:
+        each token's output counter, timeline entry and stream wake-up
+        (``Request.note_progress``), and each launch's latency samples,
+        step-plane wave and trace spans. None of it is read by a launch,
+        so the decode paths call this once the NEXT launch is on the
+        device and before they wait for it — the handler threads then
+        take the GIL while the chip is busy. A token is never held across
+        a wait: a step that prefills flushes behind that prefill's
+        dispatch, a step that leaves no row flushes at its end, and
+        preemption / cancel flush the request concerned (``only``; the
+        launch records stay queued). ``under`` names the counter of
+        ``self.emit`` the flush is counted in."""
+        if only is None:
+            rows, self._emit_rows = self._emit_rows, []
+            steps, self._emit_steps = self._emit_steps, []
+        else:
+            rows = [e for e in self._emit_rows if e[0] is only]
+            steps = []
+            if rows:
+                self._emit_rows = [
+                    e for e in self._emit_rows if e[0] is not only
+                ]
+        if not rows and not steps:
+            return
+        with annotate("rm.decode.emit"):
+            for step_t0, elapsed, samples, wave, chunks in steps:
+                for _ in range(samples):
+                    self._note_decode_time(elapsed / samples)
+                if self.step_acct is not None:
+                    self.step_acct.note_wave(*wave)
+                for tr, attrs in chunks:
+                    tr.add(
+                        "decode_chunk", step_t0, elapsed, cat="decode",
+                        **attrs,
+                    )
+            timeline_on = self.timeline is not None  # one branch when off
+            emitted = 0
+            for req, stamp, counted in rows:
+                if counted:
+                    self._m_generated.inc()
+                    emitted += 1
+                if timeline_on:
+                    self._note_token_time(req, stamp)
+                if req.state is RequestState.RUNNING:
+                    req.note_progress()
+            self.emit["tokens"] += emitted
+            self.emit[under] += 1
 
     def _preempt(self, req: Request) -> None:
         """Pool exhausted mid-decode even after eviction: publish what we
@@ -3175,6 +3280,8 @@ class Engine:
         self.stats.preemptions += 1
         self._m_preempt.inc()
         self._pressure = True
+        # Its queued tokens belong to the life that ends here.
+        self._flush_emit(_EXPOSED, only=req)
         req.requeue_time = time.monotonic()
         tr = req.trace
         if tr is not None:

@@ -150,16 +150,22 @@ class Request:
     # Per-request progress wake (server/http_frontend.py): waiters block
     # on this instead of polling, so streamed first-token latency is not
     # quantized by a poll interval and idle waiters don't spin. Notified
-    # by note_progress() on each appended token and — via __setattr__ —
-    # on ANY transition to FINISHED, so no finish site can strand a
-    # waiter.
+    # by note_progress() — at once for the first token; for a decode
+    # token once the engine's next launch is on the device
+    # (Engine._flush_emit: at most one launch's build + dispatch after the
+    # token is in output_tokens, never across a device wait or an idle
+    # runner) — and, via __setattr__, at once on ANY transition to
+    # FINISHED, so no finish site can strand a waiter.
     cond: threading.Condition = field(
         default_factory=threading.Condition, repr=False, compare=False
     )
 
     def note_progress(self) -> None:
         """Wake every thread blocked on this request (new token landed /
-        state advanced). Cheap: one uncontended lock round-trip."""
+        state advanced). Cheap: one uncontended lock round-trip. A waiter
+        reads ``output_tokens``, which may already hold tokens newer than
+        the one this call announces: decode tokens are appended when their
+        step ends and announced from ``Engine._flush_emit``."""
         with self.cond:
             self.cond.notify_all()
 

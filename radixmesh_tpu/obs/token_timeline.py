@@ -3,7 +3,8 @@
 Every prior plane stops above the token: PR 12's waterfall is
 per-request phases, PR 9's step accounting is per-launch. This module
 is the layer below — three ledgers the engine feeds from its one
-per-emitted-token funnel (``Engine._consume_token``):
+per-emitted-token funnel (``Engine._flush_emit``, the deferred half of
+a decode step; each token carries its step's own stamp):
 
 - :class:`TokenTimeline` — a bounded, change-compressed ring of
   inter-token latencies (ITL). One append per emitted token, drop-oldest

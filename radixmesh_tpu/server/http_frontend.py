@@ -725,6 +725,11 @@ class ServingFrontend:
                     # Sampler launches by the executable they ran: the
                     # argmax alone, or the one that sorts and draws.
                     "sampler": eng.sampler,
+                    # Decode tokens handed to their observers (stream
+                    # wake-up, timeline, output counter) and where each
+                    # flush ran: behind a dispatched decode launch, behind
+                    # a prefill, or with nothing on the device to hide it.
+                    "emit": eng.emit,
                 },
                 "pool": {
                     "num_slots": eng.pool.num_slots,
@@ -1329,7 +1334,9 @@ class ServingFrontend:
                             )
                         return
                     # Park until the next token lands (or the request
-                    # finishes) — the engine notifies per consumed token,
+                    # finishes) — the engine notifies per token (a decode
+                    # token's wake-up comes once the next launch is on
+                    # the device, Engine._flush_emit; never after a wait),
                     # so first-token latency is not quantized by a poll
                     # interval. The 0.5 s fallback re-check covers a
                     # notify racing the length read above.

@@ -202,7 +202,7 @@ class TestMultiStepDecode:
         assert got == ref[:want_len]
 
     def test_row_stops_mid_launch_while_others_continue(self, model):
-        # The risky interaction in the fused path: _consume_token releases
+        # The risky interaction in the fused path: _advance_token releases
         # row A mid-launch (page table reset, row reassignable) while the
         # host loop keeps consuming the SAME launch's sampled tokens for
         # rows B..N — their output must be unaffected by A's release.
@@ -490,3 +490,90 @@ class TestSamplerLaunchRule:
             [base * 3], SamplingParams(temperature=0.7, max_new_tokens=8)
         )
         assert eng.sampler["drawing_launches"] > 0
+
+
+class TestDeferredEmission:
+    """A decode step's emission (output counter, token timeline, stream
+    wake-up) runs behind the NEXT launch (``Engine._flush_emit``); the
+    advance half stays in the step. At quiescence nothing observable
+    differs from counting every token where it was made — on every decode
+    path, with a stop token, a length cap, a preemption and a cancel that
+    each meet a non-empty queue."""
+
+    PATHS = {
+        "single": {},
+        "multi": {"decode_steps_per_launch": 3},
+        "spec": {"spec_decode_tokens": 2},
+    }
+
+    @pytest.mark.parametrize("path", list(PATHS))
+    def test_nothing_observable_changes_at_quiescence(self, model, path):
+        cfg, params = model
+        rng = prompts_rng()
+        base = rng.integers(1, cfg.vocab_size, 5).tolist()
+        prompts = [
+            base * 3,  # repeats its tail: the speculative path has drafts
+            rng.integers(1, cfg.vocab_size, 11).tolist(),
+            rng.integers(1, cfg.vocab_size, 9).tolist(),
+            rng.integers(1, cfg.vocab_size, 13).tolist(),
+        ]
+        want = [oracle_generate(cfg, params, p, 12) for p in prompts]
+        # a stop token the stopped row makes late, and for the first time
+        cut = next(i for i in range(5, 12) if want[1][i] not in want[1][:i])
+        stop = want[1][cut]
+        eng = make_engine(model, **self.PATHS[path])
+        advanced = []  # (rid, token was a stop) per _advance_token call
+        orig = eng._advance_token
+
+        def spy(req, row, slot, token, stamp):
+            advanced.append((req.rid, token in req.sampling.stop_token_ids))
+            return orig(req, row, slot, token, stamp)
+
+        eng._advance_token = spy
+        a = eng.add_request(prompts[0], SamplingParams(max_new_tokens=9))
+        b = eng.add_request(prompts[1], SamplingParams(
+            max_new_tokens=12, stop_token_ids=(stop,)))
+        c = eng.add_request(prompts[2], SamplingParams(max_new_tokens=12))
+        d = eng.add_request(prompts[3], SamplingParams(max_new_tokens=12))
+        while min(len(r.output_tokens) for r in (a, b, c, d)) < 3:
+            eng.step()
+        # Mid-flight the last launch's tokens are queued, not yet emitted,
+        # yet already in output_tokens.
+        queued = [e[0].rid for e in eng._emit_rows]
+        assert c.rid in queued and d.rid in queued
+        assert eng.emit["tokens"] < eng.stats.generated_tokens
+        d_partial = list(d.output_tokens)
+        assert eng.cancel(d.rid)
+        eng._preempt(c)
+        assert not {c.rid, d.rid} & {e[0].rid for e in eng._emit_rows}
+        assert eng.emit["flushes_exposed"] == 2
+        while eng.has_work():
+            eng.step()
+
+        assert not eng._emit_rows and not eng._emit_steps
+        assert all(r.state is RequestState.FINISHED for r in (a, b, c, d))
+        assert a.output_tokens == want[0][:9]
+        assert b.output_tokens == want[1][:cut]
+        assert c.output_tokens == want[2] and not c.cancelled
+        assert d.cancelled and d.output_tokens == d_partial
+        assert d_partial == want[3][: len(d_partial)]
+        n_stop = sum(s for _, s in advanced)
+        assert n_stop == 1
+        assert eng.stats.finished == 4 and eng.stats.preemptions == 1
+        assert eng.stats.generated_tokens == len(advanced) - n_stop
+        assert eng._m_generated.value == eng.stats.generated_tokens
+        assert eng.emit["tokens"] == eng.stats.generated_tokens
+        # One timeline entry per decode token (the stop token's gap
+        # included), one useful token per first token and decode token.
+        assert eng.timeline.appends == len(advanced)
+        useful = sum(
+            t["useful_tokens"] for t in eng.goodput.report()["tenants"].values()
+        )
+        assert useful == len(advanced) + eng.stats.prefills
+        assert eng.stats.prefills == 5  # c's second life prefilled again
+        if path == "spec":
+            assert eng.stats.spec_proposed > 0
+        if path == "multi":
+            assert eng.emit["flushes_under_decode"] < eng.stats.decode_steps
+        # Every launch's latency samples arrived too: one per decode step.
+        assert eng._m_tpot.count >= eng.stats.decode_steps > 0

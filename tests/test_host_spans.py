@@ -7,8 +7,8 @@ every span name, nested as ``Engine.step`` documents them, on the engine
 thread's line; ``rm.http.emit`` on the stream handlers' lines; nothing of
 the python tracer unless ``?python=1``. Without a capture the same
 requests leave no trace file. ``/debug/state`` counts every admission's
-queue wait once, the tree's host seconds, and the sampler's launches by
-the executable they ran."""
+queue wait once, the tree's host seconds, the sampler's launches by
+the executable they ran, and every decode token's deferred emission."""
 
 import glob
 import json
@@ -39,6 +39,7 @@ ENGINE_SPANS = {
     "rm.decode.build": "rm.engine.step",
     "rm.decode.dispatch": "rm.engine.step",
     "rm.decode.sample": "rm.engine.step",
+    "rm.decode.emit": "rm.engine.step",  # the step BEFORE's tokens, behind this launch
     "rm.decode.sync": "rm.engine.step",
     "rm.decode.consume": "rm.engine.step",
     "rm.tree.publish": None,  # under rm.decode.consume at finish, a prefill at install
@@ -153,6 +154,13 @@ def test_no_capture_leaves_no_trace_and_counts_every_admission(served):
     steps = b["engine"]["decode_steps"] - a["engine"]["decode_steps"]
     assert steps > 0
     assert sb["argmax_launches"] - sa["argmax_launches"] >= steps + 2
+    # engine.emit: every decode token emitted once (5 a request: the
+    # first token is woken at its own site), all queues empty between
+    # rounds, and most flushes behind a decode launch.
+    ea, eb = a["engine"]["emit"], b["engine"]["emit"]
+    assert eb == eng.emit and eb["tokens"] - ea["tokens"] == 5 * n
+    assert not eng._emit_rows and not eng._emit_steps
+    assert eb["flushes_under_decode"] - ea["flushes_under_decode"] >= steps - 4
 
 
 def test_capture_holds_every_span_nested_as_documented(served):
@@ -174,13 +182,18 @@ def test_capture_holds_every_span_nested_as_documented(served):
     publishes = [s for s in spans if s[0] == "rm.tree.publish"]
     assert any(inside(s, "rm.decode.consume") for s in publishes)
     assert all(inside(s, "rm.engine.step") for s in publishes)
-    # a decode step's five phases follow each other inside one step
-    step = next(p for p in spans if p[0] == "rm.engine.step" and any(
-        s[0] == "rm.decode.dispatch" and p[1] <= s[1] and s[2] <= p[2] for s in spans))
+    # a decode step's six phases follow each other inside one step: the
+    # emission of the step before runs between this launch's last dispatch
+    # and the wait for it (a request's first decode step has none to emit)
+    def holds(p, name):
+        return any(s[0] == name and p[1] <= s[1] and s[2] <= p[2] for s in spans)
+
+    step = next(p for p in spans if p[0] == "rm.engine.step"
+                and holds(p, "rm.decode.dispatch") and holds(p, "rm.decode.emit"))
     phases = sorted((s for s in spans if s[0].startswith("rm.decode.")
                      and step[1] <= s[1] and s[2] <= step[2]), key=lambda s: s[1])
     assert [s[0].rsplit(".", 1)[1] for s in phases] == [
-        "build", "sample", "dispatch", "sync", "consume"]  # the fused CPU launch
+        "build", "sample", "dispatch", "emit", "sync", "consume"]  # the fused CPU launch
     assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
     # the stream handler's spans are on another line
     emit_lines = [evs for evs in lines if any(n == "rm.http.emit" for n, _, _ in evs)]

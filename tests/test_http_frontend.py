@@ -474,6 +474,107 @@ class TestConcurrentClients:
             f.close(drain_s=0.5)
 
 
+class TestDeferredEmissionStream:
+    """A decode token's wake-up runs behind the NEXT launch
+    (``Engine._flush_emit``) and is never held across a wait: neither a
+    prefill's sync, nor an engine that has no further step to run, nor the
+    0.5 s fallback of ``Handler._stream``."""
+
+    def test_tokens_arrive_without_the_fallback(self):
+        import threading
+        import time as _time
+
+        cfg = ModelConfig.tiny()
+        eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                     num_slots=1024, page_size=4, max_batch=2, name="http-emit")
+        f = ServingFrontend(eng, port=0)
+        base = f"http://127.0.0.1:{f.port}"
+        stall_s, limit_s = 0.8, 0.3
+        stamps: list[float] = []  # the step stamp of each decode token of A
+        slow = threading.Event()
+        advance, finalize = eng._advance_token, eng._finalize_first_tokens
+
+        def spy(req, row, slot, token, stamp):
+            if req.prompt[0] == 1:
+                stamps.append(stamp)
+            return advance(req, row, slot, token, stamp)
+
+        def slow_finalize(pending):
+            # A long prefill: its sync comes AFTER the flush site in _admit.
+            if slow.is_set():
+                _time.sleep(stall_s)
+            return finalize(pending)
+
+        eng._advance_token = spy
+        eng._finalize_first_tokens = slow_finalize
+
+        def round_(n_a):
+            """Stream A; once four of its tokens are here, post B, whose
+            prefill then runs between two of A's decode steps."""
+            arrivals, out = [], {}
+            req = urllib.request.Request(
+                base + "/generate",
+                data=json.dumps({"input_ids": list(range(1, 17)), "max_tokens": n_a,
+                                 "stream": True, "stop_token_ids": []}).encode(),
+                method="POST",
+            )
+            b = threading.Thread(target=lambda: out.update(_post(
+                base + "/generate",
+                {"input_ids": list(range(300, 312)), "max_tokens": 2,
+                 "stop_token_ids": []})[1]))
+            with urllib.request.urlopen(req, timeout=60) as r:
+                for line in r:
+                    line = line.decode().strip()
+                    if not line.startswith("data: "):
+                        continue
+                    evt = json.loads(line[len("data: "):])
+                    arrivals.append(_time.monotonic())
+                    if evt.get("done"):
+                        out["a"] = evt["output_ids"]
+                        break
+                    if len(arrivals) == 4:
+                        b.start()
+            b.join(timeout=60)
+            assert not b.is_alive()
+            return arrivals, out
+
+        def state():
+            with f.runner._lock:  # /debug/state takes no lock, a step does
+                return json.loads(_get(base + "/debug/state")[1])["engine"]
+
+        try:
+            round_(200)  # compiles every shape of the round
+            stamps.clear()
+            before = state()
+            slow.set()
+            arrivals, out = round_(200)
+            after = state()
+        finally:
+            f.close()
+        n = len(out["a"])
+        assert n == 200 and len(arrivals) == n + 1 and len(stamps) == n - 1
+        # No token of A waited out B's prefill, nor a fallback: each arrived
+        # within the limit of the step that made it (the first token is
+        # woken at its own site; decode token i carries stamps[i - 1]) ...
+        late = [t - s for t, s in zip(arrivals[1:n], stamps)]
+        assert max(late) < limit_s, sorted(late)[-3:]
+        # ... though that prefill really stood between two of them.
+        gaps = [b - a for a, b in zip(arrivals, arrivals[1:])]
+        assert max(gaps) > 0.9 * stall_s
+        # The last tokens and the done event of a request whose engine then
+        # has nothing left to run: at once, through the finish.
+        assert arrivals[n] - stamps[-1] < limit_s
+        # engine.emit counts every decode token once and names where each
+        # flush ran: behind decode launches, behind B's prefill, and at the
+        # end of the steps that left no row.
+        d = {k: after["emit"][k] - before["emit"][k] for k in after["emit"]}
+        assert d["tokens"] == (n - 1) + (len(out["output_ids"]) - 1)
+        assert d["flushes_under_prefill"] == 1
+        assert d["flushes_exposed"] >= 1
+        assert d["flushes_under_decode"] >= n - 4
+        assert not eng._emit_rows and not eng._emit_steps
+
+
 class TestSLOFrontend:
     """The overload control plane surfaced over HTTP: tenant field,
     429 + Retry-After on rate limit, /stats slo section."""

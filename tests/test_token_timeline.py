@@ -243,6 +243,34 @@ class TestEngineTokenPlane:
         assert snap["appends"] == 7
         assert eng.goodput.report()["useful_tokens"] == 8
 
+    def test_gaps_measure_the_step_not_the_deferral(self, model):
+        # A decode token is stamped when its step ends and entered in the
+        # timeline behind the NEXT launch: a pause between two steps is
+        # the gap of the token made AFTER it, at that token's own stamp.
+        eng = make_engine(model, token_stall_threshold_s=0.1)
+        rng = prompts_rng()
+        sp = SamplingParams(max_new_tokens=6)
+        eng.generate([rng.integers(1, 64, 8).tolist()], sp)  # compiles
+        started = time.monotonic()
+        base = eng.timeline.appends
+        stalls = sum(eng.timeline.stall_counts.values())
+        req = eng.add_request(rng.integers(1, 64, 8).tolist(), sp)
+        while len(req.output_tokens) < 3:
+            eng.step()
+        assert eng.timeline.appends == base + 1 and eng._emit_rows  # one queued
+        paused = time.monotonic()
+        time.sleep(0.25)
+        while eng.has_work():
+            eng.step()
+        snap = eng.timeline.snapshot(limit=16)
+        assert snap["appends"] == base + 5
+        assert sum(snap["stalls"].values()) == stalls + 1
+        gaps = [(e["t"], e["itl_s"]) for e in snap["recent"] if e["t"] > started]
+        (t, stall), = [g for g in gaps if g[1] >= 0.1]
+        assert 0.25 <= stall < 1.0 and t > paused + 0.25
+        # The token queued during the pause kept its own short gap.
+        assert sum(1 for t, _ in gaps if t < paused) >= 1
+
     def test_timeline_off_is_none(self, model):
         eng = make_engine(model, token_timeline_capacity=0)
         assert eng.timeline is None
