@@ -2495,7 +2495,7 @@ CONVOY_SCHEMA_VERSION = 1
 
 CONVOY_TOP_FIELDS = (
     "schema_version", "metric", "value", "unit", "workload",
-    "interleave", "stalls", "starvation", "crossover", "wall_s",
+    "interleave", "stalls", "starvation", "wall_s",
 )
 CONVOY_INTERLEAVE_FIELDS = (
     "performed", "reps", "inline_budget", "base_ttft_p50_s",
@@ -2512,10 +2512,6 @@ CONVOY_STARVATION_FIELDS = (
     "performed", "skew", "max_defer_bound", "max_step_gap",
     "max_defer_observed", "boost_waves", "bounded", "carrier_tokens",
 )
-CONVOY_CROSSOVER_FIELDS = (
-    "performed", "paged_min_batch", "sweep", "small_batch_ok",
-    "large_batch_ok",
-)
 # The ISSUE's acceptance bars: mixed waves must better the convoy'd
 # TTFT by at least this factor, and the per-request prefill_convoy
 # stall seconds must drop by at least the drop floor. The ITL ceiling
@@ -2525,7 +2521,6 @@ CONVOY_TTFT_RATIO_FLOOR = 1.5
 CONVOY_DROP_FLOOR = 2.0
 CONVOY_ITL_CEILING = 1.5
 CONVOY_SPEC_FLOOR = 0.9
-CONVOY_CROSSOVER_FLOOR = 0.9
 
 
 def validate_convoy(report) -> list[str]:
@@ -2534,10 +2529,9 @@ def validate_convoy(report) -> list[str]:
     alternating schedule's late-arrival TTFT by the pinned floor with
     BIT-IDENTICAL outputs, decode ITL p99 within the ceiling, and spec
     accepted-per-wave within the floor; the per-request
-    ``prefill_convoy`` stall seconds drop by the drop floor; the
+    ``prefill_convoy`` stall seconds drop by the drop floor; and the
     starvation proof held its wave-count bound with boost waves
-    actually exercised; and the paged/dense crossover chose a path
-    within the floor of dense at every swept batch. Sections with
+    actually exercised. Sections with
     performed=False are schema-valid but gate-exempt (the CHAOS
     convention). Import-safe from artifact tests and
     scripts/convoybench.py (no jax at module scope)."""
@@ -2632,28 +2626,6 @@ def validate_convoy(report) -> list[str]:
                 "starvation: zero boost waves fired — the skew never "
                 "exercised deferral, so the bound was proven vacuously"
             )
-    cx = report.get("crossover")
-    if "crossover" in report and not isinstance(cx, dict):
-        problems.append("crossover section is not an object")
-    if isinstance(cx, dict) and cx.get("performed"):
-        problems += [
-            f"crossover.{f}" for f in CONVOY_CROSSOVER_FIELDS if f not in cx
-        ]
-        if isinstance(cx.get("sweep"), list) and not cx["sweep"]:
-            problems.append(
-                "crossover: empty sweep — no batch sizes were measured"
-            )
-        if cx.get("small_batch_ok") is not True:
-            problems.append(
-                "crossover: small-batch effective path fell under "
-                f"{CONVOY_CROSSOVER_FLOOR} of dense — the dispatch is "
-                "picking the slow path below --paged-min-batch"
-            )
-        if cx.get("large_batch_ok") is not True:
-            problems.append(
-                "crossover: bucketed wrapper regressed the at-bucket "
-                "batch — padding is costing where it should be free"
-            )
     val = report.get("value")
     if isinstance(il, dict) and il.get("performed"):
         if not isinstance(val, (int, float)) or not val > 0:
@@ -2676,15 +2648,13 @@ def build_convoy_report(res: dict) -> dict:
             "decode-interleaved mixed waves) on an identical virtual "
             "arrival schedule, with bit-identical outputs, decode ITL "
             "p99 and spec accepted-per-wave no worse, prefill_convoy "
-            "stall s/req dropped, a wave-counted starvation bound, and "
-            "the paged/dense crossover holding at small batch"
+            "stall s/req dropped, and a wave-counted starvation bound"
         ),
         "workload": (
             "a decoding carrier stream convoyed by a 960-token prompt "
             "with a late 16-token arrival, A-B across "
             "prefill_inline_budget 0 vs >0; 20:1 skew with boost waves "
-            "for the starvation proof; jnp-path dense/bucketed timing "
-            "sweep at batch 2/4/8/32 (see workload.run_convoy_workload)"
+            "for the starvation proof (see workload.run_convoy_workload)"
         ),
         **res,
     }
@@ -3632,7 +3602,7 @@ def _overload_sweep(cfg, params, page_size: int) -> dict:
     def fresh_engine(name):
         return Engine(
             cfg, params, num_slots=16384, page_size=page_size,
-            max_batch=8, name=name, decode_steps_per_launch=4,
+            max_batch=8, name=name,
         )
 
     # Calibration 1 (closed loop): warm the jit caches at the sweep's own
@@ -3907,7 +3877,7 @@ def _real_weights_north_star() -> dict:
         f"{load_s:.0f}s (tokenizer vocab {tokenizer.vocab_size})")
     engine = Engine(
         cfg, params, num_slots=32768, page_size=16, max_batch=16,
-        name="bench-real", decode_steps_per_launch=8,
+        name="bench-real",
     )
     shapes = {
         "base": dict(n_conversations=16, n_turns=4, system_sentences=10,
@@ -4021,7 +3991,6 @@ def _north_star(cfg, params, page_size: int) -> dict:
     engine = Engine(
         cfg, params, num_slots=eng_slots, page_size=page_size,
         max_batch=max_batch, name="bench",
-        decode_steps_per_launch=8,  # one host round trip per 8 tokens
     )
     per_shape = {}
     shape_tokens: dict[str, int] = {}

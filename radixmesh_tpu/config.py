@@ -22,6 +22,19 @@ from typing import Any
 import yaml
 
 DEFAULT_MAX_MSG_BYTES = 16 * 1024 * 1024  # mirror of reference cache_config.py:12
+# Every key launch.py reads from the ``model`` section. A key outside it
+# (a typo, or one a newer build no longer has) is refused by name in
+# ``load_config``: read past, the node would serve without what it asked.
+MODEL_KEYS = frozenset({
+    "preset", "overrides", "seed", "tokenizer",
+    "page_size", "kv_slots", "max_batch", "host_cache_slots",
+    "n_layers", "n_kv_heads", "head_dim",
+    "kv_quant", "weight_quant",
+    "prefill_inline_budget", "prefill_inline_max_defer",
+    "spec_decode_tokens", "spec_adaptive",
+    "token_timeline_capacity", "token_stall_threshold_s",
+    "step_accounting", "peak_tflops",
+})
 
 
 class NodeRole(enum.Enum):
@@ -433,6 +446,9 @@ def load_config(
         # README.md:122-124); a typo'd key must fail fast, not silently
         # default one node into a different rank space.
         raise ValueError(f"unknown config keys: {sorted(unknown)}")
+    unknown = set(raw.get("model") or {}) - MODEL_KEYS
+    if unknown:
+        raise ValueError(f"unknown model keys: {sorted(unknown)}")
     cfg = MeshConfig(
         prefill_nodes=list(raw.get("prefill_nodes", [])),
         decode_nodes=list(raw.get("decode_nodes", [])),

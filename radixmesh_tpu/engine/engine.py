@@ -46,8 +46,6 @@ from radixmesh_tpu.cache.radix_tree import RadixTree
 from radixmesh_tpu.engine.request import Request, RequestState, SamplingParams
 from radixmesh_tpu.models.llama import (
     ModelConfig,
-    decode_multi,
-    decode_multi_compact,
     decode_step,
     new_moe_stats,
     prefill_chunk_paged,
@@ -55,10 +53,9 @@ from radixmesh_tpu.models.llama import (
     refuse_unsupported,
 )
 from radixmesh_tpu.ops.attention import (
+    batch_bucket,
     default_use_kernel,
     kernel_shape_ok,
-    last_dispatch,
-    select_paged,
 )
 from radixmesh_tpu.obs.attribution import shape_bucket
 from radixmesh_tpu.obs.fleet_plane import eviction_counters
@@ -202,10 +199,8 @@ class Engine:
         prefill_wave_tokens: int = 4096,
         long_prefill_threshold: int = 1024,
         sp_prefill_threshold: int = 4096,
-        decode_steps_per_launch: int = 1,
         prefill_inline_budget: int = 0,
         prefill_inline_max_defer: int = 2,
-        paged_min_batch: int = 0,
         spec_decode_tokens: int = 0,
         spec_ngram: int = 3,
         spec_adaptive: bool = False,
@@ -235,7 +230,6 @@ class Engine:
             cfg, weight_quant=weight_quant,
             kv_quant=kv_quant or (pool is not None and pool.quant),
             device_mesh=device_mesh,
-            decode_steps_per_launch=decode_steps_per_launch,
             spec_decode_tokens=spec_decode_tokens,
             prefill_inline_budget=prefill_inline_budget,
             host_cache_slots=host_cache_slots, kv_tier_dir=kv_tier_dir,
@@ -311,10 +305,6 @@ class Engine:
         # at least this long prefill sp-sharded over the device mesh —
         # TTFT scales with the sp axis instead of one chip's FLOPs.
         self.sp_prefill_threshold = sp_prefill_threshold
-        # Fused multi-step decode: sample on device and feed back, one
-        # host round trip per k tokens (decode_multi). 1 = classic
-        # step-at-a-time.
-        self.decode_steps_per_launch = decode_steps_per_launch
         # Speculative decoding: draft γ tokens (radix-tree continuation
         # first — a replayed conversation's cached generation — then
         # prompt-lookup n-grams), verify all of them in ONE chunked
@@ -335,16 +325,10 @@ class Engine:
         # legacy whole-wave alternation every existing test pins.
         self.prefill_inline_budget = max(0, prefill_inline_budget)
         self.prefill_inline_max_defer = max(0, prefill_inline_max_defer)
-        # Small-batch paged crossover (ops/attention.py::select_paged):
-        # decode waves narrower than this take the dense/compact gather
-        # path instead of the paged kernel (the ctx-sweep ratios say
-        # dispatch overhead beats the kernel at batch ≤ 8). 0 = always
-        # honor default_use_kernel.
-        self.paged_min_batch = max(0, paged_min_batch)
-        # Last decode dispatch decision (ops.note_dispatch mirror) and
-        # last prefill launch path for /debug/state — which path ran, at
-        # what batch/bucket — and what attention a chunk launch from
-        # this process runs: the Pallas kernel or the jnp reference.
+        # Last decode launch (path, batch, bucket, max_len) and last
+        # prefill launch path for /debug/state, and what attention a
+        # launch from this process runs: the Pallas kernel or the jnp
+        # reference.
         self._last_dispatch: dict | None = None
         self._last_prefill_path: str | None = None  # "sp" | "dense" | "chunk"
         # Always-on accumulators served on /debug/state (a 3 s profiler
@@ -398,9 +382,11 @@ class Engine:
         self._span_publish = _TreeSpan(
             "rm.tree.publish", self.tree_host, "publishes", "publish_s"
         )
-        self.chunk_attention = (
-            "paged" if default_use_kernel(cfg.head_dim) else "reference"
-        )
+        kernels = default_use_kernel(cfg.head_dim)
+        self.chunk_attention = "paged" if kernels else "reference"
+        # ``path`` of the decode dispatch record: chip_smoke.py proves
+        # from it that the kernel served.
+        self._decode_path = "paged" if kernels else "dense"
         self.log = get_logger("engine")
         if jax.default_backend() == "tpu" and not kernel_shape_ok(cfg.head_dim):
             self.log.warning(
@@ -2305,7 +2291,7 @@ class Engine:
         if spec or inline_allot is not None:
             # Draft BEFORE committing to the wide verify launch: when no
             # row's history repeats its tail there is nothing to verify,
-            # and the plain/fused path emits the same tokens cheaper.
+            # and the plain step emits the same tokens cheaper.
             # A mixed wave (inline_allot) ALWAYS takes the fused chunk
             # launch — drafted rows verify, undrafted rows ride as
             # width-1 windows, and the inline chunks fill the rest of
@@ -2327,38 +2313,17 @@ class Engine:
                     g if spec else 0, drafts, sources, inline=inline_allot
                 )
                 return
-        k = self.decode_steps_per_launch
-        if k > 1:
-            k_eff = self._multi_step_k(k)
-            if k_eff > 1:
-                self._decode_multi_once(k_eff)
-                return
         seeded = self._seeded_launch(self._rows)
-        n_rows = sum(1 for r in self._rows if r is not None)
-        use_paged = select_paged(
-            n_rows,
-            self.cfg.head_dim,
-            min_batch=self.paged_min_batch,
-            max_len=max(
-                (r.kv_len for r in self._rows if r is not None), default=0
-            ),
-        )
-        self._last_dispatch = last_dispatch()
-        # A model with experts or window layers decodes through
-        # decode_step on every backend (the jnp attention where there is
-        # no kernel): the compact path is decode_multi, which refuses it.
-        step_only = bool(self.cfg.n_experts or self.cfg.has_window)
-        if not self._pp and not use_paged and not seeded and not step_only:
-            # Dense single step (small-batch paged fast path,
-            # ops/attention.py::select_paged): either no paged kernel on
-            # this backend, or the batch sits below --paged-min-batch —
-            # where the paged launch's whole-pool donation-copy and
-            # block bookkeeping lose to the compact gathered working
-            # set. Seeded launches skip it: its device-side draw is
-            # batch-shaped, and replay needs the canonical per-row
-            # (seed, position) draw below.
-            self._decode_multi_once(1, force_compact=True)
-            return
+        # What /debug/state shows of the launch: the attention decode_step
+        # runs from this process (the Pallas kernel, or the jnp reference
+        # where it does not lower) and the launch's size.
+        rows = [r for r in self._rows if r is not None]
+        self._last_dispatch = {
+            "path": self._decode_path,
+            "batch": len(rows),
+            "bucket": batch_bucket(len(rows)),
+            "max_len": max((r.kv_len for r in rows), default=0),
+        }
         with annotate("rm.decode.build"):
             slots = np.full(
                 self.max_batch, self._scratch_slot, dtype=np.int32
@@ -2500,209 +2465,6 @@ class Engine:
                     req, row, int(slots[row]), int(sampled[row]), stamp
                 )
 
-    def _multi_step_k(self, k: int) -> int:
-        """The largest fusable step count ≤ k this launch: bounded by
-        every active row's sequence/page headroom and remaining output
-        budget. Fusing is preferred whenever no WAITING request could
-        actually admit (admission happens between launches, and k steps
-        per launch is k× fewer pool donation-copies + host syncs — the
-        wide-workload convoy, VERDICT round-3 next-step #6). Staggered
-        admission leaves rows at DIFFERENT budget remainders, and
-        refusing to fuse whenever any row was near its budget degraded
-        mixed batches to single-stepping for most of their lifetime —
-        shrink k to the binding row instead. Returns ≤ 1 when fusing is
-        pointless."""
-        if self.waiting and self._free_row() >= 0:
-            return 1
-        if self._pp and self.max_batch % self.device_mesh.shape["pp"]:
-            return 1
-        for req in self._rows:
-            if req is None:
-                continue
-            if req.sampling.seed is not None:
-                # Seeded replay (crash recovery): the fused launch draws
-                # its intermediate tokens from one in-scan key schedule,
-                # which would tie each draw to the LAUNCH rather than
-                # the (seed, position) pair — single-step so every draw
-                # goes through the position-keyed path.
-                return 1
-            k = min(k, self.max_seq_len - req.kv_len)
-            k = min(k, self.max_pages * self.page_size - req.kv_len)
-            # A row past its output budget would discard the tail of the
-            # fused launch — bubble compute without a latency win.
-            k = min(
-                k, req.sampling.max_new_tokens - len(req.output_tokens)
-            )
-            if k <= 1:
-                return 1
-        return k
-
-    def _compact_decode_tables(
-        self, active: list[tuple], k: int
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """Compact working-set mapping for ``decode_multi_compact``:
-        the unique live pages of every active row (with ``k`` tokens of
-        headroom) plus the scratch page, pow2-padded by DUPLICATING the
-        scratch page (the one page where duplicate scatter-back targets
-        are harmless — its contents are never read unmasked), and the
-        bucketed page table rewritten into compact indices."""
-        ps = self.page_size
-        need = [
-            (row, (req.kv_len + k - 1) // ps + 1) for row, req in active
-        ]
-        uniq = np.unique(np.concatenate(
-            [self._page_table[row, :n] for row, n in need]
-            + [np.asarray([self._scratch_page], dtype=np.int32)]
-        )).astype(np.int32)
-        n_c = _pow2_at_least(len(uniq), floor=8)
-        compact = np.full(n_c, self._scratch_page, dtype=np.int32)
-        compact[: len(uniq)] = uniq
-        scratch_idx = int(np.searchsorted(uniq, self._scratch_page))
-        maxp = self._decode_pt_bucket(k).shape[1]
-        pt_c = np.full(
-            (self.max_batch, maxp), scratch_idx, dtype=np.int32
-        )
-        for row, n in need:
-            pt_c[row, :n] = np.searchsorted(
-                uniq, self._page_table[row, :n]
-            )
-        return compact, pt_c
-
-    def _decode_multi_once(self, k: int, force_compact: bool = False) -> None:
-        """One ``decode_multi`` launch: k tokens per active request with a
-        single host round trip (device-side sampling feeds each step). See
-        ``models/llama.py::decode_multi`` for the latency rationale.
-        ``force_compact`` pins the gathered compact-working-set variant
-        even where the paged kernel exists — the small-batch crossover
-        (``select_paged``) chose dense for this wave."""
-        with annotate("rm.decode.build"):
-            lengths = np.ones(self.max_batch, dtype=np.int32)
-            active = self._provision_rows(k - 1)
-            if not active:
-                return
-            for row, req in active:
-                lengths[row] = req.kv_len + 1
-            step_t0 = time.monotonic()
-            # No aliased kernel on this backend (or the crossover chose
-            # dense): decode over a gathered compact working set so each
-            # launch pays ONE pool gather + ONE scatter-back instead of
-            # k·L pool-sized scatter copies
-            # (see models/llama.py::decode_multi_compact).
-            compact = not self._pp and (
-                force_compact or not default_use_kernel(self.cfg.head_dim)
-            )
-            if compact:
-                pages, pt_c = self._compact_decode_tables(active, k)
-                pages_d, pt_d = jnp.asarray(pages), jnp.asarray(pt_c)
-            else:
-                pt_d = jnp.asarray(self._decode_pt_bucket(k))
-            tokens_d = jnp.asarray(self._tokens)
-            lengths_d = jnp.asarray(lengths)
-            temps_d = jnp.asarray(self._temps)
-            top_ps_d = jnp.asarray(self._top_ps)
-            top_ks_d = jnp.asarray(self._top_ks)
-        with annotate("rm.decode.sample"):
-            # The sampler itself is fused into the launch (device-side
-            # sampling feeds each step): only its key is drawn here, and
-            # an argmax launch reads none (the stream does not advance).
-            draws = self._launch_draws(r for _, r in active)
-            key = self._rng
-            if draws:
-                self._rng, key = jax.random.split(self._rng)
-        with annotate("rm.decode.dispatch"):
-            if self._pp:
-                from radixmesh_tpu.parallel.pp_serving import pp_decode_multi
-
-                res = pp_decode_multi(
-                    self.params,
-                    self.cfg,
-                    tokens_d,
-                    self.pool.kv,
-                    pt_d,
-                    lengths_d,
-                    key,
-                    temps_d,
-                    top_ps_d,
-                    top_ks_d,
-                    page_size=self.page_size,
-                    k_steps=k,
-                    mesh=self.device_mesh,
-                    kv_scale=self.pool.kv_scale,
-                    scratch_slot=self._scratch_slot,
-                    draws=draws,
-                )
-            elif compact:
-                res = decode_multi_compact(
-                    self.params,
-                    self.cfg,
-                    tokens_d,
-                    self.pool.kv,
-                    pages_d,
-                    pt_d,
-                    lengths_d,
-                    key,
-                    temps_d,
-                    top_ps_d,
-                    self.page_size,
-                    k_steps=k,
-                    mesh=self.device_mesh,
-                    kv_scale=self.pool.kv_scale,
-                    top_ks=top_ks_d,
-                    draws=draws,
-                )
-            else:
-                res = decode_multi(
-                    self.params,
-                    self.cfg,
-                    tokens_d,
-                    self.pool.kv,
-                    pt_d,
-                    lengths_d,
-                    key,
-                    temps_d,
-                    top_ps_d,
-                    self.page_size,
-                    k_steps=k,
-                    mesh=self.device_mesh,
-                    kv_scale=self.pool.kv_scale,
-                    top_ks=top_ks_d,
-                    draws=draws,
-                )
-            sampled = self._commit_pool_update(res)
-        self._flush_emit(_UNDER_DECODE)
-        with annotate("rm.decode.sync"):
-            sampled = np.asarray(sampled)  # [k, B] — the ONE round trip
-        self.stats.decode_steps += k
-        stamp = time.monotonic()
-        elapsed = stamp - step_t0
-        self._emit_steps.append((
-            step_t0,
-            elapsed,
-            k,
-            (
-                "decode", k * len(active), k * self.max_batch, elapsed,
-                len(active),
-            ),
-            [
-                (r.trace, {"k_steps": k, "batch_rows": len(active)})
-                for _, r in active
-                if r.trace is not None
-            ],
-        ))
-        ps = self.page_size
-        with annotate("rm.decode.consume"):
-            for row, req in active:
-                base = req.kv_len
-                for i in range(k):
-                    pos = base + i
-                    slot = int(
-                        self._page_table[row, pos // ps] * ps + pos % ps
-                    )
-                    if self._advance_token(
-                        req, row, slot, int(sampled[i, row]), stamp
-                    ):
-                        break  # finished mid-launch: surplus discarded
-
     def _spec_ok(self, g: int) -> bool:
         """Speculative decoding is considered whenever rows are active and
         no request waits for admission (admission happens between
@@ -2734,8 +2496,8 @@ class Engine:
         """Per-row speculation gate: the verify window needs γ+1 positions
         of sequence and page-table headroom, and a row within one token of
         its output budget gains nothing from a draft (the surplus would be
-        discarded — the same bubble ``_multi_step_k`` avoids). Failing
-        rows decode normally inside the launch via an empty draft."""
+        discarded). Failing rows decode normally inside the launch via an
+        empty draft."""
         if req.kv_len + g + 1 > self.max_seq_len:
             return False
         if (req.kv_len + g) // self.page_size >= self.max_pages:
@@ -2820,12 +2582,10 @@ class Engine:
     ) -> list[tuple[int, "Request"]]:
         """Ensure every active row's page table covers positions
         ``kv_len .. kv_len+extra``; preempt rows the pool can't cover.
-        Returns the surviving (row, request) pairs. Shared by the fused
-        multi-step and speculative paths (their only difference was the
-        bound). ``extras`` overrides the bound per row — the speculative
-        path provisions only each row's actual draft window, so a row that
-        opted out (empty draft) cannot be preempted for headroom it will
-        never write."""
+        Returns the surviving (row, request) pairs. ``extras`` overrides
+        the bound per row — the speculative path provisions only each
+        row's actual draft window, so a row that opted out (empty draft)
+        cannot be preempted for headroom it will never write."""
         ps = self.page_size
         preempted: list[Request] = []
         for row, req in enumerate(self._rows):

@@ -283,12 +283,10 @@ def _run_node(args: argparse.Namespace) -> int:
             page_size=pool.page_size,
             max_batch=int(model.get("max_batch", 8)),
             host_cache_slots=int(model.get("host_cache_slots", 0)),
-            decode_steps_per_launch=int(model.get("decode_steps_per_launch", 1)),
             prefill_inline_budget=int(model.get("prefill_inline_budget", 0)),
             prefill_inline_max_defer=int(
                 model.get("prefill_inline_max_defer", 2)
             ),
-            paged_min_batch=int(model.get("paged_min_batch", 0)),
             spec_decode_tokens=int(model.get("spec_decode_tokens", 0)),
             spec_adaptive=bool(model.get("spec_adaptive", False)),
             token_timeline_capacity=int(
@@ -592,10 +590,8 @@ def _run_serve(args: argparse.Namespace) -> int:
         page_size=args.page_size,
         max_batch=args.max_batch,
         host_cache_slots=args.host_cache_slots,
-        decode_steps_per_launch=args.decode_steps_per_launch,
         prefill_inline_budget=args.prefill_inline_budget,
         prefill_inline_max_defer=args.prefill_inline_max_defer,
-        paged_min_batch=args.paged_min_batch,
         spec_decode_tokens=args.spec_decode_tokens,
         spec_adaptive=args.spec_adaptive,
         token_timeline_capacity=args.token_timeline_capacity,
@@ -929,10 +925,6 @@ def main(argv: list[str] | None = None) -> int:
     serve.add_argument("--host-cache-slots", type=int, default=0)
     serve.add_argument("--seed", type=int, default=0)
     serve.add_argument(
-        "--decode-steps-per-launch", type=int, default=1,
-        help="fuse k decode steps per device launch (device-side sampling)",
-    )
-    serve.add_argument(
         "--prefill-inline-budget", type=int, default=0,
         help="mixed compute waves (engine/waves.py): ride up to N tokens "
         "of chunked prefill from queued prompts on each decode launch "
@@ -944,14 +936,6 @@ def main(argv: list[str] | None = None) -> int:
         help="starvation bound for mixed waves: at most N consecutive "
         "prefill-only catch-up waves before a decode-bearing wave MUST "
         "run (bound stated in wave counts, not wall-clock)",
-    )
-    serve.add_argument(
-        "--paged-min-batch", type=int, default=0,
-        help="small-batch paged fast path: decode batches below N rows "
-        "take the dense compact-working-set path instead of the paged "
-        "kernel (0 = always paged where the kernel exists; see "
-        "ops/attention.py::select_paged and convoybench's crossover "
-        "sweep)",
     )
     serve.add_argument(
         "--profile-dir", default=None,

@@ -52,7 +52,6 @@ from radixmesh_tpu.ops.attention import (
 from radixmesh_tpu.ops.moe import STATS as MOE_STATS, moe_ffn
 from radixmesh_tpu.ops.norm import rms_norm
 from radixmesh_tpu.ops.rope import apply_rope, rope_frequencies, yarn_frequencies
-from radixmesh_tpu.ops.sampling import sample_tokens
 from radixmesh_tpu.ops.wquant import quantize_weight
 
 __all__ = [
@@ -62,8 +61,6 @@ __all__ = [
     "prefill_forward_sp",
     "prefill_chunk_paged",
     "decode_step",
-    "decode_multi",
-    "decode_multi_compact",
     "param_logical_axes",
     "convert_hf_state_dict",
     "UnsupportedForModel",
@@ -492,8 +489,6 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
         "hf_checkpoint": "loading a HuggingFace checkpoint (--weights)",
     }
     asked = [text for key, text in named.items() if options.get(key)]
-    if int(options.get("decode_steps_per_launch") or 1) > 1:
-        asked.append("multi-step decode (--decode-steps-per-launch)")
     if mesh is not None and any(n > 1 for n in dict(mesh.shape).values()):
         asked.append(f"a device mesh {dict(mesh.shape)} (tp / sp / pp)")
     if not asked:
@@ -504,8 +499,7 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
     )
     return (
         f"a model with {kinds} does not support " + "; ".join(asked)
-        + ": it serves on one chip, bf16 weights and KV, one decode step a "
-        "launch (ROADMAP D6 / R4)"
+        + ": it serves on one chip, bf16 weights and KV (ROADMAP D6 / R4)"
     )
 
 
@@ -962,155 +956,6 @@ def decode_step(
         params, cfg, tokens, kv_pool, slots, page_table, lengths, page_size,
         mesh, kv_scale, moe_stats,
     )
-
-
-@partial(
-    jax.jit,
-    static_argnames=("cfg", "page_size", "k_steps", "mesh", "draws"),
-    donate_argnums=(3,),
-    donate_argnames=("kv_scale",),
-)
-def decode_multi(
-    params: dict,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,  # [B] current token per sequence
-    kv_pool: jnp.ndarray,  # [2, L, Hkv, num_slots, D] (donated)
-    page_table: jnp.ndarray,  # [B, max_pages] — pages preallocated k ahead
-    lengths: jnp.ndarray,  # [B] context length incl. the first fed token
-    key: jax.Array,
-    temperatures: jnp.ndarray,  # [B]
-    top_ps: jnp.ndarray,  # [B]
-    page_size: int = 16,
-    k_steps: int = 8,
-    mesh=None,
-    kv_scale: jnp.ndarray | None = None,
-    top_ks: jnp.ndarray | int = 0,  # [B] (0 = off)
-    draws: bool = True,  # ops/sampling.py: False = every row is an argmax
-):
-    """``k_steps`` decode iterations fused in ONE dispatch: sampling stays
-    on device and each sampled token feeds the next step, so the host pays
-    a single round trip per k tokens instead of per token. The caller
-    preallocates pages covering
-    positions ``lengths-1 .. lengths+k-2`` per row; token slots are
-    derived from the page table on device. Returns ``(sampled [k, B],
-    kv_pool)``; stop-token/length bookkeeping happens on host afterwards
-    (surplus tokens past a stop are discarded — latency is bought with a
-    little bubble compute)."""
-    # One step's counters and expert ids have nowhere to go in a fused
-    # loop, and a k-step launch was never run with a window.
-    refuse_unsupported(cfg, decode_steps_per_launch=2)
-    B = tokens.shape[0]
-    rows = jnp.arange(B)
-
-    def step(carry, i):
-        toks, pool, scale, k = carry
-        lens = lengths + i
-        pos = lens - 1
-        slots = (
-            page_table[rows, pos // page_size] * page_size + pos % page_size
-        )
-        res = _decode_core(
-            params, cfg, toks, pool, slots, page_table, lens, page_size, mesh,
-            scale,
-        )
-        logits, pool = res[0], res[1]
-        if scale is not None:
-            scale = res[2]
-        sk = None
-        if draws:
-            k, sk = jax.random.split(k)
-        nxt = sample_tokens(
-            logits, sk, temperature=temperatures, top_p=top_ps, top_k=top_ks,
-            draws=draws,
-        ).astype(jnp.int32)
-        return (nxt, pool, scale, k), nxt
-
-    (_, kv_pool, kv_scale, _), sampled = jax.lax.scan(
-        step, (tokens, kv_pool, kv_scale, key), jnp.arange(k_steps)
-    )
-    if kv_scale is not None:
-        return sampled, kv_pool, kv_scale
-    return sampled, kv_pool
-
-
-@partial(
-    jax.jit,
-    static_argnames=("cfg", "page_size", "k_steps", "mesh", "draws"),
-    donate_argnums=(3,),
-    donate_argnames=("kv_scale",),
-)
-def decode_multi_compact(
-    params: dict,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,  # [B]
-    kv_pool: jnp.ndarray,  # [2, L, Hkv, num_slots, D] (donated)
-    compact_pages: jnp.ndarray,  # [n_c] UNIQUE full-pool page ids (pad = dup
-    #                               of the scratch page — see contract below)
-    page_table_c: jnp.ndarray,  # [B, maxp] indices into compact_pages
-    lengths: jnp.ndarray,  # [B] context length incl. the first fed token
-    key: jax.Array,
-    temperatures: jnp.ndarray,
-    top_ps: jnp.ndarray,
-    page_size: int = 16,
-    k_steps: int = 8,
-    mesh=None,
-    kv_scale: jnp.ndarray | None = None,
-    top_ks: jnp.ndarray | int = 0,
-    draws: bool = True,
-):
-    """``decode_multi`` over a gathered COMPACT working set — the decode
-    path for backends without the aliased Pallas kernel (CPU today).
-
-    Without aliasing, every layer's KV write into the full pool is an XLA
-    scatter that copies the WHOLE pool — ``k·L`` pool-sized copies per
-    launch dominated decode wherever donation falls back to copying (the
-    wide-workload convoy, VERDICT round-3 weak #2/#6). Here the launch
-    pays ONE pool-sized gather of the live pages into a working-set pool
-    (batch · bucketed-pages sized, typically 100-1000× smaller), runs the
-    whole fused loop against it, and scatters the touched pages back
-    once. On TPU the aliased fused kernel is strictly better — this
-    function exists for everything else.
-
-    CONTRACT: ``compact_pages`` entries must be unique except for
-    padding, which must duplicate the engine's SCRATCH page (duplicate
-    scatter-back targets write that page multiple times; scratch contents
-    are never read unmasked, so last-write-wins is harmless there and
-    must be harmless ONLY there). ``page_table_c`` maps every row's pages
-    (and inactive rows entirely) to compact indices.
-
-    Returns ``(sampled [k, B], kv_pool)`` (+ scale) — the ``decode_multi``
-    contract.
-    """
-    L, Hkv, D = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-    num_slots = kv_pool.shape[3]
-    P = num_slots // page_size
-    n_c = compact_pages.shape[0]
-    pages = kv_pool.reshape(2, L, Hkv, P, page_size, D)
-    sub_pool = pages[:, :, :, compact_pages].reshape(
-        2, L, Hkv, n_c * page_size, D
-    )
-    sub_scale = None
-    if kv_scale is not None:
-        scale_pages = kv_scale.reshape(2, L, Hkv, P, page_size)
-        sub_scale = scale_pages[:, :, :, compact_pages].reshape(
-            2, L, Hkv, n_c * page_size
-        )
-    res = decode_multi(
-        params, cfg, tokens, sub_pool, page_table_c, lengths, key,
-        temperatures, top_ps, page_size=page_size, k_steps=k_steps,
-        mesh=mesh, kv_scale=sub_scale, top_ks=top_ks, draws=draws,
-    )
-    sampled, sub_pool = res[0], res[1]
-    pages = pages.at[:, :, :, compact_pages].set(
-        sub_pool.reshape(2, L, Hkv, n_c, page_size, D)
-    )
-    kv_pool = pages.reshape(2, L, Hkv, num_slots, D)
-    if kv_scale is not None:
-        scale_pages = scale_pages.at[:, :, :, compact_pages].set(
-            res[2].reshape(2, L, Hkv, n_c, page_size)
-        )
-        return sampled, kv_pool, scale_pages.reshape(2, L, Hkv, num_slots)
-    return sampled, kv_pool
 
 
 def _decode_core(

@@ -341,10 +341,9 @@ def kernel_shape_ok(head_dim: int) -> bool:
 def default_use_kernel(head_dim: int) -> bool:
     """HOST-side answer to "will a launch from this process run the
     Pallas kernels": a TPU backend and a tileable ``head_dim``. The
-    engine picks its launch path with it (``select_paged``, compact vs
-    fused decode) and publishes it in the dispatch records; the pp stage
-    bodies take it as their default. INSIDE a jitted launch the choice
-    follows the lowering target instead (``_by_lowering_target``), so an
+    engine publishes it in the dispatch records (it selects nothing
+    there); the pp stage bodies take it as their default. INSIDE a jitted
+    launch the choice follows the lowering target instead (``_by_lowering_target``), so an
     ahead-of-time compile for a TPU topology from a CPU host lowers the
     kernels, not the reference. The two agree whenever a launch runs on
     this process's default backend — always, for an engine."""
@@ -371,66 +370,13 @@ def _dispatch(use_kernel, head_dim: int, kernel_fn, reference_fn, *args):
 
 
 def batch_bucket(n: int, floor: int = 1) -> int:
-    """Compiled-batch bucket for a decode launch: the smallest power of
-    two ≥ ``n`` (≥ ``floor``). Serving batch sizes drift one row at a
-    time as requests finish/admit; dispatching every distinct B would
-    compile a fresh kernel variant per occupancy — bucketing bounds the
-    variant count at log2(max_batch)."""
+    """The smallest power of two ≥ ``n`` (≥ ``floor``): the ``bucket``
+    of the engine's decode dispatch record (/debug/state)."""
     b = max(1, int(floor))
     n = max(1, int(n))
     while b < n:
         b <<= 1
     return b
-
-
-# Last dispatch decision made by ``select_paged`` on this process —
-# plain module dict, read lock-free by the engine's /debug/state section
-# (the "chosen path must be visible" half of the small-batch fast path).
-_LAST_DISPATCH: dict = {}
-
-
-def note_dispatch(path: str, batch: int, bucket: int, max_len: int) -> None:
-    """Record one dispatch decision (also callable by benches that probe
-    the crossover directly)."""
-    _LAST_DISPATCH.update(
-        path=path, batch=int(batch), bucket=int(bucket),
-        max_len=int(max_len),
-    )
-
-
-def last_dispatch() -> dict | None:
-    """The most recent ``select_paged`` decision, or None before the
-    first one. Returns a copy: callers may stash it in snapshots."""
-    return dict(_LAST_DISPATCH) if _LAST_DISPATCH else None
-
-
-def select_paged(
-    batch: int,
-    head_dim: int,
-    min_batch: int = 0,
-    max_len: int = 0,
-) -> bool:
-    """THE per-wave paged-vs-dense crossover for decode launches (PR 19
-    small-batch fast path). The paged Pallas kernel amortizes its DMA
-    block machinery and the whole-pool donation copy across rows; below
-    a few rows the dense gathered-working-set path wins — convoybench's
-    crossover sweep pins the threshold, ``--paged-min-batch`` sets it
-    (0 = always paged where the kernel exists, the pre-PR-19 behavior).
-    Returns True for the paged kernel path; records the decision for
-    ``last_dispatch``."""
-    if not default_use_kernel(head_dim):
-        paged = False
-    elif min_batch > 0 and batch < min_batch:
-        paged = False
-    else:
-        paged = True
-    note_dispatch(
-        "paged" if paged else "dense",
-        batch,
-        batch_bucket(batch),
-        max_len,
-    )
-    return paged
 
 
 def paged_attention(
@@ -545,45 +491,6 @@ def paged_attention_pool(
         q, kv_pages, page_table, lengths, jnp.asarray(layer, jnp.int32),
         kv_scales,
     )
-
-
-def paged_attention_pool_bucketed(
-    q: jnp.ndarray,  # [B, Hq, D]
-    kv_pages: jnp.ndarray,  # [2, L, Hkv, P, page, D]
-    page_table: jnp.ndarray,  # [B, maxp]
-    lengths: jnp.ndarray,  # [B]
-    layer: jnp.ndarray | int,
-    use_kernel: bool | None = None,
-    mesh=None,
-    kv_scales: jnp.ndarray | None = None,
-) -> jnp.ndarray:
-    """``paged_attention_pool`` with the batch padded up to a compiled
-    power-of-two bucket (PR 19 small-batch fast path): a serving batch
-    that drifts 5→4→3 rows as requests finish reuses the B=8 variant
-    instead of compiling three. Pad rows repeat row 0's query and page
-    row with length 1 — one real token of masked attention each, never
-    an out-of-bounds page id — and are sliced off the result. B already
-    at a bucket boundary is a pure pass-through."""
-    B = q.shape[0]
-    nb = batch_bucket(B)
-    if nb == B:
-        return paged_attention_pool(
-            q, kv_pages, page_table, lengths, layer,
-            use_kernel=use_kernel, mesh=mesh, kv_scales=kv_scales,
-        )
-    pad = nb - B
-    q_p = jnp.concatenate([q, jnp.repeat(q[:1], pad, axis=0)], axis=0)
-    pt_p = jnp.concatenate(
-        [page_table, jnp.repeat(page_table[:1], pad, axis=0)], axis=0
-    )
-    len_p = jnp.concatenate(
-        [lengths, jnp.ones((pad,), dtype=lengths.dtype)], axis=0
-    )
-    out = paged_attention_pool(
-        q_p, kv_pages, pt_p, len_p, layer,
-        use_kernel=use_kernel, mesh=mesh, kv_scales=kv_scales,
-    )
-    return out[:B]
 
 
 def paged_chunk_attention_kernel_sharded(

@@ -795,10 +795,11 @@ class ServingFrontend:
                 }
             dispatch = getattr(eng, "_last_dispatch", None)
             if dispatch is not None:
-                # Small-batch paged fast path: the chosen decode
-                # attention path (paged kernel vs dense compact) for the
-                # last wave, with its batch bucket — the visibility half
-                # of the ops/attention.py::select_paged crossover.
+                # The last decode launch: the attention it ran (the
+                # paged kernel, or "dense" = the jnp reference where the
+                # kernel does not lower) and its batch, bucket and
+                # longest context. chip_smoke.py proves from it that the
+                # kernel served.
                 state["decode_dispatch"] = dispatch
             if eng._last_prefill_path is not None:
                 # Which prefill launch the last admission wave took

@@ -4904,11 +4904,10 @@ def run_convoy_workload(
     max_defer: int = 2,
     reps: int = 5,
     stall_threshold_s: float = 0.02,
-    paged_min_batch: int = 16,
 ) -> dict:
     """The CONVOY acceptance workload (PR 19, killing the prefill
     convoy): one CPU cell proving decode-interleaved chunked prefill
-    and the small-batch paged dispatch end to end.
+    end to end.
 
     a. **Interleave A-B.** Two engines on IDENTICAL virtual arrival
        schedules — ``prefill_inline_budget=0`` (legacy alternating
@@ -4930,28 +4929,15 @@ def run_convoy_workload(
        counted in WAVES, not wall-clock, the carrier stream never goes
        more than ``max_defer`` consecutive engine steps without a
        token while backlog is pending.
-    d. **Crossover sweep.** Dense-vs-paged decode dispatch at batch
-       2/4/8/32 on the jnp reference path: ``select_paged`` must choose
-       dense below ``--paged-min-batch`` (so the effective path is
-       never the slow small-batch paged launch), and the bucketed paged
-       wrapper must cost ~nothing at an at-bucket batch.
     """
     import time as _time
 
     import jax
-    import jax.numpy as jnp
 
     from radixmesh_tpu.engine.engine import Engine
     from radixmesh_tpu.engine.request import SamplingParams
     from radixmesh_tpu.models.llama import ModelConfig, init_params
     from radixmesh_tpu.obs.token_timeline import TokenTimeline
-    from radixmesh_tpu.ops.attention import (
-        batch_bucket,
-        last_dispatch,
-        paged_attention_pool,
-        paged_attention_pool_bucketed,
-        select_paged,
-    )
 
     rng = np.random.default_rng(seed)
     t_start = _time.monotonic()
@@ -5132,83 +5118,9 @@ def run_convoy_workload(
         "carrier_tokens": len(carrier.output_tokens),
     }
 
-    # -- phase d: paged/dense crossover sweep --------------------------
-    # CPU tier runs the jnp reference math for BOTH paths, so the sweep
-    # proves the dispatch policy (dense below --paged-min-batch) and
-    # that the bucketed wrapper is free at an at-bucket batch; the TPU
-    # kernel crossover point itself is pinned by kernelbench.
-    dense_j = jax.jit(
-        lambda q, kv, pt, l: paged_attention_pool(
-            q, kv, pt, l, 0, use_kernel=False
-        )
-    )
-    buck_j = jax.jit(
-        lambda q, kv, pt, l: paged_attention_pool_bucketed(
-            q, kv, pt, l, 0, use_kernel=False
-        )
-    )
-
-    def timed(fn, *a) -> float:
-        t0 = _time.perf_counter()
-        jax.block_until_ready(fn(*a))
-        return _time.perf_counter() - t0
-
-    krng = jax.random.PRNGKey(seed + 1)
-    page, D, Hq, Hkv, seq = 4, 64, 2, 2, 256
-    per_pages = seq // page
-    sweep = []
-    for B in (2, 4, 8, 32):
-        k1, k2, krng = jax.random.split(krng, 3)
-        kv = jax.random.normal(
-            k1, (2, 1, Hkv, B * per_pages, page, D), jnp.float32
-        )
-        q = jax.random.normal(k2, (B, Hq, D), jnp.float32)
-        pt = jnp.arange(B * per_pages, dtype=jnp.int32).reshape(B, per_pages)
-        lens = jnp.full((B,), seq, jnp.int32)
-        # Compile both, then INTERLEAVE the timed reps — back-to-back
-        # loops see thermal/GC drift that min-of-N alone doesn't cancel.
-        jax.block_until_ready(dense_j(q, kv, pt, lens))
-        jax.block_until_ready(buck_j(q, kv, pt, lens))
-        dense_t = buck_t = float("inf")
-        for _ in range(9):
-            dense_t = min(dense_t, timed(dense_j, q, kv, pt, lens))
-            buck_t = min(buck_t, timed(buck_j, q, kv, pt, lens))
-        paged_sel = select_paged(
-            B, D, min_batch=paged_min_batch, max_len=seq
-        )
-        eff_t = buck_t if paged_sel else dense_t
-        sweep.append(
-            {
-                "batch": B,
-                "bucket": batch_bucket(B),
-                "paged_selected": bool(paged_sel),
-                "dense_t_s": round(dense_t, 6),
-                "bucketed_t_s": round(buck_t, 6),
-                "effective_over_dense": round(dense_t / eff_t, 4),
-                "bucketed_over_direct": round(dense_t / buck_t, 4),
-                "dispatch": last_dispatch(),
-            }
-        )
-    small = [e for e in sweep if e["batch"] < paged_min_batch]
-    large = [e for e in sweep if e["batch"] >= 32]
-    crossover = {
-        "performed": True,
-        "paged_min_batch": paged_min_batch,
-        "sweep": sweep,
-        "small_batch_ok": bool(
-            small
-            and all(e["effective_over_dense"] >= 0.9 for e in small)
-            and all(not e["paged_selected"] for e in small)
-        ),
-        "large_batch_ok": bool(
-            large and all(e["bucketed_over_direct"] >= 0.9 for e in large)
-        ),
-    }
-
     return {
         "interleave": interleave,
         "stalls": stalls,
         "starvation": starvation,
-        "crossover": crossover,
         "wall_s": round(_time.monotonic() - t_start, 3),
     }

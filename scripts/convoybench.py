@@ -2,8 +2,8 @@
 paired CLI emitter, like ``scripts/specbench.py`` is for SPEC).
 
 Runs ``workload.run_convoy_workload`` — the decode-interleaved chunked
-prefill A-B plus the small-batch paged dispatch sweep, all on the CPU
-tier — and checks the four convoy verdicts end to end:
+prefill A-B, all on the CPU tier — and checks the three convoy verdicts
+end to end:
 
 - **interleave** — a late-arriving short prompt's p50 TTFT beats the
   legacy alternating schedule by the pinned floor on an IDENTICAL
@@ -15,11 +15,7 @@ tier — and checks the four convoy verdicts end to end:
 - **starvation** — under 20:1 prompt-length skew with boost waves
   firing, decode never goes more than ``--prefill-inline-max-defer``
   consecutive waves without a token (counted in waves, never
-  wall-clock);
-- **crossover** — ``select_paged`` picks dense below
-  ``--paged-min-batch`` so the effective small-batch path stays within
-  the floor of dense, and the bucketed wrapper is free at an at-bucket
-  batch.
+  wall-clock).
 
 Prints ONE JSON line validated against the schema
 ``bench.validate_convoy`` pins.

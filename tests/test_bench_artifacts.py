@@ -2170,8 +2170,8 @@ class TestSpecArtifactSchema:
 class TestConvoyArtifactSchema:
     """The CONVOY artifact (PR 19, killing the prefill convoy):
     decode-interleaved chunked prefill's TTFT win with bit-identical
-    outputs, the prefill_convoy stall drop, the wave-counted starvation
-    bound, and the paged/dense crossover — the ISSUE's gate names."""
+    outputs, the prefill_convoy stall drop and the wave-counted
+    starvation bound — the ISSUE's gate names."""
 
     def _report(self) -> dict:
         return {
@@ -2206,19 +2206,6 @@ class TestConvoyArtifactSchema:
                 "max_defer_observed": 2, "boost_waves": 2,
                 "bounded": True, "carrier_tokens": 48,
             },
-            "crossover": {
-                "performed": True, "paged_min_batch": 16,
-                "sweep": [
-                    {"batch": 2, "bucket": 2, "paged_selected": False,
-                     "effective_over_dense": 1.0,
-                     "bucketed_over_direct": 1.01},
-                    {"batch": 32, "bucket": 32, "paged_selected": False,
-                     "effective_over_dense": 1.0,
-                     "bucketed_over_direct": 0.99},
-                ],
-                "small_batch_ok": True,
-                "large_batch_ok": True,
-            },
             "wall_s": 40.0,
         }
 
@@ -2232,13 +2219,11 @@ class TestConvoyArtifactSchema:
         del report["interleave"]["ttft_ratio"]
         del report["stalls"]["convoy_drop_ratio"]
         del report["starvation"]["bounded"]
-        del report["crossover"]["small_batch_ok"]
         missing = bench.validate_convoy(report)
         assert "wall_s" in missing
         assert "interleave.ttft_ratio" in missing
         assert "stalls.convoy_drop_ratio" in missing
         assert "starvation.bounded" in missing
-        assert "crossover.small_batch_ok" in missing
 
     def test_interleave_gates(self):
         report = self._report()
@@ -2273,19 +2258,9 @@ class TestConvoyArtifactSchema:
         problems = "\n".join(bench.validate_convoy(report))
         assert "proven vacuously" in problems
 
-    def test_crossover_gates(self):
-        report = self._report()
-        report["crossover"]["small_batch_ok"] = False
-        report["crossover"]["large_batch_ok"] = False
-        report["crossover"]["sweep"] = []
-        problems = "\n".join(bench.validate_convoy(report))
-        assert "picking the slow path" in problems
-        assert "padding is costing" in problems
-        assert "empty sweep" in problems
-
     def test_skipped_sections_gate_exempt(self):
         report = self._report()
-        for section in ("interleave", "stalls", "starvation", "crossover"):
+        for section in ("interleave", "stalls", "starvation"):
             report[section] = {"performed": False}
         report["value"] = None
         assert bench.validate_convoy(report) == []
@@ -2293,17 +2268,16 @@ class TestConvoyArtifactSchema:
     def test_non_dict_sections_are_violations(self):
         report = self._report()
         report["interleave"] = "done"
-        report["crossover"] = 3
+        report["starvation"] = 3
         problems = "\n".join(bench.validate_convoy(report))
         assert "interleave section is not an object" in problems
-        assert "crossover section is not an object" in problems
+        assert "starvation section is not an object" in problems
 
     def test_build_report_matches_schema(self):
         base = self._report()
         res = {
             k: base[k]
-            for k in ("interleave", "stalls", "starvation", "crossover",
-                      "wall_s")
+            for k in ("interleave", "stalls", "starvation", "wall_s")
         }
         report = bench.build_convoy_report(res)
         assert bench.validate_convoy(report) == []
@@ -2340,4 +2314,3 @@ class TestConvoyArtifactSchema:
         assert bench.validate_convoy(report) == []
         assert report["interleave"]["outputs_match"] is True
         assert report["starvation"]["bounded"] is True
-        assert report["crossover"]["small_batch_ok"] is True

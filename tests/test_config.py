@@ -121,3 +121,51 @@ local_addr: "a"
     def test_parse_addr(self):
         assert parse_addr("localhost:50000") == ("localhost", 50000)
         assert parse_addr("10.0.0.1:99") == ("10.0.0.1", 99)
+
+
+class TestRemovedDecodeOptions:
+    """One decode step is one launch: the two options that selected
+    another (multi-step launches, the paged/dense crossover) are gone,
+    and a command line or a YAML that still names one fails on it."""
+
+    @pytest.mark.parametrize(
+        "flag", ["--decode-steps-per-launch", "--paged-min-batch"]
+    )
+    def test_serve_rejects_the_flag(self, flag, capsys):
+        from radixmesh_tpu import launch
+
+        with pytest.raises(SystemExit) as e:
+            launch.main(["serve", "--model", "llama3-tiny", flag, "2"])
+        assert e.value.code == 2
+        assert f"unrecognized arguments: {flag}" in capsys.readouterr().err
+
+    @pytest.mark.parametrize(
+        "key", ["decode_steps_per_launch", "paged_min_batch"]
+    )
+    def test_yaml_model_key_refused_by_name(self, key, tmp_path):
+        p = tmp_path / "old.yaml"
+        p.write_text(
+            f"""
+prefill_nodes: ["localhost:50000"]
+local_addr: "localhost:50000"
+protocol: inproc
+model:
+  preset: llama3-tiny
+  max_batch: 4
+  {key}: 8
+"""
+        )
+        with pytest.raises(ValueError, match=f"unknown model keys.*{key}"):
+            load_config(str(p))
+
+    def test_every_model_key_launch_reads_is_known(self):
+        import inspect
+        import re
+
+        from radixmesh_tpu import launch
+        from radixmesh_tpu.config import MODEL_KEYS
+
+        read = set(re.findall(
+            r"model(?: or \{\}\))?\.get\(\s*\"(\w+)\"", inspect.getsource(launch)
+        ))
+        assert len(read) > 15 and read <= MODEL_KEYS, read - MODEL_KEYS

@@ -16,13 +16,7 @@ import pytest
 from radixmesh_tpu.engine import Engine, RequestState, SamplingParams
 from radixmesh_tpu.models.llama import ModelConfig, init_params
 from radixmesh_tpu.obs.token_timeline import STALL_CAUSES
-from radixmesh_tpu.ops.attention import (
-    batch_bucket,
-    last_dispatch,
-    paged_attention_pool,
-    paged_attention_pool_bucketed,
-    select_paged,
-)
+from radixmesh_tpu.ops.attention import batch_bucket
 
 pytestmark = pytest.mark.quick
 
@@ -308,34 +302,8 @@ class TestPagedDispatch:
         ]
         assert batch_bucket(3, floor=8) == 8
 
-    def test_select_paged_records_decision(self):
-        # CPU backend: the kernel is unavailable, so dense always wins —
-        # and the decision is recorded for /debug/state either way.
-        assert select_paged(2, 128, min_batch=8, max_len=64) is False
-        d = last_dispatch()
-        assert d == {"path": "dense", "batch": 2, "bucket": 2, "max_len": 64}
-
-    def test_bucketed_matches_direct_off_bucket(self):
-        # B=3 pads to the 4-bucket; the padded rows must not perturb the
-        # real rows' output.
-        B, Hkv, D, page, per = 3, 2, 16, 4, 8
-        key = jax.random.PRNGKey(0)
-        k1, k2 = jax.random.split(key)
-        kv = jax.random.normal(k1, (2, 1, Hkv, B * per, page, D), jnp.float32)
-        q = jax.random.normal(k2, (B, Hkv, D), jnp.float32)
-        pt = jnp.arange(B * per, dtype=jnp.int32).reshape(B, per)
-        lens = jnp.asarray([32, 17, 5], jnp.int32)
-        direct = paged_attention_pool(q, kv, pt, lens, 0, use_kernel=False)
-        bucketed = paged_attention_pool_bucketed(
-            q, kv, pt, lens, 0, use_kernel=False
-        )
-        assert bucketed.shape == direct.shape
-        np.testing.assert_allclose(
-            np.asarray(bucketed), np.asarray(direct), rtol=1e-5, atol=1e-5
-        )
-
     def test_engine_exposes_dispatch_and_wave_snapshot(self, model):
-        # The fields /debug/state renders: the crossover's last decision
+        # The fields /debug/state renders: the last plain decode launch
         # and the wave-mix counters.
         cfg, _ = model
         eng = make_engine(model, prefill_inline_budget=8)

@@ -29,7 +29,6 @@ def test_request_storm_drains_and_balances(model, seed):
         num_slots=128,  # tight: forces eviction + preemption under load
         max_batch=3,
         spec_decode_tokens=3 if seed % 2 else 0,
-        decode_steps_per_launch=2 if seed == 21 else 1,
         kv_quant="int8" if seed % 3 == 2 else None,
     )
     live: list = []

@@ -1,8 +1,8 @@
 """The program's host spans (``rm.*`` through ``obs.tracing.annotate`` =
 ``jax.profiler.TraceAnnotation``) and its two always-on counters.
 
-One tiny model served through ``ServingFrontend`` with a profile
-directory: a ``/debug/profile`` capture taken during a few requests holds
+A tiny model (each of two kinds) served through ``ServingFrontend`` with
+a profile directory: a ``/debug/profile`` capture taken during a few requests holds
 every span name, nested as ``Engine.step`` documents them, on the engine
 thread's line; ``rm.http.emit`` on the stream handlers' lines; nothing of
 the python tracer unless ``?python=1``. Without a capture the same
@@ -22,6 +22,7 @@ import pytest
 
 from radixmesh_tpu.engine.engine import Engine
 from radixmesh_tpu.engine.request import SamplingParams
+from radixmesh_tpu.models import get_config
 from radixmesh_tpu.models.llama import ModelConfig, init_params
 from radixmesh_tpu.server.http_frontend import ServingFrontend
 from radixmesh_tpu.slo.control import SLOConfig
@@ -77,14 +78,15 @@ def _lines(logdir):
     return out
 
 
-@pytest.fixture(scope="module")
-def served(tmp_path_factory):
-    """A served tiny model; the traffic of one round (a lone streamed
-    request: dense prefill; three requests put in the queue together: one
-    batched prefill) is run once to compile, then under each capture."""
-    cfg = ModelConfig.tiny()
+@pytest.fixture(scope="module", params=["llama3-tiny", "mellum2-tiny"])
+def served(request, tmp_path_factory):
+    """A served tiny model (a dense one; one with experts and window
+    layers); the traffic of one round (a lone streamed request: dense
+    prefill; three requests put in the queue together: one batched
+    prefill) is run once to compile, then under each capture."""
+    cfg = get_config(request.param)
     eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0)), num_slots=1024,
-                 page_size=4, max_batch=4, name="host-spans")
+                 page_size=4, max_batch=4, name=f"host-spans-{request.param}")
     profile_dir = str(tmp_path_factory.mktemp("profile"))
     f = ServingFrontend(eng, port=0, profile_dir=profile_dir, slo=SLOConfig())
     base = f"http://127.0.0.1:{f.port}"
@@ -193,7 +195,7 @@ def test_capture_holds_every_span_nested_as_documented(served):
     phases = sorted((s for s in spans if s[0].startswith("rm.decode.")
                      and step[1] <= s[1] and s[2] <= step[2]), key=lambda s: s[1])
     assert [s[0].rsplit(".", 1)[1] for s in phases] == [
-        "build", "sample", "dispatch", "emit", "sync", "consume"]  # the fused CPU launch
+        "build", "dispatch", "sample", "emit", "sync", "consume"]  # as on the chip
     assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
     # the stream handler's spans are on another line
     emit_lines = [evs for evs in lines if any(n == "rm.http.emit" for n, _, _ in evs)]

@@ -113,14 +113,14 @@ class TestHotPathSyncLint:
         assert "TokenTimeline.note_token" in hits
         assert "GoodputLedger.note_token" in hits
 
-    def test_wave_scheduler_and_paged_dispatch_ride_the_hot_path_clean(self):
-        """PR 19: the wave scheduler's ``plan``/``note`` run once per
-        compute wave and the paged/dense crossover once per decode
-        launch — both INSIDE the serving loop. Zero blocking findings
-        in the new policy module, and the call graph must actually see
-        both seams from the engine entry points (a wave scheduler the
-        reachability proof can't see would make the starvation bound
-        unauditable)."""
+    def test_wave_scheduler_and_decode_step_ride_the_hot_path_clean(self):
+        """The wave scheduler's ``plan``/``note`` run once per compute
+        wave and ``_decode_once`` (with the dispatch record's
+        ``batch_bucket``) once per decode step — both INSIDE the serving
+        loop. Zero blocking findings in the policy module, and the call
+        graph must actually see both seams from the engine entry points
+        (a wave scheduler the reachability proof can't see would make the
+        starvation bound unauditable)."""
         assert not _sync_findings("engine/waves.py")
         from radixmesh_tpu.analysis.callgraph import get_callgraph
         from radixmesh_tpu.analysis.hot_path import DEFAULT_ENTRY_POINTS
@@ -130,6 +130,6 @@ class TestHotPathSyncLint:
         names = {fn[1] for fn in reachable}
         assert "WaveScheduler.plan" in names
         assert "WaveScheduler.note" in names
-        assert {n for n in names if "select_paged" in n}, (
-            "the paged/dense crossover is not on the serving call graph"
+        assert "Engine._decode_once" in names and "batch_bucket" in names, (
+            "the one decode step is not on the serving call graph"
         )

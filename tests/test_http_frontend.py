@@ -673,6 +673,19 @@ class TestDebugEndpoints:
         }
         assert state["engine"]["step_failures"] == 0
 
+    def test_debug_state_names_the_decode_launch(self, frontend):
+        """``decode_dispatch``: what chip_smoke.py reads to prove the
+        kernel served. On a CPU the step ran ``decode_step``'s jnp
+        attention ("dense"); the sizes are the last plain step's."""
+        base = f"http://127.0.0.1:{frontend.port}"
+        prompt = list(range(40, 51))
+        _post(base + "/generate", {"input_ids": prompt, "max_tokens": 4})
+        d = json.loads(_get(base + "/debug/state")[1])["decode_dispatch"]
+        assert d == {
+            "path": "dense", "batch": 1, "bucket": 1,
+            "max_len": len(prompt) + 2,  # the fourth token's step
+        }
+
     def test_debug_requests_table(self, frontend):
         _post(
             f"http://127.0.0.1:{frontend.port}/generate",

@@ -209,14 +209,12 @@ class TestQuantEngine:
         )[0]
         assert len(out) == 5
 
-    def test_multi_step_and_spec_paths_quant(self, model):
+    def test_spec_path_quant(self, model):
         cfg, params = model
         rng = np.random.default_rng(8)
         prompt = rng.integers(1, cfg.vocab_size, 10).tolist()
         sp = SamplingParams(temperature=0.0, max_new_tokens=9)
         ref = quant_engine(model).generate([prompt], sp)[0]
-        multi = quant_engine(model, decode_steps_per_launch=3)
-        assert multi.generate([prompt], sp)[0] == ref
         spec = quant_engine(model, spec_decode_tokens=3)
         assert spec.generate([prompt], sp)[0] == ref
 

@@ -332,7 +332,6 @@ REFUSED = {
     "kv_quant": (dict(kv_quant="int8"), "int8 KV"),
     "tp": (lambda: dict(device_mesh=_mesh(tp=2)), "device mesh"),
     "sp": (lambda: dict(device_mesh=_mesh(sp=2)), "device mesh"),
-    "multi_step": (dict(decode_steps_per_launch=4), "multi-step decode"),
     "speculative": (dict(spec_decode_tokens=3), "speculative decode"),
     "inline_waves": (dict(prefill_inline_budget=64), "inline prefill"),
     "host_tier": (dict(host_cache_slots=64), "host KV tier"),
@@ -348,12 +347,22 @@ def test_engine_refuses_by_name(model, what):
         make_engine(model, **(kw() if callable(kw) else kw))
 
 
+def test_refusal_names_what_was_asked_and_no_launch_count(model):
+    """Every model decodes one step a launch now: the refusal says what
+    the model serves with and nothing about multi-step decode."""
+    with pytest.raises(UnsupportedForModel) as e:
+        make_engine(model, kv_quant="int8", spec_decode_tokens=2)
+    msg = str(e.value)
+    assert "an int8 KV pool" in msg and "speculative decode" in msg
+    assert msg.endswith("one chip, bf16 weights and KV (ROADMAP D6 / R4)")
+    assert "multi-step" not in msg and "a launch" not in msg
+
+
 @pytest.mark.parametrize("what", ["pp", "disagg_decode", "disagg_prefill",
-                                  "init_int8", "hf_checkpoint", "decode_multi",
-                                  "sp_prefill"])
+                                  "init_int8", "hf_checkpoint", "sp_prefill"])
 def test_other_entries_refuse_by_name(model, what):
     from radixmesh_tpu.engine.disagg import DecodeWorker, PrefillWorker
-    from radixmesh_tpu.models.llama import decode_multi, prefill_forward_sp
+    from radixmesh_tpu.models.llama import prefill_forward_sp
 
     cfg, params = model
     with pytest.raises(UnsupportedForModel) as e:
@@ -372,12 +381,6 @@ def test_other_entries_refuse_by_name(model, what):
             init_params(cfg, jax.random.PRNGKey(0), weight_quant="int8")
         elif what == "hf_checkpoint":
             convert_hf_state_dict(cfg, {})
-        elif what == "decode_multi":
-            z = jnp.zeros((4,), jnp.int32)
-            decode_multi(params, cfg, z, jnp.zeros(
-                (2, cfg.n_layers, cfg.n_kv_heads, 64, cfg.head_dim)),
-                jnp.zeros((4, 4), jnp.int32), z + 1, jax.random.PRNGKey(0),
-                jnp.zeros((4,)), jnp.ones((4,)), page_size=PAGE, k_steps=2)
         else:
             prefill_forward_sp(params, cfg, jnp.zeros((1, 8), jnp.int32),
                                jnp.arange(8)[None], _mesh(sp=2))

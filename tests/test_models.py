@@ -256,111 +256,3 @@ class TestHFConversion:
                 assert key in flat_a, f"no logical axes for {key}"
                 assert len(flat_a[key]) == leaf.ndim, f"rank mismatch for {key}"
 
-
-class TestDecodeMultiCompact:
-    """``decode_multi_compact`` (the kernel-less-backend decode path:
-    one pool gather + one scatter-back per launch instead of k·L
-    pool-sized scatter copies) must be TOKEN-EXACT with ``decode_multi``
-    and leave the full pool identical on every real slot."""
-
-    def _setup(self, quant=False):
-        from radixmesh_tpu.models.llama import ModelConfig, init_params
-
-        cfg = ModelConfig.tiny().replace(dtype=jnp.float32)
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        B, ps, maxp, k = 4, 4, 8, 3
-        # A LARGE pool (many more pages than the working set) so the
-        # compact gather actually exercises the indirection.
-        num_slots = 512 * ps
-        rng = np.random.default_rng(23)
-        if quant:
-            pool = rng.integers(
-                -127, 128,
-                (2, cfg.n_layers, cfg.n_kv_heads, num_slots, cfg.head_dim),
-            ).astype(np.int8)
-            scale = (np.abs(rng.normal(
-                size=(2, cfg.n_layers, cfg.n_kv_heads, num_slots)
-            )) * 0.01).astype(np.float32)
-        else:
-            pool = np.asarray(rng.normal(
-                size=(2, cfg.n_layers, cfg.n_kv_heads, num_slots,
-                      cfg.head_dim)
-            ), np.float32)
-            scale = None
-        # Scattered, non-contiguous pages per row (the radix allocator's
-        # steady state) + a scratch page.
-        all_pages = rng.permutation(512)[: B * maxp + 1].astype(np.int32)
-        pt = all_pages[: B * maxp].reshape(B, maxp)
-        scratch_page = int(all_pages[-1])
-        lengths = np.asarray([3, 9, 14, 6], np.int32)
-        tokens = rng.integers(1, cfg.vocab_size, B).astype(np.int32)
-        return (cfg, params, pool, scale, pt, scratch_page, lengths,
-                tokens, ps, k)
-
-    @pytest.mark.parametrize("quant", [False, True])
-    def test_matches_decode_multi(self, quant):
-        from radixmesh_tpu.models.llama import (
-            decode_multi,
-            decode_multi_compact,
-        )
-
-        (cfg, params, pool, scale, pt, scratch_page, lengths, tokens,
-         ps, k) = self._setup(quant)
-        B, maxp = pt.shape
-        zeros = jnp.zeros((B,), jnp.float32)
-        ones = jnp.ones((B,), jnp.float32)
-        topk0 = jnp.zeros((B,), jnp.int32)
-        key = jax.random.PRNGKey(31)
-        kw = dict(page_size=ps, k_steps=k, top_ks=topk0)
-        if quant:
-            res_full = decode_multi(
-                params, cfg, jnp.asarray(tokens), jnp.asarray(pool),
-                jnp.asarray(pt), jnp.asarray(lengths), key, zeros, ones,
-                kv_scale=jnp.asarray(scale), **kw,
-            )
-        else:
-            res_full = decode_multi(
-                params, cfg, jnp.asarray(tokens), jnp.asarray(pool),
-                jnp.asarray(pt), jnp.asarray(lengths), key, zeros, ones,
-                **kw,
-            )
-
-        # Compact mapping exactly as the engine builds it.
-        uniq = np.unique(np.concatenate(
-            [pt.reshape(-1), [scratch_page]]
-        )).astype(np.int32)
-        n_c = 1 << (len(uniq) - 1).bit_length()
-        compact = np.full(n_c, scratch_page, dtype=np.int32)
-        compact[: len(uniq)] = uniq
-        pt_c = np.searchsorted(uniq, pt).astype(np.int32)
-        if quant:
-            res_c = decode_multi_compact(
-                params, cfg, jnp.asarray(tokens), jnp.asarray(pool),
-                jnp.asarray(compact), jnp.asarray(pt_c),
-                jnp.asarray(lengths), key, zeros, ones,
-                kv_scale=jnp.asarray(scale), **kw,
-            )
-        else:
-            res_c = decode_multi_compact(
-                params, cfg, jnp.asarray(tokens), jnp.asarray(pool),
-                jnp.asarray(compact), jnp.asarray(pt_c),
-                jnp.asarray(lengths), key, zeros, ones, **kw,
-            )
-        np.testing.assert_array_equal(
-            np.asarray(res_c[0]), np.asarray(res_full[0])
-        )
-        # Full pool identical everywhere EXCEPT the scratch page (the
-        # compact path's padding may rewrite it; contents are dead).
-        live = np.ones(np.asarray(res_full[1]).shape[3], bool)
-        live[scratch_page * ps : (scratch_page + 1) * ps] = False
-        np.testing.assert_allclose(
-            np.asarray(res_c[1])[:, :, :, live],
-            np.asarray(res_full[1])[:, :, :, live],
-            rtol=1e-6, atol=1e-6,
-        )
-        if quant:
-            np.testing.assert_allclose(
-                np.asarray(res_c[2])[:, :, :, live],
-                np.asarray(res_full[2])[:, :, :, live],
-                rtol=1e-6, atol=1e-6,
-            )

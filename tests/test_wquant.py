@@ -142,7 +142,6 @@ class TestEngineWeightQuant:
         pp_eng = Engine(
             cfg, params, num_slots=512, page_size=4, max_batch=2,
             weight_quant="int8", device_mesh=mesh,
-            decode_steps_per_launch=3,
         )
         got = pp_eng.generate(prompts, sampling)
         assert got == want
