@@ -107,6 +107,12 @@ _ADMISSION_WAIT_LE_S = tuple(ms / 1e3 for ms in _ADMISSION_WAIT_LE_MS)
 _UNDER_DECODE = "flushes_under_decode"
 _UNDER_PREFILL = "flushes_under_prefill"
 _EXPOSED = "flushes_exposed"
+# Why a decode step's advance ran with the device waiting (keys of
+# ``Engine.handover["settled_for"]``): a row of the step finished; a
+# request was about to be admitted, cancelled or preempted; the launch, or
+# what follows it, is not a plain decode launch of the same rows; the pool
+# had no page for the launch built ahead.
+_SETTLED_FOR = ("finish", "admit", "cancel", "preempt", "launch_kind", "no_pages")
 
 
 class _TreeSpan:
@@ -147,6 +153,27 @@ class _InlineJob:
     token_slots: np.ndarray  # slot per prompt position (prefix + own)
     pos: int  # next un-prefilled prompt offset (starts at reuse)
     total: int  # len(prompt)
+
+
+@dataclass
+class _DecodeLaunch:
+    """The inputs of one decode launch (``Engine._build_launch``), kept
+    with what they were built for: ``sig`` = (row, rid, position the
+    row's token is written at) of every live row."""
+
+    active: list[tuple[int, Request]]
+    sig: list[tuple[int, int, int]]
+    idx: np.ndarray  # the live rows, as an index
+    slots: np.ndarray  # [max_batch] slot each row's K/V lands in
+    max_len: int  # longest context before the launch
+    window: tuple[int, int]  # attn_window increments (read, skipped)
+    slots_d: jax.Array
+    pt_d: jax.Array
+    lengths_d: jax.Array
+    positions_d: jax.Array | None  # the C=1 chunk launch's (pp, seeded)
+    # The ids to feed, where the step before left them on the device for
+    # exactly these rows; None: upload ``Engine._tokens`` at the launch.
+    tokens_d: jax.Array | None = None
 
 
 @dataclass
@@ -356,6 +383,24 @@ class Engine:
             _UNDER_PREFILL: 0,
             _EXPOSED: 0,
         }
+        # handover: how the hand-over between two decode launches went
+        # (_decode_once). launches_prebuilt / _rebuilt: the launch's inputs
+        # were built behind the launch before it / in the gap before this
+        # one. advances_deferred / _settled: a step's per-row advance ran
+        # behind the next launch / with the device waiting, the latter by
+        # what forced it (keys of _settle's ``why``).
+        self.handover = {
+            "launches_prebuilt": 0,
+            "launches_rebuilt": 0,
+            "advances_deferred": 0,
+            "advances_settled": 0,
+            "settled_for": dict.fromkeys(_SETTLED_FOR, 0),
+        }
+        # The last step's tokens while their advance waits for the next
+        # launch (_consume's arguments), and that launch's inputs, built
+        # ahead (_build_launch's record); None: nothing pending / built.
+        self._pending: tuple | None = None
+        self._prebuilt: _DecodeLaunch | None = None
         # Queued for the next flush. Per token, by _advance_token:
         # (request, step stamp, counted as output). Per decode launch:
         # (start, seconds, latency samples of seconds / samples, the step
@@ -865,6 +910,7 @@ class Engine:
                 return True
         for req in self._rows:
             if req is not None and req.rid == rid:
+                self._settle("cancel")  # its last token is part of its output
                 req.cancelled = True
                 self._flush_emit(_EXPOSED, only=req)
                 req.state = RequestState.FINISHED
@@ -925,6 +971,7 @@ class Engine:
         parked, mid-prefill (shutdown sweep; with ``error``, the sweep
         after a step raised: each request carries the message to its
         client). Returns the number cancelled."""
+        self._settle("cancel")
         self._flush_emit(_EXPOSED)  # no step may follow (shutdown, a failed one)
         reqs = (
             list(self.waiting)
@@ -955,6 +1002,7 @@ class Engine:
         Mid-inline-prefill requests count too: they have not produced a
         token either (only partial KV, discarded by cancel), so bouncing
         them loses at most one chunk of compute."""
+        self._settle()
         victims = (
             list(self.waiting)
             + [r for r, _ in self._restoring]
@@ -977,6 +1025,7 @@ class Engine:
         without a host tier. Run AFTER in-flight decodes finish: evict
         only touches unlocked entries, so flushing early would silently
         skip everything a running request still pins."""
+        self._settle()
         tree = self.tree
         if getattr(tree, "host", None) is None:
             return 0
@@ -999,6 +1048,7 @@ class Engine:
         ``(spills submitted, all committed)``; (0, True) without a
         tier. Run after :meth:`drain_flush_hot` so the device flush has
         landed in the arena first."""
+        self._settle()
         tree = self.tree
         plane = self.kv_transfer
         if self._kv_tier is None or plane is None:
@@ -1052,11 +1102,18 @@ class Engine:
         of convoying them.
 
         A decode step's tokens are in ``output_tokens`` when the step
-        returns, but their stream wake-ups, timeline entries and output
-        counter (:meth:`_flush_emit`) run in the NEXT step, once its
-        launch is on the device — or at the end of this one when it
-        leaves no row to decode. First tokens and finishes wake their
-        waiters at once.
+        returns or, between two plain decode launches of the same rows,
+        once the NEXT launch is on the device: such a step leaves its
+        per-row advance pending (``_decode_once``), and the next step
+        sends its launch — built behind this one — before it advances
+        this step's rows. A step in which a request finishes, and every
+        launch that is not the plain one, advances its rows before it
+        returns; :meth:`_settle` makes it so at any time, and everything
+        that reads or changes the rows calls it first. The tokens' stream
+        wake-ups, timeline entries and output counter
+        (:meth:`_flush_emit`) run in the next step too, once its launch is
+        on the device — or at the end of this one when it leaves no row
+        to decode. First tokens and finishes wake their waiters at once.
 
         Host spans (``obs.tracing.annotate``; inert without a profiler
         capture): ``rm.engine.step`` ⊃ ``rm.admit`` ⊃ {``rm.tree.match``,
@@ -1064,7 +1121,9 @@ class Engine:
         ``rm.first_token.sync``}, then ``rm.decode.build`` / ``.dispatch``
         / ``.sample`` / ``.emit`` / ``.sync`` / ``.consume`` (⊃
         ``rm.tree.publish``) — the same names in every decode launch
-        path, so a reader of a capture never needs to know which ran."""
+        path, so a reader of a capture never needs to know which ran. A
+        fast step reads ``dispatch, sample, consume`` (the step
+        before's), ``build`` (the next launch's), ``emit, sync``."""
         with annotate("rm.engine.step"):
             with annotate("rm.admit"):
                 self._admit()
@@ -1086,11 +1145,13 @@ class Engine:
                 self.kv_transfer.wait_progress()
             if not any(r is not None for r in self._rows):
                 # No decode launch will follow to hide the emission under.
+                self._settle()
                 self._flush_emit(_EXPOSED)
 
     def has_work(self) -> bool:
         return (
             bool(self.waiting)
+            or self._pending is not None
             or bool(self._inline)
             or bool(self._restoring)
             or any(r is not None for r in self._rows)
@@ -1107,6 +1168,7 @@ class Engine:
         inline chunks into the decode launch itself; prefill/boost
         plans advance the backlog alone (and count against the
         starvation bound when decode rows are waiting)."""
+        self._settle()
         decode_rows = sum(1 for r in self._rows if r is not None)
         plan = self.waves.plan(
             decode_rows, [j.total - j.pos for j in self._inline]
@@ -1210,6 +1272,7 @@ class Engine:
             if not self.has_work():
                 break
             self.step()
+        self._settle()
         unfinished = [r.rid for r in reqs if r.state is not RequestState.FINISHED]
         if unfinished:
             # A real error, not an assert (VERDICT round-1 weak #5):
@@ -1312,6 +1375,9 @@ class Engine:
                 row = self._free_row()
                 if row < 0:
                     break
+                # A row to fill: whatever is admitted sees the rows, the
+                # tree and the pool as every step before it left them.
+                self._settle("admit")
                 req = self.waiting[idx]
                 with self._span_match:
                     # One tree walk serves both the defer check and acquisition
@@ -2287,6 +2353,8 @@ class Engine:
 
     def _decode_once(self, inline_allot: list[int] | None = None) -> None:
         g = self.spec_decode_tokens
+        if g > 0 or inline_allot is not None:
+            self._settle()  # drafts and waves read the rows' own history
         spec = g > 0 and self._spec_ok(g)
         if spec or inline_allot is not None:
             # Draft BEFORE committing to the wide verify launch: when no
@@ -2314,73 +2382,44 @@ class Engine:
                 )
                 return
         seeded = self._seeded_launch(self._rows)
+        active = self._live_rows()
+        plain = self._plain_launch(active, seeded)
+        launch = self._take_prebuilt(active) if plain else None
+        if launch is not None:
+            self.handover["launches_prebuilt"] += 1
+        else:
+            # Rows joined or left since the build ahead, or the launch is
+            # of another kind: built in the gap, on rows that are settled.
+            self._settle("admit" if plain else "launch_kind")
+            with annotate("rm.decode.build"):
+                # A pp or seeded step is a C=1 chunk (below): blockwise
+                # attention needs a KV-block-multiple table width, which
+                # the bucket keeps (floor = block).
+                launch = self._build_launch(
+                    0, preempt=True, chunked=self._pp or seeded
+                )
+            if launch is None:
+                return
+            self.handover["launches_rebuilt"] += 1
+            active = launch.active
         # What /debug/state shows of the launch: the attention decode_step
         # runs from this process (the Pallas kernel, or the jnp reference
         # where it does not lower) and the launch's size.
-        rows = [r for r in self._rows if r is not None]
         self._last_dispatch = {
             "path": self._decode_path,
-            "batch": len(rows),
-            "bucket": batch_bucket(len(rows)),
-            "max_len": max((r.kv_len for r in rows), default=0),
+            "batch": len(active),
+            "bucket": batch_bucket(len(active)),
+            "max_len": launch.max_len,
         }
-        with annotate("rm.decode.build"):
-            slots = np.full(
-                self.max_batch, self._scratch_slot, dtype=np.int32
-            )
-            lengths = np.ones(self.max_batch, dtype=np.int32)
-            preempted: list[Request] = []
-            for row, req in enumerate(self._rows):
-                if req is None:
-                    continue
-                page_idx, offset = divmod(req.kv_len, self.page_size)
-                if offset == 0:  # crossing into a fresh page
-                    new = self._alloc_pages(1, cause="preempt")
-                    if new is None:
-                        preempted.append(req)
-                        continue
-                    req.own_slots = np.concatenate([req.own_slots, new])
-                    self._page_table[row, page_idx] = new[0] // self.page_size
-                    slot = int(new[0])
-                else:
-                    slot = int(
-                        self._page_table[row, page_idx] * self.page_size
-                        + offset
-                    )
-                slots[row] = slot
-                lengths[row] = req.kv_len + 1
-            for req in preempted:
-                self._preempt(req)
-            if self._sliding_layers:
-                ctx = lengths[[r is not None for r in self._rows]]
-                read = np.minimum(ctx, self.cfg.sliding_window)
-                aw = self.attn_window
-                aw["window_tokens_read"] += int(read.sum()) * self._sliding_layers
-                aw["window_tokens_skipped"] += (
-                    int((ctx - read).sum()) * self._sliding_layers
-                )
-
-            active = [
-                (row, r) for row, r in enumerate(self._rows) if r is not None
-            ]
-            if not active:
-                return
-            step_t0 = time.monotonic()
-            # A pp or seeded step is a C=1 chunk (below): blockwise
-            # attention needs a KV-block-multiple table width, which the
-            # bucket keeps (floor = block).
-            chunked = self._pp or seeded
-            tokens_d = jnp.asarray(self._tokens)
-            slots_d = jnp.asarray(slots)
-            pt_d = jnp.asarray(
-                self._decode_pt_bucket(1, floor=_KV_BLOCK_PAGES)
-                if chunked
-                else self._decode_pt_bucket(1)
-            )
-            lengths_d = jnp.asarray(lengths)
-            positions_d = jnp.asarray(lengths - 1) if chunked else None
+        aw = self.attn_window  # once per launch, whenever it was built
+        aw["window_tokens_read"] += launch.window[0]
+        aw["window_tokens_skipped"] += launch.window[1]
+        step_t0 = time.monotonic()
         with annotate("rm.decode.dispatch"):
-            if chunked:
+            tokens_d = launch.tokens_d
+            if tokens_d is None:
+                tokens_d = jnp.asarray(self._tokens)
+            if launch.positions_d is not None:
                 # A decode step is a C=1 chunk through the layer pipeline
                 # (parallel/pp_serving.py) — same page-table attention,
                 # same pool scatter, stage weights never move. Seeded
@@ -2389,10 +2428,10 @@ class Engine:
                 # draw.
                 res = self._forward_chunk(
                     tokens_d[:, None],
-                    positions_d[:, None],
-                    slots_d[:, None],
-                    pt_d,
-                    lengths_d,
+                    launch.positions_d[:, None],
+                    launch.slots_d[:, None],
+                    launch.pt_d,
+                    launch.lengths_d,
                     _KV_BLOCK_PAGES,
                 )
                 logits = self._commit_pool_update(res)[:, 0]
@@ -2402,9 +2441,9 @@ class Engine:
                     self.cfg,
                     tokens_d,
                     self.pool.kv,
-                    slots_d,
-                    pt_d,
-                    lengths_d,
+                    launch.slots_d,
+                    launch.pt_d,
+                    launch.lengths_d,
                     self.page_size,
                     mesh=self.device_mesh,
                     kv_scale=self.pool.kv_scale,
@@ -2421,6 +2460,7 @@ class Engine:
             for row, req in asked:
                 req.logit_rows.append(logits_h[row])
                 self._keep_expert_ids(req, req.kv_len, row, 1)
+        nxt, by_length, stops = None, False, ()
         if seeded:
             # The canonical per-row (seed, position) draw syncs per row:
             # sample and sync are one span here.
@@ -2439,10 +2479,32 @@ class Engine:
                     )
                 else:
                     sampled_d = sample_tokens(logits, None, draws=False)
+            if plain:
+                # The device is busy with this launch: the step before's
+                # advance, then the next launch's inputs as if every row
+                # had advanced by one — unless a row is about to reach its
+                # length cap, which is known before the sync.
+                pend, self._pending = self._pending, None
+                if pend is not None:
+                    self.handover["advances_deferred"] += 1
+                    self._consume(*pend)
+                by_length = any(
+                    len(r.output_tokens) + 1 >= r.sampling.max_new_tokens
+                    or r.num_tokens + 1 >= self.max_seq_len
+                    for _, r in active
+                )
+                if not by_length:
+                    with annotate("rm.decode.build"):
+                        nxt = self._build_launch(1, preempt=False)
+                    stops = [
+                        (row, r.sampling.stop_token_ids)
+                        for row, r in active
+                        if r.sampling.stop_token_ids
+                    ]
+            # Wake-ups last: the handlers take the GIL from nothing.
             self._flush_emit(_UNDER_DECODE)
             with annotate("rm.decode.sync"):  # the wait for the device
                 sampled = np.asarray(sampled_d)
-        self.stats.decode_steps += 1
         # sample_tokens materialized on host above, so this spans the full
         # dispatch+device time of the step — the per-token latency (TPOT)
         # seen by every active request.
@@ -2459,11 +2521,156 @@ class Engine:
                 if r.trace is not None
             ],
         ))
+        self._prebuilt = nxt
+        step = (active, launch.slots, sampled, stamp)
+        why = self._settle_why(plain, by_length, nxt, stops, sampled)
+        if why is None:
+            # The hand-over: the ids the next launch feeds, nothing else —
+            # in self._tokens, and for that launch as they lie on the
+            # device (an idle row's id is read by nothing).
+            self._tokens[nxt.idx] = sampled[nxt.idx]
+            nxt.tokens_d = sampled_d
+            self._pending = step
+            return
+        self.handover["advances_settled"] += 1
+        self.handover["settled_for"][why] += 1
+        self._consume(*step)
+
+    def _plain_launch(self, active: list[tuple], seeded: bool) -> bool:
+        """Is this decode launch the plain one — ``decode_step`` and the
+        batched sampler, no row seeded (all-seeded launches draw per row
+        on the host), no row keeping logits, no speculation configured,
+        no pp mesh? (An inline wave never comes here.) Only such a launch
+        hands over fast; see :meth:`_settle_why`."""
+        return not (
+            seeded
+            or self._pp
+            or self.spec_decode_tokens
+            or any(r.sampling.return_logits for _, r in active)
+        )
+
+    @staticmethod
+    def _settle_why(plain, by_length, nxt, stops, sampled) -> str | None:
+        """THE rule for the hand-over between two decode launches, from
+        what the launch and its rows are (no knob): None — keep the
+        step's advance for behind the next launch — iff the launch is
+        plain (:meth:`_plain_launch`), the next launch's inputs are built
+        (``nxt``) and no row finishes: none reaches a length cap
+        (``by_length``, known before the sync) and none of the rows that
+        have stop ids (``stops``) sampled one. Else the key of
+        ``handover["settled_for"]`` to count the step under: it settles
+        now, in the order every step had before, so a finished row never
+        rides another launch."""
+        if not plain:
+            return "launch_kind"
+        if by_length:
+            return "finish"
+        if nxt is None:
+            return "no_pages"
+        if any(int(sampled[row]) in ids for row, ids in stops):
+            return "finish"
+        return None
+
+    def _build_launch(
+        self, ahead: int, preempt: bool, chunked: bool = False
+    ) -> _DecodeLaunch | None:
+        """The inputs of the decode launch that writes position ``kv_len +
+        ahead`` of every live row: 0 for the rows as they are, 1 behind a
+        launch that is still on the device, as if each row had advanced
+        by one. ``preempt`` is the ordinary build's: a row the pool has
+        no page for is preempted. A build ahead never preempts — it
+        returns None and the ordinary build decides once the rows are
+        settled; the pages it had taken by then stay with their requests
+        (:meth:`_ensure_page`), and a later build finds them in the table
+        instead of taking a second. None too when no row is left."""
+        ps = self.page_size
+        slots = np.full(self.max_batch, self._scratch_slot, dtype=np.int32)
+        lengths = np.ones(self.max_batch, dtype=np.int32)
+        starved: list[Request] = []
+        for row, req in enumerate(self._rows):
+            if req is None:
+                continue
+            page_idx, offset = divmod(req.kv_len + ahead, ps)
+            page = self._ensure_page(row, req, page_idx)
+            if page is None:  # crossing into a fresh page, and none to take
+                if not preempt:
+                    return None
+                starved.append(req)
+                continue
+            slots[row] = page * ps + offset
+            lengths[row] = req.kv_len + ahead + 1
+        for req in starved:
+            self._preempt(req)
+        active = self._live_rows()
+        if not active:
+            return None
+        idx = np.fromiter((row for row, _ in active), np.intp, len(active))
+        window = (0, 0)
+        if self._sliding_layers:
+            ctx = lengths[idx]
+            read = np.minimum(ctx, self.cfg.sliding_window)
+            window = (
+                int(read.sum()) * self._sliding_layers,
+                int((ctx - read).sum()) * self._sliding_layers,
+            )
+        return _DecodeLaunch(
+            active=active,
+            sig=[(row, r.rid, r.kv_len + ahead) for row, r in active],
+            idx=idx,
+            slots=slots,
+            max_len=int(lengths[idx].max()) - 1,
+            window=window,
+            slots_d=jnp.asarray(slots),
+            pt_d=jnp.asarray(
+                self._decode_pt_bucket(1 + ahead, floor=_KV_BLOCK_PAGES)
+                if chunked
+                else self._decode_pt_bucket(1 + ahead)
+            ),
+            lengths_d=jnp.asarray(lengths),
+            positions_d=jnp.asarray(lengths - 1) if chunked else None,
+        )
+
+    def _take_prebuilt(self, active: list[tuple]) -> _DecodeLaunch | None:
+        """The inputs built ahead, iff they were built for exactly these
+        rows at the positions their tokens go to now (one further while
+        the last step's advance is pending); they are used once."""
+        pre, self._prebuilt = self._prebuilt, None
+        if pre is None:
+            return None
+        ahead = 0 if self._pending is None else 1
+        now = [(row, r.rid, r.kv_len + ahead) for row, r in active]
+        return pre if pre.sig == now else None
+
+    def _consume(
+        self, active: list[tuple], slots: np.ndarray, sampled: np.ndarray,
+        stamp: float,
+    ) -> None:
+        """One decode step's per-row advance; the step is counted where
+        its tokens are, so ``generated_tokens`` over ``decode_steps``
+        reads whole steps at any instant."""
         with annotate("rm.decode.consume"):
+            self.stats.decode_steps += 1
             for row, req in active:
                 self._advance_token(
                     req, row, int(slots[row]), int(sampled[row]), stamp
                 )
+
+    def _settle(self, why: str = "launch_kind") -> None:
+        """Run the pending advance now (idempotent; nothing pending:
+        nothing done). Everything that reads or changes the rows other
+        than a plain decode launch of the same rows calls this first —
+        admission into a free row, ``cancel`` / ``cancel_all``, the
+        drains, ``_preempt``, a wave or a speculative launch,
+        ``generate()``'s end — so each sees the rows as they were when
+        every step advanced its own. ``why`` (a key of
+        ``handover["settled_for"]``) says what forced it; the default is
+        for whatever follows that is not such a launch."""
+        pend, self._pending = self._pending, None
+        if pend is None:
+            return
+        self.handover["advances_settled"] += 1
+        self.handover["settled_for"][why] += 1
+        self._consume(*pend)
 
     def _spec_ok(self, g: int) -> bool:
         """Speculative decoding is considered whenever rows are active and
@@ -2577,6 +2784,25 @@ class Engine:
                 return hist[j : j + gamma]
         return hist[:0]
 
+    def _live_rows(self) -> list[tuple[int, "Request"]]:
+        return [(row, r) for row, r in enumerate(self._rows) if r is not None]
+
+    def _ensure_page(self, row: int, req: Request, page_idx: int) -> int | None:
+        """The pool page behind entry ``page_idx`` of the row's table,
+        taken from the pool (evicting under pressure) where the row has
+        none yet; None when the pool has none to give. A page taken is the
+        request's at once (``own_slots``): ``_release`` returns it."""
+        page = int(self._page_table[row, page_idx])
+        if page != self._scratch_page:
+            return page  # already provisioned
+        new = self._alloc_pages(1, cause="preempt")
+        if new is None:
+            return None
+        req.own_slots = np.concatenate([req.own_slots, new])
+        page = int(new[0]) // self.page_size
+        self._page_table[row, page_idx] = page
+        return page
+
     def _provision_rows(
         self, extra: int, extras: dict[int, int] | None = None
     ) -> list[tuple[int, "Request"]]:
@@ -2593,17 +2819,12 @@ class Engine:
                 continue
             row_extra = extra if extras is None else extras.get(row, extra)
             for p_idx in range(req.kv_len // ps, (req.kv_len + row_extra) // ps + 1):
-                if self._page_table[row, p_idx] != self._scratch_page:
-                    continue  # page already provisioned
-                new = self._alloc_pages(1, cause="preempt")
-                if new is None:
+                if self._ensure_page(row, req, p_idx) is None:
                     preempted.append(req)
                     break
-                req.own_slots = np.concatenate([req.own_slots, new])
-                self._page_table[row, p_idx] = new[0] // ps
         for req in preempted:
             self._preempt(req)
-        return [(row, r) for row, r in enumerate(self._rows) if r is not None]
+        return self._live_rows()
 
     def _decode_spec_once(
         self,
@@ -2936,9 +3157,11 @@ class Engine:
     def _advance_token(
         self, req: Request, row: int, slot: int, token: int, stamp: float
     ) -> bool:
-        """The half of one decode iteration that the next launch, or a
-        finish, needs: the fed token's KV landed at ``slot``, ``token``
-        was sampled in the step stamped ``stamp``. Returns True when the
+        """One row's half of a decode step's advance (:meth:`_consume`):
+        the fed token's KV landed at ``slot``, ``token`` was sampled in
+        the step stamped ``stamp``. The next launch needs none of it but
+        the token id and whether the row finished, so between two plain
+        launches it runs behind that launch. Returns True when the
         request finished (stop token / length cap) and was released —
         shared by every decode path so the subtle stop/stats bookkeeping
         cannot drift between them. What only observers need (the output
@@ -3037,6 +3260,7 @@ class Engine:
         """Pool exhausted mid-decode even after eviction: publish what we
         have, free the row, and requeue from scratch (the generated tokens
         are discarded; the published KV makes the retry a long prefix hit)."""
+        self._settle("preempt")
         self.stats.preemptions += 1
         self._m_preempt.inc()
         self._pressure = True

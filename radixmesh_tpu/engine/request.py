@@ -152,9 +152,9 @@ class Request:
     # quantized by a poll interval and idle waiters don't spin. Notified
     # by note_progress() — at once for the first token; for a decode
     # token once the engine's next launch is on the device
-    # (Engine._flush_emit: at most one launch's build + dispatch after the
-    # token is in output_tokens, never across a device wait or an idle
-    # runner) — and, via __setattr__, at once on ANY transition to
+    # (Engine._flush_emit: behind the launch that follows the token's
+    # step, never across a device wait or an idle runner) — and, via
+    # __setattr__, at once on ANY transition to
     # FINISHED, so no finish site can strand a waiter.
     cond: threading.Condition = field(
         default_factory=threading.Condition, repr=False, compare=False
@@ -165,7 +165,8 @@ class Request:
         state advanced). Cheap: one uncontended lock round-trip. A waiter
         reads ``output_tokens``, which may already hold tokens newer than
         the one this call announces: decode tokens are appended when their
-        step ends and announced from ``Engine._flush_emit``."""
+        step's rows are advanced (``Engine._consume``) and announced from
+        ``Engine._flush_emit``."""
         with self.cond:
             self.cond.notify_all()
 

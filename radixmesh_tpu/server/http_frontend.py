@@ -730,6 +730,12 @@ class ServingFrontend:
                     # flush ran: behind a dispatched decode launch, behind
                     # a prefill, or with nothing on the device to hide it.
                     "emit": eng.emit,
+                    # The hand-over between two decode launches: launches
+                    # whose inputs were built behind the launch before
+                    # them / in the gap, steps whose per-row advance ran
+                    # behind the next launch / with the device waiting,
+                    # and what forced the latter.
+                    "handover": eng.handover,
                 },
                 "pool": {
                     "num_slots": eng.pool.num_slots,

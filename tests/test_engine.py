@@ -208,6 +208,7 @@ class TestCancel:
         req = eng.add_request(prompt, SamplingParams(max_new_tokens=64))
         for _ in range(6):  # prefill + a few decode steps
             eng.step()
+        eng._settle()  # the last step's token is part of what it produced
         produced = len(req.output_tokens)
         assert 0 < produced < 64
         assert eng.cancel(req.rid)
@@ -516,6 +517,7 @@ class TestOneDecodeLaunch:
         eng = make_engine(model, num_slots=6 * PAGE, max_batch=2, **kw)
         a, b = (eng.add_request(p, sp) for p in prompts)
         eng.step()  # admits both, prefills, and runs the first decode step
+        eng._settle()  # a step is counted where its tokens are
         assert eng.stats.preemptions == 1 and eng.stats.decode_steps == 1
         assert eng.pool.free_slots == 0  # a took the one page left
         assert b.state is RequestState.QUEUED and len(a.output_tokens) == 2
@@ -656,8 +658,14 @@ class TestDeferredEmission:
         d = eng.add_request(prompts[3], SamplingParams(max_new_tokens=12))
         while min(len(r.output_tokens) for r in (a, b, c, d)) < 3:
             eng.step()
-        # Mid-flight the last launch's tokens are queued, not yet emitted,
-        # yet already in output_tokens.
+        if path == "single":
+            # Mid-flight the last plain launch's advance waits for the
+            # next launch; nothing of it is queued or counted yet.
+            assert eng._pending is not None and not eng._emit_rows
+            assert eng.emit["tokens"] == eng.stats.generated_tokens
+            eng._settle()
+        # Once advanced the last launch's tokens are queued, not yet
+        # emitted, yet already in output_tokens.
         queued = [e[0].rid for e in eng._emit_rows]
         assert c.rid in queued and d.rid in queued
         assert eng.emit["tokens"] < eng.stats.generated_tokens
@@ -694,3 +702,247 @@ class TestDeferredEmission:
             assert eng.stats.spec_proposed > 0
         # Every launch's latency samples arrived too: one per decode step.
         assert eng._m_tpot.count >= eng.stats.decode_steps > 0
+
+
+class TestHandOver:
+    """Between two plain decode launches the engine does no more than
+    "sync, did a row finish?, launch": the next launch's inputs are built
+    behind the one on the device (``_build_launch(1, ...)``) and a step's
+    per-row advance runs behind the next launch (``_pending``), unless a
+    row finishes or anything but a plain launch of the same rows follows
+    (``Engine._settle``). Each case runs once as served and once settled
+    after every step — every step's advance in the step, as before — and
+    the two agree on tokens, finish state, stats, tree and pool; greedy
+    unseeded rows also agree with the float32 oracle, and
+    ``engine.handover`` counts what happened."""
+
+    # Each case: (engine keywords, script). A script drives ``eng`` with
+    # ``tick`` (one step) and returns {name: request}; ``served`` tells
+    # the run whose advances stay pending between steps.
+
+    @staticmethod
+    def _length(eng, tick, served, model, rng):
+        ps = [rng.integers(1, 500, n).tolist() for n in (5, 9, 13)]
+        reqs = {k: eng.add_request(p, SamplingParams(max_new_tokens=n))
+                for k, p, n in zip("abc", ps, (4, 6, 9))}
+        while eng.has_work():
+            tick()
+        return reqs, {
+            # d1 d4 d6 are built in the gap (first step; rows left), d3 d5
+            # d8 end a request: known before the sync, nothing built ahead
+            "launches_rebuilt": 3, "launches_prebuilt": 5,
+            "advances_deferred": 5, "settled_for": {"finish": 3},
+        }
+
+    @staticmethod
+    def _stop(eng, tick, served, model, rng):
+        p = rng.integers(1, 500, 10).tolist()
+        want = oracle_generate(*model, p, 12)
+        # a stop token made late, for the first time, by the step whose
+        # build ahead crosses into a fresh page for the stopping row
+        cut = next(i for i in range(3, 12)
+                   if want[i] not in want[:i] and (len(p) + i) % PAGE == 0)
+        reqs = {
+            "a": eng.add_request(p, SamplingParams(
+                max_new_tokens=12, stop_token_ids=(want[cut],))),
+            "b": eng.add_request(rng.integers(1, 500, 7).tolist(),
+                                 SamplingParams(max_new_tokens=12)),
+        }
+        a = reqs["a"]
+        while a.state is not RequestState.FINISHED:
+            if served and len(a.output_tokens) == cut - 1:
+                held = len(a.own_slots)  # before the step that stops it
+            tick()
+        if served:
+            # the build ahead had taken a page for the row that stopped:
+            # released with the rest
+            assert held > 0 and len(a.own_slots) == 0
+        assert a.output_tokens == want[:cut]
+        while eng.has_work():
+            tick()
+        return reqs, {"settled_for": {"finish": 2}}
+
+    @staticmethod
+    def _cancel(eng, tick, served, model, rng):
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(),
+                                   SamplingParams(max_new_tokens=12))
+                for k, n in zip("ab", (6, 11))}
+        for _ in range(4):
+            tick()
+        if served:
+            assert eng._pending is not None and eng._prebuilt is not None
+            assert len(reqs["b"].output_tokens) == 4  # the 5th is pending
+        assert eng.cancel(reqs["b"].rid)
+        assert len(reqs["b"].output_tokens) == 5 and reqs["b"].cancelled
+        while eng.has_work():
+            tick()
+        return reqs, {"settled_for": {"cancel": 1, "finish": 1},
+                      "launches_rebuilt": 2}  # first step; a row left
+
+    @staticmethod
+    def _preempt(eng, tick, served, model, rng):
+        # a: 7 tokens in two pages; the first step writes position 7 and
+        # the build behind it crosses into a third page
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(),
+                                   SamplingParams(max_new_tokens=8))
+                for k, n in zip("ab", (7, 5))}
+        a = reqs["a"]
+        free0 = eng.pool.free_slots
+        tick()
+        row = a.row
+        assert eng._page_table[row, 2] != eng._scratch_page
+        assert eng.pool.free_slots == free0 - 5 * PAGE  # 2 + 2 prompt pages, 1 ahead
+        eng._preempt(a)
+        # the page taken ahead went back with the request's others (what
+        # the tree holds of the prompt stays cached)
+        assert eng._page_table[row, 2] == eng._scratch_page
+        assert a.own_slots.size == 0 and a.state is RequestState.QUEUED
+        while eng.has_work():
+            tick()
+        return reqs, {"settled_for": {"preempt": 1, "finish": 2}}
+
+    @staticmethod
+    def _no_pages(eng, tick, served, model, rng):
+        # scratch + 2 x 2 prompt pages + ONE more: the build ahead of the
+        # first step takes it for a (position 8); the one behind the
+        # second step finds none for b (position 8), is dropped, and the
+        # ordinary build preempts b once the rows are settled
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(),
+                                   SamplingParams(max_new_tokens=6))
+                for k, n in zip("ab", (7, 6))}
+        tick()
+        assert eng.pool.free_slots == 0 and eng.stats.preemptions == 0
+        tick()
+        assert eng._prebuilt is None and eng._pending is None
+        tick()
+        assert eng.stats.preemptions == 1
+        assert reqs["b"].state is RequestState.QUEUED
+        while eng.has_work():
+            tick()
+        return reqs, {"settled_for": {"no_pages": 1, "finish": 2}}
+
+    @staticmethod
+    def _admission(eng, tick, served, model, rng):
+        sp = lambda n: SamplingParams(max_new_tokens=n)  # noqa: E731
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(), sp(m))
+                for k, n, m in (("a", 6, 4), ("b", 9, 20), ("c", 7, 4))}
+        assert eng.max_batch == 2
+        while reqs["c"].state is not RequestState.FINISHED:
+            tick()  # c took a's row when a finished
+        assert reqs["b"].state is RequestState.RUNNING
+        tick()
+        if served:
+            assert eng._pending is not None
+        # a free row and an advance pending: admission settles first
+        reqs["d"] = eng.add_request(rng.integers(1, 500, 8).tolist(), sp(5))
+        while eng.has_work():
+            tick()
+        return reqs, {"settled_for": {"admit": 1, "finish": 4}}
+
+    @staticmethod
+    def _seeded(eng, tick, served, model, rng):
+        draw = SamplingParams(max_new_tokens=10, temperature=0.9, seed=5)
+        reqs = {"s": eng.add_request(rng.integers(1, 500, 8).tolist(), draw)}
+        tick()
+        tick()  # all-seeded launches: per-row draws on the host, never fast
+        assert eng._pending is None and eng.handover["advances_deferred"] == 0
+        reqs["a"] = eng.add_request(rng.integers(1, 500, 6).tolist(),
+                                    SamplingParams(max_new_tokens=4))
+        while reqs["a"].state is not RequestState.FINISHED:
+            tick()  # a mixed launch is the plain one
+        if served:
+            assert eng.handover["advances_deferred"] == 2
+        while eng.has_work():
+            tick()
+        return reqs, {"advances_deferred": 2, "launches_prebuilt": 2,
+                      "settled_for": {"launch_kind": 6, "finish": 1}}
+
+    @staticmethod
+    def _return_logits(eng, tick, served, model, rng):
+        cfg, params = model
+        p = rng.integers(1, 500, 9).tolist()
+        reqs = {
+            "r": eng.add_request(p, SamplingParams(
+                max_new_tokens=5, return_logits=True)),
+            "a": eng.add_request(rng.integers(1, 500, 6).tolist(),
+                                 SamplingParams(max_new_tokens=9)),
+        }
+        while reqs["r"].state is not RequestState.FINISHED:
+            tick()
+        assert eng.handover["advances_deferred"] == 0  # r kept every launch's logits
+        _, logits = oracle_steps(cfg, params, p, 5)
+        np.testing.assert_allclose(
+            np.stack(reqs["r"].logit_rows), logits, rtol=2e-4, atol=2e-4)
+        while eng.has_work():
+            tick()
+        return reqs, {"advances_deferred": 3, "launches_prebuilt": 3,
+                      "settled_for": {"launch_kind": 4, "finish": 1}}
+
+    CASES = {
+        "finish_by_length": ({}, "_length"),
+        "finish_by_stop_id": ({}, "_stop"),
+        "cancel_with_an_advance_pending": ({}, "_cancel"),
+        "preempt_after_a_page_taken_ahead": ({}, "_preempt"),
+        "no_page_for_the_build_ahead": (
+            {"num_slots": 6 * PAGE, "max_batch": 2}, "_no_pages"),
+        "admission_into_a_freed_row": ({"max_batch": 2}, "_admission"),
+        "seeded_row_joined": ({}, "_seeded"),
+        "return_logits_row": ({}, "_return_logits"),
+    }
+
+    def _run(self, model, case, served):
+        kw, script = self.CASES[case]
+        eng = make_engine(model, **kw)
+        tree0 = (eng.tree.evictable_size_, eng.tree.protected_size_)
+        free0 = eng.pool.free_slots
+
+        def tick():
+            eng.step()
+            if not served:
+                eng._settle()
+
+        reqs, counts = getattr(self, script)(
+            eng, tick, served, model, prompts_rng())
+        assert eng._pending is None and not eng.has_work()
+        assert not eng._emit_rows and not eng._emit_steps
+        assert all(r is None for r in eng._rows)
+        assert (eng._page_table == eng._scratch_page).all()
+        assert eng.tree.protected_size_ == tree0[1] == 0
+        state = {
+            "tokens": {k: list(r.output_tokens) for k, r in reqs.items()},
+            "finished": {k: (r.state, r.cancelled) for k, r in reqs.items()},
+            "stats": {k: getattr(eng.stats, k) for k in (
+                "decode_steps", "generated_tokens", "finished", "prefills",
+                "preemptions", "prompt_tokens", "cached_tokens")},
+            "tree": (eng.tree.evictable_size_, eng.tree.protected_size_),
+            "free": eng.pool.free_slots,
+            "emitted": eng.emit["tokens"],
+        }
+        eng.tree.reset()
+        assert eng.pool.free_slots == free0  # back where it started
+        assert (eng.tree.evictable_size_, eng.tree.protected_size_) == tree0
+        return eng, reqs, state, counts
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_served_as_settled_and_counted(self, model, case):
+        eng, reqs, served, counts = self._run(model, case, served=True)
+        settled_eng, _, settled, _ = self._run(model, case, served=False)
+        assert served == settled
+        for k, r in reqs.items():
+            sp = r.sampling
+            if sp.seed is None and not sp.stop_token_ids and not r.cancelled:
+                assert r.output_tokens == oracle_generate(
+                    *model, r.prompt, sp.max_new_tokens), k
+        h, steps = eng.handover, eng.stats.decode_steps
+        assert h["launches_prebuilt"] + h["launches_rebuilt"] == steps
+        assert h["advances_deferred"] + h["advances_settled"] == steps
+        assert sum(h["settled_for"].values()) == h["advances_settled"]
+        assert h["advances_deferred"] > 0
+        for key, n in counts.items():
+            if key == "settled_for":
+                got = {k: v for k, v in h[key].items() if v}
+                assert got == n, (got, n)
+            else:
+                assert h[key] == n, (key, h[key], n)
+        # settled after every step, no advance ever ran behind a launch
+        assert settled_eng.handover["advances_deferred"] == 0

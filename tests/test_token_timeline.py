@@ -257,7 +257,8 @@ class TestEngineTokenPlane:
         req = eng.add_request(rng.integers(1, 64, 8).tolist(), sp)
         while len(req.output_tokens) < 3:
             eng.step()
-        assert eng.timeline.appends == base + 1 and eng._emit_rows  # one queued
+        eng._settle()  # the step just run: its token advanced and queued
+        assert eng.timeline.appends == base + 2 and eng._emit_rows  # one queued
         paused = time.monotonic()
         time.sleep(0.25)
         while eng.has_work():
