@@ -7,8 +7,13 @@ calls ``free()`` on (``radix_cache.py:104-107,188-199``). Here the pool is a
 first-class component:
 
 - One preallocated, donated ``jax.Array`` of shape
-  ``[2, layers, kv_heads, num_slots, head_dim]`` (K and V stacked,
-  head-major) lives in HBM for the model's whole life — no allocation
+  ``[planes, layers, heads, num_slots, width]`` lives in HBM for the
+  model's whole life. The row — what one token leaves in one layer — is
+  what the model says it is (``ModelConfig.kv_row``): two planes (K and V
+  stacked, head-major) of ``kv_heads`` rows of ``head_dim``, or ONE plane
+  of one latent row shared by every head (MLA), whose head is also the
+  values. Nothing below knows which: the allocator and the radix tree hold
+  slot indices, the scatters and gathers index the slot axis — no allocation
   inside the serving loop, static shapes for XLA, and per-layer pages view
   as ``[kv_heads, num_pages, page, head_dim]`` by pure reshape for the
   Pallas paged-attention kernel.
@@ -262,7 +267,13 @@ class PagedKVPool:
         dtype: Any = jnp.bfloat16,
         sharding: jax.sharding.Sharding | None = None,
         quant: str | None = None,
+        planes: int = 2,
     ):
+        if planes not in (1, 2):
+            raise ValueError("a pool row has 1 plane (latent) or 2 (K and V)")
+        if planes == 1 and quant is not None:
+            raise ValueError("a one-plane (latent) pool has no quantized form")
+        self.planes = planes
         self.num_slots = num_slots
         self.num_layers = num_layers
         self.num_kv_heads = num_kv_heads
@@ -288,7 +299,7 @@ class PagedKVPool:
         # Born sharded: a pool that only fits spread over the mesh must
         # never exist whole on device 0 first.
         self.kv = jnp.zeros(
-            (2, num_layers, num_kv_heads, num_slots, head_dim),
+            (planes, num_layers, num_kv_heads, num_slots, head_dim),
             dtype=dtype,
             device=sharding,
         )
@@ -340,10 +351,14 @@ class PagedKVPool:
 
     # ---- device ops ----
 
-    def write(self, slots: np.ndarray | jax.Array, k: jax.Array, v: jax.Array) -> None:
+    def write(
+        self, slots: np.ndarray | jax.Array, k: jax.Array,
+        v: jax.Array | None = None,
+    ) -> None:
         """Write per-layer K/V for ``n`` tokens at ``slots``.
 
-        ``k``/``v``: ``[L, n, kv_heads, head_dim]``. The pool array is
+        ``k``/``v``: ``[L, n, kv_heads, head_dim]`` (a one-plane pool takes
+        its rows as ``k`` and no ``v``). The pool array is
         donated through the scatter so HBM is updated in place. The token
         count is padded up to a power-of-two bucket (by repeating the last
         slot/value — an idempotent duplicate write) so ``jax.jit`` compiles
@@ -353,9 +368,12 @@ class PagedKVPool:
         n = len(slots)
         if n == 0:
             return
-        slots, (k, v) = _pad_to_bucket(slots, [jnp.asarray(k), jnp.asarray(v)], [1, 1])
+        rows = [k] if self.planes == 1 else [k, v]
+        slots, rows = _pad_to_bucket(
+            slots, [jnp.asarray(r) for r in rows], [1] * len(rows)
+        )
         # [L, n, H, D] → head-major [L, H, n, D].
-        new_kv = jnp.stack([k, v]).transpose(0, 1, 3, 2, 4)
+        new_kv = jnp.stack(rows).transpose(0, 1, 3, 2, 4)
         sl = jnp.asarray(slots, dtype=jnp.int32)
         if self.quant is not None:
             self.kv, self.kv_scale = _scatter_kv_quant(
@@ -368,7 +386,7 @@ class PagedKVPool:
         """(k_pages, v_pages), each ``[Hkv, num_pages, page, D]`` — a
         zero-copy view of this layer's pool, the kernel's input layout."""
         shape = (self.num_kv_heads, self.num_pages, self.page_size, self.head_dim)
-        return self.kv[0, layer].reshape(shape), self.kv[1, layer].reshape(shape)
+        return self.kv[0, layer].reshape(shape), self.kv[-1, layer].reshape(shape)
 
     def gather_raw(self, slots: np.ndarray | jax.Array):
         """``(kv [2, L, n, H, D] in POOL dtype, scales [2, L, n, H] | None)``
@@ -426,8 +444,7 @@ class PagedKVPool:
             if scales is not None:
                 self.write_raw(slots, kv, scales)
             else:
-                kv = jnp.asarray(kv)
-                self.write(slots, kv[0], kv[1])
+                self.write(slots, *jnp.asarray(kv))
             return
         if scales is not None and self.quant is None:
             raise ValueError("raw quantized blocks target quantized pools")

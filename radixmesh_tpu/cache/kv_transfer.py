@@ -64,11 +64,12 @@ _LANES = ("restore", "writeback", "handoff", "spill")
 
 
 def kv_token_bytes(pool) -> int:
-    """Wire/HBM bytes per token-slot of ``pool`` (K+V, all layers, plus
-    quant scales when present) — the bytes-counter unit for every lane."""
+    """Wire/HBM bytes per token-slot of ``pool`` (every plane of the row,
+    all layers, plus quant scales when present) — the bytes-counter unit
+    for every lane."""
     import jax.numpy as jnp
 
-    per = 2 * pool.num_layers * pool.num_kv_heads * pool.head_dim
+    per = pool.planes * pool.num_layers * pool.num_kv_heads * pool.head_dim
     n = per * jnp.dtype(pool.dtype).itemsize
     if pool.quant is not None:
         n += 2 * pool.num_layers * pool.num_kv_heads * 4  # f32 scales

@@ -167,6 +167,7 @@ class _DecodeLaunch:
     slots: np.ndarray  # [max_batch] slot each row's K/V lands in
     max_len: int  # longest context before the launch
     window: tuple[int, int]  # attn_window increments (read, skipped)
+    latent_tokens: int  # latent rows the launch reads (rows x layers)
     slots_d: jax.Array
     pt_d: jax.Array
     lengths_d: jax.Array
@@ -412,9 +413,14 @@ class Engine:
         # asked; None for a dense model. attn_window: context tokens that
         # decode launches did / did not have to read on window layers,
         # summed over rows and sliding layers (host arithmetic on the
-        # lengths, in rm.decode.build).
+        # lengths, in rm.decode.build). For a latent cache row the same
+        # group counts the rows read: by decode launches (context x layers,
+        # summed over rows) and by prefill launches of the cached part
+        # (tokens already in pages that a chunk attends x layers).
         self._moe_stats = new_moe_stats() if cfg.n_experts else None
         self.attn_window = {"window_tokens_skipped": 0, "window_tokens_read": 0}
+        if cfg.latent:
+            self.attn_window |= {"latent_tokens_read": 0, "latent_prefix_tokens": 0}
         self._sliding_layers = (
             cfg.layer_types.count("sliding_attention") if cfg.has_window else 0
         )
@@ -457,12 +463,16 @@ class Engine:
                 f"page_size {page_size}"
             )
 
+        # The cache row is the model's (K and V per kv head, or one
+        # latent row): the pool is built from it.
+        row_planes, row_heads, row_width = cfg.kv_row
         if pool is not None:
             expected = dict(
                 page_size=page_size,
                 num_layers=cfg.n_layers,
-                num_kv_heads=cfg.n_kv_heads,
-                head_dim=cfg.head_dim,
+                planes=row_planes,
+                num_kv_heads=row_heads,
+                head_dim=row_width,
                 quant=kv_quant,
             )
             if kv_quant is None:
@@ -496,12 +506,13 @@ class Engine:
             self.pool = PagedKVPool(
                 num_slots=num_slots,
                 num_layers=cfg.n_layers,
-                num_kv_heads=cfg.n_kv_heads,
-                head_dim=cfg.head_dim,
+                num_kv_heads=row_heads,
+                head_dim=row_width,
                 page_size=page_size,
                 dtype=cfg.dtype,
                 sharding=pool_sharding,
                 quant=kv_quant,
+                planes=row_planes,
             )
         if kv_tier_dir is not None and host_cache_slots <= 0:
             raise ValueError(
@@ -1542,6 +1553,10 @@ class Engine:
                     and len(sub) == 1
                     and len(sub[0][0].prompt) - sub[0][2]
                     <= self.long_prefill_threshold
+                    # A latent prefix is read where it lies, in pages (the
+                    # chunk path): gathering 16 k rows to attend them dense
+                    # costs more than the tail's whole prefill.
+                    and not (self.cfg.latent and sub[0][2])
                 ):
                     path = "dense"
                     with annotate("rm.prefill.dense"):
@@ -1985,13 +2000,15 @@ class Engine:
         tokens = np.zeros((1, s_b), dtype=np.int32)
         tokens[0, :n_new] = prompt[reuse:]
         positions = (reuse + np.arange(s_b, dtype=np.int32))[None]
-        kv_shape = (self.cfg.n_layers, 1, p_b, self.cfg.n_kv_heads, self.cfg.head_dim)
-        cached_k = jnp.zeros(kv_shape, dtype=self.cfg.dtype)
-        cached_v = jnp.zeros(kv_shape, dtype=self.cfg.dtype)
+        planes, heads, width = self.cfg.kv_row
+        kv_shape = (self.cfg.n_layers, 1, p_b, heads, width)
+        # One buffer a plane of the row (a latent row has one: its cached_v
+        # is the same array and is not read).
+        cached = [jnp.zeros(kv_shape, dtype=self.cfg.dtype)] * planes
         if reuse:
-            g = self.pool.gather(prefix_slots)  # [2, L, n, Hkv, D]
-            cached_k = cached_k.at[:, 0, p_b - reuse :].set(g[0])
-            cached_v = cached_v.at[:, 0, p_b - reuse :].set(g[1])
+            g = self.pool.gather(prefix_slots)  # [planes, L, n, heads, width]
+            cached = [c.at[:, 0, p_b - reuse :].set(g[i])
+                      for i, c in enumerate(cached)]
         moe_kw = self._moe_kw()
         if moe_kw:  # keep the bucket's padding out of the counters
             moe_kw["new_lengths"] = jnp.full((1,), n_new, dtype=jnp.int32)
@@ -2000,8 +2017,8 @@ class Engine:
             self.cfg,
             jnp.asarray(tokens),
             jnp.asarray(positions),
-            cached_k,
-            cached_v,
+            cached[0],
+            cached[-1],
             jnp.full((1,), reuse, dtype=jnp.int32),
             **moe_kw,
         )
@@ -2009,7 +2026,10 @@ class Engine:
             self._moe_stats = aux[0]["moe_stats"]
             self._last_expert_ids = aux[0]["expert_ids"]
             self._keep_expert_ids(req, reuse, 0, n_new)
-        self.pool.write(own[:n_new], new_k[:, 0, :n_new], new_v[:, 0, :n_new])
+        self.pool.write(
+            own[:n_new], new_k[:, 0, :n_new],
+            None if new_v is None else new_v[:, 0, :n_new],
+        )
 
         req.output_tokens = []
         req.kv_len = len(prompt)
@@ -2181,6 +2201,14 @@ class Engine:
                         lastpos[i] = nv - 1  # this chunk holds the last token
                 else:
                     kvlen[i] = totals[i]
+            if self.cfg.latent:  # rows in pages that this chunk attends
+                prior = sum(
+                    reuses[i] + ci * C for i in range(N)
+                    if reuses[i] + ci * C < totals[i]
+                )
+                self.attn_window["latent_prefix_tokens"] += (
+                    prior * self.cfg.n_layers
+                )
             # Only each row's LAST prompt position is ever sampled, and
             # only in the chunk that holds it.
             at = np.zeros((B,), dtype=np.int32)
@@ -2414,6 +2442,8 @@ class Engine:
         aw = self.attn_window  # once per launch, whenever it was built
         aw["window_tokens_read"] += launch.window[0]
         aw["window_tokens_skipped"] += launch.window[1]
+        if launch.latent_tokens:
+            aw["latent_tokens_read"] += launch.latent_tokens
         step_t0 = time.monotonic()
         with annotate("rm.decode.dispatch"):
             tokens_d = launch.tokens_d
@@ -2620,6 +2650,10 @@ class Engine:
             slots=slots,
             max_len=int(lengths[idx].max()) - 1,
             window=window,
+            latent_tokens=(
+                int(lengths[idx].sum()) * self.cfg.n_layers
+                if self.cfg.latent else 0
+            ),
             slots_d=jnp.asarray(slots),
             pt_d=jnp.asarray(
                 self._decode_pt_bucket(1 + ahead, floor=_KV_BLOCK_PAGES)

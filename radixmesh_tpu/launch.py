@@ -170,14 +170,16 @@ def _run_node(args: argparse.Namespace) -> int:
         # Engine page size (pow-2 paged-attention granularity) is distinct
         # from cfg.page_size (mesh replication granularity, default 1).
         page_size = int(model.get("page_size", 16))
+        planes, heads, width = mcfg.kv_row
         pool = PagedKVPool(
             num_slots=int(model.get("kv_slots", cfg.num_kv_slots)),
             num_layers=mcfg.n_layers,
-            num_kv_heads=mcfg.n_kv_heads,
-            head_dim=mcfg.head_dim,
+            num_kv_heads=heads,
+            head_dim=width,
             page_size=page_size,
             dtype=mcfg.dtype,
             quant=model.get("kv_quant"),
+            planes=planes,
         )
         node = MeshCache(cfg, pool=None).start()
     elif role is not NodeRole.ROUTER:

@@ -6,7 +6,7 @@ from radixmesh_tpu.models.llama import (
     param_logical_axes,
     convert_hf_state_dict,
 )
-from radixmesh_tpu.models import mellum2, qwen2
+from radixmesh_tpu.models import glm4_moe_lite, mellum2, qwen2
 
 _PRESETS = {
     "llama3-8b": ModelConfig.llama3_8b,
@@ -24,6 +24,9 @@ _PRESETS = {
     "mellum2-12b-a2.5b": mellum2.mellum2_12b_a2_5b,
     "mellum2-12b-a2.5b-l12": mellum2.mellum2_12b_a2_5b_l12,
     "mellum2-tiny": mellum2.mellum2_tiny,
+    "glm-4.7-flash": glm4_moe_lite.glm47_flash,
+    "glm-4.7-flash-l8": glm4_moe_lite.glm47_flash_l8,
+    "glm-4.7-flash-tiny": glm4_moe_lite.glm47_flash_tiny,
 }
 
 

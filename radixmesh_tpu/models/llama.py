@@ -32,6 +32,15 @@ the serving stack's model side that the north star requires
   request asks) and the ``moe`` counters, accumulated on the device.
   What does not serve such a config is refused by name
   (``unsupported_reason``), never served by a dense fallback.
+- **The cache row is the config's** (``models/glm4_moe_lite.py``):
+  ``ModelConfig.kv_row`` says what a token leaves in a layer — K and V per
+  kv head, or, for latent attention (``kv_lora_rank``), ONE row shared by
+  every head. ``_attn_in`` then returns the absorbed query and that row,
+  and the three entry points attend it as multi-query attention whose
+  values are the row's head (``ops/mla_attention.py``); ``_attn_out``
+  un-absorbs. A config's leading dense layers (``first_k_dense``) are a
+  stack of their own, scanned before the sparse ones by the same body
+  (``_scan_layers``).
 """
 
 from __future__ import annotations
@@ -48,6 +57,11 @@ from radixmesh_tpu.ops.attention import (
     attend_prefill,
     paged_chunk_attention,
     paged_decode_attention,
+)
+from radixmesh_tpu.ops.mla_attention import (
+    latent_chunk_attention,
+    latent_decode_attention,
+    latent_prefill_attention,
 )
 from radixmesh_tpu.ops.moe import STATS as MOE_STATS, moe_ffn
 from radixmesh_tpu.ops.norm import rms_norm
@@ -106,6 +120,28 @@ class ModelConfig:
     n_experts_per_tok: int = 0
     moe_intermediate: int = 0
     norm_topk_prob: bool = True
+    # The router: "softmax" over all experts, or "sigmoid" scores chosen
+    # by score + a learned per-expert bias and weighed by the score alone.
+    # The chosen weights are multiplied by ``routed_scaling_factor``.
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # Experts every token takes beside the routed ones (one SwiGLU of
+    # width ``n_shared_experts * moe_intermediate``), and the number of
+    # leading layers whose FFN is the dense one of width ``intermediate``
+    # (their leaves are a stack of their own, ``params["dense_layers"]``).
+    n_shared_experts: int = 0
+    first_k_dense: int = 0
+    # Latent attention (MLA) when ``kv_lora_rank`` > 0: queries through a
+    # ``q_lora_rank`` bottleneck, ``head_dim`` = ``qk_nope_head_dim`` +
+    # ``qk_rope_head_dim`` (rotary on the second part only), and ONE cache
+    # row a token and layer, ``kv_lora_rank + qk_rope_head_dim`` elements
+    # (stored in whole 128-lane tiles), shared by every head (``kv_row``);
+    # ``n_kv_heads`` shapes nothing.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -131,6 +167,25 @@ class ModelConfig:
                 "n_experts needs 0 < n_experts_per_tok <= n_experts and "
                 "moe_intermediate > 0"
             )
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_scoring {self.router_scoring!r}")
+        if not 0 <= self.first_k_dense <= self.n_layers or (
+            (self.first_k_dense or self.n_shared_experts) and not self.n_experts
+        ):
+            raise ValueError(
+                "first_k_dense and n_shared_experts belong to a config with "
+                "experts, and first_k_dense to [0, n_layers]"
+            )
+        if self.kv_lora_rank and not (
+            self.q_lora_rank > 0 and self.v_head_dim > 0
+            and self.qk_rope_head_dim > 0 and self.qk_rope_head_dim % 2 == 0
+            and self.head_dim == self.qk_nope_head_dim + self.qk_rope_head_dim
+        ):
+            raise ValueError(
+                "latent attention needs q_lora_rank, v_head_dim and an even "
+                "qk_rope_head_dim > 0, and head_dim == qk_nope_head_dim + "
+                "qk_rope_head_dim"
+            )
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -138,6 +193,36 @@ class ModelConfig:
     @property
     def has_window(self) -> bool:
         return bool(self.layer_types) and "sliding_attention" in self.layer_types
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of the slice of a head that rotates."""
+        return self.qk_rope_head_dim if self.latent else self.head_dim
+
+    @property
+    def kv_row(self) -> tuple[int, int, int]:
+        """What one token leaves in one layer of the cache, as ``(planes,
+        heads, width)``: K and V per kv head, or the one latent row (its
+        first ``kv_lora_rank`` elements are the values too)."""
+        if self.latent:
+            # kv_lora_rank + qk_rope_head_dim elements and zeros up to whole
+            # 128-lane tiles: the device's tiled layout stores a 576-wide
+            # row as 640 whatever its logical width, and a kernel can only
+            # DMA what is aligned to the tiling.
+            elements = self.kv_lora_rank + self.qk_rope_head_dim
+            return (1, 1, -(-elements // 128) * 128)
+        return (2, self.n_kv_heads, self.head_dim)
+
+    def kv_pool_shape(self, num_slots: int) -> tuple[int, int, int, int, int]:
+        """The paged pool of this config: ``[planes, layers, heads, slots,
+        width]`` (``cache/kv_pool.py`` allocates it, the entry points below
+        view it as pages)."""
+        planes, heads, width = self.kv_row
+        return (planes, self.n_layers, heads, num_slots, width)
 
     @classmethod
     def llama3_8b(cls) -> "ModelConfig":
@@ -263,6 +348,48 @@ def _vocab_blocks(vocab: int, hidden: int, limit: int) -> int:
     return n
 
 
+def _attn_dims(cfg: ModelConfig) -> dict:
+    """``name -> (in, out)`` of a layer's attention matrices."""
+    H = cfg.hidden
+    if cfg.latent:
+        q_out = cfg.n_heads * cfg.head_dim
+        kv_out = cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+        return {
+            "wq_a": (H, cfg.q_lora_rank),
+            "wq_b": (cfg.q_lora_rank, q_out),
+            "wkv_a": (H, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+            "wkv_b": (cfg.kv_lora_rank, kv_out),
+            "wo": (cfg.n_heads * cfg.v_head_dim, H),
+        }
+    qd, kvd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    return {"wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd), "wo": (qd, H)}
+
+
+def _ffn_dims(hidden: int, width: int, prefix: str = "w_") -> dict:
+    return {prefix + "gate": (hidden, width), prefix + "up": (hidden, width),
+            prefix + "down": (width, hidden)}
+
+
+def _draw_stack(keys, n: int, dims: dict, cfg: ModelConfig, quant_axis) -> dict:
+    """One stack of ``n`` layers: the norms and every ``[n, in, out]``
+    matrix of ``dims`` (contraction is each layer block's axis 0), one key
+    a matrix in the order of ``dims``."""
+    stack = {
+        "attn_norm": jnp.ones((n, cfg.hidden), dtype=cfg.dtype),
+        "mlp_norm": jnp.ones((n, cfg.hidden), dtype=cfg.dtype),
+    }
+    if cfg.latent:
+        stack["q_norm"] = jnp.ones((n, cfg.q_lora_rank), dtype=cfg.dtype)
+        stack["kv_norm"] = jnp.ones((n, cfg.kv_lora_rank), dtype=cfg.dtype)
+    for k, (name, (i_dim, o_dim)) in zip(keys, dims.items()):
+        stack[name], s = _draw_blocks(
+            k, n, (i_dim, o_dim), i_dim, cfg.dtype, quant_axis
+        )
+        if s is not None:
+            stack[name + "_s"] = s
+    return stack
+
+
 @partial(jax.jit, static_argnames=("cfg", "weight_quant", "mesh"))
 def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
     # Contraction axis of a block to quantize along, or None for float.
@@ -270,24 +397,13 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         return a if weight_quant is not None else None
 
     keys = jax.random.split(key, 9)
-    L, H, V = cfg.n_layers, cfg.hidden, cfg.vocab_size
-    qd, kvd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    H, V = cfg.hidden, cfg.vocab_size
+    L = cfg.n_layers - cfg.first_k_dense  # layers of the main stack
     ffn_width = cfg.moe_intermediate if cfg.n_experts else cfg.intermediate
-    ffn = {"w_gate": (H, ffn_width), "w_up": (H, ffn_width),
-           "w_down": (ffn_width, H)}
-    dims = {"wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd), "wo": (qd, H)}
+    ffn = _ffn_dims(H, ffn_width)
+    dims = _attn_dims(cfg)
     dims |= {"w_router": (H, cfg.n_experts)} if cfg.n_experts else ffn
-    layers = {
-        "attn_norm": jnp.ones((L, H), dtype=cfg.dtype),
-        "mlp_norm": jnp.ones((L, H), dtype=cfg.dtype),
-    }
-    for k, (name, (i_dim, o_dim)) in zip(keys, dims.items()):
-        # [L, in, out]: contraction is each layer block's axis 0.
-        layers[name], s = _draw_blocks(
-            k, L, (i_dim, o_dim), i_dim, cfg.dtype, axis(0)
-        )
-        if s is not None:
-            layers[name + "_s"] = s
+    layers = _draw_stack(keys, L, dims, cfg, axis(0))
     if cfg.n_experts:
         # [L, E, in, out], one expert's block at a time.
         E = cfg.n_experts
@@ -296,11 +412,37 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         ):
             w, _ = _draw_blocks(k, L * E, (i_dim, o_dim), i_dim, cfg.dtype, None)
             layers[name] = w.reshape(L, E, i_dim, o_dim)
+    if cfg.n_shared_experts:
+        shared = _ffn_dims(H, cfg.n_shared_experts * cfg.moe_intermediate, "ws_")
+        for k, (name, (i_dim, o_dim)) in zip(
+            jax.random.split(jax.random.fold_in(key, 101), 3), shared.items()
+        ):
+            layers[name], _ = _draw_blocks(
+                k, L, (i_dim, o_dim), i_dim, cfg.dtype, None
+            )
+    if cfg.router_scoring == "sigmoid":
+        # The learned selection bias (the source's e_score_correction_bias):
+        # a seeded non-zero draw, so that choosing by score + bias and
+        # weighing by the score differ. Small beside the scores' own spread
+        # (~0.2): the trained bias BALANCES the experts' load, and a draw
+        # of 0.1 left 38 of 64 experts hit by 32 rows where 0.02 leaves 55
+        # (an unbiased router 56) while still changing half the choices.
+        layers["router_bias"] = 0.02 * jax.random.normal(
+            jax.random.fold_in(key, 103), (L, cfg.n_experts), jnp.float32
+        )
     if cfg.qkv_bias:
+        qd, kvd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
         layers["bq"] = jnp.zeros((L, qd), dtype=cfg.dtype)
         layers["bk"] = jnp.zeros((L, kvd), dtype=cfg.dtype)
         layers["bv"] = jnp.zeros((L, kvd), dtype=cfg.dtype)
     params = {"final_norm": jnp.ones((H,), dtype=cfg.dtype), "layers": layers}
+    if cfg.first_k_dense:
+        params["dense_layers"] = _draw_stack(
+            jax.random.split(jax.random.fold_in(key, 102), 8),
+            cfg.first_k_dense,
+            _attn_dims(cfg) | _ffn_dims(H, cfg.intermediate), cfg, axis(0),
+        )
+    qd = cfg.n_heads * cfg.head_dim
     n_blk = _vocab_blocks(V, H, H * max(ffn_width, qd))
     # [V, H] in row blocks; per-ROW scales serve the gather and the tied
     # LM head alike (ops/wquant.py).
@@ -381,6 +523,35 @@ def param_logical_axes(cfg: ModelConfig, params: dict | None = None) -> dict:
             "w_up": ("layer", "expert", "embed", "ffn"),
             "w_down": ("layer", "expert", "ffn", "embed"),
         }
+    if cfg.latent:
+        # Engine refuses a mesh for such a config (no cache axis to shard
+        # by head); the names say what a tp share would split.
+        for name in ("wq", "wk", "wv"):
+            del axes["layers"][name]
+        axes["layers"] |= {
+            "wq_a": ("layer", "embed", "q_lora"),
+            "q_norm": ("layer", "q_lora"),
+            "wq_b": ("layer", "q_lora", "q_heads"),
+            "wkv_a": ("layer", "embed", "kv_lora"),
+            "kv_norm": ("layer", "kv_lora"),
+            "wkv_b": ("layer", "kv_lora", "q_heads"),
+        }
+    if cfg.n_shared_experts:
+        axes["layers"] |= {
+            "ws_gate": ("layer", "embed", "ffn"),
+            "ws_up": ("layer", "embed", "ffn"),
+            "ws_down": ("layer", "ffn", "embed"),
+        }
+    if cfg.router_scoring == "sigmoid":
+        axes["layers"]["router_bias"] = ("layer", "expert")
+    if cfg.first_k_dense:
+        dense = {k: v for k, v in axes["layers"].items()
+                 if not k.startswith(("ws_", "w_router", "router_bias"))}
+        axes["dense_layers"] = dense | {
+            "w_gate": ("layer", "embed", "ffn"),
+            "w_up": ("layer", "embed", "ffn"),
+            "w_down": ("layer", "ffn", "embed"),
+        }
     if cfg.qkv_bias:
         axes["layers"]["bq"] = ("layer", "q_heads")
         axes["layers"]["bk"] = ("layer", "kv_heads")
@@ -457,10 +628,65 @@ def _qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig):
     )
 
 
-def _mlp(lp: dict, x: jnp.ndarray) -> jnp.ndarray:
-    gate = jax.nn.silu(_wmm(lp, "w_gate", "bsh,hi->bsi", x))
-    up = _wmm(lp, "w_up", "bsh,hi->bsi", x)
-    return _wmm(lp, "w_down", "bsi,ih->bsh", gate * up)
+def _latent_split(lp: dict, cfg: ModelConfig):
+    """``wkv_b`` [r, Hq * (nope + v)] as its two per-head parts:
+    ``(W_k [r, Hq, nope], W_v [r, Hq, v])``."""
+    w = lp["wkv_b"].reshape(
+        cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_head_dim + cfg.v_head_dim
+    )
+    return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
+
+
+def _latent_qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions, inv_freq):
+    """Latent attention's inputs from ``x`` [B, S, H]: the ABSORBED query
+    ``q_nope W_k^T || RoPE(q_rope)`` [B, S, Hq, W] and the cache row
+    ``RMSNorm(c_kv) || RoPE(k_rope)`` [B, S, 1, W] — key of every head, and
+    in its first ``r`` elements their value too; both end in zeros up to
+    the row's stored width ``W`` (``ModelConfig.kv_row``). Scores of the
+    two against each other are ``q_nope . k_nope + q_rope . k_rope`` with
+    K derived from the row: the same function, never materialised."""
+    B, S = x.shape[:2]
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    c_q = rms_norm(_wmm(lp, "wq_a", "bsh,hr->bsr", x), lp["q_norm"], cfg.rms_eps)
+    q = _wmm(lp, "wq_b", "bsr,rd->bsd", c_q).reshape(
+        B, S, cfg.n_heads, cfg.head_dim
+    )
+    q_rope = apply_rope(q[..., dn:], positions, inv_freq)
+    kv = _wmm(lp, "wkv_a", "bsh,hr->bsr", x)
+    c_kv = rms_norm(kv[..., :r], lp["kv_norm"], cfg.rms_eps)
+    k_rope = apply_rope(kv[..., None, r:], positions, inv_freq)
+    pad = cfg.kv_row[2] - r - cfg.qk_rope_head_dim
+    row = jnp.concatenate(
+        [c_kv[..., None, :], k_rope, jnp.zeros((B, S, 1, pad), k_rope.dtype)],
+        axis=-1,
+    )
+    with jax.named_scope("rm_mla_absorb"):
+        q_abs = jnp.einsum(
+            "bsqn,rqn->bsqr", q[..., :dn], _latent_split(lp, cfg)[0],
+            precision=_PREC,
+        )
+    q_pad = jnp.zeros((B, S, cfg.n_heads, pad), q_rope.dtype)
+    return jnp.concatenate([q_abs, q_rope, q_pad], axis=-1), row
+
+
+def _attn_in(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions, inv_freq,
+             rope_scale):
+    """``(q, k, v)`` of ``x`` [B, S, H], rotated, as the attention ops
+    take them: per-head K and V, or for latent attention the absorbed
+    query, the cache row as the one shared key head, and ``v`` None (the
+    values are the row's first ``kv_lora_rank`` elements)."""
+    if cfg.latent:
+        return *_latent_qkv(lp, x, cfg, positions, inv_freq), None
+    q, k, v = _qkv(lp, x, cfg)
+    q = apply_rope(q, positions, inv_freq, rope_scale)
+    k = apply_rope(k, positions, inv_freq, rope_scale)
+    return q, k, v
+
+
+def _mlp(lp: dict, x: jnp.ndarray, prefix: str = "w_") -> jnp.ndarray:
+    gate = jax.nn.silu(_wmm(lp, prefix + "gate", "bsh,hi->bsi", x))
+    up = _wmm(lp, prefix + "up", "bsh,hi->bsi", x)
+    return _wmm(lp, prefix + "down", "bsi,ih->bsh", gate * up)
 
 
 class UnsupportedForModel(ValueError):
@@ -469,13 +695,14 @@ class UnsupportedForModel(ValueError):
 
 def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
     """Why ``options`` cannot serve ``cfg``, or None. A config with
-    experts or window layers runs on one chip in bf16 through
+    experts, window layers or a latent cache row runs on one chip in bf16
+    through
     ``prefill_forward`` / ``prefill_chunk_paged`` / ``decode_step`` and
     nothing else; every other launch path assumes the dense layer and is
     refused here by name, so that none of them serves such a model with a
     dense layer's arithmetic. ``options`` are ``Engine``'s keyword names
     (a falsy value is "not asked for")."""
-    if not (cfg.n_experts or cfg.has_window):
+    if not (cfg.n_experts or cfg.has_window or cfg.latent):
         return None
     mesh = options.get("device_mesh")
     named = {
@@ -495,7 +722,8 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
         return None
     kinds = " and ".join(
         k for k, on in (("sparse experts", cfg.n_experts),
-                        ("window attention", cfg.has_window)) if on
+                        ("window attention", cfg.has_window),
+                        ("a latent (MLA) cache row", cfg.latent)) if on
     )
     return (
         f"a model with {kinds} does not support " + "; ".join(asked)
@@ -550,6 +778,13 @@ def _layer_tables(cfg: ModelConfig) -> dict | None:
     }
 
 
+def _pages_shape(kv_pool: jnp.ndarray, page_size: int) -> tuple:
+    """The pool ``[planes, L, heads, slots, width]`` viewed as pages
+    ``[planes, L, heads, slots // page, page, width]`` (a pure reshape)."""
+    planes, n_layers, heads, num_slots, width = kv_pool.shape
+    return (planes, n_layers, heads, num_slots // page_size, page_size, width)
+
+
 def _rope_of(inv_freq, table: dict | None):
     """``(inv_freq, cos/sin factor, window)`` of one layer: its row of
     ``_layer_tables``, or the model's one RoPE (``inv_freq``, computed
@@ -594,35 +829,72 @@ def _bump_moe_stats(stats, site: str, delta: jnp.ndarray) -> jnp.ndarray:
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
-def _scanned_layers(cfg: ModelConfig, layers: dict) -> dict:
+def _scanned_layers(layers: dict) -> dict:
     """What the layer scan slices per layer: every stacked leaf but the
     expert weights, which the expert kernel indexes itself (``_ffn``)."""
-    if not cfg.n_experts:
+    if "w_router" not in layers:
         return layers
     return {k: v for k, v in layers.items() if k not in _EXPERT_WEIGHTS}
 
 
+def _scan_layers(cfg: ModelConfig, params: dict, layer, carry):
+    """``lax.scan`` of ``layer(carry, xs, sparse)`` over the model's
+    layers, ``xs = (layer index, the layer's leaves, its row of
+    _layer_tables)``: one scan, or, for a config whose first layers have
+    the dense FFN (leaves of another shape), one over them and one over
+    the rest. Returns ``(carry, ys)``; ``ys`` of two scans are joined
+    along the layer axis, but for what only the sparse layers give (the
+    dense scan's None)."""
+    kd = cfg.first_k_dense
+    stacks = [(0, params["dense_layers"], False)] if kd else []
+    stacks.append((kd, params["layers"], bool(cfg.n_experts)))
+    tables = _layer_tables(cfg)
+    joined = None
+    for first, stack, sparse in stacks:
+        n = stack["attn_norm"].shape[0]
+        xs = (first + jnp.arange(n), _scanned_layers(stack),
+              jax.tree_util.tree_map(lambda t: t[first:first + n], tables))
+        carry, ys = jax.lax.scan(partial(layer, sparse=sparse), carry, xs)
+        if joined is None:
+            joined = ys
+        else:
+            joined = jax.tree_util.tree_map(
+                lambda a, b: b if a is None else jnp.concatenate([a, b]),
+                joined, ys, is_leaf=lambda a: a is None,
+            )
+    return carry, joined
+
+
 def _ffn(params: dict, lp: dict, l_idx, x: jnp.ndarray, cfg: ModelConfig,
-         site: str, valid: jnp.ndarray | None = None, **kernel_kw):
+         site: str, valid: jnp.ndarray | None = None, sparse: bool = True,
+         **kernel_kw):
     """Layer ``l_idx``'s feed-forward on ``x`` [B, S, H]: ``_mlp``, or the
     sparse expert layer for a config with experts (a static choice; it
     takes the STACKED expert weights and the layer's index, because a
     scan's per-layer slice of them handed to a kernel is a copy).
-    Returns ``(y, aux)``; ``aux`` is None for the dense layer, else
-    ``(expert ids [B, S, k], this launch's counter increments [4])``,
-    the counters over the tokens ``valid`` [B, S] marks."""
-    if not cfg.n_experts:
+    Returns ``(y, aux)``; ``aux`` is None for the dense layer (``sparse``
+    False: a layer of a dense config, or one of a sparse config's leading
+    dense layers), else ``(expert ids [B, S, k], this launch's counter
+    increments [4])``, the counters over the tokens ``valid`` [B, S]
+    marks."""
+    if not sparse:
         return _mlp(lp, x), None
     B, S, H = x.shape
     stacked = params["layers"]
     y, ids, stats = moe_ffn(
         x.reshape(B * S, H), lp["w_router"], stacked["w_gate"],
-        stacked["w_up"], stacked["w_down"], layer=l_idx,
+        stacked["w_up"], stacked["w_down"],
+        layer=l_idx - cfg.first_k_dense,  # its place in the sparse stack
         k=cfg.n_experts_per_tok, norm_topk=cfg.norm_topk_prob,
+        scoring=cfg.router_scoring, bias=lp.get("router_bias"),
+        scale=cfg.routed_scaling_factor,
         site=site, valid=None if valid is None else valid.reshape(B * S),
         **kernel_kw,
     )
-    return y.reshape(B, S, H), (ids.reshape(B, S, -1), stats)
+    y = y.reshape(B, S, H)
+    if cfg.n_shared_experts:  # every token takes it: no assignment
+        y = y + _mlp(lp, x, "ws_")
+    return y, (ids.reshape(B, S, -1), stats)
 
 
 def _moe_aux(cfg: ModelConfig, per_layer, moe_stats, site: str) -> tuple:
@@ -642,10 +914,19 @@ def _attn_out(lp: dict, attn: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """The wo projection shared by every forward variant: attn is
     [B, S, Hq·D] (or already [B, S, Hq, D])."""
     B, S = attn.shape[:2]
+    width = cfg.head_dim
+    if cfg.latent:
+        # attn is the heads' mix of latent rows [B, S, Hq, r]: their values.
+        width = cfg.v_head_dim
+        with jax.named_scope("rm_mla_unabsorb"):
+            attn = jnp.einsum(
+                "bsqr,rqv->bsqv", attn, _latent_split(lp, cfg)[1],
+                precision=_PREC,
+            )
     return _wmm(
         lp, "wo", "bsqd,qdh->bsh",
-        attn.reshape(B, S, cfg.n_heads, cfg.head_dim),
-        reshape=(cfg.n_heads, cfg.head_dim, cfg.hidden),
+        attn.reshape(B, S, cfg.n_heads, width),
+        reshape=(cfg.n_heads, width, cfg.hidden),
     )
 
 
@@ -679,7 +960,9 @@ def prefill_forward(
     padding is masked via ``kv_start``, so batched prefill with different
     hit lengths is exact. Pass ``P_max = 0`` arrays for no cache.
 
-    Returns ``(logits [B,S,V], new_k [L,B,S,Hkv,D], new_v [...])`` — the
+    Returns ``(logits [B,S,V], new_k [L,B,S,Hkv,D], new_v [...])`` (for
+    latent attention ``cached_k`` / ``new_k`` are cache rows
+    [L,B,·,1,width], ``cached_v`` is not read and ``new_v`` is None) — the
     caller writes new_k/new_v into the paged pool at the slots the radix
     tree allocated, which is how a served prompt becomes a reusable cached
     prefix (the contract the reference's commented-out scheduler hooks
@@ -687,7 +970,7 @@ def prefill_forward(
     ``aux`` as a fourth value (module docstring); ``new_lengths`` keeps
     the bucket's padding out of its counters.
     """
-    one_rope = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)
     p_max = cached_k.shape[2]
     s_new = tokens.shape[1]
@@ -700,28 +983,30 @@ def prefill_forward(
     if new_lengths is not None:
         valid = jnp.arange(s_new)[None, :] < new_lengths[:, None]
 
-    def layer(x, xs):
-        l_idx, lp, ck, cv, table = xs
+    def layer(x, xs, sparse):
+        l_idx, lp, table = xs
+        ck = jax.lax.dynamic_index_in_dim(cached_k, l_idx, 0, keepdims=False)
         inv_freq, rope_scale, window = _rope_of(one_rope, table)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(lp, h, cfg)
-        q = apply_rope(q, positions, inv_freq, rope_scale)
-        k = apply_rope(k, positions, inv_freq, rope_scale)
+        q, k, v = _attn_in(lp, h, cfg, positions, inv_freq, rope_scale)
         k_ctx = jnp.concatenate([ck, k], axis=1)  # [B, P_max + S, Hkv, D]
-        v_ctx = jnp.concatenate([cv, v], axis=1)
-        attn = attend_prefill(
-            q, k_ctx, v_ctx, attn_pos, kv_end, kv_start=pad, window=window
-        )
+        if cfg.latent:
+            attn = latent_prefill_attention(
+                q, k_ctx, attn_pos, kv_end, pad, cfg.kv_lora_rank,
+                cfg.head_dim ** -0.5,
+            )
+        else:
+            cv = jax.lax.dynamic_index_in_dim(cached_v, l_idx, 0, keepdims=False)
+            v_ctx = jnp.concatenate([cv, v], axis=1)
+            attn = attend_prefill(
+                q, k_ctx, v_ctx, attn_pos, kv_end, kv_start=pad, window=window
+            )
         x = x + _attn_out(lp, attn, cfg)
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid)
+        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse)
         return x + y, (k, v, aux)
 
-    x, (new_k, new_v, aux) = jax.lax.scan(
-        layer, x,
-        (jnp.arange(cfg.n_layers), _scanned_layers(cfg, params["layers"]),
-         cached_k, cached_v, _layer_tables(cfg)),
-    )
+    x, (new_k, new_v, aux) = _scan_layers(cfg, params, layer, x)
     return (_logits(params, cfg, x), new_k, new_v,
             *_moe_aux(cfg, aux, moe_stats, "prefill"))
 
@@ -757,7 +1042,7 @@ def prefill_forward_sp(
     from radixmesh_tpu.parallel.ring_attention import ring_self_attention
 
     refuse_unsupported(cfg, device_mesh=mesh)
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    inv_freq = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     from jax.sharding import NamedSharding, PartitionSpec
 
     seq_sharded = NamedSharding(mesh, PartitionSpec(None, axis))
@@ -831,14 +1116,10 @@ def prefill_chunk_paged(
     docstring); its counters leave out the chunk's padding.
     """
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
-    one_rope = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)  # [B, C, H]
+    kv_pages = kv_pool.reshape(_pages_shape(kv_pool, page_size))
     num_slots = kv_pool.shape[3]
-    pages_shape = (
-        2, cfg.n_layers, cfg.n_kv_heads,
-        num_slots // page_size, page_size, cfg.head_dim,
-    )
-    kv_pages = kv_pool.reshape(pages_shape)
     scale_pages = (
         None
         if kv_scale is None
@@ -855,13 +1136,11 @@ def prefill_chunk_paged(
         "use_kernel": use_kernel, "interpret": interpret,
     }
 
-    def layer(x, xs):
+    def layer(x, xs, sparse):
         l_idx, lp, table = xs
         inv_freq, rope_scale, window = _rope_of(one_rope, table)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(lp, h, cfg)  # [B,C,*,D]
-        q = apply_rope(q, positions, inv_freq, rope_scale)
-        k = apply_rope(k, positions, inv_freq, rope_scale)
+        q, k, v = _attn_in(lp, h, cfg, positions, inv_freq, rope_scale)
         if kv_scale is not None:
             # Quantize NOW and attend the dequantized copy, so the chunk
             # sees exactly what any later pool read will see (the fused
@@ -870,29 +1149,41 @@ def prefill_chunk_paged(
             from radixmesh_tpu.ops.quant import quantize_for_store
 
             k_int, v_int, k_sc, v_sc, k, v = quantize_for_store(k, v)
-        attn = paged_chunk_attention(
-            q,
-            k,
-            v,
-            kv_pages,
-            page_table,
-            positions,
-            prior_lengths,
-            kv_lengths,
-            l_idx,
-            kv_block_pages=kv_block_pages,
-            kv_scales=scale_pages,
-            use_kernel=use_kernel,
-            mesh=mesh,
-            interpret=interpret,
-            window=window,
-        )
+        if cfg.latent:
+            k = k.astype(kv_pool.dtype)  # attend what the pool will hold
+            attn = latent_chunk_attention(
+                q, k[:, :, 0], kv_pages, page_table, positions, prior_lengths,
+                kv_lengths, l_idx, cfg.kv_lora_rank, cfg.head_dim ** -0.5,
+                kv_block_pages=kv_block_pages, use_kernel=use_kernel,
+                interpret=interpret,
+            )
+        else:
+            attn = paged_chunk_attention(
+                q,
+                k,
+                v,
+                kv_pages,
+                page_table,
+                positions,
+                prior_lengths,
+                kv_lengths,
+                l_idx,
+                kv_block_pages=kv_block_pages,
+                kv_scales=scale_pages,
+                use_kernel=use_kernel,
+                mesh=mesh,
+                interpret=interpret,
+                window=window,
+            )
         x = x + _attn_out(lp, attn, cfg)
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, **moe_kw)
+        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse,
+                      **moe_kw)
         x = x + y
         if kv_scale is not None:
             return x, (k_int, v_int, k_sc, v_sc)
+        if cfg.latent:
+            return x, (k, None, aux)
         return x, (k.astype(kv_pool.dtype), v.astype(kv_pool.dtype), aux)
 
     def pick(x):
@@ -900,10 +1191,8 @@ def prefill_chunk_paged(
             return x
         return jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
 
-    xs = (jnp.arange(cfg.n_layers), _scanned_layers(cfg, params["layers"]),
-          _layer_tables(cfg))
     if kv_scale is not None:
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(layer, x, xs)
+        x, (new_k, new_v, new_ks, new_vs) = _scan_layers(cfg, params, layer, x)
         # Already quantized in-layer (same ints attention saw); scatter the
         # int8 payloads + scales: scan stacks [L, B, C, Hkv(, D)] → the
         # pool target [:, :, :, slots[B, C]] expects [2, L, Hkv, B, C(, D)].
@@ -912,11 +1201,12 @@ def prefill_chunk_paged(
         kv_pool = kv_pool.at[:, :, :, slots].set(new_kv)
         kv_scale = kv_scale.at[:, :, :, slots].set(new_s)
         return _logits(params, cfg, pick(x)), kv_pool, kv_scale
-    x, (new_k, new_v, aux) = jax.lax.scan(layer, x, xs)
+    x, (new_k, new_v, aux) = _scan_layers(cfg, params, layer, x)
     # One scatter for the whole chunk across all layers: scan stacks
-    # [L, B, C, Hkv, D]; the pool indexed at [:, :, :, slots[B,C]] expects
-    # [2, L, Hkv, B, C, D].
-    new_kv = jnp.stack([new_k, new_v]).transpose(0, 1, 4, 2, 3, 5)
+    # [L, B, C, Hkv, D] a plane; the pool indexed at [:, :, :, slots[B,C]]
+    # expects [planes, L, Hkv, B, C, D].
+    planes = [new_k] if new_v is None else [new_k, new_v]
+    new_kv = jnp.stack(planes).transpose(0, 1, 4, 2, 3, 5)
     kv_pool = kv_pool.at[:, :, :, slots].set(new_kv)
     return (_logits(params, cfg, pick(x)), kv_pool,
             *_moe_aux(cfg, aux, moe_stats, "prefill"))
@@ -972,29 +1262,27 @@ def _decode_core(
     moe_stats: jnp.ndarray | None = None,
 ):
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
-    one_rope = rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     positions = lengths - 1  # [B]
     live = (lengths > 1)[:, None]  # idle rows ride with lengths == 1
     x = _embed_lookup(params, tokens)[:, None, :]  # [B, 1, H]
     B = tokens.shape[0]
     num_slots = kv_pool.shape[3]
-    pages_shape = (
-        2, cfg.n_layers, cfg.n_kv_heads,
-        num_slots // page_size, page_size, cfg.head_dim,
-    )
+    pool_shape = kv_pool.shape
+    pages_shape = _pages_shape(kv_pool, page_size)
 
     scales_shape = (
         2, cfg.n_layers, cfg.n_kv_heads, num_slots // page_size, page_size,
     )
 
-    def layer(carry, xs):
+    def layer(carry, xs, sparse):
         x, kv_pool, kv_scale = carry
         l_idx, lp, table = xs
         inv_freq, rope_scale, window = _rope_of(one_rope, table)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(lp, h, cfg)  # [B,1,*,D]
-        q = apply_rope(q, positions[:, None], inv_freq, rope_scale)
-        k = apply_rope(k, positions[:, None], inv_freq, rope_scale)
+        q, k, v = _attn_in(  # [B,1,*,D]
+            lp, h, cfg, positions[:, None], inv_freq, rope_scale
+        )
         # Fused write+attend: the Pallas kernel writes this token's K/V row
         # into the (aliased) pool and attends over this layer's pages — the
         # pool buffer flows through the scan with zero copies. (A separate
@@ -1018,6 +1306,18 @@ def _decode_core(
             kv_scale = scale_pages.reshape(
                 2, cfg.n_layers, cfg.n_kv_heads, num_slots
             )
+        elif cfg.latent:
+            attn, kv_pages = latent_decode_attention(
+                q[:, 0],
+                k[:, 0, 0].astype(kv_pool.dtype),
+                kv_pool.reshape(pages_shape),
+                slots,
+                page_table,
+                lengths,
+                l_idx,
+                cfg.kv_lora_rank,
+                cfg.head_dim ** -0.5,
+            )
         else:
             attn, kv_pages = paged_decode_attention(
                 q[:, 0],
@@ -1031,21 +1331,14 @@ def _decode_core(
                 mesh=mesh,
                 window=window,
             )
-        kv_pool = kv_pages.reshape(2, cfg.n_layers, cfg.n_kv_heads, num_slots,
-                                   cfg.head_dim)
-        x = x + _wmm(
-            lp, "wo", "bqd,qdh->bh",
-            attn.reshape(B, cfg.n_heads, cfg.head_dim),
-            reshape=(cfg.n_heads, cfg.head_dim, cfg.hidden),
-        )[:, None, :]
+        kv_pool = kv_pages.reshape(pool_shape)
+        x = x + _attn_out(lp, attn[:, None], cfg)
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        y, aux = _ffn(params, lp, l_idx, h2, cfg, "decode", live)
+        y, aux = _ffn(params, lp, l_idx, h2, cfg, "decode", live, sparse)
         return (x + y, kv_pool, kv_scale), aux
 
-    (x, kv_pool, kv_scale), aux = jax.lax.scan(
-        layer, (x, kv_pool, kv_scale),
-        (jnp.arange(cfg.n_layers), _scanned_layers(cfg, params["layers"]),
-         _layer_tables(cfg)),
+    (x, kv_pool, kv_scale), aux = _scan_layers(
+        cfg, params, layer, (x, kv_pool, kv_scale)
     )
     logits = _logits(params, cfg, x)[:, 0]
     if kv_scale is not None:

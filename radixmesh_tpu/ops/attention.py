@@ -27,6 +27,15 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
+def _softmax_scale(head_dim: int, scale):
+    """The factor on the logits: ``scale`` as given, or 1 / sqrt(head_dim)
+    (a latent-attention caller's queries are wider than the head whose
+    width sets its scale: ``ops/mla_attention.py``)."""
+    if scale is None:
+        return 1.0 / jnp.sqrt(jnp.asarray(head_dim, dtype=jnp.float32))
+    return jnp.asarray(scale, dtype=jnp.float32)
+
+
 def _in_window(distance, window):
     """Is a key ``distance`` positions behind its query inside a window of
     ``window`` keys (the query's own among them)? 0 means no window."""
@@ -42,6 +51,7 @@ def attend_prefill(
     kv_lengths: jnp.ndarray,  # [B] valid context end (index space)
     kv_start: jnp.ndarray | None = None,  # [B] valid context begin (ragged pad)
     window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
+    scale: float | None = None,  # of the logits; None = 1 / sqrt(D)
 ) -> jnp.ndarray:
     """Causal attention where queries start mid-context (after a cached
     prefix): query at index-space position p attends to kv indices in
@@ -65,8 +75,7 @@ def attend_prefill(
         preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.HIGHEST,
     )
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, dtype=jnp.float32))
-    logits = logits * scale
+    logits = logits * _softmax_scale(D, scale)
     kv_pos = jnp.arange(k.shape[1])[None, None, None, None, :]  # [1,1,1,1,K]
     causal = kv_pos <= q_positions[:, None, None, :, None]  # [B,1,1,Q,K]
     valid = kv_pos < kv_lengths[:, None, None, None, None]
@@ -98,6 +107,7 @@ def attend_decode_ref(
     k_scales: jnp.ndarray | None = None,  # [Hkv, P, page] int8-pool scales
     v_scales: jnp.ndarray | None = None,
     window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
+    scale: float | None = None,  # of the logits; None = 1 / sqrt(D)
 ) -> jnp.ndarray:
     """Gather-based paged decode attention — the numerics oracle for the
     Pallas kernel and the CPU execution path. With ``*_scales`` the pages
@@ -116,7 +126,7 @@ def attend_decode_ref(
         k = k.astype(jnp.float32) * ks[..., None]
         v = v.astype(jnp.float32) * vs[..., None]
     qg = q.reshape(B, Hkv, G, D)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, dtype=jnp.float32))
+    scale = _softmax_scale(D, scale)
     logits = (
         jnp.einsum(
             "bhgd,bkhd->bhgk",
@@ -190,6 +200,7 @@ def _page_block_softmax(
     kv_block_pages: int,
     kv_scales: jnp.ndarray | None = None,  # [2, L, Hkv, P, page] int8 pools
     window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
+    scale: float | None = None,  # of the logits; None = 1 / sqrt(D)
 ):
     """Shared core of the chunked-prefill attentions: scan fixed-size page
     blocks of one layer's pool context, maintaining the online softmax
@@ -204,13 +215,13 @@ def _page_block_softmax(
     n_blocks = max_pages // kv_block_pages
     bk = kv_block_pages * page  # tokens per block
 
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, dtype=jnp.float32))
+    scale = _softmax_scale(D, scale)
     # [B, Hkv, G, C, D] so every block step is one fp32 MXU contraction.
     qg = (q.astype(jnp.float32) * scale).reshape(B, C, Hkv, G, D).transpose(
         0, 2, 3, 1, 4
     )
     k_layer = kv_pages[0, layer]  # [Hkv, P, page, D]
-    v_layer = kv_pages[1, layer]
+    v_layer = kv_pages[-1, layer]  # a one-plane pool: its rows are the values too
     ks_layer = vs_layer = None
     if kv_scales is not None:
         ks_layer = kv_scales[0, layer]  # [Hkv, P, page]
@@ -278,6 +289,7 @@ def attend_chunk_hybrid(
     kv_block_pages: int = 32,
     kv_scales: jnp.ndarray | None = None,
     window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
+    scale: float | None = None,  # of the logits; None = 1 / sqrt(D)
 ) -> jnp.ndarray:
     """Chunk attention with the current chunk's K/V taken DENSE from the
     layer activations instead of read back out of the pool: prior context
@@ -292,9 +304,9 @@ def attend_chunk_hybrid(
     Hkv = k_cur.shape[2]
     m, l, acc = _page_block_softmax(
         q, kv_pages, page_table, q_positions, prior_lengths, layer,
-        kv_block_pages, kv_scales, window,
+        kv_block_pages, kv_scales, window, scale,
     )
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, dtype=jnp.float32))
+    scale = _softmax_scale(D, scale)
     qg = (q.astype(jnp.float32) * scale).reshape(
         B, C, Hkv, Hq // Hkv, D
     ).transpose(0, 2, 3, 1, 4)

@@ -6,6 +6,9 @@ combine.
     S = the k largest of p   w_e = p_e / sum_S p   (``norm_topk``)
     y = sum_{e in S} w_e * W_down,e (silu(W_gate,e x) * W_up,e x)
 
+or, with a sigmoid router: ``p = sigmoid(r)``, ``S`` = the k largest of
+``p + b`` (a learned bias), ``w_e = p_e / sum_S p`` times a constant.
+
 No token is dropped and there is no capacity factor: every pair is
 computed. The pairs are laid out expert by expert, each expert's rows
 padded up to a whole tile of ``tm`` rows, so that a tile of the grouped
@@ -42,18 +45,31 @@ _PREC = jax.lax.Precision.HIGHEST
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def route(x: jnp.ndarray, w_router: jnp.ndarray, k: int, norm_topk: bool):
+def route(x: jnp.ndarray, w_router: jnp.ndarray, k: int, norm_topk: bool,
+          scoring: str = "softmax", bias: jnp.ndarray | None = None,
+          scale: float = 1.0):
     """``x`` [T, H] -> (expert ids [T, k] int32, weights [T, k] float32).
-    All arithmetic in float32, softmax over ALL experts before the top-k
-    (the source's order), ties to the lower expert id (``lax.top_k``)."""
+    All arithmetic in float32, ties to the lower expert id
+    (``lax.top_k``). ``scoring`` "softmax": softmax over ALL experts
+    before the top-k (the source's order). "sigmoid": each expert's own
+    sigmoid score; the k are chosen by score + ``bias`` [E] and weighed by
+    the score alone. ``norm_topk`` divides the chosen weights by their sum
+    (+ 1e-20 under sigmoid, as the source does); ``scale`` multiplies them."""
     r = jnp.einsum(
         "th,he->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=_PREC,
     )
-    p = jax.nn.softmax(r, axis=-1)
-    top_p, top_e = jax.lax.top_k(p, k)
+    if scoring == "softmax":
+        top_p, top_e = jax.lax.top_k(jax.nn.softmax(r, axis=-1), k)
+    else:
+        p = jax.nn.sigmoid(r)
+        _, top_e = jax.lax.top_k(p if bias is None else p + bias, k)
+        top_p = jnp.take_along_axis(p, top_e, axis=-1)
     if norm_topk:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p = top_p / (total if scoring == "softmax" else total + 1e-20)
+    if scale != 1.0:
+        top_p = top_p * scale
     return top_e.astype(jnp.int32), top_p
 
 
@@ -213,6 +229,9 @@ def moe_ffn(
     k: int,
     norm_topk: bool,
     site: str,
+    scoring: str = "softmax",  # the router (``route``)
+    bias: jnp.ndarray | None = None,  # [E] selection bias of a sigmoid router
+    scale: float = 1.0,  # multiplies the chosen weights
     valid: jnp.ndarray | None = None,  # [T] rows that are real tokens
     use_kernel: bool | None = None,
     interpret: bool = False,
@@ -229,7 +248,7 @@ def moe_ffn(
     layer = jnp.asarray(layer, jnp.int32)
     E = w_router.shape[1]
     with jax.named_scope("rm_moe_route"):
-        top_e, top_w = route(x, w_router, k, norm_topk)
+        top_e, top_w = route(x, w_router, k, norm_topk, scoring, bias, scale)
         ok = jnp.ones(x.shape[:1], bool) if valid is None else valid
         hit = (top_e[..., None] == jnp.arange(E, dtype=jnp.int32)) & ok[
             :, None, None

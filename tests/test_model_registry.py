@@ -26,6 +26,26 @@ _SIZES = {
     "qwen2.5-32b": (31, 34),
     "mellum2-12b-a2.5b": (12.03, 12.27),  # 12.15 B +- 1%
     "mellum2-12b-a2.5b-l12": (5.4, 5.55),  # 12 layers + embedding and head
+    "glm-4.7-flash": (29.6, 30.2),  # 46 sparse layers + 1 dense, no MTP module
+    "glm-4.7-flash-l8": (5.15, 5.18),  # 9.62 GiB in bf16
+}
+
+# The model's public config.json (zai-org/GLM-4.7-Flash), as the catalog of
+# the model-configs guide holds it.
+_GLM47_SOURCE = {
+    "attention_bias": False, "hidden_act": "silu", "hidden_size": 2048,
+    "intermediate_size": 10240, "max_position_embeddings": 202752,
+    "model_type": "glm4_moe_lite", "moe_intermediate_size": 1536,
+    "topk_method": "noaux_tc", "norm_topk_prob": True,
+    "num_attention_heads": 20, "n_group": 1, "topk_group": 1,
+    "n_routed_experts": 64, "n_shared_experts": 1,
+    "routed_scaling_factor": 1.8, "num_experts_per_tok": 4,
+    "first_k_dense_replace": 1, "num_hidden_layers": 47,
+    "num_key_value_heads": 20, "num_nextn_predict_layers": 1,
+    "partial_rotary_factor": 1, "rms_norm_eps": 1e-05, "rope_scaling": None,
+    "rope_theta": 1000000, "tie_word_embeddings": False, "q_lora_rank": 768,
+    "kv_lora_rank": 512, "qk_nope_head_dim": 192, "qk_rope_head_dim": 64,
+    "v_head_dim": 256, "vocab_size": 154880,
 }
 
 # The model's public config.json (JetBrains/Mellum2-12B-A2.5B-Instruct), as
@@ -130,6 +150,81 @@ def test_mellum2_presets_are_the_source_key_by_key():
     assert bench["num_hidden_layers"] == 12 and bench["layer_types"] == src["layer_types"] * 3
     assert sorted(bench["reduced"]) == [
         "layer_types", "max_position_embeddings", "mlp_layer_types", "num_hidden_layers"]
+
+
+def test_glm47_presets_are_the_source_key_by_key():
+    """The 47-layer preset against the source's config, key by key; the
+    8-layer cut and the tiny preset differ from it only where they say;
+    the benchmark's configuration file states the same numbers."""
+    import json
+    import os
+
+    src = _GLM47_SOURCE
+    cfg = get_config("glm-4.7-flash")
+    assert cfg.n_layers == src["num_hidden_layers"]
+    assert cfg.first_k_dense == src["first_k_dense_replace"]
+    assert (cfg.hidden, cfg.intermediate) == (src["hidden_size"], src["intermediate_size"])
+    assert (cfg.n_heads, cfg.n_kv_heads) == (
+        src["num_attention_heads"], src["num_key_value_heads"])
+    assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+            cfg.qk_rope_head_dim, cfg.v_head_dim) == (
+        src["q_lora_rank"], src["kv_lora_rank"], src["qk_nope_head_dim"],
+        src["qk_rope_head_dim"], src["v_head_dim"])
+    assert cfg.head_dim == src["qk_nope_head_dim"] + src["qk_rope_head_dim"]
+    assert cfg.rope_dim == src["qk_rope_head_dim"] * src["partial_rotary_factor"]
+    assert (cfg.n_experts, cfg.n_experts_per_tok, cfg.moe_intermediate,
+            cfg.n_shared_experts) == (
+        src["n_routed_experts"], src["num_experts_per_tok"],
+        src["moe_intermediate_size"], src["n_shared_experts"])
+    assert cfg.norm_topk_prob is src["norm_topk_prob"]
+    assert cfg.router_scoring == "sigmoid"  # topk_method noaux_tc
+    assert cfg.routed_scaling_factor == src["routed_scaling_factor"]
+    assert cfg.vocab_size == src["vocab_size"] and cfg.rms_eps == src["rms_norm_eps"]
+    assert cfg.rope_theta == src["rope_theta"] and cfg.rope_scaling is src["rope_scaling"]
+    assert cfg.qkv_bias is src["attention_bias"]
+    assert cfg.tie_embeddings is src["tie_word_embeddings"]
+    assert cfg.max_seq_len == 40960  # served; the source declares 202752
+    # the cache row: 512 + 64 elements, stored in whole 128-lane tiles
+    assert cfg.kv_row == (1, 1, 640) and cfg.latent
+    cut = get_config("glm-4.7-flash-l8")
+    assert cut == cfg.replace(n_layers=8)
+    tiny = get_config("glm-4.7-flash-tiny")
+    assert (tiny.first_k_dense, tiny.n_layers, tiny.n_experts, tiny.n_shared_experts,
+            tiny.router_scoring, tiny.routed_scaling_factor) == (1, 3, 8, 1, "sigmoid", 1.8)
+    assert tiny.head_dim == tiny.qk_nope_head_dim + tiny.qk_rope_head_dim
+    # active parameters a token: 4 of 64 experts in each of 46 sparse layers
+    abstract = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
+    total = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(abstract))
+    one_expert = 3 * cfg.hidden * cfg.moe_intermediate
+    active = total - 46 * (cfg.n_experts - cfg.n_experts_per_tok) * one_expert
+    assert 3.8e9 < active < 4.0e9, active  # "30B-A3B" counts no embedding
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "glm-4.7-flash-bf16-l8.json")
+    with open(path) as fh:
+        bench = json.load(fh)
+    for key, want in src.items():
+        if key in bench["reduced"]:
+            continue
+        assert bench[key] == want, key
+    assert (bench["num_hidden_layers"], bench["num_nextn_predict_layers"],
+            bench["max_position_embeddings"]) == (8, 0, 40960)
+    assert sorted(bench["reduced"]) == [
+        "max_position_embeddings", "num_hidden_layers", "num_nextn_predict_layers"]
+    for key in ("rope_pairing", "e_score_correction_bias", "torch_dtype"):
+        assert key in bench["assumed"], key
+
+
+def test_latent_config_is_checked():
+    base = get_config("glm-4.7-flash-tiny")
+    with pytest.raises(ValueError, match="head_dim == qk_nope_head_dim"):
+        base.replace(head_dim=32)
+    with pytest.raises(ValueError, match="router_scoring"):
+        base.replace(router_scoring="tanh")
+    with pytest.raises(ValueError, match="first_k_dense"):
+        get_config("llama3-tiny").replace(first_k_dense=1)
+    dense = get_config("qwen2-tiny")
+    assert dense.kv_row == (2, dense.n_kv_heads, dense.head_dim) and not dense.latent
+    assert dense.kv_pool_shape(64) == (2, dense.n_layers, dense.n_kv_heads, 64, dense.head_dim)
 
 
 def test_unknown_preset_lists_known():
