@@ -13,7 +13,9 @@ weights from a seed — and checks what comes out by the repo's own means:
 1. ``device``    what JAX finds. Not a TPU → exit 2, nothing else runs.
 2. ``kernels``   every Pallas kernel the default ``serve`` flags reach,
                  compiled at the served head geometry and compared with
-                 its jnp oracle (``radixmesh_tpu/ops/kernel_check.py``).
+                 its jnp oracle (``radixmesh_tpu/ops/kernel_check.py``);
+                 the decode kernel also at Mellum2's heads with a window
+                 start, context past the window.
 3. ``serve``     start the server; send a short prompt (dense prefill), a
                  prompt over ``long_prefill_threshold`` (chunk kernel), a
                  second turn sharing its prefix (``cached_tokens`` > 0),
@@ -69,6 +71,10 @@ CHIP = {
     "page": 16,
     "geometry": dict(hq=28, hkv=4, head_dim=128, page=16, batch=8, chunk=512,
                      max_pages=64),
+    # Mellum2's heads (G = 8) and window: rows shorter than, at and past
+    # the 1 024 keys a window layer sees (the table holds 2 048).
+    "geometry_window": dict(hq=32, hkv=4, head_dim=128, page=16, batch=8,
+                            chunk=512, max_pages=128, window=1024),
     "interpret": False,
     "short": 40,  # dense prefill
     "long": 1100,  # > long_prefill_threshold (1024) → chunk kernel
@@ -96,6 +102,8 @@ REHEARSAL = {
     "page": 16,
     "geometry": dict(hq=4, hkv=2, head_dim=32, page=4, batch=2, chunk=8,
                      max_pages=4),
+    "geometry_window": dict(hq=8, hkv=1, head_dim=32, page=4, batch=8,
+                            chunk=8, max_pages=8, window=12),
     "interpret": True,
     "short": 40,
     "long": 300,
@@ -375,13 +383,20 @@ def phase_device(_args) -> dict:
 
 
 def phase_kernels(args) -> dict:
-    from radixmesh_tpu.ops.kernel_check import DEFAULT_CASES, check_kernels
+    from radixmesh_tpu.ops.kernel_check import (
+        DEFAULT_CASES,
+        WINDOW_CASES,
+        check_kernels,
+    )
 
     m = REHEARSAL if args.rehearse_cpu else CHIP
     t0 = time.monotonic()
-    results = check_kernels(
-        DEFAULT_CASES, interpret=m["interpret"], **m["geometry"]
-    )
+    results = [
+        {**r, "geometry": m[key]}
+        for cases, key in ((DEFAULT_CASES, "geometry"),
+                           (WINDOW_CASES, "geometry_window"))
+        for r in check_kernels(cases, interpret=m["interpret"], **m[key])
+    ]
     return {"results": results, "seconds": round(time.monotonic() - t0, 2)}
 
 
@@ -643,7 +658,7 @@ def main() -> int:
     try:
         k = _child("kernels", env, extra, timeout=600)
         for r in k["results"]:
-            say(f"kernel parity at {m['geometry']}: {r}")
+            say(f"kernel parity: {r}")
         _require(all(r["ok"] for r in k["results"]) and k["results"],
                  "a kernel disagrees with its oracle or failed to compile")
         say(f"kernels: {len(k['results'])} compiled and matched in "

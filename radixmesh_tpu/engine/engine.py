@@ -413,7 +413,8 @@ class Engine:
         # asked; None for a dense model. attn_window: context tokens that
         # decode launches did / did not have to read on window layers,
         # summed over rows and sliding layers (host arithmetic on the
-        # lengths, in rm.decode.build). For a latent cache row the same
+        # lengths, in rm.decode.build); where the Pallas decode kernel
+        # serves, the tokens of one of its compute blocks beside them. For a latent cache row the same
         # group counts the rows read: by decode launches (context x layers,
         # summed over rows) and by prefill launches of the cached part
         # (tokens already in pages that a chunk attends x layers).
@@ -513,6 +514,16 @@ class Engine:
                 sharding=pool_sharding,
                 quant=kv_quant,
                 planes=row_planes,
+            )
+        if kernels and not cfg.latent:
+            from radixmesh_tpu.ops.paged_attention import decode_block_tokens
+
+            # Static: which block geometry the decode kernel's numbers on
+            # this node come from (a chip of a tp mesh holds its heads).
+            tp = device_mesh.shape.get("tp", 1) if device_mesh is not None else 1
+            self.attn_window["decode_block_tokens"] = decode_block_tokens(
+                max(1, row_heads // tp), page_size, row_width,
+                self.pool.kv.dtype, self.max_pages, kv_quant is not None,
             )
         if kv_tier_dir is not None and host_cache_slots <= 0:
             raise ValueError(

@@ -5,7 +5,8 @@ their jnp oracles in interpret mode at D=32, G in {1, 2, 4}. That proves
 the algorithm; it says nothing about what Mosaic compiles for a real
 model's head geometry on silicon. This module is the same comparison as
 a callable: ``chip_smoke.py`` runs it on the chip at the served geometry
-(Qwen2-7B: Hq 28, Hkv 4 → G 7, D 128, page 16, chunk 512) for the
+(Qwen2-7B: Hq 28, Hkv 4 → G 7, D 128, page 16, chunk 512; the decode
+kernel also at Mellum2's Hq 32 → G 8 with a 1 024-key window) for the
 kernels the default ``serve`` flags reach, ``scripts/kernelbench.py``
 runs it for the variants only a flag selects, and the CPU rehearsal runs
 it in interpret mode at tiny size.
@@ -36,20 +37,33 @@ from radixmesh_tpu.ops.paged_attention import (
 )
 from radixmesh_tpu.ops.quant import quantize_kv
 
-__all__ = ["TOLERANCE", "DEFAULT_CASES", "FLAG_CASES", "check_kernels"]
+__all__ = [
+    "TOLERANCE", "DEFAULT_CASES", "FLAG_CASES", "WINDOW_CASES", "check_kernels",
+]
 
 # Absolute AND relative bound on |kernel - oracle| per output element.
 # Both sides read the same bf16 (or int8 + f32 scale) K/V and accumulate
 # in f32; they differ by (a) the final rounding of an O(1) output to
-# bf16, one ulp = 2^-8 ≈ 4e-3 relative, and (b) the MXU's bf16 passes
-# over the f32 q·k and p·v products inside the kernel, ~2^-9 relative per
-# score, i.e. ~1e-2 on a softmax weight. 2e-2 covers both with a margin
-# of about two; K/V held in anything narrower than the pool dtype (fp8's
-# 2^-4 steps) lands at 6e-2 and fails.
+# bf16, one ulp = 2^-8 ≈ 4e-3 relative, and (b) what the MXU is given.
+# The heads-batched decode and pool kernels hand it the pool's own bf16:
+# q·k is then exact (bf16 × bf16 products, f32 sums; 1/sqrt(D) applied to
+# the f32 scores) and only ``p`` is rounded to bf16 for p·v, 2^-9 relative
+# per weight. The per-head, chunk and int8 paths hand it f32 operands, over
+# which it makes ONE bf16 pass (measured on the chip, PERF.md §6 PR 32:
+# an f32 ``q`` against a bf16 pool reads 9e-5 mean error where the bf16
+# rounding of the exact answer reads 6e-5): ``q·scale`` and ``p`` both
+# rounded, ~2^-9 per score, i.e. ~1e-2 on a softmax weight. 2e-2 covers
+# all of it with a margin of about two (the largest error the chip has
+# shown is 7e-3); K/V held in anything narrower than the pool dtype
+# (fp8's 2^-4 steps) lands at 6e-2 and fails.
 TOLERANCE = 2e-2
 
 # Kernels the default ``serve`` flags reach (bf16 pool, auto grid).
 DEFAULT_CASES = ("fused_decode", "chunk_prefill")
+# The decode kernel as a model with window layers compiles it (``window``
+# a traced scalar; ``check_kernels(..., window=N)``): run with the window
+# and with 0, against rows shorter than, at and past the window.
+WINDOW_CASES = ("fused_decode_window",)
 # Variants only a flag selects: int8 KV (``--kv-quant int8``), the
 # per-head grids (``fuse_heads=False``, huge-Hkv configs), and the
 # read-only pool kernel (pp serving, the small-batch crossover).
@@ -135,38 +149,50 @@ def _err(got, want, mask=None) -> float:
     return float(np.max(np.abs(g - w) / (1.0 + np.abs(w))))
 
 
+def _fused_decode_case(x, kv, sc, layer, interpret, fuse, window) -> dict:
+    got = paged_decode_fused_kernel(
+        x["q"], x["k_new"], x["v_new"], kv, x["slots"], x["pt"],
+        x["lengths"], layer, interpret=interpret, kv_scales=sc,
+        fuse_heads=fuse, window=window,
+    )
+    want = paged_decode_attention(
+        x["q"], x["k_new"], x["v_new"], kv, x["slots"], x["pt"],
+        x["lengths"], layer, use_kernel=False, kv_scales=sc, window=window,
+    )
+    # The fused kernel also WRITES this token's K/V row: the pool it
+    # returns must equal the reference's scatter everywhere, not just
+    # where attention read it — exactly for a bf16 pool; within one
+    # int8 step for a quantized one (the kernel wrapper quantizes the
+    # f32 row, the reference the bf16 row).
+    pool_diff = float(np.max(np.abs(
+        np.asarray(got[1], np.float32) - np.asarray(want[1], np.float32)
+    )))
+    res = {
+        "max_err": _err(got[0], want[0]),
+        "pool_write_diff": pool_diff,
+        "pool_write_ok": pool_diff <= (1.0 if sc is not None else 0.0),
+    }
+    if sc is not None:
+        res["max_err"] = max(res["max_err"], _err(got[2], want[2]))
+    return res
+
+
 def _run_case(name: str, x: dict, interpret: bool) -> dict:
     int8 = "int8" in name
     fuse = False if name.endswith("per_head") else None
     kv = x["kv8"] if int8 else x["kv"]
     sc = x["scales"] if int8 else None
     layer = 1
+    if name == "fused_decode_window":
+        # One executable, as the scanned layer body has it: the window is
+        # a traced scalar, 0 on the layers that attend everything.
+        res = [
+            _fused_decode_case(x, kv, sc, layer, interpret, fuse, jnp.int32(w))
+            for w in (x["window"], 0)
+        ]
+        return max(res, key=lambda r: (not r["pool_write_ok"], r["max_err"]))
     if name.startswith("fused_decode"):
-        got = paged_decode_fused_kernel(
-            x["q"], x["k_new"], x["v_new"], kv, x["slots"], x["pt"],
-            x["lengths"], layer, interpret=interpret, kv_scales=sc,
-            fuse_heads=fuse,
-        )
-        want = paged_decode_attention(
-            x["q"], x["k_new"], x["v_new"], kv, x["slots"], x["pt"],
-            x["lengths"], layer, use_kernel=False, kv_scales=sc,
-        )
-        # The fused kernel also WRITES this token's K/V row: the pool it
-        # returns must equal the reference's scatter everywhere, not just
-        # where attention read it — exactly for a bf16 pool; within one
-        # int8 step for a quantized one (the kernel wrapper quantizes the
-        # f32 row, the reference the bf16 row).
-        pool_diff = float(np.max(np.abs(
-            np.asarray(got[1], np.float32) - np.asarray(want[1], np.float32)
-        )))
-        res = {
-            "max_err": _err(got[0], want[0]),
-            "pool_write_diff": pool_diff,
-            "pool_write_ok": pool_diff <= (1.0 if int8 else 0.0),
-        }
-        if int8:
-            res["max_err"] = max(res["max_err"], _err(got[2], want[2]))
-        return res
+        return _fused_decode_case(x, kv, sc, layer, interpret, fuse, None)
     if name.startswith("chunk_prefill"):
         pos = x["prior"][:, None] + jnp.arange(x["qc"].shape[1])[None]
         got = paged_chunk_attention_kernel(
@@ -206,6 +232,7 @@ def check_kernels(
     max_pages: int = 64,
     interpret: bool = False,
     seed: int = 0,
+    window: int = 0,
 ) -> list[dict]:
     """Run each named case at the given geometry; one result dict per
     case: ``{"kernel", "ok", "max_err", "tolerance"}`` with ``max_err``
@@ -217,7 +244,13 @@ def check_kernels(
         raise ValueError(
             f"chunk {chunk} exceeds the table's {max_pages * page} tokens"
         )
+    if "fused_decode_window" in cases and not 0 < window < max_pages * page:
+        raise ValueError(
+            f"fused_decode_window needs 0 < window ({window}) < the table's "
+            f"{max_pages * page} tokens: a context past the window"
+        )
     x = _inputs(hq, hkv, head_dim, page, batch, chunk, max_pages, seed)
+    x["window"] = window
     out = []
     for name in cases:
         try:

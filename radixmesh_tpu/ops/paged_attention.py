@@ -6,34 +6,45 @@ shared across requests that share a prefix), and decode attention must
 gather those pages without materializing a dense [B, max_ctx, H, D] copy in
 HBM — the copy is exactly the bandwidth decode can't afford.
 
-Round-5 redesign (VERDICT round-4 weak #1/#2: 23% HBM utilization on the
-decode hot path, int8 slower than bf16). On-chip, DMA *descriptor issue
-rate* — not bytes — bounded the round-4 kernels (B × Hkv × blocks × ppb × 2
-copies of 4 KB each per launch). Three structural changes:
+On-chip, DMA *descriptor issue rate* — not bytes — bounded the first
+kernels (B × Hkv × blocks × ppb × 2 copies of 4 KB each per launch), and
+then the block loop's fixed cost per block did (PERF.md §5, PR 32: ~0.35 µs
+a block whatever it holds, against 0.31 µs of transfer for 128 tokens).
+What the kernels do about both:
 
-1. **Run-coalesced block DMAs.** The slot allocator hands out whole pages
-   and sequences mostly extend in place, so a compute block's pages are
-   usually *consecutive* ids. The wrapper precomputes a per-(row, block)
-   flag (``_contig_flags``); flagged blocks move as ONE descriptor
-   (``src.at[pl.ds(first, ppb)]`` — contiguous per head, 32 KB+), unflagged
-   blocks fall back to per-page copies. Flags ride scalar prefetch, so the
-   gate costs one SMEM read per block.
+1. **Run-coalesced block DMAs.** Where a compute block's pages are
+   *consecutive* ids, the block moves as ONE descriptor
+   (``src.at[pl.ds(first, ppb)]``), otherwise page by page. The per-head
+   and chunk kernels read a per-(row, block) flag precomputed by the
+   wrapper (``_contig_flags``); the heads-batched kernels decide from a
+   running count of the table's breaks (``_mh_tables``), which answers for
+   a block that starts at ANY page. A pool under churn hands out pages in
+   no order (the allocator's free list is LIFO), so served tables mostly
+   take the per-page plan: it has to be cheap too.
 2. **Heads-batched programs by default.** ``fuse_heads`` (grid ``(B,)``,
-   all kv heads per DMA and per MXU contraction) is now the default
-   whenever the double-buffered block fits VMEM (``_auto_fuse_heads``);
-   the per-head grid ``(B, Hkv)`` remains for huge-Hkv configs and as an
-   explicit override. Together with (1): ~2 descriptors per *block* per
-   sequence instead of ~2 per *page* per (sequence, head) — two orders of
-   magnitude fewer descriptor issues at the headline shape.
-3. **Prepared scales for int8 pools.** Round 4 fetched per-token scale
-   rows inside the kernel (2 extra strided DMAs per page + lane-rotation
-   games — measured 0.688x bf16 on chip, the scale traffic costing more
-   than the halved KV bytes saved). Now the *wrapper* gathers the page
+   all kv heads per DMA and per MXU contraction) is the default whenever
+   the double-buffered block fits VMEM (``_auto_fuse_heads``); the
+   per-head grid ``(B, Hkv)`` remains for huge-Hkv configs and as an
+   explicit override. A heads-batched block is 512 tokens
+   (``_mh_pages_per_block``), K and V in one descriptor
+   (``_MhBlockCopy``): one descriptor and one wait per block when the
+   pages coalesce, one descriptor per PAGE (16 KB at Hkv 4 / D 128 bf16)
+   and still one wait when they do not. Only the pages a row still holds
+   are moved (its last block), and a window row's first block starts at
+   the page of its first visible token, so a large block reads no more
+   than a small one would.
+3. **The MXU sees the pool's dtype.** The heads-batched kernels contract a
+   bf16 block as fetched — ``q`` bf16 and unscaled, ``1/sqrt(D)`` on the
+   float32 scores, ``p`` bf16, float32 accumulation — and mask only a
+   row's edge blocks (``_mh_contract``). (The MXU made one bf16 pass over
+   float32 operands anyway — measured, PERF.md §6 PR 32 — so this loses
+   nothing and keeps ``q`` exact.)
+4. **Prepared scales for int8 pools.** The *wrapper* gathers the page
    table's scales in one XLA gather (``_prep_scales`` →
-   ``[2, B, Hkv, nblocks, bk]`` ≈ 4 MB/layer at the headline shape, ~3% of
-   the KV bytes int8 saves) and the kernel reads aligned ``(1, bk)`` rows
-   from a pipelined VMEM input — zero in-kernel scale DMAs, zero rotation,
-   and the page-size-divides-128 constraint disappears entirely.
+   ``[2, B, Hkv, nblocks, bk]``) and the kernel reads aligned ``(1, bk)``
+   rows from a pipelined VMEM input — zero in-kernel scale DMAs, and the
+   page-size-divides-128 constraint disappears. Int8 blocks are upcast
+   to float32 in VMEM (scores and probabilities are scaled per key).
 
 Design (shared by both grids):
 
@@ -42,11 +53,14 @@ Design (shared by both grids):
   so DMA source addresses are computable before the body runs.
 - Each program loops over *compute blocks* of ``pages_per_block`` pages,
   bounded by the sequence's true length — short sequences cost short
-  loops, not ``max_pages`` iterations.
+  loops, not ``max_pages`` iterations. ``pages_per_block=None`` means the
+  kernel chooses: 512 tokens heads-batched, ~256 per head.
 - Block DMAs are **chain-prefetched across grid steps**: while block ``i``
   is being contracted on the MXU, the copy for the *next* block — which
   may belong to the next program — is already in flight in the other half
-  of a double buffer. DMA latency is exposed once per kernel launch.
+  of a double buffer. DMA latency is exposed once per kernel launch. The
+  fused heads-batched kernel fetches the NEXT program's page for the row
+  write the same way (``_MhRowWrite``).
 - Online softmax (running max / sum / fp32 accumulator in VMEM scratch)
   across the block loop; GQA by blocking the query as [G, D] per kv head
   (per-head grid) or [Hkv, G, D] batched (fused-heads grid).
@@ -85,6 +99,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "decode_block_tokens",
     "paged_attention_kernel",
     "paged_attention_pool_kernel",
     "paged_chunk_attention_kernel",
@@ -242,31 +257,76 @@ class _BlockCopy(_GatedCopy):
             ]
 
 
-class _MhBlockCopy(_GatedCopy):
-    """All-heads analog of ``_BlockCopy``: each descriptor moves the
-    strided ``(Hkv, …)`` slab for every kv head — coalesced blocks as one
-    ``(Hkv, n_pages, page, D)`` descriptor (``Hkv`` segments of
-    ``n_pages·page·D`` contiguous bytes each), fragmented blocks as
-    per-page ``(Hkv, page, D)`` copies."""
+class _MhBlockCopy:
+    """All-heads analog of ``_BlockCopy``: K and V of every kv head in one
+    descriptor, and only the ``n_valid`` leading pages of the block — the
+    pages a row's last block still holds — are moved at all.
 
-    def __init__(self, kv_hbm, which, layer, buf, sem, page_table_ref,
-                 flat_offset, n_pages, contig):
-        src = kv_hbm.at[which, layer]  # [Hkv, P, page, D]
-        first = page_table_ref[flat_offset]
-        self._contig = contig
-        self._n = n_pages
-        self._run = pltpu.make_async_copy(
-            src.at[:, pl.ds(first, n_pages)], buf, sem
-        )
-        if n_pages > 1:
-            self._pages = [
+    A coalesced block (its pages one ascending run of ids: ``breaks_ref``,
+    see ``_mh_tables``) moves as one ``(2, Hkv, 2^k, page, D)`` slab per
+    set bit of ``n_valid``: a full power-of-two block is ONE descriptor
+    of ``2·Hkv`` contiguous segments, a row's last block at most ``log2``
+    of them. A fragmented block moves as ``n_valid`` copies of
+    ``(2, Hkv, page, D)``. Either plan is awaited
+    by the same waits: a DMA semaphore counts bytes, so one wait on a
+    ``2^k``-page view of the staging buffer per set bit of ``n_valid``
+    absorbs whatever descriptors brought those bytes — one wait for a full
+    block. ``start`` and ``wait`` read ``n_valid`` from the same SMEM
+    scalars, so issued and awaited bytes always match."""
+
+    def __init__(self, kv_hbm, layer, buf, sem, page_table_ref, breaks_ref,
+                 flat_offset, n_pages, n_valid):
+        self._src = kv_hbm.at[:, layer]  # [2, Hkv, P, page, D]
+        self._buf, self._sem = buf, sem
+        self._table, self._offset = page_table_ref, flat_offset
+        self._breaks = breaks_ref
+        self._n, self._valid = n_pages, n_valid
+
+    def _chunks(self, fn):
+        """``fn(offset, size)`` under ``pl.when`` for each power-of-two
+        chunk of the binary decomposition of ``n_valid``, largest first."""
+        size = 1 << (self._n.bit_length() - 1)
+        while size:
+            off = self._valid & ~(2 * size - 1)  # the bits above this one
+            pl.when((self._valid & size) != 0)(
+                functools.partial(fn, off, size)
+            )
+            size >>= 1
+
+    def start(self):
+        first = self._table[self._offset]
+        # The pages to move are one ascending run of ids when no break
+        # (``_mh_tables``) lies between the first and the last of them.
+        last = self._offset + jnp.maximum(self._valid, 1) - 1
+        contig = self._breaks[last] == self._breaks[self._offset]
+
+        def run(off, size):
+            pltpu.make_async_copy(
+                self._src.at[:, :, pl.ds(first + off, size)],
+                self._buf.at[:, :, pl.ds(off, size)],
+                self._sem,
+            ).start()
+
+        pl.when(contig)(lambda: self._chunks(run))
+
+        @pl.when(jnp.logical_not(contig))
+        def _():
+            def page_copy(i, _):
                 pltpu.make_async_copy(
-                    src.at[:, page_table_ref[flat_offset + i]],
-                    buf.at[:, i],
-                    sem,
-                )
-                for i in range(n_pages)
-            ]
+                    self._src.at[:, :, self._table[self._offset + i]],
+                    self._buf.at[:, :, i],
+                    self._sem,
+                ).start()
+                return ()
+
+            jax.lax.fori_loop(0, self._valid, page_copy, ())
+
+    def wait(self):
+        def absorb(off, size):
+            view = self._buf.at[:, :, pl.ds(0, size)]
+            pltpu.make_async_copy(view, view, self._sem).wait()
+
+        self._chunks(absorb)
 
 
 def _run_block_loop(
@@ -470,21 +530,87 @@ def _kernel(
         o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
+def _mh_contract(
+    *, i, first_pos, kv_blk, q, scale, masked, visible, prep_ref,
+    m_scr, l_scr, acc_scr,
+):
+    """One staged block ``kv_blk`` ``(2, Hkv, ppb, page, D)`` through the
+    online softmax. The MXU sees ``q`` and K/V in ``q``'s dtype — the
+    pool's own for a float pool, so a bf16 block is contracted as it was
+    fetched, with float32 accumulation (a product of two bf16 values is
+    exact in float32: q·k loses nothing against float32 operands), and
+    ``1/sqrt(D)`` (``scale``) is applied to the float32 scores. Scores,
+    running max and sum, ``exp``, correction and accumulator are float32;
+    ``p`` enters p·v in the operand dtype. ``masked`` is static: an
+    interior block holds only visible positions and runs with no select;
+    an edge block (a row's last, a window row's first) zeroes BOTH
+    factors of p·v at every invisible position (see ``_contig_flags``)."""
+    _, Hkv, ppb, page, D = kv_blk.shape
+    bk = ppb * page
+    quantized = prep_ref is not None
+    # (Hkv, ppb, page, D) -> (Hkv, bk, D): middle collapse, minor dim
+    # untouched — a supported relayout-free reshape.
+    k = kv_blk[0].reshape(Hkv, bk, D).astype(q.dtype)
+    s = jax.lax.dot_general(  # (Hkv, G, bk), heads-batched MXU
+        q, k,
+        dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )
+    if scale is not None:
+        s = s * scale
+    if quantized:
+        s = s * prep_ref[0, :, pl.ds(i, 1), :]  # (Hkv, 1, bk) k-scales
+    if masked:
+        seen = visible(
+            first_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        )
+        s = jnp.where(seen, s, _MASK)
+
+    m_prev = m_scr[...]
+    m_blk = jnp.max(s, axis=-1, keepdims=True)  # (Hkv, G, 1)
+    m_new = jnp.maximum(m_prev, m_blk)  # lane-replicated (Hkv, G, D)
+    p = jnp.exp(s - m_new[:, :, :1])  # (Hkv, G, bk)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_scr[...] = m_new
+
+    if quantized:
+        p = p * prep_ref[1, :, pl.ds(i, 1), :]  # (Hkv, 1, bk) v-scales
+    v = kv_blk[1].reshape(Hkv, bk, D)
+    if masked:
+        # Exact zeros at masked positions (see _run_block_loop): pages
+        # past a row's end were not moved, so the buffer holds there
+        # whatever it held; NaN/Inf in it (or in pad scale rows) must not
+        # ride 0·NaN into the accumulator.
+        p = jnp.where(seen, p, 0.0)
+        v = jnp.where(
+            visible(first_pos + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)),
+            v.astype(jnp.float32),
+            0.0,
+        )
+    pv = jax.lax.dot_general(  # (Hkv, G, D)
+        p.astype(q.dtype), v.astype(q.dtype),
+        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )
+    acc_scr[...] = acc_scr[...] * corr + pv
+
+
 def _mh_block_loop(
     *,
     b,
     layer,
-    hbm_len,  # tokens resident in HBM pages for THIS program's sequence
-    q,  # (Hkv, G, D) f32, pre-scaled
+    tail: int,  # trailing tokens of lengths_ref that are NOT in pool pages
+    q,  # (Hkv, G, D) MXU operand (see _mh_operands)
+    scale,  # applied to the f32 scores; None when q arrives pre-scaled
     lengths_ref,
-    page_table_ref,
-    contig_ref,  # SMEM [B * nblocks] coalescing flags
+    page_table_ref,  # SMEM [B * pages_per_seq + ppb] (_mh_tables)
+    breaks_ref,  # SMEM, same shape: breaks of consecutiveness (_mh_tables)
     buffer_index_ref,
     init_flag_ref,
     kv_hbm,
-    k_buf,
-    v_buf,
-    sems,
+    kv_buf,  # VMEM (2, 2, Hkv, ppb, page, D): double buffer of K+V blocks
+    sems,  # DMA (2,): one per buffer half
     m_scr,
     l_scr,
     acc_scr,
@@ -492,16 +618,24 @@ def _mh_block_loop(
     pages_per_block: int,
     pages_per_seq: int,
     batch_size: int,
-    num_kv_heads: int,
-    min_length: int,  # lengths_ref value below which a row has no HBM work
     prep_ref=None,  # VMEM (2, Hkv, nblocks, bk) f32 prepared scales
     starts_ref=None,  # SMEM [B] first visible token per row (window layers)
 ):
     """The heads-batched analog of ``_run_block_loop``: one program per
-    SEQUENCE, ``(Hkv, G, ·)`` batched MXU contractions, chain-prefetched
-    ``_MhBlockCopy`` DMAs (one descriptor per block per K/V when the
-    block's pages coalesce). Shared by the read-only and fused mh kernels
-    (min_length 1 / 2, exactly like the per-head pair).
+    SEQUENCE, ``(Hkv, G, ·)`` batched MXU contractions (``_mh_contract``),
+    chain-prefetched ``_MhBlockCopy`` DMAs. Shared by the read-only and
+    fused mh kernels (``tail`` 0 / 1: the fused kernel folds the current
+    token in from VMEM).
+
+    A row's blocks are counted from the PAGE that holds its first visible
+    token: entry ``j`` of block ``i`` is position ``base + i·bk + j``,
+    read through table column ``base // page + i·ppb + j // page``, with
+    ``base`` 0 on a layer that attends everything. So a window row reads
+    at most ``page - 1`` keys it then masks, whatever the block size, and
+    only a row's LAST block (the length bound, and pages past it that were
+    never fetched) and its FIRST (the window start) can hold an invisible
+    position: those take the masked contraction, every block between them
+    the plain one.
 
     DELIBERATE duplication of ``_run_block_loop``'s machinery (parity
     pinned by tests/test_ops.py): the per-head grid survives as the
@@ -509,44 +643,55 @@ def _mh_block_loop(
     merging a head axis into it would couple both paths' shapes. (The
     GQA group axis rides implicitly in ``q``'s shape.)"""
     bk = page * pages_per_block
-    nblocks = pages_per_seq // pages_per_block
-    Hkv = num_kv_heads
-    quantized = prep_ref is not None
-    first_block, visible = _window_bounds(starts_ref, b, batch_size, bk, hbm_len)
 
-    def block_copies(bb, ii, slot):
-        off = bb * pages_per_seq + ii * pages_per_block
-        contig = contig_ref[bb * nblocks + ii]
-        return [
-            _MhBlockCopy(kv_hbm, 0, layer, k_buf.at[slot], sems.at[slot, 0],
-                         page_table_ref, off, pages_per_block, contig),
-            _MhBlockCopy(kv_hbm, 1, layer, v_buf.at[slot], sems.at[slot, 1],
-                         page_table_ref, off, pages_per_block, contig),
-        ]
+    def span(bb):
+        """``(base, tokens)`` of row ``bb``: its first position read from
+        pool pages (page-aligned) and how many follow up to its last."""
+        bb = jax.lax.clamp(0, bb, batch_size - 1)
+        hbm = lengths_ref[bb] - tail
+        if starts_ref is None:
+            return 0, hbm
+        base = starts_ref[bb] // page * page
+        return base, hbm - base
+
+    def block_copy(bb, ii, slot):
+        first, tokens = span(bb)
+        return _MhBlockCopy(
+            kv_hbm, layer, kv_buf.at[slot], sems.at[slot], page_table_ref,
+            breaks_ref,
+            bb * pages_per_seq + first // page + ii * pages_per_block,
+            pages_per_block,
+            jnp.clip(pl.cdiv(tokens, page) - ii * pages_per_block,
+                     0, pages_per_block),
+        )
+
+    base, tokens = span(b)
+    last = pl.cdiv(tokens, bk) - 1
 
     def next_indices(i):
         """Grid-order successor of block ``i`` of program ``b``, skipping
-        sequences with no HBM work (mirrors ``_run_block_loop`` minus the
-        head axis)."""
+        sequences with nothing to read from pool pages."""
 
         def advance_b():
             nb = jax.lax.fori_loop(
                 b + 1,
                 batch_size,
                 lambda _, x: jnp.where(
-                    jnp.logical_and(
-                        x < batch_size,
-                        lengths_ref[jax.lax.clamp(0, x, batch_size - 1)]
-                        < min_length,
-                    ),
+                    jnp.logical_and(x < batch_size, span(x)[1] <= 0),
                     x + 1,
                     x,
                 ),
                 b + 1,
             )
-            return (nb, first_block(nb))
+            return (nb, 0)
 
-        return jax.lax.cond(i * bk < hbm_len, lambda: (b, i), advance_b)
+        return jax.lax.cond(i <= last, lambda: (b, i), advance_b)
+
+    def visible(pos):
+        seen = pos < base + tokens
+        if starts_ref is not None:
+            seen = seen & (pos >= starts_ref[b])
+        return seen
 
     m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -560,68 +705,82 @@ def _mh_block_loop(
 
         @pl.when(init_flag)
         def _cold_start():
-            for c in block_copies(b, i, slot):
-                c.start()
+            block_copy(b, i, slot).start()
 
         @pl.when(nb < batch_size)
         def _prefetch_next():
             nslot = jnp.where(slot == 0, 1, 0)
-            for c in block_copies(nb, ni, nslot):
-                c.start()
+            block_copy(nb, ni, nslot).start()
             buffer_index_ref[0] = nslot
 
-        cs = block_copies(b, i, slot)
-        cs[0].wait()
-        # (Hkv, ppb, page, D) → (Hkv, bk, D): middle collapse, minor
-        # dim untouched — a supported relayout-free reshape.
-        k = k_buf[slot].astype(jnp.float32).reshape(Hkv, bk, -1)
-        s = jax.lax.dot_general(  # (Hkv, G, bk), heads-batched MXU
-            q, k,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+        block_copy(b, i, slot).wait()
+        contract = functools.partial(
+            _mh_contract, i=i, first_pos=base + i * bk, kv_blk=kv_buf.at[slot],
+            q=q, scale=scale, visible=visible, prep_ref=prep_ref,
+            m_scr=m_scr, l_scr=l_scr, acc_scr=acc_scr,
         )
-        if quantized:
-            s = s * prep_ref[0, :, pl.ds(i, 1), :]  # (Hkv, 1, bk) k-scales
-        pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(visible(pos), s, _MASK)
-
-        m_prev = m_scr[...]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)  # (Hkv, G, 1)
-        m_new = jnp.maximum(m_prev, m_blk)  # lane-replicated (Hkv, G, D)
-        p = jnp.exp(s - m_new[:, :, :1])  # (Hkv, G, bk)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[...] = m_new
-
-        cs[1].wait()
-        if quantized:
-            p = p * prep_ref[1, :, pl.ds(i, 1), :]  # (Hkv, 1, bk) v-scales
-        v = v_buf[slot].astype(jnp.float32).reshape(Hkv, bk, -1)
-        # Exact zeros at masked positions (see _run_block_loop): pad
-        # fetches may stage unreferenced pages; NaN/Inf there (or in pad
-        # scale rows) must not ride 0·NaN into the accumulator.
-        p = jnp.where(visible(pos), p, 0.0)
-        v = jnp.where(
-            visible(i * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)),
-            v,
-            0.0,
-        )
-        pv = jax.lax.dot_general(  # (Hkv, G, D)
-            p, v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[...] = acc_scr[...] * corr + pv
+        edge = i == last
+        if starts_ref is not None:
+            edge = edge | (i == 0)
+        pl.when(edge)(lambda: contract(masked=True))
+        pl.when(jnp.logical_not(edge))(lambda: contract(masked=False))
         return ()
 
-    jax.lax.fori_loop(first_block(b), pl.cdiv(hbm_len, bk), body, ())
+    jax.lax.fori_loop(0, last + 1, body, ())
+
+
+def _mh_tables(page_table, page: int, pages_per_block: int | None):
+    """``(table [B, padded], ppb, padded, flat table, flat breaks)`` of a
+    heads-batched call. ``breaks[b, j]`` counts the columns ``1..j`` of row
+    ``b`` whose page id does not follow its left neighbour's, so columns
+    ``[s, e]`` hold one ascending run of ids iff ``breaks[e] == breaks[s]``
+    — for any ``s``: a window row's blocks start at the page of its first
+    visible token, not on a block multiple. The flat arrays carry ``ppb``
+    entries past the last row, where such a block's unused tail may
+    point."""
+    page_table, ppb, padded = _block_geometry(page_table, page, pages_per_block)
+    pt = jnp.asarray(page_table, jnp.int32)
+    step = (pt[:, 1:] != pt[:, :-1] + 1).astype(jnp.int32)
+    breaks = jnp.pad(jnp.cumsum(step, axis=1), ((0, 0), (1, 0)))
+
+    def flat(a):
+        return jnp.pad(a.reshape(-1), (0, ppb))
+
+    return pt, ppb, padded, flat(pt), flat(breaks)
+
+
+def _mh_operands(q, kv_dtype, quantized: bool, group: int):
+    """``(q [B, Hkv, G, D], scale)`` as the mh kernels take them. A float
+    pool at least as wide as ``q``: ``q`` in the pool's dtype, unscaled,
+    and ``1/sqrt(D)`` for the kernel to apply to its float32 scores — the
+    MXU then contracts the pool's blocks as fetched. An int8 pool, or a
+    ``q`` wider than the pool: float32 ``q`` scaled here (``scale`` None)
+    against blocks upcast in VMEM, as these kernels always computed."""
+    B, Hq, D = q.shape
+    scale = 1.0 / (D ** 0.5)
+    if quantized or jnp.promote_types(q.dtype, kv_dtype) != kv_dtype:
+        q, scale = q.astype(jnp.float32) * scale, None
+    q = q.astype(jnp.float32 if scale is None else kv_dtype).reshape(
+        B, Hq // group, group, D
+    )
+    # A group fills whole 8-row tiles (zero rows, dropped by _mh_unpad):
+    # the registers hold 8 rows whatever G is.
+    return jnp.pad(q, ((0, 0), (0, 0), (0, -group % 8), (0, 0))), scale
+
+
+def _mh_unpad(out, q):
+    """``[B, Hkv, Gp, D]`` float32 kernel output -> ``[B, Hq, D]`` in
+    ``q``'s dtype."""
+    B, Hq, D = q.shape
+    G = Hq // out.shape[1]
+    return out[:, :, :G].reshape(B, Hq, D).astype(q.dtype)
 
 
 def _mh_kernel(
     # scalar prefetch
     lengths_ref,  # SMEM [B]
-    page_table_ref,  # SMEM [B * blocks_padded * ppb] flattened
-    contig_ref,  # SMEM [B * nblocks]
+    page_table_ref,  # SMEM [B * padded + ppb] flattened (_mh_tables)
+    breaks_ref,  # SMEM, same shape (_mh_tables)
     layer_ref,  # SMEM [1]
     buffer_index_ref,  # SMEM [1]
     init_flag_ref,  # SMEM [1]
@@ -630,49 +789,107 @@ def _mh_kernel(
     pages_per_block: int,
     pages_per_seq: int,
     batch_size: int,
-    num_kv_heads: int,
-    group: int,
+    scale: float | None,
     quantized: bool,
 ):
     """Heads-fused read-only pool attention: grid ``(B,)`` (see
-    ``_mh_block_loop``)."""
+    ``_mh_block_loop``; ``q_ref`` and ``scale`` from ``_mh_operands``)."""
     if quantized:
         (q_ref, kv_hbm, prep_ref, o_ref,
-         m_scr, l_scr, acc_scr, k_buf, v_buf, sems) = refs
+         m_scr, l_scr, acc_scr, kv_buf, sems) = refs
     else:
-        q_ref, kv_hbm, o_ref, m_scr, l_scr, acc_scr, k_buf, v_buf, sems = refs
+        q_ref, kv_hbm, o_ref, m_scr, l_scr, acc_scr, kv_buf, sems = refs
         prep_ref = None
     b = pl.program_id(0)
     layer = layer_ref[0]
     length = lengths_ref[b]
-    Hkv, G = num_kv_heads, group
 
     o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(length > 0)
     def _program():
-        q = q_ref[...].astype(jnp.float32).reshape(Hkv, G, -1)  # pre-scaled
         _mh_block_loop(
-            b=b, layer=layer, hbm_len=length, q=q,
+            b=b, layer=layer, tail=0, q=q_ref[...], scale=scale,
             lengths_ref=lengths_ref, page_table_ref=page_table_ref,
-            contig_ref=contig_ref,
+            breaks_ref=breaks_ref,
             buffer_index_ref=buffer_index_ref, init_flag_ref=init_flag_ref,
-            kv_hbm=kv_hbm, k_buf=k_buf, v_buf=v_buf, sems=sems,
+            kv_hbm=kv_hbm, kv_buf=kv_buf, sems=sems,
             m_scr=m_scr, l_scr=l_scr, acc_scr=acc_scr,
             page=page, pages_per_block=pages_per_block,
             pages_per_seq=pages_per_seq, batch_size=batch_size,
-            num_kv_heads=num_kv_heads, min_length=1,
             prep_ref=prep_ref,
         )
-        out = acc_scr[...] / l_scr[...]
-        o_ref[...] = out.reshape(Hkv * G, -1).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+class _MhRowWrite:
+    """The current token's K/V row of every kv head into its pool page,
+    through the ALIASED output (see ``_fused_kernel``): sublane tiling
+    forbids a partial slice on the page axis, so the whole
+    ``(2, Hkv, page, D)`` page is read, one row replaced, and written
+    back (every other row byte-identical, so racing block reads are
+    unaffected). The read of the NEXT program's page is started before
+    this program's block loop and lands behind it — a program waits for a
+    page that is already there — and the write-back lands behind the loop
+    as well. Rows write distinct pages; an idle row (length 0) is neither
+    read nor written."""
+
+    def __init__(self, kv_out, layer, slots_ref, lengths_ref, row_scr, sems,
+                 page: int, batch_size: int):
+        self._kv, self._layer = kv_out, layer
+        self._slots, self._lengths = slots_ref, lengths_ref
+        self._scr, self._sems = row_scr, sems  # (2, 2, Hkv, page, D); DMA (3,)
+        self._page, self._B = page, batch_size
+
+    def _live(self, bb):
+        return self._lengths[jax.lax.clamp(0, bb, self._B - 1)] > 0
+
+    def _window(self, bb):
+        slot = self._slots[jax.lax.clamp(0, bb, self._B - 1)]
+        return self._kv.at[:, self._layer, :, slot // self._page]
+
+    def _read(self, bb):
+        half = jax.lax.rem(bb, 2)
+        return pltpu.make_async_copy(
+            self._window(bb), self._scr.at[half], self._sems.at[half]
+        )
+
+    def _write(self, b):
+        return pltpu.make_async_copy(
+            self._scr.at[jax.lax.rem(b, 2)], self._window(b), self._sems.at[2]
+        )
+
+    def start(self, b, k_new, v_new):
+        """Program ``b``: fetch ahead, then replace row ``slot % page`` of
+        this program's page by ``k_new`` / ``v_new`` ``(Hkv, 1, D)`` and
+        start the write-back."""
+        pl.when((b == 0) & self._live(0))(lambda: self._read(0).start())
+        pl.when((b + 1 < self._B) & self._live(b + 1))(
+            lambda: self._read(b + 1).start()
+        )
+
+        @pl.when(self._live(b))
+        def _():
+            self._read(b).wait()
+            half = jax.lax.rem(b, 2)
+            shape = self._scr.shape[2:]  # (Hkv, page, D)
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            mask = row == self._slots[b] % self._page
+            for which, new in enumerate((k_new, v_new)):
+                self._scr[half, which] = jnp.where(
+                    mask, jnp.broadcast_to(new, shape), self._scr[half, which]
+                )
+            self._write(b).start()
+
+    def finish(self, b):
+        pl.when(self._live(b))(lambda: self._write(b).wait())
 
 
 def _mh_fused_kernel(
     # scalar prefetch
     lengths_ref,  # SMEM [B] context length INCLUDING the current token
-    page_table_ref,  # SMEM [B * blocks_padded * ppb] flattened
-    contig_ref,  # SMEM [B * nblocks]
+    page_table_ref,  # SMEM [B * padded + ppb] flattened (_mh_tables)
+    breaks_ref,  # SMEM, same shape (_mh_tables)
     slots_ref,  # SMEM [B] pool slot receiving this token's K/V
     layer_ref,  # SMEM [1]
     buffer_index_ref,  # SMEM [1]
@@ -682,102 +899,77 @@ def _mh_fused_kernel(
     pages_per_block: int,
     pages_per_seq: int,
     batch_size: int,
-    num_kv_heads: int,
-    group: int,
+    scale: float | None,
     quantized: bool,
     windowed: bool = False,
 ):
     """Heads-fused decode step: the ``_fused_kernel`` contract (write the
     current token's K/V row through the aliased pool output, fold it in
     from VMEM) at grid ``(B,)`` — the page-row RMW also moves all heads
-    per DMA (2 reads + 2 writes per SEQUENCE instead of per (b, h)).
+    and both of K and V per DMA (``_MhRowWrite``: one read + one write per
+    SEQUENCE instead of two of each per (b, h)).
     Quantized pools receive the row already quantized (``k_new``/``v_new``
     int8, written verbatim) PLUS its dequantized twin (``k_deq``/``v_deq``
     = int8 · scale, computed by the wrapper) for the VMEM fold-in — so
     attention sees bit-exactly what any later pool read will see, with
     zero in-kernel scale handling. The scale POOL is updated by the
     wrapper with one XLA scatter. ``windowed``: one more scalar-prefetch
-    array, the first visible token of each row (``_window_bounds``)."""
+    array, the first visible token of each row (``_window_starts``)."""
     starts_ref = None
     if windowed:
         starts_ref, *refs = refs
     if quantized:
         (q_ref, k_new_ref, v_new_ref, k_deq_ref, v_deq_ref, kv_hbm, prep_ref,
          kv_out, o_ref,
-         m_scr, l_scr, acc_scr, k_buf, v_buf, row_scr, sems, w_sem) = refs
+         m_scr, l_scr, acc_scr, kv_buf, row_scr, sems, w_sems) = refs
     else:
         (q_ref, k_new_ref, v_new_ref, kv_hbm,
          kv_out, o_ref,
-         m_scr, l_scr, acc_scr, k_buf, v_buf, row_scr, sems, w_sem) = refs
+         m_scr, l_scr, acc_scr, kv_buf, row_scr, sems, w_sems) = refs
         k_deq_ref, v_deq_ref, prep_ref = k_new_ref, v_new_ref, None
     b = pl.program_id(0)
     layer = layer_ref[0]
     length = lengths_ref[b]
-    hbm_len = length - 1
-    Hkv, G = num_kv_heads, group
-
-    slot = slots_ref[b]
-    pg, off = slot // page, slot % page
-
-    def page_window(which):
-        return kv_out.at[which, layer, :, pg]  # (Hkv, page, D) strided
-
-    rk = pltpu.make_async_copy(page_window(0), row_scr.at[0], w_sem)
-    rv = pltpu.make_async_copy(page_window(1), row_scr.at[1], w_sem)
-    wk = pltpu.make_async_copy(row_scr.at[0], page_window(0), w_sem)
-    wv = pltpu.make_async_copy(row_scr.at[1], page_window(1), w_sem)
 
     k_cur = k_deq_ref[...].astype(jnp.float32)  # (Hkv, 1, D)
     v_cur = v_deq_ref[...].astype(jnp.float32)
 
     o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(length > 0)
-    def _write():
-        rk.start()
-        rv.start()
-        rk.wait()
-        rv.wait()
-        mask = jax.lax.broadcasted_iota(jnp.int32, row_scr.shape[1:], 1) == off
-        row_scr[0] = jnp.where(
-            mask, jnp.broadcast_to(k_new_ref[...], row_scr.shape[1:]), row_scr[0]
-        )
-        row_scr[1] = jnp.where(
-            mask, jnp.broadcast_to(v_new_ref[...], row_scr.shape[1:]), row_scr[1]
-        )
-        wk.start()
-        wv.start()
+    row = _MhRowWrite(
+        kv_out, layer, slots_ref, lengths_ref, row_scr, w_sems, page, batch_size
+    )
+    row.start(b, k_new_ref[...], v_new_ref[...])
 
     @pl.when(length > 0)
     def _program():
-        q = q_ref[...].astype(jnp.float32).reshape(Hkv, G, -1)  # pre-scaled
+        q = q_ref[...]  # (Hkv, G, D)
         _mh_block_loop(
-            b=b, layer=layer, hbm_len=hbm_len, q=q,
+            b=b, layer=layer, tail=1, q=q, scale=scale,
             lengths_ref=lengths_ref, page_table_ref=page_table_ref,
-            contig_ref=contig_ref,
+            breaks_ref=breaks_ref,
             buffer_index_ref=buffer_index_ref, init_flag_ref=init_flag_ref,
-            kv_hbm=kv_hbm, k_buf=k_buf, v_buf=v_buf, sems=sems,
+            kv_hbm=kv_hbm, kv_buf=kv_buf, sems=sems,
             m_scr=m_scr, l_scr=l_scr, acc_scr=acc_scr,
             page=page, pages_per_block=pages_per_block,
             pages_per_seq=pages_per_seq, batch_size=batch_size,
-            num_kv_heads=num_kv_heads, min_length=2,
             prep_ref=prep_ref, starts_ref=starts_ref,
         )
-        s_cur = jax.lax.dot_general(  # (Hkv, G, 1)
-            q, k_cur,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+        # The current token's score on the VPU: one key a head, float32
+        # products of the values the MXU would have been given.
+        s_cur = jnp.sum(  # (Hkv, G, 1)
+            q.astype(jnp.float32) * k_cur, axis=-1, keepdims=True
         )
+        if scale is not None:
+            s_cur = s_cur * scale
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s_cur)
         p_cur = jnp.exp(s_cur - m_new[:, :, :1])  # (Hkv, G, 1)
         corr = jnp.exp(m_prev - m_new)
         l_fin = l_scr[...] * corr + p_cur
         acc_fin = acc_scr[...] * corr + p_cur * v_cur
-        out = acc_fin / l_fin
-        o_ref[...] = out.reshape(Hkv * G, -1).astype(o_ref.dtype)
-        wk.wait()
-        wv.wait()
+        o_ref[...] = (acc_fin / l_fin).astype(o_ref.dtype)
+
+    row.finish(b)
 
 
 def _fused_kernel(
@@ -894,15 +1086,21 @@ def _fused_kernel(
         wv.wait()
 
 
+def _per_head_pages_per_block(page: int, max_pages: int) -> int:
+    """The per-head grids' and the chunk kernel's default: ~256 tokens of
+    one head per compute block."""
+    return max(1, min(max_pages, -(-256 // page)))
+
+
 def _block_geometry(page_table, page: int, pages_per_block: int | None):
     """(padded page table, ppb, padded max_pages): pad max_pages up to a
     block multiple (the pad entries index page 0, whose reads are masked
     by the length bound like every other table pad)."""
     max_pages = page_table.shape[1]
     if pages_per_block is None:
-        # ~256 tokens per compute block: large enough to amortize per-block
-        # overhead, small enough that double-buffered K+V fits VMEM easily.
-        pages_per_block = max(1, min(max_pages, -(-256 // page)))
+        # (The heads-batched wrappers never get here with None: they pass
+        # ``_mh_pages_per_block``'s 512 tokens.)
+        pages_per_block = _per_head_pages_per_block(page, max_pages)
     ppb = min(pages_per_block, max_pages)
     blocks = -(-max_pages // ppb)
     padded = blocks * ppb
@@ -911,19 +1109,53 @@ def _block_geometry(page_table, page: int, pages_per_block: int | None):
     return page_table, ppb, padded
 
 
+def _mh_pages_per_block(
+    Hkv: int, page: int, D: int, dtype, max_pages: int
+) -> int:
+    """Pages per compute block of the heads-batched kernels when the
+    caller names none: 512 tokens, halved until the double-buffered K+V
+    blocks of all heads fit 4 MiB of VMEM, and never wider than the page
+    table. A block costs the loop ~0.35 us whatever it holds (the chain
+    scores -> max -> exp -> p·v does not overlap with the next block's,
+    and a DMA pays its latency once), against 0.31 us per 128 tokens of
+    transfer at Hkv 4 / D 128 bf16: at 128 tokens the overhead was the
+    larger half, at 512 the loop runs at the speed of its copies (PERF.md
+    §5). A large block wastes nothing at a row's end or a window's start:
+    ``_MhBlockCopy`` moves only the pages a row still holds, and
+    ``_mh_tables`` starts a window row at page granularity."""
+    ppb = max(1, 512 // page)
+    block = 2 * 2 * Hkv * page * D * jnp.dtype(dtype).itemsize
+    while ppb > 1 and ppb * block > 4 * 2**20:
+        ppb //= 2
+    return max(1, min(ppb, max_pages))
+
+
+def decode_block_tokens(
+    Hkv: int, page: int, D: int, dtype, max_pages: int, quantized: bool = False
+) -> int:
+    """Tokens of one compute block of ``paged_decode_fused_kernel`` when
+    its caller names no ``pages_per_block`` — what ``/debug/state``
+    ``attn.decode_block_tokens`` shows: the heads-batched kernel's choice
+    where ``_auto_fuse_heads`` takes that grid, the per-head default
+    (``_block_geometry``) elsewhere."""
+    if _auto_fuse_heads(Hkv, page, D, dtype, max_pages, None, quantized):
+        return page * _mh_pages_per_block(Hkv, page, D, dtype, max_pages)
+    return page * _per_head_pages_per_block(page, max_pages)
+
+
 def _auto_fuse_heads(
     Hkv: int, page: int, D: int, dtype, max_pages: int,
     pages_per_block: int | None, quantized: bool,
 ) -> bool:
     """Default ``fuse_heads`` policy: heads-batched programs whenever the
     VMEM the mh wrapper would actually allocate — the double-buffered
-    all-heads K+V blocks at the CALLER's ``pages_per_block`` (mh default
-    when unset), plus the int8 prepared-scales input block — stays within
-    an 8 MB budget (production GQA shapes — Hkv 8, page 16, D 128 bf16 —
-    sit near 1 MB). The per-head grid remains for huge-Hkv/page/block
-    configs."""
+    all-heads K+V blocks at the CALLER's ``pages_per_block``
+    (``_mh_pages_per_block`` when unset: at most 4 MiB), plus the int8
+    prepared-scales input block — stays within an 8 MB budget (Hkv 4,
+    page 16, D 128 bf16 at 512 tokens: 2 MiB). The per-head grid remains
+    for huge-Hkv/page/block configs."""
     if pages_per_block is None:
-        pages_per_block = max(1, -(-128 // page))
+        pages_per_block = _mh_pages_per_block(Hkv, page, D, dtype, max_pages)
     ppb = min(pages_per_block, max_pages)
     itemsize = jnp.dtype(dtype).itemsize
     vmem = 2 * 2 * Hkv * ppb * page * D * itemsize
@@ -1043,23 +1275,23 @@ def _pool_kernel_mh(
     pages_per_block: int | None = None, interpret: bool = False,
     kv_scales=None,
 ):
-    """Heads-batched pool attention wrapper (see ``_mh_kernel``). Smaller
-    default blocks than the per-head kernel: each staged block is
-    ``Hkv ×`` bigger, so bk=128 keeps the double buffers ≤ ~1 MB VMEM
-    at Hkv=8/D=128 bf16 — and bk=128 also means a ctx-128 row costs
-    exactly one coalesced descriptor pair (the short-context regime)."""
+    """Heads-batched pool attention wrapper (see ``_mh_kernel``); block
+    size from ``_mh_pages_per_block`` where the caller names none."""
     B, Hq, D = q.shape
     _, _, Hkv, P, page, _ = kv_pages.shape
     G = Hq // Hkv
     quantized = kv_scales is not None
     if pages_per_block is None:
-        pages_per_block = max(1, -(-128 // page))
-    page_table, ppb, padded = _block_geometry(page_table, page, pages_per_block)
-    contig = _contig_flags(page_table, lengths, page, ppb, P)
+        pages_per_block = _mh_pages_per_block(
+            Hkv, page, D, kv_pages.dtype, page_table.shape[1]
+        )
+    page_table, ppb, padded, flat_table, breaks = _mh_tables(
+        page_table, page, pages_per_block
+    )
 
-    scale = 1.0 / (D ** 0.5)
-    q4 = (q.astype(jnp.float32) * scale).reshape(B, Hq, 1, D)
-    q_spec = pl.BlockSpec((None, Hq, None, D), lambda b, *_: (b, 0, 0, 0))
+    q4, scale = _mh_operands(q, kv_pages.dtype, quantized, G)
+    Gp = q4.shape[2]
+    q_spec = pl.BlockSpec((None, Hkv, Gp, D), lambda b, *_: (b, 0, 0, 0))
 
     kernel = functools.partial(
         _mh_kernel,
@@ -1067,8 +1299,7 @@ def _pool_kernel_mh(
         pages_per_block=ppb,
         pages_per_seq=padded,
         batch_size=B,
-        num_kv_heads=Hkv,
-        group=G,
+        scale=scale,
         quantized=quantized,
     )
     in_specs = [q_spec, pl.BlockSpec(memory_space=pl.ANY)]
@@ -1080,12 +1311,11 @@ def _pool_kernel_mh(
             )
         )
     scratch = [
-        pltpu.VMEM((Hkv, G, D), jnp.float32),
-        pltpu.VMEM((Hkv, G, D), jnp.float32),
-        pltpu.VMEM((Hkv, G, D), jnp.float32),
-        pltpu.VMEM((2, Hkv, ppb, page, D), kv_pages.dtype),
-        pltpu.VMEM((2, Hkv, ppb, page, D), kv_pages.dtype),
-        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((Hkv, Gp, D), jnp.float32),
+        pltpu.VMEM((Hkv, Gp, D), jnp.float32),
+        pltpu.VMEM((Hkv, Gp, D), jnp.float32),
+        pltpu.VMEM((2, 2, Hkv, ppb, page, D), kv_pages.dtype),
+        pltpu.SemaphoreType.DMA((2,)),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
@@ -1096,8 +1326,8 @@ def _pool_kernel_mh(
     )
     args = [
         jnp.asarray(lengths, dtype=jnp.int32),
-        jnp.asarray(page_table, dtype=jnp.int32).reshape(-1),
-        contig,
+        flat_table,
+        breaks,
         jnp.asarray(layer, dtype=jnp.int32).reshape(1),
         jnp.zeros((1,), jnp.int32),
         jnp.ones((1,), jnp.int32),
@@ -1109,14 +1339,14 @@ def _pool_kernel_mh(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, Gp, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
         name="radixmesh_pool_attn_mh",
     )(*args)
-    return out.reshape(B, Hq, D).astype(q.dtype)
+    return _mh_unpad(out, q)
 
 
 def _window_starts(lengths, window):
@@ -1140,11 +1370,13 @@ def _fused_decode_mh(
     G = Hq // Hkv
     quantized = kv_scales is not None
     if pages_per_block is None:
-        pages_per_block = max(1, -(-128 // page))
-    page_table, ppb, padded = _block_geometry(page_table, page, pages_per_block)
+        pages_per_block = _mh_pages_per_block(
+            Hkv, page, D, kv_pages.dtype, page_table.shape[1]
+        )
     lengths = jnp.asarray(lengths, dtype=jnp.int32)
-    contig = _contig_flags(
-        page_table, jnp.maximum(lengths - 1, 0), page, ppb, P
+    starts = None if window is None else _window_starts(lengths, window)
+    page_table, ppb, padded, flat_table, breaks = _mh_tables(
+        page_table, page, pages_per_block
     )
 
     if quantized:
@@ -1169,9 +1401,9 @@ def _fused_decode_mh(
     else:
         k_deq, v_deq = k_new, v_new
 
-    scale = 1.0 / (D ** 0.5)
-    q4 = (q.astype(jnp.float32) * scale).reshape(B, Hq, 1, D)
-    q_spec = pl.BlockSpec((None, Hq, None, D), lambda b, *_: (b, 0, 0, 0))
+    q4, scale = _mh_operands(q, kv_pages.dtype, quantized, G)
+    Gp = q4.shape[2]
+    q_spec = pl.BlockSpec((None, Hkv, Gp, D), lambda b, *_: (b, 0, 0, 0))
     kv_new_spec = pl.BlockSpec((None, Hkv, 1, D), lambda b, *_: (b, 0, 0, 0))
 
     kernel = functools.partial(
@@ -1180,8 +1412,7 @@ def _fused_decode_mh(
         pages_per_block=ppb,
         pages_per_seq=padded,
         batch_size=B,
-        num_kv_heads=Hkv,
-        group=G,
+        scale=scale,
         quantized=quantized,
         windowed=window is not None,
     )
@@ -1206,25 +1437,24 @@ def _fused_decode_mh(
         in_specs=in_specs,
         out_specs=[pl.BlockSpec(memory_space=pl.ANY), q_spec],
         scratch_shapes=[
-            pltpu.VMEM((Hkv, G, D), jnp.float32),
-            pltpu.VMEM((Hkv, G, D), jnp.float32),
-            pltpu.VMEM((Hkv, G, D), jnp.float32),
-            pltpu.VMEM((2, Hkv, ppb, page, D), kv_pages.dtype),
-            pltpu.VMEM((2, Hkv, ppb, page, D), kv_pages.dtype),
-            pltpu.VMEM((2, Hkv, page, D), kv_pages.dtype),  # row RMW
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA,
+            pltpu.VMEM((Hkv, Gp, D), jnp.float32),
+            pltpu.VMEM((Hkv, Gp, D), jnp.float32),
+            pltpu.VMEM((Hkv, Gp, D), jnp.float32),
+            pltpu.VMEM((2, 2, Hkv, ppb, page, D), kv_pages.dtype),
+            pltpu.VMEM((2, 2, Hkv, page, D), kv_pages.dtype),  # row RMW
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((3,)),  # row RMW: two reads, a write
         ],
     )
     args = [
         lengths,
-        jnp.asarray(page_table, dtype=jnp.int32).reshape(-1),
-        contig,
+        flat_table,
+        breaks,
         jnp.asarray(slots, dtype=jnp.int32),
         jnp.asarray(layer, dtype=jnp.int32).reshape(1),
         jnp.zeros((1,), jnp.int32),
         jnp.ones((1,), jnp.int32),
-        *([] if window is None else [_window_starts(lengths, window)]),
+        *([] if starts is None else [starts]),
         q4,
         k_new.astype(kv_pages.dtype).reshape(B, Hkv, 1, D),
         v_new.astype(kv_pages.dtype).reshape(B, Hkv, 1, D),
@@ -1236,13 +1466,20 @@ def _fused_decode_mh(
         ]
     args.append(kv_pages)
     if quantized:
+        if starts is not None:
+            # The kernel counts a window row's blocks from the page of its
+            # first visible token: its scale rows follow the same columns.
+            cols = (starts // page)[:, None] + jnp.arange(padded)[None, :]
+            page_table = jnp.take_along_axis(
+                page_table, jnp.minimum(cols, padded - 1), axis=1
+            )
         args.append(_prep_scales(kv_scales, layer, page_table, page, ppb))
     kv_out, out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype),
-            jax.ShapeDtypeStruct((B, Hq, 1, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, Gp, D), jnp.float32),
         ],
         input_output_aliases={kv_arg: 0},
         compiler_params=pltpu.CompilerParams(
@@ -1251,7 +1488,7 @@ def _fused_decode_mh(
         interpret=interpret,
         name="radixmesh_decode_fused_mh",
     )(*args)
-    attn = out.reshape(B, Hq, D).astype(q.dtype)
+    attn = _mh_unpad(out, q)
     if quantized:
         scales_out = _scatter_new_scales(
             kv_scales, layer, slots, lengths, page, k_sc, v_sc

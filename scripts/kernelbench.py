@@ -1,39 +1,49 @@
-"""Kernel-level micro-benchmark: the Pallas paged-attention entry points
-timed in isolation (no model around them), bf16 and int8, pool + fused +
-chunk-prefill.
+"""Kernel-level micro-benchmark of the served decode-attention kernel
+(``radixmesh_decode_fused_mh``), alone and at the geometries the cells run.
 
-Exists because whole-step numbers hide where kernel time goes: the int8
-fused-decode regression (0.57x bf16) was invisible until the pool kernel
-measured at parity (0.95x) while the fused kernel didn't — the delta was
-the in-kernel scale-row RMW, removed in favor of a wrapper-side scatter.
-It answers in ~2 chip-minutes whether a kernel change helped, where
-bench.py needs ~15.
+Whole-step numbers hide where kernel time goes. This script times one
+decode step's worth of the fused decode kernel — a scan over the model's
+layers with the pool donated, exactly how ``decode_step`` calls it — at
+the three served geometries, ragged contexts and all:
 
-Before timing anything it compares EVERY variant with its jnp oracle at
-the requested head geometry (``ops/kernel_check.py`` — the default-path
-kernels and the ones only a flag selects: int8 KV, per-head grids, the
-read-only pool kernel); ``--hq 28 --hkv 4`` is Qwen2-7B's. A variant that
+- ``batch``    Qwen2-7B (Hq 28 / Hkv 4, D 128, page 16, 28 layers), 32
+  rows, context 400–1 300 (``qwen2-7b.batch-unshared``);
+- ``chat``     the same heads, 12 rows, context 1–5 k
+  (``qwen2-7b.chat-sessions``);
+- ``longtail`` Mellum2 (Hq 32 / Hkv 4, 12 layers, three of four with a
+  1 024-token window), 32 rows, context 256–8 k
+  (``mellum2-12b-l12.batch-longtail``).
+
+Each geometry runs with two page-table layouts — ``perm`` (fully permuted
+pages: the per-page DMA plan, which is what a pool under churn hands the
+kernel) and ``run`` (every row one ascending run: the coalesced plan) —
+and three ways: the kernel as it is, **copies only** (both waits, no
+contraction: ``_mh_contract`` replaced by a no-op) and **math only** (the
+contraction on whatever block is resident: ``_MhBlockCopy`` started and
+awaited by no-ops). Per case it prints ms a step, µs a program (row ×
+layer), µs a block, and the share of 819 GB/s that the bytes the rows
+NEED (visible keys only, not a block's masked tail) make of the time.
+``--pages-per-block`` sweeps explicit block sizes beside the kernel's own
+choice.
+
+Before timing anything it compares every kernel variant with its jnp
+oracle at each geometry's heads (``ops/kernel_check.py`` — the
+default-path kernels and the ones only a flag selects). A variant that
 fails to compile or disagrees is recorded with the compiler's message and
 the exit code is non-zero.
 
-Round-5 axes (VERDICT r4 next-steps #1-#3): every decode case runs with
-BOTH page-table layouts — ``run`` (consecutive page runs, the common
-radix-allocator case, takes the coalesced one-descriptor-per-block DMA
-path) and ``perm`` (fully permuted, per-page fallback) — and with both
-grids (heads-batched default vs per-head), bf16 and int8 (prepared
-scales). The chunk-prefill kernel gets its first on-chip timing.
-
 Prints one JSON line naming the device; ``--out FILE`` also writes it.
-Timing needs a TPU: without one the script exits non-zero, unless
-``--interpret`` asks for the parity check alone (Pallas interpret mode,
-numerics only — an interpreter timing is not a kernel time and is never
-taken).
+Timing needs a TPU: without one the script exits 2, unless ``--interpret``
+asks for the parity check alone (Pallas interpret mode, numerics only —
+an interpreter timing is not a kernel time and is never taken).
 
-Usage: python scripts/kernelbench.py [--batch 64] [--ctx 1024] [--iters 10]
+Usage: python scripts/kernelbench.py [--geometry batch,chat,longtail]
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import importlib
 import json
 import os
 import sys
@@ -43,41 +53,146 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
 
+HBM_BYTES_PER_S = 819e9  # one TPU v5e chip (Google Cloud documentation)
+
+# name -> heads, layers, rows, window (keys a query of a window layer sees;
+# every fourth layer of such a model attends everything), context range.
+GEOMETRIES = {
+    "batch": dict(hq=28, hkv=4, layers=28, rows=32, window=0, ctx=(400, 1300)),
+    "chat": dict(hq=28, hkv=4, layers=28, rows=12, window=0, ctx=(1000, 5000)),
+    "longtail": dict(hq=32, hkv=4, layers=12, rows=32, window=1024,
+                     ctx=(256, 8192)),
+}
+HEAD_DIM, PAGE = 128, 16
+
+
+def _contexts(name: str, g: dict, rng) -> "np.ndarray":
+    import numpy as np
+
+    lo, hi = g["ctx"]
+    if name == "batch":  # prompts uniform, replies part-way through
+        return rng.integers(lo, hi + 1, g["rows"]).astype(np.int32)
+    if name == "chat":  # turn depths 1..8 of a session: geometric spread
+        return rng.permutation(
+            np.geomspace(lo, hi, g["rows"]).astype(np.int32)
+        )
+    ln = np.exp(rng.normal(np.log(900.0), 0.9, g["rows"]))
+    return np.clip(ln, lo, hi).astype(np.int32)
+
+
+def _windows(g: dict):
+    """Per-layer window of the scanned body (0 = full attention), or None
+    for a model without window layers (the kernel then compiles without a
+    window start, as ``decode_step`` compiles it)."""
+    import numpy as np
+
+    if not g["window"]:
+        return None
+    return np.asarray(
+        [0 if l % 4 == 3 else g["window"] for l in range(g["layers"])], np.int32
+    )
+
+
+def _needed_tokens(lengths, windows, layers: int) -> int:
+    """Keys the rows must read from pool pages in one step, summed over
+    rows and layers: everything before the current token, from the window
+    start on where the layer has one."""
+    import numpy as np
+
+    hbm = np.maximum(lengths.astype(np.int64) - 1, 0)
+    if windows is None:
+        return int(hbm.sum()) * layers
+    total = 0
+    for w in windows:
+        start = np.maximum(lengths - w, 0) if w else 0
+        total += int(np.maximum(hbm - start, 0).sum())
+    return total
+
+
+def _blocks(lengths, windows, layers: int, bk: int) -> int:
+    """Blocks the kernel's loops walk in one step (a window row starts at
+    the block that holds its first visible key)."""
+    import numpy as np
+
+    hbm = np.maximum(lengths.astype(np.int64) - 1, 0)
+    last = -(-hbm // bk)
+    if windows is None:
+        return int(last.sum()) * layers
+    total = 0
+    for w in windows:
+        first = (np.maximum(lengths - w, 0) // bk) if w else 0
+        total += int(np.maximum(last - first, 0).sum())
+    return total
+
+
+@contextlib.contextmanager
+def _stubbed(pa, what: str | None):
+    """Replace one half of the mh block loop by a no-op for the duration
+    (``copies``: no contraction; ``math``: no block DMA, start nor wait)."""
+    import jax
+
+    saved = {}
+    # The kernel entry points are jitted: a trace made under another stub
+    # (or none) must not be served from their caches.
+    jax.clear_caches()
+
+    def swap(obj, name, new):
+        saved[(obj, name)] = getattr(obj, name)
+        setattr(obj, name, new)
+
+    if what == "copies":
+        swap(pa, "_mh_contract", lambda **kw: None)
+    elif what == "math":
+        swap(pa._MhBlockCopy, "start", lambda self: None)
+        swap(pa._MhBlockCopy, "wait", lambda self: None)
+    try:
+        yield
+    finally:
+        for (obj, name), old in saved.items():
+            setattr(obj, name, old)
+        jax.clear_caches()
+
 
 def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--batch", type=int, default=64)
-    ap.add_argument("--ctx", type=int, default=1024)
-    ap.add_argument("--hq", type=int, default=16)
-    ap.add_argument("--hkv", type=int, default=8)
-    ap.add_argument("--head-dim", type=int, default=128)
-    ap.add_argument("--page", type=int, default=16)
-    ap.add_argument("--iters", type=int, default=10)
-    ap.add_argument("--chunk", type=int, default=512)
-    ap.add_argument("--chunk-batch", type=int, default=8)
-    ap.add_argument("--skip-per-head", action="store_true",
-                    help="decode cases: heads-batched grid only")
+    ap = argparse.ArgumentParser(
+        description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter
+    )
+    ap.add_argument("--geometry", default="batch,chat,longtail",
+                    help="comma list of " + ", ".join(GEOMETRIES))
+    ap.add_argument("--layouts", default="perm,run",
+                    help="page-table layouts: perm (per-page DMA plan), "
+                    "run (coalesced plan)")
+    ap.add_argument("--pages-per-block", default="",
+                    help="comma list of explicit block sizes (pages) to time "
+                    "beside the kernel's own choice")
+    ap.add_argument("--no-parity", action="store_true",
+                    help="skip the kernel-vs-oracle check")
+    ap.add_argument("--no-split", action="store_true",
+                    help="skip the copies-only / math-only runs")
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--interpret", action="store_true",
                     help="parity check only, in Pallas interpret mode "
                     "(any backend; nothing is timed)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    names = [n for n in args.geometry.split(",") if n]
+    for n in names:
+        if n not in GEOMETRIES:
+            ap.error(f"unknown geometry {n!r}")
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from radixmesh_tpu.ops.paged_attention import (
-        paged_attention_pool_kernel,
-        paged_chunk_attention_kernel,
-        paged_decode_fused_kernel,
-    )
+    # (the package re-exports a FUNCTION named paged_attention)
+    pa = importlib.import_module("radixmesh_tpu.ops.paged_attention")
     from radixmesh_tpu.ops.kernel_check import (
         DEFAULT_CASES,
         FLAG_CASES,
+        WINDOW_CASES,
         check_kernels,
     )
-    from radixmesh_tpu.ops.quant import quantize_kv
 
     dev = jax.devices()[0]
     out = {
@@ -88,159 +203,157 @@ def main() -> int:
         print(f"kernelbench: needs a TPU, found {out['device']} "
               "(--interpret runs the parity check alone)", file=sys.stderr)
         return 2
-    out["parity"] = check_kernels(
-        DEFAULT_CASES + FLAG_CASES, hq=args.hq, hkv=args.hkv,
-        head_dim=args.head_dim, page=args.page, batch=args.chunk_batch,
-        chunk=args.chunk, max_pages=max(args.ctx, 2 * args.chunk) // args.page,
-        interpret=args.interpret,
-    )
-    failed = [r["kernel"] for r in out["parity"] if not r["ok"]]
+    out["parity"] = {}
+    failed = []
+    heads = sorted({(GEOMETRIES[n]["hq"], GEOMETRIES[n]["hkv"]) for n in names})
+    for hq, hkv in [] if args.no_parity else heads:
+        tiny = args.interpret
+        res = check_kernels(
+            DEFAULT_CASES + FLAG_CASES, hq=hq, hkv=hkv, head_dim=HEAD_DIM,
+            page=PAGE, batch=4 if tiny else 8, chunk=32 if tiny else 512,
+            max_pages=8 if tiny else 64, interpret=args.interpret,
+        )
+        if any(GEOMETRIES[n]["window"] for n in names
+               if (GEOMETRIES[n]["hq"], GEOMETRIES[n]["hkv"]) == (hq, hkv)):
+            res += check_kernels(
+                WINDOW_CASES, hq=hq, hkv=hkv, head_dim=HEAD_DIM, page=PAGE,
+                batch=8, chunk=32 if tiny else 512,
+                max_pages=16 if tiny else 128, window=40 if tiny else 1024,
+                interpret=args.interpret,
+            )
+        out["parity"][f"hq{hq}_hkv{hkv}"] = res
+        failed += [f"hq{hq}:{r['kernel']}" for r in res if not r["ok"]]
     if args.interpret:
         print(json.dumps(out), flush=True)
         return 1 if failed else 0
 
-    B, Hq, Hkv, D, page = args.batch, args.hq, args.hkv, args.head_dim, args.page
-    ctx, L = args.ctx, 1
-    if ctx % page:
-        ap.error(f"--ctx ({ctx}) must be a multiple of --page ({page})")
-    P = B * ctx // page
-    rng = np.random.default_rng(0)
-    kv = rng.standard_normal((2, L, Hkv, P * page, D)).astype(np.float32)
-    q8, s8 = quantize_kv(jnp.asarray(kv), axis=-1)
-    kv8 = jnp.asarray(np.asarray(q8).reshape(2, L, Hkv, P, page, D), jnp.int8)
-    scales = jnp.asarray(np.asarray(s8).reshape(2, L, Hkv, P, page))
-    kv16 = jnp.asarray(kv.reshape(2, L, Hkv, P, page, D), jnp.bfloat16)
-    q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.bfloat16)
-    kn = jnp.asarray(rng.standard_normal((B, Hkv, D)), jnp.bfloat16)
-    maxp = ctx // page
-    # Two table layouts. ``run``: each row owns one consecutive page run
-    # (rows themselves shuffled) — what the page-granular slot allocator
-    # produces for a freshly prefilled sequence; every block coalesces to
-    # one descriptor. ``perm``: fully permuted — the adversarial radix
-    # fragmentation case, per-page fallback path.
-    row_order = rng.permutation(B)
-    pt_run_np = np.stack(
-        [np.arange(r * maxp, (r + 1) * maxp, dtype=np.int32) for r in row_order]
-    )
-    pt_perm_np = rng.permutation(P).reshape(B, maxp).astype(np.int32)
-    tables = {
-        "run": (jnp.asarray(pt_run_np), jnp.asarray(
-            pt_run_np[:, -1] * page + (page - 1))),
-        "perm": (jnp.asarray(pt_perm_np), jnp.asarray(
-            pt_perm_np[:, -1] * page + (page - 1))),
-    }
-    lens = jnp.full((B,), ctx, jnp.int32)
-
-    def bench(fn, n=args.iters):
-        r = fn()
+    def bench(fn, kv, n=args.iters):
+        """Seconds a call: the better of two rounds of ``n`` (one round in
+        a dozen meets a stall of tens of ms that is the machine's)."""
+        kv, r = fn(kv)  # compile + warm
         jax.block_until_ready(r)
-        del r
-        t = time.perf_counter()
-        r = None
-        for _ in range(n):
-            r = fn()
-        jax.block_until_ready(r)
-        return (time.perf_counter() - t) / n * 1e3
+        rounds = []
+        for _ in range(2):
+            t = time.perf_counter()
+            for _ in range(n):
+                kv, r = fn(kv)
+            jax.block_until_ready(r)
+            rounds.append((time.perf_counter() - t) / n)
+        return kv, min(rounds)
 
-    out["shape"] = {"batch": B, "ctx": ctx, "hq": Hq, "hkv": Hkv,
-                    "head_dim": D, "page": page, "chunk": args.chunk}
-    out["ms"] = {}
-    cases = {}
-    grids = [("mh", True)] if args.skip_per_head else [
-        ("mh", True), ("ph", False)]
-    for tname, (ptb, slots) in tables.items():
-        for gname, fuse in grids:
-            cases[f"pool_bf16_{gname}_{tname}"] = (
-                lambda ptb=ptb, fuse=fuse: paged_attention_pool_kernel(
-                    q, kv16, ptb, lens, 0, fuse_heads=fuse)
-            )
-            cases[f"pool_int8_{gname}_{tname}"] = (
-                lambda ptb=ptb, fuse=fuse: paged_attention_pool_kernel(
-                    q, kv8, ptb, lens, 0, kv_scales=scales,
-                    fuse_heads=fuse)
-            )
-            cases[f"fused_bf16_{gname}_{tname}"] = (
-                lambda ptb=ptb, slots=slots, fuse=fuse:
-                paged_decode_fused_kernel(
-                    q, kn, kn, kv16, slots, ptb, lens, 0,
-                    fuse_heads=fuse)
-            )
-            cases[f"fused_int8_{gname}_{tname}"] = (
-                lambda ptb=ptb, slots=slots, fuse=fuse:
-                paged_decode_fused_kernel(
-                    q, kn, kn, kv8, slots, ptb, lens, 0, kv_scales=scales, fuse_heads=fuse)
-            )
-
-    # Chunk-prefill (first on-chip timing — VERDICT r4 missing #2): Bc
-    # rows each attending `ctx` prior pool tokens + a dense causal chunk.
-    Bc, C = args.chunk_batch, args.chunk
-    qc = jnp.asarray(rng.standard_normal((Bc, C, Hq, D)), jnp.bfloat16)
-    kc = jnp.asarray(rng.standard_normal((Bc, C, Hkv, D)), jnp.bfloat16)
-    prior = jnp.full((Bc,), ctx, jnp.int32)
-    for tname in tables:
-        ptb = tables[tname][0][:Bc]
-        cases[f"chunk_bf16_{tname}"] = (
-            lambda ptb=ptb: paged_chunk_attention_kernel(
-                qc, kc, kc, kv16, ptb, prior, prior + C, 0)
+    sweeps = [None] + [int(x) for x in args.pages_per_block.split(",") if x]
+    out["cases"] = []
+    for name in names:
+        g = GEOMETRIES[name]
+        rng = np.random.default_rng(args.seed)
+        B, Hq, Hkv, L = g["rows"], g["hq"], g["hkv"], g["layers"]
+        lengths = _contexts(name, g, rng)
+        windows = _windows(g)
+        row_pages = -(-lengths // PAGE)
+        maxp = int(1 << int(np.ceil(np.log2(row_pages.max()))))
+        P = int(row_pages.sum()) + maxp  # the tail keeps a run in bounds
+        key = jax.random.PRNGKey(args.seed)
+        kq, kk, kv_key = jax.random.split(key, 3)
+        q = jax.random.normal(kq, (B, Hq, HEAD_DIM), jnp.bfloat16)
+        kn = jax.random.normal(kk, (B, Hkv, HEAD_DIM), jnp.bfloat16)
+        kv = jax.random.normal(
+            kv_key, (2, L, Hkv, P, PAGE, HEAD_DIM), jnp.bfloat16
         )
-        cases[f"chunk_int8_{tname}"] = (
-            lambda ptb=ptb: paged_chunk_attention_kernel(
-                qc, kc, kc, kv8, ptb, prior, prior + C, 0,
-                kv_scales=scales)
-        )
+        starts = np.concatenate([[0], np.cumsum(row_pages)[:-1]])
+        perm = rng.permutation(int(row_pages.sum()))
+        tables = {}
+        for layout in args.layouts.split(","):
+            pt = np.zeros((B, maxp), np.int32)
+            for b in range(B):
+                ids = np.arange(starts[b], starts[b] + row_pages[b])
+                pt[b, : row_pages[b]] = perm[ids] if layout == "perm" else ids
+            pos = lengths - 1
+            slots = pt[np.arange(B), pos // PAGE] * PAGE + pos % PAGE
+            tables[layout] = (jnp.asarray(pt), jnp.asarray(slots, jnp.int32))
+        lens = jnp.asarray(lengths)
+        w_arr = None if windows is None else jnp.asarray(windows)
+        needed = _needed_tokens(lengths, windows, L) * Hkv * HEAD_DIM * 2 * 2
 
-    # Block-size sweep on the headline path: with run-coalesced DMAs the
-    # descriptor count per sequence is blocks-per-ctx, so bigger blocks
-    # trade fewer/larger descriptors against VMEM and tail waste — an
-    # on-chip question (CPU numbers are meaningless here).
-    ptb_run, slots_run = tables["run"]
-    default_ppb = max(1, -(-128 // page))
-    for ppb in (8, 16, 32):
-        if ppb * page > ctx or ppb == default_ppb:
-            # The resolved default is already timed as fused_bf16_mh_run.
-            continue
-        cases[f"fused_bf16_mh_run_ppb{ppb}"] = (
-            lambda ppb=ppb: paged_decode_fused_kernel(
-                q, kn, kn, kv16, slots_run, ptb_run, lens, 0,
-                pages_per_block=ppb, fuse_heads=True)
-        )
+        def make(pt, slots, ppb):
+            def step(kv):
+                def body(l, carry):
+                    kv, acc = carry
+                    o, kv = pa.paged_decode_fused_kernel(
+                        q, kn, kn, kv, slots, pt, lens, l,
+                        pages_per_block=ppb,
+                        window=None if w_arr is None else w_arr[l],
+                    )
+                    return kv, acc + o.astype(jnp.float32)
 
-    # EVERY kernel timing is exception-guarded and partial results are
-    # always printed/written: this repo's history shows kernels that fail
-    # ONLY at on-chip Mosaic compile — one such failure must not discard
-    # the numbers already measured (it does fail the exit code).
-    for name, thunk in cases.items():
-        try:
-            out["ms"][name] = round(bench(thunk), 3)
-        except Exception as e:  # noqa: BLE001 — record, keep measuring
-            out.setdefault("errors", {})[name] = str(e)[:300]
-    ms = out["ms"]
+                return jax.lax.fori_loop(
+                    0, L, body, (kv, jnp.zeros(q.shape, jnp.float32))
+                )
 
-    def ratio(a, b):
-        return round(ms[a] / ms[b], 3) if a in ms and b in ms else None
+            return jax.jit(step, donate_argnums=0)
 
-    out["summary"] = {
-        # >1.0 means the second (new/cheaper) case is faster.
-        "coalesce_gain_pool": ratio("pool_bf16_mh_perm", "pool_bf16_mh_run"),
-        "coalesce_gain_fused": ratio("fused_bf16_mh_perm", "fused_bf16_mh_run"),
-        "mh_gain_pool": ratio("pool_bf16_ph_run", "pool_bf16_mh_run"),
-        "mh_gain_fused": ratio("fused_bf16_ph_run", "fused_bf16_mh_run"),
-        "int8_vs_bf16_pool": ratio("pool_bf16_mh_run", "pool_int8_mh_run"),
-        "int8_vs_bf16_fused": ratio("fused_bf16_mh_run", "fused_int8_mh_run"),
-        "int8_vs_bf16_chunk": ratio("chunk_bf16_run", "chunk_int8_run"),
-    }
-    # Achieved HBM read bandwidth of the best bf16 decode case (K+V
-    # context bytes / time) — the roofline-facing number.
-    ctx_bytes = B * ctx * Hkv * 2 * D * 2
-    for key in ("fused_bf16_mh_run", "pool_bf16_mh_run"):
-        if key in ms:
-            out[f"{key}_gbps"] = round(ctx_bytes / (ms[key] / 1e3) / 1e9, 1)
+        for layout, (pt, slots) in tables.items():
+            for ppb in sweeps:
+                chosen = ppb or pa._mh_pages_per_block(
+                    Hkv, PAGE, HEAD_DIM, kv.dtype, maxp
+                )
+                blocks = _blocks(lengths, windows, L, chosen * PAGE)
+                for stub in (None,) if args.no_split else (None, "copies", "math"):
+                    case = {
+                        "geometry": name, "layout": layout,
+                        "pages_per_block": chosen, "chosen_by_kernel": ppb is None,
+                        "what": stub or "kernel",
+                    }
+                    try:
+                        with _stubbed(pa, stub):
+                            kv, sec = bench(make(pt, slots, ppb), kv)
+                    except Exception as e:  # noqa: BLE001 — record, keep measuring
+                        case["error"] = f"{type(e).__name__}: {e}"[:600]
+                        failed.append(f"{name}:{layout}:{stub}")
+                        out["cases"].append(case)
+                        continue
+                    case.update({
+                        "ms_per_step": round(sec * 1e3, 4),
+                        "us_per_program": round(sec * 1e6 / (B * L), 3),
+                        "us_per_block": round(sec * 1e6 / blocks, 4),
+                        "blocks_per_step": blocks,
+                        "programs_per_step": B * L,
+                        "needed_mb_per_step": round(needed / 1e6, 2),
+                        "hbm_share_pct": round(
+                            100 * needed / sec / HBM_BYTES_PER_S, 2
+                        ),
+                    })
+                    out["cases"].append(case)
+                    print(json.dumps(case), file=sys.stderr, flush=True)
+        del kv
+    # batch and chat share heads and block size and differ in rows and
+    # context: time = bytes / rate + programs x cost has one solution.
+    out["fit"] = []
+    for layout in tables:
+        for what in ("kernel", "copies", "math"):
+            pair = [
+                c for c in out["cases"]
+                if c["layout"] == layout and c["what"] == what
+                and c["chosen_by_kernel"] and "ms_per_step" in c
+                and c["geometry"] in ("batch", "chat")
+            ]
+            if len(pair) != 2:
+                continue
+            ms_per_mb, ms_per_program = np.linalg.solve(
+                [[c["needed_mb_per_step"], c["programs_per_step"]] for c in pair],
+                [c["ms_per_step"] for c in pair],
+            )
+            out["fit"].append({
+                "layout": layout, "what": what,
+                "gb_per_s_on_needed_bytes": round(1.0 / ms_per_mb, 1),
+                "us_per_program": round(ms_per_program * 1e3, 3),
+            })
     line = json.dumps(out)
     print(line, flush=True)
     if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as fh:
             fh.write(line + "\n")
-    return 1 if failed or out.get("errors") else 0
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

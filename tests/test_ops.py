@@ -717,6 +717,128 @@ class TestFusedHeadsDecode:
         np.testing.assert_array_equal(np.asarray(got_kv), np.asarray(want_kv))
 
 
+class TestMhBlockLoop:
+    """The heads-batched block loop at every block size the wrapper can
+    be given or can choose: a row's last block moves only the pages the
+    row still holds, a window row starts at the page of its first visible
+    key, and only those two edge blocks are masked. Against the jnp
+    oracle, interpret mode."""
+
+    PAGE, MAXP, WINDOW = 4, 24, 20
+    # tokens incl. the current one: alone, inside the first page, inside
+    # the first block of every size, ending mid-block, on a block edge of
+    # every size (hbm 64), one past it, inside the window, the whole table.
+    LENGTHS = (1, 3, 7, 35, 65, 66, 18, 96)
+
+    def _case(self, G, layout, dtype=jnp.float32, seed=0):
+        rng = np.random.default_rng(seed)
+        B, Hkv, D, page, maxp = len(self.LENGTHS), 2, 32, self.PAGE, self.MAXP
+        P = B * maxp + maxp
+        q = jnp.asarray(rng.standard_normal((B, Hkv * G, D)), dtype)
+        kn = jnp.asarray(rng.standard_normal((B, Hkv, D)), dtype)
+        vn = jnp.asarray(rng.standard_normal((B, Hkv, D)), dtype)
+        kv = jnp.asarray(rng.standard_normal((2, 2, Hkv, P, page, D)), dtype)
+        if layout == "run":  # every block of every row coalesces
+            pt = np.arange(B * maxp).reshape(B, maxp)
+        else:  # no two neighbours consecutive: the per-page plan
+            pt = rng.permutation(B * maxp).reshape(B, maxp)
+        lengths = np.asarray(self.LENGTHS, np.int32)
+        pos = lengths - 1
+        slots = pt[np.arange(B), pos // page] * page + pos % page
+        return (q, kn, vn, kv, jnp.asarray(slots, jnp.int32),
+                jnp.asarray(pt, jnp.int32), jnp.asarray(lengths))
+
+    @pytest.mark.parametrize("ppb", [None, 2, 8, 16])
+    @pytest.mark.parametrize("layout", ["run", "perm"])
+    @pytest.mark.parametrize("windowed", [False, True])
+    @pytest.mark.parametrize("G", [7, 8])
+    def test_matches_oracle(self, G, windowed, layout, ppb):
+        from radixmesh_tpu.ops.attention import paged_decode_attention
+        from radixmesh_tpu.ops.paged_attention import paged_decode_fused_kernel
+
+        args = self._case(G, layout)
+        # A windowed executable serves its full-attention layers too.
+        for w in (self.WINDOW, 0) if windowed else (None,):
+            window = None if w is None else jnp.int32(w)
+            want, want_kv = paged_decode_attention(
+                *args, 1, use_kernel=False, window=window)
+            got, got_kv = paged_decode_fused_kernel(
+                *args, 1, interpret=True, pages_per_block=ppb, window=window,
+                fuse_heads=True)
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+            np.testing.assert_array_equal(
+                np.asarray(got_kv), np.asarray(want_kv))
+
+    @pytest.mark.parametrize("windowed", [False, True])
+    @pytest.mark.parametrize("G", [7, 8])
+    def test_pool_dtype_operands_match_oracle(self, G, windowed):
+        """A bf16 pool is contracted as fetched (bf16 operands, float32
+        accumulation, bf16 ``p``): within the chip check's tolerance of
+        the float32 oracle on the same bf16 values."""
+        from radixmesh_tpu.ops.attention import paged_decode_attention
+        from radixmesh_tpu.ops.kernel_check import TOLERANCE
+        from radixmesh_tpu.ops.paged_attention import paged_decode_fused_kernel
+
+        args = self._case(G, "perm", jnp.bfloat16, seed=1)
+        window = jnp.int32(self.WINDOW) if windowed else None
+        want, _ = paged_decode_attention(
+            *(a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a
+              for a in args), 0, use_kernel=False, window=window)
+        got, _ = paged_decode_fused_kernel(
+            *args, 0, interpret=True, window=window)
+        err = np.abs(np.asarray(got, np.float32) - np.asarray(want))
+        assert np.max(err / (1 + np.abs(np.asarray(want)))) <= TOLERANCE
+
+    @pytest.mark.parametrize("ppb", [2, 8])
+    def test_unfetched_pages_of_a_last_block_hold_anything(self, ppb):
+        """Pages past a row's end are not moved: whatever the staging
+        buffer held there (here: the NaN block of the row before) is
+        zeroed in both factors."""
+        from radixmesh_tpu.ops.attention import paged_decode_attention
+        from radixmesh_tpu.ops.paged_attention import paged_decode_fused_kernel
+
+        q, kn, vn, kv, slots, pt, lengths = self._case(7, "run", seed=2)
+        # Rows 0 and 2 share a buffer half; row 1 has no pool tokens.
+        lengths = jnp.asarray([8 * self.PAGE + 1, 1, 5] + [0] * 5, jnp.int32)
+        pos = np.asarray(lengths) - 1
+        slots = jnp.asarray(
+            np.asarray(pt)[np.arange(8), np.maximum(pos, 0) // self.PAGE]
+            * self.PAGE + np.maximum(pos, 0) % self.PAGE, jnp.int32)
+        want, _ = paged_decode_attention(
+            q, kn, vn, kv, slots, pt, lengths, 0, use_kernel=False)
+        poisoned = kv.at[:, :, :, pt[0, 2:8]].set(jnp.nan)
+        got, _ = paged_decode_fused_kernel(
+            q, kn, vn, poisoned, slots, pt, lengths, 0, interpret=True,
+            pages_per_block=ppb)
+        np.testing.assert_allclose(
+            np.asarray(got)[1:3], np.asarray(want)[1:3], rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("hkv,max_pages,want", [
+        (4, 128, 32),   # Qwen2-7B / Mellum2: 512 tokens, 2 MiB of VMEM
+        (8, 512, 32),   # 4 MiB: still 512 tokens
+        (16, 512, 16),  # halved to stay within 4 MiB
+        (4, 24, 24),    # never wider than the page table
+        (4, 1, 1),
+    ])
+    def test_block_size_follows_what_the_call_shows(self, hkv, max_pages, want):
+        from radixmesh_tpu.ops.paged_attention import _mh_pages_per_block
+
+        assert _mh_pages_per_block(
+            hkv, 16, 128, jnp.bfloat16, max_pages) == want
+        # An int8 pool's block is half the bytes: twice the heads fit.
+        assert _mh_pages_per_block(
+            2 * hkv, 16, 128, jnp.int8, max_pages) == want
+
+
+    def test_block_tokens_shown_on_debug_state(self):
+        from radixmesh_tpu.ops.paged_attention import decode_block_tokens
+
+        assert decode_block_tokens(4, 16, 128, jnp.bfloat16, 2048) == 512
+        assert decode_block_tokens(4, 16, 128, jnp.int8, 2048, True) == 512
+        assert decode_block_tokens(2, 4, 32, jnp.float32, 8) == 32
+
+
 class TestContigCoalescing:
     """Round-5 run-coalesced block DMAs: the wrapper's per-(row, block)
     flags (``_contig_flags``) choose between one contiguous descriptor and
