@@ -157,6 +157,12 @@ def _scatter_kv(kv: jax.Array, slots: jax.Array, new_kv: jax.Array) -> jax.Array
     return kv.at[:, :, :, slots].set(new_kv)
 
 
+@partial(jax.jit, donate_argnums=(0,))
+def _scatter_index(index: jax.Array, slots: jax.Array, keys: jax.Array) -> jax.Array:
+    # index: [L_full, S, Di]; slots: [n]; keys: [L_full, n, Di]
+    return index.at[:, slots].set(keys)
+
+
 @jax.jit
 def _gather_kv(kv: jax.Array, slots: jax.Array) -> jax.Array:
     # → [2, L, n, H, D] (token-major, for tests/debug)
@@ -268,6 +274,7 @@ class PagedKVPool:
         sharding: jax.sharding.Sharding | None = None,
         quant: str | None = None,
         planes: int = 2,
+        index_shape: tuple[int, int, int] | None = None,
     ):
         if planes not in (1, 2):
             raise ValueError("a pool row has 1 plane (latent) or 2 (K and V)")
@@ -303,6 +310,19 @@ class PagedKVPool:
             dtype=dtype,
             device=sharding,
         )
+        # A second kind of state beside the rows (``ModelConfig.
+        # kv_index_shape``): the index keys of a model with learned sparse
+        # attention, ``[full layers, slots, width]``, addressed by the
+        # SAME slot numbers — one allocator, so a slot's row and its index
+        # keys are taken, handed to the radix tree, served by a tree hit
+        # and freed together; only the scatters are two.
+        self.index = None
+        if index_shape is not None:
+            if index_shape[1] != num_slots or quant is not None:
+                raise ValueError(
+                    "the index plane has the pool's slots and no quantized form"
+                )
+            self.index = jnp.zeros(index_shape, dtype=dtype)
         # Per-(token, head) symmetric scales for quantized pools: value ≈
         # int8 * scale (ops/quant.py). Same [2, L, Hkv, slots] geometry as
         # the data minus head_dim — shards identically over `tp`, and the
@@ -326,6 +346,14 @@ class PagedKVPool:
     @property
     def num_pages(self) -> int:
         return self.allocator.num_pages
+
+    @property
+    def index_shape(self) -> tuple[int, int, int] | None:
+        return None if self.index is None else tuple(self.index.shape)
+
+    @property
+    def index_plane_bytes(self) -> int:
+        return 0 if self.index is None else self.index.size * self.index.itemsize
 
     # ---- allocation (host side) ----
 
@@ -381,6 +409,19 @@ class PagedKVPool:
             )
         else:
             self.kv = _scatter_kv(self.kv, sl, new_kv.astype(self.dtype))
+
+    def write_index(self, slots: np.ndarray | jax.Array, keys: jax.Array) -> None:
+        """Write the index keys ``[L_full, n, width]`` of ``n`` tokens at
+        ``slots`` (the slots their rows were written at), bucketed as
+        :meth:`write`."""
+        slots = np.asarray(slots, dtype=np.int32)
+        if len(slots) == 0:
+            return
+        slots, (keys,) = _pad_to_bucket(slots, [jnp.asarray(keys)], [1])
+        self.index = _scatter_index(
+            self.index, jnp.asarray(slots, dtype=jnp.int32),
+            keys.astype(self.dtype),
+        )
 
     def pages_for_layer(self, layer: int) -> tuple[jax.Array, jax.Array]:
         """(k_pages, v_pages), each ``[Hkv, num_pages, page, D]`` — a

@@ -168,6 +168,7 @@ class _DecodeLaunch:
     max_len: int  # longest context before the launch
     window: tuple[int, int]  # attn_window increments (read, skipped)
     latent_tokens: int  # latent rows the launch reads (rows x layers)
+    contexts: np.ndarray  # the live rows' context, this token included
     slots_d: jax.Array
     pt_d: jax.Array
     lengths_d: jax.Array
@@ -418,16 +419,26 @@ class Engine:
         # group counts the rows read: by decode launches (context x layers,
         # summed over rows) and by prefill launches of the cached part
         # (tokens already in pages that a chunk attends x layers).
-        self._moe_stats = new_moe_stats() if cfg.n_experts else None
+        self._moe_stats = new_moe_stats(cfg.ep_size > 1) if cfg.n_experts else None
         self.attn_window = {"window_tokens_skipped": 0, "window_tokens_read": 0}
         if cfg.latent:
             self.attn_window |= {"latent_tokens_read": 0, "latent_prefix_tokens": 0}
+        if cfg.dsa:
+            # Learned sparse attention, by launch kind (host arithmetic on
+            # the lengths, beside latent_tokens_read): index keys a full
+            # layer scored, rows the layers attended, and rows the context
+            # holds (what dense attention would read), each x its layers.
+            self.attn_window["sparse"] = {
+                site: {"index_keys_scored": 0, "rows_selected": 0, "rows_held": 0}
+                for site in ("decode", "prefill")
+            }
         self._sliding_layers = (
             cfg.layer_types.count("sliding_attention") if cfg.has_window else 0
         )
         # The expert ids of the last launch ([L, B, S, k], on the device):
         # fetched only for a request that asked for them.
         self._last_expert_ids = None
+        self._last_selected = None  # aux["index_positions"] of the last launch
         self._span_match = _TreeSpan(
             "rm.tree.match", self.tree_host, "matches", "match_s"
         )
@@ -475,6 +486,7 @@ class Engine:
                 num_kv_heads=row_heads,
                 head_dim=row_width,
                 quant=kv_quant,
+                index_shape=cfg.kv_index_shape(pool.num_slots),
             )
             if kv_quant is None:
                 expected["dtype"] = cfg.dtype
@@ -514,6 +526,7 @@ class Engine:
                 sharding=pool_sharding,
                 quant=kv_quant,
                 planes=row_planes,
+                index_shape=cfg.kv_index_shape(num_slots),
             )
         if kernels and not cfg.latent:
             from radixmesh_tpu.ops.paged_attention import decode_block_tokens
@@ -1988,9 +2001,42 @@ class Engine:
             ids = self._last_expert_ids[:, b, :n]  # [L, n, k]
             req.expert_rows.append((int(start), ids.transpose(1, 0, 2).copy()))
 
+    def _keep_selected(self, req: Request, position: int, b: int) -> None:
+        """Same hook, learned sparse attention: what the full layers
+        selected for the query at ``position`` of ``req``, batch row ``b``
+        of the last launch (nothing where that launch attended all)."""
+        if req.sampling.return_logits and self._last_selected is not None:
+            if not isinstance(self._last_selected, np.ndarray):
+                self._last_selected = np.asarray(self._last_selected)
+            req.index_rows.append(
+                (int(position), self._last_selected[:, b, 0].copy()))
+
     def _moe_kw(self) -> dict:
         """What a step of a model with experts takes beside the rest."""
         return {} if self._moe_stats is None else {"moe_stats": self._moe_stats}
+
+    def _pool_kw(self) -> dict:
+        """What a step that writes the pool takes beside ``pool.kv``: the
+        counters of a model with experts, the index plane of one with
+        learned sparse attention."""
+        kw = self._moe_kw()
+        if self.pool.index is not None:
+            kw["index_pool"] = self.pool.index
+        return kw
+
+    def _note_sparse(self, site: str, ctx: np.ndarray, engaged: bool) -> None:
+        """``attn.sparse[site]`` for queries that have ``ctx`` rows each to
+        attend (position + 1): with the selection ``engaged`` (the
+        launch's context passes ``index_topk``) a query scores them all in
+        the full layers and attends ``min(ctx, index_topk)``."""
+        cfg = self.cfg
+        held = int(ctx.sum())
+        selected = int(np.minimum(ctx, cfg.index_topk).sum()) if engaged else held
+        c = self.attn_window["sparse"][site]
+        c["rows_held"] += held * cfg.n_layers
+        c["rows_selected"] += selected * cfg.n_layers
+        if engaged:
+            c["index_keys_scored"] += held * len(cfg.full_layers)
 
     def _prefill_dense(
         self,
@@ -2033,9 +2079,16 @@ class Engine:
             jnp.full((1,), reuse, dtype=jnp.int32),
             **moe_kw,
         )
+        if self.cfg.dsa:  # the tokens' index keys, to the same slots
+            index_keys, *aux = aux
+            self.pool.write_index(own[:n_new], index_keys[:, 0, :n_new])
+            self._note_sparse(
+                "prefill", reuse + 1 + np.arange(n_new, dtype=np.int64),
+                s_b > self.cfg.index_topk)
         if aux:
             self._moe_stats = aux[0]["moe_stats"]
             self._last_expert_ids = aux[0]["expert_ids"]
+            self._last_selected = None  # the dense path selects as a mask
             self._keep_expert_ids(req, reuse, 0, n_new)
         self.pool.write(
             own[:n_new], new_k[:, 0, :n_new],
@@ -2104,7 +2157,7 @@ class Engine:
             kv_scale=self.pool.kv_scale,
             mesh=self.device_mesh,
             logits_at=logits_at,
-            **self._moe_kw(),
+            **self._pool_kw(),
         )
 
     def _sp_capable(self, member: tuple) -> bool:
@@ -2220,6 +2273,17 @@ class Engine:
                 self.attn_window["latent_prefix_tokens"] += (
                     prior * self.cfg.n_layers
                 )
+            if self.cfg.dsa:
+                self._note_sparse(
+                    "prefill",
+                    np.concatenate([
+                        np.arange(reuses[i] + ci * C + 1,
+                                  min(totals[i], reuses[i] + ci * C + C) + 1,
+                                  dtype=np.int64)
+                        for i in range(N)
+                    ]),
+                    maxp * ps + C > self.cfg.index_topk,
+                )
             # Only each row's LAST prompt position is ever sampled, and
             # only in the chunk that holds it.
             at = np.zeros((B,), dtype=np.int32)
@@ -2240,6 +2304,8 @@ class Engine:
                 nv = min(max(totals[i] - reuses[i] - ci * C, 0), C)
                 if nv:
                     self._keep_expert_ids(group[i][0], reuses[i] + ci * C, i, nv)
+                if lastpos[i] >= 0:
+                    self._keep_selected(group[i][0], totals[i] - 1, i)
 
         out = []
         for i, (req, row, reuse, prefix_slots, own) in enumerate(group):
@@ -2350,14 +2416,18 @@ class Engine:
         """Unpack a model call's ``(out, kv_pool[, kv_scale][, aux])``
         result: store the updated pool buffers and return the leading
         value (the quantized pool threads its scale array through every
-        call; a model with experts returns its counters and the launch's
-        expert ids as a trailing ``aux``)."""
+        call, a model with learned sparse attention its index plane; a
+        model with experts returns its counters and the launch's expert
+        ids as a trailing ``aux``)."""
         if self._moe_stats is not None:
             *res, aux = res
             self._moe_stats = aux["moe_stats"]
             self._last_expert_ids = aux["expert_ids"]
+            self._last_selected = aux.get("index_positions")
         if self.pool.quant is not None:
             out, self.pool.kv, self.pool.kv_scale = res
+        elif self.pool.index is not None:
+            out, self.pool.kv, self.pool.index = res
         else:
             out, self.pool.kv = res
         return out
@@ -2455,6 +2525,11 @@ class Engine:
         aw["window_tokens_skipped"] += launch.window[1]
         if launch.latent_tokens:
             aw["latent_tokens_read"] += launch.latent_tokens
+        if self.cfg.dsa:
+            self._note_sparse(
+                "decode", launch.contexts.astype(np.int64),
+                launch.pt_d.shape[1] * self.page_size + 1 > self.cfg.index_topk,
+            )
         step_t0 = time.monotonic()
         with annotate("rm.decode.dispatch"):
             tokens_d = launch.tokens_d
@@ -2488,7 +2563,7 @@ class Engine:
                     self.page_size,
                     mesh=self.device_mesh,
                     kv_scale=self.pool.kv_scale,
-                    **self._moe_kw(),
+                    **self._pool_kw(),
                 )
                 logits = self._commit_pool_update(res)
         asked = [(row, r) for row, r in active if r.sampling.return_logits]
@@ -2501,6 +2576,7 @@ class Engine:
             for row, req in asked:
                 req.logit_rows.append(logits_h[row])
                 self._keep_expert_ids(req, req.kv_len, row, 1)
+                self._keep_selected(req, req.kv_len, row)
         nxt, by_length, stops = None, False, ()
         if seeded:
             # The canonical per-row (seed, position) draw syncs per row:
@@ -2665,6 +2741,7 @@ class Engine:
                 int(lengths[idx].sum()) * self.cfg.n_layers
                 if self.cfg.latent else 0
             ),
+            contexts=lengths[idx],
             slots_d=jnp.asarray(slots),
             pt_d=jnp.asarray(
                 self._decode_pt_bucket(1 + ahead, floor=_KV_BLOCK_PAGES)

@@ -134,6 +134,10 @@ class Request:
     # served from the tree was computed by an earlier request: no ids).
     logit_rows: list = field(default_factory=list, repr=False, compare=False)
     expert_rows: list = field(default_factory=list, repr=False, compare=False)
+    # Learned sparse attention: ``(position, token positions each full
+    # layer selected for it [L_full, K], -1 = nothing)`` for every sampled
+    # position whose launch selected (the context passed ``index_topk``).
+    index_rows: list = field(default_factory=list, repr=False, compare=False)
     # Draft tokens the LAST speculative wave rejected for this row:
     # the spec_verify_miss stall attribution, consumed (zeroed) by
     # Engine._stall_cause.

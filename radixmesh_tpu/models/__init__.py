@@ -6,7 +6,7 @@ from radixmesh_tpu.models.llama import (
     param_logical_axes,
     convert_hf_state_dict,
 )
-from radixmesh_tpu.models import glm4_moe_lite, mellum2, qwen2
+from radixmesh_tpu.models import glm4_moe_lite, glm_moe_dsa, mellum2, qwen2
 
 _PRESETS = {
     "llama3-8b": ModelConfig.llama3_8b,
@@ -27,6 +27,9 @@ _PRESETS = {
     "glm-4.7-flash": glm4_moe_lite.glm47_flash,
     "glm-4.7-flash-l8": glm4_moe_lite.glm47_flash_l8,
     "glm-4.7-flash-tiny": glm4_moe_lite.glm47_flash_tiny,
+    "glm-5.2": glm_moe_dsa.glm52,
+    "glm-5.2-ep16": glm_moe_dsa.glm52_ep16,
+    "glm-5.2-tiny": glm_moe_dsa.glm52_tiny,
 }
 
 

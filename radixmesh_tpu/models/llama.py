@@ -41,6 +41,20 @@ the serving stack's model side that the north star requires
   un-absorbs. A config's leading dense layers (``first_k_dense``) are a
   stack of their own, scanned before the sparse ones by the same body
   (``_scan_layers``).
+- **Learned sparse attention, and a second kind of cache state**
+  (``models/glm_moe_dsa.py``, ``ops/dsa.py``): a layer with an indexer
+  ("full") scores every cached token against the token's cached index key
+  — a plane of its own beside the pool, ``ModelConfig.kv_index_shape``,
+  addressed by the pool's slots — and attends the ``index_topk`` best; a
+  "shared" layer attends the set of the full layer before it, which rides
+  the layer scan (a ``lax.cond`` on the layer's table row: shared layers
+  run no scoring and no selection). ``decode_step`` and
+  ``prefill_chunk_paged`` take ``index_pool`` and return it after
+  ``kv_pool``; a page table within ``index_topk`` tokens takes the dense
+  latent kernels unchanged.
+- **An expert share** (``ModelConfig.ep_rank`` / ``ep_size``): the expert
+  weights hold this chip's experts only; the layer routes over all of
+  them and computes its own part (``ops/moe.py``).
 """
 
 from __future__ import annotations
@@ -63,7 +77,8 @@ from radixmesh_tpu.ops.mla_attention import (
     latent_decode_attention,
     latent_prefill_attention,
 )
-from radixmesh_tpu.ops.moe import STATS as MOE_STATS, moe_ffn
+from radixmesh_tpu.ops import dsa
+from radixmesh_tpu.ops.moe import SHARE_STATS as MOE_STATS, STATS, moe_ffn
 from radixmesh_tpu.ops.norm import rms_norm
 from radixmesh_tpu.ops.rope import apply_rope, rope_frequencies, yarn_frequencies
 from radixmesh_tpu.ops.wquant import quantize_weight
@@ -142,6 +157,24 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Learned sparse attention (DSA) when ``index_topk`` > 0, over latent
+    # attention: a layer whose ``indexer_types`` entry is "full" scores
+    # every cached token with ``index_n_heads`` index queries against the
+    # token's cached index key (``index_head_dim`` wide, a cache plane of
+    # its own: ``kv_index_shape``) and attends the ``index_topk`` best; a
+    # "shared" layer has no indexer and attends the set of the nearest
+    # full layer before it (the first layer is full).
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    indexer_types: tuple | None = None
+    # An expert share: this chip holds experts ``[ep_rank * E / ep_size,
+    # (ep_rank + 1) * E / ep_size)`` of every sparse layer (``E`` =
+    # ``n_experts``, the router's width). It routes over all ``E``,
+    # computes its own experts' part and counts what went elsewhere
+    # (``ops/moe.py``); on one chip there is no exchange.
+    ep_rank: int = 0
+    ep_size: int = 1
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -186,6 +219,28 @@ class ModelConfig:
                 "qk_rope_head_dim > 0, and head_dim == qk_nope_head_dim + "
                 "qk_rope_head_dim"
             )
+        if self.index_topk and not (
+            self.latent and self.index_n_heads > 0
+            and self.index_head_dim >= self.qk_rope_head_dim
+            and self.indexer_types is not None
+            and len(self.indexer_types) == self.n_layers
+            and set(self.indexer_types) <= {"full", "shared"}
+            and self.indexer_types[0] == "full"
+        ):
+            raise ValueError(
+                "learned sparse attention needs latent attention, "
+                "index_n_heads > 0, index_head_dim >= qk_rope_head_dim and "
+                f"indexer_types naming {self.n_layers} layers as full or "
+                "shared, the first of them full"
+            )
+        if not (0 <= self.ep_rank < self.ep_size) or (
+            self.ep_size > 1
+            and (not self.n_experts or self.n_experts % self.ep_size)
+        ):
+            raise ValueError(
+                "an expert share needs 0 <= ep_rank < ep_size and n_experts "
+                "divisible by ep_size"
+            )
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -197,6 +252,20 @@ class ModelConfig:
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def dsa(self) -> bool:
+        return self.index_topk > 0
+
+    @property
+    def full_layers(self) -> tuple[int, ...]:
+        """The layers that hold an indexer (and a plane of index keys)."""
+        return tuple(i for i, t in enumerate(self.indexer_types or ())
+                     if t == "full")
+
+    @property
+    def held_experts(self) -> int:
+        return self.n_experts // self.ep_size
 
     @property
     def rope_dim(self) -> int:
@@ -223,6 +292,14 @@ class ModelConfig:
         view it as pages)."""
         planes, heads, width = self.kv_row
         return (planes, self.n_layers, heads, num_slots, width)
+
+    def kv_index_shape(self, num_slots: int) -> tuple[int, int, int] | None:
+        """The index-key plane beside the pool, ``[full layers, slots,
+        index_head_dim]``, addressed by the pool's slot numbers; None for
+        a config without an indexer."""
+        if not self.dsa:
+            return None
+        return (len(self.full_layers), num_slots, self.index_head_dim)
 
     @classmethod
     def llama3_8b(cls) -> "ModelConfig":
@@ -405,8 +482,9 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
     dims |= {"w_router": (H, cfg.n_experts)} if cfg.n_experts else ffn
     layers = _draw_stack(keys, L, dims, cfg, axis(0))
     if cfg.n_experts:
-        # [L, E, in, out], one expert's block at a time.
-        E = cfg.n_experts
+        # [L, E, in, out], one expert's block at a time: the experts this
+        # chip holds (all of them without a share).
+        E = cfg.held_experts
         for k, (name, (i_dim, o_dim)) in zip(
             jax.random.split(keys[6], 3), ffn.items()
         ):
@@ -436,6 +514,26 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         layers["bk"] = jnp.zeros((L, kvd), dtype=cfg.dtype)
         layers["bv"] = jnp.zeros((L, kvd), dtype=cfg.dtype)
     params = {"final_norm": jnp.ones((H,), dtype=cfg.dtype), "layers": layers}
+    if cfg.dsa:
+        # The indexers, stacked over the FULL layers only (a shared layer
+        # has none): index queries from the query bottleneck, one key a
+        # token from the hidden state (LayerNorm with a bias: a seeded
+        # non-zero draw, so that it is exercised), a weight a head.
+        n_full = len(cfg.full_layers)
+        Hi, Di = cfg.index_n_heads, cfg.index_head_dim
+        ik = jax.random.split(jax.random.fold_in(key, 104), 4)
+        idx = {}
+        for k, (name, (i_dim, o_dim)) in zip(ik, {
+            "wq": (cfg.q_lora_rank, Hi * Di), "wk": (H, Di), "ww": (H, Hi),
+        }.items()):
+            idx[name], _ = _draw_blocks(
+                k, n_full, (i_dim, o_dim), i_dim, cfg.dtype, None
+            )
+        idx["k_norm"] = jnp.ones((n_full, Di), dtype=cfg.dtype)
+        idx["k_bias"] = (0.02 * jax.random.normal(ik[3], (n_full, Di))).astype(
+            cfg.dtype
+        )
+        params["indexer"] = idx
     if cfg.first_k_dense:
         params["dense_layers"] = _draw_stack(
             jax.random.split(jax.random.fold_in(key, 102), 8),
@@ -535,6 +633,14 @@ def param_logical_axes(cfg: ModelConfig, params: dict | None = None) -> dict:
             "wkv_a": ("layer", "embed", "kv_lora"),
             "kv_norm": ("layer", "kv_lora"),
             "wkv_b": ("layer", "kv_lora", "q_heads"),
+        }
+    if cfg.dsa:
+        axes["indexer"] = {
+            "wq": ("layer", "q_lora", "index"),
+            "wk": ("layer", "embed", "index"),
+            "ww": ("layer", "embed", "index"),
+            "k_norm": ("layer", "index"),
+            "k_bias": ("layer", "index"),
         }
     if cfg.n_shared_experts:
         axes["layers"] |= {
@@ -637,7 +743,14 @@ def _latent_split(lp: dict, cfg: ModelConfig):
     return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
 
 
-def _latent_qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions, inv_freq):
+def _latent_cq(lp: dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The normalised query bottleneck ``RMSNorm(W_qa x)`` [B, S, q_lora]:
+    what the heads' queries and an indexer's queries are projected from."""
+    return rms_norm(_wmm(lp, "wq_a", "bsh,hr->bsr", x), lp["q_norm"], cfg.rms_eps)
+
+
+def _latent_qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions, inv_freq,
+                c_q: jnp.ndarray | None = None):
     """Latent attention's inputs from ``x`` [B, S, H]: the ABSORBED query
     ``q_nope W_k^T || RoPE(q_rope)`` [B, S, Hq, W] and the cache row
     ``RMSNorm(c_kv) || RoPE(k_rope)`` [B, S, 1, W] — key of every head, and
@@ -647,10 +760,17 @@ def _latent_qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions, inv_freq)
     K derived from the row: the same function, never materialised."""
     B, S = x.shape[:2]
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    c_q = rms_norm(_wmm(lp, "wq_a", "bsh,hr->bsr", x), lp["q_norm"], cfg.rms_eps)
-    q = _wmm(lp, "wq_b", "bsr,rd->bsd", c_q).reshape(
-        B, S, cfg.n_heads, cfg.head_dim
-    )
+    if c_q is None:
+        c_q = _latent_cq(lp, x, cfg)
+    q = _wmm(lp, "wq_b", "bsr,rd->bsd", c_q)
+    if B * S < cfg.q_lora_rank:
+        # Fewer tokens than the weight has rows (a decode step): without
+        # the barrier XLA lays wq_b out for the heads' batched product
+        # below and copies the layer's slice of it, transposed — 2 x 67 MB
+        # a layer at 2048 x 16384, 1.9 ms of a decode step — where
+        # transposing these few activations costs nothing (PERF.md, PR 33).
+        q = jax.lax.optimization_barrier(q)
+    q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
     q_rope = apply_rope(q[..., dn:], positions, inv_freq)
     kv = _wmm(lp, "wkv_a", "bsh,hr->bsr", x)
     c_kv = rms_norm(kv[..., :r], lp["kv_norm"], cfg.rms_eps)
@@ -669,14 +789,43 @@ def _latent_qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions, inv_freq)
     return jnp.concatenate([q_abs, q_rope, q_pad], axis=-1), row
 
 
+def _index_in(ip: dict, c_q: jnp.ndarray, h: jnp.ndarray, cfg: ModelConfig,
+              positions, inv_freq):
+    """A full layer's indexer on ``h`` [B, S, H] and the query bottleneck
+    ``c_q``: index queries [B, S, Hi, Di] and the token's index key
+    [B, S, Di] — the first ``qk_rope_head_dim`` elements of each rotated
+    with the layer's RoPE — and the heads' weights [B, S, Hi] float32,
+    already times ``Hi^-1/2 * Di^-1/2``."""
+    B, S = h.shape[:2]
+    Hi, Di, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+
+    def rotate(t):  # [B, S, heads, Di]
+        return jnp.concatenate(
+            [apply_rope(t[..., :dr], positions, inv_freq), t[..., dr:]], axis=-1
+        )
+
+    qi = jnp.einsum("bsr,rd->bsd", c_q, ip["wq"], precision=_PREC)
+    ki = jnp.einsum("bsh,hd->bsd", h, ip["wk"], precision=_PREC)
+    k32 = ki.astype(jnp.float32)
+    k32 = k32 - jnp.mean(k32, axis=-1, keepdims=True)
+    k32 = k32 * jax.lax.rsqrt(jnp.mean(k32 * k32, axis=-1, keepdims=True) + 1e-6)
+    ki = (k32 * ip["k_norm"].astype(jnp.float32)
+          + ip["k_bias"].astype(jnp.float32)).astype(h.dtype)
+    wi = jnp.einsum(
+        "bsh,hj->bsj", h.astype(jnp.float32), ip["ww"].astype(jnp.float32),
+        precision=_PREC,
+    ) * (Hi ** -0.5 * Di ** -0.5)
+    return rotate(qi.reshape(B, S, Hi, Di)), rotate(ki[:, :, None])[:, :, 0], wi
+
+
 def _attn_in(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions, inv_freq,
-             rope_scale):
+             rope_scale, c_q: jnp.ndarray | None = None):
     """``(q, k, v)`` of ``x`` [B, S, H], rotated, as the attention ops
     take them: per-head K and V, or for latent attention the absorbed
     query, the cache row as the one shared key head, and ``v`` None (the
     values are the row's first ``kv_lora_rank`` elements)."""
     if cfg.latent:
-        return *_latent_qkv(lp, x, cfg, positions, inv_freq), None
+        return *_latent_qkv(lp, x, cfg, positions, inv_freq, c_q), None
     q, k, v = _qkv(lp, x, cfg)
     q = apply_rope(q, positions, inv_freq, rope_scale)
     k = apply_rope(k, positions, inv_freq, rope_scale)
@@ -722,8 +871,10 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
         return None
     kinds = " and ".join(
         k for k, on in (("sparse experts", cfg.n_experts),
+                        ("an expert share", cfg.ep_size > 1),
                         ("window attention", cfg.has_window),
-                        ("a latent (MLA) cache row", cfg.latent)) if on
+                        ("a latent (MLA) cache row", cfg.latent),
+                        ("learned sparse attention", cfg.dsa)) if on
     )
     return (
         f"a model with {kinds} does not support " + "; ".join(asked)
@@ -743,8 +894,18 @@ def _layer_tables(cfg: ModelConfig) -> dict | None:
     (each layer type's RoPE: default, or YaRN with its attention factor on
     cos/sin) and ``window`` [L] (keys a query sees; 0 = all). None for a
     model of one layer kind, whose body closes over one ``inv_freq``."""
+    tables = {}
+    if cfg.dsa:
+        # Which layers hold an indexer, and for each layer the place of
+        # its (a shared layer: the nearest earlier) full layer in the
+        # indexer stack and the index-key plane.
+        full = [t == "full" for t in cfg.indexer_types]
+        tables |= {
+            "dsa_full": jnp.asarray(full),
+            "dsa_slot": jnp.asarray(np.cumsum(full) - 1, jnp.int32),
+        }
     if cfg.layer_types is None:
-        return None
+        return tables or None
     by_type = {}
     for kind, pairs in cfg.rope_by_type:
         rp = dict(pairs)
@@ -764,7 +925,7 @@ def _layer_tables(cfg: ModelConfig) -> dict | None:
         else:
             raise ValueError(f"unknown rope_type {rp['rope_type']!r}")
         by_type[kind] = (inv, scale)
-    return {
+    return tables | {
         "inv_freq": jnp.stack(
             [jnp.asarray(by_type[t][0]) for t in cfg.layer_types]
         ),
@@ -789,7 +950,7 @@ def _rope_of(inv_freq, table: dict | None):
     """``(inv_freq, cos/sin factor, window)`` of one layer: its row of
     ``_layer_tables``, or the model's one RoPE (``inv_freq``, computed
     once outside the scan) and no window."""
-    if table is None:
+    if table is None or "inv_freq" not in table:
         return inv_freq, None, None
     return table["inv_freq"], table["rope_scale"], table["window"]
 
@@ -799,8 +960,11 @@ def _rope_of(inv_freq, table: dict | None):
 MOE_SITES = ("decode", "prefill")
 
 
-def new_moe_stats() -> jnp.ndarray:
-    return jnp.zeros((len(MOE_SITES), len(MOE_STATS), 2), jnp.uint32)
+def new_moe_stats(share: bool = False) -> jnp.ndarray:
+    """Zeroed counters: ``ops/moe.py``'s four a site, and the fifth of a
+    config with an expert share."""
+    return jnp.zeros(
+        (len(MOE_SITES), len(MOE_STATS if share else STATS), 2), jnp.uint32)
 
 
 def read_moe_stats(stats) -> dict:
@@ -810,7 +974,7 @@ def read_moe_stats(stats) -> dict:
     return {
         site: {
             name: int(a[i, j, 0] + (a[i, j, 1] << np.uint64(32)))
-            for j, name in enumerate(MOE_STATS)
+            for j, name in enumerate(MOE_STATS[: a.shape[1]])
         }
         for i, site in enumerate(MOE_SITES)
     }
@@ -819,7 +983,7 @@ def read_moe_stats(stats) -> dict:
 def _bump_moe_stats(stats, site: str, delta: jnp.ndarray) -> jnp.ndarray:
     """``stats[site] += delta`` ([n_counters] int32, non-negative) with
     the carry into the high word."""
-    stats = new_moe_stats() if stats is None else stats
+    stats = new_moe_stats(delta.shape[0] > len(STATS)) if stats is None else stats
     i = MOE_SITES.index(site)
     lo = stats[i, :, 0] + delta.astype(jnp.uint32)
     hi = stats[i, :, 1] + (lo < stats[i, :, 0]).astype(jnp.uint32)
@@ -889,6 +1053,7 @@ def _ffn(params: dict, lp: dict, l_idx, x: jnp.ndarray, cfg: ModelConfig,
         scoring=cfg.router_scoring, bias=lp.get("router_bias"),
         scale=cfg.routed_scaling_factor,
         site=site, valid=None if valid is None else valid.reshape(B * S),
+        first_held=cfg.ep_rank * cfg.held_experts,
         **kernel_kw,
     )
     y = y.reshape(B, S, H)
@@ -897,17 +1062,45 @@ def _ffn(params: dict, lp: dict, l_idx, x: jnp.ndarray, cfg: ModelConfig,
     return y, (ids.reshape(B, S, -1), stats)
 
 
-def _moe_aux(cfg: ModelConfig, per_layer, moe_stats, site: str) -> tuple:
+def _moe_aux(cfg: ModelConfig, per_layer, moe_stats, site: str,
+             **extra) -> tuple:
     """The trailing ``aux`` of an entry point: ``()`` for a dense model,
     else one dict — the scan's stacked expert ids [L, B, S, k] and the
-    counters with this launch added."""
+    counters with this launch added (and what ``extra`` names)."""
     if not cfg.n_experts:
         return ()
     ids, stats = per_layer
     return ({
         "expert_ids": ids,
         "moe_stats": _bump_moe_stats(moe_stats, site, jnp.sum(stats, axis=0)),
+        **extra,
     },)
+
+
+def _indexer_of(params: dict, table: dict) -> dict:
+    """The weights of the indexer of the layer whose table row this is."""
+    return jax.tree_util.tree_map(
+        lambda w: jax.lax.dynamic_index_in_dim(
+            w, table["dsa_slot"], 0, keepdims=False),
+        params["indexer"],
+    )
+
+
+def _selected(cfg: ModelConfig, positions) -> dict:
+    """``aux``'s entry for the logits hook: the token positions each full
+    layer selected for the sampled query of every row,
+    ``index_positions`` [L_full, B, 1, K] (-1: nothing); nothing where
+    the launch attended every row."""
+    if positions is None:
+        return {}
+    return {"index_positions": _of_full_layers(cfg, positions)}
+
+
+def _of_full_layers(cfg: ModelConfig, stacked: jnp.ndarray) -> jnp.ndarray:
+    """What the layer scan stacked a layer [L, ...] — index keys,
+    selected positions — cut to the full layers (a shared layer's entry
+    is zeros, or its full layer's again): [L_full, ...]."""
+    return stacked[np.asarray(cfg.full_layers)]
 
 
 def _attn_out(lp: dict, attn: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
@@ -917,12 +1110,16 @@ def _attn_out(lp: dict, attn: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     width = cfg.head_dim
     if cfg.latent:
         # attn is the heads' mix of latent rows [B, S, Hq, r]: their values.
-        width = cfg.v_head_dim
         with jax.named_scope("rm_mla_unabsorb"):
             attn = jnp.einsum(
                 "bsqr,rqv->bsqv", attn, _latent_split(lp, cfg)[1],
                 precision=_PREC,
             )
+        # One [Hq * v, H] product (a latent config takes no mesh, so no
+        # head axis to shard): the per-head form below made the layer scan
+        # materialise its slice of wo, 0.2 GB a layer at 64 heads of 256
+        # into 6144 — 3.7 ms of a 35 ms decode step (PERF.md, PR 33).
+        return _wmm(lp, "wo", "bsk,kh->bsh", attn.reshape(B, S, -1))
     return _wmm(
         lp, "wo", "bsqd,qdh->bsh",
         attn.reshape(B, S, cfg.n_heads, width),
@@ -966,9 +1163,11 @@ def prefill_forward(
     caller writes new_k/new_v into the paged pool at the slots the radix
     tree allocated, which is how a served prompt becomes a reusable cached
     prefix (the contract the reference's commented-out scheduler hooks
-    sketch, ``radix_cache.py:439-519``). A config with experts returns
-    ``aux`` as a fourth value (module docstring); ``new_lengths`` keeps
-    the bucket's padding out of its counters.
+    sketch, ``radix_cache.py:439-519``). A config with an indexer returns
+    the new tokens' index keys [L_full, B, S, Di] as a fourth value (the
+    caller writes them at the same slots), and a config with experts
+    ``aux`` as the last (module docstring); ``new_lengths`` keeps the
+    bucket's padding out of its counters.
     """
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)
@@ -982,15 +1181,51 @@ def prefill_forward(
     valid = None
     if new_lengths is not None:
         valid = jnp.arange(s_new)[None, :] < new_lengths[:, None]
+    if cfg.dsa and p_max:
+        raise ValueError(
+            "prefill_forward takes no cached prefix for a model with learned "
+            "sparse attention (the prefix's index keys lie in pages: "
+            "prefill_chunk_paged)"
+        )
+    # Past index_topk tokens the selection engages: as a mask over the
+    # dense scores (this path holds at most long_prefill_threshold tokens).
+    select = cfg.dsa and s_new > cfg.index_topk
+    causal = jnp.tril(jnp.ones((s_new, s_new), bool))[None] if select else None
+    mask0 = jnp.zeros((tokens.shape[0], s_new, s_new) if select else (), bool)
 
-    def layer(x, xs, sparse):
+    def layer(carry, xs, sparse):
+        x, mask = carry
         l_idx, lp, table = xs
         ck = jax.lax.dynamic_index_in_dim(cached_k, l_idx, 0, keepdims=False)
         inv_freq, rope_scale, window = _rope_of(one_rope, table)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _attn_in(lp, h, cfg, positions, inv_freq, rope_scale)
+        c_q = _latent_cq(lp, h, cfg) if cfg.dsa else None
+        q, k, v = _attn_in(lp, h, cfg, positions, inv_freq, rope_scale, c_q)
         k_ctx = jnp.concatenate([ck, k], axis=1)  # [B, P_max + S, Hkv, D]
-        if cfg.latent:
+        ki = None
+        if cfg.dsa:
+
+            def full(mask):
+                qi, ki, wi = _index_in(
+                    _indexer_of(params, table), c_q, h, cfg, positions, inv_freq)
+                if select:
+                    ki = ki.astype(cfg.dtype)  # score what the cache will hold
+                    mask = dsa.dense_selection_mask(
+                        dsa.own_scores(qi, wi, ki, causal), cfg.index_topk)
+                return ki, mask
+
+            ki, mask = jax.lax.cond(
+                table["dsa_full"], full,
+                lambda mask: (jnp.zeros(
+                    (*h.shape[:2], cfg.index_head_dim), h.dtype), mask),
+                mask,
+            )
+        if select:
+            attn = dsa.masked_prefill_attention(
+                q, k[:, :, 0], mask & causal, cfg.kv_lora_rank,
+                cfg.head_dim ** -0.5,
+            )
+        elif cfg.latent:
             attn = latent_prefill_attention(
                 q, k_ctx, attn_pos, kv_end, pad, cfg.kv_lora_rank,
                 cfg.head_dim ** -0.5,
@@ -1004,10 +1239,12 @@ def prefill_forward(
         x = x + _attn_out(lp, attn, cfg)
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse)
-        return x + y, (k, v, aux)
+        return (x + y, mask), (k, v, aux, ki)
 
-    x, (new_k, new_v, aux) = _scan_layers(cfg, params, layer, x)
-    return (_logits(params, cfg, x), new_k, new_v,
+    (x, _), (new_k, new_v, aux, new_ki) = _scan_layers(
+        cfg, params, layer, (x, mask0))
+    index_keys = (_of_full_layers(cfg, new_ki),) if cfg.dsa else ()
+    return (_logits(params, cfg, x), new_k, new_v, *index_keys,
             *_moe_aux(cfg, aux, moe_stats, "prefill"))
 
 
@@ -1074,7 +1311,7 @@ def prefill_forward_sp(
         "interpret",
     ),
     donate_argnums=(4,),
-    donate_argnames=("kv_scale",),
+    donate_argnames=("kv_scale", "index_pool"),
 )
 def prefill_chunk_paged(
     params: dict,
@@ -1093,6 +1330,7 @@ def prefill_chunk_paged(
     interpret: bool = False,
     logits_at: jnp.ndarray | None = None,  # [B] per-row chunk offsets, or None
     moe_stats: jnp.ndarray | None = None,  # the device's moe counters
+    index_pool: jnp.ndarray | None = None,  # [L_full, num_slots, Di] (donated)
 ):
     """One CHUNK of long-context prefill against the paged pool (SURVEY §5:
     the 32k Qwen2 gate must never materialize O(S²) scores — VERDICT
@@ -1113,13 +1351,26 @@ def prefill_chunk_paged(
     samples one position per row, and the default 8 × 512 wave's
     all-position f32 logits at a 152k vocabulary are 2.3 GiB. A config
     with experts returns ``aux`` as one more trailing value (module
-    docstring); its counters leave out the chunk's padding.
+    docstring); its counters leave out the chunk's padding. A config with
+    an indexer takes ``index_pool`` and returns it after ``kv_pool``, the
+    chunk's index keys written at ``slots``; once the context (the page
+    table's width and the chunk) passes ``index_topk`` its queries attend
+    selected rows only (``ops/dsa.py``).
     """
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)  # [B, C, H]
     kv_pages = kv_pool.reshape(_pages_shape(kv_pool, page_size))
     num_slots = kv_pool.shape[3]
+    B, C = tokens.shape
+    select = cfg.dsa and page_table.shape[1] * page_size + C > cfg.index_topk
+    sel0 = index_pages = None
+    if cfg.dsa:
+        index_pages = index_pool.reshape(
+            index_pool.shape[0], num_slots // page_size, page_size, -1)
+        sel0 = dsa.empty_selection(
+            B, C, C, page_table.shape[1], page_size, cfg.index_topk
+        ) if select else None
     scale_pages = (
         None
         if kv_scale is None
@@ -1136,11 +1387,13 @@ def prefill_chunk_paged(
         "use_kernel": use_kernel, "interpret": interpret,
     }
 
-    def layer(x, xs, sparse):
+    def layer(carry, xs, sparse):
+        x, sel = carry
         l_idx, lp, table = xs
         inv_freq, rope_scale, window = _rope_of(one_rope, table)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _attn_in(lp, h, cfg, positions, inv_freq, rope_scale)
+        c_q = _latent_cq(lp, h, cfg) if cfg.dsa else None
+        q, k, v = _attn_in(lp, h, cfg, positions, inv_freq, rope_scale, c_q)
         if kv_scale is not None:
             # Quantize NOW and attend the dequantized copy, so the chunk
             # sees exactly what any later pool read will see (the fused
@@ -1149,8 +1402,36 @@ def prefill_chunk_paged(
             from radixmesh_tpu.ops.quant import quantize_for_store
 
             k_int, v_int, k_sc, v_sc, k, v = quantize_for_store(k, v)
+        ki = None
+        if cfg.dsa:
+
+            def full(sel):
+                qi, ki, wi = _index_in(
+                    _indexer_of(params, table), c_q, h, cfg, positions, inv_freq)
+                ki = ki.astype(index_pool.dtype)  # score what the pool holds
+                if select:
+                    sel = dsa.chunk_select(
+                        qi, wi, ki, index_pages, page_table, prior_lengths,
+                        kv_lengths, table["dsa_slot"], cfg.index_topk,
+                        use_kernel=use_kernel, interpret=interpret,
+                    )
+                return ki, sel
+
+            ki, sel = jax.lax.cond(
+                table["dsa_full"], full,
+                lambda sel: (jnp.zeros(
+                    (B, C, cfg.index_head_dim), index_pool.dtype), sel),
+                sel,
+            )
         if cfg.latent:
             k = k.astype(kv_pool.dtype)  # attend what the pool will hold
+        if select:
+            attn = dsa.chunk_attend(
+                q, k[:, :, 0], sel, kv_pages, l_idx, cfg.kv_lora_rank,
+                cfg.head_dim ** -0.5, use_kernel=use_kernel,
+                interpret=interpret,
+            )
+        elif cfg.latent:
             attn = latent_chunk_attention(
                 q, k[:, :, 0], kv_pages, page_table, positions, prior_lengths,
                 kv_lengths, l_idx, cfg.kv_lora_rank, cfg.head_dim ** -0.5,
@@ -1181,10 +1462,16 @@ def prefill_chunk_paged(
                       **moe_kw)
         x = x + y
         if kv_scale is not None:
-            return x, (k_int, v_int, k_sc, v_sc)
+            return (x, sel), (k_int, v_int, k_sc, v_sc)
+        picked = None
+        if select:  # what the sampled position selected, for the logits hook
+            at = jnp.zeros((B,), jnp.int32) if logits_at is None else logits_at
+            picked = jnp.take_along_axis(
+                sel.positions, at[:, None, None], axis=1)
         if cfg.latent:
-            return x, (k, None, aux)
-        return x, (k.astype(kv_pool.dtype), v.astype(kv_pool.dtype), aux)
+            return (x, sel), (k, None, aux, ki, picked)
+        return (x, sel), (
+            k.astype(kv_pool.dtype), v.astype(kv_pool.dtype), aux, ki, picked)
 
     def pick(x):
         if logits_at is None:
@@ -1192,7 +1479,8 @@ def prefill_chunk_paged(
         return jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
 
     if kv_scale is not None:
-        x, (new_k, new_v, new_ks, new_vs) = _scan_layers(cfg, params, layer, x)
+        (x, _), (new_k, new_v, new_ks, new_vs) = _scan_layers(
+            cfg, params, layer, (x, None))
         # Already quantized in-layer (same ints attention saw); scatter the
         # int8 payloads + scales: scan stacks [L, B, C, Hkv(, D)] → the
         # pool target [:, :, :, slots[B, C]] expects [2, L, Hkv, B, C(, D)].
@@ -1201,22 +1489,27 @@ def prefill_chunk_paged(
         kv_pool = kv_pool.at[:, :, :, slots].set(new_kv)
         kv_scale = kv_scale.at[:, :, :, slots].set(new_s)
         return _logits(params, cfg, pick(x)), kv_pool, kv_scale
-    x, (new_k, new_v, aux) = _scan_layers(cfg, params, layer, x)
+    (x, _), (new_k, new_v, aux, new_ki, picked) = _scan_layers(
+        cfg, params, layer, (x, sel0))
     # One scatter for the whole chunk across all layers: scan stacks
     # [L, B, C, Hkv, D] a plane; the pool indexed at [:, :, :, slots[B,C]]
     # expects [planes, L, Hkv, B, C, D].
     planes = [new_k] if new_v is None else [new_k, new_v]
     new_kv = jnp.stack(planes).transpose(0, 1, 4, 2, 3, 5)
     kv_pool = kv_pool.at[:, :, :, slots].set(new_kv)
-    return (_logits(params, cfg, pick(x)), kv_pool,
-            *_moe_aux(cfg, aux, moe_stats, "prefill"))
+    pools = (kv_pool,)
+    if cfg.dsa:  # the chunk's index keys, at the same slots
+        pools += (index_pool.at[:, slots].set(_of_full_layers(cfg, new_ki)),)
+    return (_logits(params, cfg, pick(x)), *pools,
+            *_moe_aux(cfg, aux, moe_stats, "prefill",
+                      **_selected(cfg, picked)))
 
 
 @partial(
     jax.jit,
     static_argnames=("cfg", "page_size", "mesh"),
     donate_argnums=(3,),
-    donate_argnames=("kv_scale",),
+    donate_argnames=("kv_scale", "index_pool"),
 )
 def decode_step(
     params: dict,
@@ -1230,6 +1523,7 @@ def decode_step(
     mesh=None,
     kv_scale: jnp.ndarray | None = None,  # [2, L, Hkv, num_slots] int8 pool
     moe_stats: jnp.ndarray | None = None,  # the device's moe counters
+    index_pool: jnp.ndarray | None = None,  # [L_full, num_slots, Di] (donated)
 ):
     """One decode step for a continuous batch: writes this token's K/V into
     the paged pool inside the layer scan, attends over the radix-cache
@@ -1241,10 +1535,14 @@ def decode_step(
     axis, the Pallas kernel shard_map'd per chip; all other ops partition
     via GSPMD from the params/pool shardings. A config with experts
     returns ``aux`` as a third value (module docstring); a row whose
-    ``lengths`` is 1 is an idle batch row and stays out of its counters."""
+    ``lengths`` is 1 is an idle batch row and stays out of its counters.
+    A config with an indexer takes ``index_pool`` and returns it after
+    ``kv_pool``; once the page table is wider than ``index_topk`` tokens
+    a full layer scores the row's index keys and selects, and every layer
+    reads the selected rows only (``ops/dsa.py``)."""
     return _decode_core(
         params, cfg, tokens, kv_pool, slots, page_table, lengths, page_size,
-        mesh, kv_scale, moe_stats,
+        mesh, kv_scale, moe_stats, index_pool,
     )
 
 
@@ -1260,6 +1558,7 @@ def _decode_core(
     mesh,
     kv_scale: jnp.ndarray | None = None,
     moe_stats: jnp.ndarray | None = None,
+    index_pool: jnp.ndarray | None = None,
 ):
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
@@ -1275,14 +1574,47 @@ def _decode_core(
         2, cfg.n_layers, cfg.n_kv_heads, num_slots // page_size, page_size,
     )
 
+    select = cfg.dsa and page_table.shape[1] * page_size + 1 > cfg.index_topk
+    sel0 = dsa.empty_selection(
+        B, 1, 1, page_table.shape[1], page_size, cfg.index_topk
+    ) if select else None
+    index_shape = None if index_pool is None else index_pool.shape
+    index_pages_shape = None if index_pool is None else (
+        index_shape[0], num_slots // page_size, page_size, index_shape[2])
+
     def layer(carry, xs, sparse):
-        x, kv_pool, kv_scale = carry
+        x, kv_pool, kv_scale, index_pool, sel = carry
         l_idx, lp, table = xs
         inv_freq, rope_scale, window = _rope_of(one_rope, table)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        c_q = _latent_cq(lp, h, cfg) if cfg.dsa else None
         q, k, v = _attn_in(  # [B,1,*,D]
-            lp, h, cfg, positions[:, None], inv_freq, rope_scale
+            lp, h, cfg, positions[:, None], inv_freq, rope_scale, c_q
         )
+        if cfg.dsa:
+            index_pages = index_pool.reshape(index_pages_shape)
+
+            def full(sel):
+                qi, ki, wi = _index_in(
+                    _indexer_of(params, table), c_q, h, cfg,
+                    positions[:, None], inv_freq)
+                ki = ki[:, 0].astype(index_pool.dtype)
+                if select:
+                    sel = dsa.decode_select(
+                        qi[:, 0], wi[:, 0], ki, index_pages, page_table,
+                        lengths, table["dsa_slot"], cfg.index_topk)
+                return ki, sel
+
+            # The branch READS the index pool and returns the key alone: a
+            # pool that a branch returns is copied whole, in and out.
+            ki, sel = jax.lax.cond(
+                table["dsa_full"], full,
+                lambda sel: (jnp.zeros((B, index_shape[2]), index_pool.dtype), sel),
+                sel,
+            )
+            at = (table["dsa_slot"], slots)
+            index_pool = index_pool.at[at].set(
+                jnp.where(table["dsa_full"], ki, index_pool[at]))
         # Fused write+attend: the Pallas kernel writes this token's K/V row
         # into the (aliased) pool and attends over this layer's pages — the
         # pool buffer flows through the scan with zero copies. (A separate
@@ -1305,6 +1637,12 @@ def _decode_core(
             )
             kv_scale = scale_pages.reshape(
                 2, cfg.n_layers, cfg.n_kv_heads, num_slots
+            )
+        elif select:
+            attn, kv_pages = dsa.decode_attend(
+                q[:, 0], k[:, 0, 0].astype(kv_pool.dtype), sel,
+                kv_pool.reshape(pages_shape), slots, l_idx, cfg.kv_lora_rank,
+                cfg.head_dim ** -0.5,
             )
         elif cfg.latent:
             attn, kv_pages = latent_decode_attention(
@@ -1335,15 +1673,18 @@ def _decode_core(
         x = x + _attn_out(lp, attn[:, None], cfg)
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         y, aux = _ffn(params, lp, l_idx, h2, cfg, "decode", live, sparse)
-        return (x + y, kv_pool, kv_scale), aux
+        return (x + y, kv_pool, kv_scale, index_pool, sel), (
+            aux, sel.positions if select else None)
 
-    (x, kv_pool, kv_scale), aux = _scan_layers(
-        cfg, params, layer, (x, kv_pool, kv_scale)
+    (x, kv_pool, kv_scale, index_pool, _), (aux, picked) = _scan_layers(
+        cfg, params, layer, (x, kv_pool, kv_scale, index_pool, sel0)
     )
     logits = _logits(params, cfg, x)[:, 0]
     if kv_scale is not None:
         return logits, kv_pool, kv_scale
-    return logits, kv_pool, *_moe_aux(cfg, aux, moe_stats, "decode")
+    pools = (kv_pool,) if index_pool is None else (kv_pool, index_pool)
+    return logits, *pools, *_moe_aux(
+        cfg, aux, moe_stats, "decode", **_selected(cfg, picked))
 
 
 # ---------------------------------------------------------------------------

@@ -21,6 +21,12 @@ pipeline fetches an expert's weight once however many rows it was given,
 and an expert that no row chose is never fetched: a decode step streams
 the experts that were hit and nothing else.
 
+An expert share (``moe_ffn(first_held=)``): the weights hold ``E_held`` of
+the router's ``E`` experts. The layer routes over all ``E``; pairs whose
+expert is held elsewhere sort behind the held ones into tiles that are
+never run and add nothing to ``y``; nothing stands in for the other
+shares, and nothing is exchanged.
+
 A ``jax.numpy`` path of the same layer serves every other lowering target
 (the CPU): a scan over the experts, each applied to all tokens and
 weighted by the routing weight, which is zero where the expert was not
@@ -36,10 +42,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["route", "expert_layout", "grouped_matmul", "moe_ffn", "STATS"]
+__all__ = ["route", "expert_layout", "grouped_matmul", "moe_ffn", "STATS",
+           "SHARE_STATS"]
 
 # What one expert-layer launch adds to the ``moe`` counters, in order.
+# ``assignments``, ``experts_hit`` and ``max_load`` count the experts this
+# chip HOLDS (all of them without a share). Under an expert share a fifth
+# counts the (token, expert) pairs the router gave to experts of other
+# shares.
 STATS = ("layer_launches", "assignments", "experts_hit", "max_load")
+SHARE_STATS = STATS + ("assignments_elsewhere",)
 
 _PREC = jax.lax.Precision.HIGHEST
 _VMEM_LIMIT = 64 * 1024 * 1024
@@ -81,13 +93,17 @@ def tile_rows(n_tokens: int, k: int, n_experts: int) -> int:
     return min(256, max(16, 1 << (mean - 1).bit_length()))
 
 
-def expert_layout(top_e: jnp.ndarray, n_experts: int, tm: int) -> dict:
+def expert_layout(top_e: jnp.ndarray, n_experts: int, tm: int,
+                  n_live: int | None = None) -> dict:
     """The pairs of ``top_e`` [T, k] sorted by expert (a counting sort,
     stable in pair order), each expert's run padded to a multiple of
     ``tm``. Returns ``row_token`` [M_pad] (the token of each row; pad rows
     name token 0 and are never gathered back), ``dest`` [T, k] (the row of
     each pair), ``tile_group`` [M_pad // tm] (the expert of each tile)
-    and ``num_tiles`` (tiles in use)."""
+    and ``num_tiles`` (tiles in use). With ``n_live`` < ``n_experts`` the
+    groups from ``n_live`` on sort last and their tiles are not in use:
+    pairs that belong to no expert held here (an expert share)."""
+    n_live = n_experts if n_live is None else n_live
     T, k = top_e.shape
     M = T * k
     m_pad = -(-(M + n_experts * (tm - 1)) // tm) * tm
@@ -106,13 +122,13 @@ def expert_layout(top_e: jnp.ndarray, n_experts: int, tm: int) -> dict:
         jnp.searchsorted(
             ends // tm, jnp.arange(m_pad // tm, dtype=jnp.int32), side="right"
         ),
-        n_experts - 1,
+        n_live - 1,
     ).astype(jnp.int32)
     return {
         "row_token": row_token,
         "dest": dest.reshape(T, k),
         "tile_group": tile_group,
-        "num_tiles": (ends[-1] // tm).astype(jnp.int32),
+        "num_tiles": (ends[n_live - 1] // tm).astype(jnp.int32),
     }
 
 
@@ -170,12 +186,12 @@ def grouped_matmul(
 
 
 def _experts_kernel(x, top_e, top_w, w_gate, w_up, w_down, layer, *, site,
-                    interpret):
+                    interpret, n_routed):
     T, k = top_e.shape
-    E = w_gate.shape[1]
-    tm = tile_rows(T, k, E)
+    E = w_gate.shape[1]  # experts held here; a pair of another share is E
+    tm = tile_rows(T, k, n_routed)
     with jax.named_scope("rm_moe_route"):
-        lay = expert_layout(top_e, E, tm)
+        lay = expert_layout(top_e, E + (E < n_routed), tm, n_live=E)
         rows = x[lay["row_token"]]  # [M_pad, H]
     gmm = functools.partial(
         grouped_matmul, layer=layer, tile_group=lay["tile_group"],
@@ -186,8 +202,9 @@ def _experts_kernel(x, top_e, top_w, w_gate, w_up, w_down, layer, *, site,
     h = (jax.nn.silu(gate) * up).astype(x.dtype)
     out = gmm(h, w_down)  # [M_pad, H] float32
     with jax.named_scope("rm_moe_combine"):
-        picked = out[lay["dest"]]  # [T, k, H]
-        return jnp.sum(picked * top_w[..., None], axis=1)
+        # A pair of another share lies in a tile that was not computed.
+        picked = jnp.where((top_e < E)[..., None], out[lay["dest"]], 0.0)
+        return jnp.sum(picked * top_w[..., None], axis=1)  # [T, H]
 
 
 def _experts_reference(x, top_e, top_w, w_gate, w_up, w_down, layer):
@@ -233,38 +250,50 @@ def moe_ffn(
     bias: jnp.ndarray | None = None,  # [E] selection bias of a sigmoid router
     scale: float = 1.0,  # multiplies the chosen weights
     valid: jnp.ndarray | None = None,  # [T] rows that are real tokens
+    first_held: int = 0,  # the first expert this chip holds (an expert share)
     use_kernel: bool | None = None,
     interpret: bool = False,
 ):
     """The expert layer on ``x``. Returns ``(y [T, H] in x's dtype, expert
     ids [T, k], stats [4] int32)``; ``stats`` is what this launch adds to
-    the counters named in ``STATS``, over the rows ``valid`` marks (all,
-    if None). A layer scan passes the stacked expert weights and its
-    ``layer`` index, never a slice (``grouped_matmul``). ``use_kernel=None``
-    follows the lowering target: the Pallas kernel for a TPU, the
-    ``jax.numpy`` path for anything else."""
+    the counters named in ``STATS`` (``SHARE_STATS``, [5], under an expert
+    share), over the rows ``valid`` marks (all, if None). A layer scan
+    passes the stacked expert weights and its ``layer`` index, never a
+    slice (``grouped_matmul``). ``use_kernel=None`` follows the lowering
+    target: the Pallas kernel for a TPU, the ``jax.numpy`` path for
+    anything else.
+
+    An expert share: the router is as wide as the model's experts
+    (``w_router`` [H, E]) while the weights hold ``E_held`` of them,
+    experts ``[first_held, first_held + E_held)``. The layer routes over
+    all ``E``, computes the part of the result its own experts give (the
+    weights normalised over all ``k`` chosen, as published) and counts
+    what went elsewhere; nothing stands in for the other shares."""
     if w_gate.ndim == 3:
         w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
     layer = jnp.asarray(layer, jnp.int32)
-    E = w_router.shape[1]
+    E, held = w_router.shape[1], w_gate.shape[1]
     with jax.named_scope("rm_moe_route"):
         top_e, top_w = route(x, w_router, k, norm_topk, scoring, bias, scale)
         ok = jnp.ones(x.shape[:1], bool) if valid is None else valid
-        hit = (top_e[..., None] == jnp.arange(E, dtype=jnp.int32)) & ok[
+        local = top_e - first_held
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        hit = (local[..., None] == jnp.arange(held, dtype=jnp.int32)) & ok[
             :, None, None
         ]
-        load = jnp.sum(hit, axis=(0, 1)).astype(jnp.int32)  # [E]
-        stats = jnp.stack([
-            jnp.int32(1),
-            jnp.sum(ok).astype(jnp.int32) * k,
-            jnp.sum(load > 0).astype(jnp.int32),
-            jnp.max(load),
-        ])
+        load = jnp.sum(hit, axis=(0, 1)).astype(jnp.int32)  # [held]
+        here = jnp.sum(load)
+        stats = [jnp.int32(1), here, jnp.sum(load > 0).astype(jnp.int32),
+                 jnp.max(load)]
+        if held < E:  # a share: what the router gave to the other shares
+            stats.append(jnp.sum(ok).astype(jnp.int32) * k - here)
+        stats = jnp.stack(stats)
 
     def kernel(*args):
-        return _experts_kernel(*args, site=site, interpret=interpret)
+        return _experts_kernel(*args, site=site, interpret=interpret,
+                               n_routed=E)
 
-    args = (x, top_e, top_w, w_gate, w_up, w_down, layer)
+    args = (x, local, top_w, w_gate, w_up, w_down, layer)
     if use_kernel is None:
         y = jax.lax.platform_dependent(
             *args, tpu=kernel, default=_experts_reference

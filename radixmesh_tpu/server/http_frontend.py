@@ -375,6 +375,11 @@ def _logits_payload(req: Request) -> dict:
             {"start": start, **_b64_array(ids.astype("<i4"))}
             for start, ids in req.expert_rows
         ]
+    if req.index_rows:  # learned sparse attention: [L_full, K] a position
+        out["index_positions"] = [
+            {"position": position, **_b64_array(sel.astype("<i4"))}
+            for position, sel in req.index_rows
+        ]
     return out
 
 
@@ -743,6 +748,9 @@ class ServingFrontend:
                     "page_size": eng.pool.page_size,
                     "quant": eng.pool.quant,
                 },
+                # The state beside the rows: the index keys of a model
+                # with learned sparse attention (0: it has none).
+                "kv": {"index_plane_bytes": eng.pool.index_plane_bytes},
                 "cache": {
                     "evictable_tokens": getattr(tree, "evictable_size_", None),
                     "protected_tokens": getattr(tree, "protected_size_", None),
@@ -765,7 +773,8 @@ class ServingFrontend:
             if eng._moe_stats is not None:
                 # The expert layer's counters, by call site: kept on the
                 # device, fetched here and nowhere else.
-                state["moe"] = read_moe_stats(eng._moe_stats)
+                state["moe"] = read_moe_stats(eng._moe_stats) | {
+                    "held_experts": eng.cfg.held_experts}
             host = getattr(tree, "host", None)
             if host is not None:
                 state["host_tier"] = {
