@@ -39,7 +39,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["SlotAllocator", "PagedKVPool"]
+__all__ = [
+    "SlotAllocator", "PagedKVPool", "ALL_LAYERS", "write_runs", "write_runs_all",
+    "gather_run",
+]
 
 
 class SlotAllocator:
@@ -151,112 +154,273 @@ def _pad_to_bucket(slots: np.ndarray, arrays: list, token_axes: list):
     return slots, padded
 
 
-@partial(jax.jit, donate_argnums=(0,))
-def _scatter_kv(kv: jax.Array, slots: jax.Array, new_kv: jax.Array) -> jax.Array:
-    # kv: [2, L, H, S, D]; slots: [n]; new_kv: [2, L, H, n, D]
-    return kv.at[:, :, :, slots].set(new_kv)
+# ---------------------------------------------------------------------------
+# Rows enter and leave the pool by whole pages.
+#
+# XLA:TPU will not scatter into, or gather from, the slot axis of
+# ``[planes, L, heads, slots, width]`` in the pool's layout: it copies the
+# WHOLE pool into a layout with the slot axis outside the head axis, works
+# there and copies the whole pool back (two pool-sized copies a write, one
+# a gather, whatever the number of rows). Whole pages of the page view
+# ``[planes, L, heads, pages, page, width]`` move with neither: a page is
+# one tile of the layout the pool already has. So a writer hands over RUNS
+# — ``slots [B, C]``, each row the consecutive rows of one request, as the
+# allocator and the tree hand them out — and the run's pages are read,
+# overlaid with the new rows at their offsets, and written back whole.
+# One page after the other, in a loop: one XLA scatter of the same pages
+# takes up to 2.8 times as long on the chip (its update wants a page-major
+# copy of the rows; PERF.md section 5, PR 34), and two copies of a page
+# in it (the scratch page) would race.
+# ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, donate_argnums=(0,))
-def _scatter_index(index: jax.Array, slots: jax.Array, keys: jax.Array) -> jax.Array:
-    # index: [L_full, S, Di]; slots: [n]; keys: [L_full, n, Di]
-    return index.at[:, slots].set(keys)
+def is_run(slots: np.ndarray, page_size: int) -> bool:
+    """Host side: ``slots`` are consecutive rows of their pages — each
+    next slot is the next offset of the same page, or offset 0 of another
+    page — and no page is entered twice."""
+    slots = np.asarray(slots)
+    pg, off = slots // page_size, slots % page_size
+    step = (off[1:] == off[:-1] + 1) & (pg[1:] == pg[:-1])
+    turn = (off[1:] == 0) & (off[:-1] == page_size - 1)
+    if not np.all(step | turn):
+        return False
+    heads = np.concatenate([pg[:1], pg[1:][turn]])
+    return len(np.unique(heads)) == len(heads)
 
 
-@jax.jit
-def _gather_kv(kv: jax.Array, slots: jax.Array) -> jax.Array:
-    # → [2, L, n, H, D] (token-major, for tests/debug)
-    return kv[:, :, :, slots].transpose(0, 1, 3, 2, 4)
+def _pages_of_run(page_size: int, rows: int) -> int:
+    """The most pages a run of ``rows`` consecutive rows can touch."""
+    return (rows + 2 * page_size - 2) // page_size
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def _scatter_kv_quant(
-    kv: jax.Array,  # int8 [2, L, H, S, D]
-    kv_scale: jax.Array,  # f32 [2, L, H, S]
-    slots: jax.Array,  # [n]
-    new_kv: jax.Array,  # [2, L, H, n, D] float
-):
-    from radixmesh_tpu.ops.quant import quantize_kv
-
-    q, scale = quantize_kv(new_kv, axis=-1)
-    return kv.at[:, :, :, slots].set(q), kv_scale.at[:, :, :, slots].set(scale)
+def _i32(x: int) -> np.int32:
+    return np.int32(x)
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def _scatter_kv_raw(
-    kv: jax.Array,  # int8 [2, L, H, S, D]
-    kv_scale: jax.Array,  # f32 [2, L, H, S]
-    slots: jax.Array,  # [n]
-    new_kv: jax.Array,  # int8 token-major [2, L, n, H, D]
-    new_scale: jax.Array,  # f32 [2, L, n, H]
-):
-    return (
-        kv.at[:, :, :, slots].set(new_kv.transpose(0, 1, 3, 2, 4)),
-        kv_scale.at[:, :, :, slots].set(new_scale.transpose(0, 1, 3, 2)),
+def _cut(x: jax.Array, start: tuple, sizes: tuple) -> jax.Array:
+    """``lax.dynamic_slice`` at indices known not to be negative (their
+    normalisation is most of what tracing a slice costs)."""
+    return jax.lax.dynamic_slice(x, start, sizes, allow_negative_indices=False)
+
+
+def _slot_at(slots: jax.Array, b, j) -> jax.Array:
+    """``slots[b, j]`` for traced ``b``, ``j`` (plain ``lax``: these few
+    lines are traced into every program that writes the pool, and
+    ``jnp`` indexing costs milliseconds a line there)."""
+    return jax.lax.reshape(_cut(slots, (b, j), (1, 1)), ())
+
+
+def _open_page(slots: jax.Array, b, k, page_size: int):
+    """Run ``b`` of ``slots [B, C]`` in its own frame: local row ``i`` is
+    offset ``i % page`` of the ``i // page``-th page the run touches and
+    holds token ``i - first``, ``first`` being the offset the run starts
+    at. Returns ``(first, slot)``: that offset and the slot of the token
+    that opens local page ``k`` (clamped: a page past the run's end repeats
+    the last token, whose page it then is not)."""
+    lax = jax.lax
+    first = lax.rem(_slot_at(slots, b, _i32(0)), _i32(page_size))
+    opener = lax.clamp(
+        _i32(0), lax.sub(lax.mul(k, _i32(page_size)), first),
+        _i32(slots.shape[1] - 1))
+    return first, _slot_at(slots, b, opener)
+
+
+def _page_view(pool: jax.Array, axis: int, page_size: int) -> jax.Array:
+    shape = pool.shape
+    return pool.reshape(
+        shape[:axis] + (shape[axis] // page_size, page_size) + shape[axis + 1:]
     )
 
 
-# Layer-ranged scatters for the staged disagg handoff (engine/disagg.py
-# + cache/kv_transfer.py): a handoff packet staged per layer-block can
-# land block-by-block, so the pool update for block 0 overlaps block 1's
-# host→device transfer instead of waiting for the whole packet. ``layer0``
-# is static — one compile per (block shape, position), bounded by
-# L / block variants.
+ALL_LAYERS = (slice(None),) * 3  # `lead` of the rows: planes, layers, heads
+
+
+def write_runs(
+    pool: jax.Array,  # [*lead, slots, *tail]
+    slots: jax.Array,  # [B, C] runs (see above), or [n] any slots
+    rows: jax.Array,  # [*lead', B, C, *tail] / [*lead', n, *tail]
+    page_size: int,
+    lead: tuple = ALL_LAYERS,
+) -> jax.Array:
+    """``pool`` with ``rows`` at ``slots``, every other row as it was;
+    traced inside the caller's jit. ``lead`` indexes the axes before the
+    slot axis (all of them, or a range of layers). Runs go page by page —
+    read, overlaid with the rows that hit it, written back, one after the
+    other, so a page named twice (the scratch page) is no hazard.
+    One-dimensional ``slots`` are scattered token by token, as any set of
+    slots can be — at the price of the pool's relayout on the chip."""
+    return write_runs_all([(pool, rows, lead)], slots, page_size)[0]
+
+
+def write_runs_all(writes: list, slots: jax.Array, page_size: int) -> list:
+    """:func:`write_runs` for several arrays that share the slot numbers
+    (the rows and their index keys, an int8 pool and its scales), each a
+    ``(pool, rows, lead)``: one pass over the pages serves them all. A
+    token is written where its slot IS the row of the page its place in
+    the run says; one that does not continue its run (padding: the scratch
+    slot, a slot outside the pool) is written nowhere else."""
+    ps = page_size
+    if slots.ndim == 1 or ps == 1:  # a page of one row is a token
+        return [pool.at[lead + (slots,)].set(rows)
+                for pool, rows, lead in writes]
+    lax = jax.lax
+    B, C = slots.shape
+    num_slots = writes[0][0].shape[len(writes[0][2])]
+    P = _pages_of_run(ps, C)
+    # Token j of a run sits at j + page, so the tokens of any page the run
+    # touches, its first and last too, are one slice of the padded arrays.
+    pad = (ps, P * ps - C, 0)
+    tokens = lax.pad(slots, _i32(-1), [(0, 0, 0), pad])
+    views, pieces = [], []
+    for pool, rows, lead in writes:
+        axis = len(lead)
+        tail = pool.shape[axis + 1:]
+        views.append(_page_view(pool, axis, ps))
+        pieces.append((
+            axis,
+            lax.pad(rows, jnp.zeros((), rows.dtype),
+                    [(0, 0, 0)] * (axis + 1) + [pad] + [(0, 0, 0)] * len(tail)),
+            tuple(_i32(s.start or 0) for s in lead),
+            rows.shape[:axis] + (1, ps) + tail,
+            (_i32(0),) * len(tail),
+        ))
+
+    def one_page(n, views):
+        b, k = lax.div(n, _i32(P)), lax.rem(n, _i32(P))
+        first, slot = _open_page(slots, b, k, ps)
+        held = lax.bitwise_and(lax.ge(slot, _i32(0)), lax.lt(slot, _i32(num_slots)))
+        page = lax.clamp(
+            _i32(0), lax.div(slot, _i32(ps)), _i32(num_slots // ps - 1))
+        start = lax.sub(lax.mul(lax.add(k, _i32(1)), _i32(ps)), first)
+        rows_of_page = lax.add(
+            lax.broadcast(lax.mul(page, _i32(ps)), (1, ps)),
+            lax.iota(jnp.int32, ps).reshape(1, ps))
+        mine = lax.bitwise_and(
+            lax.eq(_cut(tokens, (b, start), (1, ps)), rows_of_page),
+            lax.broadcast(held, (1, ps)))
+        out = []
+        for view, (axis, padded, starts, slab, zeros) in zip(views, pieces):
+            at = starts + (page, _i32(0)) + zeros
+            new = _cut(padded, (_i32(0),) * axis + (b, start) + zeros, slab)
+            cur = _cut(view, at, slab)
+            keep = lax.broadcast_in_dim(mine, slab, (axis, axis + 1))
+            out.append(lax.dynamic_update_slice(
+                view, lax.select(keep, new, cur), at,
+                allow_negative_indices=False))
+        return out
+
+    views = lax.fori_loop(_i32(0), _i32(B * P), one_page, views)
+    return [v.reshape(w[0].shape) for v, w in zip(views, writes)]
+
+
+def gather_run(pool: jax.Array, slots: jax.Array, page_size: int,
+               axis: int = 3) -> jax.Array:
+    """``pool[..., slots, ...]`` for ONE run ``slots [1, n]`` — its pages
+    are fetched whole and the rows cut out of them — or, token by token,
+    for any ``slots [n]``."""
+    lead = (slice(None),) * axis
+    if slots.ndim == 1:
+        return pool[lead + (slots,)]
+    ps = page_size
+    n = slots.shape[1]
+    P = _pages_of_run(ps, n)
+    first = slots[0, 0] % ps
+    opens = jnp.clip(jnp.arange(P) * ps - first, 0, n - 1)
+    got = _page_view(pool, axis, ps)[lead + (slots[0, opens] // ps,)]
+    got = got.reshape(got.shape[:axis] + (P * ps,) + got.shape[axis + 2:])
+    return jax.lax.dynamic_slice_in_dim(got, first, n, axis=axis)
+
+
+# The pool's jitted writers and readers. ``slots`` is ``[1, n]`` — ONE run,
+# moved by pages — or ``[n]``, any slots, token by token (``write_runs``);
+# the new rows carry the same axes in the slot axis's place.
+
+
+def _at_tokens(rows: jax.Array, slots: jax.Array, axis: int = 3) -> jax.Array:
+    """``rows [.., n, ..]`` shaped for ``slots``: a run's get its axis."""
+    return jnp.expand_dims(rows, axis) if slots.ndim == 2 else rows
+
+
+def _layers(layer0: int, new_kv: jax.Array) -> tuple:
+    """``write_runs``'s ``lead`` for head-major ``new_kv [2, nL, H, ..]``
+    that holds the layers from ``layer0`` on."""
+    return (slice(None), slice(layer0, layer0 + new_kv.shape[1]), slice(None))
+
+
+# ``layer0`` serves the staged disagg handoff (engine/disagg.py +
+# cache/kv_transfer.py): a handoff packet staged per layer-block can land
+# block-by-block, so the pool update for block 0 overlaps block 1's
+# host→device transfer instead of waiting for the whole packet. It is
+# static — one compile per (block shape, position), bounded by L / block
+# variants.
+
+
+@partial(jax.jit, donate_argnums=(0,), static_argnums=(3, 4))
+def _scatter_kv(kv: jax.Array, slots: jax.Array, new_kv: jax.Array,
+                page_size: int, layer0: int = 0) -> jax.Array:
+    # kv: [2, L, H, S, D]; new_kv: head-major [2, nL, H, n, D]
+    return write_runs(kv, slots, _at_tokens(new_kv, slots), page_size,
+                      _layers(layer0, new_kv))
 
 
 @partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
-def _scatter_kv_layers(
-    kv: jax.Array,  # [2, L, H, S, D]
-    slots: jax.Array,  # [n]
-    new_kv: jax.Array,  # head-major [2, nL, H, n, D]
-    layer0: int,
-) -> jax.Array:
-    return kv.at[:, layer0 : layer0 + new_kv.shape[1], :, slots].set(new_kv)
+def _scatter_index(index: jax.Array, slots: jax.Array, keys: jax.Array,
+                   page_size: int) -> jax.Array:
+    # index: [L_full, S, Di]; keys: [L_full, n, Di]
+    return write_runs(index, slots, _at_tokens(keys, slots, 1), page_size,
+                      lead=(slice(None),))
 
 
-@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(5,))
-def _scatter_kv_raw_layers(
+@partial(jax.jit, static_argnums=(2,))
+def _gather_kv(kv: jax.Array, slots: jax.Array, page_size: int) -> jax.Array:
+    # → [2, L, n, H, D] (token-major, for tests/debug)
+    return gather_run(kv, slots, page_size).transpose(0, 1, 3, 2, 4)
+
+
+@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(4, 5))
+def _scatter_kv_quant(
     kv: jax.Array,  # int8 [2, L, H, S, D]
     kv_scale: jax.Array,  # f32 [2, L, H, S]
-    slots: jax.Array,  # [n]
-    new_kv: jax.Array,  # int8 head-major [2, nL, H, n, D]
-    new_scale: jax.Array,  # f32 head-major [2, nL, H, n]
-    layer0: int,
-):
-    nl = new_kv.shape[1]
-    return (
-        kv.at[:, layer0 : layer0 + nl, :, slots].set(new_kv),
-        kv_scale.at[:, layer0 : layer0 + nl, :, slots].set(new_scale),
-    )
-
-
-@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(4,))
-def _scatter_kv_quant_layers(
-    kv: jax.Array,  # int8 [2, L, H, S, D]
-    kv_scale: jax.Array,  # f32 [2, L, H, S]
-    slots: jax.Array,  # [n]
+    slots: jax.Array,
     new_kv: jax.Array,  # float head-major [2, nL, H, n, D]
-    layer0: int,
+    page_size: int,
+    layer0: int = 0,
 ):
     from radixmesh_tpu.ops.quant import quantize_kv
 
     q, scale = quantize_kv(new_kv, axis=-1)
-    nl = new_kv.shape[1]
-    return (
-        kv.at[:, layer0 : layer0 + nl, :, slots].set(q),
-        kv_scale.at[:, layer0 : layer0 + nl, :, slots].set(scale),
-    )
+    return _scatter_kv_raw(kv, kv_scale, slots, q, scale, page_size, layer0)
 
 
-@jax.jit
+@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(5, 6))
+def _scatter_kv_raw(
+    kv: jax.Array,  # int8 [2, L, H, S, D]
+    kv_scale: jax.Array,  # f32 [2, L, H, S]
+    slots: jax.Array,
+    new_kv: jax.Array,  # int8 head-major [2, nL, H, n, D]
+    new_scale: jax.Array,  # f32 head-major [2, nL, H, n]
+    page_size: int,
+    layer0: int = 0,
+):
+    lead = _layers(layer0, new_kv)
+    return tuple(write_runs_all(
+        [(kv, _at_tokens(new_kv, slots), lead),
+         (kv_scale, _at_tokens(new_scale, slots), lead)],
+        slots, page_size,
+    ))
+
+
+@partial(jax.jit, static_argnums=(3,))
 def _gather_kv_dequant(
-    kv: jax.Array, kv_scale: jax.Array, slots: jax.Array
+    kv: jax.Array, kv_scale: jax.Array, slots: jax.Array, page_size: int
 ) -> jax.Array:
     # → dequantized f32 [2, L, n, H, D] (token-major, for tests/debug and
     # the engine's dense-prefill cached-prefix gather)
     from radixmesh_tpu.ops.quant import dequantize_kv
 
-    deq = dequantize_kv(kv[:, :, :, slots], kv_scale[:, :, :, slots])
+    deq = dequantize_kv(
+        gather_run(kv, slots, page_size), gather_run(kv_scale, slots, page_size)
+    )
     return deq.transpose(0, 1, 3, 2, 4)
 
 
@@ -317,6 +481,8 @@ class PagedKVPool:
         # keys are taken, handed to the radix tree, served by a tree hit
         # and freed together; only the scatters are two.
         self.index = None
+        # ``/debug/state`` ``kv.write`` (``note_runs``); read by no metric.
+        self.write_stats = {"launches": 0, "pages": 0, "edge_pages": 0}
         if index_shape is not None:
             if index_shape[1] != num_slots or quant is not None:
                 raise ValueError(
@@ -379,6 +545,36 @@ class PagedKVPool:
 
     # ---- device ops ----
 
+    def _as_written(self, slots: np.ndarray, arrays: list, token_axes: list):
+        """``(slots, arrays)`` as the jitted writers take them: padded to
+        the bucket (the pad repeats the last row at the last slot: in the
+        run's frame it is that row again or the row of no page), and —
+        where the slots are a run — as ``[1, n]``, to go by whole pages.
+        Any other set of slots goes token by token."""
+        run = is_run(slots, self.page_size)
+        # A launch that goes token by token moves no page.
+        self.note_runs(slots[:1], np.asarray([len(slots) if run else 0]))
+        slots, arrays = _pad_to_bucket(slots, arrays, token_axes)
+        # (The run's axis is added on the host: an eager op would compile.)
+        return jnp.asarray(slots[None] if run else slots, jnp.int32), arrays
+
+    def note_runs(self, first_slots: np.ndarray, counts: np.ndarray) -> None:
+        """``/debug/state`` ``kv.write``: one launch wrote runs of
+        ``counts`` rows from ``first_slots`` on. ``pages`` are the pages
+        it wrote whole-page-wise, ``edge_pages`` those of them it was not
+        given every row of (entered inside, or left before the end)."""
+        ps = self.page_size
+        counts = np.asarray(counts, dtype=np.int64)
+        head = np.asarray(first_slots, dtype=np.int64)[counts > 0] % ps
+        end = head + counts[counts > 0]
+        pages = -(-end // ps)
+        cut_head, cut_tail = head > 0, end % ps > 0
+        edge = cut_head.astype(np.int64) + cut_tail - (
+            cut_head & cut_tail & (pages == 1))
+        self.write_stats["launches"] += 1
+        self.write_stats["pages"] += int(pages.sum())
+        self.write_stats["edge_pages"] += int(edge.sum())
+
     def write(
         self, slots: np.ndarray | jax.Array, k: jax.Array,
         v: jax.Array | None = None,
@@ -388,27 +584,14 @@ class PagedKVPool:
         ``k``/``v``: ``[L, n, kv_heads, head_dim]`` (a one-plane pool takes
         its rows as ``k`` and no ``v``). The pool array is
         donated through the scatter so HBM is updated in place. The token
-        count is padded up to a power-of-two bucket (by repeating the last
-        slot/value — an idempotent duplicate write) so ``jax.jit`` compiles
+        count is padded up to a power-of-two bucket so ``jax.jit`` compiles
         O(log max_n) scatter variants instead of one per distinct length.
+        A run of slots (what the allocator and the tree hand out) goes by
+        whole pages; every row of those pages that is not among ``slots``
+        stays as it was.
         """
-        slots = np.asarray(slots, dtype=np.int32)
-        n = len(slots)
-        if n == 0:
-            return
         rows = [k] if self.planes == 1 else [k, v]
-        slots, rows = _pad_to_bucket(
-            slots, [jnp.asarray(r) for r in rows], [1] * len(rows)
-        )
-        # [L, n, H, D] → head-major [L, H, n, D].
-        new_kv = jnp.stack(rows).transpose(0, 1, 3, 2, 4)
-        sl = jnp.asarray(slots, dtype=jnp.int32)
-        if self.quant is not None:
-            self.kv, self.kv_scale = _scatter_kv_quant(
-                self.kv, self.kv_scale, sl, new_kv
-            )
-        else:
-            self.kv = _scatter_kv(self.kv, sl, new_kv.astype(self.dtype))
+        self.write_block(slots, jnp.stack([jnp.asarray(r) for r in rows]))
 
     def write_index(self, slots: np.ndarray | jax.Array, keys: jax.Array) -> None:
         """Write the index keys ``[L_full, n, width]`` of ``n`` tokens at
@@ -417,10 +600,9 @@ class PagedKVPool:
         slots = np.asarray(slots, dtype=np.int32)
         if len(slots) == 0:
             return
-        slots, (keys,) = _pad_to_bucket(slots, [jnp.asarray(keys)], [1])
+        sl, (keys,) = self._as_written(slots, [jnp.asarray(keys)], [1])
         self.index = _scatter_index(
-            self.index, jnp.asarray(slots, dtype=jnp.int32),
-            keys.astype(self.dtype),
+            self.index, sl, keys.astype(self.dtype), self.page_size
         )
 
     def pages_for_layer(self, layer: int) -> tuple[jax.Array, jax.Array]:
@@ -446,17 +628,7 @@ class PagedKVPool:
         ``[2, L, n, H, D]`` int8, ``scales`` ``[2, L, n, H]``."""
         if self.quant is None:
             raise ValueError("write_raw targets quantized pools")
-        slots = np.asarray(slots, dtype=np.int32)
-        if len(slots) == 0:
-            return
-        slots, (kv, scales) = _pad_to_bucket(
-            slots,
-            [jnp.asarray(kv, self.dtype), jnp.asarray(scales, jnp.float32)],
-            [2, 2],
-        )
-        self.kv, self.kv_scale = _scatter_kv_raw(
-            self.kv, self.kv_scale, jnp.asarray(slots, jnp.int32), kv, scales
-        )
+        self.write_block(slots, kv, 0, scales)
 
     def write_block(
         self,
@@ -470,56 +642,46 @@ class PagedKVPool:
         (``engine/disagg.py``): layer-blocked packets land block-by-block
         so early blocks' scatters overlap later blocks' transfers.
 
-        Dtype dispatch mirrors the full-layer writers: ``scales`` given →
-        raw quantized store (quantized pools only); quantized pool
-        without scales → quantize-on-store; plain pool → cast + store.
-        Full-layer blocks delegate to the existing writers so the common
-        whole-packet path adds no new compile variants."""
+        Dtype dispatch: ``scales`` given → raw quantized store (quantized
+        pools only); quantized pool without scales → quantize-on-store;
+        plain pool → cast + store."""
         slots = np.asarray(slots, dtype=np.int32)
-        n = len(slots)
-        if n == 0:
-            return
-        nl = kv.shape[1]
-        full = layer0 == 0 and nl == self.num_layers
-        if full:
-            if scales is not None:
-                self.write_raw(slots, kv, scales)
-            else:
-                self.write(slots, *jnp.asarray(kv))
+        if len(slots) == 0:
             return
         if scales is not None and self.quant is None:
             raise ValueError("raw quantized blocks target quantized pools")
         arrays = [jnp.asarray(kv, self.dtype if scales is not None else None)]
-        axes = [2]
         if scales is not None:
             arrays.append(jnp.asarray(scales, jnp.float32))
-            axes.append(2)
-        slots, arrays = _pad_to_bucket(slots, arrays, axes)
-        sl = jnp.asarray(slots, dtype=jnp.int32)
+        sl, arrays = self._as_written(slots, arrays, [2] * len(arrays))
         new_kv = arrays[0].transpose(0, 1, 3, 2, 4)  # token- → head-major
         if scales is not None:
-            self.kv, self.kv_scale = _scatter_kv_raw_layers(
+            self.kv, self.kv_scale = _scatter_kv_raw(
                 self.kv, self.kv_scale, sl, new_kv,
-                arrays[1].transpose(0, 1, 3, 2), layer0,
+                arrays[1].transpose(0, 1, 3, 2), self.page_size, layer0,
             )
         elif self.quant is not None:
-            self.kv, self.kv_scale = _scatter_kv_quant_layers(
-                self.kv, self.kv_scale, sl, new_kv, layer0
+            self.kv, self.kv_scale = _scatter_kv_quant(
+                self.kv, self.kv_scale, sl, new_kv, self.page_size, layer0
             )
         else:
-            self.kv = _scatter_kv_layers(
-                self.kv, sl, new_kv.astype(self.dtype), layer0
+            self.kv = _scatter_kv(
+                self.kv, sl, new_kv.astype(self.dtype), self.page_size, layer0
             )
 
     def gather(self, slots: np.ndarray | jax.Array) -> jax.Array:
         """Gather ``[2, L, n, kv_heads, head_dim]`` for the given slots,
         dequantized for quantized pools (debug/test path and the dense-
         prefill cached-prefix gather; attention kernels read pages
-        directly)."""
-        sl = jnp.asarray(slots, dtype=jnp.int32)
+        directly). A run of slots (a tree hit's prefix) comes by whole
+        pages."""
+        slots = np.asarray(slots, dtype=np.int32)
+        if len(slots) and is_run(slots, self.page_size):
+            slots = slots[None]
+        sl = jnp.asarray(slots)
         if self.quant is not None:
-            return _gather_kv_dequant(self.kv, self.kv_scale, sl)
-        return _gather_kv(self.kv, sl)
+            return _gather_kv_dequant(self.kv, self.kv_scale, sl, self.page_size)
+        return _gather_kv(self.kv, sl, self.page_size)
 
     def page_table(self, slots: np.ndarray) -> np.ndarray:
         """Page ids covering a page-aligned run of slots — the block table

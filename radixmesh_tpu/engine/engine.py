@@ -2251,9 +2251,10 @@ class Engine:
             poss = np.zeros((B, C), dtype=np.int32)
             kvlen = np.zeros((B,), dtype=np.int32)
             lastpos = np.full((N,), -1, dtype=np.int32)
+            written = np.zeros((N,), dtype=np.int32)  # rows a run, kv.write
             for i in range(N):
                 start = reuses[i] + ci * C
-                nv = min(max(totals[i] - start, 0), C)
+                nv = written[i] = min(max(totals[i] - start, 0), C)
                 poss[i] = np.clip(
                     start + np.arange(C, dtype=np.int32), 0, self.max_seq_len - 1
                 )
@@ -2284,6 +2285,7 @@ class Engine:
                     ]),
                     maxp * ps + C > self.cfg.index_topk,
                 )
+            self.pool.note_runs(sl[:N, 0], written)
             # Only each row's LAST prompt position is ever sampled, and
             # only in the chunk that holds it.
             at = np.zeros((B,), dtype=np.int32)
@@ -2542,6 +2544,8 @@ class Engine:
                 # launches ride it on every backend: it returns LOGITS,
                 # and the replay contract needs the canonical host-side
                 # draw.
+                self.pool.note_runs(
+                    launch.slots[launch.idx], np.ones(len(launch.idx), np.int32))
                 res = self._forward_chunk(
                     tokens_d[:, None],
                     launch.positions_d[:, None],
@@ -3072,6 +3076,7 @@ class Engine:
                 kvlen[row] = pos + w
                 npg = min(-(-job.total // ps), maxp)
                 pt[row, :npg] = job.token_slots[::ps][:npg] // ps
+            self.pool.note_runs(sl[:, 0], (sl != self._scratch_slot).sum(axis=1))
             toks_d, poss_d, sl_d = (
                 jnp.asarray(toks), jnp.asarray(poss), jnp.asarray(sl)
             )

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from radixmesh_tpu.cache.kv_pool import ALL_LAYERS, write_runs_all
 from radixmesh_tpu.ops.attention import (
     attend_prefill,
     paged_chunk_attention,
@@ -1482,24 +1483,26 @@ def prefill_chunk_paged(
         (x, _), (new_k, new_v, new_ks, new_vs) = _scan_layers(
             cfg, params, layer, (x, None))
         # Already quantized in-layer (same ints attention saw); scatter the
-        # int8 payloads + scales: scan stacks [L, B, C, Hkv(, D)] → the
-        # pool target [:, :, :, slots[B, C]] expects [2, L, Hkv, B, C(, D)].
+        # int8 payloads + scales, by whole pages: scan stacks
+        # [L, B, C, Hkv(, D)] → the pool takes [2, L, Hkv, B, C(, D)].
         new_kv = jnp.stack([new_k, new_v]).transpose(0, 1, 4, 2, 3, 5)
         new_s = jnp.stack([new_ks, new_vs]).transpose(0, 1, 4, 2, 3)
-        kv_pool = kv_pool.at[:, :, :, slots].set(new_kv)
-        kv_scale = kv_scale.at[:, :, :, slots].set(new_s)
+        kv_pool, kv_scale = write_runs_all(
+            [(kv_pool, new_kv, ALL_LAYERS), (kv_scale, new_s, ALL_LAYERS)],
+            slots, page_size)
         return _logits(params, cfg, pick(x)), kv_pool, kv_scale
     (x, _), (new_k, new_v, aux, new_ki, picked) = _scan_layers(
         cfg, params, layer, (x, sel0))
-    # One scatter for the whole chunk across all layers: scan stacks
-    # [L, B, C, Hkv, D] a plane; the pool indexed at [:, :, :, slots[B,C]]
-    # expects [planes, L, Hkv, B, C, D].
+    # One write for the whole chunk across all layers, by whole pages (each
+    # row of ``slots`` is a run: cache/kv_pool.py): scan stacks
+    # [L, B, C, Hkv, D] a plane; the pool takes [planes, L, Hkv, B, C, D].
     planes = [new_k] if new_v is None else [new_k, new_v]
     new_kv = jnp.stack(planes).transpose(0, 1, 4, 2, 3, 5)
-    kv_pool = kv_pool.at[:, :, :, slots].set(new_kv)
-    pools = (kv_pool,)
+    writes = [(kv_pool, new_kv, ALL_LAYERS)]
     if cfg.dsa:  # the chunk's index keys, at the same slots
-        pools += (index_pool.at[:, slots].set(_of_full_layers(cfg, new_ki)),)
+        writes.append(
+            (index_pool, _of_full_layers(cfg, new_ki), (slice(None),)))
+    pools = write_runs_all(writes, slots, page_size)
     return (_logits(params, cfg, pick(x)), *pools,
             *_moe_aux(cfg, aux, moe_stats, "prefill",
                       **_selected(cfg, picked)))

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from radixmesh_tpu.cache.kv_pool import ALL_LAYERS, write_runs_all
 from radixmesh_tpu.models.llama import (
     ModelConfig,
     _embed_lookup,
@@ -328,9 +329,10 @@ def pp_forward_chunk(
         (_, outs), kv_ticks = jax.lax.scan(
             tick, (buf0, outs0), jnp.arange(n_ticks)
         )
-        # kv_ticks leaves: [ticks, L/pp, mb, C, Hkv/tp(, D)]. Scatter each
-        # valid tick's microbatch payloads into the local pool shard;
-        # invalid (warm-up/drain) ticks re-write the existing values.
+        # kv_ticks leaves: [ticks, L/pp, mb, C, Hkv/tp(, D)]. Write each
+        # valid tick's microbatch payloads into the local pool shard, by
+        # whole pages (cache/kv_pool.py); an invalid (warm-up/drain) tick
+        # names slots past the pool, which hit nothing.
         for t in range(n_ticks):
             m = t - idx
             safe_m = jnp.clip(m, 0, n_micro - 1)
@@ -338,20 +340,18 @@ def pp_forward_chunk(
             sl = jax.lax.dynamic_index_in_dim(
                 slots_all, safe_m, 0, keepdims=False
             )  # [mb, C]
+            sl = jnp.where(valid, sl, num_slots)
             # [L/pp, mb, C, Hkv/tp, D] → pool target [2, L/pp, Hkv/tp, mb, C, D]
-            new = jnp.stack(
+            writes = [(pool, jnp.stack(
                 [kv_ticks[0][t], kv_ticks[1][t]]
-            ).transpose(0, 1, 4, 2, 3, 5)
-            old = pool[:, :, :, sl]
-            pool = pool.at[:, :, :, sl].set(jnp.where(valid, new, old))
+            ).transpose(0, 1, 4, 2, 3, 5), ALL_LAYERS)]
             if quant:
-                new_s = jnp.stack(
+                writes.append((scale, jnp.stack(
                     [kv_ticks[2][t], kv_ticks[3][t]]
-                ).transpose(0, 1, 4, 2, 3)
-                old_s = scale[:, :, :, sl]
-                scale = scale.at[:, :, :, sl].set(
-                    jnp.where(valid, new_s, old_s)
-                )
+                ).transpose(0, 1, 4, 2, 3), ALL_LAYERS))
+            pool, *rest = write_runs_all(writes, sl, page_size)
+            if quant:
+                (scale,) = rest
         # Finished activations live on the last stage; psum replicates
         # them over pp (other stages contribute zeros). tp is already
         # uniform (both block psums precede every write into `outs`).

@@ -749,8 +749,15 @@ class ServingFrontend:
                     "quant": eng.pool.quant,
                 },
                 # The state beside the rows: the index keys of a model
-                # with learned sparse attention (0: it has none).
-                "kv": {"index_plane_bytes": eng.pool.index_plane_bytes},
+                # with learned sparse attention (0: it has none). write:
+                # launches that wrote rows into the pool outside the decode
+                # kernels (a dense prefill's, a chunk's), the pages they
+                # moved whole, and those of them given only part of
+                # their rows (read first, overlaid, written back).
+                "kv": {
+                    "index_plane_bytes": eng.pool.index_plane_bytes,
+                    "write": eng.pool.write_stats,
+                },
                 "cache": {
                     "evictable_tokens": getattr(tree, "evictable_size_", None),
                     "protected_tokens": getattr(tree, "protected_size_", None),

@@ -544,6 +544,12 @@ def test_chunk_lowers_to_the_sparse_pieces(v5e):
             "radixmesh_dsa_select", "radixmesh_dsa_gather",
             "radixmesh_moe_gmm_prefill"} <= names, names
     assert "radixmesh_mla_chunk" not in names
+    # Rows and index keys enter their pools by whole pages: no copy of
+    # either (a scatter on the slot axis relayouts both; PR 34).
+    held = {",".join(map(str, shape)) for shape in (
+        cfg.kv_pool_shape(8192), cfg.kv_index_shape(8192))}
+    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
+    assert not held & set(copied), held & set(copied)
 
 
 @pytest.mark.parametrize("preset", ["qwen2-tiny", "mellum2-tiny", "glm-4.7-flash-tiny"])

@@ -543,7 +543,8 @@ class TestDeferredEmissionStream:
                 return json.loads(_get(base + "/debug/state")[1])["engine"]
 
         try:
-            round_(200)  # compiles every shape of the round
+            round_(200)  # compiles every shape of a cold round ...
+            round_(200)  # ... and of one whose prompts the tree serves
             stamps.clear()
             before = state()
             slow.set()

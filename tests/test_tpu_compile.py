@@ -13,12 +13,15 @@ runs on the chip, at Qwen2-7B's head geometry (Hq 28, Hkv 4 → G 7, D 128,
 page 16, chunk 512).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
 
+from radixmesh_tpu.cache import kv_pool
 from radixmesh_tpu.models import get_config
 from radixmesh_tpu.models.llama import (
     _init_params,
@@ -46,6 +49,19 @@ def _sd(sharding):
     return lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=sharding
     )
+
+
+def _copies_shaped_like(compiled, *shapes) -> list[str]:
+    """The ``copy`` operations of the optimized program whose result has
+    one of ``shapes``, flat or viewed as pages: a relayout of the pool."""
+    want = set()
+    for shape in shapes:
+        slot_axis = len(shape) - 2
+        paged = (shape[:slot_axis] + (shape[slot_axis] // PAGE, PAGE)
+                 + shape[slot_axis + 1:])
+        want |= {",".join(map(str, shape)), ",".join(map(str, paged))}
+    found = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
+    return [dims for dims in found if dims in want]
 
 
 def test_same_functions_take_the_reference_on_cpu():
@@ -108,6 +124,10 @@ def test_decode_step_and_prefill_wave_fit_one_v5e(v5e):
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     ) / gib
     assert live < 15.75, f"prefill wave needs {live:.2f} GiB"
+    # Its rows enter the pool by whole pages: no copy of the pool (3.5 GiB
+    # each way round a scatter on the slot axis, until PR 34).
+    assert not _copies_shaped_like(compiled, pool.shape)
+    assert mem.temp_size_in_bytes / gib < 3.5 / 4
 
 
 def test_mellum2_period_compiles_for_one_v5e(v5e):
@@ -153,3 +173,69 @@ def test_mellum2_period_compiles_for_one_v5e(v5e):
     text = compiled.as_text()
     assert "radixmesh_moe_gmm_prefill" in text and "radixmesh_chunk_attn" in text
     assert compiled.out_info[0].shape == (B, 1, cfg.vocab_size)
+    assert not _copies_shaped_like(compiled, pool.shape)
+
+
+# The served pools (benchmarks/cells/*.json): Qwen2-7B, Mellum2 (12 layers),
+# GLM-4.7-Flash (8 layers, one latent plane), GLM-5.2 (7 layers) and its
+# index plane.
+POOLS = {
+    "qwen2": (2, 28, 4, 65536, 128),
+    "mellum2": (2, 12, 4, 65536, 128),
+    "glm47": (1, 8, 1, 196608, 640),
+    "glm52": (1, 7, 1, 221184, 640),
+}
+INDEX_PLANE = (2, 221184, 128)
+
+
+@pytest.mark.parametrize("rows", [512, 4096])
+@pytest.mark.parametrize("what", ["write", "gather"])
+@pytest.mark.parametrize("name", list(POOLS))
+def test_rows_enter_and_leave_the_pool_without_its_relayout(v5e, name, what, rows):
+    """``PagedKVPool.write`` / ``.gather`` of one run of rows: XLA:TPU
+    scatters into (gathers from) the slot axis only after copying the
+    WHOLE pool into another layout — whole pages move without. No ``copy``
+    of the pool's shape, no temporary of its size, the write in place."""
+    sd = _sd(v5e)
+    shape = POOLS[name]
+    pool = sd(shape, jnp.bfloat16)
+    size = 2 * int(jnp.prod(jnp.asarray(shape, jnp.float32)))
+    slots = sd((1, rows), jnp.int32)
+    if what == "write":
+        new = sd(shape[:3] + (rows, shape[4]), jnp.bfloat16)
+        compiled = kv_pool._scatter_kv.lower(pool, slots, new, PAGE).compile()
+        assert compiled.memory_analysis().alias_size_in_bytes == size
+    else:
+        compiled = kv_pool._gather_kv.lower(pool, slots, PAGE).compile()
+        assert compiled.out_info.shape == (
+            shape[0], shape[1], rows, shape[2], shape[4])
+    assert not _copies_shaped_like(compiled, shape)
+    assert compiled.memory_analysis().temp_size_in_bytes < size / 4
+
+
+@pytest.mark.parametrize("rows", [512, 4096])
+def test_index_keys_enter_their_plane_without_its_relayout(v5e, rows):
+    sd = _sd(v5e)
+    plane = sd(INDEX_PLANE, jnp.bfloat16)
+    size = 2 * INDEX_PLANE[0] * INDEX_PLANE[1] * INDEX_PLANE[2]
+    compiled = kv_pool._scatter_index.lower(
+        plane, sd((1, rows), jnp.int32),
+        sd((INDEX_PLANE[0], rows, INDEX_PLANE[2]), jnp.bfloat16), PAGE,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == size
+    assert mem.temp_size_in_bytes < size / 4
+    assert not _copies_shaped_like(compiled, INDEX_PLANE)
+
+
+def test_a_scatter_on_the_slot_axis_still_relayouts_the_pool(v5e):
+    """The other side: the per-token scatter that any set of slots takes
+    (and every write took until PR 34) holds two copies of the pool. If
+    this stops failing, the pages are no longer needed."""
+    sd = _sd(v5e)
+    shape = POOLS["mellum2"]
+    compiled = kv_pool._scatter_kv.lower(
+        sd(shape, jnp.bfloat16), sd((512,), jnp.int32),
+        sd(shape[:3] + (512, shape[4]), jnp.bfloat16), PAGE,
+    ).compile()
+    assert len(_copies_shaped_like(compiled, shape)) == 2
