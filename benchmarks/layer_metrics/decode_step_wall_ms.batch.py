@@ -1,0 +1,5 @@
+"""``decode_step_wall_ms`` in the closed-loop cells, where it moves ``tokens_per_s``
+(they report no token-gap tail): the same reader."""
+from benchmarks.harness.registry import load_module
+
+read = load_module("layer_metrics", "decode_step_wall_ms").read

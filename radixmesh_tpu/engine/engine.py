@@ -31,6 +31,7 @@ double-freeing slots the engine still references).
 from __future__ import annotations
 
 import bisect
+import collections
 import itertools
 import time
 from dataclasses import dataclass, field
@@ -61,7 +62,7 @@ from radixmesh_tpu.obs.attribution import shape_bucket
 from radixmesh_tpu.obs.fleet_plane import eviction_counters
 from radixmesh_tpu.obs.metrics import TOKEN_LEN_BUCKETS, get_registry
 from radixmesh_tpu.obs.trace_plane import get_recorder
-from radixmesh_tpu.obs.tracing import annotate
+from radixmesh_tpu.obs.tracing import ProcessAccount, SpanBook, since_import
 from radixmesh_tpu.ops.sampling import sample_tokens, spec_verify_sample
 from radixmesh_tpu.utils.logging import get_logger
 
@@ -100,6 +101,12 @@ _KV_BLOCK_PAGES = 32
 # bucket follows the last.
 _ADMISSION_WAIT_LE_MS = (1, 2, 5, 10, 20, 50, 100, 200, 500, 1000, 2000, 5000)
 _ADMISSION_WAIT_LE_S = tuple(ms / 1e3 for ms in _ADMISSION_WAIT_LE_MS)
+# A step whose seconds, less what its own prefill waves were expected to
+# take, pass this is recorded in ``Engine.slow_steps`` (a decode launch with
+# its hand-over is 25-35 ms, a full collection 0.3 s at most); the ring
+# keeps the last ``_SLOW_STEP_RING`` records.
+_SLOW_STEP_S = 1.0
+_SLOW_STEP_RING = 32
 # Where a flush of the deferred emission ran (keys of ``Engine.emit``):
 # behind a dispatched decode launch, behind a dispatched prefill, or with
 # no launch to hide under (end of a step that leaves no row, cancel,
@@ -113,28 +120,6 @@ _EXPOSED = "flushes_exposed"
 # what follows it, is not a plain decode launch of the same rows; the pool
 # had no page for the launch built ahead.
 _SETTLED_FOR = ("finish", "admit", "cancel", "preempt", "launch_kind", "no_pages")
-
-
-class _TreeSpan:
-    """An ``rm.tree.*`` span whose host seconds also accumulate in
-    ``Engine.tree_host`` (a capture holds too few admissions for a
-    statistic, so the counter runs always). Engine thread only; the two
-    instances never nest inside themselves."""
-
-    __slots__ = ("_name", "_acc", "_n", "_s", "_ann", "_t0")
-
-    def __init__(self, name: str, acc: dict, n_key: str, s_key: str):
-        self._name, self._acc, self._n, self._s = name, acc, n_key, s_key
-
-    def __enter__(self):
-        self._ann = annotate(self._name)
-        self._ann.__enter__()
-        self._t0 = time.monotonic()
-
-    def __exit__(self, *exc):
-        self._acc[self._n] += 1
-        self._acc[self._s] += time.monotonic() - self._t0
-        self._ann.__exit__(*exc)
 
 
 @dataclass
@@ -250,6 +235,7 @@ class Engine:
         stream_publish_tokens: int = 0,
         step_accounting: bool = False,
         peak_tflops: float | None = None,
+        process_account: ProcessAccount | None = None,
     ):
         if page_size & (page_size - 1):
             raise ValueError("page_size must be a power of two")
@@ -361,20 +347,46 @@ class Engine:
         self._last_dispatch: dict | None = None
         self._last_prefill_path: str | None = None  # "sp" | "dense" | "chunk"
         # Always-on accumulators served on /debug/state (a 3 s profiler
-        # capture holds ~8 admissions: too few for a queue statistic).
+        # capture is taken after the measured window and holds ~8
+        # admissions: too few for a statistic).
+        # spans: count and host seconds of every rm.* span this thread
+        # opens (obs/tracing.py::SpanBook; ``tree_host`` is a view of it).
+        # process_account: the process's collections and compilations,
+        # where an entry point installed one (launch.py serve); a
+        # compilation on this thread is then named by the span it fell in.
+        self.spans = SpanBook()
+        self.process_account = process_account
+        if process_account is not None:
+            process_account.book = self.spans
+        # prefill: the admission waves' work (_admit): members, uncached
+        # prompt tokens computed, tokens of the launched shapes, and host
+        # seconds from before the path's dispatch to after the first-token
+        # sync — no decode launch is in flight then, so a wave's wall time
+        # is its device time plus its dispatch and its sync tail.
+        self.prefill = {
+            "waves": 0,
+            "rows": 0,
+            "tokens_new": 0,
+            "tokens_launched": 0,
+            "wall_s": 0.0,
+            "by_path": {"dense": 0, "chunk": 0, "sp": 0},
+        }
+        # slow_steps: steps whose excess over their own prefill passed
+        # _SLOW_STEP_S (count, excess seconds, the last records).
+        self.slow_steps = {
+            "n": 0,
+            "s": 0.0,
+            "threshold_s": _SLOW_STEP_S,
+            "last": collections.deque(maxlen=_SLOW_STEP_RING),
+        }
         # admission_wait: requeue/dispatch/submission (whichever was
         # last) to the instant a batch row is secured, per admission.
-        # tree_host: host seconds in the tree walk + slot acquisition
-        # and in _publish, at the rm.tree.match / rm.tree.publish sites.
         # sampler: launches by the executable _launch_draws chose.
         self.admission_wait = {
             "count": 0,
             "sum_s": 0.0,
             "le_ms": list(_ADMISSION_WAIT_LE_MS),
             "counts": [0] * (len(_ADMISSION_WAIT_LE_MS) + 1),
-        }
-        self.tree_host = {
-            "matches": 0, "match_s": 0.0, "publishes": 0, "publish_s": 0.0,
         }
         self.sampler = {"argmax_launches": 0, "drawing_launches": 0}
         # emit: decode tokens handed to their observers (_flush_emit) and
@@ -439,12 +451,6 @@ class Engine:
         # fetched only for a request that asked for them.
         self._last_expert_ids = None
         self._last_selected = None  # aux["index_positions"] of the last launch
-        self._span_match = _TreeSpan(
-            "rm.tree.match", self.tree_host, "matches", "match_s"
-        )
-        self._span_publish = _TreeSpan(
-            "rm.tree.publish", self.tree_host, "publishes", "publish_s"
-        )
         kernels = default_use_kernel(cfg.head_dim)
         self.chunk_attention = "paged" if kernels else "reference"
         # ``path`` of the decode dispatch record: chip_smoke.py proves
@@ -1150,8 +1156,9 @@ class Engine:
         on the device — or at the end of this one when it leaves no row
         to decode. First tokens and finishes wake their waiters at once.
 
-        Host spans (``obs.tracing.annotate``; inert without a profiler
-        capture): ``rm.engine.step`` ⊃ ``rm.admit`` ⊃ {``rm.tree.match``,
+        Host spans (``self.spans``: each a profiler annotation, inert
+        without a capture, and an always-on count and seconds):
+        ``rm.engine.step`` ⊃ ``rm.admit`` ⊃ {``rm.tree.match``,
         ``rm.prefill.dense`` / ``rm.prefill.group``,
         ``rm.first_token.sync``}, then ``rm.decode.build`` / ``.dispatch``
         / ``.sample`` / ``.emit`` / ``.sync`` / ``.consume`` (⊃
@@ -1159,12 +1166,20 @@ class Engine:
         path, so a reader of a capture never needs to know which ran. A
         fast step reads ``dispatch, sample, consume`` (the step
         before's), ``build`` (the next launch's), ``emit, sync``."""
-        with annotate("rm.engine.step"):
-            with annotate("rm.admit"):
+        launched = self.prefill["tokens_launched"]
+        acct = self.process_account
+        pauses = acct and (
+            acct.gc_n, acct.gc_s, acct.compiles["n"], acct.compiles["s"]
+        )
+        kind = "idle"
+        with self.spans.step() as span:
+            with self.spans.span("rm.admit"):
                 self._admit()
             if self._inline:
+                kind = "wave"
                 self._wave_step()
             elif any(r is not None for r in self._rows):
+                kind = "decode"
                 self._decode_once()
             elif not self.waiting and (
                 self._restoring
@@ -1182,6 +1197,65 @@ class Engine:
                 # No decode launch will follow to hide the emission under.
                 self._settle()
                 self._flush_emit(_EXPOSED)
+        # A step may honestly take seconds (a group of long prompts is
+        # many chunk launches inside one _admit), so the test is on the
+        # excess over its own prefill at the running rate.
+        p = self.prefill
+        launched = p["tokens_launched"] - launched
+        excess = span.seconds
+        if launched:
+            excess -= launched * p["wall_s"] / p["tokens_launched"]
+        if excess > _SLOW_STEP_S:
+            self._note_slow_step(span, excess, kind, launched, pauses)
+
+    def _note_slow_step(self, span, excess, kind, launched, pauses) -> None:
+        """One record of ``slow_steps``: when, how long, the three spans
+        that took most of the step, what it launched, the queue, the
+        collections and compilations that fell inside it (where a
+        ``process_account`` is installed) and the device's memory, read
+        only here. It does not say what the runtime did in those seconds;
+        it says that this run had a stall, when, under which span and at
+        what memory."""
+        from radixmesh_tpu.obs.step_plane import describe_devices
+
+        slow = self.slow_steps
+        slow["n"] += 1
+        slow["s"] += excess
+        record = {
+            "at_s": round(since_import(), 3),
+            "wall_time": round(time.time(), 3),
+            "step_s": round(span.seconds, 6),
+            "excess_s": round(excess, 6),
+            "spans": self.spans.top_since(span.entry),
+            "launch": kind,
+            "prefill_tokens_launched": launched,
+            "rows": sum(1 for r in self._rows if r is not None),
+            "waiting": len(self.waiting),
+            "memory": [
+                {k: m[k] for k in ("bytes_in_use", "peak_bytes_in_use")}
+                for m in describe_devices()["memory"]
+            ],
+        }
+        if pauses:
+            acct = self.process_account
+            gc_n, gc_s, c_n, c_s = pauses
+            c = acct.compiles
+            record["gc"] = {"n": acct.gc_n - gc_n, "s": round(acct.gc_s - gc_s, 6)}
+            record["compiles"] = {"n": c["n"] - c_n, "s": round(c["s"] - c_s, 6)}
+        slow["last"].append(record)
+
+    @property
+    def tree_host(self) -> dict:
+        """Host seconds in the tree walk + slot acquisition and in
+        ``_publish``: the book's ``rm.tree.match`` / ``rm.tree.publish``
+        under the four keys ``/debug/state`` ``cache.tree_host`` has."""
+        n, s = self.spans.n, self.spans.s
+        return {
+            "matches": n["rm.tree.match"],
+            "match_s": s["rm.tree.match"],
+            "publishes": n["rm.tree.publish"],
+            "publish_s": s["rm.tree.publish"],
+        }
 
     def has_work(self) -> bool:
         return (
@@ -1414,7 +1488,7 @@ class Engine:
                 # tree and the pool as every step before it left them.
                 self._settle("admit")
                 req = self.waiting[idx]
-                with self._span_match:
+                with self.spans.span("rm.tree.match"):
                     # One tree walk serves both the defer check and acquisition
                     # (match_and_load also restores host-tier KV, so a
                     # restorable prefix never triggers a needless deferral).
@@ -1561,8 +1635,8 @@ class Engine:
                 # paged path: it is the pipeline-scheduled one (the
                 # dense/sp paths would all-gather stage weights).
                 traced = [m[0].trace for m in sub if m[0].trace is not None]
-                acct = self.step_acct
-                t_wave = time.monotonic() if traced or acct is not None else 0.0
+                clock = self.spans.clock
+                t_wave = clock()
                 self._wave_padded = 0
                 if (
                     self.pool.quant is None
@@ -1583,44 +1657,51 @@ class Engine:
                     and not (self.cfg.latent and sub[0][2])
                 ):
                     path = "dense"
-                    with annotate("rm.prefill.dense"):
+                    with self.spans.span("rm.prefill.dense"):
                         pending = [self._prefill_dense(*sub[0])]
                 else:
                     path = "chunk"
-                    with annotate("rm.prefill.group"):
+                    with self.spans.span("rm.prefill.group"):
                         pending = self._prefill_group(sub)
                 self._last_prefill_path = path
                 # The prefill is on the device and its sync comes next:
                 # the last decode step's emission goes here.
                 self._flush_emit(_UNDER_PREFILL)
-                with annotate("rm.first_token.sync"):
+                with self.spans.span("rm.first_token.sync"):
                     self._finalize_first_tokens(pending)
-                if traced or acct is not None:
-                    dur = time.monotonic() - t_wave
-                    new_tok = sum(len(m[0].prompt) - m[2] for m in sub)
-                    if acct is not None:
-                        # Step attribution (obs/step_plane.py): the wave's
-                        # real vs launched-shape tokens — each prefill
-                        # path stamped its padded count (_wave_padded).
-                        acct.note_wave(
-                            "prefill",
-                            new_tok,
-                            self._wave_padded,
-                            dur,
-                            rows=len(sub),
-                        )
-                    # One prefill-wave span per traced member (covers the
-                    # whole sub-wave through first-token finalize, so each
-                    # request's lane shows the convoy it rode in).
-                    for tr in traced:
-                        tr.add(
-                            "prefill_wave",
-                            t_wave,
-                            dur,
-                            cat="prefill",
-                            wave_rows=len(sub),
-                            wave_new_tokens=int(new_tok),
-                        )
+                # The wave's work, counted always (self.prefill): real vs
+                # launched-shape tokens — each prefill path stamped its
+                # padded count (_wave_padded) — and its wall time. Step
+                # attribution (obs/step_plane.py) is fed the same numbers.
+                dur = clock() - t_wave
+                new_tok = sum(len(m[0].prompt) - m[2] for m in sub)
+                p = self.prefill
+                p["waves"] += 1
+                p["rows"] += len(sub)
+                p["tokens_new"] += new_tok
+                p["tokens_launched"] += self._wave_padded
+                p["wall_s"] += dur
+                p["by_path"][path] += 1
+                if self.step_acct is not None:
+                    self.step_acct.note_wave(
+                        "prefill",
+                        new_tok,
+                        self._wave_padded,
+                        dur,
+                        rows=len(sub),
+                    )
+                # One prefill-wave span per traced member (covers the
+                # whole sub-wave through first-token finalize, so each
+                # request's lane shows the convoy it rode in).
+                for tr in traced:
+                    tr.add(
+                        "prefill_wave",
+                        t_wave,
+                        dur,
+                        cat="prefill",
+                        wave_rows=len(sub),
+                        wave_new_tokens=int(new_tok),
+                    )
             self._admitting = []
 
     # ------------------------------------------------------------------
@@ -2339,7 +2420,7 @@ class Engine:
         """Insert the first ``key_len`` tokens (whose KV is in the pool)
         into the tree; canonicalize shared prefixes; move the lock to the
         deepest published node."""
-        with self._span_publish:
+        with self.spans.span("rm.tree.publish"):
             tr = req.trace
             t_pub = time.monotonic() if tr is not None else 0.0
             key = self._sequence_key(req, key_len)
@@ -2502,7 +2583,7 @@ class Engine:
             # Rows joined or left since the build ahead, or the launch is
             # of another kind: built in the gap, on rows that are settled.
             self._settle("admit" if plain else "launch_kind")
-            with annotate("rm.decode.build"):
+            with self.spans.span("rm.decode.build"):
                 # A pp or seeded step is a C=1 chunk (below): blockwise
                 # attention needs a KV-block-multiple table width, which
                 # the bucket keeps (floor = block).
@@ -2533,7 +2614,7 @@ class Engine:
                 launch.pt_d.shape[1] * self.page_size + 1 > self.cfg.index_topk,
             )
         step_t0 = time.monotonic()
-        with annotate("rm.decode.dispatch"):
+        with self.spans.span("rm.decode.dispatch"):
             tokens_d = launch.tokens_d
             if tokens_d is None:
                 tokens_d = jnp.asarray(self._tokens)
@@ -2585,12 +2666,12 @@ class Engine:
         if seeded:
             # The canonical per-row (seed, position) draw syncs per row:
             # sample and sync are one span here.
-            with annotate("rm.decode.sample"):
+            with self.spans.span("rm.decode.sample"):
                 sampled = np.zeros(self.max_batch, dtype=np.int64)
                 for row, req in active:
                     sampled[row] = self._sample_seeded_row(req, logits[row])
         else:
-            with annotate("rm.decode.sample"):
+            with self.spans.span("rm.decode.sample"):
                 if self._launch_draws(r for _, r in active):
                     self._rng, key = jax.random.split(self._rng)
                     sampled_d = sample_tokens(
@@ -2615,7 +2696,7 @@ class Engine:
                     for _, r in active
                 )
                 if not by_length:
-                    with annotate("rm.decode.build"):
+                    with self.spans.span("rm.decode.build"):
                         nxt = self._build_launch(1, preempt=False)
                     stops = [
                         (row, r.sampling.stop_token_ids)
@@ -2624,7 +2705,7 @@ class Engine:
                     ]
             # Wake-ups last: the handlers take the GIL from nothing.
             self._flush_emit(_UNDER_DECODE)
-            with annotate("rm.decode.sync"):  # the wait for the device
+            with self.spans.span("rm.decode.sync"):  # the wait for the device
                 sampled = np.asarray(sampled_d)
         # sample_tokens materialized on host above, so this spans the full
         # dispatch+device time of the step — the per-token latency (TPOT)
@@ -2774,7 +2855,7 @@ class Engine:
         """One decode step's per-row advance; the step is counted where
         its tokens are, so ``generated_tokens`` over ``decode_steps``
         reads whole steps at any instant."""
-        with annotate("rm.decode.consume"):
+        with self.spans.span("rm.decode.consume"):
             self.stats.decode_steps += 1
             for row, req in active:
                 self._advance_token(
@@ -2977,7 +3058,7 @@ class Engine:
         the same wave. ``decode=False`` (prefill/boost waves, the
         all-seeded fallback's second launch) advances the backlog alone."""
         ps = self.page_size
-        with annotate("rm.decode.build"):
+        with self.spans.span("rm.decode.build"):
             jobs = (
                 [
                     (job, w)
@@ -3084,13 +3165,13 @@ class Engine:
 
         # The verify pass is just a C-wide chunk; _forward_chunk picks
         # the pipeline schedule under pp (parallel/pp_serving.py).
-        with annotate("rm.decode.dispatch"):
+        with self.spans.span("rm.decode.dispatch"):
             res = self._forward_chunk(
                 toks_d, poss_d, sl_d, pt_d, kvlen_d, kv_block
             )
             logits = self._commit_pool_update(res)
         if active:
-            with annotate("rm.decode.sample"):
+            with self.spans.span("rm.decode.sample"):
                 draws = self._launch_draws(r for _, r in active)
                 key = None
                 if draws:
@@ -3108,14 +3189,14 @@ class Engine:
         # A wave without decode rows is a prefill launch to hide under.
         self._flush_emit(_UNDER_DECODE if active else _UNDER_PREFILL)
         if active:
-            with annotate("rm.decode.sync"):
+            with self.spans.span("rm.decode.sync"):
                 accept_len = np.asarray(accept_len)  # [B] one sync
                 bonus = np.asarray(bonus)
             self.stats.decode_steps += 1
 
         emitted_total = 0
         stamp = time.monotonic()
-        with annotate("rm.decode.consume"):
+        with self.spans.span("rm.decode.consume"):
             for row, req in active:
                 draft = drafts[row]
                 a = int(accept_len[row])
@@ -3188,7 +3269,7 @@ class Engine:
             # The finalizer waits for the device with nothing queued on
             # it: this wave's tokens go first.
             self._flush_emit(_EXPOSED)
-            with annotate("rm.first_token.sync"):
+            with self.spans.span("rm.first_token.sync"):
                 self._finalize_first_tokens(pending)
         elapsed = time.monotonic() - step_t0
         # The launch processes B·C positions; the USEFUL work is the
@@ -3359,7 +3440,7 @@ class Engine:
                 ]
         if not rows and not steps:
             return
-        with annotate("rm.decode.emit"):
+        with self.spans.span("rm.decode.emit"):
             for step_t0, elapsed, samples, wave, chunks in steps:
                 for _ in range(samples):
                     self._note_decode_time(elapsed / samples)

@@ -561,11 +561,17 @@ def _run_serve(args: argparse.Namespace) -> int:
 
     from radixmesh_tpu.engine.engine import Engine
     from radixmesh_tpu.models import get_config, init_params
+    from radixmesh_tpu.obs.tracing import ProcessAccount
     from radixmesh_tpu.server.http_frontend import ServingFrontend
 
     configure_logger("serve")
     log = get_logger("launch")
     _configure_tracing(args, node="serve")
+    # The process's collections and compilations, counted from here on
+    # (/debug/state ``process``). gc.callbacks and jax.monitoring are
+    # global to the process: this entry point installs them, no library
+    # code does.
+    account = ProcessAccount().install()
     cfg = get_config(args.model)
     log.info("initializing %s (%d layers)...", args.model, cfg.n_layers)
     _log_devices(log, "devices")
@@ -612,6 +618,7 @@ def _run_serve(args: argparse.Namespace) -> int:
         stream_publish_tokens=args.stream_publish or 0,
         step_accounting=args.step_accounting,
         peak_tflops=args.peak_tflops,
+        process_account=account,
     )
     slo_cfg = None
     if args.slo or args.slo_ttft_ms is not None or args.slo_tenant:
@@ -653,6 +660,7 @@ def _run_serve(args: argparse.Namespace) -> int:
             stop.wait(1.0)
     finally:
         frontend.close()
+        account.uninstall()
         _dump_trace(args, log)
     return 0
 

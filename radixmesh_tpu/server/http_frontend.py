@@ -265,7 +265,7 @@ class EngineRunner:
     def _run(self) -> None:
         while not self._stop.is_set():
             with self._lock:
-                with annotate("rm.runner.pre_step"):
+                with self.engine.spans.span("rm.runner.pre_step"):
                     self._pre_step()
                 has_work = self.engine.has_work()
                 if has_work:
@@ -741,6 +741,21 @@ class ServingFrontend:
                     # behind the next launch / with the device waiting,
                     # and what forced the latter.
                     "handover": eng.handover,
+                    # Count and host seconds of every rm.* span the engine
+                    # thread opened, and ``outside_step``: the seconds
+                    # between two steps.
+                    "spans": eng.spans.snapshot(),
+                    # The admission waves' work: members, uncached prompt
+                    # tokens, tokens of the launched shapes, wall seconds
+                    # from dispatch to the first-token sync, waves by path.
+                    "prefill": eng.prefill,
+                    # Steps whose excess over their own prefill passed
+                    # ``threshold_s``: count, excess seconds, the last
+                    # records (when, top spans, queue, pauses, memory).
+                    "slow_steps": {
+                        **eng.slow_steps,
+                        "last": list(eng.slow_steps["last"]),
+                    },
                 },
                 "pool": {
                     "num_slots": eng.pool.num_slots,
@@ -777,6 +792,10 @@ class ServingFrontend:
                 # link fleet-tail buckets back to stitched traces.
                 "exemplars": get_registry().exemplars(),
             }
+            if eng.process_account is not None:
+                # The process's pauses (launch.py serve installs the
+                # account): collections by generation, compilations by name.
+                state["process"] = eng.process_account.snapshot()
             if eng._moe_stats is not None:
                 # The expert layer's counters, by call site: kept on the
                 # device, fetched here and nowhere else.
@@ -1333,7 +1352,11 @@ class ServingFrontend:
                                 self.wfile.write(
                                     f"data: {json.dumps({'token': t})}\n\n".encode()
                                 )
-                            done_evt = {"done": True, "output_ids": final}
+                            done_evt = {
+                                "done": True,
+                                "output_ids": final,
+                                "cached_tokens": req.prefix_len,
+                            }
                             if req.resume_offset:
                                 done_evt["resumed_from"] = req.resume_offset
                             if frontend.tokenizer is not None:

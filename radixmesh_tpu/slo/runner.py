@@ -38,7 +38,6 @@ from typing import Sequence
 
 from radixmesh_tpu.engine.engine import Engine
 from radixmesh_tpu.engine.request import Request, RequestState, SamplingParams
-from radixmesh_tpu.obs.tracing import annotate
 from radixmesh_tpu.server.http_frontend import EngineRunner
 from radixmesh_tpu.slo.control import (
     SHED_DRAINING,
@@ -149,7 +148,7 @@ class SLORunner(EngineRunner):
         # Keep the engine's own FIFO shallow: dispatch at most one
         # admission wave ahead, so ordering stays with the WFQ and a
         # deadline re-check happens as close to prefill as possible.
-        with annotate("rm.slo.pump"):
+        with self.engine.spans.span("rm.slo.pump"):
             while len(self.engine.waiting) < self.engine.max_batch:
                 req = self.ctl.pop_ready(now)
                 if req is None:

@@ -241,6 +241,133 @@ def test_python_tracer_only_on_request(served):
     assert any(n == "rm.engine.step" for evs in lines for n, _, _ in evs)
 
 
+def test_debug_state_serves_the_span_book_and_the_prefill_account(served):
+    """``engine.spans``: the fifteen engine-thread names and
+    ``outside_step``, counted with no capture running (that none leaves a
+    trace file is the test above's); ``cache.tree_host``
+    is a view of two of them; ``engine.prefill`` counts the round's two
+    admission waves; ``process`` only where an entry point installed it."""
+    base = served["base"]
+
+    def state():  # between two steps: /debug/state takes no lock
+        with served["frontend"].runner._lock:
+            return _get(base + "/debug/state")
+
+    a = state()
+    n = served["traffic"]()
+    b = state()
+    sa, sb = a["engine"]["spans"], b["engine"]["spans"]
+    assert list(sb) == [*ENGINE_SPANS, "outside_step"] and len(sb) == 16
+    assert all(set(v) == {"n", "s"} for v in sb.values())
+    d = {k: (sb[k]["n"] - sa[k]["n"], sb[k]["s"] - sa[k]["s"]) for k in sb}
+    assert all(dn >= 0 and ds >= 0.0 for dn, ds in d.values())
+    steps = d["rm.engine.step"][0]
+    assert steps > 0 and d["rm.admit"][0] == steps  # one a step
+    assert d["outside_step"][0] in (steps, steps - 1)  # a gap before each step
+    assert d["rm.runner.pre_step"][0] >= steps and d["rm.slo.pump"][0] >= steps
+    assert d["rm.tree.match"][0] == n and d["rm.tree.publish"][0] == 2 * n
+    assert d["rm.prefill.dense"][0] == 1 and d["rm.prefill.group"][0] == 1
+    assert d["rm.first_token.sync"][0] == 2
+    decode = b["engine"]["decode_steps"] - a["engine"]["decode_steps"]
+    assert d["rm.decode.dispatch"][0] == d["rm.decode.sync"][0] == decode
+    # nested spans take no more than what holds them
+    assert d["rm.admit"][1] <= d["rm.engine.step"][1]
+    assert (d["rm.prefill.dense"][1] + d["rm.prefill.group"][1]
+            + d["rm.first_token.sync"][1] + d["rm.tree.match"][1]) <= d["rm.admit"][1]
+    th = b["cache"]["tree_host"]
+    assert th == {"matches": sb["rm.tree.match"]["n"], "match_s": sb["rm.tree.match"]["s"],
+                  "publishes": sb["rm.tree.publish"]["n"],
+                  "publish_s": sb["rm.tree.publish"]["s"]}
+    pa, pb = a["engine"]["prefill"], b["engine"]["prefill"]
+    assert pb["waves"] - pa["waves"] == 2 and pb["rows"] - pa["rows"] == n
+    assert pb["tokens_new"] - pa["tokens_new"] == 20 * n  # nothing shared
+    assert pb["tokens_launched"] - pa["tokens_launched"] >= 20 * n
+    assert pb["by_path"]["dense"] - pa["by_path"]["dense"] == 1
+    assert pb["by_path"]["chunk"] - pa["by_path"]["chunk"] == 1
+    assert 0.0 < pb["wall_s"] - pa["wall_s"] <= d["rm.admit"][1]
+    slow = b["engine"]["slow_steps"]
+    assert slow["threshold_s"] == 1.0 and slow["n"] == len(slow["last"]) <= 32
+    assert "process" not in b  # gc / jax.monitoring hooks: launch.py serve's
+
+
+class _Ticks:
+    """A clock that reads 1, 2, 3, ... seconds."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1.0
+        return self.t
+
+
+def test_book_span_adds_to_its_name_and_to_no_other():
+    from radixmesh_tpu.obs.tracing import ENGINE_SPANS as NAMES, SpanBook
+
+    assert NAMES == tuple(ENGINE_SPANS)  # the table above, in its order
+    book = SpanBook(clock=_Ticks())
+    zero = book.snapshot()
+    assert list(zero) == [*ENGINE_SPANS, "outside_step"]
+    assert all(v == {"n": 0, "s": 0.0} for v in zero.values())
+    for _ in range(3):
+        with book.span("rm.decode.build") as span:
+            assert book.innermost == "rm.decode.build"
+        assert span.seconds == 1.0 and book.innermost is None
+    got = book.snapshot()
+    assert got.pop("rm.decode.build") == {"n": 3, "s": 3.0}
+    assert all(v == {"n": 0, "s": 0.0} for v in got.values())
+    with book.span("rm.not.seeded"):  # a name the book was not seeded with
+        pass
+    assert book.snapshot()["rm.not.seeded"] == {"n": 1, "s": 1.0}
+
+
+@pytest.mark.parametrize("raises", [False, True], ids=["exit", "exception"])
+def test_book_nesting_restores_the_innermost_name(raises):
+    from radixmesh_tpu.obs.tracing import SpanBook
+
+    book = SpanBook(clock=_Ticks())
+    try:
+        with book.span("rm.admit"):
+            with book.span("rm.tree.match"):
+                assert book.innermost == "rm.tree.match"
+            assert book.innermost == "rm.admit"
+            with book.span("rm.prefill.dense"):
+                if raises:
+                    raise KeyError("a prefill that fails")
+            assert book.innermost == "rm.admit"
+    except KeyError:
+        assert raises
+    assert book.innermost is None
+    got = book.snapshot()
+    assert got["rm.tree.match"] == {"n": 1, "s": 1.0}
+    assert got["rm.prefill.dense"] == {"n": 1, "s": 1.0}
+    assert got["rm.admit"] == {"n": 1, "s": 5.0}  # readings 1..6: holds both
+
+
+def test_outside_step_adds_up_between_two_steps():
+    from radixmesh_tpu.obs.tracing import SpanBook
+
+    book = SpanBook(clock=_Ticks())
+    with book.step() as first:  # readings 1, 2
+        assert book.innermost == "rm.engine.step"
+    assert book.snapshot()["outside_step"] == {"n": 0, "s": 0.0}  # no step before
+    with book.span("rm.runner.pre_step"):  # readings 3, 4: between two steps
+        pass
+    with book.step() as second:  # readings 5, 8
+        with book.span("rm.admit"):  # 6, 7
+            pass
+    with book.step():  # 9, 10: straight after
+        pass
+    got = book.snapshot()
+    assert got["outside_step"] == {"n": 2, "s": 3.0 + 1.0}
+    assert got["rm.engine.step"] == {"n": 3, "s": 1.0 + 3.0 + 1.0}
+    assert first.seconds == 1.0 and second.seconds == 3.0
+    assert book.thread == threading.get_ident()
+    # a step keeps the book's seconds at its entry: its own spans, largest first
+    assert book.top_since(second.entry) == [["rm.admit", 1.0]]
+    assert book.top_since(first.entry, k=1) == [["rm.runner.pre_step", 1.0]]
+
+
 def test_readmission_after_preemption_waits_from_its_requeue_time():
     cfg = ModelConfig.tiny()
     eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0)), num_slots=512,

@@ -103,6 +103,8 @@ class TestServingFrontend:
                 tokens.append(evt["token"])
         assert done is not None
         assert done["output_ids"] == tokens
+        # the prefix the tree served, as the non-streaming reply has it
+        assert type(done["cached_tokens"]) is int and 0 <= done["cached_tokens"] < 15
 
     def test_bad_request(self, frontend):
         with pytest.raises(urllib.error.HTTPError) as e:
