@@ -671,10 +671,9 @@ class PagedKVPool:
 
     def gather(self, slots: np.ndarray | jax.Array) -> jax.Array:
         """Gather ``[2, L, n, kv_heads, head_dim]`` for the given slots,
-        dequantized for quantized pools (debug/test path and the dense-
-        prefill cached-prefix gather; attention kernels read pages
-        directly). A run of slots (a tree hit's prefix) comes by whole
-        pages."""
+        dequantized for quantized pools (debug/test path: no launch of
+        the engine calls it — attention kernels read pages directly, a
+        cached prefix included). A run of slots comes by whole pages."""
         slots = np.asarray(slots, dtype=np.int32)
         if len(slots) and is_run(slots, self.page_size):
             slots = slots[None]

@@ -97,6 +97,38 @@ def _pow2_at_least(n: int, floor: int = 8) -> int:
 # constant so the padding and the kernels can't drift apart.
 _KV_BLOCK_PAGES = 32
 
+
+def prefill_launch_shape(
+    rows: int,
+    new_tokens: int,
+    total_tokens: int,
+    *,
+    chunk: int,
+    page_size: int,
+    max_pages: int,
+    width_selects: bool = False,
+) -> tuple[int, int, int]:
+    """``(B, C, max_pages)`` of a chunk-prefill launch — the program key of
+    ``prefill_chunk_paged`` — for ``rows`` requests of at most
+    ``new_tokens`` uncached tokens and ``total_tokens`` in all.
+
+    A lone request takes ONE table width, the engine's own
+    (``max_pages``): the attention kernels end a row's page loop at the
+    row's length, so a table wider than the row is a few KB uploaded and
+    nothing on the device, and a row's shape then depends on the
+    power-of-two bucket of its new tokens alone — requests of two exact
+    lengths in one bucket pair (new tokens, cached prefix) never meet two
+    programs. Where the width itself chooses the program's attention
+    (``width_selects``: learned sparse attention engages on it), and for
+    a group, whose widths its callers warm by burst, the width is the
+    longest row's pages, rounded up to a power of two."""
+    B = _pow2_at_least(rows, floor=1)
+    C = _pow2_at_least(min(new_tokens, chunk), floor=16)
+    pages = -(-total_tokens // page_size)
+    if rows == 1 and not width_selects:
+        pages = max_pages
+    return B, C, _pow2_at_least(pages, floor=_KV_BLOCK_PAGES)
+
 # Upper edges (ms) of ``Engine.admission_wait``'s buckets; one overflow
 # bucket follows the last.
 _ADMISSION_WAIT_LE_MS = (1, 2, 5, 10, 20, 50, 100, 200, 500, 1000, 2000, 5000)
@@ -301,10 +333,10 @@ class Engine:
         self.max_batch = max_batch
         self.max_seq_len = max_seq_len or cfg.max_seq_len
         self.max_pages = -(-self.max_seq_len // page_size)
-        # Long-context admission (SURVEY §5): prompts with more than
-        # ``long_prefill_threshold`` uncached tokens prefill in
-        # ``prefill_chunk``-token chunks against the paged pool (O(S·chunk)
-        # memory) instead of the dense path (O(S²) scores).
+        # Long-context admission (SURVEY §5): prompts of more than
+        # ``long_prefill_threshold`` tokens, and every prompt the tree serves
+        # part of, prefill in ``prefill_chunk``-token chunks against the paged
+        # pool (O(S·chunk) memory) instead of the dense path (O(S²) scores).
         self.prefill_chunk = prefill_chunk
         # Cold-burst fairness (VERDICT round-4 weak #4): a prefill wave
         # wider than the compute-saturating token count only convoys —
@@ -1460,8 +1492,12 @@ class Engine:
         """Admit waiting requests into free rows. Concurrent arrivals are
         prefilled as ONE batched chunked-paged call + ONE batched sample
         (VERDICT round-1 weak #5: per-request serial prefill made TTFT
-        degrade linearly with queue depth); a lone short request keeps the
-        dense single-request path (smallest-latency compile variant)."""
+        degrade linearly with queue depth). A lone request goes the same
+        way, as a group of one, whenever the tree serves part of its
+        prompt: the chunk path reads a cached prefix where it lies, in
+        pages. Only a lone short request with NOTHING cached keeps the
+        dense single-request path (one launch of the whole prompt, no
+        page table: the smallest-latency compile variant)."""
         self._pump_kv_transfer()
         if self._pressure and any(r is not None for r in self._rows):
             return
@@ -1649,16 +1685,16 @@ class Engine:
                     self.pool.quant is None
                     and not self._pp
                     and len(sub) == 1
-                    and len(sub[0][0].prompt) - sub[0][2]
-                    <= self.long_prefill_threshold
-                    # A latent prefix is read where it lies, in pages (the
-                    # chunk path): gathering 16 k rows to attend them dense
-                    # costs more than the tail's whole prefill.
-                    and not (self.cfg.latent and sub[0][2])
+                    # A cached prefix is read where it lies, in pages (the
+                    # chunk path): a dense copy of it would be gathered,
+                    # padded and re-laid before every such prefill.
+                    and sub[0][2] == 0
+                    and len(sub[0][0].prompt) <= self.long_prefill_threshold
                 ):
                     path = "dense"
                     with self.spans.span("rm.prefill.dense"):
-                        pending = [self._prefill_dense(*sub[0])]
+                        req, row, _, _, own = sub[0]  # reuse 0, no prefix slots
+                        pending = [self._prefill_dense(req, row, own)]
                 else:
                     path = "chunk"
                     with self.spans.span("rm.prefill.group"):
@@ -2119,34 +2155,23 @@ class Engine:
         if engaged:
             c["index_keys_scored"] += held * len(cfg.full_layers)
 
-    def _prefill_dense(
-        self,
-        req: Request,
-        row: int,
-        reuse: int,
-        prefix_slots: np.ndarray,
-        own: np.ndarray,
-    ) -> tuple:
-        """Single-request dense prefill (gathered right-aligned prefix).
+    def _prefill_dense(self, req: Request, row: int, own: np.ndarray) -> tuple:
+        """Dense prefill of one request with nothing cached: the whole
+        prompt in one ``prefill_forward`` launch, no page table. (A request
+        with a cached prefix is a group of one: :meth:`_prefill_group`.)
         Returns ``(req, final-logit device slice)`` for
         :meth:`_finalize_first_tokens`."""
         prompt = req.prompt
-        n_new = len(prompt) - reuse
+        n_new = len(prompt)
         s_b = _pow2_at_least(n_new)
         self._wave_padded = s_b  # launch shape (step attribution)
-        p_b = _pow2_at_least(reuse, floor=self.page_size) if reuse else 0
         tokens = np.zeros((1, s_b), dtype=np.int32)
-        tokens[0, :n_new] = prompt[reuse:]
-        positions = (reuse + np.arange(s_b, dtype=np.int32))[None]
-        planes, heads, width = self.cfg.kv_row
-        kv_shape = (self.cfg.n_layers, 1, p_b, heads, width)
-        # One buffer a plane of the row (a latent row has one: its cached_v
-        # is the same array and is not read).
-        cached = [jnp.zeros(kv_shape, dtype=self.cfg.dtype)] * planes
-        if reuse:
-            g = self.pool.gather(prefix_slots)  # [planes, L, n, heads, width]
-            cached = [c.at[:, 0, p_b - reuse :].set(g[i])
-                      for i, c in enumerate(cached)]
+        tokens[0, :n_new] = prompt
+        positions = np.arange(s_b, dtype=np.int32)[None]
+        _, heads, width = self.cfg.kv_row
+        # No cached rows: an empty buffer stands for both planes.
+        no_prefix = jnp.zeros(
+            (self.cfg.n_layers, 1, 0, heads, width), dtype=self.cfg.dtype)
         moe_kw = self._moe_kw()
         if moe_kw:  # keep the bucket's padding out of the counters
             moe_kw["new_lengths"] = jnp.full((1,), n_new, dtype=jnp.int32)
@@ -2155,32 +2180,32 @@ class Engine:
             self.cfg,
             jnp.asarray(tokens),
             jnp.asarray(positions),
-            cached[0],
-            cached[-1],
-            jnp.full((1,), reuse, dtype=jnp.int32),
+            no_prefix,
+            no_prefix,
+            jnp.zeros((1,), dtype=jnp.int32),
             **moe_kw,
         )
         if self.cfg.dsa:  # the tokens' index keys, to the same slots
             index_keys, *aux = aux
             self.pool.write_index(own[:n_new], index_keys[:, 0, :n_new])
             self._note_sparse(
-                "prefill", reuse + 1 + np.arange(n_new, dtype=np.int64),
+                "prefill", 1 + np.arange(n_new, dtype=np.int64),
                 s_b > self.cfg.index_topk)
         if aux:
             self._moe_stats = aux[0]["moe_stats"]
             self._last_expert_ids = aux[0]["expert_ids"]
             self._last_selected = None  # the dense path selects as a mask
-            self._keep_expert_ids(req, reuse, 0, n_new)
+            self._keep_expert_ids(req, 0, 0, n_new)
         self.pool.write(
             own[:n_new], new_k[:, 0, :n_new],
             None if new_v is None else new_v[:, 0, :n_new],
         )
 
         req.output_tokens = []
-        req.kv_len = len(prompt)
-        req.token_slots = np.concatenate([prefix_slots, own[:n_new]])
+        req.kv_len = n_new
+        req.token_slots = own[:n_new].copy()
         req.own_slots = own
-        self._install_prefilled(req, row, reuse)
+        self._install_prefilled(req, row, 0)
         return (req, logits[0, n_new - 1])
 
     def _pp_n_micro(self, batch: int) -> int:
@@ -2293,86 +2318,75 @@ class Engine:
 
     def _prefill_group(self, group: list[tuple]) -> list[tuple]:
         """Batched chunked-paged prefill for ``group`` of acquired
-        requests: all rows advance through ``prefill_chunk_paged`` in
-        lockstep (shapes bucketed to powers of two), each chunk writing
+        requests — one request with a cached prefix is a group of one:
+        all rows advance through ``prefill_chunk_paged`` in lockstep
+        (launch shape: :func:`prefill_launch_shape`), each chunk writing
         K/V into the pool in place and attending blockwise via the page
-        table — no host ``pool.gather`` round-trip, peak memory
+        table — a cached prefix is read where it lies, peak memory
         O(batch · chunk · block) regardless of prompt length. Ragged
         offsets are exact: every row carries its own positions and
         kv-length; exhausted/padded rows ride the scratch slot. One
-        batched sample at the end → one host sync for the whole group."""
+        batched sample at the end → one host sync for the whole group.
+
+        The host side is one pass over the rows a wave (their new tokens
+        and slots laid side by side, padded to whole chunks); a chunk's
+        inputs are slices of that, and the page table is uploaded once."""
         N = len(group)
         ps = self.page_size
         kv_block = _KV_BLOCK_PAGES
-        prompts = [g[0].prompt for g in group]
-        reuses = [g[2] for g in group]
-        totals = [len(p) for p in prompts]
-        token_slots_all = [
-            np.concatenate([g[3], g[4][: totals[i] - reuses[i]]])
-            for i, g in enumerate(group)
-        ]
-        n_new_max = max(t - r for t, r in zip(totals, reuses))
-        C = _pow2_at_least(min(n_new_max, self.prefill_chunk), floor=16)
-        B = _pow2_at_least(N, floor=1)
-        maxp = _pow2_at_least(
-            max(-(-t // ps) for t in totals), floor=kv_block
+        reuses = np.array([g[2] for g in group], dtype=np.int64)
+        totals = np.array([len(g[0].prompt) for g in group], dtype=np.int64)
+        new = totals - reuses
+        B, C, maxp = prefill_launch_shape(
+            N, int(new.max()), int(totals.max()), chunk=self.prefill_chunk,
+            page_size=ps, max_pages=self.max_pages,
+            width_selects=self.cfg.dsa,
         )
+        n_chunks = -(-int(new.max()) // C)
+        span = n_chunks * C
+        self._wave_padded = B * span  # launch shape (step attribution)
+        toks_all = np.zeros((B, span), dtype=np.int32)
+        sl_all = np.full((B, span), self._scratch_slot, dtype=np.int32)
         pt = np.full((B, maxp), self._scratch_page, dtype=np.int32)
-        for i, ts in enumerate(token_slots_all):
-            n_pages = -(-totals[i] // ps)
-            pt[i, :n_pages] = ts[::ps] // ps
+        token_slots_all = []
+        for i, (req, _, reuse, prefix_slots, own) in enumerate(group):
+            toks_all[i, : new[i]] = req.prompt[reuse:]
+            sl_all[i, : new[i]] = own[: new[i]]
+            ts = np.concatenate([prefix_slots, own[: new[i]]])
+            token_slots_all.append(ts)
+            pt[i, : -(-totals[i] // ps)] = ts[::ps] // ps
         pt_dev = jnp.asarray(pt)
+        offs = np.arange(C, dtype=np.int64)
+        keeps = any(g[0].sampling.return_logits for g in group)
 
         final_logits: list = [None] * N
-        n_chunks = -(-(n_new_max) // C)
-        self._wave_padded = B * C * n_chunks  # launch shape (step attribution)
         for ci in range(n_chunks):
-            toks = np.zeros((B, C), dtype=np.int32)
-            sl = np.full((B, C), self._scratch_slot, dtype=np.int32)
+            starts = reuses + ci * C  # [N] tokens in the pool before the chunk
+            nv = np.clip(totals - starts, 0, C)  # rows a run (kv.write)
+            live = nv > 0
             poss = np.zeros((B, C), dtype=np.int32)
+            poss[:N] = np.minimum(starts[:, None] + offs, self.max_seq_len - 1)
             kvlen = np.zeros((B,), dtype=np.int32)
-            lastpos = np.full((N,), -1, dtype=np.int32)
-            written = np.zeros((N,), dtype=np.int32)  # rows a run, kv.write
-            for i in range(N):
-                start = reuses[i] + ci * C
-                nv = written[i] = min(max(totals[i] - start, 0), C)
-                poss[i] = np.clip(
-                    start + np.arange(C, dtype=np.int32), 0, self.max_seq_len - 1
-                )
-                if nv > 0:
-                    toks[i, :nv] = prompts[i][start : start + nv]
-                    sl[i, :nv] = token_slots_all[i][start : start + nv]
-                    kvlen[i] = start + nv
-                    if start + nv == totals[i]:
-                        lastpos[i] = nv - 1  # this chunk holds the last token
-                else:
-                    kvlen[i] = totals[i]
+            kvlen[:N] = np.where(live, starts + nv, totals)
+            # Only each row's LAST prompt position is ever sampled, and
+            # only in the chunk that holds it.
+            last = live & (starts + nv == totals)
+            at = np.zeros((B,), dtype=np.int32)
+            at[:N] = np.where(last, nv - 1, 0)
+            sl = sl_all[:, ci * C : (ci + 1) * C]
             if self.cfg.latent:  # rows in pages that this chunk attends
-                prior = sum(
-                    reuses[i] + ci * C for i in range(N)
-                    if reuses[i] + ci * C < totals[i]
-                )
                 self.attn_window["latent_prefix_tokens"] += (
-                    prior * self.cfg.n_layers
+                    int(starts[live].sum()) * self.cfg.n_layers
                 )
             if self.cfg.dsa:
                 self._note_sparse(
                     "prefill",
-                    np.concatenate([
-                        np.arange(reuses[i] + ci * C + 1,
-                                  min(totals[i], reuses[i] + ci * C + C) + 1,
-                                  dtype=np.int64)
-                        for i in range(N)
-                    ]),
+                    (starts[:, None] + offs + 1)[offs < nv[:, None]],
                     maxp * ps + C > self.cfg.index_topk,
                 )
-            self.pool.note_runs(sl[:N, 0], written)
-            # Only each row's LAST prompt position is ever sampled, and
-            # only in the chunk that holds it.
-            at = np.zeros((B,), dtype=np.int32)
-            at[:N] = np.maximum(lastpos, 0)
+            self.pool.note_runs(sl[:N, 0], nv)
             res = self._forward_chunk(
-                jnp.asarray(toks),
+                jnp.asarray(toks_all[:, ci * C : (ci + 1) * C]),
                 jnp.asarray(poss),
                 jnp.asarray(sl),
                 pt_dev,
@@ -2381,19 +2395,19 @@ class Engine:
                 logits_at=jnp.asarray(at),
             )
             logits = self._commit_pool_update(res)
-            for i in range(N):
-                if lastpos[i] >= 0:
-                    final_logits[i] = logits[i, 0]
-                nv = min(max(totals[i] - reuses[i] - ci * C, 0), C)
-                if nv:
-                    self._keep_expert_ids(group[i][0], reuses[i] + ci * C, i, nv)
-                if lastpos[i] >= 0:
-                    self._keep_selected(group[i][0], totals[i] - 1, i)
+            for i in np.flatnonzero(last).tolist():
+                final_logits[i] = logits[i, 0]
+            if keeps:  # the logits hook: expert ids, selected rows
+                for i in np.flatnonzero(live).tolist():
+                    req = group[i][0]
+                    self._keep_expert_ids(req, int(starts[i]), i, int(nv[i]))
+                    if last[i]:
+                        self._keep_selected(req, int(totals[i]) - 1, i)
 
         out = []
         for i, (req, row, reuse, prefix_slots, own) in enumerate(group):
             req.output_tokens = []
-            req.kv_len = totals[i]
+            req.kv_len = int(totals[i])
             req.token_slots = token_slots_all[i]
             req.own_slots = own
             self._install_prefilled(req, row, reuse)

@@ -115,12 +115,13 @@ def test_prefill_then_decode_through_the_latent_cache(model, path, n_prompt):
 def test_prefix_served_from_the_tree_is_read_in_pages(model, n_tail):
     """A second request whose prompt is mostly in the radix tree: its
     latent prefix is read where it lies (the chunk path, whatever the
-    tail's length), and the counters say how many rows that was."""
+    tail's length — as every configuration's is, PR 36), and the counters
+    say how many rows that was."""
     cfg, _ = model
     eng = make_engine(model, prefill_chunk=16)
     shared = prompt_of(41, seed=1)
     eng.generate([shared + prompt_of(3, seed=2)], SamplingParams(max_new_tokens=2))
-    assert eng._last_prefill_path == "dense"  # nothing cached: gathered nothing
+    assert eng._last_prefill_path == "dense"  # nothing cached: one launch
     before = dict(eng.attn_window)
     prompt = shared + prompt_of(n_tail, seed=3)
     req = eng.add_request(

@@ -107,18 +107,22 @@ def test_prefill_then_decode_through_the_pool(model, path, n_prompt):
 @pytest.mark.parametrize("path", ["dense", "chunk"])
 def test_prefix_served_from_the_tree(model, path, n_prompt):
     """A second request whose prompt is mostly in the radix tree: window
-    layers then read their keys from pages another request wrote."""
+    layers then read their keys from pages another request wrote —
+    ``path`` is how that one prefilled (cold); the second reads its prefix
+    where it lies, as a chunk group of one, whatever the threshold."""
     eng = make_engine(
         model, prefill_chunk=16,
         long_prefill_threshold=1024 if path == "dense" else 0,
     )
     shared = prompt_of(n_prompt, seed=1)
     eng.generate([shared + prompt_of(3, seed=2)], SamplingParams(max_new_tokens=2))
+    assert eng._last_prefill_path == path
     prompt = shared + prompt_of(5, seed=3)
     req = eng.add_request(
         prompt, SamplingParams(max_new_tokens=N_NEW, return_logits=True))
     while eng.has_work():
         eng.step()
+    assert eng._last_prefill_path == "chunk"
     assert req.prefix_len >= n_prompt // PAGE * PAGE > 0
     check_against_reference(model, req, prompt)
 

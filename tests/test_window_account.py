@@ -44,7 +44,8 @@ def _run(eng, *prompts, new=3):
 SHARED = list(range(100, 120))
 WAVES = {
     "dense_cold": ([range(1, 21)], 0, "dense"),
-    "dense_prefix": ([SHARED[:16] + [7, 8, 9, 10, 11]], 16, "dense"),
+    # a lone request over a cached prefix is a group of one (PR 36)
+    "lone_prefix": ([SHARED[:16] + [7, 8, 9, 10, 11]], 16, "chunk"),
     "chunk_cold": ([range(1, 11), range(30, 43)], 0, "chunk"),
     "chunk_prefix": ([SHARED[:16] + [7, 8, 9], SHARED[:16] + [5, 6, 7, 8, 9, 10]],
                      16, "chunk"),
