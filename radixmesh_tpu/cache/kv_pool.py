@@ -371,6 +371,13 @@ def _scatter_index(index: jax.Array, slots: jax.Array, keys: jax.Array,
                       lead=(slice(None),))
 
 
+@partial(jax.jit, donate_argnums=(0,))
+def _scatter_state(state: jax.Array, slots: jax.Array, new: jax.Array) -> jax.Array:
+    """``new`` [layers, n, rows, 128] to state slots ``slots`` [n]: whole
+    slots on a leading axis, so nothing is relaid."""
+    return state.at[:, slots].set(new.astype(state.dtype))
+
+
 @partial(jax.jit, static_argnums=(2,))
 def _gather_kv(kv: jax.Array, slots: jax.Array, page_size: int) -> jax.Array:
     # → [2, L, n, H, D] (token-major, for tests/debug)
@@ -439,6 +446,7 @@ class PagedKVPool:
         quant: str | None = None,
         planes: int = 2,
         index_shape: tuple[int, int, int] | None = None,
+        state_shape: tuple[int, int, int, int] | None = None,
     ):
         if planes not in (1, 2):
             raise ValueError("a pool row has 1 plane (latent) or 2 (K and V)")
@@ -489,6 +497,22 @@ class PagedKVPool:
                     "the index plane has the pool's slots and no quantized form"
                 )
             self.index = jnp.zeros(index_shape, dtype=dtype)
+        # A third kind of state (``ModelConfig.state_shape``): what the
+        # layers that cache NOTHING per token carry — ``[layers, slots,
+        # rows, 128]``, one slot a snapshot (every such layer's state at
+        # one position of one sequence). Its unit is not a token, so it
+        # has an allocator of its own: a live row holds a working slot and
+        # a boundary slot, a tree node may hold one (valid at its end).
+        # Slot 0 is nobody's: launches send there what they do not want.
+        self.state = None
+        self.state_allocator = None
+        self.state_used_peak = 0
+        if state_shape is not None:
+            if quant is not None:
+                raise ValueError("state slots have no quantized form")
+            self.state = jnp.zeros(state_shape, dtype=dtype)
+            self.state_allocator = SlotAllocator(state_shape[1], 1)
+            self.state_allocator.reserve(np.zeros(1, np.int32))
         # Per-(token, head) symmetric scales for quantized pools: value ≈
         # int8 * scale (ops/quant.py). Same [2, L, Hkv, slots] geometry as
         # the data minus head_dim — shards identically over `tp`, and the
@@ -521,10 +545,45 @@ class PagedKVPool:
     def index_plane_bytes(self) -> int:
         return 0 if self.index is None else self.index.size * self.index.itemsize
 
+    @property
+    def state_shape(self) -> tuple[int, int, int, int] | None:
+        return None if self.state is None else tuple(self.state.shape)
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Bytes of one snapshot (all its layers)."""
+        if self.state is None:
+            return 0
+        return self.state.size // self.state.shape[1] * self.state.itemsize
+
     # ---- allocation (host side) ----
 
     def alloc(self, n_tokens: int) -> np.ndarray | None:
         return self.allocator.alloc(n_tokens)
+
+    def alloc_state(self) -> int | None:
+        """One state slot, or None when every slot is taken."""
+        got = self.state_allocator.alloc(1)
+        if got is None:
+            return None
+        a = self.state_allocator
+        self.state_used_peak = max(
+            self.state_used_peak, a.num_slots - a.free_slots)
+        return int(got[0])
+
+    def free_state(self, slot: int) -> None:
+        self.state_allocator.free(np.asarray([slot], np.int32))
+
+    @property
+    def state_slots_used(self) -> int:
+        a = self.state_allocator
+        return 0 if a is None else a.num_slots - a.free_slots
+
+    def write_state(self, slots: np.ndarray, states: jax.Array) -> None:
+        """Store ``states`` [layers, n, rows, 128] at state slots ``slots``
+        [n] (a dense prefill's; the paged launches write their own)."""
+        self.state = _scatter_state(
+            self.state, jnp.asarray(slots, jnp.int32), states)
 
     def free(self, slots: np.ndarray) -> None:
         self.allocator.free(slots)

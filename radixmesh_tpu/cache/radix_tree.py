@@ -191,6 +191,7 @@ class TreeNode:
         "chain",
         "shard",
         "id",
+        "state",
     )
 
     def __init__(self, parent: "TreeNode | None" = None):
@@ -226,6 +227,11 @@ class TreeNode:
         # nodes hash their first page. -1 = shard tracking off.
         self.shard = -1
         self.id = next(_node_ids)
+        # A state slot (``cache/kv_pool.py``) valid at this node's END, or
+        # None: what the layers that cache nothing per token carried when
+        # the sequence stood exactly there. Only a tree built with
+        # ``on_free_state`` ever sets it.
+        self.state: int | None = None
 
     @property
     def evicted(self) -> bool:
@@ -274,6 +280,19 @@ class MatchResult:
     host_nodes: list["TreeNode"] = field(default_factory=list)
     disk_values: list[Any] = field(default_factory=list)
     disk_nodes: list["TreeNode"] = field(default_factory=list)
+    # ``(position, state slot)`` of every device-resident matched node that
+    # carries a state, shallowest first (a state-bearing model resumes at
+    # one of these positions, not at ``length``); empty in a tree without
+    # states.
+    states: list[tuple[int, int]] = field(default_factory=list)
+
+    def state_at_or_before(self, limit: int) -> tuple[int, int | None]:
+        """The deepest ``(position, slot)`` with a state at or before
+        ``limit``; ``(0, None)``: none — position 0 needs no state."""
+        for pos, slot in reversed(self.states):
+            if pos <= limit:
+                return pos, slot
+        return 0, None
 
     @property
     def length(self) -> int:
@@ -340,9 +359,17 @@ class RadixTree:
         time_fn: Callable[[], float] = time.monotonic,
         on_free_host: Callable[[np.ndarray], None] | None = None,
         shard_fn: Callable[[np.ndarray], int] | None = None,
+        on_free_state: Callable[[int], None] | None = None,
     ):
         self.page_size = page_size
         self.on_free = on_free
+        # Set for a model whose layers carry a state beside the rows: nodes
+        # may then hold a state slot (``attach_state``), a match reports
+        # where states lie, and this is called with every slot a removed,
+        # reset or state-evicted node gives up. None: no node ever holds
+        # one, and no walk looks for one.
+        self.on_free_state = on_free_state
+        self.state_evictions = 0
         self.on_free_host = on_free_host
         self.enable_events = enable_events
         self._time = time_fn
@@ -417,6 +444,9 @@ class RadixTree:
                 if n is not self.root and n.disk_value is not None:
                     self.on_disk_detach(n.disk_value)
                     n.disk_value = None
+        if self.on_free_state is not None and getattr(self, "root", None) is not None:
+            for n in self._all_nodes():
+                self._drop_state(n)
         self.root = TreeNode()
         self.root.key = np.empty(0, dtype=np.int32)
         self.root.value = root_value
@@ -460,6 +490,9 @@ class RadixTree:
         disk_nodes: list[TreeNode] = []
         in_host = False  # device residency is prefix-closed; host extends it
         in_disk = False  # ... and durable disk extents extend the host chain
+        states: list[tuple[int, int]] = []
+        track = self.on_free_state is not None
+        pos = 0
         now = self._time()
         node.last_access_time = now
         while len(key) > 0:
@@ -520,6 +553,10 @@ class RadixTree:
             else:
                 values.append(child.value)
                 last_dev = child
+                if track:
+                    pos += m
+                    if child.state is not None:
+                        states.append((pos, child.state))
             node = child
             key = key[m:]
         return MatchResult(
@@ -529,6 +566,7 @@ class RadixTree:
             host_nodes=host_nodes,
             disk_values=disk_values,
             disk_nodes=disk_nodes,
+            states=states,
         )
 
     def insert(
@@ -566,6 +604,50 @@ class RadixTree:
         if len(key) == 0:
             return 0
         return self._insert_helper(self.root, key, value, on_conflict)
+
+    def attach_state(self, key: Sequence[int], slot: int) -> bool:
+        """Hand the tree state slot ``slot``, valid after exactly ``key``
+        (a page multiple of tokens, all of them device-resident in the
+        tree: the caller just inserted them). The node that ends there —
+        split off if ``key`` ends inside one — takes it and True is
+        returned; False, and the slot stays the caller's, if that node has
+        a state already or the tree does not hold ``key`` to its end."""
+        key = as_key(key)
+        node = self.root
+        while len(key) > 0:
+            child = node.children.get(self._child_key(key))
+            if child is None or child.value is None:
+                return False
+            m = self._match(child.key, key)
+            if m < len(key) and m < len(child.key) or m == 0:
+                return False
+            if m < len(child.key):
+                child = self._split_node(child, m)
+            node = child
+            key = key[m:]
+        if node is self.root or node.state is not None:
+            return False
+        node.state = slot
+        return True
+
+    def evict_states(self, n: int) -> int:
+        """Free the states of the ``n`` least recently touched unlocked
+        nodes that hold one — pressure on the state slots alone: the rows
+        stay, and a later match of them resumes from a shallower state.
+        Returns how many were freed."""
+        held = [x for x in self._all_nodes()
+                if x.state is not None and x.lock_ref == 0]
+        freed = 0
+        for node in heapq.nsmallest(n, held):
+            self._drop_state(node)
+            freed += 1
+        self.state_evictions += freed
+        return freed
+
+    def _drop_state(self, node: TreeNode) -> None:
+        if node.state is not None:
+            slot, node.state = node.state, None
+            self.on_free_state(slot)
 
     def peek_continuation(self, key: Sequence[int], max_tokens: int) -> np.ndarray:
         """Tokens the cache holds BEYOND ``key`` — the speculative drafter's
@@ -728,6 +810,8 @@ class RadixTree:
                 self.evictable_size_ -= len(n.key)
             if n.host_value is not None:
                 freed_host.append(n.host_value)
+            if n.state is not None:
+                self._drop_state(n)
             stack.extend(n.children.values())
             if n.disk_value is not None:
                 # The extent is unreachable once the node leaves the
@@ -968,7 +1052,9 @@ class RadixTree:
 
     def _split_node(self, node: TreeNode, split_len: int) -> TreeNode:
         """Split ``node`` so its first ``split_len`` tokens become a new
-        parent; returns the new parent (reference ``radix_cache.py:277-294``)."""
+        parent; returns the new parent (reference ``radix_cache.py:277-294``).
+        A state stays with the lower half: it is valid at the node's END,
+        and the upper half ends where no state was taken."""
         new_node = TreeNode(parent=node.parent)
         new_node.key = node.key[:split_len]
         new_node.value = None if node.value is None else node.value[:split_len]

@@ -96,6 +96,12 @@ def _pow2_at_least(n: int, floor: int = 8) -> int:
 # and attend_chunk_hybrid requires max_pages to divide by it — one
 # constant so the padding and the kernels can't drift apart.
 _KV_BLOCK_PAGES = 32
+# A model with conv layers: the states one row of a prefill launch may
+# leave (its working state at the row's end and two snapshots: the prompt's
+# page-aligned cut and the tree's match length), and how many of the tree's
+# snapshots one pressure on the state slots evicts.
+_STATE_SNAPS = 3
+_STATE_EVICT_BATCH = 64
 
 
 def prefill_launch_shape(
@@ -107,6 +113,7 @@ def prefill_launch_shape(
     page_size: int,
     max_pages: int,
     width_selects: bool = False,
+    one_width: bool = False,
 ) -> tuple[int, int, int]:
     """``(B, C, max_pages)`` of a chunk-prefill launch — the program key of
     ``prefill_chunk_paged`` — for ``rows`` requests of at most
@@ -121,11 +128,16 @@ def prefill_launch_shape(
     programs. Where the width itself chooses the program's attention
     (``width_selects``: learned sparse attention engages on it), and for
     a group, whose widths its callers warm by burst, the width is the
-    longest row's pages, rounded up to a power of two."""
+    longest row's pages, rounded up to a power of two. ``one_width``: a
+    group too takes the engine's own width — for a model whose chunk
+    program traces several kinds of layer (conv and attention, dense and
+    sparse FFN: ~30 s a compile at published widths), where contexts of
+    2 k-14 k tokens would otherwise multiply every (rows, chunk) pair by
+    three widths."""
     B = _pow2_at_least(rows, floor=1)
     C = _pow2_at_least(min(new_tokens, chunk), floor=16)
     pages = -(-total_tokens // page_size)
-    if rows == 1 and not width_selects:
+    if (rows == 1 or one_width) and not width_selects:
         pages = max_pages
     return B, C, _pow2_at_least(pages, floor=_KV_BLOCK_PAGES)
 
@@ -193,6 +205,10 @@ class _DecodeLaunch:
     # The ids to feed, where the step before left them on the device for
     # exactly these rows; None: upload ``Engine._tokens`` at the launch.
     tokens_d: jax.Array | None = None
+    # A model with conv layers: each row's working state slot and where
+    # the new state goes besides (its boundary slot when this token
+    # completes a page, else slot 0), [2, max_batch], on the device.
+    state_d: jax.Array | None = None
 
 
 @dataclass
@@ -280,6 +296,7 @@ class Engine:
             spec_decode_tokens=spec_decode_tokens,
             prefill_inline_budget=prefill_inline_budget,
             host_cache_slots=host_cache_slots, kv_tier_dir=kv_tier_dir,
+            mesh_cache=mesh, stream_publish_tokens=stream_publish_tokens,
         )
         self.cfg = cfg
         # Multi-chip serving (SURVEY §7 stage 7): tp shards heads/ffn/vocab
@@ -483,18 +500,28 @@ class Engine:
         # fetched only for a request that asked for them.
         self._last_expert_ids = None
         self._last_selected = None  # aux["index_positions"] of the last launch
-        kernels = default_use_kernel(cfg.head_dim)
+        # What the paged kernels tile is the pool row: a head, or two heads
+        # of 64 side by side (ModelConfig.pair_kv_heads).
+        kernel_width = cfg.kv_row[2] if cfg.pair_kv_heads else cfg.head_dim
+        kernels = default_use_kernel(kernel_width)
         self.chunk_attention = "paged" if kernels else "reference"
         # ``path`` of the decode dispatch record: chip_smoke.py proves
         # from it that the kernel served.
         self._decode_path = "paged" if kernels else "dense"
         self.log = get_logger("engine")
-        if jax.default_backend() == "tpu" and not kernel_shape_ok(cfg.head_dim):
+        if jax.default_backend() == "tpu" and not kernel_shape_ok(kernel_width):
             self.log.warning(
                 "head_dim=%d is not a multiple of 128: decode and chunked-"
                 "prefill attention take the jnp REFERENCE path on this TPU "
-                "(the Pallas paged kernels cannot tile it)",
+                "(the Pallas paged kernels cannot tile it; heads of 64 can "
+                "be laid two to a pool row: ModelConfig.pair_kv_heads)",
                 cfg.head_dim,
+            )
+        elif cfg.pair_kv_heads:
+            self.log.info(
+                "head_dim=64: two kv heads share a 128-lane pool row; the "
+                "paged kernels attend %d wide heads, no row is padded",
+                cfg.kv_row[1],
             )
         # Resolved early: the KV plane (below) and the metric labels
         # (further down) both key their series on it.
@@ -516,15 +543,18 @@ class Engine:
         # The cache row is the model's (K and V per kv head, or one
         # latent row): the pool is built from it.
         row_planes, row_heads, row_width = cfg.kv_row
+        pool_layers = len(cfg.attn_layers)  # the layers that own rows
         if pool is not None:
             expected = dict(
                 page_size=page_size,
-                num_layers=cfg.n_layers,
+                num_layers=pool_layers,
                 planes=row_planes,
                 num_kv_heads=row_heads,
                 head_dim=row_width,
                 quant=kv_quant,
                 index_shape=cfg.kv_index_shape(pool.num_slots),
+                state_shape=cfg.state_shape(
+                    cfg.default_state_slots(pool.num_slots, max_batch)),
             )
             if kv_quant is None:
                 expected["dtype"] = cfg.dtype
@@ -556,7 +586,7 @@ class Engine:
                     )
             self.pool = PagedKVPool(
                 num_slots=num_slots,
-                num_layers=cfg.n_layers,
+                num_layers=pool_layers,
                 num_kv_heads=row_heads,
                 head_dim=row_width,
                 page_size=page_size,
@@ -565,6 +595,10 @@ class Engine:
                 quant=kv_quant,
                 planes=row_planes,
                 index_shape=cfg.kv_index_shape(num_slots),
+                # Derived, not a knob: what the pool's size and the batch
+                # call for (ModelConfig.default_state_slots).
+                state_shape=cfg.state_shape(
+                    cfg.default_state_slots(num_slots, max_batch)),
             )
         if kernels and not cfg.latent:
             from radixmesh_tpu.ops.paged_attention import decode_block_tokens
@@ -623,7 +657,30 @@ class Engine:
                 self.pool, host_store, disk_tier=self._kv_tier
             )
         else:
-            self.tree = RadixTree(page_size=page_size, on_free=self.pool.free)
+            self.tree = RadixTree(
+                page_size=page_size, on_free=self.pool.free,
+                on_free_state=(
+                    None if self.pool.state is None else self.pool.free_state),
+            )
+        # state: a model whose layers carry a state beside the rows
+        # (None otherwise). kv_matched_tokens: what the tree's rows alone
+        # would have served; resumed_tokens: where the requests really
+        # resumed (the deepest snapshot at or before: ``cached_tokens``
+        # counts these); recomputed_tokens: the difference, computed
+        # again. snapshots: states handed to the tree; evictions: states
+        # the tree gave up under pressure on the slots alone.
+        self.state_stats = None
+        if self.pool.state is not None:
+            self.state_stats = {
+                "slots": self.pool.state.shape[1],
+                "slot_bytes": self.pool.state_slot_bytes,
+                "snapshots": 0,
+                "snapshots_declined": 0,
+                "snapshots_skipped": 0,
+                "kv_matched_tokens": 0,
+                "resumed_tokens": 0,
+                "recomputed_tokens": 0,
+            }
         self._kv_tier_watermark = float(kv_tier_watermark)
         self._kv_tier_min_heat = float(kv_tier_min_heat)
         self._kv_tier_destage_budget = int(kv_tier_destage_budget)
@@ -1030,6 +1087,7 @@ class Engine:
                 self._admitting.pop(i)
                 if own.size:
                     self.pool.free(own)
+                self._free_states(req)
                 if req.lock_node is not None:
                     self.tree.dec_lock_ref(req.lock_node)
                     req.lock_node = None
@@ -1888,15 +1946,121 @@ class Engine:
         reuse = min(
             match.length, (len(prompt) - 1) // self.page_size * self.page_size
         )
+        matched = reuse
+        if self.state_stats is not None:
+            # The rows alone serve no prefix: the request resumes at the
+            # deepest snapshot at or before them, and computes from there.
+            reuse, resume = match.state_at_or_before(matched)
         prefix_slots = match.indices()[:reuse]
         self.tree.inc_lock_ref(match.last_node)
         req.lock_node = match.last_node
         own = self._alloc_pages(-(-(len(prompt) - reuse) // self.page_size))
+        if own is not None and self.state_stats is not None:
+            with self.spans.span("rm.state.restore"):
+                if not self._acquire_states(req, match, matched, reuse, resume):
+                    self.pool.free(own)
+                    own = None
         if own is None:
             self.tree.dec_lock_ref(req.lock_node)
             req.lock_node = None
             return None
         return reuse, prefix_slots, own
+
+    # ------------------------------------------------------------------
+    # state slots (a model whose layers carry a state beside the rows)
+    # ------------------------------------------------------------------
+
+    def _alloc_state(self) -> int:
+        """A state slot, evicting the tree's least recently used
+        snapshots when none is free (the rows stay); 0: none to be had."""
+        slot = self.pool.alloc_state()
+        if slot is None and self.tree.evict_states(_STATE_EVICT_BATCH):
+            slot = self.pool.alloc_state()
+        return slot or 0
+
+    def _acquire_states(
+        self, req: Request, match, matched: int, reuse: int, resume: int | None
+    ) -> bool:
+        """The slots a request's life needs — its working slot and its
+        boundary slot (False, nothing held, if the pool has neither) — and
+        one for each snapshot its prefill will leave for the tree: (a) at
+        the page-aligned cut of its prompt, where ``_publish`` will cut
+        the key, and (b) at ``matched``, what the tree's rows would have
+        served, when it resumes below that — the second visitor of a
+        branch point pays the recompute, every later one resumes there. A
+        snapshot the pool has no slot for is skipped: a later match
+        resumes shallower."""
+        ps = self.page_size
+        work = self._alloc_state()
+        keep = self._alloc_state() if work else 0
+        if not keep:
+            if work:
+                self.pool.free_state(work)
+            return False
+        req.state_slot, req.state_boundary = work, keep
+        req.state_boundary_len = 0
+        req.state_resume = resume or 0
+        have = {pos for pos, _ in match.states}
+        req.state_snaps = []
+        for pos in sorted({len(req.prompt) // ps * ps, matched}):
+            if pos > reuse and pos not in have:
+                slot = self._alloc_state()
+                if slot:
+                    req.state_snaps.append((pos, slot))
+                else:
+                    self.state_stats["snapshots_skipped"] += 1
+        st = self.state_stats
+        st["kv_matched_tokens"] += matched
+        st["resumed_tokens"] += reuse
+        st["recomputed_tokens"] += matched - reuse
+        return True
+
+    def _free_states(self, req: Request) -> None:
+        """Return every state slot ``req`` still holds."""
+        for slot in (req.state_slot, req.state_boundary,
+                     *(s for _, s in req.state_snaps)):
+            if slot:
+                self.pool.free_state(slot)
+        req.state_slot = req.state_boundary = req.state_resume = 0
+        req.state_boundary_len = 0
+        req.state_snaps = []
+
+    def _hand_over_states(self, req: Request, key: np.ndarray) -> None:
+        """Give the tree every snapshot of ``req`` that lies on the key
+        just published (a node takes it, or has one and declines)."""
+        keep = []
+        for pos, slot in req.state_snaps:
+            if pos > len(key):
+                keep.append((pos, slot))
+            elif self.tree.attach_state(key[:pos], slot):
+                self.state_stats["snapshots"] += 1
+            else:
+                self.pool.free_state(slot)
+                self.state_stats["snapshots_declined"] += 1
+        req.state_snaps = keep
+
+    def _chunk_states(self, group, starts, nv, B: int, first: bool):
+        """``(state_in [B], snap_at [B, 3], state_out [B, 3])`` of one
+        chunk of a prefill group: each live row resumes from its working
+        slot (its first chunk: from the tree's snapshot) and leaves its
+        state at the chunk's true end there, and wherever one of its
+        snapshots falls inside the chunk, in that snapshot's slot. What
+        is not wanted goes to slot 0."""
+        state_in = np.zeros((B,), np.int32)
+        snap_at = np.zeros((B, _STATE_SNAPS), np.int32)
+        state_out = np.zeros((B, _STATE_SNAPS), np.int32)
+        for i, (req, *_) in enumerate(group):
+            if nv[i] <= 0:
+                continue
+            state_in[i] = req.state_resume if first else req.state_slot
+            snap_at[i, 0], state_out[i, 0] = nv[i], req.state_slot
+            inside = [(pos - starts[i], slot) for pos, slot in req.state_snaps
+                      if starts[i] < pos <= starts[i] + nv[i]]
+            for j, (at, slot) in enumerate(inside, start=1):
+                snap_at[i, j], state_out[i, j] = at, slot
+        return {"state_in": jnp.asarray(state_in),
+                "snap_at": jnp.asarray(snap_at),
+                "state_out": jnp.asarray(state_out)}
 
     def _install_running(self, req: Request, row: int, reuse: int) -> None:
         """Shared tail of admission when the first token is ALREADY known
@@ -2139,6 +2303,8 @@ class Engine:
         kw = self._moe_kw()
         if self.pool.index is not None:
             kw["index_pool"] = self.pool.index
+        if self.pool.state is not None:
+            kw["state_pool"] = self.pool.state
         return kw
 
     def _note_sparse(self, site: str, ctx: np.ndarray, engaged: bool) -> None:
@@ -2171,10 +2337,16 @@ class Engine:
         _, heads, width = self.cfg.kv_row
         # No cached rows: an empty buffer stands for both planes.
         no_prefix = jnp.zeros(
-            (self.cfg.n_layers, 1, 0, heads, width), dtype=self.cfg.dtype)
+            (len(self.cfg.attn_layers), 1, 0, heads, width),
+            dtype=self.cfg.dtype)
         moe_kw = self._moe_kw()
         if moe_kw:  # keep the bucket's padding out of the counters
             moe_kw["new_lengths"] = jnp.full((1,), n_new, dtype=jnp.int32)
+        if self.state_stats is not None:
+            # The row's state at its true end, and at each snapshot.
+            wanted = [(n_new, req.state_slot), *req.state_snaps]
+            wanted += [(0, 0)] * (_STATE_SNAPS - len(wanted))
+            moe_kw["snap_at"] = jnp.asarray([[at for at, _ in wanted]], jnp.int32)
         logits, new_k, new_v, *aux = prefill_forward(
             self.params,
             self.cfg,
@@ -2191,6 +2363,10 @@ class Engine:
             self._note_sparse(
                 "prefill", 1 + np.arange(n_new, dtype=np.int64),
                 s_b > self.cfg.index_topk)
+        if self.state_stats is not None:
+            states, *aux = aux
+            self.pool.write_state(
+                np.asarray([slot for _, slot in wanted], np.int32), states[:, 0])
         if aux:
             self._moe_stats = aux[0]["moe_stats"]
             self._last_expert_ids = aux[0]["expert_ids"]
@@ -2216,7 +2392,8 @@ class Engine:
         return pp if batch % pp == 0 else 1
 
     def _forward_chunk(
-        self, toks, poss, sl, pt, kvlen, kv_block: int, logits_at=None
+        self, toks, poss, sl, pt, kvlen, kv_block: int, logits_at=None,
+        states: dict | None = None,
     ):
         """One chunk forward through the right backend: the pipeline
         schedule under pp, ``prefill_chunk_paged`` otherwise. Shared by
@@ -2264,6 +2441,7 @@ class Engine:
             mesh=self.device_mesh,
             logits_at=logits_at,
             **self._pool_kw(),
+            **(states or {}),
         )
 
     def _sp_capable(self, member: tuple) -> bool:
@@ -2340,7 +2518,7 @@ class Engine:
         B, C, maxp = prefill_launch_shape(
             N, int(new.max()), int(totals.max()), chunk=self.prefill_chunk,
             page_size=ps, max_pages=self.max_pages,
-            width_selects=self.cfg.dsa,
+            width_selects=self.cfg.dsa, one_width=self.cfg.has_conv,
         )
         n_chunks = -(-int(new.max()) // C)
         span = n_chunks * C
@@ -2385,6 +2563,9 @@ class Engine:
                     maxp * ps + C > self.cfg.index_topk,
                 )
             self.pool.note_runs(sl[:N, 0], nv)
+            states = None
+            if self.state_stats is not None:
+                states = self._chunk_states(group, starts, nv, B, ci == 0)
             res = self._forward_chunk(
                 jnp.asarray(toks_all[:, ci * C : (ci + 1) * C]),
                 jnp.asarray(poss),
@@ -2393,6 +2574,7 @@ class Engine:
                 jnp.asarray(kvlen),
                 kv_block,
                 logits_at=jnp.asarray(at),
+                states=states,
             )
             logits = self._commit_pool_update(res)
             for i in np.flatnonzero(last).tolist():
@@ -2465,6 +2647,9 @@ class Engine:
                 if req.lock_node is not None:
                     self.tree.dec_lock_ref(req.lock_node)
                 req.lock_node = new_lock
+            if req.state_snaps:
+                with self.spans.span("rm.state.snapshot"):
+                    self._hand_over_states(req, key[:aligned])
             if self.mesh is not None and aligned > 0:
                 # Advertise the (canonical) published prefix around the ring
                 # (radix_mesh.py:193-201). Only the page-ALIGNED prefix: the
@@ -2492,7 +2677,14 @@ class Engine:
     def _release(self, req: Request) -> None:
         """cache_finished_req (radix_cache.py:439-486): publish the full
         sequence, free unpublished residue, release the lock, free the row."""
+        if req.state_boundary_len:
+            # The key is cut at the last page boundary: a decode step
+            # completed that page and kept its state for exactly this.
+            req.state_snaps.append((req.state_boundary_len, req.state_boundary))
+            req.state_boundary = 0
         self._publish(req, req.kv_len)
+        if self.state_stats is not None:
+            self._free_states(req)
         if req.own_slots.size:
             self.pool.free(req.own_slots)
             req.own_slots = np.empty(0, dtype=np.int32)
@@ -2525,6 +2717,8 @@ class Engine:
             out, self.pool.kv, self.pool.kv_scale = res
         elif self.pool.index is not None:
             out, self.pool.kv, self.pool.index = res
+        elif self.pool.state is not None:
+            out, self.pool.kv, self.pool.state = res
         else:
             out, self.pool.kv = res
         return out
@@ -2641,6 +2835,15 @@ class Engine:
                 # draw.
                 self.pool.note_runs(
                     launch.slots[launch.idx], np.ones(len(launch.idx), np.int32))
+                states = None
+                if launch.state_d is not None:
+                    # One token a row: from the working slot, back to it
+                    # and to the boundary slot (or slot 0).
+                    states = {
+                        "state_in": launch.state_d[0],
+                        "snap_at": jnp.ones((self.max_batch, 2), jnp.int32),
+                        "state_out": launch.state_d.T,
+                    }
                 res = self._forward_chunk(
                     tokens_d[:, None],
                     launch.positions_d[:, None],
@@ -2648,6 +2851,7 @@ class Engine:
                     launch.pt_d,
                     launch.lengths_d,
                     _KV_BLOCK_PAGES,
+                    states=states,
                 )
                 logits = self._commit_pool_update(res)[:, 0]
             else:
@@ -2663,6 +2867,8 @@ class Engine:
                     mesh=self.device_mesh,
                     kv_scale=self.pool.kv_scale,
                     **self._pool_kw(),
+                    **({} if launch.state_d is None else {
+                        "state_slots": launch.state_d}),
                 )
                 logits = self._commit_pool_update(res)
         asked = [(row, r) for row, r in active if r.sampling.return_logits]
@@ -2802,6 +3008,8 @@ class Engine:
         ps = self.page_size
         slots = np.full(self.max_batch, self._scratch_slot, dtype=np.int32)
         lengths = np.ones(self.max_batch, dtype=np.int32)
+        stateful = self.state_stats is not None
+        state_slots = np.zeros((2, self.max_batch), dtype=np.int32)
         starved: list[Request] = []
         for row, req in enumerate(self._rows):
             if req is None:
@@ -2815,6 +3023,12 @@ class Engine:
                 continue
             slots[row] = page * ps + offset
             lengths[row] = req.kv_len + ahead + 1
+            if stateful:
+                # The new state goes back to the working slot, and is kept
+                # in the boundary slot when this token completes a page.
+                state_slots[0, row] = req.state_slot
+                if lengths[row] % ps == 0:
+                    state_slots[1, row] = req.state_boundary
         for req in starved:
             self._preempt(req)
         active = self._live_rows()
@@ -2849,6 +3063,7 @@ class Engine:
             ),
             lengths_d=jnp.asarray(lengths),
             positions_d=jnp.asarray(lengths - 1) if chunked else None,
+            state_d=jnp.asarray(state_slots) if stateful else None,
         )
 
     def _take_prebuilt(self, active: list[tuple]) -> _DecodeLaunch | None:
@@ -3392,6 +3607,9 @@ class Engine:
         ``Request.__setattr__``."""
         req.token_slots = np.append(req.token_slots, slot)
         req.kv_len += 1
+        if req.state_boundary and req.kv_len % self.page_size == 0:
+            # The step completed a page: its state is in the boundary slot.
+            req.state_boundary_len = req.kv_len
         req.output_tokens.append(token)
         self.stats.generated_tokens += 1
         finished = (

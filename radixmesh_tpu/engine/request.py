@@ -85,6 +85,18 @@ class Request:
         default_factory=lambda: np.empty(0, dtype=np.int32)
     )
     lock_node: object = None  # TreeNode protected while RUNNING
+    # A model whose layers carry a state beside the rows (state slots of
+    # ``cache/kv_pool.py``; 0 = none, slot 0 is nobody's): the row's
+    # working slot, the slot that keeps the state as of the last page
+    # boundary a decode step completed (``state_boundary_len``: where; 0 =
+    # none yet), the slot prefill resumes from (the tree's, borrowed), and
+    # the snapshots a prefill wrote for the tree to take at the next
+    # publish, ``[(position, slot)]``.
+    state_slot: int = 0
+    state_boundary: int = 0
+    state_boundary_len: int = 0
+    state_resume: int = 0
+    state_snaps: list = field(default_factory=list)
     cancelled: bool = False  # aborted by Engine.cancel (output is partial)
     # Set when the engine step serving this request raised: the message
     # the runner fails the request with (server/http_frontend.py).

@@ -6,7 +6,9 @@ from radixmesh_tpu.models.llama import (
     param_logical_axes,
     convert_hf_state_dict,
 )
-from radixmesh_tpu.models import glm4_moe_lite, glm_moe_dsa, mellum2, qwen2
+from radixmesh_tpu.models import (
+    glm4_moe_lite, glm_moe_dsa, lfm2_moe, mellum2, qwen2,
+)
 
 _PRESETS = {
     "llama3-8b": ModelConfig.llama3_8b,
@@ -30,6 +32,9 @@ _PRESETS = {
     "glm-5.2": glm_moe_dsa.glm52,
     "glm-5.2-ep16": glm_moe_dsa.glm52_ep16,
     "glm-5.2-tiny": glm_moe_dsa.glm52_tiny,
+    "lfm2-24b-a2b": lfm2_moe.lfm2_24b_a2b,
+    "lfm2-24b-a2b-ep8": lfm2_moe.lfm2_24b_a2b_ep8,
+    "lfm2-tiny": lfm2_moe.lfm2_tiny,
 }
 
 

@@ -176,23 +176,73 @@ class ModelConfig:
     # (``ops/moe.py``); on one chip there is no exchange.
     ep_rank: int = 0
     ep_size: int = 1
+    # Gated short-convolution layers (``layer_types`` entry "conv", beside
+    # "full_attention"): such a layer leaves NO cache row; it carries the
+    # last ``conv_L_cache - 1`` positions of its gated input, ``hidden``
+    # wide, whatever the context length (``state_shape``). Its leaves are
+    # a stack of their own (``params["conv"]``), the attention layers'
+    # another (``params["attn"]``), and the pool's layer axis counts the
+    # attention layers only.
+    conv_L_cache: int = 0
+    # RMSNorm over each q and each k head (a learned [head_dim] weight)
+    # before the rotary.
+    qk_norm: bool = False
+    # Two kv heads of 64 side by side in one 128-lane pool row: the pool
+    # holds ``n_kv_heads / 2`` rows of 128 a token, plane and layer (no
+    # padding: a 64-wide row is stored 128 wide by the device's tiling),
+    # and the paged kernels see that many "wide" kv heads (``_pair_heads``).
+    pair_kv_heads: bool = False
 
     def __post_init__(self):
         if self.layer_types is not None:
             kinds = set(self.layer_types)
-            if len(self.layer_types) != self.n_layers or not kinds <= {
-                "sliding_attention", "full_attention"
-            }:
+            if len(self.layer_types) != self.n_layers or not (
+                kinds <= {"sliding_attention", "full_attention"}
+                or kinds <= {"conv", "full_attention"}
+            ):
                 raise ValueError(
                     f"layer_types must name {self.n_layers} layers as "
-                    f"sliding_attention or full_attention, got "
+                    f"sliding_attention or full_attention, or as conv or "
+                    f"full_attention, got "
                     f"{len(self.layer_types)} of {sorted(kinds)}"
                 )
             if "sliding_attention" in kinds and self.sliding_window < 2:
                 raise ValueError("sliding layers need sliding_window >= 2")
             missing = kinds - {t for t, _ in self.rope_by_type or ()}
-            if missing:
+            if missing and "conv" not in kinds:
                 raise ValueError(f"rope_by_type lacks {sorted(missing)}")
+        if self.has_conv:
+            taps = self.conv_L_cache
+            if taps < 2 or ((taps - 1) * self.hidden) % 128:
+                raise ValueError(
+                    f"conv layers need conv_L_cache >= 2 and (conv_L_cache - "
+                    f"1) * hidden in whole 128-lane rows, got conv_L_cache="
+                    f"{taps}, hidden={self.hidden}"
+                )
+            if "full_attention" not in self.layer_types:
+                raise ValueError(
+                    "layer_types names no full_attention layer: the paged "
+                    "pool and the radix tree need one layer that owns rows"
+                )
+            if self.latent or self.qkv_bias or self.rope_by_type:
+                raise ValueError(
+                    "conv layers go with plain GQA attention layers under "
+                    "one RoPE: no latent attention, qkv_bias or rope_by_type"
+                )
+        elif self.conv_L_cache:
+            raise ValueError("conv_L_cache belongs to a config with conv layers")
+        if self.pair_kv_heads and (
+            self.latent or self.head_dim != 64 or self.n_kv_heads % 2
+        ):
+            raise ValueError(
+                "pair_kv_heads lays two kv heads of 64 in one 128-lane row: "
+                "it needs head_dim == 64, an even n_kv_heads and no latent "
+                "attention"
+            )
+        if self.qk_norm and not self.has_conv:
+            raise ValueError(
+                "qk_norm's weights live in the attention stack of a config "
+                "with conv layers (params[\"attn\"]): no other config has them")
         if self.n_experts and not (
             0 < self.n_experts_per_tok <= self.n_experts
             and self.moe_intermediate > 0
@@ -251,6 +301,24 @@ class ModelConfig:
         return bool(self.layer_types) and "sliding_attention" in self.layer_types
 
     @property
+    def has_conv(self) -> bool:
+        return bool(self.layer_types) and "conv" in self.layer_types
+
+    @property
+    def conv_layers(self) -> tuple[int, ...]:
+        """The gated short-convolution layers (each carries a state)."""
+        return tuple(i for i, t in enumerate(self.layer_types or ())
+                     if t == "conv")
+
+    @property
+    def attn_layers(self) -> tuple[int, ...]:
+        """The layers that own cache rows: all of them, or, beside conv
+        layers, the attention layers alone."""
+        if not self.has_conv:
+            return tuple(range(self.n_layers))
+        return tuple(i for i, t in enumerate(self.layer_types) if t != "conv")
+
+    @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
 
@@ -285,14 +353,41 @@ class ModelConfig:
             # DMA what is aligned to the tiling.
             elements = self.kv_lora_rank + self.qk_rope_head_dim
             return (1, 1, -(-elements // 128) * 128)
+        if self.pair_kv_heads:
+            return (2, self.n_kv_heads // 2, 2 * self.head_dim)
         return (2, self.n_kv_heads, self.head_dim)
 
     def kv_pool_shape(self, num_slots: int) -> tuple[int, int, int, int, int]:
         """The paged pool of this config: ``[planes, layers, heads, slots,
         width]`` (``cache/kv_pool.py`` allocates it, the entry points below
-        view it as pages)."""
+        view it as pages); ``layers`` counts the layers that own rows
+        (``attn_layers``)."""
         planes, heads, width = self.kv_row
-        return (planes, self.n_layers, heads, num_slots, width)
+        return (planes, len(self.attn_layers), heads, num_slots, width)
+
+    def state_shape(self, num_states: int) -> tuple[int, int, int, int] | None:
+        """The state slots beside the pool, ``[conv layers, slots, rows,
+        128]``: one slot holds, for every conv layer, the ``conv_L_cache -
+        1`` positions x ``hidden`` channels the layer carries, laid in
+        whole 128-lane rows (a ``[2, hidden]`` minor pair would be stored
+        16 rows tall by the device's tiling). Addressed by an allocator of
+        its own (``cache/kv_pool.py``): the unit is a snapshot, not a
+        token. None for a config without conv layers."""
+        if not self.has_conv:
+            return None
+        rows = (self.conv_L_cache - 1) * self.hidden // 128
+        return (len(self.conv_layers), num_states, rows, 128)
+
+    def default_state_slots(self, num_slots: int, max_batch: int) -> int:
+        """How many state slots go with a pool of ``num_slots`` rows: a
+        working and a boundary slot for every batch row, the scratch slot,
+        and a snapshot for every 100 rows of the pool (a turn of an agent
+        session leaves two snapshots and some 200-700 rows), in whole 256s.
+        0 for a config without conv layers."""
+        if not self.has_conv:
+            return 0
+        want = 1 + 2 * max_batch + num_slots // 100
+        return -(-want // 256) * 256
 
     def kv_index_shape(self, num_slots: int) -> tuple[int, int, int] | None:
         """The index-key plane beside the pool, ``[full layers, slots,
@@ -468,6 +563,36 @@ def _draw_stack(keys, n: int, dims: dict, cfg: ModelConfig, quant_axis) -> dict:
     return stack
 
 
+def _draw_operators(key, cfg: ModelConfig) -> dict:
+    """The operators' leaves of a config with conv layers, a stack a kind:
+    ``conv`` — ``in_proj`` [H, 3H] (the gates B, C and the input x, in
+    that order), the depthwise kernel ``conv_w`` [taps, H] (tap 0 the
+    oldest position) and ``out_proj`` [H, H] — and ``attn``: the four
+    attention matrices and the per-head norms' weights (seeded draws
+    around 1, so that they are exercised)."""
+    H, D = cfg.hidden, cfg.head_dim
+    n_conv, n_attn = len(cfg.conv_layers), len(cfg.attn_layers)
+    kc = jax.random.split(key, 8)
+    conv = {}
+    for k, (name, (i_dim, o_dim)) in zip(kc, {
+        "in_proj": (H, 3 * H), "out_proj": (H, H),
+    }.items()):
+        conv[name], _ = _draw_blocks(k, n_conv, (i_dim, o_dim), i_dim,
+                                     cfg.dtype, None)
+    conv["conv_w"], _ = _draw_blocks(
+        kc[2], n_conv, (cfg.conv_L_cache, H), cfg.conv_L_cache, cfg.dtype, None)
+    attn = {}
+    for k, (name, (i_dim, o_dim)) in zip(kc[3:], _attn_dims(cfg).items()):
+        attn[name], _ = _draw_blocks(k, n_attn, (i_dim, o_dim), i_dim,
+                                     cfg.dtype, None)
+    if cfg.qk_norm:
+        for k, name in zip(jax.random.split(kc[7]), ("q_head_norm", "k_head_norm")):
+            attn[name] = (
+                1.0 + 0.1 * jax.random.normal(k, (n_attn, D), jnp.float32)
+            ).astype(cfg.dtype)
+    return {"conv": conv, "attn": attn}
+
+
 @partial(jax.jit, static_argnames=("cfg", "weight_quant", "mesh"))
 def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
     # Contraction axis of a block to quantize along, or None for float.
@@ -479,7 +604,10 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
     L = cfg.n_layers - cfg.first_k_dense  # layers of the main stack
     ffn_width = cfg.moe_intermediate if cfg.n_experts else cfg.intermediate
     ffn = _ffn_dims(H, ffn_width)
-    dims = _attn_dims(cfg)
+    # Beside conv layers the operators' leaves are stacks of their own
+    # (below): the per-layer stacks keep the norms and the FFN.
+    op_dims = {} if cfg.has_conv else _attn_dims(cfg)
+    dims = dict(op_dims)
     dims |= {"w_router": (H, cfg.n_experts)} if cfg.n_experts else ffn
     layers = _draw_stack(keys, L, dims, cfg, axis(0))
     if cfg.n_experts:
@@ -539,8 +667,10 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         params["dense_layers"] = _draw_stack(
             jax.random.split(jax.random.fold_in(key, 102), 8),
             cfg.first_k_dense,
-            _attn_dims(cfg) | _ffn_dims(H, cfg.intermediate), cfg, axis(0),
+            op_dims | _ffn_dims(H, cfg.intermediate), cfg, axis(0),
         )
+    if cfg.has_conv:
+        params |= _draw_operators(jax.random.fold_in(key, 105), cfg)
     qd = cfg.n_heads * cfg.head_dim
     n_blk = _vocab_blocks(V, H, H * max(ffn_width, qd))
     # [V, H] in row blocks; per-ROW scales serve the gather and the tied
@@ -728,11 +858,12 @@ def _qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig):
         k = k + lp["bk"]
         v = v + lp["bv"]
     B, S = x.shape[:2]
-    return (
-        q.reshape(B, S, cfg.n_heads, cfg.head_dim),
-        k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim),
-        v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim),
-    )
+    q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:  # over each head, before the rotary
+        q = rms_norm(q, lp["q_head_norm"], cfg.rms_eps)
+        k = rms_norm(k, lp["k_head_norm"], cfg.rms_eps)
+    return q, k, v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
 
 
 def _latent_split(lp: dict, cfg: ModelConfig):
@@ -839,6 +970,68 @@ def _mlp(lp: dict, x: jnp.ndarray, prefix: str = "w_") -> jnp.ndarray:
     return _wmm(lp, prefix + "down", "bsi,ih->bsh", gate * up)
 
 
+def _pair_heads(cfg: ModelConfig, q, k, v):
+    """``(q, k, v)`` of ``[B, S, heads, 64]`` as the paged kernels take
+    them under ``pair_kv_heads``: kv heads ``2j`` and ``2j + 1`` side by
+    side as wide head ``j`` ``[B, S, Hkv / 2, 128]`` (a reshape: what the
+    pool stores), and every query ``[B, S, Hq, 128]`` zero in its
+    partner's 64 lanes — its scores against the wide key are its scores
+    against its own key, exactly, and its own 64 lanes of the output
+    (``_own_lanes``) are its attention over its own values. The logits'
+    factor stays ``head_dim ** -0.5``: it is passed, not derived from the
+    row's width."""
+    B, S, Hq, D = q.shape
+    wide, g = cfg.n_kv_heads // 2, Hq // cfg.n_kv_heads
+    own = jnp.eye(2, dtype=q.dtype)[:, None, :, None]  # [half, 1, lanes' half, 1]
+    q = (q.reshape(B, S, wide, 2, g, 1, D) * own).reshape(B, S, Hq, 2 * D)
+    return q, k.reshape(B, S, wide, 2 * D), v.reshape(B, S, wide, 2 * D)
+
+
+def _own_lanes(cfg: ModelConfig, out: jnp.ndarray) -> jnp.ndarray:
+    """``_pair_heads``' output ``[B, S, Hq, 128]`` cut to each query
+    head's own 64 lanes: ``[B, S, Hq, 64]``."""
+    B, S, Hq, W = out.shape
+    wide, g, D = cfg.n_kv_heads // 2, Hq // cfg.n_kv_heads, W // 2
+    o = out.reshape(B, S, wide, 2, g, 2, D)
+    return jnp.stack([o[:, :, :, 0, :, 0], o[:, :, :, 1, :, 1]], axis=3).reshape(
+        B, S, Hq, D)
+
+
+def _state_rows(cfg: ModelConfig, slots_state: jnp.ndarray) -> jnp.ndarray:
+    """State slots' content ``[..., rows, 128]`` as positions x channels
+    ``[..., conv_L_cache - 1, hidden]``."""
+    return slots_state.reshape(
+        *slots_state.shape[:-2], cfg.conv_L_cache - 1, cfg.hidden)
+
+
+def _short_conv(lp: dict, h: jnp.ndarray, state: jnp.ndarray, cfg: ModelConfig,
+                snap_at: jnp.ndarray | None) -> tuple:
+    """The gated short convolution on ``h`` [B, S, H] from ``state`` [B,
+    taps - 1, H] (the gated input ``z`` of the positions before the first:
+    zeros at position 0): ``[Bg, Cg, x] = h W_in``; ``z = Bg * x``; ``c_t
+    = sum_k w[k] * z_{t - (taps - 1) + k}`` (depthwise, causal); the
+    operator's output ``(Cg * c) W_out``. Returns it with the state after
+    ``snap_at`` [B, K] tokens of these ``S`` each (``[B, K, taps - 1,
+    H]``; 0 hands back ``state``): an index a row, so a padded row's
+    state is exact at its true end, and a snapshot inside a chunk is an
+    input, not a shape. ``snap_at`` None: the one state after all ``S``
+    (a decode step's: a slice, no gather)."""
+    taps, S = cfg.conv_L_cache, h.shape[1]
+    with jax.named_scope("rm_shortconv"):
+        gates = _wmm(lp, "in_proj", "bsh,hd->bsd", h)
+        b_gate, c_gate, x_in = jnp.split(gates, 3, axis=-1)
+        z = b_gate * x_in
+        zs = jnp.concatenate([state.astype(z.dtype), z], axis=1)  # [B, S+taps-1, H]
+        w = lp["conv_w"].astype(jnp.float32)
+        c = sum(w[k] * zs[:, k:k + S].astype(jnp.float32) for k in range(taps))
+        y = _wmm(lp, "out_proj", "bsh,hd->bsd", c_gate * c.astype(z.dtype))
+        if snap_at is None:
+            return y, zs[:, None, S:]
+        at = snap_at[:, :, None] + jnp.arange(taps - 1)  # [B, K, taps-1]
+        states = jax.vmap(lambda rows, ix: rows[ix])(zs, at)
+    return y, states
+
+
 class UnsupportedForModel(ValueError):
     """A serving option that this model's layers cannot run under."""
 
@@ -852,7 +1045,7 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
     refused here by name, so that none of them serves such a model with a
     dense layer's arithmetic. ``options`` are ``Engine``'s keyword names
     (a falsy value is "not asked for")."""
-    if not (cfg.n_experts or cfg.has_window or cfg.latent):
+    if not (cfg.n_experts or cfg.has_window or cfg.latent or cfg.has_conv):
         return None
     mesh = options.get("device_mesh")
     named = {
@@ -865,6 +1058,15 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
         "disaggregated": "disaggregated prefill/decode (engine/disagg.py)",
         "hf_checkpoint": "loading a HuggingFace checkpoint (--weights)",
     }
+    if cfg.has_conv:
+        # A prefix is handed back only where its state was snapshotted:
+        # whatever moves or advertises a prefix without its state.
+        named |= {
+            "mesh_cache": "a prefix ring (cache/mesh_cache.py: an advertised "
+                          "prefix would promise a hit the state cannot serve)",
+            "stream_publish_tokens": "mid-decode publishing "
+                                     "(--stream-publish-tokens)",
+        }
     asked = [text for key, text in named.items() if options.get(key)]
     if mesh is not None and any(n > 1 for n in dict(mesh.shape).values()):
         asked.append(f"a device mesh {dict(mesh.shape)} (tp / sp / pp)")
@@ -875,7 +1077,9 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
                         ("an expert share", cfg.ep_size > 1),
                         ("window attention", cfg.has_window),
                         ("a latent (MLA) cache row", cfg.latent),
-                        ("learned sparse attention", cfg.dsa)) if on
+                        ("learned sparse attention", cfg.dsa),
+                        ("gated-convolution layers with a recurrent state",
+                         cfg.has_conv)) if on
     )
     return (
         f"a model with {kinds} does not support " + "; ".join(asked)
@@ -905,7 +1109,7 @@ def _layer_tables(cfg: ModelConfig) -> dict | None:
             "dsa_full": jnp.asarray(full),
             "dsa_slot": jnp.asarray(np.cumsum(full) - 1, jnp.int32),
         }
-    if cfg.layer_types is None:
+    if cfg.layer_types is None or cfg.has_conv:
         return tables or None
     by_type = {}
     for kind, pairs in cfg.rope_by_type:
@@ -1010,6 +1214,8 @@ def _scan_layers(cfg: ModelConfig, params: dict, layer, carry):
     the rest. Returns ``(carry, ys)``; ``ys`` of two scans are joined
     along the layer axis, but for what only the sparse layers give (the
     dense scan's None)."""
+    if cfg.has_conv:
+        return _scan_mixed(cfg, params, layer, carry)
     kd = cfg.first_k_dense
     stacks = [(0, params["dense_layers"], False)] if kd else []
     stacks.append((kd, params["layers"], bool(cfg.n_experts)))
@@ -1027,6 +1233,102 @@ def _scan_layers(cfg: ModelConfig, params: dict, layer, carry):
                 lambda a, b: b if a is None else jnp.concatenate([a, b]),
                 joined, ys, is_leaf=lambda a: a is None,
             )
+    return carry, joined
+
+
+def _mixed_runs(cfg: ModelConfig) -> list[tuple[int, int, int]]:
+    """A config with conv layers as runs ``(first layer, layers a unit,
+    units)``: the leading dense-FFN layers (one unit), then the smallest
+    period of operator kinds that the rest repeats (its FFNs are of one
+    kind), as many whole units as fit, and what is left over (one unit). A run of one
+    unit is traced layer by layer; a run of several is ONE traced unit
+    under ``lax.scan`` — compile time stays flat in depth."""
+    kd = cfg.first_k_dense
+    body = cfg.layer_types[kd:]
+    period = next(
+        p for p in range(1, len(body) + 1)
+        if all(body[i] == body[i % p] for i in range(len(body)))
+    )
+    units = len(body) // period
+    if units < 2:
+        period, units = len(body), 1
+    runs = [(0, kd, 1), (kd, period, units),
+            (kd + period * units, len(body) - period * units, 1)]
+    return [r for r in runs if r[1]]
+
+
+def _scan_mixed(cfg: ModelConfig, params: dict, layer, carry):
+    """``_scan_layers`` for a config whose operators differ in kind (conv
+    and attention: leaves of other shapes, in stacks of their own).
+    ``layer(carry, xs, sparse, kind)`` gets ``xs = (layer index, the
+    layer's leaves — its norms and FFN from its per-layer stack, its
+    operator's from the stack of its kind — and a table row {"op_idx":
+    the layer's place in its kind's stack})`` and returns ``(carry, ys)``
+    with ``ys`` a dict whose entries are None where the layer has nothing
+    to give; the result's entries stack what the layers that gave
+    something gave, in layer order. Every stack is indexed where it lies
+    (no slice of a stack is built: the conv stack is a gigabyte)."""
+    kd = cfg.first_k_dense
+    is_conv = np.asarray([t == "conv" for t in cfg.layer_types])
+    # Each layer's place in its kind's stack.
+    place = np.where(is_conv, np.cumsum(is_conv) - 1, np.cumsum(~is_conv) - 1)
+    per_layer = _scanned_layers(params["layers"])
+
+    def at(stack, i):
+        return jax.tree_util.tree_map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, i, 0, keepdims=False)
+            if isinstance(i, jax.Array) else w[i], stack)
+
+    def unit(carry, first, unit_idx, n, stride):
+        """Layers ``first + unit_idx * n + [0, n)``; ``stride`` maps a kind
+        to its layers a unit (``unit_idx`` is traced under the scan)."""
+        ys = []
+        for j in range(n):
+            l0 = first + j  # the layer of unit 0: its kind is every unit's
+            kind = "conv" if is_conv[l0] else "attn"
+            l_idx = l0 + unit_idx * n
+            op_idx = int(place[l0]) + unit_idx * stride[kind]
+            dense = l0 < kd
+            lp = at(params["dense_layers"], l_idx) if dense else at(
+                per_layer, l_idx - kd)
+            lp = lp | at(params[kind], op_idx)
+            carry, y = layer(
+                carry, (l_idx, lp, {"op_idx": op_idx}),
+                sparse=bool(cfg.n_experts) and not dense, kind=kind)
+            ys.append(y)
+        return carry, ys
+
+    def gather(ys_run):
+        """``{key: [layers that gave it, ...]}`` of one run's ``ys``: a
+        list over the unit's layers, each leaf leading with the unit axis
+        — [units, ...] a layer -> [units * layers, ...], in layer order."""
+        out = {}
+        for key in ys_run[0]:
+            gave = [y[key] for y in ys_run if y[key] is not None]
+            if gave:
+                out[key] = jax.tree_util.tree_map(
+                    lambda *a: jnp.stack(a, axis=1).reshape(-1, *a[0].shape[1:]),
+                    *gave)
+        return out
+
+    parts = []
+    for first, n, units in _mixed_runs(cfg):
+        kinds = is_conv[first:first + n]
+        stride = {"conv": int(kinds.sum()), "attn": int((~kinds).sum())}
+        if units == 1:
+            carry, ys = unit(carry, first, 0, n, stride)
+            ys = jax.tree_util.tree_map(lambda a: a[None], ys)
+        else:
+            carry, ys = jax.lax.scan(
+                lambda c, i: unit(c, first, i, n, stride), carry,
+                jnp.arange(units))
+        parts.append(gather(ys))
+    joined = {}
+    # sorted: a set of strings iterates in the process's hash order, and the
+    # order the joins are traced in is part of the compile cache's key
+    for key in sorted({k for p in parts for k in p}):
+        joined[key] = jax.tree_util.tree_map(
+            lambda *a: jnp.concatenate(a), *[p[key] for p in parts if key in p])
     return carry, joined
 
 
@@ -1150,6 +1452,7 @@ def prefill_forward(
     prefix_lengths: jnp.ndarray,  # [B] valid cached-prefix tokens (≤ P_max)
     new_lengths: jnp.ndarray | None = None,  # [B] real new tokens (counters)
     moe_stats: jnp.ndarray | None = None,  # the device's moe counters
+    snap_at: jnp.ndarray | None = None,  # [B, K] conv states wanted after
 ):
     """Prefill new tokens against an optional cached prefix.
 
@@ -1168,11 +1471,21 @@ def prefill_forward(
     the new tokens' index keys [L_full, B, S, Di] as a fourth value (the
     caller writes them at the same slots), and a config with experts
     ``aux`` as the last (module docstring); ``new_lengths`` keeps the
-    bucket's padding out of its counters.
+    bucket's padding out of its counters. A config with conv layers takes
+    no cached prefix either (a prefix is resumed from a state slot:
+    ``prefill_chunk_paged``), returns ``new_k`` / ``new_v`` over its
+    attention layers only and as the pool stores them, and after them the
+    conv layers' states after ``snap_at`` tokens each, ``[L_conv, B, K,
+    rows, 128]`` (the caller writes them to state slots).
     """
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)
     p_max = cached_k.shape[2]
+    if cfg.has_conv and p_max:
+        raise ValueError(
+            "prefill_forward takes no cached prefix for a model with conv "
+            "layers (a prefix resumes from a state slot: prefill_chunk_paged)"
+        )
     s_new = tokens.shape[1]
     pad = p_max - prefix_lengths  # [B] front padding per row
     # Index-space position of query t (abs position p) inside the context
@@ -1193,6 +1506,33 @@ def prefill_forward(
     select = cfg.dsa and s_new > cfg.index_topk
     causal = jnp.tril(jnp.ones((s_new, s_new), bool))[None] if select else None
     mask0 = jnp.zeros((tokens.shape[0], s_new, s_new) if select else (), bool)
+
+    def mixed_layer(carry, xs, sparse, kind):
+        """A layer of a config with conv layers (``_scan_mixed``)."""
+        x, mask = carry
+        l_idx, lp, _ = xs
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        k = v = states = None
+        if kind == "conv":
+            zero = jnp.zeros(
+                (x.shape[0], cfg.conv_L_cache - 1, cfg.hidden), x.dtype)
+            op, states = _short_conv(lp, h, zero, cfg, snap_at)
+            states = states.reshape(*states.shape[:2], -1, 128)
+        else:
+            q, k, v = _attn_in(lp, h, cfg, positions, one_rope, None)
+            op = _attn_out(lp, attend_prefill(
+                q, k, v, attn_pos, kv_end, kv_start=pad), cfg)
+            if cfg.pair_kv_heads:  # as the pool stores them
+                _, k, v = _pair_heads(cfg, q, k, v)
+        x = x + op
+        h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse)
+        return (x + y, mask), {"k": k, "v": v, "aux": aux, "state": states}
+
+    if cfg.has_conv:
+        (x, _), ys = _scan_layers(cfg, params, mixed_layer, (x, mask0))
+        return (_logits(params, cfg, x), ys["k"], ys["v"], ys["state"],
+                *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
 
     def layer(carry, xs, sparse):
         x, mask = carry
@@ -1312,7 +1652,7 @@ def prefill_forward_sp(
         "interpret",
     ),
     donate_argnums=(4,),
-    donate_argnames=("kv_scale", "index_pool"),
+    donate_argnames=("kv_scale", "index_pool", "state_pool"),
 )
 def prefill_chunk_paged(
     params: dict,
@@ -1332,6 +1672,10 @@ def prefill_chunk_paged(
     logits_at: jnp.ndarray | None = None,  # [B] per-row chunk offsets, or None
     moe_stats: jnp.ndarray | None = None,  # the device's moe counters
     index_pool: jnp.ndarray | None = None,  # [L_full, num_slots, Di] (donated)
+    state_pool: jnp.ndarray | None = None,  # [L_conv, S, rows, 128] (donated)
+    state_in: jnp.ndarray | None = None,  # [B] state slot a row resumes from
+    snap_at: jnp.ndarray | None = None,  # [B, K] states wanted after ... tokens
+    state_out: jnp.ndarray | None = None,  # [B, K] the slots they go to
 ):
     """One CHUNK of long-context prefill against the paged pool (SURVEY §5:
     the 32k Qwen2 gate must never materialize O(S²) scores — VERDICT
@@ -1356,7 +1700,13 @@ def prefill_chunk_paged(
     an indexer takes ``index_pool`` and returns it after ``kv_pool``, the
     chunk's index keys written at ``slots``; once the context (the page
     table's width and the chunk) passes ``index_topk`` its queries attend
-    selected rows only (``ops/dsa.py``).
+    selected rows only (``ops/dsa.py``). A config with conv layers takes
+    ``state_pool`` and returns it after ``kv_pool``: each row's
+    convolutions start from the state in slot ``state_in`` (zeros where
+    the chunk starts at position 0), and the states after ``snap_at``
+    tokens of the chunk go to slots ``state_out`` — the row's working
+    slot at its true end, a snapshot wherever the tree will cut; a slot
+    nobody reads (slot 0) takes what is not wanted.
     """
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
@@ -1387,6 +1737,46 @@ def prefill_chunk_paged(
     moe_kw = {} if use_kernel is None else {
         "use_kernel": use_kernel, "interpret": interpret,
     }
+
+    if cfg.has_conv:
+        # Every conv layer's state of every row, read once before the
+        # layers and written once after them: the state array stays out of
+        # the layer scan (and of any branch) as the pool does.
+        resumed = _state_rows(cfg, state_pool[:, state_in])  # [Lc, B, t-1, H]
+        resumed = jnp.where(
+            (positions[:, 0] == 0)[None, :, None, None], 0, resumed)
+
+    def mixed_layer(carry, xs, sparse, kind):
+        """A layer of a config with conv layers (``_scan_mixed``)."""
+        x, sel = carry
+        l_idx, lp, table = xs
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        k = v = states = None
+        if kind == "conv":
+            mine = jax.lax.dynamic_index_in_dim(
+                resumed, table["op_idx"], 0, keepdims=False)
+            op, states = _short_conv(lp, h, mine, cfg, snap_at)
+            states = states.reshape(*states.shape[:2], -1, 128)
+        else:
+            q, k, v = _attn_in(lp, h, cfg, positions, one_rope, None)
+            scale = None
+            if cfg.pair_kv_heads:
+                q, k, v = _pair_heads(cfg, q, k, v)
+                scale = cfg.head_dim ** -0.5
+            k, v = k.astype(kv_pool.dtype), v.astype(kv_pool.dtype)
+            attn = paged_chunk_attention(
+                q, k, v, kv_pages, page_table, positions, prior_lengths,
+                kv_lengths, table["op_idx"], kv_block_pages=kv_block_pages,
+                use_kernel=use_kernel, interpret=interpret, scale=scale,
+            )
+            if cfg.pair_kv_heads:
+                attn = _own_lanes(cfg, attn)
+            op = _attn_out(lp, attn, cfg)
+        x = x + op
+        h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse,
+                      **moe_kw)
+        return (x + y, sel), {"k": k, "v": v, "aux": aux, "state": states}
 
     def layer(carry, xs, sparse):
         x, sel = carry
@@ -1491,6 +1881,16 @@ def prefill_chunk_paged(
             [(kv_pool, new_kv, ALL_LAYERS), (kv_scale, new_s, ALL_LAYERS)],
             slots, page_size)
         return _logits(params, cfg, pick(x)), kv_pool, kv_scale
+    if cfg.has_conv:
+        (x, _), ys = _scan_layers(cfg, params, mixed_layer, (x, None))
+        new_kv = jnp.stack([ys["k"], ys["v"]]).transpose(0, 1, 4, 2, 3, 5)
+        kv_pool, = write_runs_all(
+            [(kv_pool, new_kv, ALL_LAYERS)], slots, page_size)
+        states = ys["state"]  # [Lc, B, K, rows, 128]
+        state_pool = state_pool.at[:, state_out.reshape(-1)].set(
+            states.reshape(states.shape[0], -1, *states.shape[3:]))
+        return (_logits(params, cfg, pick(x)), kv_pool, state_pool,
+                *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
     (x, _), (new_k, new_v, aux, new_ki, picked) = _scan_layers(
         cfg, params, layer, (x, sel0))
     # One write for the whole chunk across all layers, by whole pages (each
@@ -1512,7 +1912,7 @@ def prefill_chunk_paged(
     jax.jit,
     static_argnames=("cfg", "page_size", "mesh"),
     donate_argnums=(3,),
-    donate_argnames=("kv_scale", "index_pool"),
+    donate_argnames=("kv_scale", "index_pool", "state_pool"),
 )
 def decode_step(
     params: dict,
@@ -1527,6 +1927,8 @@ def decode_step(
     kv_scale: jnp.ndarray | None = None,  # [2, L, Hkv, num_slots] int8 pool
     moe_stats: jnp.ndarray | None = None,  # the device's moe counters
     index_pool: jnp.ndarray | None = None,  # [L_full, num_slots, Di] (donated)
+    state_pool: jnp.ndarray | None = None,  # [L_conv, S, rows, 128] (donated)
+    state_slots: jnp.ndarray | None = None,  # [2, B]: working slot, second slot
 ):
     """One decode step for a continuous batch: writes this token's K/V into
     the paged pool inside the layer scan, attends over the radix-cache
@@ -1542,10 +1944,16 @@ def decode_step(
     A config with an indexer takes ``index_pool`` and returns it after
     ``kv_pool``; once the page table is wider than ``index_topk`` tokens
     a full layer scores the row's index keys and selects, and every layer
-    reads the selected rows only (``ops/dsa.py``)."""
+    reads the selected rows only (``ops/dsa.py``). A config with conv
+    layers takes ``state_pool`` and returns it after ``kv_pool``: a row's
+    state is read from its working slot ``state_slots[0]`` and the new one
+    written back there, and to ``state_slots[1]`` as well — the row's
+    boundary slot when this token completes a page (the state the tree
+    will want at release), slot 0 otherwise (one array: one upload a
+    step)."""
     return _decode_core(
         params, cfg, tokens, kv_pool, slots, page_table, lengths, page_size,
-        mesh, kv_scale, moe_stats, index_pool,
+        mesh, kv_scale, moe_stats, index_pool, state_pool, state_slots,
     )
 
 
@@ -1562,6 +1970,8 @@ def _decode_core(
     kv_scale: jnp.ndarray | None = None,
     moe_stats: jnp.ndarray | None = None,
     index_pool: jnp.ndarray | None = None,
+    state_pool: jnp.ndarray | None = None,
+    state_slots: jnp.ndarray | None = None,
 ):
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
@@ -1584,6 +1994,51 @@ def _decode_core(
     index_shape = None if index_pool is None else index_pool.shape
     index_pages_shape = None if index_pool is None else (
         index_shape[0], num_slots // page_size, page_size, index_shape[2])
+
+    if cfg.has_conv:
+        # Read once, written once (prefill_chunk_paged): 2 x hidden a row
+        # and conv layer, never the state array inside the scan.
+        resumed = _state_rows(cfg, state_pool[:, state_slots[0]])  # [Lc, B, t-1, H]
+
+    def mixed_layer(carry, xs, sparse, kind):
+        """A layer of a config with conv layers (``_scan_mixed``)."""
+        x, kv_pool = carry
+        l_idx, lp, table = xs
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        states = None
+        if kind == "conv":
+            mine = jax.lax.dynamic_index_in_dim(
+                resumed, table["op_idx"], 0, keepdims=False)
+            op, states = _short_conv(lp, h, mine, cfg, None)
+            states = states.reshape(B, -1, 128)
+        else:
+            q, k, v = _attn_in(lp, h, cfg, positions[:, None], one_rope, None)
+            scale = None
+            if cfg.pair_kv_heads:
+                q, k, v = _pair_heads(cfg, q, k, v)
+                scale = cfg.head_dim ** -0.5
+            attn, kv_pages = paged_decode_attention(
+                q[:, 0], k[:, 0].astype(kv_pool.dtype),
+                v[:, 0].astype(kv_pool.dtype), kv_pool.reshape(pages_shape),
+                slots, page_table, lengths, table["op_idx"], scale=scale,
+            )
+            kv_pool = kv_pages.reshape(pool_shape)
+            attn = attn[:, None]
+            if cfg.pair_kv_heads:
+                attn = _own_lanes(cfg, attn)
+            op = _attn_out(lp, attn, cfg)
+        x = x + op
+        h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        y, aux = _ffn(params, lp, l_idx, h2, cfg, "decode", live, sparse)
+        return (x + y, kv_pool), {"aux": aux, "state": states}
+
+    if cfg.has_conv:
+        (x, kv_pool), ys = _scan_layers(cfg, params, mixed_layer, (x, kv_pool))
+        states = ys["state"]  # [Lc, B, rows, 128]
+        state_pool = state_pool.at[:, state_slots.reshape(-1)].set(
+            jnp.concatenate([states, states], axis=1))
+        return (_logits(params, cfg, x)[:, 0], kv_pool, state_pool,
+                *_moe_aux(cfg, ys.get("aux"), moe_stats, "decode"))
 
     def layer(carry, xs, sparse):
         x, kv_pool, kv_scale, index_pool, sel = carry

@@ -343,10 +343,14 @@ def attend_chunk_hybrid(
 
 
 def kernel_shape_ok(head_dim: int) -> bool:
-    """Shapes the Pallas kernels can tile: ``head_dim`` must be a lane
-    multiple of 128 for the DMA tiling (llama3.2-1b's 64 and every tiny
-    preset's 32 are not — those models run the jnp reference on any
-    backend, and ``Engine`` says so once at start on a TPU)."""
+    """Shapes the Pallas kernels can tile: the width of a pool row must be
+    a lane multiple of 128 for the DMA tiling. A head of 64 gets there two
+    to a row (``ModelConfig.pair_kv_heads``: the kernels then see the
+    128-wide row, every query zero in its partner's lanes, the logits'
+    factor passed in); without the pairing (llama3.2-1b, whose tp layouts
+    split its 8 kv heads one a chip) and for every tiny preset's 32 the
+    model runs the jnp reference on any backend, and ``Engine`` says so
+    once at start on a TPU."""
     return head_dim % 128 == 0
 
 
@@ -579,6 +583,7 @@ def paged_chunk_attention(
     interpret: bool = False,
     kv_scales: jnp.ndarray | None = None,
     window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
+    scale: float | None = None,  # of the logits; None = 1 / sqrt(D)
 ) -> jnp.ndarray:
     """Chunk-prefill attention with the ``_dispatch`` choice, mirroring
     ``paged_decode_attention``: the Pallas chunk kernel or the jnp
@@ -603,7 +608,7 @@ def paged_chunk_attention(
         return paged_chunk_attention_kernel(
             q, k_cur, v_cur, kv_pages, page_table, prior_lengths,
             kv_lengths, layer, interpret=interpret, kv_scales=kv_scales,
-            window=window,
+            window=window, scale=scale,
         )
 
     def reference(q, k_cur, v_cur, kv_pages, page_table, q_positions,
@@ -611,7 +616,7 @@ def paged_chunk_attention(
         return attend_chunk_hybrid(
             q, k_cur, v_cur, kv_pages, page_table, q_positions,
             prior_lengths, kv_lengths, layer, kv_block_pages=kv_block_pages,
-            kv_scales=kv_scales, window=window,
+            kv_scales=kv_scales, window=window, scale=scale,
         )
 
     return _dispatch(
@@ -705,6 +710,7 @@ def paged_decode_attention(
     mesh=None,
     kv_scales: jnp.ndarray | None = None,  # [2, L, Hkv, P, page] — updated too
     window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
+    scale: float | None = None,  # of the logits; None = 1 / sqrt(D)
 ):
     """One decode step's KV write + paged attention, fused.
 
@@ -728,7 +734,7 @@ def paged_decode_attention(
 
         return paged_decode_fused_kernel(
             q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
-            kv_scales=kv_scales, window=window,
+            kv_scales=kv_scales, window=window, scale=scale,
         )
 
     def reference(q, k_new, v_new, kv_pages, slots, page_table, lengths,
@@ -757,7 +763,7 @@ def paged_decode_attention(
         kv_pages = kv_pages.at[1, layer, :, pg, off].set(v_new)
         attn = attend_decode_ref(
             q, kv_pages[0, layer], kv_pages[1, layer], page_table, lengths,
-            window=window,
+            window=window, scale=scale,
         )
         return attn, kv_pages
 

@@ -749,7 +749,8 @@ def _mh_tables(page_table, page: int, pages_per_block: int | None):
     return pt, ppb, padded, flat(pt), flat(breaks)
 
 
-def _mh_operands(q, kv_dtype, quantized: bool, group: int):
+def _mh_operands(q, kv_dtype, quantized: bool, group: int,
+                 scale: float | None = None):
     """``(q [B, Hkv, G, D], scale)`` as the mh kernels take them. A float
     pool at least as wide as ``q``: ``q`` in the pool's dtype, unscaled,
     and ``1/sqrt(D)`` for the kernel to apply to its float32 scores — the
@@ -757,7 +758,8 @@ def _mh_operands(q, kv_dtype, quantized: bool, group: int):
     ``q`` wider than the pool: float32 ``q`` scaled here (``scale`` None)
     against blocks upcast in VMEM, as these kernels always computed."""
     B, Hq, D = q.shape
-    scale = 1.0 / (D ** 0.5)
+    if scale is None:  # else the caller's: the row is wider than the head
+        scale = 1.0 / (D ** 0.5)
     if quantized or jnp.promote_types(q.dtype, kv_dtype) != kv_dtype:
         q, scale = q.astype(jnp.float32) * scale, None
     q = q.astype(jnp.float32 if scale is None else kv_dtype).reshape(
@@ -1362,7 +1364,7 @@ def _window_starts(lengths, window):
 def _fused_decode_mh(
     q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
     pages_per_block: int | None = None, interpret: bool = False,
-    kv_scales=None, window=None,
+    kv_scales=None, window=None, scale: float | None = None,
 ):
     """Heads-batched fused decode wrapper (see ``_mh_fused_kernel``)."""
     B, Hq, D = q.shape
@@ -1401,7 +1403,7 @@ def _fused_decode_mh(
     else:
         k_deq, v_deq = k_new, v_new
 
-    q4, scale = _mh_operands(q, kv_pages.dtype, quantized, G)
+    q4, scale = _mh_operands(q, kv_pages.dtype, quantized, G, scale)
     Gp = q4.shape[2]
     q_spec = pl.BlockSpec((None, Hkv, Gp, D), lambda b, *_: (b, 0, 0, 0))
     kv_new_spec = pl.BlockSpec((None, Hkv, 1, D), lambda b, *_: (b, 0, 0, 0))
@@ -1520,7 +1522,8 @@ def _scatter_new_scales(kv_scales, layer, slots, lengths, page, k_sc, v_sc):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("pages_per_block", "interpret", "fuse_heads")
+    jax.jit,
+    static_argnames=("pages_per_block", "interpret", "fuse_heads", "scale"),
 )
 def paged_decode_fused_kernel(
     q: jnp.ndarray,  # [B, Hq, D]
@@ -1536,6 +1539,7 @@ def paged_decode_fused_kernel(
     kv_scales: jnp.ndarray | None = None,  # [2, L, Hkv, P, page] int8 pool
     fuse_heads: bool | None = None,  # None → _auto_fuse_heads policy
     window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
+    scale: float | None = None,  # of the logits; None = 1 / sqrt(D)
 ):
     """Fused decode step attention: returns ``(attn_out [B, Hq, D],
     kv_pages)`` — plus the updated ``kv_scales`` when quantized — where
@@ -1560,7 +1564,7 @@ def paged_decode_fused_kernel(
         return _fused_decode_mh(
             q, k_new, v_new, kv_pages, slots, page_table, lengths, layer,
             pages_per_block=pages_per_block, interpret=interpret,
-            kv_scales=kv_scales, window=window,
+            kv_scales=kv_scales, window=window, scale=scale,
         )
     page_table, ppb, padded = _block_geometry(page_table, page, pages_per_block)
     lengths = jnp.asarray(lengths, dtype=jnp.int32)
@@ -1582,7 +1586,8 @@ def paged_decode_fused_kernel(
     else:
         k_deq, v_deq = k_new, v_new
 
-    scale = 1.0 / (D ** 0.5)
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
     q4 = (q.astype(jnp.float32) * scale).reshape(B, Hq, 1, D)
     q_spec = pl.BlockSpec((None, G, None, D), lambda b, h, *_: (b, h, 0, 0))
     kv_new_spec = pl.BlockSpec((None, None, 1, D), lambda b, h, *_: (b, h, 0, 0))
@@ -1861,7 +1866,8 @@ def _chunk_block(chunk: int, group: int, max_rows: int = 1024) -> int:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("pages_per_block", "q_block", "interpret")
+    jax.jit,
+    static_argnames=("pages_per_block", "q_block", "interpret", "scale"),
 )
 def paged_chunk_attention_kernel(
     q: jnp.ndarray,  # [B, C, Hq, D] — pre-rope'd chunk queries
@@ -1877,6 +1883,7 @@ def paged_chunk_attention_kernel(
     interpret: bool = False,
     kv_scales: jnp.ndarray | None = None,  # [2, L, Hkv, P, page] int8 pool
     window: jnp.ndarray | None = None,  # () keys a query sees; 0 = all
+    scale: float | None = None,  # of the logits; None = 1 / sqrt(D)
 ) -> jnp.ndarray:
     """Pallas chunk-prefill attention: SURVEY §7 hard part (a) for the
     PREFILL side (VERDICT round-3 next-step #3 "pool-page chunk
@@ -1908,7 +1915,8 @@ def paged_chunk_attention_kernel(
     if C % cblk:
         raise ValueError(f"q_block={cblk} must divide chunk C={C}")
 
-    scale = 1.0 / (D ** 0.5)
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
     # [B, Hkv, C, G, D]: kv-head-major so each program's q block is one
     # contiguous [Cblk, G, D] tile.
     q5 = (q.astype(jnp.float32) * scale).reshape(B, C, Hkv, G, D).transpose(

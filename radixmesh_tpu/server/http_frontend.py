@@ -801,6 +801,19 @@ class ServingFrontend:
                 # device, fetched here and nowhere else.
                 state["moe"] = read_moe_stats(eng._moe_stats) | {
                     "held_experts": eng.cfg.held_experts}
+            if eng.state_stats is not None:
+                # The state slots beside the rows (a model whose layers
+                # carry a state): how requests resumed, what the tree took.
+                state["state"] = eng.state_stats | {
+                    "slots_used": eng.pool.state_slots_used,
+                    "slots_used_peak": eng.pool.state_used_peak,
+                    "evictions": tree.state_evictions,
+                    # the rows beside them: one token in every layer that
+                    # owns rows, as stored
+                    "kv_bytes_per_token": (
+                        eng.pool.kv.size // eng.pool.num_slots
+                        * eng.pool.kv.dtype.itemsize),
+                }
             host = getattr(tree, "host", None)
             if host is not None:
                 state["host_tier"] = {
