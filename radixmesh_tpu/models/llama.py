@@ -814,18 +814,14 @@ def param_logical_axes(cfg: ModelConfig, params: dict | None = None) -> dict:
 _PREC = jax.lax.Precision.HIGHEST
 
 
-def _wmm(lp: dict, name: str, eq: str, x: jnp.ndarray, reshape=None,
-         **einsum_kw):
+def _wmm(lp: dict, name: str, eq: str, x: jnp.ndarray, **einsum_kw):
     """Dense matmul honoring W8A16 storage (``ops/wquant.py``): int8
     weights feed the MXU as bf16 (only HBM *streaming* shrinks — compute
     precision is unchanged) and the per-out-channel scale applies to the
     output, which is exact for per-out-channel quantization."""
     w = lp[name]
     if w.dtype == jnp.int8:
-        wm = w.astype(x.dtype)
-        if reshape is not None:
-            wm = wm.reshape(reshape)
-        y = jnp.einsum(eq, x, wm, precision=_PREC, **einsum_kw)
+        y = jnp.einsum(eq, x, w.astype(x.dtype), precision=_PREC, **einsum_kw)
         y = y * lp[name + "_s"]
         # The f32 scale would otherwise promote the whole activation
         # stream to f32 from the first quantized layer on — cast back
@@ -833,8 +829,6 @@ def _wmm(lp: dict, name: str, eq: str, x: jnp.ndarray, reshape=None,
         if "preferred_element_type" not in einsum_kw:
             y = y.astype(x.dtype)
         return y
-    if reshape is not None:
-        w = w.reshape(reshape)
     return jnp.einsum(eq, x, w, precision=_PREC, **einsum_kw)
 
 
@@ -853,6 +847,14 @@ def _qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig):
     q = _wmm(lp, "wq", "bsh,hd->bsd", x)
     k = _wmm(lp, "wk", "bsh,hd->bsd", x)
     v = _wmm(lp, "wv", "bsh,hd->bsd", x)
+    # Without the barrier XLA folds the reshape to heads below into the
+    # products, which then want each weight as [heads, D, H]: a layer scan
+    # slices wq / wk / wv out of their stacks and copies the slices,
+    # transposed, before every product — 2.3 ms of Qwen2-7B's 14.2 ms
+    # decode step where the products alone take 0.7. Behind it each
+    # product reads its stack at the layer's index, as the FFN's do, in
+    # every program (PERF.md §5, PR 40).
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
     if cfg.qkv_bias:
         q = q + lp["bq"]
         k = k + lp["bk"]
@@ -1410,7 +1412,6 @@ def _attn_out(lp: dict, attn: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """The wo projection shared by every forward variant: attn is
     [B, S, Hq·D] (or already [B, S, Hq, D])."""
     B, S = attn.shape[:2]
-    width = cfg.head_dim
     if cfg.latent:
         # attn is the heads' mix of latent rows [B, S, Hq, r]: their values.
         with jax.named_scope("rm_mla_unabsorb"):
@@ -1418,16 +1419,13 @@ def _attn_out(lp: dict, attn: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
                 "bsqr,rqv->bsqv", attn, _latent_split(lp, cfg)[1],
                 precision=_PREC,
             )
-        # One [Hq * v, H] product (a latent config takes no mesh, so no
-        # head axis to shard): the per-head form below made the layer scan
-        # materialise its slice of wo, 0.2 GB a layer at 64 heads of 256
-        # into 6144 — 3.7 ms of a 35 ms decode step (PERF.md, PR 33).
-        return _wmm(lp, "wo", "bsk,kh->bsh", attn.reshape(B, S, -1))
-    return _wmm(
-        lp, "wo", "bsqd,qdh->bsh",
-        attn.reshape(B, S, cfg.n_heads, width),
-        reshape=(cfg.n_heads, width, cfg.hidden),
-    )
+    # One [Hq * D, H] product: written per head ("bsqd,qdh->bsh" on wo
+    # viewed [Hq, D, H]) the layer scan writes its slice of wo out before
+    # the product — 0.2 GB a layer at 64 heads of 256 into 6144, 3.7 ms of
+    # a 35 ms latent decode step (PERF.md, PR 33); at Qwen2-7B 0.5 ms a
+    # step, as much as the product itself (PR 40). A tp mesh shards the
+    # flat rows by head all the same ("q_heads" leads them).
+    return _wmm(lp, "wo", "bsk,kh->bsh", attn.reshape(B, S, -1))
 
 
 def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:

@@ -274,11 +274,7 @@ def pp_forward_chunk(
                 # Row-split projections: the per-out-channel W8A16
                 # scale is constant across tp shards, so applying it to
                 # the partial sums before the psum is exact.
-                o = _wmm(
-                    lp, "wo", "bsqd,qdh->bsh",
-                    attn.reshape(mb, C, hq_loc, D),
-                    reshape=(hq_loc, D, cfg.hidden),
-                )
+                o = _wmm(lp, "wo", "bsk,kh->bsh", attn.reshape(mb, C, -1))
                 h = h + jax.lax.psum(o, "tp")
                 h2 = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
                 gate = jax.nn.silu(_wmm(lp, "w_gate", "bsh,hi->bsi", h2))

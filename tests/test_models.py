@@ -13,12 +13,18 @@ import pytest
 
 from radixmesh_tpu.models import get_config
 from radixmesh_tpu.models.llama import (
+    _PREC,
+    _attn_out,
+    _qkv,
+    _wmm,
     convert_hf_state_dict,
     decode_step,
     init_params,
     param_logical_axes,
     prefill_forward,
 )
+from radixmesh_tpu.ops.norm import rms_norm
+from radixmesh_tpu.ops.wquant import quantize_weight
 
 PAGE = 4
 
@@ -256,3 +262,103 @@ class TestHFConversion:
                 assert key in flat_a, f"no logical axes for {key}"
                 assert len(flat_a[key]) == leaf.ndim, f"rank mismatch for {key}"
 
+
+def _qkv_reshaped_behind_the_product(lp, x, cfg):
+    """``_qkv`` as it was until PR 40: nothing between the three products
+    and the bias / reshape to heads."""
+    B, S = x.shape[:2]
+    q, k, v = (_wmm(lp, w, "bsh,hd->bsd", x) for w in ("wq", "wk", "wv"))
+    if cfg.qkv_bias:
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_head_norm"], cfg.rms_eps)
+        k = rms_norm(k, lp["k_head_norm"], cfg.rms_eps)
+    return q, k, v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+
+
+def _attn_out_by_head(lp, attn, cfg):
+    """``_attn_out`` as it was until PR 40 for a config without latent
+    attention: ``wo`` viewed ``[Hq, D, H]`` and contracted per head."""
+    B, S = attn.shape[:2]
+    w = lp["wo"]
+    y = jnp.einsum(
+        "bsqd,qdh->bsh", attn.reshape(B, S, cfg.n_heads, cfg.head_dim),
+        w.astype(attn.dtype).reshape(cfg.n_heads, cfg.head_dim, cfg.hidden),
+        precision=_PREC,
+    )
+    if w.dtype == jnp.int8:
+        y = (y * lp["wo_s"]).astype(attn.dtype)
+    return y
+
+
+class TestProjectionForms:
+    """PR 40 changed how ``_qkv`` and ``_attn_out`` are WRITTEN (a barrier
+    behind the products, ``wo`` as one flat product) so that a layer scan
+    reads each weight where it lies in its stack; the values are the old
+    forms', at the served head geometries, in both weight storages."""
+
+    LAYERS, TOKENS = 2, 5
+
+    def _stack(self, preset, quant):
+        cfg = get_config(preset)
+        keys = iter(jax.random.split(jax.random.PRNGKey(40), 16))
+        H, q_w, kv_w = cfg.hidden, cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+        stack = {}
+        for name, shape in (("wq", (H, q_w)), ("wk", (H, kv_w)),
+                            ("wv", (H, kv_w)), ("wo", (q_w, H))):
+            w = jax.random.normal(next(keys), (self.LAYERS, *shape)) * shape[0] ** -0.5
+            if quant:
+                stack[name], stack[name + "_s"] = quantize_weight(w, axis=1)
+            else:
+                stack[name] = w.astype(cfg.dtype)
+        if cfg.qkv_bias:
+            for name, n in (("bq", q_w), ("bk", kv_w), ("bv", kv_w)):
+                stack[name] = jax.random.normal(
+                    next(keys), (self.LAYERS, n)).astype(cfg.dtype)
+        if cfg.qk_norm:
+            for name in ("q_head_norm", "k_head_norm"):
+                stack[name] = 1 + 0.1 * jax.random.normal(
+                    next(keys), (self.LAYERS, cfg.head_dim)).astype(cfg.dtype)
+        x = jax.random.normal(next(keys), (2, self.TOKENS, H)).astype(cfg.dtype)
+        return cfg, stack, x
+
+    @staticmethod
+    def _scanned(qkv, attn_out, cfg, stack, x):
+        """Both projections of every layer of the stack under one
+        ``lax.scan`` on the same ``x``, the heads' q standing in for the
+        attention's output: ``(q, k, v, out)``, each ``[layers, ...]``."""
+        def layer(x, lp):
+            q, k, v = qkv(lp, x, cfg)
+            return x, (q, k, v, attn_out(lp, q, cfg))
+
+        return jax.jit(lambda x, stack: jax.lax.scan(layer, x, stack)[1])(x, stack)
+
+    @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("preset", ["qwen2-7b", "lfm2-24b-a2b-ep8"])
+    def test_values_are_the_old_forms(self, preset, quant):
+        cfg, stack, x = self._stack(preset, quant)
+        assert cfg.qkv_bias == (preset == "qwen2-7b")
+        assert cfg.qk_norm == (preset != "qwen2-7b")
+        new = self._scanned(_qkv, _attn_out, cfg, stack, x)
+        old = self._scanned(
+            _qkv_reshaped_behind_the_product, _attn_out_by_head, cfg, stack, x)
+        # One bf16 unit on values of order 1: XLA:CPU rounds a product that
+        # feeds a float32 norm once less where nothing stands between them,
+        # and wo's contraction is associated otherwise.
+        for a, b in zip(new, old):
+            assert a.shape == b.shape and a.dtype == b.dtype == cfg.dtype
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                rtol=2.0 ** -7, atol=2.0 ** -7)
+
+    @pytest.mark.parametrize("preset", ["qwen2-7b", "lfm2-24b-a2b-ep8"])
+    def test_attn_out_takes_heads_or_flat(self, preset):
+        cfg, stack, x = self._stack(preset, quant=False)
+        lp = jax.tree.map(lambda w: w[0], stack)
+        q, _, _ = _qkv(lp, x, cfg)
+        flat = q.reshape(*q.shape[:2], -1)
+        np.testing.assert_array_equal(
+            np.asarray(_attn_out(lp, q, cfg), np.float32),
+            np.asarray(_attn_out(lp, flat, cfg), np.float32))

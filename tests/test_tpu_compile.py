@@ -24,9 +24,12 @@ from jax.sharding import SingleDeviceSharding
 from radixmesh_tpu.cache import kv_pool
 from radixmesh_tpu.models import get_config
 from radixmesh_tpu.models.llama import (
+    _PREC,
     _init_params,
+    _wmm,
     decode_step,
     prefill_chunk_paged,
+    prefill_forward,
 )
 from radixmesh_tpu.ops.attention import paged_decode_attention
 
@@ -239,3 +242,138 @@ def test_a_scatter_on_the_slot_axis_still_relayouts_the_pool(v5e):
         sd(shape[:3] + (512, shape[4]), jnp.bfloat16), PAGE,
     ).compile()
     assert len(_copies_shaped_like(compiled, shape)) == 2
+
+
+# ---------------------------------------------------------------------------
+# the layer scan reads the attention projections where they lie (PR 40)
+# ---------------------------------------------------------------------------
+
+_HLO_DTYPE = {"int8": "s8", "bfloat16": "bf16"}
+
+
+def _standalone_weight_ops(compiled, head_dim: int, weights) -> list[str]:
+    """The ``copy`` operations and the fusions of the optimized program
+    that stand ALONE — in the entry, a loop body or a branch, not inside
+    another fusion — and whose result is one of ``weights`` (a layer's
+    slice ``[in, out]`` of a stacked projection, by shape and dtype): flat,
+    transposed, or viewed by head. A product that reads its stack at the
+    layer's index holds the slice INSIDE its own fusion and returns
+    activations; a stand-alone one is the slice written out to a buffer
+    of its own (``constant_dynamic-slice_fusion.N``), a ``copy`` its
+    relayout. The callers keep their token counts off every weight's
+    dimensions, so no activation has such a shape."""
+    want = set()
+    for w in weights:
+        dtype = _HLO_DTYPE[str(w.dtype)]
+        a, b = w.shape[-2:]
+        views = {(a, b), (b, a)}
+        for x, y in ((a, b), (b, a)):
+            if y % head_dim == 0:
+                views |= {(x, y // head_dim, head_dim), (y // head_dim, head_dim, x)}
+        for v in views:
+            dims = ",".join(map(str, v))
+            want |= {(dtype, dims), (dtype, "1," + dims)}
+    text = compiled.as_text()
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    found, inside = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(", line)
+        if head:
+            inside = head.group(1)
+        op = re.match(r"\s+(?:ROOT )?(\S+) = (\w+)\[([\d,]+)\]\S* (copy|fusion)\(", line)
+        if op and inside not in fused and (op.group(2), op.group(3)) in want:
+            found.append(f"{op.group(1)} {op.group(2)}[{op.group(3)}]")
+    return found
+
+
+def _served(name):
+    """``(cfg, weight_quant, rows of the chunk wave)`` of a served GQA
+    configuration at published widths: Qwen2-7B W8A16 (bias), one period
+    of Mellum2 (bf16) and three of LFM2 (bf16, heads of 64 with their
+    norms, a scanned run of two periods between unscanned layers). The
+    wave's tokens (rows x 512) and the dense prefill's 1 024 equal no
+    dimension of the configuration's projections."""
+    if name == "qwen2":
+        return get_config("qwen2-7b"), "int8", 8
+    if name == "mellum2":
+        from radixmesh_tpu.models.mellum2 import mellum2_12b_a2_5b
+
+        return mellum2_12b_a2_5b(4), None, 4
+    period = ("conv", "conv", "full_attention", "conv")
+    return get_config("lfm2-24b-a2b-ep8").replace(
+        n_layers=12, layer_types=period * 3, vocab_size=4096), None, 8
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_paged",
+                                     "prefill_forward"])
+@pytest.mark.parametrize("name", ["qwen2", "mellum2", "lfm2"])
+def test_projections_are_read_where_they_lie_in_the_stack(v5e, name, program):
+    """No entry point's layer scan slices ``wq`` / ``wk`` / ``wv`` / ``wo``
+    out of their stacks into buffers of their own, nor copies a slice
+    transposed: every projection product takes the stack and the layer's
+    index, as the FFN's do (until PR 40: 29 MB a layer sliced, 16.5 MB of
+    it copied again, before the products of every Qwen2-7B decode step)."""
+    sd = _sd(v5e)
+    cfg, quant, wave_rows = _served(name)
+    shapes = jax.eval_shape(
+        lambda k: _init_params(k, cfg, quant, None),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+    )
+    params = jax.tree.map(lambda x: sd(x.shape, x.dtype), shapes)
+    stack = shapes["attn" if cfg.has_conv else "layers"]
+    weights = [stack[w] for w in ("wq", "wk", "wv", "wo")]
+    ints = lambda *shape: sd(shape, jnp.int32)  # noqa: E731
+    pool = sd(cfg.kv_pool_shape(65536), cfg.dtype)
+    state_pool = sd(cfg.state_shape(256), cfg.dtype) if cfg.has_conv else None
+
+    def stateful(**kw):  # the state arguments, for a config that has a state
+        return {"state_pool": state_pool, **kw} if cfg.has_conv else {}
+
+    if program == "decode_step":  # both row buckets the Qwen2 cells launch
+        lowered = [decode_step.lower(
+            params, cfg, ints(rows), pool, ints(rows), ints(rows, MAXP),
+            ints(rows), PAGE, **stateful(state_slots=ints(2, rows)))
+            for rows in (16, 32)]
+    elif program == "prefill_chunk_paged":
+        r = wave_rows
+        lowered = [prefill_chunk_paged.lower(
+            params, cfg, ints(r, CHUNK), ints(r, CHUNK), pool, ints(r, CHUNK),
+            ints(r, 128), ints(r), page_size=PAGE, logits_at=ints(r),
+            **stateful(state_in=ints(r), snap_at=ints(r, 3),
+                       state_out=ints(r, 3)))]
+    else:
+        kv = sd((len(cfg.attn_layers), 1, 0, *cfg.kv_row[1:]), cfg.dtype)
+        lowered = [prefill_forward.lower(
+            params, cfg, ints(1, 1024), ints(1, 1024), kv, kv, ints(1),
+            **({"snap_at": ints(1, 3)} if cfg.has_conv else {}))]
+    for low in lowered:
+        assert not _standalone_weight_ops(low.compile(), cfg.head_dim, weights)
+
+
+def test_a_product_by_head_still_slices_and_copies_its_weight(v5e):
+    """The other side: the forms the projections had until PR 40 — the
+    reshape to heads right behind the ``wq`` product, ``wo`` contracted
+    per head — under a layer scan at Qwen2-7B's widths. XLA writes both
+    slices out and copies ``wq``'s transposed. If this stops failing, the
+    barrier in ``_qkv`` and the flat ``wo`` are no longer needed."""
+    sd = _sd(v5e)
+    H, heads = 3584, 28
+
+    def layer(x, lp):
+        B, S = x.shape[:2]
+        q = _wmm(lp, "wq", "bsh,hd->bsd", x) + lp["bq"]
+        q = jax.nn.silu(q.reshape(B, S, heads, D))  # any consumer by head
+        wo = lp["wo"].astype(x.dtype).reshape(heads, D, H)
+        y = jnp.einsum("bsqd,qdh->bsh", q, wo, precision=_PREC) * lp["wo_s"]
+        return x + y.astype(x.dtype), None
+
+    stack = {
+        "wq": sd((L, H, H), jnp.int8), "wq_s": sd((L, H), jnp.float32),
+        "bq": sd((L, H), jnp.bfloat16),
+        "wo": sd((L, H, H), jnp.int8), "wo_s": sd((L, H), jnp.float32),
+    }
+    compiled = jax.jit(lambda x, stack: jax.lax.scan(layer, x, stack)[0]).lower(
+        sd((32, 1, H), jnp.bfloat16), stack).compile()
+    found = _standalone_weight_ops(compiled, D, [stack["wq"]])
+    assert sum("copy" in op for op in found) == 1, found
+    assert sum("dynamic-slice_fusion" in op for op in found) == 2, found
