@@ -447,6 +447,9 @@ class PagedKVPool:
         planes: int = 2,
         index_shape: tuple[int, int, int] | None = None,
         state_shape: tuple[int, int, int, int] | None = None,
+        state_dtype: Any = None,
+        conv_state_shape: tuple[int, int, int, int] | None = None,
+        window_shape: tuple[int, int, int, int, int] | None = None,
     ):
         if planes not in (1, 2):
             raise ValueError("a pool row has 1 plane (latent) or 2 (K and V)")
@@ -470,6 +473,7 @@ class PagedKVPool:
             dtype = KV_QUANT_DTYPES[quant]
         self.dtype = dtype
         self.allocator = SlotAllocator(num_slots, page_size)
+        self.used_peak = 0  # most slots ever taken at once (/debug/state)
         # Head-major layout [2, L, Hkv, slots, D]: per-layer pages view as
         # [Hkv, num_pages, page, D] by pure reshape (no copy), which is the
         # layout the Pallas paged-attention kernel DMAs (batch dims of its
@@ -510,9 +514,39 @@ class PagedKVPool:
         if state_shape is not None:
             if quant is not None:
                 raise ValueError("state slots have no quantized form")
-            self.state = jnp.zeros(state_shape, dtype=dtype)
+            self.state = jnp.zeros(state_shape, dtype=state_dtype or dtype)
             self.state_allocator = SlotAllocator(state_shape[1], 1)
             self.state_allocator.reserve(np.zeros(1, np.int32))
+        # A model with selective state-space layers keeps the recurrent
+        # part in ``state`` (float32) and the last inputs of its
+        # convolutions here (``ModelConfig.conv_state_shape``, the model's
+        # dtype): one allocator, one snapshot = one slot of both.
+        self.state_conv = None
+        if conv_state_shape is not None:
+            if state_shape is None or conv_state_shape[1] != state_shape[1]:
+                raise ValueError("the conv states share the state slots")
+            self.state_conv = jnp.zeros(conv_state_shape, dtype=dtype)
+        # A second GROUP of rows (``ModelConfig.window_pool_shape``): the
+        # window layers' K / V of a model whose full and window layers do
+        # not share an array, ``[planes, window layers, heads, slots,
+        # width]``, wanted for the last ``sliding_window`` tokens of a row
+        # only. Its pages are counted, not owned: a row of the batch holds
+        # one count on every page of its window, a snapshot in the radix
+        # tree one on every page of its tail, and a page returns to the
+        # free list when the last count goes (``unref_window``). Page 0 is
+        # nobody's: idle rows write there.
+        self.window = None
+        self.window_refs = None
+        self.window_used_peak = 0
+        if window_shape is not None:
+            if quant is not None or window_shape[3] % page_size:
+                raise ValueError(
+                    "the window group has whole pages and no quantized form")
+            self.window = jnp.zeros(window_shape, dtype=dtype)
+            n_pages = window_shape[3] // page_size
+            self.window_refs = np.zeros(n_pages, np.int32)
+            self.window_refs[0] = 1
+            self._window_free = list(range(n_pages - 1, 0, -1))
         # Per-(token, head) symmetric scales for quantized pools: value ≈
         # int8 * scale (ops/quant.py). Same [2, L, Hkv, slots] geometry as
         # the data minus head_dim — shards identically over `tp`, and the
@@ -552,14 +586,75 @@ class PagedKVPool:
     @property
     def state_slot_bytes(self) -> int:
         """Bytes of one snapshot (all its layers)."""
-        if self.state is None:
+        return sum(a.size // a.shape[1] * a.itemsize
+                   for a in (self.state, self.state_conv) if a is not None)
+
+    # ---- the window group (host side: counted pages) ----
+
+    @property
+    def window_slots(self) -> int:
+        return 0 if self.window is None else self.window.shape[3]
+
+    @property
+    def window_slots_used(self) -> int:
+        """Slots of the window group's pages that somebody counts on (the
+        scratch page is nobody's)."""
+        if self.window is None:
             return 0
-        return self.state.size // self.state.shape[1] * self.state.itemsize
+        return (len(self.window_refs) - 1 - len(self._window_free)) * self.page_size
+
+    def alloc_window(self, n_pages: int) -> np.ndarray | None:
+        """``n_pages`` pages of the window group, one count each (the
+        caller's); None when fewer are free."""
+        if n_pages > len(self._window_free):
+            return None
+        pages = np.asarray(
+            [self._window_free.pop() for _ in range(n_pages)], np.int32)
+        self.window_refs[pages] = 1
+        self.window_used_peak = max(self.window_used_peak, self.window_slots_used)
+        return pages
+
+    def ref_window(self, pages) -> None:
+        """One more count on each of ``pages`` (held already by someone)."""
+        pages = np.asarray(pages, np.int32)
+        if pages.size and self.window_refs[pages].min() < 1:
+            raise ValueError("a count on a free window page")
+        np.add.at(self.window_refs, pages, 1)
+
+    def unref_window(self, pages) -> int:
+        """One count less on each of ``pages``; those nobody counts on any
+        more return to the free list. Returns how many did."""
+        pages = np.asarray(pages, np.int32)
+        if pages.size == 0:
+            return 0
+        np.add.at(self.window_refs, pages, -1)
+        if self.window_refs[pages].min() < 0 or self.window_refs[0] < 1:
+            raise ValueError("double free of window pages")
+        freed = np.unique(pages[self.window_refs[pages] == 0])
+        self._window_free.extend(int(p) for p in freed)
+        return len(freed)
+
+    def write_window(self, slots: np.ndarray, k: jax.Array, v: jax.Array) -> None:
+        """Write the window layers' K / V ``[layers, n, heads, width]`` of
+        ``n`` tokens at the window group's ``slots`` (a dense prefill's;
+        the paged launches write their own), bucketed as :meth:`write`."""
+        slots = np.asarray(slots, dtype=np.int32)
+        if len(slots) == 0:
+            return
+        sl, (new,) = self._as_written(
+            slots, [jnp.stack([jnp.asarray(k), jnp.asarray(v)])], [2])
+        self.window = _scatter_kv(
+            self.window, sl, new.transpose(0, 1, 3, 2, 4).astype(self.dtype),
+            self.page_size, 0)
 
     # ---- allocation (host side) ----
 
     def alloc(self, n_tokens: int) -> np.ndarray | None:
-        return self.allocator.alloc(n_tokens)
+        got = self.allocator.alloc(n_tokens)
+        if got is not None:
+            self.used_peak = max(
+                self.used_peak, self.num_slots - self.allocator.free_slots)
+        return got
 
     def alloc_state(self) -> int | None:
         """One state slot, or None when every slot is taken."""
@@ -579,11 +674,15 @@ class PagedKVPool:
         a = self.state_allocator
         return 0 if a is None else a.num_slots - a.free_slots
 
-    def write_state(self, slots: np.ndarray, states: jax.Array) -> None:
+    def write_state(self, slots: np.ndarray, states) -> None:
         """Store ``states`` [layers, n, rows, 128] at state slots ``slots``
-        [n] (a dense prefill's; the paged launches write their own)."""
-        self.state = _scatter_state(
-            self.state, jnp.asarray(slots, jnp.int32), states)
+        [n] (a dense prefill's; the paged launches write their own); with
+        conv states beside them, ``states`` is the pair of both."""
+        slots = jnp.asarray(slots, jnp.int32)
+        if self.state_conv is not None:
+            states, conv = states
+            self.state_conv = _scatter_state(self.state_conv, slots, conv)
+        self.state = _scatter_state(self.state, slots, states)
 
     def free(self, slots: np.ndarray) -> None:
         self.allocator.free(slots)

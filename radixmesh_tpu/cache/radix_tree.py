@@ -192,6 +192,7 @@ class TreeNode:
         "shard",
         "id",
         "state",
+        "tail",
     )
 
     def __init__(self, parent: "TreeNode | None" = None):
@@ -232,6 +233,11 @@ class TreeNode:
         # the sequence stood exactly there. Only a tree built with
         # ``on_free_state`` ever sets it.
         self.state: int | None = None
+        # With a state, where the model keeps a group of rows for a window
+        # only: the pages of that group holding the window's tokens BEFORE
+        # this node's end (one count on each is this node's), oldest
+        # first; None: the model has no such group.
+        self.tail: np.ndarray | None = None
 
     @property
     def evicted(self) -> bool:
@@ -285,6 +291,16 @@ class MatchResult:
     # one of these positions, not at ``length``); empty in a tree without
     # states.
     states: list[tuple[int, int]] = field(default_factory=list)
+    # Beside ``states``, entry for entry: each node's window tail (None
+    # where the model keeps no window group).
+    tails: list = field(default_factory=list)
+
+    def tail_at(self, position: int):
+        """The window tail of the state at ``position`` (None: none)."""
+        for (pos, _), tail in zip(self.states, self.tails):
+            if pos == position:
+                return tail
+        return None
 
     def state_at_or_before(self, limit: int) -> tuple[int, int | None]:
         """The deepest ``(position, slot)`` with a state at or before
@@ -360,6 +376,7 @@ class RadixTree:
         on_free_host: Callable[[np.ndarray], None] | None = None,
         shard_fn: Callable[[np.ndarray], int] | None = None,
         on_free_state: Callable[[int], None] | None = None,
+        on_free_tail: Callable[[np.ndarray], None] | None = None,
     ):
         self.page_size = page_size
         self.on_free = on_free
@@ -369,6 +386,9 @@ class RadixTree:
         # reset or state-evicted node gives up. None: no node ever holds
         # one, and no walk looks for one.
         self.on_free_state = on_free_state
+        # Called with the window tail a node gives up with its state (the
+        # counts on those pages were the node's).
+        self.on_free_tail = on_free_tail
         self.state_evictions = 0
         self.on_free_host = on_free_host
         self.enable_events = enable_events
@@ -491,6 +511,7 @@ class RadixTree:
         in_host = False  # device residency is prefix-closed; host extends it
         in_disk = False  # ... and durable disk extents extend the host chain
         states: list[tuple[int, int]] = []
+        tails: list = []
         track = self.on_free_state is not None
         pos = 0
         now = self._time()
@@ -557,6 +578,7 @@ class RadixTree:
                     pos += m
                     if child.state is not None:
                         states.append((pos, child.state))
+                        tails.append(child.tail)
             node = child
             key = key[m:]
         return MatchResult(
@@ -567,6 +589,7 @@ class RadixTree:
             disk_values=disk_values,
             disk_nodes=disk_nodes,
             states=states,
+            tails=tails,
         )
 
     def insert(
@@ -605,8 +628,11 @@ class RadixTree:
             return 0
         return self._insert_helper(self.root, key, value, on_conflict)
 
-    def attach_state(self, key: Sequence[int], slot: int) -> bool:
-        """Hand the tree state slot ``slot``, valid after exactly ``key``
+    def attach_state(self, key: Sequence[int], slot: int,
+                     tail: np.ndarray | None = None) -> bool:
+        """Hand the tree state slot ``slot`` (and, with it, the window
+        ``tail``: pages the caller has counted once for the tree), valid
+        after exactly ``key``
         (a page multiple of tokens, all of them device-resident in the
         tree: the caller just inserted them). The node that ends there —
         split off if ``key`` ends inside one — takes it and True is
@@ -627,27 +653,54 @@ class RadixTree:
             key = key[m:]
         if node is self.root or node.state is not None:
             return False
-        node.state = slot
+        node.state, node.tail = slot, tail
         return True
 
-    def evict_states(self, n: int) -> int:
+    def evict_states(self, n: int, pinned: set | None = None) -> int:
         """Free the states of the ``n`` least recently touched unlocked
         nodes that hold one — pressure on the state slots alone: the rows
         stay, and a later match of them resumes from a shallower state.
-        Returns how many were freed."""
-        held = [x for x in self._all_nodes()
-                if x.state is not None and x.lock_ref == 0]
-        freed = 0
-        for node in heapq.nsmallest(n, held):
+        Returns how many were freed. With ``pinned`` (the slots some
+        request is about to resume from; may be empty) a LOCKED node's
+        state may go too: a lock keeps rows that a page table names, and a
+        state is copied out when a request resumes, its tail counted by
+        the row itself — and of the candidates those go first that a
+        deeper state on the same unbranched path has superseded (a
+        session's earlier turns), then those at a locked leaf (a live
+        row's own prompt: its release will leave a deeper one), whatever
+        their age; what a waiting request would resume from goes last."""
+        if pinned is None:
+            held = [x for x in self._all_nodes()
+                    if x.state is not None and x.lock_ref == 0]
+            order = heapq.nsmallest(n, held)
+        else:
+            held = [x for x in self._all_nodes()
+                    if x.state is not None and x.state not in pinned]
+            order = sorted(held, key=lambda x: (
+                0 if self._superseded(x)
+                else 1 if x.lock_ref > 0 and not x.children else 2,
+                x.last_access_time))[:n]
+        for node in order:
             self._drop_state(node)
-            freed += 1
-        self.state_evictions += freed
-        return freed
+        self.state_evictions += len(order)
+        return len(order)
+
+    @staticmethod
+    def _superseded(node: TreeNode) -> bool:
+        """Does a deeper state lie below ``node`` before the path branches?"""
+        while len(node.children) == 1:
+            (node,) = node.children.values()
+            if node.state is not None:
+                return True
+        return False
 
     def _drop_state(self, node: TreeNode) -> None:
         if node.state is not None:
             slot, node.state = node.state, None
             self.on_free_state(slot)
+            if node.tail is not None:
+                tail, node.tail = node.tail, None
+                self.on_free_tail(tail)
 
     def peek_continuation(self, key: Sequence[int], max_tokens: int) -> np.ndarray:
         """Tokens the cache holds BEYOND ``key`` — the speculative drafter's

@@ -102,6 +102,25 @@ _KV_BLOCK_PAGES = 32
 # snapshots one pressure on the state slots evicts.
 _STATE_SNAPS = 3
 _STATE_EVICT_BATCH = 64
+# ... and of snapshots that hold a window tail too (each frees up to a
+# window of rows: 20 MiB at published widths, not 240 KiB).
+_TAIL_EVICT_BATCH = 8
+
+
+def pool_groups(cfg: ModelConfig, num_slots: int, max_batch: int,
+                page_size: int) -> dict:
+    """What ``PagedKVPool`` holds beside the rows for ``cfg``, every size
+    derived: the state slots of a model whose layers carry a state (and
+    the conv states beside a float32 recurrent part), the window group of
+    one whose window layers keep rows of their own."""
+    n_states = cfg.default_state_slots(num_slots, max_batch)
+    return dict(
+        state_shape=cfg.state_shape(n_states),
+        state_dtype=cfg.state_dtype,
+        conv_state_shape=cfg.conv_state_shape(n_states),
+        window_shape=cfg.window_pool_shape(
+            cfg.default_window_slots(max_batch, page_size)),
+    )
 
 
 def prefill_launch_shape(
@@ -209,6 +228,11 @@ class _DecodeLaunch:
     # the new state goes besides (its boundary slot when this token
     # completes a page, else slot 0), [2, max_batch], on the device.
     state_d: jax.Array | None = None
+    # A model with a window group of rows: each row's slot IN that group
+    # for this token, and its page table there (logical pages, as
+    # ``pt_d``; what left the window reads the group's scratch page).
+    w_slots_d: jax.Array | None = None
+    w_pt_d: jax.Array | None = None
 
 
 @dataclass
@@ -555,6 +579,7 @@ class Engine:
                 index_shape=cfg.kv_index_shape(pool.num_slots),
                 state_shape=cfg.state_shape(
                     cfg.default_state_slots(pool.num_slots, max_batch)),
+                window_slots=cfg.default_window_slots(max_batch, page_size),
             )
             if kv_quant is None:
                 expected["dtype"] = cfg.dtype
@@ -596,9 +621,9 @@ class Engine:
                 planes=row_planes,
                 index_shape=cfg.kv_index_shape(num_slots),
                 # Derived, not a knob: what the pool's size and the batch
-                # call for (ModelConfig.default_state_slots).
-                state_shape=cfg.state_shape(
-                    cfg.default_state_slots(num_slots, max_batch)),
+                # call for (ModelConfig.default_state_slots /
+                # default_window_slots).
+                **pool_groups(cfg, num_slots, max_batch, page_size),
             )
         if kernels and not cfg.latent:
             from radixmesh_tpu.ops.paged_attention import decode_block_tokens
@@ -661,6 +686,8 @@ class Engine:
                 page_size=page_size, on_free=self.pool.free,
                 on_free_state=(
                     None if self.pool.state is None else self.pool.free_state),
+                on_free_tail=(
+                    None if self.pool.window is None else self._tail_freed),
             )
         # state: a model whose layers carry a state beside the rows
         # (None otherwise). kv_matched_tokens: what the tree's rows alone
@@ -681,6 +708,44 @@ class Engine:
                 "resumed_tokens": 0,
                 "recomputed_tokens": 0,
             }
+            self.log.info(
+                "state slots beside the rows: %d x %d B a snapshot",
+                self.pool.state.shape[1], self.pool.state_slot_bytes)
+        # window: a model whose window layers keep a group of rows of their
+        # own (None otherwise). pages_released: pages a live row gave up
+        # because they left its window; slots_written: rows the launches
+        # wrote there; slots_held_by_rows / held_peak_row: what the rows of
+        # the last decode launch held, summed, and the most any row of any
+        # launch held; tail_slots_in_tree: rows the tree's snapshots count
+        # on. The slots a request is about to resume from are pinned
+        # against eviction until its first chunk has read them.
+        self.window_stats = None
+        self._resuming: collections.Counter = collections.Counter()
+        if self.pool.window is not None:
+            self.window_stats = {
+                "slots": self.pool.window_slots,
+                "pages_released": 0,
+                "slots_written": 0,
+                "slots_held_by_rows": 0,
+                "held_peak_row": 0,
+                "tail_slots_in_tree": 0,
+            }
+            # context tokens x reading layers a decode step: the one full
+            # layer's K/V read by itself and every cross-attention layer
+            self.attn_window["shared_kv_tokens_read"] = 0
+            self._kv_readers = 1 + cfg.layer_types.count("cross_attention")
+            gib = 2.0 ** 30
+            self.log.info(
+                "two groups of rows: full %d slots x %d B = %.2f GiB (%d "
+                "reading layers), window %d slots x %d B = %.2f GiB; %d state "
+                "slots x %d B = %.2f GiB",
+                self.pool.num_slots, self.pool.kv.nbytes // self.pool.num_slots,
+                self.pool.kv.nbytes / gib, self._kv_readers,
+                self.pool.window_slots,
+                self.pool.window.nbytes // self.pool.window_slots,
+                self.pool.window.nbytes / gib, self.pool.state.shape[1],
+                self.pool.state_slot_bytes,
+                (self.pool.state.nbytes + self.pool.state_conv.nbytes) / gib)
         self._kv_tier_watermark = float(kv_tier_watermark)
         self._kv_tier_min_heat = float(kv_tier_min_heat)
         self._kv_tier_destage_budget = int(kv_tier_destage_budget)
@@ -751,6 +816,10 @@ class Engine:
             (max_batch, maxp_b), self._scratch_page, dtype=np.int32
         )
         self._page_table = self._page_table_padded[:, : self.max_pages]
+        # The window group's table, same geometry (page 0 is its scratch).
+        self._w_page_table_padded = (
+            None if self.pool.window is None
+            else np.zeros((max_batch, maxp_b), dtype=np.int32))
         self._temps = np.zeros(max_batch, dtype=np.float32)
         self._top_ps = np.ones(max_batch, dtype=np.float32)
         self._top_ks = np.zeros(max_batch, dtype=np.int32)
@@ -992,6 +1061,15 @@ class Engine:
         )
         if not (0 < len(req.prompt) < self.max_seq_len):
             raise ValueError(f"prompt length {len(req.prompt)} out of range")
+        if self.window_stats is not None:
+            # A prefill holds the window pages of its whole uncached span
+            # until it installs: a prompt the group cannot hold would wait
+            # at the head of the queue for ever.
+            have = self.pool.window_slots - self.page_size  # the scratch page
+            if len(req.prompt) > have:
+                raise ValueError(
+                    f"prompt length {len(req.prompt)} exceeds the window "
+                    f"group's {have} slots")
         if resume_offset:
             self.stats.resurrections += 1
             # The whole resumed prompt is replay: the original prompt AND
@@ -1951,6 +2029,10 @@ class Engine:
             # The rows alone serve no prefix: the request resumes at the
             # deepest snapshot at or before them, and computes from there.
             reuse, resume = match.state_at_or_before(matched)
+            if resume and self.window_stats is not None:
+                # Pinned before anything below can evict it: there a lock
+                # does not keep a snapshot (``_evict_states``).
+                self._resuming[resume] += 1
         prefix_slots = match.indices()[:reuse]
         self.tree.inc_lock_ref(match.last_node)
         req.lock_node = match.last_node
@@ -1960,7 +2042,15 @@ class Engine:
                 if not self._acquire_states(req, match, matched, reuse, resume):
                     self.pool.free(own)
                     own = None
+                elif self.window_stats is not None and not self._acquire_window(
+                        req, match.tail_at(reuse), reuse):
+                    self._free_states(req)
+                    resume = None  # unpinned with the states
+                    self.pool.free(own)
+                    own = None
         if own is None:
+            if self.window_stats is not None and resume and not req.state_resume:
+                self._unpin(resume)
             self.tree.dec_lock_ref(req.lock_node)
             req.lock_node = None
             return None
@@ -1974,9 +2064,88 @@ class Engine:
         """A state slot, evicting the tree's least recently used
         snapshots when none is free (the rows stay); 0: none to be had."""
         slot = self.pool.alloc_state()
-        if slot is None and self.tree.evict_states(_STATE_EVICT_BATCH):
+        if slot is None and self._evict_states(_STATE_EVICT_BATCH):
             slot = self.pool.alloc_state()
         return slot or 0
+
+    def _evict_states(self, n: int) -> int:
+        """Pressure on the snapshots. With a window group a snapshot is 23
+        MiB, not 240 KiB, and a session's path holds one or two a turn
+        under its lock: there the locked ones may go too, superseded ones
+        first, all but those a request is about to resume from
+        (``RadixTree.evict_states``)."""
+        if self.window_stats is None:
+            return self.tree.evict_states(n)
+        return self.tree.evict_states(min(n, _TAIL_EVICT_BATCH),
+                                      pinned=self._resuming)
+
+    # ------------------------------------------------------------------
+    # the window group (a model whose window layers keep rows of their own)
+    # ------------------------------------------------------------------
+
+    def _alloc_window(self, n_pages: int) -> np.ndarray | None:
+        """Pages of the window group, evicting the tree's snapshots (state
+        and tail) while that frees any; None: none to be had."""
+        pages = self.pool.alloc_window(n_pages)
+        while pages is None and self._evict_states(_TAIL_EVICT_BATCH):
+            pages = self.pool.alloc_window(n_pages)
+        return pages
+
+    def _tail_freed(self, tail: np.ndarray) -> None:
+        """The tree gave up a snapshot's window tail."""
+        self.pool.unref_window(tail)
+        self.window_stats["tail_slots_in_tree"] -= len(tail) * self.page_size
+
+    def _acquire_window(self, req: Request, tail, reuse: int) -> bool:
+        """The window pages a request's prefill needs: a count on every
+        page of the tail of the snapshot it resumes from (read-only: they
+        hold the window before ``reuse``) and pages of its own from
+        ``reuse`` to the prompt's end. False, nothing held, if the group
+        has none to give."""
+        ps = self.page_size
+        tail = np.empty(0, np.int32) if tail is None else tail
+        self.pool.ref_window(tail)  # before any eviction could free them
+        own = self._alloc_window(-(-(len(req.prompt) - reuse) // ps))
+        if own is None:
+            self.pool.unref_window(tail)
+            return False
+        req.w_first = reuse // ps - len(tail)
+        req.w_pages = [*tail.tolist(), *own.tolist()]
+        return True
+
+    def _unpin(self, slot: int) -> None:
+        """One request less is about to resume from state slot ``slot``."""
+        if slot and self._resuming[slot] > 0:
+            self._resuming[slot] -= 1
+            if not self._resuming[slot]:
+                del self._resuming[slot]
+
+    def _free_window(self, req: Request) -> None:
+        if req.w_pages:
+            self.pool.unref_window(req.w_pages)
+        req.w_first, req.w_pages = 0, []
+
+    def _release_window(self, req: Request, length: int) -> None:
+        """Give back the pages that have left the window of a row that
+        holds ``length`` tokens: those wholly before the ``sliding_window``
+        tokens that end with the last page boundary at or before
+        ``length`` (what the next positions attend, and what a snapshot
+        cut there takes as its tail) — and not before the tail of a
+        snapshot the row has yet to hand over."""
+        ps = self.page_size
+        pending = [pos for pos, _ in req.state_snaps]
+        keep = (min([length, *pending]) // ps) - self.cfg.sliding_window // ps
+        n = min(keep - req.w_first, len(req.w_pages))
+        if n <= 0:
+            return
+        with self.spans.span("rm.window.release"):
+            gone, req.w_pages = req.w_pages[:n], req.w_pages[n:]
+            if req.row >= 0:
+                self._w_page_table_padded[
+                    req.row, req.w_first:req.w_first + n] = 0
+            req.w_first += n
+            self.pool.unref_window(gone)
+            self.window_stats["pages_released"] += n
 
     def _acquire_states(
         self, req: Request, match, matched: int, reuse: int, resume: int | None
@@ -2021,9 +2190,12 @@ class Engine:
                      *(s for _, s in req.state_snaps)):
             if slot:
                 self.pool.free_state(slot)
+        self._unpin(req.state_resume)
         req.state_slot = req.state_boundary = req.state_resume = 0
         req.state_boundary_len = 0
         req.state_snaps = []
+        if self.window_stats is not None:
+            self._free_window(req)
 
     def _hand_over_states(self, req: Request, key: np.ndarray) -> None:
         """Give the tree every snapshot of ``req`` that lies on the key
@@ -2032,12 +2204,30 @@ class Engine:
         for pos, slot in req.state_snaps:
             if pos > len(key):
                 keep.append((pos, slot))
-            elif self.tree.attach_state(key[:pos], slot):
+                continue
+            tail = None if self.window_stats is None else self._tail_of(req, pos)
+            if self.tree.attach_state(key[:pos], slot, tail):
                 self.state_stats["snapshots"] += 1
+                if tail is not None:
+                    self.window_stats["tail_slots_in_tree"] += (
+                        len(tail) * self.page_size)
             else:
                 self.pool.free_state(slot)
+                if tail is not None:
+                    self.pool.unref_window(tail)
                 self.state_stats["snapshots_declined"] += 1
         req.state_snaps = keep
+
+    def _tail_of(self, req: Request, pos: int) -> np.ndarray:
+        """The window pages of the ``sliding_window`` tokens before the
+        page-aligned ``pos`` of ``req``'s sequence, counted once more: a
+        snapshot's tail, for the tree to hold."""
+        ps = self.page_size
+        end = pos // ps - req.w_first
+        first = max(0, end - self.cfg.sliding_window // ps)
+        tail = np.asarray(req.w_pages[first:end], np.int32)
+        self.pool.ref_window(tail)
+        return tail
 
     def _chunk_states(self, group, starts, nv, B: int, first: bool):
         """``(state_in [B], snap_at [B, 3], state_out [B, 3])`` of one
@@ -2105,6 +2295,13 @@ class Engine:
             self.stats.replayed_cached_tokens += reuse
 
         self._publish(req, len(req.prompt))
+        if self.window_stats is not None:
+            # The snapshots are the tree's now: what left the window goes
+            # back (a prefill holds its whole new span until here).
+            self._release_window(req, req.kv_len)
+            self._w_page_table_padded[row] = 0
+            self._w_page_table_padded[
+                row, req.w_first:req.w_first + len(req.w_pages)] = req.w_pages
 
         self._rows[row] = req
         if req.output_tokens:
@@ -2303,7 +2500,10 @@ class Engine:
         kw = self._moe_kw()
         if self.pool.index is not None:
             kw["index_pool"] = self.pool.index
-        if self.pool.state is not None:
+        if self.pool.window is not None:
+            kw["window_pool"] = self.pool.window
+            kw["state_pool"] = (self.pool.state, self.pool.state_conv)
+        elif self.pool.state is not None:
             kw["state_pool"] = self.pool.state
         return kw
 
@@ -2347,6 +2547,9 @@ class Engine:
             wanted = [(n_new, req.state_slot), *req.state_snaps]
             wanted += [(0, 0)] * (_STATE_SNAPS - len(wanted))
             moe_kw["snap_at"] = jnp.asarray([[at for at, _ in wanted]], jnp.int32)
+        windowed = self.window_stats is not None
+        if windowed:  # the full layer's last position alone is computed
+            moe_kw["new_lengths"] = jnp.full((1,), n_new, dtype=jnp.int32)
         logits, new_k, new_v, *aux = prefill_forward(
             self.params,
             self.cfg,
@@ -2363,10 +2566,18 @@ class Engine:
             self._note_sparse(
                 "prefill", 1 + np.arange(n_new, dtype=np.int64),
                 s_b > self.cfg.index_topk)
+        if windowed:  # the window layers' rows, to the window group
+            win_k, win_v, *aux = aux
+            at = np.arange(n_new)
+            w_slots = (np.asarray(req.w_pages, np.int32)[at // self.page_size]
+                       * self.page_size + at % self.page_size)
+            self.pool.write_window(w_slots, win_k[:, 0, :n_new], win_v[:, 0, :n_new])
+            self.window_stats["slots_written"] += n_new
         if self.state_stats is not None:
             states, *aux = aux
             self.pool.write_state(
-                np.asarray([slot for _, slot in wanted], np.int32), states[:, 0])
+                np.asarray([slot for _, slot in wanted], np.int32),
+                jax.tree_util.tree_map(lambda a: a[:, 0], states))
         if aux:
             self._moe_stats = aux[0]["moe_stats"]
             self._last_expert_ids = aux[0]["expert_ids"]
@@ -2382,7 +2593,7 @@ class Engine:
         req.token_slots = own[:n_new].copy()
         req.own_slots = own
         self._install_prefilled(req, row, 0)
-        return (req, logits[0, n_new - 1])
+        return (req, logits[0, 0 if windowed else n_new - 1])
 
     def _pp_n_micro(self, batch: int) -> int:
         """GPipe microbatch count for a pp launch: fill every stage when
@@ -2518,7 +2729,8 @@ class Engine:
         B, C, maxp = prefill_launch_shape(
             N, int(new.max()), int(totals.max()), chunk=self.prefill_chunk,
             page_size=ps, max_pages=self.max_pages,
-            width_selects=self.cfg.dsa, one_width=self.cfg.has_conv,
+            width_selects=self.cfg.dsa,
+            one_width=self.cfg.has_conv or self.cfg.has_ssm,
         )
         n_chunks = -(-int(new.max()) // C)
         span = n_chunks * C
@@ -2534,6 +2746,16 @@ class Engine:
             token_slots_all.append(ts)
             pt[i, : -(-totals[i] // ps)] = ts[::ps] // ps
         pt_dev = jnp.asarray(pt)
+        windowed = self.window_stats is not None
+        if windowed:  # the rows' slots and tables in the window group
+            w_sl_all = np.zeros((B, span), dtype=np.int32)
+            wt = np.zeros((B, maxp), dtype=np.int32)
+            for i, (req, _, reuse, _, _) in enumerate(group):
+                wt[i, req.w_first:req.w_first + len(req.w_pages)] = req.w_pages
+                at = reuse + np.arange(new[i])
+                w_sl_all[i, : new[i]] = wt[i, at // ps] * ps + at % ps
+            wt_dev = jnp.asarray(wt)
+            self.window_stats["slots_written"] += int(new.sum())
         offs = np.arange(C, dtype=np.int64)
         keeps = any(g[0].sampling.return_logits for g in group)
 
@@ -2566,6 +2788,10 @@ class Engine:
             states = None
             if self.state_stats is not None:
                 states = self._chunk_states(group, starts, nv, B, ci == 0)
+                if windowed:
+                    states["window_slots"] = jnp.asarray(
+                        w_sl_all[:, ci * C : (ci + 1) * C])
+                    states["window_table"] = wt_dev
             res = self._forward_chunk(
                 jnp.asarray(toks_all[:, ci * C : (ci + 1) * C]),
                 jnp.asarray(poss),
@@ -2577,6 +2803,10 @@ class Engine:
                 states=states,
             )
             logits = self._commit_pool_update(res)
+            if ci == 0 and self._resuming:  # the snapshots have been read
+                for g in group:
+                    self._unpin(g[0].state_resume)
+                    g[0].state_resume = 0
             for i in np.flatnonzero(last).tolist():
                 final_logits[i] = logits[i, 0]
             if keeps:  # the logits hook: expert ids, selected rows
@@ -2694,6 +2924,8 @@ class Engine:
         if req.row >= 0:
             self._rows[req.row] = None
             self._page_table[req.row] = self._scratch_page
+            if self._w_page_table_padded is not None:
+                self._w_page_table_padded[req.row] = 0
             self._tokens[req.row] = 0
             req.row = -1
 
@@ -2717,6 +2949,9 @@ class Engine:
             out, self.pool.kv, self.pool.kv_scale = res
         elif self.pool.index is not None:
             out, self.pool.kv, self.pool.index = res
+        elif self.pool.window is not None:
+            out, self.pool.kv, self.pool.window, states = res
+            self.pool.state, self.pool.state_conv = states
         elif self.pool.state is not None:
             out, self.pool.kv, self.pool.state = res
         else:
@@ -2816,6 +3051,10 @@ class Engine:
         aw["window_tokens_skipped"] += launch.window[1]
         if launch.latent_tokens:
             aw["latent_tokens_read"] += launch.latent_tokens
+        if self.window_stats is not None:
+            aw["shared_kv_tokens_read"] += (
+                int(launch.contexts.sum()) * self._kv_readers)
+            self.window_stats["slots_written"] += len(launch.idx)
         if self.cfg.dsa:
             self._note_sparse(
                 "decode", launch.contexts.astype(np.int64),
@@ -2844,6 +3083,9 @@ class Engine:
                         "snap_at": jnp.ones((self.max_batch, 2), jnp.int32),
                         "state_out": launch.state_d.T,
                     }
+                    if launch.w_slots_d is not None:
+                        states["window_slots"] = launch.w_slots_d[:, None]
+                        states["window_table"] = launch.w_pt_d
                 res = self._forward_chunk(
                     tokens_d[:, None],
                     launch.positions_d[:, None],
@@ -2869,6 +3111,9 @@ class Engine:
                     **self._pool_kw(),
                     **({} if launch.state_d is None else {
                         "state_slots": launch.state_d}),
+                    **({} if launch.w_slots_d is None else {
+                        "window_slots": launch.w_slots_d,
+                        "window_table": launch.w_pt_d}),
                 )
                 logits = self._commit_pool_update(res)
         asked = [(row, r) for row, r in active if r.sampling.return_logits]
@@ -3010,12 +3255,20 @@ class Engine:
         lengths = np.ones(self.max_batch, dtype=np.int32)
         stateful = self.state_stats is not None
         state_slots = np.zeros((2, self.max_batch), dtype=np.int32)
+        windowed = self.window_stats is not None
+        w_slots = np.zeros(self.max_batch, dtype=np.int32)
         starved: list[Request] = []
         for row, req in enumerate(self._rows):
             if req is None:
                 continue
             page_idx, offset = divmod(req.kv_len + ahead, ps)
             page = self._ensure_page(row, req, page_idx)
+            if page is not None and windowed:
+                w_page = self._ensure_window_page(row, req, page_idx)
+                if w_page is None:
+                    page = None
+                else:
+                    w_slots[row] = w_page * ps + offset
             if page is None:  # crossing into a fresh page, and none to take
                 if not preempt:
                     return None
@@ -3043,6 +3296,13 @@ class Engine:
                 int(read.sum()) * self._sliding_layers,
                 int((ctx - read).sum()) * self._sliding_layers,
             )
+        pt = (self._decode_pt_bucket(1 + ahead, floor=_KV_BLOCK_PAGES)
+              if chunked else self._decode_pt_bucket(1 + ahead))
+        if windowed:
+            held = [len(r.w_pages) * ps for _, r in active]
+            ws = self.window_stats
+            ws["slots_held_by_rows"] = sum(held)
+            ws["held_peak_row"] = max(ws["held_peak_row"], max(held))
         return _DecodeLaunch(
             active=active,
             sig=[(row, r.rid, r.kv_len + ahead) for row, r in active],
@@ -3056,14 +3316,13 @@ class Engine:
             ),
             contexts=lengths[idx],
             slots_d=jnp.asarray(slots),
-            pt_d=jnp.asarray(
-                self._decode_pt_bucket(1 + ahead, floor=_KV_BLOCK_PAGES)
-                if chunked
-                else self._decode_pt_bucket(1 + ahead)
-            ),
+            pt_d=jnp.asarray(pt),
             lengths_d=jnp.asarray(lengths),
             positions_d=jnp.asarray(lengths - 1) if chunked else None,
             state_d=jnp.asarray(state_slots) if stateful else None,
+            w_slots_d=jnp.asarray(w_slots) if windowed else None,
+            w_pt_d=jnp.asarray(
+                self._w_page_table_padded[:, : pt.shape[1]]) if windowed else None,
         )
 
     def _take_prebuilt(self, active: list[tuple]) -> _DecodeLaunch | None:
@@ -3238,6 +3497,20 @@ class Engine:
         page = int(new[0]) // self.page_size
         self._page_table[row, page_idx] = page
         return page
+
+    def _ensure_window_page(self, row: int, req: Request, page_idx: int) -> int | None:
+        """:meth:`_ensure_page` for the window group: the page behind
+        logical page ``page_idx`` of the row, taken (one count, the row's)
+        where the row has none yet; None when the group has none to give."""
+        at = page_idx - req.w_first
+        if at < len(req.w_pages):
+            return req.w_pages[at]
+        new = self._alloc_window(1)
+        if new is None:
+            return None
+        req.w_pages.append(int(new[0]))
+        self._w_page_table_padded[row, page_idx] = new[0]
+        return int(new[0])
 
     def _provision_rows(
         self, extra: int, extras: dict[int, int] | None = None
@@ -3610,6 +3883,8 @@ class Engine:
         if req.state_boundary and req.kv_len % self.page_size == 0:
             # The step completed a page: its state is in the boundary slot.
             req.state_boundary_len = req.kv_len
+            if self.window_stats is not None:
+                self._release_window(req, req.kv_len)
         req.output_tokens.append(token)
         self.stats.generated_tokens += 1
         finished = (

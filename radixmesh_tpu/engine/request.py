@@ -97,6 +97,12 @@ class Request:
     state_boundary_len: int = 0
     state_resume: int = 0
     state_snaps: list = field(default_factory=list)
+    # A model with a window group of rows: the pages of that group this
+    # request counts on, for logical pages ``w_first ..`` of its sequence
+    # in order (the tail of the snapshot it resumed from, then its own);
+    # what left the window has been given back from the front.
+    w_first: int = 0
+    w_pages: list = field(default_factory=list)
     cancelled: bool = False  # aborted by Engine.cancel (output is partial)
     # Set when the engine step serving this request raised: the message
     # the runner fails the request with (server/http_frontend.py).

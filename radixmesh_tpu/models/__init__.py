@@ -7,7 +7,7 @@ from radixmesh_tpu.models.llama import (
     convert_hf_state_dict,
 )
 from radixmesh_tpu.models import (
-    glm4_moe_lite, glm_moe_dsa, lfm2_moe, mellum2, qwen2,
+    glm4_moe_lite, glm_moe_dsa, lfm2_moe, mellum2, phi4flash, qwen2,
 )
 
 _PRESETS = {
@@ -35,6 +35,8 @@ _PRESETS = {
     "lfm2-24b-a2b": lfm2_moe.lfm2_24b_a2b,
     "lfm2-24b-a2b-ep8": lfm2_moe.lfm2_24b_a2b_ep8,
     "lfm2-tiny": lfm2_moe.lfm2_tiny,
+    "phi-4-mini-flash-reasoning": phi4flash.phi4_mini_flash_reasoning,
+    "phi4flash-tiny": phi4flash.phi4flash_tiny,
 }
 
 

@@ -80,8 +80,9 @@ from radixmesh_tpu.ops.mla_attention import (
 )
 from radixmesh_tpu.ops import dsa
 from radixmesh_tpu.ops.moe import SHARE_STATS as MOE_STATS, STATS, moe_ffn
-from radixmesh_tpu.ops.norm import rms_norm
+from radixmesh_tpu.ops.norm import layer_norm, rms_norm
 from radixmesh_tpu.ops.rope import apply_rope, rope_frequencies, yarn_frequencies
+from radixmesh_tpu.ops.ssm import selective_scan, ssm_step
 from radixmesh_tpu.ops.wquant import quantize_weight
 
 __all__ = [
@@ -192,9 +193,40 @@ class ModelConfig:
     # padding: a 64-wide row is stored 128 wide by the device's tiling),
     # and the paged kernels see that many "wide" kv heads (``_pair_heads``).
     pair_kv_heads: bool = False
+    # Selective state-space layers (``layer_types`` entry "mamba": Mamba-1,
+    # ``ssm_state`` > 0) in a decoder-hybrid-decoder (``models/
+    # phi4flash.py``): pairs of (mamba, sliding_attention), one more mamba
+    # layer whose scan output is kept per token as the MEMORY, the one
+    # full_attention layer, then pairs of ("gmu", "cross_attention") — a
+    # gmu layer gates the memory, a cross_attention layer has a query
+    # projection only and reads the full layer's K/V. A mamba layer
+    # leaves no cache row: it carries ``[ssm_state, ssm_expand * hidden]``
+    # float32 and the last ``ssm_conv - 1`` inputs of its convolution
+    # (``state_shape`` / ``conv_state_shape``). The cache rows are two
+    # groups with an allocator each: the full layer's (``kv_pool_shape``,
+    # every token) and the sliding layers' (``window_pool_shape``, the
+    # last ``sliding_window`` tokens of a row).
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 0
+    # Differential attention: heads pair up, the second softmax map is
+    # subtracted ``lambda`` times, an RMSNorm over the pair's 2 * head_dim
+    # outputs follows (``_diff_combine``). ``use_rope`` False: no
+    # positional encoding. ``layer_norm``: LayerNorm with weight and bias
+    # where the others take RMSNorm. ``attn_out_bias``: a bias on wo.
+    diff_attention: bool = False
+    use_rope: bool = True
+    layer_norm: bool = False
+    attn_out_bias: bool = False
 
     def __post_init__(self):
-        if self.layer_types is not None:
+        if self.layer_types is not None and (
+            self.ssm_state or {"mamba", "gmu", "cross_attention"}
+            & set(self.layer_types)
+        ):
+            self._check_hybrid()
+        elif self.layer_types is not None:
             kinds = set(self.layer_types)
             if len(self.layer_types) != self.n_layers or not (
                 kinds <= {"sliding_attention", "full_attention"}
@@ -239,6 +271,10 @@ class ModelConfig:
                 "it needs head_dim == 64, an even n_kv_heads and no latent "
                 "attention"
             )
+        if self.diff_attention and not self.has_ssm:
+            raise ValueError(
+                "diff_attention's weights live in the attention stacks of a "
+                "config with mamba layers: no other config has them")
         if self.qk_norm and not self.has_conv:
             raise ValueError(
                 "qk_norm's weights live in the attention stack of a config "
@@ -293,8 +329,87 @@ class ModelConfig:
                 "divisible by ep_size"
             )
 
+    def _check_hybrid(self) -> None:
+        """A config with mamba / gmu / cross_attention layers runs as one
+        shape only (class docstring); anything else is refused by name."""
+        types = tuple(self.layer_types)
+        kinds = set(types)
+        known = {"mamba", "sliding_attention", "full_attention", "gmu",
+                 "cross_attention"}
+        if len(types) != self.n_layers or not kinds <= known:
+            raise ValueError(
+                f"layer_types must name {self.n_layers} layers, got "
+                f"{len(types)} of {sorted(kinds)}")
+        full = [i for i, t in enumerate(types) if t == "full_attention"]
+        for i, t in enumerate(types):
+            if t == "cross_attention" and not any(f < i for f in full):
+                raise ValueError(
+                    f"layer {i} is cross_attention with no full_attention "
+                    "layer below it: it has no K/V to read")
+            if t == "gmu" and "mamba" not in types[:i]:
+                raise ValueError(
+                    f"layer {i} is gmu with no mamba layer below it: it has "
+                    "no memory to gate")
+        if "mamba" not in kinds or len(full) != 1 or full[0] < 1:
+            raise ValueError(
+                "a config with state-space layers needs mamba layers and "
+                "exactly one full_attention layer above the first of them")
+        f = full[0]
+        low, up = types[:f - 1], types[f + 1:]
+        if (types[f - 1] != "mamba" or not low
+                or low != ("mamba", "sliding_attention") * (len(low) // 2)
+                or up != ("gmu", "cross_attention") * (len(up) // 2)):
+            raise ValueError(
+                "layer_types this program runs: (mamba, sliding_attention) "
+                "pairs, mamba, full_attention, (gmu, cross_attention) pairs; "
+                f"got {types}")
+        if not (self.ssm_state > 0 and self.ssm_conv >= 2 and self.ssm_dt_rank > 0
+                and (self.ssm_expand * self.hidden) % 128 == 0):
+            raise ValueError(
+                "mamba layers need ssm_state, ssm_dt_rank > 0, ssm_conv >= 2 "
+                "and ssm_expand * hidden in whole 128-lane rows")
+        if "sliding_attention" in kinds and (
+                self.sliding_window < 2 or self.sliding_window % 16):
+            raise ValueError(
+                "the window layers' rows are given back by whole pages: "
+                "sliding_window must be a multiple of the page (16), got "
+                f"{self.sliding_window}")
+        if not (self.diff_attention and self.pair_kv_heads
+                and self.n_heads == 2 * self.n_kv_heads and not self.use_rope
+                and self.layer_norm and self.qkv_bias and self.attn_out_bias
+                and self.tie_embeddings) or self.n_experts or self.latent:
+            raise ValueError(
+                "a config with state-space layers runs differential "
+                "attention over paired heads of 64 (n_heads = 2 * "
+                "n_kv_heads), no rotary, LayerNorm, qkv and output biases, "
+                "a tied head, dense FFNs: nothing else is implemented")
+
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+    @property
+    def has_ssm(self) -> bool:
+        return bool(self.layer_types) and "mamba" in self.layer_types
+
+    def _layers_of(self, kind: str) -> tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.layer_types or ())
+                     if t == kind)
+
+    @property
+    def mamba_layers(self) -> tuple[int, ...]:
+        """The selective state-space layers (each carries a state); the
+        last of them is the one whose scan output is the memory."""
+        return self._layers_of("mamba")
+
+    @property
+    def window_layers(self) -> tuple[int, ...]:
+        """The sliding layers of a config with mamba layers: they own the
+        rows of the WINDOW group (``window_pool_shape``)."""
+        return self._layers_of("sliding_attention") if self.has_ssm else ()
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_expand * self.hidden
 
     @property
     def has_window(self) -> bool:
@@ -314,6 +429,8 @@ class ModelConfig:
     def attn_layers(self) -> tuple[int, ...]:
         """The layers that own cache rows: all of them, or, beside conv
         layers, the attention layers alone."""
+        if self.has_ssm:  # the full layer: the window layers' rows are
+            return self._layers_of("full_attention")  # another group
         if not self.has_conv:
             return tuple(range(self.n_layers))
         return tuple(i for i, t in enumerate(self.layer_types) if t != "conv")
@@ -365,6 +482,55 @@ class ModelConfig:
         planes, heads, width = self.kv_row
         return (planes, len(self.attn_layers), heads, num_slots, width)
 
+    def window_pool_shape(self, num_slots: int) -> tuple | None:
+        """The WINDOW group of a config with mamba layers: the sliding
+        layers' rows, ``[planes, sliding layers, heads, slots, width]``,
+        under an allocator of its own — a row of the batch holds them for
+        the last ``sliding_window`` tokens only and gives the pages before
+        back (``engine/engine.py``). None for every other config: its
+        window layers, if any, share the one pool array."""
+        if not self.has_ssm:
+            return None
+        planes, heads, width = self.kv_row
+        return (planes, len(self.window_layers), heads, num_slots, width)
+
+    def default_window_slots(self, max_batch: int, page_size: int,
+                             wave_tokens: int = 4096) -> int:
+        """Slots of the window group, derived: every batch row's window
+        and the two pages around it, the window tails of the snapshots the
+        tree has to keep (five for every four rows: what a waiting caller
+        of a closed loop of two callers a row resumes from, and the shared
+        preambles; a live row's own snapshots go first under pressure and
+        share pages with the row), one prefill wave's chunk rows, the
+        scratch page. 0 without the group."""
+        if not self.has_ssm:
+            return 0
+        w = self.sliding_window
+        want = (max_batch * (w + 2 * page_size) + (5 * max_batch // 4) * w
+                + wave_tokens + page_size)
+        return -(-want // page_size) * page_size
+
+    def conv_state_shape(self, num_states: int) -> tuple | None:
+        """Beside ``state_shape`` of a config with mamba layers: the last
+        ``ssm_conv - 1`` inputs of each layer's convolution, ``[mamba
+        layers, slots, rows, 128]`` in the model's dtype, under the state
+        slots' allocator (one snapshot = one slot of both arrays)."""
+        if not self.has_ssm:
+            return None
+        rows = (self.ssm_conv - 1) * self.ssm_inner // 128
+        return (len(self.mamba_layers), num_states, rows, 128)
+
+    def state_slot_bytes(self) -> int:
+        """Bytes of one snapshot (every state-bearing layer)."""
+        item = jnp.dtype(self.dtype).itemsize
+        if self.has_ssm:
+            per = (self.ssm_state * 4 + (self.ssm_conv - 1) * item) * self.ssm_inner
+            return len(self.mamba_layers) * per
+        if self.has_conv:
+            return (len(self.conv_layers) * (self.conv_L_cache - 1)
+                    * self.hidden * item)
+        return 0
+
     def state_shape(self, num_states: int) -> tuple[int, int, int, int] | None:
         """The state slots beside the pool, ``[conv layers, slots, rows,
         128]``: one slot holds, for every conv layer, the ``conv_L_cache -
@@ -372,22 +538,46 @@ class ModelConfig:
         whole 128-lane rows (a ``[2, hidden]`` minor pair would be stored
         16 rows tall by the device's tiling). Addressed by an allocator of
         its own (``cache/kv_pool.py``): the unit is a snapshot, not a
-        token. None for a config without conv layers."""
+        token. A config with mamba layers: the recurrent part, ``[mamba
+        layers, slots, ssm_state, ssm_inner]`` FLOAT32 (``state_dtype``;
+        the convolutions' part is ``conv_state_shape``). None for a config
+        with neither."""
+        if self.has_ssm:
+            return (len(self.mamba_layers), num_states, self.ssm_state,
+                    self.ssm_inner)
         if not self.has_conv:
             return None
         rows = (self.conv_L_cache - 1) * self.hidden // 128
         return (len(self.conv_layers), num_states, rows, 128)
 
+    @property
+    def state_dtype(self):
+        return jnp.float32 if self.has_ssm else self.dtype
+
     def default_state_slots(self, num_slots: int, max_batch: int) -> int:
-        """How many state slots go with a pool of ``num_slots`` rows: a
-        working and a boundary slot for every batch row, the scratch slot,
-        and a snapshot for every 100 rows of the pool (a turn of an agent
-        session leaves two snapshots and some 200-700 rows), in whole 256s.
-        0 for a config without conv layers."""
-        if not self.has_conv:
+        """How many state slots go with a pool of ``num_slots`` rows,
+        counted BY BYTES: wanted are a working and a boundary slot for
+        every batch row, the scratch slot, and a snapshot for every 100
+        rows of the pool (a turn of an agent session leaves two snapshots
+        and some 200-700 rows), in whole 256s — as long as the snapshots
+        take no more than a quarter of what the pool's rows take. A
+        240 KiB snapshot (gated convolutions) never meets that bound: 2 048
+        slots beside 196 608 rows are 0.47 of 7.5 GiB. A 3 MiB snapshot
+        (selective state-space layers) does — 6 682 slots would be 20 GiB
+        beside 3 GiB of rows — and gets what the quarter buys, never fewer
+        than five a batch row and the scratch slot (its working and
+        boundary slot, the snapshot its prompt's publish leaves, the one
+        a waiting session of a closed loop of two callers a row resumes
+        from, and one for the shared preambles and the churn between
+        them). 0 for a config whose layers carry no state."""
+        if not (self.has_conv or self.has_ssm):
             return 0
-        want = 1 + 2 * max_batch + num_slots // 100
-        return -(-want // 256) * 256
+        want = -(-(1 + 2 * max_batch + num_slots // 100) // 256) * 256
+        planes, heads, width = self.kv_row
+        row_bytes = (planes * len(self.attn_layers) * heads * width
+                     * jnp.dtype(self.dtype).itemsize)
+        afford = num_slots * row_bytes // 4 // self.state_slot_bytes()
+        return min(want, max(1 + 5 * max_batch, afford))
 
     def kv_index_shape(self, num_slots: int) -> tuple[int, int, int] | None:
         """The index-key plane beside the pool, ``[full layers, slots,
@@ -601,6 +791,11 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
 
     keys = jax.random.split(key, 9)
     H, V = cfg.hidden, cfg.vocab_size
+    if cfg.has_ssm:
+        params = _draw_hybrid(jax.random.fold_in(key, 106), cfg)
+        n_blk = _vocab_blocks(V, H, H * cfg.intermediate)
+        w, _ = _draw_blocks(keys[7], n_blk, (V // n_blk, H), H, cfg.dtype, None)
+        return params | {"embed": w.reshape(V, H)}
     L = cfg.n_layers - cfg.first_k_dense  # layers of the main stack
     ffn_width = cfg.moe_intermediate if cfg.n_experts else cfg.intermediate
     ffn = _ffn_dims(H, ffn_width)
@@ -982,11 +1177,18 @@ def _pair_heads(cfg: ModelConfig, q, k, v):
     (``_own_lanes``) are its attention over its own values. The logits'
     factor stays ``head_dim ** -0.5``: it is passed, not derived from the
     row's width."""
+    B, S, _, D = q.shape
+    wide = cfg.n_kv_heads // 2
+    return (_pair_q(cfg, q), k.reshape(B, S, wide, 2 * D),
+            v.reshape(B, S, wide, 2 * D))
+
+
+def _pair_q(cfg: ModelConfig, q: jnp.ndarray) -> jnp.ndarray:
+    """``_pair_heads``' queries alone (a layer that projects no K / V)."""
     B, S, Hq, D = q.shape
     wide, g = cfg.n_kv_heads // 2, Hq // cfg.n_kv_heads
     own = jnp.eye(2, dtype=q.dtype)[:, None, :, None]  # [half, 1, lanes' half, 1]
-    q = (q.reshape(B, S, wide, 2, g, 1, D) * own).reshape(B, S, Hq, 2 * D)
-    return q, k.reshape(B, S, wide, 2 * D), v.reshape(B, S, wide, 2 * D)
+    return (q.reshape(B, S, wide, 2, g, 1, D) * own).reshape(B, S, Hq, 2 * D)
 
 
 def _own_lanes(cfg: ModelConfig, out: jnp.ndarray) -> jnp.ndarray:
@@ -1034,6 +1236,476 @@ def _short_conv(lp: dict, h: jnp.ndarray, state: jnp.ndarray, cfg: ModelConfig,
     return y, states
 
 
+# ---------------------------------------------------------------------------
+# decoder-hybrid-decoder: state-space, window, full, gated-memory and
+# cross-attention layers (models/phi4flash.py)
+# ---------------------------------------------------------------------------
+
+
+def _draw_hybrid(key, cfg: ModelConfig) -> dict:
+    """The pytree of a config with mamba layers: ``layers`` — the two
+    LayerNorms (weight and bias) and the SwiGLU of every layer, one stack
+    — and a stack a kind for the operators: ``mamba`` (``in_x`` / ``in_z`` [H,
+    di] each: the input projection's two halves, stored apart as the FFN's
+    gate and up are — the same bytes, and every product of the operator
+    then makes or reads a tensor ``di`` wide, which no other layer's does:
+    how a trace without framework names finds it; ``conv_w`` [taps, di], tap 0 the oldest, ``conv_b``;
+    ``x_proj`` [di, R + 2N]: delta, B, C; ``dt_proj`` [R, di], ``dt_bias``;
+    ``A_log`` [N, di]; ``D``; ``out_proj``), ``attn`` (the sliding layers
+    then the full one: ``wq`` / ``wk`` / ``wv`` and biases, ``wo`` /
+    ``bo``, the four lambda vectors [D], ``subln`` [2D]), ``gmu`` (``w_g``
+    [H, di], ``w_out`` [di, H]) and ``cross`` (``attn`` without ``wk`` /
+    ``wv``). ``A_log = log(1..N)`` a channel, ``dt_bias`` the inverse
+    softplus of a log-uniform [1e-3, 1e-1], ``D = 1`` (the family's
+    initialisation): a normal draw makes the state vanish or overflow
+    within a page. Norm weights are seeded draws around 1 and the biases
+    small non-zero draws, so that they are exercised."""
+    H, di, N, R, D = (cfg.hidden, cfg.ssm_inner, cfg.ssm_state,
+                      cfg.ssm_dt_rank, cfg.head_dim)
+    qd, kvd = cfg.n_heads * D, cfg.n_kv_heads * D
+    L = cfg.n_layers
+    n_m, n_c = len(cfg.mamba_layers), len(cfg._layers_of("cross_attention"))
+    n_a = len(cfg.window_layers) + 1
+    f32 = jnp.float32
+    keys = iter(jax.random.split(key, 64))
+
+    def mats(n: int, dims: dict) -> dict:
+        return {name: _draw_blocks(next(keys), n, (i, o), i, cfg.dtype, None)[0]
+                for name, (i, o) in dims.items()}
+
+    def near(n: int, width: int, centre: float, spread: float, dtype=cfg.dtype):
+        return (centre + spread * jax.random.normal(next(keys), (n, width), f32)
+                ).astype(dtype)
+
+    def diff_leaves(n: int) -> dict:
+        out = {"bq": near(n, qd, 0.0, 0.02), "bo": near(n, H, 0.0, 0.02),
+               "subln": near(n, 2 * D, 1.0, 0.1)}
+        for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+            out[name] = near(n, D, 0.0, 0.1, f32)
+        return out
+
+    layers = mats(L, _ffn_dims(H, cfg.intermediate))
+    for name in ("attn_norm", "mlp_norm"):
+        layers[name] = near(L, H, 1.0, 0.1)
+        layers[name + "_b"] = near(L, H, 0.0, 0.02)
+    mamba = mats(n_m, {"in_x": (H, di), "in_z": (H, di), "x_proj": (di, R + 2 * N),
+                       "dt_proj": (R, di), "out_proj": (di, H)})
+    mamba["conv_w"], _ = _draw_blocks(
+        next(keys), n_m, (cfg.ssm_conv, di), cfg.ssm_conv, cfg.dtype, None)
+    mamba["conv_b"] = near(n_m, di, 0.0, 0.02)
+    dt = jnp.exp(jax.random.uniform(next(keys), (n_m, di), f32)
+                 * (np.log(1e-1) - np.log(1e-3)) + np.log(1e-3))
+    mamba["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+    mamba["A_log"] = jnp.broadcast_to(
+        jnp.log(jnp.arange(1, N + 1, dtype=f32))[None, :, None], (n_m, N, di))
+    mamba["D"] = jnp.ones((n_m, di), f32)
+    attn = mats(n_a, {"wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd),
+                      "wo": (qd, H)})
+    attn |= diff_leaves(n_a) | {"bk": near(n_a, kvd, 0.0, 0.02),
+                                "bv": near(n_a, kvd, 0.0, 0.02)}
+    gmu = mats(n_c, {"w_g": (H, di), "w_out": (di, H)})
+    cross = mats(n_c, {"wq": (H, qd), "wo": (qd, H)}) | diff_leaves(n_c)
+    return {
+        "layers": layers, "mamba": mamba, "attn": attn, "gmu": gmu,
+        "cross": cross,
+        "final_norm": near(1, H, 1.0, 0.1)[0],
+        "final_norm_b": near(1, H, 0.0, 0.02)[0],
+    }
+
+
+def _stack_at(stack: dict, i) -> dict:
+    """Layer ``i`` of a stack, indexed where it lies (``_scan_mixed``'s
+    rule: the consumer's fusion reads the slice, none is built)."""
+    return jax.tree_util.tree_map(
+        lambda w: jax.lax.dynamic_index_in_dim(w, i, 0, keepdims=False)
+        if isinstance(i, jax.Array) else w[i], stack)
+
+
+def _ln(lp: dict, name: str, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    return layer_norm(x, lp[name], cfg.rms_eps, lp[name + "_b"])
+
+
+def _lambda_init(layer) -> jnp.ndarray:
+    """Differential attention's ``lambda_0`` of layer ``layer`` (its
+    depth in the model, traced under a scan)."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, jnp.float32))
+
+
+def _diff_combine(ap: dict, attn: jnp.ndarray, cfg: ModelConfig, layer) -> jnp.ndarray:
+    """The two softmax maps of every head pair, as ``_pair_heads`` laid
+    them — ``attn`` [B, S, Hq, 2D]: query head ``(wide, half, g)``, half 0
+    scored against the wide head's first key, half 1 against its second,
+    all 2D output lanes each (over ``v1 | v2``) — combined: ``a1 - lambda
+    * a2``, RMSNorm over the 2D lanes, times ``1 - lambda_0``. Returns [B,
+    S, Hq * D] as ``wo`` takes it."""
+    B, S, Hq, W = attn.shape
+    wide, g = cfg.n_kv_heads // 2, Hq // cfg.n_kv_heads
+    a = attn.astype(jnp.float32).reshape(B, S, wide, 2, g, W)
+    lam0 = _lambda_init(layer)
+    lam = (jnp.exp(jnp.sum(ap["lambda_q1"] * ap["lambda_k1"]))
+           - jnp.exp(jnp.sum(ap["lambda_q2"] * ap["lambda_k2"])) + lam0)
+    o = rms_norm(a[:, :, :, 0] - lam * a[:, :, :, 1], ap["subln"], cfg.rms_eps)
+    return (o * (1.0 - lam0)).astype(attn.dtype).reshape(B, S, Hq * W // 2)
+
+
+def _diff_out(ap: dict, attn: jnp.ndarray, cfg: ModelConfig, layer) -> jnp.ndarray:
+    """Differential attention's output projection on the kernels' (or
+    the jnp attention's) paired-row result."""
+    with jax.named_scope("rm_diff_attn"):
+        o = _diff_combine(ap, attn, cfg, layer)
+    return _wmm(ap, "wo", "bsk,kh->bsh", o) + ap["bo"]
+
+
+def _cross_q(cp: dict, u: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """A cross-attention layer's queries, paired: [B, S, Hq, 2D]."""
+    B, S = u.shape[:2]
+    q = jax.lax.optimization_barrier(_wmm(cp, "wq", "bsh,hd->bsd", u)) + cp["bq"]
+    return _pair_q(cfg, q.reshape(B, S, cfg.n_heads, cfg.head_dim))
+
+
+def _conv_rows(cfg: ModelConfig, stored: jnp.ndarray) -> jnp.ndarray:
+    """Conv-state slots' content ``[..., rows, 128]`` as positions x
+    channels ``[..., ssm_conv - 1, ssm_inner]``."""
+    return stored.reshape(*stored.shape[:-2], cfg.ssm_conv - 1, cfg.ssm_inner)
+
+
+def _mamba(mp: dict, u: jnp.ndarray, h0: jnp.ndarray, conv0: jnp.ndarray,
+           cfg: ModelConfig, snap_at: jnp.ndarray | None, scan_kw: dict) -> tuple:
+    """The Mamba-1 operator on ``u`` [B, S, H] from the state ``h0`` [B, N,
+    di] float32 and the ``ssm_conv - 1`` inputs of the convolution before
+    position 0 of these, ``conv0`` [B, taps - 1, di]. Returns ``(Op [B, S,
+    H], y [B, S, di] — the scan's output before the z gate, with the D
+    term: the MEMORY where this is the memory layer —, h [B, K, N, di],
+    conv [B, K, taps - 1, di])``: the state after ``snap_at`` [B, K]
+    positions each (``ops/ssm.py``); ``snap_at`` None: one position, one
+    state (a decode step: no kernel, no gather)."""
+    taps, N, R, S = cfg.ssm_conv, cfg.ssm_state, cfg.ssm_dt_rank, u.shape[1]
+    f32 = jnp.float32
+    with jax.named_scope("rm_ssm"):
+        x, z = jax.lax.optimization_barrier((
+            _wmm(mp, "in_x", "bsh,hd->bsd", u), _wmm(mp, "in_z", "bsh,hd->bsd", u)))
+        xs = jnp.concatenate([conv0.astype(x.dtype), x], axis=1)  # [B, S+taps-1, di]
+        w = mp["conv_w"].astype(f32)
+        xc = sum(w[k] * xs[:, k:k + S].astype(f32) for k in range(taps))
+        xc = jax.nn.silu(xc + mp["conv_b"].astype(f32))
+        dbc = _wmm(mp, "x_proj", "bsd,dr->bsr", xc.astype(x.dtype))
+        dt = jnp.einsum("bsr,rd->bsd", dbc[..., :R], mp["dt_proj"],
+                        precision=_PREC, preferred_element_type=f32)
+        dt = jax.nn.softplus(dt + mp["dt_bias"])
+        b = dbc[..., R:R + N].astype(f32)
+        c = dbc[..., R + N:].astype(f32)
+        a = -jnp.exp(mp["A_log"])
+        if snap_at is None:
+            y, h = ssm_step(xc[:, 0], dt[:, 0], b[:, 0], c[:, 0], a, mp["D"], h0)
+            y, h, conv = y[:, None], h[:, None], xs[:, None, S:]
+        else:
+            y, h = selective_scan(xc, dt, b, c, a, mp["D"], h0, snap_at, **scan_kw)
+            at = snap_at[:, :, None] + jnp.arange(taps - 1)
+            conv = jax.vmap(lambda rows, ix: rows[ix])(xs, at)
+        gated = (y * jax.nn.silu(z.astype(f32))).astype(u.dtype)
+        out = _wmm(mp, "out_proj", "bsd,dh->bsh", gated)
+    return out, y.astype(u.dtype), h, conv
+
+
+def _gmu(gp: dict, u: jnp.ndarray, memory: jnp.ndarray) -> jnp.ndarray:
+    """A Gated Memory Unit: no token mixing of its own, it gates the
+    memory layer's scan output at the same position."""
+    with jax.named_scope("rm_gmu"):
+        gate = jax.nn.silu(_wmm(gp, "w_g", "bsh,hd->bsd", u))
+        return _wmm(gp, "w_out", "bsd,dh->bsh", memory * gate)
+
+
+def _hybrid_plan(cfg: ModelConfig) -> tuple[int, int, int, int]:
+    """``(lower pairs, memory layer, full layer, upper pairs)``."""
+    full = cfg.attn_layers[0]
+    return (full - 1) // 2, full - 1, full, (cfg.n_layers - full - 1) // 2
+
+
+def _hybrid_lower(cfg: ModelConfig, params: dict, carry, mamba_layer, window_layer):
+    """The self-decoder: ``(mamba, sliding_attention)`` pairs as ONE
+    traced unit under ``lax.scan``, then the memory layer. ``mamba_layer
+    (carry, lp, mp, op_idx) -> (carry, y, ys)``, ``window_layer(carry, lp,
+    ap, op_idx, layer) -> (carry, ys)``; returns ``(carry, memory, ys of
+    the mamba layers [n_m, ...], ys of the window layers)``."""
+    pairs, mem, _, _ = _hybrid_plan(cfg)
+    per_layer, mamba, attn = params["layers"], params["mamba"], params["attn"]
+
+    def unit(carry, i):
+        carry, _, ys_m = mamba_layer(
+            carry, _stack_at(per_layer, 2 * i), _stack_at(mamba, i), i)
+        carry, ys_w = window_layer(
+            carry, _stack_at(per_layer, 2 * i + 1), _stack_at(attn, i), i,
+            2 * i + 1)
+        return carry, (ys_m, ys_w)
+
+    carry, (ys_m, ys_w) = jax.lax.scan(unit, carry, jnp.arange(pairs))
+    carry, memory, last = mamba_layer(
+        carry, _stack_at(per_layer, mem), _stack_at(mamba, pairs), pairs)
+    if last is not None:  # a launch that collects its states over the layers
+        ys_m = jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([a, b[None]]), ys_m, last)
+    return carry, memory, ys_m, ys_w
+
+
+def _hybrid_upper(cfg: ModelConfig, params: dict, carry, memory, cross_layer):
+    """The cross-decoder: ``(gmu, cross_attention)`` pairs as one traced
+    unit under ``lax.scan``. ``carry`` leads with ``x``; ``cross_layer
+    (carry, u, cp, layer) -> (carry, paired attention output)``."""
+    _, _, full, pairs = _hybrid_plan(cfg)
+    per_layer = params["layers"]
+
+    def unit(carry, i):
+        x, *rest = carry
+        lp = _stack_at(per_layer, full + 1 + 2 * i)
+        x = x + _gmu(_stack_at(params["gmu"], i), _ln(lp, "attn_norm", x, cfg),
+                     memory)
+        x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+        layer = full + 2 + 2 * i
+        lp = _stack_at(per_layer, layer)
+        cp = _stack_at(params["cross"], i)
+        carry, attn = cross_layer(
+            (x, *rest), _ln(lp, "attn_norm", x, cfg), cp, layer)
+        x, *rest = carry
+        x = x + _diff_out(cp, attn, cfg, layer)
+        x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+        return (x, *rest), None
+
+    if pairs:
+        carry, _ = jax.lax.scan(unit, carry, jnp.arange(pairs))
+    return carry
+
+
+def _hybrid_qkv(ap: dict, u: jnp.ndarray, cfg: ModelConfig, dtype=None):
+    """A self-attention layer's paired ``(q [B, S, Hq, 2D], k, v [B, S,
+    Hkv / 2, 2D])``, K and V in the pool's dtype."""
+    q, k, v = _pair_heads(cfg, *_qkv(ap, u, cfg))
+    dtype = dtype or k.dtype
+    return q, k.astype(dtype), v.astype(dtype)
+
+
+def _hybrid_prefill(params, cfg: ModelConfig, tokens, positions, new_lengths,
+                    snap_at):
+    """``prefill_forward`` of a config with mamba layers: one launch of
+    the whole prompt from a zero state, nothing cached. The self-decoder
+    runs every position; the full layer's attention and the cross-decoder
+    run each row's LAST position only (``new_lengths - 1``). Returns
+    ``(logits [B, 1, V], K / V of the full layer [1, B, S, ...], K / V of
+    the window layers [n, B, S, ...], (h, conv) states after ``snap_at``
+    tokens ([n_m, B, K, ...]))``."""
+    B, S = tokens.shape
+    x = _embed_lookup(params, tokens)
+    scale = cfg.head_dim ** -0.5
+    kv_end = jnp.full((B,), S, jnp.int32)
+    window = jnp.asarray(cfg.sliding_window, jnp.int32)
+    h0 = jnp.zeros((B, cfg.ssm_state, cfg.ssm_inner), jnp.float32)
+    conv0 = jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_inner), x.dtype)
+
+    def mamba_layer(x, lp, mp, op_idx):
+        op, y, h, conv = _mamba(mp, _ln(lp, "attn_norm", x, cfg), h0, conv0,
+                                cfg, snap_at, {})
+        x = x + op
+        x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+        return x, y, (h, conv.reshape(*conv.shape[:2], -1, 128))
+
+    def window_layer(x, lp, ap, op_idx, layer):
+        q, k, v = _hybrid_qkv(ap, _ln(lp, "attn_norm", x, cfg), cfg)
+        attn = attend_prefill(q, k, v, positions, kv_end, window=window,
+                              scale=scale)
+        x = x + _diff_out(ap, attn, cfg, layer)
+        x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+        return x, (k, v)
+
+    x, memory, states, (wk, wv) = _hybrid_lower(
+        cfg, params, x, mamba_layer, window_layer)
+    pairs, _, full, _ = _hybrid_plan(cfg)
+    last = (new_lengths - 1)[:, None]  # [B, 1]
+    lp, ap = _stack_at(params["layers"], full), _stack_at(params["attn"], pairs)
+    q, fk, fv = _hybrid_qkv(ap, _ln(lp, "attn_norm", x, cfg), cfg)
+    x = jnp.take_along_axis(x, last[:, :, None], axis=1)
+    memory = jnp.take_along_axis(memory, last[:, :, None], axis=1)
+    q = jnp.take_along_axis(q, last[:, :, None, None], axis=1)
+
+    def attend_last(q):  # one query a row against the full layer's keys
+        return attend_prefill(q, fk, fv, last, new_lengths, scale=scale)
+
+    x = x + _diff_out(ap, attend_last(q), cfg, full)
+    x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+    (x,) = _hybrid_upper(
+        cfg, params, (x,), memory,
+        lambda carry, u, cp, layer: (carry, attend_last(_cross_q(cp, u, cfg))))
+    return (_logits(params, cfg, x), fk[None], fv[None], wk, wv, states)
+
+
+def _hybrid_top(params, cfg: ModelConfig, x, memory, q, fk, fv, kv_pool, slots,
+                page_table, lengths, page_size: int):
+    """The full layer's attention and the cross-decoder for ONE position a
+    row — a decode step's, or a prefill's last: ``x``, ``memory`` [B, 1,
+    ·], the position's paired K / V of the full layer ``fk`` / ``fv`` [B,
+    Hkv / 2, 2D], its slot and its length (keys through itself). Every
+    one of these layers is a launch of the fused decode kernel on the
+    pool's layer 0: it writes the position's row — the full layer's, the
+    same bytes again in a cross layer — and attends the row's pages.
+    Returns ``(x, kv_pool)``."""
+    pairs, _, full, _ = _hybrid_plan(cfg)
+    pool_shape, pages_shape = kv_pool.shape, _pages_shape(kv_pool, page_size)
+    scale = cfg.head_dim ** -0.5
+
+    def attend(kv_pool, q):  # q [B, 1, Hq, 2D]
+        with jax.named_scope("rm_shared_kv_attn"):
+            attn, kv_pages = paged_decode_attention(
+                q[:, 0], fk, fv, kv_pool.reshape(pages_shape), slots,
+                page_table, lengths, 0, scale=scale)
+        return kv_pages.reshape(pool_shape), attn[:, None]
+
+    lp, ap = _stack_at(params["layers"], full), _stack_at(params["attn"], pairs)
+    kv_pool, attn = attend(kv_pool, q)
+    x = x + _diff_out(ap, attn, cfg, full)
+    x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+
+    def cross_layer(carry, u, cp, layer):
+        x, kv_pool = carry
+        kv_pool, attn = attend(kv_pool, _cross_q(cp, u, cfg))
+        return (x, kv_pool), attn
+
+    return _hybrid_upper(cfg, params, (x, kv_pool), memory, cross_layer)
+
+
+def _hybrid_chunk(params, cfg: ModelConfig, tokens, positions, kv_pool, slots,
+                  page_table, kv_lengths, page_size, kv_block_pages, use_kernel,
+                  interpret, logits_at, window_pool, window_slots, window_table,
+                  state_pool, state_in, snap_at, state_out):
+    """``prefill_chunk_paged`` of a config with mamba layers. The
+    self-decoder runs the chunk: a mamba layer from the state in slot
+    ``state_in`` (zeros where the chunk starts at position 0) through the
+    scan kernel, the states after ``snap_at`` tokens to slots
+    ``state_out``; a sliding layer attends its window of the WINDOW group
+    through ``window_table`` and the chunk's own rows, which go to
+    ``window_slots`` after the scan. The full layer's K / V of every
+    position go to ``slots``; its attention and the cross-decoder run the
+    position ``logits_at`` of each row only (``_hybrid_top``: that row's
+    last real one in its final chunk; in any other chunk its result is
+    nobody's). Returns ``(logits [B, 1, V], kv_pool, window_pool, (h,
+    conv))``."""
+    B, C = tokens.shape
+    x = _embed_lookup(params, tokens)
+    w_pages = window_pool.reshape(_pages_shape(window_pool, page_size))
+    prior_lengths = jnp.minimum(positions[:, 0], kv_lengths)
+    scale = cfg.head_dim ** -0.5
+    window = jnp.asarray(cfg.sliding_window, jnp.int32)
+    scan_kw = {} if use_kernel is None else {
+        "use_kernel": use_kernel, "interpret": interpret}
+    # A layer reads its rows' state at ``state_in`` and writes the states
+    # wanted to ``state_out`` INSIDE the layer, as a decode step does: the
+    # slots' arrays ride the scan, updated in place (collected over the
+    # layers instead, 64 rows x 3 states x 9 layers are 0.6 GB of float32).
+    fresh = (positions[:, 0] == 0)[:, None, None]
+    written = state_out.reshape(-1)
+
+    def mamba_layer(carry, lp, mp, op_idx):
+        x, h_pool, conv_pool = carry
+        h0 = jnp.where(fresh, 0, h_pool[op_idx, state_in])
+        conv0 = jnp.where(fresh, 0, _conv_rows(cfg, conv_pool[op_idx, state_in]))
+        op, y, h, conv = _mamba(mp, _ln(lp, "attn_norm", x, cfg), h0, conv0, cfg,
+                                snap_at, scan_kw)
+        h_pool = h_pool.at[op_idx, written].set(h.reshape(-1, *h.shape[2:]))
+        conv_pool = conv_pool.at[op_idx, written].set(
+            conv.reshape(written.shape[0], -1, 128).astype(conv_pool.dtype))
+        x = x + op
+        x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+        return (x, h_pool, conv_pool), y, None
+
+    def window_layer(carry, lp, ap, op_idx, layer):
+        x, *pools = carry
+        q, k, v = _hybrid_qkv(ap, _ln(lp, "attn_norm", x, cfg), cfg,
+                              window_pool.dtype)
+        attn = paged_chunk_attention(
+            q, k, v, w_pages, window_table, positions, prior_lengths,
+            kv_lengths, op_idx, kv_block_pages=kv_block_pages,
+            use_kernel=use_kernel, interpret=interpret, window=window,
+            scale=scale)
+        x = x + _diff_out(ap, attn, cfg, layer)
+        x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+        return (x, *pools), (k, v)
+
+    (x, h_pool, conv_pool), memory, _, (wk, wv) = _hybrid_lower(
+        cfg, params, (x, *state_pool), mamba_layer, window_layer)
+    new_w = jnp.stack([wk, wv]).transpose(0, 1, 4, 2, 3, 5)
+    window_pool, = write_runs_all(
+        [(window_pool, new_w, ALL_LAYERS)], window_slots, page_size)
+
+    pairs, _, full, _ = _hybrid_plan(cfg)
+    lp, ap = _stack_at(params["layers"], full), _stack_at(params["attn"], pairs)
+    q, fk, fv = _hybrid_qkv(ap, _ln(lp, "attn_norm", x, cfg), cfg, kv_pool.dtype)
+    new_f = jnp.stack([fk, fv])[:, None].transpose(0, 1, 4, 2, 3, 5)
+    kv_pool, = write_runs_all([(kv_pool, new_f, ALL_LAYERS)], slots, page_size)
+    at = jnp.zeros((B,), jnp.int32) if logits_at is None else logits_at
+
+    def pick(t):  # [B, C, ...] -> [B, 1, ...] at each row's position
+        ix = at.reshape(B, *([1] * (t.ndim - 1)))
+        return jnp.take_along_axis(t, ix, axis=1)
+
+    x, kv_pool = _hybrid_top(
+        params, cfg, pick(x), pick(memory), pick(q), pick(fk)[:, 0], pick(fv)[:, 0],
+        kv_pool, pick(slots)[:, 0], page_table, pick(positions)[:, 0] + 1,
+        page_size)
+    return _logits(params, cfg, x), kv_pool, window_pool, (h_pool, conv_pool)
+
+
+def _hybrid_decode(params, cfg: ModelConfig, tokens, kv_pool, slots, page_table,
+                   lengths, page_size, window_pool, window_slots, window_table,
+                   state_pool, state_slots):
+    """``decode_step`` of a config with mamba layers: one position a row.
+    A mamba layer reads its state at the row's working slot
+    ``state_slots[0]`` INSIDE the layer and writes the new one back there
+    and to ``state_slots[1]`` (the boundary slot when this token completes
+    a page, else slot 0) — the slots' arrays ride the layer scan as the
+    pools do, updated in place; a sliding layer is a launch of the fused
+    decode kernel on the WINDOW group with its window. Returns ``(logits
+    [B, V], kv_pool, window_pool, (h, conv))``."""
+    B = tokens.shape[0]
+    x = _embed_lookup(params, tokens)[:, None, :]
+    w_shape, w_pages = window_pool.shape, _pages_shape(window_pool, page_size)
+    scale = cfg.head_dim ** -0.5
+    window = jnp.asarray(cfg.sliding_window, jnp.int32)
+    read, written = state_slots[0], state_slots.reshape(-1)
+
+    def mamba_layer(carry, lp, mp, op_idx):
+        x, window_pool, h_pool, conv_pool = carry
+        op, y, h, conv = _mamba(
+            mp, _ln(lp, "attn_norm", x, cfg), h_pool[op_idx, read],
+            _conv_rows(cfg, conv_pool[op_idx, read]), cfg, None, {})
+        h, conv = h[:, 0], conv[:, 0].reshape(B, -1, 128).astype(conv_pool.dtype)
+        h_pool = h_pool.at[op_idx, written].set(jnp.concatenate([h, h]))
+        conv_pool = conv_pool.at[op_idx, written].set(jnp.concatenate([conv, conv]))
+        x = x + op
+        x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+        return (x, window_pool, h_pool, conv_pool), y, None
+
+    def window_layer(carry, lp, ap, op_idx, layer):
+        x, window_pool, h_pool, conv_pool = carry
+        q, k, v = _hybrid_qkv(ap, _ln(lp, "attn_norm", x, cfg), cfg,
+                              window_pool.dtype)
+        with jax.named_scope("rm_window_attn"):
+            attn, pages = paged_decode_attention(
+                q[:, 0], k[:, 0], v[:, 0], window_pool.reshape(w_pages),
+                window_slots, window_table, lengths, op_idx, window=window,
+                scale=scale)
+        x = x + _diff_out(ap, attn[:, None], cfg, layer)
+        x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
+        return (x, pages.reshape(w_shape), h_pool, conv_pool), None
+
+    (x, window_pool, h_pool, conv_pool), memory, _, _ = _hybrid_lower(
+        cfg, params, (x, window_pool, *state_pool), mamba_layer, window_layer)
+    pairs, _, full, _ = _hybrid_plan(cfg)
+    lp, ap = _stack_at(params["layers"], full), _stack_at(params["attn"], pairs)
+    q, fk, fv = _hybrid_qkv(ap, _ln(lp, "attn_norm", x, cfg), cfg, kv_pool.dtype)
+    x, kv_pool = _hybrid_top(
+        params, cfg, x, memory, q, fk[:, 0], fv[:, 0], kv_pool, slots,
+        page_table, lengths, page_size)
+    return (_logits(params, cfg, x)[:, 0], kv_pool, window_pool,
+            (h_pool, conv_pool))
+
+
 class UnsupportedForModel(ValueError):
     """A serving option that this model's layers cannot run under."""
 
@@ -1047,7 +1719,8 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
     refused here by name, so that none of them serves such a model with a
     dense layer's arithmetic. ``options`` are ``Engine``'s keyword names
     (a falsy value is "not asked for")."""
-    if not (cfg.n_experts or cfg.has_window or cfg.latent or cfg.has_conv):
+    if not (cfg.n_experts or cfg.has_window or cfg.latent or cfg.has_conv
+            or cfg.has_ssm):
         return None
     mesh = options.get("device_mesh")
     named = {
@@ -1060,7 +1733,7 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
         "disaggregated": "disaggregated prefill/decode (engine/disagg.py)",
         "hf_checkpoint": "loading a HuggingFace checkpoint (--weights)",
     }
-    if cfg.has_conv:
+    if cfg.has_conv or cfg.has_ssm:
         # A prefix is handed back only where its state was snapshotted:
         # whatever moves or advertises a prefix without its state.
         named |= {
@@ -1081,7 +1754,11 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
                         ("a latent (MLA) cache row", cfg.latent),
                         ("learned sparse attention", cfg.dsa),
                         ("gated-convolution layers with a recurrent state",
-                         cfg.has_conv)) if on
+                         cfg.has_conv),
+                        ("state-space layers with a recurrent state",
+                         cfg.has_ssm),
+                        ("a cross-decoder that reads one layer's K/V",
+                         cfg.has_ssm)) if on
     )
     return (
         f"a model with {kinds} does not support " + "; ".join(asked)
@@ -1429,7 +2106,10 @@ def _attn_out(lp: dict, attn: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
 
 
 def _logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    if cfg.layer_norm:
+        x = layer_norm(x, params["final_norm"], cfg.rms_eps, params["final_norm_b"])
+    else:
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if cfg.tie_embeddings:
         name, eq = "embed", "bsh,vh->bsv"
     else:
@@ -1476,9 +2156,16 @@ def prefill_forward(
     conv layers' states after ``snap_at`` tokens each, ``[L_conv, B, K,
     rows, 128]`` (the caller writes them to state slots).
     """
+    p_max = cached_k.shape[2]
+    if cfg.has_ssm:
+        if p_max or new_lengths is None or snap_at is None:
+            raise ValueError(
+                "prefill_forward of a model with state-space layers takes no "
+                "cached prefix (a prefix resumes from a state slot: "
+                "prefill_chunk_paged) and needs new_lengths and snap_at")
+        return _hybrid_prefill(params, cfg, tokens, positions, new_lengths, snap_at)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)
-    p_max = cached_k.shape[2]
     if cfg.has_conv and p_max:
         raise ValueError(
             "prefill_forward takes no cached prefix for a model with conv "
@@ -1650,7 +2337,7 @@ def prefill_forward_sp(
         "interpret",
     ),
     donate_argnums=(4,),
-    donate_argnames=("kv_scale", "index_pool", "state_pool"),
+    donate_argnames=("kv_scale", "index_pool", "state_pool", "window_pool"),
 )
 def prefill_chunk_paged(
     params: dict,
@@ -1674,6 +2361,9 @@ def prefill_chunk_paged(
     state_in: jnp.ndarray | None = None,  # [B] state slot a row resumes from
     snap_at: jnp.ndarray | None = None,  # [B, K] states wanted after ... tokens
     state_out: jnp.ndarray | None = None,  # [B, K] the slots they go to
+    window_pool: jnp.ndarray | None = None,  # the window group (donated)
+    window_slots: jnp.ndarray | None = None,  # [B, C] its slot per chunk token
+    window_table: jnp.ndarray | None = None,  # [B, max_pages] its pages
 ):
     """One CHUNK of long-context prefill against the paged pool (SURVEY §5:
     the 32k Qwen2 gate must never materialize O(S²) scores — VERDICT
@@ -1704,9 +2394,19 @@ def prefill_chunk_paged(
     the chunk starts at position 0), and the states after ``snap_at``
     tokens of the chunk go to slots ``state_out`` — the row's working
     slot at its true end, a snapshot wherever the tree will cut; a slot
-    nobody reads (slot 0) takes what is not wanted.
+    nobody reads (slot 0) takes what is not wanted. A config with mamba
+    layers takes the window group too (``window_pool`` and the row's slots
+    and page table IN it), ``state_pool`` as ``(h, conv)``, and returns
+    ``(logits [B, 1, V], kv_pool, window_pool, state_pool)``
+    (``_hybrid_chunk``).
     """
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
+    if cfg.has_ssm:
+        return _hybrid_chunk(
+            params, cfg, tokens, positions, kv_pool, slots, page_table,
+            kv_lengths, page_size, kv_block_pages, use_kernel, interpret,
+            logits_at, window_pool, window_slots, window_table, state_pool,
+            state_in, snap_at, state_out)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)  # [B, C, H]
     kv_pages = kv_pool.reshape(_pages_shape(kv_pool, page_size))
@@ -1910,7 +2610,7 @@ def prefill_chunk_paged(
     jax.jit,
     static_argnames=("cfg", "page_size", "mesh"),
     donate_argnums=(3,),
-    donate_argnames=("kv_scale", "index_pool", "state_pool"),
+    donate_argnames=("kv_scale", "index_pool", "state_pool", "window_pool"),
 )
 def decode_step(
     params: dict,
@@ -1927,6 +2627,9 @@ def decode_step(
     index_pool: jnp.ndarray | None = None,  # [L_full, num_slots, Di] (donated)
     state_pool: jnp.ndarray | None = None,  # [L_conv, S, rows, 128] (donated)
     state_slots: jnp.ndarray | None = None,  # [2, B]: working slot, second slot
+    window_pool: jnp.ndarray | None = None,  # the window group (donated)
+    window_slots: jnp.ndarray | None = None,  # [B] its slot for this token
+    window_table: jnp.ndarray | None = None,  # [B, max_pages] its pages
 ):
     """One decode step for a continuous batch: writes this token's K/V into
     the paged pool inside the layer scan, attends over the radix-cache
@@ -1948,7 +2651,14 @@ def decode_step(
     written back there, and to ``state_slots[1]`` as well — the row's
     boundary slot when this token completes a page (the state the tree
     will want at release), slot 0 otherwise (one array: one upload a
-    step)."""
+    step). A config with mamba layers takes the window group too and
+    returns ``(logits, kv_pool, window_pool, state_pool)``
+    (``_hybrid_decode``)."""
+    if cfg.has_ssm:
+        refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
+        return _hybrid_decode(
+            params, cfg, tokens, kv_pool, slots, page_table, lengths, page_size,
+            window_pool, window_slots, window_table, state_pool, state_slots)
     return _decode_core(
         params, cfg, tokens, kv_pool, slots, page_table, lengths, page_size,
         mesh, kv_scale, moe_stats, index_pool, state_pool, state_slots,

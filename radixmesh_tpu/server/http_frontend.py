@@ -347,6 +347,12 @@ def _ids_from_body(body: dict, tokenizer, who: str) -> list[int]:
 class _FrontendServer(ThreadingHTTPServer):
     daemon_threads = True
     allow_reuse_address = True
+    # The listen backlog (socketserver's default is 5). A closed loop of
+    # 128 callers, or a burst of 64, connects at once; past the backlog
+    # the kernel drops the handshake and one connection in a few hundred
+    # is reset a second later ("Connection reset by peer" before a byte
+    # of the request was read: PR 41). The kernel caps it at somaxconn.
+    request_queue_size = 1024
 
 
 def _b64_array(a: np.ndarray) -> dict:
@@ -813,7 +819,26 @@ class ServingFrontend:
                     "kv_bytes_per_token": (
                         eng.pool.kv.size // eng.pool.num_slots
                         * eng.pool.kv.dtype.itemsize),
+                    "bytes_per_slot": eng.pool.state_slot_bytes,
                 }
+            if eng.window_stats is not None:
+                # Two groups of rows (a model whose window layers keep
+                # their own): each group's slots and the most ever taken,
+                # and what the rows and the tree's snapshots hold of the
+                # window group.
+                pool = eng.pool
+                state["kv"]["groups"] = {
+                    "full": {"slots": pool.num_slots, "used_peak": pool.used_peak,
+                             "bytes_per_token": (pool.kv.size // pool.num_slots
+                                                 * pool.kv.dtype.itemsize)},
+                    "window": {"slots": pool.window_slots,
+                               "used_peak": pool.window_used_peak,
+                               "used": pool.window_slots_used,
+                               "bytes_per_token": (
+                                   pool.window.size // pool.window_slots
+                                   * pool.window.dtype.itemsize)},
+                }
+                state["window"] = dict(eng.window_stats)
             host = getattr(tree, "host", None)
             if host is not None:
                 state["host_tier"] = {

@@ -142,6 +142,34 @@ class TestServingFrontend:
             )
         assert all(len(r) >= 1 for r in results)
 
+    def test_a_burst_of_callers_connects_whole(self, frontend):
+        """A closed loop of 128 callers, or a warm-up burst of 64, connects
+        at once. Behind socketserver's backlog of 5 the kernel dropped
+        handshakes and reset a connection in a few hundred a second later;
+        the server asks for a backlog the callers fit in."""
+        import threading
+
+        assert frontend._server.request_queue_size >= 256
+        statuses = []
+
+        def caller(k):
+            try:
+                status, body = _post(
+                    f"http://127.0.0.1:{frontend.port}/generate",
+                    {"input_ids": list(range(k, k + 9)), "max_tokens": 1})
+                statuses.append(status if body["output_ids"] else "empty")
+            except OSError as e:  # URLError, ConnectionResetError
+                statuses.append(repr(e))
+
+        for _ in range(2):
+            threads = [threading.Thread(target=caller, args=(k,))
+                       for k in range(96)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+        assert statuses == [200] * 192, [s for s in statuses if s != 200]
+
 
 class TestRouterFrontend:
     def test_route_endpoint(self):
