@@ -15,7 +15,9 @@ weights from a seed — and checks what comes out by the repo's own means:
                  compiled at the served head geometry and compared with
                  its jnp oracle (``radixmesh_tpu/ops/kernel_check.py``);
                  the decode kernel also at Mellum2's heads with a window
-                 start, context past the window.
+                 start, context past the window; the latent (MLA) decode
+                 kernel at GLM-4.7-Flash's row, with and without rows
+                 that share their first pages.
 3. ``serve``     start the server; send a short prompt (dense prefill), a
                  prompt over ``long_prefill_threshold`` (chunk kernel), a
                  second turn sharing its prefix (``cached_tokens`` > 0),
@@ -75,6 +77,10 @@ CHIP = {
     # the 1 024 keys a window layer sees (the table holds 2 048).
     "geometry_window": dict(hq=32, hkv=4, head_dim=128, page=16, batch=8,
                             chunk=512, max_pages=128, window=1024),
+    # GLM-4.7-Flash's latent row (20 heads over 576 stored in 640, values
+    # its first 512): six rows on 1 280 shared tokens, two on their own.
+    "geometry_latent": dict(hq=20, width=640, v_width=512, page=16, batch=8,
+                            max_pages=128),
     "interpret": False,
     "short": 40,  # dense prefill
     "long": 1100,  # > long_prefill_threshold (1024) → chunk kernel
@@ -104,6 +110,8 @@ REHEARSAL = {
                      max_pages=4),
     "geometry_window": dict(hq=8, hkv=1, head_dim=32, page=4, batch=8,
                             chunk=8, max_pages=8, window=12),
+    "geometry_latent": dict(hq=4, width=128, v_width=32, page=4, batch=8,
+                            max_pages=16, block_tokens=16),
     "interpret": True,
     "short": 40,
     "long": 300,
@@ -387,6 +395,7 @@ def phase_kernels(args) -> dict:
         DEFAULT_CASES,
         WINDOW_CASES,
         check_kernels,
+        check_latent_decode,
     )
 
     m = REHEARSAL if args.rehearse_cpu else CHIP
@@ -396,6 +405,10 @@ def phase_kernels(args) -> dict:
         for cases, key in ((DEFAULT_CASES, "geometry"),
                            (WINDOW_CASES, "geometry_window"))
         for r in check_kernels(cases, interpret=m["interpret"], **m[key])
+    ] + [
+        {**r, "geometry": m["geometry_latent"]}
+        for r in check_latent_decode(
+            interpret=m["interpret"], **m["geometry_latent"])
     ]
     return {"results": results, "seconds": round(time.monotonic() - t0, 2)}
 

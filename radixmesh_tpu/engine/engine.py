@@ -63,6 +63,7 @@ from radixmesh_tpu.obs.fleet_plane import eviction_counters
 from radixmesh_tpu.obs.metrics import TOKEN_LEN_BUCKETS, get_registry
 from radixmesh_tpu.obs.trace_plane import get_recorder
 from radixmesh_tpu.obs.tracing import ProcessAccount, SpanBook, since_import
+from radixmesh_tpu.ops.mla_attention import decode_groups
 from radixmesh_tpu.ops.sampling import sample_tokens, spec_verify_sample
 from radixmesh_tpu.utils.logging import get_logger
 
@@ -203,6 +204,10 @@ class _InlineJob:
     total: int  # len(prompt)
 
 
+# ``decode_groups``' counts for a launch in which no rows were grouped.
+_NO_GROUPS = {"groups": 0, "members": 0, "passes": 0, "shared_tokens": 0}
+
+
 @dataclass
 class _DecodeLaunch:
     """The inputs of one decode launch (``Engine._build_launch``), kept
@@ -215,7 +220,9 @@ class _DecodeLaunch:
     slots: np.ndarray  # [max_batch] slot each row's K/V lands in
     max_len: int  # longest context before the launch
     window: tuple[int, int]  # attn_window increments (read, skipped)
-    latent_tokens: int  # latent rows the launch reads (rows x layers)
+    # Latent rows the launch has to read, x layers: every row's context,
+    # but what a group of rows shares once and not once a member.
+    latent_tokens: int
     contexts: np.ndarray  # the live rows' context, this token included
     slots_d: jax.Array
     pt_d: jax.Array
@@ -233,6 +240,11 @@ class _DecodeLaunch:
     # ``pt_d``; what left the window reads the group's scratch page).
     w_slots_d: jax.Array | None = None
     w_pt_d: jax.Array | None = None
+    # A latent row read by the decode kernel: which rows hold the same
+    # pages first (``decode_groups``: its counts, and its lines on the
+    # device).
+    groups: dict | None = None
+    groups_d: jax.Array | None = None
 
 
 @dataclass
@@ -502,12 +514,24 @@ class Engine:
         # lengths, in rm.decode.build); where the Pallas decode kernel
         # serves, the tokens of one of its compute blocks beside them. For a latent cache row the same
         # group counts the rows read: by decode launches (context x layers,
-        # summed over rows) and by prefill launches of the cached part
+        # summed over rows: latent_tokens_attended; of those, the rows a
+        # launch did not have to read again because several of its rows
+        # hold the same pages, latent_tokens_shared; the rest,
+        # latent_tokens_read) and by prefill launches of the cached part
         # (tokens already in pages that a chunk attends x layers).
+        # decode_groups: the last decode launch's groups, members, passes.
         self._moe_stats = new_moe_stats(cfg.ep_size > 1) if cfg.n_experts else None
         self.attn_window = {"window_tokens_skipped": 0, "window_tokens_read": 0}
         if cfg.latent:
-            self.attn_window |= {"latent_tokens_read": 0, "latent_prefix_tokens": 0}
+            self.attn_window |= {
+                "latent_tokens_read": 0, "latent_prefix_tokens": 0,
+                "latent_tokens_attended": 0, "latent_tokens_shared": 0,
+                "decode_groups": _NO_GROUPS,
+            }
+        # decode_groups of the last launch built, with what it was built
+        # for (_grouping): a row's first pages are laid once, when it is
+        # installed, so the groups change only when a row joins or leaves.
+        self._groups_for: tuple | None = None
         if cfg.dsa:
             # Learned sparse attention, by launch kind (host arithmetic on
             # the lengths, beside latent_tokens_read): index keys a full
@@ -2314,6 +2338,7 @@ class Engine:
         self._page_table[row, :n_pages] = (
             req.token_slots[:: self.page_size] // self.page_size
         )
+        self._groups_for = None  # a request that comes back has other pages
 
     @staticmethod
     def _seeded_launch(rows: Iterable[Request]) -> bool:
@@ -3050,7 +3075,11 @@ class Engine:
         aw["window_tokens_read"] += launch.window[0]
         aw["window_tokens_skipped"] += launch.window[1]
         if launch.latent_tokens:
+            attended = int(launch.contexts.sum()) * self.cfg.n_layers
             aw["latent_tokens_read"] += launch.latent_tokens
+            aw["latent_tokens_attended"] += attended
+            aw["latent_tokens_shared"] += attended - launch.latent_tokens
+            aw["decode_groups"] = launch.groups
         if self.window_stats is not None:
             aw["shared_kv_tokens_read"] += (
                 int(launch.contexts.sum()) * self._kv_readers)
@@ -3114,6 +3143,8 @@ class Engine:
                     **({} if launch.w_slots_d is None else {
                         "window_slots": launch.w_slots_d,
                         "window_table": launch.w_pt_d}),
+                    **({} if launch.groups_d is None else {
+                        "decode_groups": launch.groups_d}),
                 )
                 logits = self._commit_pool_update(res)
         asked = [(row, r) for row, r in active if r.sampling.return_logits]
@@ -3303,6 +3334,17 @@ class Engine:
             ws = self.window_stats
             ws["slots_held_by_rows"] = sum(held)
             ws["held_peak_row"] = max(ws["held_peak_row"], max(held))
+        latent_tokens, groups, groups_d = 0, None, None
+        if self.cfg.latent:
+            latent_tokens = int(lengths[idx].sum())
+            groups = _NO_GROUPS
+            # Where the latent decode kernel serves (not the C=1 chunk, not
+            # the selecting programs), it reads what rows share once.
+            if not chunked and not (
+                    self.cfg.dsa and pt.shape[1] * ps + 1 > self.cfg.index_topk):
+                groups, groups_d = self._grouping(active, pt, lengths, idx)
+                latent_tokens -= groups["shared_tokens"]
+            latent_tokens *= self.cfg.n_layers
         return _DecodeLaunch(
             active=active,
             sig=[(row, r.rid, r.kv_len + ahead) for row, r in active],
@@ -3310,10 +3352,7 @@ class Engine:
             slots=slots,
             max_len=int(lengths[idx].max()) - 1,
             window=window,
-            latent_tokens=(
-                int(lengths[idx].sum()) * self.cfg.n_layers
-                if self.cfg.latent else 0
-            ),
+            latent_tokens=latent_tokens,
             contexts=lengths[idx],
             slots_d=jnp.asarray(slots),
             pt_d=jnp.asarray(pt),
@@ -3323,7 +3362,25 @@ class Engine:
             w_slots_d=jnp.asarray(w_slots) if windowed else None,
             w_pt_d=jnp.asarray(
                 self._w_page_table_padded[:, : pt.shape[1]]) if windowed else None,
+            groups=groups,
+            groups_d=groups_d,
         )
+
+    def _grouping(
+        self, active: list[tuple], pt: np.ndarray, lengths: np.ndarray,
+        idx: np.ndarray,
+    ) -> tuple[dict, jax.Array]:
+        """``decode_groups`` of a launch of these rows, as counts and as
+        lines on the device. The first pages of a row's table are laid
+        once, when the row is installed, and contexts only grow: the groups
+        of one set of (row, rid) under one table width hold until a row
+        joins or leaves, so a build pays a comparison of two tuples, and
+        neither the table's comparison nor an upload."""
+        key = (tuple((row, r.rid) for row, r in active), pt.shape[1])
+        if self._groups_for is None or self._groups_for[0] != key:
+            groups, stats = decode_groups(pt, lengths, idx, self.page_size)
+            self._groups_for = (key, stats, jnp.asarray(groups))
+        return self._groups_for[1:]
 
     def _take_prebuilt(self, active: list[tuple]) -> _DecodeLaunch | None:
         """The inputs built ahead, iff they were built for exactly these

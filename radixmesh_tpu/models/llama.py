@@ -2630,6 +2630,7 @@ def decode_step(
     window_pool: jnp.ndarray | None = None,  # the window group (donated)
     window_slots: jnp.ndarray | None = None,  # [B] its slot for this token
     window_table: jnp.ndarray | None = None,  # [B, max_pages] its pages
+    decode_groups: jnp.ndarray | None = None,  # rows that read the same pages
 ):
     """One decode step for a continuous batch: writes this token's K/V into
     the paged pool inside the layer scan, attends over the radix-cache
@@ -2653,7 +2654,10 @@ def decode_step(
     will want at release), slot 0 otherwise (one array: one upload a
     step). A config with mamba layers takes the window group too and
     returns ``(logits, kv_pool, window_pool, state_pool)``
-    (``_hybrid_decode``)."""
+    (``_hybrid_decode``). A config with a latent row may be told which
+    rows hold the same pages first (``ops/mla_attention.py::decode_groups``,
+    from the launch's page table; its shape depends on ``B`` alone): the
+    latent decode kernel then reads those pages once for all of them."""
     if cfg.has_ssm:
         refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
         return _hybrid_decode(
@@ -2662,6 +2666,7 @@ def decode_step(
     return _decode_core(
         params, cfg, tokens, kv_pool, slots, page_table, lengths, page_size,
         mesh, kv_scale, moe_stats, index_pool, state_pool, state_slots,
+        decode_groups,
     )
 
 
@@ -2680,6 +2685,7 @@ def _decode_core(
     index_pool: jnp.ndarray | None = None,
     state_pool: jnp.ndarray | None = None,
     state_slots: jnp.ndarray | None = None,
+    decode_groups: jnp.ndarray | None = None,
 ):
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
@@ -2821,6 +2827,7 @@ def _decode_core(
                 l_idx,
                 cfg.kv_lora_rank,
                 cfg.head_dim ** -0.5,
+                decode_groups,
             )
         else:
             attn, kv_pages = paged_decode_attention(

@@ -30,6 +30,11 @@ from radixmesh_tpu.ops.attention import (
     paged_chunk_attention,
     paged_decode_attention,
 )
+from radixmesh_tpu.ops.mla_attention import (
+    decode_groups,
+    latent_decode_attention,
+    mla_decode_kernel,
+)
 from radixmesh_tpu.ops.paged_attention import (
     paged_attention_pool_kernel,
     paged_chunk_attention_kernel,
@@ -38,7 +43,8 @@ from radixmesh_tpu.ops.paged_attention import (
 from radixmesh_tpu.ops.quant import quantize_kv
 
 __all__ = [
-    "TOLERANCE", "DEFAULT_CASES", "FLAG_CASES", "WINDOW_CASES", "check_kernels",
+    "TOLERANCE", "DEFAULT_CASES", "FLAG_CASES", "WINDOW_CASES", "LATENT_CASES",
+    "check_kernels", "check_latent_decode",
 ]
 
 # Absolute AND relative bound on |kernel - oracle| per output element.
@@ -64,6 +70,10 @@ DEFAULT_CASES = ("fused_decode", "chunk_prefill")
 # a traced scalar; ``check_kernels(..., window=N)``): run with the window
 # and with 0, against rows shorter than, at and past the window.
 WINDOW_CASES = ("fused_decode_window",)
+# The decode kernel of a latent (MLA) cache row (``check_latent_decode``):
+# every row over its whole context, and the same launch with the rows that
+# hold the same pages first told so (one shared pass, then each row's own).
+LATENT_CASES = ("mla_decode", "mla_decode_grouped")
 # Variants only a flag selects: int8 KV (``--kv-quant int8``), the
 # per-head grids (``fuse_heads=False``, huge-Hkv configs), and the
 # read-only pool kernel (pp serving, the small-batch crossover).
@@ -220,6 +230,87 @@ def _run_case(name: str, x: dict, interpret: bool) -> dict:
     raise ValueError(f"unknown kernel case {name!r}")
 
 
+def _verdict(name: str, run) -> dict:
+    """One case's result dict: ``run()``'s numbers against the tolerance,
+    or the exception it raised (the compiler's message when Mosaic refuses
+    the kernel) — recorded, not skipped."""
+    try:
+        res = run()
+    except Exception as exc:  # noqa: BLE001 — the verdict records the compiler's message; callers fail on ok=False
+        return {
+            "kernel": name, "ok": False,
+            "error": f"{type(exc).__name__}: {exc}"[:2000],
+        }
+    return {
+        "kernel": name,
+        "ok": bool(
+            res["max_err"] <= TOLERANCE and res.get("pool_write_ok", True)
+        ),
+        "tolerance": TOLERANCE,
+        **res,
+    }
+
+
+def check_latent_decode(
+    *,
+    hq: int,
+    width: int,
+    v_width: int,
+    page: int,
+    batch: int = 8,
+    max_pages: int = 128,
+    block_tokens: int = 512,
+    interpret: bool = False,
+    seed: int = 0,
+) -> list[dict]:
+    """``LATENT_CASES`` at the given geometry, ``check_kernels``' result
+    dicts: ``radixmesh_mla_decode`` against the gather reference on a bf16
+    pool of one ``width``-wide row a token. All rows but the last two hold
+    the same pages over the first five eighths of the table — consecutive
+    ones (the one-descriptor copy) or permuted ones by ``seed``'s parity —
+    two and a half blocks at the defaults, so two are shared; ragged
+    lengths past them, one row of a single token."""
+    rng = np.random.default_rng(seed)
+    shared = max_pages * 5 // 8
+    L, P = 2, 1 + shared + batch * max_pages
+    first = 1 + (rng.permutation(shared) if seed % 2 else np.arange(shared))
+    pages = 1 + shared + rng.permutation(batch * max_pages).reshape(
+        batch, max_pages)
+    pages[:-2, :shared] = first
+    full = max_pages * page
+    ragged = [full, shared * page + 1, full - page - 1, shared * page + page]
+    lengths = np.asarray([ragged[b % 4] for b in range(batch)], np.int32)
+    lengths[-1] = 1
+    pos = lengths - 1
+    slots = (pages[np.arange(batch), pos // page] * page + pos % page).astype(
+        np.int32)
+    pages = pages.astype(np.int32)
+    pool = jnp.asarray(
+        rng.standard_normal((1, L, 1, P, page, width)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((batch, hq, width)), jnp.bfloat16)
+    new = jnp.asarray(rng.standard_normal((batch, width)), jnp.bfloat16)
+    scale = float(width) ** -0.5
+    want = latent_decode_attention(
+        q, new, pool, slots, pages, lengths, 1, v_width, scale, use_kernel=False)
+    groups, stats = decode_groups(
+        pages, lengths, range(batch), page, block_tokens=block_tokens)
+
+    def case(groups):
+        got = mla_decode_kernel(
+            q, new, pool, slots, pages, lengths, 1, groups, v_width=v_width,
+            scale=scale, block_tokens=block_tokens, interpret=interpret)
+        diff = float(np.max(np.abs(
+            np.asarray(got[1], np.float32) - np.asarray(want[1], np.float32))))
+        return {"max_err": _err(got[0], want[0], lengths > 1),
+                "pool_write_diff": diff, "pool_write_ok": diff == 0.0}
+
+    return [
+        _verdict("mla_decode", lambda: case(None)),
+        _verdict("mla_decode_grouped",
+                 lambda: {**case(jnp.asarray(groups)), "groups": stats}),
+    ]
+
+
 def check_kernels(
     cases: tuple[str, ...],
     *,
@@ -251,22 +342,5 @@ def check_kernels(
         )
     x = _inputs(hq, hkv, head_dim, page, batch, chunk, max_pages, seed)
     x["window"] = window
-    out = []
-    for name in cases:
-        try:
-            res = _run_case(name, x, interpret)
-        except Exception as exc:  # noqa: BLE001 — the verdict records the compiler's message; callers fail on ok=False
-            out.append({
-                "kernel": name, "ok": False,
-                "error": f"{type(exc).__name__}: {exc}"[:2000],
-            })
-            continue
-        out.append({
-            "kernel": name,
-            "ok": bool(
-                res["max_err"] <= TOLERANCE and res.get("pool_write_ok", True)
-            ),
-            "tolerance": TOLERANCE,
-            **res,
-        })
-    return out
+    return [_verdict(name, lambda: _run_case(name, x, interpret))
+            for name in cases]

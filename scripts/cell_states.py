@@ -13,7 +13,8 @@ numbers on stdout. One JSON line a snapshot goes to ``--out`` (default
 ``chiprun_out/states/<workload>.<seed>.jsonl`` under this checkout):
 ``engine.prefill`` (``by_path``: which launch the waves took),
 ``engine.spans``, ``engine.slow_steps``, ``process`` (``compiles.last``
-names each compiled program, when, and the span it compiled under)."""
+names each compiled program, when, and the span it compiled under),
+``attn`` (what decode launches attended, read and were spared)."""
 from __future__ import annotations
 
 import argparse
@@ -59,6 +60,7 @@ def main() -> int:
                 "t": snap["t"],
                 "engine": {k: state["engine"].get(k) for k in KEEP},
                 "process": state.get("process"),
+                "attn": state.get("attn"),
             }) + "\n")
         return snap
 

@@ -11,6 +11,7 @@ equal the reference's FULL forward pass over prompt + generated tokens —
 logits, not tokens."""
 
 import inspect
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ import pytest
 from benchmarks.reference import glm4_moe_lite as ref
 from radixmesh_tpu.cache import radix_tree
 from radixmesh_tpu.engine import Engine, SamplingParams
+from radixmesh_tpu.engine.request import RequestState
 from radixmesh_tpu.models import get_config, init_params
 from radixmesh_tpu.models.llama import (
     UnsupportedForModel,
@@ -31,7 +33,9 @@ from radixmesh_tpu.models.llama import (
     prefill_forward,
     read_moe_stats,
 )
+from radixmesh_tpu.ops.kernel_check import TOLERANCE, _err
 from radixmesh_tpu.ops.mla_attention import (
+    decode_groups,
     latent_chunk_attention,
     latent_decode_attention,
     latent_prefill_attention,
@@ -308,6 +312,141 @@ def test_decode_kernel_in_interpret_mode(block_tokens):
     assert got.shape == (4, 4, 32)
 
 
+def _shared_prefix_case(n_members, shared_pages, contiguous, own=(), seed=0,
+                        dtype=jnp.float32, page=16, width=12, W=128, Hq=4):
+    """A decode launch in which ``n_members`` rows hold the same
+    ``shared_pages`` pages first and pages of their own after, beside a
+    row that shares nothing, an empty row (length 0, whose table starts
+    with the shared pages all the same) and an idle one (length 1).
+    ``own``: tokens a member has past the shared pages, the current one
+    included (cycled). ``(kernel inputs, page table, lengths, live rows)``."""
+    rng = np.random.default_rng(seed)
+    B = n_members + 3
+    P = 2 + shared_pages + B * width  # page 0 and the last: nobody's context
+    ids = 1 + (np.arange(shared_pages) if contiguous
+               else rng.permutation(shared_pages))
+    free = iter(range(1 + shared_pages, P - 1))
+    pt = np.zeros((B, width), np.int32)
+    lengths = np.ones(B, np.int32)
+    own = own or (1, page + 3, 2 * page, 7)
+    for b in range(B - 1):  # the last row idles
+        pt[b] = [next(free) for _ in range(width)]
+        if b != n_members:  # row n_members shares nothing
+            pt[b, :shared_pages] = ids
+        lengths[b] = shared_pages * page + own[b % len(own)]
+    lengths[n_members + 1] = 0
+    pos = np.maximum(lengths - 1, 0)
+    slots = pt[np.arange(B), pos // page] * page + pos % page
+    slots[n_members + 1] = (P - 1) * page  # the reference writes it, alone
+    x = (
+        jnp.asarray(rng.normal(size=(B, Hq, W)), dtype),
+        jnp.asarray(rng.normal(size=(B, W)), dtype),
+        jnp.asarray(rng.normal(size=(1, 2, 1, P, page, W)), dtype),
+        slots.astype(np.int32),
+    )
+    live = [b for b in range(B) if lengths[b] > 1]
+    return x, pt, lengths, live
+
+
+def _check_grouped(x, pt, lengths, groups, atol=1e-5, block_tokens=32):
+    """The grouped launch against the gather reference, against the same
+    launch with no groups, and its write through the aliased pool."""
+    q, row, pool, slots = x
+    kw = dict(v_width=32, scale=0.2, block_tokens=block_tokens, interpret=True)
+    want, pool_ref = latent_decode_attention(
+        q, row, pool, slots, pt, lengths, 1, 32, 0.2, use_kernel=False)
+    got, pool_k = mla_decode_kernel(
+        q, row, pool, slots, pt, lengths, 1, jnp.asarray(groups), **kw)
+    alone, _ = mla_decode_kernel(q, row, pool, slots, pt, lengths, 1, **kw)
+    live = lengths > 1
+    for other in (want, alone):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[live],
+            np.asarray(other, np.float32)[live], atol=atol)
+    assert not np.asarray(got)[lengths == 0].any()
+    np.testing.assert_array_equal(  # but for the empty row's page, the last
+        np.asarray(pool_k)[..., :-1, :, :], np.asarray(pool_ref)[..., :-1, :, :])
+
+
+@pytest.mark.parametrize("contiguous", [True, False])
+@pytest.mark.parametrize("n_members", [1, 2, 6, 7, 13, 17])
+def test_decode_kernel_reads_a_shared_prefix_once(n_members, contiguous):
+    """Rows that hold the same pages first: one pass over those pages for
+    all of their queries (6 and 7 members: two tiles of query rows; 13 and
+    17: two and three passes), then each row over its own. A representative
+    table of consecutive pages takes the one-descriptor copy, a fragmented
+    one a copy a page."""
+    x, pt, lengths, live = _shared_prefix_case(n_members, 6, contiguous)
+    groups, stats = decode_groups(pt, lengths, live, 16, block_tokens=32)
+    assert groups.shape == ((n_members + 3) // 2, 9)  # of the batch alone
+    if n_members == 1:
+        assert stats == {"groups": 0, "members": 0, "passes": 0,
+                         "shared_tokens": 0}
+        assert (groups[:, 1:] == -1).all()
+    else:
+        assert stats == {
+            "groups": 1, "members": n_members, "passes": -(-n_members // 8),
+            "shared_tokens": (n_members - 1) * 96}
+        listed = groups[:, 1:][groups[:, 1:] >= 0]
+        assert sorted(listed) == list(range(n_members))  # not the empty row
+        assert set(groups[:stats["passes"], 0]) == {6}
+    _check_grouped(x, pt, lengths, groups)
+
+
+def test_shared_length_is_cut_to_whole_blocks():
+    """Five pages in common under blocks of two: two blocks are shared,
+    the fifth page is each row's own business — whether the host cuts it
+    (``decode_groups``) or the kernel is handed the pages uncut."""
+    x, pt, lengths, live = _shared_prefix_case(3, 5, True)
+    groups, stats = decode_groups(pt, lengths, live, 16, block_tokens=32)
+    assert stats["shared_tokens"] == 2 * 64 and groups[0, 0] == 4
+    _check_grouped(x, pt, lengths, groups)
+    groups[0, 0] = 5
+    _check_grouped(x, pt, lengths, groups)
+
+
+def test_a_member_that_ends_inside_the_shared_pages_caps_the_group():
+    """A member's context ends inside what the others share: the group
+    shares only the blocks that are whole below it. (Its token lies in a
+    shared page; it writes the row that is there already.)"""
+    (q, row, pool, slots), pt, lengths, live = _shared_prefix_case(4, 6, False)
+    lengths[2] = 3 * 16 + 5  # one whole block of two pages, and a half
+    at = lengths[2] - 1
+    slots[2] = pt[2, at // 16] * 16 + at % 16
+    row = row.at[2].set(pool[0, 1, 0, pt[2, at // 16], at % 16])
+    groups, stats = decode_groups(pt, lengths, live, 16, block_tokens=32)
+    assert stats["shared_tokens"] == 3 * 32 and groups[0, 0] == 2
+    _check_grouped((q, row, pool, slots), pt, lengths, groups)
+
+
+def test_an_empty_row_listed_in_a_group_reads_nothing():
+    """A pass that lists a row of length 0 folds blocks into a query
+    nobody reads: the row's own program still does nothing."""
+    x, pt, lengths, live = _shared_prefix_case(3, 4, True)
+    groups, _ = decode_groups(pt, lengths, live, 16, block_tokens=32)
+    assert groups[0, 4] == -1
+    groups[0, 4] = 4  # the empty row: its table starts alike, a tile of its own
+    _check_grouped(x, pt, lengths, groups)
+
+
+def test_two_groups_and_the_pool_dtype():
+    """Two groups in one launch, bf16 operands as served: within
+    ``kernel_check``'s tolerance of the reference."""
+    x, pt, lengths, live = _shared_prefix_case(
+        5, 4, False, dtype=jnp.bfloat16, seed=3)
+    spare = x[2].shape[3] - 2  # the idle row's pages were never handed out
+    pt[3:5, :4] = [spare, spare - 2, spare - 1, spare - 3]  # another prefix
+    groups, stats = decode_groups(pt, lengths, live, 16, block_tokens=32)
+    assert (stats["groups"], stats["members"], stats["passes"]) == (2, 5, 2)
+    q, row, pool, slots = x
+    want, _ = latent_decode_attention(
+        q, row, pool, slots, pt, lengths, 1, 32, 0.2, use_kernel=False)
+    got, _ = mla_decode_kernel(
+        q, row, pool, slots, pt, lengths, 1, jnp.asarray(groups), v_width=32,
+        scale=0.2, block_tokens=32, interpret=True)
+    assert _err(got, want, lengths > 1) <= TOLERANCE
+
+
 @pytest.mark.parametrize("block_tokens", [16, 64])
 def test_chunk_kernel_in_interpret_mode(block_tokens):
     """``radixmesh_mla_chunk``: a latent prefix in pages, then the chunk's
@@ -354,6 +493,84 @@ def test_model_steps_with_the_kernels_in_interpret_mode(model):
     with mock.patch.object(llama, "latent_decode_attention", forced):
         got = decode_step.__wrapped__(*args, page_size=16)[0]
     assert rel(np.asarray(got), np.asarray(want)) < TOL
+
+
+def test_rows_on_one_published_prefix_decode_through_one_shared_pass(model):
+    """Three requests on one published prefix of two kernel blocks and one
+    that shares nothing decode together, through the interpreted kernel:
+    the token ids each gives alone; the counters say what was attended,
+    what the launch had to read, and what it was spared; the groups follow
+    the rows — a member finishes, a newcomer makes the tree split the
+    prefix's node under the live rows and shares one block with them."""
+    import functools
+    from unittest import mock
+
+    import radixmesh_tpu.engine.engine as engine_mod
+    import radixmesh_tpu.models.llama as llama
+
+    cfg, _ = model
+    BLOCK = 2 * PAGE
+    shared = prompt_of(2 * BLOCK, seed=1)
+    asked = [(shared + prompt_of(5 + i, seed=10 + i), n)
+             for i, n in enumerate((9, 5, 9))]
+    asked.append((prompt_of(21, seed=20), 3))  # shares nothing, leaves first
+    late = (shared[:BLOCK] + prompt_of(9, seed=30), 6)  # splits the node
+
+    def alone(prompt, n):
+        eng = make_engine(model)
+        req = eng.add_request(prompt, SamplingParams(max_new_tokens=n))
+        while eng.has_work():
+            eng.step()
+        return req.generated
+
+    want = [alone(p, n) for p, n in asked + [late]]
+
+    def forced(q, row, pool, slots, pt, lengths, layer, v_width, scale, groups):
+        return mla_decode_kernel(
+            q, row, pool, slots, pt, lengths, layer, groups, v_width=v_width,
+            scale=scale, block_tokens=BLOCK, interpret=True)
+
+    step = jax.jit(decode_step.__wrapped__,  # traced here, with the kernel
+                   static_argnames=("cfg", "page_size", "mesh"))
+    eng = make_engine(model, max_batch=4)
+    with mock.patch.object(llama, "latent_decode_attention", forced), \
+            mock.patch.object(engine_mod, "decode_step", step), \
+            mock.patch.object(engine_mod, "decode_groups", functools.partial(
+                decode_groups, block_tokens=BLOCK)):
+        eng.generate([shared + prompt_of(3, seed=2)],
+                     SamplingParams(max_new_tokens=2))  # publishes the prefix
+        reqs = [eng.add_request(p, SamplingParams(max_new_tokens=n))
+                for p, n in asked]
+        seen = []
+        while eng.has_work():
+            if late is not None and reqs[3].state is RequestState.FINISHED:
+                reqs.append(eng.add_request(
+                    late[0], SamplingParams(max_new_tokens=late[1])))
+                late = None
+            before = dict(eng.attn_window)
+            eng.step()
+            aw = eng.attn_window
+            moved = {k: aw[k] - before[k] for k in (
+                "latent_tokens_attended", "latent_tokens_read",
+                "latent_tokens_shared")}
+            if not moved["latent_tokens_attended"]:
+                continue  # no decode launch in this step
+            g = aw["decode_groups"]
+            seen.append((g["groups"], g["members"], g["shared_tokens"]))
+            assert moved["latent_tokens_shared"] == g["shared_tokens"] * cfg.n_layers
+            assert moved["latent_tokens_read"] == (
+                moved["latent_tokens_attended"] - moved["latent_tokens_shared"])
+            assert g["passes"] == g["groups"] <= 1
+    assert [r.generated for r in reqs] == want
+    assert all(r.prefix_len == 2 * BLOCK for r in reqs[:3])
+    assert reqs[4].prefix_len == BLOCK
+    kinds = [k for k, _ in itertools.groupby(seen)]
+    assert kinds == [
+        (1, 3, 2 * 2 * BLOCK),  # three members on two blocks: two spared them
+        (1, 4, 3 * BLOCK),  # the newcomer shares ONE block: one level, so all do
+        (1, 3, 2 * BLOCK),  # a member finishes
+        (1, 2, 2 * BLOCK),  # the newcomer finishes: two blocks again, for two
+    ]
 
 
 @pytest.mark.parametrize("what", sorted(REFUSED))

@@ -179,6 +179,53 @@ def test_mellum2_period_compiles_for_one_v5e(v5e):
     assert not _copies_shaped_like(compiled, pool.shape)
 
 
+def test_glm47_decode_reads_shared_pages_in_one_pass_at_the_cells_bucket(v5e):
+    """GLM-4.7-Flash ``decode_step`` at the docs cell's bucket (32 rows x
+    2 048 pages, the served pool's slots): both passes of the latent decode
+    kernel lower for the chip under the one name the trace readers key on,
+    the pool aliased through. What the rows share is DATA: the groups are
+    an abstract ``[rows // 2, 9]`` argument here, so a group count or a
+    shared length that reached a static argument or a shape would fail the
+    trace; and the host gives lines of that one shape whatever the page
+    table holds, so every launch of a row bucket runs this program."""
+    import numpy as np
+
+    from radixmesh_tpu.ops.mla_attention import decode_groups
+
+    sd = _sd(v5e)
+    rows, pages = 32, 2048
+    cfg = get_config("glm-4.7-flash", n_layers=2, vocab_size=4096)
+    params = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(lambda k: _init_params(k, cfg, None, None),
+                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    pool_shape = cfg.kv_pool_shape(196608)
+    table = np.arange(rows * pages, dtype=np.int32).reshape(rows, pages)
+    lengths = np.full(rows, 20000, np.int32)
+    nothing, none = decode_groups(table, lengths, range(rows), PAGE)
+    table[:13, :1024] = table[0, :1024]  # 13 rows on one 16 k-token document
+    table[20:22, :64] = table[20, :64]  # two on another kilotoken
+    shared, some = decode_groups(table, lengths, range(rows), PAGE)
+    assert (none["groups"], some["groups"], some["passes"]) == (0, 2, 3)
+    assert some["shared_tokens"] == 12 * 16384 + 1024
+    assert nothing.shape == shared.shape == (rows // 2, 9)
+    assert nothing.dtype == shared.dtype == np.int32
+    compiled = decode_step.lower(
+        params, cfg, sd((rows,), jnp.int32), sd(pool_shape, cfg.dtype),
+        sd((rows,), jnp.int32), sd((rows, pages), jnp.int32),
+        sd((rows,), jnp.int32), PAGE,
+        decode_groups=sd(shared.shape, jnp.int32),
+    ).compile()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+                       r"radixmesh_mla_decode", compiled.as_text())
+    kernels = set(re.findall(r"radixmesh_(?!tpu)[a-z_]+[a-z]", compiled.as_text()))
+    # the dense first layer and the scanned sparse ones: two passes each
+    assert len(calls) == 4, calls
+    assert kernels == {"radixmesh_mla_decode", "radixmesh_moe_gmm_decode"}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= np.prod(pool_shape) * 2
+
+
 # The served pools (benchmarks/cells/*.json): Qwen2-7B, Mellum2 (12 layers),
 # GLM-4.7-Flash (8 layers, one latent plane), GLM-5.2 (7 layers) and its
 # index plane.
