@@ -17,7 +17,10 @@ weights from a seed — and checks what comes out by the repo's own means:
                  the decode kernel also at Mellum2's heads with a window
                  start, context past the window; the latent (MLA) decode
                  kernel at GLM-4.7-Flash's row, with and without rows
-                 that share their first pages.
+                 that share their first pages; the chunk kernel over a
+                 prefix of several blocks at a chat wave's, an agent
+                 turn's and a window model's geometry (edge blocks,
+                 plain blocks, query blocks with no token).
 3. ``serve``     start the server; send a short prompt (dense prefill), a
                  prompt over ``long_prefill_threshold`` (chunk kernel), a
                  second turn sharing its prefix (``cached_tokens`` > 0),
@@ -81,6 +84,17 @@ CHIP = {
     # its first 512): six rows on 1 280 shared tokens, two on their own.
     "geometry_latent": dict(hq=20, width=640, v_width=512, page=16, batch=8,
                             max_pages=128),
+    # The chunk kernel over a prefix of several 512-token blocks, as a turn
+    # of a session launches it: a chat wave (256 new on up to 3.8 k), an
+    # agent turn (LFM2's two heads of 64 to a row: 4 wide heads, G = 8,
+    # logits scaled for 64; 512 new on up to 7.7 k) and Mellum2's window.
+    "geometry_prefix": [
+        dict(hq=28, hkv=4, head_dim=128, page=16, chunk=256, max_pages=256),
+        dict(hq=32, hkv=4, head_dim=128, page=16, chunk=512, max_pages=512,
+             scale=0.125),
+        dict(hq=32, hkv=4, head_dim=128, page=16, chunk=512, max_pages=256,
+             window=1024),
+    ],
     "interpret": False,
     "short": 40,  # dense prefill
     "long": 1100,  # > long_prefill_threshold (1024) → chunk kernel
@@ -112,6 +126,11 @@ REHEARSAL = {
                             chunk=8, max_pages=8, window=12),
     "geometry_latent": dict(hq=4, width=128, v_width=32, page=4, batch=8,
                             max_pages=16, block_tokens=16),
+    "geometry_prefix": [
+        dict(hq=7, hkv=1, head_dim=32, page=4, chunk=16, max_pages=16),
+        dict(hq=8, hkv=2, head_dim=32, page=4, chunk=16, max_pages=16,
+             scale=0.125, window=20),
+    ],
     "interpret": True,
     "short": 40,
     "long": 300,
@@ -394,6 +413,7 @@ def phase_kernels(args) -> dict:
     from radixmesh_tpu.ops.kernel_check import (
         DEFAULT_CASES,
         WINDOW_CASES,
+        check_chunk_prefix,
         check_kernels,
         check_latent_decode,
     )
@@ -409,6 +429,10 @@ def phase_kernels(args) -> dict:
         {**r, "geometry": m["geometry_latent"]}
         for r in check_latent_decode(
             interpret=m["interpret"], **m["geometry_latent"])
+    ] + [
+        {**r, "geometry": g}
+        for g in m["geometry_prefix"]
+        for r in check_chunk_prefix(interpret=m["interpret"], **g)
     ]
     return {"results": results, "seconds": round(time.monotonic() - t0, 2)}
 

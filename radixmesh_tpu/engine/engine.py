@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import functools
 import itertools
 import time
 from dataclasses import dataclass, field
@@ -649,16 +650,32 @@ class Engine:
                 # default_window_slots).
                 **pool_groups(cfg, num_slots, max_batch, page_size),
             )
-        if kernels and not cfg.latent:
-            from radixmesh_tpu.ops.paged_attention import decode_block_tokens
+        if not cfg.latent:
+            from radixmesh_tpu.ops.paged_attention import (
+                chunk_query_tokens,
+                decode_block_tokens,
+            )
 
-            # Static: which block geometry the decode kernel's numbers on
+            # Static: which block geometry the paged kernels' numbers on
             # this node come from (a chip of a tp mesh holds its heads).
             tp = device_mesh.shape.get("tp", 1) if device_mesh is not None else 1
-            self.attn_window["decode_block_tokens"] = decode_block_tokens(
-                max(1, row_heads // tp), page_size, row_width,
-                self.pool.kv.dtype, self.max_pages, kv_quant is not None,
-            )
+            shape = dict(Hkv=max(1, row_heads // tp), page=page_size,
+                         D=row_width, dtype=self.pool.kv.dtype,
+                         quantized=kv_quant is not None)
+            if kernels:
+                self.attn_window["decode_block_tokens"] = decode_block_tokens(
+                    max_pages=self.max_pages, **shape)
+            # A chunk launch's query blocks (rows x chunk // the chunk
+            # kernel's queries a program: one program a layer each), and
+            # those whose first query lies past the row's last new token —
+            # padding of the launched shape, which the kernel answers with
+            # zeros and no work. Host arithmetic where _prefill_group
+            # counts, whichever of kernel and reference serves.
+            self.attn_window |= {
+                "chunk_query_blocks": 0, "chunk_query_blocks_skipped": 0}
+            self._chunk_query_tokens = functools.partial(
+                chunk_query_tokens, group=cfg.n_heads // row_heads,
+                q_dtype=cfg.dtype, **shape)
         if kv_tier_dir is not None and host_cache_slots <= 0:
             raise ValueError(
                 "kv_tier_dir requires a host tier (host_cache_slots > 0): "
@@ -2810,6 +2827,12 @@ class Engine:
                     maxp * ps + C > self.cfg.index_topk,
                 )
             self.pool.note_runs(sl[:N, 0], nv)
+            if not self.cfg.latent:
+                cblk = self._chunk_query_tokens(C, max_pages=maxp)
+                blocks = B * (C // cblk)
+                self.attn_window["chunk_query_blocks"] += blocks
+                self.attn_window["chunk_query_blocks_skipped"] += (
+                    blocks - int((-(-nv // cblk)).sum()))
             states = None
             if self.state_stats is not None:
                 states = self._chunk_states(group, starts, nv, B, ci == 0)

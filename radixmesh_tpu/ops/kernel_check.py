@@ -6,8 +6,9 @@ the algorithm; it says nothing about what Mosaic compiles for a real
 model's head geometry on silicon. This module is the same comparison as
 a callable: ``chip_smoke.py`` runs it on the chip at the served geometry
 (Qwen2-7B: Hq 28, Hkv 4 → G 7, D 128, page 16, chunk 512; the decode
-kernel also at Mellum2's Hq 32 → G 8 with a 1 024-key window) for the
-kernels the default ``serve`` flags reach, ``scripts/kernelbench.py``
+kernel also at Mellum2's Hq 32 → G 8 with a 1 024-key window; the chunk
+kernel also over a prefix of several blocks, ``check_chunk_prefix``) for
+the kernels the default ``serve`` flags reach, ``scripts/kernelbench.py``
 runs it for the variants only a flag selects, and the CPU rehearsal runs
 it in interpret mode at tiny size.
 
@@ -44,17 +45,19 @@ from radixmesh_tpu.ops.quant import quantize_kv
 
 __all__ = [
     "TOLERANCE", "DEFAULT_CASES", "FLAG_CASES", "WINDOW_CASES", "LATENT_CASES",
-    "check_kernels", "check_latent_decode",
+    "PREFIX_CASES", "check_kernels", "check_latent_decode",
+    "check_chunk_prefix",
 ]
 
 # Absolute AND relative bound on |kernel - oracle| per output element.
 # Both sides read the same bf16 (or int8 + f32 scale) K/V and accumulate
 # in f32; they differ by (a) the final rounding of an O(1) output to
 # bf16, one ulp = 2^-8 ≈ 4e-3 relative, and (b) what the MXU is given.
-# The heads-batched decode and pool kernels hand it the pool's own bf16:
+# The heads-batched decode and pool kernels and (PR 43) the chunk kernel
+# hand it the pool's own bf16:
 # q·k is then exact (bf16 × bf16 products, f32 sums; 1/sqrt(D) applied to
 # the f32 scores) and only ``p`` is rounded to bf16 for p·v, 2^-9 relative
-# per weight. The per-head, chunk and int8 paths hand it f32 operands, over
+# per weight. The per-head and int8 paths hand it f32 operands, over
 # which it makes ONE bf16 pass (measured on the chip, PERF.md §6 PR 32:
 # an f32 ``q`` against a bf16 pool reads 9e-5 mean error where the bf16
 # rounding of the exact answer reads 6e-5): ``q·scale`` and ``p`` both
@@ -74,6 +77,10 @@ WINDOW_CASES = ("fused_decode_window",)
 # every row over its whole context, and the same launch with the rows that
 # hold the same pages first told so (one shared pass, then each row's own).
 LATENT_CASES = ("mla_decode", "mla_decode_grouped")
+# The chunk kernel over a prefix of several blocks (``check_chunk_prefix``):
+# what a turn of a session launches. Its rows' edge blocks, the blocks
+# between them and the query blocks that hold no token.
+PREFIX_CASES = ("chunk_prefill_prefix",)
 # Variants only a flag selects: int8 KV (``--kv-quant int8``), the
 # per-head grids (``fuse_heads=False``, huge-Hkv configs), and the
 # read-only pool kernel (pp serving, the small-batch crossover).
@@ -309,6 +316,79 @@ def check_latent_decode(
         _verdict("mla_decode_grouped",
                  lambda: {**case(jnp.asarray(groups)), "groups": stats}),
     ]
+
+
+def check_chunk_prefix(
+    *,
+    hq: int,
+    hkv: int,
+    head_dim: int,
+    page: int,
+    chunk: int,
+    max_pages: int,
+    window: int = 0,
+    scale: float | None = None,
+    interpret: bool = False,
+    seed: int = 0,
+) -> list[dict]:
+    """``PREFIX_CASES`` at the given geometry, ``check_kernels``' result
+    dicts: ``radixmesh_chunk_attn`` against the gather reference on a bf16
+    pool, four rows of one launch. Row 0 holds one ascending run of pages
+    and a prefix that ends mid-page, as deep as the table allows; row 1
+    permuted pages, a prefix of whole pages and a chunk whose last three
+    quarters hold no token; row 2 nothing at all (a row on the scratch
+    slot: its output must be zeros); row 3 permuted pages and a prefix of
+    one page and three tokens. The kernel's pool holds NaN in every slot of
+    a row's last prefix page past the prefix — fetched, never visible.
+    With ``window`` the call runs twice through one traced scalar, with it
+    and with 0 (a model's window and full layers share an executable)."""
+    rng = np.random.default_rng(seed)
+    B, L, full = 4, 2, max_pages * page
+    P = B * max_pages
+    pages = np.concatenate([  # one run, then the other rows' own, shuffled
+        np.arange(max_pages),
+        max_pages + rng.permutation((B - 1) * max_pages),
+    ]).reshape(B, max_pages).astype(np.int32)
+    deep = full - chunk
+    prior = np.asarray(
+        [deep - page // 2, deep // page // 2 * page, 0, page + 3], np.int32)
+    new = np.asarray([chunk, max(1, chunk // 4 - 3), 0, chunk], np.int32)
+    kv = rng.standard_normal((2, L, hkv, P, page, head_dim)).astype(np.float32)
+    dirty = kv.copy()
+    for b in range(B):
+        if prior[b] % page:
+            dirty[:, :, :, pages[b, prior[b] // page], prior[b] % page:] = np.nan
+
+    def bf16(shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    q = bf16((B, chunk, hq, head_dim))
+    kc, vc = bf16((B, chunk, hkv, head_dim)), bf16((B, chunk, hkv, head_dim))
+    pt, pr, kvlen = jnp.asarray(pages), jnp.asarray(prior), jnp.asarray(prior + new)
+    pos = pr[:, None] + jnp.arange(chunk)[None]
+    valid = np.arange(chunk)[None, :] < new[:, None]
+
+    def case(w):
+        w = None if w is None else jnp.int32(w)
+        got = paged_chunk_attention_kernel(
+            q, kc, vc, jnp.asarray(dirty, jnp.bfloat16), pt, pr, kvlen, 1,
+            interpret=interpret, window=w, scale=scale,
+        )
+        want = paged_chunk_attention(
+            q, kc, vc, jnp.asarray(kv, jnp.bfloat16), pt, pos, pr, kvlen, 1,
+            use_kernel=False, window=w, scale=scale,
+            kv_block_pages=int(np.gcd(max_pages, 32)),
+        )
+        idle = float(np.max(np.abs(np.asarray(got[2], np.float32))))
+        return {"max_err": max(_err(got, want, valid), idle),
+                "idle_row_max": idle}
+
+    def run():
+        if not window:
+            return case(None)
+        return max((case(window), case(0)), key=lambda r: r["max_err"])
+
+    return [_verdict(PREFIX_CASES[0], run)]
 
 
 def check_kernels(

@@ -54,6 +54,7 @@ from radixmesh_tpu.ops.attention import (
 from radixmesh_tpu.ops.paged_attention import (
     _MASK,
     _GatedCopy,
+    _block_chain,
     _block_geometry,
     _contig_flags,
 )
@@ -175,52 +176,6 @@ def _row_block_copier(pool_hbm, layer, buf, sems, page_table_ref, contig_ref,
         )
 
     return copy
-
-
-def _block_chain(g, n_programs, row_of, first_of, end_of, bk, copy,
-                 buffer_index_ref, init_flag_ref):
-    """The blocks of every program of a grid through ONE double buffer.
-    Program ``g`` reads blocks ``first_of(g), first_of(g) + 1, ...`` of
-    page-table row ``row_of(g)``, as far as ``end_of(g)`` tokens reach;
-    ``copy(row, block, slot)`` is a block's transfer. Returns ``fetch(i)``:
-    the buffer slot that holds block ``i`` of this program, with the copy
-    of its grid-order successor in flight — the program's next block, or
-    the first block of the next program that has any. The slot in use and
-    whether anything was started yet live in SMEM and persist across
-    programs."""
-
-    def successor(i):
-        def next_program():
-            def skip_empty(_, x):
-                at = jax.lax.clamp(0, x, n_programs - 1)
-                empty = first_of(at) * bk >= end_of(at)
-                return jnp.where((x < n_programs) & empty, x + 1, x)
-
-            ng = jax.lax.fori_loop(g + 1, n_programs, skip_empty, g + 1)
-            return ng, first_of(jax.lax.clamp(0, ng, n_programs - 1))
-
-        return jax.lax.cond(i * bk < end_of(g), lambda: (g, i), next_program)
-
-    def fetch(i):
-        first = init_flag_ref[0]
-        init_flag_ref[0] = 0
-        s = buffer_index_ref[0]
-        ng, ni = successor(i + 1)
-
-        @pl.when(first != 0)
-        def _cold_start():
-            copy(row_of(g), i, s).start()
-
-        @pl.when(ng < n_programs)
-        def _prefetch_next():
-            ns = 1 - s
-            copy(row_of(jax.lax.clamp(0, ng, n_programs - 1)), ni, ns).start()
-            buffer_index_ref[0] = ns
-
-        copy(row_of(g), i, s).wait()
-        return s
-
-    return fetch
 
 
 def _mla_shared_kernel(

@@ -15,10 +15,10 @@ What the kernels do about both:
 1. **Run-coalesced block DMAs.** Where a compute block's pages are
    *consecutive* ids, the block moves as ONE descriptor
    (``src.at[pl.ds(first, ppb)]``), otherwise page by page. The per-head
-   and chunk kernels read a per-(row, block) flag precomputed by the
-   wrapper (``_contig_flags``); the heads-batched kernels decide from a
-   running count of the table's breaks (``_mh_tables``), which answers for
-   a block that starts at ANY page. A pool under churn hands out pages in
+   decode kernels read a per-(row, block) flag precomputed by the wrapper
+   (``_contig_flags``); the heads-batched kernels and the chunk kernel
+   decide from a running count of the table's breaks (``_mh_tables``),
+   which answers for a block that starts at ANY page. A pool under churn hands out pages in
    no order (the allocator's free list is LIFO), so served tables mostly
    take the per-page plan: it has to be cheap too.
 2. **Heads-batched programs by default.** ``fuse_heads`` (grid ``(B,)``,
@@ -36,7 +36,8 @@ What the kernels do about both:
 3. **The MXU sees the pool's dtype.** The heads-batched kernels contract a
    bf16 block as fetched — ``q`` bf16 and unscaled, ``1/sqrt(D)`` on the
    float32 scores, ``p`` bf16, float32 accumulation — and mask only a
-   row's edge blocks (``_mh_contract``). (The MXU made one bf16 pass over
+   row's edge blocks (``_mh_contract``; the chunk kernel's ``_chunk_fold``
+   takes the same operands and masks every block). (The MXU made one bf16 pass over
    float32 operands anyway — measured, PERF.md §6 PR 32 — so this loses
    nothing and keeps ``q`` exact.)
 4. **Prepared scales for int8 pools.** The *wrapper* gathers the page
@@ -80,8 +81,10 @@ geometry by ``ops/kernel_check.py``; each ``pallas_call`` carries a stable
   current token's contribution is folded in from VMEM — which also kills
   the read-after-write hazard with cross-program block prefetch.
 - ``paged_chunk_attention_kernel`` — prefill: prior pool pages streamed
-  through the online softmax, the current chunk folded in as one dense
-  causal block.
+  through the online softmax — 512-token blocks of all kv heads, chained
+  across the (row, query block) programs as the decode kernels chain
+  theirs — the current chunk folded in as one dense causal block; a query
+  block that holds no token of its row does nothing.
 """
 
 # meshcheck: file-ok[timeout-audit] every wait() in this file is a
@@ -99,6 +102,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "chunk_query_tokens",
     "decode_block_tokens",
     "paged_attention_kernel",
     "paged_attention_pool_kernel",
@@ -275,8 +279,10 @@ class _MhBlockCopy:
     scalars, so issued and awaited bytes always match."""
 
     def __init__(self, kv_hbm, layer, buf, sem, page_table_ref, breaks_ref,
-                 flat_offset, n_pages, n_valid):
-        self._src = kv_hbm.at[:, layer]  # [2, Hkv, P, page, D]
+                 flat_offset, n_pages, n_valid, heads=None):
+        # [2, Hkv, P, page, D], or the ``heads`` of it that ``buf`` holds
+        self._src = (kv_hbm.at[:, layer] if heads is None
+                     else kv_hbm.at[:, layer, heads])
         self._buf, self._sem = buf, sem
         self._table, self._offset = page_table_ref, flat_offset
         self._breaks = breaks_ref
@@ -327,6 +333,51 @@ class _MhBlockCopy:
             pltpu.make_async_copy(view, view, self._sem).wait()
 
         self._chunks(absorb)
+
+
+def _block_chain(g, n_programs, row_of, first_of, end_of, bk, copy,
+                 buffer_index_ref, init_flag_ref):
+    """The blocks of every program of a grid through ONE double buffer.
+    Program ``g`` reads blocks ``first_of(g), first_of(g) + 1, ...`` of
+    page-table row ``row_of(g)``, as far as ``end_of(g)`` tokens reach;
+    ``copy(row, block, slot)`` is a block's transfer. Returns ``fetch(i)``:
+    the buffer slot that holds block ``i`` of this program, with the copy
+    of its grid-order successor in flight — the program's next block, or
+    the first block of the next program that has any. The slot in use and
+    whether anything was started yet live in SMEM and persist across
+    programs."""
+
+    def successor(i):
+        def next_program():
+            def empty(x):
+                at = jax.lax.clamp(0, x, n_programs - 1)
+                return (x < n_programs) & (first_of(at) * bk >= end_of(at))
+
+            ng = jax.lax.while_loop(empty, lambda x: x + 1, g + 1)
+            return ng, first_of(jax.lax.clamp(0, ng, n_programs - 1))
+
+        return jax.lax.cond(i * bk < end_of(g), lambda: (g, i), next_program)
+
+    def fetch(i):
+        first = init_flag_ref[0]
+        init_flag_ref[0] = 0
+        s = buffer_index_ref[0]
+        ng, ni = successor(i + 1)
+
+        @pl.when(first != 0)
+        def _cold_start():
+            copy(row_of(g), i, s).start()
+
+        @pl.when(ng < n_programs)
+        def _prefetch_next():
+            ns = 1 - s
+            copy(row_of(jax.lax.clamp(0, ng, n_programs - 1)), ni, ns).start()
+            buffer_index_ref[0] = ns
+
+        copy(row_of(g), i, s).wait()
+        return s
+
+    return fetch
 
 
 def _run_block_loop(
@@ -749,22 +800,35 @@ def _mh_tables(page_table, page: int, pages_per_block: int | None):
     return pt, ppb, padded, flat(pt), flat(breaks)
 
 
+def _operand_q(q, kv_dtype, quantized: bool, scale: float | None = None):
+    """``(q [..., D], scale)`` as the MXU takes it against a block of the
+    pool. A float pool at least as wide as ``q``: ``q`` in the pool's
+    dtype, unscaled, and ``1/sqrt(D)`` (or the caller's ``scale``: the row
+    is wider than the head) for the kernel to apply to its float32 scores
+    — the MXU then contracts the pool's blocks as fetched. An int8 pool, or
+    a ``q`` wider than the pool: float32 ``q`` scaled here (``scale`` None)
+    against blocks upcast in VMEM, as these kernels always computed."""
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    if _operand_dtype(q.dtype, kv_dtype, quantized) != kv_dtype:
+        return q.astype(jnp.float32) * scale, None
+    return q.astype(kv_dtype), scale
+
+
+def _operand_dtype(q_dtype, kv_dtype, quantized: bool):
+    """The dtype ``_operand_q`` gives ``q``, from the dtypes alone."""
+    if quantized or jnp.promote_types(q_dtype, kv_dtype) != kv_dtype:
+        return jnp.dtype(jnp.float32)
+    return jnp.dtype(kv_dtype)
+
+
 def _mh_operands(q, kv_dtype, quantized: bool, group: int,
                  scale: float | None = None):
-    """``(q [B, Hkv, G, D], scale)`` as the mh kernels take them. A float
-    pool at least as wide as ``q``: ``q`` in the pool's dtype, unscaled,
-    and ``1/sqrt(D)`` for the kernel to apply to its float32 scores — the
-    MXU then contracts the pool's blocks as fetched. An int8 pool, or a
-    ``q`` wider than the pool: float32 ``q`` scaled here (``scale`` None)
-    against blocks upcast in VMEM, as these kernels always computed."""
+    """``(q [B, Hkv, G, D], scale)`` as the mh decode kernels take them
+    (``_operand_q``)."""
     B, Hq, D = q.shape
-    if scale is None:  # else the caller's: the row is wider than the head
-        scale = 1.0 / (D ** 0.5)
-    if quantized or jnp.promote_types(q.dtype, kv_dtype) != kv_dtype:
-        q, scale = q.astype(jnp.float32) * scale, None
-    q = q.astype(jnp.float32 if scale is None else kv_dtype).reshape(
-        B, Hq // group, group, D
-    )
+    q, scale = _operand_q(q, kv_dtype, quantized, scale)
+    q = q.reshape(B, Hq // group, group, D)
     # A group fills whole 8-row tiles (zero rows, dropped by _mh_unpad):
     # the registers hold 8 rows whatever G is.
     return jnp.pad(q, ((0, 0), (0, 0), (0, -group % 8), (0, 0))), scale
@@ -1089,8 +1153,8 @@ def _fused_kernel(
 
 
 def _per_head_pages_per_block(page: int, max_pages: int) -> int:
-    """The per-head grids' and the chunk kernel's default: ~256 tokens of
-    one head per compute block."""
+    """The per-head decode grids' default: ~256 tokens of one head per
+    compute block."""
     return max(1, min(max_pages, -(-256 // page)))
 
 
@@ -1680,33 +1744,109 @@ def paged_decode_fused_kernel(
     return attn, kv_out
 
 
+def _across_lanes(col, n: int):
+    """A lane-replicated ``[R, W]`` value (the running max) as the
+    ``[R, n]`` that a block's scores subtract: whole copies of it side by
+    side where ``W`` divides ``n`` — registers reused, where a broadcast
+    of its first column writes each of them (PERF.md §6 PR 43: an eighth
+    of the kernel's time at 896 x 512 scores) — else that broadcast."""
+    w = col.shape[-1]
+    if n % w:
+        return col[:, :1]
+    return col if n == w else pltpu.repeat(col, n // w, 1)
+
+
+def _chunk_fold(q, k, v, scale, seen, seen_v, k_scale, v_scale,
+                m_ref, l_ref, acc_ref):
+    """One block of keys through one kv head's online softmax: ``q``
+    ``[R, D]`` against ``k`` / ``v`` ``[n, D]``. q·k as ``_mh_contract``
+    has it — the MXU sees ``q``'s dtype (the pool's own for a float pool,
+    so a bf16 block is contracted as it was fetched; a product of two bf16
+    values is exact in float32) and ``scale`` is applied to the float32
+    scores (what that buys is VMEM — half the q, chunk K and chunk V tiles,
+    so twice the queries a program — not MXU time: float32 operands
+    measured the same, PERF.md §6 PR 43); scores, running max and sum,
+    ``exp``, correction and accumulator are float32. ``p`` enters p·v as the float32 it is (NOT
+    rounded to the operand dtype as the decode kernels round it: a chunk's
+    logits are held to the dense prefill's at 2e-2,
+    ``tests/test_long_context.py``, and a bf16 ``p`` moved them to 5e-2;
+    on the chip the MXU makes its one bf16 pass over it either way). ``seen``
+    (broadcastable to ``[R, n]``) says which keys a query sees; BOTH factors
+    of p·v are zeroed at every invisible position (see ``_contig_flags``),
+    ``v`` by ``seen_v`` ``[n, 1]`` — None for the chunk's own dense K/V,
+    which hold activations wherever they are masked. ``k_scale`` /
+    ``v_scale``: an int8 block's ``(1, n)`` prepared scale rows."""
+    s = jax.lax.dot_general(  # [R, n]
+        q, k.astype(q.dtype),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    if scale is not None:
+        s = s * scale
+    if k_scale is not None:
+        s = s * k_scale
+    s = jnp.where(seen, s, _MASK)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - _across_lanes(m_new, s.shape[-1]))
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    if v_scale is not None:
+        p = p * v_scale
+    p = jnp.where(seen, p, 0.0)
+    if seen_v is not None:
+        v = jnp.where(seen_v, v, jnp.zeros_like(v))
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(  # [R, D]
+        p, v.astype(jnp.float32),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _chunk_kernel(
     # scalar prefetch
     prior_ref,  # SMEM [B] pool-context tokens per row (page-part bound)
     kvlen_ref,  # SMEM [B] valid context incl. this chunk
-    page_table_ref,  # SMEM [B * padded] flattened
-    contig_ref,  # SMEM [B * nblocks]
+    page_table_ref,  # SMEM [B * padded + ppb] flattened (_mh_tables)
+    breaks_ref,  # SMEM, same shape: breaks of consecutiveness (_mh_tables)
     layer_ref,  # SMEM [1]
+    buffer_index_ref,  # SMEM [1] — double-buffer slot, persists across programs
+    init_flag_ref,  # SMEM [1] — 1 until the first block of the launch started
     *refs,
     page: int,
     pages_per_block: int,
     pages_per_seq: int,
-    chunk: int,  # C — dense keys per program
+    batch_size: int,
+    head_groups: int,  # Hkv // heads a program
+    q_blocks: int,  # C // Cblk
     c_block: int,  # Cblk — queries per program
     group: int,  # G — q heads per kv head
+    scale: float | None,  # of the f32 scores; None: q arrives pre-scaled
     quantized: bool,
     windowed: bool = False,
 ):
-    """Chunk-prefill attention program for one ``(b, h, c-block)``: stream
-    the row's PRIOR context from pool pages through the online softmax
-    (double-buffered DMA within the program), then fold the current chunk
-    in as one dense causal block from VMEM. Query positions are canonical
-    (``prior + chunk offset`` — see the wrapper's contract), so masks
-    derive from scalars: prior bound for the page part, intra-chunk
-    causality + ``kvlen`` bound for the dense part. ``windowed``: one
-    more scalar-prefetch value, the keys a query of this layer sees (its
-    own among them; 0 = all). Keys further back are masked, and the page
-    loop starts at the block that holds the first key the program's
+    """Chunk-prefill attention program for one ``(b, head group, c-block)``:
+    stream the row's PRIOR context from pool pages through the online
+    softmax — K and V of the program's kv heads in one ``_MhBlockCopy`` a
+    block, chain-prefetched across programs (``_block_chain``: the next
+    program's first block is in flight behind this program's dense block)
+    — then fold the current chunk in as one dense causal block from VMEM.
+    ``q_ref`` is ``[heads, Cblk * G, D]``, row ``r`` = query ``r // G`` of
+    the block, q head ``r % G`` of the kv head (``_operand_q``).
+
+    Query positions are canonical (``prior + chunk offset`` — see the
+    wrapper's contract), so masks derive from scalars: prior bound for the
+    page part, intra-chunk causality + ``kvlen`` bound for the dense part.
+    Every prior block takes the same masked fold (a plain fold for the
+    blocks between a program's edges measured the same, PERF.md §6 PR 43).
+    A program whose first query is at or past the row's last new token —
+    the padding of the chunk's shape, a row that has run out, a row on the
+    scratch slot — writes zeros and moves nothing. ``windowed``: one more
+    scalar-prefetch value, the keys a query of this layer sees (its own
+    among them; 0 = all). Keys further back are masked, and the blocks
+    are counted from the page (an int8 pool: the block, whose prepared
+    scale rows are block-aligned) that holds the first key the program's
     FIRST query still sees — earlier pages are not fetched."""
     window = None
     if windowed:
@@ -1714,155 +1854,195 @@ def _chunk_kernel(
         window = window_ref[0]
     if quantized:
         (q_ref, kc_ref, vc_ref, kv_hbm, prep_ref, o_ref,
-         m_scr, l_scr, acc_scr, k_buf, v_buf, sems) = refs
+         m_scr, l_scr, acc_scr, kv_buf, sems) = refs
     else:
         (q_ref, kc_ref, vc_ref, kv_hbm, o_ref,
-         m_scr, l_scr, acc_scr, k_buf, v_buf, sems) = refs
+         m_scr, l_scr, acc_scr, kv_buf, sems) = refs
         prep_ref = None
-    b, h, cb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, hg, cb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     layer = layer_ref[0]
-    prior = prior_ref[b]
-    kvlen = kvlen_ref[b]
+    heads, rows = q_ref.shape[0], q_ref.shape[1]
     bk = page * pages_per_block
-    nblocks = pages_per_seq // pages_per_block
-    q_rows = c_block * group
+    align = bk if quantized else page
+    n_programs = batch_size * head_groups * q_blocks
 
-    def block_copies(i, slot):
-        off = b * pages_per_seq + i * pages_per_block
-        contig = contig_ref[b * nblocks + i]
-        return [
-            _BlockCopy(kv_hbm, 0, layer, h, k_buf.at[slot], sems.at[slot, 0],
-                       page_table_ref, off, pages_per_block, contig),
-            _BlockCopy(kv_hbm, 1, layer, h, v_buf.at[slot], sems.at[slot, 1],
-                       page_table_ref, off, pages_per_block, contig),
-        ]
-
-    q = q_ref[...].astype(jnp.float32).reshape(q_rows, -1)  # pre-scaled
-    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-    n_blocks = pl.cdiv(prior, bk)
-    first = 0
-    if windowed:
-        # Query row r of this program sits at prior + cb*Cblk + r // G.
-        q_pos = (
-            prior + cb * c_block
-            + jax.lax.broadcasted_iota(jnp.int32, (q_rows, 1), 0) // group
-        )
-        first = jnp.where(
-            window > 0,
-            jnp.maximum(prior + cb * c_block - window + 1, 0) // bk,
-            0,
-        )
-
-        def in_window(kv_pos):
-            return (window <= 0) | (q_pos - kv_pos < window)
-
-    @pl.when(n_blocks > first)
-    def _cold_start():
-        for c in block_copies(first, jax.lax.rem(first, 2) if windowed else 0):
-            c.start()
-
-    def body(i, _):
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_blocks)
-        def _prefetch_next():
-            for c in block_copies(i + 1, 1 - slot):
-                c.start()
-
-        cs = block_copies(i, slot)
-        cs[0].wait()
-        k = k_buf[slot].astype(jnp.float32).reshape(bk, -1)
-        s = jax.lax.dot_general(  # [q_rows, bk]
-            q, k,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if quantized:
-            s = s * prep_ref[0, pl.ds(i, 1), :]
-        kv_pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # Canonical query positions sit at/after ``prior``, so the page
-        # part needs only the prior bound (strictly causal already).
-        seen = kv_pos < prior
+    def span(g):
+        """``(row, head group, base, tokens)`` of program ``g`` in grid
+        order: the first position it reads from pool pages and how many
+        follow up to ``prior`` — none for a program with no query."""
+        bb, hh, cc = (g // (head_groups * q_blocks), g // q_blocks % head_groups,
+                      g % q_blocks)
+        prior, first_q = prior_ref[bb], cc * c_block
+        base = 0
         if windowed:
-            seen = seen & in_window(kv_pos)
-        s = jnp.where(seen, s, _MASK)
+            base = jnp.where(
+                window > 0,
+                jnp.maximum(prior + first_q - window + 1, 0) // align * align,
+                0,
+            )
+        tokens = jnp.where(first_q < kvlen_ref[bb] - prior, prior - base, 0)
+        return bb, hh, base, jnp.maximum(tokens, 0)
 
-        m_prev = m_scr[...]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(s - m_new[:, :1])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[...] = m_new
-
-        cs[1].wait()
-        if quantized:
-            p = p * prep_ref[1, pl.ds(i, 1), :]
-        v = v_buf[slot].astype(jnp.float32).reshape(bk, -1)
-        # Exact zeros at masked positions (see _run_block_loop): pad
-        # fetches may stage unreferenced pages; NaN/Inf there (or in pad
-        # scale rows) must not ride 0·NaN into the accumulator.
-        p = jnp.where(seen, p, 0.0)
-        v = jnp.where(
-            i * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-            < prior,
-            v,
-            0.0,
+    def copy(g, i, slot):
+        bb, hh, base, tokens = span(g)
+        return _MhBlockCopy(
+            kv_hbm, layer, kv_buf.at[slot], sems.at[slot], page_table_ref,
+            breaks_ref,
+            bb * pages_per_seq + base // page + i * pages_per_block,
+            pages_per_block,
+            jnp.clip(pl.cdiv(tokens, page) - i * pages_per_block,
+                     0, pages_per_block),
+            heads=None if head_groups == 1 else pl.ds(hh * heads, heads),
         )
-        pv = jax.lax.dot_general(
-            p, v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+
+    def each_head(fn):
+        if heads == 1:
+            fn(0)
+        else:
+            jax.lax.fori_loop(0, heads, lambda h, _: fn(h), None)
+
+    prior = prior_ref[b]
+    new = kvlen_ref[b] - prior  # the row's tokens in this chunk
+
+    @pl.when(cb * c_block >= new)
+    def _no_query():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(cb * c_block < new)
+    def _program():
+        g = (b * head_groups + hg) * q_blocks + cb
+        _, _, base, tokens = span(g)
+        fetch = _block_chain(
+            g, n_programs, lambda x: x, lambda x: 0,
+            lambda x: pl.cdiv(span(x)[3], bk), 1, copy,
+            buffer_index_ref, init_flag_ref,
         )
-        acc_scr[...] = acc_scr[...] * corr + pv
-        return ()
+        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        # Query row r of this program sits at prior + c_q[r].
+        c_q = cb * c_block + (
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group
+        )
 
-    jax.lax.fori_loop(first, n_blocks, body, ())
+        def body(i, _):
+            slot = fetch(i)
+            first_pos = base + i * bk
+            # Canonical query positions sit at/after ``prior``, so the page
+            # part needs only the prior bound (strictly causal already) —
+            # and the window, a query's own.
+            kv_pos = first_pos + jax.lax.broadcasted_iota(
+                jnp.int32, (1, bk), 1)
+            seen = kv_pos < prior
+            if windowed:
+                seen = seen & (
+                    (window <= 0) | (prior + c_q - kv_pos < window))
+            seen_v = first_pos + jax.lax.broadcasted_iota(
+                jnp.int32, (bk, 1), 0) < prior
 
-    # Dense block: the chunk itself, causal in chunk coordinates. Key
-    # c_k's absolute position is prior + c_k; query row r (= c*G + g of
-    # this c-block) sits at prior + cb*Cblk + c.
-    kc = kc_ref[...].astype(jnp.float32)  # [C, D]
-    vc = vc_ref[...].astype(jnp.float32)
-    s2 = jax.lax.dot_general(  # [q_rows, C]
-        q, kc,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    c_q = (
-        cb * c_block
-        + jax.lax.broadcasted_iota(jnp.int32, s2.shape, 0) // group
-    )
-    c_k = jax.lax.broadcasted_iota(jnp.int32, s2.shape, 1)
-    ok = (c_k <= c_q) & (prior + c_k < kvlen)
-    if windowed:
-        ok = ok & ((window <= 0) | (c_q - c_k < window))
-    s2 = jnp.where(ok, s2, _MASK)
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s2, axis=-1, keepdims=True))
-    p2 = jnp.exp(s2 - m_new[:, :1])
-    corr = jnp.exp(m_prev - m_new)
-    l_fin = l_scr[...] * corr + jnp.sum(p2, axis=-1, keepdims=True)
-    acc_fin = acc_scr[...] * corr + jax.lax.dot_general(
-        p2, vc,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    out = jnp.where(l_fin > 0, acc_fin / jnp.maximum(l_fin, 1e-30), 0.0)
-    o_ref[...] = out.reshape(c_block, group, -1).astype(o_ref.dtype)
+            def head(h):
+                ks = vs = None
+                if quantized:
+                    at = pl.ds(first_pos // bk, 1)
+                    ks, vs = prep_ref[0, h, at, :], prep_ref[1, h, at, :]
+                # (ppb, page, D) -> (bk, D): a relayout-free collapse.
+                _chunk_fold(
+                    q_ref[h], kv_buf[slot, 0, h].reshape(bk, -1),
+                    kv_buf[slot, 1, h].reshape(bk, -1), scale, seen, seen_v,
+                    ks, vs, m_scr.at[h], l_scr.at[h], acc_scr.at[h],
+                )
+
+            each_head(head)
+            return ()
+
+        jax.lax.fori_loop(0, pl.cdiv(tokens, bk), body, ())
+
+        # Dense block: the chunk itself, causal in chunk coordinates. Key
+        # c_k's absolute position is prior + c_k.
+        c_k = jax.lax.broadcasted_iota(jnp.int32, (1, kc_ref.shape[1]), 1)
+        ok = (c_k <= c_q) & (c_k < new)
+        if windowed:
+            ok = ok & ((window <= 0) | (c_q - c_k < window))
+
+        def dense(h):
+            m_h, l_h, acc_h = m_scr.at[h], l_scr.at[h], acc_scr.at[h]
+            _chunk_fold(q_ref[h], kc_ref[h], vc_ref[h], scale, ok, None,
+                        None, None, m_h, l_h, acc_h)
+            l_fin = l_h[...]
+            o_ref[h] = jnp.where(
+                l_fin > 0, acc_h[...] / jnp.maximum(l_fin, 1e-30), 0.0
+            ).astype(o_ref.dtype)
+
+        each_head(dense)
 
 
-def _chunk_block(chunk: int, group: int, max_rows: int = 1024) -> int:
-    """Largest power-of-two divisor of ``chunk`` whose query-row count
-    (``Cblk * G``) stays within the VMEM scratch budget."""
+# What the chunk kernel may hold in VMEM, by ``_chunk_geometry``'s own
+# estimate: inside the 16 MiB a kernel gets without asking. It does NOT ask
+# for more (``vmem_limit_bytes``): XLA keeps operands of the call in VMEM
+# too — a 64-row chunk's q, K and V, 63 MB — and a kernel that had asked
+# for 64 (or 32) MiB hung the chip inside that program, where alone, its
+# operands in HBM, it ran (PERF.md §6 PR 43).
+_CHUNK_VMEM_BUDGET = 14 * 2**20
+
+
+def _chunk_geometry(
+    chunk: int, group: int, Hkv: int, page: int, D: int, dtype, q_dtype,
+    max_pages: int, pages_per_block: int | None, q_block: int | None,
+    quantized: bool,
+) -> tuple[int, int, int]:
+    """``(heads a program, pages a block, queries a program)`` of the
+    chunk kernel, from the shapes and the pool's dtype alone. Heads and
+    block as the decode kernels choose them: all kv heads where their
+    double-buffered K+V block fits (``_auto_fuse_heads``), 512 tokens
+    halved until it does (``_mh_pages_per_block``: a block's K and V of
+    all heads in one copy and one wait keep pace with the MXU at 512, PERF.md
+    §6 PR 43) — else one head a program and a block. Queries: the largest
+    power-of-two divisor of ``chunk`` whose rows (``Cblk * G``) stay
+    within 1 024 and whose blocks, scratch and scores stay within
+    ``_CHUNK_VMEM_BUDGET`` — q and the chunk's K/V counted in the operand
+    dtype (``_operand_dtype``: float32 against an int8 pool, which also
+    holds a block's upcast and the table's prepared scale rows)."""
+    heads = Hkv if _auto_fuse_heads(
+        Hkv, page, D, dtype, max_pages, pages_per_block, quantized) else 1
+    if pages_per_block is None:
+        pages_per_block = _mh_pages_per_block(heads, page, D, dtype, max_pages)
+    ppb = min(pages_per_block, max_pages)
+    if q_block is not None:
+        return heads, ppb, q_block
+    kv, qb = jnp.dtype(dtype).itemsize, jnp.dtype(q_dtype).itemsize
+    operand = _operand_dtype(q_dtype, dtype, quantized)
+    op, bk = operand.itemsize, ppb * page
+    # the K+V double buffer; the chunk's own K and V (double-buffered)
+    fixed = heads * D * (2 * 2 * bk * kv + 2 * 2 * chunk * op)
+    if operand != jnp.dtype(dtype):  # a head's K and V block upcast for a fold
+        fixed += 2 * bk * D * op
+    if quantized:  # the prepared scale rows of the whole table, twice
+        fixed += 2 * 2 * heads * pl.cdiv(max_pages, ppb) * bk * 4
+
+    def vmem(cblk):
+        rows = cblk * group
+        # q and out blocks (double-buffered), m / l / acc, s and p of a fold
+        return fixed + rows * (
+            heads * D * (2 * op + 2 * qb + 3 * 4) + 3 * 4 * max(bk, chunk))
+
     cblk = 1
-    while (
-        chunk % (cblk * 2) == 0 and cblk * 2 * group <= max_rows
-    ):
+    while (chunk % (cblk * 2) == 0 and cblk * 2 * group <= 1024
+           and vmem(cblk * 2) <= _CHUNK_VMEM_BUDGET):
         cblk *= 2
-    return cblk
+    return heads, ppb, cblk
+
+
+def chunk_query_tokens(
+    chunk: int, group: int, Hkv: int, page: int, D: int, dtype, q_dtype,
+    max_pages: int, quantized: bool = False,
+) -> int:
+    """Queries of one program of ``paged_chunk_attention_kernel`` at these
+    shapes (``_chunk_geometry``): a launch of ``B`` rows of ``chunk``
+    tokens is ``B * chunk // this`` query blocks a layer, and a block
+    whose first query is past its row's last new token does no work —
+    what ``/debug/state`` ``attn.chunk_query_blocks*`` count."""
+    return _chunk_geometry(chunk, group, Hkv, page, D, dtype, q_dtype,
+                           max_pages, None, None, quantized)[2]
 
 
 @functools.partial(
@@ -1889,8 +2069,10 @@ def paged_chunk_attention_kernel(
     PREFILL side (VERDICT round-3 next-step #3 "pool-page chunk
     attention"). The jnp oracle is ``ops/attention.py::attend_chunk_hybrid``
     — same online-softmax merge of prior pool pages + the dense causal
-    chunk, but pages stream HBM→VMEM per (sequence, kv-head, query-block)
-    program instead of gathering [B, Hkv, bk, D] copies through XLA.
+    chunk, but pages stream HBM→VMEM per (sequence, query-block) program
+    — all kv heads of a block in one copy; per (sequence, kv head,
+    query-block) where that does not fit VMEM (``_chunk_geometry``) —
+    instead of gathering [B, Hkv, bk, D] copies through XLA.
 
     CONTRACT: query positions are canonical —
     ``q_positions == prior_lengths[:, None] + arange(C)`` (the only form
@@ -1899,7 +2081,9 @@ def paged_chunk_attention_kernel(
     ``prior_lengths``/``kv_lengths`` and the chunk offset alone, and the
     chunk's K/V arrive dense from the layer activations (``k_cur``
     already dequantized when the pool is int8, preserving the
-    see-what-you-store invariant).
+    see-what-you-store invariant). A query at or past
+    ``kv_lengths - prior_lengths`` is padding: its output is unspecified
+    (zeros where its whole query block is padding).
 
     Returns ``[B, C, Hq, D]``.
     """
@@ -1909,26 +2093,28 @@ def paged_chunk_attention_kernel(
         raise ValueError(f"Hq={Hq} must divide by Hkv={Hkv}")
     G = Hq // Hkv
     quantized = kv_scales is not None
-    page_table, ppb, padded = _block_geometry(page_table, page, pages_per_block)
-    contig = _contig_flags(page_table, prior_lengths, page, ppb, P)
-    cblk = q_block if q_block is not None else _chunk_block(C, G)
+    heads, ppb, cblk = _chunk_geometry(
+        C, G, Hkv, page, D, kv_pages.dtype, q.dtype, page_table.shape[1],
+        pages_per_block, q_block, quantized,
+    )
     if C % cblk:
         raise ValueError(f"q_block={cblk} must divide chunk C={C}")
-
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    # [B, Hkv, C, G, D]: kv-head-major so each program's q block is one
-    # contiguous [Cblk, G, D] tile.
-    q5 = (q.astype(jnp.float32) * scale).reshape(B, C, Hkv, G, D).transpose(
-        0, 2, 1, 3, 4
+    page_table, ppb, padded, flat_table, flat_breaks = _mh_tables(
+        page_table, page, ppb)
+    qo, scale = _operand_q(q, kv_pages.dtype, quantized, scale)
+    # [B, Hkv, C * G, D]: kv-head-major, so each program's q block is one
+    # contiguous [Cblk * G, D] tile a head. The chunk's own K/V enter the
+    # products beside the pool's blocks, in q's operand dtype.
+    qo = qo.reshape(B, C, Hkv, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        B, Hkv, C * G, D
     )
-    kc = k_cur.transpose(0, 2, 1, 3)  # [B, Hkv, C, D]
-    vc = v_cur.transpose(0, 2, 1, 3)
+    kc = k_cur.transpose(0, 2, 1, 3).astype(qo.dtype)  # [B, Hkv, C, D]
+    vc = v_cur.transpose(0, 2, 1, 3).astype(qo.dtype)
     q_spec = pl.BlockSpec(
-        (None, None, cblk, G, D), lambda b, h, cb, *_: (b, h, cb, 0, 0)
+        (None, heads, cblk * G, D), lambda b, h, cb, *_: (b, h, cb, 0)
     )
     kc_spec = pl.BlockSpec(
-        (None, None, C, D), lambda b, h, cb, *_: (b, h, 0, 0)
+        (None, heads, C, D), lambda b, h, cb, *_: (b, h, 0, 0)
     )
 
     kernel = functools.partial(
@@ -1936,9 +2122,12 @@ def paged_chunk_attention_kernel(
         page=page,
         pages_per_block=ppb,
         pages_per_seq=padded,
-        chunk=C,
+        batch_size=B,
+        head_groups=Hkv // heads,
+        q_blocks=C // cblk,
         c_block=cblk,
         group=G,
+        scale=scale,
         quantized=quantized,
         windowed=window is not None,
     )
@@ -1946,22 +2135,21 @@ def paged_chunk_attention_kernel(
     if quantized:
         in_specs.append(
             pl.BlockSpec(
-                (2, None, None, padded // ppb, ppb * page),
+                (2, None, heads, padded // ppb, ppb * page),
                 lambda b, h, cb, *_: (0, b, h, 0, 0),
             )
         )
     scratch = [
-        pltpu.VMEM((cblk * G, D), jnp.float32),
-        pltpu.VMEM((cblk * G, D), jnp.float32),
-        pltpu.VMEM((cblk * G, D), jnp.float32),
-        pltpu.VMEM((2, ppb, page, D), kv_pages.dtype),
-        pltpu.VMEM((2, ppb, page, D), kv_pages.dtype),
-        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((heads, cblk * G, D), jnp.float32),
+        pltpu.VMEM((heads, cblk * G, D), jnp.float32),
+        pltpu.VMEM((heads, cblk * G, D), jnp.float32),
+        pltpu.VMEM((2, 2, heads, ppb, page, D), kv_pages.dtype),
+        pltpu.SemaphoreType.DMA((2,)),
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 + (window is not None),
-        grid=(B, Hkv, C // cblk),
+        num_scalar_prefetch=7 + (window is not None),
+        grid=(B, Hkv // heads, C // cblk),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=scratch,
@@ -1969,12 +2157,14 @@ def paged_chunk_attention_kernel(
     args = [
         jnp.asarray(prior_lengths, dtype=jnp.int32),
         jnp.asarray(kv_lengths, dtype=jnp.int32),
-        jnp.asarray(page_table, dtype=jnp.int32).reshape(-1),
-        contig,
+        flat_table,
+        flat_breaks,
         jnp.asarray(layer, dtype=jnp.int32).reshape(1),
+        jnp.zeros((1,), jnp.int32),
+        jnp.ones((1,), jnp.int32),
         *([] if window is None
           else [jnp.asarray(window, dtype=jnp.int32).reshape(1)]),
-        q5,
+        qo,
         kc,
         vc,
         kv_pages,
@@ -1984,14 +2174,16 @@ def paged_chunk_attention_kernel(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, C, G, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, C * G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
         name="radixmesh_chunk_attn",
     )(*args)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, C, Hq, D).astype(q.dtype)
+    return out.reshape(B, Hkv, C, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        B, C, Hq, D
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -37,7 +37,30 @@ Timing needs a TPU: without one the script exits 2, unless ``--interpret``
 asks for the parity check alone (Pallas interpret mode, numerics only —
 an interpreter timing is not a kernel time and is never taken).
 
+``--kernel chunk`` times the chunk-prefill kernel (``radixmesh_chunk_attn``)
+instead, alone, as ``prefill_chunk_paged`` calls it — a scan over the
+model's attention layers — at two geometries, one ascending-run row each
+and the same rows on permuted pages:
+
+- ``chat``   Qwen2-7B (Hq 28 / Hkv 4, 28 layers): 1 x 256 new tokens on a
+  4.2 k prefix, a 512-page table (``qwen2-7b.chat-sessions``);
+- ``agent``  LFM2-24B-A2B (two heads of 64 to a pool row: Hq 32 / Hkv 4
+  wide, 10 attention layers, logits scaled by 1/8): 1 x 512 on 8 k, the
+  2 048-page table every chunk program of that cell has
+  (``lfm2-24b-a2b-ep8.agent-sessions``).
+
+Each runs ``full`` (every query of the launched shape is a token) and
+``padded`` (two thirds are: the cells' ``prefill_pad_share``), as it is,
+copies only, math only, and with one part of the kernel put back to what it
+was before PR 43 (``f32_operands``, ``per_head``), over
+``--pages-per-block`` and ``--q-block``. Per case: ms a launch and a
+layer, the seconds of the first call (the compile), and the share of 197
+TFLOP/s that the products the VALID queries need (4 x D x visible keys a
+query and q head) make of the time. A checkout
+whose kernel lacks a hook (the parent's) runs what it can.
+
 Usage: python scripts/kernelbench.py [--geometry batch,chat,longtail]
+       python scripts/kernelbench.py --kernel chunk [--geometry chat,agent]
 """
 from __future__ import annotations
 
@@ -64,6 +87,17 @@ GEOMETRIES = {
                      ctx=(256, 8192)),
 }
 HEAD_DIM, PAGE = 128, 16
+MXU_FLOPS_PER_S = 197e12  # bf16, the same chip and source
+
+# --kernel chunk: heads, attention layers, rows x chunk tokens launched,
+# tokens already in pages, page-table width, scale of the logits (None:
+# 1/sqrt(D)).
+CHUNK_GEOMETRIES = {
+    "chat": dict(hq=28, hkv=4, layers=28, rows=1, chunk=256, prior=4200,
+                 max_pages=512, scale=None),
+    "agent": dict(hq=32, hkv=4, layers=10, rows=1, chunk=512, prior=8192,
+                  max_pages=2048, scale=0.125),
+}
 
 
 def _contexts(name: str, g: dict, rng) -> "np.ndarray":
@@ -127,8 +161,10 @@ def _blocks(lengths, windows, layers: int, bk: int) -> int:
 
 @contextlib.contextmanager
 def _stubbed(pa, what: str | None):
-    """Replace one half of the mh block loop by a no-op for the duration
-    (``copies``: no contraction; ``math``: no block DMA, start nor wait)."""
+    """Replace one half of the block loops by a no-op for the duration
+    (``copies``: no contraction; ``math``: no block DMA, start nor wait),
+    or put one part of the chunk kernel back to what it was before PR 43.
+    A checkout whose kernel lacks the hook raises ``AttributeError``."""
     import jax
 
     saved = {}
@@ -140,12 +176,21 @@ def _stubbed(pa, what: str | None):
         saved[(obj, name)] = getattr(obj, name)
         setattr(obj, name, new)
 
-    if what == "copies":
-        swap(pa, "_mh_contract", lambda **kw: None)
-    elif what == "math":
-        swap(pa._MhBlockCopy, "start", lambda self: None)
-        swap(pa._MhBlockCopy, "wait", lambda self: None)
     try:
+        if what == "copies":
+            swap(pa, "_mh_contract", lambda **kw: None)
+            swap(pa, "_chunk_fold", lambda *a, **kw: None)
+        elif what == "math":
+            for copy in (pa._MhBlockCopy, pa._GatedCopy):
+                swap(copy, "start", lambda self: None)
+                swap(copy, "wait", lambda self: None)
+        elif what == "f32_operands":  # q, K, V and p float32 into the products
+            operand_q = pa._operand_q
+            swap(pa, "_operand_q",
+                 lambda q, kv_dtype, quantized, scale=None: operand_q(
+                     q, kv_dtype, True, scale))
+        elif what == "per_head":  # grid (B, Hkv, C // Cblk)
+            swap(pa, "_auto_fuse_heads", lambda *a: False)
         yield
     finally:
         for (obj, name), old in saved.items():
@@ -153,12 +198,122 @@ def _stubbed(pa, what: str | None):
         jax.clear_caches()
 
 
+def _finish(out: dict, args, failed: list) -> int:
+    line = json.dumps(out)
+    print(line, flush=True)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w") as fh:
+            fh.write(line + "\n")
+    return 1 if failed else 0
+
+
+def _chunk_cases(args, names, sweeps, pa, bench, cases: list) -> list[str]:
+    """``--kernel chunk``: time ``paged_chunk_attention_kernel`` over a
+    model's attention layers at each named geometry (module docstring);
+    appends one dict a case to ``cases`` and returns the names of those
+    that raised."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    failed = []
+    # the kernel's own block and query block, then each swept alone
+    combos = [(ppb, None) for ppb in sweeps] + [
+        (None, int(x)) for x in args.q_block.split(",") if x]
+    for name in names:
+        g = CHUNK_GEOMETRIES[name]
+        rng = np.random.default_rng(args.seed)
+        B, C, Hq, Hkv, L = g["rows"], g["chunk"], g["hq"], g["hkv"], g["layers"]
+        maxp, prior = g["max_pages"], g["prior"]
+        held = -(-(prior + C) // PAGE)  # pages a row holds after the chunk
+        P = B * held + maxp
+        ks = jax.random.split(jax.random.PRNGKey(args.seed), 4)
+        q = jax.random.normal(ks[0], (B, C, Hq, HEAD_DIM), jnp.bfloat16)
+        kc = jax.random.normal(ks[1], (B, C, Hkv, HEAD_DIM), jnp.bfloat16)
+        vc = jax.random.normal(ks[2], (B, C, Hkv, HEAD_DIM), jnp.bfloat16)
+        kv = jax.random.normal(
+            ks[3], (2, L, Hkv, P, PAGE, HEAD_DIM), jnp.bfloat16
+        )
+        perm = rng.permutation(B * held)
+        tables = {}
+        for layout in args.layouts.split(","):
+            pt = np.zeros((B, maxp), np.int32)
+            for b in range(B):
+                ids = np.arange(b * held, (b + 1) * held)
+                pt[b, :held] = perm[ids] if layout == "perm" else ids
+            tables[layout] = jnp.asarray(pt)
+        priors = jnp.full((B,), prior, jnp.int32)
+
+        def make(pt, new, ppb, qb):
+            kvlen = priors + new
+
+            def step(kv):
+                def body(l, acc):
+                    o = pa.paged_chunk_attention_kernel(
+                        q, kc, vc, kv, pt, priors, kvlen, l,
+                        pages_per_block=ppb, q_block=qb, scale=g["scale"],
+                    )
+                    return acc + o.astype(jnp.float32)
+
+                return kv, jax.lax.fori_loop(
+                    0, L, body, jnp.zeros(q.shape, jnp.float32))
+
+            return jax.jit(step)
+
+        for layout, pt in tables.items():
+            for fill, new in (("full", C), ("padded", C * 2 // 3)):
+                # products the valid queries need: q.k and p.v over the
+                # prefix and the causal half of the chunk
+                keys = new * prior + new * (new + 1) // 2
+                flops = 4 * HEAD_DIM * keys * Hq * L * B
+                for ppb, qb in combos:
+                    whats = (None,) if args.no_split or ppb or qb else (
+                        None, "copies", "math", "f32_operands", "per_head")
+                    for stub in whats:
+                        case = {
+                            "geometry": name, "layout": layout,
+                            "fill": fill, "new_tokens": new,
+                            "pages_per_block": ppb, "q_block": qb,
+                            "what": stub or "kernel",
+                        }
+                        try:
+                            with _stubbed(pa, stub):
+                                kv, sec, first = bench(make(pt, new, ppb, qb), kv)
+                        except Exception as e:  # noqa: BLE001 — record, keep measuring
+                            case["error"] = f"{type(e).__name__}: {e}"[:600]
+                            if not isinstance(e, AttributeError):
+                                failed.append(f"{name}:{layout}:{stub}")
+                            cases.append(case)
+                            continue
+                        case.update({
+                            "first_call_s": round(first, 2),
+                            "ms_per_launch": round(sec * 1e3, 4),
+                            "ms_per_layer": round(sec * 1e3 / L, 4),
+                            "needed_gflop": round(flops / 1e9, 2),
+                            "mxu_share_pct": round(
+                                100 * flops / sec / MXU_FLOPS_PER_S, 2),
+                        })
+                        cases.append(case)
+                        print(json.dumps(case), file=sys.stderr, flush=True)
+        del kv
+    return failed
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(
         description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter
     )
-    ap.add_argument("--geometry", default="batch,chat,longtail",
-                    help="comma list of " + ", ".join(GEOMETRIES))
+    ap.add_argument("--kernel", choices=("decode", "chunk"), default="decode",
+                    help="decode: radixmesh_decode_fused_mh (the default); "
+                    "chunk: radixmesh_chunk_attn")
+    ap.add_argument("--geometry", default=None,
+                    help="comma list of " + ", ".join(GEOMETRIES) + " (decode: "
+                    "all three) or of " + ", ".join(CHUNK_GEOMETRIES)
+                    + " (chunk: both)")
+    ap.add_argument("--q-block", default="",
+                    help="chunk: comma list of explicit query blocks (tokens a "
+                    "program) to time beside the kernel's own choice")
     ap.add_argument("--layouts", default="perm,run",
                     help="page-table layouts: perm (per-page DMA plan), "
                     "run (coalesced plan)")
@@ -176,9 +331,10 @@ def main() -> int:
                     "(any backend; nothing is timed)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
-    names = [n for n in args.geometry.split(",") if n]
+    known = CHUNK_GEOMETRIES if args.kernel == "chunk" else GEOMETRIES
+    names = [n for n in (args.geometry or ",".join(known)).split(",") if n]
     for n in names:
-        if n not in GEOMETRIES:
+        if n not in known:
             ap.error(f"unknown geometry {n!r}")
 
     import jax
@@ -205,7 +361,7 @@ def main() -> int:
         return 2
     out["parity"] = {}
     failed = []
-    heads = sorted({(GEOMETRIES[n]["hq"], GEOMETRIES[n]["hkv"]) for n in names})
+    heads = sorted({(known[n]["hq"], known[n]["hkv"]) for n in names})
     for hq, hkv in [] if args.no_parity else heads:
         tiny = args.interpret
         res = check_kernels(
@@ -213,8 +369,8 @@ def main() -> int:
             page=PAGE, batch=4 if tiny else 8, chunk=32 if tiny else 512,
             max_pages=8 if tiny else 64, interpret=args.interpret,
         )
-        if any(GEOMETRIES[n]["window"] for n in names
-               if (GEOMETRIES[n]["hq"], GEOMETRIES[n]["hkv"]) == (hq, hkv)):
+        if any(known[n].get("window") for n in names
+               if (known[n]["hq"], known[n]["hkv"]) == (hq, hkv)):
             res += check_kernels(
                 WINDOW_CASES, hq=hq, hkv=hkv, head_dim=HEAD_DIM, page=PAGE,
                 batch=8, chunk=32 if tiny else 512,
@@ -228,10 +384,13 @@ def main() -> int:
         return 1 if failed else 0
 
     def bench(fn, kv, n=args.iters):
-        """Seconds a call: the better of two rounds of ``n`` (one round in
-        a dozen meets a stall of tens of ms that is the machine's)."""
+        """``(kv, seconds a call, seconds of the first call)``: the better
+        of two rounds of ``n`` (one round in a dozen meets a stall of tens
+        of ms that is the machine's), and the call that compiled."""
+        t = time.perf_counter()
         kv, r = fn(kv)  # compile + warm
         jax.block_until_ready(r)
+        first = time.perf_counter() - t
         rounds = []
         for _ in range(2):
             t = time.perf_counter()
@@ -239,10 +398,13 @@ def main() -> int:
                 kv, r = fn(kv)
             jax.block_until_ready(r)
             rounds.append((time.perf_counter() - t) / n)
-        return kv, min(rounds)
+        return kv, min(rounds), first
 
     sweeps = [None] + [int(x) for x in args.pages_per_block.split(",") if x]
     out["cases"] = []
+    if args.kernel == "chunk":
+        failed += _chunk_cases(args, names, sweeps, pa, bench, out["cases"])
+        return _finish(out, args, failed)
     for name in names:
         g = GEOMETRIES[name]
         rng = np.random.default_rng(args.seed)
@@ -305,7 +467,7 @@ def main() -> int:
                     }
                     try:
                         with _stubbed(pa, stub):
-                            kv, sec = bench(make(pt, slots, ppb), kv)
+                            kv, sec, _ = bench(make(pt, slots, ppb), kv)
                     except Exception as e:  # noqa: BLE001 — record, keep measuring
                         case["error"] = f"{type(e).__name__}: {e}"[:600]
                         failed.append(f"{name}:{layout}:{stub}")
@@ -347,13 +509,7 @@ def main() -> int:
                 "gb_per_s_on_needed_bytes": round(1.0 / ms_per_mb, 1),
                 "us_per_program": round(ms_per_program * 1e3, 3),
             })
-    line = json.dumps(out)
-    print(line, flush=True)
-    if args.out:
-        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
-        with open(args.out, "w") as fh:
-            fh.write(line + "\n")
-    return 1 if failed else 0
+    return _finish(out, args, failed)
 
 
 if __name__ == "__main__":

@@ -573,6 +573,101 @@ class TestPagedChunkKernel:
         )
 
 
+    # One launch whose rows are every way a prefix can lie (block = 8
+    # tokens: ``pages_per_block=2`` of 4): nothing cached; a prefix that
+    # ends mid-page; exactly one block; several blocks on fragmented pages,
+    # with a chunk whose last three query blocks hold no token; four blocks
+    # on one ascending run of pages; and a row on the scratch slot.
+    PRIOR = np.asarray([0, 5, 8, 27, 32, 0], np.int32)
+    NEW = np.asarray([16, 16, 16, 3, 16, 0], np.int32)
+
+    @pytest.mark.parametrize("window", [None, 3, 20, 0],
+                             ids=["full", "in-block", "spans-blocks", "w0"])
+    @pytest.mark.parametrize(
+        "hq,hkv,scale", [(8, 2, 0.25), (7, 1, None), (8, 1, None)],
+        ids=["G4-paired-wide", "G7", "G8"])
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, None)],
+                             ids=["f32-pool", "bf16-pool"])
+    def test_operands_edges_and_empty_query_blocks(
+            self, dtype, tol, hq, hkv, scale, window):
+        """Operands in the pool's dtype (a bf16 pool against the float32
+        oracle at ``kernel_check``'s tolerance), the prior bound and the
+        window masked in whichever block they fall, blocks counted from a
+        window's first page, no work for a
+        query block without a token — and NaN wherever the kernel must not
+        look: past a prefix inside its last page, and in the page that
+        every unused table entry names."""
+        from radixmesh_tpu.ops.attention import attend_chunk_hybrid
+        from radixmesh_tpu.ops.kernel_check import TOLERANCE
+        from radixmesh_tpu.ops.paged_attention import (
+            paged_chunk_attention_kernel,
+        )
+
+        rng = np.random.default_rng(43)
+        B, C, D, page, maxp, L = 6, 16, 32, 4, 16, 2
+        P = B * maxp + 1
+        # pages 1..80 shuffled over the five other rows, row 4's own run
+        pt = 1 + rng.permutation(P - 1 - maxp).astype(np.int32)
+        pt = np.insert(pt.reshape(B - 1, maxp), 4, P - maxp + np.arange(maxp), 0)
+        used = -(-(self.PRIOR + C) // page)
+        pt[np.arange(maxp)[None] >= used[:, None]] = 0  # the poisoned page
+        kv = rng.normal(size=(2, L, hkv, P, page, D)).astype(np.float32)
+        dirty = kv.copy()
+        dirty[:, :, :, 0] = np.nan
+        for b in np.flatnonzero(self.PRIOR % page):
+            at = self.PRIOR[b]
+            dirty[:, :, :, pt[b, at // page], at % page:] = np.nan
+        q, kc, vc = (
+            jnp.asarray(rng.normal(size=(B, C, h, D)), dtype)
+            for h in (hq, hkv, hkv))
+        prior, kvlen = jnp.asarray(self.PRIOR), jnp.asarray(self.PRIOR + self.NEW)
+        pos = prior[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+        w = None if window is None else jnp.int32(window)
+        f32 = lambda x: jnp.asarray(x, dtype).astype(jnp.float32)  # noqa: E731
+        want = attend_chunk_hybrid(
+            f32(q), f32(kc), f32(vc), f32(kv), jnp.asarray(pt), pos, prior,
+            kvlen, 1, kv_block_pages=4, window=w, scale=scale,
+        )
+        got = paged_chunk_attention_kernel(
+            q, kc, vc, jnp.asarray(dirty, dtype), jnp.asarray(pt), prior,
+            kvlen, 1, pages_per_block=2, q_block=4, interpret=True, window=w,
+            scale=scale,
+        )
+        assert got.dtype == dtype
+        got = np.asarray(got, np.float32)
+        valid = np.arange(C)[None, :] < self.NEW[:, None]
+        tol = tol or TOLERANCE
+        np.testing.assert_allclose(
+            got[valid], np.asarray(want)[valid], rtol=tol, atol=tol)
+        # Query blocks 1..3 of row 3 and the whole scratch row: zeros.
+        assert not got[3, 4:].any() and not got[5].any()
+
+    @pytest.mark.parametrize("hkv,heads", [(2, 2), (64, 1)])
+    def test_heads_a_program_follow_the_vmem_budget(self, hkv, heads, monkeypatch):
+        """All kv heads a program while their double-buffered block fits
+        (``_auto_fuse_heads``), one a program past that — the same kernel,
+        the same answer."""
+        from radixmesh_tpu.ops import paged_attention as pa
+
+        # 64 heads of 128-token pages, float32: one page of K+V, double-
+        # buffered, is 16 MiB
+        geometry = pa._chunk_geometry(
+            256, 4, hkv, 128, 128, jnp.float32, jnp.float32, 8, None, None,
+            False)
+        assert geometry[0] == heads
+        q, kc, vc, kv, pt = self._setup(5, Hq=4, Hkv=2, C=8)
+        prior = jnp.asarray([9, 0, 30], jnp.int32)
+        args = (q, kc, vc, kv, pt, prior, prior + 8, 1)
+        fused = pa.paged_chunk_attention_kernel(*args, interpret=True)
+        monkeypatch.setattr(pa, "_auto_fuse_heads", lambda *a: False)
+        jax.clear_caches()  # the entry point is jitted: trace it again
+        per_head = pa.paged_chunk_attention_kernel(*args, interpret=True)
+        jax.clear_caches()
+        np.testing.assert_allclose(
+            np.asarray(per_head), np.asarray(fused), rtol=2e-5, atol=2e-5)
+
+
 class TestPoolKernelFusedHeads:
     """Heads-batched pool-kernel variant (``fuse_heads=True``): one
     program per sequence, one strided DMA per page for ALL kv heads —

@@ -216,3 +216,54 @@ def test_two_lengths_of_one_bucket_pair_share_every_program(model, case):
     (size_1, _), (size_2, compiled_2) = seen
     assert size_2 == size_1  # the jit met no new shape
     assert compiled_2 == []  # and the admission compiled nothing at all
+
+
+# ----------------------------------------------------------------------
+# (d) a grouped wave through the chunk kernel itself (interpreted)
+# ----------------------------------------------------------------------
+
+
+def test_a_grouped_wave_through_the_kernel_equals_its_rows_alone(
+        model, monkeypatch):
+    """Three requests admitted as ONE chunk group over a cached document,
+    their launches lowered through the Pallas chunk kernel (interpreted):
+    rows of 33, 40 and 64 new tokens (one size bucket) ride four chunks of
+    16 in a launch of four rows, so the launch holds query blocks with no
+    token — the fourth row, on the scratch slot, and the last chunk of the
+    two shorter rows — which the kernel answers with zeros. Every row's
+    tokens equal what it generates alone on the reference path, and
+    ``attn.chunk_query_blocks*`` equal the host's arithmetic on the rows'
+    lengths."""
+    import functools
+
+    from radixmesh_tpu.engine import engine as engine_mod
+
+    doc = tokens_of(24, seed=43)
+    news = (33, 40, 64)
+    prompts = [doc + tokens_of(n, seed=50 + n) for n in news]
+    alone = []
+    for p in prompts:
+        eng = make_engine(model)
+        run(eng, doc + tokens_of(2, seed=49), n_out=2)
+        alone.append(run(eng, p).generated)
+
+    monkeypatch.setattr(
+        engine_mod, "prefill_chunk_paged", functools.partial(
+            prefill_chunk_paged, use_kernel=True, interpret=True))
+    eng = make_engine(model)
+    run(eng, doc + tokens_of(2, seed=49), n_out=2)
+    before = dict(eng.attn_window)
+    waves = eng.prefill["waves"]
+    reqs = [eng.add_request(p, SamplingParams(max_new_tokens=N_OUT))
+            for p in prompts]
+    while eng.has_work():
+        eng.step()
+    assert eng.prefill["waves"] == waves + 1 and eng._last_prefill_path == "chunk"
+    assert [r.generated for r in reqs] == alone
+    # A program holds the whole 16-token chunk of a row (16 x G rows <=
+    # 1 024): 4 rows x 4 chunks, less the chunks a row has tokens in.
+    blocks = eng.attn_window["chunk_query_blocks"] - before["chunk_query_blocks"]
+    skipped = (eng.attn_window["chunk_query_blocks_skipped"]
+               - before["chunk_query_blocks_skipped"])
+    assert blocks == 4 * 4
+    assert skipped == blocks - sum(-(-n // CHUNK) for n in news) == 6

@@ -179,6 +179,70 @@ def test_mellum2_period_compiles_for_one_v5e(v5e):
     assert not _copies_shaped_like(compiled, pool.shape)
 
 
+# The chunk kernel at the shapes the cells' chunk programs hand it: rows x
+# chunk, q / kv heads of the pool row, page-table width, window, scale,
+# the pool's dtype.
+CHUNK_LAUNCHES = {
+    # a lone chat turn: one row at the engine's one table width
+    "qwen2-1x256": (1, 256, 28, 4, 512, False, None, jnp.bfloat16),
+    "qwen2-8x512": (8, 512, 28, 4, 128, False, None, jnp.bfloat16),
+    # ``--kv-quant int8``: float32 q, chunk K/V and upcast blocks, and the
+    # table's prepared scale rows, beside the int8 double buffer
+    "qwen2-8x512-int8-pool": (8, 512, 28, 4, 128, False, None, jnp.int8),
+    # a window and a full layer share the executable: the window is traced
+    "mellum2-8x512-window": (8, 512, 32, 4, 512, True, None, jnp.bfloat16),
+    # two heads of 64 to a pool row, the logits scaled for 64; every chunk
+    # program of the agent cell has the 2 048-page table
+    "lfm2-1x512-one-width": (1, 512, 32, 4, 2048, False, 0.125, jnp.bfloat16),
+    # the window group's launch: 64 rows x 64 tokens x 1 024 pages, ten
+    # wide heads (a 512-token block of them is over the 4 MiB a block gets)
+    "phi4-64x64-window-group": (64, 64, 40, 10, 1024, True, 0.125,
+                                jnp.bfloat16),
+    # the speculative verify chunk: a few tokens a row
+    "qwen2-8x4-verify": (8, 4, 28, 4, 128, False, None, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("launch", list(CHUNK_LAUNCHES))
+def test_chunk_kernel_lowers_at_the_cells_launch_shapes(v5e, launch):
+    """``paged_chunk_attention`` lowered for the v5e takes the Pallas chunk
+    kernel (one ``radixmesh_chunk_attn``), and Mosaic accepts it inside the
+    16 MiB of VMEM a kernel gets without asking (it refuses a kernel over
+    that; the wrapper asks for no more: ``_CHUNK_VMEM_BUDGET``) — heads a
+    program, block and query block derived from these shapes alone."""
+    from radixmesh_tpu.ops import paged_attention as pa
+    from radixmesh_tpu.ops.attention import paged_chunk_attention
+
+    rows, chunk, hq, hkv, pages, windowed, scale, pool = CHUNK_LAUNCHES[launch]
+    quantized = pool == jnp.int8
+    sd = _sd(v5e)
+    bf16 = lambda *shape: sd(shape, jnp.bfloat16)  # noqa: E731
+    ints = lambda *shape: sd(shape, jnp.int32)  # noqa: E731
+    heads, ppb, cblk = pa._chunk_geometry(
+        chunk, hq // hkv, hkv, PAGE, D, pool, jnp.bfloat16, pages,
+        None, None, quantized)
+    assert heads == hkv and chunk % cblk == 0
+    assert ppb * PAGE == (256 if hkv == 10 else 512)
+
+    def attend(q, k, v, kv, pt, pos, prior, kvlen, **kw):
+        return paged_chunk_attention(
+            q, k, v, kv, pt, pos, prior, kvlen, 1, scale=scale, **kw)
+
+    more = {"window": ints()} if windowed else {}
+    if quantized:
+        more["kv_scales"] = sd((2, L, hkv, 4096, PAGE), jnp.float32)
+    compiled = jax.jit(attend).lower(
+        bf16(rows, chunk, hq, D), bf16(rows, chunk, hkv, D),
+        bf16(rows, chunk, hkv, D), sd((2, L, hkv, 4096, PAGE, D), pool),
+        ints(rows, pages), ints(rows, chunk), ints(rows), ints(rows), **more,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "radixmesh_chunk_attn" in text
+    call, = (line for line in text.splitlines()
+             if "tpu_custom_call" in line and "radixmesh_chunk_attn" in line)
+    assert '"scoped_memory_configs":[]' in call  # no VMEM asked for
+
+
 def test_glm47_decode_reads_shared_pages_in_one_pass_at_the_cells_bucket(v5e):
     """GLM-4.7-Flash ``decode_step`` at the docs cell's bucket (32 rows x
     2 048 pages, the served pool's slots): both passes of the latent decode
