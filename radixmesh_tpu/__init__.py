@@ -18,9 +18,8 @@ from radixmesh_tpu.cache.radix_tree import RadixTree, TreeNode, MatchResult
 
 def __getattr__(name: str):
     # PEP 562 lazy exports: kv_pool imports jax (~5 s cold), which the
-    # pure cache/mesh/router surface never needs — a 50-process ringscale
-    # sweep on one core must not pay 50 jax imports (scripts/ringscale.py
-    # --procs).
+    # pure cache/mesh/router surface never needs — fifty ring-node
+    # processes on one core must not pay 50 jax imports.
     if name in ("PagedKVPool", "SlotAllocator"):
         from radixmesh_tpu.cache import kv_pool
 

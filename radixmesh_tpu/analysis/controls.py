@@ -15,9 +15,9 @@ Marker grammar (inside fixture files):
   lines where a trailing comment would change what is being tested
   (e.g. a malformed suppression directive).
 
-``scripts/meshcheck.py`` embeds the results in the ANALYSIS artifact
-(``positive_controls``), and ``bench.validate_analysis`` fails the
-artifact if any control did not trip — the analysis-plane equivalent of
+``scripts/meshcheck.py`` embeds the results in the ANALYSIS report
+(``positive_controls``), and ``analysis/report.py::validate_analysis``
+fails the report if any control did not trip — the analysis-plane equivalent of
 the old lint tests' ``test_positive_control_*`` methods, but enforced
 for every checker uniformly.
 """

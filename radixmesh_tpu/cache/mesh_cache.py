@@ -301,11 +301,6 @@ class MeshCache:
         # rank was declared dead — nobody routes to it, so no message can
         # tell it). It re-asserts itself with a JOIN.
         self._last_rx = time.monotonic()
-        # Instrumentation seam: called (with the oplog, under the tree
-        # lock) when this node's OWN oplog returns after a full ring lap —
-        # the lap-latency probe for ``scripts/ringbench.py``. The
-        # reference's benchmark has no timers at all (``benchmark.py:24-31``).
-        self.on_lap_complete = None
         self._last_self_join = 0.0
         self._succ_rank: int | None = None
         # Channel retargets requested by view changes, applied by each
@@ -421,8 +416,8 @@ class MeshCache:
             ("node", "cause", "kind"),
         )
         # Prefix-ownership sharding telemetry. owned_shards tracks the
-        # RF-invariant's local share; bytes_per_insert is the EWMA the
-        # ringscale flatness gate watches live; pullthrough counts the
+        # RF-invariant's local share; bytes_per_insert is the EWMA of
+        # what a local insert costs on the wire; pullthrough counts the
         # non-owner cache-fill traffic by outcome (sent/send_failed on
         # the requester, served/miss on the owner).
         self._g_owned_shards = reg.gauge(
@@ -1070,13 +1065,7 @@ class MeshCache:
                 self._handle_digest(op, data)
                 return
             if op.origin_rank == self.rank:
-                # Lap complete (radix_mesh.py:401-402). Fire the
-                # instrumentation seam before dropping. In hier mode the
-                # seam fires on the GROUP lap's return; a leader-origin's
-                # returning SPINE copy is just dropped.
-                cb = self.on_lap_complete
-                if cb is not None and not op.spine:
-                    cb(op)
+                # Lap complete (radix_mesh.py:401-402): drop.
                 return
             # Apply BEFORE any TTL-based drop: with elastic membership an
             # oplog can carry a TTL computed from a stale (smaller) view,

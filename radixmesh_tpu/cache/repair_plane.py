@@ -116,7 +116,7 @@ class RepairConfig:
     backoff_base_s: float = 2.0
     backoff_max_s: float = 60.0
     jitter_frac: float = 0.25
-    # Accounting bound: the bench/acceptance scenario asserts an episode
+    # Accounting bound: the chaos scenario asserts an episode
     # (divergence detected → converged) heals within this many rounds.
     round_budget: int = 8
     # Warm-join bulk sessions (policy/lifecycle.py): a BOOTSTRAPPING

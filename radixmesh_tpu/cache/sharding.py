@@ -1,8 +1,8 @@
 """Prefix-ownership sharding: bounded-replication-factor placement.
 
 Full replication circulates every INSERT around the whole ring — 12
-frames / ~3 KB per insert at just 12 nodes, growing linearly with N
-(RINGSCALE_r05.json), which cannot reach hundreds of nodes. This module
+frames / ~3 KB per insert at just 12 nodes, growing linearly with N,
+which cannot reach hundreds of nodes. This module
 breaks that wall: the token space is partitioned into :data:`NUM_SHARDS`
 **subtree shards** (a key's shard is a pure hash of its first page — the
 subtree ROOT segment, so every prefix of a request lands in one shard),

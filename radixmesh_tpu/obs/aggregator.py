@@ -2,8 +2,8 @@
 
 Everything observability built through PR 13 answers for ONE node:
 ``/debug/timeseries`` is this node's rings, ``/cluster/doctor`` judges
-this node's seams, a black box dumps this node's history. At the N=200
-scale ringscale already simulates, an operator (or the ROADMAP item-2
+this node's seams, a black box dumps this node's history. At N=200
+an operator (or the ROADMAP item-2
 autoscale executor) must poll 200 endpoints and merge by hand — and the
 one merge that matters most, latency percentiles, is exactly the one
 hand-merging gets wrong (an average of per-node p99s is not the fleet
@@ -329,9 +329,8 @@ class FleetAggregator:
         )
         self._m_pull_seconds = reg.histogram(
             "radixmesh_agg_pull_seconds",
-            "wall cost of one full pull sweep over every peer — the "
-            "aggregation-overhead gate input (AGG artifact: < 1% of "
-            "run wall time)",
+            "wall cost of one full pull sweep over every peer — what "
+            "aggregation costs its host",
             buckets=TRANSFER_SECONDS_BUCKETS,
         )
         self._m_fleet_nodes = reg.gauge(
@@ -720,8 +719,7 @@ class FleetAggregator:
                 "peers": len(self._peers),
                 "sweeps": self._sweeps,
                 "last_sweep_t": self._last_sweep_t,
-                # This instance's own cumulative sweep cost — the AGG
-                # artifact's < 1% overhead gate input (the shared
+                # This instance's own cumulative sweep cost (the shared
                 # radixmesh_agg_pull_seconds histogram folds every
                 # aggregator in the process).
                 "pull_seconds_total": self._pull_seconds_total,

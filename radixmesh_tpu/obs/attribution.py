@@ -165,8 +165,8 @@ def waterfall_from_spans(spans: list[Span], retire: Span) -> Waterfall:
     consecutive endpoints to the highest-priority phase covering its
     midpoint (none → :data:`RESIDUAL_PHASE`). Exclusive by construction:
     each segment lands in exactly one phase, so the phase times sum to
-    the window length — the property ``bench.validate_doctor`` gates on
-    and ``tests/test_attribution.py`` proves over seeded traces."""
+    the window length — the property ``tests/test_attribution.py``
+    proves over seeded traces."""
     lo = retire.t0
     hi = retire.t0 + max(0.0, retire.dur)
     prio = {p: i for i, p in enumerate(PHASE_PRIORITY)}

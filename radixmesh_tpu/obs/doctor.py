@@ -1,14 +1,13 @@
 """The mesh doctor: a rule engine over the observability planes.
 
 Every diagnosis since PR 9 was a human reading raw artifacts — the
-prefill convoy (a 1 756 ms wide-shape p50 TTFT in the r05 CPU record), the
-hot-shard skew (16.3, OBS_r09), the paged small-batch gap — all found
-by eyeball. This module closes the loop: it CONSUMES the substrate
+prefill convoy, the hot-shard skew, the paged small-batch gap — all
+found by eyeball. This module closes the loop: it CONSUMES the substrate
 (FleetView health + shard heat, the phase attributor's per-shape
 waterfall aggregates, step accounting, the SLO token-bucket plane,
 engine spec counters) and emits **ranked findings, each carrying its
-evidence** — metric values, shard ids, owner sets — in a file-format
-the DOCTOR artifact schema pins (``bench.validate_doctor``), so "what
+evidence** — metric values, shard ids, owner sets — with the evidence
+fields of every rule pinned (:data:`RULE_EVIDENCE_FIELDS`), so "what
 is wrong with the mesh" is a GET, not an afternoon.
 
 Rules (each fires at most one finding; evidence fields are part of the
@@ -32,9 +31,8 @@ schema contract — see :data:`RULE_EVIDENCE_FIELDS`):
   floor with enough proposals to matter: names the shape whose drafts
   miss (the item-1(a) adaptive-γ substrate).
 
-A healthy mesh yields ZERO findings — the acceptance workload
-(``workload.run_doctor_workload``) gates on that as hard as it gates on
-the seeded pathologies being named.
+A healthy mesh yields ZERO findings — the black-box scenario's healthy
+phase (``workload.run_blackbox_workload``) gates on that.
 
 Import-light on purpose (stdlib only): both frontends, the router, and
 ``scripts/doctor.py`` construct doctors without a backend; every input
@@ -86,8 +84,8 @@ RULES = (
 )
 
 # The pinned evidence vocabulary per rule: every finding MUST carry at
-# least these keys (bench.validate_doctor checks artifacts against this
-# map; tests/test_doctor.py checks live findings against it). Evidence
+# least these keys (tests/test_doctor.py checks live findings against
+# this map). Evidence
 # without a contract rots into prose.
 RULE_EVIDENCE_FIELDS = {
     "hot_shard": ("skew_score", "shard", "owners", "reporters"),
@@ -1212,7 +1210,7 @@ class MeshDoctor:
         # looked at anything, so it must NOT appear in rules_checked —
         # that list is the honesty field the module contract promises
         # ("no findings" vs "nothing was looked at"), and the healthy-
-        # phase gate in bench.validate_doctor is vacuous without it.
+        # phase gate in workload.validate_blackbox is vacuous without it.
         attr = self.attributor
         available = {
             "hot_shard": self.mesh is not None,

@@ -765,8 +765,8 @@ class FleetPlane:
         )
 
     def publish_once(self) -> NodeDigest:
-        """One assemble+broadcast cycle (tests and the bench drive this
-        directly; the thread just calls it on a timer)."""
+        """One assemble+broadcast cycle (tests and the scenarios drive
+        this directly; the thread just calls it on a timer)."""
         d = self.build_digest()
         self.mesh.broadcast_digest(d)
         self.published += 1

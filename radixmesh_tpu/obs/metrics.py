@@ -336,7 +336,7 @@ class Histogram(_Family):
         Returning the bucket's upper bound snapped every estimate to a
         bucket edge — a 1.1 ms median read as 2.5 ms — wherever a
         histogram-derived quantile surfaces (``/debug/state`` latency
-        estimates; bench/workload medians come from raw samples and were
+        estimates; workload medians come from raw samples and were
         never affected). Still approximate (uniform-within-bucket
         assumption); exact values need the raw samples."""
         with self._lock:

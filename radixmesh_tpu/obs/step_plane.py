@@ -24,7 +24,7 @@ speculative decoding + chunked prefill) gates its before/after on:
 - Emitted as ``radixmesh_step_mfu`` / ``radixmesh_wave_pad_fraction``
   gauges (labels: engine, kind) plus ``step_wave`` recorder spans on
   the ``step:<engine>`` lane, and aggregated into :meth:`report` for
-  ``/debug/state`` and the OBS bench artifact.
+  ``/debug/state`` and the obs scenario's report.
 
 Accounting is OFF by default (``Engine(step_accounting=True)`` /
 ``launch.py --step-accounting``): the wave hot paths keep the PR 2

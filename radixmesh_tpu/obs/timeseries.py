@@ -29,8 +29,8 @@ with it. This module is the missing history axis:
   client never sees half a sample.
 - **Self-accounting**: the sampler registers ``radixmesh_history_*``
   families for its own sample count / cost / ring size, so the
-  history's overhead is itself visible in the history (the BLACKBOX
-  acceptance artifact gates it under 1% of a step-accounting run).
+  history's overhead is itself visible in the history (the black-box
+  scenario holds it to a budget of sweeps, series and segment bytes).
 - The doctor's burn-rate windows feed from here: every sample forwards
   the SLO burn counters into any bound
   :class:`~radixmesh_tpu.obs.doctor.BurnRateTracker`, so the 5 m / 1 h

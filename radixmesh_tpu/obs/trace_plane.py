@@ -86,6 +86,7 @@ __all__ = [
     "write_trace",
     "new_trace_id",
     "stitch_traces",
+    "validate_trace",
     "dropped_spans_counter",
 ]
 
@@ -439,7 +440,7 @@ class FlightRecorder:
         lanes: dict[str, int] = {}
         events: list[dict] = []
         # Sort by (lane, t0): within-lane ts monotonicity is part of the
-        # artifact contract (bench.validate_trace checks it).
+        # artifact contract (validate_trace checks it).
         for s in sorted(spans, key=lambda s: (s.lane, s.t0)):
             tid = lanes.setdefault(s.lane, len(lanes) + 1)
             ev = {
@@ -674,3 +675,42 @@ def write_trace(path: str, drain: bool = True) -> int:
     with open(path, "w") as fh:
         json.dump(obj, fh)
     return len(spans)
+
+
+def validate_trace(obj) -> list[str]:
+    """Schema violations of a Chrome trace-event artifact emitted by the
+    flight recorder (``chrome_trace`` / ``stitch_traces``) — empty list =
+    valid. Pinned contract: a JSON object with a ``traceEvents`` list;
+    every complete event (``ph == "X"``) carries numeric non-negative
+    ``ts``/``dur`` and a ``tid``; within each tid lane the ``ts`` values
+    are non-decreasing (Perfetto renders out-of-order lanes, but a
+    regression here means the exporter's sort broke)."""
+    problems: list[str] = []
+    if not isinstance(obj, dict):
+        return ["artifact is not a JSON object"]
+    events = obj.get("traceEvents")
+    if not isinstance(events, list):
+        return ["traceEvents missing or not a list"]
+    last_ts: dict = {}
+    for i, ev in enumerate(events):
+        if not isinstance(ev, dict):
+            problems.append(f"traceEvents[{i}] is not an object")
+            continue
+        if ev.get("ph") != "X":
+            continue  # metadata / instant events carry no duration
+        ts, dur, tid = ev.get("ts"), ev.get("dur"), ev.get("tid")
+        if not isinstance(ts, (int, float)) or ts < 0:
+            problems.append(f"traceEvents[{i}].ts invalid: {ts!r}")
+            continue
+        if not isinstance(dur, (int, float)) or dur < 0:
+            problems.append(f"traceEvents[{i}].dur invalid: {dur!r}")
+        if tid is None:
+            problems.append(f"traceEvents[{i}].tid missing")
+            continue
+        if ts < last_ts.get(tid, 0.0):
+            problems.append(
+                f"traceEvents[{i}].ts={ts} regresses within tid={tid} "
+                f"(prev {last_ts[tid]})"
+            )
+        last_ts[tid] = ts
+    return problems

@@ -2,8 +2,7 @@
 
 The reference's open roadmap question — "better topo if nodes over some
 number (like 50?)" (``/root/reference/README.md:57``) — made concrete. On
-the flat ring every oplog takes O(N) *serial* hops to propagate
-(``RINGSCALE_r04.json``: lap p50 grows 12x from N=6 to N=50). Here the
+the flat ring every oplog takes O(N) *serial* hops to propagate. Here the
 static ring ranks are partitioned into contiguous **groups** of
 ``group_size``; each group runs its own small ring, and the **leaders**
 (lowest alive rank per group) form a second ring — the **spine** — that

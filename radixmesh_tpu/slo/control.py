@@ -39,8 +39,8 @@ plane, engine-agnostic and fully deterministic under an injected clock:
 
 Everything is observable: queue depth, shed counts by reason, admission
 wait, backlog, service-rate EWMA, and the degradation tier all export
-through ``obs/metrics.py``; tier transitions keep an event log the bench
-overload sweep records (``SLO_r{N}.json``).
+through ``obs/metrics.py``; tier transitions keep an event log
+(``tier_events``).
 
 Thread model: frontend handler threads call :meth:`offer`/:meth:`enqueue`;
 the engine runner thread calls :meth:`pop_ready`/:meth:`note_first_token`.
@@ -295,9 +295,8 @@ class OverloadController:
         self._tier = 0
         self._above_since: float | None = None
         self._below_since: float | None = None
-        # (t, old_tier, new_tier, backlog_s) — the bench overload sweep
-        # records these per point; bounded so a flapping config can't
-        # grow without bound.
+        # (t, old_tier, new_tier, backlog_s); bounded so a flapping
+        # config can't grow without bound.
         self.tier_events: list[tuple[float, int, int, float]] = []
         self._shed_at_dispatch: list = []
         self.total_shed = 0

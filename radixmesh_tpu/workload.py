@@ -29,13 +29,11 @@ import numpy as np
 
 __all__ = [
     "MultiTurnWorkload",
-    "OverloadWorkload",
     "TextMultiTurnWorkload",
+    "run_blackbox_workload",
+    "run_chaos_workload",
     "run_engine_workload",
-    "run_fleet_churn_workload",
-    "run_kvflow_workload",
     "run_obs_workload",
-    "run_overload_workload",
     "run_tier_workload",
     "synth_text",
 ]
@@ -205,7 +203,7 @@ def run_engine_workload(
     With ``trace_path`` (and the flight recorder enabled — see
     ``obs/trace_plane.configure``), the run's spans are drained into a
     Chrome trace-event artifact next to the numeric report, so every
-    bench number comes with the timeline that produced it.
+    number comes with the timeline that produced it.
 
     ``ceiling_hit_rate`` is what an INFINITE, never-evicting cache would
     score on the same traffic (page-aligned like real admission): turn
@@ -276,414 +274,6 @@ def run_engine_workload(
         # must NOT slice that by request count).
         "ttft_s": list(ttft),
     }
-
-
-class OverloadWorkload:
-    """Open-loop multi-tenant overload shape for the SLO control plane
-    (``radixmesh_tpu/slo/``): requests ARRIVE on their own clock at
-    ``offered_tokens_per_s`` of prompt tokens regardless of how fast the
-    engine drains them — the regime where admission control, fairness,
-    and shedding are decidable at all (the closed-loop multi-turn shapes
-    above can never oversubscribe: each round waits for the last).
-
-    Tenants are drawn weight-proportionally; each tenant's prompts share
-    a ``shared_frac`` system head (so the cache sees realistic reuse)
-    with fresh per-request tails. Inter-arrival gaps are exponential
-    (Poisson process), deterministic under ``seed``."""
-
-    def __init__(
-        self,
-        tenants: dict[str, float] | None = None,
-        duration_s: float = 4.0,
-        offered_tokens_per_s: float = 2000.0,
-        prompt_len: int = 48,
-        shared_frac: float = 0.5,
-        gen_len: int = 8,
-        vocab_size: int = 512,
-        seed: int = 0,
-    ):
-        self.tenants = tenants or {"free": 1.0, "pro": 2.0}
-        self.duration_s = duration_s
-        self.offered_tokens_per_s = offered_tokens_per_s
-        self.prompt_len = prompt_len
-        self.gen_len = gen_len
-        rng = np.random.default_rng(seed)
-        names = sorted(self.tenants)
-        weights = np.asarray([self.tenants[n] for n in names], dtype=float)
-        weights /= weights.sum()
-        shared = max(0, min(int(prompt_len * shared_frac), prompt_len - 1))
-        heads = {
-            n: rng.integers(1, vocab_size, size=shared).tolist() for n in names
-        }
-        rate = offered_tokens_per_s / prompt_len  # arrivals per second
-        self.arrivals: list[tuple[float, str, list[int]]] = []
-        t = 0.0
-        while True:
-            t += float(rng.exponential(1.0 / rate))
-            if t >= duration_s:
-                break
-            tenant = names[int(rng.choice(len(names), p=weights))]
-            tail = rng.integers(
-                1, vocab_size, size=prompt_len - shared
-            ).tolist()
-            self.arrivals.append((t, tenant, heads[tenant] + tail))
-
-    @property
-    def offered_requests(self) -> int:
-        return len(self.arrivals)
-
-
-def run_overload_workload(
-    runner,
-    workload: OverloadWorkload,
-    ttft_deadline_s: float | None = None,
-    e2e_deadline_s: float | None = None,
-    wait_timeout_s: float = 120.0,
-) -> dict:
-    """Drive an :class:`OverloadWorkload` open-loop against an
-    :class:`~radixmesh_tpu.slo.runner.SLORunner` (wall-clock paced: the
-    submitting thread sleeps to each arrival instant, so offered load is
-    independent of service rate) and report the overload scorecard:
-    goodput (tokens of deadline-met requests per second), shed counts by
-    reason, per-tenant admitted shares, and TTFT percentiles over
-    admitted requests."""
-    from radixmesh_tpu.engine.request import SamplingParams
-    from radixmesh_tpu.slo.control import RequestShed
-
-    import time as _time
-
-    sampling = SamplingParams(temperature=0.0, max_new_tokens=workload.gen_len)
-    t0 = _time.monotonic()
-    inflight: list[tuple[str, object]] = []
-    shed: dict[str, int] = {}
-    submitted = 0
-    for t_arr, tenant, prompt in workload.arrivals:
-        delay = t0 + t_arr - _time.monotonic()
-        if delay > 0:
-            _time.sleep(delay)
-        submitted += 1
-        try:
-            req = runner.submit(
-                prompt,
-                sampling,
-                tenant=tenant,
-                ttft_deadline_s=ttft_deadline_s,
-                e2e_deadline_s=e2e_deadline_s,
-            )
-        except RequestShed as e:
-            shed[e.reason] = shed.get(e.reason, 0) + 1
-            continue
-        inflight.append((tenant, req))
-    deadline = _time.monotonic() + wait_timeout_s
-    ttft: list[float] = []
-    met = 0
-    good_tokens = 0  # prompt+generated tokens of deadline-met requests
-    served_tokens = 0  # prompt+generated tokens of ALL served requests
-    admitted_tokens: dict[str, int] = {}
-    timed_out = 0
-    for tenant, req in inflight:
-        try:
-            runner.wait(req, timeout=max(0.0, deadline - _time.monotonic()))
-        except TimeoutError:
-            # One stalled request must cost ONE scorecard row, not the
-            # whole report (and, from the bench sweep, the whole round's
-            # curve): count it unserved and keep collecting.
-            timed_out += 1
-            continue
-        if req.shed and not req.output_tokens:
-            # Dropped from the SLO queue at dispatch time.
-            shed[req.shed_reason] = shed.get(req.shed_reason, 0) + 1
-            continue
-        admitted_tokens[tenant] = admitted_tokens.get(tenant, 0) + len(
-            req.prompt
-        )
-        n_tok = len(req.prompt) + len(req.output_tokens)
-        served_tokens += n_tok
-        t_first = req.first_token_time - req.submit_time
-        ttft.append(t_first)
-        if ttft_deadline_s is None or t_first <= ttft_deadline_s:
-            met += 1
-            good_tokens += n_tok
-    elapsed = _time.monotonic() - t0
-    n_adm = len(ttft)
-    return {
-        "offered_requests": submitted,
-        "admitted_requests": n_adm,
-        "served_requests": n_adm,
-        "shed_requests": sum(shed.values()),
-        "shed_by_reason": shed,
-        "timed_out_requests": timed_out,
-        "deadline_met": met,
-        "deadline_met_frac": met / n_adm if n_adm else 0.0,
-        # Token rates are prompt+generated per wall second (submission
-        # window + drain): goodput counts only deadline-met requests,
-        # served_tok_s counts everything that ran to completion — under
-        # deadline-free saturation it IS the admission path's capacity.
-        "goodput_tok_s": good_tokens / elapsed if elapsed > 0 else 0.0,
-        "served_tok_s": served_tokens / elapsed if elapsed > 0 else 0.0,
-        "admitted_tokens_by_tenant": admitted_tokens,
-        "p50_ttft_s": float(np.median(ttft)) if ttft else 0.0,
-        "p99_ttft_s": float(np.quantile(ttft, 0.99)) if ttft else 0.0,
-        "elapsed_s": elapsed,
-    }
-
-
-class _StallableStats:
-    """Engine stand-in for fleet-bench stall injection: reports a full
-    batch whose ``decode_steps`` counter advances only while healthy —
-    exactly the signature the stall watchdog keys on, without needing a
-    (jax-heavy) real engine to actually wedge."""
-
-    def __init__(self):
-        self.healthy = True
-        self._steps = 0
-
-    def telemetry(self) -> dict:
-        if self.healthy:
-            self._steps += 7
-        return {
-            "batch_occupancy": 1.0,
-            "waiting": 3,
-            "decode_steps": self._steps,
-            "decode_ewma_s": 0.01,
-            "cache_hit_rate": 0.5,
-            "pool_fill": 0.5,
-            "host_fill": 0.0,
-            "evictions": {},
-        }
-
-
-def run_fleet_churn_workload(
-    n_inserts: int = 120,
-    key_len: int = 24,
-    fan_in_rounds: int = 5,
-    digest_interval_s: float = 0.1,
-    seed: int = 0,
-    timeout_s: float = 20.0,
-    health_threshold: float = 0.5,
-) -> dict:
-    """Drive the fleet telemetry plane (``obs/fleet_plane.py``) through
-    its three claims on an in-proc 2-prefill + 1-decode + router mesh and
-    measure each:
-
-    1. **Digest fan-in** — per publish round, seconds from the slowest
-       node's origination until every node (router included) holds all
-       three fresh digests.
-    2. **Convergence audit under churn** — seeded multi-writer inserts
-       while digests gossip; the max pairwise ``convergence_age_seconds``
-       observed during churn, and the time from quiescence to all four
-       replicas reporting one fingerprint. Then an injected divergence
-       (a key applied to ONE replica only — a stand-in partition): the
-       age must rise while diverged and return to ~0 after the heal.
-    3. **Health reaction** — a stall injected into one node's telemetry
-       (batch full, decode frozen); seconds until the router's fleet
-       view scores it below ``health_threshold``, and whether a
-       health-aware router actually stops selecting it.
-
-    Transport-light by design (no jax, no sockets): the phenomena under
-    test live in the gossip/fold/score layer, which is identical over
-    the inproc hub and TCP."""
-    import time as _time
-
-    from radixmesh_tpu.cache.mesh_cache import MeshCache
-    from radixmesh_tpu.comm.inproc import InprocHub
-    from radixmesh_tpu.config import MeshConfig, NodeRole
-    from radixmesh_tpu.obs.fleet_plane import FleetPlane
-    from radixmesh_tpu.router.cache_aware_router import CacheAwareRouter
-
-    def wait_for(pred, timeout=timeout_s, interval=0.005):
-        deadline = _time.monotonic() + timeout
-        while _time.monotonic() < deadline:
-            if pred():
-                return True
-            _time.sleep(interval)
-        return pred()
-
-    rng = np.random.default_rng(seed)
-    t_start = _time.monotonic()
-    InprocHub.reset_default()
-    prefill, decode, router = ["fp0", "fp1"], ["fd0"], ["fr0"]
-    nodes: list = []
-    for addr in prefill + decode + router:
-        cfg = MeshConfig(
-            prefill_nodes=prefill,
-            decode_nodes=decode,
-            router_nodes=router,
-            local_addr=addr,
-            protocol="inproc",
-            tick_interval_s=0.05,
-            gc_interval_s=30.0,
-        )
-        nodes.append(MeshCache(cfg, pool=None).start())
-    planes = []
-    try:
-        for n in nodes:
-            if not n.wait_ready(timeout=timeout_s):
-                raise RuntimeError(f"node {n.rank} never passed the barrier")
-        ring = [n for n in nodes if n.role is not NodeRole.ROUTER]
-        router_mesh = nodes[-1]
-        stall = _StallableStats()
-        planes = [
-            FleetPlane(
-                n,
-                engine=stall if i == 1 else None,
-                interval_s=digest_interval_s,
-            )
-            for i, n in enumerate(ring)
-        ]
-        digest_bytes = max(p.build_digest().encoded_size() for p in planes)
-
-        # -- 1. digest fan-in ------------------------------------------
-        fan_in: list[float] = []
-        for _ in range(fan_in_rounds):
-            want = {}
-            t0 = _time.monotonic()
-            for p in planes:
-                want[p.mesh.rank] = p.publish_once().seq
-            assert wait_for(
-                lambda: all(
-                    (d := n.fleet.digests()).keys() >= want.keys()
-                    and all(d[r].seq >= s for r, s in want.items())
-                    for n in nodes
-                )
-            ), "digest fan-in never completed"
-            fan_in.append(_time.monotonic() - t0)
-
-        # -- 2. convergence under churn --------------------------------
-        churn_t0 = _time.monotonic()
-        max_age_churn = 0.0
-        for i in range(n_inserts):
-            writer = ring[int(rng.integers(0, len(ring)))]
-            key = rng.integers(0, 512, size=key_len).astype(np.int32)
-            writer.insert(key, np.arange(key_len, dtype=np.int32))
-            if i % 10 == 0:
-                for p in planes:
-                    p.publish_once()
-                max_age_churn = max(
-                    max_age_churn,
-                    router_mesh.fleet.convergence()["max_convergence_age_s"],
-                )
-        churn_s = _time.monotonic() - churn_t0
-        quiesce_t0 = _time.monotonic()
-
-        def _converged() -> bool:
-            for p in planes:
-                p.publish_once()
-            fps = {n.tree.fingerprint_ for n in nodes}
-            return (
-                len(fps) == 1
-                and router_mesh.fleet.convergence()["converged"]
-            )
-
-        converged = wait_for(_converged, interval=digest_interval_s)
-        quiesce_s = _time.monotonic() - quiesce_t0
-
-        # Injected divergence: one replica learns a key the others never
-        # see (partition stand-in); heal by replicating it for real.
-        rogue = ring[0]
-        key = rng.integers(600, 900, size=key_len).astype(np.int32)
-        idx = np.arange(key_len, dtype=np.int32)
-        from radixmesh_tpu.cache.mesh_values import PrefillValue
-
-        with rogue._lock:
-            rogue._mesh_insert(key, PrefillValue(idx, rogue.rank))
-        for p in planes:
-            p.publish_once()
-        diverged = wait_for(
-            lambda: not router_mesh.fleet.convergence()["converged"]
-        )
-        age_t0 = _time.monotonic()
-        _time.sleep(3 * digest_interval_s)
-        for p in planes:
-            p.publish_once()
-        age_while_diverged = router_mesh.fleet.convergence()[
-            "max_convergence_age_s"
-        ]
-        rogue.insert(key, idx)  # heal: replicate the divergent key
-        healed = wait_for(_converged, interval=digest_interval_s)
-        heal_s = _time.monotonic() - age_t0
-
-        # -- 3. stall injection + health-aware demotion ----------------
-        sick = planes[1].mesh  # the plane wired to the stallable stats
-        cr = CacheAwareRouter(
-            router_mesh,
-            router_mesh.cfg,
-            health_aware=True,
-            health_threshold=health_threshold,
-        )
-        cr.finish_warm_up()
-        planes[1].publish_once()  # healthy baseline digest
-        stall.healthy = False
-        stall_t0 = _time.monotonic()
-
-        def _scored_sick() -> bool:
-            planes[1].publish_once()
-            return (
-                router_mesh.fleet.health_score(sick.rank) < health_threshold
-            )
-
-        reacted = wait_for(_scored_sick, interval=digest_interval_s)
-        reaction_s = _time.monotonic() - stall_t0
-        sick_addr = sick.cfg.addr_of_rank(sick.rank)
-        routed = {
-            cr.cache_aware_route(
-                rng.integers(0, 512, size=8).astype(np.int32)
-            ).prefill_addr
-            for _ in range(32)
-        }
-        demoted = reacted and sick_addr not in routed
-
-        # Frame discipline: each DIGEST origination is exactly one ring
-        # frame, and the router receives each exactly once (master
-        # fan-out) — ratio ~1.0 proves one-frame-per-interval-per-node.
-        from radixmesh_tpu.cache.oplog import OplogType
-
-        total_published = sum(p.published for p in planes)
-        router_digests = int(
-            router_mesh._m_received[OplogType.DIGEST].value
-        )
-        frames_per_publish = router_digests / max(1, total_published)
-
-        return {
-            "nodes": len(nodes),
-            "topology": "2 prefill + 1 decode + 1 router (inproc)",
-            "digest_interval_s": digest_interval_s,
-            "digest_bytes": int(digest_bytes),
-            "fan_in": {
-                "rounds": fan_in_rounds,
-                "p50_s": float(np.median(fan_in)),
-                "max_s": float(max(fan_in)),
-            },
-            "convergence": {
-                "inserts": n_inserts,
-                "writers": len(ring),
-                "churn_s": round(churn_s, 3),
-                "max_age_during_churn_s": round(max_age_churn, 3),
-                "quiesce_to_converged_s": round(quiesce_s, 3),
-                "converged": bool(converged),
-                "injected_divergence_detected": bool(diverged),
-                "age_while_diverged_s": round(age_while_diverged, 3),
-                "healed": bool(healed),
-                "heal_s": round(heal_s, 3),
-            },
-            "stall_reaction": {
-                "injected": True,
-                "detected": bool(reacted),
-                "reaction_s": round(reaction_s, 3),
-                "score_after": router_mesh.fleet.health_score(sick.rank),
-                "threshold": health_threshold,
-            },
-            "health_aware_demotion": bool(demoted),
-            "digests_published": total_published,
-            "digest_frames_per_publish": round(frames_per_publish, 3),
-            "wall_s": round(_time.monotonic() - t_start, 3),
-        }
-    finally:
-        for p in planes:
-            p.close()
-        for n in nodes:
-            n.close()
-        InprocHub.reset_default()
 
 
 def _pair_converged(a, b) -> bool:
@@ -1163,6 +753,14 @@ def _chaos_crash_phase(
         per_addr[rec.addr] = per_addr.get(rec.addr, 0) + 1
     victim = max(decode, key=lambda a: per_addr.get(a, 0))
     interrupted = [r for r in streams if r.addr == victim]
+    # The victim dies mid-stream, not mid-enqueue: its sender is a
+    # thread, and on a busy machine a kill at whatever instant the loop
+    # above ended can find every publish still queued in the victim. So
+    # the kill waits (bounded) for the victim's own outbound lanes to
+    # drain, and for nothing else — what the survivors then hold of the
+    # interrupted streams, through the seeded loss and the ring's hops,
+    # is what the resurrection gate measures.
+    by_addr[victim].flush_outbound()
     plan.kill(victim)
     victim_node = by_addr[victim]
     for plane in kill_planes(victim_node):
@@ -1728,8 +1326,8 @@ def run_chaos_workload(
     router_kill_streams: int = 10,
     router_kill_tokens: int = 16,
 ) -> dict:
-    """The chaos acceptance scenario (``bench.validate_chaos`` pins its
-    artifact): a seeded FaultPlan injects ``drop_p`` frame loss across
+    """The chaos acceptance scenario (``validate_chaos`` below pins its
+    report): a seeded FaultPlan injects ``drop_p`` frame loss across
     the whole fault window plus a symmetric ``partition_s`` partition of
     one prefill node, while routed requests keep flowing —
 
@@ -2156,6 +1754,386 @@ def run_chaos_workload(
         InprocHub.reset_default()
 
 
+# ----------------------------------------------------------------------
+# CHAOS report (PR 5, self-healing mesh; PR 6, membership lifecycle;
+# PR 7, request recovery; PR 14, rebalance and router kill): what
+# ``run_chaos_workload`` returns, held to its gates — seeded frame loss + a
+# scheduled partition (comm/faults.py) diverge replicas; the
+# anti-entropy repair plane (cache/repair_plane.py) must converge every
+# replica (router included) within a bounded number of repair rounds
+# while requests keep being served, then go quiet.
+# The elastic-membership phases (policy/lifecycle.py): a
+# graceful drain under sustained loss (zero failed requests, in-flight
+# requeued-and-served, hot tokens written back, departure via LEAVE —
+# never failure detection) and a cold rejoin during an active partition
+# (bulk-bootstrap from a donor within the round budget, router
+# withholding cache hits until convergence).
+# The crash phase (server/recovery.py): an UNCLEAN decode-node
+# kill mid-stream under loss — zero failed requests, every interrupted
+# stream resumed with a byte-identical delivered prefix, resurrection
+# served ≥ 0.8 from the replicated cache, every recovery hop bounded by
+# the admission deadline budget, hedged prefill first-writer-wins.
+# Every section is required: no report of an older version exists.
+# ----------------------------------------------------------------------
+
+CHAOS_SCHEMA_VERSION = 4
+
+CHAOS_TOP_FIELDS = (
+    "schema_version", "metric", "value", "unit", "workload", "nodes",
+    "topology", "round_budget", "fault_plan", "served", "divergence",
+    "repair", "quiescence", "wall_s",
+)
+CHAOS_FAULT_FIELDS = (
+    "seed", "drop_p", "drop_window_s", "partition_s", "partitioned_node",
+    "frames_dropped", "frames_delivered",
+)
+CHAOS_SERVED_FIELDS = ("attempted", "ok", "ok_rate_during_fault")
+CHAOS_DIVERGENCE_FIELDS = ("detected", "peak_diverged_pairs", "max_age_s")
+CHAOS_REPAIR_FIELDS = (
+    "converged", "converge_s", "max_episode_rounds", "within_round_budget",
+    "probes_sent", "summaries_sent", "keys_pushed", "oplogs_reemitted",
+    "heals",
+)
+CHAOS_QUIESCENCE_FIELDS = (
+    "window_s", "traffic_before", "traffic_after", "quiet",
+)
+# v2 membership-lifecycle sections. Required when the section reports
+# performed=True (a run that skipped the phase ships {"performed":
+# false} and is schema-valid but gate-exempt).
+CHAOS_DRAIN_FIELDS = (
+    "performed", "node", "drop_p", "requeued", "requeued_served",
+    "attempted_during_drain", "ok_during_drain", "zero_failed",
+    "left_without_failure_detection", "writeback_tokens",
+    "writeback_flushed", "drain_s",
+)
+CHAOS_JOIN_FIELDS = (
+    "performed", "joiner", "donor_rank", "partition_active_at_join",
+    "partition_s", "bootstrap_converge_s", "bootstrap_rounds",
+    "round_budget", "within_round_budget", "converged_with_donor",
+    "withheld_hits", "hits_to_bootstrapping",
+    "fleet_converged_after_join",
+)
+# v3 request-recovery section (crash-mid-decode). Required when the
+# section reports performed=True; {"performed": false} is schema-valid
+# and gate-exempt, like the v2 sections.
+CHAOS_CRASH_FIELDS = (
+    "performed", "node", "drop_p", "streams", "tokens_per_stream",
+    "killed_at_token", "interrupted", "resumed", "failed",
+    "prefix_identical", "replayed_tokens", "replayed_cached_tokens",
+    "resurrection_hit_ratio", "retries", "resurrections",
+    "failover_routes", "detection", "budget", "hedge", "crash_s",
+)
+# The structural acceptance floor the resurrection claim rides on.
+CHAOS_CRASH_MIN_HIT_RATIO = 0.8
+
+# v4 robustness-loop sections (PR 14): heat-driven rebalancing under a
+# zipf storm, and a router kill at an N>=2 multi-router front door.
+# Required when performed=True; {"performed": false} is schema-valid
+# and gate-exempt, the v2/v3 convention.
+CHAOS_REBALANCE_FIELDS = (
+    "performed", "skew_before", "skew_after", "skew_dropped", "moves",
+    "max_moves_per_round", "moves_bounded", "boosted_shards", "hot_shard",
+    "attempted_mid_move", "ok_mid_move", "failed_mid_move",
+    "overrides_version", "overrides_converged", "handoff_entries",
+    "rebalance_s",
+)
+CHAOS_ROUTER_KILL_FIELDS = (
+    "performed", "routers", "killed", "survivor", "streams",
+    "inflight_at_kill", "completed", "failed", "failovers",
+    "survivor_served", "router_kill_s",
+)
+
+
+def _rebalance_section_problems(sec: dict) -> list[str]:
+    """Gates for a performed rebalance-under-storm section (shared by
+    validate_chaos and validate_rebalance): the skew score STRICTLY
+    dropped, zero requests failed mid-move, movement happened and
+    stayed bounded, and every node converged on the decider's override
+    version."""
+    problems = [
+        f"rebalance.{f}" for f in CHAOS_REBALANCE_FIELDS if f not in sec
+    ]
+    before, after = sec.get("skew_before"), sec.get("skew_after")
+    if (
+        not isinstance(before, (int, float))
+        or not isinstance(after, (int, float))
+        or not (after < before)
+    ):
+        problems.append(
+            f"rebalance: the zipf storm's skew score did not strictly "
+            f"drop under rebalancing ({before} -> {after})"
+        )
+    if sec.get("failed_mid_move", 1) != 0:
+        problems.append(
+            f"rebalance: {sec.get('failed_mid_move')} request(s) failed "
+            "mid-move — an ownership move must be invisible to traffic"
+        )
+    if not sec.get("moves", 0):
+        problems.append(
+            "rebalance: zero adopted moves (the storm never triggered "
+            "the rebalancer — the drop proves nothing)"
+        )
+    if sec.get("moves_bounded") is not True:
+        problems.append(
+            f"rebalance: {sec.get('moves')} moves exceeded the per-round "
+            f"bound of {sec.get('max_moves_per_round')}"
+        )
+    if sec.get("overrides_converged") is not True:
+        problems.append(
+            "rebalance: the fleet never converged on the decider's "
+            "override version (split-brain owner sets)"
+        )
+    return problems
+
+
+def _router_kill_section_problems(sec: dict) -> list[str]:
+    """Gates for a performed router-kill section: N >= 2 routers, the
+    kill landed mid-traffic, every in-flight request completed through
+    the surviving router's edge, and the front door actually failed
+    over (a kill nobody noticed proves nothing)."""
+    problems = [
+        f"router_kill.{f}" for f in CHAOS_ROUTER_KILL_FIELDS if f not in sec
+    ]
+    if int(sec.get("routers", 0) or 0) < 2:
+        problems.append(
+            f"router_kill: only {sec.get('routers')} router(s) — the "
+            "multi-router front door needs N >= 2 to prove failover"
+        )
+    if sec.get("failed", 1) != 0:
+        problems.append(
+            f"router_kill: {sec.get('failed')} request(s) LOST to the "
+            "router kill — the front door exists to make this zero"
+        )
+    if sec.get("completed") != sec.get("streams"):
+        problems.append(
+            "router_kill: in-flight requests did not all complete "
+            f"({sec.get('completed')}/{sec.get('streams')})"
+        )
+    if not sec.get("inflight_at_kill", 0):
+        problems.append(
+            "router_kill: the kill interrupted zero in-flight streams "
+            "(the failover path went unexercised)"
+        )
+    if not sec.get("failovers", 0):
+        problems.append(
+            "router_kill: the front door never failed over (was the "
+            "victim really killed mid-traffic?)"
+        )
+    if sec.get("survivor_served") is not True:
+        problems.append(
+            "router_kill: the surviving router's edge served no "
+            "post-kill routes"
+        )
+    return problems
+
+
+def validate_chaos(report) -> list[str]:
+    """Schema violations of a CHAOS artifact vs the pinned contract
+    (empty = valid): all top/section fields present, plus the three
+    structural acceptance gates — every replica converged, within the
+    repair-round budget, and ZERO repair traffic once converged
+    (quiescence)."""
+    if not isinstance(report, dict):
+        return ["artifact is not a JSON object"]
+    problems = [f for f in CHAOS_TOP_FIELDS if f not in report]
+    for section, fields in (
+        ("fault_plan", CHAOS_FAULT_FIELDS),
+        ("served", CHAOS_SERVED_FIELDS),
+        ("divergence", CHAOS_DIVERGENCE_FIELDS),
+        ("repair", CHAOS_REPAIR_FIELDS),
+        ("quiescence", CHAOS_QUIESCENCE_FIELDS),
+    ):
+        sec = report.get(section)
+        if isinstance(sec, dict):
+            problems += [f"{section}.{f}" for f in fields if f not in sec]
+    rep = report.get("repair")
+    if isinstance(rep, dict):
+        if rep.get("converged") is not True:
+            problems.append(
+                "repair.converged is not True (replicas never healed)"
+            )
+        if rep.get("within_round_budget") is not True:
+            problems.append(
+                f"repair.max_episode_rounds {rep.get('max_episode_rounds')} "
+                f"exceeded round_budget {report.get('round_budget')}"
+            )
+    div = report.get("divergence")
+    if isinstance(div, dict) and div.get("detected") is not True:
+        problems.append(
+            "divergence.detected is not True (the fault injected nothing — "
+            "the heal proves nothing)"
+        )
+    q = report.get("quiescence")
+    if isinstance(q, dict) and q.get("quiet") is not True:
+        problems.append(
+            f"quiescence: repair traffic kept flowing after convergence "
+            f"({q.get('traffic_before')} → {q.get('traffic_after')})"
+        )
+    # Membership-lifecycle sections + gates.
+    drain = report.get("drain")
+    if not isinstance(drain, dict):
+        problems.append("drain section missing")
+    if isinstance(drain, dict) and drain.get("performed"):
+        problems += [
+            f"drain.{f}" for f in CHAOS_DRAIN_FIELDS if f not in drain
+        ]
+        if drain.get("zero_failed") is not True:
+            problems.append(
+                "drain: requests failed during the graceful drain "
+                f"({drain.get('ok_during_drain')}/"
+                f"{drain.get('attempted_during_drain')} ok, "
+                f"{drain.get('requeued_served')}/{drain.get('requeued')} "
+                "requeued-and-served)"
+            )
+        if drain.get("requeued_served") != drain.get("requeued"):
+            problems.append(
+                "drain: parked requests were requeued but not all served "
+                f"({drain.get('requeued_served')}/{drain.get('requeued')})"
+            )
+        if drain.get("left_without_failure_detection") is not True:
+            problems.append(
+                "drain: the planned departure tripped failure detection "
+                "(a 'dead'-cause successor transition fired)"
+            )
+        if drain.get("writeback_flushed") is not True:
+            problems.append(
+                "drain: hot prefixes were not written back before LEAVE"
+            )
+    join = report.get("join")
+    if not isinstance(join, dict):
+        problems.append("join section missing")
+    if isinstance(join, dict) and join.get("performed"):
+        problems += [
+            f"join.{f}" for f in CHAOS_JOIN_FIELDS if f not in join
+        ]
+        if join.get("converged_with_donor") is not True:
+            problems.append(
+                "join: the bootstrapping node never converged with its "
+                "donor"
+            )
+        if join.get("within_round_budget") is not True:
+            problems.append(
+                f"join: bootstrap took {join.get('bootstrap_rounds')} "
+                f"rounds, over the budget of {join.get('round_budget')}"
+            )
+        if join.get("hits_to_bootstrapping", 0) != 0:
+            problems.append(
+                "join: the router routed cache hits to a BOOTSTRAPPING "
+                f"node ({join.get('hits_to_bootstrapping')} times)"
+            )
+        if not join.get("withheld_hits", 0) and not int(
+            report.get("replication_factor", 0) or 0
+        ):
+            # Sharded runs (replication_factor > 0) are exempt: the
+            # router routes from owner summaries there, and a COLD
+            # joiner advertises no warmth — there is never a hit to
+            # withhold, and hits_to_bootstrapping == 0 (gated above) is
+            # the whole invariant.
+            problems.append(
+                "join: the router never withheld a hit during bootstrap "
+                "(the withhold path went unexercised — the gate proves "
+                "nothing)"
+            )
+    # Request-recovery section + gates.
+    crash = report.get("crash")
+    if not isinstance(crash, dict):
+        problems.append("crash section missing")
+    if isinstance(crash, dict) and crash.get("performed"):
+        problems += [
+            f"crash.{f}" for f in CHAOS_CRASH_FIELDS if f not in crash
+        ]
+        if crash.get("failed") != 0:
+            problems.append(
+                f"crash: {crash.get('failed')} request(s) LOST to the "
+                "unclean kill — a node death must be a latency blip, "
+                "never a request loss"
+            )
+        if not crash.get("interrupted", 0):
+            problems.append(
+                "crash: the kill interrupted zero live streams (the "
+                "resurrection path went unexercised — the gate proves "
+                "nothing)"
+            )
+        if crash.get("resumed") != crash.get("interrupted"):
+            problems.append(
+                "crash: interrupted streams were not all resurrected "
+                f"({crash.get('resumed')}/{crash.get('interrupted')})"
+            )
+        if crash.get("prefix_identical") is not True:
+            problems.append(
+                "crash: a resumed stream re-emitted, skipped, or "
+                "corrupted already-delivered tokens (prefix not "
+                "byte-identical)"
+            )
+        ratio = crash.get("resurrection_hit_ratio")
+        if not isinstance(ratio, (int, float)) or (
+            ratio < CHAOS_CRASH_MIN_HIT_RATIO
+        ):
+            problems.append(
+                f"crash: resurrection cache-hit ratio {ratio} below "
+                f"{CHAOS_CRASH_MIN_HIT_RATIO} — replay recomputed what "
+                "the replicated tree should have served"
+            )
+        budget = crash.get("budget")
+        if not isinstance(budget, dict) or (
+            budget.get("within_one_backoff") is not True
+        ):
+            problems.append(
+                "crash: a recovered request overran its admission "
+                "deadline by more than one retry backoff (the budget "
+                "was not threaded through every hop)"
+            )
+        hedge = crash.get("hedge")
+        if isinstance(hedge, dict) and hedge.get("fired"):
+            if hedge.get("first_writer_wins") is not True:
+                problems.append(
+                    "crash: the hedge's first successful writer did "
+                    "not win"
+                )
+            if hedge.get("loser_cancelled") is not True:
+                problems.append(
+                    "crash: the hedge loser was not cancelled (its "
+                    "pages would leak)"
+                )
+    # Robustness-loop sections + gates.
+    reb = report.get("rebalance")
+    if not isinstance(reb, dict):
+        problems.append("rebalance section missing")
+    if isinstance(reb, dict) and reb.get("performed"):
+        problems += _rebalance_section_problems(reb)
+    rk = report.get("router_kill")
+    if not isinstance(rk, dict):
+        problems.append("router_kill section missing")
+    if isinstance(rk, dict) and rk.get("performed"):
+        problems += _router_kill_section_problems(rk)
+    return problems
+
+
+def build_chaos_report(res: dict) -> dict:
+    """Assemble a schema-complete CHAOS artifact from
+    ``workload.run_chaos_workload``'s result."""
+    fp = res.get("fault_plan", {})
+    rep = res.get("repair", {})
+    return {
+        "schema_version": CHAOS_SCHEMA_VERSION,
+        "metric": "chaos_heal_converge_s",
+        "value": rep.get("converge_s"),
+        "unit": "s from fault-window close to ALL replicas (P/D/router) "
+        "pairwise fingerprint-equal via anti-entropy repair",
+        "workload": (
+            f"{int(100 * fp.get('drop_p', 0))}% seeded frame loss for "
+            f"{fp.get('drop_window_s', 0)}s + {fp.get('partition_s', 0)}s "
+            f"symmetric partition of {fp.get('partitioned_node')} while "
+            "routed requests keep flowing, then a graceful drain under "
+            "re-opened loss, a cold rejoin during a fresh partition, "
+            "and an unclean decode-node kill mid-stream with "
+            "request resurrection from the replicated prefix cache "
+            "(inproc ring; see workload.run_chaos_workload)"
+        ),
+        **res,
+    }
+
+
 def _obs_zipf_heat_phase(
     *,
     ring,
@@ -2380,7 +2358,7 @@ def run_obs_workload(
     stitched_trace_path: str | None = None,
 ) -> dict:
     """The mesh-wide observability acceptance scenario (PR 9;
-    ``bench.validate_obs`` pins its artifact) — three legs over one
+    ``validate_obs`` below pins its report) — three legs over one
     sharded cluster (4 prefill + 2 decode + 1 router, rf defaults 3):
 
     a. **Cross-node trace stitching.** A chaos-style crash+resurrection
@@ -2514,7 +2492,45 @@ def run_obs_workload(
             # spans must land on >= 3 distinct node tracks under its
             # single trace id, with publish + replication edges visible.
             rec = get_recorder()
-            spans = rec.snapshot()
+
+            def audit():
+                spans = rec.snapshot()
+                best = {"trace_id": 0, "nodes": set(), "lag": 0, "publish": 0}
+                for irec in interrupted:
+                    tid = irec.trace_id
+                    node_set = {
+                        s.node for s in spans if s.trace_id == tid and s.node
+                    }
+                    lag = sum(
+                        1
+                        for s in spans
+                        if s.trace_id == tid and s.name == "replication_lag"
+                    )
+                    pub = sum(
+                        1
+                        for s in spans
+                        if s.trace_id == tid and s.name == "mesh_publish"
+                    )
+                    if len(node_set) > len(best["nodes"]):
+                        best = {
+                            "trace_id": tid, "nodes": node_set,
+                            "lag": lag, "publish": pub,
+                        }
+                return spans, best
+
+            # A replica records its replication edge when it applies the
+            # frame, on its own thread: the audit reads the recorder once
+            # the edges are there (or the scenario's timeout has passed),
+            # not at whatever instant the serving loop happened to end.
+            def edges_recorded() -> bool:
+                _, best = audit()
+                return (
+                    len(best["nodes"]) >= OBS_MIN_NODE_TRACKS
+                    and best["lag"] > 0
+                )
+
+            wait_for(edges_recorded)
+            spans, best = audit()
             # Clock-offset correction from the digest timestamps the
             # fleet already gossips (rank-keyed → node-label-keyed).
             offsets = {
@@ -2523,27 +2539,6 @@ def run_obs_workload(
                 if r in by_rank
             }
             stitched = stitch_traces([rec.export_spans()], offsets)
-            best = {"trace_id": 0, "nodes": set(), "lag": 0, "publish": 0}
-            for irec in interrupted:
-                tid = irec.trace_id
-                node_set = {
-                    s.node for s in spans if s.trace_id == tid and s.node
-                }
-                lag = sum(
-                    1
-                    for s in spans
-                    if s.trace_id == tid and s.name == "replication_lag"
-                )
-                pub = sum(
-                    1
-                    for s in spans
-                    if s.trace_id == tid and s.name == "mesh_publish"
-                )
-                if len(node_set) > len(best["nodes"]):
-                    best = {
-                        "trace_id": tid, "nodes": node_set,
-                        "lag": lag, "publish": pub,
-                    }
             stitch_report.update(
                 {
                     "trace_id": f"{best['trace_id']:#018x}",
@@ -2656,332 +2651,172 @@ def run_obs_workload(
     }
 
 
-def run_kvflow_workload(
-    n_restore_requests: int = 3,
-    prompt_tokens: int = 1536,
-    gen_len: int = 2,
-    # Three chunks per restore unit at the default prompt length: the
-    # artifact must exercise the multi-chunk staging path, not just the
-    # degenerate one-chunk case.
-    chunk_tokens: int = 512,
-    background_tokens: int = 48,
-    repeats: int = 3,
-    seed: int = 0,
-    max_steps: int = 20_000,
-) -> dict:
-    """Drive the async KV-movement plane (``cache/kv_transfer.py``)
-    through its three lanes against the synchronous baseline — the
-    KVFLOW artifact's data source.
+# ----------------------------------------------------------------------
+# OBS stable schema (PR 9, mesh-wide observability plane): one artifact
+# per round recording the three legs of workload.run_obs_workload —
+# (a) cross-node trace stitching (crash+resurrection under full tracing,
+# one Perfetto export, interrupted request on >= OBS_MIN_NODE_TRACKS
+# node tracks under a single trace id), (b) per-shard heat & skew (zipf
+# inserts drive the skew score; the router names the hot shard + owner
+# set from gossip alone), and (c) TPU step attribution (per-wave MFU +
+# pad fraction for prefill AND decode), plus the wire gate (traceless
+# frames bit-for-bit pre-PR-9). Bump the version ONLY when adding fields
+# (never remove or rename).
+# ----------------------------------------------------------------------
 
-    **Restore TTFT** (phase A): seed ``n_restore_requests`` distinct
-    long prefixes, write them back to the host tier, then re-serve them
-    in a MIXED burst — each restore request interleaved with a fresh
-    (uncached) request — and compare the burst's mean TTFT between the
-    synchronous inline-restore path and the staged plane. The mix is the
-    claim's shape: synchronously, every admission in the pass convoys
-    behind the serial inline restores (fresh requests pay for KV copies
-    they don't need); with the plane, restoring requests park and fresh
-    ones admit immediately, so the burst mean drops even though the
-    parked requests themselves land at rough parity (both sub-means are
-    reported). Runs ``repeats`` interleaved trials per mode (fresh
-    engines, shared jit cache) to decorrelate machine drift.
+OBS_SCHEMA_VERSION = 1
 
-    **Decode overlap** (phase B): the same burst with a background
-    request decoding. The synchronous engine restores inline inside
-    ``_admit`` — decode provably makes ZERO progress while any restore
-    is in flight; the plane engine parks the requests and keeps
-    stepping. ``decode_steps_during_restore`` is the claim's direct
-    counter, and the max inter-decode-step gap bounds the stall.
+OBS_TOP_FIELDS = (
+    "schema_version", "metric", "value", "unit", "workload", "nodes",
+    "topology", "replication_factor", "stitch", "heat", "steps", "wire",
+    "wall_s",
+)
+OBS_STITCH_FIELDS = (
+    "performed", "node", "streams", "tokens_per_stream", "interrupted",
+    "resumed", "failed", "trace_id", "node_tracks", "nodes_on_track",
+    "replication_edges", "publish_edges", "span_count", "stitched_events",
+)
+OBS_HEAT_FIELDS = (
+    "performed", "inserts", "distinct_keys", "zipf_alpha", "skew_score",
+    "hot_shard", "expected_hot_shard", "hot_owners", "expected_hot_owners",
+    "owner_set_correct", "reporters",
+)
+OBS_STEP_FIELDS = ("performed", "n_params", "peak_tflops", "prefill", "decode")
+OBS_WAVE_FIELDS = ("waves", "real_tokens", "padded_tokens", "mfu", "pad_fraction")
+OBS_WIRE_FIELDS = (
+    "rf0_traceless_unchanged", "trace_trailer_roundtrip", "trailer_bytes",
+)
+# Structural acceptance floors.
+OBS_MIN_NODE_TRACKS = 3
+OBS_MIN_SKEW_SCORE = 2.0
 
-    **Write-back**: the eviction sweeps above pin the fused-gather
-    contract — one device gather per sweep regardless of node count
-    (``HierarchicalCache.wb_gathers / wb_sweeps``), both modes.
 
-    **Prefetch** (phase C): re-evict, fire idempotent hints (duplicates
-    included) for every prefix, let the plane restore with NO request in
-    the system, then submit the requests — ``hit_ahead_rate`` is the
-    fraction that admitted without parking (their restore ran ahead of
-    them).
-
-    CPU-runnable by design: the phenomena under test are scheduling
-    overlaps, not FLOPs — but on CPU the restore copies are small next
-    to compute, so treat the TTFT comparison as structural (does
-    overlapping REGRESS TTFT?) rather than a hardware claim; the TPU
-    story is the bytes moved per stall-free decode step.
-    """
-    import time as _time
-
-    import jax
-
-    from radixmesh_tpu.engine.engine import Engine
-    from radixmesh_tpu.engine.request import RequestState, SamplingParams
-    from radixmesh_tpu.models.llama import ModelConfig
-
-    from radixmesh_tpu.models.llama import init_params
-
-    # Wider-KV small model: restore bytes per token are what the plane
-    # moves, FLOPs are what CPU steps cost — keep the former meaningful.
-    cfg = ModelConfig(
-        vocab_size=256, hidden=128, n_layers=4, n_heads=4, n_kv_heads=4,
-        head_dim=64, intermediate=256,
-        max_seq_len=max(2048, 2 * prompt_tokens),
-    )
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    rng = np.random.default_rng(seed)
-    page_size = 4
-    prompts = [
-        rng.integers(1, cfg.vocab_size - 1, size=prompt_tokens).astype(np.int32)
-        for _ in range(n_restore_requests)
-    ]
-
-    def fresh_prompts() -> list[np.ndarray]:
-        """Distinct uncached companions for one burst (never repeated, so
-        no trial ever serves them from the cache)."""
-        return [
-            rng.integers(
-                1, cfg.vocab_size - 1, size=max(64, prompt_tokens // 6)
-            ).astype(np.int32)
-            for _ in range(n_restore_requests)
+def validate_obs(report) -> list[str]:
+    """Schema violations of an OBS artifact vs the pinned contract
+    (empty = valid). Gates: the stitched trace shows the interrupted
+    request on >= OBS_MIN_NODE_TRACKS node tracks under ONE trace id
+    with replication edges visible and zero lost streams; the zipf hot
+    shard is detected with the correct owner set and a skew score above
+    the floor; per-wave MFU + pad fraction are reported for BOTH
+    prefill and decode; and the traceless wire is bit-for-bit the
+    pre-trace encoding. Sections with performed=False are schema-valid
+    but gate-exempt (the CHAOS v2/v3 convention)."""
+    if not isinstance(report, dict):
+        return ["artifact is not a JSON object"]
+    problems = [f for f in OBS_TOP_FIELDS if f not in report]
+    stitch = report.get("stitch")
+    if isinstance(stitch, dict) and stitch.get("performed"):
+        problems += [
+            f"stitch.{f}" for f in OBS_STITCH_FIELDS if f not in stitch
         ]
+        if stitch.get("failed") != 0:
+            problems.append(
+                f"stitch: {stitch.get('failed')} stream(s) LOST during the "
+                "traced crash drill"
+            )
+        if not stitch.get("interrupted", 0):
+            problems.append(
+                "stitch: the kill interrupted zero live streams (the "
+                "cross-node path went unexercised)"
+            )
+        if stitch.get("resumed") != stitch.get("interrupted"):
+            problems.append(
+                "stitch: interrupted streams were not all resurrected "
+                f"({stitch.get('resumed')}/{stitch.get('interrupted')})"
+            )
+        tracks = stitch.get("node_tracks")
+        if not isinstance(tracks, int) or tracks < OBS_MIN_NODE_TRACKS:
+            problems.append(
+                f"stitch: interrupted request spans only {tracks} node "
+                f"track(s) (< {OBS_MIN_NODE_TRACKS}) — the journey did "
+                "not stitch"
+            )
+        if not stitch.get("replication_edges", 0):
+            problems.append(
+                "stitch: no replication edges under the trace id (the "
+                "oplog trace trailer never landed receiver-side)"
+            )
+    heat = report.get("heat")
+    if isinstance(heat, dict) and heat.get("performed"):
+        problems += [f"heat.{f}" for f in OBS_HEAT_FIELDS if f not in heat]
+        skew = heat.get("skew_score")
+        if not isinstance(skew, (int, float)) or skew < OBS_MIN_SKEW_SCORE:
+            problems.append(
+                f"heat: skew score {skew} below {OBS_MIN_SKEW_SCORE} — the "
+                "zipf workload failed to drive (or the plane failed to "
+                "measure) a hot shard"
+            )
+        if heat.get("hot_shard") != heat.get("expected_hot_shard"):
+            problems.append(
+                f"heat: detected hot shard {heat.get('hot_shard')} != "
+                f"ground truth {heat.get('expected_hot_shard')}"
+            )
+        if heat.get("owner_set_correct") is not True:
+            problems.append(
+                "heat: the hot shard's owner set was not correctly named "
+                f"({heat.get('hot_owners')} vs "
+                f"{heat.get('expected_hot_owners')})"
+            )
+        if not heat.get("reporters", 0):
+            problems.append("heat: zero heat reporters (gossip never folded)")
+    steps = report.get("steps")
+    if isinstance(steps, dict) and steps.get("performed"):
+        problems += [f"steps.{f}" for f in OBS_STEP_FIELDS if f not in steps]
+        for kind in ("prefill", "decode"):
+            wave = steps.get(kind)
+            if not isinstance(wave, dict):
+                continue
+            problems += [
+                f"steps.{kind}.{f}" for f in OBS_WAVE_FIELDS if f not in wave
+            ]
+            if not wave.get("waves", 0):
+                problems.append(f"steps: zero {kind} waves accounted")
+            mfu = wave.get("mfu")
+            if not isinstance(mfu, (int, float)) or not (mfu > 0):
+                problems.append(
+                    f"steps: {kind} MFU {mfu!r} not a positive number"
+                )
+            pad = wave.get("pad_fraction")
+            if not isinstance(pad, (int, float)) or not (0.0 <= pad < 1.0):
+                problems.append(
+                    f"steps: {kind} pad fraction {pad!r} outside [0, 1)"
+                )
+    wire = report.get("wire")
+    if isinstance(wire, dict):
+        problems += [f"wire.{f}" for f in OBS_WIRE_FIELDS if f not in wire]
+        if wire.get("rf0_traceless_unchanged") is not True:
+            problems.append(
+                "wire: a traceless frame is NOT bit-for-bit the pre-trace "
+                "encoding (tracing off must cost zero wire bytes)"
+            )
+        if wire.get("trace_trailer_roundtrip") is not True:
+            problems.append("wire: the trace trailer did not round-trip")
+    return problems
 
-    bg_prompt = rng.integers(
-        1, cfg.vocab_size - 1, size=max(16, prompt_tokens // 4)
-    ).astype(np.int32)
-    sampling = SamplingParams(temperature=0.0, max_new_tokens=gen_len)
-    bg_sampling = SamplingParams(temperature=0.0, max_new_tokens=background_tokens)
-    t_start = _time.monotonic()
 
-    # Phase A (the TTFT comparison) stages WHOLE restore units: the sync
-    # path pays one pool scatter per node, and on XLA:CPU every scatter
-    # copies the entire pool buffer, so per-chunk scatters would tax the
-    # async side with copies the TPU donation path never pays — the
-    # comparison must differ only in WHERE the copy blocks, not in how
-    # many device ops run. Phases B/C run at ``chunk_tokens`` so the
-    # multi-chunk staging pipeline is exercised under measurement too.
-    ttft_chunk_tokens = max(chunk_tokens, prompt_tokens)
-
-    def make_engine(use_plane: bool, tag: str, chunk: int | None = None) -> Engine:
-        return Engine(
-            cfg,
-            params,
-            # Sized to the workload, not generously: on XLA:CPU every
-            # pool scatter copies the whole buffer, so an oversized pool
-            # taxes the async path's per-chunk scatters hardest — the
-            # TPU story (donation = in-place) has no such tax.
-            num_slots=max(
-                4096, (n_restore_requests + 1) * prompt_tokens + 4096
-            ),
-            page_size=page_size,
-            max_batch=2 * n_restore_requests + 1,
-            host_cache_slots=max(
-                8192, (n_restore_requests + 2) * prompt_tokens * 2
-            ),
-            kv_transfer_async=use_plane,
-            kv_transfer_chunk_tokens=chunk if chunk is not None else chunk_tokens,
-            name=tag,
-        )
-
-    def close(eng: Engine) -> None:
-        if eng.kv_transfer is not None:
-            eng.kv_transfer.close()
-
-    def seed_and_evict(eng: Engine) -> dict:
-        for p in prompts:
-            eng.generate([list(p)], sampling)
-        t0 = _time.monotonic()
-        eng.tree.evict(10 * prompt_tokens * n_restore_requests)
-        stall = _time.monotonic() - t0
-        if eng.kv_transfer is not None:
-            eng.kv_transfer.wait_host_ready()
-        return {
-            "evict_stall_s": stall,
-            "sweeps": eng.tree.wb_sweeps,
-            "gathers": eng.tree.wb_gathers,
-        }
-
-    def serve_burst(eng: Engine, background: bool, mixed: bool = False) -> dict:
-        bg = None
-        if background:
-            bg = eng.add_request(list(bg_prompt), bg_sampling)
-            eng.step()  # admit + first decode for the background row
-        reqs = []
-        fresh = fresh_prompts() if mixed else []
-        for i, p in enumerate(prompts):
-            reqs.append(eng.add_request(list(p), sampling))
-            if mixed:
-                reqs.append(eng.add_request(list(fresh[i]), sampling))
-        restore_rids = {r.rid for r in reqs[:: 2 if mixed else 1]}
-        parked: set = set()
-        decode_steps_during_restore = 0
-        last_decode_t = _time.monotonic()
-        max_gap = 0.0
-        for _ in range(max_steps):
-            before = eng.stats.decode_steps
-            eng.step()
-            now = _time.monotonic()
-            restoring = bool(getattr(eng, "_restoring", ()))
-            for r in reqs:
-                if r.state is RequestState.RESTORING:
-                    parked.add(r.rid)
-            stepped = eng.stats.decode_steps - before
-            if stepped and background:
-                # Max inter-decode-step gap: the synchronous path's
-                # inline restores stretch it (admission blocks the whole
-                # step); the plane keeps it at ~one step time.
-                max_gap = max(max_gap, now - last_decode_t)
-                last_decode_t = now
-            if restoring:
-                decode_steps_during_restore += stepped
-            if all(r.state is RequestState.FINISHED for r in reqs):
-                break
-        if bg is not None and bg.state is not RequestState.FINISHED:
-            eng.cancel(bg.rid)
-        ttfts = [r.first_token_time - r.submit_time for r in reqs]
-        rest_tt = [
-            r.first_token_time - r.submit_time
-            for r in reqs
-            if r.rid in restore_rids
-        ]
-        fresh_tt = [
-            r.first_token_time - r.submit_time
-            for r in reqs
-            if r.rid not in restore_rids
-        ]
-        return {
-            "mean_ttft_s": float(np.mean(ttfts)),
-            "restore_ttft_s": float(np.mean(rest_tt)) if rest_tt else 0.0,
-            "fresh_ttft_s": float(np.mean(fresh_tt)) if fresh_tt else 0.0,
-            "parked": len(parked),
-            "decode_steps_during_restore": decode_steps_during_restore,
-            "max_decode_gap_s": max_gap,
-        }
-
-    # ---- phase A: restore TTFT, interleaved repeats, no background ----
-    # One unmeasured warm-up pair first: both modes share the process-
-    # wide jit cache, and the compile bill (hundreds of ms) would
-    # otherwise land entirely on whichever measured trial runs first.
-    for warm in (True, False):
-        eng = make_engine(warm, f"kvflow-warm-{int(warm)}", chunk=ttft_chunk_tokens)
-        seed_and_evict(eng)
-        serve_burst(eng, background=False, mixed=True)
-        close(eng)
-    # Async first within each measured pair: any residual one-time cost
-    # still biases AGAINST the overlap claim.
-    a_trials: list[dict] = []
-    s_trials: list[dict] = []
-    wb = {}
-    for t in range(max(1, repeats)):
-        eng = make_engine(True, f"kvflow-a{t}", chunk=ttft_chunk_tokens)
-        wb_a = seed_and_evict(eng)
-        a_trials.append(serve_burst(eng, background=False, mixed=True))
-        close(eng)
-        eng = make_engine(False, f"kvflow-s{t}")
-        wb_s = seed_and_evict(eng)
-        s_trials.append(serve_burst(eng, background=False, mixed=True))
-        close(eng)
-        wb = {"async": wb_a, "sync": wb_s}
-    a_ttfts = [x["mean_ttft_s"] for x in a_trials]
-    s_ttfts = [x["mean_ttft_s"] for x in s_trials]
-
-    # ---- phase B: decode overlap under a live background row ----
-    eng_a = make_engine(True, "kvflow-ov-a")
-    seed_and_evict(eng_a)
-    ov_a = serve_burst(eng_a, background=True)
-    eng_s = make_engine(False, "kvflow-ov-s")
-    seed_and_evict(eng_s)
-    ov_s = serve_burst(eng_s, background=True)
-    close(eng_s)
-
-    # ---- phase C: prefetch hit-ahead (reuses the async overlap engine) ----
-    plane = eng_a.kv_transfer
-    hints_seen0 = plane.hints_seen
-    eng_a.tree.evict(10 * prompt_tokens * n_restore_requests)
-    plane.wait_host_ready()
-    for p in prompts:
-        plane.note_hint(p)
-        plane.note_hint(p)  # duplicate: must dedupe/join, not double-restore
-    hints_sent = plane.hints_seen - hints_seen0
-    t0 = _time.monotonic()
-    for _ in range(max_steps):
-        eng_a.step()
-        if plane.idle() or _time.monotonic() - t0 > 30:
-            break
-    hints_joined = plane.stats()["hints_joined"]
-    reqs = [eng_a.add_request(list(p), sampling) for p in prompts]
-    parked: set = set()
-    for _ in range(max_steps):
-        eng_a.step()
-        for r in reqs:
-            if r.state is RequestState.RESTORING:
-                parked.add(r.rid)
-        if all(r.state is RequestState.FINISHED for r in reqs):
-            break
-    hit_ahead = 1.0 - len(parked) / max(1, len(reqs))
-    close(eng_a)
-
-    sync_ttft = float(np.mean(s_ttfts))
-    over_ttft = float(np.mean(a_ttfts))
-    restored_tokens = n_restore_requests * (
-        prompt_tokens - prompt_tokens % page_size
-    )
+def build_obs_report(res: dict) -> dict:
+    """Assemble a schema-complete OBS artifact from
+    ``workload.run_obs_workload``'s result."""
+    stitch = res.get("stitch", {})
+    heat = res.get("heat", {})
     return {
-        "restore": {
-            "requests": n_restore_requests,
-            "repeats": max(1, repeats),
-            "sync_ttft_s": round(sync_ttft, 6),
-            "overlapped_ttft_s": round(over_ttft, 6),
-            "overlap_ratio": (
-                round(over_ttft / sync_ttft, 4) if sync_ttft else 0.0
-            ),
-            "overlap_wins": bool(over_ttft <= sync_ttft),
-            "sync_ttft_trials_s": [round(x, 6) for x in s_ttfts],
-            "overlapped_ttft_trials_s": [round(x, 6) for x in a_ttfts],
-            # Burst composition sub-means: the win comes from fresh
-            # admissions no longer convoying behind inline restores;
-            # parked requests themselves land at rough parity.
-            "sync_restore_ttft_s": round(
-                float(np.mean([x["restore_ttft_s"] for x in s_trials])), 6
-            ),
-            "overlapped_restore_ttft_s": round(
-                float(np.mean([x["restore_ttft_s"] for x in a_trials])), 6
-            ),
-            "sync_fresh_ttft_s": round(
-                float(np.mean([x["fresh_ttft_s"] for x in s_trials])), 6
-            ),
-            "overlapped_fresh_ttft_s": round(
-                float(np.mean([x["fresh_ttft_s"] for x in a_trials])), 6
-            ),
-            "restored_tokens": restored_tokens,
-            "parked_requests": ov_a["parked"],
-            "decode_steps_during_restore": ov_a["decode_steps_during_restore"],
-            "sync_decode_steps_during_restore": ov_s[
-                "decode_steps_during_restore"
-            ],
-            "max_decode_gap_s": round(ov_a["max_decode_gap_s"], 6),
-            "sync_max_decode_gap_s": round(ov_s["max_decode_gap_s"], 6),
-        },
-        "writeback": {
-            "tokens_written_back": restored_tokens,
-            "sweeps": int(wb["async"]["sweeps"]),
-            "gathers": int(wb["async"]["gathers"]),
-            "gathers_per_sweep": round(
-                wb["async"]["gathers"] / max(1, wb["async"]["sweeps"]), 4
-            ),
-            "sync_gathers_per_sweep": round(
-                wb["sync"]["gathers"] / max(1, wb["sync"]["sweeps"]), 4
-            ),
-            "evict_stall_s": round(wb["async"]["evict_stall_s"], 6),
-            "sync_evict_stall_s": round(wb["sync"]["evict_stall_s"], 6),
-        },
-        "prefetch": {
-            "hints_sent": int(hints_sent),
-            "hints_joined": int(hints_joined),
-            "hit_ahead_rate": round(hit_ahead, 4),
-        },
-        "chunk_tokens": chunk_tokens,
-        "ttft_chunk_tokens": ttft_chunk_tokens,
-        "page_size": page_size,
-        "wall_s": round(_time.monotonic() - t_start, 3),
+        "schema_version": OBS_SCHEMA_VERSION,
+        "metric": "obs_stitched_node_tracks",
+        "value": stitch.get("node_tracks"),
+        "unit": (
+            "node tracks carrying the interrupted request's spans in ONE "
+            "stitched Perfetto trace under a single 64-bit trace id"
+        ),
+        "workload": (
+            f"{stitch.get('streams', 0)} traced streams, busiest decode "
+            "node killed mid-stream, resurrection on the survivor "
+            f"(rf={res.get('replication_factor')}); zipf(alpha="
+            f"{heat.get('zipf_alpha')}) inserts over "
+            f"{heat.get('distinct_keys')} subtree roots for the heat map; "
+            "tiny-engine burst for step attribution "
+            "(see workload.run_obs_workload)"
+        ),
+        **res,
     }
 
 
@@ -2997,8 +2832,8 @@ def run_tier_workload(
     max_steps: int = 40_000,
 ) -> dict:
     """Drive the durable KV spill tier (``cache/kv_tier.py``) through
-    the TIER artifact's three claims — the data source for
-    ``bench.validate_tier`` / ``scripts/tierbench.py``.
+    the TIER report's three claims — the data source for
+    ``validate_tier`` below.
 
     **Capacity** (phase A): a working set of ``n_prefixes`` distinct
     ``prefix_tokens``-token prefixes — sized >= 10x the host arena —
@@ -3333,411 +3168,221 @@ def run_tier_workload(
     }
 
 
-def run_doctor_workload(
-    seed: int = 0,
-    replication_factor: int = 3,
-    balanced_shards: int = 24,
-    zipf_keys: int = 64,
-    zipf_inserts: int = 400,
-    zipf_alpha: float = 1.4,
-    key_len: int = 8,
-    short_prompt: int = 96,
-    long_prompt: int = 1536,
-    restore_prompt: int = 512,
-    restore_chunk_tokens: int = 64,
-    summary_interval_s: float = 0.2,
-    timeout_s: float = 60.0,
-    max_steps: int = 20_000,
-) -> dict:
-    """The diagnosis-plane acceptance scenario (PR 12;
-    ``bench.validate_doctor`` pins its artifact): one rf=3 inproc mesh
-    (4 prefill + 2 decode + 1 router) plus a traced CPU engine, driven
-    through a provably HEALTHY phase and then three deterministically
-    seeded pathologies — and ONE :class:`~radixmesh_tpu.obs.doctor.
-    MeshDoctor` (the burn windows need continuity) must stay silent on
-    the former and NAME each of the latter with evidence matching the
-    seeded ground truth:
+# ----------------------------------------------------------------------
+# TIER stable schema (PR 15, the durable KV spill tier): one artifact
+# per round recording (a) hit-rate at a working set >= 10x host
+# capacity beating the no-tier baseline (the tier stack finally
+# outlives DRAM), (b) the restore-overlap contract extended one tier
+# down — decode never blocks on disk restores (KVFLOW's
+# decode-never-blocks discipline), (c) the cold-cell resurrection drill:
+# the WHOLE serving cell killed hard mid-decode, restarted, every
+# interrupted stream resumed byte-identical from disk alone, with
+# seeded torn/corrupt extents detected and dropped rather than served,
+# and (d) meshcheck clean on the new plane (the hotpath-file-io
+# invariant live with its positive control tripping).
+# ----------------------------------------------------------------------
 
-    0. **Healthy.** One balanced insert per ``balanced_shards`` distinct
-       shards (skew ≈ 1) at each shard's primary owner, plus a traced
-       two-shape engine burst with decode-dominant requests. Every rule
-       runs; zero findings is the gate — a diagnosis plane that cries
-       wolf gets muted.
-    a. **Zipf heat storm** (reuses the OBS leg): deterministic
-       rank^-alpha insert counts drive one shard provably hottest; the
-       doctor must name THAT shard and its true owner set (the item-2
-       rebalancer's trigger evidence).
-    b. **Convoying long-prompt burst**: ``long_prompt``-token requests
-       served in small prefill waves spend most of their e2e in
-       exclusive prefill time and run well slower than the short-shape
-       fleet — the r05 wide-shape pathology, seeded on purpose; the
-       doctor must name the convoying SHAPE bucket from the phase
-       attributor's per-shape table.
-    c. **Throttled restore lane**: host-tier prefixes re-requested
-       through a tiny-chunk KV-transfer plane park in RESTORING behind
-       a staged-chunk backlog that is never pumped before the
-       diagnosis — the doctor must name the restore lane with the live
-       parked count.
+TIER_SCHEMA_VERSION = 1
 
-    The phase attributor audits every traced request along the way; the
-    workload returns its sum-error high-water mark so the artifact can
-    gate "exclusive phase times sum to e2e within epsilon" on real
-    traffic, not just the property test's synthetic traces."""
-    import time as _time
+TIER_TOP_FIELDS = (
+    "schema_version", "metric", "value", "unit", "workload",
+    "capacity", "spill", "restore_overlap", "cold_start", "corruption",
+    "meshcheck", "page_size", "wall_s",
+)
+TIER_CAPACITY_FIELDS = (
+    "working_set_tokens", "host_slots", "working_set_ratio",
+    "tier_hit_rate", "baseline_hit_rate", "hit_rate_gain",
+    "requests", "distinct_prefixes",
+)
+TIER_SPILL_FIELDS = (
+    "spilled_tokens", "extents", "demotes", "promotes", "drops",
+    "resident_bytes",
+)
+TIER_RESTORE_FIELDS = (
+    "parked_requests", "disk_restored_tokens",
+    "decode_steps_during_restore", "max_decode_gap_s", "overlap_ok",
+)
+TIER_COLD_START_FIELDS = (
+    "performed", "interrupted", "resumed", "byte_identical", "failed",
+    "disk_hit_tokens", "grafted_nodes", "orphaned",
+    "corrupt_detected", "corrupt_served", "restart_s",
+)
+TIER_CORRUPTION_FIELDS = (
+    "extents_attacked", "truncated", "bitflipped", "detected",
+    "served_corrupt",
+)
+TIER_MESHCHECK_FIELDS = ("files", "findings", "clean")
+TIER_MIN_WORKING_SET_RATIO = 10.0
 
-    import jax
 
-    from radixmesh_tpu.cache.mesh_cache import MeshCache
-    from radixmesh_tpu.cache.sharding import shard_of_tokens
-    from radixmesh_tpu.comm.inproc import InprocHub
-    from radixmesh_tpu.config import MeshConfig, NodeRole
-    from radixmesh_tpu.engine.engine import Engine
-    from radixmesh_tpu.engine.request import SamplingParams
-    from radixmesh_tpu.models.llama import ModelConfig, init_params
-    from radixmesh_tpu.obs.aggregator import FleetAggregator, InprocPeer
-    from radixmesh_tpu.obs.attribution import ensure_attributor, shape_bucket
-    from radixmesh_tpu.obs.doctor import MeshDoctor
-    from radixmesh_tpu.obs.timeseries import TelemetryHistory
-    from radixmesh_tpu.obs.trace_plane import (
-        FlightRecorder,
-        get_recorder,
-        set_recorder,
-    )
-    from radixmesh_tpu.slo.control import OverloadController, SLOConfig
-
-    def wait_for(pred, timeout=timeout_s, interval=0.02):
-        deadline = _time.monotonic() + timeout
-        while _time.monotonic() < deadline:
-            if pred():
-                return True
-            _time.sleep(interval)
-        return pred()
-
-    def finding_for(report: dict, rule: str) -> dict | None:
-        for f in report["findings"]:
-            if f["rule"] == rule:
-                return f
-        return None
-
-    rng = np.random.default_rng(seed)
-    t_start = _time.monotonic()
-    InprocHub.reset_default()
-    prev_recorder = get_recorder()
-    # 4 prefills so rf=3 owner sets are PROPER subsets of the prefill
-    # role (the hot-owner evidence gate must not be vacuous).
-    prefill = ["dp0", "dp1", "dp2", "dp3"]
-    decode = ["dd0", "dd1"]
-    router_addrs = ["dr0"]
-    nodes: list = []
-    eng = None
-    try:
-        for addr in prefill + decode + router_addrs:
-            cfg = MeshConfig(
-                prefill_nodes=prefill,
-                decode_nodes=decode,
-                router_nodes=router_addrs,
-                local_addr=addr,
-                protocol="inproc",
-                tick_interval_s=0.1,
-                gc_interval_s=60.0,
-                failure_timeout_s=60.0,
-                replication_factor=replication_factor,
-                shard_summary_interval_s=summary_interval_s,
-            )
-            nodes.append(MeshCache(cfg, pool=None).start())
-        for n in nodes:
-            if not n.wait_ready(timeout=timeout_s):
-                raise RuntimeError(f"node {n.rank} never passed the barrier")
-        ring = [n for n in nodes if n.role is not NodeRole.ROUTER]
-        router_mesh = nodes[-1]
-        by_rank = {n.rank: n for n in ring}
-        any_node = ring[0]
-        page = max(1, any_node.page)
-        ownership = any_node.ownership
-
-        # -- engine (the convoy + restore substrate) -------------------
-        mcfg = ModelConfig(
-            vocab_size=256, hidden=64, n_layers=2, n_heads=2, n_kv_heads=2,
-            head_dim=32, intermediate=128,
-            max_seq_len=max(4096, 2 * long_prompt),
-        )
-        eng = Engine(
-            mcfg,
-            init_params(mcfg, jax.random.PRNGKey(seed)),
-            num_slots=16384,
-            page_size=4,
-            max_batch=12,
-            host_cache_slots=8192,
-            kv_transfer_async=True,
-            kv_transfer_chunk_tokens=restore_chunk_tokens,
-            name="doctor-eng",
-            # CPU-tier jit compiles take seconds; a serving-tuned 50ms
-            # stall threshold would attribute compile time as decode
-            # stalls and trip the healthy-phase zero-findings gate.
-            token_stall_threshold_s=5.0,
-        )
-
-        def prompts_of(n_tokens: int, count: int) -> list[np.ndarray]:
-            return [
-                rng.integers(
-                    1, mcfg.vocab_size - 1, size=n_tokens
-                ).astype(np.int32)
-                for _ in range(count)
-            ]
-
-        short_sampling = SamplingParams(temperature=0.0, max_new_tokens=12)
-        long_sampling = SamplingParams(temperature=0.0, max_new_tokens=2)
-        healthy_sampling = SamplingParams(temperature=0.0, max_new_tokens=10)
-
-        # Warm-up UNTRACED (sample=0), one run of EVERY measured burst
-        # composition (same shapes, same batch sizes, fresh prompts):
-        # jit compiles land here, so the traced phases time steady-state
-        # waves, not compilation, and the attributor never sees these
-        # requests.
-        set_recorder(FlightRecorder(capacity=4096, sample=0.0, node="warm"))
-        eng.generate(
-            [list(p) for p in prompts_of(24, 3) + prompts_of(48, 3)],
-            healthy_sampling,
-        )
-        eng.generate(
-            [list(p) for p in prompts_of(short_prompt, 6)], short_sampling
-        )
-        eng.generate(
-            [list(p) for p in prompts_of(long_prompt, 3)], long_sampling
-        )
-        # Restore-phase prefixes seed (and compile) here too — their
-        # re-serve in pathology (c) must find them in the HOST tier.
-        restore_prompts = prompts_of(restore_prompt, 3)
-        eng.generate([list(p) for p in restore_prompts], long_sampling)
-
-        # Traced from here: fresh recorder at full sampling, attributor
-        # installed on its retire hook, ONE doctor over every plane.
-        rec = FlightRecorder(
-            capacity=1 << 16, sample=1.0, node="doctor-eng"
-        )
-        set_recorder(rec)
-        attr = ensure_attributor(rec)
-        slo = OverloadController(SLOConfig())
-        # Fleet-aggregation seam (PR 17): an in-proc aggregator over the
-        # router's own ring, pulled by hand before each diagnosis, so
-        # the fleet rules (straggler_node / fleet_burn_slope /
-        # telemetry_gap) RUN in the healthy phase — the schema's
-        # rules_checked gate requires every live rule, and a quiet
-        # fleet must yield zero fleet findings. The history seam (PR 18)
-        # arms goodput_regression the same way.
-        agg_hist = TelemetryHistory(
-            interval_s=0.2, mesh=router_mesh, node="dr0"
-        )
-        agg = FleetAggregator(
-            peers=[InprocPeer("dr0", agg_hist, rank=router_mesh.rank)],
-            interval_s=0.2,
-        )
-        doctor = MeshDoctor(
-            mesh=router_mesh,
-            engine=eng,
-            slo=slo,
-            attributor=ensure_attributor,
-            history=agg_hist,
-            aggregator=agg,
-        )
-
-        # -- phase 0: healthy ------------------------------------------
-        # Balanced heat: ONE key per distinct shard, equal token counts,
-        # inserted at the shard's primary owner → skew ≈ 1.
-        seen_shards: set[int] = set()
-        attempts = 0
-        while len(seen_shards) < balanced_shards and attempts < 10_000:
-            attempts += 1
-            key = np.concatenate([
-                np.asarray([11_000 + attempts], dtype=np.int32),
-                rng.integers(1, 600, size=key_len - 1).astype(np.int32),
-            ])
-            sid = shard_of_tokens(key[:page])
-            if sid in seen_shards:
+def validate_tier(report) -> list[str]:
+    """Schema violations of a TIER artifact vs the pinned contract
+    (empty = valid). Gates: working set >= 10x host capacity with the
+    tier's hit-rate strictly beating the no-tier baseline; decode
+    progress > 0 while disk restores were parked (the restore-overlap
+    contract one tier down); the cold-start phase losing zero requests,
+    resuming every interrupted stream byte-identical from disk alone,
+    and detecting (never serving) every seeded corrupt/torn extent; and
+    meshcheck clean on the tier plane. performed=False sections are
+    schema-valid but gate-exempt (the CHAOS convention)."""
+    if not isinstance(report, dict):
+        return ["artifact is not a JSON object"]
+    problems = [f for f in TIER_TOP_FIELDS if f not in report]
+    for section, fields in (
+        ("capacity", TIER_CAPACITY_FIELDS),
+        ("spill", TIER_SPILL_FIELDS),
+        ("restore_overlap", TIER_RESTORE_FIELDS),
+        ("cold_start", TIER_COLD_START_FIELDS),
+        ("corruption", TIER_CORRUPTION_FIELDS),
+        ("meshcheck", TIER_MESHCHECK_FIELDS),
+    ):
+        sec = report.get(section)
+        if section in report and not isinstance(sec, dict):
+            problems.append(f"{section} section is not an object")
+            continue
+        if isinstance(sec, dict):
+            if section == "cold_start" and not sec.get("performed"):
+                # The CHAOS convention: a skipped phase is schema-valid
+                # ({"performed": False}) but gate-exempt.
                 continue
-            seen_shards.add(sid)
-            node = by_rank[ownership.primary(sid)]
-            slots = np.arange(len(key), dtype=np.int32)
-            node.insert(key, slots)
-            node.match_prefix(key)
-        for n in ring:
-            n.broadcast_shard_summary()
-        wait_for(
-            lambda: router_mesh.fleet.shard_heat()["reporters"]
-            >= len(ring) - 1
-        )
-        # Decode-dominant two-shape burst: neither shape may look like a
-        # convoy (share < threshold, similar e2e).
-        healthy_prompts = prompts_of(24, 3) + prompts_of(48, 3)
-        eng.generate([list(p) for p in healthy_prompts], healthy_sampling)
-        agg_hist.sample()
-        agg.pull_once()
-        healthy_report = doctor.diagnose()
-        healthy = {
-            "performed": True,
-            "findings": healthy_report["findings"],
-            "rules_checked": healthy_report["rules_checked"],
-            "inputs": healthy_report["inputs"],
-            "audited_requests": attr.stats()["audited"],
-            "balanced_shards": len(seen_shards),
-            "skew_score": router_mesh.shard_heat_report().get("skew_score"),
-        }
+            problems += [f"{section}.{f}" for f in fields if f not in sec]
+    cap = report.get("capacity")
+    if isinstance(cap, dict):
+        ratio = cap.get("working_set_ratio")
+        if isinstance(ratio, (int, float)) and ratio < TIER_MIN_WORKING_SET_RATIO:
+            problems.append(
+                f"capacity: working set only {ratio}x host capacity "
+                f"(gate {TIER_MIN_WORKING_SET_RATIO}x) — the claim is "
+                "'past DRAM', not 'fits in DRAM'"
+            )
+        t, b = cap.get("tier_hit_rate"), cap.get("baseline_hit_rate")
+        if (
+            isinstance(t, (int, float))
+            and isinstance(b, (int, float))
+            and not t > b
+        ):
+            problems.append(
+                f"capacity: tier hit-rate {t} does not beat the no-tier "
+                f"baseline {b}"
+            )
+    ro = report.get("restore_overlap")
+    if isinstance(ro, dict):
+        if not ro.get("parked_requests", 0):
+            problems.append(
+                "restore_overlap: zero parked disk restores — the "
+                "overlap claim never saw a disk restore"
+            )
+        steps = ro.get("decode_steps_during_restore")
+        if isinstance(steps, (int, float)) and not steps > 0:
+            problems.append(
+                "restore_overlap: decode made zero progress while disk "
+                "restores were in flight (decode-never-blocks contract, "
+                "one tier down)"
+            )
+        if ro.get("overlap_ok") is not True:
+            problems.append("restore_overlap: overlap_ok is not True")
+    cs = report.get("cold_start")
+    if isinstance(cs, dict) and cs.get("performed"):
+        if cs.get("failed", 1) != 0:
+            problems.append(
+                f"cold_start: {cs.get('failed')} request(s) failed — "
+                "the full-restart drill must lose nothing"
+            )
+        if not cs.get("interrupted", 0):
+            problems.append(
+                "cold_start: zero interrupted streams — nothing was "
+                "proven about mid-decode crash recovery"
+            )
+        if cs.get("resumed") != cs.get("interrupted"):
+            problems.append(
+                f"cold_start: resumed {cs.get('resumed')} != interrupted "
+                f"{cs.get('interrupted')}"
+            )
+        if cs.get("byte_identical") is not True:
+            problems.append(
+                "cold_start: resumed streams were NOT byte-identical to "
+                "their pre-kill expectation"
+            )
+        if not cs.get("disk_hit_tokens", 0):
+            problems.append(
+                "cold_start: zero disk-served hit tokens after restart "
+                "— recovery never actually read the durable tier"
+            )
+        if not cs.get("corrupt_detected", 0):
+            problems.append(
+                "cold_start: the seeded corrupt extent was not detected"
+            )
+        if cs.get("corrupt_served", 1) != 0:
+            problems.append(
+                f"cold_start: {cs.get('corrupt_served')} corrupt "
+                "extent(s) SERVED — the checksum gate failed"
+            )
+    cor = report.get("corruption")
+    if isinstance(cor, dict):
+        attacked = int(cor.get("extents_attacked", 0) or 0)
+        if attacked:
+            if cor.get("detected") != attacked:
+                problems.append(
+                    f"corruption: {cor.get('detected')} of {attacked} "
+                    "attacked extents detected — torn tails/bit-flips "
+                    "must never go unnoticed"
+                )
+            if cor.get("served_corrupt", 1) != 0:
+                problems.append(
+                    f"corruption: {cor.get('served_corrupt')} corrupt "
+                    "extent(s) served"
+                )
+    mc = report.get("meshcheck")
+    if isinstance(mc, dict):
+        if mc.get("clean") is not True or mc.get("findings", 1) != 0:
+            problems.append(
+                f"meshcheck: {mc.get('findings')} finding(s) on the "
+                "tier plane — the hotpath-file-io boundary must be "
+                "statically clean"
+            )
+    val = report.get("value")
+    if isinstance(cap, dict):
+        if not isinstance(val, (int, float)) or val <= 1.0:
+            problems.append(
+                f"value: hit-rate gain {val} is not > 1 (the tier did "
+                "not beat the no-tier baseline)"
+            )
+    return problems
 
-        # -- pathology (a): zipf heat storm ----------------------------
-        heat = _obs_zipf_heat_phase(
-            ring=ring,
-            router_mesh=router_mesh,
-            by_rank=by_rank,
-            rng=rng,
-            wait_for=wait_for,
-            zipf_keys=zipf_keys,
-            zipf_inserts=zipf_inserts,
-            zipf_alpha=zipf_alpha,
-            key_len=key_len,
-        )
-        # The zipf phase's reporter wait can be satisfied by the STALE
-        # healthy-phase fold (reporters is a set size, not a freshness
-        # signal) — hold the diagnosis until the storm's heat actually
-        # folded at the router, or the doctor reads last round's map.
-        wait_for(
-            lambda: router_mesh.shard_heat_report().get("skew_score", 0.0)
-            >= doctor.cfg.hot_shard_skew
-        )
-        hot_finding = finding_for(doctor.diagnose(), "hot_shard")
-        hot_expected = {
-            "shard": heat["expected_hot_shard"],
-            "owners": heat["expected_hot_owners"],
-            "min_skew": doctor.cfg.hot_shard_skew,
-        }
-        hot = {
-            "performed": True,
-            "rule": "hot_shard",
-            "detected": hot_finding is not None,
-            "score": (hot_finding or {}).get("score"),
-            "summary": (hot_finding or {}).get("summary", ""),
-            "evidence": (hot_finding or {}).get("evidence", {}),
-            "expected": hot_expected,
-            "evidence_correct": bool(
-                hot_finding is not None
-                and hot_finding["evidence"].get("shard")
-                == heat["expected_hot_shard"]
-                and sorted(hot_finding["evidence"].get("owners", []))
-                == heat["expected_hot_owners"]
-                and hot_finding["evidence"].get("skew_score", 0)
-                >= doctor.cfg.hot_shard_skew
-            ),
-        }
 
-        # -- pathology (b): convoying long-prompt burst ----------------
-        eng.generate(
-            [list(p) for p in prompts_of(short_prompt, 6)], short_sampling
-        )
-        eng.generate(
-            [list(p) for p in prompts_of(long_prompt, 3)], long_sampling
-        )
-        convoy_shape = shape_bucket(long_prompt)
-        convoy_finding = finding_for(doctor.diagnose(), "prefill_convoy")
-        convoy_expected = {
-            "shape": convoy_shape,
-            "min_share": doctor.cfg.convoy_prefill_share,
-            "requests": 3,
-        }
-        convoy = {
-            "performed": True,
-            "rule": "prefill_convoy",
-            "detected": convoy_finding is not None,
-            "score": (convoy_finding or {}).get("score"),
-            "summary": (convoy_finding or {}).get("summary", ""),
-            "evidence": (convoy_finding or {}).get("evidence", {}),
-            "expected": convoy_expected,
-            "evidence_correct": bool(
-                convoy_finding is not None
-                and convoy_finding["evidence"].get("shape") == convoy_shape
-                and convoy_finding["evidence"].get("prefill_share", 0)
-                >= doctor.cfg.convoy_prefill_share
-                and convoy_finding["evidence"].get("requests") == 3
-            ),
-        }
-
-        # -- pathology (c): throttled restore lane ---------------------
-        # Push the warm-up prefixes to the HOST tier, re-request them
-        # through the tiny-chunk plane, step JUST until they park —
-        # then diagnose with the staged backlog deliberately unpumped
-        # (the engine thread is the only pump; we hold it).
-        eng.tree.evict(10 * restore_prompt * len(restore_prompts))
-        eng.kv_transfer.wait_host_ready()
-        parked_reqs = [
-            eng.add_request(list(p), long_sampling) for p in restore_prompts
-        ]
-        for _ in range(50):
-            eng.step()
-            if len(eng._restoring) >= len(parked_reqs):
-                break
-        stall_finding = finding_for(doctor.diagnose(), "restore_park_stall")
-        stall_expected = {
-            "lane": "restore",
-            "parked": len(parked_reqs),
-        }
-        stall = {
-            "performed": True,
-            "rule": "restore_park_stall",
-            "detected": stall_finding is not None,
-            "score": (stall_finding or {}).get("score"),
-            "summary": (stall_finding or {}).get("summary", ""),
-            "evidence": (stall_finding or {}).get("evidence", {}),
-            "expected": stall_expected,
-            "evidence_correct": bool(
-                stall_finding is not None
-                and stall_finding["evidence"].get("lane") == "restore"
-                and stall_finding["evidence"].get("parked")
-                == len(parked_reqs)
-                and stall_finding["evidence"].get("restores_queued", 0) > 0
-            ),
-        }
-        # Release the lane and let the parked requests finish — the
-        # pathology is a diagnosis scenario, not a leaked stall.
-        from radixmesh_tpu.engine.request import RequestState
-
-        for _ in range(max_steps):
-            eng.step()
-            if all(
-                r.state is RequestState.FINISHED for r in parked_reqs
-            ):
-                break
-
-        stats = attr.stats()
-        attribution = {
-            "audited": stats["audited"],
-            "refused": stats["refused"],
-            "max_sum_error_s": stats["max_sum_error_s"],
-            "epsilon_s": 1e-6,
-            "sums_ok": bool(stats["max_sum_error_s"] <= 1e-6),
-            "phases": {
-                p: {"count": v["count"], "p99_s": v["p99_s"]}
-                for p, v in attr.report()["phases"].items()
-            },
-        }
-    finally:
-        set_recorder(prev_recorder)
-        if eng is not None and eng.kv_transfer is not None:
-            eng.kv_transfer.close()
-        for n in nodes:
-            n.close()
-        InprocHub.reset_default()
-
+def build_tier_report(res: dict, meshcheck: dict | None = None) -> dict:
+    """Assemble a schema-complete TIER artifact from
+    ``workload.run_tier_workload``'s result plus a meshcheck verdict."""
+    cap = res.get("capacity", {}) or {}
     return {
-        "nodes": len(prefill) + len(decode) + len(router_addrs),
-        "topology": "4 prefill + 2 decode + 1 router (inproc) + traced "
-        "CPU engine",
-        "replication_factor": replication_factor,
-        "healthy": healthy,
-        "pathologies": {
-            "hot_shard": hot,
-            "prefill_convoy": convoy,
-            "restore_park_stall": stall,
-        },
-        "attribution": attribution,
-        "wall_s": round(_time.monotonic() - t_start, 3),
+        "schema_version": TIER_SCHEMA_VERSION,
+        "metric": "tier_hit_rate_gain",
+        "value": cap.get("hit_rate_gain"),
+        "unit": (
+            "prefix-cache hit-rate with the durable disk tier / no-tier "
+            "baseline, at a working set >= 10x host capacity (> 1 = the "
+            "tier serves what DRAM alone cannot), with decode never "
+            "blocking on disk restores and a whole-cell kill-and-restart "
+            "resuming every stream byte-identical from disk alone"
+        ),
+        "workload": (
+            "zipf re-visit traffic over a working set 10x the host "
+            "arena (tier vs no-tier engines), a parked-disk-restore "
+            "decode-overlap phase, and a cold-cell drill: every volatile "
+            "tier destroyed mid-decode, one extent bit-flipped + one "
+            "truncated, the cell restarted from the extent directory "
+            "and interrupted streams resumed byte-identical "
+            "(see workload.run_tier_workload)"
+        ),
+        "capacity": cap,
+        "spill": res.get("spill", {}),
+        "restore_overlap": res.get("restore_overlap", {}),
+        "cold_start": res.get("cold_start", {}),
+        "corruption": res.get("corruption", {}),
+        "meshcheck": meshcheck
+        or {"files": [], "findings": -1, "clean": False},
+        "page_size": res.get("page_size"),
+        "wall_s": res.get("wall_s"),
     }
 
 
@@ -3758,8 +3403,8 @@ def run_blackbox_workload(
     timeout_s: float = 60.0,
     blackbox_dir: str | None = None,
 ) -> dict:
-    """The black-box acceptance scenario (PR 13; ``bench.
-    validate_blackbox`` pins its artifact): one rf=3 inproc mesh
+    """The black-box acceptance scenario (PR 13;
+    ``validate_blackbox`` below pins its report): one rf=3 inproc mesh
     (4 prefill + 2 decode + 1 router, per-node fleet digesters) plus a
     step-accounted CPU engine, with TWO telemetry histories recording —
     the ROUTER's (the observer: fleet health, shard heat, skew) and the
@@ -3785,9 +3430,13 @@ def run_blackbox_workload(
        unclean death with the truncation point within one segment of
        the kill.
 
-    The sampler's own cost is gated: both histories' self-accounted
-    sweep seconds must stay under 1% of the run's wall clock (the run
-    is a step-accounting run — the engine leg has it on)."""
+    The sampler's own cost is gated in units of its own work, each
+    against a stated bound (``BLACKBOX_MAX_*`` below): neither history
+    sweeps more often than its interval allows, sweeps more series or
+    refuses one, and no black box commits a larger segment. The
+    self-accounted sweep seconds are reported as a share of the run's
+    wall clock, ungated (the run is a step-accounting run —
+    the engine leg has it on)."""
     import os
     import shutil
     import tempfile
@@ -3945,7 +3594,7 @@ def run_blackbox_workload(
         )
         boxes.append(obs_bb)
         # Fleet-aggregation seam (PR 17): the observer doubles as the
-        # aggregation host, pulling its own ring — schema v4 requires
+        # aggregation host, pulling its own ring — the gate requires
         # the fleet rules in the healthy rules_checked, and a healthy
         # fleet must keep them silent.
         agg = FleetAggregator(
@@ -3965,6 +3614,7 @@ def run_blackbox_workload(
             aggregator=agg,
         )
         obs_bb.doctor = doctor
+        obs_t0 = _time.monotonic()
         obs_hist.start()
 
         # -- phase 0: healthy ------------------------------------------
@@ -4039,6 +3689,7 @@ def run_blackbox_workload(
             segment_every=segment_every,
         )
         boxes.append(victim_bb)
+        victim_t0 = _time.monotonic()
         victim_hist.start()
         # The observer must SAMPLE the storm at its peak before the
         # kill (the rings are the post-mortem's only evidence).
@@ -4053,17 +3704,20 @@ def run_blackbox_workload(
         wait_for(lambda: victim_bb.stats()["segments"] >= 1)
 
         # -- phase b: kill the hot owner mid-storm ---------------------
+        # To its peers a node dies when it is last heard: t_kill is
+        # stamped the moment the victim's digester has stopped (close
+        # joins it, so a publish racing the close lands before), and
+        # the rest of the teardown, however long this machine takes
+        # over it, happens to a node already dead.
         for fp in fleet_planes:
             if fp.mesh is victim:
                 fp.close()
+        t_kill = _time.monotonic()
         victim_hist.close()
+        victim_t1 = _time.monotonic()
+        victim_seq_at_kill = victim_hist.stats()["seq"]
         victim_bb.close()  # NO flush: the kill -9 simulation
         victim.close()
-        # t_kill is stamped AFTER the teardown completes: a sampler
-        # tick or digest publish racing the close must land before it,
-        # or the truncation/crash-window gates flake on an otherwise
-        # correct run (last committed sample > t_kill).
-        t_kill = _time.monotonic()
         detected = wait_for(
             lambda: any(
                 p[2] < 0.5
@@ -4084,6 +3738,7 @@ def run_blackbox_workload(
         # -- flush the observer (the SIGTERM exit path) ----------------
         flush_info = obs_bb.flush("sigterm")
         obs_hist.close()
+        t_obs_closed = _time.monotonic()
 
         # -- phase c: post-mortem from the dumps alone -----------------
         obs_dump = load_blackbox(obs_dir)
@@ -4099,12 +3754,16 @@ def run_blackbox_workload(
         window_contains_kill = bool(
             window is not None and window[0] - 0.05 <= t_kill <= window[1]
         )
-        trunc_slack = 2.0 * segment_every * history_interval_s + 0.5
-        trunc_last_t = (victim_dump.get("last_t") or 0.0)
+        # The victim's dump must end within two segments of the last
+        # sample its sampler took, counted in samples: a segment commits
+        # every ``segment_every`` of them, whatever their spacing was.
+        trunc_slack = 2 * segment_every
+        trunc_last_seq = victim_dump.get("last_seq")
         truncation_within = bool(
             victim_dump["unclean"]
             and trunc_f is not None
-            and 0.0 <= t_kill - trunc_last_t <= trunc_slack
+            and trunc_last_seq is not None
+            and 0 <= victim_seq_at_kill - trunc_last_seq <= trunc_slack
         )
         postmortem = {
             "observer": {
@@ -4123,8 +3782,9 @@ def run_blackbox_workload(
                 "findings": victim_pm["findings"],
                 "unclean": victim_pm["unclean"],
                 "segments": victim_dump["segments"],
-                "last_t": round(trunc_last_t, 3),
-                "truncation_slack_s": round(trunc_slack, 3),
+                "last_seq": trunc_last_seq,
+                "seq_at_kill": victim_seq_at_kill,
+                "truncation_slack_samples": trunc_slack,
                 "truncation_named": truncation_within,
                 "truncation_evidence": (trunc_f or {}).get("evidence", {}),
             },
@@ -4136,10 +3796,30 @@ def run_blackbox_workload(
         }
 
         wall_s = _time.monotonic() - t_start
-        sampler_cost = sum(
-            h.stats()["sample_seconds_total"] for h in histories
+        hist_stats = [h.stats() for h in histories]
+        sampler_cost = sum(st["sample_seconds_total"] for st in hist_stats)
+        # What the samplers and their black boxes cost is gated in units
+        # of their own work, each against its stated bound — a share of
+        # a shared CPU's wall clock measures the neighbours: sweeps (no
+        # sooner than the interval allows over the time each sampler
+        # ran), series swept a sweep, bytes committed a segment, and no
+        # series refused. The measured share is reported beside them,
+        # ungated.
+        samples = sum(st["seq"] + 1 for st in hist_stats)
+        max_samples = sum(
+            int((t1 - t0) / history_interval_s) + 1
+            for t0, t1 in ((obs_t0, t_obs_closed), (victim_t0, victim_t1))
         )
-        obs_stats = obs_hist.stats()
+        series_per_sweep = max(st["series"] for st in hist_stats)
+        segment_bytes = [
+            os.path.getsize(os.path.join(root, name))
+            for d in (obs_dir, victim_dir)
+            for root, _, names in os.walk(d)
+            for name in names
+            if name.startswith("segment-")
+        ]
+        dropped = sum(st["dropped_series"] for st in hist_stats)
+        obs_stats = hist_stats[0]  # the observer's: appended first
         history = {
             "interval_s": history_interval_s,
             "capacity": history_capacity,
@@ -4152,10 +3832,16 @@ def run_blackbox_workload(
                 "sample_seconds_total": round(sampler_cost, 6),
                 "wall_s": round(wall_s, 3),
                 "fraction": round(sampler_cost / max(1e-9, wall_s), 6),
-                "budget_fraction": 0.01,
-                "under_budget": bool(sampler_cost / max(1e-9, wall_s) < 0.01),
+                "samples": samples,
+                "max_samples": max_samples,
+                "series_per_sweep": series_per_sweep,
+                "segments": len(segment_bytes),
+                "segment_bytes_max": max(segment_bytes, default=0),
+                "dropped_series": dropped,
             },
         }
+        overhead = history["self_overhead"]
+        overhead["under_budget"] = not _blackbox_over_budget(overhead)
         blackbox = {
             "schema_version": obs_dump["schema_version"],
             "observer": {
@@ -4206,921 +3892,214 @@ def run_blackbox_workload(
     }
 
 
-class _FixedDecodeTelemetry:
-    """Engine stand-in for straggler seeding: reports a constant decode
-    step-time EWMA through the fleet-digest seam
-    (``obs/fleet_plane.py::FleetPlane.build_digest`` reads exactly
-    ``telemetry()["decode_ewma_s"]``) — the AGG workload pins one decode
-    node's signal high and its sibling's low without needing a real
-    engine to actually be slow."""
+# ----------------------------------------------------------------------
+# BLACKBOX stable schema (PR 13): the flight-recorder acceptance
+# artifact. A node killed mid-zipf-storm must yield black-box dumps
+# (obs/blackbox.py) from which the post-mortem doctor
+# (obs/doctor.py::postmortem_report) names the seeded hot shard and the
+# crash window FROM THE DUMPS ALONE, the live history-backed doctor must
+# stay silent on the healthy phase, and the telemetry sampler must
+# stay inside its budget of sweeps, series and segment bytes.
+# ----------------------------------------------------------------------
 
-    def __init__(self, decode_ewma_s: float):
-        self._ewma = float(decode_ewma_s)
+# v2 (PR 14): the healthy-phase rules_checked gate grew the
+# rebalancer_asleep rule; v3 (PR 15): tier_thrash; v4 (PR 17): the
+# three fleet rules (the workload arms an in-proc FleetAggregator for
+# its healthy phase); v5 (PR 18): the three token-plane rules
+# (decode_stall, spec_misconfigured, goodput_regression).
+BLACKBOX_SCHEMA_VERSION = 5
 
-    def telemetry(self) -> dict:
-        return {"decode_ewma_s": self._ewma}
+BLACKBOX_TOP_FIELDS = (
+    "schema_version", "metric", "value", "unit", "workload", "nodes",
+    "topology", "replication_factor", "healthy", "storm", "crash",
+    "postmortem", "history", "blackbox", "wall_s",
+)
+BLACKBOX_HEALTHY_FIELDS = (
+    "performed", "findings", "rules_checked", "inputs", "history_samples",
+)
+BLACKBOX_CRASH_FIELDS = (
+    "performed", "victim_rank", "victim_is_hot_owner", "t_kill",
+    "observer_detected_live",
+)
+BLACKBOX_OVERHEAD_FIELDS = (
+    "sample_seconds_total", "wall_s", "fraction", "samples", "max_samples",
+    "series_per_sweep", "segments", "segment_bytes_max", "dropped_series",
+    "under_budget",
+)
+# The sampler's budget, in units of its own work. A sweep folds every
+# series once and a segment serializes the points ``segment_every``
+# sweeps appended, so series a sweep and bytes a segment are what a
+# dearer sampler shows first. The scenario's seven nodes, one engine and
+# one tenant make about 740 series and segments of 90-130 KB: the bounds
+# are the next power of two above each.
+BLACKBOX_MAX_SERIES_PER_SWEEP = 1024
+BLACKBOX_MAX_SEGMENT_BYTES = 256 * 1024
 
 
-def run_agg_workload(
-    seed: int = 0,
-    replication_factor: int = 3,
-    history_interval_s: float = 0.2,
-    agg_interval_s: float = 0.25,
-    digest_interval_s: float = 0.2,
-    stale_after_s: float = 0.6,
-    straggler_ewma_s: float = 0.08,
-    healthy_ewma_s: float = 0.004,
-    telemetry_gap_s: float = 1.0,
-    request_batches: int = 3,
-    batch_size: int = 8,
-    sim_peers: int = 200,
-    sim_cadence_s: float = 2.0,
-    overhead_budget: float = 0.01,
-    timeout_s: float = 60.0,
-) -> dict:
-    """The control-room acceptance scenario (PR 17; ``bench.
-    validate_agg`` pins its artifact): an inproc 4 prefill + 2 decode +
-    2 router rf=3 cell where every ring node runs a fleet digester and
-    its own telemetry history, all cursor-pulled by one router-hosted
-    :class:`~radixmesh_tpu.obs.aggregator.FleetAggregator`. Four fleet
-    verdicts must be NAMED over the merged store, never hand-waved:
+def _blackbox_over_budget(overhead: dict) -> list[str]:
+    """What of ``history.self_overhead`` is over its bound, by name: more
+    sweeps than the interval allows, a series refused, more series a
+    sweep or bytes a segment than the bounds above, or no segment
+    committed at all (nothing was measured)."""
+    over = []
+    if overhead["samples"] > overhead["max_samples"]:
+        over.append(
+            f"{overhead['samples']} sweeps of {overhead['max_samples']} "
+            "allowed"
+        )
+    if overhead["series_per_sweep"] > BLACKBOX_MAX_SERIES_PER_SWEEP:
+        over.append(
+            f"{overhead['series_per_sweep']} series a sweep of "
+            f"{BLACKBOX_MAX_SERIES_PER_SWEEP}"
+        )
+    if not overhead["segments"]:
+        over.append("no segment committed")
+    if overhead["segment_bytes_max"] > BLACKBOX_MAX_SEGMENT_BYTES:
+        over.append(
+            f"{overhead['segment_bytes_max']} bytes a segment of "
+            f"{BLACKBOX_MAX_SEGMENT_BYTES}"
+        )
+    if overhead["dropped_series"]:
+        over.append(f"{overhead['dropped_series']} series refused")
+    return over
 
-    a. **Merged percentiles.** A traced CPU-engine burst lands TTFT
-       observations; every request object is retained, so the raw
-       records ARE the ground truth. The fleet-merged p99 (bucket
-       counts summed across the reporting nodes, quantile interpolated
-       inside the merged distribution) must land within one histogram
-       bucket of the raw-record p99 — the gate average-of-percentiles
-       fails exactly when it matters.
-    b. **Straggler by rank.** One decode node's digest carries a
-       pinned-high decode EWMA (the seeded delay); the fleet doctor's
-       ``straggler_node`` rule must name that RANK from the aggregated
-       per-rank signal fold.
-    c. **Exemplar → stitched trace.** The merged-p99 bucket's exemplar
-       (collected off the slow node's registry during the pull sweep)
-       must join by trace id into a stitched trace containing the slow
-       node's span.
-    d. **Gap, not silence.** One prefill node dies (digester + sampler
-       stop); the doctor's ``telemetry_gap`` rule must surface it with
-       a dead-vs-sampler verdict from the mesh health cross-check.
 
-    Plus two budget rows: total aggregation cost under 1% of run wall
-    time, and an N=200 simulated-transport fan-in sweep completing
-    inside one pull cadence."""
-    import bisect
-    import time as _time
+# The three post-mortem verdicts the acceptance run must name from the
+# dumps alone.
+BLACKBOX_NAMED_TOTAL = 3
 
-    import jax
 
-    from radixmesh_tpu.cache.mesh_cache import MeshCache
-    from radixmesh_tpu.comm.inproc import InprocHub
-    from radixmesh_tpu.config import MeshConfig, NodeRole
-    from radixmesh_tpu.engine.engine import Engine
-    from radixmesh_tpu.engine.request import SamplingParams
-    from radixmesh_tpu.models.llama import ModelConfig, init_params
-    from radixmesh_tpu.obs.aggregator import FleetAggregator, InprocPeer
-    from radixmesh_tpu.obs.attribution import ensure_attributor
-    from radixmesh_tpu.obs.doctor import DoctorConfig, MeshDoctor
-    from radixmesh_tpu.obs.fleet_plane import FleetPlane
-    from radixmesh_tpu.obs.metrics import DEFAULT_BUCKETS, get_registry
-    from radixmesh_tpu.obs.timeseries import TelemetryHistory
-    from radixmesh_tpu.obs.trace_plane import (
-        FlightRecorder,
-        get_recorder,
-        set_recorder,
-        stitch_traces,
-    )
+def validate_blackbox(report) -> list[str]:
+    """Schema violations of a BLACKBOX artifact vs the pinned contract
+    (empty = valid). Gates: the healthy phase ran EVERY live rule and
+    found nothing; the post-mortem doctor named the seeded hot shard
+    and a crash window containing the true kill time from the
+    OBSERVER's dump, and the unclean-death truncation from the
+    VICTIM's segment-only dump; the victim's dump really is unclean
+    (segments, no final); and the sampler stayed inside its budget of
+    sweeps, series and segment bytes. Sections with performed=False are
+    schema-valid but gate-exempt (the CHAOS convention)."""
+    from radixmesh_tpu.obs.doctor import RULES
 
-    def wait_for(pred, timeout=timeout_s, interval=0.02):
-        deadline = _time.monotonic() + timeout
-        while _time.monotonic() < deadline:
-            if pred():
-                return True
-            _time.sleep(interval)
-        return pred()
-
-    def finding_for(report: dict, rule: str):
-        for f in report["findings"]:
-            if f["rule"] == rule:
-                return f
-        return None
-
-    def bucket_index(value: float) -> int:
-        # The bucket a value lands in, by the same predicate
-        # Histogram.observe uses (first bound >= value; past the last
-        # bound = the +Inf slot at len(buckets)).
-        return bisect.bisect_left(DEFAULT_BUCKETS, value)
-
-    def le_index(le: str) -> int:
-        if le == "+Inf":
-            return len(DEFAULT_BUCKETS)
-        return bisect.bisect_left(DEFAULT_BUCKETS, float(le))
-
-    rng = np.random.default_rng(seed)
-    t_start = _time.monotonic()
-    InprocHub.reset_default()
-    prev_recorder = get_recorder()
-    prefill = ["ap0", "ap1", "ap2", "ap3"]
-    decode = ["ad0", "ad1"]
-    router_addrs = ["ar0", "ar1"]
-    nodes: list = []
-    fleet_planes: list = []
-    histories: list = []
-    aggs: list = []
-    try:
-        for addr in prefill + decode + router_addrs:
-            cfg = MeshConfig(
-                prefill_nodes=prefill,
-                decode_nodes=decode,
-                router_nodes=router_addrs,
-                local_addr=addr,
-                protocol="inproc",
-                tick_interval_s=0.1,
-                gc_interval_s=60.0,
-                failure_timeout_s=60.0,
-                replication_factor=replication_factor,
-                shard_summary_interval_s=0.2,
+    if not isinstance(report, dict):
+        return ["artifact is not a JSON object"]
+    problems = [f for f in BLACKBOX_TOP_FIELDS if f not in report]
+    healthy = report.get("healthy")
+    if isinstance(healthy, dict) and healthy.get("performed"):
+        problems += [
+            f"healthy.{f}" for f in BLACKBOX_HEALTHY_FIELDS if f not in healthy
+        ]
+        if healthy.get("findings") != []:
+            problems.append(
+                "healthy: the live doctor reported findings on the "
+                f"healthy phase ({healthy.get('findings')})"
             )
-            nodes.append(MeshCache(cfg, pool=None).start())
-        for n in nodes:
-            if not n.wait_ready(timeout=timeout_s):
-                raise RuntimeError(f"node {n.rank} never passed the barrier")
-        ring = [n for n in nodes if n.role is not NodeRole.ROUTER]
-        routers = [n for n in nodes if n.role is NodeRole.ROUTER]
-        router_mesh = routers[0]
-        # Fast staleness verdicts on the aggregation host: the
-        # telemetry_gap rule's dead-vs-sampler cross-check reads this
-        # mesh's health view, which must see a killed node's digest go
-        # stale within a second, not the 15 s default.
-        for r in routers:
-            r.fleet.cfg.stale_after_s = stale_after_s
-
-        def peer_name(n) -> str:
-            return f"{n.role.value}{n.rank}"
-
-        # -- per-node digesters: the straggler seed ---------------------
-        # Decode nodes publish a pinned decode EWMA through the real
-        # digest seam — one high (the straggler), one low (the healthy
-        # sibling the ratio is judged against). Prefill planes publish
-        # 0.0, which the straggler rule filters as "not a decode rank".
-        straggler_rank = None
-        for n in ring:
-            stub = None
-            if n.role is NodeRole.DECODE:
-                if straggler_rank is None:
-                    straggler_rank = n.rank
-                    stub = _FixedDecodeTelemetry(straggler_ewma_s)
-                else:
-                    stub = _FixedDecodeTelemetry(healthy_ewma_s)
-            fleet_planes.append(
-                FleetPlane(n, engine=stub, interval_s=digest_interval_s)
-                .start()
+        missing_rules = [
+            r for r in RULES
+            if r not in (healthy.get("rules_checked") or [])
+        ]
+        if missing_rules:
+            problems.append(
+                f"healthy: rules {missing_rules} never ran — 'no "
+                "findings' is only evidence when every rule looked"
             )
-        straggler_name = f"decode{straggler_rank}"
-
-        # -- the traced engine (runs ON the straggler node) -------------
-        # Everything is traced from the first request: the compile-heavy
-        # first batch IS the p99 tail, and the exemplar gate needs the
-        # p99-bucket observation to carry a trace id.
-        rec = FlightRecorder(
-            capacity=1 << 15, sample=1.0, node=straggler_name
-        )
-        set_recorder(rec)
-        mcfg = ModelConfig(
-            vocab_size=256, hidden=64, n_layers=2, n_heads=2, n_kv_heads=2,
-            head_dim=32, intermediate=128, max_seq_len=1024,
-        )
-        eng = Engine(
-            mcfg,
-            init_params(mcfg, jax.random.PRNGKey(seed)),
-            num_slots=2048,
-            page_size=4,
-            max_batch=8,
-            name=straggler_name,
-        )
-        sampling = SamplingParams(temperature=0.0, max_new_tokens=8)
-
-        def run_batch(n_tokens: int, count: int) -> list:
-            reqs = [
-                eng.add_request(
-                    list(rng.integers(1, mcfg.vocab_size - 1, size=n_tokens)),
-                    sampling,
-                )
-                for _ in range(count)
-            ]
-            while eng.has_work():
-                eng.step()
-            return reqs
-
-        # Raw records: EVERY request object is retained — their
-        # first-token stamps are the ground truth the merged quantile
-        # is judged against, so nothing (warm compiles included) may
-        # observe into the histogram without also landing here.
-        all_reqs = run_batch(24, 4) + run_batch(48, 2)
-        for _ in range(request_batches):
-            all_reqs += run_batch(int(rng.integers(16, 49)), batch_size)
-
-        # -- per-node histories + the router-hosted aggregator ----------
-        for n in ring:
-            h = TelemetryHistory(
-                interval_s=history_interval_s,
-                mesh=n,
-                node=peer_name(n),
+        if not healthy.get("history_samples", 0):
+            problems.append(
+                "healthy: zero history samples — the rings never saw "
+                "the healthy phase"
             )
-            histories.append(h)
-            h.start()
-        # Only the straggler's peer carries a registry: each real node
-        # would serve its own process registry; in this one-process cell
-        # the engine ran on the straggler, so its peer is the one whose
-        # exemplar fetch may claim the traced observations.
-        agg = FleetAggregator(
-            peers=[
-                InprocPeer(
-                    peer_name(n),
-                    h,
-                    registry=(
-                        get_registry() if n.rank == straggler_rank else None
-                    ),
-                    rank=n.rank,
-                )
-                for n, h in zip(ring, histories)
-            ],
-            interval_s=agg_interval_s,
-            node=f"router{router_mesh.rank}",
-        )
-        aggs.append(agg)
-        doctor = MeshDoctor(
-            mesh=router_mesh,
-            attributor=ensure_attributor,
-            aggregator=agg,
-            cfg=DoctorConfig(telemetry_gap_s=telemetry_gap_s),
-        )
-
-        # -- verdict a: merged p99 vs raw-record truth ------------------
-        ttfts = sorted(
-            r.first_token_time - r.submit_time
-            for r in all_reqs
-            if r.first_token_time and r.submit_time
-        )
-        # Every reporting node must have sampled the burst's final
-        # counts before the pull that feeds the merge.
-        ttft_total = len(ttfts)
-        wait_for(
-            lambda: all(h.stats()["seq"] >= 1 for h in histories)
-        )
-        _time.sleep(history_interval_s + 0.05)
-        agg.pull_once()
-        fleet = agg.fleet_slo()
-        tenants = fleet["tenants"]
-        tenant = "default" if "default" in tenants else next(iter(tenants))
-        tb = tenants[tenant]["ttft"]
-        truth_p99 = float(np.quantile(np.asarray(ttfts), 0.99))
-        fleet_le = tb.get("p99_bucket")
-        idx_truth = bucket_index(truth_p99)
-        idx_fleet = le_index(fleet_le) if fleet_le else -99
-        bucket_lo = (
-            DEFAULT_BUCKETS[idx_fleet - 1]
-            if 0 < idx_fleet <= len(DEFAULT_BUCKETS)
-            else 0.0
-        )
-        bucket_hi = (
-            DEFAULT_BUCKETS[idx_fleet]
-            if 0 <= idx_fleet < len(DEFAULT_BUCKETS)
-            else None
-        )
-        percentiles = {
-            "performed": True,
-            "tenant": tenant,
-            "fleet_p99_s": tb.get("p99"),
-            "truth_p99_s": round(truth_p99, 6),
-            "bucket_lo_s": bucket_lo,
-            "bucket_hi_s": bucket_hi,
-            "within_one_bucket": bool(abs(idx_fleet - idx_truth) <= 1),
-            "count": tb.get("count", 0),
-            "nodes": tb.get("nodes", []),
-            "raw_requests": ttft_total,
-        }
-
-        # -- verdict b: straggler named by rank -------------------------
-        # The seeded EWMA must cross gossip → per-node derived series →
-        # pull → per-rank fold before the rule can see both decode
-        # ranks.
-        def decode_ranks_folded() -> bool:
-            agg.pull_once()
-            vals = agg.rank_signal("fleet:decode_ewma_seconds")
-            return (
-                vals.get(str(straggler_rank), 0.0) > 0.0
-                and sum(1 for v in vals.values() if v > 0.0) >= 2
+    crash = report.get("crash")
+    if isinstance(crash, dict) and crash.get("performed"):
+        problems += [
+            f"crash.{f}" for f in BLACKBOX_CRASH_FIELDS if f not in crash
+        ]
+        if crash.get("victim_is_hot_owner") is not True:
+            problems.append(
+                "crash: the killed node was not an owner of the hot "
+                "shard — the scenario must kill where the storm lives"
             )
-
-        wait_for(decode_ranks_folded)
-        strag_report = doctor.diagnose()
-        strag_f = finding_for(strag_report, "straggler_node")
-        strag_ev = (strag_f or {}).get("evidence", {})
-        straggler = {
-            "performed": True,
-            "seeded_rank": straggler_rank,
-            "named_rank": strag_ev.get("rank"),
-            "detected": strag_f is not None,
-            "ratio": strag_ev.get("ratio"),
-            "signal": strag_ev.get("signal"),
-        }
-
-        # -- verdict c: p99 exemplar → stitched trace -------------------
-        ex = tb.get("p99_exemplar") or {}
-        stitched_doc = stitch_traces([rec.export_spans()])
-        node_of_pid = {
-            e.get("pid"): e.get("args", {}).get("name")
-            for e in stitched_doc["traceEvents"]
-            if e.get("ph") == "M" and e.get("name") == "process_name"
-        }
-        tid = ex.get("trace_id")
-        hit_nodes = {
-            node_of_pid.get(e.get("pid"))
-            for e in stitched_doc["traceEvents"]
-            if tid and e.get("args", {}).get("trace_id") == tid
-        }
-        exemplar = {
-            "performed": True,
-            "trace_id": tid,
-            "node": ex.get("node"),
-            "le": ex.get("le"),
-            "stitched": bool(tid and hit_nodes),
-            "has_straggler_span": straggler_name in hit_nodes,
-        }
-
-        # -- verdict d: killed node surfaces as telemetry_gap -----------
-        victim = ring[0]
-        victim_name = peer_name(victim)
-        for fp in fleet_planes:
-            if fp.mesh is victim:
-                fp.close()
-        histories[0].close()
-
-        gap_f = None
-
-        def gap_named() -> bool:
-            nonlocal gap_f
-            agg.pull_once()
-            rep = doctor.diagnose()
-            f = finding_for(rep, "telemetry_gap")
-            if f is not None and f["evidence"].get("peer") == victim_name:
-                gap_f = f
-                return True
-            return False
-
-        wait_for(gap_named, interval=0.1)
-        gap_ev = (gap_f or {}).get("evidence", {})
-        gap = {
-            "performed": True,
-            "killed_peer": victim_name,
-            "detected": gap_f is not None,
-            "verdict": gap_ev.get("verdict"),
-            "stalled_s": gap_ev.get("stalled_s"),
-        }
-
-        # -- fan-in row: N=200 simulated peers, one sweep ---------------
-        # Each simulated peer is a real TelemetryHistory fed through the
-        # real ingest path (no sampler thread, no sockets): the sweep
-        # exercises the true query/fold pipeline at ringscale N without
-        # 200 registry snapshots.
-        sim_histories = []
-        t_sim = _time.monotonic()
-        for i in range(sim_peers):
-            h = TelemetryHistory(
-                interval_s=0.5, capacity=16, node=f"sim{i:03d}",
-                max_series=64,
+        if crash.get("observer_detected_live") is not True:
+            problems.append(
+                "crash: the observer's rings never recorded the "
+                "victim's health collapse"
             )
-            for k in range(2):
-                h.ingest(f"sim{i:03d}", {
-                    "seq": k,
-                    "interval_s": 0.5,
-                    "wall_offset": h.wall_offset,
-                    "series": {
-                        "engine:decode_steps": {
-                            "points": [[k, t_sim + 0.5 * k, float(7 * k + i)]],
-                        },
-                        f'fleet:health_score{{rank="{i}"}}': {
-                            "points": [[k, t_sim + 0.5 * k, 1.0]],
-                        },
-                        "slo:queue_depth": {
-                            "points": [[k, t_sim + 0.5 * k, float(i % 5)]],
-                        },
-                    },
-                })
-            sim_histories.append(h)
-        fan_agg = FleetAggregator(
-            peers=[
-                InprocPeer(f"sim{i:03d}", h)
-                for i, h in enumerate(sim_histories)
-            ],
-            interval_s=sim_cadence_s,
-            capacity=64,
-            node="fan-in",
-            max_series=32768,
-        )
-        aggs.append(fan_agg)
-        sweep = fan_agg.pull_once()
-        fan_in = {
-            "performed": True,
-            "peers": sweep["peers"],
-            "sweep_s": round(sweep["duration_s"], 6),
-            "cadence_s": sim_cadence_s,
-            "within_cadence": bool(sweep["duration_s"] < sim_cadence_s),
-            "points": sweep["points"],
-            "errors": sweep["errors"],
-        }
-
-        # -- overhead row -----------------------------------------------
-        wall_s = _time.monotonic() - t_start
-        pull_cost = sum(a.stats()["pull_seconds_total"] for a in aggs)
-        overhead = {
-            "pull_seconds_total": round(pull_cost, 6),
-            "wall_s": round(wall_s, 3),
-            "fraction": round(pull_cost / max(1e-9, wall_s), 6),
-            "budget_fraction": overhead_budget,
-            "under_budget": bool(
-                pull_cost / max(1e-9, wall_s) < overhead_budget
-            ),
-        }
-    finally:
-        set_recorder(prev_recorder)
-        for a in aggs:
-            a.close()
-        for h in histories:
-            h.close()
-        for fp in fleet_planes:
-            fp.close()
-        for n in nodes:
-            n.close()
-        InprocHub.reset_default()
-
-    named = sum([
-        percentiles["within_one_bucket"],
-        bool(
-            straggler["detected"]
-            and str(straggler["named_rank"]) == str(straggler["seeded_rank"])
-        ),
-        bool(exemplar["stitched"] and exemplar["has_straggler_span"]),
-        bool(gap["detected"] and gap["verdict"] in (
-            "node_dead", "sampler_dead",
-        )),
-    ])
-    return {
-        "nodes": len(prefill) + len(decode) + len(router_addrs),
-        "topology": "4 prefill + 2 decode + 2 routers (inproc, per-node "
-        "fleet digesters + telemetry histories, router-hosted "
-        "aggregator) + traced CPU engine on the slow decode node",
-        "replication_factor": replication_factor,
-        "named": named,
-        "percentiles": percentiles,
-        "straggler": straggler,
-        "exemplar": exemplar,
-        "gap": gap,
-        "overhead": overhead,
-        "fan_in": fan_in,
-        "wall_s": round(_time.monotonic() - t_start, 3),
-    }
-
-
-def run_spec_workload(
-    seed: int = 0,
-    gamma: int = 4,
-    stall_sleep_s: float = 0.5,
-    stall_threshold_s: float = 0.2,
-    overhead_tokens: int = 1000,
-    overhead_budget: float = 0.01,
-    adaptive_ratio_floor: float = 0.85,
-) -> dict:
-    """The SPEC acceptance workload (PR 18, the speedometer): one CPU
-    cell proving the token-level observability plane end to end.
-
-    a. **Acceptance + conservation.** Repetitive prompts (n-gram
-       drafts land) generated once, then REPLAYED (the first pass's
-       continuations live in the radix tree, so tree-peek drafts land
-       too) — every verify path must conserve draft tokens
-       (proposed == accepted + rejected, engine counters AND ledger
-       totals), with the per-shape and per-draft-source breakdowns
-       populated.
-    b. **ITL + seeded stall.** A driver-side sleep between mid-decode
-       steps is a real scheduler-side stall; the timeline must
-       attribute at least one stall event to ``scheduler_wait`` and
-       yield per-token percentiles from >0 timed gaps.
-    c. **Adaptive-γ A-B.** Fixed-γ vs ``--spec-adaptive`` engines on
-       identical seeds and prompt schedules: the controller's
-       acceptance-weighted goodput (useful tokens per wall second) must
-       land no worse than the fixed baseline (floor loose enough that
-       CPU jitter cannot fail a neutral controller).
-    d. **Overhead.** The marginal cost of the token-append path,
-       measured directly (N appends timed against the same loop with
-       the timeline's one-branch no-op), judged against wall time at a
-       1k tok/s decode cadence — the speedometer may not slow the car.
-    """
-    import time as _time
-
-    import jax
-
-    from radixmesh_tpu.engine.engine import Engine
-    from radixmesh_tpu.engine.request import SamplingParams
-    from radixmesh_tpu.models.llama import ModelConfig, init_params
-    from radixmesh_tpu.obs.token_timeline import TokenTimeline
-
-    rng = np.random.default_rng(seed)
-    t_start = _time.monotonic()
-    mcfg = ModelConfig(
-        vocab_size=256, hidden=64, n_layers=2, n_heads=2, n_kv_heads=2,
-        head_dim=32, intermediate=128, max_seq_len=1024,
-    )
-    params = init_params(mcfg, jax.random.PRNGKey(seed))
-    sampling = SamplingParams(temperature=0.0, max_new_tokens=16)
-
-    def make_engine(adaptive: bool, capacity: int = 4096) -> Engine:
-        return Engine(
-            mcfg,
-            params,
-            num_slots=4096,
-            page_size=4,
-            max_batch=8,
-            spec_decode_tokens=gamma,
-            spec_adaptive=adaptive,
-            token_timeline_capacity=capacity,
-            token_stall_threshold_s=stall_threshold_s,
-            name="spec-eng",
-        )
-
-    def prompts_for(n_tokens: int, count: int) -> list[list[int]]:
-        # Period-4 repeating tails: the n-gram drafter finds its
-        # context match, and greedy decoding over a tiny model keeps
-        # continuations deterministic for the replay pass.
-        out = []
-        for i in range(count):
-            head = list(
-                rng.integers(1, mcfg.vocab_size - 1, size=4).astype(int)
+    pm = report.get("postmortem")
+    if isinstance(pm, dict):
+        obs = pm.get("observer", {})
+        victim = pm.get("victim", {})
+        if obs.get("hot_shard_named") is not True:
+            problems.append(
+                "postmortem: the observer dump did not name the seeded "
+                f"hot shard (evidence {obs.get('hot_shard_evidence')} vs "
+                f"expected {pm.get('expected')})"
             )
-            out.append((head * ((n_tokens // 4) + 1))[:n_tokens])
-        return out
-
-    # -- phase a: acceptance + conservation ----------------------------
-    eng = make_engine(adaptive=False)
-    schedule = prompts_for(16, 3) + prompts_for(48, 3)
-    eng.generate(schedule, sampling)
-    eng.generate(schedule, sampling)  # replay: tree-peek drafts land
-    led = eng.spec_ledger
-    totals = led.totals()
-    st = eng.stats
-    conserved = (
-        st.spec_proposed == st.spec_accepted + st.spec_rejected
-        and totals["proposed"] == totals["accepted"] + totals["rejected"]
-        and totals["proposed"] == st.spec_proposed
-    )
-    by_shape: dict = {}
-    by_source: dict = {}
-    waves = 0
-    for c in led.report().values():
-        waves += c["waves"]
-        for axis, key in ((by_shape, c["shape"]), (by_source, c["source"])):
-            cell = axis.setdefault(
-                key, {"proposed": 0, "accepted": 0, "rejected": 0}
+        if obs.get("crash_window_named") is not True:
+            problems.append(
+                "postmortem: the observer dump's crash window does not "
+                f"contain the true kill time (evidence "
+                f"{obs.get('crash_evidence')} vs expected "
+                f"{pm.get('expected')})"
             )
-            cell["proposed"] += c["proposed"]
-            cell["accepted"] += c["accepted"]
-            cell["rejected"] += c["rejected"]
-    for axis in (by_shape, by_source):
-        for cell in axis.values():
-            cell["acceptance"] = round(
-                cell["accepted"] / max(1, cell["proposed"]), 4
+        if victim.get("truncation_named") is not True:
+            problems.append(
+                "postmortem: the victim's segment-only dump did not "
+                "yield an unclean-death truncation window within one "
+                "segment of the kill"
             )
-    acceptance = {
-        "performed": True,
-        "proposed": totals["proposed"],
-        "accepted": totals["accepted"],
-        "rejected": totals["rejected"],
-        "conserved": bool(conserved),
-        "waves": waves,
-        "accepted_per_step": round(totals["accepted"] / max(1, waves), 4),
-        "by_shape": by_shape,
-        "by_source": by_source,
-    }
-
-    # -- phase b: ITL + seeded scheduler_wait stall --------------------
-    reqs = [eng.add_request(p, sampling) for p in prompts_for(16, 2)]
-    steps = 0
-    while eng.has_work() and steps < 200:
-        eng.step()
-        steps += 1
-        if steps == 3:
-            # Mid-decode driver sleep: from the stream's point of view
-            # this IS a scheduler-side stall (nothing else is parked,
-            # restoring, or mid-prefill).
-            _time.sleep(stall_sleep_s)
-    snap = eng.timeline.snapshot(limit=16)
-    itl_all = snap["itl"].get("default", {})
-    seeded_cause = "scheduler_wait"
-    itl = {
-        "performed": True,
-        "count": int(itl_all.get("count", 0)),
-        "p50_s": itl_all.get("p50_s"),
-        "p99_s": itl_all.get("p99_s"),
-        "stalls": snap["stalls"],
-        "stall_seconds": snap["stall_seconds"],
-        "seeded_cause": seeded_cause,
-        "seeded_detected": bool(snap["stalls"].get(seeded_cause, 0) >= 1),
-    }
-
-    # -- phase c: adaptive-γ A-B ---------------------------------------
-    ab = {}
-    for label, adaptive in (("fixed", False), ("adaptive", True)):
-        e = make_engine(adaptive=adaptive)
-        sched = prompts_for(16, 3) + prompts_for(48, 3)
-        e.generate(sched, sampling)  # warm pass: compiles + tree fill
-        warm_tokens = e.stats.generated_tokens
-        t0 = _time.monotonic()
-        e.generate(sched, sampling)
-        t1 = _time.monotonic()
-        tot = e.spec_ledger.totals()
-        timed_tokens = e.stats.generated_tokens - warm_tokens
-        ab[label] = {
-            "tokens": timed_tokens,
-            "wall_s": round(t1 - t0, 4),
-            "tps": round(timed_tokens / max(1e-9, t1 - t0), 2),
-            "acceptance": round(
-                tot["accepted"] / max(1, tot["proposed"]), 4
-            ),
-        }
-    ratio = ab["adaptive"]["tps"] / max(1e-9, ab["fixed"]["tps"])
-    adaptive = {
-        "performed": True,
-        "gamma_base": gamma,
-        "fixed_goodput_tps": ab["fixed"]["tps"],
-        "adaptive_goodput_tps": ab["adaptive"]["tps"],
-        "goodput_ratio": round(ratio, 4),
-        "no_worse": bool(ratio >= adaptive_ratio_floor),
-        "fixed_acceptance": ab["fixed"]["acceptance"],
-        "adaptive_acceptance": ab["adaptive"]["acceptance"],
-    }
-
-    # -- phase d: token-append overhead at a 1k tok/s cadence ----------
-    tl = TokenTimeline(
-        capacity=4096, stall_threshold_s=stall_threshold_s, node="ovh"
-    )
-    gaps = rng.uniform(0.001, 0.02, size=overhead_tokens)
-    t0 = _time.monotonic()
-    for i in range(overhead_tokens):
-        tl.note_token(i % 8, "default", float(gaps[i]), now=float(i))
-    on_s = _time.monotonic() - t0
-    none_tl = None
-    t0 = _time.monotonic()
-    for i in range(overhead_tokens):
-        # The disabled path the engine hot loop pays: one branch.
-        if none_tl is not None:
-            none_tl.note_token(i % 8, "default", float(gaps[i]))
-    off_s = _time.monotonic() - t0
-    # Marginal append cost vs the wall available at 1k tok/s (1 ms per
-    # token): the <1% budget the tentpole promises.
-    wall_at_1k = overhead_tokens * 1e-3
-    fraction = max(0.0, on_s - off_s) / wall_at_1k
-    overhead = {
-        "tokens": overhead_tokens,
-        "timeline_on_s": round(on_s, 6),
-        "timeline_off_s": round(off_s, 6),
-        "fraction": round(fraction, 6),
-        "budget_fraction": overhead_budget,
-        "under_budget": bool(fraction < overhead_budget),
-    }
-
-    return {
-        "acceptance": acceptance,
-        "itl": itl,
-        "adaptive": adaptive,
-        "overhead": overhead,
-        "requests": len(schedule) * 2 + len(reqs),
-        "wall_s": round(_time.monotonic() - t_start, 3),
-    }
-
-
-def run_convoy_workload(
-    seed: int = 0,
-    inline_budget: int = 32,
-    max_defer: int = 2,
-    reps: int = 5,
-    stall_threshold_s: float = 0.02,
-) -> dict:
-    """The CONVOY acceptance workload (PR 19, killing the prefill
-    convoy): one CPU cell proving decode-interleaved chunked prefill
-    end to end.
-
-    a. **Interleave A-B.** Two engines on IDENTICAL virtual arrival
-       schedules — ``prefill_inline_budget=0`` (legacy alternating
-       waves) vs ``>0`` (mixed waves). A carrier stream decodes; a long
-       prompt arrives; a short prompt arrives one wave later. In the
-       base arm the short prompt's TTFT eats the long prompt's whole
-       prefill wave (the convoy); in the mixed arm the long prompt
-       advances in budget-sized chunks and SPT allotment lets the short
-       prompt jump the line. Outputs must match bit-for-bit (greedy +
-       deterministic spec verify), TTFT must improve, decode ITL p99
-       and spec accepted-per-wave must not regress.
-    b. **Stall decomposition.** The token timeline's per-cause stall
-       seconds for the same two arms: ``prefill_convoy`` per request
-       must drop, and what mixing leaves behind is attributed to the
-       new ``prefill_inline`` cause instead of bleeding into
-       ``scheduler_wait``.
-    c. **Starvation proof.** 20:1 prompt-length skew with boost waves
-       enabled (``prefill_wave_tokens`` shrunk below the backlog):
-       counted in WAVES, not wall-clock, the carrier stream never goes
-       more than ``max_defer`` consecutive engine steps without a
-       token while backlog is pending.
-    """
-    import time as _time
-
-    import jax
-
-    from radixmesh_tpu.engine.engine import Engine
-    from radixmesh_tpu.engine.request import SamplingParams
-    from radixmesh_tpu.models.llama import ModelConfig, init_params
-    from radixmesh_tpu.obs.token_timeline import TokenTimeline
-
-    rng = np.random.default_rng(seed)
-    t_start = _time.monotonic()
-    mcfg = ModelConfig(
-        vocab_size=256, hidden=64, n_layers=2, n_heads=2, n_kv_heads=2,
-        head_dim=32, intermediate=128, max_seq_len=1024,
-    )
-    params = init_params(mcfg, jax.random.PRNGKey(seed))
-    samp_carrier = SamplingParams(temperature=0.0, max_new_tokens=48)
-    samp_tail = SamplingParams(temperature=0.0, max_new_tokens=8)
-
-    def prompts_for(n_tokens: int, count: int) -> list[list[int]]:
-        # Period-4 repeating tails (same recipe as run_spec_workload):
-        # n-gram drafts land, greedy keeps both arms bit-identical.
-        out = []
-        for _ in range(count):
-            head = list(
-                rng.integers(1, mcfg.vocab_size - 1, size=4).astype(int)
+        if victim.get("unclean") is not True:
+            problems.append(
+                "postmortem: the victim dump is not unclean — a final "
+                "flush survived the 'hard kill', so nothing was proven "
+                "about crash survival"
             )
-            out.append((head * ((n_tokens // 4) + 1))[:n_tokens])
-        return out
-
-    def make_engine(budget: int, **kw) -> Engine:
-        return Engine(
-            mcfg,
-            params,
-            num_slots=4096,
-            page_size=4,
-            max_batch=4,
-            spec_decode_tokens=kw.pop("spec", 2),
-            prefill_inline_budget=budget,
-            prefill_inline_max_defer=max_defer,
-            token_timeline_capacity=4096,
-            token_stall_threshold_s=stall_threshold_s,
-            name=f"convoy-{'mixed' if budget else 'base'}",
-            **kw,
-        )
-
-    # -- phases a+b: interleave A-B + stall decomposition --------------
-    # Identical virtual arrival schedule per arm: carrier decoding, the
-    # long prompt enqueued, the late short request STAMPED (submit_time
-    # starts its TTFT clock) before the wave it cannot join, enqueued
-    # right after. Iteration 0 is shape warmup (compiles), discarded.
-    schedules = []
-    for _ in range(reps + 1):
-        schedules.append(
-            (
-                prompts_for(16, 1)[0],
-                prompts_for(960, 1)[0],
-                prompts_for(16, 1)[0],
-            )
-        )
-
-    def run_arm(budget: int) -> dict:
-        eng = make_engine(budget)
-        ttfts: list[float] = []
-        outputs: list[list[list[int]]] = []
-        for it, (pc, pl, ps) in enumerate(schedules):
-            carrier = eng.add_request(pc, samp_carrier)
-            for _ in range(3):
-                eng.step()
-            long_req = eng.add_request(pl, samp_tail)
-            late = eng.make_request(ps, samp_tail)
-            eng.step()  # the convoy wave (base) / one mixed chunk
-            eng.enqueue(late)
-            steps = 0
-            while eng.has_work() and steps < 800:
-                eng.step()
-                steps += 1
-            if it == 0:
-                # Warmup done: swap in a fresh timeline so the compile
-                # spikes don't pollute the measured ITL percentiles or
-                # the stall-cause decomposition.
-                eng.timeline = TokenTimeline(
-                    capacity=4096,
-                    stall_threshold_s=stall_threshold_s,
-                    node=eng.name,
-                )
-                continue
-            ttfts.append(late.first_token_time - late.submit_time)
-            outputs.append(
-                [
-                    list(map(int, r.output_tokens))
-                    for r in (carrier, long_req, late)
-                ]
-            )
-        snap = eng.timeline.snapshot(limit=1)
-        stall_s = {
-            c: round(s, 6)
-            for c, s in eng.timeline.stall_seconds.items()
-            if s > 0
-        }
-        st = eng.stats
-        return {
-            "ttft_p50_s": float(np.median(ttfts)),
-            "itl_p99_s": snap["itl"].get("default", {}).get("p99_s"),
-            "outputs": outputs,
-            "stall_seconds": stall_s,
-            "requests": 3 * reps,
-            "spec_accepted_per_wave": round(
-                st.spec_accepted / max(1, st.decode_steps), 4
-            ),
-            "waves": (
-                eng.waves.snapshot() if eng.waves is not None else None
-            ),
-        }
-
-    base = run_arm(0)
-    mixed = run_arm(inline_budget)
-    ttft_ratio = base["ttft_p50_s"] / max(1e-9, mixed["ttft_p50_s"])
-    interleave = {
-        "performed": True,
-        "reps": reps,
-        "inline_budget": inline_budget,
-        "base_ttft_p50_s": round(base["ttft_p50_s"], 6),
-        "mixed_ttft_p50_s": round(mixed["ttft_p50_s"], 6),
-        "ttft_ratio": round(ttft_ratio, 4),
-        "base_itl_p99_s": base["itl_p99_s"],
-        "mixed_itl_p99_s": mixed["itl_p99_s"],
-        "outputs_match": bool(base["outputs"] == mixed["outputs"]),
-        "base_accepted_per_wave": base["spec_accepted_per_wave"],
-        "mixed_accepted_per_wave": mixed["spec_accepted_per_wave"],
-        "waves": mixed["waves"],
-    }
-    per_req = lambda arm: arm["stall_seconds"].get(  # noqa: E731
-        "prefill_convoy", 0.0
-    ) / max(1, arm["requests"])
-    base_cv, mixed_cv = per_req(base), per_req(mixed)
-    stalls = {
-        "performed": True,
-        "stall_threshold_s": stall_threshold_s,
-        "base_convoy_s_per_req": round(base_cv, 6),
-        "mixed_convoy_s_per_req": round(mixed_cv, 6),
-        "convoy_drop_ratio": round(min(base_cv / max(1e-9, mixed_cv), 1e6), 2),
-        "base_causes": base["stall_seconds"],
-        "mixed_causes": mixed["stall_seconds"],
-        "inline_attributed_s": mixed["stall_seconds"].get(
-            "prefill_inline", 0.0
-        ),
-    }
-
-    # -- phase c: starvation bound under 20:1 skew (virtual time) ------
-    # prefill_wave_tokens shrunk below the long prompt so boost waves
-    # actually fire; the bound is counted in engine STEPS the carrier
-    # goes without a token while inline backlog is pending — wall-clock
-    # never enters the judgment.
-    eng = make_engine(inline_budget, spec=0, prefill_wave_tokens=128)
-    carrier = eng.add_request(prompts_for(16, 1)[0], samp_carrier)
-    for _ in range(3):
-        eng.step()
-    eng.add_request(prompts_for(320, 1)[0], samp_tail)  # 20:1 skew
-    gap = max_gap = 0
-    last = len(carrier.output_tokens)
-    steps = 0
-    while eng.has_work() and steps < 800:
-        pending = bool(eng._inline)
-        eng.step()
-        steps += 1
-        n = len(carrier.output_tokens)
-        if n > last or not pending or carrier.state.name == "FINISHED":
-            gap = 0
+    hist = report.get("history")
+    if isinstance(hist, dict):
+        overhead = hist.get("self_overhead")
+        if not isinstance(overhead, dict):
+            problems.append("history.self_overhead")
         else:
-            gap += 1
-            max_gap = max(max_gap, gap)
-        last = n
-    wsnap = eng.waves.snapshot()
-    starvation = {
-        "performed": True,
-        "skew": "320:16",
-        "max_defer_bound": max_defer,
-        "max_step_gap": max_gap,
-        "max_defer_observed": wsnap["max_defer_observed"],
-        "boost_waves": wsnap["counts"]["boost"],
-        "bounded": bool(
-            max_gap <= max_defer
-            and wsnap["max_defer_observed"] <= max_defer
-        ),
-        "carrier_tokens": len(carrier.output_tokens),
-    }
+            missing = [
+                f"history.self_overhead.{f}"
+                for f in BLACKBOX_OVERHEAD_FIELDS
+                if f not in overhead
+            ]
+            problems += missing
+            over = [] if missing else _blackbox_over_budget(overhead)
+            if over or overhead.get("under_budget") is not True:
+                over.append(f"measured share {overhead.get('fraction')}")
+                problems.append(
+                    "history: sampler overhead out of budget "
+                    f"({'; '.join(over)})"
+                )
+    if isinstance(pm, dict) and report.get("value") != BLACKBOX_NAMED_TOTAL:
+        problems.append(
+            f"value: {report.get('value')} of {BLACKBOX_NAMED_TOTAL} "
+            "post-mortem verdicts named"
+        )
+    return problems
 
+
+def build_blackbox_report(res: dict) -> dict:
+    """Assemble a schema-complete BLACKBOX artifact from
+    ``workload.run_blackbox_workload``'s result."""
     return {
-        "interleave": interleave,
-        "stalls": stalls,
-        "starvation": starvation,
-        "wall_s": round(_time.monotonic() - t_start, 3),
+        "schema_version": BLACKBOX_SCHEMA_VERSION,
+        "metric": "blackbox_postmortem_named",
+        "value": res.get("named", 0),
+        "unit": (
+            f"of {BLACKBOX_NAMED_TOTAL} post-mortem verdicts (hot shard, "
+            "crash window, unclean-death truncation) named from "
+            "black-box dumps alone, with zero live findings on the "
+            "healthy phase and sampler overhead under budget"
+        ),
+        "workload": (
+            "healthy balanced phase + zipf heat storm over one rf=3 "
+            "inproc cluster with per-node fleet digesters and a "
+            "step-accounted CPU engine; the hot shard's primary owner "
+            "is killed hard mid-storm (segments survive, no final "
+            "flush) and the post-mortem doctor diagnoses from the "
+            "observer + victim dumps alone "
+            "(see workload.run_blackbox_workload)"
+        ),
+        **res,
     }

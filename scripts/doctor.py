@@ -1,6 +1,5 @@
-"""The mesh doctor's CLI: diagnose a LIVE cluster, a DEAD node's
-black-box dump, or run the seeded acceptance workload and emit the
-round's DOCTOR artifact.
+"""The mesh doctor's CLI: diagnose a LIVE cluster or a DEAD node's
+black-box dump.
 
 Live mode (default) hits a frontend's ``GET /cluster/doctor``
 (``obs/doctor.py`` runs server-side — the burn-rate windows live in the
@@ -17,17 +16,10 @@ burn rates at their in-window peaks, and the crash itself (health
 collapse windows, unclean-death truncation). Same exit codes; no
 cluster required.
 
-Workload mode (``--workload``) runs ``workload.run_doctor_workload`` —
-healthy phase + three deterministically seeded pathologies over an rf=3
-inproc cluster — folds in the benchdiff sentinel self-check, validates
-against the pinned DOCTOR schema (``bench.validate_doctor``), and
-writes ``DOCTOR_r{N}.json``.
-
 Usage::
 
     python scripts/doctor.py [--url http://HOST:PORT] [--watch SECONDS]
     python scripts/doctor.py --blackbox /var/dumps/prefill@2 [--json]
-    python scripts/doctor.py --workload [--seed 0] [--out FILE]
 """
 
 from __future__ import annotations
@@ -109,37 +101,6 @@ def _postmortem(path: str, as_json: bool) -> int:
     return 0 if report.get("healthy") else 1
 
 
-def _workload(seed: int, out: str | None) -> int:
-    import bench
-    from radixmesh_tpu.workload import run_doctor_workload
-
-    res = run_doctor_workload(seed=seed)
-    res["benchdiff"] = bench.benchdiff_selfcheck()
-    report = bench.build_doctor_report(res)
-    problems = bench.validate_doctor(report)
-    if problems:
-        report["schema_violation"] = problems
-    path = out or os.path.join(
-        _REPO_ROOT, f"DOCTOR_r{bench.current_round():02d}.json"
-    )
-    with open(path, "w") as fh:
-        json.dump(report, fh, indent=1, sort_keys=True)
-        fh.write("\n")
-    named = report["value"]
-    total = len(bench.DOCTOR_PATHOLOGIES)
-    print(json.dumps({
-        "metric": report["metric"],
-        "value": named,
-        "healthy_findings": len(report["healthy"]["findings"]),
-        "audited": report["attribution"]["audited"],
-        "max_sum_error_s": report["attribution"]["max_sum_error_s"],
-        "benchdiff": report["benchdiff"],
-        "schema_violation": problems or None,
-        "artifact": os.path.basename(path),
-    }))
-    return 0 if named == total and not problems else 1
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(prog="doctor")
     ap.add_argument(
@@ -161,21 +122,9 @@ def main() -> int:
         "--json", action="store_true",
         help="print the full post-mortem report as JSON (--blackbox mode)",
     )
-    ap.add_argument(
-        "--workload", action="store_true",
-        help="run the seeded acceptance workload and write DOCTOR_r{N}.json "
-        "instead of querying a live cluster",
-    )
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument(
-        "--out", default=None, metavar="FILE",
-        help="workload-mode artifact path (default DOCTOR_r{N}.json)",
-    )
     args = ap.parse_args()
     if args.blackbox:
         return _postmortem(args.blackbox, args.json)
-    if args.workload:
-        return _workload(args.seed, args.out)
     return _live(args.url, args.watch)
 
 

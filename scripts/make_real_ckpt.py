@@ -7,7 +7,7 @@ itself prescribes: generate an HF-format checkpoint locally with the
 installed ``transformers`` at the 1B-preset config (random weights,
 declared as such in the artifact) and a REAL byte-level-BPE tokenizer
 trained with the installed ``tokenizers`` on a locally generated corpus.
-``bench.py`` then exercises the full production seam — sharded
+``tests/test_real_ckpt.py`` then exercises the full production seam — sharded
 safetensors → ``models/hf_io.py`` → ``convert_hf_state_dict``,
 ``AutoTokenizer`` → ``server/tokenizer.py`` → text workload — with
 nothing stubbed.

@@ -2,10 +2,10 @@
 
 Runs every checker (``radixmesh_tpu/analysis/``) over the product
 package, runs the positive-control fixtures, prints findings as
-``file:line: [invariant-id] message``, and optionally writes the
-round's schema-pinned ``ANALYSIS_r{N}.json`` artifact (validated
-against ``bench.validate_analysis`` before writing — a violation is
-recorded in the artifact, not silently shipped).
+``file:line: [invariant-id] message``, and with ``--out`` writes the
+schema-pinned ANALYSIS report (validated against
+``radixmesh_tpu.analysis.report.validate_analysis`` before writing — a
+violation is recorded in the report, not silently shipped).
 
 Exit status (pinned — commit hooks branch on it):
   0 = tree clean AND all positive controls tripped
@@ -20,8 +20,7 @@ Usage::
                                                #   reverse-import deps —
                                                #   cheap enough per commit
     python scripts/meshcheck.py --json         # full report on stdout
-    python scripts/meshcheck.py --write-artifact            # ANALYSIS_r{N}.json
-    python scripts/meshcheck.py --write-artifact --out X.json
+    python scripts/meshcheck.py --out X.json   # the full report, to a file
     python scripts/meshcheck.py --no-fixtures  # skip positive controls
 
 ``--changed`` analyzes the WHOLE tree (one parse is the cheap part;
@@ -40,7 +39,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import re
 import subprocess
 import sys
 
@@ -48,7 +46,6 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
 
-import bench  # noqa: E402  (schema + validator live with the other validators)
 from radixmesh_tpu.analysis import (  # noqa: E402
     all_checkers,
     changed_scope,
@@ -60,37 +57,10 @@ from radixmesh_tpu.analysis.core import (  # noqa: E402
     package_root,
     run_checkers,
 )
-
-
-def analysis_round() -> int:
-    """The round in progress = 1 + the highest N across every OTHER
-    plane's recorded ``*_r{N}.json`` artifact (ANALYSIS rides whatever
-    round they are on — e.g. OBS_r09 makes this round 10). An existing
-    ANALYSIS artifact at/after that round is OVERWRITTEN only when it
-    already carries the current schema version (a rerun of this round);
-    an older-schema artifact is history — a schema bump starts the next
-    round instead of clobbering it."""
-    rounds = [0]
-    analysis_rounds = []
-    for name in os.listdir(_REPO_ROOT):
-        m = re.fullmatch(r"[A-Z_]+_r(\d+)\.json", name)
-        if not m:
-            continue
-        if name.startswith("ANALYSIS_"):
-            analysis_rounds.append((int(m.group(1)), name))
-        else:
-            rounds.append(int(m.group(1)))
-    base = max(rounds) + 1
-    for n, name in sorted(analysis_rounds):
-        if n < base:
-            continue
-        try:
-            with open(os.path.join(_REPO_ROOT, name)) as fh:
-                version = json.load(fh).get("schema_version", 1)
-        except (OSError, ValueError):
-            version = None
-        base = n if version == bench.ANALYSIS_SCHEMA_VERSION else n + 1
-    return base
+from radixmesh_tpu.analysis.report import (  # noqa: E402
+    build_analysis_report,
+    validate_analysis,
+)
 
 
 def git_changed_files() -> list[str] | None:
@@ -133,7 +103,7 @@ def main() -> int:
     ap.add_argument(
         "--no-fixtures", action="store_true",
         help="skip the positive-control pass (a clean verdict then "
-        "proves less; the artifact writer refuses this mode)",
+        "proves less; --out refuses this mode)",
     )
     ap.add_argument(
         "--changed", action="store_true",
@@ -142,16 +112,14 @@ def main() -> int:
     )
     ap.add_argument("--json", action="store_true", help="print the full report")
     ap.add_argument(
-        "--write-artifact", action="store_true",
-        help="write the round's ANALYSIS_r{N}.json to the repo root",
+        "--out", default=None, help="write the validated report to this path",
     )
-    ap.add_argument("--out", default=None, help="artifact path override")
     args = ap.parse_args()
 
-    if args.changed and args.write_artifact:
+    if args.changed and args.out:
         print(
-            "meshcheck: refusing --write-artifact with --changed (the "
-            "round artifact must cover the whole tree)",
+            "meshcheck: refusing --out with --changed (the written "
+            "report must cover the whole tree)",
             file=sys.stderr,
         )
         return 2
@@ -201,7 +169,7 @@ def main() -> int:
             )
             return 2
 
-    report = bench.build_analysis_report(
+    report = build_analysis_report(
         result, controls, len(index.modules), thread_map.roots
     )
     blind = [c for c in controls if not c.tripped]
@@ -230,25 +198,22 @@ def main() -> int:
             f"controls tripped{scoped}"
         )
 
-    if args.write_artifact:
+    if args.out:
         if args.no_fixtures:
             print(
-                "meshcheck: refusing --write-artifact with --no-fixtures "
+                "meshcheck: refusing --out with --no-fixtures "
                 "(the schema gates on positive controls)",
                 file=sys.stderr,
             )
             return 2
-        problems = bench.validate_analysis(report)
+        problems = validate_analysis(report)
         if problems:
             report["schema_violation"] = problems
             print(f"meshcheck: SCHEMA VIOLATION {problems}", file=sys.stderr)
-        path = args.out or os.path.join(
-            _REPO_ROOT, f"ANALYSIS_r{analysis_round():02d}.json"
-        )
-        with open(path, "w") as fh:
+        with open(args.out, "w") as fh:
             json.dump(report, fh, indent=1)
             fh.write("\n")
-        print(f"meshcheck: wrote {os.path.basename(path)}")
+        print(f"meshcheck: wrote {args.out}")
 
     return 0 if (not result.findings and not blind) else 1
 

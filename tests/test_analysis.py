@@ -20,6 +20,7 @@ from pathlib import Path
 import pytest
 
 from radixmesh_tpu.analysis import all_checkers
+from radixmesh_tpu.analysis import report as analysis_report
 from radixmesh_tpu.analysis.controls import (
     default_fixtures_root,
     run_positive_controls,
@@ -832,12 +833,37 @@ def test_meshcheck_cli_changed_refuses_artifact():
     proc = subprocess.run(
         [
             sys.executable, str(_REPO / "scripts" / "meshcheck.py"),
-            "--changed", "--write-artifact",
+            "--changed", "--out", "unwritten.json",
         ],
         capture_output=True, text=True, timeout=120,
     )
     assert proc.returncode == 2
     assert "whole tree" in proc.stderr
+
+
+def test_meshcheck_cli_out_writes_a_validated_report(tmp_path):
+    """``--out`` writes the report where it says, and what it writes
+    passes the report's own gates; without the positive controls it
+    refuses, since the gates read them."""
+    import json
+
+    out = tmp_path / "analysis.json"
+    proc = subprocess.run(
+        [sys.executable, str(_REPO / "scripts" / "meshcheck.py"),
+         "--out", str(out)],
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    written = json.loads(out.read_text())
+    assert analysis_report.validate_analysis(written) == []
+    assert "schema_violation" not in written
+    refused = subprocess.run(
+        [sys.executable, str(_REPO / "scripts" / "meshcheck.py"),
+         "--no-fixtures", "--out", str(tmp_path / "unwritten.json")],
+        capture_output=True, text=True, timeout=120,
+    )
+    assert refused.returncode == 2
+    assert not (tmp_path / "unwritten.json").exists()
 
 
 def test_meshcheck_cli_exit_one_on_findings(tmp_path):
@@ -876,7 +902,161 @@ def test_meshcheck_changed_gate_covers_this_pr(tmp_path):
     assert "0 finding(s)" in proc.stdout
     refused = subprocess.run(
         [sys.executable, str(_REPO / "scripts" / "meshcheck.py"),
-         "--changed", "--write-artifact"],
+         "--changed", "--out", str(tmp_path / "unwritten.json")],
         capture_output=True, text=True, timeout=120,
     )
     assert refused.returncode == 2
+
+
+class TestAnalysisArtifactSchema:
+    """The ANALYSIS report (meshcheck, concurrency plane included) gates
+    on ZERO unsuppressed findings over the tree, every default checker
+    present (thread-roots/guarded-by/protocol among them, each with
+    per-checker control counts), every positive-control fixture tripped,
+    a justification on every suppression, and a non-empty derived thread
+    map."""
+
+    def _report(self) -> dict:
+        checker = {
+            "id": "lock-order",
+            "description": "x",
+            "raw_findings": 0,
+            "kept_findings": 0,
+            "suppressed": 0,
+            "controls": 1,
+            "controls_tripped": 1,
+        }
+        ids = analysis_report.ANALYSIS_CHECKER_IDS
+        report = {
+            "schema_version": analysis_report.ANALYSIS_SCHEMA_VERSION,
+            "metric": "unsuppressed_findings",
+            "value": 0,
+            "package": "radixmesh_tpu",
+            "files_indexed": 80,
+            "checkers": [dict(checker, id=cid) for cid in ids],
+            "findings": [],
+            "suppressions": [
+                {
+                    "file": "workload.py", "line": 19, "scope": "file",
+                    "invariants": ["sleep-audit"],
+                    "justification": "generators pace by wall clock",
+                    "used": True,
+                },
+            ],
+            "positive_controls": [
+                {
+                    "fixture": "lock_cycle",
+                    "invariant": "lock-order-cycle",
+                    "file": "engine/engine.py", "line": 19,
+                    "tripped": True,
+                },
+            ],
+            "clean": True,
+        }
+        report["thread_roots"] = {
+            "count": 2,
+            "roots": [
+                {
+                    "name": "mesh-sender",
+                    "target": "cache/mesh_cache.py:MeshCache._sender",
+                    "file": "cache/mesh_cache.py", "line": 624,
+                    "multi": False, "kind": "spawn",
+                },
+                {
+                    "name": "wire-receive",
+                    "target": "cache/mesh_cache.py:MeshCache.oplog_received",
+                    "file": "cache/mesh_cache.py", "line": 905,
+                    "multi": True, "kind": "declared",
+                },
+            ],
+        }
+        return report
+
+    def test_valid_report_passes(self):
+        assert analysis_report.validate_analysis(self._report()) == []
+
+    def test_v2_requires_concurrency_checkers(self):
+        report = self._report()
+        report["checkers"] = [
+            c for c in report["checkers"] if c["id"] != "guarded-by"
+        ]
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "guarded-by" in problems and "checked less" in problems
+
+    def test_v2_requires_thread_map(self):
+        report = self._report()
+        del report["thread_roots"]
+        assert any(
+            "thread_roots" in p for p in analysis_report.validate_analysis(report)
+        )
+        report = self._report()
+        report["thread_roots"] = {"count": 0, "roots": []}
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "checked nothing" in problems
+
+    def test_v2_thread_map_entries_are_schema_complete(self):
+        report = self._report()
+        del report["thread_roots"]["roots"][0]["multi"]
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "mesh-sender" in problems and ".multi" in problems
+        report = self._report()
+        report["thread_roots"]["count"] = 5
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "disagrees" in problems
+
+    def test_v2_requires_control_counts(self):
+        report = self._report()
+        del report["checkers"][0]["controls_tripped"]
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "controls_tripped" in problems
+
+    def test_missing_fields_reported(self):
+        report = self._report()
+        del report["positive_controls"]
+        del report["files_indexed"]
+        missing = analysis_report.validate_analysis(report)
+        assert any("files_indexed" in p for p in missing)
+        assert any("positive_controls" in p for p in missing)
+
+    def test_non_dict_rejected(self):
+        assert analysis_report.validate_analysis([1]) == ["artifact is not a JSON object"]
+
+    def test_findings_fail_the_gate(self):
+        report = self._report()
+        report["findings"] = [
+            {"file": "cache/mesh_cache.py", "line": 7,
+             "invariant": "send-seam", "message": "raw send"},
+        ]
+        report["clean"] = False
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "unsuppressed finding" in problems
+
+    def test_clean_flag_must_agree(self):
+        report = self._report()
+        report["clean"] = False
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "clean flag disagrees" in problems
+
+    def test_untripped_control_fails(self):
+        report = self._report()
+        report["positive_controls"][0]["tripped"] = False
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "NOT tripped" in problems and "went blind" in problems
+
+    def test_empty_controls_fail(self):
+        report = self._report()
+        report["positive_controls"] = []
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "proves nothing" in problems
+
+    def test_missing_checker_fails(self):
+        report = self._report()
+        report["checkers"] = report["checkers"][1:]
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "lock-order" in problems and "checked less" in problems
+
+    def test_unjustified_suppression_fails(self):
+        report = self._report()
+        report["suppressions"][0]["justification"] = "  "
+        problems = "\n".join(analysis_report.validate_analysis(report))
+        assert "silencing" in problems

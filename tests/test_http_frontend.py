@@ -770,10 +770,10 @@ class TestDebugEndpoints:
             f.close()
 
     def test_debug_trace_drains_chrome_json(self, frontend):
-        import bench
         from radixmesh_tpu.obs.trace_plane import (
             FlightRecorder,
             set_recorder,
+            validate_trace,
         )
 
         set_recorder(FlightRecorder(capacity=4096, sample=1.0))
@@ -785,7 +785,7 @@ class TestDebugEndpoints:
         status, body = _get(f"http://127.0.0.1:{frontend.port}/debug/trace")
         assert status == 200
         obj = json.loads(body)
-        assert bench.validate_trace(obj) == []
+        assert validate_trace(obj) == []
         names = {
             ev["name"] for ev in obj["traceEvents"] if ev.get("ph") == "X"
         }
@@ -838,7 +838,6 @@ class TestDebugEndpoints:
     def test_router_debug_endpoints_concurrent(self):
         import concurrent.futures as cf
 
-        import bench
         from radixmesh_tpu.cache.mesh_cache import MeshCache
         from radixmesh_tpu.comm.inproc import InprocHub
         from radixmesh_tpu.config import MeshConfig, NodeRole
@@ -846,6 +845,7 @@ class TestDebugEndpoints:
         from radixmesh_tpu.obs.trace_plane import (
             FlightRecorder,
             set_recorder,
+            validate_trace,
         )
         from radixmesh_tpu.router.cache_aware_router import CacheAwareRouter
 
@@ -900,7 +900,7 @@ class TestDebugEndpoints:
                 assert status == 200 and table["requests"] == []
                 status, trace = dbg("/debug/trace")
                 assert status == 200
-                assert bench.validate_trace(trace) == []
+                assert validate_trace(trace) == []
                 route_spans = [
                     ev for ev in trace["traceEvents"]
                     if ev.get("ph") == "X" and ev["name"] == "route"
@@ -1009,6 +1009,7 @@ class TestStitchingSurfaces:
             FlightRecorder,
             set_recorder,
             stitch_traces,
+            validate_trace,
         )
 
         set_recorder(FlightRecorder(capacity=4096, sample=1.0, node="serve"))
@@ -1035,9 +1036,7 @@ class TestStitchingSurfaces:
         assert adopted, "no span adopted the upstream trace id"
         assert {"prefill_wave", "publish"} <= {s["name"] for s in adopted}
         # The export stitches into a valid single-process document.
-        import bench
-
-        assert bench.validate_trace(stitch_traces([export])) == []
+        assert validate_trace(stitch_traces([export])) == []
 
     def test_generate_rejects_bad_trace_id(self, frontend):
         for bad in (0, "soup"):
@@ -1249,7 +1248,6 @@ class TestTimeseriesAndBlackboxEndpoints:
             InprocHub.reset_default()
 
     def test_router_frontend_serves_timeseries(self):
-        import bench  # noqa: F401 — repo-root import convention
         from radixmesh_tpu.cache.mesh_cache import MeshCache
         from radixmesh_tpu.comm.inproc import InprocHub
         from radixmesh_tpu.config import MeshConfig

@@ -632,10 +632,12 @@ class TestTierThrashRule:
 class TestTierWorkloadAcceptance:
     def test_run_tier_workload_gates_green(self):
         """One reduced-size live run of the whole acceptance workload:
-        every validate_tier gate must hold on fresh data, not just on
-        the checked-in artifact."""
-        import bench
-        from radixmesh_tpu.workload import run_tier_workload
+        every validate_tier gate must hold on fresh data."""
+        from radixmesh_tpu.workload import (
+            build_tier_report,
+            run_tier_workload,
+            validate_tier,
+        )
 
         # Spills stage THROUGH the host arena, so one prefix must fit
         # it (prefix_tokens < host_slots) while the whole set exceeds
@@ -656,10 +658,10 @@ class TestTierWorkloadAcceptance:
         assert cs["byte_identical"] is True
         assert cs["disk_hit_tokens"] > 0
         assert cs["corrupt_detected"] >= 2 and cs["corrupt_served"] == 0
-        report = bench.build_tier_report(
+        report = build_tier_report(
             res, meshcheck={"files": [], "findings": 0, "clean": True}
         )
-        assert bench.validate_tier(report) == []
+        assert validate_tier(report) == []
 
 
 # ---------------------------------------------------------------------------

@@ -10,10 +10,14 @@ compile here.)"""
 
 import json
 
-import pytest
-
-import bench
-from radixmesh_tpu.workload import run_obs_workload
+from radixmesh_tpu.obs.trace_plane import validate_trace
+from radixmesh_tpu.workload import (
+    OBS_MIN_NODE_TRACKS,
+    OBS_MIN_SKEW_SCORE,
+    build_obs_report,
+    run_obs_workload,
+    validate_obs,
+)
 
 
 class TestObsScenario:
@@ -27,20 +31,20 @@ class TestObsScenario:
             stitched_trace_path=trace_path,
             timeout_s=45.0,
         )
-        report = bench.build_obs_report(res)
+        report = build_obs_report(res)
         # Gates (validate_obs enforces them too; asserted directly so a
         # failure names the exact leg). steps is gate-exempt here
         # (performed=False — covered by test_trace_plane's engine test).
-        assert bench.validate_obs(report) == []
+        assert validate_obs(report) == []
         stitch = res["stitch"]
         assert stitch["failed"] == 0
         assert stitch["interrupted"] > 0
         assert stitch["resumed"] == stitch["interrupted"]
-        assert stitch["node_tracks"] >= bench.OBS_MIN_NODE_TRACKS
+        assert stitch["node_tracks"] >= OBS_MIN_NODE_TRACKS
         assert stitch["replication_edges"] > 0
         assert stitch["publish_edges"] > 0
         heat = res["heat"]
-        assert heat["skew_score"] >= bench.OBS_MIN_SKEW_SCORE
+        assert heat["skew_score"] >= OBS_MIN_SKEW_SCORE
         assert heat["hot_shard"] == heat["expected_hot_shard"]
         assert heat["owner_set_correct"]
         wire = res["wire"]
@@ -53,7 +57,7 @@ class TestObsScenario:
         # through the interrupted request's events.
         with open(trace_path) as fh:
             doc = json.load(fh)
-        assert bench.validate_trace(doc) == []
+        assert validate_trace(doc) == []
         assert doc["otherData"]["stitched"] is True
         procs = {
             ev["args"]["name"]
@@ -68,24 +72,4 @@ class TestObsScenario:
             if ev.get("ph") == "X"
             and (ev.get("args") or {}).get("trace_id") == tid
         }
-        assert len(pids_under_tid) >= bench.OBS_MIN_NODE_TRACKS
-
-    @pytest.mark.quick
-    def test_emitter_report_shape(self):
-        """scripts/obsbench.py assembles through the same builder the
-        schema tests pin — import seam only (the full run is the
-        unmarked test above + the checked-in artifact)."""
-        import importlib.util
-        import os
-
-        spec = importlib.util.spec_from_file_location(
-            "obsbench",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                "scripts",
-                "obsbench.py",
-            ),
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        assert callable(mod.run) and callable(mod.main)
+        assert len(pids_under_tid) >= OBS_MIN_NODE_TRACKS

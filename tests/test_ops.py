@@ -1,5 +1,5 @@
 """Numerics tests for the TPU compute ops (CPU jax; the Pallas kernel runs
-in interpreter mode here and compiled on real TPU via bench.py)."""
+in interpreter mode here and compiled on real TPU via chip_smoke.py)."""
 
 import jax
 import jax.numpy as jnp

@@ -11,8 +11,7 @@ for ``radixmesh_tpu/slo/``:
 
 All scenarios run the controller against a virtual clock and a
 deterministic fixed-rate server model (capacity C prompt-tokens/s), so
-every number here is exactly reproducible — the wall-clock analog runs in
-``bench.py``'s overload sweep (``SLO_r{N}.json``)."""
+every number here is exactly reproducible."""
 
 import numpy as np
 import pytest

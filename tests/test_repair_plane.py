@@ -498,10 +498,12 @@ class TestChaosAcceptance:
         (bootstrap within budget, router withholds hits until
         convergence) — then the PR 7 crash phase: an unclean decode-node
         kill mid-stream (zero lost requests, byte-identical resumes,
-        resurrection ≥ 0.8 cache hit, budget-bounded recovery hops).
-        The full 10 s version is scripts/chaosbench.py."""
-        import bench
-        from radixmesh_tpu.workload import run_chaos_workload
+        resurrection ≥ 0.8 cache hit, budget-bounded recovery hops)."""
+        from radixmesh_tpu.workload import (
+            build_chaos_report,
+            run_chaos_workload,
+            validate_chaos,
+        )
 
         # 60 requests paced through a 1.5 s fault window put ~200
         # seeded-droppable data frames on the wire — enough that the
@@ -519,8 +521,8 @@ class TestChaosAcceptance:
             crash_streams=8,
             crash_tokens=16,
         )
-        report = bench.build_chaos_report(res)
-        assert bench.validate_chaos(report) == []
+        report = build_chaos_report(res)
+        assert validate_chaos(report) == []
         assert res["divergence"]["detected"]
         assert res["repair"]["converged"]
         assert res["repair"]["within_round_budget"]

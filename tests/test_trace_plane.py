@@ -1,7 +1,7 @@
 """Request-flight tracing plane (``obs/trace_plane.py``): flight-recorder
 semantics (bounded, drop-oldest, one-branch no-op when off), the span
 instrumentation threaded through the serving stack, and the Chrome
-trace-event artifact contract (``bench.validate_trace``)."""
+trace-event artifact contract (``validate_trace``)."""
 
 import json
 import time
@@ -9,13 +9,13 @@ import time
 import jax
 import pytest
 
-import bench
 from radixmesh_tpu.engine.engine import Engine
 from radixmesh_tpu.models.llama import ModelConfig, init_params
 from radixmesh_tpu.obs.trace_plane import (
     FlightRecorder,
     get_recorder,
     set_recorder,
+    validate_trace,
     write_trace,
 )
 from radixmesh_tpu.workload import MultiTurnWorkload, run_engine_workload
@@ -76,9 +76,9 @@ class TestFlightRecorder:
         for i in range(10):
             rec.event(f"lane{i % 3}", "e", float(10 - i), 0.25, k=i)
         obj = rec.chrome_trace()
-        assert bench.validate_trace(obj) == []
+        assert validate_trace(obj) == []
         # Round-trips through JSON (the /debug/trace body).
-        assert bench.validate_trace(json.loads(json.dumps(obj))) == []
+        assert validate_trace(json.loads(json.dumps(obj))) == []
         names = {
             ev["args"]["name"]
             for ev in obj["traceEvents"]
@@ -177,7 +177,7 @@ class TestEngineWorkloadTrace:
 
         with open(path) as fh:
             obj = json.load(fh)
-        assert bench.validate_trace(obj) == []
+        assert validate_trace(obj) == []
         by_trace: dict[int, set] = {}
         lag_spans = []
         for ev in obj["traceEvents"]:
@@ -209,7 +209,7 @@ class TestEngineWorkloadTrace:
         assert report["trace_artifact"] == path
         assert report["trace_spans"] > 0
         with open(path) as fh:
-            assert bench.validate_trace(json.load(fh)) == []
+            assert validate_trace(json.load(fh)) == []
 
 
 class TestSLOQueueSpan:
@@ -375,7 +375,7 @@ class TestCrossNodeStitching:
         ea, eb = a.export_spans(), b.export_spans()
         eb["wall_offset"] += 3.0  # a second process's clock base
         doc = stitch_traces([ea, eb], clock_offsets={"decode@1": 3.0})
-        assert bench.validate_trace(doc) == []
+        assert validate_trace(doc) == []
         procs = {
             ev["args"]["name"]
             for ev in doc["traceEvents"]
@@ -573,3 +573,52 @@ class TestStepAccounting:
         # The step_wave spans landed on the engine's step lane.
         lanes = {s.lane for s in get_recorder().snapshot()}
         assert "step:steps-on" in lanes
+
+
+class TestTraceArtifactSchema:
+    """The flight-recorder trace artifact (workload
+    trace_path emissions) stays machine-loadable: valid JSON object, a
+    traceEvents list, numeric ts/dur, monotonic ts within each lane."""
+
+    def _trace(self) -> dict:
+        from radixmesh_tpu.obs.trace_plane import FlightRecorder
+
+        rec = FlightRecorder(capacity=256, sample=1.0)
+        ctx = rec.trace("req:1")
+        ctx.add("admission_wait", 1.0, 0.01)
+        ctx.add("prefill_wave", 1.01, 0.2, wave_rows=2)
+        ctx.add("decode_chunk", 1.21, 0.05, k_steps=8)
+        ctx.add("publish", 1.26, 0.002)
+        rec.event("ring:decode@1", "replication_lag", 1.27, 0.003,
+                  origin_rank=0)
+        return json.loads(json.dumps(rec.chrome_trace()))
+
+    def test_recorder_export_validates(self):
+        assert validate_trace(self._trace()) == []
+
+    def test_violations_are_named(self):
+        obj = self._trace()
+        xs = [e for e in obj["traceEvents"] if e["ph"] == "X"]
+        xs[0]["ts"] = -5.0           # negative timestamp
+        xs[1]["dur"] = "fast"        # non-numeric duration
+        del xs[2]["tid"]             # no lane
+        problems = "\n".join(validate_trace(obj))
+        assert "ts invalid" in problems
+        assert "dur invalid" in problems
+        assert "tid missing" in problems
+
+    def test_ts_regression_within_lane_is_flagged(self):
+        obj = self._trace()
+        xs = [e for e in obj["traceEvents"] if e["ph"] == "X"]
+        same_lane = [e for e in xs if e["tid"] == xs[0]["tid"]]
+        assert len(same_lane) >= 2
+        same_lane[-1]["ts"] = 0.0  # jump backwards in its lane
+        assert any(
+            "regresses within tid" in p for p in validate_trace(obj)
+        )
+
+    def test_not_an_object_and_missing_events(self):
+        assert validate_trace([1, 2]) == ["artifact is not a JSON object"]
+        assert validate_trace({"x": 1}) == [
+            "traceEvents missing or not a list"
+        ]
