@@ -94,6 +94,12 @@ CHIP = {
              scale=0.125),
         dict(hq=32, hkv=4, head_dim=128, page=16, chunk=512, max_pages=256,
              window=1024),
+        # Laguna-XS.2's two kinds in one model: 48 query heads over 8 kv
+        # heads (G = 6) on the pool, 64 (G = 8) under a window of 512 on the
+        # window group; a query block of all heads falls back to what fits.
+        dict(hq=48, hkv=8, head_dim=128, page=16, chunk=512, max_pages=512),
+        dict(hq=64, hkv=8, head_dim=128, page=16, chunk=512, max_pages=256,
+             window=512),
     ],
     "interpret": False,
     "short": 40,  # dense prefill
@@ -130,6 +136,7 @@ REHEARSAL = {
         dict(hq=7, hkv=1, head_dim=32, page=4, chunk=16, max_pages=16),
         dict(hq=8, hkv=2, head_dim=32, page=4, chunk=16, max_pages=16,
              scale=0.125, window=20),
+        dict(hq=6, hkv=2, head_dim=32, page=4, chunk=16, max_pages=16),
     ],
     "interpret": True,
     "short": 40,

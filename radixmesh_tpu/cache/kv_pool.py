@@ -534,7 +534,9 @@ class PagedKVPool:
         # one count on every page of its window, a snapshot in the radix
         # tree one on every page of its tail, and a page returns to the
         # free list when the last count goes (``unref_window``). Page 0 is
-        # nobody's: idle rows write there.
+        # nobody's: idle rows write there. The group goes with state slots
+        # (a model with state-space layers) or without any (attention kinds
+        # of two shapes: a snapshot there IS its tail, counts and no copy).
         self.window = None
         self.window_refs = None
         self.window_used_peak = 0

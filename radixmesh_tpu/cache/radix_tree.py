@@ -236,8 +236,17 @@ class TreeNode:
         # With a state, where the model keeps a group of rows for a window
         # only: the pages of that group holding the window's tokens BEFORE
         # this node's end (one count on each is this node's), oldest
-        # first; None: the model has no such group.
+        # first; None: the model has no such group. A model with such a
+        # group and NO state leaves a tail alone (``state`` None): the
+        # node is a resume point all the same (``resumable``).
         self.tail: np.ndarray | None = None
+
+    @property
+    def resumable(self) -> bool:
+        """Does a request that matched to this node's end find there what
+        the model's layers need beside the rows (a state slot, a window
+        tail, or both)?"""
+        return self.state is not None or self.tail is not None
 
     @property
     def evicted(self) -> bool:
@@ -287,10 +296,11 @@ class MatchResult:
     disk_values: list[Any] = field(default_factory=list)
     disk_nodes: list["TreeNode"] = field(default_factory=list)
     # ``(position, state slot)`` of every device-resident matched node that
-    # carries a state, shallowest first (a state-bearing model resumes at
-    # one of these positions, not at ``length``); empty in a tree without
-    # states.
-    states: list[tuple[int, int]] = field(default_factory=list)
+    # is a resume point, shallowest first (a state-bearing model resumes at
+    # one of these positions, not at ``length``; the slot is None where the
+    # resume point is a window tail and nothing else); empty in a tree
+    # without states.
+    states: list[tuple[int, int | None]] = field(default_factory=list)
     # Beside ``states``, entry for entry: each node's window tail (None
     # where the model keeps no window group).
     tails: list = field(default_factory=list)
@@ -303,8 +313,9 @@ class MatchResult:
         return None
 
     def state_at_or_before(self, limit: int) -> tuple[int, int | None]:
-        """The deepest ``(position, slot)`` with a state at or before
-        ``limit``; ``(0, None)``: none — position 0 needs no state."""
+        """The deepest ``(position, slot)`` with a resume point at or before
+        ``limit`` (slot None at a position > 0: a tail alone); ``(0,
+        None)``: none — position 0 needs no state."""
         for pos, slot in reversed(self.states):
             if pos <= limit:
                 return pos, slot
@@ -387,7 +398,9 @@ class RadixTree:
         # one, and no walk looks for one.
         self.on_free_state = on_free_state
         # Called with the window tail a node gives up with its state (the
-        # counts on those pages were the node's).
+        # counts on those pages were the node's). Set WITHOUT
+        # ``on_free_state`` for a model that keeps a window group and no
+        # state: its resume points are tails alone.
         self.on_free_tail = on_free_tail
         self.state_evictions = 0
         self.on_free_host = on_free_host
@@ -464,7 +477,7 @@ class RadixTree:
                 if n is not self.root and n.disk_value is not None:
                     self.on_disk_detach(n.disk_value)
                     n.disk_value = None
-        if self.on_free_state is not None and getattr(self, "root", None) is not None:
+        if self._tracks_states and getattr(self, "root", None) is not None:
             for n in self._all_nodes():
                 self._drop_state(n)
         self.root = TreeNode()
@@ -512,7 +525,7 @@ class RadixTree:
         in_disk = False  # ... and durable disk extents extend the host chain
         states: list[tuple[int, int]] = []
         tails: list = []
-        track = self.on_free_state is not None
+        track = self._tracks_states
         pos = 0
         now = self._time()
         node.last_access_time = now
@@ -576,7 +589,7 @@ class RadixTree:
                 last_dev = child
                 if track:
                     pos += m
-                    if child.state is not None:
+                    if child.resumable:
                         states.append((pos, child.state))
                         tails.append(child.tail)
             node = child
@@ -628,10 +641,15 @@ class RadixTree:
             return 0
         return self._insert_helper(self.root, key, value, on_conflict)
 
-    def attach_state(self, key: Sequence[int], slot: int,
+    @property
+    def _tracks_states(self) -> bool:
+        return self.on_free_state is not None or self.on_free_tail is not None
+
+    def attach_state(self, key: Sequence[int], slot: int | None,
                      tail: np.ndarray | None = None) -> bool:
         """Hand the tree state slot ``slot`` (and, with it, the window
-        ``tail``: pages the caller has counted once for the tree), valid
+        ``tail``: pages the caller has counted once for the tree; ``slot``
+        None: the tail alone, of a model that carries no state), valid
         after exactly ``key``
         (a page multiple of tokens, all of them device-resident in the
         tree: the caller just inserted them). The node that ends there —
@@ -651,7 +669,7 @@ class RadixTree:
                 child = self._split_node(child, m)
             node = child
             key = key[m:]
-        if node is self.root or node.state is not None:
+        if node is self.root or node.resumable or (slot is None and tail is None):
             return False
         node.state, node.tail = slot, tail
         return True
@@ -671,11 +689,11 @@ class RadixTree:
         their age; what a waiting request would resume from goes last."""
         if pinned is None:
             held = [x for x in self._all_nodes()
-                    if x.state is not None and x.lock_ref == 0]
+                    if x.resumable and x.lock_ref == 0]
             order = heapq.nsmallest(n, held)
         else:
             held = [x for x in self._all_nodes()
-                    if x.state is not None and x.state not in pinned]
+                    if x.resumable and x.state not in pinned]
             order = sorted(held, key=lambda x: (
                 0 if self._superseded(x)
                 else 1 if x.lock_ref > 0 and not x.children else 2,
@@ -690,7 +708,7 @@ class RadixTree:
         """Does a deeper state lie below ``node`` before the path branches?"""
         while len(node.children) == 1:
             (node,) = node.children.values()
-            if node.state is not None:
+            if node.resumable:
                 return True
         return False
 
@@ -698,9 +716,9 @@ class RadixTree:
         if node.state is not None:
             slot, node.state = node.state, None
             self.on_free_state(slot)
-            if node.tail is not None:
-                tail, node.tail = node.tail, None
-                self.on_free_tail(tail)
+        if node.tail is not None:
+            tail, node.tail = node.tail, None
+            self.on_free_tail(tail)
 
     def peek_continuation(self, key: Sequence[int], max_tokens: int) -> np.ndarray:
         """Tokens the cache holds BEYOND ``key`` — the speculative drafter's
@@ -863,8 +881,7 @@ class RadixTree:
                 self.evictable_size_ -= len(n.key)
             if n.host_value is not None:
                 freed_host.append(n.host_value)
-            if n.state is not None:
-                self._drop_state(n)
+            self._drop_state(n)
             stack.extend(n.children.values())
             if n.disk_value is not None:
                 # The extent is unreachable once the node leaves the

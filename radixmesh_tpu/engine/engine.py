@@ -107,6 +107,11 @@ _STATE_EVICT_BATCH = 64
 # ... and of snapshots that hold a window tail too (each frees up to a
 # window of rows: 20 MiB at published widths, not 240 KiB).
 _TAIL_EVICT_BATCH = 8
+# A pool that runs full (finished prefixes are kept until the room is
+# needed) evicts on every allocation, and ``RadixTree.evict`` walks the
+# whole tree to find its leaves: 6 ms at 3 000 nodes, on the engine thread,
+# for a page. One pressure therefore frees at least this share of the pool.
+_EVICT_POOL_SHARE = 64
 
 
 def pool_groups(cfg: ModelConfig, num_slots: int, max_batch: int,
@@ -771,11 +776,18 @@ class Engine:
                 "held_peak_row": 0,
                 "tail_slots_in_tree": 0,
             }
-            # context tokens x reading layers a decode step: the one full
-            # layer's K/V read by itself and every cross-attention layer
-            self.attn_window["shared_kv_tokens_read"] = 0
-            self._kv_readers = 1 + cfg.layer_types.count("cross_attention")
+            # context tokens x reading layers a decode step: the full
+            # layers' K/V, each read by itself (and, in a cross-decoder, by
+            # every cross-attention layer once more)
+            self._kv_readers = (
+                cfg.layer_types.count("full_attention")
+                + cfg.layer_types.count("cross_attention"))
+            self._kv_reads = (
+                "shared_kv_tokens_read" if cfg.has_ssm else "full_tokens_read")
+            self.attn_window[self._kv_reads] = 0
             gib = 2.0 ** 30
+            states = [a for a in (self.pool.state, self.pool.state_conv)
+                      if a is not None]
             self.log.info(
                 "two groups of rows: full %d slots x %d B = %.2f GiB (%d "
                 "reading layers), window %d slots x %d B = %.2f GiB; %d state "
@@ -784,9 +796,26 @@ class Engine:
                 self.pool.kv.nbytes / gib, self._kv_readers,
                 self.pool.window_slots,
                 self.pool.window.nbytes // self.pool.window_slots,
-                self.pool.window.nbytes / gib, self.pool.state.shape[1],
-                self.pool.state_slot_bytes,
-                (self.pool.state.nbytes + self.pool.state_conv.nbytes) / gib)
+                self.pool.window.nbytes / gib,
+                states[0].shape[1] if states else 0,
+                self.pool.state_slot_bytes, sum(a.nbytes for a in states) / gib)
+        # tail: a model that keeps a window group and NO state — what the
+        # tree holds for a resume is a window tail alone (counts on pages,
+        # no copy). matches: requests whose rows the tree held; resumes:
+        # those that resumed at a tail (the deepest at or before their
+        # match); kv_matched / resumed / recomputed_tokens as ``state``'s;
+        # snapshots: tails handed to the tree (evictions: the tree's own
+        # count, on ``/debug/state``).
+        self.tail_stats = None
+        if self.pool.window is not None and self.pool.state is None:
+            self.tail_stats = {
+                "matches": 0, "resumes": 0, "kv_matched_tokens": 0,
+                "resumed_tokens": 0, "recomputed_tokens": 0,
+                "snapshots": 0, "snapshots_declined": 0,
+            }
+        # A prefix is served only from a resume point (a state, a tail).
+        self._resumes = (
+            self.state_stats is not None or self.tail_stats is not None)
         self._kv_tier_watermark = float(kv_tier_watermark)
         self._kv_tier_min_heat = float(kv_tier_min_heat)
         self._kv_tier_destage_budget = int(kv_tier_destage_budget)
@@ -1627,6 +1656,8 @@ class Engine:
         if slots is None:
             rec = get_recorder()
             t_ev = time.monotonic() if rec.enabled else 0.0
+            want = max(n - self.pool.free_slots,
+                       self.pool.num_slots // _EVICT_POOL_SHARE)
             if self.mesh is not None:
                 # Eviction that DESTROYS KV must un-advertise the prefix
                 # ring-wide — otherwise the router keeps routing
@@ -1635,11 +1666,9 @@ class Engine:
                 # trees invoke it just when write-back fails (a written-back
                 # prefix stays servable via restore, so it stays
                 # advertised).
-                freed = self.tree.evict(
-                    n - self.pool.free_slots, on_evict=self._unadvertise
-                )
+                freed = self.tree.evict(want, on_evict=self._unadvertise)
             else:
-                freed = self.tree.evict(n - self.pool.free_slots)
+                freed = self.tree.evict(want)
             if freed:
                 self._m_evicted[cause].inc(freed)
             slots = self.pool.alloc(n)
@@ -2066,7 +2095,7 @@ class Engine:
             match.length, (len(prompt) - 1) // self.page_size * self.page_size
         )
         matched = reuse
-        if self.state_stats is not None:
+        if self._resumes:
             # The rows alone serve no prefix: the request resumes at the
             # deepest snapshot at or before them, and computes from there.
             reuse, resume = match.state_at_or_before(matched)
@@ -2087,6 +2116,15 @@ class Engine:
                         req, match.tail_at(reuse), reuse):
                     self._free_states(req)
                     resume = None  # unpinned with the states
+                    self.pool.free(own)
+                    own = None
+        elif own is not None and self.tail_stats is not None:
+            # Nothing between the match and here evicts a tail, and the
+            # counts below are taken before anything after can.
+            with self.spans.span("rm.state.restore"):
+                if self._acquire_window(req, match.tail_at(reuse), reuse):
+                    self._plan_tails(req, match, matched, reuse)
+                else:
                     self.pool.free(own)
                     own = None
         if own is None:
@@ -2200,7 +2238,6 @@ class Engine:
         branch point pays the recompute, every later one resumes there. A
         snapshot the pool has no slot for is skipped: a later match
         resumes shallower."""
-        ps = self.page_size
         work = self._alloc_state()
         keep = self._alloc_state() if work else 0
         if not keep:
@@ -2210,20 +2247,38 @@ class Engine:
         req.state_slot, req.state_boundary = work, keep
         req.state_boundary_len = 0
         req.state_resume = resume or 0
-        have = {pos for pos, _ in match.states}
         req.state_snaps = []
-        for pos in sorted({len(req.prompt) // ps * ps, matched}):
-            if pos > reuse and pos not in have:
-                slot = self._alloc_state()
-                if slot:
-                    req.state_snaps.append((pos, slot))
-                else:
-                    self.state_stats["snapshots_skipped"] += 1
-        st = self.state_stats
+        for pos in self._snapshot_cuts(req, match, matched, reuse, self.state_stats):
+            slot = self._alloc_state()
+            if slot:
+                req.state_snaps.append((pos, slot))
+            else:
+                self.state_stats["snapshots_skipped"] += 1
+        return True
+
+    def _snapshot_cuts(self, req: Request, match, matched: int, reuse: int,
+                       st: dict) -> list[int]:
+        """Where ``req``'s prefill will leave a resume point for the tree —
+        its prompt's page-aligned cut and, when it resumes below it, its
+        match — unless one lies there already; and the three counters of
+        how it resumed, into ``st``."""
         st["kv_matched_tokens"] += matched
         st["resumed_tokens"] += reuse
         st["recomputed_tokens"] += matched - reuse
-        return True
+        have = {pos for pos, _ in match.states}
+        ps = self.page_size
+        return [pos for pos in sorted({len(req.prompt) // ps * ps, matched})
+                if pos > reuse and pos not in have]
+
+    def _plan_tails(self, req: Request, match, matched: int, reuse: int) -> None:
+        """``_acquire_states`` for a model whose resume points are tails
+        alone: the same two places, and nothing to allocate (a tail is
+        counts on pages the row holds anyway: slot 0 stands for "none")."""
+        st = self.tail_stats
+        req.state_snaps = [
+            (pos, 0) for pos in self._snapshot_cuts(req, match, matched, reuse, st)]
+        st["matches"] += matched > 0
+        st["resumes"] += reuse > 0
 
     def _free_states(self, req: Request) -> None:
         """Return every state slot ``req`` still holds."""
@@ -2247,16 +2302,18 @@ class Engine:
                 keep.append((pos, slot))
                 continue
             tail = None if self.window_stats is None else self._tail_of(req, pos)
-            if self.tree.attach_state(key[:pos], slot, tail):
-                self.state_stats["snapshots"] += 1
+            st = self.state_stats or self.tail_stats
+            if self.tree.attach_state(key[:pos], slot or None, tail):
+                st["snapshots"] += 1
                 if tail is not None:
                     self.window_stats["tail_slots_in_tree"] += (
                         len(tail) * self.page_size)
             else:
-                self.pool.free_state(slot)
+                if slot:
+                    self.pool.free_state(slot)
                 if tail is not None:
                     self.pool.unref_window(tail)
-                self.state_stats["snapshots_declined"] += 1
+                st["snapshots_declined"] += 1
         req.state_snaps = keep
 
     def _tail_of(self, req: Request, pos: int) -> np.ndarray:
@@ -2544,7 +2601,8 @@ class Engine:
             kw["index_pool"] = self.pool.index
         if self.pool.window is not None:
             kw["window_pool"] = self.pool.window
-            kw["state_pool"] = (self.pool.state, self.pool.state_conv)
+            if self.pool.state is not None:
+                kw["state_pool"] = (self.pool.state, self.pool.state_conv)
         elif self.pool.state is not None:
             kw["state_pool"] = self.pool.state
         return kw
@@ -2590,7 +2648,9 @@ class Engine:
             wanted += [(0, 0)] * (_STATE_SNAPS - len(wanted))
             moe_kw["snap_at"] = jnp.asarray([[at for at, _ in wanted]], jnp.int32)
         windowed = self.window_stats is not None
-        if windowed:  # the full layer's last position alone is computed
+        # A cross-decoder computes the full layer's last position alone.
+        last_only = self.cfg.has_ssm
+        if last_only:
             moe_kw["new_lengths"] = jnp.full((1,), n_new, dtype=jnp.int32)
         logits, new_k, new_v, *aux = prefill_forward(
             self.params,
@@ -2635,7 +2695,7 @@ class Engine:
         req.token_slots = own[:n_new].copy()
         req.own_slots = own
         self._install_prefilled(req, row, 0)
-        return (req, logits[0, 0 if windowed else n_new - 1])
+        return (req, logits[0, 0 if last_only else n_new - 1])
 
     def _pp_n_micro(self, batch: int) -> int:
         """GPipe microbatch count for a pp launch: fill every stage when
@@ -2772,7 +2832,7 @@ class Engine:
             N, int(new.max()), int(totals.max()), chunk=self.prefill_chunk,
             page_size=ps, max_pages=self.max_pages,
             width_selects=self.cfg.dsa,
-            one_width=self.cfg.has_conv or self.cfg.has_ssm,
+            one_width=self._resumes,
         )
         n_chunks = -(-int(new.max()) // C)
         span = n_chunks * C
@@ -2836,10 +2896,11 @@ class Engine:
             states = None
             if self.state_stats is not None:
                 states = self._chunk_states(group, starts, nv, B, ci == 0)
-                if windowed:
-                    states["window_slots"] = jnp.asarray(
-                        w_sl_all[:, ci * C : (ci + 1) * C])
-                    states["window_table"] = wt_dev
+            if windowed:
+                states = (states or {}) | {
+                    "window_slots": jnp.asarray(
+                        w_sl_all[:, ci * C : (ci + 1) * C]),
+                    "window_table": wt_dev}
             res = self._forward_chunk(
                 jnp.asarray(toks_all[:, ci * C : (ci + 1) * C]),
                 jnp.asarray(poss),
@@ -2961,7 +3022,7 @@ class Engine:
             req.state_snaps.append((req.state_boundary_len, req.state_boundary))
             req.state_boundary = 0
         self._publish(req, req.kv_len)
-        if self.state_stats is not None:
+        if self._resumes:
             self._free_states(req)
         if req.own_slots.size:
             self.pool.free(req.own_slots)
@@ -2998,8 +3059,9 @@ class Engine:
         elif self.pool.index is not None:
             out, self.pool.kv, self.pool.index = res
         elif self.pool.window is not None:
-            out, self.pool.kv, self.pool.window, states = res
-            self.pool.state, self.pool.state_conv = states
+            out, self.pool.kv, self.pool.window, *states = res
+            if states:  # a model that carries a state beside both groups
+                self.pool.state, self.pool.state_conv = states[0]
         elif self.pool.state is not None:
             out, self.pool.kv, self.pool.state = res
         else:
@@ -3104,8 +3166,7 @@ class Engine:
             aw["latent_tokens_shared"] += attended - launch.latent_tokens
             aw["decode_groups"] = launch.groups
         if self.window_stats is not None:
-            aw["shared_kv_tokens_read"] += (
-                int(launch.contexts.sum()) * self._kv_readers)
+            aw[self._kv_reads] += int(launch.contexts.sum()) * self._kv_readers
             self.window_stats["slots_written"] += len(launch.idx)
         if self.cfg.dsa:
             self._note_sparse(
@@ -3135,9 +3196,10 @@ class Engine:
                         "snap_at": jnp.ones((self.max_batch, 2), jnp.int32),
                         "state_out": launch.state_d.T,
                     }
-                    if launch.w_slots_d is not None:
-                        states["window_slots"] = launch.w_slots_d[:, None]
-                        states["window_table"] = launch.w_pt_d
+                if launch.w_slots_d is not None:
+                    states = (states or {}) | {
+                        "window_slots": launch.w_slots_d[:, None],
+                        "window_table": launch.w_pt_d}
                 res = self._forward_chunk(
                     tokens_d[:, None],
                     launch.positions_d[:, None],
@@ -3960,8 +4022,10 @@ class Engine:
         ``Request.__setattr__``."""
         req.token_slots = np.append(req.token_slots, slot)
         req.kv_len += 1
-        if req.state_boundary and req.kv_len % self.page_size == 0:
-            # The step completed a page: its state is in the boundary slot.
+        if ((req.state_boundary or self.tail_stats is not None)
+                and req.kv_len % self.page_size == 0):
+            # The step completed a page: its state is in the boundary slot
+            # (a tail is the pages the row holds there: nothing to keep).
             req.state_boundary_len = req.kv_len
             if self.window_stats is not None:
                 self._release_window(req, req.kv_len)

@@ -7,7 +7,7 @@ from radixmesh_tpu.models.llama import (
     convert_hf_state_dict,
 )
 from radixmesh_tpu.models import (
-    glm4_moe_lite, glm_moe_dsa, lfm2_moe, mellum2, phi4flash, qwen2,
+    glm4_moe_lite, glm_moe_dsa, laguna, lfm2_moe, mellum2, phi4flash, qwen2,
 )
 
 _PRESETS = {
@@ -37,6 +37,9 @@ _PRESETS = {
     "lfm2-tiny": lfm2_moe.lfm2_tiny,
     "phi-4-mini-flash-reasoning": phi4flash.phi4_mini_flash_reasoning,
     "phi4flash-tiny": phi4flash.phi4flash_tiny,
+    "laguna-xs.2": laguna.laguna_xs2,
+    "laguna-xs.2-l5": laguna.laguna_xs2_l5,
+    "laguna-tiny": laguna.laguna_tiny,
 }
 
 

@@ -59,6 +59,7 @@ the serving stack's model side that the north star requires
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from functools import partial
 from typing import Any
@@ -219,8 +220,31 @@ class ModelConfig:
     use_rope: bool = True
     layer_norm: bool = False
     attn_out_bias: bool = False
+    # Attention layers of one model that differ in SHAPE (``models/
+    # laguna.py``): ``heads_by_type`` = ``((layer type, query heads),
+    # ...)`` over the same kv heads. ``wq`` / ``wo`` of a sliding and of a
+    # full layer then cannot lie in one stack: each kind's attention
+    # matrices are a stack of its own (``params[<layer type>]``), the
+    # norms and the FFN stay per layer, and the layers run through
+    # ``_scan_mixed`` by their static kind. ``n_heads`` is the most any
+    # kind has. The sliding layers' rows are the WINDOW group
+    # (``window_pool_shape``), the full layers' the pool's; such a config
+    # carries no state, so what the tree keeps for a resume is a window
+    # tail alone. A ``rope_by_type`` entry may give ``partial_rotary_factor``:
+    # the first so much of each head rotates (as two halves), the rest
+    # passes. ``attn_gate``: every head's attention output is multiplied
+    # by ``sigmoid(h W_g)`` of the layer's normed input (``wg`` [hidden,
+    # heads], in the kind's stack) before ``wo``.
+    heads_by_type: tuple | None = None
+    attn_gate: bool = False
 
     def __post_init__(self):
+        if self.heads_by_type is not None:
+            self._check_kinds()
+        elif self.attn_gate:
+            raise ValueError(
+                "attn_gate's weights live in the attention stacks of a "
+                "config with heads_by_type: no other config has them")
         if self.layer_types is not None and (
             self.ssm_state or {"mamba", "gmu", "cross_attention"}
             & set(self.layer_types)
@@ -329,6 +353,41 @@ class ModelConfig:
                 "divisible by ep_size"
             )
 
+    def _check_kinds(self) -> None:
+        """A config whose attention kinds are stacks of their own."""
+        heads = dict(self.heads_by_type)
+        kinds = set(self.layer_types or ())
+        if (not kinds or kinds != set(heads)
+                or kinds != {"sliding_attention", "full_attention"}):
+            raise ValueError(
+                "heads_by_type names the query heads of sliding_attention "
+                "and of full_attention, and layer_types holds both: the "
+                "window group and the pool need a layer each; got "
+                f"{sorted(heads)} for layers of {sorted(kinds)}")
+        if any(h <= 0 or h % self.n_kv_heads for h in heads.values()) or (
+                self.n_heads != max(heads.values())):
+            raise ValueError(
+                "every kind's query heads are a multiple of n_kv_heads, and "
+                f"n_heads the most of them; got {heads}, n_heads="
+                f"{self.n_heads}, n_kv_heads={self.n_kv_heads}")
+        if self.sliding_window < 2 or self.sliding_window % 16:
+            raise ValueError(
+                "the window layers' rows are given back by whole pages: "
+                "sliding_window must be a multiple of the page (16), got "
+                f"{self.sliding_window}")
+        if (self.latent or self.qkv_bias or self.pair_kv_heads or self.qk_norm
+                or self.layer_norm or not self.use_rope):
+            raise ValueError(
+                "attention kinds in stacks of their own are plain GQA layers "
+                "under RMSNorm and a RoPE each: no latent attention, "
+                "qkv_bias, pair_kv_heads, qk_norm or layer_norm")
+        for kind, pairs in self.rope_by_type or ():
+            rot = dict(pairs).get("partial_rotary_factor", 1.0) * self.head_dim
+            if rot != int(rot) or int(rot) % 2 or not 0 < rot <= self.head_dim:
+                raise ValueError(
+                    f"{kind}: partial_rotary_factor must leave an even "
+                    f"number of rotating dims within head_dim, got {rot}")
+
     def _check_hybrid(self) -> None:
         """A config with mamba / gmu / cross_attention layers runs as one
         shape only (class docstring); anything else is refused by name."""
@@ -403,9 +462,18 @@ class ModelConfig:
 
     @property
     def window_layers(self) -> tuple[int, ...]:
-        """The sliding layers of a config with mamba layers: they own the
-        rows of the WINDOW group (``window_pool_shape``)."""
-        return self._layers_of("sliding_attention") if self.has_ssm else ()
+        """The sliding layers that own the rows of the WINDOW group
+        (``window_pool_shape``): those of a config whose layer kinds are
+        stacks of their own (mamba layers, or ``heads_by_type``); none
+        where window and full layers share one scanned body and one pool
+        array."""
+        if self.has_ssm or self.heads_by_type:
+            return self._layers_of("sliding_attention")
+        return ()
+
+    def heads_of(self, kind: str) -> int:
+        """Query heads of a layer of ``kind``."""
+        return dict(self.heads_by_type or ()).get(kind, self.n_heads)
 
     @property
     def ssm_inner(self) -> int:
@@ -429,8 +497,8 @@ class ModelConfig:
     def attn_layers(self) -> tuple[int, ...]:
         """The layers that own cache rows: all of them, or, beside conv
         layers, the attention layers alone."""
-        if self.has_ssm:  # the full layer: the window layers' rows are
-            return self._layers_of("full_attention")  # another group
+        if self.window_layers:  # the full layers: the window layers' rows
+            return self._layers_of("full_attention")  # are another group
         if not self.has_conv:
             return tuple(range(self.n_layers))
         return tuple(i for i, t in enumerate(self.layer_types) if t != "conv")
@@ -483,13 +551,15 @@ class ModelConfig:
         return (planes, len(self.attn_layers), heads, num_slots, width)
 
     def window_pool_shape(self, num_slots: int) -> tuple | None:
-        """The WINDOW group of a config with mamba layers: the sliding
-        layers' rows, ``[planes, sliding layers, heads, slots, width]``,
-        under an allocator of its own — a row of the batch holds them for
-        the last ``sliding_window`` tokens only and gives the pages before
-        back (``engine/engine.py``). None for every other config: its
-        window layers, if any, share the one pool array."""
-        if not self.has_ssm:
+        """The WINDOW group of a config whose layer kinds are stacks of
+        their own (``window_layers``: beside mamba layers, or attention
+        kinds of two shapes): the sliding layers' rows, ``[planes, sliding
+        layers, heads, slots, width]``, under an allocator of its own — a
+        row of the batch holds them for the last ``sliding_window`` tokens
+        only and gives the pages before back (``engine/engine.py``). None
+        for every other config: its window layers, if any, share the one
+        pool array."""
+        if not self.window_layers:
             return None
         planes, heads, width = self.kv_row
         return (planes, len(self.window_layers), heads, num_slots, width)
@@ -502,8 +572,9 @@ class ModelConfig:
         of a closed loop of two callers a row resumes from, and the shared
         preambles; a live row's own snapshots go first under pressure and
         share pages with the row), one prefill wave's chunk rows, the
-        scratch page. 0 without the group."""
-        if not self.has_ssm:
+        scratch page. The same whether a snapshot holds a state beside its
+        tail or the tail alone. 0 without the group."""
+        if not self.window_layers:
             return 0
         w = self.sliding_window
         want = (max_batch * (w + 2 * page_size) + (5 * max_batch // 4) * w
@@ -711,8 +782,9 @@ def _vocab_blocks(vocab: int, hidden: int, limit: int) -> int:
     return n
 
 
-def _attn_dims(cfg: ModelConfig) -> dict:
-    """``name -> (in, out)`` of a layer's attention matrices."""
+def _attn_dims(cfg: ModelConfig, n_heads: int | None = None) -> dict:
+    """``name -> (in, out)`` of a layer's attention matrices (``n_heads``:
+    the query heads of the layer's kind, where kinds differ)."""
     H = cfg.hidden
     if cfg.latent:
         q_out = cfg.n_heads * cfg.head_dim
@@ -724,8 +796,12 @@ def _attn_dims(cfg: ModelConfig) -> dict:
             "wkv_b": (cfg.kv_lora_rank, kv_out),
             "wo": (cfg.n_heads * cfg.v_head_dim, H),
         }
-    qd, kvd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-    return {"wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd), "wo": (qd, H)}
+    n_heads = n_heads or cfg.n_heads
+    qd, kvd = n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    dims = {"wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd), "wo": (qd, H)}
+    if cfg.attn_gate:
+        dims["wg"] = (H, n_heads)
+    return dims
 
 
 def _ffn_dims(hidden: int, width: int, prefix: str = "w_") -> dict:
@@ -783,6 +859,23 @@ def _draw_operators(key, cfg: ModelConfig) -> dict:
     return {"conv": conv, "attn": attn}
 
 
+def _draw_kinds(key, cfg: ModelConfig) -> dict:
+    """The attention matrices of a config with ``heads_by_type``: a stack a
+    kind, ``{layer type: {wq, wk, wv, wo[, wg]}}`` over that kind's layers
+    in layer order, each of its own shape."""
+    out = {}
+    for i, (kind, heads) in enumerate(cfg.heads_by_type):
+        n = cfg.layer_types.count(kind)
+        dims = _attn_dims(cfg, heads)
+        out[kind] = {
+            name: _draw_blocks(k, n, shape, shape[0], cfg.dtype, None)[0]
+            for k, (name, shape) in zip(
+                jax.random.split(jax.random.fold_in(key, i), len(dims)),
+                dims.items())
+        }
+    return out
+
+
 @partial(jax.jit, static_argnames=("cfg", "weight_quant", "mesh"))
 def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
     # Contraction axis of a block to quantize along, or None for float.
@@ -801,7 +894,7 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
     ffn = _ffn_dims(H, ffn_width)
     # Beside conv layers the operators' leaves are stacks of their own
     # (below): the per-layer stacks keep the norms and the FFN.
-    op_dims = {} if cfg.has_conv else _attn_dims(cfg)
+    op_dims = {} if cfg.has_conv or cfg.heads_by_type else _attn_dims(cfg)
     dims = dict(op_dims)
     dims |= {"w_router": (H, cfg.n_experts)} if cfg.n_experts else ffn
     layers = _draw_stack(keys, L, dims, cfg, axis(0))
@@ -866,6 +959,8 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         )
     if cfg.has_conv:
         params |= _draw_operators(jax.random.fold_in(key, 105), cfg)
+    if cfg.heads_by_type:
+        params |= _draw_kinds(jax.random.fold_in(key, 107), cfg)
     qd = cfg.n_heads * cfg.head_dim
     n_blk = _vocab_blocks(V, H, H * max(ffn_width, qd))
     # [V, H] in row blocks; per-ROW scales serve the gather and the tied
@@ -984,6 +1079,17 @@ def param_logical_axes(cfg: ModelConfig, params: dict | None = None) -> dict:
             "w_up": ("layer", "embed", "ffn"),
             "w_down": ("layer", "ffn", "embed"),
         }
+    if cfg.heads_by_type:
+        # Engine refuses a mesh for such a config; the names say what a tp
+        # share would split (the gate by head, as wq's columns).
+        ops = {name: axes["layers"].pop(name) for name in ("wq", "wk", "wv", "wo")}
+        for stack in ("dense_layers",) if cfg.first_k_dense else ():
+            for name in ops:
+                del axes[stack][name]
+        if cfg.attn_gate:
+            ops["wg"] = ("layer", "embed", "q_heads")
+        for kind, _ in cfg.heads_by_type:
+            axes[kind] = dict(ops)
     if cfg.qkv_bias:
         axes["layers"]["bq"] = ("layer", "q_heads")
         axes["layers"]["bk"] = ("layer", "kv_heads")
@@ -1055,7 +1161,8 @@ def _qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig):
         k = k + lp["bk"]
         v = v + lp["bv"]
     B, S = x.shape[:2]
-    q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
+    # -1: the layer's own head count where kinds differ (heads_by_type).
+    q = q.reshape(B, S, -1 if cfg.heads_by_type else cfg.n_heads, cfg.head_dim)
     k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
     if cfg.qk_norm:  # over each head, before the rotary
         q = rms_norm(q, lp["q_head_norm"], cfg.rms_eps)
@@ -1733,9 +1840,9 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
         "disaggregated": "disaggregated prefill/decode (engine/disagg.py)",
         "hf_checkpoint": "loading a HuggingFace checkpoint (--weights)",
     }
-    if cfg.has_conv or cfg.has_ssm:
-        # A prefix is handed back only where its state was snapshotted:
-        # whatever moves or advertises a prefix without its state.
+    if cfg.has_conv or cfg.window_layers:
+        # A prefix is handed back only where its state (or its window tail)
+        # was snapshotted: whatever moves or advertises a prefix without it.
         named |= {
             "mesh_cache": "a prefix ring (cache/mesh_cache.py: an advertised "
                           "prefix would promise a hit the state cannot serve)",
@@ -1758,7 +1865,12 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
                         ("state-space layers with a recurrent state",
                          cfg.has_ssm),
                         ("a cross-decoder that reads one layer's K/V",
-                         cfg.has_ssm)) if on
+                         cfg.has_ssm),
+                        ("attention kinds of two shapes in stacks of their "
+                         "own (a gated output, a partly rotated head)",
+                         cfg.heads_by_type),
+                        ("a window group of rows resumed from a tail",
+                         cfg.window_layers and not cfg.has_ssm)) if on
     )
     return (
         f"a model with {kinds} does not support " + "; ".join(asked)
@@ -1770,6 +1882,58 @@ def refuse_unsupported(cfg: ModelConfig, **options) -> None:
     reason = unsupported_reason(cfg, **options)
     if reason is not None:
         raise UnsupportedForModel(reason)
+
+
+def _type_rope(cfg: ModelConfig, kind: str) -> tuple:
+    """``(inv_freq [rot / 2], cos/sin factor, rot)`` of layer type ``kind``
+    from ``rope_by_type``: default, or YaRN with its attention factor, over
+    the ``rot`` = ``partial_rotary_factor * head_dim`` leading dims of a
+    head that rotate (all of them unless the entry says otherwise)."""
+    rp = dict(dict(cfg.rope_by_type)[kind])
+    theta = float(rp.get("rope_theta", cfg.rope_theta))
+    rot = int(rp.get("partial_rotary_factor", 1.0) * cfg.head_dim)
+    if rp.get("rope_type", "default") == "yarn":
+        inv = yarn_frequencies(
+            rot, theta, float(rp["factor"]),
+            int(rp["original_max_position_embeddings"]),
+            float(rp.get("beta_fast", 32.0)),
+            float(rp.get("beta_slow", 1.0)),
+            bool(rp.get("truncate", True)),
+        )
+        return inv, float(rp["attention_factor"]), rot
+    if rp.get("rope_type", "default") == "default":
+        return rope_frequencies(rot, theta), 1.0, rot
+    raise ValueError(f"unknown rope_type {rp['rope_type']!r}")
+
+
+def _kind_attn_in(lp: dict, h: jnp.ndarray, cfg: ModelConfig, kind: str,
+                  positions):
+    """``(q, k, v)`` of a layer of attention kind ``kind`` (a config with
+    ``heads_by_type``): the kind's head count from its own ``wq``, its own
+    RoPE over the leading ``rot`` dims of every head, the rest passing."""
+    q, k, v = _qkv(lp, h, cfg)
+    inv, scale, rot = _type_rope(cfg, kind)
+    scale = None if scale == 1.0 else scale
+
+    def rotate(t):
+        if rot == cfg.head_dim:
+            return apply_rope(t, positions, inv, scale)
+        return jnp.concatenate(
+            [apply_rope(t[..., :rot], positions, inv, scale), t[..., rot:]],
+            axis=-1)
+
+    return rotate(q), rotate(k), v
+
+
+def _kind_attn_out(lp: dict, attn: jnp.ndarray, h: jnp.ndarray,
+                   cfg: ModelConfig) -> jnp.ndarray:
+    """``_attn_out`` behind the head-wise output gate: ``attn`` [B, S, Hq,
+    D] times ``sigmoid(h W_g)`` [B, S, Hq] of the layer's normed input."""
+    if cfg.attn_gate:
+        gate = jax.nn.sigmoid(
+            _wmm(lp, "wg", "bsh,hq->bsq", h).astype(jnp.float32))
+        attn = (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
+    return _attn_out(lp, attn, cfg)
 
 
 def _layer_tables(cfg: ModelConfig) -> dict | None:
@@ -1790,25 +1954,7 @@ def _layer_tables(cfg: ModelConfig) -> dict | None:
         }
     if cfg.layer_types is None or cfg.has_conv:
         return tables or None
-    by_type = {}
-    for kind, pairs in cfg.rope_by_type:
-        rp = dict(pairs)
-        theta = float(rp.get("rope_theta", cfg.rope_theta))
-        if rp.get("rope_type", "default") == "yarn":
-            inv = yarn_frequencies(
-                cfg.head_dim, theta, float(rp["factor"]),
-                int(rp["original_max_position_embeddings"]),
-                float(rp.get("beta_fast", 32.0)),
-                float(rp.get("beta_slow", 1.0)),
-                bool(rp.get("truncate", True)),
-            )
-            scale = float(rp["attention_factor"])
-        elif rp.get("rope_type", "default") == "default":
-            inv = rope_frequencies(cfg.head_dim, theta)
-            scale = 1.0
-        else:
-            raise ValueError(f"unknown rope_type {rp['rope_type']!r}")
-        by_type[kind] = (inv, scale)
+    by_type = {kind: _type_rope(cfg, kind)[:2] for kind, _ in cfg.rope_by_type}
     return tables | {
         "inv_freq": jnp.stack(
             [jnp.asarray(by_type[t][0]) for t in cfg.layer_types]
@@ -1893,7 +2039,7 @@ def _scan_layers(cfg: ModelConfig, params: dict, layer, carry):
     the rest. Returns ``(carry, ys)``; ``ys`` of two scans are joined
     along the layer axis, but for what only the sparse layers give (the
     dense scan's None)."""
-    if cfg.has_conv:
+    if cfg.has_conv or cfg.heads_by_type:
         return _scan_mixed(cfg, params, layer, carry)
     kd = cfg.first_k_dense
     stacks = [(0, params["dense_layers"], False)] if kd else []
@@ -1936,9 +2082,19 @@ def _mixed_runs(cfg: ModelConfig) -> list[tuple[int, int, int]]:
     return [r for r in runs if r[1]]
 
 
+def _layer_kinds(cfg: ModelConfig) -> list[str]:
+    """The name of each layer's operator stack in the pytree: ``conv`` /
+    ``attn`` beside conv layers, the layer type itself where attention
+    kinds differ in shape (``heads_by_type``)."""
+    if cfg.has_conv:
+        return ["conv" if t == "conv" else "attn" for t in cfg.layer_types]
+    return list(cfg.layer_types)
+
+
 def _scan_mixed(cfg: ModelConfig, params: dict, layer, carry):
     """``_scan_layers`` for a config whose operators differ in kind (conv
-    and attention: leaves of other shapes, in stacks of their own).
+    and attention, or attention layers of two shapes: leaves of other
+    shapes, in stacks of their own, named by ``_layer_kinds``).
     ``layer(carry, xs, sparse, kind)`` gets ``xs = (layer index, the
     layer's leaves — its norms and FFN from its per-layer stack, its
     operator's from the stack of its kind — and a table row {"op_idx":
@@ -1948,9 +2104,9 @@ def _scan_mixed(cfg: ModelConfig, params: dict, layer, carry):
     something gave, in layer order. Every stack is indexed where it lies
     (no slice of a stack is built: the conv stack is a gigabyte)."""
     kd = cfg.first_k_dense
-    is_conv = np.asarray([t == "conv" for t in cfg.layer_types])
+    kinds = _layer_kinds(cfg)
     # Each layer's place in its kind's stack.
-    place = np.where(is_conv, np.cumsum(is_conv) - 1, np.cumsum(~is_conv) - 1)
+    place = [kinds[:l].count(k) for l, k in enumerate(kinds)]
     per_layer = _scanned_layers(params["layers"])
 
     def at(stack, i):
@@ -1964,9 +2120,9 @@ def _scan_mixed(cfg: ModelConfig, params: dict, layer, carry):
         ys = []
         for j in range(n):
             l0 = first + j  # the layer of unit 0: its kind is every unit's
-            kind = "conv" if is_conv[l0] else "attn"
+            kind = kinds[l0]
             l_idx = l0 + unit_idx * n
-            op_idx = int(place[l0]) + unit_idx * stride[kind]
+            op_idx = place[l0] + unit_idx * stride[kind]
             dense = l0 < kd
             lp = at(params["dense_layers"], l_idx) if dense else at(
                 per_layer, l_idx - kd)
@@ -1992,8 +2148,7 @@ def _scan_mixed(cfg: ModelConfig, params: dict, layer, carry):
 
     parts = []
     for first, n, units in _mixed_runs(cfg):
-        kinds = is_conv[first:first + n]
-        stride = {"conv": int(kinds.sum()), "attn": int((~kinds).sum())}
+        stride = collections.Counter(kinds[first:first + n])
         if units == 1:
             carry, ys = unit(carry, first, 0, n, stride)
             ys = jax.tree_util.tree_map(lambda a: a[None], ys)
@@ -2166,10 +2321,11 @@ def prefill_forward(
         return _hybrid_prefill(params, cfg, tokens, positions, new_lengths, snap_at)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)
-    if cfg.has_conv and p_max:
+    if (cfg.has_conv or cfg.heads_by_type) and p_max:
         raise ValueError(
             "prefill_forward takes no cached prefix for a model with conv "
-            "layers (a prefix resumes from a state slot: prefill_chunk_paged)"
+            "layers or a window group (a prefix resumes from a state slot or "
+            "a window tail: prefill_chunk_paged)"
         )
     s_new = tokens.shape[1]
     pad = p_max - prefix_lengths  # [B] front padding per row
@@ -2193,16 +2349,28 @@ def prefill_forward(
     mask0 = jnp.zeros((tokens.shape[0], s_new, s_new) if select else (), bool)
 
     def mixed_layer(carry, xs, sparse, kind):
-        """A layer of a config with conv layers (``_scan_mixed``)."""
+        """A layer of a config whose operators are stacks of their own
+        (``_scan_mixed``): conv / attention, or attention kinds of two
+        shapes — whose sliding layers' K / V go out apart (``wk`` /
+        ``wv``: the window group's)."""
         x, mask = carry
         l_idx, lp, _ = xs
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        k = v = states = None
+        k = v = states = wk = wv = None
         if kind == "conv":
             zero = jnp.zeros(
                 (x.shape[0], cfg.conv_L_cache - 1, cfg.hidden), x.dtype)
             op, states = _short_conv(lp, h, zero, cfg, snap_at)
             states = states.reshape(*states.shape[:2], -1, 128)
+        elif cfg.heads_by_type:
+            q, k, v = _kind_attn_in(lp, h, cfg, kind, positions)
+            sliding = kind == "sliding_attention"
+            op = _kind_attn_out(lp, attend_prefill(
+                q, k, v, attn_pos, kv_end, kv_start=pad,
+                window=jnp.asarray(cfg.sliding_window) if sliding else None,
+            ), h, cfg)
+            if sliding:
+                k, v, wk, wv = None, None, k, v
         else:
             q, k, v = _attn_in(lp, h, cfg, positions, one_rope, None)
             op = _attn_out(lp, attend_prefill(
@@ -2212,11 +2380,13 @@ def prefill_forward(
         x = x + op
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse)
-        return (x + y, mask), {"k": k, "v": v, "aux": aux, "state": states}
+        return (x + y, mask), {"k": k, "v": v, "aux": aux, "state": states,
+                               "wk": wk, "wv": wv}
 
-    if cfg.has_conv:
+    if cfg.has_conv or cfg.heads_by_type:
         (x, _), ys = _scan_layers(cfg, params, mixed_layer, (x, mask0))
-        return (_logits(params, cfg, x), ys["k"], ys["v"], ys["state"],
+        groups = (ys["state"],) if cfg.has_conv else (ys["wk"], ys["wv"])
+        return (_logits(params, cfg, x), ys["k"], ys["v"], *groups,
                 *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
 
     def layer(carry, xs, sparse):
@@ -2398,7 +2568,10 @@ def prefill_chunk_paged(
     layers takes the window group too (``window_pool`` and the row's slots
     and page table IN it), ``state_pool`` as ``(h, conv)``, and returns
     ``(logits [B, 1, V], kv_pool, window_pool, state_pool)``
-    (``_hybrid_chunk``).
+    (``_hybrid_chunk``). A config with attention kinds of two shapes
+    (``heads_by_type``) takes the window group and no state: its sliding
+    layers read and write there, and it returns ``(logits, kv_pool,
+    window_pool, aux)``.
     """
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
     if cfg.has_ssm:
@@ -2436,6 +2609,8 @@ def prefill_chunk_paged(
         "use_kernel": use_kernel, "interpret": interpret,
     }
 
+    if cfg.heads_by_type:
+        w_pages = window_pool.reshape(_pages_shape(window_pool, page_size))
     if cfg.has_conv:
         # Every conv layer's state of every row, read once before the
         # layers and written once after them: the state array stays out of
@@ -2445,16 +2620,34 @@ def prefill_chunk_paged(
             (positions[:, 0] == 0)[None, :, None, None], 0, resumed)
 
     def mixed_layer(carry, xs, sparse, kind):
-        """A layer of a config with conv layers (``_scan_mixed``)."""
+        """A layer of a config whose operators are stacks of their own
+        (``_scan_mixed``). An attention kind picks its group of rows by its
+        static name: a sliding layer reads the WINDOW group's pages under
+        that group's table, a full layer the pool's."""
         x, sel = carry
         l_idx, lp, table = xs
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        k = v = states = None
+        k = v = states = wk = wv = None
         if kind == "conv":
             mine = jax.lax.dynamic_index_in_dim(
                 resumed, table["op_idx"], 0, keepdims=False)
             op, states = _short_conv(lp, h, mine, cfg, snap_at)
             states = states.reshape(*states.shape[:2], -1, 128)
+        elif cfg.heads_by_type:
+            q, k, v = _kind_attn_in(lp, h, cfg, kind, positions)
+            k, v = k.astype(kv_pool.dtype), v.astype(kv_pool.dtype)
+            sliding = kind == "sliding_attention"
+            attn = paged_chunk_attention(
+                q, k, v, w_pages if sliding else kv_pages,
+                window_table if sliding else page_table, positions,
+                prior_lengths, kv_lengths, table["op_idx"],
+                kv_block_pages=kv_block_pages, use_kernel=use_kernel,
+                interpret=interpret,
+                window=jnp.asarray(cfg.sliding_window) if sliding else None,
+            )
+            op = _kind_attn_out(lp, attn, h, cfg)
+            if sliding:
+                k, v, wk, wv = None, None, k, v
         else:
             q, k, v = _attn_in(lp, h, cfg, positions, one_rope, None)
             scale = None
@@ -2474,7 +2667,8 @@ def prefill_chunk_paged(
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse,
                       **moe_kw)
-        return (x + y, sel), {"k": k, "v": v, "aux": aux, "state": states}
+        return (x + y, sel), {"k": k, "v": v, "aux": aux, "state": states,
+                              "wk": wk, "wv": wv}
 
     def layer(carry, xs, sparse):
         x, sel = carry
@@ -2579,6 +2773,18 @@ def prefill_chunk_paged(
             [(kv_pool, new_kv, ALL_LAYERS), (kv_scale, new_s, ALL_LAYERS)],
             slots, page_size)
         return _logits(params, cfg, pick(x)), kv_pool, kv_scale
+    if cfg.heads_by_type:
+        (x, _), ys = _scan_layers(cfg, params, mixed_layer, (x, None))
+        # Each group's rows at its own slots, by whole pages.
+        kv_pool, = write_runs_all(
+            [(kv_pool, jnp.stack([ys["k"], ys["v"]]).transpose(0, 1, 4, 2, 3, 5),
+              ALL_LAYERS)], slots, page_size)
+        window_pool, = write_runs_all(
+            [(window_pool,
+              jnp.stack([ys["wk"], ys["wv"]]).transpose(0, 1, 4, 2, 3, 5),
+              ALL_LAYERS)], window_slots, page_size)
+        return (_logits(params, cfg, pick(x)), kv_pool, window_pool,
+                *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
     if cfg.has_conv:
         (x, _), ys = _scan_layers(cfg, params, mixed_layer, (x, None))
         new_kv = jnp.stack([ys["k"], ys["v"]]).transpose(0, 1, 4, 2, 3, 5)
@@ -2654,7 +2860,9 @@ def decode_step(
     will want at release), slot 0 otherwise (one array: one upload a
     step). A config with mamba layers takes the window group too and
     returns ``(logits, kv_pool, window_pool, state_pool)``
-    (``_hybrid_decode``). A config with a latent row may be told which
+    (``_hybrid_decode``); a config with attention kinds of two shapes
+    takes the window group alone and returns ``(logits, kv_pool,
+    window_pool, aux)``. A config with a latent row may be told which
     rows hold the same pages first (``ops/mla_attention.py::decode_groups``,
     from the launch's page table; its shape depends on ``B`` alone): the
     latent decode kernel then reads those pages once for all of them."""
@@ -2666,7 +2874,7 @@ def decode_step(
     return _decode_core(
         params, cfg, tokens, kv_pool, slots, page_table, lengths, page_size,
         mesh, kv_scale, moe_stats, index_pool, state_pool, state_slots,
-        decode_groups,
+        decode_groups, window_pool, window_slots, window_table,
     )
 
 
@@ -2686,6 +2894,9 @@ def _decode_core(
     state_pool: jnp.ndarray | None = None,
     state_slots: jnp.ndarray | None = None,
     decode_groups: jnp.ndarray | None = None,
+    window_pool: jnp.ndarray | None = None,
+    window_slots: jnp.ndarray | None = None,
+    window_table: jnp.ndarray | None = None,
 ):
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
@@ -2715,8 +2926,12 @@ def _decode_core(
         resumed = _state_rows(cfg, state_pool[:, state_slots[0]])  # [Lc, B, t-1, H]
 
     def mixed_layer(carry, xs, sparse, kind):
-        """A layer of a config with conv layers (``_scan_mixed``)."""
-        x, kv_pool = carry
+        """A layer of a config whose operators are stacks of their own
+        (``_scan_mixed``). An attention kind writes and reads its own group
+        of rows, picked by its static name: a sliding layer is a launch of
+        the fused decode kernel on the WINDOW group with its window, a full
+        layer one on the pool; both arrays ride the carry in place."""
+        x, kv_pool, window_pool = carry
         l_idx, lp, table = xs
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         states = None
@@ -2725,6 +2940,26 @@ def _decode_core(
                 resumed, table["op_idx"], 0, keepdims=False)
             op, states = _short_conv(lp, h, mine, cfg, None)
             states = states.reshape(B, -1, 128)
+        elif kind == "sliding_attention":
+            q, k, v = _kind_attn_in(lp, h, cfg, kind, positions[:, None])
+            with jax.named_scope("rm_window_attn"):
+                attn, pages = paged_decode_attention(
+                    q[:, 0], k[:, 0].astype(window_pool.dtype),
+                    v[:, 0].astype(window_pool.dtype),
+                    window_pool.reshape(_pages_shape(window_pool, page_size)),
+                    window_slots, window_table, lengths, table["op_idx"],
+                    window=jnp.asarray(cfg.sliding_window, jnp.int32))
+            window_pool = pages.reshape(window_pool.shape)
+            op = _kind_attn_out(lp, attn[:, None], h, cfg)
+        elif cfg.heads_by_type:
+            q, k, v = _kind_attn_in(lp, h, cfg, kind, positions[:, None])
+            with jax.named_scope("rm_full_attn"):
+                attn, pages = paged_decode_attention(
+                    q[:, 0], k[:, 0].astype(kv_pool.dtype),
+                    v[:, 0].astype(kv_pool.dtype), kv_pool.reshape(pages_shape),
+                    slots, page_table, lengths, table["op_idx"])
+            kv_pool = pages.reshape(pool_shape)
+            op = _kind_attn_out(lp, attn[:, None], h, cfg)
         else:
             q, k, v = _attn_in(lp, h, cfg, positions[:, None], one_rope, None)
             scale = None
@@ -2744,10 +2979,16 @@ def _decode_core(
         x = x + op
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         y, aux = _ffn(params, lp, l_idx, h2, cfg, "decode", live, sparse)
-        return (x + y, kv_pool), {"aux": aux, "state": states}
+        return (x + y, kv_pool, window_pool), {"aux": aux, "state": states}
 
+    if cfg.heads_by_type:
+        (x, kv_pool, window_pool), ys = _scan_layers(
+            cfg, params, mixed_layer, (x, kv_pool, window_pool))
+        return (_logits(params, cfg, x)[:, 0], kv_pool, window_pool,
+                *_moe_aux(cfg, ys.get("aux"), moe_stats, "decode"))
     if cfg.has_conv:
-        (x, kv_pool), ys = _scan_layers(cfg, params, mixed_layer, (x, kv_pool))
+        (x, kv_pool, _), ys = _scan_layers(
+            cfg, params, mixed_layer, (x, kv_pool, None))
         states = ys["state"]  # [Lc, B, rows, 128]
         state_pool = state_pool.at[:, state_slots.reshape(-1)].set(
             jnp.concatenate([states, states], axis=1))

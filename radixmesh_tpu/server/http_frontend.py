@@ -821,6 +821,11 @@ class ServingFrontend:
                         * eng.pool.kv.dtype.itemsize),
                     "bytes_per_slot": eng.pool.state_slot_bytes,
                 }
+            if eng.tail_stats is not None:
+                # A model whose resume points are window tails alone: how
+                # requests resumed, what the tree took and gave up.
+                state["tail"] = eng.tail_stats | {
+                    "evictions": tree.state_evictions}
             if eng.window_stats is not None:
                 # Two groups of rows (a model whose window layers keep
                 # their own): each group's slots and the most ever taken,

@@ -167,6 +167,37 @@ class TestMemoryPressure:
         for p, o in zip(prompts, outs):
             assert o == oracle_generate(cfg, params, p, 6)
 
+    @pytest.mark.parametrize("num_slots", [1024, 4096])
+    def test_one_pressure_frees_a_share_of_the_pool(self, model, num_slots):
+        """A pool that runs full would otherwise walk the whole tree for
+        every page: the walk that finds room frees a 64th of the pool (or
+        what is asked, if that is more), so few allocations walk at all."""
+        cfg, params = model
+        rng = prompts_rng()
+        eng = make_engine(model, num_slots=num_slots, max_batch=2)
+        calls = []
+        evict = eng.tree.evict
+
+        def counted(n, **kw):
+            freed = evict(n, **kw)
+            calls.append((n, freed))
+            return freed
+
+        eng.tree.evict = counted
+        share = num_slots // 64
+        n_req = num_slots // 16 + 8  # a page and a half each: the pool fills
+        prompts = [rng.integers(0, cfg.vocab_size, 16).tolist()
+                   for _ in range(n_req)]
+        outs = eng.generate(prompts, SamplingParams(max_new_tokens=6))
+        assert outs[-1] == oracle_generate(cfg, params, prompts[-1], 6)
+        assert calls and all(asked >= share for asked, _ in calls)
+        freed = sum(f for _, f in calls)
+        # Each walk freed about what it was asked for (a leaf is a page or
+        # two here), so the walks are one for every `share` slots consumed
+        # and not one an allocation.
+        assert len(calls) <= freed // share + 1
+        assert len(calls) < n_req // 2
+
     def test_all_slots_recovered_after_reset(self, model):
         eng = make_engine(model)
         rng = prompts_rng()
@@ -946,3 +977,88 @@ class TestHandOver:
                 assert h[key] == n, (key, h[key], n)
         # settled after every step, no advance ever ran behind a launch
         assert settled_eng.handover["advances_deferred"] == 0
+
+
+class TestTailOnlyResume:
+    """A model that keeps a window group and no state (``laguna-tiny``):
+    what the tree holds for a resume is a tail without a state slot."""
+
+    @pytest.fixture(scope="class")
+    def eng_of(self):
+        cfg, params = preset_model("laguna-tiny")
+
+        def make(**kw):
+            return Engine(cfg, params, num_slots=2048, page_size=16, max_batch=2,
+                          max_seq_len=512, prefill_chunk=64, **kw)
+
+        return make
+
+    @staticmethod
+    def prompt(n, seed):
+        return np.random.default_rng([7, seed]).integers(0, 512, n).tolist()
+
+    def test_attach_match_evict_free(self, eng_of):
+        eng = eng_of()
+        assert eng.state_stats is None and eng.tail_stats is not None
+        assert eng.pool.state is None and eng.pool.window is not None
+        p = self.prompt(70, 0)
+        out = eng.generate([p], SamplingParams(max_new_tokens=30))[0]
+        m = eng.tree.match_prefix(np.asarray(p + out, np.int32))
+        # the prompt's cut and the release's boundary, no slot at either
+        assert m.states == [(64, None), (96, None)]
+        assert [len(t) for t in m.tails] == [1, 1]  # a window = one page
+        assert eng.tail_stats["snapshots"] == 2
+        held = eng.pool.window_slots_used
+        assert 0 < held <= eng.window_stats["tail_slots_in_tree"] == 32
+        # a second turn resumes from the deeper one: counted, not copied
+        p2 = p + out + self.prompt(9, 1)
+        req = eng.add_request(p2, SamplingParams(max_new_tokens=4))
+        while eng.has_work():
+            eng.step()
+        assert req.prefix_len == 96 and eng.stats.cached_tokens == 96
+        assert eng.tail_stats["resumes"] == eng.tail_stats["matches"] == 1
+        # evicted under the rows: every count returns, the rows stay
+        assert eng.tree.evict_states(16) > 0
+        assert eng.pool.window_slots_used == 0
+        assert eng.window_stats["tail_slots_in_tree"] == 0
+        assert eng.pool.window_refs.sum() == 1
+        assert eng.tree.match_prefix(np.asarray(p2, np.int32)).length >= 96
+
+    def test_a_cancelled_admission_returns_its_window_pages(self, eng_of):
+        eng = eng_of()
+        p = self.prompt(50, 2)
+        req = eng.add_request(p, SamplingParams(max_new_tokens=40))
+        while req.kv_len < 60:
+            eng.step()
+            eng._settle()
+        assert req.w_pages and eng.pool.window_slots_used > 0
+        assert eng.cancel(req.rid)
+        eng.tree.reset()
+        assert eng.pool.window_slots_used == 0 and eng.pool.window_refs.sum() == 1
+
+    def test_the_debug_state_publishes_the_tail_group(self, eng_of):
+        from radixmesh_tpu.server.http_frontend import ServingFrontend
+        import json
+        import urllib.request
+
+        eng = eng_of()
+        fe = ServingFrontend(eng, port=0)
+        try:
+            p = self.prompt(40, 3)
+            for prompt in (p, p + [1, 2, 3]):
+                body = json.dumps({"input_ids": prompt, "max_tokens": 20}).encode()
+                urllib.request.urlopen(urllib.request.Request(
+                    f"http://127.0.0.1:{fe.port}/generate", data=body,
+                    headers={"Content-Type": "application/json"}), timeout=120).read()
+            state = json.loads(urllib.request.urlopen(
+                f"http://127.0.0.1:{fe.port}/debug/state", timeout=30).read())
+        finally:
+            fe.close(drain_s=0.0)
+        assert "state" not in state
+        assert state["tail"]["matches"] == state["tail"]["resumes"] == 1
+        assert state["tail"]["snapshots"] >= 2 and state["tail"]["evictions"] == 0
+        assert state["kv"]["groups"]["window"]["bytes_per_token"] == 2 * 3 * 2 * 32 * 4
+        assert state["window"]["pages_released"] > 0
+        assert state["attn"]["full_tokens_read"] > 0
+        assert {"rm.window.release", "rm.state.restore", "rm.state.snapshot"} <= set(
+            state["engine"]["spans"])

@@ -409,3 +409,28 @@ def test_engine_prefills_count_their_pages():
     eng.generate([list(range(100, 138))], greedy)  # 38 tokens: 16 + 16 + 6
     assert eng._last_prefill_path == "chunk"
     assert eng.pool.write_stats == {"launches": 4, "pages": 13, "edge_pages": 2}
+
+
+def test_a_window_group_without_state_slots_counts_its_pages():
+    """The window group of a model that carries no state: pages under
+    counts, a tail's count beside a row's, the scratch page nobody's."""
+    from radixmesh_tpu.cache.kv_pool import PagedKVPool
+
+    pool = PagedKVPool(num_slots=256, num_layers=2, num_kv_heads=2, head_dim=32,
+                       page_size=16, dtype=jnp.float32,
+                       window_shape=(2, 3, 2, 128, 32))
+    assert pool.state is None and pool.state_allocator is None
+    assert pool.window.shape == (2, 3, 2, 128, 32) and pool.window_slots == 128
+    assert pool.window_slots_used == 0 and pool.window_refs[0] == 1
+    row = pool.alloc_window(3)
+    assert 0 not in row and pool.window_slots_used == 48
+    tail = row[1:]
+    pool.ref_window(tail)  # a snapshot's count on the same pages
+    assert pool.unref_window(row) is not None  # the row leaves
+    assert pool.window_slots_used == 32  # the tail still holds two
+    pool.unref_window(tail)
+    assert pool.window_slots_used == 0 and pool.window_refs.sum() == 1
+    assert pool.window_used_peak == 48
+    with pytest.raises(ValueError, match="double free"):
+        pool.unref_window(tail)
+    assert pool.alloc_window(8) is None  # seven pages beside the scratch page

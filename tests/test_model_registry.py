@@ -28,6 +28,8 @@ _SIZES = {
     "mellum2-12b-a2.5b-l12": (5.4, 5.55),  # 12 layers + embedding and head
     "glm-4.7-flash": (29.6, 30.2),  # 46 sparse layers + 1 dense, no MTP module
     "glm-4.7-flash-l8": (5.15, 5.18),  # 9.62 GiB in bf16
+    "laguna-xs.2": (33.40, 33.48),  # the card's "33.4B": a head-wise gate
+    "laguna-xs.2-l5": (3.865, 3.875),  # 7.21 GiB in bf16
 }
 
 # The model's public config.json (zai-org/GLM-4.7-Flash), as the catalog of
@@ -278,3 +280,56 @@ def test_tied_embeddings_matches_transformers(tmp_path):
     np.testing.assert_allclose(
         np.asarray(ours[0], np.float32), theirs, rtol=2e-4, atol=2e-4
     )
+
+
+def test_laguna_presets_are_the_source_key_by_key():
+    """The 40-layer preset against the catalog's copy of the source's
+    config, key by key; the one-chip cut keeps its first five layers and
+    nothing else differs; the benchmark's configuration file states the
+    same numbers (``benchmarks/tests/test_laguna_cell.py`` holds the file
+    to the catalog's row)."""
+    import json
+    import os
+
+    from radixmesh_tpu.models.llama import unsupported_reason
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            here, "benchmarks", "configs", "laguna-xs.2-bf16-l5.json")) as fh:
+        src = json.load(fh)
+    period = ["full_attention"] + ["sliding_attention"] * 3
+    cfg = get_config("laguna-xs.2")
+    assert cfg.n_layers == src["published"]["num_hidden_layers"] == 40
+    assert list(cfg.layer_types) == period * 10
+    assert (cfg.hidden, cfg.intermediate) == (src["hidden_size"], src["intermediate_size"])
+    assert (cfg.n_kv_heads, cfg.head_dim) == (
+        src["num_key_value_heads"], src["head_dim"])
+    assert dict(cfg.heads_by_type) == {
+        "full_attention": src["num_attention_heads"], "sliding_attention": 64}
+    assert cfg.n_heads == 64  # the most any kind has
+    assert (cfg.n_experts, cfg.n_experts_per_tok, cfg.moe_intermediate) == (
+        src["num_experts"], src["num_experts_per_tok"], src["moe_intermediate_size"])
+    assert cfg.n_shared_experts == 1 and cfg.first_k_dense == 1
+    assert cfg.router_scoring == "sigmoid" and cfg.norm_topk_prob
+    assert cfg.routed_scaling_factor == src["moe_routed_scaling_factor"]
+    assert cfg.attn_gate is src["gating"]
+    assert cfg.sliding_window == src["sliding_window"]
+    assert cfg.vocab_size == src["vocab_size"]
+    assert cfg.rms_eps == src["rms_norm_eps"]
+    assert cfg.qkv_bias is src["attention_bias"]
+    assert cfg.tie_embeddings is src["tie_word_embeddings"]
+    rope = {k: dict(v) for k, v in cfg.rope_by_type}
+    for kind, want in src["rope_parameters"].items():
+        if isinstance(want, dict):
+            assert {**rope[kind], "partial_rotary_factor":
+                    rope[kind].get("partial_rotary_factor", 1)} == want
+    assert cfg.max_seq_len == 16384  # served; the source declares 262144
+    cut = get_config("laguna-xs.2-l5")
+    assert cut == cfg.replace(n_layers=5, layer_types=tuple(period + period[:1]))
+    assert list(cut.layer_types) == src["layer_types"]
+    tiny = get_config("laguna-tiny")
+    assert tiny.layer_types == cut.layer_types and tiny.first_k_dense == 1
+    assert dict(tiny.heads_by_type) == {"full_attention": 6, "sliding_attention": 8}
+    for c in (cfg, cut, tiny):
+        assert c.window_layers and c.state_shape(4) is None
+        assert "resumed from a tail" in unsupported_reason(c, kv_quant="int8")

@@ -504,3 +504,82 @@ class TestFingerprintBuckets:
             (n for n in a._all_nodes() if n is not a.root),
             key=lambda n: len(n.chain),
         )) in hb
+
+
+# ---------------------------------------------------------------------------
+# a resume point without a state slot: a window tail and nothing else
+# ---------------------------------------------------------------------------
+
+
+def _tail_tree(**kw):
+    refs = {}
+
+    def free_tail(tail):
+        for p in tail.tolist():
+            refs[p] -= 1
+
+    tree = RadixTree(page_size=4, on_free_tail=free_tail, **kw)
+
+    def tail(*pages):
+        for p in pages:
+            refs[p] = refs.get(p, 0) + 1
+        return np.asarray(pages, np.int32)
+
+    return tree, refs, tail
+
+
+def test_a_tail_alone_is_a_resume_point():
+    tree, refs, tail = _tail_tree()
+    assert tree.on_free_state is None
+    key = ids(24)
+    tree.insert(key, np.arange(100, 124, dtype=np.int32))
+    t24 = tail(7, 8)
+    assert tree.attach_state(key, None, t24)
+    assert not tree.attach_state(key, None, tail(9))  # it has one
+    refs[9] -= 1  # declined: the count stays the caller's, who returns it
+    assert not tree.attach_state(key[:8], None, None)  # nothing to hold
+    assert tree.attach_state(key[:8], None, tail(3))  # splits at 8
+    m = tree.match_prefix(key)
+    assert m.states == [(8, None), (24, None)] and m.tail_at(24) is t24
+    assert list(m.tail_at(8)) == [3] and m.tail_at(16) is None
+    # the deepest resume point at or before a limit; nothing: position 0
+    assert m.state_at_or_before(23) == (8, None)
+    assert m.state_at_or_before(24) == (24, None)
+    assert m.state_at_or_before(7) == (0, None)
+    node = m.last_node
+    assert node.resumable and node.state is None
+
+
+@pytest.mark.parametrize("how", ["evict_states", "evict", "reset", "pinned"])
+def test_every_way_a_tail_goes_its_counts_return_to_zero(how):
+    tree, refs, tail = _tail_tree()
+    key = ids(24)
+    tree.insert(key, np.arange(100, 124, dtype=np.int32))
+    assert tree.attach_state(key, None, tail(7, 8))
+    assert tree.attach_state(key[:8], None, tail(3, 7))  # page 7: two tails
+    assert refs == {7: 2, 8: 1, 3: 1}
+    if how == "evict_states":
+        assert tree.evict_states(5) == 2
+        assert tree.match_prefix(key).length == 24  # the rows stay
+    elif how == "pinned":
+        tree.inc_lock_ref(tree.match_prefix(key).last_node)  # a live row
+        assert tree.evict_states(5) == 0  # plain: unlocked only
+        # the superseded one first, although locked
+        assert tree.evict_states(1, pinned=set()) == 1
+        assert tree.match_prefix(key).states == [(24, None)]
+        assert tree.evict_states(1, pinned=set()) == 1
+    elif how == "evict":
+        tree.evict(24)
+        assert tree.match_prefix(key).length == 0
+    else:
+        tree.reset()
+    assert tree.match_prefix(key).states == []
+    assert set(refs.values()) == {0}
+    assert tree.state_evictions == (2 if how in ("evict_states", "pinned") else 0)
+
+
+def test_a_tree_without_either_callback_tracks_no_resume_point():
+    tree = make_tree()
+    tree.insert(ids(8), ids(8))
+    assert tree.match_prefix(ids(8)).states == []
+    assert not tree._tracks_states
