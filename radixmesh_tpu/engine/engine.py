@@ -185,10 +185,12 @@ _UNDER_DECODE = "flushes_under_decode"
 _UNDER_PREFILL = "flushes_under_prefill"
 _EXPOSED = "flushes_exposed"
 # Why a decode step's advance ran with the device waiting (keys of
-# ``Engine.handover["settled_for"]``): a row of the step finished; a
-# request was about to be admitted, cancelled or preempted; the launch, or
-# what follows it, is not a plain decode launch of the same rows; the pool
-# had no page for the launch built ahead.
+# ``Engine.handover["settled_for"]``): a row of the step sampled a stop id,
+# or every row reached its length cap (rows that end by length beside rows
+# that go on do not settle: ``Engine._settle_why``); a request was about to
+# be admitted, cancelled or preempted; the launch, or what follows it, is
+# not a plain decode launch of the same rows; the pool had no page for the
+# launch built ahead.
 _SETTLED_FOR = ("finish", "admit", "cancel", "preempt", "launch_kind", "no_pages")
 
 
@@ -218,10 +220,15 @@ _NO_GROUPS = {"groups": 0, "members": 0, "passes": 0, "shared_tokens": 0}
 class _DecodeLaunch:
     """The inputs of one decode launch (``Engine._build_launch``), kept
     with what they were built for: ``sig`` = (row, rid, position the
-    row's token is written at) of every live row."""
+    row's token is written at) of every row of the launch — the live
+    rows, less ``ending``."""
 
     active: list[tuple[int, Request]]
     sig: list[tuple[int, int, int]]
+    # Rows left out of a launch built ahead: the launch on the device
+    # takes each to its length cap. They sit in ``Engine._rows`` until
+    # that launch's advance has run, and hold the scratch values here.
+    ending: frozenset[int]
     idx: np.ndarray  # the live rows, as an index
     slots: np.ndarray  # [max_batch] slot each row's K/V lands in
     max_len: int  # longest context before the launch
@@ -493,12 +500,15 @@ class Engine:
         # were built behind the launch before it / in the gap before this
         # one. advances_deferred / _settled: a step's per-row advance ran
         # behind the next launch / with the device waiting, the latter by
-        # what forced it (keys of _settle's ``why``).
+        # what forced it (keys of _settle's ``why``). finishes_deferred:
+        # requests that a deferred advance finished — released and
+        # published behind a launch they are no row of.
         self.handover = {
             "launches_prebuilt": 0,
             "launches_rebuilt": 0,
             "advances_deferred": 0,
             "advances_settled": 0,
+            "finishes_deferred": 0,
             "settled_for": dict.fromkeys(_SETTLED_FOR, 0),
         }
         # The last step's tokens while their advance waits for the next
@@ -1189,6 +1199,8 @@ class Engine:
         for req in self._rows:
             if req is not None and req.rid == rid:
                 self._settle("cancel")  # its last token is part of its output
+                if req.state is RequestState.FINISHED:
+                    return False  # that token took it to its length cap
                 req.cancelled = True
                 self._flush_emit(_EXPOSED, only=req)
                 req.state = RequestState.FINISHED
@@ -1381,18 +1393,23 @@ class Engine:
         of convoying them.
 
         A decode step's tokens are in ``output_tokens`` when the step
-        returns or, between two plain decode launches of the same rows,
-        once the NEXT launch is on the device: such a step leaves its
-        per-row advance pending (``_decode_once``), and the next step
-        sends its launch — built behind this one — before it advances
-        this step's rows. A step in which a request finishes, and every
-        launch that is not the plain one, advances its rows before it
-        returns; :meth:`_settle` makes it so at any time, and everything
-        that reads or changes the rows calls it first. The tokens' stream
-        wake-ups, timeline entries and output counter
-        (:meth:`_flush_emit`) run in the next step too, once its launch is
-        on the device — or at the end of this one when it leaves no row
-        to decode. First tokens and finishes wake their waiters at once.
+        returns or, between two plain decode launches, once the NEXT
+        launch is on the device: such a step leaves its per-row advance
+        pending (``_decode_once``), and the next step sends its launch —
+        built behind this one — before it advances this step's rows. A
+        row that this step takes to its length cap (``max_new_tokens``,
+        ``max_seq_len``: known before the launch is sent) is no row of
+        that next launch, and is finished, released and published behind
+        it, with the advance: until then it sits in its row, RUNNING. A
+        step in which a row samples one of its stop ids or after which
+        no row goes on, and every launch that is not the plain one,
+        advances its rows before it returns; :meth:`_settle` makes it so
+        at any time, and everything that reads or changes the rows calls
+        it first. The tokens' stream wake-ups, timeline entries and
+        output counter (:meth:`_flush_emit`) run in the next step too,
+        once its launch is on the device — or at the end of this one
+        when it leaves no row to decode. First tokens wake their waiters
+        at once, a finish when its advance runs.
 
         Host spans (``self.spans``: each a profiler annotation, inert
         without a capture, and an always-on count and seconds):
@@ -1403,7 +1420,9 @@ class Engine:
         ``rm.tree.publish``) — the same names in every decode launch
         path, so a reader of a capture never needs to know which ran. A
         fast step reads ``dispatch, sample, consume`` (the step
-        before's), ``build`` (the next launch's), ``emit, sync``."""
+        before's, ``rm.tree.publish`` inside it where that step took a
+        row to its length cap), ``build`` (the next launch's), ``emit,
+        sync``; a step that settles ends ``sync, consume``."""
         launched = self.prefill["tokens_launched"]
         acct = self.process_account
         pauses = acct and (
@@ -3069,11 +3088,12 @@ class Engine:
         return out
 
     def _decode_pt_bucket(
-        self, headroom: int, floor: int = 4
+        self, active: list[tuple], headroom: int, floor: int = 4
     ) -> np.ndarray:
         """Length-bucketed page-table slice for one decode launch: wide
-        enough for every active row's context plus ``headroom`` tokens,
-        bucketed to a power of two. A mixed batch must not pay the
+        enough for the context of every row of the launch (``active``)
+        plus ``headroom`` tokens, bucketed to a power of two. A mixed
+        batch must not pay the
         ``max_seq_len``-wide table on every step — short rows were
         attending (masked) over the full 8k-table width, which is THE
         wide-workload TTFT collapse (VERDICT round-3 weak #2 / next-step
@@ -3082,11 +3102,8 @@ class Engine:
         go through blockwise chunk attention (its page blocks must divide
         the table width)."""
         need = 1
-        for req in self._rows:
-            if req is not None:
-                need = max(
-                    need, (req.kv_len + headroom - 1) // self.page_size + 1
-                )
+        for _, req in active:
+            need = max(need, (req.kv_len + headroom - 1) // self.page_size + 1)
         maxp = min(
             _pow2_at_least(need, floor=floor),
             self._page_table_padded.shape[1],
@@ -3126,8 +3143,8 @@ class Engine:
                     g if spec else 0, drafts, sources, inline=inline_allot
                 )
                 return
-        seeded = self._seeded_launch(self._rows)
-        active = self._live_rows()
+        active = self._going_on()
+        seeded = self._seeded_launch(r for _, r in active)
         plain = self._plain_launch(active, seeded)
         launch = self._take_prebuilt(active) if plain else None
         if launch is not None:
@@ -3243,7 +3260,7 @@ class Engine:
                 req.logit_rows.append(logits_h[row])
                 self._keep_expert_ids(req, req.kv_len, row, 1)
                 self._keep_selected(req, req.kv_len, row)
-        nxt, by_length, stops = None, False, ()
+        nxt, goes_on, stops = None, False, ()
         if seeded:
             # The canonical per-row (seed, position) draw syncs per row:
             # sample and sync are one span here.
@@ -3264,24 +3281,30 @@ class Engine:
                     sampled_d = sample_tokens(logits, None, draws=False)
             if plain:
                 # The device is busy with this launch: the step before's
-                # advance, then the next launch's inputs as if every row
-                # had advanced by one — unless a row is about to reach its
-                # length cap, which is known before the sync.
+                # advance — the release and the tree publish of the rows
+                # it took to their length cap included — then the next
+                # launch's inputs, as if every row had advanced by one.
+                # That launch is of the rows that go on: which rows this
+                # one takes to a length cap is known before the sync.
                 pend, self._pending = self._pending, None
                 if pend is not None:
-                    self.handover["advances_deferred"] += 1
+                    h, finished = self.handover, self.stats.finished
+                    h["advances_deferred"] += 1
                     self._consume(*pend)
-                by_length = any(
-                    len(r.output_tokens) + 1 >= r.sampling.max_new_tokens
+                    h["finishes_deferred"] += self.stats.finished - finished
+                ending = frozenset(
+                    row for row, r in active
+                    if len(r.output_tokens) + 1 >= r.sampling.max_new_tokens
                     or r.num_tokens + 1 >= self.max_seq_len
-                    for _, r in active
                 )
-                if not by_length:
+                goes_on = len(ending) < len(active)
+                if goes_on:
                     with self.spans.span("rm.decode.build"):
-                        nxt = self._build_launch(1, preempt=False)
+                        nxt = self._build_launch(1, preempt=False, ending=ending)
+                if nxt is not None:
                     stops = [
                         (row, r.sampling.stop_token_ids)
-                        for row, r in active
+                        for row, r in nxt.active
                         if r.sampling.stop_token_ids
                     ]
             # Wake-ups last: the handlers take the GIL from nothing.
@@ -3306,11 +3329,12 @@ class Engine:
         ))
         self._prebuilt = nxt
         step = (active, launch.slots, sampled, stamp)
-        why = self._settle_why(plain, by_length, nxt, stops, sampled)
+        why = self._settle_why(plain, goes_on, nxt, stops, sampled)
         if why is None:
             # The hand-over: the ids the next launch feeds, nothing else —
             # in self._tokens, and for that launch as they lie on the
-            # device (an idle row's id is read by nothing).
+            # device (the id of an idle row, or of one that ends here, is
+            # read by nothing).
             self._tokens[nxt.idx] = sampled[nxt.idx]
             nxt.tokens_d = sampled_d
             self._pending = step
@@ -3333,21 +3357,25 @@ class Engine:
         )
 
     @staticmethod
-    def _settle_why(plain, by_length, nxt, stops, sampled) -> str | None:
+    def _settle_why(plain, goes_on, nxt, stops, sampled) -> str | None:
         """THE rule for the hand-over between two decode launches, from
         what the launch and its rows are (no knob): None — keep the
         step's advance for behind the next launch — iff the launch is
-        plain (:meth:`_plain_launch`), the next launch's inputs are built
-        (``nxt``) and no row finishes: none reaches a length cap
-        (``by_length``, known before the sync) and none of the rows that
-        have stop ids (``stops``) sampled one. Else the key of
-        ``handover["settled_for"]`` to count the step under: it settles
-        now, in the order every step had before, so a finished row never
-        rides another launch."""
+        plain (:meth:`_plain_launch`), a row goes on (``goes_on``), the
+        next launch's inputs are built (``nxt``) and no row finishes
+        that the next launch holds. A row that reaches a length cap is
+        known before this launch is sent, so it is not in ``nxt``
+        (``_DecodeLaunch.ending``) and its finish — the release, the tree
+        publish — waits with the advance; a row that sampled one of its
+        stop ids (``stops``: the rows of ``nxt`` that have any) is known
+        only after the sync. Else the key of ``handover["settled_for"]``
+        to count the step under: it settles now, in the order every step
+        had before PR 30. Either way a finished row never rides another
+        launch."""
         if not plain:
             return "launch_kind"
-        if by_length:
-            return "finish"
+        if not goes_on:
+            return "finish"  # every row ends by length: nothing to build
         if nxt is None:
             return "no_pages"
         if any(int(sampled[row]) in ids for row, ids in stops):
@@ -3355,12 +3383,18 @@ class Engine:
         return None
 
     def _build_launch(
-        self, ahead: int, preempt: bool, chunked: bool = False
+        self, ahead: int, preempt: bool, chunked: bool = False,
+        ending: frozenset[int] = frozenset(),
     ) -> _DecodeLaunch | None:
         """The inputs of the decode launch that writes position ``kv_len +
         ahead`` of every live row: 0 for the rows as they are, 1 behind a
         launch that is still on the device, as if each row had advanced
-        by one. ``preempt`` is the ordinary build's: a row the pool has
+        by one. The rows that launch takes to their length cap
+        (``ending``) are none of this one's: no page, window page or
+        state slot is taken for them, and they hold an idle row's scratch
+        values, so what the launch on the device leaves of them — rows,
+        state, window tail — is what their release hands to the tree.
+        ``preempt`` is the ordinary build's: a row the pool has
         no page for is preempted. A build ahead never preempts — it
         returns None and the ordinary build decides once the rows are
         settled; the pages it had taken by then stay with their requests
@@ -3375,7 +3409,7 @@ class Engine:
         w_slots = np.zeros(self.max_batch, dtype=np.int32)
         starved: list[Request] = []
         for row, req in enumerate(self._rows):
-            if req is None:
+            if req is None or row in ending:
                 continue
             page_idx, offset = divmod(req.kv_len + ahead, ps)
             page = self._ensure_page(row, req, page_idx)
@@ -3400,7 +3434,7 @@ class Engine:
                     state_slots[1, row] = req.state_boundary
         for req in starved:
             self._preempt(req)
-        active = self._live_rows()
+        active = self._live_rows(ending)
         if not active:
             return None
         idx = np.fromiter((row for row, _ in active), np.intp, len(active))
@@ -3412,8 +3446,8 @@ class Engine:
                 int(read.sum()) * self._sliding_layers,
                 int((ctx - read).sum()) * self._sliding_layers,
             )
-        pt = (self._decode_pt_bucket(1 + ahead, floor=_KV_BLOCK_PAGES)
-              if chunked else self._decode_pt_bucket(1 + ahead))
+        pt = (self._decode_pt_bucket(active, 1 + ahead, floor=_KV_BLOCK_PAGES)
+              if chunked else self._decode_pt_bucket(active, 1 + ahead))
         if windowed:
             held = [len(r.w_pages) * ps for _, r in active]
             ws = self.window_stats
@@ -3433,6 +3467,7 @@ class Engine:
         return _DecodeLaunch(
             active=active,
             sig=[(row, r.rid, r.kv_len + ahead) for row, r in active],
+            ending=ending,
             idx=idx,
             slots=slots,
             max_len=int(lengths[idx].max()) - 1,
@@ -3469,8 +3504,10 @@ class Engine:
 
     def _take_prebuilt(self, active: list[tuple]) -> _DecodeLaunch | None:
         """The inputs built ahead, iff they were built for exactly these
-        rows at the positions their tokens go to now (one further while
-        the last step's advance is pending); they are used once."""
+        rows — the rows that go on (:meth:`_going_on`), not the live ones:
+        a row the pending advance ends is none of theirs — at the
+        positions their tokens go to now (one further while the last
+        step's advance is pending); they are used once."""
         pre, self._prebuilt = self._prebuilt, None
         if pre is None:
             return None
@@ -3495,11 +3532,13 @@ class Engine:
     def _settle(self, why: str = "launch_kind") -> None:
         """Run the pending advance now (idempotent; nothing pending:
         nothing done). Everything that reads or changes the rows other
-        than a plain decode launch of the same rows calls this first —
-        admission into a free row, ``cancel`` / ``cancel_all``, the
-        drains, ``_preempt``, a wave or a speculative launch,
+        than a plain decode launch of the rows that go on calls this
+        first — admission into a free row, ``cancel`` / ``cancel_all``,
+        the drains, ``_preempt``, a wave or a speculative launch,
         ``generate()``'s end — so each sees the rows as they were when
-        every step advanced its own. ``why`` (a key of
+        every step advanced its own: a row the pending step took to its
+        length cap is finished and gone (``cancel`` looks again at the
+        request it was called for). ``why`` (a key of
         ``handover["settled_for"]``) says what forced it; the default is
         for whatever follows that is not such a launch."""
         pend, self._pending = self._pending, None
@@ -3621,8 +3660,20 @@ class Engine:
                 return hist[j : j + gamma]
         return hist[:0]
 
-    def _live_rows(self) -> list[tuple[int, "Request"]]:
-        return [(row, r) for row, r in enumerate(self._rows) if r is not None]
+    def _live_rows(
+        self, less: frozenset[int] = frozenset()
+    ) -> list[tuple[int, "Request"]]:
+        return [(row, r) for row, r in enumerate(self._rows)
+                if r is not None and row not in less]
+
+    def _going_on(self) -> list[tuple[int, "Request"]]:
+        """The rows the next decode launch is of: the live rows, less
+        those the pending advance ends (the launch built behind that
+        step says which: they stay in ``_rows`` until the advance has
+        run)."""
+        if self._pending is None:
+            return self._live_rows()
+        return self._live_rows(self._prebuilt.ending)
 
     def _ensure_page(self, row: int, req: Request, page_idx: int) -> int | None:
         """The pool page behind entry ``page_idx`` of the row's table,

@@ -24,7 +24,7 @@ import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-KEEP = ("prefill", "spans", "slow_steps", "decode_steps", "handover")
+KEEP = ("prefill", "spans", "slow_steps", "decode_steps", "finished", "handover")
 
 
 def main() -> int:

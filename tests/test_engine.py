@@ -411,7 +411,7 @@ class TestSamplerLaunchRule:
         drawer = eng.add_request(
             short_p, SamplingParams(max_new_tokens=4, temperature=0.8)
         )
-        while drawer.state is not RequestState.FINISHED:
+        while len(drawer.output_tokens) < 3:  # its fourth is made, and pending
             before = dict(eng.sampler)
             eng.step()
             # Every launch that held the drawing row drew: its first-token
@@ -419,14 +419,19 @@ class TestSamplerLaunchRule:
             assert eng.sampler["argmax_launches"] == before["argmax_launches"]
             assert eng.sampler["drawing_launches"] > before["drawing_launches"]
         row = next(i for i, t in enumerate(eng._temps) if t > 0)
+        # It ends by its length: it sits in its row until the advance has
+        # run, behind the next launch — which is of the greedy row alone.
+        assert eng._rows[row] is drawer and eng._pending is not None
+        assert drawer.state is RequestState.RUNNING
         drew = eng.sampler["drawing_launches"]
         steps = 0
         while eng.has_work():
             eng.step()
             steps += 1
             # The finished request's row is empty and still holds its
-            # temperature: the rule reads the live rows, not ``_temps``.
+            # temperature: the rule reads the launch's rows, not ``_temps``.
             assert eng._rows[row] is None
+            assert drawer.state is RequestState.FINISHED
             assert eng._temps[row] == np.float32(0.8)
         assert steps > 5 and greedy.state is RequestState.FINISHED
         assert eng.sampler["drawing_launches"] == drew
@@ -737,11 +742,14 @@ class TestDeferredEmission:
 
 class TestHandOver:
     """Between two plain decode launches the engine does no more than
-    "sync, did a row finish?, launch": the next launch's inputs are built
-    behind the one on the device (``_build_launch(1, ...)``) and a step's
-    per-row advance runs behind the next launch (``_pending``), unless a
-    row finishes or anything but a plain launch of the same rows follows
-    (``Engine._settle``). Each case runs once as served and once settled
+    "sync, did a row sample a stop id?, launch": the next launch's inputs
+    are built behind the one on the device (``_build_launch(1, ...)``),
+    for the rows that go on — a row that reaches its length cap is known
+    before the launch is sent and is none of the next one's — and a
+    step's per-row advance, the release and publish of the rows it ends
+    by length included, runs behind the next launch (``_pending``),
+    unless a row samples a stop id, no row goes on, or anything but a
+    plain launch of those rows follows (``Engine._settle``). Each case runs once as served and once settled
     after every step — every step's advance in the step, as before — and
     the two agree on tokens, finish state, stats, tree and pool; greedy
     unseeded rows also agree with the float32 oracle, and
@@ -759,10 +767,12 @@ class TestHandOver:
         while eng.has_work():
             tick()
         return reqs, {
-            # d1 d4 d6 are built in the gap (first step; rows left), d3 d5
-            # d8 end a request: known before the sync, nothing built ahead
-            "launches_rebuilt": 3, "launches_prebuilt": 5,
-            "advances_deferred": 5, "settled_for": {"finish": 3},
+            # d1 is built in the gap (first step). d3 and d5 end a request
+            # beside rows that go on: d4 and d6 are built ahead without it
+            # and sent before it is released. d8 ends the last row.
+            "launches_rebuilt": 1, "launches_prebuilt": 7,
+            "advances_deferred": 7, "finishes_deferred": 2,
+            "settled_for": {"finish": 1},
         }
 
     @staticmethod
@@ -859,16 +869,18 @@ class TestHandOver:
                 for k, n, m in (("a", 6, 4), ("b", 9, 20), ("c", 7, 4))}
         assert eng.max_batch == 2
         while reqs["c"].state is not RequestState.FINISHED:
-            tick()  # c took a's row when a finished
+            tick()  # c took a's row once a's advance had freed it
         assert reqs["b"].state is RequestState.RUNNING
         tick()
         if served:
             assert eng._pending is not None
-        # a free row and an advance pending: admission settles first
+        # a free row and an advance pending: admission settles first (c's
+        # did too); a and c ended behind a launch of b, and b or d ends last
         reqs["d"] = eng.add_request(rng.integers(1, 500, 8).tolist(), sp(5))
         while eng.has_work():
             tick()
-        return reqs, {"settled_for": {"admit": 1, "finish": 4}}
+        return reqs, {"finishes_deferred": 3,
+                      "settled_for": {"admit": 2, "finish": 1}}
 
     @staticmethod
     def _seeded(eng, tick, served, model, rng):
@@ -885,8 +897,10 @@ class TestHandOver:
             assert eng.handover["advances_deferred"] == 2
         while eng.has_work():
             tick()
+        # a ends by length beside s: its advance waits, and settles for
+        # the all-seeded launch that follows, not for the finish
         return reqs, {"advances_deferred": 2, "launches_prebuilt": 2,
-                      "settled_for": {"launch_kind": 6, "finish": 1}}
+                      "finishes_deferred": 0, "settled_for": {"launch_kind": 7}}
 
     @staticmethod
     def _return_logits(eng, tick, served, model, rng):
@@ -909,6 +923,161 @@ class TestHandOver:
         return reqs, {"advances_deferred": 3, "launches_prebuilt": 3,
                       "settled_for": {"launch_kind": 4, "finish": 1}}
 
+
+    @staticmethod
+    def _beside(eng, tick, served, model, rng):
+        # a ends by length in d3; the build behind d3 crosses into a fresh
+        # page for b (position 12) — and would for a (position 8), were a
+        # not left out of it
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(),
+                                   SamplingParams(max_new_tokens=m))
+                for k, n, m in (("a", 5, 4), ("b", 9, 9))}
+        a, b = reqs["a"], reqs["b"]
+        for _ in range(3):
+            tick()
+        assert eng._page_table[b.row, 3] != eng._scratch_page
+        if served:
+            # a's last token is made and its advance pending: it sits in
+            # its row, holds what d3 left it, and is no row of d4
+            assert a.state is RequestState.RUNNING and eng._rows[a.row] is a
+            assert len(a.output_tokens) == 3 and eng.stats.finished == 0
+            row, nxt = a.row, eng._prebuilt
+            assert nxt.ending == {row} and [r for r, _ in nxt.active] == [b.row]
+            assert eng._page_table[row, 2] == eng._scratch_page
+            assert nxt.slots[row] == eng._scratch_slot
+            tick()  # d4 goes out, then a is advanced, released, published
+            assert a.state is RequestState.FINISHED and eng._rows[row] is None
+            assert eng.handover["finishes_deferred"] == 1
+        while eng.has_work():
+            tick()
+        return reqs, {"launches_rebuilt": 1, "finishes_deferred": 1,
+                      "settled_for": {"finish": 1}}  # b, the last row
+
+    @staticmethod
+    def _all_end(eng, tick, served, model, rng):
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(),
+                                   SamplingParams(max_new_tokens=5))
+                for k, n in zip("ab", (6, 10))}
+        while eng.has_work():
+            tick()
+            # no row goes on after d4: nothing is built, and it settles
+            assert eng._prebuilt is None or eng.stats.finished == 0
+        return reqs, {"finishes_deferred": 0, "launches_prebuilt": 3,
+                      "settled_for": {"finish": 1}}
+
+    @staticmethod
+    def _stop_and_length(eng, tick, served, model, rng):
+        p = rng.integers(1, 500, 10).tolist()
+        want = oracle_generate(*model, p, 12)
+        cut = next(i for i in range(3, 12) if want[i] not in want[:i])
+        # d<cut> makes a's stop id and b's last token; c goes on
+        reqs = {
+            "a": eng.add_request(p, SamplingParams(
+                max_new_tokens=12, stop_token_ids=(want[cut],))),
+            "b": eng.add_request(rng.integers(1, 500, 7).tolist(),
+                                 SamplingParams(max_new_tokens=cut + 1)),
+            "c": eng.add_request(rng.integers(1, 500, 6).tolist(),
+                                 SamplingParams(max_new_tokens=cut + 4)),
+        }
+        a, b = reqs["a"], reqs["b"]
+        while a.state is not RequestState.FINISHED:
+            tick()
+        # the stop id is known only after the sync: both finish in the gap,
+        # and the launch built ahead for a and c is dropped
+        assert b.state is RequestState.FINISHED and eng._pending is None
+        assert a.output_tokens == want[:cut] and len(b.output_tokens) == cut + 1
+        while eng.has_work():
+            tick()
+        return reqs, {"finishes_deferred": 0, "launches_rebuilt": 2,
+                      "settled_for": {"finish": 2}}
+
+    @staticmethod
+    def _cancel_beside(eng, tick, served, model, rng):
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(),
+                                   SamplingParams(max_new_tokens=m))
+                for k, n, m in (("a", 6, 4), ("b", 11, 12), ("c", 7, 12))}
+        a, b = reqs["a"], reqs["b"]
+        for _ in range(3):
+            tick()
+        if served:
+            assert eng._pending is not None and a.state is RequestState.RUNNING
+        # a's own advance takes it to its cap: it is finished, not cancelled
+        assert not eng.cancel(a.rid)
+        assert a.state is RequestState.FINISHED and not a.cancelled
+        assert len(a.output_tokens) == 4 and eng._pending is None
+        assert eng.cancel(b.rid) and len(b.output_tokens) == 4
+        while eng.has_work():
+            tick()
+        return reqs, {"finishes_deferred": 0,
+                      "settled_for": {"cancel": 1, "finish": 1}}
+
+    @staticmethod
+    def _preempt_beside(eng, tick, served, model, rng):
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(),
+                                   SamplingParams(max_new_tokens=m))
+                for k, n, m in (("a", 6, 4), ("b", 11, 8))}
+        a, b = reqs["a"], reqs["b"]
+        for _ in range(3):
+            tick()
+        if served:
+            assert eng._pending is not None and eng._prebuilt.ending == {a.row}
+        eng._preempt(b)  # settles first: a ends with its tokens, b starts over
+        assert a.state is RequestState.FINISHED and len(a.output_tokens) == 4
+        assert b.state is RequestState.QUEUED and eng.stats.preemptions == 1
+        while eng.has_work():
+            tick()
+        return reqs, {"finishes_deferred": 0,
+                      "settled_for": {"preempt": 1, "finish": 1}}
+
+    @staticmethod
+    def _successor(eng, tick, served, model, rng):
+        sp = lambda n: SamplingParams(max_new_tokens=n)  # noqa: E731
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(), sp(m))
+                for k, n, m in (("a", 6, 4), ("b", 9, 14), ("c", 7, 4))}
+        a, c = reqs["a"], reqs["c"]
+        assert eng.max_batch == 2
+        for _ in range(3):
+            tick()  # d3 makes a's last token
+        joined = 0
+        while c.state is RequestState.QUEUED:
+            tick()
+            joined += 1
+        # the row is free when a's advance has run, behind d4: c joins in
+        # the step after, one later than where every step is settled
+        assert joined == (2 if served else 1) and c.row >= 0
+        assert a.state is RequestState.FINISHED
+        while eng.has_work():
+            tick()
+        return reqs, {"finishes_deferred": 2,
+                      "settled_for": {"admit": 1, "finish": 1}}
+
+    @staticmethod
+    def _two_steps(eng, tick, served, model, rng):
+        sp = lambda n: SamplingParams(max_new_tokens=n)  # noqa: E731
+        reqs = {k: eng.add_request(rng.integers(1, 500, n).tolist(), sp(m))
+                for k, n, m in (("a", 6, 4), ("b", 9, 5), ("c", 7, 16),
+                                ("d", 5, 6), ("e", 8, 6))}
+        assert eng.max_batch == 3
+        for _ in range(3):
+            tick()  # d3 makes a's last token; d4 will make b's
+        waves = eng.prefill["waves"]
+        joined = []
+        for _ in range(3):
+            tick()
+            joined.append([k for k in "de"
+                           if reqs[k].state is not RequestState.QUEUED])
+        # a's row is free before the admission that settles d4, b's is
+        # freed BY that settle: both are filled by that one admission, in
+        # one wave, where a run that settles every step fills them in two
+        assert joined == ([[], ["d", "e"], ["d", "e"]] if served
+                          else [["d"], ["d", "e"], ["d", "e"]])
+        assert eng.prefill["waves"] == waves + (1 if served else 2)
+        while eng.has_work():
+            tick()
+        # a, then d and e beside c, end behind a launch; b in the settle
+        return reqs, {"finishes_deferred": 3,
+                      "settled_for": {"admit": 1, "finish": 1}}
+
     CASES = {
         "finish_by_length": ({}, "_length"),
         "finish_by_stop_id": ({}, "_stop"),
@@ -919,6 +1088,13 @@ class TestHandOver:
         "admission_into_a_freed_row": ({"max_batch": 2}, "_admission"),
         "seeded_row_joined": ({}, "_seeded"),
         "return_logits_row": ({}, "_return_logits"),
+        "finisher_beside_a_row_crossing_a_page": ({}, "_beside"),
+        "every_live_row_ends": ({}, "_all_end"),
+        "stop_id_and_length_cap_in_one_launch": ({}, "_stop_and_length"),
+        "cancel_with_a_finish_pending": ({}, "_cancel_beside"),
+        "preempt_with_a_finish_pending": ({}, "_preempt_beside"),
+        "successor_of_a_deferred_finish": ({"max_batch": 2}, "_successor"),
+        "finishers_of_two_steps_join_in_one_wave": ({"max_batch": 3}, "_two_steps"),
     }
 
     def _run(self, model, case, served):

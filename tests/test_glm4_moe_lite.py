@@ -514,7 +514,10 @@ def test_rows_on_one_published_prefix_decode_through_one_shared_pass(model):
     asked = [(shared + prompt_of(5 + i, seed=10 + i), n)
              for i, n in enumerate((9, 5, 9))]
     asked.append((prompt_of(21, seed=20), 3))  # shares nothing, leaves first
-    late = (shared[:BLOCK] + prompt_of(9, seed=30), 6)  # splits the node
+    # splits the node; it joins the step after the one that frees the row
+    # (the row's request ends by its length, behind the next launch), so
+    # five tokens end it one step before the two long members
+    late = (shared[:BLOCK] + prompt_of(9, seed=30), 5)
 
     def alone(prompt, n):
         eng = make_engine(model)

@@ -18,6 +18,7 @@ import time
 import urllib.request
 
 import jax
+import numpy as np
 import pytest
 
 from radixmesh_tpu.engine.engine import Engine
@@ -115,6 +116,41 @@ def served(request, tmp_path_factory):
         for r in reqs:
             f.runner.wait(r, timeout=60)
 
+    def stopping():
+        """Traffic in which a stop id ends a request: two requests
+        admitted in one wave, of which one samples its stop id — learned
+        here, by a run of the same prompt to its length — in the step
+        before the other's last."""
+        while True:
+            k = 1000 * next(rounds)
+            # ids inside the vocabulary: the reply then varies
+            ids = np.random.default_rng(k).integers(
+                1, cfg.vocab_size, 20).tolist()
+            with f.runner._lock:
+                probe = eng.add_request(ids, SamplingParams(max_new_tokens=6))
+            f.runner._wake.set()
+            f.runner.wait(probe, timeout=60)
+            out = list(probe.output_tokens)
+            # not its first decode step: that one is built in the gap
+            cut = next((i for i in range(2, 6) if out[i] not in out[:i]), None)
+            if cut is not None:
+                break
+
+        def run():
+            with f.runner._lock:
+                reqs = [
+                    eng.add_request(ids, SamplingParams(
+                        max_new_tokens=6, stop_token_ids=(out[cut],))),
+                    eng.add_request(list(range(k + 100, k + 120)),
+                                    SamplingParams(max_new_tokens=cut + 2)),
+                ]
+            f.runner._wake.set()
+            for r in reqs:
+                f.runner.wait(r, timeout=60)
+            assert reqs[0].output_tokens == out[:cut]
+
+        return run
+
     def capture(query="", more=None):
         got = {}
         t = threading.Thread(target=lambda: got.update(
@@ -129,8 +165,10 @@ def served(request, tmp_path_factory):
 
     traffic()  # compiles
     staggered(3, 4, 6)
+    stopping()()
     yield {"frontend": f, "engine": eng, "base": base, "traffic": traffic,
-           "staggered": staggered, "capture": capture, "profile_dir": profile_dir}
+           "staggered": staggered, "stopping": stopping, "capture": capture,
+           "profile_dir": profile_dir}
     f.close()
 
 
@@ -180,10 +218,12 @@ def test_no_capture_leaves_no_trace_and_counts_every_admission(served):
 
 
 def test_capture_holds_every_span_nested_as_documented(served):
-    # besides the round's traffic, three requests of which two end one
-    # step apart: the second one's last step is built in the gap AND ends
-    # a request
-    lines = served["capture"](more=lambda: served["staggered"](3, 4, 6))
+    # besides the round's traffic, three requests of which two end by
+    # their length one step apart, beside one that goes on; and two of
+    # which one samples its stop id one step before the other's last
+    stop_traffic = served["stopping"]()
+    lines = served["capture"](
+        more=lambda: (served["staggered"](3, 4, 6), stop_traffic()))
     engine = max(lines, key=lambda evs: sum(n == "rm.engine.step" for n, _, _ in evs))
     spans = [e for e in engine if e[0].startswith("rm.")]
     names = {n for n, _, _ in spans}
@@ -202,20 +242,28 @@ def test_capture_holds_every_span_nested_as_documented(served):
     assert any(inside(s, "rm.decode.consume") for s in publishes)
     assert all(inside(s, "rm.engine.step") for s in publishes)
     # A decode step's phases follow each other inside one step, in one of
-    # two orders (as on the chip). FAST, between two plain launches of the
-    # same rows: the launch goes out with inputs built behind the launch
-    # before it; behind it run the step BEFORE's advance, the next
-    # launch's build and the step before's emission; then the wait.
-    # SETTLED, the order every step had before (here: built in the gap
-    # because a row had left, and ending a request, which is known before
-    # the sync): nothing is built ahead and the advance follows the wait.
-    orders = []
+    # two orders (as on the chip). FAST, between two plain launches: the
+    # launch goes out with inputs built behind the launch before it;
+    # behind it run the step BEFORE's advance, the next launch's build and
+    # the step before's emission; then the wait. A step that takes a row
+    # to its length cap beside rows that go on is such a step: the next
+    # launch is built without that row, and the row's release and tree
+    # publish are in the NEXT step's consume. SETTLED, the order every
+    # step had before (here: built in the gap because a row that sampled
+    # its stop id had left, and ending the last row): nothing is built
+    # ahead and the advance follows the wait.
+    orders, deferred_finishes = [], 0
     for step in (p for p in spans if p[0] == "rm.engine.step"):
         phases = sorted((s for s in spans if s[0].startswith("rm.decode.")
                          and step[1] <= s[1] and s[2] <= step[2]),
                         key=lambda s: s[1])
         assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
         order = [s[0].rsplit(".", 1)[1] for s in phases]
+        fast = ["dispatch", "sample", "consume", "build", "emit", "sync"]
+        if order == fast and any(
+                s[0] == "rm.tree.publish" and phases[2][1] <= s[1]
+                and s[2] <= phases[2][2] for s in spans):
+            deferred_finishes += 1
         if order:
             orders.append(order)
             # whatever the order, one dispatch, the sampler behind it, the
@@ -225,7 +273,19 @@ def test_capture_holds_every_span_nested_as_documented(served):
             i = order.index("dispatch")
             assert order[i + 1] == "sample" and "sync" in order[i + 2:]
     assert ["dispatch", "sample", "consume", "build", "emit", "sync"] in orders
-    assert ["build", "dispatch", "sample", "emit", "sync", "consume"] in orders
+    # the requests of 3 and of 4 tokens were released behind the launch
+    # after their last: between its dispatch and its sync
+    assert deferred_finishes >= 2
+    # the last live row ends: nothing to build, the step settles, and its
+    # own tokens are emitted at its end (no launch follows to hide them)
+    assert ["dispatch", "sample", "consume", "emit", "sync", "consume",
+            "emit"] in orders
+    # a stop id is known only after the sync: built ahead, settled all the
+    # same; the launch after it is built in the gap, and ends the last row
+    assert ["dispatch", "sample", "consume", "build", "emit", "sync",
+            "consume"] in orders
+    assert ["build", "dispatch", "sample", "emit", "sync", "consume",
+            "emit"] in orders
     # a request's first decode step: built in the gap, and again ahead
     assert ["build", "dispatch", "sample", "build", "sync"] in orders
     # the stream handler's spans are on another line

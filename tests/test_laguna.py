@@ -303,6 +303,48 @@ def test_plain_launches_keep_the_tail(model):
     assert cold.generate([p2], sp)[0] == o2
 
 
+# 15: the launch that makes its last token completes page 4 (kv_len 64), so
+# the tail its release hands over is that launch's; 11: kv_len 60
+@pytest.mark.parametrize("n_new", [15, 11], ids=["on_a_page", "inside_a_page"])
+def test_a_row_that_ends_by_length_is_released_behind_the_next_launch(model, n_new):
+    """A request reaches its length cap while another goes on (70 tokens:
+    its window slides, and gives pages back, all the while): the next
+    launch goes out first, without it, and it is advanced, released and
+    published behind that launch. What its release gives the tree is what
+    its OWN last launch left: its next turn resumes there and equals the
+    reference (logits, not tokens), the other row's too, and the run
+    agrees with one that settles after every step."""
+    pa, pb = prompt_of(50, seed=20), prompt_of(37, seed=21)
+
+    def serve(settled):
+        eng = make_engine(model)
+        a = eng.add_request(pa, SamplingParams(max_new_tokens=n_new))
+        b = eng.add_request(pb, SamplingParams(max_new_tokens=70))
+        while eng.has_work():
+            eng.step()
+            if settled:
+                eng._settle()
+        assert eng.handover["finishes_deferred"] == (0 if settled else 1)
+        turns = [pa + a.generated + prompt_of(7, seed=22),
+                 pb + b.generated + prompt_of(5, seed=23)]
+        return eng, [a, b] + [run(eng, t) for t in turns], turns
+
+    eng, reqs, turns = serve(settled=False)
+    cut = (50 + n_new - 1) // PAGE * PAGE
+    assert reqs[2].prefix_len == cut and reqs[3].prefix_len == 96
+    assert eng.window_stats["pages_released"] > 0
+    for req, turn in zip(reqs[2:], turns):
+        check(model, req, turn)
+    eng2, reqs2, _ = serve(settled=True)
+    assert [r.generated for r in reqs] == [r.generated for r in reqs2]
+    assert [r.prefix_len for r in reqs] == [r.prefix_len for r in reqs2]
+    assert eng.tail_stats == eng2.tail_stats
+    assert eng.window_stats == eng2.window_stats
+    assert eng.pool.window_slots_used == eng2.pool.window_slots_used
+    assert eng.pool.free_slots == eng2.pool.free_slots
+    assert eng.tree.evictable_size_ == eng2.tree.evictable_size_
+
+
 def test_pressure_on_the_window_group_evicts_tails_not_rows(model):
     """A window group with room for one prompt's prefill and nothing else:
     the tree's tails go, no request fails or is preempted, the full

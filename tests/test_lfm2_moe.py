@@ -316,6 +316,45 @@ def test_plain_launches_keep_the_boundary_state(model):
     check_against_reference(model, req, p2 + o2 + prompt_of(3, seed=16))
 
 
+# 15: the launch that makes its last token completes page 4 (kv_len 64), so
+# the boundary state its release hands over is that launch's; 11: kv_len 60
+@pytest.mark.parametrize("n_new", [15, 11], ids=["on_a_page", "inside_a_page"])
+def test_a_row_that_ends_by_length_is_released_behind_the_next_launch(model, n_new):
+    """A request reaches its length cap while another goes on: the next
+    launch goes out first, without it, and it is advanced, released and
+    published behind that launch. What its release gives the tree is what
+    its OWN last launch left: its next turn resumes at that snapshot and
+    equals the reference (logits, not tokens), the other row's too, and
+    the run agrees with one that settles after every step."""
+    pa, pb = prompt_of(50, seed=20), prompt_of(37, seed=21)
+
+    def serve(settled):
+        eng = make_engine(model)
+        a = eng.add_request(pa, SamplingParams(max_new_tokens=n_new))
+        b = eng.add_request(pb, SamplingParams(max_new_tokens=40))
+        while eng.has_work():
+            eng.step()
+            if settled:
+                eng._settle()
+        assert eng.handover["finishes_deferred"] == (0 if settled else 1)
+        turns = [pa + a.generated + prompt_of(7, seed=22),
+                 pb + b.generated + prompt_of(5, seed=23)]
+        return eng, [a, b] + [run(eng, t) for t in turns], turns
+
+    eng, reqs, turns = serve(settled=False)
+    cut = (50 + n_new - 1) // PAGE * PAGE
+    assert reqs[2].prefix_len == cut and reqs[3].prefix_len == 64
+    for req, turn in zip(reqs[2:], turns):
+        check_against_reference(model, req, turn)
+    eng2, reqs2, _ = serve(settled=True)
+    assert [r.generated for r in reqs] == [r.generated for r in reqs2]
+    assert [r.prefix_len for r in reqs] == [r.prefix_len for r in reqs2]
+    assert eng.state_stats == eng2.state_stats
+    assert eng.pool.state_slots_used == eng2.pool.state_slots_used
+    assert eng.pool.free_slots == eng2.pool.free_slots
+    assert eng.tree.evictable_size_ == eng2.tree.evictable_size_
+
+
 def test_resuming_from_a_zeroed_state_fails_the_tolerance(model):
     """The control: the same second turn with the state slots zeroed
     misses the reference by far more than the tolerance."""
