@@ -118,9 +118,10 @@ CHIP = {
 }
 # CPU rehearsal: same phases, tiny preset (head_dim 32 → reference path by
 # design, kernels checked in Pallas interpret mode). The tiny model's 512
-# positions cannot exceed the 1 024-token chunk threshold, so the "long"
-# prompt takes the dense path here; concurrent admission still takes the
-# chunk launch.
+# positions cannot exceed the 1 024-token chunk threshold; its "long"
+# prompt takes the chunk launches all the same, because its dense launch
+# (512 tokens) would be bound by arithmetic, not by the weights it streams
+# (``Engine._admit``, PR 47).
 REHEARSAL = {
     "model": "qwen2-tiny",
     "kv_slots": 4096,
@@ -141,7 +142,7 @@ REHEARSAL = {
     "interpret": True,
     "short": 40,
     "long": 300,
-    "long_path": "dense",
+    "long_path": "chunk",
     "turn": 24,
     "gen": 8,
     "streams": 4,

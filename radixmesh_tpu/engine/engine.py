@@ -45,6 +45,14 @@ import numpy as np
 
 from radixmesh_tpu.cache.kv_pool import PagedKVPool
 from radixmesh_tpu.cache.radix_tree import RadixTree
+from radixmesh_tpu.engine.prefill_plan import (
+    KV_BLOCK_PAGES as _KV_BLOCK_PAGES,
+    boxed_tokens,
+    launch_cost,
+    plan_prefill,
+    pow2_at_least as _pow2_at_least,
+    subwaves,
+)
 from radixmesh_tpu.engine.request import Request, RequestState, SamplingParams
 from radixmesh_tpu.models.llama import (
     ModelConfig,
@@ -88,16 +96,6 @@ def _mix64(x: int) -> int:
     return x ^ (x >> 31)
 
 
-def _pow2_at_least(n: int, floor: int = 8) -> int:
-    n = max(n, floor)
-    return 1 << (n - 1).bit_length()
-
-
-# Pages per attention KV block in every chunked launch (prefill, spec
-# verify, pp decode). Page tables are pow2-padded with this as the floor,
-# and attend_chunk_hybrid requires max_pages to divide by it — one
-# constant so the padding and the kernels can't drift apart.
-_KV_BLOCK_PAGES = 32
 # A model with conv layers: the states one row of a prefill launch may
 # leave (its working state at the row's end and two snapshots: the prompt's
 # page-aligned cut and the tree's match length), and how many of the tree's
@@ -129,43 +127,6 @@ def pool_groups(cfg: ModelConfig, num_slots: int, max_batch: int,
             cfg.default_window_slots(max_batch, page_size)),
     )
 
-
-def prefill_launch_shape(
-    rows: int,
-    new_tokens: int,
-    total_tokens: int,
-    *,
-    chunk: int,
-    page_size: int,
-    max_pages: int,
-    width_selects: bool = False,
-    one_width: bool = False,
-) -> tuple[int, int, int]:
-    """``(B, C, max_pages)`` of a chunk-prefill launch — the program key of
-    ``prefill_chunk_paged`` — for ``rows`` requests of at most
-    ``new_tokens`` uncached tokens and ``total_tokens`` in all.
-
-    A lone request takes ONE table width, the engine's own
-    (``max_pages``): the attention kernels end a row's page loop at the
-    row's length, so a table wider than the row is a few KB uploaded and
-    nothing on the device, and a row's shape then depends on the
-    power-of-two bucket of its new tokens alone — requests of two exact
-    lengths in one bucket pair (new tokens, cached prefix) never meet two
-    programs. Where the width itself chooses the program's attention
-    (``width_selects``: learned sparse attention engages on it), and for
-    a group, whose widths its callers warm by burst, the width is the
-    longest row's pages, rounded up to a power of two. ``one_width``: a
-    group too takes the engine's own width — for a model whose chunk
-    program traces several kinds of layer (conv and attention, dense and
-    sparse FFN: ~30 s a compile at published widths), where contexts of
-    2 k-14 k tokens would otherwise multiply every (rows, chunk) pair by
-    three widths."""
-    B = _pow2_at_least(rows, floor=1)
-    C = _pow2_at_least(min(new_tokens, chunk), floor=16)
-    pages = -(-total_tokens // page_size)
-    if (rows == 1 or one_width) and not width_selects:
-        pages = max_pages
-    return B, C, _pow2_at_least(pages, floor=_KV_BLOCK_PAGES)
 
 # Upper edges (ms) of ``Engine.admission_wait``'s buckets; one overflow
 # bucket follows the last.
@@ -456,16 +417,23 @@ class Engine:
         self.process_account = process_account
         if process_account is not None:
             process_account.book = self.spans
-        # prefill: the admission waves' work (_admit): members, uncached
-        # prompt tokens computed, tokens of the launched shapes, and host
-        # seconds from before the path's dispatch to after the first-token
-        # sync — no decode launch is in flight then, so a wave's wall time
-        # is its device time plus its dispatch and its sync tail.
+        # prefill: the admission waves' work (_admit): sub-waves, their
+        # members, the device launches that prefilled them, uncached
+        # prompt tokens computed, tokens of the launched shapes (the sum of
+        # B x C over the launches sent) and what the one box a sub-wave
+        # before PR 47 would have launched for the same rows
+        # (``boxed_tokens``: 1 - launched / boxed is what the planner
+        # saved, launches / waves what it paid), and host seconds from
+        # before the path's dispatch to after the first-token sync — no
+        # decode launch is in flight then, so a wave's wall time is its
+        # device time plus its dispatch and its sync tail.
         self.prefill = {
             "waves": 0,
             "rows": 0,
+            "launches": 0,
             "tokens_new": 0,
             "tokens_launched": 0,
+            "tokens_boxed": 0,
             "wall_s": 0.0,
             "by_path": {"dense": 0, "chunk": 0, "sp": 0},
         }
@@ -1062,11 +1030,32 @@ class Engine:
         # default — the wave hot paths keep the one-branch-when-off
         # contract (a single `is not None` test per wave).
         self.step_acct = None
-        # Padded-token count of the LAST prefill launch, set by whichever
-        # prefill path ran (single scheduler thread): the launch SHAPE
-        # lives inside each path, so this is how the wave accounting in
-        # _admit learns it without re-deriving bucket math.
+        # Tokens of the launched shapes and launches of the LAST sub-wave,
+        # added up by whichever prefill path ran (single scheduler thread):
+        # the launch SHAPES live inside each path, so this is how the wave
+        # accounting in _admit learns them without re-deriving bucket math.
         self._wave_padded = 0
+        self._wave_launches = 0
+        # What the wave planner weighs (engine/prefill_plan.py), from the
+        # weights this engine holds: a launch streams every leaf but the
+        # embedding table (a gather), a token meets every parameter but,
+        # of the experts held, those it is not routed to. Under pp a launch
+        # is a pipeline schedule of its own: the plan stays the box.
+        leaves = jax.tree_util.tree_flatten_with_path(self.params)[0]
+        streamed = [
+            leaf for path, leaf in leaves
+            if hasattr(leaf, "shape")
+            and (cfg.tie_embeddings or "embed" not in jax.tree_util.keystr(path))
+        ]
+        met = sum(
+            leaf.size * (cfg.n_experts_per_tok / cfg.n_experts
+                         if cfg.n_experts and leaf.ndim == 4 else 1.0)
+            for leaf in streamed)
+        self.prefill_cost = launch_cost(
+            sum(leaf.size * leaf.dtype.itemsize for leaf in streamed),
+            2.0 * met)
+        if self._pp:
+            self.prefill_cost = (float("inf"), self.prefill_cost[1])
         if step_accounting:
             from radixmesh_tpu.obs.step_plane import StepAccounting
 
@@ -1715,14 +1704,18 @@ class Engine:
 
     def _admit(self) -> None:
         """Admit waiting requests into free rows. Concurrent arrivals are
-        prefilled as ONE batched chunked-paged call + ONE batched sample
-        (VERDICT round-1 weak #5: per-request serial prefill made TTFT
-        degrade linearly with queue depth). A lone request goes the same
-        way, as a group of one, whenever the tree serves part of its
-        prompt: the chunk path reads a cached prefix where it lies, in
-        pages. Only a lone short request with NOTHING cached keeps the
-        dense single-request path (one launch of the whole prompt, no
-        page table: the smallest-latency compile variant)."""
+        prefilled together and sampled together (VERDICT round-1 weak #5:
+        per-request serial prefill made TTFT degrade linearly with queue
+        depth): the admission is cut into sub-waves by bucket of new
+        tokens, and ONE rule decides the shape of every launch that
+        prefills a sub-wave — ``plan_prefill`` (engine/prefill_plan.py),
+        which launches the tokens the rows have where a padded token costs
+        more than a launch, by a cost this engine derived from its weights
+        (``self.prefill_cost``); a lone request goes the same way, as a
+        group of one, and the chunk path reads a cached prefix where it
+        lies, in pages. Only a lone request with NOTHING cached whose one
+        dense launch is bound by the weights it streams keeps the dense
+        single-request path (``_prefill_dense``)."""
         self._pump_kv_transfer()
         if self._pressure and any(r is not None for r in self._rows):
             return
@@ -1854,37 +1847,16 @@ class Engine:
                     self._inline_rows.add(row)
                 self._admitting = []
                 continue
-            # Sub-waves by prefill-size bucket, shortest first: a short
-            # request must not ride as a padded row through a 32k
-            # groupmate's chunks, nor wait for them to sample its first
-            # token. Each sub-wave finalizes itself (one batched
-            # sample + one device→host sync), so TTFT is bounded by the
-            # request's own bucket.
-            def bucket(member):
-                # UNCAPPED size bucket: a 512-token prompt must not share a
-                # sub-wave (and its finalize) with a 32k prompt's chunk
-                # loop. (The chunk SHAPE inside _prefill_group stays capped
-                # at prefill_chunk.)
-                n_new = len(member[0].prompt) - member[2]
-                return _pow2_at_least(n_new, floor=16)
-
-            group.sort(key=bucket)
-            subwaves: list[list[tuple]] = []
-            start = 0
-            for i in range(1, len(group) + 1):
-                if i == len(group) or bucket(group[i]) != bucket(group[start]):
-                    sub = group[start:i]
-                    start = i
-                    # Slice the bucket at the compute-saturating width
-                    # (see ``prefill_wave_tokens``): slices finalize their
-                    # first tokens as they complete instead of convoying
-                    # behind the whole bucket.
-                    per_chunk = min(bucket(sub[0]), self.prefill_chunk)
-                    rows = max(1, self.prefill_wave_tokens // per_chunk)
-                    subwaves.extend(
-                        sub[j : j + rows] for j in range(0, len(sub), rows)
-                    )
-            for sub in subwaves:
+            # Sub-waves by prefill-size bucket, shortest first, each sliced
+            # at ``prefill_wave_tokens`` (``prefill_plan.subwaves``). Each
+            # sub-wave finalizes itself (one batched sample + one
+            # device→host sync), so TTFT is bounded by the request's own
+            # bucket; WHICH launches prefill it is the planner's
+            # (``_prefill_group``).
+            cut = subwaves(
+                [len(m[0].prompt) - m[2] for m in group],
+                chunk=self.prefill_chunk, wave_tokens=self.prefill_wave_tokens)
+            for sub in ([group[i] for i in rows] for rows in cut):
                 # Quantized pools always prefill through the chunked
                 # paged path: it attends the already-quantized K/V
                 # (see prefill_chunk_paged), so prefill-time logits
@@ -1898,23 +1870,30 @@ class Engine:
                 traced = [m[0].trace for m in sub if m[0].trace is not None]
                 clock = self.spans.clock
                 t_wave = clock()
-                self._wave_padded = 0
-                if (
-                    self.pool.quant is None
-                    and not self._pp
-                    and (len(sub) == 1 and self._sp_capable(sub[0]))
-                ):
-                    path = "sp"
-                    pending = [self._prefill_sp(*sub[0])]
-                elif (
+                self._wave_padded = self._wave_launches = 0
+                news = [len(m[0].prompt) - m[2] for m in sub]
+                # One request with nothing cached, on a pool and a mesh
+                # that leave the choice: the dense launch, where it is
+                # bound by the weights it streams (see ``_prefill_dense``).
+                lone_cold = (
                     self.pool.quant is None
                     and not self._pp
                     and len(sub) == 1
+                    and sub[0][2] == 0
+                )
+                dense_up_to = self.long_prefill_threshold if lone_cold else 0
+                boxed = boxed_tokens(
+                    news, chunk=self.prefill_chunk, dense_up_to=dense_up_to)
+                if lone_cold and self._sp_capable(sub[0]):
+                    path = "sp"
+                    pending = [self._prefill_sp(*sub[0])]
+                    boxed = self._wave_padded  # its one launch, as before
+                elif (
                     # A cached prefix is read where it lies, in pages (the
                     # chunk path): a dense copy of it would be gathered,
                     # padded and re-laid before every such prefill.
-                    and sub[0][2] == 0
-                    and len(sub[0][0].prompt) <= self.long_prefill_threshold
+                    news[0] <= dense_up_to
+                    and _pow2_at_least(news[0]) <= self.prefill_cost[0]
                 ):
                     path = "dense"
                     with self.spans.span("rm.prefill.dense"):
@@ -1931,16 +1910,19 @@ class Engine:
                 with self.spans.span("rm.first_token.sync"):
                     self._finalize_first_tokens(pending)
                 # The wave's work, counted always (self.prefill): real vs
-                # launched-shape tokens — each prefill path stamped its
-                # padded count (_wave_padded) — and its wall time. Step
+                # launched-shape tokens — each prefill path added up its
+                # launches (_wave_padded, _wave_launches) — beside the box
+                # of the rule before PR 47, and its wall time. Step
                 # attribution (obs/step_plane.py) is fed the same numbers.
                 dur = clock() - t_wave
-                new_tok = sum(len(m[0].prompt) - m[2] for m in sub)
+                new_tok = sum(news)
                 p = self.prefill
                 p["waves"] += 1
                 p["rows"] += len(sub)
+                p["launches"] += self._wave_launches
                 p["tokens_new"] += new_tok
                 p["tokens_launched"] += self._wave_padded
+                p["tokens_boxed"] += boxed
                 p["wall_s"] += dur
                 p["by_path"][path] += 1
                 if self.step_acct is not None:
@@ -2346,25 +2328,24 @@ class Engine:
         self.pool.ref_window(tail)
         return tail
 
-    def _chunk_states(self, group, starts, nv, B: int, first: bool):
+    def _chunk_states(self, reqs, starts, nv, B: int, first):
         """``(state_in [B], snap_at [B, 3], state_out [B, 3])`` of one
-        chunk of a prefill group: each live row resumes from its working
-        slot (its first chunk: from the tree's snapshot) and leaves its
-        state at the chunk's true end there, and wherever one of its
-        snapshots falls inside the chunk, in that snapshot's slot. What
-        is not wanted goes to slot 0."""
+        launch of a prefill group, whose batch row ``j`` computes ``nv[j]``
+        tokens of ``reqs[j]`` from position ``starts[j]``: the row resumes
+        from its working slot (in its ``first`` launch: from the tree's
+        snapshot) and leaves its state at the launch's true end there,
+        and wherever one of its snapshots falls inside the launch, in
+        that snapshot's slot. What is not wanted goes to slot 0."""
         state_in = np.zeros((B,), np.int32)
         snap_at = np.zeros((B, _STATE_SNAPS), np.int32)
         state_out = np.zeros((B, _STATE_SNAPS), np.int32)
-        for i, (req, *_) in enumerate(group):
-            if nv[i] <= 0:
-                continue
-            state_in[i] = req.state_resume if first else req.state_slot
-            snap_at[i, 0], state_out[i, 0] = nv[i], req.state_slot
-            inside = [(pos - starts[i], slot) for pos, slot in req.state_snaps
-                      if starts[i] < pos <= starts[i] + nv[i]]
-            for j, (at, slot) in enumerate(inside, start=1):
-                snap_at[i, j], state_out[i, j] = at, slot
+        for j, req in enumerate(reqs):
+            state_in[j] = req.state_resume if first[j] else req.state_slot
+            snap_at[j, 0], state_out[j, 0] = nv[j], req.state_slot
+            inside = [(pos - starts[j], slot) for pos, slot in req.state_snaps
+                      if starts[j] < pos <= starts[j] + nv[j]]
+            for k, (at, slot) in enumerate(inside, start=1):
+                snap_at[j, k], state_out[j, k] = at, slot
         return {"state_in": jnp.asarray(state_in),
                 "snap_at": jnp.asarray(snap_at),
                 "state_out": jnp.asarray(state_out)}
@@ -2642,14 +2623,24 @@ class Engine:
 
     def _prefill_dense(self, req: Request, row: int, own: np.ndarray) -> tuple:
         """Dense prefill of one request with nothing cached: the whole
-        prompt in one ``prefill_forward`` launch, no page table. (A request
-        with a cached prefix is a group of one: :meth:`_prefill_group`.)
+        prompt in one ``prefill_forward`` launch of its power-of-two
+        bucket, no page table. (A request with a cached prefix is a group
+        of one: :meth:`_prefill_group`.) ``_admit`` takes it where that one
+        launch is bound by the weights it streams (``pow2(n) <=
+        prefill_cost[0]``, up to ``long_prefill_threshold``): there no
+        plan of chunk launches can cost less than the one launch, and the
+        plainer attention shows nowhere — a model with experts, a short
+        prompt. Above that the chunk launches are faster, as measured on
+        a v5e at Qwen2-7B: 23.3 / 42.1 / 84.2 ms for 256 / 512 / 1 024
+        tokens against 24.5 / 44.5 / 90.8 here, and a 544-token prompt is
+        512 + 32, not 1 024 (PERF.md section 6, PR 47).
         Returns ``(req, final-logit device slice)`` for
         :meth:`_finalize_first_tokens`."""
         prompt = req.prompt
         n_new = len(prompt)
         s_b = _pow2_at_least(n_new)
-        self._wave_padded = s_b  # launch shape (step attribution)
+        self._wave_padded += s_b  # launch shape (step attribution)
+        self._wave_launches += 1
         tokens = np.zeros((1, s_b), dtype=np.int32)
         tokens[0, :n_new] = prompt
         positions = np.arange(s_b, dtype=np.int32)[None]
@@ -2806,7 +2797,8 @@ class Engine:
         sp = self.device_mesh.shape["sp"]
         s_b = _pow2_at_least(n, floor=max(16, sp))
         s_b = -(-s_b // sp) * sp  # shard_map needs S divisible by sp
-        self._wave_padded = s_b  # launch shape (step attribution)
+        self._wave_padded += s_b  # launch shape (step attribution)
+        self._wave_launches += 1
         tokens = np.zeros((1, s_b), dtype=np.int32)
         tokens[0, :n] = prompt
         positions = np.arange(s_b, dtype=np.int32)[None]
@@ -2827,77 +2819,91 @@ class Engine:
         return (req, logits[0, 0])
 
     def _prefill_group(self, group: list[tuple]) -> list[tuple]:
-        """Batched chunked-paged prefill for ``group`` of acquired
-        requests — one request with a cached prefix is a group of one:
-        all rows advance through ``prefill_chunk_paged`` in lockstep
-        (launch shape: :func:`prefill_launch_shape`), each chunk writing
-        K/V into the pool in place and attending blockwise via the page
-        table — a cached prefix is read where it lies, peak memory
-        O(batch · chunk · block) regardless of prompt length. Ragged
-        offsets are exact: every row carries its own positions and
-        kv-length; exhausted/padded rows ride the scratch slot. One
-        batched sample at the end → one host sync for the whole group.
+        """Chunked-paged prefill of a sub-wave: ``group`` of acquired
+        requests — one request with a cached prefix is a group of one —
+        through the launches :func:`plan_prefill` draws for their lengths
+        (engine/prefill_plan.py: the rows still live, seated in launches
+        of 2^k rows, a short tail in launches of its own), each a
+        ``prefill_chunk_paged`` call writing K/V into the pool in place
+        and attending blockwise via the page table — a cached prefix is
+        read where it lies, peak memory O(batch · chunk · block)
+        regardless of prompt length. Ragged offsets are exact: every row
+        of a launch carries its own positions and kv-length, whatever its
+        launch-mates'; padded rows ride the scratch slot. One batched
+        sample at the end → one host sync for the whole group.
 
-        The host side is one pass over the rows a wave (their new tokens
-        and slots laid side by side, padded to whole chunks); a chunk's
-        inputs are slices of that, and the page table is uploaded once."""
-        N = len(group)
+        The host side is one pass over the rows a wave (their new tokens,
+        slots and pages); a launch's inputs are slices of that, and a
+        page table is uploaded once for the launches that share it."""
         ps = self.page_size
         kv_block = _KV_BLOCK_PAGES
         reuses = np.array([g[2] for g in group], dtype=np.int64)
         totals = np.array([len(g[0].prompt) for g in group], dtype=np.int64)
         new = totals - reuses
-        B, C, maxp = prefill_launch_shape(
-            N, int(new.max()), int(totals.max()), chunk=self.prefill_chunk,
-            page_size=ps, max_pages=self.max_pages,
-            width_selects=self.cfg.dsa,
-            one_width=self._resumes,
+        floor_tokens, launch_tokens = self.prefill_cost
+        plan = plan_prefill(
+            list(zip(new.tolist(), reuses.tolist())),
+            chunk=self.prefill_chunk, page_size=ps, max_pages=self.max_pages,
+            floor_tokens=floor_tokens, launch_tokens=launch_tokens,
+            width_selects=self.cfg.dsa, one_width=self._resumes,
         )
-        n_chunks = -(-int(new.max()) // C)
-        span = n_chunks * C
-        self._wave_padded = B * span  # launch shape (step attribution)
-        toks_all = np.zeros((B, span), dtype=np.int32)
-        sl_all = np.full((B, span), self._scratch_slot, dtype=np.int32)
-        pt = np.full((B, maxp), self._scratch_page, dtype=np.int32)
-        token_slots_all = []
+        windowed = self.window_stats is not None
+        token_slots_all, pages_all, w_pages_all, w_slots_all = [], [], [], []
         for i, (req, _, reuse, prefix_slots, own) in enumerate(group):
-            toks_all[i, : new[i]] = req.prompt[reuse:]
-            sl_all[i, : new[i]] = own[: new[i]]
             ts = np.concatenate([prefix_slots, own[: new[i]]])
             token_slots_all.append(ts)
-            pt[i, : -(-totals[i] // ps)] = ts[::ps] // ps
-        pt_dev = jnp.asarray(pt)
-        windowed = self.window_stats is not None
-        if windowed:  # the rows' slots and tables in the window group
-            w_sl_all = np.zeros((B, span), dtype=np.int32)
-            wt = np.zeros((B, maxp), dtype=np.int32)
-            for i, (req, _, reuse, _, _) in enumerate(group):
-                wt[i, req.w_first:req.w_first + len(req.w_pages)] = req.w_pages
+            pages_all.append(ts[::ps] // ps)
+            if windowed:  # the row's pages and slots in the window group
+                held = req.w_first + len(req.w_pages)
+                wp = np.zeros((max(len(pages_all[i]), held),), dtype=np.int32)
+                wp[req.w_first:held] = req.w_pages
                 at = reuse + np.arange(new[i])
-                w_sl_all[i, : new[i]] = wt[i, at // ps] * ps + at % ps
-            wt_dev = jnp.asarray(wt)
+                w_pages_all.append(wp)
+                w_slots_all.append(wp[at // ps] * ps + at % ps)
+        if windowed:
             self.window_stats["slots_written"] += int(new.sum())
-        offs = np.arange(C, dtype=np.int64)
         keeps = any(g[0].sampling.return_logits for g in group)
+        tables: dict = {}  # (rows, B, width) -> the uploaded page tables
 
-        final_logits: list = [None] * N
-        for ci in range(n_chunks):
-            starts = reuses + ci * C  # [N] tokens in the pool before the chunk
-            nv = np.clip(totals - starts, 0, C)  # rows a run (kv.write)
-            live = nv > 0
+        final_logits: list = [None] * len(group)
+        for launch in plan:
+            B, C, maxp = launch.B, launch.C, launch.pages
+            idx = np.asarray(launch.rows)
+            n = len(idx)
+            done = np.asarray(launch.starts, dtype=np.int64)
+            nv = np.asarray(launch.lens, dtype=np.int64)  # rows a run (kv.write)
+            starts = reuses[idx] + done  # [n] tokens in the pool before
+            self._wave_padded += B * C  # launch shape (step attribution)
+            self._wave_launches += 1
+            toks = np.zeros((B, C), dtype=np.int32)
+            sl = np.full((B, C), self._scratch_slot, dtype=np.int32)
+            for j, i in enumerate(launch.rows):
+                req, _, _, _, own = group[i]
+                toks[j, : nv[j]] = req.prompt[starts[j] : starts[j] + nv[j]]
+                sl[j, : nv[j]] = own[done[j] : done[j] + nv[j]]
+            if (launch.rows, B, maxp) not in tables:
+                pt = np.full((B, maxp), self._scratch_page, dtype=np.int32)
+                wt = np.zeros((B, maxp), dtype=np.int32)
+                for j, i in enumerate(launch.rows):
+                    pt[j, : len(pages_all[i])] = pages_all[i]
+                    if windowed:
+                        wt[j, : len(w_pages_all[i])] = w_pages_all[i]
+                tables[launch.rows, B, maxp] = (
+                    jnp.asarray(pt), jnp.asarray(wt) if windowed else None)
+            pt_dev, wt_dev = tables[launch.rows, B, maxp]
+            offs = np.arange(C, dtype=np.int64)
             poss = np.zeros((B, C), dtype=np.int32)
-            poss[:N] = np.minimum(starts[:, None] + offs, self.max_seq_len - 1)
+            poss[:n] = np.minimum(starts[:, None] + offs, self.max_seq_len - 1)
             kvlen = np.zeros((B,), dtype=np.int32)
-            kvlen[:N] = np.where(live, starts + nv, totals)
+            kvlen[:n] = starts + nv
             # Only each row's LAST prompt position is ever sampled, and
-            # only in the chunk that holds it.
-            last = live & (starts + nv == totals)
+            # only in the launch that holds it.
+            last = starts + nv == totals[idx]
             at = np.zeros((B,), dtype=np.int32)
-            at[:N] = np.where(last, nv - 1, 0)
-            sl = sl_all[:, ci * C : (ci + 1) * C]
-            if self.cfg.latent:  # rows in pages that this chunk attends
+            at[:n] = np.where(last, nv - 1, 0)
+            if self.cfg.latent:  # rows in pages that this launch attends
                 self.attn_window["latent_prefix_tokens"] += (
-                    int(starts[live].sum()) * self.cfg.n_layers
+                    int(starts.sum()) * self.cfg.n_layers
                 )
             if self.cfg.dsa:
                 self._note_sparse(
@@ -2905,23 +2911,25 @@ class Engine:
                     (starts[:, None] + offs + 1)[offs < nv[:, None]],
                     maxp * ps + C > self.cfg.index_topk,
                 )
-            self.pool.note_runs(sl[:N, 0], nv)
+            self.pool.note_runs(sl[:n, 0], nv)
             if not self.cfg.latent:
                 cblk = self._chunk_query_tokens(C, max_pages=maxp)
                 blocks = B * (C // cblk)
                 self.attn_window["chunk_query_blocks"] += blocks
                 self.attn_window["chunk_query_blocks_skipped"] += (
                     blocks - int((-(-nv // cblk)).sum()))
+            reqs = [group[i][0] for i in launch.rows]
             states = None
             if self.state_stats is not None:
-                states = self._chunk_states(group, starts, nv, B, ci == 0)
+                states = self._chunk_states(reqs, starts, nv, B, done == 0)
             if windowed:
+                w_sl = np.zeros((B, C), dtype=np.int32)
+                for j, i in enumerate(launch.rows):
+                    w_sl[j, : nv[j]] = w_slots_all[i][done[j] : done[j] + nv[j]]
                 states = (states or {}) | {
-                    "window_slots": jnp.asarray(
-                        w_sl_all[:, ci * C : (ci + 1) * C]),
-                    "window_table": wt_dev}
+                    "window_slots": jnp.asarray(w_sl), "window_table": wt_dev}
             res = self._forward_chunk(
-                jnp.asarray(toks_all[:, ci * C : (ci + 1) * C]),
+                jnp.asarray(toks),
                 jnp.asarray(poss),
                 jnp.asarray(sl),
                 pt_dev,
@@ -2931,18 +2939,18 @@ class Engine:
                 states=states,
             )
             logits = self._commit_pool_update(res)
-            if ci == 0 and self._resuming:  # the snapshots have been read
-                for g in group:
-                    self._unpin(g[0].state_resume)
-                    g[0].state_resume = 0
-            for i in np.flatnonzero(last).tolist():
-                final_logits[i] = logits[i, 0]
+            if self._resuming:  # a row's first launch has read its snapshot
+                for req, first in zip(reqs, done == 0):
+                    if first:
+                        self._unpin(req.state_resume)
+                        req.state_resume = 0
+            for j in np.flatnonzero(last).tolist():
+                final_logits[idx[j]] = logits[j, 0]
             if keeps:  # the logits hook: expert ids, selected rows
-                for i in np.flatnonzero(live).tolist():
-                    req = group[i][0]
-                    self._keep_expert_ids(req, int(starts[i]), i, int(nv[i]))
-                    if last[i]:
-                        self._keep_selected(req, int(totals[i]) - 1, i)
+                for j, req in enumerate(reqs):
+                    self._keep_expert_ids(req, int(starts[j]), j, int(nv[j]))
+                    if last[j]:
+                        self._keep_selected(req, int(totals[idx[j]]) - 1, j)
 
         out = []
         for i, (req, row, reuse, prefix_slots, own) in enumerate(group):

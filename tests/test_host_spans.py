@@ -234,11 +234,30 @@ def test_capture_holds_every_span_nested_as_documented(served):
         return any(p[0] == parent and p[1] <= child[1] and child[2] <= p[2]
                    for p in spans)
 
+    # The capture may begin INSIDE a step (a waiter wakes on its request's
+    # finish, before the step that finished it has flushed its emission:
+    # the round before is still in its last step when this capture starts,
+    # in 3 runs of 5), and may end inside one. The step's own annotation,
+    # open across the capture's edge, is not in the capture; its children
+    # are. So what lies before the first step the capture holds, or after
+    # the last, is a cut step's, and is held to no parent.
+    steps = [s for s in spans if s[0] == "rm.engine.step"]
+    first, last = min(s[1] for s in steps), max(s[2] for s in steps)
+
+    def cut(span):
+        return span[2] <= first or last <= span[1]
+
+    def under_step(name):
+        while name is not None and name != "rm.engine.step":
+            name = ENGINE_SPANS[name]
+        return name is not None
+
     for name, parent in ENGINE_SPANS.items():
         if parent is not None:
-            mine = [s for s in spans if s[0] == name]
-            assert all(inside(s, parent) for s in mine), (name, parent)
-    publishes = [s for s in spans if s[0] == "rm.tree.publish"]
+            mine = [s for s in spans if s[0] == name
+                    and not (under_step(name) and cut(s))]
+            assert mine and all(inside(s, parent) for s in mine), (name, parent)
+    publishes = [s for s in spans if s[0] == "rm.tree.publish" and not cut(s)]
     assert any(inside(s, "rm.decode.consume") for s in publishes)
     assert all(inside(s, "rm.engine.step") for s in publishes)
     # A decode step's phases follow each other inside one step, in one of

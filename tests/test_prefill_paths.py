@@ -6,7 +6,10 @@ keeps the dense single launch; both give the tokens a cold prefill gives.
 The shape of a one-row launch is a pure function of the power-of-two
 buckets of its lengths (``prefill_launch_shape``), so two requests of one
 bucket pair never meet two programs: the benchmark's warm-up sends one
-request a bucket pair (``benchmarks/run.py::census_cover``)."""
+request a bucket pair (``benchmarks/run.py::census_cover``). Since PR 47
+a request's launches are the planner's (``plan_prefill``: a tail may
+take chunks of its own), still a function of its new tokens alone:
+``tests/test_prefill_plan.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -14,10 +17,11 @@ import numpy as np
 import pytest
 
 from benchmarks import run as bench  # the warm-up's cover (imports no jax)
-from radixmesh_tpu.engine.engine import (
-    _KV_BLOCK_PAGES,
-    Engine,
-    _pow2_at_least,
+from radixmesh_tpu.engine.engine import Engine
+from radixmesh_tpu.engine.prefill_plan import (
+    KV_BLOCK_PAGES as _KV_BLOCK_PAGES,
+    plan_prefill,
+    pow2_at_least as _pow2_at_least,
     prefill_launch_shape,
 )
 from radixmesh_tpu.engine.request import SamplingParams
@@ -158,6 +162,17 @@ def test_a_bucket_pair_has_exactly_one_one_row_shape(grid):
     assert {(b, w) for b, _, w in shapes} == {(1, width)}
     assert {c for _, c, _ in shapes} <= {
         _pow2_at_least(min(n, chunk), floor=16) for n in news}
+    # PR 47: a lone request's launches are the planner's — the same set of
+    # programs (a tail's chunks are the smaller members of it), whatever
+    # the model's cost, and again reached by the warm-up's cover.
+    for cost in ((120.0, 35.0), (1300.0, 280.0), (float("inf"), 35.0)):
+        def launched(reuse, new):
+            return {(p.B, p.C, p.pages) for p in plan_prefill(
+                [(new, reuse)], floor_tokens=cost[0], launch_tokens=cost[1], **kw)}
+        planned = set().union(*(launched(*pair) for pair in census))
+        assert planned <= shapes
+        assert planned == set().union(
+            *(launched(*pair) for pair in bench.census_cover(census, page)))
 
 
 @pytest.mark.parametrize("rows,width_selects", [(1, True), (2, False), (5, False),
@@ -165,14 +180,22 @@ def test_a_bucket_pair_has_exactly_one_one_row_shape(grid):
 def test_groups_and_selecting_widths_keep_the_longest_rows_pages(rows, width_selects):
     """A group's width, and a one-row width where the width itself engages
     learned sparse attention, are what they were: the longest row's pages
-    rounded up to a power of two (at least one attention block)."""
+    rounded up to a power of two (at least one attention block) — the
+    box's, and under the planner (PR 47) every launch's that holds
+    several rows, or one where the width selects."""
     for new, total in [(5, 9), (32, 1136), (32, 2064), (700, 5000), (9000, 9000)]:
-        B, C, maxp = prefill_launch_shape(
-            rows, new, total, chunk=512, page_size=16, max_pages=2048,
-            width_selects=width_selects)
+        kw = dict(chunk=512, page_size=16, max_pages=2048,
+                  width_selects=width_selects)
+        B, C, maxp = prefill_launch_shape(rows, new, total, **kw)
         assert B == _pow2_at_least(rows, floor=1)
         assert C == _pow2_at_least(min(new, 512), floor=16)
         assert maxp == _pow2_at_least(-(-total // 16), floor=_KV_BLOCK_PAGES)
+        wave = [(new, total - new)] + [(max(1, new - i), 0) for i in range(1, rows)]
+        for cost in ((120.0, 35.0), (1300.0, 280.0)):
+            for p in plan_prefill(wave, floor_tokens=cost[0],
+                                  launch_tokens=cost[1], **kw):
+                assert p.C <= C and p.B <= B
+                assert p.pages == (maxp if p.B > 1 or width_selects else 2048)
 
 
 # ----------------------------------------------------------------------
@@ -227,10 +250,11 @@ def test_a_grouped_wave_through_the_kernel_equals_its_rows_alone(
         model, monkeypatch):
     """Three requests admitted as ONE chunk group over a cached document,
     their launches lowered through the Pallas chunk kernel (interpreted):
-    rows of 33, 40 and 64 new tokens (one size bucket) ride four chunks of
-    16 in a launch of four rows, so the launch holds query blocks with no
-    token — the fourth row, on the scratch slot, and the last chunk of the
-    two shorter rows — which the kernel answers with zeros. Every row's
+    rows of 33, 40 and 64 new tokens (one size bucket) ride three chunks of
+    16 in a launch of four rows, and the row still live a fourth of one
+    row (the planner, PR 47), so the launches hold query blocks with no
+    token — the fourth row, on the scratch slot, and the third chunk of
+    the two shorter rows — which the kernel answers with zeros. Every row's
     tokens equal what it generates alone on the reference path, and
     ``attn.chunk_query_blocks*`` equal the host's arithmetic on the rows'
     lengths."""
@@ -261,9 +285,10 @@ def test_a_grouped_wave_through_the_kernel_equals_its_rows_alone(
     assert eng.prefill["waves"] == waves + 1 and eng._last_prefill_path == "chunk"
     assert [r.generated for r in reqs] == alone
     # A program holds the whole 16-token chunk of a row (16 x G rows <=
-    # 1 024): 4 rows x 4 chunks, less the chunks a row has tokens in.
+    # 1 024): 4 rows x 3 chunks and 1 row x 1, less the chunks a row has
+    # tokens in.
     blocks = eng.attn_window["chunk_query_blocks"] - before["chunk_query_blocks"]
     skipped = (eng.attn_window["chunk_query_blocks_skipped"]
                - before["chunk_query_blocks_skipped"])
-    assert blocks == 4 * 4
-    assert skipped == blocks - sum(-(-n // CHUNK) for n in news) == 6
+    assert blocks == 4 * 3 + 1
+    assert skipped == blocks - sum(-(-n // CHUNK) for n in news) == 3
