@@ -1,0 +1,14 @@
+"""prefix_hit_share's reader on the cell of the configuration with KDA
+linear-attention layers (cached_tokens counts resumed positions: a prefix is served only at a snapshot): the accepted
+lists of the namesake are pinned to their cells, so this cell reports
+under a name of its own."""
+from benchmarks.harness import kda_trace
+from benchmarks.harness.registry import load_module
+
+_read = load_module("layer_metrics", "prefix_hit_share").read
+
+
+def read(ctx):
+    if not kda_trace.is_cell(ctx):  # not this configuration's cell
+        return None
+    return _read(ctx)

@@ -84,6 +84,9 @@ CHIP = {
     # its first 512): six rows on 1 280 shared tokens, two on their own.
     "geometry_latent": dict(hq=20, width=640, v_width=512, page=16, batch=8,
                             max_pages=128),
+    # Ling-3.0-flash's linear-attention state: 32 heads of 128 x 128 float32,
+    # 2 MiB a row and layer, a chunk of 64 from the rows' slots.
+    "geometry_kda": dict(heads=32, dim=128, batch=4, chunk=64),
     # The chunk kernel over a prefix of several 512-token blocks, as a turn
     # of a session launches it: a chat wave (256 new on up to 3.8 k), an
     # agent turn (LFM2's two heads of 64 to a row: 4 wide heads, G = 8,
@@ -133,6 +136,7 @@ REHEARSAL = {
                             chunk=8, max_pages=8, window=12),
     "geometry_latent": dict(hq=4, width=128, v_width=32, page=4, batch=8,
                             max_pages=16, block_tokens=16),
+    "geometry_kda": dict(heads=4, dim=16, batch=3, chunk=16),
     "geometry_prefix": [
         dict(hq=7, hkv=1, head_dim=32, page=4, chunk=16, max_pages=16),
         dict(hq=8, hkv=2, head_dim=32, page=4, chunk=16, max_pages=16,
@@ -422,6 +426,7 @@ def phase_kernels(args) -> dict:
         DEFAULT_CASES,
         WINDOW_CASES,
         check_chunk_prefix,
+        check_kda,
         check_kernels,
         check_latent_decode,
     )
@@ -441,6 +446,9 @@ def phase_kernels(args) -> dict:
         {**r, "geometry": g}
         for g in m["geometry_prefix"]
         for r in check_chunk_prefix(interpret=m["interpret"], **g)
+    ] + [
+        {**r, "geometry": m["geometry_kda"]}
+        for r in check_kda(interpret=m["interpret"], **m["geometry_kda"])
     ]
     return {"results": results, "seconds": round(time.monotonic() - t0, 2)}
 

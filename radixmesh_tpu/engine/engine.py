@@ -219,6 +219,8 @@ class _DecodeLaunch:
     # device).
     groups: dict | None = None
     groups_d: jax.Array | None = None
+    # Rows whose state this launch also keeps in their boundary slot.
+    boundary_copies: int = 0
 
 
 @dataclass
@@ -504,7 +506,8 @@ class Engine:
         # latent_tokens_read) and by prefill launches of the cached part
         # (tokens already in pages that a chunk attends x layers).
         # decode_groups: the last decode launch's groups, members, passes.
-        self._moe_stats = new_moe_stats(cfg.ep_size > 1) if cfg.n_experts else None
+        self._moe_stats = (new_moe_stats(cfg.ep_size > 1, cfg.n_group > 1)
+                           if cfg.n_experts else None)
         self.attn_window = {"window_tokens_skipped": 0, "window_tokens_read": 0}
         if cfg.latent:
             self.attn_window |= {
@@ -532,9 +535,12 @@ class Engine:
         # fetched only for a request that asked for them.
         self._last_expert_ids = None
         self._last_selected = None  # aux["index_positions"] of the last launch
-        # What the paged kernels tile is the pool row: a head, or two heads
-        # of 64 side by side (ModelConfig.pair_kv_heads).
-        kernel_width = cfg.kv_row[2] if cfg.pair_kv_heads else cfg.head_dim
+        # What the paged kernels tile is the pool row: a head, two heads of
+        # 64 side by side (ModelConfig.pair_kv_heads), or the one latent row
+        # (whole 128-lane tiles whatever nope + rope a head has: 192 here,
+        # 256 there).
+        kernel_width = (cfg.kv_row[2] if cfg.pair_kv_heads or cfg.latent
+                        else cfg.head_dim)
         kernels = default_use_kernel(kernel_width)
         self.chunk_attention = "paged" if kernels else "reference"
         # ``path`` of the decode dispatch record: chip_smoke.py proves
@@ -735,6 +741,22 @@ class Engine:
             self.log.info(
                 "state slots beside the rows: %d x %d B a snapshot",
                 self.pool.state.shape[1], self.pool.state_slot_bytes)
+        # kda: a model with linear-attention layers (None otherwise), what
+        # its launches move of the state slots, by host arithmetic on the
+        # launched shapes. decode_rows: live rows of decode launches;
+        # chunk_tokens: new tokens of prefill launches; state_bytes_read /
+        # _written: whole snapshots (every kda layer's matrix and
+        # convolution inputs) the launches read and wrote — a decode launch
+        # reads and writes every row of the launched batch (idle rows: the
+        # scratch slot) and writes one more for each boundary copy, a
+        # prefill launch reads one a row and writes ``_STATE_SNAPS`` a row;
+        # boundary_copies: rows of decode launches whose token completed a
+        # page and whose state was kept in the boundary slot.
+        self.kda_stats = None
+        if cfg.has_kda:
+            self.kda_stats = {
+                "decode_rows": 0, "chunk_tokens": 0, "state_bytes_read": 0,
+                "state_bytes_written": 0, "boundary_copies": 0}
         # window: a model whose window layers keep a group of rows of their
         # own (None otherwise). pages_released: pages a live row gave up
         # because they left its window; slots_written: rows the launches
@@ -745,6 +767,10 @@ class Engine:
         # against eviction until its first chunk has read them.
         self.window_stats = None
         self._resuming: collections.Counter = collections.Counter()
+        # Snapshots of megabytes (a window tail, a kda layer's matrices)
+        # are given up in order — superseded ones first, never one a
+        # request is about to resume from — and a few at a time.
+        self._ordered_evict = cfg.has_kda or self.pool.window is not None
         if self.pool.window is not None:
             self.window_stats = {
                 "slots": self.pool.window_slots,
@@ -2100,7 +2126,7 @@ class Engine:
             # The rows alone serve no prefix: the request resumes at the
             # deepest snapshot at or before them, and computes from there.
             reuse, resume = match.state_at_or_before(matched)
-            if resume and self.window_stats is not None:
+            if resume and self._ordered_evict:
                 # Pinned before anything below can evict it: there a lock
                 # does not keep a snapshot (``_evict_states``).
                 self._resuming[resume] += 1
@@ -2129,7 +2155,7 @@ class Engine:
                     self.pool.free(own)
                     own = None
         if own is None:
-            if self.window_stats is not None and resume and not req.state_resume:
+            if self._ordered_evict and resume and not req.state_resume:
                 self._unpin(resume)
             self.tree.dec_lock_ref(req.lock_node)
             req.lock_node = None
@@ -2150,11 +2176,11 @@ class Engine:
 
     def _evict_states(self, n: int) -> int:
         """Pressure on the snapshots. With a window group a snapshot is 23
-        MiB, not 240 KiB, and a session's path holds one or two a turn
-        under its lock: there the locked ones may go too, superseded ones
-        first, all but those a request is about to resume from
-        (``RadixTree.evict_states``)."""
-        if self.window_stats is None:
+        MiB, with kda layers 12 MiB, not 240 KiB, and a session's path
+        holds one or two a turn under its lock: there the locked ones may
+        go too, superseded ones first, all but those a request is about to
+        resume from (``RadixTree.evict_states``)."""
+        if not self._ordered_evict:
             return self.tree.evict_states(n)
         return self.tree.evict_states(min(n, _TAIL_EVICT_BATCH),
                                       pinned=self._resuming)
@@ -2603,9 +2629,20 @@ class Engine:
             kw["window_pool"] = self.pool.window
             if self.pool.state is not None:
                 kw["state_pool"] = (self.pool.state, self.pool.state_conv)
+        elif self.pool.state_conv is not None:
+            kw["state_pool"] = (self.pool.state, self.pool.state_conv)
         elif self.pool.state is not None:
             kw["state_pool"] = self.pool.state
         return kw
+
+    def _note_kda(self, read: int = 0, written: int = 0, **counts) -> None:
+        """``kda``'s counters for one launch that read and wrote so many
+        whole snapshots."""
+        st = self.kda_stats
+        for name, n in counts.items():
+            st[name] += n
+        st["state_bytes_read"] += read * self.pool.state_slot_bytes
+        st["state_bytes_written"] += written * self.pool.state_slot_bytes
 
     def _note_sparse(self, site: str, ctx: np.ndarray, engaged: bool) -> None:
         """``attn.sparse[site]`` for queries that have ``ctx`` rows each to
@@ -2685,6 +2722,8 @@ class Engine:
                        * self.page_size + at % self.page_size)
             self.pool.write_window(w_slots, win_k[:, 0, :n_new], win_v[:, 0, :n_new])
             self.window_stats["slots_written"] += n_new
+        if self.kda_stats is not None:
+            self._note_kda(chunk_tokens=n_new, written=_STATE_SNAPS)
         if self.state_stats is not None:
             states, *aux = aux
             self.pool.write_state(
@@ -2903,7 +2942,7 @@ class Engine:
             at[:n] = np.where(last, nv - 1, 0)
             if self.cfg.latent:  # rows in pages that this launch attends
                 self.attn_window["latent_prefix_tokens"] += (
-                    int(starts.sum()) * self.cfg.n_layers
+                    int(starts.sum()) * len(self.cfg.attn_layers)
                 )
             if self.cfg.dsa:
                 self._note_sparse(
@@ -2922,6 +2961,9 @@ class Engine:
             states = None
             if self.state_stats is not None:
                 states = self._chunk_states(reqs, starts, nv, B, done == 0)
+            if self.kda_stats is not None:
+                self._note_kda(chunk_tokens=int(nv.sum()), read=B,
+                               written=B * _STATE_SNAPS)
             if windowed:
                 w_sl = np.zeros((B, C), dtype=np.int32)
                 for j, i in enumerate(launch.rows):
@@ -3089,6 +3131,8 @@ class Engine:
             out, self.pool.kv, self.pool.window, *states = res
             if states:  # a model that carries a state beside both groups
                 self.pool.state, self.pool.state_conv = states[0]
+        elif self.pool.state_conv is not None:
+            out, self.pool.kv, (self.pool.state, self.pool.state_conv) = res
         elif self.pool.state is not None:
             out, self.pool.kv, self.pool.state = res
         else:
@@ -3185,11 +3229,16 @@ class Engine:
         aw["window_tokens_read"] += launch.window[0]
         aw["window_tokens_skipped"] += launch.window[1]
         if launch.latent_tokens:
-            attended = int(launch.contexts.sum()) * self.cfg.n_layers
+            attended = int(launch.contexts.sum()) * len(self.cfg.attn_layers)
             aw["latent_tokens_read"] += launch.latent_tokens
             aw["latent_tokens_attended"] += attended
             aw["latent_tokens_shared"] += attended - launch.latent_tokens
             aw["decode_groups"] = launch.groups
+        if self.kda_stats is not None:
+            self._note_kda(
+                decode_rows=len(launch.idx), read=self.max_batch,
+                written=self.max_batch + launch.boundary_copies,
+                boundary_copies=launch.boundary_copies)
         if self.window_stats is not None:
             aw[self._kv_reads] += int(launch.contexts.sum()) * self._kv_readers
             self.window_stats["slots_written"] += len(launch.idx)
@@ -3471,7 +3520,7 @@ class Engine:
                     self.cfg.dsa and pt.shape[1] * ps + 1 > self.cfg.index_topk):
                 groups, groups_d = self._grouping(active, pt, lengths, idx)
                 latent_tokens -= groups["shared_tokens"]
-            latent_tokens *= self.cfg.n_layers
+            latent_tokens *= len(self.cfg.attn_layers)
         return _DecodeLaunch(
             active=active,
             sig=[(row, r.rid, r.kv_len + ahead) for row, r in active],
@@ -3487,6 +3536,7 @@ class Engine:
             lengths_d=jnp.asarray(lengths),
             positions_d=jnp.asarray(lengths - 1) if chunked else None,
             state_d=jnp.asarray(state_slots) if stateful else None,
+            boundary_copies=int(np.count_nonzero(state_slots[1])),
             w_slots_d=jnp.asarray(w_slots) if windowed else None,
             w_pt_d=jnp.asarray(
                 self._w_page_table_padded[:, : pt.shape[1]]) if windowed else None,
@@ -4131,8 +4181,9 @@ class Engine:
         (``Request.note_progress``), and each launch's latency samples,
         step-plane wave and trace spans. None of it is read by a launch,
         so the decode paths call this once the NEXT launch is on the
-        device and before they wait for it — the handler threads then
-        take the GIL while the chip is busy. A token is never held across
+        device and before they wait for it — the streams' writer (the
+        frontend's one emitter thread, or a waiter's handler thread) then
+        takes the GIL while the chip is busy. A token is never held across
         a wait: a step that prefills flushes behind that prefill's
         dispatch, a step that leaves no row flushes at its end, and
         preemption / cancel flush the request concerned (``only``; the

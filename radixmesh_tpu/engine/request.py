@@ -181,14 +181,26 @@ class Request:
     cond: threading.Condition = field(
         default_factory=threading.Condition, repr=False, compare=False
     )
+    # Who is told of a new token in place of the waiters on ``cond``
+    # (None: the waiters are). An SSE stream sets it to its emitter's
+    # wake-up (server/http_frontend.py::_StreamEmitter): a launch of 64
+    # rows then wakes one thread that writes 64 sockets, not 64 threads
+    # that each queue for the interpreter while the engine thread wants it
+    # back for the next launch. A finish always notifies ``cond``.
+    on_progress: object = field(default=None, repr=False, compare=False)
 
     def note_progress(self) -> None:
-        """Wake every thread blocked on this request (new token landed /
-        state advanced). Cheap: one uncontended lock round-trip. A waiter
-        reads ``output_tokens``, which may already hold tokens newer than
+        """Tell whoever follows this request that a new token landed:
+        ``on_progress`` where one is set, else every thread blocked on
+        ``cond`` (one uncontended lock round-trip). A reader finds
+        ``output_tokens``, which may already hold tokens newer than
         the one this call announces: decode tokens are appended when their
         step's rows are advanced (``Engine._consume``) and announced from
         ``Engine._flush_emit``."""
+        hook = self.on_progress
+        if hook is not None:
+            hook()
+            return
         with self.cond:
             self.cond.notify_all()
 

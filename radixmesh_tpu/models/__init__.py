@@ -7,7 +7,8 @@ from radixmesh_tpu.models.llama import (
     convert_hf_state_dict,
 )
 from radixmesh_tpu.models import (
-    glm4_moe_lite, glm_moe_dsa, laguna, lfm2_moe, mellum2, phi4flash, qwen2,
+    bailing_hybrid, glm4_moe_lite, glm_moe_dsa, laguna, lfm2_moe, mellum2,
+    phi4flash, qwen2,
 )
 
 _PRESETS = {
@@ -40,6 +41,9 @@ _PRESETS = {
     "laguna-xs.2": laguna.laguna_xs2,
     "laguna-xs.2-l5": laguna.laguna_xs2_l5,
     "laguna-tiny": laguna.laguna_tiny,
+    "ling-3.0-flash": bailing_hybrid.ling3_flash,
+    "ling-3.0-flash-ep8": bailing_hybrid.ling3_flash_ep8,
+    "ling-tiny": bailing_hybrid.ling_tiny,
 }
 
 

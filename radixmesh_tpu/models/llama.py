@@ -55,6 +55,17 @@ the serving stack's model side that the north star requires
 - **An expert share** (``ModelConfig.ep_rank`` / ``ep_size``): the expert
   weights hold this chip's experts only; the layer routes over all of
   them and computes its own part (``ops/moe.py``).
+- **Linear-attention layers beside latent attention** (``models/
+  bailing_hybrid.py``, ``ops/kda.py``): a layer of kind "kda" (the gated
+  delta rule with a per-channel decay) leaves no cache row; it carries a
+  float32 matrix a head and its convolutions' last inputs in STATE SLOTS
+  (``state_shape`` / ``conv_state_shape``), read and written inside the
+  layer: a chunk launch reads a row's slot and writes the states the
+  engine asks for, a decode launch updates the row's slot in place and,
+  behind the layers, copies it to the boundary slot for the rows whose
+  token completed a page. The latent layers beside them (``params["mla"]``:
+  no query bottleneck, a head-wise output gate) own the pool's rows, and
+  the layers run through ``_scan_mixed`` by their static kind.
 """
 
 from __future__ import annotations
@@ -80,9 +91,10 @@ from radixmesh_tpu.ops.mla_attention import (
     latent_prefill_attention,
 )
 from radixmesh_tpu.ops import dsa
-from radixmesh_tpu.ops.moe import SHARE_STATS as MOE_STATS, STATS, moe_ffn
+from radixmesh_tpu.ops.moe import GROUP_STATS as MOE_STATS, SHARE_STATS, STATS, moe_ffn
 from radixmesh_tpu.ops.norm import layer_norm, rms_norm
 from radixmesh_tpu.ops.rope import apply_rope, rope_frequencies, yarn_frequencies
+from radixmesh_tpu.ops import kda
 from radixmesh_tpu.ops.ssm import selective_scan, ssm_step
 from radixmesh_tpu.ops.wquant import quantize_weight
 
@@ -237,14 +249,38 @@ class ModelConfig:
     # heads], in the kind's stack) before ``wo``.
     heads_by_type: tuple | None = None
     attn_gate: bool = False
+    # Linear-attention layers (``layer_types`` entry "kda", beside
+    # "full_attention" layers of LATENT attention; ``models/
+    # bailing_hybrid.py``): the gated delta rule with a per-channel decay
+    # (``ops/kda.py``), ``kda_heads`` heads of ``kda_head_dim`` keys and
+    # values, behind a depth-wise convolution of ``kda_conv`` taps. Such a
+    # layer leaves NO cache row: it carries one ``[kda_head_dim,
+    # kda_head_dim]`` float32 matrix a head and the last ``kda_conv - 1``
+    # inputs of its convolutions, whatever the context length
+    # (``state_shape`` / ``conv_state_shape``). Its leaves are a stack of
+    # their own (``params["kda"]``), the latent layers' another
+    # (``params["mla"]``, with ``wg`` under ``attn_gate``), and the pool's
+    # layer axis counts the latent layers only. The gate is ``g =
+    # kda_gate_lower_bound * sigmoid(exp(A_log) * (h W_a + dt_bias))``.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 0
+    kda_gate_lower_bound: float = -5.0
+    # Group-limited routing of a sigmoid router: the experts are
+    # ``n_group`` runs, the ``topk_group`` best groups (by the sum of their
+    # two best score + bias) stay, the experts are chosen among theirs
+    # (``ops/moe.py::route``). 1: no groups.
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self):
         if self.heads_by_type is not None:
             self._check_kinds()
-        elif self.attn_gate:
+        elif self.attn_gate and not self.has_kda:
             raise ValueError(
                 "attn_gate's weights live in the attention stacks of a "
-                "config with heads_by_type: no other config has them")
+                "config with heads_by_type or kda layers: no other config "
+                "has them")
         if self.layer_types is not None and (
             self.ssm_state or {"mamba", "gmu", "cross_attention"}
             & set(self.layer_types)
@@ -255,17 +291,18 @@ class ModelConfig:
             if len(self.layer_types) != self.n_layers or not (
                 kinds <= {"sliding_attention", "full_attention"}
                 or kinds <= {"conv", "full_attention"}
+                or kinds <= {"kda", "full_attention"}
             ):
                 raise ValueError(
                     f"layer_types must name {self.n_layers} layers as "
                     f"sliding_attention or full_attention, or as conv or "
-                    f"full_attention, got "
+                    f"full_attention, or as kda or full_attention, got "
                     f"{len(self.layer_types)} of {sorted(kinds)}"
                 )
             if "sliding_attention" in kinds and self.sliding_window < 2:
                 raise ValueError("sliding layers need sliding_window >= 2")
             missing = kinds - {t for t, _ in self.rope_by_type or ()}
-            if missing and "conv" not in kinds:
+            if missing and not kinds & {"conv", "kda"}:
                 raise ValueError(f"rope_by_type lacks {sorted(missing)}")
         if self.has_conv:
             taps = self.conv_L_cache
@@ -287,6 +324,22 @@ class ModelConfig:
                 )
         elif self.conv_L_cache:
             raise ValueError("conv_L_cache belongs to a config with conv layers")
+        if self.has_kda:
+            if not (self.kda_heads > 0 and self.kda_head_dim > 0
+                    and self.kda_conv >= 2 and self.kda_gate_lower_bound < 0):
+                raise ValueError(
+                    "kda layers need kda_heads, kda_head_dim > 0, kda_conv >= "
+                    "2 and a negative kda_gate_lower_bound")
+            if (not self.latent or "full_attention" not in self.layer_types
+                    or self.qkv_bias or self.rope_by_type or self.pair_kv_heads
+                    or self.dsa):
+                raise ValueError(
+                    "kda layers go with latent full_attention layers under "
+                    "one RoPE (the paged pool and the radix tree need one "
+                    "layer that owns rows): no qkv_bias, rope_by_type, "
+                    "pair_kv_heads or learned sparse attention")
+        elif self.kda_heads or self.kda_conv:
+            raise ValueError("kda_heads / kda_conv belong to a config with kda layers")
         if self.pair_kv_heads and (
             self.latent or self.head_dim != 64 or self.n_kv_heads % 2
         ):
@@ -321,17 +374,18 @@ class ModelConfig:
                 "experts, and first_k_dense to [0, n_layers]"
             )
         if self.kv_lora_rank and not (
-            self.q_lora_rank > 0 and self.v_head_dim > 0
+            self.q_lora_rank >= 0 and self.v_head_dim > 0
             and self.qk_rope_head_dim > 0 and self.qk_rope_head_dim % 2 == 0
             and self.head_dim == self.qk_nope_head_dim + self.qk_rope_head_dim
         ):
             raise ValueError(
-                "latent attention needs q_lora_rank, v_head_dim and an even "
+                "latent attention needs v_head_dim and an even "
                 "qk_rope_head_dim > 0, and head_dim == qk_nope_head_dim + "
-                "qk_rope_head_dim"
+                "qk_rope_head_dim (q_lora_rank 0: queries without a "
+                "bottleneck)"
             )
         if self.index_topk and not (
-            self.latent and self.index_n_heads > 0
+            self.latent and self.q_lora_rank > 0 and self.index_n_heads > 0
             and self.index_head_dim >= self.qk_rope_head_dim
             and self.indexer_types is not None
             and len(self.indexer_types) == self.n_layers
@@ -339,8 +393,8 @@ class ModelConfig:
             and self.indexer_types[0] == "full"
         ):
             raise ValueError(
-                "learned sparse attention needs latent attention, "
-                "index_n_heads > 0, index_head_dim >= qk_rope_head_dim and "
+                "learned sparse attention needs latent attention with a query "
+                "bottleneck, index_n_heads > 0, index_head_dim >= qk_rope_head_dim and "
                 f"indexer_types naming {self.n_layers} layers as full or "
                 "shared, the first of them full"
             )
@@ -352,6 +406,18 @@ class ModelConfig:
                 "an expert share needs 0 <= ep_rank < ep_size and n_experts "
                 "divisible by ep_size"
             )
+        if self.n_group > 1 and not (
+            self.router_scoring == "sigmoid" and self.n_experts
+            and self.n_experts % self.n_group == 0
+            and 0 < self.topk_group <= self.n_group
+            and self.n_experts // self.n_group >= 2
+            and self.n_experts_per_tok
+            <= self.topk_group * (self.n_experts // self.n_group)
+        ):
+            raise ValueError(
+                "group-limited routing needs a sigmoid router, n_experts in "
+                "n_group runs of two or more, 0 < topk_group <= n_group and "
+                "n_experts_per_tok within the kept groups' experts")
 
     def _check_kinds(self) -> None:
         """A config whose attention kinds are stacks of their own."""
@@ -488,6 +554,27 @@ class ModelConfig:
         return bool(self.layer_types) and "conv" in self.layer_types
 
     @property
+    def has_kda(self) -> bool:
+        return bool(self.layer_types) and "kda" in self.layer_types
+
+    @property
+    def kda_layers(self) -> tuple[int, ...]:
+        """The linear-attention layers (each carries a state)."""
+        return self._layers_of("kda")
+
+    @property
+    def kda_inner(self) -> int:
+        """Channels of q, of k and of v of a kda layer."""
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def kda_conv_rows(self) -> int:
+        """128-lane rows of a kda layer's convolution inputs in a state
+        slot: ``kda_conv - 1`` positions of q, k and v, zeros up to a
+        whole row."""
+        return -(-(self.kda_conv - 1) * 3 * self.kda_inner // 128)
+
+    @property
     def conv_layers(self) -> tuple[int, ...]:
         """The gated short-convolution layers (each carries a state)."""
         return tuple(i for i, t in enumerate(self.layer_types or ())
@@ -497,8 +584,10 @@ class ModelConfig:
     def attn_layers(self) -> tuple[int, ...]:
         """The layers that own cache rows: all of them, or, beside conv
         layers, the attention layers alone."""
-        if self.window_layers:  # the full layers: the window layers' rows
-            return self._layers_of("full_attention")  # are another group
+        if self.window_layers or self.has_kda:
+            # the full layers: the window layers' rows are another group,
+            # a kda layer leaves none
+            return self._layers_of("full_attention")
         if not self.has_conv:
             return tuple(range(self.n_layers))
         return tuple(i for i, t in enumerate(self.layer_types) if t != "conv")
@@ -582,10 +671,13 @@ class ModelConfig:
         return -(-want // page_size) * page_size
 
     def conv_state_shape(self, num_states: int) -> tuple | None:
-        """Beside ``state_shape`` of a config with mamba layers: the last
-        ``ssm_conv - 1`` inputs of each layer's convolution, ``[mamba
-        layers, slots, rows, 128]`` in the model's dtype, under the state
-        slots' allocator (one snapshot = one slot of both arrays)."""
+        """Beside ``state_shape`` of a config with mamba or kda layers:
+        the last ``ssm_conv - 1`` (``kda_conv - 1``: of q, k and v) inputs
+        of each layer's convolution, ``[mamba | kda layers, slots, rows,
+        128]`` in the model's dtype, under the state slots' allocator (one
+        snapshot = one slot of both arrays)."""
+        if self.has_kda:
+            return (len(self.kda_layers), num_states, self.kda_conv_rows, 128)
         if not self.has_ssm:
             return None
         rows = (self.ssm_conv - 1) * self.ssm_inner // 128
@@ -597,6 +689,10 @@ class ModelConfig:
         if self.has_ssm:
             per = (self.ssm_state * 4 + (self.ssm_conv - 1) * item) * self.ssm_inner
             return len(self.mamba_layers) * per
+        if self.has_kda:
+            per = (self.kda_inner * self.kda_head_dim * 4
+                   + self.kda_conv_rows * 128 * item)
+            return len(self.kda_layers) * per
         if self.has_conv:
             return (len(self.conv_layers) * (self.conv_L_cache - 1)
                     * self.hidden * item)
@@ -611,8 +707,14 @@ class ModelConfig:
         its own (``cache/kv_pool.py``): the unit is a snapshot, not a
         token. A config with mamba layers: the recurrent part, ``[mamba
         layers, slots, ssm_state, ssm_inner]`` FLOAT32 (``state_dtype``;
-        the convolutions' part is ``conv_state_shape``). None for a config
-        with neither."""
+        the convolutions' part is ``conv_state_shape``). A config with kda
+        layers: every head's matrix, ``[kda layers, slots, kda_heads *
+        kda_head_dim, kda_head_dim]`` FLOAT32 — key channels down, value
+        channels across, 2 MiB a layer and slot at 32 heads of 128
+        (``ops/kda.py``). None for a config with none of them."""
+        if self.has_kda:
+            return (len(self.kda_layers), num_states, self.kda_inner,
+                    self.kda_head_dim)
         if self.has_ssm:
             return (len(self.mamba_layers), num_states, self.ssm_state,
                     self.ssm_inner)
@@ -623,7 +725,7 @@ class ModelConfig:
 
     @property
     def state_dtype(self):
-        return jnp.float32 if self.has_ssm else self.dtype
+        return jnp.float32 if self.has_ssm or self.has_kda else self.dtype
 
     def default_state_slots(self, num_slots: int, max_batch: int) -> int:
         """How many state slots go with a pool of ``num_slots`` rows,
@@ -640,8 +742,11 @@ class ModelConfig:
         boundary slot, the snapshot its prompt's publish leaves, the one
         a waiting session of a closed loop of two callers a row resumes
         from, and one for the shared preambles and the churn between
-        them). 0 for a config whose layers carry no state."""
-        if not (self.has_conv or self.has_ssm):
+        them). A 12.4 MiB snapshot (kda layers' matrices) beside a latent
+        row of 1 280 B a token is the same case, further out: the quarter
+        of 524 288 rows buys 12, and the five a batch row hold. 0 for a
+        config whose layers carry no state."""
+        if not (self.has_conv or self.has_ssm or self.has_kda):
             return 0
         want = -(-(1 + 2 * max_batch + num_slots // 100) // 256) * 256
         planes, heads, width = self.kv_row
@@ -789,13 +894,16 @@ def _attn_dims(cfg: ModelConfig, n_heads: int | None = None) -> dict:
     if cfg.latent:
         q_out = cfg.n_heads * cfg.head_dim
         kv_out = cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
-        return {
-            "wq_a": (H, cfg.q_lora_rank),
-            "wq_b": (cfg.q_lora_rank, q_out),
+        # queries through a bottleneck, or (q_lora_rank 0) projected directly
+        q_dims = {"wq_a": (H, cfg.q_lora_rank),
+                  "wq_b": (cfg.q_lora_rank, q_out)} if cfg.q_lora_rank else {
+                      "wq": (H, q_out)}
+        gate = {"wg": (H, cfg.n_heads)} if cfg.attn_gate else {}
+        return q_dims | {
             "wkv_a": (H, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
             "wkv_b": (cfg.kv_lora_rank, kv_out),
             "wo": (cfg.n_heads * cfg.v_head_dim, H),
-        }
+        } | gate
     n_heads = n_heads or cfg.n_heads
     qd, kvd = n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
     dims = {"wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd), "wo": (qd, H)}
@@ -817,8 +925,9 @@ def _draw_stack(keys, n: int, dims: dict, cfg: ModelConfig, quant_axis) -> dict:
         "attn_norm": jnp.ones((n, cfg.hidden), dtype=cfg.dtype),
         "mlp_norm": jnp.ones((n, cfg.hidden), dtype=cfg.dtype),
     }
-    if cfg.latent:
-        stack["q_norm"] = jnp.ones((n, cfg.q_lora_rank), dtype=cfg.dtype)
+    if cfg.latent and not cfg.has_kda:  # (beside kda layers: in params["mla"])
+        if cfg.q_lora_rank:
+            stack["q_norm"] = jnp.ones((n, cfg.q_lora_rank), dtype=cfg.dtype)
         stack["kv_norm"] = jnp.ones((n, cfg.kv_lora_rank), dtype=cfg.dtype)
     for k, (name, (i_dim, o_dim)) in zip(keys, dims.items()):
         stack[name], s = _draw_blocks(
@@ -859,6 +968,39 @@ def _draw_operators(key, cfg: ModelConfig) -> dict:
     return {"conv": conv, "attn": attn}
 
 
+def _draw_kda(key, cfg: ModelConfig) -> dict:
+    """The operators' leaves of a config with kda layers, a stack a kind:
+    ``kda`` — ``wq`` / ``wk`` / ``wv`` [H, C] (C = ``kda_inner``), the
+    depth-wise kernel ``conv_w`` [taps, 3C] over their concatenation (tap 0
+    the oldest position), the gate's ``w_alpha`` [H, C] (a FULL projection),
+    ``dt_bias`` [C] and ``A_log`` [heads] (``exp(A_log)`` uniform in [1,
+    16]: the gate is driven through its whole range), ``w_beta`` [H, heads],
+    the output norm's weight ``o_norm`` [C] (a head's own 128 each: a
+    seeded draw around 1), the output gate ``wg`` [H, C] and ``wo`` [C, H]
+    — and ``mla``: the latent layers' matrices (``_attn_dims``: ``wq``
+    direct, ``wg`` [H, heads]) and ``kv_norm``."""
+    H, C, nh = cfg.hidden, cfg.kda_inner, cfg.kda_heads
+    n_k, n_m = len(cfg.kda_layers), len(cfg.attn_layers)
+    keys = iter(jax.random.split(key, 24))
+    f32 = jnp.float32
+
+    def mats(n: int, dims: dict) -> dict:
+        return {name: _draw_blocks(next(keys), n, shape, shape[0], cfg.dtype, None)[0]
+                for name, shape in dims.items()}
+
+    op = mats(n_k, {"wq": (H, C), "wk": (H, C), "wv": (H, C), "w_alpha": (H, C),
+                    "w_beta": (H, nh), "wg": (H, C), "wo": (C, H)})
+    op["conv_w"], _ = _draw_blocks(
+        next(keys), n_k, (cfg.kda_conv, 3 * C), cfg.kda_conv, cfg.dtype, None)
+    op["A_log"] = jnp.log(jax.random.uniform(next(keys), (n_k, nh), f32, 1.0, 16.0))
+    op["dt_bias"] = 0.5 * jax.random.normal(next(keys), (n_k, C), f32)
+    op["o_norm"] = (1.0 + 0.1 * jax.random.normal(next(keys), (n_k, C), f32)
+                    ).astype(cfg.dtype)
+    mla = mats(n_m, _attn_dims(cfg))
+    mla["kv_norm"] = jnp.ones((n_m, cfg.kv_lora_rank), dtype=cfg.dtype)
+    return {"kda": op, "mla": mla}
+
+
 def _draw_kinds(key, cfg: ModelConfig) -> dict:
     """The attention matrices of a config with ``heads_by_type``: a stack a
     kind, ``{layer type: {wq, wk, wv, wo[, wg]}}`` over that kind's layers
@@ -894,7 +1036,8 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
     ffn = _ffn_dims(H, ffn_width)
     # Beside conv layers the operators' leaves are stacks of their own
     # (below): the per-layer stacks keep the norms and the FFN.
-    op_dims = {} if cfg.has_conv or cfg.heads_by_type else _attn_dims(cfg)
+    op_dims = {} if (cfg.has_conv or cfg.heads_by_type or cfg.has_kda
+                     ) else _attn_dims(cfg)
     dims = dict(op_dims)
     dims |= {"w_router": (H, cfg.n_experts)} if cfg.n_experts else ffn
     layers = _draw_stack(keys, L, dims, cfg, axis(0))
@@ -961,6 +1104,8 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         params |= _draw_operators(jax.random.fold_in(key, 105), cfg)
     if cfg.heads_by_type:
         params |= _draw_kinds(jax.random.fold_in(key, 107), cfg)
+    if cfg.has_kda:
+        params |= _draw_kda(jax.random.fold_in(key, 108), cfg)
     qd = cfg.n_heads * cfg.head_dim
     n_blk = _vocab_blocks(V, H, H * max(ffn_width, qd))
     # [V, H] in row blocks; per-ROW scales serve the gather and the tied
@@ -1047,10 +1192,17 @@ def param_logical_axes(cfg: ModelConfig, params: dict | None = None) -> dict:
         # by head); the names say what a tp share would split.
         for name in ("wq", "wk", "wv"):
             del axes["layers"][name]
+        if cfg.q_lora_rank:
+            axes["layers"] |= {
+                "wq_a": ("layer", "embed", "q_lora"),
+                "q_norm": ("layer", "q_lora"),
+                "wq_b": ("layer", "q_lora", "q_heads"),
+            }
+        else:
+            axes["layers"]["wq"] = ("layer", "embed", "q_heads")
+        if cfg.attn_gate:
+            axes["layers"]["wg"] = ("layer", "embed", "q_heads")
         axes["layers"] |= {
-            "wq_a": ("layer", "embed", "q_lora"),
-            "q_norm": ("layer", "q_lora"),
-            "wq_b": ("layer", "q_lora", "q_heads"),
             "wkv_a": ("layer", "embed", "kv_lora"),
             "kv_norm": ("layer", "kv_lora"),
             "wkv_b": ("layer", "kv_lora", "q_heads"),
@@ -1090,6 +1242,21 @@ def param_logical_axes(cfg: ModelConfig, params: dict | None = None) -> dict:
             ops["wg"] = ("layer", "embed", "q_heads")
         for kind, _ in cfg.heads_by_type:
             axes[kind] = dict(ops)
+    if cfg.has_kda:
+        # Engine refuses a mesh for such a config; the names say what a tp
+        # share would split (a kda layer by head, as attention).
+        names = [name for name in ("wq", "wkv_a", "kv_norm", "wkv_b", "wo", "wg")
+                 if name in axes["layers"]]
+        axes["mla"] = {name: axes["layers"].pop(name) for name in names}
+        for name in names if cfg.first_k_dense else ():
+            del axes["dense_layers"][name]
+        by_head = ("layer", "embed", "q_heads")
+        axes["kda"] = {
+            "wq": by_head, "wk": by_head, "wv": by_head, "w_alpha": by_head,
+            "w_beta": by_head, "wg": by_head, "wo": ("layer", "q_heads", "embed"),
+            "conv_w": ("layer", None, "q_heads"), "A_log": ("layer", "q_heads"),
+            "dt_bias": ("layer", "q_heads"), "o_norm": ("layer", "q_heads"),
+        }
     if cfg.qkv_bias:
         axes["layers"]["bq"] = ("layer", "q_heads")
         axes["layers"]["bk"] = ("layer", "kv_heads")
@@ -1196,10 +1363,13 @@ def _latent_qkv(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions, inv_freq,
     K derived from the row: the same function, never materialised."""
     B, S = x.shape[:2]
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    if c_q is None:
-        c_q = _latent_cq(lp, x, cfg)
-    q = _wmm(lp, "wq_b", "bsr,rd->bsd", c_q)
-    if B * S < cfg.q_lora_rank:
+    if not cfg.q_lora_rank:  # no bottleneck, no query norm
+        q = _wmm(lp, "wq", "bsh,hd->bsd", x)
+    else:
+        if c_q is None:
+            c_q = _latent_cq(lp, x, cfg)
+        q = _wmm(lp, "wq_b", "bsr,rd->bsd", c_q)
+    if B * S < (cfg.q_lora_rank or cfg.hidden):
         # Fewer tokens than the weight has rows (a decode step): without
         # the barrier XLA lays wq_b out for the heads' batched product
         # below and copies the layer's slice of it, transposed — 2 x 67 MB
@@ -1341,6 +1511,64 @@ def _short_conv(lp: dict, h: jnp.ndarray, state: jnp.ndarray, cfg: ModelConfig,
         at = snap_at[:, :, None] + jnp.arange(taps - 1)  # [B, K, taps-1]
         states = jax.vmap(lambda rows, ix: rows[ix])(zs, at)
     return y, states
+
+
+def _kda_conv_store(cfg: ModelConfig, conv: jnp.ndarray) -> jnp.ndarray:
+    """A kda layer's convolution inputs ``[..., kda_conv - 1, 3 *
+    kda_inner]`` as a state slot holds them: ``[..., rows, 128]``, zeros
+    up to a whole row."""
+    flat = conv.reshape(*conv.shape[:-2], -1)
+    pad = cfg.kda_conv_rows * 128 - flat.shape[-1]
+    if pad:
+        flat = jnp.concatenate(
+            [flat, jnp.zeros((*flat.shape[:-1], pad), flat.dtype)], axis=-1)
+    return flat.reshape(*flat.shape[:-1], cfg.kda_conv_rows, 128)
+
+
+def _kda_conv_load(cfg: ModelConfig, stored: jnp.ndarray) -> jnp.ndarray:
+    """``_kda_conv_store``'s inverse."""
+    n = (cfg.kda_conv - 1) * 3 * cfg.kda_inner
+    flat = stored.reshape(*stored.shape[:-2], -1)[..., :n]
+    return flat.reshape(*flat.shape[:-1], cfg.kda_conv - 1, 3 * cfg.kda_inner)
+
+
+def _kda_in(lp: dict, h: jnp.ndarray, conv0: jnp.ndarray, cfg: ModelConfig,
+            snap_at: jnp.ndarray | None):
+    """A kda layer's inputs from its normed input ``h`` [B, S, H] and the
+    ``kda_conv - 1`` inputs of its convolutions before the first position
+    (``conv0`` [B, taps - 1, 3C]): ``(q, k, v [B, S, heads, d] float32 —
+    convolved, SiLU, q and k of unit length, q times d^-1/2 —, the
+    log-decay g [B, S, heads, d] in (lower bound, 0), beta [B, S, heads],
+    the convolutions' inputs before ``snap_at`` [B, K] positions [B, K,
+    taps - 1, 3C])`` (``ops/kda.py::short_conv``)."""
+    B, S = h.shape[:2]
+    nh, d = cfg.kda_heads, cfg.kda_head_dim
+    f32 = jnp.float32
+    qkv = jnp.concatenate(
+        [_wmm(lp, name, "bsh,hd->bsd", h) for name in ("wq", "wk", "wv")], axis=-1)
+    y, conv = kda.short_conv(qkv, lp["conv_w"], conv0, snap_at)
+    q, k, v = (t.reshape(B, S, nh, d) for t in jnp.split(y, 3, axis=-1))
+
+    def unit(t):
+        return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+    a = _wmm(lp, "w_alpha", "bsh,hd->bsd", h).astype(f32) + lp["dt_bias"]
+    g = cfg.kda_gate_lower_bound * jax.nn.sigmoid(
+        jnp.exp(lp["A_log"])[:, None] * a.reshape(B, S, nh, d))
+    beta = jax.nn.sigmoid(_wmm(lp, "w_beta", "bsh,hq->bsq", h).astype(f32))
+    return unit(q) * d ** -0.5, unit(k), v, g, beta, conv
+
+
+def _kda_out(lp: dict, o: jnp.ndarray, h: jnp.ndarray, cfg: ModelConfig):
+    """A kda layer's output from ``o`` [B, S, heads, d] float32: an RMSNorm
+    over each head (its own learned weight), times ``sigmoid(h W_g)``
+    element by element, then ``wo``."""
+    B, S, nh, d = o.shape
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_eps)
+    o = o * lp["o_norm"].astype(jnp.float32).reshape(nh, d)
+    gate = jax.nn.sigmoid(_wmm(lp, "wg", "bsh,hd->bsd", h).astype(jnp.float32))
+    return _wmm(lp, "wo", "bsk,kh->bsh",
+                (o.reshape(B, S, nh * d) * gate).astype(h.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -1827,7 +2055,7 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
     dense layer's arithmetic. ``options`` are ``Engine``'s keyword names
     (a falsy value is "not asked for")."""
     if not (cfg.n_experts or cfg.has_window or cfg.latent or cfg.has_conv
-            or cfg.has_ssm):
+            or cfg.has_ssm or cfg.has_kda):
         return None
     mesh = options.get("device_mesh")
     named = {
@@ -1840,7 +2068,7 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
         "disaggregated": "disaggregated prefill/decode (engine/disagg.py)",
         "hf_checkpoint": "loading a HuggingFace checkpoint (--weights)",
     }
-    if cfg.has_conv or cfg.window_layers:
+    if cfg.has_conv or cfg.window_layers or cfg.has_kda:
         # A prefix is handed back only where its state (or its window tail)
         # was snapshotted: whatever moves or advertises a prefix without it.
         named |= {
@@ -1864,6 +2092,8 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
                          cfg.has_conv),
                         ("state-space layers with a recurrent state",
                          cfg.has_ssm),
+                        ("linear-attention (kda) layers with a recurrent "
+                         "matrix state", cfg.has_kda),
                         ("a cross-decoder that reads one layer's K/V",
                          cfg.has_ssm),
                         ("attention kinds of two shapes in stacks of their "
@@ -1952,7 +2182,7 @@ def _layer_tables(cfg: ModelConfig) -> dict | None:
             "dsa_full": jnp.asarray(full),
             "dsa_slot": jnp.asarray(np.cumsum(full) - 1, jnp.int32),
         }
-    if cfg.layer_types is None or cfg.has_conv:
+    if cfg.layer_types is None or cfg.has_conv or cfg.has_kda:
         return tables or None
     by_type = {kind: _type_rope(cfg, kind)[:2] for kind, _ in cfg.rope_by_type}
     return tables | {
@@ -1990,11 +2220,11 @@ def _rope_of(inv_freq, table: dict | None):
 MOE_SITES = ("decode", "prefill")
 
 
-def new_moe_stats(share: bool = False) -> jnp.ndarray:
-    """Zeroed counters: ``ops/moe.py``'s four a site, and the fifth of a
-    config with an expert share."""
-    return jnp.zeros(
-        (len(MOE_SITES), len(MOE_STATS if share else STATS), 2), jnp.uint32)
+def new_moe_stats(share: bool = False, groups: bool = False) -> jnp.ndarray:
+    """Zeroed counters: ``ops/moe.py``'s four a site, the fifth of a
+    config with an expert share, the six of a router with groups."""
+    names = MOE_STATS if groups else SHARE_STATS if share else STATS
+    return jnp.zeros((len(MOE_SITES), len(names), 2), jnp.uint32)
 
 
 def read_moe_stats(stats) -> dict:
@@ -2013,7 +2243,8 @@ def read_moe_stats(stats) -> dict:
 def _bump_moe_stats(stats, site: str, delta: jnp.ndarray) -> jnp.ndarray:
     """``stats[site] += delta`` ([n_counters] int32, non-negative) with
     the carry into the high word."""
-    stats = new_moe_stats(delta.shape[0] > len(STATS)) if stats is None else stats
+    if stats is None:
+        stats = jnp.zeros((len(MOE_SITES), delta.shape[0], 2), jnp.uint32)
     i = MOE_SITES.index(site)
     lo = stats[i, :, 0] + delta.astype(jnp.uint32)
     hi = stats[i, :, 1] + (lo < stats[i, :, 0]).astype(jnp.uint32)
@@ -2039,7 +2270,7 @@ def _scan_layers(cfg: ModelConfig, params: dict, layer, carry):
     the rest. Returns ``(carry, ys)``; ``ys`` of two scans are joined
     along the layer axis, but for what only the sparse layers give (the
     dense scan's None)."""
-    if cfg.has_conv or cfg.heads_by_type:
+    if cfg.has_conv or cfg.heads_by_type or cfg.has_kda:
         return _scan_mixed(cfg, params, layer, carry)
     kd = cfg.first_k_dense
     stacks = [(0, params["dense_layers"], False)] if kd else []
@@ -2088,6 +2319,8 @@ def _layer_kinds(cfg: ModelConfig) -> list[str]:
     kinds differ in shape (``heads_by_type``)."""
     if cfg.has_conv:
         return ["conv" if t == "conv" else "attn" for t in cfg.layer_types]
+    if cfg.has_kda:
+        return ["kda" if t == "kda" else "mla" for t in cfg.layer_types]
     return list(cfg.layer_types)
 
 
@@ -2191,6 +2424,7 @@ def _ffn(params: dict, lp: dict, l_idx, x: jnp.ndarray, cfg: ModelConfig,
         scale=cfg.routed_scaling_factor,
         site=site, valid=None if valid is None else valid.reshape(B * S),
         first_held=cfg.ep_rank * cfg.held_experts,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
         **kernel_kw,
     )
     y = y.reshape(B, S, H)
@@ -2321,11 +2555,11 @@ def prefill_forward(
         return _hybrid_prefill(params, cfg, tokens, positions, new_lengths, snap_at)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)
-    if (cfg.has_conv or cfg.heads_by_type) and p_max:
+    if (cfg.has_conv or cfg.heads_by_type or cfg.has_kda) and p_max:
         raise ValueError(
-            "prefill_forward takes no cached prefix for a model with conv "
-            "layers or a window group (a prefix resumes from a state slot or "
-            "a window tail: prefill_chunk_paged)"
+            "prefill_forward takes no cached prefix for a model with conv or "
+            "kda layers or a window group (a prefix resumes from a state slot "
+            "or a window tail: prefill_chunk_paged)"
         )
     s_new = tokens.shape[1]
     pad = p_max - prefix_lengths  # [B] front padding per row
@@ -2362,6 +2596,29 @@ def prefill_forward(
                 (x.shape[0], cfg.conv_L_cache - 1, cfg.hidden), x.dtype)
             op, states = _short_conv(lp, h, zero, cfg, snap_at)
             states = states.reshape(*states.shape[:2], -1, 128)
+        elif kind == "kda":
+            # from a zero state; the states after ``snap_at`` go out
+            B = x.shape[0]
+            q, k, v, g, beta, conv = _kda_in(
+                lp, h, jnp.zeros((B, cfg.kda_conv - 1, 3 * cfg.kda_inner), x.dtype),
+                cfg, snap_at)
+            # no slot to read, every row fresh: the states land in slots of
+            # a launch's own (slot 0 is nobody's) and go out as they lie
+            n = snap_at.size
+            o, snaps = kda.kda_chunk(
+                q, k, v, g, beta,
+                jnp.zeros((1, 1 + n, cfg.kda_inner, cfg.kda_head_dim), jnp.float32),
+                0, jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.int32), snap_at,
+                1 + jnp.arange(n, dtype=jnp.int32).reshape(snap_at.shape))
+            op = _kda_out(lp, o, h, cfg)
+            k = v = None
+            states = (snaps[0, 1:].reshape(*snap_at.shape, *snaps.shape[2:]),
+                      _kda_conv_store(cfg, conv))
+        elif kind == "mla":
+            q, k, v = _attn_in(lp, h, cfg, positions, one_rope, None)
+            op = _kind_attn_out(lp, latent_prefill_attention(
+                q, k, attn_pos, kv_end, pad, cfg.kv_lora_rank,
+                cfg.head_dim ** -0.5), h, cfg)
         elif cfg.heads_by_type:
             q, k, v = _kind_attn_in(lp, h, cfg, kind, positions)
             sliding = kind == "sliding_attention"
@@ -2383,10 +2640,10 @@ def prefill_forward(
         return (x + y, mask), {"k": k, "v": v, "aux": aux, "state": states,
                                "wk": wk, "wv": wv}
 
-    if cfg.has_conv or cfg.heads_by_type:
+    if cfg.has_conv or cfg.heads_by_type or cfg.has_kda:
         (x, _), ys = _scan_layers(cfg, params, mixed_layer, (x, mask0))
-        groups = (ys["state"],) if cfg.has_conv else (ys["wk"], ys["wv"])
-        return (_logits(params, cfg, x), ys["k"], ys["v"], *groups,
+        groups = (ys["wk"], ys["wv"]) if cfg.heads_by_type else (ys["state"],)
+        return (_logits(params, cfg, x), ys["k"], ys.get("v"), *groups,
                 *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
 
     def layer(carry, xs, sparse):
@@ -2633,6 +2890,36 @@ def prefill_chunk_paged(
                 resumed, table["op_idx"], 0, keepdims=False)
             op, states = _short_conv(lp, h, mine, cfg, snap_at)
             states = states.reshape(*states.shape[:2], -1, 128)
+        elif kind == "kda":
+            # The layer reads its rows' slots and writes the states after
+            # ``snap_at`` to ``state_out`` HERE: the two arrays ride the
+            # carry, no layer's snapshots are kept for a later scatter (the
+            # matrices never leave the kernel but to their slots).
+            s_pool, c_pool = sel
+            i = table["op_idx"]
+            fresh = positions[:, 0] == 0
+            q, k, v, g, beta, conv = _kda_in(
+                lp, h, jnp.where(
+                    fresh[:, None, None], 0,
+                    _kda_conv_load(cfg, c_pool[i, state_in])),
+                cfg, snap_at)
+            o, s_pool = kda.kda_chunk(
+                q, k, v, g, beta, s_pool, i, state_in, fresh, snap_at, state_out,
+                use_kernel=use_kernel, interpret=interpret)
+            op = _kda_out(lp, o, h, cfg)
+            k = v = None
+            conv = _kda_conv_store(cfg, conv).astype(c_pool.dtype)
+            sel = (s_pool, c_pool.at[i, state_out.reshape(-1)].set(
+                conv.reshape(-1, *conv.shape[2:])))
+        elif kind == "mla":
+            q, k, _ = _attn_in(lp, h, cfg, positions, one_rope, None)
+            k = k.astype(kv_pool.dtype)  # attend what the pool will hold
+            attn = latent_chunk_attention(
+                q, k[:, :, 0], kv_pages, page_table, positions, prior_lengths,
+                kv_lengths, table["op_idx"], cfg.kv_lora_rank,
+                cfg.head_dim ** -0.5, kv_block_pages=kv_block_pages,
+                use_kernel=use_kernel, interpret=interpret)
+            op = _kind_attn_out(lp, attn, h, cfg)
         elif cfg.heads_by_type:
             q, k, v = _kind_attn_in(lp, h, cfg, kind, positions)
             k, v = k.astype(kv_pool.dtype), v.astype(kv_pool.dtype)
@@ -2784,6 +3071,14 @@ def prefill_chunk_paged(
               jnp.stack([ys["wk"], ys["wv"]]).transpose(0, 1, 4, 2, 3, 5),
               ALL_LAYERS)], window_slots, page_size)
         return (_logits(params, cfg, pick(x)), kv_pool, window_pool,
+                *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
+    if cfg.has_kda:
+        (x, state_pool), ys = _scan_layers(
+            cfg, params, mixed_layer, (x, tuple(state_pool)))
+        kv_pool, = write_runs_all(
+            [(kv_pool, ys["k"][None].transpose(0, 1, 4, 2, 3, 5), ALL_LAYERS)],
+            slots, page_size)
+        return (_logits(params, cfg, pick(x)), kv_pool, state_pool,
                 *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
     if cfg.has_conv:
         (x, _), ys = _scan_layers(cfg, params, mixed_layer, (x, None))
@@ -2940,6 +3235,27 @@ def _decode_core(
                 resumed, table["op_idx"], 0, keepdims=False)
             op, states = _short_conv(lp, h, mine, cfg, None)
             states = states.reshape(B, -1, 128)
+        elif kind == "kda":
+            # The row's working slot, read and written in place; the state
+            # arrays ride the carry where another config's window group does.
+            s_pool, c_pool = window_pool
+            i, work = table["op_idx"], state_slots[0]
+            q, k, v, g, beta, conv = _kda_in(
+                lp, h, _kda_conv_load(cfg, c_pool[i, work]), cfg, None)
+            o, s_pool = kda.kda_decode(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s_pool, i, work)
+            window_pool = (s_pool, c_pool.at[i, work].set(
+                _kda_conv_store(cfg, conv[:, 0]).astype(c_pool.dtype)))
+            op = _kda_out(lp, o[:, None], h, cfg)
+        elif kind == "mla":
+            q, k, _ = _attn_in(lp, h, cfg, positions[:, None], one_rope, None)
+            attn, pages = latent_decode_attention(
+                q[:, 0], k[:, 0, 0].astype(kv_pool.dtype),
+                kv_pool.reshape(pages_shape), slots, page_table, lengths,
+                table["op_idx"], cfg.kv_lora_rank, cfg.head_dim ** -0.5,
+                decode_groups)
+            kv_pool = pages.reshape(pool_shape)
+            op = _kind_attn_out(lp, attn[:, None], h, cfg)
         elif kind == "sliding_attention":
             q, k, v = _kind_attn_in(lp, h, cfg, kind, positions[:, None])
             with jax.named_scope("rm_window_attn"):
@@ -2985,6 +3301,16 @@ def _decode_core(
         (x, kv_pool, window_pool), ys = _scan_layers(
             cfg, params, mixed_layer, (x, kv_pool, window_pool))
         return (_logits(params, cfg, x)[:, 0], kv_pool, window_pool,
+                *_moe_aux(cfg, ys.get("aux"), moe_stats, "decode"))
+    if cfg.has_kda:
+        (x, kv_pool, state_pool), ys = _scan_layers(
+            cfg, params, mixed_layer, (x, kv_pool, tuple(state_pool)))
+        # The boundary copies: the rows whose token completed a page keep
+        # the state they leave in their boundary slot (state_slots[1]; 0:
+        # none), slot to slot over every layer, behind the layers.
+        state_pool = tuple(
+            kda.copy_slots(a, state_slots[0], state_slots[1]) for a in state_pool)
+        return (_logits(params, cfg, x)[:, 0], kv_pool, state_pool,
                 *_moe_aux(cfg, ys.get("aux"), moe_stats, "decode"))
     if cfg.has_conv:
         (x, kv_pool, _), ys = _scan_layers(

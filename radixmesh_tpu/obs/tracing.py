@@ -11,8 +11,8 @@ Four levels:
   that runs always (a 3 s capture is taken after the measured window and
   holds too few of most spans for a statistic). The ``rm.*`` spans of
   ``engine/engine.py``, ``server/http_frontend.py``'s runner and
-  ``slo/runner.py`` go through it; ``rm.http.emit`` (handler threads)
-  stays a bare ``annotate``.
+  ``slo/runner.py`` go through it; ``rm.http.emit`` (the SSE streams'
+  emitter thread and the handler threads) stays a bare ``annotate``.
 - :class:`ProcessAccount` — the process's pauses: garbage collections by
   generation and XLA compilations by name, from ``gc.callbacks`` and
   ``jax.monitoring``. Both hooks are global to the process, so only an

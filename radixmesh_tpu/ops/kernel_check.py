@@ -41,12 +41,13 @@ from radixmesh_tpu.ops.paged_attention import (
     paged_chunk_attention_kernel,
     paged_decode_fused_kernel,
 )
+from radixmesh_tpu.ops import kda
 from radixmesh_tpu.ops.quant import quantize_kv
 
 __all__ = [
     "TOLERANCE", "DEFAULT_CASES", "FLAG_CASES", "WINDOW_CASES", "LATENT_CASES",
-    "PREFIX_CASES", "check_kernels", "check_latent_decode",
-    "check_chunk_prefix",
+    "PREFIX_CASES", "KDA_CASES", "KDA_TOLERANCE", "check_kernels",
+    "check_latent_decode", "check_chunk_prefix", "check_kda",
 ]
 
 # Absolute AND relative bound on |kernel - oracle| per output element.
@@ -66,6 +67,19 @@ __all__ = [
 # shown is 7e-3); K/V held in anything narrower than the pool dtype
 # (fp8's 2^-4 steps) lands at 6e-2 and fails.
 TOLERANCE = 2e-2
+
+# The linear-attention state kernels (``check_kda``, ``ops/kda.py``): a
+# chunk from the rows' slots with snapshots inside it, one position a row
+# through the slots in place, the boundary copies. Float32 on the vector
+# unit against the same recurrence in ``jax.numpy``: they differ by the
+# order of two 128-term sums — 2.4e-7 on the states and 7e-8 on the outputs
+# on the chip at 32 heads of 128 (PERF.md, PR 48) — and a state held in
+# bf16 reads 4e-3 after ONE step. KDA_TOLERANCE lies between the two: this
+# check is where the state's precision can be told on the chip (the served
+# logits cannot: bf16 activations cost three times what a bf16 state does,
+# ``benchmarks/tools/ref_compare_kda.py``).
+KDA_CASES = ("kda_chunk", "kda_decode", "kda_boundary")
+KDA_TOLERANCE = 1e-4
 
 # Kernels the default ``serve`` flags reach (bf16 pool, auto grid).
 DEFAULT_CASES = ("fused_decode", "chunk_prefill")
@@ -237,7 +251,7 @@ def _run_case(name: str, x: dict, interpret: bool) -> dict:
     raise ValueError(f"unknown kernel case {name!r}")
 
 
-def _verdict(name: str, run) -> dict:
+def _verdict(name: str, run, tolerance: float = TOLERANCE) -> dict:
     """One case's result dict: ``run()``'s numbers against the tolerance,
     or the exception it raised (the compiler's message when Mosaic refuses
     the kernel) — recorded, not skipped."""
@@ -251,9 +265,9 @@ def _verdict(name: str, run) -> dict:
     return {
         "kernel": name,
         "ok": bool(
-            res["max_err"] <= TOLERANCE and res.get("pool_write_ok", True)
+            res["max_err"] <= tolerance and res.get("pool_write_ok", True)
         ),
-        "tolerance": TOLERANCE,
+        "tolerance": tolerance,
         **res,
     }
 
@@ -424,3 +438,88 @@ def check_kernels(
     x["window"] = window
     return [_verdict(name, lambda: _run_case(name, x, interpret))
             for name in cases]
+
+
+def check_kda(
+    *,
+    heads: int,
+    dim: int,
+    batch: int = 4,
+    chunk: int = 64,
+    slots: int = 20,
+    interpret: bool = False,
+    seed: int = 0,
+    state_dtype=None,
+) -> list[dict]:
+    """``KDA_CASES`` at the given geometry, ``check_kernels``' result
+    dicts: each kernel of ``ops/kda.py`` called DIRECTLY against its
+    ``jax.numpy`` form on the same seeded inputs — gates drawn over their
+    whole range (the bound of -5 included), one row fresh and the others
+    resuming from their slots, snapshots at a chunk's end, inside it and at
+    0; a decode launch over distinct slots; copies for half the rows. Held
+    to ``KDA_TOLERANCE``. ``state_dtype`` is the control: the states the
+    kernels wrote, as slots of that dtype would hold them (bf16 has to
+    fail)."""
+    rng = np.random.default_rng(seed)
+    B, T, H, d = batch, chunk, heads, dim
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def unit(t):
+        return t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+
+    q, k = unit(normal(B, T, H, d)) * d ** -0.5, unit(normal(B, T, H, d))
+    v = normal(B, T, H, d)
+    g = -5.0 / (1.0 + jnp.exp(-4.0 * normal(B, T, H, d)))
+    g = g.at[0, : T // 2].set(-5.0)  # a run at the bound
+    beta = 1.0 / (1.0 + jnp.exp(-normal(B, T, H)))
+    pool = normal(2, slots, H * d, d)
+    rows = jnp.asarray(rng.permutation(np.arange(1, slots))[:B], jnp.int32)
+    fresh = jnp.zeros((B,), jnp.int32).at[-1].set(1)
+    snap = jnp.asarray([[T, max(T // 2 - 3, 0), 0]] * B, jnp.int32)
+    layer = jnp.asarray(1, jnp.int32)
+    taken = set(np.asarray(rows).tolist())
+    free = [s_ for s_ in range(1, slots) if s_ not in taken]  # nobody's start
+
+    def written(pool_out, want, held=slice(None)) -> dict:
+        got = jnp.asarray(pool_out)
+        if state_dtype is not None:
+            got = got.astype(state_dtype).astype(jnp.float32)
+        diff = float(np.max(np.abs(
+            np.asarray(got)[:, held] - np.asarray(want)[:, held])))
+        return {"pool_write_diff": diff, "pool_write_ok": diff <= KDA_TOLERANCE}
+
+    def chunk_case():
+        # every state to a slot of its own, but row 0's last (0: not kept)
+        keep = jnp.asarray(free[:3 * B], jnp.int32).reshape(B, 3).at[0, 2].set(0)
+        want = kda.kda_chunk(q, k, v, g, beta, pool, layer, rows, fresh, snap,
+                             keep, use_kernel=False)
+        got = kda.kda_chunk_kernel(q, k, v, g, beta, pool + 0, layer, rows,
+                                   fresh, snap, keep, interpret=interpret)
+        held = np.ones((slots,), bool)
+        held[0] = False  # the reference leaves what nobody keeps there
+        return {"max_err": _err(got[0], want[0]), **written(got[1], want[1], held)}
+
+    step = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+
+    def decode_case():
+        want = kda.kda_decode_ref(*step, pool, layer, rows)
+        got = kda.kda_decode_kernel(*step, pool + 0, layer, rows,
+                                    interpret=interpret)
+        return {"max_err": _err(got[0], want[0]), **written(got[1], want[1])}
+
+    def boundary_case():
+        dst = jnp.where(jnp.arange(B) % 2 == 0,
+                        jnp.asarray(free[:B], jnp.int32), 0)
+        got = kda.copy_slots_kernel(pool + 0, rows, dst, interpret=interpret)
+        want = np.asarray(pool).copy()
+        for s_, d_ in zip(np.asarray(rows), np.asarray(dst)):
+            if d_:
+                want[:, d_] = want[:, s_]
+        diff = float(np.max(np.abs(np.asarray(got) - want)))
+        return {"max_err": diff, "pool_write_diff": diff,
+                "pool_write_ok": diff == 0.0}
+
+    return [_verdict(name, case, KDA_TOLERANCE) for name, case in zip(
+        KDA_CASES, (chunk_case, decode_case, boundary_case))]

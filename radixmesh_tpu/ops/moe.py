@@ -43,7 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["route", "expert_layout", "grouped_matmul", "moe_ffn", "STATS",
-           "SHARE_STATS"]
+           "SHARE_STATS", "GROUP_STATS"]
 
 # What one expert-layer launch adds to the ``moe`` counters, in order.
 # ``assignments``, ``experts_hit`` and ``max_load`` count the experts this
@@ -52,37 +52,64 @@ __all__ = ["route", "expert_layout", "grouped_matmul", "moe_ffn", "STATS",
 # shares.
 STATS = ("layer_launches", "assignments", "experts_hit", "max_load")
 SHARE_STATS = STATS + ("assignments_elsewhere",)
+# A router with groups (``n_group`` > 1) reports all five and a sixth: the
+# (token, kept group) pairs whose group has experts held here — under a
+# share that is one routing group, the tokens that reach this chip at all.
+GROUP_STATS = SHARE_STATS + ("groups_kept",)
 
 _PREC = jax.lax.Precision.HIGHEST
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def route(x: jnp.ndarray, w_router: jnp.ndarray, k: int, norm_topk: bool,
-          scoring: str = "softmax", bias: jnp.ndarray | None = None,
-          scale: float = 1.0):
-    """``x`` [T, H] -> (expert ids [T, k] int32, weights [T, k] float32).
-    All arithmetic in float32, ties to the lower expert id
-    (``lax.top_k``). ``scoring`` "softmax": softmax over ALL experts
-    before the top-k (the source's order). "sigmoid": each expert's own
-    sigmoid score; the k are chosen by score + ``bias`` [E] and weighed by
-    the score alone. ``norm_topk`` divides the chosen weights by their sum
-    (+ 1e-20 under sigmoid, as the source does); ``scale`` multiplies them."""
+def _route(x, w_router, k, norm_topk, scoring, bias, scale, n_group, topk_group):
+    """:func:`route`, and the groups it kept ``[T, n_group]`` bool (None
+    for a router without groups)."""
     r = jnp.einsum(
         "th,he->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=_PREC,
     )
+    kept = None
     if scoring == "softmax":
         top_p, top_e = jax.lax.top_k(jax.nn.softmax(r, axis=-1), k)
     else:
         p = jax.nn.sigmoid(r)
-        _, top_e = jax.lax.top_k(p if bias is None else p + bias, k)
+        choice = p if bias is None else p + bias
+        if n_group > 1:
+            T, E = choice.shape
+            grouped = choice.reshape(T, n_group, E // n_group)
+            best_two = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+            _, groups = jax.lax.top_k(best_two, topk_group)  # [T, topk_group]
+            kept = jnp.any(
+                groups[..., None] == jnp.arange(n_group, dtype=groups.dtype),
+                axis=1)
+            choice = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(T, E)
+        _, top_e = jax.lax.top_k(choice, k)
         top_p = jnp.take_along_axis(p, top_e, axis=-1)
     if norm_topk:
         total = jnp.sum(top_p, axis=-1, keepdims=True)
         top_p = top_p / (total if scoring == "softmax" else total + 1e-20)
     if scale != 1.0:
         top_p = top_p * scale
-    return top_e.astype(jnp.int32), top_p
+    return top_e.astype(jnp.int32), top_p, kept
+
+
+def route(x: jnp.ndarray, w_router: jnp.ndarray, k: int, norm_topk: bool,
+          scoring: str = "softmax", bias: jnp.ndarray | None = None,
+          scale: float = 1.0, n_group: int = 1, topk_group: int = 1):
+    """``x`` [T, H] -> (expert ids [T, k] int32, weights [T, k] float32).
+    All arithmetic in float32, ties to the lower expert id
+    (``lax.top_k``). ``scoring`` "softmax": softmax over ALL experts
+    before the top-k (the source's order). "sigmoid": each expert's own
+    sigmoid score; the k are chosen by score + ``bias`` [E] and weighed by
+    the score alone. ``norm_topk`` divides the chosen weights by their sum
+    (+ 1e-20 under sigmoid, as the source does); ``scale`` multiplies them.
+    ``n_group`` > 1 (a sigmoid router): the experts are ``n_group`` runs of
+    ``E / n_group``; a group's score is the sum of its two largest score +
+    bias, the ``topk_group`` best groups stay (ties to the lower group) and
+    the k are chosen among their experts alone. ``n_group`` 1 traces what
+    a router without groups traces."""
+    return _route(x, w_router, k, norm_topk, scoring, bias, scale, n_group,
+                  topk_group)[:2]
 
 
 def tile_rows(n_tokens: int, k: int, n_experts: int) -> int:
@@ -251,13 +278,15 @@ def moe_ffn(
     scale: float = 1.0,  # multiplies the chosen weights
     valid: jnp.ndarray | None = None,  # [T] rows that are real tokens
     first_held: int = 0,  # the first expert this chip holds (an expert share)
+    n_group: int = 1,  # group-limited routing (``route``)
+    topk_group: int = 1,
     use_kernel: bool | None = None,
     interpret: bool = False,
 ):
     """The expert layer on ``x``. Returns ``(y [T, H] in x's dtype, expert
     ids [T, k], stats [4] int32)``; ``stats`` is what this launch adds to
     the counters named in ``STATS`` (``SHARE_STATS``, [5], under an expert
-    share), over the rows ``valid`` marks (all, if None). A layer scan
+    share; ``GROUP_STATS``, [6], for a router with groups), over the rows ``valid`` marks (all, if None). A layer scan
     passes the stacked expert weights and its ``layer`` index, never a
     slice (``grouped_matmul``). ``use_kernel=None`` follows the lowering
     target: the Pallas kernel for a TPU, the ``jax.numpy`` path for
@@ -274,7 +303,8 @@ def moe_ffn(
     layer = jnp.asarray(layer, jnp.int32)
     E, held = w_router.shape[1], w_gate.shape[1]
     with jax.named_scope("rm_moe_route"):
-        top_e, top_w = route(x, w_router, k, norm_topk, scoring, bias, scale)
+        top_e, top_w, kept = _route(
+            x, w_router, k, norm_topk, scoring, bias, scale, n_group, topk_group)
         ok = jnp.ones(x.shape[:1], bool) if valid is None else valid
         local = top_e - first_held
         local = jnp.where((local >= 0) & (local < held), local, held)
@@ -285,8 +315,13 @@ def moe_ffn(
         here = jnp.sum(load)
         stats = [jnp.int32(1), here, jnp.sum(load > 0).astype(jnp.int32),
                  jnp.max(load)]
-        if held < E:  # a share: what the router gave to the other shares
+        if held < E or kept is not None:
+            # a share: what the router gave to the other shares
             stats.append(jnp.sum(ok).astype(jnp.int32) * k - here)
+        if kept is not None:
+            size = E // n_group
+            mine = slice(first_held // size, -(-(first_held + held) // size))
+            stats.append(jnp.sum(kept[:, mine] & ok[:, None]).astype(jnp.int32))
         stats = jnp.stack(stats)
 
     def kernel(*args):

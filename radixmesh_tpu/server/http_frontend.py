@@ -29,8 +29,9 @@ that frontend with stdlib-only HTTP (no framework dependency):
 Threading model: the engine is single-threaded by design (host-side tree
 mutation between device steps, SURVEY §7 hard part (c)); an
 :class:`EngineRunner` thread owns it exclusively, stepping while work
-exists. HTTP handler threads only enqueue requests and poll for their
-completion — they never touch engine internals.
+exists. HTTP handler threads only enqueue requests and wait for their
+completion — they never touch engine internals. The decode tokens of every
+SSE stream are written by one emitter thread (:class:`_StreamEmitter`).
 
 The API is token-ids-native: tokenization is the client's concern (no
 tokenizer download in the serving path). A ``transformers`` tokenizer can
@@ -42,6 +43,7 @@ from __future__ import annotations
 import base64
 import json
 import os
+import socket
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -353,6 +355,107 @@ class _FrontendServer(ThreadingHTTPServer):
     # is reset a second later ("Connection reset by peer" before a byte
     # of the request was read: PR 41). The kernel caps it at somaxconn.
     request_queue_size = 1024
+
+
+def _token_events(tokens: Sequence[int]) -> bytes:
+    """The SSE lines of these tokens, as ``json.dumps({"token": t})``
+    writes them."""
+    return b"".join([b'data: {"token": %d}\n\n' % t for t in tokens])
+
+
+class _SseStream:
+    """One SSE reply: the request, its socket and how many tokens went out.
+    The emitter writes while ``emitter_owns``; a write that the socket does
+    not take at once (a reader that fell behind) or that fails hands the
+    stream to its handler thread for good, with what is still to go in
+    ``rest`` — so one slow reader never holds the other streams up."""
+
+    def __init__(self, req: Request, sock):
+        self.req, self.sock = req, sock
+        self.sent = 0
+        self.rest = b""
+        self.emitter_owns = True
+        self.lock = threading.Lock()
+
+    def pump(self) -> None:
+        """Write the tokens that landed since the last call (emitter
+        thread; never blocks)."""
+        with self.lock:
+            tokens = self.req.output_tokens
+            n = len(tokens)
+            if not self.emitter_owns or n <= self.sent:
+                return
+            data = _token_events(tokens[self.sent:n])
+            self.sent = n
+            try:
+                done = self.sock.send(data, socket.MSG_DONTWAIT)
+            except OSError:  # full (BlockingIOError) or gone: the handler's
+                done = 0     # own write waits, or raises where it always did
+            if done < len(data):
+                self.rest = data[done:]
+                self.release()
+
+    def release(self) -> None:
+        """From here on the handler thread writes (call with ``lock``)."""
+        self.emitter_owns = False
+        self.req.on_progress = None
+        with self.req.cond:
+            self.req.cond.notify_all()
+
+
+class _StreamEmitter:
+    """The one thread that writes the decode tokens of every SSE stream.
+    A decode step of B rows announces B tokens at once
+    (``Engine._flush_emit``); a handler thread a stream would wake B
+    threads, each taking the interpreter two or three times for one line —
+    and the engine thread, back from its wait for the device, queues behind
+    them for the next launch. Here the step sets one event B times, this
+    thread wakes once and writes B sockets; the handlers sleep until their
+    request finishes (``Request.cond``) and write the ``done`` event."""
+
+    def __init__(self):
+        self._streams: list[_SseStream] = []
+        self._lock = threading.Lock()
+        self._wake = threading.Event()
+        self._closed = False
+        self._thread: threading.Thread | None = None
+
+    def add(self, stream: _SseStream) -> None:
+        with self._lock:
+            self._streams.append(stream)
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, daemon=True, name="http-emit")
+                self._thread.start()
+        stream.req.on_progress = self._wake.set
+        self._wake.set()  # what landed before the hook was set
+
+    def remove(self, stream: _SseStream) -> None:
+        with stream.lock:
+            if stream.emitter_owns:
+                stream.release()
+        with self._lock:
+            self._streams.remove(stream)
+
+    def close(self) -> None:
+        self._closed = True
+        self._wake.set()
+
+    def _run(self) -> None:
+        while not self._closed:
+            if not self._wake.wait(timeout=1.0):
+                continue
+            self._wake.clear()
+            with self._lock:
+                streams = list(self._streams)
+            with annotate("rm.http.emit"):
+                for stream in streams:
+                    try:
+                        stream.pump()
+                    except Exception:  # noqa: BLE001 — this thread serves every stream: one that cannot be written goes back to its handler, which fails alone
+                        with stream.lock:
+                            if stream.emitter_owns:
+                                stream.release()
 
 
 def _b64_array(a: np.ndarray) -> dict:
@@ -675,6 +778,7 @@ class ServingFrontend:
         self.profile_dir = profile_dir
         self._profile_lock = threading.Lock()
         self._profile_seq = 0
+        self.emitter = _StreamEmitter()  # the SSE streams' one writer
         frontend = self
 
         # -- /debug surfaces (flight-recorder + live state) ------------
@@ -821,6 +925,10 @@ class ServingFrontend:
                         * eng.pool.kv.dtype.itemsize),
                     "bytes_per_slot": eng.pool.state_slot_bytes,
                 }
+            if eng.kda_stats is not None:
+                # Linear-attention layers: what the launches moved of the
+                # state slots (Engine.kda_stats).
+                state["kda"] = dict(eng.kda_stats)
             if eng.tail_stats is not None:
                 # A model whose resume points are window tails alone: how
                 # requests resumed, what the tree took and gave up.
@@ -1375,26 +1483,37 @@ class ServingFrontend:
                 self.send_header("Content-Type", "text/event-stream")
                 self.send_header("Cache-Control", "no-cache")
                 self.end_headers()
-                sent = 0
+                # The emitter thread writes the tokens (_StreamEmitter);
+                # this thread sleeps until the request finishes — or until
+                # the emitter hands the stream back (a reader that fell
+                # behind), and then writes them itself, as below.
+                stream = _SseStream(req, self.connection)
+                frontend.emitter.add(stream)
+                try:
+                    with req.cond:
+                        while (stream.emitter_owns
+                               and req.state is not RequestState.FINISHED):
+                            req.cond.wait(timeout=0.5)
+                finally:
+                    frontend.emitter.remove(stream)
+                if stream.rest:
+                    self.wfile.write(stream.rest)
+                sent = stream.sent
                 while True:
                     # One span per wake-up (not per token): encode +
                     # write + flush of whatever landed since the last.
                     with annotate("rm.http.emit"):
-                        tokens = frontend.runner.tokens_so_far(req)
-                        for t in tokens[sent:]:
-                            self.wfile.write(
-                                f"data: {json.dumps({'token': t})}\n\n"
-                                .encode()
-                            )
-                        sent = len(tokens)
+                        tokens = req.output_tokens
+                        n = len(tokens)
+                        if n > sent:
+                            self.wfile.write(_token_events(tokens[sent:n]))
+                            sent = n
                         self.wfile.flush()
                     if req.state is RequestState.FINISHED:
                         with annotate("rm.http.emit"):
                             final = frontend.runner.tokens_so_far(req)
-                            for t in final[sent:]:
-                                self.wfile.write(
-                                    f"data: {json.dumps({'token': t})}\n\n".encode()
-                                )
+                            if len(final) > sent:
+                                self.wfile.write(_token_events(final[sent:]))
                             done_evt = {
                                 "done": True,
                                 "output_ids": final,
@@ -1447,6 +1566,7 @@ class ServingFrontend:
     def close(self, drain_s: float = 5.0) -> None:
         self._server.shutdown()
         self._server.server_close()
+        self.emitter.close()
         if self.blackbox is not None:
             # Graceful shutdown writes one last final (the drain hook
             # may already have written its own; each final is complete

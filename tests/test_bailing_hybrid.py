@@ -1,0 +1,680 @@
+"""Ling-3.0-flash (``models/bailing_hybrid.py``) at toy widths on the CPU:
+the three entry points, the state slots (a float32 matrix a head and the
+convolutions' inputs), the latent pool and the radix tree's snapshots
+against the plain float32 reference (``benchmarks/reference/
+bailing_hybrid.py``) — logits, not tokens — the forms of the recurrence
+against each other, the group-limited router, what must NOT agree (the
+controls), the expert shares, and what is refused."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import bailing_hybrid as ref
+from radixmesh_tpu.engine.engine import Engine, pool_groups
+from radixmesh_tpu.engine.request import SamplingParams
+from radixmesh_tpu.models import get_config, init_params
+from radixmesh_tpu.models.llama import (
+    ModelConfig,
+    UnsupportedForModel,
+    _ffn,
+    read_moe_stats,
+)
+from radixmesh_tpu.ops import kda
+from radixmesh_tpu.ops.kernel_check import KDA_CASES, KDA_TOLERANCE, check_kda
+from radixmesh_tpu.ops.moe import GROUP_STATS, moe_ffn, route
+from tests.test_mellum2 import REFUSED  # the same options, by name
+
+pytestmark = pytest.mark.quick
+
+PAGE = 16
+N_NEW = 9  # one token from the prefill, 8 decode steps through the slots
+TOL = 1e-4  # float32 against float32: other orders of the same sums
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = get_config("ling-tiny").replace(dtype=jnp.float32)
+    return cfg, init_params(cfg, jax.random.PRNGKey(48))
+
+
+def make_engine(model, **kw):
+    cfg, params = model
+    kw.setdefault("num_slots", 4096)
+    kw.setdefault("page_size", PAGE)
+    kw.setdefault("max_batch", 4)
+    kw.setdefault("max_seq_len", 1024)
+    kw.setdefault("prefill_chunk", 64)
+    return Engine(cfg, params, **kw)
+
+
+def prompt_of(n, seed=0):
+    return np.random.default_rng([48, seed]).integers(0, 512, n).tolist()
+
+
+def run(eng, prompt, n_new=N_NEW):
+    req = eng.add_request(prompt, SamplingParams(
+        max_new_tokens=n_new, return_logits=True))
+    while eng.has_work():
+        eng.step()
+    return req
+
+
+def distance(got, want) -> float:
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def error(model, req, prompt, **control):
+    """``max|a - b| / max|b|`` of the request's logits rows against the
+    reference's full forward pass of the same sequence."""
+    cfg, params = model
+    seq = list(prompt) + req.generated[:-1]
+    want = ref.forward(params, cfg, seq, logits_from=len(prompt) - 1, **control)
+    return distance(np.stack(req.logit_rows), want["logits"])
+
+
+def check(model, req, prompt, n_new=N_NEW):
+    assert len(req.generated) == n_new and len(req.logit_rows) == n_new
+    err = error(model, req, prompt)
+    assert err < TOL, err
+    assert [int(x) for x in np.stack(req.logit_rows).argmax(-1)] == req.generated
+
+
+# ---------------------------------------------------------------------------
+# the three entry points against the reference
+# ---------------------------------------------------------------------------
+
+
+# 7: inside one page; 48: a page multiple; 150: chunks of 64 over ten pages
+@pytest.mark.parametrize("n_prompt", [7, 48, 150])
+@pytest.mark.parametrize("path", ["dense", "chunk"])
+def test_prefill_then_decode_through_the_slots_and_the_latent_pool(
+        model, path, n_prompt):
+    eng = make_engine(
+        model, long_prefill_threshold=1024 if path == "dense" else 0)
+    prompt = prompt_of(n_prompt)
+    req = run(eng, prompt)
+    assert eng._last_prefill_path == path
+    check(model, req, prompt)
+    cfg = model[0]
+    # rows of the one latent layer alone in the pool; a float32 matrix a
+    # head and the three convolutions' inputs in the state slots
+    assert eng.pool.kv.shape == (1, 1, 1, 4096, 128)
+    assert eng.pool.state.shape[0] == 3 and eng.pool.state.dtype == jnp.float32
+    assert eng.pool.state.shape[2:] == (4 * 16, 16)
+    assert eng.pool.state_conv.shape[2:] == (5, 128)  # 3 x 192 in 640
+    assert eng.pool.state_slot_bytes == cfg.state_slot_bytes() == 3 * (
+        64 * 16 * 4 + 5 * 128 * 4)
+
+
+def test_a_batch_of_ragged_rows(model):
+    eng = make_engine(model)
+    prompts = [prompt_of(n, seed=30 + i) for i, n in enumerate((20, 75, 130, 41))]
+    reqs = [eng.add_request(p, SamplingParams(
+        max_new_tokens=N_NEW + i, return_logits=True))
+        for i, p in enumerate(prompts)]
+    while eng.has_work():
+        eng.step()
+    for i, (req, p) in enumerate(zip(reqs, prompts)):
+        check(model, req, p, n_new=N_NEW + i)
+
+
+def test_forty_decode_steps_keep_the_state_exact(model):
+    eng = make_engine(model)
+    prompt = prompt_of(50, seed=1)
+    req = run(eng, prompt, n_new=40)
+    check(model, req, prompt, n_new=40)
+
+
+# ---------------------------------------------------------------------------
+# every way a snapshot is reached
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n_prompt, resumed", [(100, 96), (96, 80)])
+def test_the_identical_prompt_again_resumes_from_the_snapshot(
+        model, n_prompt, resumed):
+    """At a resume point (100: the snapshot at 96), and one page short of
+    the match (96, a page multiple: a token must be left to compute, the
+    request resumes at 80 where its second visit left a snapshot, and
+    computes the page between again)."""
+    eng = make_engine(model)
+    prompt = prompt_of(n_prompt, seed=3)
+    first = run(eng, prompt)
+    assert first.prefix_len == 0
+    again = run(eng, prompt)
+    third = run(eng, prompt)
+    assert third.prefix_len == resumed and eng._last_prefill_path == "chunk"
+    assert again.prefix_len == (resumed if n_prompt % PAGE else 0)
+    for req in (first, again, third):
+        check(model, req, prompt)
+    st = eng.state_stats
+    assert st["resumed_tokens"] == again.prefix_len + third.prefix_len
+    assert eng.stats.cached_tokens == st["resumed_tokens"]
+
+
+def test_the_next_turn_resumes_from_the_release_snapshot(model):
+    """Turn 2 resumes from the state the decode steps kept at the last page
+    boundary (a boundary copy; no prefill ever computed it)."""
+    eng = make_engine(model)
+    p1 = prompt_of(70, seed=4)
+    t1 = run(eng, p1, n_new=30)  # context 99 at release: cut at 96
+    check(model, t1, p1, n_new=30)
+    p2 = p1 + t1.generated + prompt_of(11, seed=5)
+    t2 = run(eng, p2)
+    assert t2.prefix_len == 96 and eng.state_stats["recomputed_tokens"] == 0
+    check(model, t2, p2)
+    p3 = p2 + t2.generated + prompt_of(5, seed=6)
+    t3 = run(eng, p3)
+    assert t3.prefix_len == (len(p2) + N_NEW - 1) // PAGE * PAGE
+    check(model, t3, p3)
+
+
+def test_two_requests_branch_inside_a_shared_prefix(model):
+    eng = make_engine(model)
+    shared = prompt_of(64, seed=7)
+    a = shared + prompt_of(50, seed=8)
+    run(eng, a)
+    b = shared + prompt_of(40, seed=9)
+    rb = run(eng, b)
+    assert rb.prefix_len == 0  # rows for 64 tokens, no snapshot there
+    assert eng.state_stats["recomputed_tokens"] == 64
+    c = shared + prompt_of(33, seed=10)
+    rc = run(eng, c)
+    assert rc.prefix_len == 64  # b left one at the branch point
+    check(model, rb, b)
+    check(model, rc, c)
+
+
+def test_one_launch_holds_a_row_that_resumes_and_a_cold_one(model):
+    eng = make_engine(model)
+    first = prompt_of(100, seed=11)
+    run(eng, first)
+    warm = first + prompt_of(80, seed=13)  # resumes at 96: 84 to compute
+    cold = prompt_of(90, seed=12)
+    before = dict(eng.prefill)
+    reqs = [eng.add_request(p, SamplingParams(
+        max_new_tokens=N_NEW, return_logits=True)) for p in (warm, cold)]
+    while eng.has_work():
+        eng.step()
+    assert [r.prefix_len for r in reqs] == [96, 0]
+    # ONE wave of two rows, chunk launches of both: one row reads its state
+    # from the tree's snapshot, the other starts from zeros
+    assert eng.prefill["waves"] - before["waves"] == 1
+    assert eng.prefill["rows"] - before["rows"] == 2
+    assert eng.prefill["launches"] - before["launches"] == 2  # 64 + 32 > 84, 90
+    check(model, reqs[0], warm)
+    check(model, reqs[1], cold)
+
+
+def test_a_zeroed_snapshot_would_not_pass(model):
+    """The control of the resume tests: the reference that forgets every
+    KDA state and convolution input at the resume position is far off."""
+    eng = make_engine(model)
+    prompt = prompt_of(100, seed=3)
+    run(eng, prompt)
+    again = run(eng, prompt)
+    assert again.prefix_len == 96
+    assert error(model, again, prompt) < TOL
+    assert error(model, again, prompt, forget_at=96) > 100 * TOL
+
+
+def test_a_snapshot_evicted_under_its_rows_is_computed_again(model):
+    eng = make_engine(model)
+    prompt = prompt_of(100, seed=14)
+    run(eng, prompt)
+    run(eng, prompt + prompt_of(30, seed=15))
+    n = eng.pool.state_slots_used - 1
+    assert n > 0 and eng.tree.evict_states(8) == n
+    assert eng.pool.state_slots_used == 1  # the scratch slot
+    m = eng.tree.match_prefix(np.asarray(prompt, np.int32))
+    assert m.length == 96 and m.states == []  # the latent rows stay
+    again = run(eng, prompt)
+    assert again.prefix_len == 0 and eng.state_stats["recomputed_tokens"] == 96
+    check(model, again, prompt)
+    assert run(eng, prompt).prefix_len == 96  # the snapshot is back
+
+
+def test_plain_launches_keep_the_boundary_state(model):
+    """Without the logits hook the decode launches hand over (inputs built
+    a step ahead): a session served that way continues exactly as a cold
+    engine computes it, and resumes where the boundary copy was made."""
+    sp = SamplingParams(max_new_tokens=70)
+    p1 = prompt_of(50, seed=17)
+    warm = make_engine(model)
+    o1 = warm.generate([p1], sp)[0]
+    p2 = p1 + o1 + prompt_of(7, seed=18)
+    o2 = warm.generate([p2], sp)[0]
+    assert warm.handover["launches_prebuilt"] > 0
+    assert warm.stats.cached_tokens == 112  # 50 + 70 - 1 = 119 -> 112
+    cold = make_engine(model)
+    assert cold.generate([p2], sp)[0] == o2
+
+
+# ---------------------------------------------------------------------------
+# the state path: by bytes, a boundary copy on a page-completing step only
+# ---------------------------------------------------------------------------
+
+
+def test_state_slots_are_counted_by_bytes_at_published_widths():
+    cfg = get_config("ling-3.0-flash-ep8")
+    # 6 kda layers x (32 x 128 x 128 float32 + 3 x 12 288 bf16)
+    assert cfg.state_slot_bytes() == 6 * (2 * 2**20 + 3 * 12288 * 2) == 13025280
+    assert cfg.state_shape(321) == (6, 321, 4096, 128)
+    assert cfg.conv_state_shape(321) == (6, 321, 288, 128)
+    assert cfg.state_dtype == jnp.float32
+    # a quarter of the latent pool buys 12 snapshots: the five a row hold
+    assert cfg.kv_pool_shape(524288) == (1, 1, 1, 524288, 640)
+    assert cfg.default_state_slots(524288, 64) == 1 + 5 * 64
+    assert cfg.default_state_slots(524288, 32) == 1 + 5 * 32
+    groups = pool_groups(cfg, 524288, 64, 16)
+    assert groups["state_shape"] == (6, 321, 4096, 128)
+    assert groups["conv_state_shape"] == (6, 321, 288, 128)
+    assert groups["window_shape"] is None
+    whole = get_config("ling-3.0-flash")
+    assert len(whole.kda_layers) == 35 and len(whole.attn_layers) == 7
+    assert whole.attn_layers == (5, 11, 17, 23, 29, 35, 41)
+
+
+def test_a_boundary_copy_is_made_on_the_step_that_completes_a_page(model):
+    """44 decode steps from a context of 20: the tokens at positions 31, 47
+    and 63 complete a page. Three copies in all, none on any other step,
+    and each leaves the working slot's state in the boundary slot."""
+    eng = make_engine(model)
+    req = eng.add_request(prompt_of(20, seed=21), SamplingParams(
+        max_new_tokens=45, return_logits=True))
+    copies = []
+    while eng.has_work():
+        before = eng.kda_stats["boundary_copies"]
+        kv_before = req.kv_len
+        eng.step()
+        eng._settle()
+        made = eng.kda_stats["boundary_copies"] - before
+        copies.append(made)
+        if made:
+            assert req.kv_len % PAGE == 0 and req.kv_len == kv_before + 1
+            work, keep = req.state_slot, req.state_boundary
+            if work and keep:  # still in its row
+                assert np.array_equal(eng.pool.state[:, work], eng.pool.state[:, keep])
+                assert np.array_equal(
+                    eng.pool.state_conv[:, work], eng.pool.state_conv[:, keep])
+    assert sum(copies) == 3
+    st = eng.kda_stats
+    assert st["decode_rows"] == 44 and st["chunk_tokens"] == 20
+    per = eng.pool.state_slot_bytes
+    # a launch moves every row of the launched batch; 3 prefill snapshots
+    assert st["state_bytes_written"] == (44 * 4 + 3 + 3) * per
+    assert st["state_bytes_read"] == 44 * 4 * per
+
+
+def test_a_full_state_group_gives_up_superseded_snapshots_first(model):
+    """Slots squeezed to what two sessions need: the tree gives up the
+    snapshots a later one of the same sequence supersedes before any a
+    waiting session resumes from, and nothing served goes wrong."""
+    eng = make_engine(model, max_batch=2)
+    taken = []
+    while eng.pool.state_allocator.free_slots > 4:
+        taken.append(eng.pool.alloc_state())
+    p = prompt_of(60, seed=22)
+    first = run(eng, p, n_new=40)
+    check(model, first, p, n_new=40)
+    p2 = p + first.generated + prompt_of(9, seed=23)
+    second = run(eng, p2, n_new=40)
+    assert second.prefix_len == 96
+    check(model, second, p2, n_new=40)
+    p3 = p2 + second.generated + prompt_of(9, seed=24)
+    third = run(eng, p3, n_new=40)
+    check(model, third, p3, n_new=40)
+    assert third.prefix_len == (len(p2) + 39) // PAGE * PAGE
+    assert eng.tree.state_evictions > 0 and eng.stats.preemptions == 0
+    # what is left is the deepest: the earlier turns' snapshots went first
+    m = eng.tree.match_prefix(np.asarray(p3 + third.generated, np.int32))
+    assert m.states and max(pos for pos, _ in m.states) >= third.prefix_len
+    for slot in taken:
+        eng.pool.free_state(slot)
+
+
+# ---------------------------------------------------------------------------
+# the forms of the recurrence
+# ---------------------------------------------------------------------------
+
+
+def _kda_inputs(B, T, H, d, seed, g=None):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (B, T, H, d))) * d ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, T, H, d)))
+    v = jax.random.normal(ks[2], (B, T, H, d))
+    if g is None:
+        g = -5 * jax.nn.sigmoid(3 * jax.random.normal(ks[3], (B, T, H, d)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H)))
+    S0 = jax.random.normal(ks[5], (B, H, d, d))
+    return q, k, v, g, beta, S0
+
+
+@pytest.mark.parametrize("gate", ["drawn", "at_its_bound", "open"])
+@pytest.mark.parametrize("block", [16, 64])
+def test_the_chunked_form_equals_the_recurrence(gate, block):
+    """With every gate driven to its bound (g = -5 over 64 tokens: the
+    cumulative log-decay reaches -320) the chunked form stays finite and
+    within 1e-4: it works in differences of the cumulative log-decay."""
+    g = {"drawn": None, "at_its_bound": jnp.full((2, 64, 4, 16), -5.0),
+         "open": jnp.full((2, 64, 4, 16), -1e-4)}[gate]
+    q, k, v, g, beta, S0 = _kda_inputs(2, 64, 4, 16, 1, g)
+    o, snaps = kda.kda_recurrent_ref(q, k, v, g, beta, S0, jnp.full((2, 1), 64))
+    ob, Sb = kda.kda_blocked_ref(q, k, v, g, beta, S0, block=block)
+    assert bool(jnp.isfinite(ob).all()) and bool(jnp.isfinite(Sb).all())
+    assert distance(np.asarray(ob), np.asarray(o)) < TOL
+    assert distance(np.asarray(Sb), np.asarray(snaps[:, 0])) < TOL
+
+
+@pytest.mark.parametrize("T", [1, 8, 48])
+def test_the_chunk_kernel_equals_the_recurrence_with_snapshots_inside(T):
+    q, k, v, g, beta, S0 = _kda_inputs(2, T, 4, 16, 2)
+    snap = jnp.asarray([[T, T // 2, 0], [max(T - 3, 0), 1, T]], jnp.int32)
+    o, snaps = kda.kda_recurrent_ref(q, k, v, g, beta, S0, snap)
+    pool = jnp.stack([jnp.ones((5, 64, 16)), jnp.concatenate(
+        [jnp.ones((3, 64, 16)), S0.reshape(2, 64, 16)])])  # slots 3, 4 of layer 1
+    # row 0 keeps to slots 5, 6, 7 and row 1 to 8, 0 (not kept), 9
+    keep = jnp.asarray([[5, 6, 7], [8, 0, 9]], jnp.int32)
+    pool = jnp.concatenate([pool, jnp.ones((2, 5, 64, 16))], axis=1)
+    ok, pk = kda.kda_chunk(q, k, v, g, beta, pool + 0, 1, jnp.asarray([3, 4]),
+                           jnp.zeros((2,)), snap, keep, use_kernel=True,
+                           interpret=True)
+    assert distance(np.asarray(ok), np.asarray(o)) < 1e-6
+    for b, j in ((0, 0), (0, 1), (0, 2), (1, 0), (1, 2)):
+        assert distance(np.asarray(pk[1, keep[b, j]]),
+                        np.asarray(snaps[b, j].reshape(64, 16))) < 1e-6
+    assert np.array_equal(pk[1, 7], S0[0].reshape(64, 16))  # 0: as it started
+    # nothing else of the array moved: not slot 0, not the other layer
+    assert np.array_equal(pk[0], pool[0])
+    assert np.array_equal(pk[1, :5], pool[1, :5])
+    # a fresh row starts from zeros whatever its slot holds
+    zero = jnp.zeros_like(S0)
+    o0, _ = kda.kda_recurrent_ref(q, k, v, g, beta, zero, snap)
+    for kernel in (True, False):
+        of, pf = kda.kda_chunk(q, k, v, g, beta, pool + 0, 1, jnp.asarray([3, 4]),
+                               jnp.ones((2,)), snap, keep, use_kernel=kernel,
+                               interpret=True)
+        assert distance(np.asarray(of), np.asarray(o0)) < 1e-6
+        assert not np.asarray(pf[1, 7]).any()
+
+
+def test_a_launch_of_more_rows_than_a_call_takes_is_several_calls_on_one_array():
+    """``_ROWS_A_CALL`` rows a call (a launch of 32 rows in one call beside
+    the latent chunk kernel never returned on the chip): the calls share
+    the slot array, each row keeps to its own slots."""
+    B = kda._ROWS_A_CALL + 3
+    q, k, v, g, beta, S0 = _kda_inputs(B, 16, 2, 16, 6)
+    snap = jnp.tile(jnp.asarray([[16, 5]], jnp.int32), (B, 1))
+    _, snaps = kda.kda_recurrent_ref(q, k, v, g, beta, S0, snap)
+    pool = jnp.concatenate([jnp.ones((1, 32, 16)), S0.reshape(B, 32, 16),
+                            jnp.ones((2 * B, 32, 16))])[None]
+    rows = 1 + jnp.arange(B)
+    keep = (1 + B + jnp.arange(2 * B)).reshape(B, 2)
+    want = kda.kda_chunk(q, k, v, g, beta, pool, 0, rows, jnp.zeros((B,)), snap,
+                         keep, use_kernel=False)
+    got = kda.kda_chunk(q, k, v, g, beta, pool + 0, 0, rows, jnp.zeros((B,)),
+                        snap, keep, use_kernel=True, interpret=True)
+    assert distance(np.asarray(got[0]), np.asarray(want[0])) < 1e-6
+    assert distance(np.asarray(got[1]), np.asarray(want[1])) < 1e-6
+    assert distance(np.asarray(got[1][0, 1 + B:]),
+                    np.asarray(snaps.reshape(2 * B, 32, 16))) < 1e-6
+
+
+def test_the_decode_kernel_updates_the_rows_slots_in_place_and_no_other():
+    q, k, v, g, beta, _ = _kda_inputs(3, 1, 4, 16, 3)
+    pool = jax.random.normal(jax.random.PRNGKey(9), (2, 9, 64, 16))
+    slots = jnp.asarray([4, 7, 1])
+    args = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+    o, new = kda.kda_decode_ref(*args, pool, 1, slots)
+    ok, nk = kda.kda_decode(*args, pool + 0, 1, slots, use_kernel=True,
+                            interpret=True)
+    assert distance(np.asarray(ok), np.asarray(o)) < 1e-6
+    assert distance(np.asarray(nk), np.asarray(new)) < 1e-6
+    untouched = np.ones((2, 9), bool)
+    untouched[1, [4, 7, 1]] = False
+    assert np.array_equal(np.asarray(nk)[untouched], np.asarray(pool)[untouched])
+    o1, _ = kda.kda_recurrent_ref(
+        q, k, v, g, beta, pool[1, slots].reshape(3, 4, 16, 16), jnp.ones((3, 1)))
+    assert distance(np.asarray(ok), np.asarray(o1[:, 0])) < 1e-6
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_boundary_copies_move_the_rows_that_want_one(kernel):
+    pool = jax.random.normal(jax.random.PRNGKey(4), (3, 8, 64, 16))
+    src, dst = jnp.asarray([2, 5, 6]), jnp.asarray([0, 3, 0])
+    out = kda.copy_slots(pool + 0, src, dst, use_kernel=kernel, interpret=True)
+    assert np.array_equal(out[:, 3], pool[:, 5])
+    keep = [1, 2, 4, 5, 6, 7] + ([0] if kernel else [])  # slot 0 is nobody's
+    assert np.array_equal(out[:, keep], pool[:, keep])
+
+
+def test_the_convolution_hands_back_the_inputs_before_each_snapshot():
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 10, 24))
+    w = jax.random.normal(jax.random.PRNGKey(6), (4, 24))
+    state = jax.random.normal(jax.random.PRNGKey(7), (2, 3, 24))
+    y, snaps = kda.short_conv(x, w, state, jnp.asarray([[10, 0, 2], [4, 1, 10]]))
+    xs = np.concatenate([state, x], axis=1)
+    want = sum(np.asarray(w)[j] * xs[:, j:j + 10] for j in range(4))
+    assert distance(np.asarray(y), np.asarray(jax.nn.silu(want))) < 1e-6
+    assert np.array_equal(snaps[0, 0], x[0, 7:10])
+    assert np.array_equal(snaps[0, 1], state[0])
+    assert np.array_equal(snaps[0, 2], xs[0, 2:5])
+    assert np.array_equal(snaps[1, 1], xs[1, 1:4])
+    y1, last = kda.short_conv(x, w, state, None)
+    assert np.array_equal(last[:, 0], x[:, 7:10]) and np.array_equal(y1, y)
+
+
+# ---------------------------------------------------------------------------
+# the router's groups
+# ---------------------------------------------------------------------------
+
+
+def _router(seed=0, T=64, H=32, E=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (T, H)), jax.random.normal(ks[1], (H, E)),
+            0.02 * jax.random.normal(ks[2], (E,)))
+
+
+def test_without_groups_the_router_is_todays():
+    x, w, b = _router()
+    old = route(x, w, 2, True, "sigmoid", b, 2.5)
+    new = route(x, w, 2, True, "sigmoid", b, 2.5, n_group=1, topk_group=1)
+    assert all(np.array_equal(a, c) for a, c in zip(old, new))
+    text = [jax.jit(lambda x, w, b, kw=kw: route(
+        x, w, 2, True, "sigmoid", b, 2.5, **kw)).lower(x, w, b).as_text()
+        for kw in ({}, {"n_group": 1, "topk_group": 1})]
+    assert text[0] == text[1]  # the program it traces today
+
+
+def test_the_groups_limit_the_choice_and_match_the_reference():
+    x, w, b = _router(1)
+    ids, weights = route(x, w, 2, True, "sigmoid", b, 2.5, n_group=4, topk_group=2)
+    free, _ = route(x, w, 2, True, "sigmoid", b, 2.5)
+    assert not np.array_equal(ids, free)  # a reference that ignores groups differs
+    lp = {"w_router": w, "router_bias": b}
+    kw = dict(top_k=2, norm_topk=True, scaling=2.5)
+    r_ids, r_w, scores, raw = ref.route(x, lp, n_group=4, topk_group=2, **kw)
+    assert np.array_equal(ids, r_ids) and distance(weights, r_w) < 1e-6
+    assert not np.array_equal(ref.route(x, lp, n_group=1, topk_group=1, **kw)[0], ids)
+    # two groups kept a token, the chosen experts inside them
+    kept = np.isfinite(np.asarray(scores)).reshape(64, 4, 4).all(-1)
+    assert (kept.sum(-1) == 2).all()
+    assert kept[np.arange(64)[:, None], np.asarray(ids) // 4].all()
+    # a group's score is the sum of its TWO best: by hand for one token
+    s = np.asarray(jax.nn.sigmoid(x @ w) + b)[0].reshape(4, 4)
+    best = np.sort(s, axis=-1)[:, -2:].sum(-1)
+    assert set(np.argsort(-best)[:2]) == set(np.flatnonzero(kept[0]))
+    # the tool's measure of a choice: 0 for the router's own, positive for
+    # an expert it did not choose, by what its group lacks where that is out
+    raw = np.asarray(raw)
+    assert not ref.shortfall(raw, np.asarray(ids), 4, 2).any()
+    out = np.flatnonzero(~kept[0])[0] * 4  # an expert of a group not kept
+    lack = ref.shortfall(raw[:1], np.asarray([[out, out + 1]]), 4, 2)
+    assert (lack > 0).all() and np.allclose(
+        lack, np.sort(best)[-2] - best[out // 4])
+
+
+def test_ties_between_groups_go_to_the_lower_group():
+    w = jnp.zeros((8, 16))
+    ids, _ = route(jnp.ones((3, 8)), w, 2, True, "sigmoid", None, 1.0,
+                   n_group=4, topk_group=2)
+    assert np.array_equal(ids, np.tile([0, 1], (3, 1)))
+
+
+def test_groups_kept_counts_the_tokens_that_reach_this_share(model):
+    cfg, params = model
+    x = jax.random.normal(jax.random.PRNGKey(3), (40, cfg.hidden))
+    lp = {k: v[0] for k, v in params["layers"].items()}
+    stacked = {k: params["layers"][k][0] for k in ("w_gate", "w_up", "w_down")}
+    kw = dict(k=2, norm_topk=True, site="decode", scoring="sigmoid",
+              bias=lp["router_bias"], scale=2.5, n_group=4, topk_group=2,
+              use_kernel=False)
+    _, ids, stats = moe_ffn(x, lp["w_router"], *stacked.values(), **kw)
+    st = dict(zip(GROUP_STATS, map(int, stats)))
+    assert st["groups_kept"] == 40 * 2 and st["assignments_elsewhere"] == 0
+    # the first of four shares: experts 0-3, one routing group
+    share = {k: v[:4] for k, v in stacked.items()}
+    _, ids4, stats4 = moe_ffn(x, lp["w_router"], *share.values(), **kw)
+    st4 = dict(zip(GROUP_STATS, map(int, stats4)))
+    assert np.array_equal(ids, ids4)
+    assert st4["assignments"] == int((np.asarray(ids) < 4).sum())
+    assert st4["assignments"] + st4["assignments_elsewhere"] == 80
+    # a token reaches this chip only when group 0 is among its kept groups
+    reach = int((np.asarray(ids) < 4).any(-1).sum())
+    assert reach <= st4["groups_kept"] <= 40
+
+
+def test_the_engine_publishes_groups_kept(model):
+    eng = make_engine(model)
+    run(eng, prompt_of(30, seed=40))
+    stats = read_moe_stats(eng._moe_stats)
+    assert stats["prefill"]["groups_kept"] == 30 * 3 * 2  # tokens x layers x kept
+    assert stats["decode"]["groups_kept"] == 8 * 3 * 2
+
+
+# ---------------------------------------------------------------------------
+# the controls: what must not agree
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "part", ["channel_decay", "beta", "conv", "gate", "kda_gate", "groups"])
+def test_the_reference_without_a_part_is_refused(model, part):
+    """The per-channel decay (against one decay a head), beta, the
+    convolution, the latent layers' head-wise gate, the KDA output gate
+    and the router's groups each matter: without it the reference is far
+    outside the tolerance the program meets."""
+    eng = make_engine(model)
+    prompt = prompt_of(60, seed=50)
+    req = run(eng, prompt)
+    assert error(model, req, prompt) < TOL
+    assert error(model, req, prompt, without=(part,)) > 100 * TOL
+
+
+def test_a_bf16_state_is_refused(model):
+    eng = make_engine(model)
+    prompt = prompt_of(60, seed=51)
+    req = run(eng, prompt)
+    assert error(model, req, prompt, state_dtype=jnp.bfloat16) > 10 * TOL
+
+
+@pytest.mark.parametrize("state_dtype", [None, jnp.bfloat16])
+def test_the_kernel_check_tells_the_states_precision(state_dtype):
+    """``check_kda`` (the chip smoke's kernels phase) is where a state slot
+    of lower precision shows on the chip: float32 slots pass its tolerance
+    with room, the same states held in bf16 fail it."""
+    res = check_kda(heads=4, dim=16, batch=3, chunk=16, interpret=True,
+                    state_dtype=state_dtype)
+    assert [r["kernel"] for r in res] == list(KDA_CASES)
+    assert all(r["tolerance"] == KDA_TOLERANCE for r in res)
+    chunk, decode, boundary = res
+    if state_dtype is None:
+        assert all(r["ok"] for r in res), res
+        assert max(chunk["pool_write_diff"], decode["pool_write_diff"]) < KDA_TOLERANCE / 10
+    else:
+        assert not chunk["ok"] and not decode["ok"], res
+        assert min(chunk["pool_write_diff"], decode["pool_write_diff"]) > 10 * KDA_TOLERANCE
+
+
+# ---------------------------------------------------------------------------
+# the expert shares add up
+# ---------------------------------------------------------------------------
+
+
+def test_the_shares_routed_parts_and_the_shared_expert_once_add_up(model):
+    """Four shares of the 16 experts (one routing group each): their routed
+    parts plus the shared expert counted once are the uncut layer."""
+    cfg, params = model
+    x = jax.random.normal(jax.random.PRNGKey(11), (1, 24, cfg.hidden))
+    lp = {k: v[1] for k, v in params["layers"].items()}
+    whole, _ = _ffn(params, lp, 2, x, cfg, "prefill", use_kernel=False)
+    shared = (jax.nn.silu(x @ lp["ws_gate"]) * (x @ lp["ws_up"])) @ lp["ws_down"]
+    parts = []
+    for rank in range(4):
+        held = slice(4 * rank, 4 * rank + 4)
+        sliced = {"layers": params["layers"] | {
+            k: params["layers"][k][:, held] for k in ("w_gate", "w_up", "w_down")}}
+        y, (ids, stats) = _ffn(
+            sliced, lp, 2, x, cfg.replace(ep_size=4, ep_rank=rank), "prefill",
+            use_kernel=False)
+        parts.append(y - shared)
+    assert distance(np.asarray(sum(parts) + shared), np.asarray(whole)) < 1e-5
+    assert distance(np.asarray(parts[0]), np.asarray(whole - shared)) > 0.1
+    # and the reference given the same share computes the same part
+    flat = {k: v for k, v in lp.items()}
+    flat |= {k: params["layers"][k][1][:4] for k in ("w_gate", "w_up", "w_down")}
+    ids, w, *_ = ref.route(x[0], flat, top_k=2, norm_topk=True, scaling=2.5,
+                           n_group=4, topk_group=2)
+    assert distance(np.asarray(ref._experts(x[0], flat, ids, w, 0)),
+                    np.asarray(parts[0][0])) < 1e-5
+
+
+# ---------------------------------------------------------------------------
+# what is refused, and what the config refuses
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("what", sorted(REFUSED))
+def test_what_assumes_the_dense_layer_is_refused(model, what):
+    kw, name = REFUSED[what]
+    with pytest.raises(UnsupportedForModel, match=name) as e:
+        make_engine(model, **(kw() if callable(kw) else kw))
+    assert "linear-attention (kda) layers" in str(e.value)
+
+
+@pytest.mark.parametrize("option", [
+    {"stream_publish_tokens": 8}, {"weight_quant": "int8"},
+    {"spec_decode_tokens": 2}])
+def test_what_moves_a_prefix_without_its_state_is_refused(model, option):
+    cfg, params = model
+    with pytest.raises(UnsupportedForModel, match="matrix state"):
+        if "weight_quant" in option:
+            init_params(cfg, jax.random.PRNGKey(0), **option)
+        else:
+            make_engine(model, **option)
+
+
+@pytest.mark.parametrize("change, message", [
+    ({"kda_heads": 0}, "kda layers need"),
+    ({"kv_lora_rank": 0, "q_lora_rank": 0}, "latent full_attention"),
+    ({"layer_types": ("kda",) * 4}, "latent full_attention"),
+    ({"n_group": 3}, "group-limited"),
+    ({"topk_group": 5}, "group-limited"),
+    ({"n_group": 4, "topk_group": 1, "n_experts_per_tok": 5}, "group-limited"),
+    ({"router_scoring": "softmax"}, "group-limited"),
+])
+def test_the_config_refuses_what_the_program_does_not_run(change, message):
+    with pytest.raises(ValueError, match=message):
+        get_config("ling-tiny", **change)
+
+
+def test_only_a_kda_config_takes_kda_sizes():
+    with pytest.raises(ValueError, match="kda_heads"):
+        ModelConfig.tiny().replace(kda_heads=4)
+    # latent attention without a bottleneck needs no kda layers
+    cfg = get_config("glm-4.7-flash-tiny", q_lora_rank=0)
+    params = init_params(cfg.replace(dtype=jnp.float32), jax.random.PRNGKey(0))
+    assert "wq" in params["layers"] and "q_norm" not in params["layers"]

@@ -1238,3 +1238,40 @@ class TestTailOnlyResume:
         assert state["attn"]["full_tokens_read"] > 0
         assert {"rm.window.release", "rm.state.restore", "rm.state.snapshot"} <= set(
             state["engine"]["spans"])
+
+
+@pytest.mark.parametrize("model", ["ling-tiny", "lfm2-tiny", "phi4flash-tiny"])
+def test_a_decode_launch_names_a_boundary_slot_only_where_a_page_completes(model):
+    """Every state-bearing model: a launch's second state slot is the row's
+    boundary slot when its token completes a page, slot 0 otherwise — and a
+    model with linear-attention layers copies for those rows alone
+    (``kda.boundary_copies``), the others write both every step."""
+    from radixmesh_tpu.models import init_params
+
+    cfg = get_config(model).replace(dtype=jnp.float32)
+    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(1)), num_slots=4096,
+                 page_size=16, max_batch=4, max_seq_len=512)
+    rng = np.random.default_rng(3)
+    reqs = [eng.add_request(rng.integers(0, 500, n).tolist(),
+                            SamplingParams(max_new_tokens=24))
+            for n in (10, 27)]
+    seen = 0
+    while eng.has_work():
+        eng.step()
+        eng._settle()
+        launch = eng._build_launch(0, preempt=False)
+        if launch is None:
+            continue
+        work, keep = np.asarray(launch.state_d)
+        for row, req in launch.active:
+            completes = (req.kv_len + 1) % 16 == 0
+            assert work[row] == req.state_slot
+            assert keep[row] == (req.state_boundary if completes else 0)
+            seen += completes
+        assert launch.boundary_copies == int(np.count_nonzero(keep))
+    assert seen >= 2 and all(len(r.generated) == 24 for r in reqs)
+    if cfg.has_kda:
+        assert eng.kda_stats["boundary_copies"] >= 2
+        assert eng.kda_stats["boundary_copies"] <= seen
+    else:
+        assert eng.kda_stats is None

@@ -606,6 +606,108 @@ class TestDeferredEmissionStream:
         assert not eng._emit_rows and not eng._emit_steps
 
 
+class TestStreamEmitter:
+    """The decode tokens of every SSE stream are written by ONE thread
+    (``_StreamEmitter``): a step's rows wake it once, not a handler each.
+    A stream whose socket does not take a write at once goes back to its
+    handler for good, with what is still to go."""
+
+    @pytest.mark.parametrize("tokens", [[0], [7, 151_643, 12], []])
+    def test_events_are_the_json_form(self, tokens):
+        from radixmesh_tpu.server.http_frontend import _token_events
+
+        want = "".join(f"data: {json.dumps({'token': t})}\n\n" for t in tokens)
+        assert _token_events(tokens) == want.encode()
+
+    def test_one_thread_writes_the_streams(self, monkeypatch):
+        import threading
+
+        from radixmesh_tpu.server import http_frontend
+
+        cfg = ModelConfig.tiny()
+        eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                     num_slots=1024, page_size=4, max_batch=4, name="http-one")
+        f = ServingFrontend(eng, port=0)
+        wrote: dict[str, int] = {}
+        events = http_frontend._token_events
+
+        def spy(tokens):
+            name = threading.current_thread().name
+            wrote[name] = wrote.get(name, 0) + len(tokens)
+            return events(tokens)
+
+        monkeypatch.setattr(http_frontend, "_token_events", spy)
+        outs: dict[int, list] = {}
+
+        def client(i):
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{f.port}/generate",
+                data=json.dumps({"input_ids": list(range(1 + i, 13 + i)),
+                                 "max_tokens": 40, "stream": True,
+                                 "stop_token_ids": []}).encode(),
+                method="POST")
+            got = []
+            with urllib.request.urlopen(req, timeout=60) as r:
+                for line in r:
+                    line = line.decode().strip()
+                    if line.startswith("data: "):
+                        got.append(json.loads(line[len("data: "):]))
+            outs[i] = got
+
+        try:
+            threads = [threading.Thread(target=client, args=(i,)) for i in range(4)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            f.close()
+        for got in outs.values():
+            *tokens, done = got
+            assert done["done"] and len(done["output_ids"]) == 40
+            assert [e["token"] for e in tokens] == done["output_ids"]
+        assert len(outs) == 4
+        # the handlers wrote at most what a finish left them
+        assert wrote["http-emit"] >= 4 * 40 - 4 * 2
+        assert sum(wrote.values()) == 4 * 40
+        assert not f.emitter._streams
+
+    def test_a_reader_that_falls_behind_gets_its_handler_back(self):
+        import socket
+
+        from radixmesh_tpu.engine.request import Request, SamplingParams
+        from radixmesh_tpu.server.http_frontend import (
+            _SseStream, _StreamEmitter, _token_events)
+
+        a, b = socket.socketpair()
+        a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+        req = Request(prompt=np.arange(4), sampling=SamplingParams())
+        em = _StreamEmitter()
+        stream = _SseStream(req, a)
+        em.add(stream)
+        assert req.on_progress is not None
+        req.output_tokens.extend(range(3))
+        stream.pump()
+        assert stream.emitter_owns and stream.sent == 3
+        req.output_tokens.extend(range(3, 200_000))  # more than the socket takes
+        stream.pump()
+        assert not stream.emitter_owns and stream.rest
+        assert req.on_progress is None  # its waiters are told again
+        req.output_tokens.append(5)
+        stream.pump()  # no longer the emitter's: nothing is written or counted
+        assert stream.sent == 200_000
+        em.remove(stream)
+        em.close()
+        b.settimeout(5)
+        got = bytearray()
+        want = _token_events(range(200_000))
+        while len(got) + len(stream.rest) < len(want):
+            got += b.recv(1 << 20)
+        assert bytes(got) + stream.rest == want
+        a.close()
+        b.close()
+
+
 class TestSLOFrontend:
     """The overload control plane surfaced over HTTP: tenant field,
     429 + Retry-After on rate limit, /stats slo section."""

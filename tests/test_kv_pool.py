@@ -434,3 +434,36 @@ def test_a_window_group_without_state_slots_counts_its_pages():
     with pytest.raises(ValueError, match="double free"):
         pool.unref_window(tail)
     assert pool.alloc_window(8) is None  # seven pages beside the scratch page
+
+
+@pytest.mark.parametrize("kind, state, conv, dtype, want", [
+    # a linear-attention matrix a head (float32) and its convolutions' inputs
+    ("kda", (3, 9, 64, 16), (3, 9, 5, 128), jnp.float32, 3 * (64 * 16 * 4 + 5 * 128 * 4)),
+    ("kda_bf16_conv", (6, 5, 4096, 128), (6, 5, 288, 128), jnp.bfloat16,
+     6 * (4096 * 128 * 4 + 288 * 128 * 2)),  # 13 025 280 B: published widths
+    ("conv_only", (4, 9, 2, 128), None, jnp.bfloat16, 4 * 2 * 128 * 2),
+])
+def test_state_slots_are_counted_by_the_bytes_of_both_arrays(kind, state, conv,
+                                                            dtype, want):
+    """One snapshot = one slot of the float32 states AND of the conv states
+    beside them, under one allocator; ``state_slot_bytes`` is what both
+    hold of it, whatever the snapshot's size."""
+    pool = PagedKVPool(
+        num_slots=64, num_layers=1, num_kv_heads=1, head_dim=128, page_size=16,
+        dtype=dtype, planes=1, state_shape=state,
+        state_dtype=jnp.float32 if conv else dtype, conv_state_shape=conv)
+    assert pool.state_slot_bytes == want
+    assert pool.state_allocator.num_slots == state[1]
+    first = pool.alloc_state()
+    assert first == 1 and pool.state_slots_used == 2  # slot 0 is nobody's
+    if conv is not None:
+        assert pool.state.dtype == jnp.float32 and pool.state_conv.dtype == dtype
+        if kind == "kda":
+            pool.write_state(np.asarray([first]), (
+                jnp.ones((state[0], 1, *state[2:])),
+                jnp.full((conv[0], 1, *conv[2:]), 2.0)))
+            assert float(pool.state[:, first].min()) == 1.0
+            assert float(pool.state_conv[:, first].min()) == 2.0
+            assert not np.asarray(pool.state[:, 2]).any()
+    pool.free_state(first)
+    assert pool.state_slots_used == 1

@@ -30,6 +30,8 @@ _SIZES = {
     "glm-4.7-flash-l8": (5.15, 5.18),  # 9.62 GiB in bf16
     "laguna-xs.2": (33.40, 33.48),  # the card's "33.4B": a head-wise gate
     "laguna-xs.2-l5": (3.865, 3.875),  # 7.21 GiB in bf16
+    "ling-3.0-flash": (124.0, 124.8),  # the card's "~125B" (W_alpha, W_g full)
+    "ling-3.0-flash-ep8": (2.866, 2.867),  # 5.34 GiB in bf16
 }
 
 # The model's public config.json (zai-org/GLM-4.7-Flash), as the catalog of
@@ -333,3 +335,58 @@ def test_laguna_presets_are_the_source_key_by_key():
     for c in (cfg, cut, tiny):
         assert c.window_layers and c.state_shape(4) is None
         assert "resumed from a tail" in unsupported_reason(c, kv_quant="int8")
+
+
+def test_ling3_presets_are_the_source_key_by_key():
+    """The 42-layer preset against the catalog's copy of the source's
+    config (the benchmark's configuration file holds every key of it, the
+    cut ones under ``published``), and the one-chip cut against the file."""
+    import json
+    import os
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            here, "benchmarks", "configs", "ling-3.0-flash-bf16-ep8.json")) as fh:
+        bench = json.load(fh)
+    src = bench | bench["published"]
+    cfg = get_config("ling-3.0-flash")
+    assert (cfg.hidden, cfg.n_layers, cfg.vocab_size, cfg.intermediate) == (
+        src["hidden_size"], src["num_hidden_layers"], src["vocab_size"],
+        src["intermediate_size"])
+    assert (cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim, cfg.kv_lora_rank, cfg.q_lora_rank) == (
+        src["num_attention_heads"], src["qk_nope_head_dim"],
+        src["qk_rope_head_dim"], src["v_head_dim"], src["kv_lora_rank"], 0)
+    assert src["q_lora_rank"] is None and cfg.head_dim == src["qk_head_dim"]
+    assert (cfg.n_experts, cfg.n_experts_per_tok, cfg.moe_intermediate,
+            cfg.n_group, cfg.topk_group, cfg.n_shared_experts,
+            cfg.first_k_dense) == (
+        src["num_experts"], src["num_experts_per_tok"],
+        src["moe_intermediate_size"], src["n_group"], src["topk_group"],
+        src["num_shared_experts"], src["first_k_dense_replace"])
+    assert (cfg.routed_scaling_factor, cfg.router_scoring, cfg.norm_topk_prob,
+            cfg.rms_eps, cfg.rope_theta, cfg.tie_embeddings) == (
+        src["routed_scaling_factor"], src["score_function"],
+        src["norm_topk_prob"], src["rms_norm_eps"], src["rope_theta"],
+        src["tie_word_embeddings"])
+    assert (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv,
+            cfg.kda_gate_lower_bound) == (
+        src["num_attention_heads"], src["head_dim"],
+        src["short_conv_kernel_size"], src["kda_lower_bound"])
+    assert cfg.attn_gate and src["gated_attention_proj_granularity_type"] == "head_wise"
+    # latent where (l + 1) % layer_group_size == 0
+    g = src["layer_group_size"]
+    assert cfg.layer_types == tuple(
+        "full_attention" if (l + 1) % g == 0 else "kda" for l in range(42))
+    cut = get_config("ling-3.0-flash-ep8")
+    assert list(cut.layer_types) == bench["layer_types"]
+    assert (cut.n_layers, cut.first_k_dense, cut.vocab_size, cut.held_experts,
+            cut.n_experts, cut.ep_size, cut.ep_rank, cut.max_seq_len) == (
+        bench["num_hidden_layers"], bench["first_k_dense_replace"],
+        bench["vocab_size"], bench["num_experts"],
+        bench["serving"]["router_experts"], bench["serving"]["ep_size"],
+        bench["serving"]["ep_rank"], bench["max_position_embeddings"])
+    tiny = get_config("ling-tiny")
+    assert tiny.layer_types == ("kda", "kda", "full_attention", "kda")
+    assert (tiny.kda_heads, tiny.kda_head_dim, tiny.n_group, tiny.topk_group,
+            tiny.q_lora_rank, tiny.attn_gate) == (4, 16, 4, 2, 0, True)

@@ -583,3 +583,46 @@ def test_a_tree_without_either_callback_tracks_no_resume_point():
     tree.insert(ids(8), ids(8))
     assert tree.match_prefix(ids(8)).states == []
     assert not tree._tracks_states
+
+
+def _state_tree():
+    freed = []
+    tree = RadixTree(page_size=4, on_free_state=freed.append)
+    return tree, freed
+
+
+@pytest.mark.parametrize("n, gone", [(1, [11]), (2, [11, 12]), (3, [11, 12, 21]),
+                                     (4, [11, 12, 21, 13])])
+def test_a_full_state_group_gives_up_snapshots_in_order(n, gone):
+    """Pressure on snapshots of megabytes (``evict_states(pinned=...)``):
+    first those a deeper snapshot of the same unbranched path supersedes (a
+    session's earlier turns: slots 11, 12 under 13), oldest first; then a
+    locked leaf's own (a live row's prompt: its release leaves a deeper
+    one); then the rest, least recently used; never a pinned one."""
+    tree, freed = _state_tree()
+    session, other, live = ids(24), ids(16, start=100), ids(12, start=200)
+    for key in (session, other, live):
+        tree.insert(key, np.arange(len(key), dtype=np.int32))
+    for pos, slot in ((8, 11), (16, 12), (24, 13)):
+        assert tree.attach_state(session[:pos], slot)
+    assert tree.attach_state(other, 31)  # what a waiting request resumes from
+    assert tree.attach_state(live, 21)
+    tree.inc_lock_ref(tree.match_prefix(live).last_node)
+    tree.match_prefix(session)  # touched last: the least recently used is `other`
+    assert tree.evict_states(n, pinned={31}) == n
+    assert freed == gone
+    assert tree.state_evictions == n
+    # the rows stay whatever went: a later match resumes shallower
+    assert tree.match_prefix(session).length == 24
+    deepest = tree.match_prefix(session).state_at_or_before(24)
+    assert deepest == ((24, 13) if n < 4 else (0, None))
+
+
+def test_plain_pressure_leaves_locked_snapshots_alone():
+    tree, freed = _state_tree()
+    key = ids(16)
+    tree.insert(key, np.arange(16, dtype=np.int32))
+    assert tree.attach_state(key[:8], 5) and tree.attach_state(key, 6)
+    tree.inc_lock_ref(tree.match_prefix(key).last_node)
+    assert tree.evict_states(4) == 0 and freed == []  # LFM2's rule: unlocked only
+    assert tree.evict_states(4, pinned={6}) == 1 and freed == [5]

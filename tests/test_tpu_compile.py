@@ -488,3 +488,46 @@ def test_a_product_by_head_still_slices_and_copies_its_weight(v5e):
     found = _standalone_weight_ops(compiled, D, [stack["wq"]])
     assert sum("copy" in op for op in found) == 1, found
     assert sum("dynamic-slice_fusion" in op for op in found) == 2, found
+
+
+@pytest.mark.parametrize("rows", [16, 64])
+def test_the_kda_kernels_lower_at_ling3s_widths_and_ask_for_no_vmem(v5e, rows):
+    """``radixmesh_kda_chunk`` / ``_decode`` / ``_boundary`` at 32 heads of
+    128 x 128 over the cell's slot array: Mosaic takes them, none asks for
+    VMEM of its own (a kernel that did hung inside a 64-row program, PR
+    43), and a launch of more than 16 rows is calls of 16 (one call over
+    32 rows beside the latent chunk kernel never returned on the chip, PR
+    48)."""
+    from radixmesh_tpu.ops import kda
+
+    sd = _sd(v5e)
+    H, d, T = 32, 128, 64
+    f32, i32 = jnp.float32, jnp.int32
+    pool = sd((6, 321, H * d, d), f32)
+    seq = sd((rows, T, H, d), f32)
+
+    def chunk(q, k, v, g, beta, pool, rows_in, fresh, snap, keep):
+        return kda.kda_chunk(q, k, v, g, beta, pool, 2, rows_in, fresh, snap, keep)
+
+    text = jax.jit(chunk, donate_argnums=(5,)).lower(
+        seq, seq, seq, seq, sd((rows, T, H), f32), pool, sd((rows,), i32),
+        sd((rows,), i32), sd((rows, 3), i32), sd((rows, 3), i32)).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "radixmesh_kda_chunk" in ln]
+    assert len(calls) == -(-rows // kda._ROWS_A_CALL)
+    assert all('"scoped_memory_configs":[]' in c for c in calls)
+    assert all(f"f32[{kda._ROWS_A_CALL},{T},{H},{d}]" in c for c in calls)
+
+    row = sd((rows, H, d), f32)
+
+    def step(q, k, v, g, beta, pool, slots, keep):
+        o, pool = kda.kda_decode(q, k, v, g, beta, pool, 2, slots)
+        return o, kda.copy_slots(pool, slots, keep)
+
+    text = jax.jit(step, donate_argnums=(5,)).lower(
+        row, row, row, row, sd((rows, H), f32), pool, sd((rows,), i32),
+        sd((rows,), i32)).compile().as_text()
+    for name in ("radixmesh_kda_decode", "radixmesh_kda_boundary"):
+        call, = [ln for ln in text.splitlines()
+                 if "custom-call(" in ln and name in ln]
+        assert '"scoped_memory_configs":[]' in call
