@@ -85,8 +85,16 @@ CHIP = {
     "geometry_latent": dict(hq=20, width=640, v_width=512, page=16, batch=8,
                             max_pages=128),
     # Ling-3.0-flash's linear-attention state: 32 heads of 128 x 128 float32,
-    # 2 MiB a row and layer, a chunk of 64 from the rows' slots.
-    "geometry_kda": dict(heads=32, dim=128, batch=4, chunk=64),
+    # 2 MiB a row and layer, chunks from the rows' slots as the cells launch
+    # them: a turn's 64, a cold prompt's 512 (sixteen blocks, half of them
+    # at the gate's bound), tails that allow one part a block (48, 16), and
+    # more rows than a call takes.
+    "geometry_kda": [
+        dict(heads=32, dim=128, batch=4, chunk=64),
+        dict(heads=32, dim=128, batch=2, chunk=512),
+        dict(heads=32, dim=128, batch=17, chunk=48, slots=72),
+        dict(heads=32, dim=128, batch=4, chunk=16),
+    ],
     # The chunk kernel over a prefix of several 512-token blocks, as a turn
     # of a session launches it: a chat wave (256 new on up to 3.8 k), an
     # agent turn (LFM2's two heads of 64 to a row: 4 wide heads, G = 8,
@@ -136,7 +144,8 @@ REHEARSAL = {
                             chunk=8, max_pages=8, window=12),
     "geometry_latent": dict(hq=4, width=128, v_width=32, page=4, batch=8,
                             max_pages=16, block_tokens=16),
-    "geometry_kda": dict(heads=4, dim=16, batch=3, chunk=16),
+    "geometry_kda": [dict(heads=4, dim=16, batch=3, chunk=16),
+                     dict(heads=4, dim=16, batch=2, chunk=64)],
     "geometry_prefix": [
         dict(hq=7, hkv=1, head_dim=32, page=4, chunk=16, max_pages=16),
         dict(hq=8, hkv=2, head_dim=32, page=4, chunk=16, max_pages=16,
@@ -447,8 +456,9 @@ def phase_kernels(args) -> dict:
         for g in m["geometry_prefix"]
         for r in check_chunk_prefix(interpret=m["interpret"], **g)
     ] + [
-        {**r, "geometry": m["geometry_kda"]}
-        for r in check_kda(interpret=m["interpret"], **m["geometry_kda"])
+        {**r, "geometry": g}
+        for g in m["geometry_kda"]
+        for r in check_kda(interpret=m["interpret"], **g)
     ]
     return {"results": results, "seconds": round(time.monotonic() - t0, 2)}
 

@@ -21,14 +21,31 @@ Forms of the one recurrence:
   the gate at its bound of -5 a token, 64 tokens are ``exp(320)``). The
   form whose operations ``benchmarks/harness/opsbytes_kda.py`` counts.
 - ``radixmesh_kda_chunk`` (:func:`kda_chunk_kernel`) — the prefill kernel:
-  a program DMAs a row's whole state (all heads, 2 MiB at 32 x 128 x 128)
-  from its slot into VMEM and walks the chunk's positions, leaving the
-  state after ``snap_at[b, k]`` positions (0: the state it started from) in
-  slot ``state_out[b, k]`` by DMA, in place — an index a row, so a padded
-  row's state is exact at its true end and a snapshot inside a chunk is an
-  input, never a compiled shape (``ops/ssm.py``'s contract). It is the recurrence on the vector unit, not the blocked form
-  on the MXU: a position costs a head ~110 vector operations on the
-  head's 16 vregs of state and no HBM traffic beyond its 64 KiB of inputs.
+  the chunked form on the MXU, one algorithm whatever the chunk. A grid
+  step takes 8 heads of a row through a block of 32 positions (what the
+  chunk allows otherwise: ``_chunk_geometry``); the heads' states stay in
+  VMEM from block to block, read from the row's slot by DMA (64 KiB a
+  head, contiguous) while the block's own products are computed, and the
+  state after ``snap_at[b, k]`` positions (0: the state it started from) is
+  left in slot ``state_out[b, k]`` by DMA, in place — an index a row, so a
+  padded row's state is exact at its true end and a snapshot inside a block
+  is an input, never a compiled shape (``ops/ssm.py``'s contract): there
+  the block's own sum is taken again under a mask, only where a row asks.
+  A block is two parts of 16. Inside a part the decay between two positions
+  is the product of one's to the part's middle and the other's from it
+  (exponents of at most 8 positions: exp(40) at the gate's bound), so all
+  parts of 128 rows are the diagonal of one product; ``I + N`` of a part is
+  inverted by forward substitution in registers (15 steps, every part of
+  the step at once) and the parts are merged two by two across a cut, where
+  a decay is the product of a row's from the cut and a column's up to it
+  (both exponents <= 0). Every cumulative log-decay is a sum of terms of one
+  sign inside a part or a half — never a difference of two long sums, never
+  ``1 / exp(G)`` over more than 8 positions. Every product takes float32
+  operands at ``Precision.HIGHEST`` (six MXU passes), so the kernel's
+  ceiling against the bf16 peak is a sixth of what its products fill.
+  Nothing is unrolled over heads, rows or positions but the 15 steps of
+  the substitution: the traced body is the same for 8 heads and 32, for 16
+  positions and 1 024 (``tests/test_bailing_hybrid.py`` holds it).
 - ``radixmesh_kda_decode`` (:func:`kda_decode_kernel`) — one position a
   row: a program DMAs the row's state slot in, applies the same update
   and writes it back IN PLACE (the slot array is aliased), so a step
@@ -38,11 +55,15 @@ Forms of the one recurrence:
   a decode launch: for the rows that completed a page, slot to slot over
   every layer by DMA, HBM to HBM; rows that did not cost nothing.
 
-The kernels take the per-key-channel vectors of a position (``a``, ``k``,
-``a * b * k``, ``q``) as COLUMNS: ``cols`` [B, T, dk, 4 * heads], laid by
-XLA (a transpose of what the projections give, 64 KiB a position), because
-a vector along the sublanes times the state needs no relayout in the
-kernel, and a reduction over the key channels is then a sum of vregs.
+``radixmesh_kda_decode`` takes the per-key-channel vectors of a position
+(``a``, ``k``, ``a * b * k``, ``q``) as COLUMNS: ``cols`` [B, dk, 4 * heads],
+laid by XLA (a transpose of what the projections give), because a vector
+along the sublanes times the state needs no relayout in the kernel, and a
+reduction over the key channels is then a sum of vregs. The chunk kernel
+takes ``q``, ``k``, ``b k``, ``g`` and ``b v`` as the projections give them
+([B, T, heads, d]: a block of 32 positions x 8 heads x d is a block of the
+array, and a head's [32, d] tile a strided read of it): no relayout by
+XLA, two products more for its fusions.
 
 :func:`short_conv` is the depth-wise causal convolution in front (width
 4, SiLU), from the last ``taps - 1`` inputs a row carries.
@@ -55,6 +76,7 @@ from __future__ import annotations
 # wait; no timeout concept exists on-device.
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -186,58 +208,231 @@ def _update_heads(cols, bv_row, s_load, s_store, o_store, n_heads: int, dk: int)
         o_store(h, jnp.sum(S * qq, axis=0, keepdims=True))
 
 
-def _chunk_kernel(snap_ref, layer_ref, in_ref, fresh_ref, keep_ref, cols_ref,
-                  bv_ref, pool_in, o_ref, pool_ref, s_scr, sem, *, tblk: int,
-                  n_snaps: int, n_heads: int, dk: int, dv: int):
+# Positions of a diagonal part, positions and heads of a grid step at most.
+# On a v5e at 32 heads of 128 (PERF.md, PR 50): 0.69 us a token and layer at
+# 32 x 8, 0.74 at 64 x 8, 0.70 at 16 x 16, 0.94 at 64 x 2 — a step is a
+# chain of products each waiting for the one before, and heads are what
+# runs beside it.
+_SUB, _BLOCK, _HEADS_A_STEP = 16, 32, 8
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _chunk_geometry(T: int, H: int) -> tuple[int, int, int]:
+    """``(s, n, hb)`` of a chunk of ``T`` positions and ``H`` heads: a grid
+    step takes ``hb`` heads through a block of ``n`` parts of ``s``
+    positions — 2 x 16 where ``T`` allows, what ``T`` allows otherwise (16
+    or 48: one part of 16 a block; 8: one part of 8)."""
+    s = min(T, _SUB)
+    if T % s or s & (s - 1):
+        raise ValueError(f"positions {T} must be a multiple of {_SUB}, or "
+                         f"a power of two below it")
+    return s, math.gcd(T // s, _BLOCK // _SUB), math.gcd(H, _HEADS_A_STEP)
+
+
+def _mm(spec, a, b):
+    """A product of float32 operands at full precision (six passes of the
+    MXU): a bf16 pass is a lower precision than the state's."""
+    return jnp.einsum(spec, a, b, precision=_HI, preferred_element_type=_F32)
+
+
+def _chunk_kernel(snap_ref, layer_ref, in_ref, fresh_ref, keep_ref, q_ref,
+                  k_ref, kb_ref, g_ref, bv_ref, pool_in, o_ref, pool_ref,
+                  s_scr, snap_scr, sem, *, s: int, n: int, hb: int,
+                  n_snaps: int, dk: int, dv: int):
+    """A block of ``C = n * s`` positions of ``hb`` heads of one row (module
+    docstring): grid ``(rows, heads / hb, T / C)``, positions innermost, the
+    heads' state in ``s_scr`` from block to block."""
     del pool_in  # aliased to pool_ref
-    row, t_blk = pl.program_id(0), pl.program_id(1)
-    layer = layer_ref[0]
+    row, h_blk, t_blk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    C, t0 = n * s, t_blk * (n * s)
+    slots = pool_ref.at[layer_ref[0]]
 
-    def keep_where(done):
-        """The state as it stands goes to the slots that asked for it after
-        ``done`` positions (slot 0: nobody did)."""
+    def mine(slot):
+        """The step's ``hb`` heads of a slot: ``[hb * dk, dv]``, contiguous."""
+        return slots.at[slot, pl.ds(h_blk * (hb * dk), hb * dk)]
+
+    def keep(j, src):
+        """``src`` [hb * dk, dv] to the ``j``-th slot this row keeps to."""
+        dma = pltpu.make_async_copy(src, mine(keep_ref[row * n_snaps + j]), sem)
+        dma.start()
+        dma.wait()
+
+    def wanted(j, at):
+        """Does the row keep its state after ``at`` positions in its
+        ``j``-th slot (slot 0: nobody does)?"""
+        return (keep_ref[row * n_snaps + j] != 0) & at(
+            snap_ref[row * n_snaps + j])
+
+    def keep_where(at, src):
         for j in range(n_snaps):
-            slot = keep_ref[row * n_snaps + j]
+            pl.when(wanted(j, at))(functools.partial(keep, j, src))
 
-            @pl.when((snap_ref[row * n_snaps + j] == done) & (slot != 0))
-            def _keep(slot=slot):
-                dma = pltpu.make_async_copy(s_scr, pool_ref.at[layer, slot], sem)
-                dma.start()
-                dma.wait()
+    # the state the row starts from is on its way while everything that
+    # does not need it is computed
+    resumes = (t_blk == 0) & (fresh_ref[row] == 0)
+    load = pltpu.make_async_copy(mine(in_ref[row]), s_scr, sem)
+    pl.when(resumes)(load.start)
 
-    @pl.when(t_blk == 0)
-    def _start():
-        @pl.when(fresh_ref[row] == 0)
-        def _resume():
-            dma = pltpu.make_async_copy(
-                pool_ref.at[layer, in_ref[row]], s_scr, sem)
-            dma.start()
-            dma.wait()
+    @pl.when((t_blk == 0) & (fresh_ref[row] != 0))
+    def _zero():
+        s_scr[...] = jnp.zeros_like(s_scr)
 
-        @pl.when(fresh_ref[row] != 0)
-        def _zero():
-            s_scr[...] = jnp.zeros_like(s_scr)
+    def heads(ref):
+        """A block ``[C, hb, d]`` — as the projections lay it: a position's
+        heads on the sublanes — as ``[hb, C, d]``: a strided read a head."""
+        return jnp.stack([ref[:, i, :] for i in range(hb)])
 
-        keep_where(0)
+    def of_row(x, size, at):
+        """Row ``at`` of every ``size`` rows of ``x`` [hb, C, d], to all of
+        them."""
+        x = x.reshape(hb, C // size, size, dk)[:, :, at:at + 1]
+        return jnp.broadcast_to(x, (hb, C // size, size, dk)).reshape(hb, C, dk)
 
-    def step(i, _):
-        def s_load(h):
-            return s_scr[h * dk:(h + 1) * dk, :]
+    def column(x):
+        """Rows ``[hb, 1, dk]`` as columns ``[hb, dk, 1]`` (a masked sum
+        over the lanes: no transpose)."""
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+        return jnp.sum(jnp.where(eye, x, 0.0), axis=2, keepdims=True)
 
-        def s_store(h, S):
-            s_scr[h * dk:(h + 1) * dk, :] = S
+    q, k, kb, g = (heads(ref) for ref in (q_ref, k_ref, kb_ref, g_ref))
+    pos = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
 
-        def o_store(h, o):
-            o_ref[i, h:h + 1, :] = o
+    def widen(cum, rest, size):
+        """``cum`` (the log-decay from a group's first position to each of
+        its positions, inclusive) and ``rest`` (after a position to its
+        group's end) of groups of ``size`` as those of ``2 * size``: a
+        half's whole sum — its last row's — to the other half's rows. Sums
+        of one sign throughout: no decay below is ``exp`` of a difference
+        of two long sums (after 32 positions at the gate's bound a sum is
+        -160 and its float32 step 1.5e-5)."""
+        right = pos & size != 0
+        return (
+            jnp.where(right, cum + of_row(cum, 2 * size, size - 1), cum),
+            jnp.where(right, rest, rest + of_row(cum, 2 * size, 2 * size - 1)))
 
-        bv = bv_ref[i]  # [H, dv]
-        _update_heads(
-            cols_ref[i], lambda h: bv[h:h + 1], s_load, s_store, o_store,
-            n_heads, dk)
-        keep_where(t_blk * tblk + i + 1)
-        return 0
+    half = max(s // 2, 1)
+    at = pos & (half - 1)
+    cum, shift = g, 1
+    rest = jnp.where(at < half - 1, pltpu.roll(g, C - 1, 1), 0.0)
+    while shift < half:
+        cum = cum + jnp.where(at >= shift, pltpu.roll(cum, shift, 1), 0.0)
+        rest = rest + jnp.where(
+            at < half - shift, pltpu.roll(rest, C - shift, 1), 0.0)
+        shift *= 2
 
-    jax.lax.fori_loop(0, tblk, step, 0)
+    # -- the diagonal parts, ``s`` positions each: the decay between two
+    # positions of a part is the product of one's to the part's MIDDLE and
+    # the other's from it — one of the two a positive exponent, but of at
+    # most 8 positions (exp(40) at the gate's bound) — so the parts of 128
+    # rows (four heads of a block of 32) are the blocks on the diagonal of
+    # ONE product; what lies off it is not used
+    to_mid = jnp.where(pos & half != 0, cum, -rest) if s > 1 else cum
+    f_row, f_col = jnp.exp(to_mid), jnp.exp(-to_mid)
+    Wd = min(hb * C, 128)
+    P, Gw = hb * C // Wd, Wd // s
+    both = _mm(
+        "ptd,pcd->ptc",
+        jnp.concatenate([(kb * f_row).reshape(P, Wd, dk),
+                         (q * f_row).reshape(P, Wd, dk)], axis=1),
+        (k * f_col).reshape(P, Wd, dk)).reshape(P, 2 * Gw, s, Wd)
+    # packed: row t of every part side by side, [P, s, (part, column)] (the
+    # sizes are powers of two: a group is what two indices share above it)
+    iota4 = functools.partial(
+        jax.lax.broadcasted_iota, jnp.int32, (P, 2 * Gw, s, Wd))
+    packed = jnp.where(
+        iota4(3) ^ (iota4(1) * s & (Wd - 1)) < s, both, 0.0,
+    ).reshape(P, 2, Gw, s, Wd).sum(axis=2)
+    t_id = jax.lax.broadcasted_iota(jnp.int32, (s, Wd), 0)
+    c_id = jax.lax.broadcasted_iota(jnp.int32, (s, Wd), 1) & (s - 1)
+    n_p = jnp.where(t_id > c_id, packed[:, 0], 0.0)
+    # column c of every part's N = Diag(b) tril(A, -1), to all the lanes of
+    # its part (a product with blocks of ones: exact), then the forward
+    # substitution that inverts I + N by columns — row t loses N[t, c]
+    # times row c — in registers, two vregs a 128 rows
+    spread = jax.lax.broadcasted_iota(jnp.int32, (s, s, Wd), 0)
+    same_part = (jax.lax.broadcasted_iota(jnp.int32, (Wd, Wd), 0)
+                 ^ jax.lax.broadcasted_iota(jnp.int32, (Wd, Wd), 1)) < s
+    cols = _mm(
+        "rw,wv->rv",
+        jnp.where(c_id == spread, n_p[:, None], 0.0).reshape(P * s * s, Wd),
+        jnp.where(same_part, 1.0, 0.0)).reshape(P, s, s, Wd)
+    x = jnp.broadcast_to(jnp.where(t_id == c_id, 1.0, 0.0), (P, s, Wd))
+    for c in range(s - 1):
+        x = x - cols[:, c] * x[:, c:c + 1]
+
+    def unpacked(x):
+        """``[P, s, (part, column)]`` as the parts on the diagonal of
+        ``[hb, C, C]``."""
+        x = jnp.where(
+            same_part,
+            jnp.broadcast_to(x[:, None], (P, Gw, s, Wd)).reshape(P, Wd, Wd),
+            0.0)
+        if Wd == C:
+            return x
+        return jnp.stack([x[:, i * C:(i + 1) * C, i * C:(i + 1) * C]
+                          for i in range(Wd // C)], axis=1).reshape(hb, C, C)
+
+    inv = unpacked(x)
+    qm = unpacked(jnp.where(t_id >= c_id, packed[:, 1], 0.0))
+    if s > 1:
+        cum, rest = widen(cum, rest, half)
+
+    # -- the parts merged two by two: across a cut, a decay is the product
+    # of the row's from the cut and the column's up to it (both <= 0 as
+    # exponents), so the off-diagonal parts are matrix products, and
+    # ``[[A, 0], [N, D]]^-1 = X - X [[0, 0], [N, 0]] X`` with ``X`` the
+    # halves' inverses on the diagonal
+    r_id = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    c_id = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    size = s
+    while size < C:
+        f = jnp.exp(jnp.where(pos & size != 0, cum, rest))
+        m = _mm("htd,hsd->hts",
+                jnp.concatenate([kb * f, q * f], axis=1), k * f)
+        # a row of a right half and a column of the left half beside it
+        pair = ((r_id ^ c_id) & -size == size) & (r_id & size != 0)
+        qm = qm + jnp.where(pair, m[:, C:], 0.0)
+        inv = inv - _mm("hts,hsu->htu", inv, _mm(
+            "hts,hsu->htu", jnp.where(pair, m[:, :C], 0.0), inv))
+        cum, rest = widen(cum, rest, size)
+        size *= 2
+
+    # -- the block against the state it started from
+    @pl.when(resumes)
+    def _arrived():
+        load.wait()
+
+    keep_where(lambda p: (t_blk == 0) & (p == 0), s_scr)  # as it started
+    S0 = s_scr[...].reshape(hb, dk, dv)
+    f_in = jnp.exp(cum)
+    met = _mm("htk,hkv->htv",
+              jnp.concatenate([kb * f_in, q * f_in], axis=1), S0)
+    u = _mm("hts,hsv->htv", inv, heads(bv_ref) - met[:, :C])
+    o = met[:, C:] + _mm("hts,hsv->htv", qm, u)
+    for i in range(hb):
+        o_ref[:, i, :] = o[i]
+
+    def state_after(decay_to, decay_all):
+        """``Diag(decay_all) S_0 + sum_s (decay_to_s k_s) u_s^T``."""
+        return (column(decay_all) * S0 + _mm(
+            "hsk,hsv->hkv", k * decay_to, u)).reshape(hb * dk, dv)
+
+    # a state asked for INSIDE the block: the same sum, masked at the
+    # row's position (its decays from a masked sum of g: one sign again)
+    for j in range(n_snaps):
+        @pl.when(wanted(j, lambda p: (p > t0) & (p < t0 + C)))
+        def _inside(j=j):
+            p = snap_ref[row * n_snaps + j] - t0
+            between = jnp.where((c_id > r_id) & (c_id < p), 1.0, 0.0)
+            to_p = _mm("ts,hsd->htd", between, g)
+            upto = jnp.sum(jnp.where(pos < p, g, 0.0), axis=1, keepdims=True)
+            snap_scr[...] = state_after(
+                jnp.where(pos < p, jnp.exp(to_p), 0.0), jnp.exp(upto))
+            keep(j, snap_scr)
+
+    s_scr[...] = state_after(jnp.exp(rest), jnp.exp(cum[:, C - 1:]))
+    keep_where(lambda p: p == t0 + C, s_scr)
 
 
 # Rows a call of ``radixmesh_kda_chunk``. A launch of 32 rows x 64 positions
@@ -251,42 +446,45 @@ def _chunk_kernel(snap_ref, layer_ref, in_ref, fresh_ref, keep_ref, cols_ref,
 _ROWS_A_CALL = 16
 
 
-def _chunk_call(cols, bv, pool, layer, state_in, fresh, snap_at, state_out,
+def _chunk_call(seqs, pool, layer, state_in, fresh, snap_at, state_out,
                 interpret: bool):
-    """``radixmesh_kda_chunk`` over the rows given: ``(o, pool)``."""
-    B, T, dk, H = cols.shape
-    H //= 4
-    dv = bv.shape[-1]
+    """``radixmesh_kda_chunk`` over the rows given (``seqs``: q, k, b k, g
+    and b v, each [B, T, H, d]): ``(o [B, T, H, dv], pool)``."""
+    B, T, H, dk = seqs[0].shape
+    dv = seqs[4].shape[3]
     K = snap_at.shape[1]
-    tb = min(T, 16)
-    seq = pl.BlockSpec((None, tb, H, dv), lambda r, t, *_: (r, t, 0, 0))
+    s, n, hb = _chunk_geometry(T, H)
+    C = s * n
+
+    def seq(d):
+        return pl.BlockSpec((None, C, hb, d), lambda r, h, t, *_: (r, t, h, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(B, T // tb),
-        in_specs=[
-            pl.BlockSpec((None, tb, dk, 4 * H), lambda r, t, *_: (r, t, 0, 0)),
-            seq,
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[seq, pl.BlockSpec(memory_space=pl.ANY)],
-        scratch_shapes=[pltpu.VMEM((H * dk, dv), _F32),
+        grid=(B, H // hb, T // C),
+        in_specs=[seq(dk), seq(dk), seq(dk), seq(dk), seq(dv),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[seq(dv), pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[pltpu.VMEM((hb * dk, dv), _F32),
+                        pltpu.VMEM((hb * dk, dv), _F32),
                         pltpu.SemaphoreType.DMA(())],
     )
     return pl.pallas_call(
-        functools.partial(_chunk_kernel, tblk=tb, n_snaps=K, n_heads=H,
-                          dk=dk, dv=dv),
+        functools.partial(_chunk_kernel, s=s, n=n, hb=hb, n_snaps=K, dk=dk,
+                          dv=dv),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, T, H, dv), _F32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        # operands: snap_at, layer, state_in, fresh, state_out, cols, bv, pool
-        input_output_aliases={7: 1},
+        # operands: snap_at, layer, state_in, fresh, state_out, q, k, b k,
+        # g, b v, pool
+        input_output_aliases={10: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             has_side_effects=True),
         interpret=interpret,
         name="radixmesh_kda_chunk",
     )(snap_at.reshape(-1), layer, state_in, fresh, state_out.reshape(-1),
-      cols, bv, pool)
+      *seqs, pool)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(5,))
@@ -300,22 +498,22 @@ def kda_chunk_kernel(q, k, v, g, beta, pool, layer, state_in, fresh, snap_at,
     the states itself, slot to VMEM and VMEM to slot by DMA, in place (the
     slot array is donated and aliased): an XLA gather or scatter of rows of
     a 3.6 GiB array is lowered through slices of the WHOLE array. Returns
-    ``(o [B, T, H, dv] float32, pool)``. Grid ``(rows, position blocks)``,
-    positions innermost: the state stays in VMEM scratch from block to
-    block; ``_ROWS_A_CALL`` rows a call. A slot one row keeps to must not
-    be the one another row of the launch starts from."""
-    B, T = q.shape[:2]
-    if T % min(T, 16):
-        raise ValueError(f"positions {T} must be a multiple of 16")
-    cols = _columns(q, k, g, beta)
-    bv = beta.astype(_F32)[..., None] * v.astype(_F32)
+    ``(o [B, T, H, dv] float32, pool)``. ``_ROWS_A_CALL`` rows a call. A
+    slot one row keeps to must not be the one another row of the launch
+    starts from."""
+    B = q.shape[0]
+    b = beta.astype(_F32)[..., None]
+    k = k.astype(_F32)
+    # as the projections give them: no relayout, two products folded into
+    # what makes k and v
+    seqs = tuple(t.astype(_F32) for t in (q, k, b * k, g, b * v.astype(_F32)))
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     rows = (state_in.astype(jnp.int32), fresh.astype(jnp.int32),
             snap_at.astype(jnp.int32), state_out.astype(jnp.int32))
     outs = []
     for r0 in range(0, B, _ROWS_A_CALL):
         sl = slice(r0, r0 + _ROWS_A_CALL)
-        o, pool = _chunk_call(cols[sl], bv[sl], pool, layer,
+        o, pool = _chunk_call(tuple(t[sl] for t in seqs), pool, layer,
                               *(t[sl] for t in rows), interpret)
         outs.append(o)
     return (outs[0] if len(outs) == 1 else jnp.concatenate(outs)), pool

@@ -70,13 +70,17 @@ TOLERANCE = 2e-2
 
 # The linear-attention state kernels (``check_kda``, ``ops/kda.py``): a
 # chunk from the rows' slots with snapshots inside it, one position a row
-# through the slots in place, the boundary copies. Float32 on the vector
-# unit against the same recurrence in ``jax.numpy``: they differ by the
-# order of two 128-term sums — 2.4e-7 on the states and 7e-8 on the outputs
-# on the chip at 32 heads of 128 (PERF.md, PR 48) — and a state held in
-# bf16 reads 4e-3 after ONE step. KDA_TOLERANCE lies between the two: this
-# check is where the state's precision can be told on the chip (the served
-# logits cannot: bf16 activations cost three times what a bf16 state does,
+# through the slots in place, the boundary copies. Float32 against the same
+# recurrence in ``jax.numpy``. The decode kernel differs from it by the
+# order of two 128-term sums — 2.4e-7 on the states and 4e-8 on the outputs
+# on the chip at 32 heads of 128 (PERF.md, PR 48); the chunk kernel is the
+# blocked form, whose decays are ``exp`` of sums of up to 32 log-decays
+# where the recurrence multiplies 32 factors — 2.5e-7 to 4.6e-7 on the
+# outputs and 1.8e-6 to 1.8e-5 on the states it leaves, at chunks of 8 to
+# 512 (PERF.md, PR 50) — and a state held in bf16 reads 4e-3 after ONE
+# step. KDA_TOLERANCE lies between the two: this check is where the
+# state's precision can be told on the chip (the served logits cannot: bf16
+# activations cost three times what a bf16 state does,
 # ``benchmarks/tools/ref_compare_kda.py``).
 KDA_CASES = ("kda_chunk", "kda_decode", "kda_boundary")
 KDA_TOLERANCE = 1e-4

@@ -369,23 +369,40 @@ def test_the_chunked_form_equals_the_recurrence(gate, block):
     assert distance(np.asarray(Sb), np.asarray(snaps[:, 0])) < TOL
 
 
-@pytest.mark.parametrize("T", [1, 8, 48])
+# the kernel is the blocked form: other orders of the same float32 sums than
+# the walk's (1e-6 at these sizes, 2.4e-6 on the chip at 32 heads of 128)
+KERNEL_TOL = 1e-5
+
+
+def _slots_of(S0, spare):
+    """A slot array of two layers: ``S0``'s rows in slots 3.. of layer 1,
+    ``spare`` slots of ones behind them."""
+    B, H, d, _ = S0.shape
+    ones = lambda n: jnp.ones((n, H * d, d))  # noqa: E731
+    return jnp.stack([ones(3 + B + spare), jnp.concatenate(
+        [ones(3), S0.reshape(B, H * d, d), ones(spare)])])
+
+
+@pytest.mark.parametrize("T", [1, 8, 16, 48, 64, 128, 512])
 def test_the_chunk_kernel_equals_the_recurrence_with_snapshots_inside(T):
+    """Blocks of 64 where ``T`` allows (64, 128, 512), parts of 16 (16,
+    48) or one shorter part (1, 8) otherwise; snapshots at the end, at 0,
+    and INSIDE a part (no multiple of 16)."""
     q, k, v, g, beta, S0 = _kda_inputs(2, T, 4, 16, 2)
-    snap = jnp.asarray([[T, T // 2, 0], [max(T - 3, 0), 1, T]], jnp.int32)
+    inside = T // 2 + 5 if T >= 16 else T // 2
+    snap = jnp.asarray([[T, inside, 0], [max(T - 3, 0), 1, T]], jnp.int32)
     o, snaps = kda.kda_recurrent_ref(q, k, v, g, beta, S0, snap)
-    pool = jnp.stack([jnp.ones((5, 64, 16)), jnp.concatenate(
-        [jnp.ones((3, 64, 16)), S0.reshape(2, 64, 16)])])  # slots 3, 4 of layer 1
+    pool = _slots_of(S0, 5)  # the rows start from slots 3, 4 of layer 1
     # row 0 keeps to slots 5, 6, 7 and row 1 to 8, 0 (not kept), 9
     keep = jnp.asarray([[5, 6, 7], [8, 0, 9]], jnp.int32)
-    pool = jnp.concatenate([pool, jnp.ones((2, 5, 64, 16))], axis=1)
     ok, pk = kda.kda_chunk(q, k, v, g, beta, pool + 0, 1, jnp.asarray([3, 4]),
                            jnp.zeros((2,)), snap, keep, use_kernel=True,
                            interpret=True)
-    assert distance(np.asarray(ok), np.asarray(o)) < 1e-6
+    assert ok.dtype == pk.dtype == jnp.float32
+    assert distance(np.asarray(ok), np.asarray(o)) < KERNEL_TOL
     for b, j in ((0, 0), (0, 1), (0, 2), (1, 0), (1, 2)):
         assert distance(np.asarray(pk[1, keep[b, j]]),
-                        np.asarray(snaps[b, j].reshape(64, 16))) < 1e-6
+                        np.asarray(snaps[b, j].reshape(64, 16))) < KERNEL_TOL
     assert np.array_equal(pk[1, 7], S0[0].reshape(64, 16))  # 0: as it started
     # nothing else of the array moved: not slot 0, not the other layer
     assert np.array_equal(pk[0], pool[0])
@@ -397,30 +414,161 @@ def test_the_chunk_kernel_equals_the_recurrence_with_snapshots_inside(T):
         of, pf = kda.kda_chunk(q, k, v, g, beta, pool + 0, 1, jnp.asarray([3, 4]),
                                jnp.ones((2,)), snap, keep, use_kernel=kernel,
                                interpret=True)
-        assert distance(np.asarray(of), np.asarray(o0)) < 1e-6
+        assert distance(np.asarray(of), np.asarray(o0)) < KERNEL_TOL
         assert not np.asarray(pf[1, 7]).any()
 
 
-def test_a_launch_of_more_rows_than_a_call_takes_is_several_calls_on_one_array():
+@pytest.mark.parametrize("bound", ["a_block", "the_chunk", "a_run_then_open"])
+def test_the_chunk_kernel_holds_a_gate_at_its_bound(bound):
+    """g = -5 over a WHOLE block of 64 (the cumulative log-decay reaches
+    -320: ``exp`` of its negative is not a float32), over the whole chunk,
+    and over a run followed by gates near 0 (the decays after the run are
+    differences of sums near -160: taken part by part they stay exact).
+    One row resumes, the other is fresh; snapshots inside the run."""
+    T = 128
+    q, k, v, g, beta, S0 = _kda_inputs(2, T, 4, 16, 7)
+    g = {"a_block": g.at[:, 64:].set(-5.0),
+         "the_chunk": jnp.full_like(g, -5.0),
+         "a_run_then_open": g.at[:, :32].set(-5.0).at[:, 32:].multiply(1e-3),
+         }[bound]
+    fresh = jnp.asarray([0, 1])
+    snap = jnp.asarray([[T, 77, 0], [101, 64, 13]], jnp.int32)
+    start = S0 * (1 - fresh)[:, None, None, None]
+    o, snaps = kda.kda_recurrent_ref(q, k, v, g, beta, start, snap)
+    keep = jnp.asarray([[5, 6, 7], [8, 9, 10]], jnp.int32)
+    ok, pk = kda.kda_chunk(q, k, v, g, beta, _slots_of(S0, 6), 1,
+                           jnp.asarray([3, 4]), fresh, snap, keep,
+                           use_kernel=True, interpret=True)
+    assert bool(jnp.isfinite(ok).all()) and bool(jnp.isfinite(pk).all())
+    assert distance(np.asarray(ok), np.asarray(o)) < KERNEL_TOL
+    for b in range(2):
+        for j in range(3):
+            want = np.asarray(snaps[b, j].reshape(64, 16))
+            got = np.asarray(pk[1, keep[b, j]])
+            assert np.abs(got - want).max() < KERNEL_TOL * max(
+                1.0, np.abs(want).max())
+
+
+def test_a_state_carried_over_three_chunks_is_one_longer_chunks():
+    """Three launches of 64 through a working slot (the cold long prompt's
+    way: fresh, then from the slot the launch before kept to) against one
+    launch of 192 and against the recurrence."""
+    q, k, v, g, beta, S0 = _kda_inputs(2, 192, 4, 16, 8)
+    whole = jnp.full((2, 1), 192, jnp.int32)
+    o, snaps = kda.kda_recurrent_ref(
+        q, k, v, g, beta, jnp.zeros_like(S0), whole)
+    rows, pool = jnp.asarray([3, 4]), _slots_of(S0, 0)
+    one = kda.kda_chunk(q, k, v, g, beta, pool + 0, 1, rows, jnp.ones((2,)),
+                        whole, rows[:, None], use_kernel=True, interpret=True)
+    outs = []
+    for c in range(3):
+        part = [t[:, 64 * c:64 * (c + 1)] for t in (q, k, v, g, beta)]
+        oc, pool = kda.kda_chunk(
+            *part, pool, 1, rows, jnp.full((2,), c == 0),
+            jnp.full((2, 1), 64, jnp.int32), rows[:, None], use_kernel=True,
+            interpret=True)
+        outs.append(oc)
+    for got_o, got_pool in ((jnp.concatenate(outs, axis=1), pool), one):
+        assert distance(np.asarray(got_o), np.asarray(o)) < KERNEL_TOL
+        assert distance(np.asarray(got_pool[1, 3:]),
+                        np.asarray(snaps.reshape(2, 64, 16))) < KERNEL_TOL
+
+
+@pytest.mark.parametrize("T", [16, 64])
+def test_a_launch_of_more_rows_than_a_call_takes_is_several_calls_on_one_array(T):
     """``_ROWS_A_CALL`` rows a call (a launch of 32 rows in one call beside
     the latent chunk kernel never returned on the chip): the calls share
-    the slot array, each row keeps to its own slots."""
+    the slot array, each row keeps to its own slots; fresh rows and rows
+    that resume lie in one call."""
     B = kda._ROWS_A_CALL + 3
-    q, k, v, g, beta, S0 = _kda_inputs(B, 16, 2, 16, 6)
-    snap = jnp.tile(jnp.asarray([[16, 5]], jnp.int32), (B, 1))
-    _, snaps = kda.kda_recurrent_ref(q, k, v, g, beta, S0, snap)
+    q, k, v, g, beta, S0 = _kda_inputs(B, T, 2, 16, 6)
+    fresh = jnp.arange(B) % 3 == 1
+    snap = jnp.tile(jnp.asarray([[T, 5]], jnp.int32), (B, 1))
+    _, snaps = kda.kda_recurrent_ref(
+        q, k, v, g, beta, S0 * ~fresh[:, None, None, None], snap)
     pool = jnp.concatenate([jnp.ones((1, 32, 16)), S0.reshape(B, 32, 16),
                             jnp.ones((2 * B, 32, 16))])[None]
     rows = 1 + jnp.arange(B)
     keep = (1 + B + jnp.arange(2 * B)).reshape(B, 2)
-    want = kda.kda_chunk(q, k, v, g, beta, pool, 0, rows, jnp.zeros((B,)), snap,
+    want = kda.kda_chunk(q, k, v, g, beta, pool, 0, rows, fresh, snap,
                          keep, use_kernel=False)
-    got = kda.kda_chunk(q, k, v, g, beta, pool + 0, 0, rows, jnp.zeros((B,)),
+    got = kda.kda_chunk(q, k, v, g, beta, pool + 0, 0, rows, fresh,
                         snap, keep, use_kernel=True, interpret=True)
-    assert distance(np.asarray(got[0]), np.asarray(want[0])) < 1e-6
-    assert distance(np.asarray(got[1]), np.asarray(want[1])) < 1e-6
+    assert distance(np.asarray(got[0]), np.asarray(want[0])) < KERNEL_TOL
+    assert distance(np.asarray(got[1]), np.asarray(want[1])) < KERNEL_TOL
     assert distance(np.asarray(got[1][0, 1 + B:]),
-                    np.asarray(snaps.reshape(2 * B, 32, 16))) < 1e-6
+                    np.asarray(snaps.reshape(2 * B, 32, 16))) < KERNEL_TOL
+
+
+def _inner(eqn):
+    """The jaxprs an equation holds."""
+    for val in eqn.params.values():
+        for x in val if isinstance(val, (list, tuple)) else (val,):
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield x
+
+
+def _eqns(jaxpr) -> int:
+    """Equations of a jaxpr and of every jaxpr its equations hold."""
+    return sum(1 + sum(map(_eqns, _inner(e))) for e in jaxpr.eqns)
+
+
+def _chunk_calls(jaxpr) -> list:
+    """The ``radixmesh_kda_chunk`` calls of a traced program."""
+    found = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            found += [e] * ("radixmesh_kda_chunk" in str(e.params["name"]))
+        else:
+            found += [c for j in _inner(e) for c in _chunk_calls(j)]
+    return found
+
+
+def test_the_chunk_kernels_trace_does_not_grow_with_heads_or_rows(model):
+    """What set-up pays for the kernel a program: PR 49's blocked form was
+    refused for `setup_s` +19.3% in `ling-3.0-flash-ep8.batch-longtail`
+    (bound 10%) with a gain of 36% in hand — its body unrolled the heads
+    and the block's positions in Python, in each of up to 24 calls of each
+    of ~70 prefill programs a set-up traces and lowers, cache or no cache.
+    Held here: the body's equations do not grow with the heads (a grid
+    step takes ``_HEADS_A_STEP`` of them, batched: the same body at 8
+    heads and at the published 32, a smaller one at 2), the positions or
+    the rows, and stay under a cap (the walk's body at 32 heads was ~680;
+    a snapshot a row costs ~100), and a prefill program of ``ling-tiny``
+    holds one call a kda layer and ``_ROWS_A_CALL`` rows, no more."""
+    from radixmesh_tpu.models.llama import new_moe_stats, prefill_chunk_paged
+
+    f32, i32 = jnp.float32, jnp.int32
+    sd = jax.ShapeDtypeStruct
+
+    def body(H, T=64, B=2, d=16, K=3):
+        seq = sd((B, T, H, d), f32)
+        jaxpr = jax.make_jaxpr(kda.kda_chunk_kernel)(
+            seq, seq, seq, seq, sd((B, T, H), f32), sd((1, 9, H * d, d), f32),
+            sd((), i32), sd((B,), i32), sd((B,), i32), sd((B, K), i32),
+            sd((B, K), i32))
+        return max(_eqns(c.params["jaxpr"]) for c in _chunk_calls(jaxpr.jaxpr))
+
+    assert body(2) <= body(8) == body(32) <= 900
+    assert body(8, T=16) <= body(8) and body(8, T=512) == body(8, B=33)
+
+    cfg, params = model
+    shapes = jax.tree.map(lambda x: sd(x.shape, x.dtype), params)
+    groups = pool_groups(cfg, 4096, 4, PAGE)
+    state = (sd(groups["state_shape"], groups["state_dtype"]),
+             sd(groups["conv_state_shape"], cfg.dtype))
+    moe = jax.eval_shape(lambda: new_moe_stats(cfg.ep_size > 1, cfg.n_group > 1))
+    for rows in (4, 2 * kda._ROWS_A_CALL):
+        ints = lambda *s: sd(s, i32)  # noqa: E731
+        traced = prefill_chunk_paged.trace(
+            shapes, cfg, ints(rows, 64), ints(rows, 64),
+            sd(cfg.kv_pool_shape(4096), cfg.dtype), ints(rows, 64),
+            ints(rows, 32), ints(rows), page_size=PAGE, logits_at=ints(rows),
+            state_pool=state, state_in=ints(rows), snap_at=ints(rows, 3),
+            state_out=ints(rows, 3), moe_stats=moe)
+        assert len(_chunk_calls(traced.jaxpr.jaxpr)) == len(
+            cfg.kda_layers) * -(-rows // kda._ROWS_A_CALL)
 
 
 def test_the_decode_kernel_updates_the_rows_slots_in_place_and_no_other():
