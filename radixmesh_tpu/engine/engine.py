@@ -1440,8 +1440,9 @@ class Engine:
         sync``; a step that settles ends ``sync, consume``."""
         launched = self.prefill["tokens_launched"]
         acct = self.process_account
+        c = acct and acct.compiles
         pauses = acct and (
-            acct.gc_n, acct.gc_s, acct.compiles["n"], acct.compiles["s"]
+            acct.gc_n, acct.gc_s, c["n"], c["s"], c["trace_s"], c["lower_s"]
         )
         kind = "idle"
         with self.spans.step() as span:
@@ -1510,10 +1511,15 @@ class Engine:
         }
         if pauses:
             acct = self.process_account
-            gc_n, gc_s, c_n, c_s = pauses
+            gc_n, gc_s, c_n, c_s, c_trace_s, c_lower_s = pauses
             c = acct.compiles
             record["gc"] = {"n": acct.gc_n - gc_n, "s": round(acct.gc_s - gc_s, 6)}
-            record["compiles"] = {"n": c["n"] - c_n, "s": round(c["s"] - c_s, 6)}
+            record["compiles"] = {
+                "n": c["n"] - c_n,
+                "s": round(c["s"] - c_s, 6),
+                "trace_s": round(c["trace_s"] - c_trace_s, 6),
+                "lower_s": round(c["lower_s"] - c_lower_s, 6),
+            }
         slow["last"].append(record)
 
     @property

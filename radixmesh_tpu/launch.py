@@ -567,59 +567,66 @@ def _run_serve(args: argparse.Namespace) -> int:
     configure_logger("serve")
     log = get_logger("launch")
     _configure_tracing(args, node="serve")
-    # The process's collections and compilations, counted from here on
-    # (/debug/state ``process``). gc.callbacks and jax.monitoring are
-    # global to the process: this entry point installs them, no library
-    # code does.
+    # The process's collections, compilations and start-up, counted from
+    # here on (/debug/state ``process``). gc.callbacks and jax.monitoring
+    # are global to the process: this entry point installs them, no library
+    # code does. The phases: ``imports`` (the process's start -> here),
+    # ``devices`` (the backend), ``params`` (until the weights are on the
+    # device), ``engine`` (pool and tables), ``listen``.
     account = ProcessAccount().install()
-    cfg = get_config(args.model)
-    log.info("initializing %s (%d layers)...", args.model, cfg.n_layers)
-    _log_devices(log, "devices")
-    if args.weights:
-        from radixmesh_tpu.models.hf_io import load_hf_checkpoint
+    account.started("imports")
+    with account.phase("devices"):
+        cfg = get_config(args.model)
+        log.info("initializing %s (%d layers)...", args.model, cfg.n_layers)
+        _log_devices(log, "devices")
+    with account.phase("params"):
+        if args.weights:
+            from radixmesh_tpu.models.hf_io import load_hf_checkpoint
 
-        log.info("loading HF checkpoint from %s", args.weights)
-        params = load_hf_checkpoint(args.weights, cfg)
-    else:
-        # Drawn directly in the serving form (W8A16 when asked), a layer
-        # at a time: the float pytree of a 7B model never exists.
-        params = init_params(
-            cfg, jax.random.PRNGKey(args.seed), weight_quant=args.weight_quant
-        )
+            log.info("loading HF checkpoint from %s", args.weights)
+            params = load_hf_checkpoint(args.weights, cfg)
+        else:
+            # Drawn directly in the serving form (W8A16 when asked), a layer
+            # at a time: the float pytree of a 7B model never exists.
+            params = init_params(
+                cfg, jax.random.PRNGKey(args.seed), weight_quant=args.weight_quant
+            )
+        jax.block_until_ready(params)
     tokenizer = None
     if args.tokenizer:
         from radixmesh_tpu.server.tokenizer import load_tokenizer
 
         tokenizer = load_tokenizer(args.tokenizer)
-    engine = Engine(
-        cfg,
-        params,
-        num_slots=args.kv_slots,
-        page_size=args.page_size,
-        max_batch=args.max_batch,
-        host_cache_slots=args.host_cache_slots,
-        prefill_inline_budget=args.prefill_inline_budget,
-        prefill_inline_max_defer=args.prefill_inline_max_defer,
-        spec_decode_tokens=args.spec_decode_tokens,
-        spec_adaptive=args.spec_adaptive,
-        token_timeline_capacity=args.token_timeline_capacity,
-        token_stall_threshold_s=args.token_stall_threshold_ms / 1e3,
-        kv_quant=args.kv_quant,
-        weight_quant=args.weight_quant,
-        kv_transfer_async=args.kv_transfer_async,
-        kv_transfer_chunk_tokens=args.kv_transfer_chunk or 512,
-        kv_transfer_min_restore_tokens=args.kv_transfer_min_restore or 0,
-        kv_tier_dir=args.kv_tier_dir,
-        kv_tier_capacity_bytes=(
-            int(args.kv_tier_capacity_gb * (1 << 30))
-            if args.kv_tier_capacity_gb is not None
-            else 1 << 30
-        ),
-        stream_publish_tokens=args.stream_publish or 0,
-        step_accounting=args.step_accounting,
-        peak_tflops=args.peak_tflops,
-        process_account=account,
-    )
+    with account.phase("engine"):
+        engine = Engine(
+            cfg,
+            params,
+            num_slots=args.kv_slots,
+            page_size=args.page_size,
+            max_batch=args.max_batch,
+            host_cache_slots=args.host_cache_slots,
+            prefill_inline_budget=args.prefill_inline_budget,
+            prefill_inline_max_defer=args.prefill_inline_max_defer,
+            spec_decode_tokens=args.spec_decode_tokens,
+            spec_adaptive=args.spec_adaptive,
+            token_timeline_capacity=args.token_timeline_capacity,
+            token_stall_threshold_s=args.token_stall_threshold_ms / 1e3,
+            kv_quant=args.kv_quant,
+            weight_quant=args.weight_quant,
+            kv_transfer_async=args.kv_transfer_async,
+            kv_transfer_chunk_tokens=args.kv_transfer_chunk or 512,
+            kv_transfer_min_restore_tokens=args.kv_transfer_min_restore or 0,
+            kv_tier_dir=args.kv_tier_dir,
+            kv_tier_capacity_bytes=(
+                int(args.kv_tier_capacity_gb * (1 << 30))
+                if args.kv_tier_capacity_gb is not None
+                else 1 << 30
+            ),
+            stream_publish_tokens=args.stream_publish or 0,
+            step_accounting=args.step_accounting,
+            peak_tflops=args.peak_tflops,
+            process_account=account,
+        )
     slo_cfg = None
     if args.slo or args.slo_ttft_ms is not None or args.slo_tenant:
         from radixmesh_tpu.slo import SLOConfig, TenantConfig
@@ -645,11 +652,12 @@ def _run_serve(args: argparse.Namespace) -> int:
         )
         log.info("SLO control plane enabled (%d tenants)", len(tenants))
     _log_devices(log, "weights + KV pool resident")
-    frontend = ServingFrontend(
-        engine, host=args.host, port=args.http_port,
-        profile_dir=args.profile_dir, tokenizer=tokenizer, slo=slo_cfg,
-        **_history_kwargs(args),
-    )
+    with account.phase("listen"):
+        frontend = ServingFrontend(
+            engine, host=args.host, port=args.http_port,
+            profile_dir=args.profile_dir, tokenizer=tokenizer, slo=slo_cfg,
+            **_history_kwargs(args),
+        )
     print(f"serving {args.model} on http://{args.host}:{frontend.port}", flush=True)
 
     stop = threading.Event()

@@ -13,10 +13,12 @@ Four levels:
   ``engine/engine.py``, ``server/http_frontend.py``'s runner and
   ``slo/runner.py`` go through it; ``rm.http.emit`` (the SSE streams'
   emitter thread and the handler threads) stays a bare ``annotate``.
-- :class:`ProcessAccount` — the process's pauses: garbage collections by
-  generation and XLA compilations by name, from ``gc.callbacks`` and
-  ``jax.monitoring``. Both hooks are global to the process, so only an
-  entry point (``launch.py serve``) installs one.
+- :class:`ProcessAccount` — the process's pauses and its set-up: garbage
+  collections by generation; every program's tracing, lowering,
+  compilation and cache-load seconds, by name and by where the thread
+  was; the start-up's phases. From ``gc.callbacks`` and ``jax.monitoring``:
+  both hooks are global to the process, so only an entry point
+  (``launch.py serve``) installs one.
 - :func:`profile` — capture a full device+host trace of a block into a
   TensorBoard logdir.
 
@@ -32,6 +34,7 @@ import collections
 import contextlib
 import functools
 import gc
+import os
 import threading
 import time
 from typing import Iterator
@@ -192,33 +195,114 @@ class SpanBook:
         return [[name, round(d, 6)] for d, name in top if d > 0]
 
 
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+# The three intervals ``jax._src.dispatch.log_elapsed_time`` reports of a
+# program — a scalar of the event's name as it opens, a duration as it
+# closes — and the key each one's seconds are counted under.
+_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
 _CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
+def _process_age() -> float:
+    """Seconds since the kernel started this process: ``/proc/self/stat``'s
+    start time (field 22, clock ticks since boot) against ``/proc/uptime``.
+    It counts the interpreter's start and every import, which no stamp
+    inside the program can; what it leaves out is the parent's ``fork`` and
+    ``exec``. One tick is 10 ms. Where there is no such ``/proc`` (off
+    Linux), or its two clocks disagree (a container whose ``/proc/uptime``
+    is its own while the start time counts from the host's boot reads an
+    age below this module's), the age is :func:`since_import`'s, and
+    ``imports`` is simply short."""
+    floor = since_import()
+    try:
+        with open("/proc/self/stat") as fh:
+            ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as fh:
+            uptime = float(fh.read().split()[0])
+        age = uptime - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return floor
+    return max(age, floor)
+
+
+def _program(fun_name) -> str:
+    """One key for a program's three events: tracing names the function
+    (``outer``), lowering and the compiler the module (``jit(outer)``)."""
+    name = str(fun_name)
+    if name.endswith(")") and "(" in name:
+        name = name[name.index("(") + 1 : -1]
+    return name
+
+
+class _OpenIntervals(threading.local):
+    """What one thread has open of the three intervals, and what it carries
+    from one of a program's intervals to the next."""
+
+    depth = 0  # intervals open on this thread, one inside the other
+    where = None  # the outermost one's ``by_span`` key, taken as it opened
+    nested_s = 0.0  # compilations that ended inside the outermost one
+    hit = False  # the open compilation was served by the persistent cache
+    traced = None  # {name, trace_s, lower_s} of a program waiting for its compilation
+
+
 class ProcessAccount:
-    """What paused the whole process, counted always once installed.
+    """What paused the whole process, and what its set-up went to, counted
+    always once installed.
 
     ``gc``: per generation ``{"n", "s", "max_s"}`` from one ``gc.callbacks``
-    entry that stamps ``start`` and adds at ``stop``. ``compiles``: every
-    backend compilation JAX reports (``backend_compile_duration`` wraps the
-    persistent cache's lookup too, so ``n`` counts hits as the
-    ``Finished XLA compilation`` log lines do; ``cache_hits`` says how many
-    were), its seconds, and the last 32 by name with the span the engine
-    thread had open (``book``'s innermost) or, from any other thread, that
-    thread's name. ``gc_n`` / ``gc_s`` are the sums over generations, for a
-    reader that wants a cheap before/after."""
+    entry that stamps ``start`` and adds at ``stop``. ``gc_n`` / ``gc_s`` are
+    the sums over generations, for a reader that wants a cheap before/after.
+
+    ``compiles``: every backend compilation JAX reports
+    (``backend_compile_duration`` wraps the persistent cache's lookup too,
+    so ``n`` counts hits as the ``Finished XLA compilation`` log lines do;
+    ``cache_hits`` says how many were and ``cache_load_s`` what they took of
+    ``s``), and the last 32 by name with the span the engine thread had
+    open (``book``'s innermost) or, from any other thread, that thread's
+    name. ``trace_s`` / ``lower_s``: the seconds of tracing and of lowering
+    to MLIR. The three intervals nest (tracing ``outer`` traces ``inner``;
+    lowering traces again), so a second goes to the kind of the OUTERMOST
+    interval its thread has open and to nothing else — but for a
+    compilation that ends inside a trace (``ensure_compile_time_eval``),
+    which keeps its place in ``n`` and ``s`` and is taken off the interval
+    around it. So ``trace_s + lower_s + s`` counts no second twice.
+    ``by_program`` (the same seconds by function name, ``max_s`` the longest
+    single interval) and ``by_span`` (by where the thread was when the
+    outermost interval opened) hold at most ``TABLE`` names and
+    ``"(other)"``; each sums to the totals. ``programs``: the names in
+    ``by_program``; ``first_at_s``: when a name was first heard, to lay
+    beside a slow step's ``at_s``.
+
+    ``startup``: the phases ``launch.py serve`` marks with :meth:`started`
+    and :meth:`phase`; ``ready_at_s``, the process's age (see
+    ``_process_age``) as the last of them ended; ``compiling_s``, what
+    ``trace_s + lower_s + s`` read then (drawing the weights is a program
+    too: those seconds are the start-up's AND the compile path's, and a
+    reader that adds the two takes them off one). ``at_s`` /
+    ``first_at_s`` stay on :func:`since_import`'s clock.
+
+    The ``jax.monitoring`` listeners run when JAX traces, lowers, compiles
+    or looks a program up and never in a step that does none of these."""
 
     RING = 32
+    TABLE = 256
 
     def __init__(self, book: SpanBook | None = None):
         self.book = book
         self.gc = [{"n": 0, "s": 0.0, "max_s": 0.0} for _ in range(3)]
         self.gc_n, self.gc_s = 0, 0.0
-        self.compiles = {"n": 0, "s": 0.0, "cache_hits": 0}
+        self.compiles = {"n": 0, "s": 0.0, "cache_hits": 0, "trace_s": 0.0,
+                         "lower_s": 0.0, "cache_load_s": 0.0}
         self.last: collections.deque = collections.deque(maxlen=self.RING)
+        self.by_program: dict[str, dict] = {}
+        self.by_span: dict[str, dict] = {}
+        self.startup = {"phases": [], "ready_at_s": None, "compiling_s": 0.0}
+        self._t_start = time.monotonic() - _process_age()
         self._gc_t0 = 0.0
-        self._hit_threads: set[int] = set()
+        self._open = _OpenIntervals()
         self._lock = threading.Lock()  # compilations run on any thread
         self._installed = False
 
@@ -227,6 +311,7 @@ class ProcessAccount:
             from jax import monitoring
 
             gc.callbacks.append(self._on_gc)
+            monitoring.register_scalar_listener(self._on_open)
             monitoring.register_event_duration_secs_listener(self._on_duration)
             self._installed = True
         return self
@@ -236,8 +321,29 @@ class ProcessAccount:
             from jax import monitoring
 
             gc.callbacks.remove(self._on_gc)
+            monitoring.unregister_scalar_listener(self._on_open)
             monitoring.unregister_event_duration_listener(self._on_duration)
             self._installed = False
+
+    def started(self, name: str) -> None:
+        """The phase that began with the process ends here (``imports``)."""
+        self._phase_ended(name, self._t_start)
+
+    @contextlib.contextmanager
+    def phase(self, name: str) -> Iterator[None]:
+        """One phase of the start-up: its seconds. No ``annotate`` span:
+        ``serve`` has no way to capture its own start (the profile endpoint
+        exists only after ``listen``), so nothing could read one."""
+        t0 = time.monotonic()
+        yield
+        self._phase_ended(name, t0)
+
+    def _phase_ended(self, name: str, t0: float) -> None:
+        now = time.monotonic()
+        c = self.compiles
+        self.startup["phases"].append([name, now - t0])
+        self.startup["ready_at_s"] = now - self._t_start
+        self.startup["compiling_s"] = c["trace_s"] + c["lower_s"] + c["s"]
 
     def _on_gc(self, phase: str, info: dict) -> None:
         # Collections do not overlap (the collector is not re-entrant), so
@@ -254,38 +360,100 @@ class ProcessAccount:
         self.gc_n += 1
         self.gc_s += dt
 
+    def _where(self) -> str:
+        book = self.book
+        if book is not None and book.thread == threading.get_ident():
+            return book.innermost or OUTSIDE_STEP
+        return threading.current_thread().name
+
+    def _row(self, table: dict, key: str, **more) -> dict:
+        """``table``'s row for ``key``, made with ``more`` beside the four
+        counts; past ``TABLE`` names a new one is ``"(other)"``, so nothing
+        a request sends grows a table."""
+        row = table.get(key)
+        if row is None:
+            if len(table) >= self.TABLE:
+                key = "(other)"
+            row = table.setdefault(
+                key, {"n": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0} | more)
+        return row
+
+    def _on_open(self, event: str, value: float, **kw) -> None:
+        if event in _KINDS:
+            t = self._open
+            if t.depth == 0:
+                t.where = self._where()
+            t.depth += 1
+
     def _on_duration(self, event: str, seconds: float, **kw) -> None:
+        t = self._open
         if event == _CACHE_RETRIEVAL:
             # Fires inside the backend-compile interval of the same thread.
-            self._hit_threads.add(threading.get_ident())
+            t.hit = True
             return
-        if event != _BACKEND_COMPILE:
+        kind = _KINDS.get(event)
+        if kind is None:
             return
-        ident = threading.get_ident()
-        book = self.book
-        if book is not None and book.thread == ident:
-            where = book.innermost or OUTSIDE_STEP
+        if t.depth:  # 0: installed while this interval was open
+            t.depth -= 1
+        backend = kind == "backend_s"
+        if t.depth and not backend:
+            return  # inside another interval: that one's seconds
+        own = seconds
+        if t.depth:  # a compilation inside a trace: taken off the trace
+            t.nested_s += seconds
         else:
-            where = threading.current_thread().name
+            own -= t.nested_s
+            t.nested_s = 0.0
+        name = _program(kw.get("fun_name"))
+        where = t.where or self._where()
+        traced = t.traced
+        if traced is None or traced["name"] != name:
+            traced = {"name": name, "trace_s": 0.0, "lower_s": 0.0}
+        if backend:
+            hit, t.hit, t.traced = t.hit, False, None
+        else:
+            traced[kind] += own
+            t.traced = traced
+        at_s = since_import()
         with self._lock:
-            hit = ident in self._hit_threads
-            self._hit_threads.discard(ident)
             c = self.compiles
-            c["n"] += 1
+            program = self._row(self.by_program, name, cache_hits=0, max_s=0.0,
+                                first_at_s=round(at_s - seconds, 3))
+            span = self._row(self.by_span, where)
+            program[kind] += own
+            span[kind] += own
+            program["max_s"] = max(program["max_s"], own)
+            if not backend:
+                c[kind] += own
+                return
+            for counts in (c, program, span):
+                counts["n"] += 1
             c["s"] += seconds
             c["cache_hits"] += hit
+            program["cache_hits"] += hit
+            if hit:
+                c["cache_load_s"] += seconds
             self.last.append({
                 "fun_name": str(kw.get("fun_name")),
                 "seconds": round(seconds, 6),
+                "trace_s": round(traced["trace_s"], 6),
+                "lower_s": round(traced["lower_s"], 6),
                 "cache_hit": hit,
-                "at_s": round(since_import(), 3),
+                "at_s": round(at_s, 3),
                 "span": where,
             })
 
     def snapshot(self) -> dict:
+        with self._lock:
+            by_program = {k: dict(v) for k, v in self.by_program.items()}
+            by_span = {k: dict(v) for k, v in self.by_span.items()}
+            compiles = {**self.compiles, "last": list(self.last)}
         return {
             "gc": {str(i): dict(g) for i, g in enumerate(self.gc)},
-            "compiles": {**self.compiles, "last": list(self.last)},
+            "compiles": {**compiles, "programs": len(by_program),
+                         "by_program": by_program, "by_span": by_span},
+            "startup": {**self.startup, "phases": list(self.startup["phases"])},
         }
 
 

@@ -173,18 +173,20 @@ def _window_of(config_name: str) -> dict:
     twin = config["rehearsal"]
     args = _server_args(twin["server_args"])
     cfg = get_config(twin["preset"])
-    # launch.py serve installs the account; a frontend built by hand has
-    # no ``process`` group without it.
+    # launch.py serve installs the account and marks the start-up's phases;
+    # a frontend built by hand has no ``process`` group without the one and
+    # no ``startup.ready_at_s`` without the other.
     account = ProcessAccount().install()
-    engine = Engine(
-        cfg,
-        init_params(cfg, jax.random.PRNGKey(0), weight_quant=args.weight_quant),
-        num_slots=args.kv_slots,
-        page_size=args.page_size,
-        max_batch=args.max_batch,
-        weight_quant=args.weight_quant,
-        process_account=account,
-    )
+    with account.phase("engine"):
+        engine = Engine(
+            cfg,
+            init_params(cfg, jax.random.PRNGKey(0), weight_quant=args.weight_quant),
+            num_slots=args.kv_slots,
+            page_size=args.page_size,
+            max_batch=args.max_batch,
+            weight_quant=args.weight_quant,
+            process_account=account,
+        )
     frontend = ServingFrontend(engine, port=0)
     base = f"http://127.0.0.1:{frontend.port}"
     try:
