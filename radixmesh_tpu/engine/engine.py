@@ -53,6 +53,7 @@ from radixmesh_tpu.engine.prefill_plan import (
     pow2_at_least as _pow2_at_least,
     subwaves,
 )
+from radixmesh_tpu.engine.programs import ProgramStore
 from radixmesh_tpu.engine.request import Request, RequestState, SamplingParams
 from radixmesh_tpu.models.llama import (
     ModelConfig,
@@ -75,6 +76,18 @@ from radixmesh_tpu.obs.tracing import ProcessAccount, SpanBook, since_import
 from radixmesh_tpu.ops.mla_attention import decode_groups
 from radixmesh_tpu.ops.sampling import sample_tokens, spec_verify_sample
 from radixmesh_tpu.utils.logging import get_logger
+
+# The three big programs of the served path and their static argument
+# names (their decorators', models/llama.py): what an engine launches
+# through its program store. Keyed by the functions themselves: whatever
+# else stands under one of these names at a launch (a test's own jit) is
+# called as it is.
+_STORED_PROGRAMS = {
+    prefill_chunk_paged: ("cfg", "page_size", "kv_block_pages", "mesh",
+                          "use_kernel", "interpret"),
+    decode_step: ("cfg", "page_size", "mesh"),
+    prefill_forward: ("cfg",),
+}
 
 # Per-process engine sequence: disaggregated harnesses run a prefill engine
 # and a decode engine in one process, so each needs its own metric series.
@@ -419,6 +432,11 @@ class Engine:
         self.process_account = process_account
         if process_account is not None:
             process_account.book = self.spans
+        # The launches of the three big programs: from a stored executable
+        # where JAX's persistent cache is configured and there is no device
+        # mesh, jax.jit's own otherwise (engine/programs.py).
+        self._launch = ProgramStore(
+            _STORED_PROGRAMS, account=process_account, mesh=device_mesh)
         # prefill: the admission waves' work (_admit): sub-waves, their
         # members, the device launches that prefilled them, uncached
         # prompt tokens computed, tokens of the launched shapes (the sum of
@@ -1442,7 +1460,8 @@ class Engine:
         acct = self.process_account
         c = acct and acct.compiles
         pauses = acct and (
-            acct.gc_n, acct.gc_s, c["n"], c["s"], c["trace_s"], c["lower_s"]
+            acct.gc_n, acct.gc_s, c["n"], c["s"], c["trace_s"], c["lower_s"],
+            acct.stored["hits"], acct.stored["load_s"],
         )
         kind = "idle"
         with self.spans.step() as span:
@@ -1484,9 +1503,9 @@ class Engine:
     def _note_slow_step(self, span, excess, kind, launched, pauses) -> None:
         """One record of ``slow_steps``: when, how long, the three spans
         that took most of the step, what it launched, the queue, the
-        collections and compilations that fell inside it (where a
-        ``process_account`` is installed) and the device's memory, read
-        only here. It does not say what the runtime did in those seconds;
+        collections, compilations and loads of stored programs that fell
+        inside it (where a ``process_account`` is installed) and the
+        device's memory, read only here. It does not say what the runtime did in those seconds;
         it says that this run had a stall, when, under which span and at
         what memory."""
         from radixmesh_tpu.obs.step_plane import describe_devices
@@ -1511,7 +1530,7 @@ class Engine:
         }
         if pauses:
             acct = self.process_account
-            gc_n, gc_s, c_n, c_s, c_trace_s, c_lower_s = pauses
+            gc_n, gc_s, c_n, c_s, c_trace_s, c_lower_s, loads, load_s = pauses
             c = acct.compiles
             record["gc"] = {"n": acct.gc_n - gc_n, "s": round(acct.gc_s - gc_s, 6)}
             record["compiles"] = {
@@ -1519,6 +1538,9 @@ class Engine:
                 "s": round(c["s"] - c_s, 6),
                 "trace_s": round(c["trace_s"] - c_trace_s, 6),
                 "lower_s": round(c["lower_s"] - c_lower_s, 6),
+                # programs loaded from the store: no compilation, a pause
+                "loads": acct.stored["hits"] - loads,
+                "load_s": round(acct.stored["load_s"] - load_s, 6),
             }
         slow["last"].append(record)
 
@@ -2705,7 +2727,8 @@ class Engine:
         last_only = self.cfg.has_ssm
         if last_only:
             moe_kw["new_lengths"] = jnp.full((1,), n_new, dtype=jnp.int32)
-        logits, new_k, new_v, *aux = prefill_forward(
+        logits, new_k, new_v, *aux = self._launch(
+            prefill_forward,
             self.params,
             self.cfg,
             jnp.asarray(tokens),
@@ -2794,7 +2817,8 @@ class Engine:
                 res[0], logits_at[:, None, None], axis=1
             )
             return (picked, *res[1:])
-        return prefill_chunk_paged(
+        return self._launch(
+            prefill_chunk_paged,
             self.params,
             self.cfg,
             toks,
@@ -3291,7 +3315,8 @@ class Engine:
                 )
                 logits = self._commit_pool_update(res)[:, 0]
             else:
-                res = decode_step(
+                res = self._launch(
+                    decode_step,
                     self.params,
                     self.cfg,
                     tokens_d,

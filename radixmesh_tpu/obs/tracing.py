@@ -276,6 +276,17 @@ class ProcessAccount:
     ``by_program``; ``first_at_s``: when a name was first heard, to lay
     beside a slow step's ``at_s``.
 
+    ``compiles["stored"]``: the programs that went through no compile path
+    at all or filled the store that spares the next start one
+    (``engine/programs.py``, which calls the three ``program_*`` methods):
+    ``hits`` loaded in ``load_s`` (a program's row has its own ``hits`` /
+    ``load_s``, so a loaded program has a row and a ``first_at_s``),
+    ``misses`` compiled as above and written in ``write_s``, ``bytes`` of
+    the entries read and written, ``refused`` by reason — what stayed on or
+    fell back to ``jax.jit``. JAX hears nothing of a load: ``trace_s``,
+    ``lower_s``, ``n`` and ``cache_load_s`` count the programs that are not
+    stored, and a stored one's first build.
+
     ``startup``: the phases ``launch.py serve`` marks with :meth:`started`
     and :meth:`phase`; ``ready_at_s``, the process's age (see
     ``_process_age``) as the last of them ended; ``compiling_s``, what
@@ -296,6 +307,8 @@ class ProcessAccount:
         self.gc_n, self.gc_s = 0, 0.0
         self.compiles = {"n": 0, "s": 0.0, "cache_hits": 0, "trace_s": 0.0,
                          "lower_s": 0.0, "cache_load_s": 0.0}
+        self.stored = {"hits": 0, "misses": 0, "load_s": 0.0, "write_s": 0.0,
+                       "bytes": 0, "refused": {}}
         self.last: collections.deque = collections.deque(maxlen=self.RING)
         self.by_program: dict[str, dict] = {}
         self.by_span: dict[str, dict] = {}
@@ -378,6 +391,35 @@ class ProcessAccount:
                 key, {"n": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0} | more)
         return row
 
+    def _program_row(self, name: str, first_at_s: float) -> dict:
+        return self._row(self.by_program, name, cache_hits=0, max_s=0.0,
+                         first_at_s=round(first_at_s, 3), hits=0, load_s=0.0)
+
+    def program_loaded(self, name: str, seconds: float, nbytes: int) -> None:
+        """A stored executable was read and loaded instead of built."""
+        at_s = since_import()
+        with self._lock:
+            stored = self.stored
+            program = self._program_row(name, at_s - seconds)
+            for counts in (stored, program):
+                counts["hits"] += 1
+                counts["load_s"] += seconds
+            stored["bytes"] += nbytes
+
+    def program_missed(self, name: str, seconds: float, nbytes: int) -> None:
+        """The store had no entry: the program was compiled (JAX reported
+        that) and its executable written in ``seconds``."""
+        with self._lock:
+            stored = self.stored
+            stored["misses"] += 1
+            stored["write_s"] += seconds
+            stored["bytes"] += nbytes
+
+    def program_refused(self, reason: str) -> None:
+        with self._lock:
+            refused = self.stored["refused"]
+            refused[reason] = refused.get(reason, 0) + 1
+
     def _on_open(self, event: str, value: float, **kw) -> None:
         if event in _KINDS:
             t = self._open
@@ -418,8 +460,7 @@ class ProcessAccount:
         at_s = since_import()
         with self._lock:
             c = self.compiles
-            program = self._row(self.by_program, name, cache_hits=0, max_s=0.0,
-                                first_at_s=round(at_s - seconds, 3))
+            program = self._program_row(name, at_s - seconds)
             span = self._row(self.by_span, where)
             program[kind] += own
             span[kind] += own
@@ -448,7 +489,9 @@ class ProcessAccount:
         with self._lock:
             by_program = {k: dict(v) for k, v in self.by_program.items()}
             by_span = {k: dict(v) for k, v in self.by_span.items()}
-            compiles = {**self.compiles, "last": list(self.last)}
+            compiles = {**self.compiles, "last": list(self.last),
+                        "stored": {**self.stored,
+                                   "refused": dict(self.stored["refused"])}}
         return {
             "gc": {str(i): dict(g) for i, g in enumerate(self.gc)},
             "compiles": {**compiles, "programs": len(by_program),

@@ -39,9 +39,9 @@ def main() -> int:
     def flag(name: str) -> str:
         return rest[rest.index(name) + 1] if name in rest else "none"
 
-    out = args.out or os.path.join(
+    out = os.path.abspath(args.out or os.path.join(  # kept across the chdir below
         REPO, "chiprun_out", "states",
-        f"{flag('--workload')}.{flag('--seed')}.jsonl")
+        f"{flag('--workload')}.{flag('--seed')}.jsonl"))
     os.makedirs(os.path.dirname(out), exist_ok=True)
     open(out, "w").close()
 

@@ -82,3 +82,41 @@ def _fresh_trace_recorder():
     set_recorder(FlightRecorder())
     yield
     set_recorder(old)
+
+
+@pytest.fixture(autouse=True)
+def _no_program_store(request, monkeypatch):
+    """Engines of the suite launch through ``jax.jit``, not through stored
+    executables (``engine/programs.py``), unless a test asks for
+    ``program_store``. The store keys an executable by what it is built
+    FROM — the package's files, the static values, the argument types —
+    and that cannot see a function patched in this process: a test that
+    patches code under trace would write an entry every later test (and
+    run of the suite) loads under the unpatched key, where JAX's own cache
+    above, keyed by the traced module, only misses."""
+    if "program_store" not in request.fixturenames:
+        monkeypatch.setattr(
+            "radixmesh_tpu.engine.programs.store_dir", lambda: None)
+
+
+@pytest.fixture
+def program_store(tmp_path):
+    """A program store of the test's own: JAX's persistent cache configured
+    at a temporary directory, which is all that turns the store on, and
+    JAX's own cache moved there with the setting (``reset_cache``) so that
+    what the test builds first is compiled, not handed back: XLA:CPU does
+    not serialise a handed-back executable whole, and the store takes none
+    such there. Returns the store's directory (not there until an entry is
+    written)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from radixmesh_tpu.engine import programs
+
+    old = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "xla"))
+    compilation_cache.reset_cache()
+    try:
+        yield programs.store_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+        compilation_cache.reset_cache()

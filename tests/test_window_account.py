@@ -284,10 +284,41 @@ def test_a_second_goes_to_the_outermost_interval_and_to_nothing_else(case):
         assert c["by_program"]["outer"] == {
             "n": 1, "trace_s": 0.25, "lower_s": 0.5, "backend_s": 0.125,
             "cache_hits": 1, "first_at_s": c["by_program"]["outer"]["first_at_s"],
-            "max_s": 0.5}
+            "max_s": 0.5, "hits": 0, "load_s": 0.0}
         assert {k: c["last"][0][k] for k in ("seconds", "trace_s", "lower_s",
                                              "cache_hit")} == {
             "seconds": 0.125, "trace_s": 0.25, "lower_s": 0.5, "cache_hit": True}
+
+
+def test_stored_programs_have_a_block_and_a_row_of_their_own():
+    """What ``engine/programs.py`` reports: a load is no compilation (``n``,
+    ``trace_s``, ``last`` stay), and still has a row and a ``first_at_s``."""
+    acct = ProcessAccount()
+    empty = {"hits": 0, "misses": 0, "load_s": 0.0, "write_s": 0.0, "bytes": 0,
+             "refused": {}}
+    assert acct.snapshot()["compiles"]["stored"] == empty
+    _opened(acct, BACKEND, "jit(decode_step)")  # the first start: built, written
+    _closed(acct, BACKEND, "jit(decode_step)", 2.0)
+    acct.program_missed("decode_step", 0.25, 1000)
+    acct.program_loaded("decode_step", 0.5, 1000)  # the next start, same account
+    acct.program_loaded("prefill_chunk_paged", 0.125, 500)
+    acct.program_refused("corrupt")
+    acct.program_refused("corrupt")
+    acct.program_refused("mesh")
+    c = acct.snapshot()["compiles"]
+    assert c["stored"] == {"hits": 2, "misses": 1, "load_s": 0.625, "write_s": 0.25,
+                           "bytes": 2500, "refused": {"corrupt": 2, "mesh": 1}}
+    assert (c["n"], c["s"], c["trace_s"], len(c["last"]), c["programs"]) == (
+        1, 2.0, 0.0, 1, 2)
+    loaded = c["by_program"]["prefill_chunk_paged"]
+    assert loaded == {"n": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                      "cache_hits": 0, "max_s": 0.0, "hits": 1, "load_s": 0.125,
+                      "first_at_s": loaded["first_at_s"]}
+    built = c["by_program"]["decode_step"]
+    assert (built["n"], built["backend_s"], built["hits"], built["load_s"]) == (
+        1, 2.0, 1, 0.5)
+    c["stored"]["refused"]["mesh"] = 7  # a snapshot is a copy
+    assert acct.snapshot()["compiles"]["stored"]["refused"]["mesh"] == 1
 
 
 def test_nested_jits_in_a_scan_are_one_program_counted_once():
@@ -452,6 +483,7 @@ def test_slow_step_names_the_tracing_and_lowering_inside_it(model):
                                        (BACKEND, "jit(box)", 0.125)):
                     _opened(acct, event, name)
                     _closed(acct, event, name, s)
+                acct.program_loaded("stored_box", 0.75, 10)  # and one loaded
             return super().__call__()
 
     eng.spans.clock = clock = Clock(eng.spans)
@@ -461,7 +493,8 @@ def test_slow_step_names_the_tracing_and_lowering_inside_it(model):
     clock.stall["rm.decode.dispatch"] = 2.7
     eng.step()
     (rec,) = eng.slow_steps["last"]
-    assert rec["compiles"] == {"n": 1, "s": 0.125, "trace_s": 1.25, "lower_s": 0.5}
+    assert rec["compiles"] == {"n": 1, "s": 0.125, "trace_s": 1.25, "lower_s": 0.5,
+                               "loads": 1, "load_s": 0.75}
     assert acct.snapshot()["compiles"]["by_span"]["rm.decode.dispatch"]["n"] == 1
     while eng.has_work():
         eng.step()
