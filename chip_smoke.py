@@ -95,6 +95,14 @@ CHIP = {
         dict(heads=32, dim=128, batch=17, chunk=48, slots=72),
         dict(heads=32, dim=128, batch=4, chunk=16),
     ],
+    # Nemotron-3-Nano's Mamba-2 state: 64 heads of 64 x 128 float32 in 8
+    # groups, 2 MiB a row and block: a turn's 64, a cold prompt's 512 (four
+    # blocks of 128), more rows than a call takes.
+    "geometry_ssd": [
+        dict(heads=64, dim=64, groups=8, state=128, batch=4, chunk=64),
+        dict(heads=64, dim=64, groups=8, state=128, batch=2, chunk=512),
+        dict(heads=64, dim=64, groups=8, state=128, batch=17, chunk=128, slots=72),
+    ],
     # The chunk kernel over a prefix of several 512-token blocks, as a turn
     # of a session launches it: a chat wave (256 new on up to 3.8 k), an
     # agent turn (LFM2's two heads of 64 to a row: 4 wide heads, G = 8,
@@ -146,6 +154,7 @@ REHEARSAL = {
                             max_pages=16, block_tokens=16),
     "geometry_kda": [dict(heads=4, dim=16, batch=3, chunk=16),
                      dict(heads=4, dim=16, batch=2, chunk=64)],
+    "geometry_ssd": [dict(heads=8, dim=32, groups=2, state=32, batch=3, chunk=16)],
     "geometry_prefix": [
         dict(hq=7, hkv=1, head_dim=32, page=4, chunk=16, max_pages=16),
         dict(hq=8, hkv=2, head_dim=32, page=4, chunk=16, max_pages=16,
@@ -438,6 +447,7 @@ def phase_kernels(args) -> dict:
         check_kda,
         check_kernels,
         check_latent_decode,
+        check_ssd,
     )
 
     m = REHEARSAL if args.rehearse_cpu else CHIP
@@ -459,6 +469,10 @@ def phase_kernels(args) -> dict:
         {**r, "geometry": g}
         for g in m["geometry_kda"]
         for r in check_kda(interpret=m["interpret"], **g)
+    ] + [
+        {**r, "geometry": g}
+        for g in m["geometry_ssd"]
+        for r in check_ssd(interpret=m["interpret"], **g)
     ]
     return {"results": results, "seconds": round(time.monotonic() - t0, 2)}
 

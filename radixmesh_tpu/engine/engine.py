@@ -759,20 +759,22 @@ class Engine:
             self.log.info(
                 "state slots beside the rows: %d x %d B a snapshot",
                 self.pool.state.shape[1], self.pool.state_slot_bytes)
-        # kda: a model with linear-attention layers (None otherwise), what
-        # its launches move of the state slots, by host arithmetic on the
-        # launched shapes. decode_rows: live rows of decode launches;
+        # matrix_stats: a model whose layers carry a float32 matrix a head
+        # (``cfg.matrix_state``: kda linear attention, Mamba-2; None
+        # otherwise; ``/debug/state`` publishes it under the kind's name),
+        # what its launches move of the state slots, by host arithmetic on
+        # the launched shapes. decode_rows: live rows of decode launches;
         # chunk_tokens: new tokens of prefill launches; state_bytes_read /
-        # _written: whole snapshots (every kda layer's matrix and
+        # _written: whole snapshots (every such layer's matrix and
         # convolution inputs) the launches read and wrote — a decode launch
         # reads and writes every row of the launched batch (idle rows: the
         # scratch slot) and writes one more for each boundary copy, a
         # prefill launch reads one a row and writes ``_STATE_SNAPS`` a row;
         # boundary_copies: rows of decode launches whose token completed a
         # page and whose state was kept in the boundary slot.
-        self.kda_stats = None
-        if cfg.has_kda:
-            self.kda_stats = {
+        self.matrix_stats = None
+        if cfg.matrix_state:
+            self.matrix_stats = {
                 "decode_rows": 0, "chunk_tokens": 0, "state_bytes_read": 0,
                 "state_bytes_written": 0, "boundary_copies": 0}
         # window: a model whose window layers keep a group of rows of their
@@ -788,7 +790,7 @@ class Engine:
         # Snapshots of megabytes (a window tail, a kda layer's matrices)
         # are given up in order — superseded ones first, never one a
         # request is about to resume from — and a few at a time.
-        self._ordered_evict = cfg.has_kda or self.pool.window is not None
+        self._ordered_evict = bool(cfg.matrix_state) or self.pool.window is not None
         if self.pool.window is not None:
             self.window_stats = {
                 "slots": self.pool.window_slots,
@@ -2663,10 +2665,10 @@ class Engine:
             kw["state_pool"] = self.pool.state
         return kw
 
-    def _note_kda(self, read: int = 0, written: int = 0, **counts) -> None:
-        """``kda``'s counters for one launch that read and wrote so many
-        whole snapshots."""
-        st = self.kda_stats
+    def _note_matrix(self, read: int = 0, written: int = 0, **counts) -> None:
+        """``matrix_stats`` for one launch that read and wrote so many whole
+        snapshots."""
+        st = self.matrix_stats
         for name, n in counts.items():
             st[name] += n
         st["state_bytes_read"] += read * self.pool.state_slot_bytes
@@ -2751,8 +2753,8 @@ class Engine:
                        * self.page_size + at % self.page_size)
             self.pool.write_window(w_slots, win_k[:, 0, :n_new], win_v[:, 0, :n_new])
             self.window_stats["slots_written"] += n_new
-        if self.kda_stats is not None:
-            self._note_kda(chunk_tokens=n_new, written=_STATE_SNAPS)
+        if self.matrix_stats is not None:
+            self._note_matrix(chunk_tokens=n_new, written=_STATE_SNAPS)
         if self.state_stats is not None:
             states, *aux = aux
             self.pool.write_state(
@@ -2991,8 +2993,8 @@ class Engine:
             states = None
             if self.state_stats is not None:
                 states = self._chunk_states(reqs, starts, nv, B, done == 0)
-            if self.kda_stats is not None:
-                self._note_kda(chunk_tokens=int(nv.sum()), read=B,
+            if self.matrix_stats is not None:
+                self._note_matrix(chunk_tokens=int(nv.sum()), read=B,
                                written=B * _STATE_SNAPS)
             if windowed:
                 w_sl = np.zeros((B, C), dtype=np.int32)
@@ -3264,8 +3266,8 @@ class Engine:
             aw["latent_tokens_attended"] += attended
             aw["latent_tokens_shared"] += attended - launch.latent_tokens
             aw["decode_groups"] = launch.groups
-        if self.kda_stats is not None:
-            self._note_kda(
+        if self.matrix_stats is not None:
+            self._note_matrix(
                 decode_rows=len(launch.idx), read=self.max_batch,
                 written=self.max_batch + launch.boundary_copies,
                 boundary_copies=launch.boundary_copies)

@@ -556,6 +556,20 @@ def _run_node(args: argparse.Namespace) -> int:
     return 0
 
 
+def _apply_libtpu_args(cfg, log) -> None:
+    """``cfg.libtpu_init_args`` into ``LIBTPU_INIT_ARGS``, keeping what the
+    caller set (a flag the caller names wins). Called before anything touches
+    a device: libtpu reads the variable once, when the backend starts; a
+    process held to the CPU never reads it."""
+    import os
+
+    have = os.environ.get("LIBTPU_INIT_ARGS", "")
+    extra = [a for a in cfg.libtpu_init_args if a.split("=")[0] not in have]
+    if extra:
+        os.environ["LIBTPU_INIT_ARGS"] = " ".join([have, *extra]).strip()
+        log.info("LIBTPU_INIT_ARGS for this model: %s", " ".join(extra))
+
+
 def _run_serve(args: argparse.Namespace) -> int:
     import jax
 
@@ -578,6 +592,7 @@ def _run_serve(args: argparse.Namespace) -> int:
     with account.phase("devices"):
         cfg = get_config(args.model)
         log.info("initializing %s (%d layers)...", args.model, cfg.n_layers)
+        _apply_libtpu_args(cfg, log)
         _log_devices(log, "devices")
     with account.phase("params"):
         if args.weights:

@@ -8,7 +8,7 @@ from radixmesh_tpu.models.llama import (
 )
 from radixmesh_tpu.models import (
     bailing_hybrid, glm4_moe_lite, glm_moe_dsa, laguna, lfm2_moe, mellum2,
-    phi4flash, qwen2,
+    nemotron_h, phi4flash, qwen2,
 )
 
 _PRESETS = {
@@ -44,6 +44,9 @@ _PRESETS = {
     "ling-3.0-flash": bailing_hybrid.ling3_flash,
     "ling-3.0-flash-ep8": bailing_hybrid.ling3_flash_ep8,
     "ling-tiny": bailing_hybrid.ling_tiny,
+    "nemotron-3-nano-30b-a3b": nemotron_h.nemotron3_nano,
+    "nemotron-3-nano-ep8": nemotron_h.nemotron3_nano_ep8,
+    "nemotron-tiny": nemotron_h.nemotron_tiny,
 }
 
 

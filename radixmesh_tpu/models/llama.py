@@ -66,11 +66,19 @@ the serving stack's model side that the north star requires
   token completed a page. The latent layers beside them (``params["mla"]``:
   no query bottleneck, a head-wise output gate) own the pool's rows, and
   the layers run through ``_scan_mixed`` by their static kind.
+- **Blocks of one sublayer** (``models/nemotron_h.py``, ``ops/ssd.py``): a
+  config with "mamba2" layers names BLOCKS in ``layer_types`` — a Mamba-2
+  mixer ("mamba2"), plain GQA ("full_attention") or the expert FFN ("moe")
+  alone, ``x + f(norm(x))`` with one ``f`` and one pre-norm each — every
+  kind's leaves a stack of its own, through the same ``_scan_mixed`` and
+  the same three layer bodies (``_sublayers``). A Mamba-2 block carries a
+  float32 matrix a head in the state slots as a kda layer does.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any
@@ -272,6 +280,29 @@ class ModelConfig:
     # (``ops/moe.py::route``). 1: no groups.
     n_group: int = 1
     topk_group: int = 1
+    # Mamba-2 blocks (``layer_types`` entry "mamba2"; ``models/
+    # nemotron_h.py``, ``ops/ssd.py``): ``ssd_heads`` heads of
+    # ``ssd_head_dim`` channels, B and C of ``ssd_state`` shared by the heads
+    # of each of ``ssd_groups`` groups, behind a depth-wise convolution of
+    # ``ssd_conv`` taps with a bias. ``layer_types`` then names BLOCKS of
+    # one sublayer: "mamba2", "full_attention" (plain GQA; ``use_rope`` may
+    # be False) or "moe" (the expert FFN alone), a stack a kind. A mamba2
+    # block leaves no cache row: it carries ``[ssd_head_dim, ssd_state]``
+    # float32 a head and the last ``ssd_conv - 1`` inputs of its convolution
+    # (``state_shape`` / ``conv_state_shape``). ``mlp_gated`` False: experts
+    # (and the shared one, ``moe_shared_intermediate`` wide) without a gate
+    # matrix, ``W_down relu(W_up x)^2``.
+    ssd_heads: int = 0
+    ssd_head_dim: int = 0
+    ssd_groups: int = 0
+    ssd_state: int = 0
+    ssd_conv: int = 0
+    mlp_gated: bool = True
+    moe_shared_intermediate: int = 0
+    # Flags this config's programs need of the TPU compiler, for the entry
+    # point that starts the backend to put into ``LIBTPU_INIT_ARGS`` BEFORE
+    # it exists (``launch.py::_apply_libtpu_args``; libtpu reads them once).
+    libtpu_init_args: tuple = ()
 
     def __post_init__(self):
         if self.heads_by_type is not None:
@@ -292,17 +323,19 @@ class ModelConfig:
                 kinds <= {"sliding_attention", "full_attention"}
                 or kinds <= {"conv", "full_attention"}
                 or kinds <= {"kda", "full_attention"}
+                or kinds <= {"mamba2", "moe", "full_attention"}
             ):
                 raise ValueError(
                     f"layer_types must name {self.n_layers} layers as "
                     f"sliding_attention or full_attention, or as conv or "
-                    f"full_attention, or as kda or full_attention, got "
+                    f"full_attention, or as kda or full_attention, or (blocks "
+                    f"of one sublayer) as mamba2, moe or full_attention, got "
                     f"{len(self.layer_types)} of {sorted(kinds)}"
                 )
             if "sliding_attention" in kinds and self.sliding_window < 2:
                 raise ValueError("sliding layers need sliding_window >= 2")
             missing = kinds - {t for t, _ in self.rope_by_type or ()}
-            if missing and not kinds & {"conv", "kda"}:
+            if missing and not kinds & {"conv", "kda", "mamba2"}:
                 raise ValueError(f"rope_by_type lacks {sorted(missing)}")
         if self.has_conv:
             taps = self.conv_L_cache
@@ -340,6 +373,7 @@ class ModelConfig:
                     "pair_kv_heads or learned sparse attention")
         elif self.kda_heads or self.kda_conv:
             raise ValueError("kda_heads / kda_conv belong to a config with kda layers")
+        self._check_blocks()
         if self.pair_kv_heads and (
             self.latent or self.head_dim != 64 or self.n_kv_heads % 2
         ):
@@ -453,6 +487,41 @@ class ModelConfig:
                 raise ValueError(
                     f"{kind}: partial_rotary_factor must leave an even "
                     f"number of rotating dims within head_dim, got {rot}")
+
+    def _check_blocks(self) -> None:
+        """A config with mamba2 layers: blocks of one sublayer."""
+        if not self.has_mamba2:
+            if (self.ssd_heads or self.ssd_conv or not self.mlp_gated
+                    or self.moe_shared_intermediate or "moe" in (
+                        self.layer_types or ())):
+                raise ValueError(
+                    "ssd_heads / ssd_conv, moe blocks, un-gated experts "
+                    "(mlp_gated False) and moe_shared_intermediate belong to "
+                    "a config with mamba2 layers")
+            return
+        hp = 128 // max(self.ssd_head_dim, 1)
+        if not (self.ssd_heads > 0 and self.ssd_groups > 0 and self.ssd_conv >= 2
+                and self.ssd_state > 0 and self.ssd_state % 8 == 0
+                and 0 < self.ssd_head_dim <= 128 and 128 % self.ssd_head_dim == 0
+                and self.ssd_heads % self.ssd_groups == 0
+                and (self.ssd_heads // self.ssd_groups) % hp == 0):
+            raise ValueError(
+                "mamba2 layers need ssd_heads in ssd_groups equal groups, "
+                "ssd_conv >= 2, ssd_state in whole sublanes (a multiple of 8) "
+                "and a group's heads of ssd_head_dim filling whole 128-lane "
+                "rows (ops/ssd.py's state slot)")
+        if ("full_attention" not in self.layer_types
+                or "moe" not in self.layer_types or not self.n_experts
+                or self.first_k_dense or self.latent or self.qkv_bias
+                or self.rope_by_type or self.pair_kv_heads or self.dsa
+                or self.n_shared_experts > 1):
+            raise ValueError(
+                "mamba2 blocks go with plain GQA full_attention blocks (the "
+                "paged pool and the radix tree need one that owns rows) and "
+                "moe blocks of routed experts beside at most one shared "
+                "expert (moe_shared_intermediate): no first_k_dense, latent "
+                "attention, qkv_bias, rope_by_type, pair_kv_heads or learned "
+                "sparse attention")
 
     def _check_hybrid(self) -> None:
         """A config with mamba / gmu / cross_attention layers runs as one
@@ -575,6 +644,44 @@ class ModelConfig:
         return -(-(self.kda_conv - 1) * 3 * self.kda_inner // 128)
 
     @property
+    def has_mamba2(self) -> bool:
+        return bool(self.layer_types) and "mamba2" in self.layer_types
+
+    @property
+    def mamba2_layers(self) -> tuple[int, ...]:
+        """The Mamba-2 blocks (each carries a state)."""
+        return self._layers_of("mamba2")
+
+    @property
+    def ssd_inner(self) -> int:
+        """Channels of a mamba2 block's input ``u`` and gate ``z``."""
+        return self.ssd_heads * self.ssd_head_dim
+
+    @property
+    def ssd_conv_channels(self) -> int:
+        """Channels of a mamba2 block's convolution: ``u``, ``B`` and ``C``."""
+        return self.ssd_inner + 2 * self.ssd_groups * self.ssd_state
+
+    @property
+    def ssd_conv_rows(self) -> int:
+        """128-lane rows of a mamba2 block's convolution inputs in a state
+        slot (zeros up to a whole row)."""
+        return -(-(self.ssd_conv - 1) * self.ssd_conv_channels // 128)
+
+    @property
+    def matrix_state(self) -> str | None:
+        """The kind of a config whose layers carry a float32 MATRIX a head
+        in the state slots — megabytes a snapshot, read and written inside
+        the layer, copied at page boundaries, evicted in order — or None:
+        "kda" (delta-rule linear attention) or "ssd" (Mamba-2)."""
+        return "kda" if self.has_kda else "ssd" if self.has_mamba2 else None
+
+    @property
+    def own_stacks(self) -> bool:
+        """Do the operators' leaves lie in a stack a kind (``_scan_mixed``)?"""
+        return bool(self.has_conv or self.heads_by_type or self.matrix_state)
+
+    @property
     def conv_layers(self) -> tuple[int, ...]:
         """The gated short-convolution layers (each carries a state)."""
         return tuple(i for i, t in enumerate(self.layer_types or ())
@@ -584,9 +691,9 @@ class ModelConfig:
     def attn_layers(self) -> tuple[int, ...]:
         """The layers that own cache rows: all of them, or, beside conv
         layers, the attention layers alone."""
-        if self.window_layers or self.has_kda:
+        if self.window_layers or self.matrix_state:
             # the full layers: the window layers' rows are another group,
-            # a kda layer leaves none
+            # a kda layer or a mamba2 / moe block leaves none
             return self._layers_of("full_attention")
         if not self.has_conv:
             return tuple(range(self.n_layers))
@@ -678,6 +785,8 @@ class ModelConfig:
         snapshot = one slot of both arrays)."""
         if self.has_kda:
             return (len(self.kda_layers), num_states, self.kda_conv_rows, 128)
+        if self.has_mamba2:
+            return (len(self.mamba2_layers), num_states, self.ssd_conv_rows, 128)
         if not self.has_ssm:
             return None
         rows = (self.ssm_conv - 1) * self.ssm_inner // 128
@@ -693,6 +802,10 @@ class ModelConfig:
             per = (self.kda_inner * self.kda_head_dim * 4
                    + self.kda_conv_rows * 128 * item)
             return len(self.kda_layers) * per
+        if self.has_mamba2:
+            per = (self.ssd_inner * self.ssd_state * 4
+                   + self.ssd_conv_rows * 128 * item)
+            return len(self.mamba2_layers) * per
         if self.has_conv:
             return (len(self.conv_layers) * (self.conv_L_cache - 1)
                     * self.hidden * item)
@@ -711,10 +824,16 @@ class ModelConfig:
         layers: every head's matrix, ``[kda layers, slots, kda_heads *
         kda_head_dim, kda_head_dim]`` FLOAT32 — key channels down, value
         channels across, 2 MiB a layer and slot at 32 heads of 128
-        (``ops/kda.py``). None for a config with none of them."""
+        (``ops/kda.py``). A config with mamba2 blocks: ``[mamba2 blocks,
+        slots, ssd_inner / 128 * ssd_state, 128]`` FLOAT32 (``ops/ssd.py::
+        pack_state``), 2 MiB a block and slot at 64 heads of 64 x 128. None
+        for a config with none of them."""
         if self.has_kda:
             return (len(self.kda_layers), num_states, self.kda_inner,
                     self.kda_head_dim)
+        if self.has_mamba2:
+            return (len(self.mamba2_layers), num_states,
+                    self.ssd_inner // 128 * self.ssd_state, 128)
         if self.has_ssm:
             return (len(self.mamba_layers), num_states, self.ssm_state,
                     self.ssm_inner)
@@ -725,7 +844,7 @@ class ModelConfig:
 
     @property
     def state_dtype(self):
-        return jnp.float32 if self.has_ssm or self.has_kda else self.dtype
+        return jnp.float32 if self.has_ssm or self.matrix_state else self.dtype
 
     def default_state_slots(self, num_slots: int, max_batch: int) -> int:
         """How many state slots go with a pool of ``num_slots`` rows,
@@ -744,9 +863,10 @@ class ModelConfig:
         from, and one for the shared preambles and the churn between
         them). A 12.4 MiB snapshot (kda layers' matrices) beside a latent
         row of 1 280 B a token is the same case, further out: the quarter
-        of 524 288 rows buys 12, and the five a batch row hold. 0 for a
-        config whose layers carry no state."""
-        if not (self.has_conv or self.has_ssm or self.has_kda):
+        of 524 288 rows buys 12, and the five a batch row hold (20.4 MiB of
+        mamba2 blocks beside 3 KiB a token: the same). 0 for a config whose
+        layers carry no state."""
+        if not (self.has_conv or self.has_ssm or self.matrix_state):
             return 0
         want = -(-(1 + 2 * max_batch + num_slots // 100) // 256) * 256
         planes, heads, width = self.kv_row
@@ -912,9 +1032,11 @@ def _attn_dims(cfg: ModelConfig, n_heads: int | None = None) -> dict:
     return dims
 
 
-def _ffn_dims(hidden: int, width: int, prefix: str = "w_") -> dict:
-    return {prefix + "gate": (hidden, width), prefix + "up": (hidden, width),
-            prefix + "down": (width, hidden)}
+def _ffn_dims(hidden: int, width: int, prefix: str = "w_",
+              gated: bool = True) -> dict:
+    gate = {prefix + "gate": (hidden, width)} if gated else {}
+    return gate | {prefix + "up": (hidden, width),
+                   prefix + "down": (width, hidden)}
 
 
 def _draw_stack(keys, n: int, dims: dict, cfg: ModelConfig, quant_axis) -> dict:
@@ -1001,6 +1123,69 @@ def _draw_kda(key, cfg: ModelConfig) -> dict:
     return {"kda": op, "mla": mla}
 
 
+def _draw_blocks_of_one(key, cfg: ModelConfig) -> dict:
+    """The pytree of a config with mamba2 layers (blocks of one sublayer)
+    but its embedding and head: ``layers`` — every block's ONE pre-norm
+    (``attn_norm``, whatever the block) — and a stack a kind: ``mamba2``
+    (``in_proj`` [H, di + (di + 2 G N) + heads]: the gate z, the
+    convolution's u | B | C, dt; ``conv_w`` [taps, di + 2 G N], tap 0 the
+    oldest, ``conv_b``; ``dt_bias`` the inverse softplus of a log-uniform
+    [1e-3, 1e-1] floored at 1e-4, ``A_log = log(uniform[1, 16])``, ``D``
+    around 1 (the family's initialisation: decays from about 0.2 to 0.999 a
+    token); the gated norm's weight ``ssd_norm`` [di]; ``out_proj``),
+    ``attn`` (``wq`` / ``wk`` / ``wv`` / ``wo``) and ``moe`` (``w_router``,
+    ``router_bias``, the held experts' ``w_up`` / ``w_down`` [n, E_held, .,
+    .], their width stored in whole 128-lane rows, and the shared expert's
+    ``ws_up`` / ``ws_down`` — plus the gates under ``mlp_gated``)."""
+    H, di, nh = cfg.hidden, cfg.ssd_inner, cfg.ssd_heads
+    n_m, n_a = len(cfg.mamba2_layers), len(cfg.attn_layers)
+    n_e, E = cfg.layer_types.count("moe"), cfg.held_experts
+    f32 = jnp.float32
+    keys = iter(jax.random.split(key, 32))
+
+    def mats(n: int, dims: dict) -> dict:
+        return {name: _draw_blocks(next(keys), n, shape, shape[0], cfg.dtype, None)[0]
+                for name, shape in dims.items()}
+
+    def near(shape, centre: float, spread: float, dtype=cfg.dtype):
+        return (centre + spread * jax.random.normal(next(keys), shape, f32)
+                ).astype(dtype)
+
+    m2 = mats(n_m, {"in_proj": (H, di + cfg.ssd_conv_channels + nh),
+                    "out_proj": (di, H)})
+    m2["conv_w"], _ = _draw_blocks(
+        next(keys), n_m, (cfg.ssd_conv, cfg.ssd_conv_channels), cfg.ssd_conv,
+        cfg.dtype, None)
+    m2["conv_b"] = near((n_m, cfg.ssd_conv_channels), 0.0, 0.02)
+    dt = jnp.maximum(jnp.exp(
+        jax.random.uniform(next(keys), (n_m, nh), f32)
+        * (np.log(1e-1) - np.log(1e-3)) + np.log(1e-3)), 1e-4)
+    m2["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+    m2["A_log"] = jnp.log(jax.random.uniform(next(keys), (n_m, nh), f32, 1.0, 16.0))
+    m2["D"] = near((n_m, nh), 1.0, 0.1, f32)
+    m2["ssd_norm"] = near((n_m, di), 1.0, 0.1)
+    moe = mats(n_e, {"w_router": (H, cfg.n_experts)} | _ffn_dims(
+        H, cfg.moe_shared_intermediate, "ws_", cfg.mlp_gated))
+    # The routed experts are STORED in whole 128-lane rows (1856 -> 1920):
+    # zero columns of w_up / rows of w_down, exact (relu(0)^2 = 0, silu(0) *
+    # 0 = 0). At the published width the grouped product's operand is
+    # relaid by a copy of the whole stack every launch (1.5 GiB: the
+    # compile-only tool, benchmarks/tools/aot_fit_ssd.py).
+    F = cfg.moe_intermediate
+    pad = -F % 128
+    for name, (i_dim, o_dim) in _ffn_dims(H, F, gated=cfg.mlp_gated).items():
+        w, _ = _draw_blocks(next(keys), n_e * E, (i_dim, o_dim), i_dim, cfg.dtype, None)
+        w = jnp.pad(w, ((0, 0), (0, pad), (0, 0)) if i_dim == F
+                    else ((0, 0), (0, 0), (0, pad)))
+        moe[name] = w.reshape(n_e, E, *w.shape[1:])
+    moe["router_bias"] = near((n_e, cfg.n_experts), 0.0, 0.02, f32)
+    return {
+        "layers": {"attn_norm": jnp.ones((cfg.n_layers, H), dtype=cfg.dtype)},
+        "mamba2": m2, "attn": mats(n_a, _attn_dims(cfg)), "moe": moe,
+        "final_norm": jnp.ones((H,), dtype=cfg.dtype),
+    }
+
+
 def _draw_kinds(key, cfg: ModelConfig) -> dict:
     """The attention matrices of a config with ``heads_by_type``: a stack a
     kind, ``{layer type: {wq, wk, wv, wo[, wg]}}`` over that kind's layers
@@ -1031,6 +1216,9 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         n_blk = _vocab_blocks(V, H, H * cfg.intermediate)
         w, _ = _draw_blocks(keys[7], n_blk, (V // n_blk, H), H, cfg.dtype, None)
         return params | {"embed": w.reshape(V, H)}
+    if cfg.has_mamba2:
+        params = _draw_blocks_of_one(jax.random.fold_in(key, 109), cfg)
+        return _draw_vocab(params, cfg, keys, axis, mesh, cfg.moe_shared_intermediate)
     L = cfg.n_layers - cfg.first_k_dense  # layers of the main stack
     ffn_width = cfg.moe_intermediate if cfg.n_experts else cfg.intermediate
     ffn = _ffn_dims(H, ffn_width)
@@ -1106,6 +1294,14 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         params |= _draw_kinds(jax.random.fold_in(key, 107), cfg)
     if cfg.has_kda:
         params |= _draw_kda(jax.random.fold_in(key, 108), cfg)
+    return _draw_vocab(params, cfg, keys, axis, mesh, ffn_width)
+
+
+def _draw_vocab(params: dict, cfg: ModelConfig, keys, axis, mesh,
+                ffn_width: int) -> dict:
+    """``_init_params``' tail: the embedding and the head, drawn in blocks no
+    larger than a layer's largest slab, and the mesh's constraint."""
+    H, V = cfg.hidden, cfg.vocab_size
     qd = cfg.n_heads * cfg.head_dim
     n_blk = _vocab_blocks(V, H, H * max(ffn_width, qd))
     # [V, H] in row blocks; per-ROW scales serve the gather and the tied
@@ -1257,6 +1453,25 @@ def param_logical_axes(cfg: ModelConfig, params: dict | None = None) -> dict:
             "conv_w": ("layer", None, "q_heads"), "A_log": ("layer", "q_heads"),
             "dt_bias": ("layer", "q_heads"), "o_norm": ("layer", "q_heads"),
         }
+    if cfg.has_mamba2:
+        # Engine refuses a mesh for such a config; the names say what a tp
+        # share would split (a mamba2 block by head: one group a chip).
+        by_head = ("layer", "embed", "q_heads")
+        axes["layers"] = {"attn_norm": ("layer", "embed")}
+        axes["attn"] = {"wq": by_head, "wk": ("layer", "embed", "kv_heads"),
+                        "wv": ("layer", "embed", "kv_heads"),
+                        "wo": ("layer", "q_heads", "embed")}
+        axes["mamba2"] = {
+            "in_proj": by_head, "out_proj": ("layer", "q_heads", "embed"),
+            "conv_w": ("layer", None, "q_heads"), "conv_b": ("layer", "q_heads"),
+            "dt_bias": ("layer", "q_heads"), "A_log": ("layer", "q_heads"),
+            "D": ("layer", "q_heads"), "ssd_norm": ("layer", "q_heads")}
+        axes["moe"] = {
+            "w_router": ("layer", "embed", "expert"),
+            "router_bias": ("layer", "expert"),
+            "w_up": ("layer", "expert", "embed", "ffn"),
+            "w_down": ("layer", "expert", "ffn", "embed"),
+            "ws_up": ("layer", "embed", "ffn"), "ws_down": ("layer", "ffn", "embed")}
     if cfg.qkv_bias:
         axes["layers"]["bq"] = ("layer", "q_heads")
         axes["layers"]["bk"] = ("layer", "kv_heads")
@@ -1433,12 +1648,17 @@ def _attn_in(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions, inv_freq,
     if cfg.latent:
         return *_latent_qkv(lp, x, cfg, positions, inv_freq, c_q), None
     q, k, v = _qkv(lp, x, cfg)
+    if not cfg.use_rope:  # no positional encoding
+        return q, k, v
     q = apply_rope(q, positions, inv_freq, rope_scale)
     k = apply_rope(k, positions, inv_freq, rope_scale)
     return q, k, v
 
 
 def _mlp(lp: dict, x: jnp.ndarray, prefix: str = "w_") -> jnp.ndarray:
+    if prefix + "gate" not in lp:  # un-gated: relu(up)^2
+        up = _wmm(lp, prefix + "up", "bsh,hi->bsi", x)
+        return _wmm(lp, prefix + "down", "bsi,ih->bsh", jnp.square(jax.nn.relu(up)))
     gate = jax.nn.silu(_wmm(lp, prefix + "gate", "bsh,hi->bsi", x))
     up = _wmm(lp, prefix + "up", "bsh,hi->bsi", x)
     return _wmm(lp, prefix + "down", "bsi,ih->bsh", gate * up)
@@ -1513,23 +1733,65 @@ def _short_conv(lp: dict, h: jnp.ndarray, state: jnp.ndarray, cfg: ModelConfig,
     return y, states
 
 
-def _kda_conv_store(cfg: ModelConfig, conv: jnp.ndarray) -> jnp.ndarray:
-    """A kda layer's convolution inputs ``[..., kda_conv - 1, 3 *
-    kda_inner]`` as a state slot holds them: ``[..., rows, 128]``, zeros
-    up to a whole row."""
+def _conv_store(conv: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """A kda or mamba2 layer's convolution inputs ``[..., taps - 1,
+    channels]`` as a state slot holds them: ``[..., rows, 128]``, zeros up
+    to a whole row."""
     flat = conv.reshape(*conv.shape[:-2], -1)
-    pad = cfg.kda_conv_rows * 128 - flat.shape[-1]
+    pad = rows * 128 - flat.shape[-1]
     if pad:
         flat = jnp.concatenate(
             [flat, jnp.zeros((*flat.shape[:-1], pad), flat.dtype)], axis=-1)
-    return flat.reshape(*flat.shape[:-1], cfg.kda_conv_rows, 128)
+    return flat.reshape(*flat.shape[:-1], rows, 128)
+
+
+def _conv_load(stored: jnp.ndarray, positions: int, channels: int) -> jnp.ndarray:
+    """``_conv_store``'s inverse."""
+    flat = stored.reshape(*stored.shape[:-2], -1)[..., :positions * channels]
+    return flat.reshape(*flat.shape[:-1], positions, channels)
+
+
+def _kda_conv_store(cfg: ModelConfig, conv: jnp.ndarray) -> jnp.ndarray:
+    return _conv_store(conv, cfg.kda_conv_rows)
 
 
 def _kda_conv_load(cfg: ModelConfig, stored: jnp.ndarray) -> jnp.ndarray:
-    """``_kda_conv_store``'s inverse."""
-    n = (cfg.kda_conv - 1) * 3 * cfg.kda_inner
-    flat = stored.reshape(*stored.shape[:-2], -1)[..., :n]
-    return flat.reshape(*flat.shape[:-1], cfg.kda_conv - 1, 3 * cfg.kda_inner)
+    return _conv_load(stored, cfg.kda_conv - 1, 3 * cfg.kda_inner)
+
+
+def _ssd_conv_load(cfg: ModelConfig, stored: jnp.ndarray) -> jnp.ndarray:
+    return _conv_load(stored, cfg.ssd_conv - 1, cfg.ssd_conv_channels)
+
+
+def _mamba2(lp: dict, h: jnp.ndarray, conv0: jnp.ndarray, cfg: ModelConfig,
+            snap_at: jnp.ndarray | None, run) -> tuple:
+    """The Mamba-2 mixer on its normed input ``h`` [B, S, H] from the
+    ``ssd_conv - 1`` inputs of its convolution before the first position
+    (``conv0`` [B, taps - 1, channels]): ``[z | xBC | dt] = h W_in``; ``xBC
+    = SiLU(conv(xBC) + b)``; ``Delta = softplus(dt + dt_bias)``, the
+    log-decay ``g = -exp(A_log) Delta`` a head; ``y, extra = run(Delta u, g,
+    B, C)`` is the recurrence through the state slots (``ops/ssd.py``); then
+    the skip ``D u``, the gate FIRST and an RMS norm inside each group, and
+    ``W_out``. Returns ``(f [B, S, H], the convolution's inputs before
+    snap_at [B, K, taps - 1, channels], extra)``."""
+    B, S = h.shape[:2]
+    nh, P, G, N = cfg.ssd_heads, cfg.ssd_head_dim, cfg.ssd_groups, cfg.ssd_state
+    di, f32 = cfg.ssd_inner, jnp.float32
+    with jax.named_scope("rm_ssd"):
+        zxd = _wmm(lp, "in_proj", "bsh,hd->bsd", h)
+        xbc, conv = kda.short_conv(
+            zxd[..., di:di + cfg.ssd_conv_channels], lp["conv_w"], conv0,
+            snap_at, bias=lp["conv_b"])
+        u = xbc[..., :di].reshape(B, S, nh, P)
+        bm, cm = (t.reshape(B, S, G, N)
+                  for t in jnp.split(xbc[..., di:], 2, axis=-1))
+        delta = jax.nn.softplus(zxd[..., -nh:].astype(f32) + lp["dt_bias"])
+        y, extra = run(delta[..., None] * u, -jnp.exp(lp["A_log"]) * delta, bm, cm)
+        y = (y + lp["D"][:, None] * u).reshape(B, S, G, di // G)
+        y = y * jax.nn.silu(zxd[..., :di].astype(f32)).reshape(y.shape)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_eps)
+        y = y.reshape(B, S, di) * lp["ssd_norm"].astype(f32)
+        return _wmm(lp, "out_proj", "bsk,kh->bsh", y.astype(h.dtype)), conv, extra
 
 
 def _kda_in(lp: dict, h: jnp.ndarray, conv0: jnp.ndarray, cfg: ModelConfig,
@@ -2055,7 +2317,7 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
     dense layer's arithmetic. ``options`` are ``Engine``'s keyword names
     (a falsy value is "not asked for")."""
     if not (cfg.n_experts or cfg.has_window or cfg.latent or cfg.has_conv
-            or cfg.has_ssm or cfg.has_kda):
+            or cfg.has_ssm or cfg.matrix_state):
         return None
     mesh = options.get("device_mesh")
     named = {
@@ -2068,7 +2330,7 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
         "disaggregated": "disaggregated prefill/decode (engine/disagg.py)",
         "hf_checkpoint": "loading a HuggingFace checkpoint (--weights)",
     }
-    if cfg.has_conv or cfg.window_layers or cfg.has_kda:
+    if cfg.has_conv or cfg.window_layers or cfg.matrix_state:
         # A prefix is handed back only where its state (or its window tail)
         # was snapshotted: whatever moves or advertises a prefix without it.
         named |= {
@@ -2094,6 +2356,10 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
                          cfg.has_ssm),
                         ("linear-attention (kda) layers with a recurrent "
                          "matrix state", cfg.has_kda),
+                        ("Mamba-2 (ssd) layers with a recurrent matrix state "
+                         "in blocks of one sublayer (a mixer or an FFN alone)",
+                         cfg.has_mamba2),
+                        ("un-gated (relu2) experts", not cfg.mlp_gated),
                         ("a cross-decoder that reads one layer's K/V",
                          cfg.has_ssm),
                         ("attention kinds of two shapes in stacks of their "
@@ -2182,7 +2448,7 @@ def _layer_tables(cfg: ModelConfig) -> dict | None:
             "dsa_full": jnp.asarray(full),
             "dsa_slot": jnp.asarray(np.cumsum(full) - 1, jnp.int32),
         }
-    if cfg.layer_types is None or cfg.has_conv or cfg.has_kda:
+    if cfg.layer_types is None or cfg.has_conv or cfg.matrix_state:
         return tables or None
     by_type = {kind: _type_rope(cfg, kind)[:2] for kind, _ in cfg.rope_by_type}
     return tables | {
@@ -2270,7 +2536,7 @@ def _scan_layers(cfg: ModelConfig, params: dict, layer, carry):
     the rest. Returns ``(carry, ys)``; ``ys`` of two scans are joined
     along the layer axis, but for what only the sparse layers give (the
     dense scan's None)."""
-    if cfg.has_conv or cfg.heads_by_type or cfg.has_kda:
+    if cfg.own_stacks:
         return _scan_mixed(cfg, params, layer, carry)
     kd = cfg.first_k_dense
     stacks = [(0, params["dense_layers"], False)] if kd else []
@@ -2321,6 +2587,8 @@ def _layer_kinds(cfg: ModelConfig) -> list[str]:
         return ["conv" if t == "conv" else "attn" for t in cfg.layer_types]
     if cfg.has_kda:
         return ["kda" if t == "kda" else "mla" for t in cfg.layer_types]
+    if cfg.has_mamba2:  # blocks: a mixer or the expert FFN alone
+        return ["attn" if t == "full_attention" else t for t in cfg.layer_types]
     return list(cfg.layer_types)
 
 
@@ -2359,7 +2627,8 @@ def _scan_mixed(cfg: ModelConfig, params: dict, layer, carry):
             dense = l0 < kd
             lp = at(params["dense_layers"], l_idx) if dense else at(
                 per_layer, l_idx - kd)
-            lp = lp | at(params[kind], op_idx)
+            # (a moe block's expert weights stay stacked: ``_scanned_layers``)
+            lp = lp | at(_scanned_layers(params[kind]), op_idx)
             carry, y = layer(
                 carry, (l_idx, lp, {"op_idx": op_idx}),
                 sparse=bool(cfg.n_experts) and not dense, kind=kind)
@@ -2414,9 +2683,9 @@ def _ffn(params: dict, lp: dict, l_idx, x: jnp.ndarray, cfg: ModelConfig,
     if not sparse:
         return _mlp(lp, x), None
     B, S, H = x.shape
-    stacked = params["layers"]
+    stacked = params["moe" if cfg.has_mamba2 else "layers"]
     y, ids, stats = moe_ffn(
-        x.reshape(B * S, H), lp["w_router"], stacked["w_gate"],
+        x.reshape(B * S, H), lp["w_router"], stacked.get("w_gate"),
         stacked["w_up"], stacked["w_down"],
         layer=l_idx - cfg.first_k_dense,  # its place in the sparse stack
         k=cfg.n_experts_per_tok, norm_topk=cfg.norm_topk_prob,
@@ -2431,6 +2700,29 @@ def _ffn(params: dict, lp: dict, l_idx, x: jnp.ndarray, cfg: ModelConfig,
     if cfg.n_shared_experts:  # every token takes it: no assignment
         y = y + _mlp(lp, x, "ws_")
     return y, (ids.reshape(B, S, -1), stats)
+
+
+def _sublayers(params: dict, lp: dict, xs, x, h, op, cfg: ModelConfig,
+               site: str, valid, sparse: bool, kind: str, **moe_kw) -> tuple:
+    """What follows a block's operator ``op`` in the three ``mixed_layer``s:
+    the residual, then the FFN behind its own pre-norm — or, in a config
+    whose blocks hold ONE sublayer (mamba2 layers), a mixer's residual
+    alone, and for a "moe" block (``op`` None) the expert FFN alone on the
+    block's one pre-norm ``h``, at its place in the moe stack. Returns
+    ``(x, aux)``."""
+    l_idx, _, table = xs
+    if cfg.has_mamba2:
+        if kind != "moe":
+            return x + op, None
+        # (beside ``radixmesh_ssd_chunk``, whose row slices the compiler
+        # keeps in VMEM, the grouped product asks for none of its own)
+        y, aux = _ffn(params, lp, table["op_idx"], h, cfg, site, valid, True,
+                      fit_vmem=True, **moe_kw)
+        return x + y, aux
+    x = x + op
+    h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+    y, aux = _ffn(params, lp, l_idx, h2, cfg, site, valid, sparse, **moe_kw)
+    return x + y, aux
 
 
 def _moe_aux(cfg: ModelConfig, per_layer, moe_stats, site: str,
@@ -2555,7 +2847,7 @@ def prefill_forward(
         return _hybrid_prefill(params, cfg, tokens, positions, new_lengths, snap_at)
     one_rope = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     x = _embed_lookup(params, tokens)
-    if (cfg.has_conv or cfg.heads_by_type or cfg.has_kda) and p_max:
+    if cfg.own_stacks and p_max:
         raise ValueError(
             "prefill_forward takes no cached prefix for a model with conv or "
             "kda layers or a window group (a prefix resumes from a state slot "
@@ -2614,6 +2906,24 @@ def prefill_forward(
             k = v = None
             states = (snaps[0, 1:].reshape(*snap_at.shape, *snaps.shape[2:]),
                       _kda_conv_store(cfg, conv))
+        elif kind == "mamba2":
+            # as a kda layer: from a zero state, the states go out
+            from radixmesh_tpu.ops import ssd  # (only such a config's import)
+            B, n = x.shape[0], snap_at.size
+
+            def run(*seqs):
+                y, snaps = ssd.ssd_chunk(
+                    *seqs, jnp.zeros((1, 1 + n, *cfg.state_shape(1)[2:]), jnp.float32),
+                    0, jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.int32),
+                    snap_at, 1 + jnp.arange(n, dtype=jnp.int32).reshape(snap_at.shape))
+                return y, snaps[0, 1:].reshape(*snap_at.shape, *snaps.shape[2:])
+
+            op, conv, snaps = _mamba2(
+                lp, h, jnp.zeros((B, cfg.ssd_conv - 1, cfg.ssd_conv_channels), x.dtype),
+                cfg, snap_at, run)
+            states = (snaps, _conv_store(conv, cfg.ssd_conv_rows))
+        elif kind == "moe":
+            op = None
         elif kind == "mla":
             q, k, v = _attn_in(lp, h, cfg, positions, one_rope, None)
             op = _kind_attn_out(lp, latent_prefill_attention(
@@ -2634,13 +2944,12 @@ def prefill_forward(
                 q, k, v, attn_pos, kv_end, kv_start=pad), cfg)
             if cfg.pair_kv_heads:  # as the pool stores them
                 _, k, v = _pair_heads(cfg, q, k, v)
-        x = x + op
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse)
-        return (x + y, mask), {"k": k, "v": v, "aux": aux, "state": states,
-                               "wk": wk, "wv": wv}
+        x, aux = _sublayers(params, lp, xs, x, h, op, cfg, "prefill", valid,
+                            sparse, kind)
+        return (x, mask), {"k": k, "v": v, "aux": aux, "state": states,
+                           "wk": wk, "wv": wv}
 
-    if cfg.has_conv or cfg.heads_by_type or cfg.has_kda:
+    if cfg.own_stacks:
         (x, _), ys = _scan_layers(cfg, params, mixed_layer, (x, mask0))
         groups = (ys["wk"], ys["wv"]) if cfg.heads_by_type else (ys["state"],)
         return (_logits(params, cfg, x), ys["k"], ys.get("v"), *groups,
@@ -2911,6 +3220,23 @@ def prefill_chunk_paged(
             conv = _kda_conv_store(cfg, conv).astype(c_pool.dtype)
             sel = (s_pool, c_pool.at[i, state_out.reshape(-1)].set(
                 conv.reshape(-1, *conv.shape[2:])))
+        elif kind == "mamba2":
+            # as a kda layer: the slots are read and written HERE
+            from radixmesh_tpu.ops import ssd
+            s_pool, c_pool = sel
+            i = table["op_idx"]
+            fresh = positions[:, 0] == 0
+            op, conv, s_pool = _mamba2(
+                lp, h, jnp.where(fresh[:, None, None], 0,
+                                 _ssd_conv_load(cfg, c_pool[i, state_in])),
+                cfg, snap_at, lambda *seqs: ssd.ssd_chunk(
+                    *seqs, s_pool, i, state_in, fresh, snap_at, state_out,
+                    use_kernel=use_kernel, interpret=interpret))
+            conv = _conv_store(conv, cfg.ssd_conv_rows).astype(c_pool.dtype)
+            sel = (s_pool, c_pool.at[i, state_out.reshape(-1)].set(
+                conv.reshape(-1, *conv.shape[2:])))
+        elif kind == "moe":
+            op = None
         elif kind == "mla":
             q, k, _ = _attn_in(lp, h, cfg, positions, one_rope, None)
             k = k.astype(kv_pool.dtype)  # attend what the pool will hold
@@ -2950,12 +3276,10 @@ def prefill_chunk_paged(
             if cfg.pair_kv_heads:
                 attn = _own_lanes(cfg, attn)
             op = _attn_out(lp, attn, cfg)
-        x = x + op
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse,
-                      **moe_kw)
-        return (x + y, sel), {"k": k, "v": v, "aux": aux, "state": states,
-                              "wk": wk, "wv": wv}
+        x, aux = _sublayers(params, lp, xs, x, h, op, cfg, "prefill", valid,
+                            sparse, kind, **moe_kw)
+        return (x, sel), {"k": k, "v": v, "aux": aux, "state": states,
+                          "wk": wk, "wv": wv}
 
     def layer(carry, xs, sparse):
         x, sel = carry
@@ -3072,11 +3396,12 @@ def prefill_chunk_paged(
               ALL_LAYERS)], window_slots, page_size)
         return (_logits(params, cfg, pick(x)), kv_pool, window_pool,
                 *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
-    if cfg.has_kda:
+    if cfg.matrix_state:
         (x, state_pool), ys = _scan_layers(
             cfg, params, mixed_layer, (x, tuple(state_pool)))
+        new_kv = ys["k"][None] if cfg.latent else jnp.stack([ys["k"], ys["v"]])
         kv_pool, = write_runs_all(
-            [(kv_pool, ys["k"][None].transpose(0, 1, 4, 2, 3, 5), ALL_LAYERS)],
+            [(kv_pool, new_kv.transpose(0, 1, 4, 2, 3, 5), ALL_LAYERS)],
             slots, page_size)
         return (_logits(params, cfg, pick(x)), kv_pool, state_pool,
                 *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
@@ -3247,6 +3572,21 @@ def _decode_core(
             window_pool = (s_pool, c_pool.at[i, work].set(
                 _kda_conv_store(cfg, conv[:, 0]).astype(c_pool.dtype)))
             op = _kda_out(lp, o[:, None], h, cfg)
+        elif kind == "mamba2":
+            from radixmesh_tpu.ops import ssd
+            s_pool, c_pool = window_pool
+            i, work = table["op_idx"], state_slots[0]
+
+            def run(*seqs):
+                y, pool = ssd.ssd_decode(*(t[:, 0] for t in seqs), s_pool, i, work)
+                return y[:, None], pool
+
+            op, conv, s_pool = _mamba2(
+                lp, h, _ssd_conv_load(cfg, c_pool[i, work]), cfg, None, run)
+            window_pool = (s_pool, c_pool.at[i, work].set(_conv_store(
+                conv[:, 0], cfg.ssd_conv_rows).astype(c_pool.dtype)))
+        elif kind == "moe":
+            op = None
         elif kind == "mla":
             q, k, _ = _attn_in(lp, h, cfg, positions[:, None], one_rope, None)
             attn, pages = latent_decode_attention(
@@ -3282,27 +3622,29 @@ def _decode_core(
             if cfg.pair_kv_heads:
                 q, k, v = _pair_heads(cfg, q, k, v)
                 scale = cfg.head_dim ** -0.5
-            attn, kv_pages = paged_decode_attention(
-                q[:, 0], k[:, 0].astype(kv_pool.dtype),
-                v[:, 0].astype(kv_pool.dtype), kv_pool.reshape(pages_shape),
-                slots, page_table, lengths, table["op_idx"], scale=scale,
-            )
+            # (a trace tells a one-sublayer config's blocks apart by scope)
+            with (jax.named_scope("rm_full_attn") if cfg.has_mamba2
+                  else contextlib.nullcontext()):
+                attn, kv_pages = paged_decode_attention(
+                    q[:, 0], k[:, 0].astype(kv_pool.dtype),
+                    v[:, 0].astype(kv_pool.dtype), kv_pool.reshape(pages_shape),
+                    slots, page_table, lengths, table["op_idx"], scale=scale,
+                )
             kv_pool = kv_pages.reshape(pool_shape)
             attn = attn[:, None]
             if cfg.pair_kv_heads:
                 attn = _own_lanes(cfg, attn)
             op = _attn_out(lp, attn, cfg)
-        x = x + op
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        y, aux = _ffn(params, lp, l_idx, h2, cfg, "decode", live, sparse)
-        return (x + y, kv_pool, window_pool), {"aux": aux, "state": states}
+        x, aux = _sublayers(params, lp, xs, x, h, op, cfg, "decode", live,
+                            sparse, kind)
+        return (x, kv_pool, window_pool), {"aux": aux, "state": states}
 
     if cfg.heads_by_type:
         (x, kv_pool, window_pool), ys = _scan_layers(
             cfg, params, mixed_layer, (x, kv_pool, window_pool))
         return (_logits(params, cfg, x)[:, 0], kv_pool, window_pool,
                 *_moe_aux(cfg, ys.get("aux"), moe_stats, "decode"))
-    if cfg.has_kda:
+    if cfg.matrix_state:
         (x, kv_pool, state_pool), ys = _scan_layers(
             cfg, params, mixed_layer, (x, kv_pool, tuple(state_pool)))
         # The boundary copies: the rows whose token completed a page keep

@@ -702,9 +702,10 @@ def copy_slots(pool, src, dst, use_kernel: bool | None = None,
 # ---------------------------------------------------------------------------
 
 
-def short_conv(x, w, state, snap_at):
-    """Depth-wise causal convolution and SiLU: ``x`` [B, T, C], ``w``
-    [taps, C] (tap 0 the oldest position), ``state`` [B, taps - 1, C] the
+def short_conv(x, w, state, snap_at, bias=None):
+    """Depth-wise causal convolution (plus ``bias`` [C], if any) and SiLU:
+    ``x`` [B, T, C], ``w`` [taps, C] (tap 0 the oldest position), ``state``
+    [B, taps - 1, C] the
     inputs before the first -> ``(silu(conv) [B, T, C] float32, states [B,
     K, taps - 1, C])``: the inputs before position ``snap_at`` [B, K] of
     these ``T`` (0 hands back ``state``). ``snap_at`` None: the one state
@@ -714,6 +715,8 @@ def short_conv(x, w, state, snap_at):
         xs = jnp.concatenate([state.astype(x.dtype), x], axis=1)
         w = w.astype(_F32)
         y = sum(w[j] * xs[:, j:j + T].astype(_F32) for j in range(taps))
+        if bias is not None:
+            y = y + bias.astype(_F32)
         y = jax.nn.silu(y)
         if snap_at is None:
             return y, xs[:, None, T:]

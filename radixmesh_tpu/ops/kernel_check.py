@@ -85,6 +85,18 @@ TOLERANCE = 2e-2
 KDA_CASES = ("kda_chunk", "kda_decode", "kda_boundary")
 KDA_TOLERANCE = 1e-4
 
+# The Mamba-2 state kernels (``check_ssd``, ``ops/ssd.py``): a chunk from the
+# rows' slots with snapshots inside it, one position a row through the slots
+# in place (the boundary copies are ``check_kda``'s kernel). Float32 against
+# the same recurrence in ``jax.numpy``: the chunk kernel is the chunked form
+# (``exp`` of differences of a cumulative sum where the recurrence multiplies
+# factors), a few 1e-6 at chunks of 16 to 512; a state held in bf16 reads
+# 4e-3 after ONE step. SSD_TOLERANCE lies between the two, relative to the
+# largest state the check holds (they grow with the chunk where the decays
+# are near 1).
+SSD_CASES = ("ssd_chunk", "ssd_decode")
+SSD_TOLERANCE = 1e-4
+
 # Kernels the default ``serve`` flags reach (bf16 pool, auto grid).
 DEFAULT_CASES = ("fused_decode", "chunk_prefill")
 # The decode kernel as a model with window layers compiles it (``window``
@@ -527,3 +539,82 @@ def check_kda(
 
     return [_verdict(name, case, KDA_TOLERANCE) for name, case in zip(
         KDA_CASES, (chunk_case, decode_case, boundary_case))]
+
+
+def check_ssd(
+    *,
+    heads: int,
+    dim: int,
+    groups: int,
+    state: int,
+    batch: int = 4,
+    chunk: int = 128,
+    slots: int = 20,
+    interpret: bool = False,
+    seed: int = 0,
+    state_dtype=None,
+) -> list[dict]:
+    """``SSD_CASES`` at the given geometry, ``check_kernels``' result dicts:
+    each kernel of ``ops/ssd.py`` called DIRECTLY against its ``jax.numpy``
+    form on the same seeded inputs — decays drawn from e^-4.5 to 0.9975 a
+    token with a run at ``Delta A`` = -40, one row fresh and the others
+    resuming from their slots, snapshots at a chunk's end, inside it and at
+    0; a decode launch over distinct slots. Differences relative to the
+    largest state, held to ``SSD_TOLERANCE``. ``state_dtype`` is the
+    control: the states the kernels wrote, as slots of that dtype would hold
+    them (bf16 has to fail)."""
+    from radixmesh_tpu.ops import ssd
+
+    rng = np.random.default_rng(seed)
+    B, T, H, P, G, N = batch, chunk, heads, dim, groups, state
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    x, bm, cm = normal(B, T, H, P), normal(B, T, G, N), normal(B, T, G, N)
+    g = -jnp.exp(jnp.asarray(rng.uniform(-6.0, 1.5, (B, T, H)), jnp.float32))
+    g = g.at[0, : T // 2].set(-40.0)
+    pool = normal(2, slots, H * P // 128 * N, 128)
+    rows = jnp.asarray(rng.permutation(np.arange(1, slots))[:B], jnp.int32)
+    fresh = jnp.zeros((B,), jnp.int32).at[-1].set(1)
+    snap = jnp.asarray([[T, max(T // 2 - 3, 0), 0]] * B, jnp.int32)
+    layer = jnp.asarray(1, jnp.int32)
+    taken = set(np.asarray(rows).tolist())
+    free = [s_ for s_ in range(1, slots) if s_ not in taken]  # nobody's start
+
+    def written(pool_out, want, held=slice(None)) -> dict:
+        got = jnp.asarray(pool_out)
+        if state_dtype is not None:
+            got = got.astype(state_dtype).astype(jnp.float32)
+        want = np.asarray(want)[:, held]
+        diff = float(np.max(np.abs(np.asarray(got)[:, held] - want))
+                     / max(1.0, np.max(np.abs(want))))
+        return {"pool_write_diff": diff, "pool_write_ok": diff <= SSD_TOLERANCE}
+
+    def far(got, want) -> float:
+        """``max|got - want|`` against the LARGEST output: a decay is ``exp``
+        of a difference of cumulative sums, exact to the sums' float32 step,
+        which an output near zero beside one of 80 cannot be held to."""
+        want = np.asarray(want)
+        return float(np.max(np.abs(np.asarray(got) - want))
+                     / max(1.0, np.max(np.abs(want))))
+
+    def chunk_case():
+        keep = jnp.asarray(free[:3 * B], jnp.int32).reshape(B, 3).at[0, 2].set(0)
+        want = ssd.ssd_chunk(x, g, bm, cm, pool, layer, rows, fresh, snap, keep,
+                             use_kernel=False)
+        got = ssd.ssd_chunk_kernel(x, g, bm, cm, pool + 0, layer, rows, fresh,
+                                   snap, keep, interpret=interpret)
+        held = np.ones((slots,), bool)
+        held[0] = False  # the reference leaves what nobody keeps there
+        return {"max_err": far(got[0], want[0]), **written(got[1], want[1], held)}
+
+    def decode_case():
+        step = (x[:, 0], g[:, 0], bm[:, 0], cm[:, 0])
+        want = ssd.ssd_decode_ref(*step, pool, layer, rows)
+        got = ssd.ssd_decode_kernel(*step, pool + 0, layer, rows,
+                                    interpret=interpret)
+        return {"max_err": far(got[0], want[0]), **written(got[1], want[1])}
+
+    return [_verdict(name, case, SSD_TOLERANCE) for name, case in zip(
+        SSD_CASES, (chunk_case, decode_case))]

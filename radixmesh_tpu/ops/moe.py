@@ -7,7 +7,9 @@ combine.
     y = sum_{e in S} w_e * W_down,e (silu(W_gate,e x) * W_up,e x)
 
 or, with a sigmoid router: ``p = sigmoid(r)``, ``S`` = the k largest of
-``p + b`` (a learned bias), ``w_e = p_e / sum_S p`` times a constant.
+``p + b`` (a learned bias), ``w_e = p_e / sum_S p`` times a constant. An
+UN-GATED expert (``w_gate`` None) is ``W_down,e relu(W_up,e x)^2``: two
+grouped products where SwiGLU has three.
 
 No token is dropped and there is no capacity factor: every pair is
 computed. The pairs are laid out expert by expert, each expert's rows
@@ -159,6 +161,19 @@ def expert_layout(top_e: jnp.ndarray, n_experts: int, tm: int,
     }
 
 
+def _column_blocks(tm: int, K: int, N: int, itemsize: int,
+                   budget: int = 12 * 1024 * 1024) -> int:
+    """The fewest equal column blocks of ``N`` (each whole 128-lane tiles)
+    whose double-buffered weight, row tile and output fit ``budget``."""
+    lanes = N // 128 if N % 128 == 0 else 1
+    for n in range(1, lanes + 1):
+        nb = N // n
+        if lanes % n == 0 and 2 * (K * nb * itemsize + tm * K * itemsize
+                                   + tm * nb * 4) <= budget:
+            return n
+    return lanes
+
+
 def _gmm_kernel(tile_group_ref, layer_ref, lhs_ref, rhs_ref, out_ref, *,
                 precision):
     del tile_group_ref, layer_ref  # consumed by the index maps
@@ -180,42 +195,77 @@ def grouped_matmul(
     tm: int,
     site: str,  # "decode" | "prefill": the kernel's name in the trace
     interpret: bool = False,
+    fit_vmem: bool = False,  # ask for no VMEM: column blocks that fit
 ) -> jnp.ndarray:
     """``out[tile] = lhs[tile] @ rhs[layer, tile_group[tile]]`` for the
     tiles in use, float32 out. Rows of tiles past ``num_tiles`` are not
     written. The kernel takes the STACKED weights and the layer's index
     (scalar prefetch, as the attention kernels take the whole pool): a
     layer's slice handed to a custom call is a copy, 0.8 GB a layer at
-    the published widths — three times the step (PERF.md, PR 27)."""
+    the published widths — three times the step (PERF.md, PR 27).
+
+    ``fit_vmem``: the kernel asks for NO VMEM beyond the compiler's default
+    (16 MiB): an expert's weight goes through in the fewest column blocks
+    whose double buffers fit (:func:`_column_blocks`), columns OUTERMOST, so
+    an expert's block is still fetched once however many row tiles it has
+    (a row tile is fetched once a column block instead). For a program whose
+    other kernels' operands the compiler keeps in VMEM: a kernel that asks
+    for 64 MiB there is told it owns VMEM from offset 0 and never returned
+    (PERF.md, PR 53; ``vmem_limit_bytes`` wants a compiler flag beside it)."""
     m_pad, K = lhs.shape
     N = rhs.shape[-1]
     precision = _PREC if lhs.dtype == jnp.float32 else None
+    if fit_vmem:
+        nb = N // _column_blocks(tm, K, N, lhs.dtype.itemsize)
+        grid = (N // nb, num_tiles)
+        in_specs = [
+            pl.BlockSpec((tm, K), lambda j, i, tg, ly: (i, 0)),
+            pl.BlockSpec((None, None, K, nb),
+                         lambda j, i, tg, ly: (ly[0], tg[i], 0, j)),
+        ]
+        out_specs = pl.BlockSpec((tm, nb), lambda j, i, tg, ly: (i, j))
+        params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"))
+    else:
+        grid = (num_tiles,)
+        in_specs = [
+            pl.BlockSpec((tm, K), lambda i, tg, ly: (i, 0)),
+            pl.BlockSpec((None, None, K, N),
+                         lambda i, tg, ly: (ly[0], tg[i], 0, 0)),
+        ]
+        out_specs = pl.BlockSpec((tm, N), lambda i, tg, ly: (i, 0))
+        params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        )
     return pl.pallas_call(
         functools.partial(_gmm_kernel, precision=precision),
         out_shape=jax.ShapeDtypeStruct((m_pad, N), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(num_tiles,),
-            in_specs=[
-                pl.BlockSpec((tm, K), lambda i, tg, ly: (i, 0)),
-                pl.BlockSpec((None, None, K, N),
-                             lambda i, tg, ly: (ly[0], tg[i], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((tm, N), lambda i, tg, ly: (i, 0)),
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs,
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
+        compiler_params=params,
         interpret=interpret,
         name=f"radixmesh_moe_gmm_{site}",
     )(tile_group, jnp.asarray(layer, jnp.int32).reshape(1), lhs, rhs)
 
 
+def _act(gate, up):
+    """SwiGLU, or (no gate) the un-gated ``relu(up)^2``, in float32."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate) * up
+
+
+def _without_gate(fn, x, top_e, top_w, *rest):
+    return fn(x, top_e, top_w, None, *rest)
+
+
 def _experts_kernel(x, top_e, top_w, w_gate, w_up, w_down, layer, *, site,
-                    interpret, n_routed):
+                    interpret, n_routed, fit_vmem=False):
     T, k = top_e.shape
-    E = w_gate.shape[1]  # experts held here; a pair of another share is E
+    E = w_up.shape[1]  # experts held here; a pair of another share is E
     tm = tile_rows(T, k, n_routed)
     with jax.named_scope("rm_moe_route"):
         lay = expert_layout(top_e, E + (E < n_routed), tm, n_live=E)
@@ -223,10 +273,10 @@ def _experts_kernel(x, top_e, top_w, w_gate, w_up, w_down, layer, *, site,
     gmm = functools.partial(
         grouped_matmul, layer=layer, tile_group=lay["tile_group"],
         num_tiles=lay["num_tiles"], tm=tm, site=site, interpret=interpret,
+        fit_vmem=fit_vmem,
     )
-    gate = gmm(rows, w_gate)
-    up = gmm(rows, w_up)
-    h = (jax.nn.silu(gate) * up).astype(x.dtype)
+    gate = None if w_gate is None else gmm(rows, w_gate)
+    h = _act(gate, gmm(rows, w_up)).astype(x.dtype)
     out = gmm(h, w_down)  # [M_pad, H] float32
     with jax.named_scope("rm_moe_combine"):
         # A pair of another share lies in a tile that was not computed.
@@ -238,21 +288,22 @@ def _experts_reference(x, top_e, top_w, w_gate, w_up, w_down, layer):
     """The same layer without the sort: every expert applied to all
     tokens, weighted by its routing weight (zero where it was not chosen),
     one expert at a time."""
+    gated = w_gate is not None
     w_gate, w_up, w_down = (
         jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
-        for w in (w_gate, w_up, w_down)
+        for w in (w_gate if gated else w_up, w_up, w_down)
     )
-    E = w_gate.shape[0]
+    E = w_up.shape[0]
     hit = top_e[..., None] == jnp.arange(E, dtype=jnp.int32)  # [T, k, E]
     w_full = jnp.sum(jnp.where(hit, top_w[..., None], 0.0), axis=1)  # [T, E]
 
     def one(acc, xs):
         wg, wu, wd, w_e = xs
         gate = jnp.einsum("th,hf->tf", x, wg, precision=_PREC,
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32) if gated else None
         up = jnp.einsum("th,hf->tf", x, wu, precision=_PREC,
                         preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+        h = _act(gate, up).astype(x.dtype)
         o = jnp.einsum("tf,fh->th", h, wd, precision=_PREC,
                        preferred_element_type=jnp.float32)
         return acc + w_e[:, None] * o, None
@@ -265,8 +316,8 @@ def _experts_reference(x, top_e, top_w, w_gate, w_up, w_down, layer):
 def moe_ffn(
     x: jnp.ndarray,  # [T, H]
     w_router: jnp.ndarray,  # [H, E]
-    w_gate: jnp.ndarray,  # [E, H, F], or every layer's [L, E, H, F]
-    w_up: jnp.ndarray,  # [E, H, F]
+    w_gate: jnp.ndarray | None,  # [E, H, F] (every layer's: [L, E, H, F]);
+    w_up: jnp.ndarray,  # [E, H, F]      None: un-gated experts, relu(up)^2
     w_down: jnp.ndarray,  # [E, F, H]
     *,
     layer: jnp.ndarray | int = 0,  # which layer of stacked expert weights
@@ -282,6 +333,7 @@ def moe_ffn(
     topk_group: int = 1,
     use_kernel: bool | None = None,
     interpret: bool = False,
+    fit_vmem: bool = False,  # the grouped product's (``grouped_matmul``)
 ):
     """The expert layer on ``x``. Returns ``(y [T, H] in x's dtype, expert
     ids [T, k], stats [4] int32)``; ``stats`` is what this launch adds to
@@ -298,10 +350,11 @@ def moe_ffn(
     all ``E``, computes the part of the result its own experts give (the
     weights normalised over all ``k`` chosen, as published) and counts
     what went elsewhere; nothing stands in for the other shares."""
-    if w_gate.ndim == 3:
-        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+    if w_up.ndim == 3:
+        w_gate = None if w_gate is None else w_gate[None]
+        w_up, w_down = w_up[None], w_down[None]
     layer = jnp.asarray(layer, jnp.int32)
-    E, held = w_router.shape[1], w_gate.shape[1]
+    E, held = w_router.shape[1], w_up.shape[1]
     with jax.named_scope("rm_moe_route"):
         top_e, top_w, kept = _route(
             x, w_router, k, norm_topk, scoring, bias, scale, n_group, topk_group)
@@ -326,13 +379,16 @@ def moe_ffn(
 
     def kernel(*args):
         return _experts_kernel(*args, site=site, interpret=interpret,
-                               n_routed=E)
+                               n_routed=E, fit_vmem=fit_vmem)
 
+    reference = _experts_reference
     args = (x, local, top_w, w_gate, w_up, w_down, layer)
+    if w_gate is None:  # (a None among the operands below is no operand)
+        kernel, reference = (functools.partial(_without_gate, fn)
+                             for fn in (kernel, reference))
+        args = (x, local, top_w, w_up, w_down, layer)
     if use_kernel is None:
-        y = jax.lax.platform_dependent(
-            *args, tpu=kernel, default=_experts_reference
-        )
+        y = jax.lax.platform_dependent(*args, tpu=kernel, default=reference)
     else:
-        y = (kernel if use_kernel else _experts_reference)(*args)
+        y = (kernel if use_kernel else reference)(*args)
     return y.astype(x.dtype), top_e, stats

@@ -925,10 +925,11 @@ class ServingFrontend:
                         * eng.pool.kv.dtype.itemsize),
                     "bytes_per_slot": eng.pool.state_slot_bytes,
                 }
-            if eng.kda_stats is not None:
-                # Linear-attention layers: what the launches moved of the
-                # state slots (Engine.kda_stats).
-                state["kda"] = dict(eng.kda_stats)
+            if eng.matrix_stats is not None:
+                # Layers with a matrix state ("kda": linear attention,
+                # "ssd": Mamba-2): what the launches moved of the state
+                # slots (Engine.matrix_stats), under the kind's name.
+                state[eng.cfg.matrix_state] = dict(eng.matrix_stats)
             if eng.tail_stats is not None:
                 # A model whose resume points are window tails alone: how
                 # requests resumed, what the tree took and gave up.

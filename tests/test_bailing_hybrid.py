@@ -286,11 +286,11 @@ def test_a_boundary_copy_is_made_on_the_step_that_completes_a_page(model):
         max_new_tokens=45, return_logits=True))
     copies = []
     while eng.has_work():
-        before = eng.kda_stats["boundary_copies"]
+        before = eng.matrix_stats["boundary_copies"]
         kv_before = req.kv_len
         eng.step()
         eng._settle()
-        made = eng.kda_stats["boundary_copies"] - before
+        made = eng.matrix_stats["boundary_copies"] - before
         copies.append(made)
         if made:
             assert req.kv_len % PAGE == 0 and req.kv_len == kv_before + 1
@@ -300,7 +300,7 @@ def test_a_boundary_copy_is_made_on_the_step_that_completes_a_page(model):
                 assert np.array_equal(
                     eng.pool.state_conv[:, work], eng.pool.state_conv[:, keep])
     assert sum(copies) == 3
-    st = eng.kda_stats
+    st = eng.matrix_stats
     assert st["decode_rows"] == 44 and st["chunk_tokens"] == 20
     per = eng.pool.state_slot_bytes
     # a launch moves every row of the launched batch; 3 prefill snapshots

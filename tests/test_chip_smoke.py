@@ -52,7 +52,7 @@ def test_cpu_rehearsal_runs_every_phase_and_says_so():
     lines = _assert_rehearsal(_smoke("--rehearse-cpu"), 1)
     out = "\n".join(lines)
     for phase in (
-        "kernels: 14 compiled and matched",  # three the chunk kernel over a prefix, three the kda state kernels at each of two chunks
+        "kernels: 16 compiled and matched",  # three the chunk kernel over a prefix, three the kda state kernels at each of two chunks, the two ssd state kernels
         "short prompt: 40 tok",
         "long prompt: 300 tok",
         "second turn: 332 tok, cached_tokens 304",

@@ -1240,7 +1240,8 @@ class TestTailOnlyResume:
             state["engine"]["spans"])
 
 
-@pytest.mark.parametrize("model", ["ling-tiny", "lfm2-tiny", "phi4flash-tiny"])
+@pytest.mark.parametrize(
+    "model", ["ling-tiny", "nemotron-tiny", "lfm2-tiny", "phi4flash-tiny"])
 def test_a_decode_launch_names_a_boundary_slot_only_where_a_page_completes(model):
     """Every state-bearing model: a launch's second state slot is the row's
     boundary slot when its token completes a page, slot 0 otherwise — and a
@@ -1270,8 +1271,8 @@ def test_a_decode_launch_names_a_boundary_slot_only_where_a_page_completes(model
             seen += completes
         assert launch.boundary_copies == int(np.count_nonzero(keep))
     assert seen >= 2 and all(len(r.generated) == 24 for r in reqs)
-    if cfg.has_kda:
-        assert eng.kda_stats["boundary_copies"] >= 2
-        assert eng.kda_stats["boundary_copies"] <= seen
+    if cfg.matrix_state:
+        assert eng.matrix_stats["boundary_copies"] >= 2
+        assert eng.matrix_stats["boundary_copies"] <= seen
     else:
-        assert eng.kda_stats is None
+        assert eng.matrix_stats is None

@@ -406,7 +406,8 @@ def test_what_the_key_cannot_hold_stays_on_jax_jit(why, program_store):
 # ---- two engines in turn over one store ----------------------------------
 
 MODELS = ["llama3-tiny", "qwen2-tiny", "mellum2-tiny", "glm-4.7-flash-tiny",
-          "glm-5.2-tiny", "lfm2-tiny", "phi4flash-tiny", "laguna-tiny", "ling-tiny"]
+          "glm-5.2-tiny", "lfm2-tiny", "phi4flash-tiny", "laguna-tiny", "ling-tiny",
+          "nemotron-tiny"]
 SERVED = ("prefill_chunk_paged", "decode_step", "prefill_forward")
 
 

@@ -531,3 +531,39 @@ def test_the_kda_kernels_lower_at_ling3s_widths_and_ask_for_no_vmem(v5e, rows):
         call, = [ln for ln in text.splitlines()
                  if "custom-call(" in ln and name in ln]
         assert '"scoped_memory_configs":[]' in call
+
+
+@pytest.mark.parametrize("rows", [16, 64])
+def test_the_ssd_kernels_lower_at_nemotron3s_widths_and_ask_for_no_vmem(v5e, rows):
+    """``radixmesh_ssd_chunk`` / ``_decode`` at 64 heads of 64 x 128 in 8
+    groups over the cell's slot array: Mosaic takes them, neither asks for
+    VMEM of its own, and a launch of more than 16 rows is calls of 16
+    (``ops/kda.py``'s reason)."""
+    from radixmesh_tpu.ops import ssd
+
+    sd = _sd(v5e)
+    H, P, G, N, T = 64, 64, 8, 128, 128
+    f32, i32 = jnp.float32, jnp.int32
+    pool = sd((10, 321, H * P // 128 * N, 128), f32)
+
+    def chunk(x, g, bm, cm, pool, rows_in, fresh, snap, keep):
+        return ssd.ssd_chunk(x, g, bm, cm, pool, 2, rows_in, fresh, snap, keep)
+
+    text = jax.jit(chunk, donate_argnums=(4,)).lower(
+        sd((rows, T, H, P), f32), sd((rows, T, H), f32), sd((rows, T, G, N), f32),
+        sd((rows, T, G, N), f32), pool, sd((rows,), i32), sd((rows,), i32),
+        sd((rows, 3), i32), sd((rows, 3), i32)).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "radixmesh_ssd_chunk" in ln]
+    assert len(calls) == -(-rows // ssd._ROWS_A_CALL)
+    assert all('"scoped_memory_configs":[]' in c for c in calls)
+
+    def step(x, g, bm, cm, pool, slots):
+        return ssd.ssd_decode(x, g, bm, cm, pool, 2, slots)
+
+    text = jax.jit(step, donate_argnums=(4,)).lower(
+        sd((rows, H, P), f32), sd((rows, H), f32), sd((rows, G, N), f32),
+        sd((rows, G, N), f32), pool, sd((rows,), i32)).compile().as_text()
+    call, = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "radixmesh_ssd_decode" in ln]
+    assert '"scoped_memory_configs":[]' in call
