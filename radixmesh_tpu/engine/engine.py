@@ -48,6 +48,7 @@ from radixmesh_tpu.cache.radix_tree import RadixTree
 from radixmesh_tpu.engine.prefill_plan import (
     KV_BLOCK_PAGES as _KV_BLOCK_PAGES,
     boxed_tokens,
+    chunk_width,
     launch_cost,
     plan_prefill,
     pow2_at_least as _pow2_at_least,
@@ -377,15 +378,19 @@ class Engine:
         self.max_pages = -(-self.max_seq_len // page_size)
         # Long-context admission (SURVEY §5): prompts of more than
         # ``long_prefill_threshold`` tokens, and every prompt the tree serves
-        # part of, prefill in ``prefill_chunk``-token chunks against the paged
-        # pool (O(S·chunk) memory) instead of the dense path (O(S²) scores).
+        # part of, prefill in chunks against the paged pool (O(S·chunk)
+        # memory) instead of the dense path (O(S²) scores). ``prefill_chunk``
+        # is the MINIMUM chunk (and the inline-wave and speculative paths'
+        # own): how wide a chunk launch may be is read from the weights
+        # (``prefill_width``, below).
         self.prefill_chunk = prefill_chunk
         # Cold-burst fairness (VERDICT round-4 weak #4): a prefill wave
         # wider than the compute-saturating token count only convoys —
         # every member then finalizes its first token when the LAST one
         # does, so an N-request cold burst lands p50 TTFT == p99 == the
         # whole burst's prefill time. Sub-waves are sliced to at most
-        # ``prefill_wave_tokens // chunk`` rows; slices preserve arrival
+        # ``prefill_wave_tokens // chunk`` rows, and no launch holds more
+        # tokens than that; slices preserve arrival
         # order (FIFO within a size bucket), so with equal jobs TTFT
         # approaches the single-server SPT floor (mean ≈ half the burst).
         self.prefill_wave_tokens = prefill_wave_tokens
@@ -447,10 +452,16 @@ class Engine:
         # before the path's dispatch to after the first-token sync — no
         # decode launch is in flight then, so a wave's wall time is its
         # device time plus its dispatch and its sync tail.
+        # ``launches_below_floor``: launches whose B x C is under
+        # ``prefill_cost[0]``, bound by the weights they stream whatever
+        # they hold; ``by_chunk``: launches by their chunk C (a dense or sp
+        # launch: its one bucket).
         self.prefill = {
             "waves": 0,
             "rows": 0,
             "launches": 0,
+            "launches_below_floor": 0,
+            "by_chunk": {},
             "tokens_new": 0,
             "tokens_launched": 0,
             "tokens_boxed": 0,
@@ -680,9 +691,10 @@ class Engine:
             # counts, whichever of kernel and reference serves.
             self.attn_window |= {
                 "chunk_query_blocks": 0, "chunk_query_blocks_skipped": 0}
+            self._chunk_shape = dict(
+                group=cfg.n_heads // row_heads, q_dtype=cfg.dtype, **shape)
             self._chunk_query_tokens = functools.partial(
-                chunk_query_tokens, group=cfg.n_heads // row_heads,
-                q_dtype=cfg.dtype, **shape)
+                chunk_query_tokens, **self._chunk_shape)
         if kv_tier_dir is not None and host_cache_slots <= 0:
             raise ValueError(
                 "kv_tier_dir requires a host tier (host_cache_slots > 0): "
@@ -1076,12 +1088,11 @@ class Engine:
         # default — the wave hot paths keep the one-branch-when-off
         # contract (a single `is not None` test per wave).
         self.step_acct = None
-        # Tokens of the launched shapes and launches of the LAST sub-wave,
-        # added up by whichever prefill path ran (single scheduler thread):
-        # the launch SHAPES live inside each path, so this is how the wave
-        # accounting in _admit learns them without re-deriving bucket math.
-        self._wave_padded = 0
-        self._wave_launches = 0
+        # The launched shapes (rows, chunk) of the LAST sub-wave, noted by
+        # whichever prefill path ran (single scheduler thread): the launch
+        # SHAPES live inside each path, so this is how the wave accounting
+        # in _admit learns them without re-deriving bucket math.
+        self._wave_shapes: list[tuple[int, int]] = []
         # What the wave planner weighs (engine/prefill_plan.py), from the
         # weights this engine holds: a launch streams every leaf but the
         # embedding table (a gather), a token meets every parameter but,
@@ -1100,8 +1111,21 @@ class Engine:
         self.prefill_cost = launch_cost(
             sum(leaf.size * leaf.dtype.itemsize for leaf in streamed),
             2.0 * met)
+        # How wide a chunk launch may be: the power of two that holds the
+        # floor, where the chunk kernels this model runs admit it
+        # (``prefill_width`` also holds it within the wave's tokens). A
+        # model that resumes keeps the chunk: its launches of several rows
+        # take ONE table width, so rows of every bucket share the
+        # ``[B, chunk]`` programs a burst of short rows compiled — a turn
+        # that resumes 2 k tokens back among them — and a wider chunk for
+        # the long buckets would be a program no warm-up has sent.
+        self._chunk_width = prefill_chunk
         if self._pp:
             self.prefill_cost = (float("inf"), self.prefill_cost[1])
+        elif not self._resumes:
+            self._chunk_width = chunk_width(
+                prefill_chunk, self.prefill_cost[0], prefill_wave_tokens,
+                self._chunk_admits)
         if step_accounting:
             from radixmesh_tpu.obs.step_plane import StepAccounting
 
@@ -1911,7 +1935,8 @@ class Engine:
             # (``_prefill_group``).
             cut = subwaves(
                 [len(m[0].prompt) - m[2] for m in group],
-                chunk=self.prefill_chunk, wave_tokens=self.prefill_wave_tokens)
+                chunk=self.prefill_chunk, wave_tokens=self.prefill_wave_tokens,
+                width=self.prefill_width)
             for sub in ([group[i] for i in rows] for rows in cut):
                 # Quantized pools always prefill through the chunked
                 # paged path: it attends the already-quantized K/V
@@ -1926,7 +1951,7 @@ class Engine:
                 traced = [m[0].trace for m in sub if m[0].trace is not None]
                 clock = self.spans.clock
                 t_wave = clock()
-                self._wave_padded = self._wave_launches = 0
+                self._wave_shapes = shapes = []
                 news = [len(m[0].prompt) - m[2] for m in sub]
                 # One request with nothing cached, on a pool and a mesh
                 # that leave the choice: the dense launch, where it is
@@ -1939,11 +1964,12 @@ class Engine:
                 )
                 dense_up_to = self.long_prefill_threshold if lone_cold else 0
                 boxed = boxed_tokens(
-                    news, chunk=self.prefill_chunk, dense_up_to=dense_up_to)
+                    news, chunk=self.prefill_chunk, dense_up_to=dense_up_to,
+                    width=self.prefill_width)
                 if lone_cold and self._sp_capable(sub[0]):
                     path = "sp"
                     pending = [self._prefill_sp(*sub[0])]
-                    boxed = self._wave_padded  # its one launch, as before
+                    boxed = shapes[0][0] * shapes[0][1]  # its one launch
                 elif (
                     # A cached prefix is read where it lies, in pages (the
                     # chunk path): a dense copy of it would be gathered,
@@ -1966,18 +1992,23 @@ class Engine:
                 with self.spans.span("rm.first_token.sync"):
                     self._finalize_first_tokens(pending)
                 # The wave's work, counted always (self.prefill): real vs
-                # launched-shape tokens — each prefill path added up its
-                # launches (_wave_padded, _wave_launches) — beside the box
-                # of the rule before PR 47, and its wall time. Step
-                # attribution (obs/step_plane.py) is fed the same numbers.
+                # launched-shape tokens — each prefill path noted its
+                # launches (_wave_shapes) — beside the one box of the same
+                # rows, and its wall time. Step attribution
+                # (obs/step_plane.py) is fed the same numbers.
                 dur = clock() - t_wave
                 new_tok = sum(news)
+                launched = sum(b * c for b, c in shapes)
                 p = self.prefill
                 p["waves"] += 1
                 p["rows"] += len(sub)
-                p["launches"] += self._wave_launches
+                p["launches"] += len(shapes)
+                p["launches_below_floor"] += sum(
+                    b * c < self.prefill_cost[0] for b, c in shapes)
+                for _, c in shapes:
+                    p["by_chunk"][c] = p["by_chunk"].get(c, 0) + 1
                 p["tokens_new"] += new_tok
-                p["tokens_launched"] += self._wave_padded
+                p["tokens_launched"] += launched
                 p["tokens_boxed"] += boxed
                 p["wall_s"] += dur
                 p["by_path"][path] += 1
@@ -1985,7 +2016,7 @@ class Engine:
                     self.step_acct.note_wave(
                         "prefill",
                         new_tok,
-                        self._wave_padded,
+                        launched,
                         dur,
                         rows=len(sub),
                     )
@@ -2688,6 +2719,36 @@ class Engine:
         if engaged:
             c["index_keys_scored"] += held * len(cfg.full_layers)
 
+    @property
+    def prefill_width(self) -> int:
+        """The widest chunk of a prefill launch: the power of two that
+        holds the floor of ``prefill_cost`` where the chunk kernels admit
+        it (``prefill_plan.chunk_width``), within the wave's tokens as
+        they are now (the SLO ladder narrows a wave), never under
+        ``prefill_chunk``. What ``subwaves`` slices by and
+        ``plan_prefill`` launches at."""
+        width = self._chunk_width
+        while width > max(self.prefill_chunk, self.prefill_wave_tokens):
+            width //= 2
+        return width
+
+    def _chunk_admits(self, width: int) -> bool:
+        """Whether this model's chunk kernels take a chunk ``width`` wide,
+        from shapes alone (``prefill_plan.chunk_width``). The attention
+        kernel holds the chunk's own rows whole in VMEM and says; the
+        selecting, delta-rule, SSD and convolution kernels walk a chunk in
+        blocks of their own and take any."""
+        cfg = self.cfg
+        if cfg.latent:
+            from radixmesh_tpu.ops.mla_attention import chunk_admits
+
+            return chunk_admits(
+                width, cfg.n_heads, cfg.kv_row[2], cfg.kv_lora_rank,
+                self.pool.kv.dtype.itemsize)
+        from radixmesh_tpu.ops.paged_attention import chunk_admits
+
+        return chunk_admits(width, max_pages=self.max_pages, **self._chunk_shape)
+
     def _prefill_dense(self, req: Request, row: int, own: np.ndarray) -> tuple:
         """Dense prefill of one request with nothing cached: the whole
         prompt in one ``prefill_forward`` launch of its power-of-two
@@ -2706,8 +2767,7 @@ class Engine:
         prompt = req.prompt
         n_new = len(prompt)
         s_b = _pow2_at_least(n_new)
-        self._wave_padded += s_b  # launch shape (step attribution)
-        self._wave_launches += 1
+        self._wave_shapes.append((1, s_b))
         tokens = np.zeros((1, s_b), dtype=np.int32)
         tokens[0, :n_new] = prompt
         positions = np.arange(s_b, dtype=np.int32)[None]
@@ -2868,8 +2928,7 @@ class Engine:
         sp = self.device_mesh.shape["sp"]
         s_b = _pow2_at_least(n, floor=max(16, sp))
         s_b = -(-s_b // sp) * sp  # shard_map needs S divisible by sp
-        self._wave_padded += s_b  # launch shape (step attribution)
-        self._wave_launches += 1
+        self._wave_shapes.append((1, s_b))
         tokens = np.zeros((1, s_b), dtype=np.int32)
         tokens[0, :n] = prompt
         positions = np.arange(s_b, dtype=np.int32)[None]
@@ -2917,6 +2976,7 @@ class Engine:
             chunk=self.prefill_chunk, page_size=ps, max_pages=self.max_pages,
             floor_tokens=floor_tokens, launch_tokens=launch_tokens,
             width_selects=self.cfg.dsa, one_width=self._resumes,
+            width=self.prefill_width,
         )
         windowed = self.window_stats is not None
         token_slots_all, pages_all, w_pages_all, w_slots_all = [], [], [], []
@@ -2944,8 +3004,7 @@ class Engine:
             done = np.asarray(launch.starts, dtype=np.int64)
             nv = np.asarray(launch.lens, dtype=np.int64)  # rows a run (kv.write)
             starts = reuses[idx] + done  # [n] tokens in the pool before
-            self._wave_padded += B * C  # launch shape (step attribution)
-            self._wave_launches += 1
+            self._wave_shapes.append((B, C))
             toks = np.zeros((B, C), dtype=np.int32)
             sl = np.full((B, C), self._scratch_slot, dtype=np.int32)
             for j, i in enumerate(launch.rows):

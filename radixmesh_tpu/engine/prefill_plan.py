@@ -12,13 +12,23 @@ to stream the weights, not the arithmetic, bounds it (``floor_tokens``),
 and what a launch costs beside its tokens (``launch_tokens``) — both in
 units of one token's arithmetic.
 
+The same cost sets how wide a chunk may be (PR 54, :func:`chunk_width`):
+the engine's ``prefill_chunk`` is the MINIMUM; where the floor lies above
+it the widest chunk (``width`` below) is the power of two that holds the
+floor, within ``prefill_wave_tokens`` and what the chunk kernels admit
+from shapes alone. A sub-wave takes the widest chunk EVERY row of its
+bucket fills (:func:`bucket_chunk`: half the bucket, from ``chunk`` to
+``width``) — a row that HAS the tokens is not launched in pieces each
+bound by the weights they stream, and no row's first launch is padded.
+
 The shapes it draws from are closed, and a cell's warm-up reaches them
 the way it reached the box's:
 
 * a launch of SEVERAL rows is ``[B, C0, width]`` with ``C0`` and
-  ``width`` what :func:`prefill_launch_shape` gives the sub-wave, and
-  ``B`` a power of two no greater than the box's — the shapes a burst of
-  alike requests compiles;
+  ``width`` what :func:`prefill_launch_shape` gives the sub-wave — a
+  function of its bucket and of the engine's weights, never of how many
+  rows it happens to hold — and ``B`` a power of two no greater than the
+  box's: the shapes a burst of alike requests compiles;
 * a launch of ONE row is ``[1, C, lone width]`` with ``C`` a power of two
   from 16 to ``C0`` — and exactly the launches the same request gets
   when it is admitted alone, so a request of every length sent alone
@@ -28,12 +38,14 @@ the way it reached the box's:
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Sequence
+from typing import Callable, NamedTuple, Sequence
 
 __all__ = [
     "KV_BLOCK_PAGES",
     "PrefillLaunch",
     "boxed_tokens",
+    "bucket_chunk",
+    "chunk_width",
     "launch_cost",
     "plan_prefill",
     "pow2_at_least",
@@ -77,6 +89,7 @@ def prefill_launch_shape(
     max_pages: int,
     width_selects: bool = False,
     one_width: bool = False,
+    width: int | None = None,
 ) -> tuple[int, int, int]:
     """``(B, C, max_pages)`` of the BOX around ``rows`` requests of at
     most ``new_tokens`` uncached tokens and ``total_tokens`` in all — the
@@ -99,9 +112,12 @@ def prefill_launch_shape(
     width — for a model whose chunk program traces several kinds of
     layer (conv and attention, dense and sparse FFN: ~30 s a compile at
     published widths), where contexts of 2 k-14 k tokens would otherwise
-    multiply every (rows, chunk) pair by three widths."""
+    multiply every (rows, chunk) pair by three widths. ``chunk`` is the
+    engine's minimum chunk and ``width`` its widest (:func:`chunk_width`;
+    the chunk when not given): ``C`` is :func:`bucket_chunk` of the
+    bucket of ``new_tokens``."""
     B = pow2_at_least(rows, floor=1)
-    C = pow2_at_least(min(new_tokens, chunk), floor=_MIN_CHUNK)
+    C = bucket_chunk(pow2_at_least(new_tokens, floor=_MIN_CHUNK), chunk, width)
     pages = -(-total_tokens // page_size)
     if (rows == 1 or one_width) and not width_selects:
         pages = max_pages
@@ -121,18 +137,52 @@ def launch_cost(stream_bytes: float, flop_per_token: float) -> tuple[float, floa
     return (stream_bytes / _PEAK_BYTES) / token_s, _LAUNCH_S / token_s
 
 
+def chunk_width(
+    chunk: int, floor_tokens: float, wave_tokens: int,
+    admits: Callable[[int], bool] = lambda width: True,
+) -> int:
+    """The widest chunk of a launch: the power of two that holds
+    ``max(chunk, floor_tokens)`` — past the floor a launch is bound by
+    its arithmetic and a wider one saves nothing but its fixed charge —
+    no wider than a wave's ``wave_tokens``, and halved while the chunk
+    kernels refuse it from shapes alone (``admits``); never under
+    ``chunk``, which the kernels were built at."""
+    width = pow2_at_least(
+        int(min(max(chunk, floor_tokens), max(chunk, wave_tokens))))
+    while width > chunk and (width > wave_tokens or not admits(width)):
+        width //= 2
+    return max(width, chunk)
+
+
+def bucket_chunk(bucket: int, chunk: int, width: int | None = None) -> int:
+    """The chunk ``C0`` of a sub-wave of one power-of-two ``bucket`` of new
+    tokens: the bucket itself up to ``chunk``; above, the widest power of
+    two that EVERY row of the bucket fills — its rows hold more than half
+    of it — from ``chunk`` up to ``width``. A function of the bucket and
+    of the engine's weights alone, never of the rows a sub-wave happens
+    to hold: a burst of alike rows compiles what any wave of the bucket
+    launches. Buckets up to twice ``chunk`` keep the chunk they had when
+    ``chunk`` was the cap (as every bucket does where no ``width`` is
+    given)."""
+    return min(bucket, max(chunk, min(width or chunk, bucket // 2)))
+
+
 def subwaves(
     news: Sequence[int], *, chunk: int, wave_tokens: int,
+    width: int | None = None,
 ) -> list[list[int]]:
     """An admission's rows (by index, ``news[i]`` uncached tokens each) cut
     into sub-waves: by power-of-two bucket of new tokens, shortest first —
     a short request must not wait for a 32k groupmate's chunks to sample
     its first token — and each bucket sliced at the compute-saturating
-    width, ``wave_tokens // chunk`` rows of whole chunks, in arrival
-    order: slices finalize their first tokens as they complete instead
-    of convoying behind the whole bucket. The bucket is UNCAPPED (a
+    width, ``wave_tokens // C0`` rows of whole chunks with ``C0`` the
+    chunk :func:`plan_prefill` will launch them at (:func:`bucket_chunk`:
+    no launch holds more than ``wave_tokens`` tokens), in arrival order:
+    slices finalize their first tokens as they complete instead of
+    convoying behind the whole bucket. The bucket is UNCAPPED (a
     512-token prompt shares no sub-wave with a 32k prompt); the chunk
-    inside a sub-wave stays capped at ``chunk``."""
+    inside a sub-wave stays capped at ``width`` (``chunk`` when not
+    given)."""
     def bucket(i: int) -> int:
         return pow2_at_least(news[i], floor=_MIN_CHUNK)
 
@@ -141,7 +191,8 @@ def subwaves(
     start = 0
     for end in range(1, len(order) + 1):
         if end == len(order) or bucket(order[end]) != bucket(order[start]):
-            rows = max(1, wave_tokens // min(bucket(order[start]), chunk))
+            c0 = bucket_chunk(bucket(order[start]), chunk, width)
+            rows = max(1, wave_tokens // c0)
             cut.extend(
                 order[j:min(j + rows, end)] for j in range(start, end, rows))
             start = end
@@ -164,15 +215,17 @@ class PrefillLaunch(NamedTuple):
 
 def boxed_tokens(
     news: Sequence[int], *, chunk: int, dense_up_to: int = 0,
+    width: int | None = None,
 ) -> int:
     """What the rule before PR 47 launched for a sub-wave of rows with
     ``news`` new tokens each: the rows padded to a power of two, each to
-    the longest row's whole chunks; a lone cold prompt of at most
-    ``dense_up_to`` tokens its power-of-two bucket, in one launch."""
+    the longest row's whole chunks (of the sub-wave's own ``C0``); a lone
+    cold prompt of at most ``dense_up_to`` tokens its power-of-two
+    bucket, in one launch."""
     longest = max(news)
     if len(news) == 1 and longest <= dense_up_to:
         return pow2_at_least(longest)
-    C = pow2_at_least(min(longest, chunk), floor=_MIN_CHUNK)
+    C = bucket_chunk(pow2_at_least(longest, floor=_MIN_CHUNK), chunk, width)
     return pow2_at_least(len(news), floor=1) * -(-longest // C) * C
 
 
@@ -186,15 +239,18 @@ def plan_prefill(
     launch_tokens: float,
     width_selects: bool = False,
     one_width: bool = False,
+    width: int | None = None,
 ) -> list[PrefillLaunch]:
     """The launches that prefill a sub-wave of ``rows`` = ``(new tokens,
     cached tokens)``, cheapest first by the estimate ``max(floor_tokens,
-    B x C) + launch_tokens`` a launch, then fewest.
+    B x C) + launch_tokens`` a launch, then fewest, then those of the
+    fewest launched tokens.
 
-    The rows advance by levels of ``C0`` tokens (the box's chunk). At a
-    level, a row with ``C0`` tokens or more left RIDES a launch of
-    several rows; a row with fewer — its tail — rides too where a seat
-    costs less than a launch of its own, and else goes ALONE, in
+    The rows advance by levels of ``C0`` tokens (the box's chunk:
+    :func:`bucket_chunk` of their bucket, between ``chunk`` and
+    ``width``). At a level, a row with ``C0`` tokens or more left RIDES a
+    launch of several rows; a row with fewer — its tail — rides too where
+    a seat costs less than a launch of its own, and else goes ALONE, in
     launches of one row whose chunks are the powers of two that hold the
     tail (288 tokens: 256 + 32, where two launches cost less than the
     224 padded tokens of one). The riders, longest first, are seated in
@@ -202,28 +258,35 @@ def plan_prefill(
     cheaper than three padded rows — so a later level launches only the
     rows still live. Where a launch is bound by the weights it streams
     (``floor_tokens`` in the thousands: experts, or few new tokens on a
-    long cached prefix) nothing is cheaper than the box, and the plan is
-    the box."""
+    long cached prefix) no plan of MORE launches is cheaper than the box,
+    and a sub-wave of one level is the box; a tail that needs a launch
+    of its own either way takes the power of two that holds it, not
+    ``C0``."""
     news = [int(n) for n, _ in rows]
-    box_b, c0, width = prefill_launch_shape(
+    box_b, c0, pages = prefill_launch_shape(
         len(rows), max(news), max(n + c for n, c in rows), chunk=chunk,
         page_size=page_size, max_pages=max_pages,
-        width_selects=width_selects, one_width=one_width)
-    lone_width = width if width_selects else prefill_launch_shape(
+        width_selects=width_selects, one_width=one_width, width=width)
+    lone_width = pages if width_selects else prefill_launch_shape(
         1, 1, 1, chunk=chunk, page_size=page_size, max_pages=max_pages)[2]
 
-    def cost(b: int, c: int) -> tuple[float, int]:
-        return max(floor_tokens, b * c) + launch_tokens, 1
+    def cost(b: int, c: int) -> tuple[float, int, int]:
+        """Of one launch: its estimate; then 1 and its tokens — of two
+        plans that cost the same the one of fewer launches is taken, and
+        of those the one that launches fewer tokens (under the floor the
+        estimate cannot tell ``[1, 256]`` from ``[1, 1024]``; the chip
+        can, PERF.md section 6, PR 54)."""
+        return max(floor_tokens, b * c) + launch_tokens, 1, b * c
 
     def add(x, y):
-        return x[0] + y[0], x[1] + y[1]
+        return x[0] + y[0], x[1] + y[1], x[2] + y[2]
 
     @functools.lru_cache(maxsize=None)
-    def seats(m: int) -> tuple[tuple[float, int], tuple[int, ...]]:
+    def seats(m: int) -> tuple[tuple[float, int, int], tuple[int, ...]]:
         """Cheapest launches of ``C0`` tokens that seat ``m`` rows: their
         estimate, and their batch sizes, largest first."""
         if m <= 0:
-            return (0.0, 0), ()
+            return (0.0, 0, 0), ()
         best = None
         b = 1
         while b <= box_b:
@@ -235,7 +298,7 @@ def plan_prefill(
         return best[0], tuple(sorted(best[1], reverse=True))
 
     @functools.lru_cache(maxsize=None)
-    def alone(t: int) -> tuple[tuple[float, int], tuple[int, ...]]:
+    def alone(t: int) -> tuple[tuple[float, int, int], tuple[int, ...]]:
         """Cheapest one-row launches for a tail of ``t`` tokens: their
         estimate, and their chunks in order."""
         c = pow2_at_least(t, floor=_MIN_CHUNK)
@@ -270,7 +333,7 @@ def plan_prefill(
             plan.append(PrefillLaunch(
                 tuple(seated), (done,) * len(seated),
                 tuple(min(c0, news[i] - done) for i in seated),
-                b, c0, lone_width if b == 1 else width))
+                b, c0, lone_width if b == 1 else pages))
         for i, (_, chunks) in zip(live[full + best_k:], tails[best_k:]):
             at = done
             for c in chunks:

@@ -63,6 +63,7 @@ __all__ = [
     "latent_decode_attention",
     "latent_chunk_attention",
     "latent_prefill_attention",
+    "chunk_admits",
     "decode_groups",
     "mla_decode_kernel",
     "mla_chunk_kernel",
@@ -744,6 +745,22 @@ def _chunk_rows(chunk: int, group: int, max_rows: int = 1024) -> int:
     while chunk % (cblk * 2) == 0 and cblk * 2 * group <= max_rows:
         cblk *= 2
     return cblk
+
+
+def chunk_admits(chunk: int, group: int, width: int, v_width: int,
+                 itemsize: int) -> bool:
+    """Whether ``mla_chunk_kernel`` holds a chunk this wide, from the
+    shapes alone: a program keeps the chunk's own rows and its queries
+    double-buffered, its float32 output and accumulators, and folds the
+    whole chunk at once — scores, their exponentials and the mask, each
+    ``[rows, chunk]`` float32 — inside ``_VMEM_LIMIT`` (the planner's
+    widest chunk, ``engine/prefill_plan.py::chunk_width``)."""
+    rows = _chunk_rows(chunk, group) * group
+    held = (2 * (chunk + rows) * width * itemsize  # own rows, queries
+            + 3 * rows * v_width * 4 + 2 * rows * 128 * 4  # out x 2, acc, m, l
+            + 2 * _CHUNK_BLOCK_TOKENS * width * itemsize  # the prefix block
+            + 3 * rows * chunk * 4)  # the fold of the chunk itself
+    return held <= _VMEM_LIMIT
 
 
 @functools.partial(

@@ -102,6 +102,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "chunk_admits",
     "chunk_query_tokens",
     "decode_block_tokens",
     "paged_attention_kernel",
@@ -2043,6 +2044,30 @@ def chunk_query_tokens(
     what ``/debug/state`` ``attn.chunk_query_blocks*`` count."""
     return _chunk_geometry(chunk, group, Hkv, page, D, dtype, q_dtype,
                            max_pages, None, None, quantized)[2]
+
+
+# The fewest query rows (tokens x the heads of a group) of a program at
+# which a wider chunk was still seen to pay: half a tile of the MXU's rows.
+# On a v5e at 64 rows Mellum2's [1, 2048] took 74.0 ms against two
+# [1, 1024] (256 rows) at 76.0, and Laguna's 4 096-token prompt 107.3 ms in
+# [1, 1024] against 130.6 in [1, 512] (128 rows); the next halving leaves
+# the chunk's own K and V no room beside one query (PERF.md §6 PR 54).
+_MIN_QUERY_ROWS = 64
+
+
+def chunk_admits(
+    chunk: int, group: int, Hkv: int, page: int, D: int, dtype, q_dtype,
+    max_pages: int, quantized: bool = False,
+) -> bool:
+    """Whether ``paged_chunk_attention_kernel`` takes a chunk this wide at
+    pace, from the shapes alone: it holds the chunk's own K and V
+    double-buffered (``heads x D x 4 x chunk`` elements), and what that
+    leaves of ``_CHUNK_VMEM_BUDGET`` must still hold the blocks of a
+    program of ``_MIN_QUERY_ROWS`` query rows (``_chunk_geometry``; the
+    planner's widest chunk, ``engine/prefill_plan.py::chunk_width``)."""
+    cblk = chunk_query_tokens(
+        chunk, group, Hkv, page, D, dtype, q_dtype, max_pages, quantized)
+    return cblk == chunk or cblk * group >= _MIN_QUERY_ROWS
 
 
 @functools.partial(

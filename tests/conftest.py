@@ -99,6 +99,28 @@ def _no_program_store(request, monkeypatch):
             "radixmesh_tpu.engine.programs.store_dir", lambda: None)
 
 
+@pytest.fixture(autouse=True)
+def _chunks_as_given(request, monkeypatch):
+    """Engines of the suite launch chunks of the ``prefill_chunk`` their
+    test gave them, unless the test asks for ``planned_width``. The width
+    the planner reads from the weights (``prefill_plan.chunk_width``, PR
+    54) is the power of two that holds the floor — hundreds of tokens for
+    any model, a toy one included (the floor is a ratio of bytes to
+    arithmetic, not a size) — so a toy prompt of 40 tokens would be ONE
+    launch whatever ``prefill_chunk=16`` says, and the tests that chunk a
+    prompt to reach the paths between chunks would stop reaching them."""
+    if "planned_width" not in request.fixturenames:
+        monkeypatch.setattr(
+            "radixmesh_tpu.engine.engine.chunk_width",
+            lambda chunk, *_: chunk)
+
+
+@pytest.fixture
+def planned_width():
+    """Engines built in this test take the chunk width the planner reads
+    from their weights, as a served one does."""
+
+
 @pytest.fixture
 def program_store(tmp_path):
     """A program store of the test's own: JAX's persistent cache configured

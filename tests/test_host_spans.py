@@ -363,6 +363,13 @@ def test_debug_state_serves_the_span_book_and_the_prefill_account(served):
     assert pb["tokens_launched"] - pa["tokens_launched"] >= 20 * n
     assert pb["by_path"]["dense"] - pa["by_path"]["dense"] == 1
     assert pb["by_path"]["chunk"] - pa["by_path"]["chunk"] == 1
+    # every launch under its chunk (JSON keys are strings), and counted
+    # under the floor of ``prefill_cost`` or not (PR 54)
+    assert list(pb)[:5] == ["waves", "rows", "launches",
+                            "launches_below_floor", "by_chunk"]
+    assert sum(pb["by_chunk"].values()) == pb["launches"] > pa["launches"]
+    assert all(int(c) & (int(c) - 1) == 0 for c in pb["by_chunk"])
+    assert 0 <= pb["launches_below_floor"] <= pb["launches"]
     assert 0.0 < pb["wall_s"] - pa["wall_s"] <= d["rm.admit"][1]
     slow = b["engine"]["slow_steps"]
     assert slow["threshold_s"] == 1.0 and slow["n"] == len(slow["last"]) <= 32

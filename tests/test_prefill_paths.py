@@ -81,7 +81,7 @@ def test_a_lone_request_takes_the_path_its_prefix_gives_it(model, threshold):
     assert eng._last_prefill_path == "chunk"
     assert eng.prefill["by_path"]["chunk"] == before["chunk"] + 1
     assert eng.prefill["by_path"]["dense"] == before["dense"]
-    assert eng._wave_padded == 16  # one row, one chunk of the 16-token floor
+    assert eng._wave_shapes == [(1, 16)]  # one row, one chunk of the 16-token floor
 
 
 def test_the_dense_path_holds_no_prefix_code():

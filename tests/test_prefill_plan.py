@@ -19,6 +19,7 @@ import pytest
 from radixmesh_tpu.engine.prefill_plan import (
     KV_BLOCK_PAGES,
     boxed_tokens,
+    chunk_width,
     launch_cost,
     plan_prefill,
     pow2_at_least,
@@ -245,3 +246,201 @@ def test_a_launch_bound_by_its_weights_keeps_the_box():
     assert [(p.B, p.C, p.rows) for p in plan] == [
         (4, 512, (0, 1, 2)), (4, 512, (0, 1, 2))]
     assert KV_BLOCK_PAGES == 32 and plan[0].pages == 64
+
+
+# ----------------------------------------------------------------------
+# PR 54: the chunk's width comes from the same cost
+# ----------------------------------------------------------------------
+
+# What ``Engine.prefill_cost`` reads for the two models the cold-prompt
+# cells of PR 50 and PR 53 added (same arithmetic as ``MODELS``).
+MODELS |= {
+    "ling-3.0-flash-ep8": (5.63, 0.586),
+    "nemotron-3-nano-ep8": (4.71, 0.782),
+}
+LONGTAIL = json.loads((TRAFFIC / "batch-longtail.json").read_text())["params"]
+CELL_FILES = TRAFFIC.parent / "cells"
+
+
+def cost_of(model):
+    gb, g_met = MODELS[model]
+    return launch_cost(gb * 1e9, 2 * g_met * 1e9)
+
+
+def admissions(seed, n_admissions=60, cached=(0, 0, 0, 512, 4096)):
+    """Seeded admissions of 1-16 rows of (new tokens, cached tokens): new
+    tokens log-normal around 700 on a 16 grid up to 8 192 — every bucket,
+    tails of every size."""
+    rng = np.random.default_rng([54, seed])
+    for _ in range(n_admissions):
+        n = int(rng.integers(1, 17))
+        news = np.clip(np.exp(rng.normal(np.log(700), 1.0, n)), 16, 8192)
+        yield [(int(round(x / 16)) * 16, int(rng.choice(cached))) for x in news]
+
+
+def planned(rows, width, cost, **shape):
+    """``(sub-wave rows, its plan)`` of one admission, as ``Engine._admit``
+    and ``_prefill_group`` draw them."""
+    wide = {} if width == CHUNK else {"width": width}  # the parent has none
+    kw = dict(chunk=CHUNK, page_size=PAGE, max_pages=2048,
+              floor_tokens=cost[0], launch_tokens=cost[1], **shape, **wide)
+    for sub in subwaves([n for n, _ in rows], chunk=CHUNK,
+                        wave_tokens=WAVE_TOKENS, **wide):
+        yield sub, plan_prefill([rows[i] for i in sub], **kw)
+
+
+# sha256 of the launches of ``admissions(seed)`` under the planner of the
+# parent commit (5d3249c: ``subwaves`` and ``plan_prefill`` at chunk 512),
+# and how many they are.
+GOLDEN = {
+    ("qwen2-7b-w8a16", 0): ("bc26678024e1a3d8", 1077),
+    ("qwen2-7b-w8a16", 1): ("50fe86cc1653e190", 1051),
+    ("qwen2-7b-w8a16", 2): ("1f0de84f14d58481", 1151),
+    ("qwen2-7b-w8a16", 3): ("1ed0d4ffed85f952", 1178),
+    ("phi-4-mini-flash", 0): ("a6c64c90dd2b3f98", 894),
+    ("phi-4-mini-flash", 1): ("e2afc836ddf7f1cb", 867),
+    ("phi-4-mini-flash", 2): ("af64727c8ad3f6a6", 950),
+    ("phi-4-mini-flash", 3): ("83b28215b0f63417", 975),
+}
+
+
+def digest(model, seed, width):
+    import hashlib
+
+    launches = [
+        [sub, [list(map(list, (p.rows, p.starts, p.lens))) + [p.B, p.C, p.pages]
+               for p in plan]]
+        for rows in admissions(seed)
+        for sub, plan in planned(rows, width, cost_of(model))]
+    text = json.dumps(launches, separators=(",", ":"))
+    return hashlib.sha256(text.encode()).hexdigest()[:16], sum(
+        len(plan) for _, plan in launches)
+
+
+@pytest.mark.parametrize("model,seed", GOLDEN)
+def test_a_floor_under_the_chunk_keeps_the_plans_of_the_parent(model, seed):
+    """Qwen2-7B int8 (floor 120) and Phi-4 (241): the width is the chunk,
+    and every sub-wave of every admission is launched as it was, launch
+    for launch."""
+    cost = cost_of(model)
+    assert cost[0] < CHUNK
+    width = chunk_width(CHUNK, cost[0], WAVE_TOKENS)
+    assert width == CHUNK
+    assert digest(model, seed, width) == GOLDEN[model, seed]
+
+
+@pytest.mark.parametrize("chunk,floor,wave_tokens,admits,width", [
+    (512, 120.1, 4096, None, 512),
+    (512, 241.0, 4096, None, 512),
+    (512, 692.0, 4096, None, 1024),
+    (512, 845.0, 4096, None, 1024),
+    (512, 1167.0, 4096, None, 2048),
+    (512, 1633.0, 4096, None, 2048),
+    (512, 1167.0, 4096, lambda w: w <= 1024, 1024),  # a kernel refuses 2 048
+    (512, 1633.0, 4096, lambda w: False, 512),  # and never under the chunk
+    (512, 5000.0, 4096, None, 4096),  # within a wave's tokens
+    (512, 1167.0, 1024, None, 1024),
+    (512, 1167.0, 3000, None, 2048),  # a wave that is no power of two
+    (512, 1167.0, 256, None, 512),
+    (512, float("inf"), 4096, None, 4096),
+    (16, 481.0, 4096, None, 512),  # a toy chunk under a real floor
+    (2048, 120.0, 4096, None, 2048),
+])
+def test_the_width_holds_the_floor_within_a_wave_and_the_kernels(
+        chunk, floor, wave_tokens, admits, width):
+    kw = {} if admits is None else {"admits": admits}
+    assert chunk_width(chunk, floor, wave_tokens, **kw) == width
+
+
+@pytest.mark.parametrize("model,seed", [
+    (m, s) for m in ("lfm2-24b-a2b-ep8", "mellum2-12b-l12", "laguna-xs.2-l5")
+    for s in range(3)])
+def test_a_wide_plan_fills_its_launches_within_a_wave(model, seed):
+    """Floors of 845, 1 167 and 1 633: no launch holds more than a wave's
+    tokens, every row's tokens are launched exactly once and in order, a
+    row's first launch holds no padded token of its own (its bucket's
+    chunk is one every row of the bucket fills), a row with more than a
+    whole width of tokens left is never sent under the floor, and buckets
+    up to twice the chunk are planned as they were."""
+    cost = cost_of(model)
+    width = chunk_width(CHUNK, cost[0], WAVE_TOKENS)
+    assert width == {"lfm2-24b-a2b-ep8": 1024}.get(model, 2048) >= cost[0]
+    wide = narrow = 0
+    for rows in admissions(seed):
+        before = {tuple(sub): plan for sub, plan in planned(rows, CHUNK, cost)}
+        for sub, plan in planned(rows, width, cost):
+            mine = [rows[i] for i in sub]
+            assert covered(mine, plan)
+            if max(n for n, _ in mine) <= 2 * CHUNK:
+                assert plan == before[tuple(sub)]
+            for launch in plan:
+                assert launch.B * launch.C <= WAVE_TOKENS
+                left = [mine[r][0] - s for r, s in zip(launch.rows, launch.starts)]
+                if max(left) > width:
+                    assert launch.C == width and launch.B * launch.C >= cost[0]
+                if launch.starts[0] == 0 and max(n for n, _ in mine) > CHUNK:
+                    assert set(launch.lens) == {launch.C}
+            wide += len(plan)
+        narrow += sum(map(len, before.values()))
+    assert wide < 0.9 * narrow  # and it is fewer launches
+
+
+def _programs(rows, width, cost, one_width, threshold=1024):
+    """The prefill programs one cold admission reaches, as ``_admit``
+    chooses them: a lone prompt bound by the weights it streams takes
+    ``prefill_forward`` at its bucket, everything else the plan's
+    launches (``planned`` gives an engine 2 048 pages: a lone row's)."""
+    out = set()
+    for sub, plan in planned(rows, width, cost, one_width=one_width):
+        n = rows[sub[0]][0]
+        if len(sub) == 1 and n <= threshold and pow2_at_least(n) <= cost[0]:
+            out.add(("dense", pow2_at_least(n)))
+        else:
+            out |= {("chunk", p.B, p.C, p.pages) for p in plan}
+    return out
+
+
+# cell: (model, its chunk programs take one width whatever the rows)
+LONGTAIL_CELLS = {
+    "mellum2-12b-l12.batch-longtail": ("mellum2-12b-l12", False),
+    "glm-4.7-flash-l8.batch-longtail": ("glm-4.7-flash-l8", False),
+    "ling-3.0-flash-ep8.batch-longtail": ("ling-3.0-flash-ep8", True),
+}
+
+
+@pytest.mark.parametrize("width", [1024, 2048])
+@pytest.mark.parametrize("name", LONGTAIL_CELLS)
+def test_a_wide_longtail_wave_launches_what_the_warm_up_compiled(name, width):
+    """Shape closure at the widths the three cold-prompt cells may take
+    (2 048 by their floors; 1 024 where a kernel refuses that): whatever
+    a mixed admission of the cell's own length distribution launches, the
+    cell's warm-up has compiled — its census cover (every length alone)
+    and its bursts (``n`` alike rows behind a blocker), read from the cell
+    file as ``benchmarks/run.py::warm_shapes`` reads it — and the programs
+    are no more than today's and five (a model whose launches all take
+    one table width shared ``[B, 512]`` between its buckets: Ling)."""
+    from benchmarks.generators.sessions import draw
+
+    model, one_width = LONGTAIL_CELLS[name]
+    cost = cost_of(model)
+    assert chunk_width(CHUNK, cost[0], WAVE_TOKENS) == 2048
+    warm = json.loads((CELL_FILES / f"{name}.json").read_text())["warmup"]
+    lengths = grid(LONGTAIL["turn_tokens"])
+    as_list = lambda x: x if isinstance(x, list) else [x]
+    bursts = [[(t, 0)] * n for b in warm["bursts"]
+              for n in as_list(b["n"]) for t in as_list(b["new_tokens"])]
+    blockers = [[(b["blocker"], 0)] for b in warm["bursts"]]
+
+    def compiled(w):
+        return set().union(*(
+            _programs(rows, w, cost, one_width)
+            for rows in [[(n, 0)] for n in lengths] + bursts + blockers))
+
+    have = compiled(width)
+    assert len(have) <= len(compiled(CHUNK)) + 5
+    rng = np.random.default_rng([54, 3])
+    for _ in range(400):
+        n = int(rng.integers(1, 13))
+        rows = [(int(t), 0) for t in draw(LONGTAIL["turn_tokens"], rng, n)]
+        launched = _programs(rows, width, cost, one_width)
+        assert launched <= have, (rows, launched - have)

@@ -64,7 +64,8 @@ def test_prefill_launch_site_counts_its_work(model, case):
     eng = _engine(model, f"acct-{case}")
     if cached:
         _run(eng, SHARED)  # publishes the prefix
-    before = {**eng.prefill, "by_path": dict(eng.prefill["by_path"])}
+    before = {**eng.prefill, "by_path": dict(eng.prefill["by_path"]),
+              "by_chunk": dict(eng.prefill["by_chunk"])}
     admit_before = eng.spans.s["rm.admit"]
     reqs = _run(eng, *prompts)
     assert all(r.prefix_len == cached for r in reqs)
@@ -74,7 +75,14 @@ def test_prefill_launch_site_counts_its_work(model, case):
     assert p["rows"] - before["rows"] == len(prompts)
     assert p["tokens_new"] - before["tokens_new"] == new
     launched = p["tokens_launched"] - before["tokens_launched"]
-    assert launched == eng._wave_padded >= new
+    assert launched == sum(b * c for b, c in eng._wave_shapes) >= new
+    # every launch is counted under its chunk, and under the floor or not
+    launches = p["launches"] - before["launches"]
+    assert launches == len(eng._wave_shapes) == sum(
+        n - before["by_chunk"].get(c, 0) for c, n in p["by_chunk"].items())
+    assert set(p["by_chunk"]) >= {c for _, c in eng._wave_shapes}
+    below = p["launches_below_floor"] - before["launches_below_floor"]
+    assert below == sum(b * c < eng.prefill_cost[0] for b, c in eng._wave_shapes)
     assert {k: v - before["by_path"][k] for k, v in p["by_path"].items()} == {
         "dense": int(path == "dense"), "chunk": int(path == "chunk"), "sp": 0}
     assert eng._last_prefill_path == path
