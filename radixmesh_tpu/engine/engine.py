@@ -537,7 +537,13 @@ class Engine:
         # decode_groups: the last decode launch's groups, members, passes.
         self._moe_stats = (new_moe_stats(cfg.ep_size > 1, cfg.n_group > 1)
                            if cfg.n_experts else None)
-        self.attn_window = {"window_tokens_skipped": 0, "window_tokens_read": 0}
+        # decode_context_tokens: the live rows' context (this token
+        # included) summed over decode launches — bytes of K/V a step read,
+        # without guessing the mean context; table_pages_max: the widest
+        # page table any launch carried (a decode step's bucket, a prefill
+        # launch's), in pages of ``pool.page_size``.
+        self.attn_window = {"window_tokens_skipped": 0, "window_tokens_read": 0,
+                            "decode_context_tokens": 0, "table_pages_max": 0}
         if cfg.latent:
             self.attn_window |= {
                 "latent_tokens_read": 0, "latent_prefix_tokens": 0,
@@ -799,10 +805,12 @@ class Engine:
         # against eviction until its first chunk has read them.
         self.window_stats = None
         self._resuming: collections.Counter = collections.Counter()
-        # Snapshots of megabytes (a window tail, a kda layer's matrices)
-        # are given up in order — superseded ones first, never one a
-        # request is about to resume from — and a few at a time.
-        self._ordered_evict = bool(cfg.matrix_state) or self.pool.window is not None
+        # Snapshots of megabytes (a window tail, a kda layer's matrices, the
+        # states of two dozen mamba layers) are given up in order —
+        # superseded ones first, never one a request is about to resume
+        # from — and a few at a time.
+        self._ordered_evict = bool(
+            cfg.matrix_state or cfg.has_ssm or self.pool.window is not None)
         if self.pool.window is not None:
             self.window_stats = {
                 "slots": self.pool.window_slots,
@@ -819,7 +827,7 @@ class Engine:
                 cfg.layer_types.count("full_attention")
                 + cfg.layer_types.count("cross_attention"))
             self._kv_reads = (
-                "shared_kv_tokens_read" if cfg.has_ssm else "full_tokens_read")
+                "shared_kv_tokens_read" if cfg.cross_decoder else "full_tokens_read")
             self.attn_window[self._kv_reads] = 0
             gib = 2.0 ** 30
             states = [a for a in (self.pool.state, self.pool.state_conv)
@@ -2243,8 +2251,15 @@ class Engine:
         resume from (``RadixTree.evict_states``)."""
         if not self._ordered_evict:
             return self.tree.evict_states(n)
+        # ... nor a live row's own prompt snapshot before a decode step of
+        # the row has completed a page: until then its release leaves no
+        # deeper one (at a page of 128 a reply of 64 tokens often never
+        # does), and the session's next turn would compute its prefix again.
+        own = {slot for req in self._rows
+               if req is not None and not req.state_boundary_len
+               for slot in req.state_handed}
         return self.tree.evict_states(min(n, _TAIL_EVICT_BATCH),
-                                      pinned=self._resuming)
+                                      pinned=own.union(self._resuming))
 
     # ------------------------------------------------------------------
     # the window group (a model whose window layers keep rows of their own)
@@ -2377,7 +2392,7 @@ class Engine:
         self._unpin(req.state_resume)
         req.state_slot = req.state_boundary = req.state_resume = 0
         req.state_boundary_len = 0
-        req.state_snaps = []
+        req.state_snaps, req.state_handed = [], []
         if self.window_stats is not None:
             self._free_window(req)
 
@@ -2393,6 +2408,8 @@ class Engine:
             st = self.state_stats or self.tail_stats
             if self.tree.attach_state(key[:pos], slot or None, tail):
                 st["snapshots"] += 1
+                if slot:
+                    req.state_handed.append(slot)
                 if tail is not None:
                     self.window_stats["tail_slots_in_tree"] += (
                         len(tail) * self.page_size)
@@ -2786,7 +2803,7 @@ class Engine:
             moe_kw["snap_at"] = jnp.asarray([[at for at, _ in wanted]], jnp.int32)
         windowed = self.window_stats is not None
         # A cross-decoder computes the full layer's last position alone.
-        last_only = self.cfg.has_ssm
+        last_only = self.cfg.cross_decoder
         if last_only:
             moe_kw["new_lengths"] = jnp.full((1,), n_new, dtype=jnp.int32)
         logits, new_k, new_v, *aux = self._launch(
@@ -3005,6 +3022,8 @@ class Engine:
             nv = np.asarray(launch.lens, dtype=np.int64)  # rows a run (kv.write)
             starts = reuses[idx] + done  # [n] tokens in the pool before
             self._wave_shapes.append((B, C))
+            self.attn_window["table_pages_max"] = max(
+                self.attn_window["table_pages_max"], maxp)
             toks = np.zeros((B, C), dtype=np.int32)
             sl = np.full((B, C), self._scratch_slot, dtype=np.int32)
             for j, i in enumerate(launch.rows):
@@ -3319,6 +3338,8 @@ class Engine:
         aw = self.attn_window  # once per launch, whenever it was built
         aw["window_tokens_read"] += launch.window[0]
         aw["window_tokens_skipped"] += launch.window[1]
+        aw["decode_context_tokens"] += int(launch.contexts.sum())
+        aw["table_pages_max"] = max(aw["table_pages_max"], launch.pt_d.shape[1])
         if launch.latent_tokens:
             attended = int(launch.contexts.sum()) * len(self.cfg.attn_layers)
             aw["latent_tokens_read"] += launch.latent_tokens

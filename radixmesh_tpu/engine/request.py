@@ -97,6 +97,10 @@ class Request:
     state_boundary_len: int = 0
     state_resume: int = 0
     state_snaps: list = field(default_factory=list)
+    # ... and the slots of those the tree took while the request lives: its
+    # own resume points until a decode step of its has completed a page
+    # (``Engine._evict_states`` keeps them till then).
+    state_handed: list = field(default_factory=list)
     # A model with a window group of rows: the pages of that group this
     # request counts on, for logical pages ``w_first ..`` of its sequence
     # in order (the tail of the snapshot it resumed from, then its own);

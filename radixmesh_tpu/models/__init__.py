@@ -7,7 +7,7 @@ from radixmesh_tpu.models.llama import (
     convert_hf_state_dict,
 )
 from radixmesh_tpu.models import (
-    bailing_hybrid, glm4_moe_lite, glm_moe_dsa, laguna, lfm2_moe, mellum2,
+    bailing_hybrid, glm4_moe_lite, glm_moe_dsa, jamba, laguna, lfm2_moe, mellum2,
     nemotron_h, phi4flash, qwen2,
 )
 
@@ -47,6 +47,8 @@ _PRESETS = {
     "nemotron-3-nano-30b-a3b": nemotron_h.nemotron3_nano,
     "nemotron-3-nano-ep8": nemotron_h.nemotron3_nano_ep8,
     "nemotron-tiny": nemotron_h.nemotron_tiny,
+    "jamba2-3b": jamba.jamba2_3b,
+    "jamba-tiny": jamba.jamba_tiny,
 }
 
 

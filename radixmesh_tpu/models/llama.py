@@ -215,7 +215,13 @@ class ModelConfig:
     # and the paged kernels see that many "wide" kv heads (``_pair_heads``).
     pair_kv_heads: bool = False
     # Selective state-space layers (``layer_types`` entry "mamba": Mamba-1,
-    # ``ssm_state`` > 0) in a decoder-hybrid-decoder (``models/
+    # ``ssm_state`` > 0), in one of two layouts. INTERLEAVED with plain GQA
+    # "full_attention" layers (``models/jamba.py``): every layer "norm,
+    # operator, add, norm, SwiGLU, add" under RMSNorm, a layer of the
+    # by-kind body (``_scan_mixed``: ``params["mamba"]`` / ``params["attn"]``),
+    # ``ssm_inner_norms`` True: RMSNorm with a learned weight over each of
+    # delta, B and C as ``x_proj`` gives them. Or a decoder-hybrid-decoder
+    # (``cross_decoder``; ``models/
     # phi4flash.py``): pairs of (mamba, sliding_attention), one more mamba
     # layer whose scan output is kept per token as the MEMORY, the one
     # full_attention layer, then pairs of ("gmu", "cross_attention") — a
@@ -231,6 +237,7 @@ class ModelConfig:
     ssm_conv: int = 0
     ssm_expand: int = 2
     ssm_dt_rank: int = 0
+    ssm_inner_norms: bool = False
     # Differential attention: heads pair up, the second softmax map is
     # subtracted ``lambda`` times, an RMSNorm over the pair's 2 * head_dim
     # outputs follows (``_diff_combine``). ``use_rope`` False: no
@@ -525,7 +532,9 @@ class ModelConfig:
 
     def _check_hybrid(self) -> None:
         """A config with mamba / gmu / cross_attention layers runs as one
-        shape only (class docstring); anything else is refused by name."""
+        of two layouts (class docstring: interleaved with plain attention
+        layers, or the decoder-hybrid-decoder); anything else is refused by
+        name."""
         types = tuple(self.layer_types)
         kinds = set(types)
         known = {"mamba", "sliding_attention", "full_attention", "gmu",
@@ -534,6 +543,27 @@ class ModelConfig:
             raise ValueError(
                 f"layer_types must name {self.n_layers} layers, got "
                 f"{len(types)} of {sorted(kinds)}")
+        if not (self.ssm_state > 0 and self.ssm_conv >= 2 and self.ssm_dt_rank > 0
+                and (self.ssm_expand * self.hidden) % 128 == 0):
+            raise ValueError(
+                "mamba layers need ssm_state, ssm_dt_rank > 0, ssm_conv >= 2 "
+                "and ssm_expand * hidden in whole 128-lane rows")
+        if not self.cross_decoder:
+            # mamba layers interleaved with plain attention layers
+            if kinds != {"mamba", "full_attention"} or (
+                    self.n_experts or self.latent or self.qkv_bias
+                    or self.rope_by_type or self.pair_kv_heads or self.layer_norm
+                    or self.attn_out_bias or self.qk_norm or self.attn_gate):
+                raise ValueError(
+                    "layer_types with mamba layers run as one of two layouts: "
+                    "mamba layers interleaved with plain GQA full_attention "
+                    "layers (the paged pool and the radix tree need one layer "
+                    "that owns rows) under RMSNorm with dense SwiGLU FFNs — no "
+                    "experts, latent attention, qkv_bias, rope_by_type, "
+                    "pair_kv_heads, layer_norm or output bias —, or "
+                    "(diff_attention) (mamba, sliding_attention) pairs, mamba, "
+                    f"full_attention, (gmu, cross_attention) pairs; got {types}")
+            return
         full = [i for i, t in enumerate(types) if t == "full_attention"]
         for i, t in enumerate(types):
             if t == "cross_attention" and not any(f < i for f in full):
@@ -557,11 +587,6 @@ class ModelConfig:
                 "layer_types this program runs: (mamba, sliding_attention) "
                 "pairs, mamba, full_attention, (gmu, cross_attention) pairs; "
                 f"got {types}")
-        if not (self.ssm_state > 0 and self.ssm_conv >= 2 and self.ssm_dt_rank > 0
-                and (self.ssm_expand * self.hidden) % 128 == 0):
-            raise ValueError(
-                "mamba layers need ssm_state, ssm_dt_rank > 0, ssm_conv >= 2 "
-                "and ssm_expand * hidden in whole 128-lane rows")
         if "sliding_attention" in kinds and (
                 self.sliding_window < 2 or self.sliding_window % 16):
             raise ValueError(
@@ -577,6 +602,11 @@ class ModelConfig:
                 "attention over paired heads of 64 (n_heads = 2 * "
                 "n_kv_heads), no rotary, LayerNorm, qkv and output biases, "
                 "a tied head, dense FFNs: nothing else is implemented")
+        if self.ssm_inner_norms:
+            raise ValueError(
+                "ssm_inner_norms belongs to mamba layers interleaved with "
+                "full_attention layers: the decoder-hybrid-decoder's Mamba "
+                "operator has none")
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -584,6 +614,24 @@ class ModelConfig:
     @property
     def has_ssm(self) -> bool:
         return bool(self.layer_types) and "mamba" in self.layer_types
+
+    @property
+    def cross_decoder(self) -> bool:
+        """Mamba layers in the decoder-hybrid-decoder layout (``_hybrid_*``)
+        — what differential attention or any of its other layer kinds asks
+        for — and not interleaved with plain attention layers (a kind of
+        the by-kind body)."""
+        kinds = set(self.layer_types or ())
+        return bool(kinds and (
+            self.diff_attention or kinds & {"gmu", "cross_attention"}
+            or {"mamba", "sliding_attention"} <= kinds))
+
+    @property
+    def carried_state(self) -> bool:
+        """Do the state slots' two arrays ride the by-kind body's carry,
+        read and written inside the layer (``matrix_state``, or mamba
+        layers interleaved with attention)?"""
+        return bool(self.matrix_state or (self.has_ssm and not self.cross_decoder))
 
     def _layers_of(self, kind: str) -> tuple[int, ...]:
         return tuple(i for i, t in enumerate(self.layer_types or ())
@@ -679,7 +727,7 @@ class ModelConfig:
     @property
     def own_stacks(self) -> bool:
         """Do the operators' leaves lie in a stack a kind (``_scan_mixed``)?"""
-        return bool(self.has_conv or self.heads_by_type or self.matrix_state)
+        return bool(self.has_conv or self.heads_by_type or self.carried_state)
 
     @property
     def conv_layers(self) -> tuple[int, ...]:
@@ -691,9 +739,9 @@ class ModelConfig:
     def attn_layers(self) -> tuple[int, ...]:
         """The layers that own cache rows: all of them, or, beside conv
         layers, the attention layers alone."""
-        if self.window_layers or self.matrix_state:
+        if self.window_layers or self.carried_state:
             # the full layers: the window layers' rows are another group,
-            # a kda layer or a mamba2 / moe block leaves none
+            # a kda or mamba layer or a mamba2 / moe block leaves none
             return self._layers_of("full_attention")
         if not self.has_conv:
             return tuple(range(self.n_layers))
@@ -1211,7 +1259,7 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
 
     keys = jax.random.split(key, 9)
     H, V = cfg.hidden, cfg.vocab_size
-    if cfg.has_ssm:
+    if cfg.cross_decoder:
         params = _draw_hybrid(jax.random.fold_in(key, 106), cfg)
         n_blk = _vocab_blocks(V, H, H * cfg.intermediate)
         w, _ = _draw_blocks(keys[7], n_blk, (V // n_blk, H), H, cfg.dtype, None)
@@ -1225,7 +1273,7 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
     # Beside conv layers the operators' leaves are stacks of their own
     # (below): the per-layer stacks keep the norms and the FFN.
     op_dims = {} if (cfg.has_conv or cfg.heads_by_type or cfg.has_kda
-                     ) else _attn_dims(cfg)
+                     or cfg.has_ssm) else _attn_dims(cfg)
     dims = dict(op_dims)
     dims |= {"w_router": (H, cfg.n_experts)} if cfg.n_experts else ffn
     layers = _draw_stack(keys, L, dims, cfg, axis(0))
@@ -1294,6 +1342,8 @@ def _init_params(key, cfg: ModelConfig, weight_quant, mesh) -> dict:
         params |= _draw_kinds(jax.random.fold_in(key, 107), cfg)
     if cfg.has_kda:
         params |= _draw_kda(jax.random.fold_in(key, 108), cfg)
+    if cfg.has_ssm:
+        params |= _draw_interleaved(jax.random.fold_in(key, 110), cfg)
     return _draw_vocab(params, cfg, keys, axis, mesh, ffn_width)
 
 
@@ -1453,6 +1503,21 @@ def param_logical_axes(cfg: ModelConfig, params: dict | None = None) -> dict:
             "conv_w": ("layer", None, "q_heads"), "A_log": ("layer", "q_heads"),
             "dt_bias": ("layer", "q_heads"), "o_norm": ("layer", "q_heads"),
         }
+    if cfg.has_ssm and not cfg.cross_decoder:
+        # Engine refuses a mesh for such a config; the names say what a tp
+        # share would split (a mamba operator by channel, as the FFN).
+        axes["attn"] = {name: axes["layers"].pop(name)
+                        for name in ("wq", "wk", "wv", "wo")}
+        by_channel = ("layer", "ffn")
+        axes["mamba"] = {
+            "in_x": ("layer", "embed", "ffn"), "in_z": ("layer", "embed", "ffn"),
+            "x_proj": ("layer", "ffn", None), "dt_proj": ("layer", None, "ffn"),
+            "out_proj": ("layer", "ffn", "embed"), "conv_w": ("layer", None, "ffn"),
+            "conv_b": by_channel, "dt_bias": by_channel, "D": by_channel,
+            "A_log": ("layer", None, "ffn")}
+        if cfg.ssm_inner_norms:
+            axes["mamba"] |= {"dt_norm": ("layer", None), "b_norm": ("layer", None),
+                              "c_norm": ("layer", None)}
     if cfg.has_mamba2:
         # Engine refuses a mesh for such a config; the names say what a tp
         # share would split (a mamba2 block by head: one group a chip).
@@ -1839,6 +1904,49 @@ def _kda_out(lp: dict, o: jnp.ndarray, h: jnp.ndarray, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
+def _draw_mamba(keys, cfg: ModelConfig) -> dict:
+    """The Mamba-1 operators' stack (``_draw_hybrid``'s docstring has the
+    leaves), one key of the iterator ``keys`` a draw; under
+    ``ssm_inner_norms`` also ``dt_norm`` [R], ``b_norm`` / ``c_norm`` [N],
+    seeded draws around 1."""
+    H, di, N, R = cfg.hidden, cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    n_m, f32 = len(cfg.mamba_layers), jnp.float32
+
+    def near(width: int, centre: float, spread: float):
+        return (centre + spread * jax.random.normal(next(keys), (n_m, width), f32)
+                ).astype(cfg.dtype)
+
+    mamba = {name: _draw_blocks(next(keys), n_m, (i, o), i, cfg.dtype, None)[0]
+             for name, (i, o) in {
+                 "in_x": (H, di), "in_z": (H, di), "x_proj": (di, R + 2 * N),
+                 "dt_proj": (R, di), "out_proj": (di, H)}.items()}
+    mamba["conv_w"], _ = _draw_blocks(
+        next(keys), n_m, (cfg.ssm_conv, di), cfg.ssm_conv, cfg.dtype, None)
+    mamba["conv_b"] = near(di, 0.0, 0.02)
+    dt = jnp.exp(jax.random.uniform(next(keys), (n_m, di), f32)
+                 * (np.log(1e-1) - np.log(1e-3)) + np.log(1e-3))
+    mamba["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+    mamba["A_log"] = jnp.broadcast_to(
+        jnp.log(jnp.arange(1, N + 1, dtype=f32))[None, :, None], (n_m, N, di))
+    mamba["D"] = jnp.ones((n_m, di), f32)
+    if cfg.ssm_inner_norms:
+        mamba |= {"dt_norm": near(R, 1.0, 0.1), "b_norm": near(N, 1.0, 0.1),
+                  "c_norm": near(N, 1.0, 0.1)}
+    return mamba
+
+
+def _draw_interleaved(key, cfg: ModelConfig) -> dict:
+    """The operators' leaves of a config whose mamba layers are interleaved
+    with attention layers, a stack a kind: ``mamba`` (``_draw_mamba``) and
+    ``attn`` (``wq`` / ``wk`` / ``wv`` / ``wo``)."""
+    keys = iter(jax.random.split(key, 24))
+    mamba = _draw_mamba(keys, cfg)
+    attn = {name: _draw_blocks(next(keys), len(cfg.attn_layers), shape, shape[0],
+                               cfg.dtype, None)[0]
+            for name, shape in _attn_dims(cfg).items()}
+    return {"mamba": mamba, "attn": attn}
+
+
 def _draw_hybrid(key, cfg: ModelConfig) -> dict:
     """The pytree of a config with mamba layers: ``layers`` — the two
     LayerNorms (weight and bias) and the SwiGLU of every layer, one stack
@@ -1857,11 +1965,10 @@ def _draw_hybrid(key, cfg: ModelConfig) -> dict:
     initialisation): a normal draw makes the state vanish or overflow
     within a page. Norm weights are seeded draws around 1 and the biases
     small non-zero draws, so that they are exercised."""
-    H, di, N, R, D = (cfg.hidden, cfg.ssm_inner, cfg.ssm_state,
-                      cfg.ssm_dt_rank, cfg.head_dim)
+    H, di, D = cfg.hidden, cfg.ssm_inner, cfg.head_dim
     qd, kvd = cfg.n_heads * D, cfg.n_kv_heads * D
     L = cfg.n_layers
-    n_m, n_c = len(cfg.mamba_layers), len(cfg._layers_of("cross_attention"))
+    n_c = len(cfg._layers_of("cross_attention"))
     n_a = len(cfg.window_layers) + 1
     f32 = jnp.float32
     keys = iter(jax.random.split(key, 64))
@@ -1885,17 +1992,7 @@ def _draw_hybrid(key, cfg: ModelConfig) -> dict:
     for name in ("attn_norm", "mlp_norm"):
         layers[name] = near(L, H, 1.0, 0.1)
         layers[name + "_b"] = near(L, H, 0.0, 0.02)
-    mamba = mats(n_m, {"in_x": (H, di), "in_z": (H, di), "x_proj": (di, R + 2 * N),
-                       "dt_proj": (R, di), "out_proj": (di, H)})
-    mamba["conv_w"], _ = _draw_blocks(
-        next(keys), n_m, (cfg.ssm_conv, di), cfg.ssm_conv, cfg.dtype, None)
-    mamba["conv_b"] = near(n_m, di, 0.0, 0.02)
-    dt = jnp.exp(jax.random.uniform(next(keys), (n_m, di), f32)
-                 * (np.log(1e-1) - np.log(1e-3)) + np.log(1e-3))
-    mamba["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
-    mamba["A_log"] = jnp.broadcast_to(
-        jnp.log(jnp.arange(1, N + 1, dtype=f32))[None, :, None], (n_m, N, di))
-    mamba["D"] = jnp.ones((n_m, di), f32)
+    mamba = _draw_mamba(keys, cfg)
     attn = mats(n_a, {"wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd),
                       "wo": (qd, H)})
     attn |= diff_leaves(n_a) | {"bk": near(n_a, kvd, 0.0, 0.02),
@@ -1975,7 +2072,8 @@ def _mamba(mp: dict, u: jnp.ndarray, h0: jnp.ndarray, conv0: jnp.ndarray,
     term: the MEMORY where this is the memory layer —, h [B, K, N, di],
     conv [B, K, taps - 1, di])``: the state after ``snap_at`` [B, K]
     positions each (``ops/ssm.py``); ``snap_at`` None: one position, one
-    state (a decode step: no kernel, no gather)."""
+    state (a decode step: no kernel, no gather). Under ``ssm_inner_norms``
+    delta, B and C pass an RMSNorm each as ``x_proj`` gives them."""
     taps, N, R, S = cfg.ssm_conv, cfg.ssm_state, cfg.ssm_dt_rank, u.shape[1]
     f32 = jnp.float32
     with jax.named_scope("rm_ssm"):
@@ -1986,11 +2084,15 @@ def _mamba(mp: dict, u: jnp.ndarray, h0: jnp.ndarray, conv0: jnp.ndarray,
         xc = sum(w[k] * xs[:, k:k + S].astype(f32) for k in range(taps))
         xc = jax.nn.silu(xc + mp["conv_b"].astype(f32))
         dbc = _wmm(mp, "x_proj", "bsd,dr->bsr", xc.astype(x.dtype))
-        dt = jnp.einsum("bsr,rd->bsd", dbc[..., :R], mp["dt_proj"],
-                        precision=_PREC, preferred_element_type=f32)
+
+        def normed(t, name):  # the inner norms of an interleaved layer
+            return rms_norm(t, mp[name], cfg.rms_eps) if cfg.ssm_inner_norms else t
+
+        dt = jnp.einsum("bsr,rd->bsd", normed(dbc[..., :R], "dt_norm"),
+                        mp["dt_proj"], precision=_PREC, preferred_element_type=f32)
         dt = jax.nn.softplus(dt + mp["dt_bias"])
-        b = dbc[..., R:R + N].astype(f32)
-        c = dbc[..., R + N:].astype(f32)
+        b = normed(dbc[..., R:R + N], "b_norm").astype(f32)
+        c = normed(dbc[..., R + N:], "c_norm").astype(f32)
         a = -jnp.exp(mp["A_log"])
         if snap_at is None:
             y, h = ssm_step(xc[:, 0], dt[:, 0], b[:, 0], c[:, 0], a, mp["D"], h0)
@@ -2002,6 +2104,25 @@ def _mamba(mp: dict, u: jnp.ndarray, h0: jnp.ndarray, conv0: jnp.ndarray,
         gated = (y * jax.nn.silu(z.astype(f32))).astype(u.dtype)
         out = _wmm(mp, "out_proj", "bsd,dh->bsh", gated)
     return out, y.astype(u.dtype), h, conv
+
+
+def _states_in(cfg: ModelConfig, pools: tuple, op_idx, fresh, state_in) -> tuple:
+    """What a chunk's Mamba layer ``op_idx`` starts from: each row's ``(h,
+    conv)`` in slot ``state_in`` of the state slots ``pools``, zeros where
+    ``fresh`` [B, 1, 1] (the chunk starts at position 0)."""
+    h_pool, conv_pool = pools
+    return (jnp.where(fresh, 0, h_pool[op_idx, state_in]),
+            jnp.where(fresh, 0, _conv_rows(cfg, conv_pool[op_idx, state_in])))
+
+
+def _states_out(pools: tuple, op_idx, written, h, conv) -> tuple:
+    """``pools`` with the layer's states after ``snap_at`` tokens (``_mamba``'s
+    ``h`` / ``conv`` [B, K, ...]) in the slots ``written`` [B * K]: inside
+    the layer, the arrays updated in place."""
+    h_pool, conv_pool = pools
+    return (h_pool.at[op_idx, written].set(h.reshape(-1, *h.shape[2:])),
+            conv_pool.at[op_idx, written].set(
+                conv.reshape(written.shape[0], -1, 128).astype(conv_pool.dtype)))
 
 
 def _gmu(gp: dict, u: jnp.ndarray, memory: jnp.ndarray) -> jnp.ndarray:
@@ -2199,17 +2320,14 @@ def _hybrid_chunk(params, cfg: ModelConfig, tokens, positions, kv_pool, slots,
     written = state_out.reshape(-1)
 
     def mamba_layer(carry, lp, mp, op_idx):
-        x, h_pool, conv_pool = carry
-        h0 = jnp.where(fresh, 0, h_pool[op_idx, state_in])
-        conv0 = jnp.where(fresh, 0, _conv_rows(cfg, conv_pool[op_idx, state_in]))
+        x, *pools = carry
+        h0, conv0 = _states_in(cfg, pools, op_idx, fresh, state_in)
         op, y, h, conv = _mamba(mp, _ln(lp, "attn_norm", x, cfg), h0, conv0, cfg,
                                 snap_at, scan_kw)
-        h_pool = h_pool.at[op_idx, written].set(h.reshape(-1, *h.shape[2:]))
-        conv_pool = conv_pool.at[op_idx, written].set(
-            conv.reshape(written.shape[0], -1, 128).astype(conv_pool.dtype))
+        pools = _states_out(pools, op_idx, written, h, conv)
         x = x + op
         x = x + _mlp(lp, _ln(lp, "mlp_norm", x, cfg))
-        return (x, h_pool, conv_pool), y, None
+        return (x, *pools), y, None
 
     def window_layer(carry, lp, ap, op_idx, layer):
         x, *pools = carry
@@ -2330,7 +2448,7 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
         "disaggregated": "disaggregated prefill/decode (engine/disagg.py)",
         "hf_checkpoint": "loading a HuggingFace checkpoint (--weights)",
     }
-    if cfg.has_conv or cfg.window_layers or cfg.matrix_state:
+    if cfg.has_conv or cfg.window_layers or cfg.carried_state:
         # A prefix is handed back only where its state (or its window tail)
         # was snapshotted: whatever moves or advertises a prefix without it.
         named |= {
@@ -2361,7 +2479,7 @@ def unsupported_reason(cfg: ModelConfig, **options) -> str | None:
                          cfg.has_mamba2),
                         ("un-gated (relu2) experts", not cfg.mlp_gated),
                         ("a cross-decoder that reads one layer's K/V",
-                         cfg.has_ssm),
+                         cfg.cross_decoder),
                         ("attention kinds of two shapes in stacks of their "
                          "own (a gated output, a partly rotated head)",
                          cfg.heads_by_type),
@@ -2448,7 +2566,7 @@ def _layer_tables(cfg: ModelConfig) -> dict | None:
             "dsa_full": jnp.asarray(full),
             "dsa_slot": jnp.asarray(np.cumsum(full) - 1, jnp.int32),
         }
-    if cfg.layer_types is None or cfg.has_conv or cfg.matrix_state:
+    if cfg.layer_types is None or cfg.has_conv or cfg.carried_state:
         return tables or None
     by_type = {kind: _type_rope(cfg, kind)[:2] for kind, _ in cfg.rope_by_type}
     return tables | {
@@ -2587,6 +2705,8 @@ def _layer_kinds(cfg: ModelConfig) -> list[str]:
         return ["conv" if t == "conv" else "attn" for t in cfg.layer_types]
     if cfg.has_kda:
         return ["kda" if t == "kda" else "mla" for t in cfg.layer_types]
+    if cfg.has_ssm:
+        return ["mamba" if t == "mamba" else "attn" for t in cfg.layer_types]
     if cfg.has_mamba2:  # blocks: a mixer or the expert FFN alone
         return ["attn" if t == "full_attention" else t for t in cfg.layer_types]
     return list(cfg.layer_types)
@@ -2766,6 +2886,16 @@ def _of_full_layers(cfg: ModelConfig, stacked: jnp.ndarray) -> jnp.ndarray:
     return stacked[np.asarray(cfg.full_layers)]
 
 
+def _full_attn_scope(cfg: ModelConfig, decode: bool = False):
+    """``rm_full_attn`` around the attention of a config whose other layers
+    carry a state in the by-kind body (a trace tells its few attention
+    layers apart by scope): all three programs' beside mamba layers, a
+    decode step's where the blocks hold one sublayer."""
+    return (jax.named_scope("rm_full_attn")
+            if cfg.has_ssm or (decode and cfg.has_mamba2)
+            else contextlib.nullcontext())
+
+
 def _attn_out(lp: dict, attn: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """The wo projection shared by every forward variant: attn is
     [B, S, Hq·D] (or already [B, S, Hq, D])."""
@@ -2838,7 +2968,7 @@ def prefill_forward(
     rows, 128]`` (the caller writes them to state slots).
     """
     p_max = cached_k.shape[2]
-    if cfg.has_ssm:
+    if cfg.cross_decoder:
         if p_max or new_lengths is None or snap_at is None:
             raise ValueError(
                 "prefill_forward of a model with state-space layers takes no "
@@ -2922,6 +3052,14 @@ def prefill_forward(
                 lp, h, jnp.zeros((B, cfg.ssd_conv - 1, cfg.ssd_conv_channels), x.dtype),
                 cfg, snap_at, run)
             states = (snaps, _conv_store(conv, cfg.ssd_conv_rows))
+        elif kind == "mamba":
+            # from a zero state; the states after ``snap_at`` go out
+            B = x.shape[0]
+            op, _, hs, conv = _mamba(
+                lp, h, jnp.zeros((B, cfg.ssm_state, cfg.ssm_inner), jnp.float32),
+                jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_inner), x.dtype),
+                cfg, snap_at, {})
+            states = (hs, conv.reshape(*conv.shape[:2], -1, 128))
         elif kind == "moe":
             op = None
         elif kind == "mla":
@@ -2940,8 +3078,9 @@ def prefill_forward(
                 k, v, wk, wv = None, None, k, v
         else:
             q, k, v = _attn_in(lp, h, cfg, positions, one_rope, None)
-            op = _attn_out(lp, attend_prefill(
-                q, k, v, attn_pos, kv_end, kv_start=pad), cfg)
+            with _full_attn_scope(cfg):
+                attn = attend_prefill(q, k, v, attn_pos, kv_end, kv_start=pad)
+            op = _attn_out(lp, attn, cfg)
             if cfg.pair_kv_heads:  # as the pool stores them
                 _, k, v = _pair_heads(cfg, q, k, v)
         x, aux = _sublayers(params, lp, xs, x, h, op, cfg, "prefill", valid,
@@ -3140,7 +3279,7 @@ def prefill_chunk_paged(
     window_pool, aux)``.
     """
     refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
-    if cfg.has_ssm:
+    if cfg.cross_decoder:
         return _hybrid_chunk(
             params, cfg, tokens, positions, kv_pool, slots, page_table,
             kv_lengths, page_size, kv_block_pages, use_kernel, interpret,
@@ -3171,7 +3310,8 @@ def prefill_chunk_paged(
     # the masking below stays finite either way.)
     prior_lengths = jnp.minimum(positions[:, 0], kv_lengths)
     valid = positions < kv_lengths[:, None]  # real tokens, for the counters
-    moe_kw = {} if use_kernel is None else {
+    # what every kernel of the chunk takes (the experts', the scan's)
+    kernel_kw = {} if use_kernel is None else {
         "use_kernel": use_kernel, "interpret": interpret,
     }
 
@@ -3235,6 +3375,13 @@ def prefill_chunk_paged(
             conv = _conv_store(conv, cfg.ssd_conv_rows).astype(c_pool.dtype)
             sel = (s_pool, c_pool.at[i, state_out.reshape(-1)].set(
                 conv.reshape(-1, *conv.shape[2:])))
+        elif kind == "mamba":
+            # as a kda layer: the slots are read and written HERE
+            h0, conv0 = _states_in(
+                cfg, sel, table["op_idx"], (positions[:, 0] == 0)[:, None, None],
+                state_in)
+            op, _, hs, conv = _mamba(lp, h, h0, conv0, cfg, snap_at, kernel_kw)
+            sel = _states_out(sel, table["op_idx"], state_out.reshape(-1), hs, conv)
         elif kind == "moe":
             op = None
         elif kind == "mla":
@@ -3268,16 +3415,17 @@ def prefill_chunk_paged(
                 q, k, v = _pair_heads(cfg, q, k, v)
                 scale = cfg.head_dim ** -0.5
             k, v = k.astype(kv_pool.dtype), v.astype(kv_pool.dtype)
-            attn = paged_chunk_attention(
-                q, k, v, kv_pages, page_table, positions, prior_lengths,
-                kv_lengths, table["op_idx"], kv_block_pages=kv_block_pages,
-                use_kernel=use_kernel, interpret=interpret, scale=scale,
-            )
+            with _full_attn_scope(cfg):
+                attn = paged_chunk_attention(
+                    q, k, v, kv_pages, page_table, positions, prior_lengths,
+                    kv_lengths, table["op_idx"], kv_block_pages=kv_block_pages,
+                    use_kernel=use_kernel, interpret=interpret, scale=scale,
+                )
             if cfg.pair_kv_heads:
                 attn = _own_lanes(cfg, attn)
             op = _attn_out(lp, attn, cfg)
         x, aux = _sublayers(params, lp, xs, x, h, op, cfg, "prefill", valid,
-                            sparse, kind, **moe_kw)
+                            sparse, kind, **kernel_kw)
         return (x, sel), {"k": k, "v": v, "aux": aux, "state": states,
                           "wk": wk, "wv": wv}
 
@@ -3353,7 +3501,7 @@ def prefill_chunk_paged(
         x = x + _attn_out(lp, attn, cfg)
         h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         y, aux = _ffn(params, lp, l_idx, h2, cfg, "prefill", valid, sparse,
-                      **moe_kw)
+                      **kernel_kw)
         x = x + y
         if kv_scale is not None:
             return (x, sel), (k_int, v_int, k_sc, v_sc)
@@ -3396,7 +3544,7 @@ def prefill_chunk_paged(
               ALL_LAYERS)], window_slots, page_size)
         return (_logits(params, cfg, pick(x)), kv_pool, window_pool,
                 *_moe_aux(cfg, ys.get("aux"), moe_stats, "prefill"))
-    if cfg.matrix_state:
+    if cfg.carried_state:
         (x, state_pool), ys = _scan_layers(
             cfg, params, mixed_layer, (x, tuple(state_pool)))
         new_kv = ys["k"][None] if cfg.latent else jnp.stack([ys["k"], ys["v"]])
@@ -3486,7 +3634,7 @@ def decode_step(
     rows hold the same pages first (``ops/mla_attention.py::decode_groups``,
     from the launch's page table; its shape depends on ``B`` alone): the
     latent decode kernel then reads those pages once for all of them."""
-    if cfg.has_ssm:
+    if cfg.cross_decoder:
         refuse_unsupported(cfg, kv_quant=kv_scale is not None, device_mesh=mesh)
         return _hybrid_decode(
             params, cfg, tokens, kv_pool, slots, page_table, lengths, page_size,
@@ -3585,6 +3733,15 @@ def _decode_core(
                 lp, h, _ssd_conv_load(cfg, c_pool[i, work]), cfg, None, run)
             window_pool = (s_pool, c_pool.at[i, work].set(_conv_store(
                 conv[:, 0], cfg.ssd_conv_rows).astype(c_pool.dtype)))
+        elif kind == "mamba":
+            # the row's working slot, read and written in place
+            h_pool, c_pool = window_pool
+            i, work = table["op_idx"], state_slots[0]
+            op, _, hs, conv = _mamba(
+                lp, h, h_pool[i, work], _conv_rows(cfg, c_pool[i, work]), cfg,
+                None, {})
+            window_pool = (h_pool.at[i, work].set(hs[:, 0]), c_pool.at[i, work].set(
+                conv[:, 0].reshape(B, -1, 128).astype(c_pool.dtype)))
         elif kind == "moe":
             op = None
         elif kind == "mla":
@@ -3622,9 +3779,7 @@ def _decode_core(
             if cfg.pair_kv_heads:
                 q, k, v = _pair_heads(cfg, q, k, v)
                 scale = cfg.head_dim ** -0.5
-            # (a trace tells a one-sublayer config's blocks apart by scope)
-            with (jax.named_scope("rm_full_attn") if cfg.has_mamba2
-                  else contextlib.nullcontext()):
+            with _full_attn_scope(cfg, decode=True):
                 attn, kv_pages = paged_decode_attention(
                     q[:, 0], k[:, 0].astype(kv_pool.dtype),
                     v[:, 0].astype(kv_pool.dtype), kv_pool.reshape(pages_shape),
@@ -3644,7 +3799,7 @@ def _decode_core(
             cfg, params, mixed_layer, (x, kv_pool, window_pool))
         return (_logits(params, cfg, x)[:, 0], kv_pool, window_pool,
                 *_moe_aux(cfg, ys.get("aux"), moe_stats, "decode"))
-    if cfg.matrix_state:
+    if cfg.carried_state:
         (x, kv_pool, state_pool), ys = _scan_layers(
             cfg, params, mixed_layer, (x, kv_pool, tuple(state_pool)))
         # The boundary copies: the rows whose token completed a page keep

@@ -14,7 +14,9 @@ numbers on stdout. One JSON line a snapshot goes to ``--out`` (default
 ``engine.prefill`` (``by_path``: which launch the waves took),
 ``engine.spans``, ``engine.slow_steps``, ``process`` (``compiles.last``
 names each compiled program, when, and the span it compiled under),
-``attn`` (what decode launches attended, read and were spared)."""
+``attn`` (what decode launches attended, read and were spared), ``state``
+(where requests resumed, what the state slots hold and gave up) and
+``/stats``."""
 from __future__ import annotations
 
 import argparse
@@ -61,6 +63,8 @@ def main() -> int:
                 "engine": {k: state["engine"].get(k) for k in KEEP},
                 "process": state.get("process"),
                 "attn": state.get("attn"),
+                "state": state.get("state"),
+                "stats": snap["stats"],
             }) + "\n")
         return snap
 

@@ -36,6 +36,7 @@ _SIZES = {
     # stored 1920 wide: 23 x 128 x 2 x 2688 x 64 = 1.01 B)
     "nemotron-3-nano-30b-a3b": (32.55, 32.63),
     "nemotron-3-nano-ep8": (2.400, 2.401),  # 4.47 GiB in bf16 as stored
+    "jamba2-3b": (3.029, 3.030),  # the card's 3 B: 5.64 GiB in bf16, whole
 }
 
 # The model's public config.json (zai-org/GLM-4.7-Flash), as the catalog of
@@ -446,3 +447,41 @@ def test_nemotron3_presets_are_the_source_key_by_key():
     tiny = get_config("nemotron-tiny")
     assert tiny.layer_types == block_types("MEMEM*E" * 2 + "ME")
     assert tiny.moe_intermediate % 128 and not tiny.mlp_gated
+
+
+def test_jamba2_preset_is_the_source_key_by_key():
+    """The preset against the benchmark's configuration file (every key of
+    the catalog's copy of the source; the positions served under
+    ``published``), the layer order derived from period and offset, and the
+    tiny twin with every mechanism."""
+    import json
+    import os
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "jamba2-3b-bf16.json")) as fh:
+        src = json.load(fh)
+    cfg = get_config("jamba2-3b")
+    assert (cfg.hidden, cfg.n_layers, cfg.vocab_size, cfg.n_heads, cfg.n_kv_heads,
+            cfg.intermediate, cfg.rms_eps, cfg.tie_embeddings) == (
+        src["hidden_size"], src["num_hidden_layers"], src["vocab_size"],
+        src["num_attention_heads"], src["num_key_value_heads"],
+        src["intermediate_size"], src["rms_norm_eps"], src["tie_word_embeddings"])
+    assert cfg.head_dim == src["hidden_size"] // src["num_attention_heads"] == 128
+    assert (cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank, cfg.ssm_expand) == (
+        src["mamba_d_state"], src["mamba_d_conv"], src["mamba_dt_rank"],
+        src["mamba_expand"])
+    assert cfg.layer_types == tuple(
+        "full_attention" if i % src["attn_layer_period"] == src["attn_layer_offset"]
+        else "mamba" for i in range(src["num_hidden_layers"]))
+    assert list(cfg.layer_types) == src["layer_types"]
+    assert src["num_experts"] == 1 and not cfg.n_experts  # every FFN dense
+    assert src["mamba_conv_bias"] and not src["mamba_proj_bias"]
+    assert cfg.ssm_inner_norms and not cfg.use_rope and not cfg.qkv_bias
+    assert cfg.max_seq_len == src["max_position_embeddings"] == 131072
+    assert src["published"]["max_position_embeddings"] == 262144
+    assert cfg.has_ssm and not cfg.cross_decoder and cfg.matrix_state is None
+    assert cfg.own_stacks and cfg.carried_state and cfg.libtpu_init_args == ()
+    tiny = get_config("jamba-tiny")
+    assert tiny.layer_types == ("mamba", "full_attention", "mamba", "mamba") * 2
+    assert (tiny.n_kv_heads, tiny.ssm_inner_norms, tiny.use_rope) == (1, True, False)

@@ -567,3 +567,71 @@ def test_the_ssd_kernels_lower_at_nemotron3s_widths_and_ask_for_no_vmem(v5e, row
     call, = [ln for ln in text.splitlines()
              if "custom-call(" in ln and "radixmesh_ssd_decode" in ln]
     assert '"scoped_memory_configs":[]' in call
+
+
+def _jamba2_launch(sd, page: int, slots: int = 2 ** 21, rows: int = 64):
+    """Jamba2-3B's arguments as the cell's server holds them (2 M rows of
+    1 KiB, 321 state slots of 8.9 MiB), for a table of 131 072 positions a
+    row at ``page`` tokens a page."""
+    from radixmesh_tpu.engine.engine import pool_groups
+
+    cfg = get_config("jamba2-3b")
+    params = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(lambda k: _init_params(k, cfg, None, None),
+                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    groups = pool_groups(cfg, slots, rows, page)
+    state = (sd(groups["state_shape"], groups["state_dtype"]),
+             sd(groups["conv_state_shape"], cfg.dtype))
+    return cfg, params, sd(cfg.kv_pool_shape(slots), cfg.dtype), state
+
+
+def test_64_rows_of_131072_positions_lower_at_jamba2s_page_of_128(v5e):
+    """ROADMAP R11, way A: the paged kernels scalar-prefetch a launch's
+    whole page table, 8 B a row and page with the run flags, into 1 MiB of
+    SMEM. At this model's page of 128 tokens 131 072 positions a row are
+    1 024 entries: ``decode_step[64 rows]`` and the widest prefill wave (64
+    rows x 64 tokens, and 8 x 512) compile for one v5e with the pool (2
+    GiB) and the state slots (2.8 GiB) flowing through in place."""
+    sd = _sd(v5e)
+    i32, gib = jnp.int32, 2.0 ** 30
+    page, rows, width = 128, 64, 131072 // 128
+    cfg, params, pool, state = _jamba2_launch(sd, page)
+    assert cfg.max_seq_len == width * page
+    compiled = decode_step.lower(
+        params, cfg, sd((rows,), i32), pool, sd((rows,), i32),
+        sd((rows, width), i32), sd((rows,), i32), page, state_pool=state,
+        state_slots=sd((2, rows), i32)).compile()
+    mem = compiled.memory_analysis()
+    assert "radixmesh_decode_fused_mh" in compiled.as_text()
+    assert mem.alias_size_in_bytes / gib == pytest.approx(4.786, abs=0.01)
+    assert mem.temp_size_in_bytes / gib < 0.05
+    for r, c in ((64, 64), (8, 512)):
+        compiled = prefill_chunk_paged.lower(
+            params, cfg, sd((r, c), i32), sd((r, c), i32), pool, sd((r, c), i32),
+            sd((r, width), i32), sd((r,), i32), page_size=page,
+            logits_at=sd((r,), i32), state_pool=state, state_in=sd((r,), i32),
+            snap_at=sd((r, 3), i32), state_out=sd((r, 3), i32)).compile()
+        mem = compiled.memory_analysis()
+        text = compiled.as_text()
+        assert "radixmesh_chunk_attn" in text and "radixmesh_ssm_scan" in text
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes) / gib
+        assert live < 11.5, f"the {r} x {c} wave needs {live:.2f} GiB"
+
+
+def test_at_the_page_of_16_the_same_launch_is_refused(v5e):
+    """The old bound, recorded beside the new one: the same 64 rows x
+    131 072 positions at the page of 16 are 8 192 entries a row, 2.1 MB of
+    table and run flags, and the compiler refuses the decode step's kernel
+    for its scalar memory (what every other configuration's 16 384-49 152
+    served positions stay under)."""
+    sd = _sd(v5e)
+    i32 = jnp.int32
+    page, rows, width = 16, 64, 131072 // 16
+    cfg, params, pool, state = _jamba2_launch(sd, page)
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED.*smem"):
+        decode_step.lower(
+            params, cfg, sd((rows,), i32), pool, sd((rows,), i32),
+            sd((rows, width), i32), sd((rows,), i32), page, state_pool=state,
+            state_slots=sd((2, rows), i32)).compile()
