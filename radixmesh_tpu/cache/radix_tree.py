@@ -136,6 +136,13 @@ def match_len(a: np.ndarray, b: np.ndarray) -> int:
     return n if eq.all() else int(np.argmin(eq))
 
 
+def _concat_slots(values: Sequence[Any]) -> np.ndarray:
+    """The slot indices of a run of node values, as one int32 array."""
+    if not values:
+        return np.empty(0, dtype=np.int32)
+    return np.concatenate([np.asarray(v, dtype=np.int32) for v in values])
+
+
 def as_key(key: Sequence[int] | np.ndarray) -> np.ndarray:
     arr = np.asarray(key, dtype=np.int32)
     if arr.ndim != 1:
@@ -346,16 +353,10 @@ class MatchResult:
         return self.host_nodes[-1] if self.host_nodes else None
 
     def indices(self) -> np.ndarray:
-        if not self.values:
-            return np.empty(0, dtype=np.int32)
-        return np.concatenate([np.asarray(v, dtype=np.int32) for v in self.values])
+        return _concat_slots(self.values)
 
     def host_indices(self) -> np.ndarray:
-        if not self.host_values:
-            return np.empty(0, dtype=np.int32)
-        return np.concatenate(
-            [np.asarray(v, dtype=np.int32) for v in self.host_values]
-        )
+        return _concat_slots(self.host_values)
 
 
 class RadixTree:
@@ -645,30 +646,119 @@ class RadixTree:
     def _tracks_states(self) -> bool:
         return self.on_free_state is not None or self.on_free_tail is not None
 
+    def insert_below(
+        self,
+        node: TreeNode,
+        depth: int,
+        key: Sequence[int],
+        value: Any,
+        canon_from: int | None = None,
+    ) -> tuple[int, TreeNode, np.ndarray]:
+        """``insert`` then ``match_prefix`` of a sequence whose first
+        ``depth`` tokens are the path that ``node`` ENDS, in one walk of
+        the REST: ``key`` / ``value`` are the sequence's tokens and slots
+        from ``depth`` on. ``node`` is one a lock holds (or the root, with
+        ``depth`` 0): it and everything above it are in place and
+        device-resident, and where it ends does not move — a split by
+        another request leaves the lower half its identity — so nothing
+        above it is compared again.
+
+        Returns ``(present, last, canon)``: how many of ``key``'s tokens the
+        tree already held (an adopted span is not counted, as in
+        ``insert``), the deepest node of the sequence (``match_prefix``'s
+        ``last_node``: the one to lock; it ends at ``depth`` + the aligned
+        ``len(key)``), and the tree's own slots for the positions
+        ``[canon_from, that end)`` — ``canon_from <= depth`` (default
+        ``depth``), what lies above ``depth`` read off ``node`` and its
+        parents. The tree is left as the two root walks leave it, their
+        touches included (``last_access_time``, ``hit_count``): the path
+        above ``node`` is touched by climbing its parents, a step a node
+        and no token compared."""
+        key = as_key(key)
+        if len(value) != len(key):
+            raise ValueError(f"value length {len(value)} != key length {len(key)}")
+        if self.page_size > 1:
+            n = self._aligned_len(len(key))
+            key, value = key[:n], value[:n]
+        lo = depth if canon_from is None else canon_from
+        now = self._time()
+        above: list[Any] = []  # the path's values over [lo, depth), deepest first
+        anc, end = node, depth
+        while anc is not self.root:
+            anc.last_access_time = now
+            anc.hit_count += 1
+            start = end - len(anc.key)
+            if end > lo:
+                above.append(anc.value[max(lo - start, 0):])
+            anc, end = anc.parent, start
+        if end != 0 or lo > depth:
+            raise ValueError(f"node does not end at depth {depth}")
+        self.root.last_access_time = now
+        present = 0
+        below: list[Any] = []
+        last = node
+        while len(key) > 0:
+            child_key = self._child_key(key)
+            child = last.children.get(child_key)
+            if child is None:
+                child = self._add_leaf(last, child_key, key, value, now)
+                child.hit_count = 1
+                m = len(key)
+            else:
+                m = self._match(child.key, key)
+                # Before the split, as ``insert`` does: the half that
+                # branches off keeps the touch.
+                child.last_access_time = now
+                if m < len(child.key):
+                    child = self._split_node(child, m)
+                child.hit_count += 1
+                if child.value is None:
+                    # Host-resident: adopts the span (see ``insert``).
+                    child.value = value[:m]
+                    self.evictable_size_ += len(child.key)
+                    self._record_store_event(child)
+                else:
+                    present += m
+            below.append(child.value)
+            last = child
+            key, value = key[m:], value[m:]
+        return present, last, _concat_slots([*reversed(above), *below])
+
     def attach_state(self, key: Sequence[int], slot: int | None,
                      tail: np.ndarray | None = None) -> bool:
+        """:meth:`attach_state_at` for a caller that holds no node: walks
+        ``key`` from the root to the node it ends in (False if the tree
+        does not hold ``key``, device-resident, to its end)."""
+        key = as_key(key)
+        node, depth = self.root, 0
+        while depth < len(key):
+            child = node.children.get(self._child_key(key[depth:]))
+            if child is None or child.value is None:
+                return False
+            m = self._match(child.key, key[depth:])
+            if m < len(key) - depth and m < len(child.key) or m == 0:
+                return False
+            node, depth = child, depth + len(child.key)
+        return self.attach_state_at(node, depth, len(key), slot, tail)
+
+    def attach_state_at(self, node: TreeNode, depth: int, pos: int,
+                        slot: int | None, tail: np.ndarray | None = None) -> bool:
         """Hand the tree state slot ``slot`` (and, with it, the window
         ``tail``: pages the caller has counted once for the tree; ``slot``
         None: the tail alone, of a model that carries no state), valid
-        after exactly ``key``
-        (a page multiple of tokens, all of them device-resident in the
-        tree: the caller just inserted them). The node that ends there —
-        split off if ``key`` ends inside one — takes it and True is
-        returned; False, and the slot stays the caller's, if that node has
-        a state already or the tree does not hold ``key`` to its end."""
-        key = as_key(key)
-        node = self.root
-        while len(key) > 0:
-            child = node.children.get(self._child_key(key))
-            if child is None or child.value is None:
-                return False
-            m = self._match(child.key, key)
-            if m < len(key) and m < len(child.key) or m == 0:
-                return False
-            if m < len(child.key):
-                child = self._split_node(child, m)
-            node = child
-            key = key[m:]
+        after exactly the first ``pos`` tokens (a page multiple) of the
+        path that ``node`` ends, ``depth >= pos`` tokens from the root, all
+        of them device-resident: the caller just published them and holds
+        ``node`` locked. The node that ends at ``pos`` — found by climbing
+        from ``node``, split off if ``pos`` falls inside one — takes it and
+        True is returned; False, and the slot stays the caller's, if that
+        node has a state already (or ``pos`` is 0: the root takes none)."""
+        if pos > depth:
+            return False
+        while depth - len(node.key) >= pos and node is not self.root:
+            node, depth = node.parent, depth - len(node.key)
+        if pos < depth:
+            node = self._split_node(node, pos - (depth - len(node.key)))
         if node is self.root or node.resumable or (slot is None and tail is None):
             return False
         node.state, node.tail = slot, tail
@@ -1166,6 +1256,20 @@ class RadixTree:
             node.block_hashes = node.block_hashes[n_pages:]
         return new_node
 
+    def _add_leaf(self, parent: TreeNode, child_key: Any, key: np.ndarray,
+                  value: Any, now: float) -> TreeNode:
+        """A new leaf ``key`` → ``value`` under ``parent``, accounted for
+        (size, fingerprint, journal)."""
+        leaf = TreeNode(parent=parent)
+        leaf.key = key
+        leaf.value = value
+        leaf.last_access_time = now
+        parent.children[child_key] = leaf
+        self.evictable_size_ += len(key)
+        self._fp_attach(leaf)
+        self._record_store_event(leaf)
+        return leaf
+
     def _insert_helper(
         self,
         node: TreeNode,
@@ -1178,14 +1282,7 @@ class RadixTree:
         while True:
             child = node.children.get(self._child_key(key))
             if child is None:
-                leaf = TreeNode(parent=node)
-                leaf.key = key
-                leaf.value = value
-                leaf.last_access_time = self._time()
-                node.children[self._child_key(key)] = leaf
-                self.evictable_size_ += len(key)
-                self._fp_attach(leaf)
-                self._record_store_event(leaf)
+                self._add_leaf(node, self._child_key(key), key, value, self._time())
                 return total_prefix
             m = self._match(child.key, key)
             child.last_access_time = self._time()

@@ -434,6 +434,10 @@ class Engine:
         # where an entry point installed one (launch.py serve); a
         # compilation on this thread is then named by the span it fell in.
         self.spans = SpanBook()
+        # tree_publish: the tokens of every published key, and those of
+        # them ``_publish`` built, copied or compared (the rest lie above
+        # the request's lock); also under ``tree_host``.
+        self.tree_publish = {"key_tokens": 0, "walked_tokens": 0}
         self.process_account = process_account
         if process_account is not None:
             process_account.book = self.spans
@@ -1589,6 +1593,8 @@ class Engine:
             "match_s": s["rm.tree.match"],
             "publishes": n["rm.tree.publish"],
             "publish_s": s["rm.tree.publish"],
+            "publish_key_tokens": self.tree_publish["key_tokens"],
+            "publish_walked_tokens": self.tree_publish["walked_tokens"],
         }
 
     def has_work(self) -> bool:
@@ -2201,7 +2207,7 @@ class Engine:
                 self._resuming[resume] += 1
         prefix_slots = match.indices()[:reuse]
         self.tree.inc_lock_ref(match.last_node)
-        req.lock_node = match.last_node
+        req.lock_node, req.lock_end = match.last_node, match.length
         own = self._alloc_pages(-(-(len(prompt) - reuse) // self.page_size))
         if own is not None and self.state_stats is not None:
             with self.spans.span("rm.state.restore"):
@@ -2396,17 +2402,19 @@ class Engine:
         if self.window_stats is not None:
             self._free_window(req)
 
-    def _hand_over_states(self, req: Request, key: np.ndarray) -> None:
+    def _hand_over_states(self, req: Request) -> None:
         """Give the tree every snapshot of ``req`` that lies on the key
-        just published (a node takes it, or has one and declines)."""
+        just published, which ``req.lock_node`` ends (a node takes it, or
+        has one and declines)."""
         keep = []
         for pos, slot in req.state_snaps:
-            if pos > len(key):
+            if pos > req.lock_end:
                 keep.append((pos, slot))
                 continue
             tail = None if self.window_stats is None else self._tail_of(req, pos)
             st = self.state_stats or self.tail_stats
-            if self.tree.attach_state(key[:pos], slot or None, tail):
+            if self.tree.attach_state_at(
+                    req.lock_node, req.lock_end, pos, slot or None, tail):
                 st["snapshots"] += 1
                 if slot:
                     req.state_handed.append(slot)
@@ -2470,9 +2478,12 @@ class Engine:
         admission defers the sample sync until every wave of the admission
         round has been dispatched — one device→host round trip total);
         ``_finalize_first_tokens`` fills it in before decode runs."""
-        req.prefix_len = reuse
+        req.prefix_len = req.canon_len = reuse
         req.state = RequestState.RUNNING
         req.row = row
+        # Room for the reply: a decode step appends its slot in place.
+        req.reserve_slots(min(req.sampling.max_new_tokens,
+                              self.max_seq_len - req.kv_len))
 
         self.stats.prefills += 1
         # Stall attribution (obs/token_timeline.py): a decode gap that
@@ -3118,45 +3129,62 @@ class Engine:
     # publish / release (the cache_*_req contract)
     # ------------------------------------------------------------------
 
-    def _sequence_key(self, req: Request, key_len: int) -> np.ndarray:
-        if key_len <= len(req.prompt):
-            return req.prompt[:key_len]
-        return np.concatenate(
-            [
-                req.prompt,
-                np.asarray(
-                    req.output_tokens[: key_len - len(req.prompt)], dtype=np.int32
-                ),
-            ]
+    def _sequence_key(self, req: Request, key_len: int, start: int = 0) -> np.ndarray:
+        """Tokens ``[start, key_len)`` of ``req``'s sequence (prompt, then
+        output)."""
+        n = len(req.prompt)
+        if key_len <= n:
+            return req.prompt[start:key_len]
+        out = np.asarray(
+            req.output_tokens[max(start - n, 0) : key_len - n], dtype=np.int32
         )
+        return out if start >= n else np.concatenate([req.prompt[start:], out])
 
     def _publish(self, req: Request, key_len: int) -> None:
         """Insert the first ``key_len`` tokens (whose KV is in the pool)
         into the tree; canonicalize shared prefixes; move the lock to the
-        deepest published node."""
+        deepest published node. The walk starts where ``req``'s lock ends
+        (``RadixTree.insert_below``): the lock keeps everything above in
+        place, so the publish builds, copies and compares only what is new
+        since (``self.tree_publish`` counts both)."""
         with self.spans.span("rm.tree.publish"):
             tr = req.trace
             t_pub = time.monotonic() if tr is not None else 0.0
-            key = self._sequence_key(req, key_len)
-            matched = self.tree.insert(key, req.token_slots[:key_len].copy())
-            m2 = self.tree.match_prefix(key)
-            new_lock = m2.last_node
-            if matched > req.prefix_len:
-                # Over [prefix_len, matched) the tree kept already-present
-                # slots. Where they're ours (this request published them
-                # earlier) nothing changes; where another request published the
-                # same tokens first, ours are duplicates — point our page table
-                # at the canonical slots and free only the differing ones.
-                canon = m2.indices()
-                old = req.token_slots[: len(canon)].copy()
+            ps = self.page_size
+            aligned = key_len - key_len % ps
+            node, base = req.lock_node, req.lock_end
+            if node is None:
+                node, base = self.tree.root, 0
+            # Over [canon_len, base) — a first publish's recomputed span:
+            # ``_acquire_prompt_slots`` resumes at or below its match — and
+            # over what of the tail was already present, the tree kept its
+            # slots. Where they're ours (this request published them
+            # earlier) nothing changes; where another request published the
+            # same tokens first, ours are duplicates — point our page table
+            # at the canonical slots and free only the differing ones.
+            lo = min(req.canon_len, base)
+            if self.mesh is None:
+                tail = self._sequence_key(req, aligned, base)
+            else:  # the ring is told the whole key: only there is it built
+                key = self._sequence_key(req, aligned)
+                tail = key[base:]
+            present, new_lock, canon = self.tree.insert_below(
+                node, base, tail, req.token_slots[base:aligned].copy(),
+                canon_from=lo,
+            )
+            self.tree_publish["key_tokens"] += key_len
+            self.tree_publish["walked_tokens"] += (
+                key_len - lo if self.mesh is None else key_len)
+            matched = base + present
+            if matched > lo:
+                old = req.token_slots[lo:aligned]
                 dup = old[old != canon]
                 if dup.size:
-                    req.token_slots[: len(canon)] = canon
+                    old[:] = canon
                     req.own_slots = np.setdiff1d(req.own_slots, dup)
                     self.pool.free(dup)
             # Slots now referenced by tree nodes are tree-owned: drop them from
             # own_slots so release() never double-frees them.
-            aligned = key_len - key_len % self.page_size
             tree_owned = req.token_slots[matched:aligned]
             if tree_owned.size:
                 req.own_slots = np.setdiff1d(req.own_slots, tree_owned)
@@ -3165,9 +3193,10 @@ class Engine:
                 if req.lock_node is not None:
                     self.tree.dec_lock_ref(req.lock_node)
                 req.lock_node = new_lock
+            req.lock_end = req.canon_len = aligned
             if req.state_snaps:
                 with self.spans.span("rm.state.snapshot"):
-                    self._hand_over_states(req, key[:aligned])
+                    self._hand_over_states(req)
             if self.mesh is not None and aligned > 0:
                 # Advertise the (canonical) published prefix around the ring
                 # (radix_mesh.py:193-201). Only the page-ALIGNED prefix: the
@@ -3177,9 +3206,10 @@ class Engine:
                 # router would promise hits the node cannot serve. A traced
                 # request's trace id rides the frames (old-wire-tolerant
                 # trailer) so replicas stitch their apply/lag spans under it.
+                # A copy: the row keeps writing its buffer.
                 self.mesh.insert(
-                    key[:aligned],
-                    req.token_slots[:aligned],
+                    key,
+                    req.token_slots[:aligned].copy(),
                     trace_id=tr.trace_id if tr is not None else 0,
                 )
             if tr is not None:
@@ -4242,7 +4272,7 @@ class Engine:
         counter, the token timeline, the stream wake-up) is queued for
         :meth:`_flush_emit`; a finish wakes its waiters at once, through
         ``Request.__setattr__``."""
-        req.token_slots = np.append(req.token_slots, slot)
+        req.append_slot(slot)
         req.kv_len += 1
         if ((req.state_boundary or self.tail_stats is not None)
                 and req.kv_len % self.page_size == 0):

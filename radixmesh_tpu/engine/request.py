@@ -74,17 +74,27 @@ class Request:
     row: int = -1  # decode-batch row while RUNNING
     kv_len: int = 0  # tokens whose KV is in the pool
     prefix_len: int = 0  # tokens reused from the radix cache at prefill
-    # Slot index per token position [kv_len]: canonical (tree-owned) slots
-    # over the reused prefix, this request's slots after it.
-    token_slots: np.ndarray = field(
-        default_factory=lambda: np.empty(0, dtype=np.int32)
+    # Behind ``token_slots`` (the property below): a buffer that keeps
+    # spare capacity past its first ``_n_slots`` entries, so a decode
+    # step's append copies nothing of the row's earlier slots.
+    _slots: np.ndarray = field(
+        default_factory=lambda: np.empty(0, dtype=np.int32),
+        repr=False, compare=False,
     )
+    _n_slots: int = field(default=0, repr=False, compare=False)
     # Slots allocated by/for this request (whole pages; superset of the
     # tail of token_slots until handed to the tree or freed).
     own_slots: np.ndarray = field(
         default_factory=lambda: np.empty(0, dtype=np.int32)
     )
     lock_node: object = None  # TreeNode protected while RUNNING
+    # ... and how many tokens from the root that node ENDS (a split by
+    # another request leaves the lower half its identity and its end):
+    # where ``Engine._publish`` starts, everything above being in place.
+    lock_end: int = 0
+    # ``token_slots[:canon_len]`` are the tree's own slots: the reused
+    # prefix at admission, everything up to ``lock_end`` after a publish.
+    canon_len: int = 0
     # A model whose layers carry a state beside the rows (state slots of
     # ``cache/kv_pool.py``; 0 = none, slot 0 is nobody's): the row's
     # working slot, the slot that keeps the state as of the last page
@@ -242,6 +252,34 @@ class Request:
                     cancelled=bool(self.cancelled),
                     shed=bool(self.shed),
                 )
+
+    @property
+    def token_slots(self) -> np.ndarray:
+        """Slot index per token position ``[kv_len]``: canonical
+        (tree-owned) slots over the reused prefix, this request's slots
+        after it. A view of exactly that many entries: no reader sees the
+        spare capacity. Assigning an array makes it the buffer as it is."""
+        return self._slots[: self._n_slots]
+
+    @token_slots.setter
+    def token_slots(self, slots: np.ndarray) -> None:
+        self._slots, self._n_slots = slots, len(slots)
+
+    def reserve_slots(self, n: int) -> None:
+        """Room for ``n`` more slots: one copy now (or at a doubling, should
+        the row outgrow it), none at an append."""
+        need = self._n_slots + n
+        if need > len(self._slots):
+            buf = np.empty(max(need, 2 * len(self._slots)), dtype=np.int32)
+            buf[: self._n_slots] = self._slots[: self._n_slots]
+            self._slots = buf
+
+    def append_slot(self, slot: int) -> None:
+        n = self._n_slots
+        if n == len(self._slots):
+            self.reserve_slots(1)
+        self._slots[n] = slot
+        self._n_slots = n + 1
 
     @property
     def next_token(self) -> int:

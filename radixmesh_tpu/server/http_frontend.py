@@ -339,9 +339,11 @@ def _ids_from_body(body: dict, tokenizer, who: str) -> list[int]:
         ids = tokenizer.encode(body["text"])
     else:
         ids = body["input_ids"]
-    if not isinstance(ids, list) or not all(
-        isinstance(t, int) and not isinstance(t, bool) for t in ids
-    ):
+    # One pass in C, not an ``isinstance`` a token: a turn on a long
+    # document re-sends 33 k-101 k ids, and this thread holds the
+    # interpreter the engine thread needs to admit the next request
+    # (``bool`` is a type of its own here: True is no token id).
+    if not isinstance(ids, list) or not set(map(type, ids)) <= {int}:
         raise ValueError("input_ids must be a list of ints")
     return ids
 

@@ -15,8 +15,9 @@ numbers on stdout. One JSON line a snapshot goes to ``--out`` (default
 ``engine.spans``, ``engine.slow_steps``, ``process`` (``compiles.last``
 names each compiled program, when, and the span it compiled under),
 ``attn`` (what decode launches attended, read and were spared), ``state``
-(where requests resumed, what the state slots hold and gave up) and
-``/stats``."""
+(where requests resumed, what the state slots hold and gave up),
+``cache.tree_host`` (the tree walks' host seconds, and how much of each
+published key the publish walked) and ``/stats``."""
 from __future__ import annotations
 
 import argparse
@@ -64,6 +65,7 @@ def main() -> int:
                 "process": state.get("process"),
                 "attn": state.get("attn"),
                 "state": state.get("state"),
+                "tree_host": (state.get("cache") or {}).get("tree_host"),
                 "stats": snap["stats"],
             }) + "\n")
         return snap

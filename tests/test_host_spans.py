@@ -356,7 +356,11 @@ def test_debug_state_serves_the_span_book_and_the_prefill_account(served):
     th = b["cache"]["tree_host"]
     assert th == {"matches": sb["rm.tree.match"]["n"], "match_s": sb["rm.tree.match"]["s"],
                   "publishes": sb["rm.tree.publish"]["n"],
-                  "publish_s": sb["rm.tree.publish"]["s"]}
+                  "publish_s": sb["rm.tree.publish"]["s"],
+                  "publish_key_tokens": served["engine"].tree_publish["key_tokens"],
+                  "publish_walked_tokens":
+                      served["engine"].tree_publish["walked_tokens"]}
+    assert 0 < th["publish_walked_tokens"] <= th["publish_key_tokens"]
     pa, pb = a["engine"]["prefill"], b["engine"]["prefill"]
     assert pb["waves"] - pa["waves"] == 2 and pb["rows"] - pa["rows"] == n
     assert pb["tokens_new"] - pa["tokens_new"] == 20 * n  # nothing shared

@@ -114,6 +114,18 @@ class TestServingFrontend:
             )
         assert e.value.code == 400
 
+    @pytest.mark.parametrize("ids", [
+        [1, 2, True], [1, 2.0, 3], [1, "2"], [1, None], [[1], 2], (1, 2), "12",
+    ], ids=repr)
+    def test_input_ids_are_a_list_of_ints_and_nothing_else(self, ids):
+        from radixmesh_tpu.server.http_frontend import _ids_from_body
+
+        with pytest.raises(ValueError, match="list of ints"):
+            _ids_from_body({"input_ids": ids}, None, "server")
+        good = list(range(5))
+        assert _ids_from_body({"input_ids": good}, None, "server") is good
+        assert _ids_from_body({"input_ids": []}, None, "server") == []
+
     def test_healthz_stats_metrics(self, frontend):
         status, _ = _get(f"http://127.0.0.1:{frontend.port}/healthz")
         assert status == 200

@@ -626,3 +626,465 @@ def test_plain_pressure_leaves_locked_snapshots_alone():
     tree.inc_lock_ref(tree.match_prefix(key).last_node)
     assert tree.evict_states(4) == 0 and freed == []  # LFM2's rule: unlocked only
     assert tree.evict_states(4, pinned={6}) == 1 and freed == [5]
+
+
+# ---------------------------------------------------------------------------
+# a publish that starts at the request's locked node (``insert_below`` +
+# ``attach_state_at``) leaves the tree the root walks leave
+# ---------------------------------------------------------------------------
+
+
+def _attach_from_root(tree, key, slot, tail):
+    """The oracle: ``attach_state`` as a walk of the whole key from the
+    root (what the engine's publish did before it started at the lock)."""
+    node = tree.root
+    while len(key) > 0:
+        child = node.children.get(tree._child_key(key))
+        if child is None or child.value is None:
+            return False
+        m = tree._match(child.key, key)
+        if m < len(key) and m < len(child.key) or m == 0:
+            return False
+        if m < len(child.key):
+            child = tree._split_node(child, m)
+        node = child
+        key = key[m:]
+    if node is tree.root or node.resumable or (slot is None and tail is None):
+        return False
+    node.state, node.tail = slot, tail
+    return True
+
+
+class _Row:
+    def __init__(self, prompt):
+        self.prompt = np.asarray(prompt, np.int32)
+        self.out = []
+        self.slots = self.own = np.empty(0, np.int32)
+        self.lock, self.lock_end = None, 0
+        self.prefix_len = self.canon_len = 0
+        self.snaps = []
+
+    def key(self, start, end):
+        return np.concatenate(
+            [self.prompt, np.asarray(self.out, np.int32)])[start:end]
+
+    @property
+    def kv_len(self):
+        return len(self.prompt) + len(self.out)
+
+
+class _Side:
+    """One tree with the requests that publish into it: ``from_lock``
+    False walks from the root (insert, match_prefix, attach by key), True
+    starts at each request's lock."""
+
+    def __init__(self, from_lock, page, states, tails, clock):
+        self.from_lock, self.page = from_lock, page
+        self.states, self.tails = states, tails
+        self.freed = []  # every slot, state slot and tail page given back
+        self.next_slot = self.next_state = self.next_page = 0
+        self.tree = RadixTree(
+            page_size=page, enable_events=True, time_fn=lambda: clock[0],
+            on_free=lambda a: self.freed.append(("kv", tuple(sorted(a.tolist())))),
+            on_free_state=(
+                (lambda s: self.freed.append(("state", s))) if states else None),
+            on_free_tail=(
+                (lambda t: self.freed.append(("tail", tuple(t.tolist()))))
+                if tails else None),
+        )
+        self.rows = {}
+
+    def alloc(self, n):
+        self.next_slot += n
+        return np.arange(self.next_slot - n, self.next_slot, dtype=np.int32)
+
+    def free(self, slots):
+        self.freed.append(("kv", tuple(sorted(np.asarray(slots).tolist()))))
+
+    def admit(self, rid, prompt, resume_below):
+        """An admission as ``Engine._acquire_prompt_slots`` makes it: the
+        match, the lock at its end, slots for what is computed — from the
+        match, or with ``resume_below`` from that many pages above it (a
+        model that resumes from a snapshot shallower than its match)."""
+        row = self.rows[rid] = _Row(prompt)
+        ps = self.page
+        m = self.tree.match_prefix(row.prompt)
+        matched = min(m.length, (len(row.prompt) - 1) // ps * ps)
+        reuse = max(matched - resume_below * ps, 0)
+        self.tree.inc_lock_ref(m.last_node)
+        row.lock, row.lock_end = m.last_node, m.length
+        row.prefix_len = row.canon_len = reuse
+        row.own = self.alloc(len(row.prompt) - reuse)
+        row.slots = np.concatenate([m.indices()[:reuse], row.own])
+        if self.states or self.tails:
+            have = {pos for pos, _ in m.states}
+            for pos in sorted({len(row.prompt) // ps * ps, matched}):
+                if pos > reuse and pos not in have:
+                    row.snaps.append((pos, self._snap()))
+
+    def _snap(self):
+        slot = tail = None
+        if self.states:
+            self.next_state += 1
+            slot = self.next_state
+        if self.tails:
+            self.next_page += 2
+            tail = np.asarray([self.next_page - 1, self.next_page], np.int32)
+        return slot, tail
+
+    def extend(self, rid, tokens):
+        row = self.rows[rid]
+        new = self.alloc(len(tokens))
+        row.out += list(tokens)
+        row.slots = np.concatenate([row.slots, new])
+        row.own = np.concatenate([row.own, new])
+
+    def publish(self, rid):
+        row = self.rows[rid]
+        tree, ps, key_len = self.tree, self.page, row.kv_len
+        aligned = key_len - key_len % ps
+        if self.from_lock:
+            base = row.lock_end
+            lo = min(row.canon_len, base)
+            present, new_lock, canon = tree.insert_below(
+                row.lock, base, row.key(base, aligned),
+                row.slots[base:aligned].copy(), canon_from=lo)
+            matched = base + present
+            assert len(canon) == aligned - lo
+        else:
+            lo = 0
+            key = row.key(0, key_len)
+            matched = tree.insert(key, row.slots[:key_len].copy())
+            m2 = tree.match_prefix(key)
+            new_lock, canon = m2.last_node, m2.indices()
+            assert len(canon) == aligned
+        if matched > row.prefix_len:
+            old = row.slots[lo:aligned]
+            dup = old[old != canon]
+            if dup.size:
+                old[:] = canon
+                row.own = np.setdiff1d(row.own, dup)
+                self.free(dup)
+        row.own = np.setdiff1d(row.own, row.slots[matched:aligned])
+        if new_lock is not row.lock:
+            tree.inc_lock_ref(new_lock)
+            tree.dec_lock_ref(row.lock)
+            row.lock = new_lock
+        row.lock_end = row.canon_len = aligned
+        keep = []
+        for pos, (slot, tail) in row.snaps:
+            if pos > aligned:
+                keep.append((pos, (slot, tail)))
+            elif not (
+                tree.attach_state_at(new_lock, aligned, pos, slot, tail)
+                if self.from_lock else
+                _attach_from_root(tree, row.key(0, pos), slot, tail)
+            ):
+                self.freed.append(("declined", pos, slot))
+        row.snaps = keep
+
+    def release(self, rid):
+        row = self.rows[rid]
+        cut = row.kv_len // self.page * self.page
+        if (self.states or self.tails) and cut > len(row.prompt):
+            row.snaps.append((cut, self._snap()))  # the boundary's state
+        self.publish(rid)
+        if row.own.size:
+            self.free(row.own)
+        self.tree.dec_lock_ref(row.lock)
+        del self.rows[rid]
+
+    def evict(self, n, to_host):
+        def writeback(node):  # the host tier takes it: the node stays
+            node.host_value = np.asarray(node.value, np.int32) + 1_000_000
+            return True
+
+        self.tree._evict_impl(n, writeback=writeback if to_host else None)
+
+    def dump(self):
+        def rec(n):
+            return (
+                n.key.tolist(),
+                None if n.value is None else np.asarray(n.value).tolist(),
+                None if n.host_value is None else n.host_value.tolist(),
+                n.lock_ref, n.hit_count, n.last_access_time, n.state,
+                None if n.tail is None else n.tail.tolist(),
+                n.block_hashes, n.chain.tolist(),
+                [(k, rec(c)) for k, c in n.children.items()],  # in dict order
+            )
+
+        t = self.tree
+        rows = {
+            rid: (r.slots.tolist(), sorted(r.own.tolist()), r.lock_end,
+                  t.path_hash(r.lock), r.lock.lock_ref, r.snaps and
+                  [(p, s, None if tl is None else tl.tolist())
+                   for p, (s, tl) in r.snaps])
+            for rid, r in self.rows.items()
+        }
+        return {
+            "tree": rec(t.root), "evictable": t.evictable_size_,
+            "protected": t.protected_size_, "total": t.total_size(),
+            "fingerprint": t.fingerprint_, "buckets": t.fp_buckets_.tolist(),
+            "events": list(t._events), "freed": list(self.freed),
+            "state_evictions": t.state_evictions, "rows": rows,
+        }
+
+
+class _Twins:
+    """The same operations on a root-walking side and a from-the-lock
+    side, compared after every one of them."""
+
+    def __init__(self, page, states=False, tails=False):
+        self.clock = [0.0]
+        self.sides = [_Side(f, page, states, tails, self.clock) for f in (False, True)]
+        self.page = page
+
+    def __getattr__(self, op):
+        def run(*args):
+            self.clock[0] += 1.0
+            for side in self.sides:
+                getattr(side, op)(*args)
+            a, b = (side.dump() for side in self.sides)
+            for part in a:
+                assert a[part] == b[part], (op, args, part)
+            return self.sides[1]
+
+        return run
+
+    def match(self, key):
+        """Another request's admission walk (it splits where it ends)."""
+        self.clock[0] += 1.0
+        lens = [s.tree.match_prefix(np.asarray(key, np.int32)).length
+                for s in self.sides]
+        assert lens[0] == lens[1]
+
+
+_PAGES = [1, 16, 128]
+_KINDS = {"rows": {}, "states": {"states": True}, "tails": {"tails": True},
+          "states_and_tails": {"states": True, "tails": True}}
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("kind", _KINDS)
+@pytest.mark.parametrize("page", _PAGES)
+def test_a_publish_from_the_lock_leaves_the_tree_the_root_walk_leaves(
+        page, kind, seed):
+    """Seeded sessions on shared documents: admissions (some resuming
+    above their match), replies, mid-reply publishes, releases, other
+    requests' walks, evictions to nothing and to the host tier — the twin
+    trees, their requests' slots and everything given back stay equal."""
+    rng = np.random.default_rng(1000 * page + seed)
+    tw = _Twins(page, **_KINDS[kind])
+
+    def some(n, choice):  # one of a few spans: requests meet on them
+        g = np.random.default_rng(choice)
+        return g.integers(0, 50, int(g.integers(1, n + 1)))
+
+    docs = [some(4 * page, 100 + i) for i in range(2)]
+    finished = []  # whole sequences of released requests: a next turn's stem
+    live, waiting, rid = [], set(), 0  # waiting: admitted, prompt not yet published
+    for _ in range(80):
+        op = rng.choice(["admit", "extend", "publish", "release", "match", "evict"],
+                        p=[0.3, 0.3, 0.1, 0.15, 0.1, 0.05])
+        if op == "admit" and len(live) < 5:
+            stems = docs + finished[-2:]
+            stem = stems[int(rng.integers(len(stems)))]
+            cut = len(stem) - int(rng.integers(2)) * min(page, len(stem) - 1)
+            prompt = np.concatenate(
+                [stem[:cut], some(2 * page + 1, int(rng.integers(2)))])
+            resume_below = int(rng.integers(0, 3)) if kind != "rows" else 0
+            tw.admit(rid, prompt, resume_below)
+            waiting.add(rid)  # a wave installs its members after all are admitted
+            live.append(rid)
+            rid += 1
+        elif op in ("extend", "publish", "release") and live:
+            r = live[int(rng.integers(len(live)))]
+            if r in waiting:
+                tw.publish(r)  # the prompt's install
+                waiting.discard(r)
+            elif op == "extend":
+                tw.extend(r, some(page + 3, 200 + int(rng.integers(2))).tolist())
+            elif op == "publish":
+                tw.publish(r)
+            else:
+                row = tw.sides[1].rows[r]
+                finished.append(row.key(0, row.kv_len))
+                tw.release(r)
+                live.remove(r)
+        elif op == "match":
+            stem = (docs + finished)[int(rng.integers(len(docs) + len(finished)))]
+            tw.match(stem[: int(rng.integers(0, len(stem) + 1))])
+        elif op == "evict":
+            tw.evict(int(rng.integers(1, 4 * page)), bool(rng.integers(2)))
+    for r in live:
+        tw.release(r)
+    side = tw.sides[1]
+    assert side.tree.protected_size() == 0 and not side.rows
+    # every slot handed out is in the tree or was given back, exactly once
+    back = [s for kind_, *rest in side.freed if kind_ == "kv" for s in rest[0]]
+    held = side.tree.all_values_flatten().tolist()
+    assert sorted(back + held) == list(range(side.next_slot))
+
+
+@pytest.mark.parametrize("page", _PAGES)
+def test_the_locked_node_split_by_another_request_keeps_its_end(page):
+    tw = _Twins(page, states=True)
+    doc = ids(4 * page)
+    tw.admit(0, np.concatenate([doc, ids(page + 1, start=500)]), 0)
+    tw.publish(0)
+    tw.release(0)
+    tw.admit(1, np.concatenate([doc, ids(page + 1, start=500), ids(3, start=900)]), 0)
+    row = tw.sides[1].rows[1]
+    lock, end = row.lock, row.lock_end
+    assert end == (5 * page + 1) // page * page and len(lock.key) == end
+    tw.match(doc[: 2 * page])  # splits the locked node above its end
+    tw.match(np.concatenate([doc[: 3 * page], ids(page, start=700)]))  # and again
+    assert row.lock is lock and len(lock.key) == end - 3 * page  # the lower half
+    tw.publish(1)
+    tw.extend(1, [7] * (page + 2))
+    tw.release(1)
+    m = tw.sides[1].tree.match_prefix(np.concatenate([doc, ids(page + 1, start=500)]))
+    assert m.length == end
+
+
+@pytest.mark.parametrize("page", _PAGES)
+def test_a_publish_canonicalises_from_a_prefix_above_its_lock(page):
+    """A resuming model computes again from a snapshot shallower than its
+    match: the slots it wrote over ``[prefix_len, lock's end)`` are
+    duplicates of the tree's, found by climbing the lock's parents."""
+    tw = _Twins(page, states=True, tails=True)
+    doc = ids(6 * page)
+    tw.admit(0, doc, 0)
+    tw.publish(0)
+    tw.release(0)
+    tw.match(doc[: 2 * page])
+    tw.match(doc[: 4 * page])  # three nodes above the next lock's end
+    tw.admit(1, np.concatenate([doc, ids(5, start=800)]), 3)
+    row = tw.sides[1].rows[1]
+    assert row.prefix_len == 3 * page and row.lock_end == 6 * page
+    before = row.slots[3 * page: 6 * page].copy()
+    side = tw.publish(1)
+    assert side.freed[-1] == ("kv", tuple(before.tolist()))  # exactly those
+    assert row.slots[: 6 * page].tolist() == list(range(6 * page))
+    assert row.canon_len == row.lock_end == (6 * page + 5) // page * page
+    tw.extend(1, [3] * (2 * page))
+    tw.release(1)
+
+
+@pytest.mark.parametrize("page", _PAGES)
+def test_a_key_that_ends_inside_a_node_splits_it_once(page):
+    tw = _Twins(page)
+    long = ids(5 * page)
+    tw.admit(0, long, 0)
+    tw.publish(0)
+    tw.release(0)
+    tw.evict(10 * page, False)  # nothing held: the tree is empty again
+    tw.admit(1, long[: 2 * page + 1], 0)  # cold: locked at the root
+    tw.admit(2, long, 0)
+    tw.publish(2)
+    tw.release(2)  # the whole of it, one node
+    side = tw.publish(1)  # ends inside that node
+    row = side.rows[1]
+    end = (2 * page + 1) // page * page
+    assert row.lock_end == end and len(row.lock.key) == end
+    assert len(next(iter(row.lock.children.values())).key) == 5 * page - end
+    assert side.freed[-1][0] == "kv" and len(side.freed[-1][1]) == end
+    tw.release(1)
+
+
+@pytest.mark.parametrize("page", _PAGES)
+def test_a_duplicate_published_first_frees_exactly_the_differing_slots(page):
+    tw = _Twins(page)
+    doc, q = ids(2 * page), ids(page, start=300)
+    tw.admit(0, doc, 0)
+    tw.publish(0)
+    tw.release(0)
+    for rid in (1, 2):  # two requests ask the same: neither sees the other
+        tw.admit(rid, np.concatenate([doc, q, [9]]), 0)
+    tw.publish(1)
+
+    def al(n):
+        return n // page * page
+
+    end = al(3 * page + 1)
+    mine = tw.sides[1].rows[2].slots[2 * page: end].copy()
+    theirs = tw.sides[1].rows[1].slots[2 * page: end].copy()
+    side = tw.publish(2)
+    assert side.freed[-1] == ("kv", tuple(mine.tolist()))
+    assert side.rows[2].slots[:end].tolist() == [
+        *range(2 * page), *theirs.tolist()]
+    for rid in (1, 2):  # replies that agree for a page, then differ
+        tw.extend(rid, [5] * page + [rid] * page)
+    tw.release(1)
+    n = len(tw.sides[1].freed)
+    side = tw.release(2)
+    own = side.freed[n:]
+    # what agrees and the residue went back, what differs stays
+    agree, whole = al(4 * page + 1), al(5 * page + 1)
+    assert sum(len(f[1]) for f in own if f[0] == "kv") == (
+        agree - end + 5 * page + 1 - whole)
+    assert side.tree.total_size() == whole + whole - agree
+
+
+@pytest.mark.parametrize("page", _PAGES)
+def test_a_host_resident_node_below_the_lock_adopts_the_span(page):
+    tw = _Twins(page, tails=True)
+    doc = ids(2 * page)
+    reply = ids(2 * page, start=400)
+    tw.admit(0, doc, 0)
+    tw.publish(0)
+    tw.extend(0, reply.tolist())
+    tw.release(0)
+    tw.admit(1, np.concatenate([doc, [1]]), 0)  # holds the document
+    tw.publish(1)
+    tw.evict(2 * page, True)  # the reply goes to the host tier, its node stays
+    side = tw.sides[1]
+
+    def hosted():
+        return [n for n in side.tree._all_nodes() if n.host_value is not None]
+
+    (below,) = hosted()
+    assert below.value is None and len(below.key) == 2 * page
+    tw.admit(2, np.concatenate([doc, reply[:page], [2]]), 0)
+    assert side.rows[2].lock_end == 2 * page  # the device-resident prefix
+    n_free = len(side.freed)
+    tw.publish(2)  # computes the reply's first page again: adopted, not a duplicate
+    assert len(side.freed) == n_free
+    node, below = hosted()  # split where the prompt left it
+    assert node.value is not None and below.value is None
+    assert len(node.key) == page
+    assert side.rows[2].own.size == (3 * page + 1) % page
+    tw.release(2)
+    tw.release(1)
+
+
+def test_insert_below_refuses_a_depth_that_is_not_the_nodes_end():
+    tree = make_tree(page_size=4)
+    tree.insert(ids(8), ids(8))
+    node = tree.match_prefix(ids(8)).last_node
+    with pytest.raises(ValueError, match="does not end at depth"):
+        tree.insert_below(node, 4, ids(4, start=8), ids(4))
+    with pytest.raises(ValueError, match="does not end at depth"):
+        tree.insert_below(node, 8, ids(4, start=8), ids(4), canon_from=12)
+    with pytest.raises(ValueError, match="value length"):
+        tree.insert_below(node, 8, ids(4, start=8), ids(3))
+    # nothing below: the node itself, and its own slots from where asked
+    present, last, canon = tree.insert_below(node, 8, ids(3, start=8), ids(3),
+                                             canon_from=2)
+    assert (present, last) == (0, node) and canon.tolist() == list(range(2, 8))
+
+
+def test_attach_state_at_climbs_to_the_node_that_ends_there():
+    tree, freed = _state_tree()
+    key = ids(24)
+    tree.insert(key[:8], ids(8))
+    tree.insert(key, ids(24))
+    deepest = tree.match_prefix(key).last_node
+    assert not tree.attach_state_at(deepest, 24, 28, 9)  # below the node's end
+    assert not tree.attach_state_at(deepest, 24, 0, 9)  # the root takes none
+    assert tree.attach_state_at(deepest, 24, 8, 5)  # the parent ends there
+    assert tree.attach_state_at(deepest, 24, 16, 6)  # splits the node at 16
+    assert tree.attach_state_at(deepest, 24, 24, 7)
+    assert not tree.attach_state_at(deepest, 24, 16, 8)  # it has one
+    assert tree.match_prefix(key).states == [(8, 5), (16, 6), (24, 7)]
+    assert len(deepest.key) == 8 and freed == []
