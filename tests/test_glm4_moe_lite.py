@@ -20,7 +20,7 @@ import pytest
 
 from benchmarks.reference import glm4_moe_lite as ref
 from radixmesh_tpu.cache import radix_tree
-from radixmesh_tpu.engine import Engine, SamplingParams
+from radixmesh_tpu.engine import SamplingParams
 from radixmesh_tpu.engine.request import RequestState
 from radixmesh_tpu.models import get_config, init_params
 from radixmesh_tpu.models.llama import (
@@ -28,7 +28,6 @@ from radixmesh_tpu.models.llama import (
     _attn_out,
     _ffn,
     _latent_qkv,
-    convert_hf_state_dict,
     decode_step,
     prefill_forward,
     read_moe_stats,
@@ -44,75 +43,23 @@ from radixmesh_tpu.ops.mla_attention import (
 )
 from radixmesh_tpu.ops.moe import route
 from radixmesh_tpu.ops.rope import rope_frequencies
-from tests.test_mellum2 import REFUSED, _mesh  # the same options, by name
+from tests.model_conformance import *  # noqa: F401,F403  (the shared cases)
+from tests.model_conformance import ModelSpec, logits_from
+from tests.model_conformance import distance as rel
 
 pytestmark = pytest.mark.quick
 
-PAGE = 4
-N_NEW = 9  # one token from the prefill, 8 decode steps through the pool
-TOL = 1e-4  # float32 against float32: other orders of the same sums
-
-
-@pytest.fixture(scope="module")
-def model():
-    cfg = get_config("glm-4.7-flash-tiny").replace(dtype=jnp.float32)
-    return cfg, init_params(cfg, jax.random.PRNGKey(31))
-
-
-def make_engine(model, **kw):
-    cfg, params = model
-    kw.setdefault("num_slots", 1024)
-    kw.setdefault("page_size", PAGE)
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("max_seq_len", 160)
-    return Engine(cfg, params, **kw)
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng([31, seed]).integers(0, 512, n).tolist()
-
-
-def rel(a, b):
-    return float(np.abs(a - b).max() / np.abs(b).max())
-
-
-def check_against_reference(model, req, prompt):
-    """The request's kept logits rows and expert ids against the
-    reference's full forward pass over prompt + generated tokens."""
-    cfg, params = model
-    out = req.generated
-    assert len(out) == N_NEW and len(req.logit_rows) == N_NEW
-    seq = list(prompt) + out[:-1]
-    r = ref.forward(params, cfg, seq, logits_from=len(prompt) - 1)
-    got = np.stack(req.logit_rows)
-    assert got.dtype == np.float32 and got.shape == r["logits"].shape
-    assert rel(got, r["logits"]) < TOL
-    assert [int(x) for x in got.argmax(-1)] == out  # greedy: what was sampled
-    seen = set()
-    for start, ids in req.expert_rows:  # every computed position's choice
-        n = ids.shape[0]
-        # the sparse layers alone: layer 0 is dense and chooses nothing
-        assert ids.shape[1:] == (cfg.n_layers - 1, cfg.n_experts_per_tok)
-        np.testing.assert_array_equal(
-            np.sort(ids, -1), np.sort(r["expert_ids"][start:start + n], -1))
-        seen.update(range(start, start + n))
-    assert seen == set(range(req.prefix_len, len(seq)))
-
-
-@pytest.mark.parametrize("n_prompt", [7, 16, 53])
-@pytest.mark.parametrize("path", ["dense", "chunk"])
-def test_prefill_then_decode_through_the_latent_cache(model, path, n_prompt):
-    eng = make_engine(
-        model, prefill_chunk=16,
-        long_prefill_threshold=1024 if path == "dense" else 0,
-    )
-    prompt = prompt_of(n_prompt)
-    req = eng.add_request(
-        prompt, SamplingParams(max_new_tokens=N_NEW, return_logits=True))
-    while eng.has_work():
-        eng.step()
-    assert eng._last_prefill_path == path
-    check_against_reference(model, req, prompt)
+SPEC = ModelSpec(
+    preset="glm-4.7-flash-tiny", seed=31, reference=logits_from(ref.forward),
+    engine=dict(page_size=4, num_slots=1024, max_batch=4, max_seq_len=256,
+                prefill_chunk=16),
+    # 7: inside two pages; 16: a chunk; 53: chunks of 16 with a ragged end
+    lengths=(7, 16, 53),
+    says=("a latent (MLA) cache row",),
+)
+make_engine, prompt_of, check_against_reference = (
+    SPEC.make_engine, SPEC.prompt_of, SPEC.check)
+PAGE, N_NEW, TOL = SPEC.engine["page_size"], SPEC.n_new, SPEC.tol
 
 
 @pytest.mark.parametrize("n_tail", [5, 21])
@@ -576,60 +523,16 @@ def test_rows_on_one_published_prefix_decode_through_one_shared_pass(model):
     ]
 
 
-@pytest.mark.parametrize("what", sorted(REFUSED))
-def test_engine_refuses_by_name(model, what):
-    kw, name = REFUSED[what]
-    with pytest.raises(UnsupportedForModel, match=name) as e:
-        make_engine(model, **(kw() if callable(kw) else kw))
-    assert "a latent (MLA) cache row" in str(e.value)
+def test_a_latent_row_alone_is_refused_int8_weights():
+    """A latent row without experts or a window is refused as well."""
+    from radixmesh_tpu.models.llama import unsupported_reason
 
-
-@pytest.mark.parametrize("what", ["pp", "disagg_decode", "disagg_prefill",
-                                  "init_int8", "hf_checkpoint", "sp_prefill",
-                                  "latent_alone"])
-def test_other_entries_refuse_by_name(model, what):
-    from radixmesh_tpu.engine.disagg import DecodeWorker, PrefillWorker
-    from radixmesh_tpu.models.llama import prefill_forward_sp, unsupported_reason
-
-    cfg, params = model
-    with pytest.raises(UnsupportedForModel) as e:
-        if what == "pp":
-            from jax.sharding import Mesh
-
-            make_engine(model, device_mesh=Mesh(
-                np.asarray(jax.devices()[:2]).reshape(2), ("pp",)))
-        elif what == "disagg_decode":
-            DecodeWorker(make_engine(model))
-        elif what == "disagg_prefill":
-            PrefillWorker(cfg, params, num_slots=256, page_size=PAGE,
-                          max_batch=2, max_seq_len=64).prefill_handoff([1, 2, 3])
-        elif what == "init_int8":
-            init_params(cfg, jax.random.PRNGKey(0), weight_quant="int8")
-        elif what == "hf_checkpoint":
-            convert_hf_state_dict(cfg, {})
-        elif what == "sp_prefill":
-            prefill_forward_sp(params, cfg, jnp.zeros((1, 8), jnp.int32),
-                               jnp.arange(8)[None], _mesh(sp=2))
-        else:
-            # a latent row alone (no experts, no window) is refused as well
-            dense = get_config("llama3-tiny").replace(
-                q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=24,
-                qk_rope_head_dim=8, v_head_dim=24)
-            assert unsupported_reason(dense) is None
-            init_params(dense, jax.random.PRNGKey(0), weight_quant="int8")
-    assert "does not support" in str(e.value)
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", cached)
+    dense = get_config("llama3-tiny").replace(
+        q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=24,
+        qk_rope_head_dim=8, v_head_dim=24)
+    assert unsupported_reason(dense) is None
+    with pytest.raises(UnsupportedForModel, match="does not support"):
+        init_params(dense, jax.random.PRNGKey(0), weight_quant="int8")
 
 
 LOWERS_TO = {
@@ -655,26 +558,15 @@ def test_decode_lowers_to_the_pool_and_kernels_of_its_config(v5e, which):
     """At published widths, compiled for a v5e with the chip's own
     compilers: a Qwen2 and a Mellum2 step keep the K/V-per-head pool and
     the kernels they had, the latent model takes its own."""
-    import re
-
-    from radixmesh_tpu.models.llama import _init_params
-
     preset, over, pool_shape, inside, outside = LOWERS_TO[which]
     cfg = get_config(preset, **over)
     assert cfg.kv_pool_shape(4096) == pool_shape
-
-    def sd(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    params = jax.tree.map(
-        lambda x: sd(x.shape, x.dtype),
-        jax.eval_shape(lambda k: _init_params(k, cfg, None, None),
-                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    _, params, sd = abstract_params(cfg, v5e)
     compiled = decode_step.lower(
         params, cfg, sd((8,), jnp.int32), sd(pool_shape, cfg.dtype),
         sd((8,), jnp.int32), sd((8, 64), jnp.int32), sd((8,), jnp.int32), 16,
     ).compile()
-    names = set(re.findall(r"radixmesh_[a-z_]+[a-z]", compiled.as_text()))
+    names = kernels_in(compiled)
     assert inside <= names and not (outside & names), names
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= np.prod(pool_shape) * 2  # pool aliased through

@@ -19,10 +19,9 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import glm_moe_dsa as ref
-from radixmesh_tpu.engine import Engine, SamplingParams
+from radixmesh_tpu.engine import SamplingParams
 from radixmesh_tpu.models import get_config, init_params
 from radixmesh_tpu.models.llama import (
-    UnsupportedForModel,
     _ffn,
     decode_step,
     new_moe_stats,
@@ -32,80 +31,33 @@ from radixmesh_tpu.models.llama import (
 )
 from radixmesh_tpu.ops import dsa
 from radixmesh_tpu.ops.moe import SHARE_STATS, STATS, moe_ffn
-from tests.test_mellum2 import REFUSED  # the same options, by name
+from tests.model_conformance import *  # noqa: F401,F403  (the shared cases)
+from tests.model_conformance import ModelSpec, logits_from
+from tests.model_conformance import distance as rel
 
 pytestmark = pytest.mark.quick
 
-PAGE = 4
-N_NEW = 9  # one token from the prefill, 8 decode steps through the pool
-TOL = 1e-4  # float32 against float32: other orders of the same sums
+
+def _the_selection_engages_past_index_topk(eng, want, prompt, **_):
+    """What the last position attends in each full layer: all it has up to
+    ``index_topk`` (24), the 24 best past it."""
+    seen = min(len(prompt) + SPEC.n_new - 1, eng.cfg.index_topk)
+    assert want["selected"][-1].tolist() == [seen, seen]
 
 
-@pytest.fixture(scope="module")
-def model():
-    cfg = get_config("glm-5.2-tiny").replace(dtype=jnp.float32)
-    return cfg, init_params(cfg, jax.random.PRNGKey(33))
-
-
-def make_engine(model, **kw):
-    cfg, params = model
-    kw.setdefault("num_slots", 1024)
-    kw.setdefault("page_size", PAGE)
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("max_seq_len", 256)
-    return Engine(cfg, params, **kw)
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng([33, seed]).integers(0, 512, n).tolist()
-
-
-def rel(a, b):
-    return float(np.abs(a - b).max() / np.abs(b).max())
-
-
-def run(eng, prompt, **sampling):
-    req = eng.add_request(prompt, SamplingParams(
-        max_new_tokens=N_NEW, return_logits=True, **sampling))
-    while eng.has_work():
-        eng.step()
-    return req
-
-
-def check_against_reference(model, req, prompt):
-    cfg, params = model
-    out = req.generated
-    assert len(out) == N_NEW and len(req.logit_rows) == N_NEW
-    seq = list(prompt) + out[:-1]
-    r = ref.forward(params, cfg, seq, logits_from=len(prompt) - 1)
-    got = np.stack(req.logit_rows)
-    assert got.shape == r["logits"].shape
-    assert rel(got, r["logits"]) < TOL
-    assert [int(x) for x in got.argmax(-1)] == out
-    for start, ids in req.expert_rows:  # global expert ids, the sparse layers
-        assert ids.shape[1:] == (cfg.n_layers - 1, cfg.n_experts_per_tok)
-        np.testing.assert_array_equal(
-            np.sort(ids, -1),
-            np.sort(r["expert_ids"][start:start + ids.shape[0]], -1))
-    return r
-
-
-# 7 and 16: the whole context is attended (<= index_topk 24); 40 and 75: the
-# selection engages in the prefill and in every decode step.
-@pytest.mark.parametrize("n_prompt", [7, 16, 40, 75])
-@pytest.mark.parametrize("path", ["dense", "chunk"])
-def test_prefill_then_decode_through_the_cache(model, path, n_prompt):
-    eng = make_engine(
-        model, prefill_chunk=16,
-        long_prefill_threshold=1024 if path == "dense" else 0,
-    )
-    prompt = prompt_of(n_prompt)
-    req = run(eng, prompt)
-    assert eng._last_prefill_path == path
-    r = check_against_reference(model, req, prompt)
-    cfg = model[0]
-    want = min(len(prompt) + N_NEW - 1, cfg.index_topk)
-    assert r["selected"][-1].tolist() == [want, want]
+SPEC = ModelSpec(
+    preset="glm-5.2-tiny", seed=33, reference=logits_from(ref.forward),
+    engine=dict(page_size=4, num_slots=1024, max_batch=4, max_seq_len=256,
+                prefill_chunk=16),
+    # 7 and 16: the whole context is attended (<= index_topk 24); 40 and 75:
+    # the selection engages in the prefill and in every decode step
+    lengths=(7, 16, 40, 75),
+    says=("learned sparse attention",),
+    hooks={"prefill": _the_selection_engages_past_index_topk},
+)
+make_engine, prompt_of, run, check_against_reference = (
+    SPEC.make_engine, SPEC.prompt_of, SPEC.run, SPEC.check)
+PAGE, N_NEW, TOL = SPEC.engine["page_size"], SPEC.n_new, SPEC.tol
 
 
 @pytest.mark.parametrize("n_tail", [5, 21])
@@ -221,14 +173,6 @@ def test_the_pool_holds_two_arrays_under_one_allocator(model):
                               keys[:, [list(slots).index(s) for s in reused]])
     with pytest.raises(ValueError, match="index plane"):
         type(pool)(256, 6, 1, 128, page_size=4, planes=1, index_shape=(2, 128, 16))
-
-
-@pytest.mark.parametrize("what", sorted(REFUSED))
-def test_engine_refuses_by_name(model, what):
-    kw, name = REFUSED[what]
-    with pytest.raises(UnsupportedForModel, match=name) as e:
-        make_engine(model, **(kw() if callable(kw) else kw))
-    assert "learned sparse attention" in str(e.value)
 
 
 def test_refusals_name_the_expert_share_and_the_indexer(model):
@@ -454,18 +398,6 @@ def test_selection_is_exact_and_ties_go_to_the_lower_position():
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", cached)
-
-
 SPARSE = {"radixmesh_dsa_index_decode", "radixmesh_mla_sparse_decode",
           "radixmesh_dsa_select", "radixmesh_dsa_gather"}
 
@@ -480,31 +412,21 @@ def test_decode_lowers_to_dense_or_sparse_by_the_context(v5e, pages, inside, out
     latent kernel unchanged (and writes the index keys), a wider one the
     index, selection, gather and sparse-attention pieces; both pools are
     aliased through; the expert layer holds its 16 of 256."""
-    import re
-
-    from radixmesh_tpu.models.llama import _init_params
-
     cfg = get_config("glm-5.2").replace(
         n_layers=3, first_k_dense=1, indexer_types=("full", "shared", "full"),
         vocab_size=4096, ep_size=16)
     assert cfg.kv_pool_shape(4096) == (1, 3, 1, 4096, 640)
     assert cfg.kv_index_shape(4096) == (2, 4096, 128)
-
-    def sd(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    shapes = jax.eval_shape(lambda k: _init_params(k, cfg, None, None),
-                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    shapes, params, sd = abstract_params(cfg, v5e)
     assert shapes["layers"]["w_gate"].shape == (2, 16, 6144, 2048)
     assert shapes["layers"]["w_router"].shape == (2, 6144, 256)
     assert shapes["indexer"]["wq"].shape == (2, 2048, 32 * 128)
-    params = jax.tree.map(lambda x: sd(x.shape, x.dtype), shapes)
     compiled = decode_step.lower(
         params, cfg, sd((8,), jnp.int32), sd(cfg.kv_pool_shape(4096), cfg.dtype),
         sd((8,), jnp.int32), sd((8, pages), jnp.int32), sd((8,), jnp.int32), 16,
         index_pool=sd(cfg.kv_index_shape(4096), cfg.dtype),
     ).compile()
-    names = set(re.findall(r"radixmesh_[a-z_]+[a-z]", compiled.as_text()))
+    names = kernels_in(compiled)
     assert inside <= names and not (outside & names), names
     assert "radixmesh_moe_gmm_decode" in names
     mem = compiled.memory_analysis()
@@ -517,21 +439,12 @@ def test_chunk_lowers_to_the_sparse_pieces(v5e):
     Mosaic accepts the chunk variants of both kernels (32 queries x 32
     index heads a program; one query's 2 048 gathered rows a program) and
     the index keys of the chunk land in the aliased index pool."""
-    import re
-
-    from radixmesh_tpu.models.llama import _init_params, prefill_chunk_paged
+    from radixmesh_tpu.models.llama import prefill_chunk_paged
 
     cfg = get_config("glm-5.2").replace(
         n_layers=2, first_k_dense=1, indexer_types=("full", "shared"),
         vocab_size=4096, ep_size=16)
-
-    def sd(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    params = jax.tree.map(
-        lambda x: sd(x.shape, x.dtype),
-        jax.eval_shape(lambda k: _init_params(k, cfg, None, None),
-                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    _, params, sd = abstract_params(cfg, v5e)
     compiled = prefill_chunk_paged.lower(
         params, cfg, sd((2, 64), jnp.int32), sd((2, 64), jnp.int32),
         sd(cfg.kv_pool_shape(8192), cfg.dtype), sd((2, 64), jnp.int32),
@@ -539,7 +452,7 @@ def test_chunk_lowers_to_the_sparse_pieces(v5e):
         logits_at=sd((2,), jnp.int32),
         index_pool=sd(cfg.kv_index_shape(8192), cfg.dtype),
     ).compile()
-    names = set(re.findall(r"radixmesh_[a-z_]+[a-z]", compiled.as_text()))
+    names = kernels_in(compiled)
     assert {"radixmesh_dsa_index_chunk", "radixmesh_mla_sparse_chunk",
             "radixmesh_dsa_select", "radixmesh_dsa_gather",
             "radixmesh_moe_gmm_prefill"} <= names, names
@@ -548,19 +461,6 @@ def test_chunk_lowers_to_the_sparse_pieces(v5e):
     # either (a scatter on the slot axis relayouts both; PR 34).
     held = {",".join(map(str, shape)) for shape in (
         cfg.kv_pool_shape(8192), cfg.kv_index_shape(8192))}
-    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
-    assert not held & set(copied), held & set(copied)
+    assert not held & copied_shapes(compiled), held & copied_shapes(compiled)
 
 
-@pytest.mark.parametrize("preset", ["qwen2-tiny", "mellum2-tiny", "glm-4.7-flash-tiny"])
-def test_the_other_models_have_no_index_plane_and_no_share(preset):
-    cfg = get_config(preset)
-    assert not cfg.dsa and cfg.kv_index_shape(64) is None and cfg.ep_size == 1
-    assert cfg.held_experts == cfg.n_experts
-    eng = Engine(cfg.replace(dtype=jnp.float32), init_params(
-        cfg.replace(dtype=jnp.float32), jax.random.PRNGKey(0)),
-        num_slots=256, page_size=4, max_batch=2, max_seq_len=64)
-    assert eng.pool.index is None and eng.pool.index_plane_bytes == 0
-    assert "sparse" not in eng.attn_window
-    out = eng.generate([prompt_of(9)], SamplingParams(max_new_tokens=3))
-    assert len(out[0]) == 3

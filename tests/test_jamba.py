@@ -15,97 +15,33 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import jamba as ref
-from radixmesh_tpu.engine.engine import Engine, pool_groups
+from radixmesh_tpu.engine.engine import pool_groups
 from radixmesh_tpu.engine.request import SamplingParams
 from radixmesh_tpu.models import get_config, init_params
 from radixmesh_tpu.models.llama import (
     ModelConfig,
-    UnsupportedForModel,
     _mixed_runs,
     decode_step,
     prefill_chunk_paged,
     prefill_forward,
 )
 from radixmesh_tpu.ops.attention import attend_chunk_hybrid, attend_decode_ref
-from tests.test_mellum2 import REFUSED  # the same options, by name
+from tests.model_conformance import *  # noqa: F401,F403  (the shared cases)
+from tests.model_conformance import ModelSpec
 
 pytestmark = pytest.mark.quick
 
-N_NEW = 9  # one token from the prefill, 8 decode steps through the slots
-TOL = 1e-4  # float32 against float32: other orders of the same sums
-# bf16 weights, activations, K/V and convolution inputs against the float32
-# reference at toy widths (8 layers of 256): readings 0.012-0.031 over the
-# paths below; a bf16 recurrent state reads the same here (nine positions of
-# decay do not tell it apart at these widths: the chip's control does)
-BAND_BF16 = 0.06
+
+def reference(params, cfg, seq, logits_from, **control):
+    return ref.forward(params, cfg, seq, at=np.arange(logits_from, len(seq)),
+                       **control)
 
 
-@pytest.fixture(scope="module")
-def model():
-    cfg = get_config("jamba-tiny").replace(dtype=jnp.float32)
-    return cfg, init_params(cfg, jax.random.PRNGKey(55))
-
-
-def make_engine(model, page=16, **kw):
-    cfg, params = model
-    kw.setdefault("num_slots", 8192)
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("max_seq_len", 2048)
-    kw.setdefault("prefill_chunk", 64)
-    return Engine(cfg, params, page_size=page, **kw)
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng([55, seed]).integers(0, 512, n).tolist()
-
-
-def run(eng, prompt, n_new=N_NEW):
-    req = eng.add_request(prompt, SamplingParams(
-        max_new_tokens=n_new, return_logits=True))
-    while eng.has_work():
-        eng.step()
-    return req
-
-
-def error(model, req, prompt, **control):
-    """``max|a - b| / max|b|`` of the request's logits rows against the
-    reference's full forward pass of the same sequence."""
-    cfg, params = model
-    seq = list(prompt) + req.generated[:-1]
-    want = np.asarray(ref.forward(
-        params, cfg, seq, at=np.arange(len(prompt) - 1, len(seq)), **control))
-    return ref.distance(np.stack(req.logit_rows), want)[0]
-
-
-def check(model, req, prompt, n_new=N_NEW, tol=TOL):
-    assert len(req.generated) == n_new and len(req.logit_rows) == n_new
-    err = error(model, req, prompt)
-    assert err < tol, err
-    if tol == TOL:
-        assert [int(x) for x in np.stack(req.logit_rows).argmax(-1)] == req.generated
-
-
-# ---------------------------------------------------------------------------
-# every prompt path against the reference, at both pages
-# ---------------------------------------------------------------------------
-
-
-# 7: inside one page; 48: a multiple of 16; 150 and 300: chunks of 64 carrying
-# the state (300: three pages of 128)
-@pytest.mark.parametrize("page", [16, 128])
-@pytest.mark.parametrize("n_prompt", [7, 48, 150, 300])
-@pytest.mark.parametrize("path", ["dense", "chunk"])
-def test_prefill_then_decode_through_the_slots_and_the_pool(
-        model, path, n_prompt, page):
-    eng = make_engine(
-        model, page, long_prefill_threshold=1024 if path == "dense" else 0)
-    prompt = prompt_of(n_prompt)
-    req = run(eng, prompt)
+def _the_pool_and_the_slots(eng, n_prompt, path, **_):
+    """K and V of the two attention layers alone in the pool; the recurrent
+    part float32 and the convolution's inputs beside it in the state slots."""
+    cfg, page = eng.cfg, eng.page_size
     assert eng._last_prefill_path == ("chunk" if n_prompt == 300 else path)
-    check(model, req, prompt)
-    cfg = model[0]
-    # K and V of the two attention layers alone in the pool; the recurrent
-    # part float32 and the convolution's inputs beside it in the state slots
     assert eng.pool.kv.shape == (2, 2, 1, 8192, 64)
     assert eng.pool.state.shape[0] == 6 and eng.pool.state.dtype == jnp.float32
     assert eng.pool.state.shape[2:] == (16, 512)
@@ -114,108 +50,42 @@ def test_prefill_then_decode_through_the_slots_and_the_pool(
         16 * 4 + 3 * 4)
     aw = eng.attn_window
     assert aw["decode_context_tokens"] == sum(
-        n_prompt + i for i in range(1, N_NEW))
-    assert aw["table_pages_max"] >= -(-(n_prompt + N_NEW) // page)
+        n_prompt + i for i in range(1, SPEC.n_new))
+    assert aw["table_pages_max"] >= -(-(n_prompt + SPEC.n_new) // page)
 
 
-@pytest.mark.parametrize("page", [16, 128])
-def test_a_batch_of_ragged_rows(model, page):
-    eng = make_engine(model, page)
-    prompts = [prompt_of(n, seed=30 + i) for i, n in enumerate((20, 75, 130, 41))]
-    reqs = [eng.add_request(p, SamplingParams(
-        max_new_tokens=N_NEW + i, return_logits=True))
-        for i, p in enumerate(prompts)]
-    while eng.has_work():
-        eng.step()
-    for i, (req, p) in enumerate(zip(reqs, prompts)):
-        check(model, req, p, N_NEW + i)
+SPEC = ModelSpec(
+    preset="jamba-tiny", seed=55, reference=reference,
+    engine=dict(page_size=16, num_slots=8192, max_batch=4, max_seq_len=2048,
+                prefill_chunk=64),
+    # 7: inside one page; 48: a multiple of 16; 150 and 300: chunks of 64
+    # carrying the state (300: three pages of 128); and at this model's page
+    lengths=(7, 48, 150, 300), pages=(128,),
+    # bf16 weights, activations, K/V and convolution inputs against the
+    # float32 reference at toy widths (8 layers of 256): readings 0.012-0.031
+    # over the paths below; a bf16 recurrent state reads the same here (nine
+    # positions of decay do not tell it apart at these widths: the chip's
+    # control does)
+    band_bf16=0.06,
+    # without the three inner norms, with int8 weights, with the recurrent
+    # state held in bf16: each further off than float32 rounding by orders
+    parts={"inner_norms": (dict(no_inner_norms=True), 30),
+           "int8_weights": (dict(weight_map=ref.int8_weights), 30),
+           "f32_state": (dict(state_dtype=jnp.bfloat16), 30)},
+    parts_prompt=150,
+    forget={"state": lambda at: dict(forget_state_at=at)},
+    forget_is_exact=("state",),
+    says=("state-space layers with a recurrent state",),
+    omits=("cross-decoder",),  # the other layout's
+    hooks={"prefill": _the_pool_and_the_slots},
+)
+make_engine, prompt_of, run, check = (
+    SPEC.make_engine, SPEC.prompt_of, SPEC.run, SPEC.check)
 
 
-def test_forty_decode_steps_keep_the_state_exact(model):
-    eng = make_engine(model)
-    prompt = prompt_of(33, seed=2)
-    check(model, run(eng, prompt, 41), prompt, 41)
-
-
-@pytest.mark.parametrize("page, n_prompt, resumed", [
-    (16, 100, 96), (16, 96, 80), (128, 300, 256), (128, 256, 128)])
-def test_the_identical_prompt_again_resumes_from_the_snapshot(
-        model, page, n_prompt, resumed):
-    """The pages of the two attention layers from the tree AND the snapshot
-    its first visit left there, a tail to compute."""
-    eng = make_engine(model, page)
-    prompt = prompt_of(n_prompt, seed=3)
-    first = run(eng, prompt)
-    assert first.prefix_len == 0
-    again = run(eng, prompt)
-    third = run(eng, prompt)
-    assert third.prefix_len == resumed and eng._last_prefill_path == "chunk"
-    assert again.prefix_len == (resumed if n_prompt % page else 0)
-    for req in (first, again, third):
-        check(model, req, prompt)
-    st = eng.state_stats
-    assert st["resumed_tokens"] == again.prefix_len + third.prefix_len
-    assert eng.stats.cached_tokens == st["resumed_tokens"]
-
-
-@pytest.mark.parametrize("page, n1, out1, cut", [(16, 70, 30, 96), (128, 200, 70, 256)])
-def test_the_next_turn_resumes_from_the_release_snapshot(model, page, n1, out1, cut):
-    """Turn 2 resumes from the state the decode steps kept at the last page
-    boundary (a boundary copy; no prefill ever computed it)."""
-    eng = make_engine(model, page)
-    p1 = prompt_of(n1, seed=4)
-    t1 = run(eng, p1, n_new=out1)
-    check(model, t1, p1, n_new=out1)
-    p2 = p1 + t1.generated + prompt_of(11, seed=5)
-    t2 = run(eng, p2)
-    assert t2.prefix_len == cut and eng.state_stats["recomputed_tokens"] == 0
-    check(model, t2, p2)
-    p3 = p2 + t2.generated + prompt_of(5, seed=6)
-    t3 = run(eng, p3)
-    assert t3.prefix_len == (len(p2) + N_NEW - 1) // page * page
-    check(model, t3, p3)
-
-
-def test_one_launch_holds_a_row_that_resumes_and_a_cold_one(model):
-    eng = make_engine(model)
-    first = prompt_of(100, seed=11)
-    run(eng, first)
-    warm = first + prompt_of(80, seed=13)  # resumes at 96: 84 to compute
-    cold = prompt_of(90, seed=12)
-    reqs = [eng.add_request(p, SamplingParams(
-        max_new_tokens=N_NEW, return_logits=True)) for p in (warm, cold)]
-    while eng.has_work():
-        eng.step()
-    assert [r.prefix_len for r in reqs] == [96, 0]
-    check(model, reqs[0], warm)
-    check(model, reqs[1], cold)
-
-
-def test_a_snapshot_evicted_under_its_rows_is_computed_again(model):
-    eng = make_engine(model)
-    prompt = prompt_of(100, seed=14)
-    run(eng, prompt)
-    run(eng, prompt + prompt_of(30, seed=15))
-    n = eng.pool.state_slots_used - 1
-    assert n > 0 and eng.tree.evict_states(8) == n
-    m = eng.tree.match_prefix(np.asarray(prompt, np.int32))
-    assert m.length == 96 and m.states == []  # the K / V rows stay
-    again = run(eng, prompt)
-    assert again.prefix_len == 0 and eng.state_stats["recomputed_tokens"] == 96
-    check(model, again, prompt)
-    assert run(eng, prompt).prefix_len == 96  # the snapshot is back
-
-
-def test_plain_launches_keep_the_boundary_state(model):
-    sp = SamplingParams(max_new_tokens=70)
-    p1 = prompt_of(50, seed=17)
-    warm = make_engine(model)
-    o1 = warm.generate([p1], sp)[0]
-    p2 = p1 + o1 + prompt_of(7, seed=18)
-    o2 = warm.generate([p2], sp)[0]
-    assert warm.stats.cached_tokens == 112  # 50 + 70 - 1 = 119 -> 112
-    cold = make_engine(model)
-    assert cold.generate([p2], sp)[0] == o2
+# ---------------------------------------------------------------------------
+# the state slots under pressure
+# ---------------------------------------------------------------------------
 
 
 def test_a_full_state_group_gives_up_snapshots_and_serves_on(model):
@@ -264,38 +134,11 @@ def test_a_live_rows_prompt_snapshot_is_kept_until_it_completes_a_page(
 # ---------------------------------------------------------------------------
 
 
-def test_a_zeroed_snapshot_would_not_pass(model):
-    """The control of the resume tests: the reference that forgets every
-    Mamba state and convolution input at the resume position is far off."""
-    eng = make_engine(model)
-    prompt = prompt_of(100, seed=3)
-    run(eng, prompt)
-    again = run(eng, prompt)
-    assert again.prefix_len == 96
-    assert error(model, again, prompt) < TOL
-    assert error(model, again, prompt, forget_state_at=96) > 100 * TOL
-
-
-@pytest.mark.parametrize("control", [
-    dict(no_inner_norms=True), dict(weight_map=ref.int8_weights),
-    dict(state_dtype=jnp.bfloat16)])
-def test_the_reference_without_a_part_is_refused(model, control):
-    """Without the three inner norms, with int8 weights, with the recurrent
-    state held in bf16: each is further from the program than float32
-    rounding by orders of magnitude."""
-    eng = make_engine(model)
-    prompt = prompt_of(150, seed=21)
-    req = run(eng, prompt)
-    assert error(model, req, prompt) < TOL
-    assert error(model, req, prompt, **control) > 30 * TOL
-
-
 @pytest.mark.parametrize("path", ["dense", "chunk", "again", "turn"])
 def test_bf16_stays_inside_its_band(path):
     """The configuration's own precision on every path, against the float32
     reference of the same (bf16-drawn) weights."""
-    cfg = get_config("jamba-tiny")
-    model = (cfg, init_params(cfg, jax.random.PRNGKey(55)))
+    model = SPEC.build(dtype=jnp.bfloat16)
     eng = make_engine(model, long_prefill_threshold=1024 if path == "dense" else 0)
     prompt = prompt_of(150, seed=22)
     req = run(eng, prompt)
@@ -306,7 +149,7 @@ def test_bf16_stays_inside_its_band(path):
         prompt = prompt + req.generated + prompt_of(9, seed=23)
         req = run(eng, prompt)
         assert req.prefix_len == 144
-    check(model, req, prompt, tol=BAND_BF16)
+    check(model, req, prompt, tol=SPEC.band_bf16)
 
 
 # ---------------------------------------------------------------------------
@@ -501,26 +344,6 @@ def test_the_inner_norms_belong_to_the_interleaved_layout():
     plain = get_config("jamba-tiny").replace(ssm_inner_norms=False)
     params = init_params(plain, jax.random.PRNGKey(0))
     assert "dt_norm" not in params["mamba"]
-
-
-@pytest.mark.parametrize("what", sorted(REFUSED))
-def test_what_assumes_the_dense_layer_is_refused(model, what):
-    kw, name = REFUSED[what]
-    with pytest.raises(UnsupportedForModel, match=name) as e:
-        make_engine(model, **(kw() if callable(kw) else kw))
-    assert "state-space layers with a recurrent state" in str(e.value)
-    assert "cross-decoder" not in str(e.value)  # the other layout's
-
-
-@pytest.mark.parametrize("option", [
-    {"stream_publish_tokens": 8}, {"weight_quant": "int8"}])
-def test_what_moves_a_prefix_without_its_state_is_refused(model, option):
-    cfg, params = model
-    with pytest.raises(UnsupportedForModel, match="state-space"):
-        if "weight_quant" in option:
-            init_params(cfg, jax.random.PRNGKey(0), **option)
-        else:
-            make_engine(model, **option)
 
 
 def test_a_config_without_mamba_layers_takes_no_ssm_sizes():

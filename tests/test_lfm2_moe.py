@@ -13,8 +13,6 @@ session, a branch inside a shared prefix, a split above and below a
 snapshot, a state evicted under its rows, a preempted request's second
 life. A zeroed state has to FAIL the same tolerance."""
 
-import re
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,8 +20,8 @@ import pytest
 
 from benchmarks.reference import lfm2_moe as ref
 from radixmesh_tpu.cache.radix_tree import RadixTree
-from radixmesh_tpu.engine import Engine, SamplingParams
-from radixmesh_tpu.models import get_config, init_params
+from radixmesh_tpu.engine import SamplingParams
+from radixmesh_tpu.models import get_config
 from radixmesh_tpu.models.llama import (
     UnsupportedForModel,
     _mixed_runs,
@@ -36,216 +34,47 @@ from radixmesh_tpu.models.llama import (
 )
 from radixmesh_tpu.ops.attention import attend_decode_ref, kernel_shape_ok
 from radixmesh_tpu.ops.moe import moe_ffn
-from tests.test_mellum2 import REFUSED  # the same options, by name
+from tests.model_conformance import *  # noqa: F401,F403  (the shared cases)
+from tests.model_conformance import ModelSpec, logits_from
+from tests.model_conformance import distance as rel
 
 pytestmark = pytest.mark.quick
 
-PAGE = 16
-N_NEW = 9  # one token from the prefill, 8 decode steps through the pool
-TOL = 1e-4  # float32 against float32: other orders of the same sums
 
-
-@pytest.fixture(scope="module")
-def model():
-    cfg = get_config("lfm2-tiny").replace(dtype=jnp.float32)
-    return cfg, init_params(cfg, jax.random.PRNGKey(39))
-
-
-def make_engine(model, **kw):
-    cfg, params = model
-    kw.setdefault("num_slots", 4096)
-    kw.setdefault("page_size", PAGE)
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("max_seq_len", 1024)
-    kw.setdefault("prefill_chunk", 64)
-    return Engine(cfg, params, **kw)
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng([39, seed]).integers(0, 512, n).tolist()
-
-
-def rel(a, b):
-    return float(np.abs(a - b).max() / np.abs(b).max())
-
-
-def run(eng, prompt, n_new=N_NEW):
-    req = eng.add_request(prompt, SamplingParams(
-        max_new_tokens=n_new, return_logits=True))
-    while eng.has_work():
-        eng.step()
-    return req
-
-
-def error_against_reference(model, req, prompt):
-    cfg, params = model
-    out = req.generated
-    seq = list(prompt) + out[:-1]
-    r = ref.forward(params, cfg, seq, logits_from=len(prompt) - 1)
-    got = np.stack(req.logit_rows)
-    assert got.shape == r["logits"].shape
-    return rel(got, r["logits"]), got, r
-
-
-def check_against_reference(model, req, prompt, n_new=N_NEW):
-    cfg = model[0]
-    assert len(req.generated) == n_new and len(req.logit_rows) == n_new
-    err, got, r = error_against_reference(model, req, prompt)
-    assert err < TOL, err
-    assert [int(x) for x in got.argmax(-1)] == req.generated
-    for start, ids in req.expert_rows:  # global expert ids, the sparse layers
-        assert ids.shape[1:] == (cfg.n_layers - 2, cfg.n_experts_per_tok)
-        np.testing.assert_array_equal(
-            np.sort(ids, -1),
-            np.sort(r["expert_ids"][start:start + ids.shape[0]], -1))
-    return r
-
-
-# ---------------------------------------------------------------------------
-# the three entry points against the reference
-# ---------------------------------------------------------------------------
-
-
-# 7: inside one page; 48: a page multiple; 100: chunks of 64 with a ragged end
-@pytest.mark.parametrize("n_prompt", [7, 48, 100])
-@pytest.mark.parametrize("path", ["dense", "chunk"])
-def test_prefill_then_decode_through_the_pool_and_the_state(model, path, n_prompt):
-    eng = make_engine(
-        model, long_prefill_threshold=1024 if path == "dense" else 0)
-    prompt = prompt_of(n_prompt)
-    req = run(eng, prompt)
-    assert eng._last_prefill_path == path
-    check_against_reference(model, req, prompt)
-    cfg = model[0]
-    # rows in the 3 attention layers only, two heads to a 128-lane row
+def _rows_of_the_attention_layers_alone(eng, **_):
+    """Rows in the 3 attention layers only, two heads to a 128-lane row."""
+    cfg = eng.cfg
     assert eng.pool.kv.shape == (2, 3, 1, 4096, 128) == cfg.kv_pool_shape(4096)
     assert eng.pool.state.shape == cfg.state_shape(
         cfg.default_state_slots(4096, 4)) == (9, 256, 2, 128)
 
 
-def test_a_batch_of_ragged_rows_prefills_and_decodes_together(model):
-    """Four prompts of other lengths in one wave (padded rows: each row's
-    state is taken at its own true end), then decoded as one batch."""
-    eng = make_engine(model)
-    prompts = [prompt_of(n, seed=n) for n in (5, 37, 64, 90)]
-    reqs = [eng.add_request(p, SamplingParams(
-        max_new_tokens=N_NEW, return_logits=True)) for p in prompts]
-    while eng.has_work():
-        eng.step()
-    for req, p in zip(reqs, prompts):
-        check_against_reference(model, req, p)
+def _slot_0_and_the_two_snapshots(eng, **_):
+    assert eng.pool.state_slots_used == 3
+
+
+SPEC = ModelSpec(
+    preset="lfm2-tiny", seed=39, reference=logits_from(ref.forward),
+    engine=dict(page_size=16, num_slots=4096, max_batch=4, max_seq_len=1024,
+                prefill_chunk=64),
+    # 7: inside one page; 48: a page multiple; 100: chunks of 64 with a ragged end
+    lengths=(7, 48, 100),
+    # padded rows: each row's state is taken at its own true end
+    ragged=(5, 37, 64, 90),
+    forget={"state": lambda at: dict(forget_at=at)},
+    says=("gated-convolution layers with a recurrent state",),
+    hooks={"prefill": _rows_of_the_attention_layers_alone,
+           "before_eviction": _slot_0_and_the_two_snapshots,
+           "preempted": _slot_0_and_the_two_snapshots},
+)
+make_engine, prompt_of, run, check_against_reference = (
+    SPEC.make_engine, SPEC.prompt_of, SPEC.run, SPEC.check)
+PAGE, TOL = SPEC.engine["page_size"], SPEC.tol
 
 
 # ---------------------------------------------------------------------------
-# every way a state is reached
+# a snapshot and the tree: splits, and slots that run out
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("n_prompt, resumed", [(100, 96), (96, 80)])
-def test_the_same_prompt_again_resumes_from_a_snapshot(model, n_prompt, resumed):
-    """The prompt's publish left a snapshot at its page-aligned cut; the
-    second visit resumes there — one page short of it when the prompt is a
-    page multiple (a token must be left to compute): it then starts from
-    nothing, and leaves the snapshot the third visit resumes from."""
-    eng = make_engine(model)
-    prompt = prompt_of(n_prompt, seed=1)
-    first = run(eng, prompt)
-    assert first.prefix_len == 0
-    again = run(eng, prompt)
-    third = run(eng, prompt)
-    assert third.prefix_len == resumed and eng._last_prefill_path == "chunk"
-    assert again.prefix_len == (resumed if n_prompt % PAGE else 0)
-    for req in (first, again, third):
-        check_against_reference(model, req, prompt)
-    st = eng.state_stats
-    assert st["kv_matched_tokens"] == 2 * resumed
-    assert st["resumed_tokens"] == again.prefix_len + third.prefix_len
-    assert st["recomputed_tokens"] == 2 * resumed - st["resumed_tokens"]
-    assert eng.stats.cached_tokens == st["resumed_tokens"]
-
-
-def test_the_next_turn_resumes_from_the_release_snapshot(model):
-    """Turn 2 = turn 1's prompt + what it generated + a few new tokens, at
-    a length that is no page multiple: it resumes from the state the decode
-    steps kept at the last page boundary (none of it was ever prefilled)."""
-    eng = make_engine(model)
-    p1 = prompt_of(70, seed=2)
-    t1 = run(eng, p1, n_new=30)  # context 99 at release: cut at 96
-    check_against_reference(model, t1, p1, n_new=30)
-    p2 = p1 + t1.generated + prompt_of(11, seed=3)
-    t2 = run(eng, p2)
-    assert t2.prefix_len == 96 and eng.state_stats["recomputed_tokens"] == 0
-    check_against_reference(model, t2, p2)
-    p3 = p2 + t2.generated + prompt_of(5, seed=4)
-    t3 = run(eng, p3)
-    assert t3.prefix_len == (len(p2) + N_NEW - 1) // PAGE * PAGE
-    check_against_reference(model, t3, p3)
-
-
-def test_two_requests_branch_inside_a_shared_prefix(model):
-    """B shares 64 tokens with A, whose snapshots lie at 112 and beyond: the
-    rows match 64 tokens, no state lies there, so B computes from 0 and
-    leaves a snapshot AT the branch point; C, branching there too, resumes
-    from it."""
-    eng = make_engine(model)
-    shared = prompt_of(64, seed=5)
-    a = shared + prompt_of(50, seed=6)
-    run(eng, a)
-    b = shared + prompt_of(40, seed=7)
-    rb = run(eng, b)
-    assert rb.prefix_len == 0
-    st = dict(eng.state_stats)
-    assert st["kv_matched_tokens"] == 64 and st["recomputed_tokens"] == 64
-    c = shared + prompt_of(33, seed=8)
-    rc = run(eng, c)
-    assert rc.prefix_len == 64
-    assert eng.state_stats["recomputed_tokens"] == 64  # nothing more
-    check_against_reference(model, rb, b)
-    check_against_reference(model, rc, c)
-
-
-def test_a_split_leaves_the_state_with_the_lower_half(model):
-    """A's one node [0, 112) holds a snapshot at its end. B splits it at
-    48 (above the snapshot): the upper half has no state, the lower keeps
-    A's; A again still resumes at 112, and B again at its own cut."""
-    eng = make_engine(model)
-    a = prompt_of(115, seed=9)
-    run(eng, a)
-    b = a[:48] + prompt_of(30, seed=10)
-    rb = run(eng, b)
-    assert rb.prefix_len == 0  # rows for 48, no state there yet
-    ra = run(eng, a)
-    assert ra.prefix_len == 112
-    check_against_reference(model, ra, a)
-    m = eng.tree.match_prefix(np.asarray(a, np.int32))
-    assert [pos for pos, _ in m.states] == [48, 112]  # B's rule (b), then A's
-    rb2 = run(eng, b)
-    assert rb2.prefix_len == 64  # b's own aligned cut (78 -> 64)
-    check_against_reference(model, rb2, b)
-    # below the snapshot: a prompt that ends inside A's lower half
-    c = a[:80] + prompt_of(9, seed=11)
-    rc = run(eng, c)
-    assert rc.prefix_len == 48  # rows to 80; the deepest state at or before
-    check_against_reference(model, rc, c)
-
-
-def test_a_state_evicted_under_its_rows(model):
-    """Pressure on the state slots alone: the tree gives up its least
-    recently used snapshots, the rows stay, and the next visit resumes
-    from nothing — still exact."""
-    eng = make_engine(model)
-    prompt = prompt_of(100, seed=12)
-    run(eng, prompt)
-    run(eng, prompt + prompt_of(30, seed=20))  # a second snapshot, at 128
-    assert eng.pool.state_slots_used == 3  # slot 0 and the two snapshots
-    assert eng.tree.evict_states(8) == 2 and eng.tree.state_evictions == 2
-    assert eng.pool.state_slots_used == 1
-    m = eng.tree.match_prefix(np.asarray(prompt, np.int32))
-    assert m.length == 96 and m.states == []
-    again = run(eng, prompt)
-    assert again.prefix_len == 0 and eng.state_stats["recomputed_tokens"] == 96
-    check_against_reference(model, again, prompt)
-    assert run(eng, prompt).prefix_len == 96  # the snapshot is back
 
 
 def test_slots_run_out_and_the_tree_gives_way(model):
@@ -273,100 +102,6 @@ def test_slots_run_out_and_the_tree_gives_way(model):
     assert eng.pool.state_slots_used == 254
     assert eng.tree.match_prefix(
         np.asarray(prompt_of(40, seed=200), np.int32)).states == []
-
-
-def test_a_preempted_request_resumes_from_what_it_published(model):
-    """Preempted after some decode steps (context past 96: the release
-    cut there holds the boundary state a decode step kept), the second
-    life matches its own prompt and resumes at the prompt's cut."""
-    eng = make_engine(model)
-    prompt = prompt_of(90, seed=13)
-    req = eng.add_request(prompt, SamplingParams(
-        max_new_tokens=20, return_logits=True))
-    while req.kv_len < 98:
-        eng.step()
-        eng._settle()
-    eng._preempt(req)
-    assert eng.pool.state_slots_used == 3  # slot 0 and the two snapshots
-    req.logit_rows.clear()
-    req.expert_rows.clear()
-    eng._pressure = False
-    while eng.has_work():
-        eng.step()
-    assert eng.stats.preemptions == 1 and req.prefix_len == 80
-    check_against_reference(model, req, prompt, n_new=20)
-
-
-def test_plain_launches_keep_the_boundary_state(model):
-    """Without the logits hook the decode launches hand over (inputs
-    built a step ahead): a session served that way continues exactly as a
-    cold engine computes it."""
-    sp = SamplingParams(max_new_tokens=40)
-    p1 = prompt_of(50, seed=14)
-    warm = make_engine(model)
-    o1 = warm.generate([p1], sp)[0]
-    p2 = p1 + o1 + prompt_of(7, seed=15)
-    o2 = warm.generate([p2], sp)[0]
-    assert warm.handover["launches_prebuilt"] > 0
-    assert warm.stats.cached_tokens == 80  # 50 + 40 - 1 = 89 -> 80
-    cold = make_engine(model)
-    assert cold.generate([p2], sp)[0] == o2
-    req = run(warm, p2 + o2 + prompt_of(3, seed=16))
-    assert req.prefix_len == (len(p2) + 40 - 1) // PAGE * PAGE
-    check_against_reference(model, req, p2 + o2 + prompt_of(3, seed=16))
-
-
-# 15: the launch that makes its last token completes page 4 (kv_len 64), so
-# the boundary state its release hands over is that launch's; 11: kv_len 60
-@pytest.mark.parametrize("n_new", [15, 11], ids=["on_a_page", "inside_a_page"])
-def test_a_row_that_ends_by_length_is_released_behind_the_next_launch(model, n_new):
-    """A request reaches its length cap while another goes on: the next
-    launch goes out first, without it, and it is advanced, released and
-    published behind that launch. What its release gives the tree is what
-    its OWN last launch left: its next turn resumes at that snapshot and
-    equals the reference (logits, not tokens), the other row's too, and
-    the run agrees with one that settles after every step."""
-    pa, pb = prompt_of(50, seed=20), prompt_of(37, seed=21)
-
-    def serve(settled):
-        eng = make_engine(model)
-        a = eng.add_request(pa, SamplingParams(max_new_tokens=n_new))
-        b = eng.add_request(pb, SamplingParams(max_new_tokens=40))
-        while eng.has_work():
-            eng.step()
-            if settled:
-                eng._settle()
-        assert eng.handover["finishes_deferred"] == (0 if settled else 1)
-        turns = [pa + a.generated + prompt_of(7, seed=22),
-                 pb + b.generated + prompt_of(5, seed=23)]
-        return eng, [a, b] + [run(eng, t) for t in turns], turns
-
-    eng, reqs, turns = serve(settled=False)
-    cut = (50 + n_new - 1) // PAGE * PAGE
-    assert reqs[2].prefix_len == cut and reqs[3].prefix_len == 64
-    for req, turn in zip(reqs[2:], turns):
-        check_against_reference(model, req, turn)
-    eng2, reqs2, _ = serve(settled=True)
-    assert [r.generated for r in reqs] == [r.generated for r in reqs2]
-    assert [r.prefix_len for r in reqs] == [r.prefix_len for r in reqs2]
-    assert eng.state_stats == eng2.state_stats
-    assert eng.pool.state_slots_used == eng2.pool.state_slots_used
-    assert eng.pool.free_slots == eng2.pool.free_slots
-    assert eng.tree.evictable_size_ == eng2.tree.evictable_size_
-
-
-def test_resuming_from_a_zeroed_state_fails_the_tolerance(model):
-    """The control: the same second turn with the state slots zeroed
-    misses the reference by far more than the tolerance."""
-    eng = make_engine(model)
-    p1 = prompt_of(70, seed=2)
-    t1 = run(eng, p1, n_new=30)
-    eng.pool.state = jnp.zeros_like(eng.pool.state)
-    p2 = p1 + t1.generated + prompt_of(11, seed=3)
-    t2 = run(eng, p2)
-    assert t2.prefix_len == 96
-    err, _, _ = error_against_reference(model, t2, p2)
-    assert err > 100 * TOL, err
 
 
 # ---------------------------------------------------------------------------
@@ -549,14 +284,6 @@ def test_a_share_serves_through_the_same_path(model):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("what", sorted(REFUSED))
-def test_engine_refuses_by_name(model, what):
-    kw, name = REFUSED[what]
-    with pytest.raises(UnsupportedForModel, match=name) as e:
-        make_engine(model, **(kw() if callable(kw) else kw))
-    assert "gated-convolution layers with a recurrent state" in str(e.value)
-
-
 def test_a_prefix_is_moved_or_advertised_nowhere_without_its_state(model):
     cfg, params = model
     for option, name in (
@@ -615,101 +342,56 @@ def test_the_config_refuses_what_it_cannot_run():
     assert big.held_experts == 8
 
 
-@pytest.mark.parametrize("preset", ["qwen2-tiny", "mellum2-tiny", "glm-5.2-tiny"])
-def test_the_other_models_have_no_state(preset):
-    cfg = get_config(preset)
-    assert not cfg.has_conv and cfg.state_shape(64) is None
-    assert cfg.default_state_slots(4096, 8) == 0
-    assert cfg.attn_layers == tuple(range(cfg.n_layers))
-    eng = Engine(cfg.replace(dtype=jnp.float32), init_params(
-        cfg.replace(dtype=jnp.float32), jax.random.PRNGKey(0)),
-        num_slots=256, page_size=4, max_batch=2)
-    assert eng.pool.state is None and eng.state_stats is None
-    assert eng.tree.on_free_state is None
-    eng.generate([[1, 2, 3, 4, 5, 6, 7, 8, 9]], SamplingParams(max_new_tokens=3))
-    assert not any(name.startswith("rm.state") for name in eng.spans.n)
-
-
 # ---------------------------------------------------------------------------
 # what the entry points lower to, at published widths
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", cached)
-
-
 def _published(v5e, periods=3):
     """Published widths, ``periods`` periods deep (a scanned run of two
-    periods when 3), a small vocabulary: shapes only."""
-    from radixmesh_tpu.models.llama import _init_params
-
+    periods when 3), a small vocabulary, the pool and the state slots:
+    shapes only."""
     cfg = get_config("lfm2-24b-a2b-ep8").replace(
         n_layers=4 * periods, layer_types=("conv", "conv", "full_attention",
                                            "conv") * periods, vocab_size=4096)
-
-    def sd(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    shapes = jax.eval_shape(lambda k: _init_params(k, cfg, None, None),
-                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    shapes, params, sd = abstract_params(cfg, v5e)
     assert shapes["layers"]["w_gate"].shape == (4 * periods - 2, 8, 2048, 1536)
     assert shapes["layers"]["w_router"].shape == (4 * periods - 2, 2048, 64)
     assert shapes["conv"]["in_proj"].shape == (3 * periods, 2048, 6144)
     assert shapes["attn"]["wk"].shape == (periods, 2048, 512)
-    return cfg, jax.tree.map(lambda x: sd(x.shape, x.dtype), shapes), sd
-
-
-def _no_copy_of(compiled, *shapes):
-    held = {",".join(map(str, s)) for s in shapes}
-    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
-    assert not held & set(copied), held & set(copied)
+    pools = dict(kv=sd(cfg.kv_pool_shape(8192), cfg.dtype),
+                 state_pool=sd(cfg.state_shape(256), cfg.dtype))
+    return cfg, params, sd, pools
 
 
 def test_decode_lowers_for_a_v5e_at_published_widths(v5e):
     """The fused decode kernel on four wide heads (no jnp attention), the
     expert kernel on its 8 of 64, the pool AND the state slots aliased
     through, neither copied."""
-    cfg, params, sd = _published(v5e)
-    slots, states = 8192, 256
+    cfg, params, sd, pools = _published(v5e)
     compiled = decode_step.lower(
-        params, cfg, sd((8,), jnp.int32), sd(cfg.kv_pool_shape(slots), cfg.dtype),
+        params, cfg, sd((8,), jnp.int32), pools["kv"],
         sd((8,), jnp.int32), sd((8, 64), jnp.int32), sd((8,), jnp.int32), 16,
-        state_pool=sd(cfg.state_shape(states), cfg.dtype),
-        state_slots=sd((2, 8), jnp.int32),
+        state_pool=pools["state_pool"], state_slots=sd((2, 8), jnp.int32),
     ).compile()
-    names = set(re.findall(r"radixmesh_[a-z_]+[a-z]", compiled.as_text()))
+    names = kernels_in(compiled)
     assert {"radixmesh_decode_fused_mh", "radixmesh_moe_gmm_decode"} <= names, names
-    both = (np.prod(cfg.kv_pool_shape(slots)) + np.prod(cfg.state_shape(states))) * 2
-    assert compiled.memory_analysis().alias_size_in_bytes >= both
-    _no_copy_of(compiled, cfg.kv_pool_shape(slots), cfg.state_shape(states))
+    aliased_and_uncopied(compiled, pools.values())
 
 
 def test_chunk_lowers_for_a_v5e_at_published_widths(v5e):
-    cfg, params, sd = _published(v5e)
-    slots, states = 8192, 256
+    cfg, params, sd, pools = _published(v5e)
     compiled = prefill_chunk_paged.lower(
         params, cfg, sd((2, 256), jnp.int32), sd((2, 256), jnp.int32),
-        sd(cfg.kv_pool_shape(slots), cfg.dtype), sd((2, 256), jnp.int32),
+        pools["kv"], sd((2, 256), jnp.int32),
         sd((2, 256), jnp.int32), sd((2,), jnp.int32), page_size=16,
-        logits_at=sd((2,), jnp.int32),
-        state_pool=sd(cfg.state_shape(states), cfg.dtype),
+        logits_at=sd((2,), jnp.int32), state_pool=pools["state_pool"],
         state_in=sd((2,), jnp.int32), snap_at=sd((2, 3), jnp.int32),
         state_out=sd((2, 3), jnp.int32),
     ).compile()
-    names = set(re.findall(r"radixmesh_[a-z_]+[a-z]", compiled.as_text()))
+    names = kernels_in(compiled)
     assert {"radixmesh_chunk_attn", "radixmesh_moe_gmm_prefill"} <= names, names
-    both = (np.prod(cfg.kv_pool_shape(slots)) + np.prod(cfg.state_shape(states))) * 2
-    assert compiled.memory_analysis().alias_size_in_bytes >= both
-    _no_copy_of(compiled, cfg.kv_pool_shape(slots), cfg.state_shape(states))
+    aliased_and_uncopied(compiled, pools.values())
 
 
 _LOWER_DECODE = """

@@ -17,114 +17,40 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import mellum2 as ref
-from radixmesh_tpu.engine import Engine, SamplingParams
+from radixmesh_tpu.engine import SamplingParams
 from radixmesh_tpu.models import get_config, init_params
 from radixmesh_tpu.models.llama import (
     UnsupportedForModel,
     _bump_moe_stats,
-    convert_hf_state_dict,
     new_moe_stats,
     prefill_chunk_paged,
     read_moe_stats,
 )
 from radixmesh_tpu.ops.moe import moe_ffn
+from tests.model_conformance import *  # noqa: F401,F403  (the shared cases)
+from tests.model_conformance import ModelSpec, logits_from
+from tests.model_conformance import distance as rel
 
 pytestmark = pytest.mark.quick
 
-PAGE = 4
 WINDOW = 16
-N_NEW = 17  # one token from the prefill, 16 decode steps through the pool
-TOL = 1e-4  # float32 against float32: other orders of the same sums
+
+SPEC = ModelSpec(
+    preset="mellum2-tiny", seed=27, reference=logits_from(ref.forward),
+    engine=dict(page_size=4, num_slots=1024, max_batch=4, max_seq_len=256,
+                prefill_chunk=16),
+    # below, at, one past, and well past the window (and past a chunk of 16)
+    lengths=(WINDOW - 7, WINDOW, WINDOW + 1, 3 * WINDOW + 5),
+    n_new=17,  # one token from the prefill, 16 decode steps through the pool
+    says=("sparse experts and window attention",),
+)
+make_engine, prompt_of, check_against_reference = (
+    SPEC.make_engine, SPEC.prompt_of, SPEC.check)
+PAGE, N_NEW, TOL = SPEC.engine["page_size"], SPEC.n_new, SPEC.tol
 
 
-@pytest.fixture(scope="module")
-def model():
-    cfg = get_config("mellum2-tiny").replace(dtype=jnp.float32)
-    assert cfg.sliding_window == WINDOW
-    return cfg, init_params(cfg, jax.random.PRNGKey(27))
-
-
-def make_engine(model, **kw):
-    cfg, params = model
-    kw.setdefault("num_slots", 1024)
-    kw.setdefault("page_size", PAGE)
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("max_seq_len", 128)
-    return Engine(cfg, params, **kw)
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng([27, seed]).integers(0, 512, n).tolist()
-
-
-def rel(a, b):
-    return float(np.abs(a - b).max() / np.abs(b).max())
-
-
-def check_against_reference(model, req, prompt):
-    """The request's kept logits rows and expert ids against the
-    reference's full forward pass over prompt + generated tokens."""
-    cfg, params = model
-    out = req.generated
-    assert len(out) == N_NEW and len(req.logit_rows) == N_NEW
-    seq = list(prompt) + out[:-1]
-    r = ref.forward(params, cfg, seq, logits_from=len(prompt) - 1)
-    got = np.stack(req.logit_rows)
-    assert got.dtype == np.float32 and got.shape == r["logits"].shape
-    assert rel(got, r["logits"]) < TOL
-    assert [int(x) for x in got.argmax(-1)] == out  # greedy: what was sampled
-    seen = set()
-    for start, ids in req.expert_rows:  # every computed position's choice
-        n = ids.shape[0]
-        assert ids.shape[1:] == (cfg.n_layers, cfg.n_experts_per_tok)
-        np.testing.assert_array_equal(
-            np.sort(ids, -1), np.sort(r["expert_ids"][start:start + n], -1))
-        seen.update(range(start, start + n))
-    assert seen == set(range(req.prefix_len, len(seq)))
-
-
-# below, at, one past, and well past the window (and past a chunk of 16)
-LENGTHS = [WINDOW - 7, WINDOW, WINDOW + 1, 3 * WINDOW + 5]
-
-
-@pytest.mark.parametrize("n_prompt", LENGTHS)
-@pytest.mark.parametrize("path", ["dense", "chunk"])
-def test_prefill_then_decode_through_the_pool(model, path, n_prompt):
-    eng = make_engine(
-        model, prefill_chunk=16,
-        long_prefill_threshold=1024 if path == "dense" else 0,
-    )
-    prompt = prompt_of(n_prompt)
-    req = eng.add_request(
-        prompt, SamplingParams(max_new_tokens=N_NEW, return_logits=True))
-    while eng.has_work():
-        eng.step()
-    assert eng._last_prefill_path == path
-    check_against_reference(model, req, prompt)
-
-
-@pytest.mark.parametrize("n_prompt", [WINDOW + 4, 3 * WINDOW + 5])
-@pytest.mark.parametrize("path", ["dense", "chunk"])
-def test_prefix_served_from_the_tree(model, path, n_prompt):
-    """A second request whose prompt is mostly in the radix tree: window
-    layers then read their keys from pages another request wrote —
-    ``path`` is how that one prefilled (cold); the second reads its prefix
-    where it lies, as a chunk group of one, whatever the threshold."""
-    eng = make_engine(
-        model, prefill_chunk=16,
-        long_prefill_threshold=1024 if path == "dense" else 0,
-    )
-    shared = prompt_of(n_prompt, seed=1)
-    eng.generate([shared + prompt_of(3, seed=2)], SamplingParams(max_new_tokens=2))
-    assert eng._last_prefill_path == path
-    prompt = shared + prompt_of(5, seed=3)
-    req = eng.add_request(
-        prompt, SamplingParams(max_new_tokens=N_NEW, return_logits=True))
-    while eng.has_work():
-        eng.step()
-    assert eng._last_prefill_path == "chunk"
-    assert req.prefix_len >= n_prompt // PAGE * PAGE > 0
-    check_against_reference(model, req, prompt)
+def test_the_window_is_the_presets(model):
+    assert model[0].sliding_window == WINDOW
 
 
 def test_batched_rows_and_counters(model):
@@ -325,32 +251,6 @@ def test_yarn_table_is_transformers():
     assert list(kinds[:4]) == ["sliding_attention"] * 3 + ["full_attention"]
 
 
-def _mesh(**axes):
-    from radixmesh_tpu.parallel.sharding import MeshPlan, make_mesh
-
-    return make_mesh(MeshPlan(**axes))
-
-
-REFUSED = {
-    "weight_quant": (dict(weight_quant="int8"), "int8 weights"),
-    "kv_quant": (dict(kv_quant="int8"), "int8 KV"),
-    "tp": (lambda: dict(device_mesh=_mesh(tp=2)), "device mesh"),
-    "sp": (lambda: dict(device_mesh=_mesh(sp=2)), "device mesh"),
-    "speculative": (dict(spec_decode_tokens=3), "speculative decode"),
-    "inline_waves": (dict(prefill_inline_budget=64), "inline prefill"),
-    "host_tier": (dict(host_cache_slots=64), "host KV tier"),
-    "disk_tier": (dict(host_cache_slots=64, kv_tier_dir="/nonexistent"),
-                  "disk KV tier"),
-}
-
-
-@pytest.mark.parametrize("what", sorted(REFUSED))
-def test_engine_refuses_by_name(model, what):
-    kw, name = REFUSED[what]
-    with pytest.raises(UnsupportedForModel, match=name):
-        make_engine(model, **(kw() if callable(kw) else kw))
-
-
 def test_refusal_names_what_was_asked_and_no_launch_count(model):
     """Every model decodes one step a launch now: the refusal says what
     the model serves with and nothing about multi-step decode."""
@@ -360,35 +260,6 @@ def test_refusal_names_what_was_asked_and_no_launch_count(model):
     assert "an int8 KV pool" in msg and "speculative decode" in msg
     assert msg.endswith("one chip, bf16 weights and KV (ROADMAP D6 / R4)")
     assert "multi-step" not in msg and "a launch" not in msg
-
-
-@pytest.mark.parametrize("what", ["pp", "disagg_decode", "disagg_prefill",
-                                  "init_int8", "hf_checkpoint", "sp_prefill"])
-def test_other_entries_refuse_by_name(model, what):
-    from radixmesh_tpu.engine.disagg import DecodeWorker, PrefillWorker
-    from radixmesh_tpu.models.llama import prefill_forward_sp
-
-    cfg, params = model
-    with pytest.raises(UnsupportedForModel) as e:
-        if what == "pp":
-            from jax.sharding import Mesh
-
-            make_engine(model, device_mesh=Mesh(
-                np.asarray(jax.devices()[:2]).reshape(2), ("pp",)))
-        elif what == "disagg_decode":
-            DecodeWorker(make_engine(model))
-        elif what == "disagg_prefill":
-            cfg_, params_ = model
-            PrefillWorker(cfg_, params_, num_slots=256, page_size=PAGE,
-                          max_batch=2, max_seq_len=64).prefill_handoff([1, 2, 3])
-        elif what == "init_int8":
-            init_params(cfg, jax.random.PRNGKey(0), weight_quant="int8")
-        elif what == "hf_checkpoint":
-            convert_hf_state_dict(cfg, {})
-        else:
-            prefill_forward_sp(params, cfg, jnp.zeros((1, 8), jnp.int32),
-                               jnp.arange(8)[None], _mesh(sp=2))
-    assert "does not support" in str(e.value)
 
 
 def _post(base, body, stream=False):

@@ -14,11 +14,9 @@ import pytest
 
 from benchmarks.reference import nemotron_h as ref
 from radixmesh_tpu.engine.engine import Engine, pool_groups
-from radixmesh_tpu.engine.request import SamplingParams
 from radixmesh_tpu.models import get_config, init_params
 from radixmesh_tpu.models.llama import (
     ModelConfig,
-    UnsupportedForModel,
     _ffn,
     _mixed_runs,
     read_moe_stats,
@@ -26,207 +24,49 @@ from radixmesh_tpu.models.llama import (
 from radixmesh_tpu.ops import kda, ssd
 from radixmesh_tpu.ops.kernel_check import SSD_CASES, SSD_TOLERANCE, check_ssd
 from radixmesh_tpu.ops.moe import moe_ffn
-from tests.test_mellum2 import REFUSED  # the same options, by name
+from tests.model_conformance import *  # noqa: F401,F403  (the shared cases)
+from tests.model_conformance import ModelSpec, distance, logits_from
 
 pytestmark = pytest.mark.quick
 
-PAGE = 16
-N_NEW = 9  # one token from the prefill, 8 decode steps through the slots
-TOL = 1e-4  # float32 against float32: other orders of the same sums
 
-
-@pytest.fixture(scope="module")
-def model():
-    cfg = get_config("nemotron-tiny").replace(dtype=jnp.float32)
-    return cfg, init_params(cfg, jax.random.PRNGKey(53))
-
-
-def make_engine(model, **kw):
-    cfg, params = model
-    kw.setdefault("num_slots", 4096)
-    kw.setdefault("page_size", PAGE)
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("max_seq_len", 1024)
-    kw.setdefault("prefill_chunk", 64)
-    return Engine(cfg, params, **kw)
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng([53, seed]).integers(0, 512, n).tolist()
-
-
-def run(eng, prompt, n_new=N_NEW):
-    req = eng.add_request(prompt, SamplingParams(
-        max_new_tokens=n_new, return_logits=True))
-    while eng.has_work():
-        eng.step()
-    return req
-
-
-def distance(got, want) -> float:
-    return float(np.abs(got - want).max() / np.abs(want).max())
-
-
-def error(model, req, prompt, **control):
-    """``max|a - b| / max|b|`` of the request's logits rows against the
-    reference's full forward pass of the same sequence."""
-    cfg, params = model
-    seq = list(prompt) + req.generated[:-1]
-    want = ref.forward(params, cfg, seq, logits_from=len(prompt) - 1, **control)
-    return distance(np.stack(req.logit_rows), want["logits"])
-
-
-def check(model, req, prompt, n_new=N_NEW):
-    assert len(req.generated) == n_new and len(req.logit_rows) == n_new
-    err = error(model, req, prompt)
-    assert err < TOL, err
-    assert [int(x) for x in np.stack(req.logit_rows).argmax(-1)] == req.generated
-
-
-# ---------------------------------------------------------------------------
-# the three entry points against the reference
-# ---------------------------------------------------------------------------
-
-
-# 7: inside one page; 48: a page multiple; 150: chunks of 64 over ten pages
-@pytest.mark.parametrize("n_prompt", [7, 48, 150])
-@pytest.mark.parametrize("path", ["dense", "chunk"])
-def test_prefill_then_decode_through_the_slots_and_the_pool(model, path, n_prompt):
-    eng = make_engine(
-        model, long_prefill_threshold=1024 if path == "dense" else 0)
-    prompt = prompt_of(n_prompt)
-    req = run(eng, prompt)
-    assert eng._last_prefill_path == path
-    check(model, req, prompt)
-    cfg = model[0]
-    # K and V of the two attention blocks alone in the pool; a float32
-    # matrix a head and the convolution's inputs in the state slots
+def _the_pool_and_the_slots(eng, **_):
+    """K and V of the two attention blocks alone in the pool; a float32
+    matrix a head and the convolution's inputs in the state slots; the
+    experts' ids and counters from the moe blocks alone."""
+    cfg = eng.cfg
     assert eng.pool.kv.shape == (2, 2, 2, 4096, 32)
     assert eng.pool.state.shape[0] == 7 and eng.pool.state.dtype == jnp.float32
     assert eng.pool.state.shape[2:] == (8 * 32 // 128 * 32, 128)
     assert eng.pool.state_conv.shape[2:] == (9, 128)  # 3 x 384
     assert eng.pool.state_slot_bytes == cfg.state_slot_bytes() == 7 * (
         64 * 128 * 4 + 9 * 128 * 4)
-    # the experts' ids and counters come from the moe blocks alone
     assert np.asarray(eng._last_expert_ids).shape[0] == 7
     assert read_moe_stats(eng._moe_stats)["decode"]["layer_launches"] == 7 * 8
 
 
-def test_a_batch_of_ragged_rows(model):
-    eng = make_engine(model)
-    prompts = [prompt_of(n, seed=30 + i) for i, n in enumerate((20, 75, 130, 41))]
-    reqs = [eng.add_request(p, SamplingParams(
-        max_new_tokens=N_NEW + i, return_logits=True))
-        for i, p in enumerate(prompts)]
-    while eng.has_work():
-        eng.step()
-    for i, (req, p) in enumerate(zip(reqs, prompts)):
-        check(model, req, p, n_new=N_NEW + i)
-
-
-def test_forty_decode_steps_keep_the_state_exact(model):
-    eng = make_engine(model)
-    prompt = prompt_of(50, seed=1)
-    req = run(eng, prompt, n_new=40)
-    check(model, req, prompt, n_new=40)
-
-
-# ---------------------------------------------------------------------------
-# every way a snapshot is reached
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("n_prompt, resumed", [(100, 96), (96, 80)])
-def test_the_identical_prompt_again_resumes_from_the_snapshot(
-        model, n_prompt, resumed):
-    eng = make_engine(model)
-    prompt = prompt_of(n_prompt, seed=3)
-    first = run(eng, prompt)
-    assert first.prefix_len == 0
-    again = run(eng, prompt)
-    third = run(eng, prompt)
-    assert third.prefix_len == resumed and eng._last_prefill_path == "chunk"
-    assert again.prefix_len == (resumed if n_prompt % PAGE else 0)
-    for req in (first, again, third):
-        check(model, req, prompt)
-    st = eng.state_stats
-    assert st["resumed_tokens"] == again.prefix_len + third.prefix_len
-    assert eng.stats.cached_tokens == st["resumed_tokens"]
-
-
-def test_the_next_turn_resumes_from_the_release_snapshot(model):
-    """Turn 2 resumes from the state the decode steps kept at the last page
-    boundary (a boundary copy; no prefill ever computed it)."""
-    eng = make_engine(model)
-    p1 = prompt_of(70, seed=4)
-    t1 = run(eng, p1, n_new=30)  # context 99 at release: cut at 96
-    check(model, t1, p1, n_new=30)
-    p2 = p1 + t1.generated + prompt_of(11, seed=5)
-    t2 = run(eng, p2)
-    assert t2.prefix_len == 96 and eng.state_stats["recomputed_tokens"] == 0
-    check(model, t2, p2)
-    p3 = p2 + t2.generated + prompt_of(5, seed=6)
-    t3 = run(eng, p3)
-    assert t3.prefix_len == (len(p2) + N_NEW - 1) // PAGE * PAGE
-    check(model, t3, p3)
-
-
-def test_one_launch_holds_a_row_that_resumes_and_a_cold_one(model):
-    eng = make_engine(model)
-    first = prompt_of(100, seed=11)
-    run(eng, first)
-    warm = first + prompt_of(80, seed=13)  # resumes at 96: 84 to compute
-    cold = prompt_of(90, seed=12)
-    reqs = [eng.add_request(p, SamplingParams(
-        max_new_tokens=N_NEW, return_logits=True)) for p in (warm, cold)]
-    while eng.has_work():
-        eng.step()
-    assert [r.prefix_len for r in reqs] == [96, 0]
-    check(model, reqs[0], warm)
-    check(model, reqs[1], cold)
-
-
-def test_a_zeroed_snapshot_would_not_pass(model):
-    """The control of the resume tests: the reference that forgets every
-    Mamba-2 state and convolution input at the resume position is far off."""
-    eng = make_engine(model)
-    prompt = prompt_of(100, seed=3)
-    run(eng, prompt)
-    again = run(eng, prompt)
-    assert again.prefix_len == 96
-    assert error(model, again, prompt) < TOL
-    assert error(model, again, prompt, forget_at=96) > 100 * TOL
-
-
-def test_a_snapshot_evicted_under_its_rows_is_computed_again(model):
-    eng = make_engine(model)
-    prompt = prompt_of(100, seed=14)
-    run(eng, prompt)
-    run(eng, prompt + prompt_of(30, seed=15))
-    n = eng.pool.state_slots_used - 1
-    assert n > 0 and eng.tree.evict_states(8) == n
-    m = eng.tree.match_prefix(np.asarray(prompt, np.int32))
-    assert m.length == 96 and m.states == []  # the K / V rows stay
-    again = run(eng, prompt)
-    assert again.prefix_len == 0 and eng.state_stats["recomputed_tokens"] == 96
-    check(model, again, prompt)
-    assert run(eng, prompt).prefix_len == 96  # the snapshot is back
-
-
-def test_plain_launches_keep_the_boundary_state(model):
-    sp = SamplingParams(max_new_tokens=70)
-    p1 = prompt_of(50, seed=17)
-    warm = make_engine(model)
-    o1 = warm.generate([p1], sp)[0]
-    p2 = p1 + o1 + prompt_of(7, seed=18)
-    o2 = warm.generate([p2], sp)[0]
-    assert warm.stats.cached_tokens == 112  # 50 + 70 - 1 = 119 -> 112
-    cold = make_engine(model)
-    assert cold.generate([p2], sp)[0] == o2
+SPEC = ModelSpec(
+    preset="nemotron-tiny", seed=53, reference=logits_from(ref.forward),
+    engine=dict(page_size=16, num_slots=4096, max_batch=4, max_seq_len=1024,
+                prefill_chunk=64),
+    # 7: inside one page; 48: a page multiple; 150: chunks of 64 over ten pages
+    lengths=(7, 48, 150),
+    parts={part: (dict(without=(part,)), 100) for part in (
+        "decay", "conv", "conv_bias", "skip", "gate", "group_norm", "groups",
+        "relu2", "shared", "scaling", "bias")} | {
+        "f32_state": (dict(state_dtype=jnp.bfloat16), 10)},
+    forget={"state": lambda at: dict(forget_at=at)},
+    says=("Mamba-2 (ssd) layers with a recurrent matrix state",
+          "blocks of one sublayer", "un-gated (relu2) experts"),
+    hooks={"prefill": _the_pool_and_the_slots},
+)
+make_engine, prompt_of, run, check = (
+    SPEC.make_engine, SPEC.prompt_of, SPEC.run, SPEC.check)
+PAGE, TOL = SPEC.engine["page_size"], SPEC.tol
 
 
 # ---------------------------------------------------------------------------
-# the state path: by bytes, a boundary copy on a page-completing step only
+# the state path, by bytes
 # ---------------------------------------------------------------------------
 
 
@@ -251,54 +91,6 @@ def test_state_slots_are_counted_by_bytes_at_published_widths():
             whole.layer_types.count("moe")) == (23, 6, 23)
     assert get_config("ling-tiny").matrix_state == "kda"
     assert get_config("lfm2-tiny").matrix_state is None
-
-
-def test_a_boundary_copy_is_made_on_the_step_that_completes_a_page(model):
-    eng = make_engine(model)
-    req = eng.add_request(prompt_of(20, seed=21), SamplingParams(
-        max_new_tokens=45, return_logits=True))
-    copies = []
-    while eng.has_work():
-        before = eng.matrix_stats["boundary_copies"]
-        kv_before = req.kv_len
-        eng.step()
-        eng._settle()
-        made = eng.matrix_stats["boundary_copies"] - before
-        copies.append(made)
-        if made:
-            assert req.kv_len % PAGE == 0 and req.kv_len == kv_before + 1
-            work, keep = req.state_slot, req.state_boundary
-            if work and keep:  # still in its row
-                assert np.array_equal(eng.pool.state[:, work], eng.pool.state[:, keep])
-                assert np.array_equal(
-                    eng.pool.state_conv[:, work], eng.pool.state_conv[:, keep])
-    assert sum(copies) == 3
-    st = eng.matrix_stats
-    assert st["decode_rows"] == 44 and st["chunk_tokens"] == 20
-    per = eng.pool.state_slot_bytes
-    assert st["state_bytes_written"] == (44 * 4 + 3 + 3) * per
-    assert st["state_bytes_read"] == 44 * 4 * per
-
-
-def test_a_full_state_group_gives_up_superseded_snapshots_first(model):
-    eng = make_engine(model, max_batch=2)
-    assert eng._ordered_evict
-    taken = []
-    while eng.pool.state_allocator.free_slots > 4:
-        taken.append(eng.pool.alloc_state())
-    p = prompt_of(60, seed=22)
-    first = run(eng, p, n_new=40)
-    check(model, first, p, n_new=40)
-    p2 = p + first.generated + prompt_of(9, seed=23)
-    second = run(eng, p2, n_new=40)
-    assert second.prefix_len == 96
-    check(model, second, p2, n_new=40)
-    p3 = p2 + second.generated + prompt_of(9, seed=24)
-    third = run(eng, p3, n_new=40)
-    check(model, third, p3, n_new=40)
-    assert eng.tree.state_evictions > 0 and eng.stats.preemptions == 0
-    for slot in taken:
-        eng.pool.free_state(slot)
 
 
 # ---------------------------------------------------------------------------
@@ -436,28 +228,6 @@ def test_a_launch_of_more_rows_than_a_call_takes_is_several_calls_on_one_array()
     assert distance(np.asarray(got[1]), np.asarray(want[1])) < KERNEL_TOL
 
 
-def _inner(eqn):
-    for val in eqn.params.values():
-        for x in val if isinstance(val, (list, tuple)) else (val,):
-            x = getattr(x, "jaxpr", x)
-            if hasattr(x, "eqns"):
-                yield x
-
-
-def _eqns(jaxpr) -> int:
-    return sum(1 + sum(map(_eqns, _inner(e))) for e in jaxpr.eqns)
-
-
-def _chunk_calls(jaxpr) -> list:
-    found = []
-    for e in jaxpr.eqns:
-        if e.primitive.name == "pallas_call":
-            found += [e] * ("radixmesh_ssd_chunk" in str(e.params["name"]))
-        else:
-            found += [c for j in _inner(e) for c in _chunk_calls(j)]
-    return found
-
-
 def test_the_chunk_kernels_trace_does_not_grow_with_heads_groups_or_rows(model):
     """What set-up pays for the kernel a program (PR 49 was refused for it):
     the body's equations do not grow with the heads, the groups, the
@@ -476,7 +246,8 @@ def test_the_chunk_kernels_trace_does_not_grow_with_heads_groups_or_rows(model):
             sd((B, T, groups, N), f32), sd((B, T, groups, N), f32),
             sd((1, 9, heads * P // 128 * N, 128), f32), sd((), i32),
             sd((B,), i32), sd((B,), i32), sd((B, K), i32), sd((B, K), i32))
-        return max(_eqns(c.params["jaxpr"]) for c in _chunk_calls(jaxpr.jaxpr))
+        return max(equations(c.params["jaxpr"])
+                   for c in kernel_calls(jaxpr.jaxpr, "radixmesh_ssd_chunk"))
 
     assert body(1) == body(2) == body(8) <= 700
     assert body(2, T=16) == body(2) == body(2, T=512) == body(2, B=33)
@@ -498,8 +269,8 @@ def test_the_chunk_kernels_trace_does_not_grow_with_heads_groups_or_rows(model):
             state_out=ints(rows, 3), moe_stats=moe, use_kernel=True)
         # ONE unit of the block pattern is traced (3 mamba2 blocks) and the
         # two blocks left over (1): not the 7 the config holds
-        assert len(_chunk_calls(traced.jaxpr.jaxpr)) == (3 + 1) * -(
-            -rows // ssd._ROWS_A_CALL)
+        calls = kernel_calls(traced.jaxpr.jaxpr, "radixmesh_ssd_chunk")
+        assert len(calls) == (3 + 1) * -(-rows // ssd._ROWS_A_CALL)
 
 
 def test_the_decode_kernel_updates_the_rows_slots_in_place_and_no_other():
@@ -550,29 +321,6 @@ def test_the_kernel_check_tells_the_states_precision(state_dtype):
     else:
         assert not chunk["ok"] and not decode["ok"], res
         assert min(chunk["pool_write_diff"], decode["pool_write_diff"]) > 10 * SSD_TOLERANCE
-
-
-# ---------------------------------------------------------------------------
-# the controls: what must not agree
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("part", [
-    "decay", "conv", "conv_bias", "skip", "gate", "group_norm", "groups",
-    "relu2", "shared", "scaling", "bias"])
-def test_the_reference_without_a_part_is_refused(model, part):
-    eng = make_engine(model)
-    prompt = prompt_of(60, seed=50)
-    req = run(eng, prompt)
-    assert error(model, req, prompt) < TOL
-    assert error(model, req, prompt, without=(part,)) > 100 * TOL
-
-
-def test_a_bf16_state_is_refused(model):
-    eng = make_engine(model)
-    prompt = prompt_of(60, seed=51)
-    req = run(eng, prompt)
-    assert error(model, req, prompt, state_dtype=jnp.bfloat16) > 10 * TOL
 
 
 # ---------------------------------------------------------------------------
@@ -637,28 +385,6 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up(model):
 # ---------------------------------------------------------------------------
 # what is refused, and what the config refuses
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("what", sorted(REFUSED))
-def test_what_assumes_the_dense_layer_is_refused(model, what):
-    kw, name = REFUSED[what]
-    with pytest.raises(UnsupportedForModel, match=name) as e:
-        make_engine(model, **(kw() if callable(kw) else kw))
-    assert "Mamba-2 (ssd) layers" in str(e.value)
-    assert "blocks of one sublayer" in str(e.value)
-    assert "un-gated (relu2) experts" in str(e.value)
-
-
-@pytest.mark.parametrize("option", [
-    {"stream_publish_tokens": 8}, {"weight_quant": "int8"},
-    {"spec_decode_tokens": 2}])
-def test_what_moves_a_prefix_without_its_state_is_refused(model, option):
-    cfg, params = model
-    with pytest.raises(UnsupportedForModel, match="matrix state"):
-        if "weight_quant" in option:
-            init_params(cfg, jax.random.PRNGKey(0), **option)
-        else:
-            make_engine(model, **option)
 
 
 @pytest.mark.parametrize("change, message", [

@@ -6,7 +6,6 @@ not tokens — and what must NOT agree (the controls), what is refused, what
 the programs lower to for a v5e at published widths."""
 
 import hashlib
-import re
 
 import jax
 import jax.numpy as jnp
@@ -15,12 +14,11 @@ import pytest
 
 from benchmarks.reference import phi4flash as ref
 from radixmesh_tpu.cache.radix_tree import RadixTree
-from radixmesh_tpu.engine.engine import Engine, pool_groups
+from radixmesh_tpu.engine.engine import pool_groups
 from radixmesh_tpu.engine.request import RequestState, SamplingParams
-from radixmesh_tpu.models import get_config, init_params
+from radixmesh_tpu.models import get_config
 from radixmesh_tpu.models.llama import (
     ModelConfig,
-    UnsupportedForModel,
     _diff_combine,
     _init_params,
     _pair_heads,
@@ -31,81 +29,23 @@ from radixmesh_tpu.models.llama import (
 from radixmesh_tpu.ops import ssm
 from radixmesh_tpu.ops.attention import attend_prefill
 from radixmesh_tpu.ops.norm import layer_norm, rms_norm
-from tests.test_mellum2 import REFUSED  # the same options, by name
+from tests.model_conformance import *  # noqa: F401,F403  (the shared cases)
+from tests.model_conformance import ModelSpec
 
 pytestmark = pytest.mark.quick
 
-PAGE = 16
 WINDOW = 32
-N_NEW = 9  # one token from the prefill, 8 decode steps through the pool
-TOL = 1e-4  # float32 against float32: other orders of the same sums
 
 
-@pytest.fixture(scope="module")
-def model():
-    cfg = get_config("phi4flash-tiny").replace(dtype=jnp.float32)
-    return cfg, init_params(cfg, jax.random.PRNGKey(41))
+def reference(params, cfg, seq, logits_from, **control):
+    return ref.forward(params, cfg, seq, at=np.arange(logits_from, len(seq)),
+                       **control)
 
 
-def make_engine(model, **kw):
-    cfg, params = model
-    kw.setdefault("num_slots", 4096)
-    kw.setdefault("page_size", PAGE)
-    kw.setdefault("max_batch", 4)
-    kw.setdefault("max_seq_len", 1024)
-    kw.setdefault("prefill_chunk", 64)
-    return Engine(cfg, params, **kw)
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng([41, seed]).integers(0, 512, n).tolist()
-
-
-def run(eng, prompt, n_new=N_NEW):
-    req = eng.add_request(prompt, SamplingParams(
-        max_new_tokens=n_new, return_logits=True))
-    while eng.has_work():
-        eng.step()
-    return req
-
-
-def error(model, req, prompt, at=None, **control):
-    """``max|a - b| / max|b|`` of the request's logits rows against the
-    reference's full forward pass of the same sequence (``at``: the rows
-    compared, default all)."""
-    cfg, params = model
-    seq = list(prompt) + req.generated[:-1]
-    rows = np.arange(len(req.logit_rows)) if at is None else np.asarray(at)
-    want = ref.forward(params, cfg, seq, at=len(prompt) - 1 + rows, **control)
-    return ref.distance(np.stack(req.logit_rows)[rows], want)[0]
-
-
-def check(model, req, prompt, n_new=N_NEW):
-    assert len(req.generated) == n_new and len(req.logit_rows) == n_new
-    err = error(model, req, prompt)
-    assert err < TOL, err
-    assert [int(x) for x in np.stack(req.logit_rows).argmax(-1)] == req.generated
-
-
-# ---------------------------------------------------------------------------
-# the three entry points against the reference
-# ---------------------------------------------------------------------------
-
-
-# 7: inside one page; 48: a page multiple past the window; 100: chunks of
-# 64 that cut the window (32) and the snapshot at 96
-@pytest.mark.parametrize("n_prompt", [7, 48, 100])
-@pytest.mark.parametrize("path", ["dense", "chunk"])
-def test_prefill_then_decode_through_both_groups_and_the_state(model, path, n_prompt):
-    eng = make_engine(
-        model, long_prefill_threshold=1024 if path == "dense" else 0)
-    prompt = prompt_of(n_prompt)
-    req = run(eng, prompt)
-    assert eng._last_prefill_path == path
-    check(model, req, prompt)
-    cfg = model[0]
-    # rows of the full layer alone in the pool, of the two window layers in
-    # the window group, two heads to a 128-lane row; float32 states
+def _both_groups_and_the_slots(eng, **_):
+    """Rows of the full layer alone in the pool, of the two window layers in
+    the window group, two heads to a 128-lane row; float32 states."""
+    cfg = eng.cfg
     assert eng.pool.kv.shape == (2, 1, 1, 4096, 128)
     assert eng.pool.window.shape == (2, 2, 1, eng.pool.window_slots, 128)
     assert eng.pool.state.shape[0] == 3 and eng.pool.state.dtype == jnp.float32
@@ -115,30 +55,34 @@ def test_prefill_then_decode_through_both_groups_and_the_state(model, path, n_pr
         16 * 512 * 4 + 3 * 512 * 4)
 
 
-def test_eighty_decode_steps_release_window_pages_and_still_agree(model):
-    """The window slides two windows past the prompt: the row gives back
-    what left it, never holds more than window + 2 pages, and the last
-    logits agree with the reference's full forward pass as the first do."""
-    eng = make_engine(model)
-    prompt = prompt_of(50, seed=1)
-    req = eng.add_request(prompt, SamplingParams(
-        max_new_tokens=90, return_logits=True))
-    held = []
-    while eng.has_work():
-        eng.step()
-        eng._settle()
-        if req.row >= 0:
-            held.append(len(req.w_pages) * PAGE)
-    check(model, req, prompt, n_new=90)
-    assert max(held) <= WINDOW + 2 * PAGE
-    ws = eng.window_stats
-    assert ws["held_peak_row"] <= WINDOW + 2 * PAGE
-    assert ws["pages_released"] >= (50 + 90 - WINDOW) // PAGE - 1
-    # what is left: the release snapshot's tail and the prompt-cut's
-    # (counted a tail each; a page two tails share is held once)
-    assert 0 < eng.pool.window_slots_used <= ws["tail_slots_in_tree"]
+def _the_cross_decoder_read_the_full_layers_rows(eng, **_):
     assert eng.attn_window["shared_kv_tokens_read"] > 0
-    assert eng.attn_window["window_tokens_skipped"] > 0
+
+
+SPEC = ModelSpec(
+    preset="phi4flash-tiny", seed=41, reference=reference,
+    engine=dict(page_size=16, num_slots=4096, max_batch=4, max_seq_len=1024,
+                prefill_chunk=64),
+    # 7: inside one page; 48: a page multiple past the window; 100: chunks of
+    # 64 that cut the window (32) and the snapshot at 96
+    lengths=(7, 48, 100),
+    # each must fail the tolerance the paths pass: lambda forced to zero, a
+    # window of 33, int8 weights
+    parts={"lambda": (dict(lambda_zero=True), 100),
+           "window_of_32": (dict(window=WINDOW + 1), 100),
+           "int8_weights": (dict(weight_map=ref.int8_weights), 100)},
+    parts_prompt=100,
+    forget={"state": lambda at: dict(forget_state_at=at),
+            "tail": lambda at: dict(zero_tail_before=at)},
+    forget_is_exact=("state", "tail"),
+    says=("state-space layers with a recurrent state",
+          "a cross-decoder that reads one layer's K/V"),
+    hooks={"prefill": _both_groups_and_the_slots,
+           "long_decode": _the_cross_decoder_read_the_full_layers_rows},
+)
+make_engine, prompt_of, run, error, check = (
+    SPEC.make_engine, SPEC.prompt_of, SPEC.run, SPEC.error, SPEC.check)
+PAGE, TOL = SPEC.engine["page_size"], SPEC.tol
 
 
 def test_the_cross_decoder_at_the_last_position_equals_every_position(model):
@@ -153,198 +97,9 @@ def test_the_cross_decoder_at_the_last_position_equals_every_position(model):
             assert error(model, req, prompt_of(n, seed=2)) < TOL
 
 
-def test_a_batch_of_ragged_rows(model):
-    eng = make_engine(model)
-    prompts = [prompt_of(n, seed=30 + i) for i, n in enumerate((20, 75, 130, 41))]
-    reqs = [eng.add_request(p, SamplingParams(
-        max_new_tokens=N_NEW + i, return_logits=True))
-        for i, p in enumerate(prompts)]
-    while eng.has_work():
-        eng.step()
-    for i, (req, p) in enumerate(zip(reqs, prompts)):
-        check(model, req, p, n_new=N_NEW + i)
-
-
 # ---------------------------------------------------------------------------
-# every way a snapshot (state + window tail) is reached
+# a snapshot is a state AND a window tail: splits, pressure, a closed loop
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("n_prompt, resumed", [(100, 96), (96, 80)])
-def test_the_identical_prompt_again_resumes_from_the_snapshot(
-        model, n_prompt, resumed):
-    eng = make_engine(model)
-    prompt = prompt_of(n_prompt, seed=3)
-    first = run(eng, prompt)
-    assert first.prefix_len == 0
-    again = run(eng, prompt)
-    third = run(eng, prompt)
-    assert third.prefix_len == resumed and eng._last_prefill_path == "chunk"
-    assert again.prefix_len == (resumed if n_prompt % PAGE else 0)
-    for req in (first, again, third):
-        check(model, req, prompt)
-    st = eng.state_stats
-    assert st["resumed_tokens"] == again.prefix_len + third.prefix_len
-    assert eng.stats.cached_tokens == st["resumed_tokens"]
-
-
-def test_the_next_turn_resumes_from_the_release_snapshot(model):
-    """Turn 2 = turn 1's prompt + what it generated + a few new tokens, at
-    a length that is no page multiple: it resumes from the state the
-    decode steps kept at the last page boundary and the window tail the
-    row held there (none of either was ever prefilled)."""
-    eng = make_engine(model)
-    p1 = prompt_of(70, seed=4)
-    t1 = run(eng, p1, n_new=30)  # context 99 at release: cut at 96
-    check(model, t1, p1, n_new=30)
-    p2 = p1 + t1.generated + prompt_of(11, seed=5)
-    t2 = run(eng, p2)
-    assert t2.prefix_len == 96 and eng.state_stats["recomputed_tokens"] == 0
-    check(model, t2, p2)
-    p3 = p2 + t2.generated + prompt_of(5, seed=6)
-    t3 = run(eng, p3)
-    assert t3.prefix_len == (len(p2) + N_NEW - 1) // PAGE * PAGE
-    check(model, t3, p3)
-
-
-def test_two_requests_branch_inside_a_shared_prefix(model):
-    eng = make_engine(model)
-    shared = prompt_of(64, seed=7)
-    a = shared + prompt_of(50, seed=8)
-    run(eng, a)
-    b = shared + prompt_of(40, seed=9)
-    rb = run(eng, b)
-    assert rb.prefix_len == 0  # rows for 64 tokens, no snapshot there
-    assert eng.state_stats["recomputed_tokens"] == 64
-    c = shared + prompt_of(33, seed=10)
-    rc = run(eng, c)
-    assert rc.prefix_len == 64  # b left one at the branch point (rule b)
-    assert eng.state_stats["recomputed_tokens"] == 64
-    check(model, rb, b)
-    check(model, rc, c)
-
-
-def test_a_split_leaves_state_and_tail_with_the_lower_half(model):
-    eng = make_engine(model)
-    a = prompt_of(115, seed=11)
-    run(eng, a)
-    b = a[:48] + prompt_of(30, seed=12)
-    rb = run(eng, b)
-    assert rb.prefix_len == 0
-    ra = run(eng, a)
-    assert ra.prefix_len == 112
-    check(model, ra, a)
-    m = eng.tree.match_prefix(np.asarray(a, np.int32))
-    assert [pos for pos, _ in m.states] == [48, 112]
-    assert [len(t) for t in m.tails] == [2, 2]  # a window = 2 pages each
-    assert m.tail_at(112) is m.tails[1] and m.tail_at(64) is None
-    rb2 = run(eng, b)
-    assert rb2.prefix_len == 64
-    check(model, rb2, b)
-    c = a[:80] + prompt_of(9, seed=13)  # ends inside a's lower half
-    rc = run(eng, c)
-    assert rc.prefix_len == 48
-    check(model, rc, c)
-
-
-def test_a_snapshot_evicted_under_its_rows_frees_state_and_tail(model):
-    eng = make_engine(model)
-    prompt = prompt_of(100, seed=14)
-    run(eng, prompt)
-    run(eng, prompt + prompt_of(30, seed=15))
-    assert 0 < eng.pool.window_slots_used <= eng.window_stats["tail_slots_in_tree"]
-    n = eng.pool.state_slots_used - 1
-    assert eng.tree.evict_states(8) == n
-    assert eng.pool.state_slots_used == 1 and eng.pool.window_slots_used == 0
-    assert eng.window_stats["tail_slots_in_tree"] == 0
-    m = eng.tree.match_prefix(np.asarray(prompt, np.int32))
-    assert m.length == 96 and m.states == []  # the full layer's rows stay
-    again = run(eng, prompt)
-    assert again.prefix_len == 0 and eng.state_stats["recomputed_tokens"] == 96
-    check(model, again, prompt)
-    assert run(eng, prompt).prefix_len == 96  # the snapshot is back
-
-
-def test_a_preempted_request_resumes_from_what_it_published(model):
-    eng = make_engine(model)
-    prompt = prompt_of(90, seed=16)
-    req = eng.add_request(prompt, SamplingParams(
-        max_new_tokens=20, return_logits=True))
-    while req.kv_len < 98:
-        eng.step()
-        eng._settle()
-    eng._preempt(req)
-    assert req.w_pages == [] and 0 < eng.pool.window_slots_used <= (
-        eng.window_stats["tail_slots_in_tree"])
-    req.logit_rows.clear()
-    eng._pressure = False
-    while eng.has_work():
-        eng.step()
-    assert eng.stats.preemptions == 1 and req.prefix_len == 80
-    check(model, req, prompt, n_new=20)
-
-
-def test_plain_launches_keep_the_boundary_state_and_the_tail(model):
-    """Without the logits hook the decode launches hand over (inputs built
-    a step ahead, pages released behind the next launch): a session served
-    that way continues exactly as a cold engine computes it."""
-    sp = SamplingParams(max_new_tokens=70)
-    p1 = prompt_of(50, seed=17)
-    warm = make_engine(model)
-    o1 = warm.generate([p1], sp)[0]
-    p2 = p1 + o1 + prompt_of(7, seed=18)
-    o2 = warm.generate([p2], sp)[0]
-    assert warm.handover["launches_prebuilt"] > 0
-    assert warm.stats.cached_tokens == 112  # 50 + 70 - 1 = 119 -> 112
-    assert warm.window_stats["pages_released"] > 0
-    cold = make_engine(model)
-    assert cold.generate([p2], sp)[0] == o2
-    p3 = p2 + o2 + prompt_of(3, seed=19)
-    req = run(warm, p3)
-    assert req.prefix_len == (len(p2) + 70 - 1) // PAGE * PAGE
-    check(model, req, p3)
-
-
-# 15: the launch that makes its last token completes page 4 (kv_len 64), so
-# the boundary state and the tail its release hands over is that launch's; 11: kv_len 60
-@pytest.mark.parametrize("n_new", [15, 11], ids=["on_a_page", "inside_a_page"])
-def test_a_row_that_ends_by_length_is_released_behind_the_next_launch(model, n_new):
-    """A request reaches its length cap while another goes on (70 tokens:
-    its window slides, and gives pages back, all the while): the next
-    launch goes out first, without it, and it is advanced, released and
-    published behind that launch. What its release gives the tree is what
-    its OWN last launch left: its next turn resumes there and equals the
-    reference (logits, not tokens), the other row's too, and the run
-    agrees with one that settles after every step."""
-    pa, pb = prompt_of(50, seed=20), prompt_of(37, seed=21)
-
-    def serve(settled):
-        eng = make_engine(model)
-        a = eng.add_request(pa, SamplingParams(max_new_tokens=n_new))
-        b = eng.add_request(pb, SamplingParams(max_new_tokens=70))
-        while eng.has_work():
-            eng.step()
-            if settled:
-                eng._settle()
-        assert eng.handover["finishes_deferred"] == (0 if settled else 1)
-        turns = [pa + a.generated + prompt_of(7, seed=22),
-                 pb + b.generated + prompt_of(5, seed=23)]
-        return eng, [a, b] + [run(eng, t) for t in turns], turns
-
-    eng, reqs, turns = serve(settled=False)
-    cut = (50 + n_new - 1) // PAGE * PAGE
-    assert reqs[2].prefix_len == cut and reqs[3].prefix_len == 96
-    assert eng.window_stats["pages_released"] > 0
-    for req, turn in zip(reqs[2:], turns):
-        check(model, req, turn)
-    eng2, reqs2, _ = serve(settled=True)
-    assert [r.generated for r in reqs] == [r.generated for r in reqs2]
-    assert [r.prefix_len for r in reqs] == [r.prefix_len for r in reqs2]
-    assert eng.state_stats == eng2.state_stats
-    assert eng.window_stats == eng2.window_stats
-    assert eng.pool.window_slots_used == eng2.pool.window_slots_used
-    assert eng.pool.free_slots == eng2.pool.free_slots
-    assert eng.tree.evictable_size_ == eng2.tree.evictable_size_
 
 
 def test_pressure_on_the_window_group_evicts_snapshots_not_rows(model):
@@ -437,59 +192,6 @@ def test_a_prompt_the_window_group_cannot_hold_is_refused_by_name(model):
     with pytest.raises(ValueError, match="window group"):
         eng.add_request(prompt_of(have + 1), SamplingParams(max_new_tokens=2))
     assert len(run(eng, prompt_of(40), n_new=2).generated) == 2
-
-
-# ---------------------------------------------------------------------------
-# controls: each must fail the tolerance the paths above pass
-# ---------------------------------------------------------------------------
-
-
-def _second_turn(model, spoil=None):
-    eng = make_engine(model)
-    p1 = prompt_of(70, seed=4)
-    t1 = run(eng, p1, n_new=30)
-    if spoil is not None:
-        spoil(eng)
-    p2 = p1 + t1.generated + prompt_of(11, seed=5)
-    t2 = run(eng, p2)
-    assert t2.prefix_len == 96
-    return t2, p2
-
-
-def test_control_a_zeroed_restored_state_fails(model):
-    def spoil(eng):
-        eng.pool.state = jnp.zeros_like(eng.pool.state)
-        eng.pool.state_conv = jnp.zeros_like(eng.pool.state_conv)
-
-    t2, p2 = _second_turn(model, spoil)
-    assert error(model, t2, p2) > 100 * TOL
-    # ... and the reference's own control reads what the program computed
-    assert error(model, t2, p2, forget_state_at=96) < TOL
-
-
-def test_control_a_zeroed_restored_tail_fails(model):
-    def spoil(eng):
-        eng.pool.window = jnp.zeros_like(eng.pool.window)
-
-    t2, p2 = _second_turn(model, spoil)
-    assert error(model, t2, p2) > 100 * TOL
-    assert error(model, t2, p2, zero_tail_before=96) < TOL
-
-
-def test_control_lambda_forced_to_zero_fails(model):
-    t2, p2 = _second_turn(model)
-    assert error(model, t2, p2) < TOL
-    assert error(model, t2, p2, lambda_zero=True) > 100 * TOL
-
-
-def test_control_a_window_of_33_fails(model):
-    t2, p2 = _second_turn(model)
-    assert error(model, t2, p2, window=WINDOW + 1) > 100 * TOL
-
-
-def test_control_int8_weights_fail(model):
-    t2, p2 = _second_turn(model)
-    assert error(model, t2, p2, weight_map=ref.int8_weights) > 100 * TOL
 
 
 # ---------------------------------------------------------------------------
@@ -674,21 +376,6 @@ def test_the_published_configuration_by_the_numbers():
     assert lfm2.window_pool_shape(64) is None and lfm2.conv_state_shape(4) is None
 
 
-@pytest.mark.parametrize("what", sorted(REFUSED))
-def test_engine_refuses_by_name(model, what):
-    kw, name = REFUSED[what]
-    with pytest.raises(UnsupportedForModel, match=name) as e:
-        make_engine(model, **(kw() if callable(kw) else kw))
-    assert "state-space layers with a recurrent state" in str(e.value)
-    assert "a cross-decoder that reads one layer's K/V" in str(e.value)
-
-
-def test_the_prefix_ring_and_mid_decode_publishing_are_refused(model):
-    for kw in ({"mesh": object()}, {"stream_publish_tokens": 8}):
-        with pytest.raises(UnsupportedForModel, match="state-space"):
-            make_engine(model, **kw)
-
-
 @pytest.mark.parametrize("change, message", [
     (dict(layer_types=("mamba", "cross_attention", "mamba", "sliding_attention",
                        "mamba", "full_attention", "gmu", "cross_attention")),
@@ -719,27 +406,10 @@ def test_a_layer_pattern_it_cannot_run_is_refused_by_name(change, message):
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", cached)
-
-
 def _published(v5e):
     """Published widths and depth, a small vocabulary: shapes only."""
     cfg = get_config("phi-4-mini-flash-reasoning").replace(vocab_size=4096)
-
-    def sd(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    shapes = jax.eval_shape(lambda k: _init_params(k, cfg, None, None),
-                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    shapes, params, sd = abstract_params(cfg, v5e)
     assert shapes["mamba"]["in_x"].shape == shapes["mamba"]["in_z"].shape == (
         9, 2560, 5120)
     assert shapes["mamba"]["x_proj"].shape == (9, 5120, 192)
@@ -752,16 +422,12 @@ def _published(v5e):
         window_pool=sd(groups["window_shape"], cfg.dtype),
         state_pool=(sd(groups["state_shape"], jnp.float32),
                     sd(groups["conv_state_shape"], cfg.dtype)))
-    return cfg, jax.tree.map(lambda x: sd(x.shape, x.dtype), shapes), sd, pools
+    return cfg, params, sd, pools
 
 
 def _aliased_and_uncopied(compiled, pools):
-    arrays = [pools["kv"], pools["window_pool"], *pools["state_pool"]]
-    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arrays)
-    assert compiled.memory_analysis().alias_size_in_bytes >= held
-    shapes = {",".join(map(str, a.shape)) for a in arrays}
-    copied = set(re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", compiled.as_text()))
-    assert not shapes & copied, shapes & copied
+    aliased_and_uncopied(
+        compiled, [pools["kv"], pools["window_pool"], *pools["state_pool"]])
 
 
 def test_decode_lowers_for_a_v5e_at_published_widths(v5e):
@@ -790,7 +456,7 @@ def test_chunk_lowers_for_a_v5e_at_published_widths(v5e):
         state_in=sd((2,), i32), snap_at=sd((2, 3), i32),
         state_out=sd((2, 3), i32), window_pool=pools["window_pool"],
         window_slots=sd((2, 256), i32), window_table=sd((2, 256), i32)).compile()
-    names = set(re.findall(r"radixmesh_[a-z_]+[a-z]", compiled.as_text()))
+    names = kernels_in(compiled)
     assert {"radixmesh_chunk_attn", "radixmesh_decode_fused_mh",
             "radixmesh_ssm_scan"} <= names, names
     _aliased_and_uncopied(compiled, pools)
